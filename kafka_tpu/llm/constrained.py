@@ -985,7 +985,7 @@ class ToolCallMaskFn:
 # ---------------------------------------------------------------------------
 #
 # The host mask path above needs the previous token back on host before it
-# can build the next mask — on tunneled links that is ~RTT per constrained
+# can build the next mask — one device->host round trip per constrained
 # token.  compile_tool_call_grammar() lowers the SAME automaton into three
 # dense arrays a jitted decode step can consume with zero host round trips:
 #
@@ -1415,7 +1415,12 @@ def compile_grammar_for_mask_fn(
     if cache is not None and key in cache:
         return cache[key]
     if defer is None:
-        defer = vocab_size > _grammar_sync_vocab()
+        # compile cost scales with the tokens the compiler must index, not
+        # with the model's embedding rows: a byte tokenizer padded out to a
+        # 128k-row model (ByteTokenizer.mask_vocab_size) still compiles in
+        # well under a second and stays synchronous
+        defer = min(vocab_size, getattr(tok, "mask_vocab_size", vocab_size)
+                    ) > _grammar_sync_vocab()
     if defer:
         _enqueue_deferred(tok, mask_fn, vocab_size, key)
         return None  # host-mask path now; on-device once the table lands

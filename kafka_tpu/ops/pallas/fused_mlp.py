@@ -1,7 +1,7 @@
 """Fused decode-MLP block: rmsnorm + SwiGLU + residual in one kernel.
 
-MEASURED OUTCOME (round 5, scripts/bench_fused_mlp.py on the v5e chip,
-device-resident timing with RTT differencing): this kernel does NOT beat
+MEASURED OUTCOME (round 5, a since-deleted micro-benchmark on an earlier
+v5e machine; not measured on the current one): this kernel does NOT beat
 XLA's own formulation at decode shapes and is therefore NOT wired into
 the serving path.  At llama-3.2-1b shapes (H=2048, F=8192, L=16, B=8),
 16-layer MLP stack per pass:

@@ -39,10 +39,9 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
-from ...compat import shard_map
 
 NEG_INF = -1e30
 

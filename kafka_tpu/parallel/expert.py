@@ -25,10 +25,8 @@ from typing import Dict
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-from ..compat import shard_map
 
 Params = Dict[str, jnp.ndarray]
 

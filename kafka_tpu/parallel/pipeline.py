@@ -38,10 +38,10 @@ from typing import Any, Dict
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
+from jax.lax import pcast
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ..compat import pcast, shard_map
 from ..models.config import ModelConfig
 from ..models.llama import Params, _attention_block, _mlp_block
 from ..ops.norms import rms_norm

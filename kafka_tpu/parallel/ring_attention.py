@@ -30,10 +30,10 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
+from jax.lax import pcast
 from jax.sharding import Mesh, PartitionSpec as P
 
-from ..compat import pcast, shard_map
 from ..ops.attention import NEG_INF, repeat_kv
 
 

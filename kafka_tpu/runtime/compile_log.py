@@ -15,7 +15,7 @@ compile axis:
   the function unchanged and no listener ever registers).  One record =
   one compilation: program label (the engine's ``_FN_CACHE`` tag),
   wall-clock seconds, persistent-cache disposition (``hit`` / ``miss``
-  / ``off`` — the ``compile_cache_dir`` wired in ``server/config.py``),
+  / ``off`` — the directory :func:`enable_compile_cache` reports),
   and the engine phase that triggered it (``boot`` / ``warmup`` /
   ``first_traffic`` / ``rebuild``).
 
@@ -61,12 +61,22 @@ RING_ENV = "KAFKA_TPU_COMPILE_RING"
 STORM_N_ENV = "KAFKA_TPU_COMPILE_STORM_N"
 STORM_S_ENV = "KAFKA_TPU_COMPILE_STORM_S"
 
-# the jax.monitoring event that fires once per real backend compile
-# (probed on jax 0.4.37; silent for cached-executable calls)
+# the jax.monitoring duration event that brackets compile_or_get_cached
+# (jax._src.dispatch.BACKEND_COMPILE_EVENT): fires once per program the
+# process had not compiled yet — persistent-cache hit or miss alike —
+# and stays silent for already-compiled calls
 _COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
-# fired per compile request when the persistent cache is enabled; the
-# presence of a cache *hit* event marks the in-flight label as "hit"
+# fired (jax._src.compiler) when the persistent cache served the request,
+# BEFORE the duration event above closes: it marks the in-flight compile
+# so the record that follows lands as "hit"
 _CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
+
+# JAX's own variable for the persistent-cache directory; when set, JAX
+# reads it and this module only reports it (enable_compile_cache)
+CACHE_DIR_ENV = "JAX_COMPILATION_CACHE_DIR"
+# off switch ("0"/"off"/"false"/""): the test suite disables the cache
+# because serializing CPU SPMD executables has crashed it
+CACHE_SWITCH_ENV = "KAFKA_TPU_COMPILE_CACHE"
 
 PHASES = ("boot", "warmup", "first_traffic", "rebuild")
 
@@ -150,11 +160,13 @@ class CompileObservatory:
 
     def record(self, label: str, seconds: float,
                cache: Optional[str] = None,
-               now: Optional[float] = None) -> None:
+               now: Optional[float] = None,
+               fn: Optional[str] = None) -> None:
         """One compilation happened.  ``cache`` defaults from the
         persistent-cache configuration: ``off`` when no cache dir is
         configured, ``miss`` otherwise (a hit is marked explicitly by
-        the cache-hit listener)."""
+        the cache-hit listener).  ``fn`` is jax's own name for the
+        program — what tells the unlabeled ``?`` records apart."""
         now = time.time() if now is None else now
         if cache is None:
             cache = "miss" if self.cache_dir else "off"
@@ -167,6 +179,8 @@ class CompileObservatory:
                 "cache": cache,
                 "phase": self.phase,
             }
+            if fn:
+                rec["fn"] = fn
             if len(self._ring) < self.size:
                 self._ring.append(rec)
             else:
@@ -199,21 +213,16 @@ class CompileObservatory:
                         label, seconds, self.phase, cache)
 
     def mark_cache_hit(self) -> None:
-        """The persistent cache served the in-flight compile (seen via
-        the cache-hit monitoring event).  Rewrites the most recent
-        record for the current label context, or records a zero-cost
-        hit if the backend-compile event never fired (a true hit skips
-        backend compilation entirely on some runtimes)."""
-        label = self._current_label() or "?"
-        with self._lock:
-            for rec in reversed(self._ring):
-                if rec["label"] == label and rec["cache"] != "hit":
-                    self.by_cache[rec["cache"]] -= 1
-                    rec["cache"] = "hit"
-                    self.by_cache["hit"] = self.by_cache.get(
-                        "hit", 0) + 1
-                    return
-        self.record(label, 0.0, cache="hit")
+        """The persistent cache served the compile in flight on this
+        thread (the cache-hit monitoring event, which fires before the
+        compile-duration event closes): the next record this thread
+        writes lands as ``hit``."""
+        self._tls.cache_hit = True
+
+    def _take_cache_hit(self) -> bool:
+        hit = getattr(self._tls, "cache_hit", False)
+        self._tls.cache_hit = False
+        return hit
 
     # -- storm -----------------------------------------------------------
 
@@ -325,7 +334,9 @@ def _on_duration_event(event: str, duration_s: float, **kw: Any) -> None:
     if label is not None:
         obs._tls.observed = True
     try:
-        obs.record(label or "?", duration_s)
+        obs.record(label or "?", duration_s,
+                   cache="hit" if obs._take_cache_hit() else None,
+                   fn=kw.get("fun_name"))
     except Exception:  # pragma: no cover - never break a compile
         logger.debug("compile record failed", exc_info=True)
 
@@ -409,6 +420,45 @@ def configure_cache(cache_dir: Optional[str]) -> None:
     obs = _OBS
     if obs is not None:
         obs.cache_dir = cache_dir or None
+
+
+def compile_cache_enabled() -> bool:
+    """KAFKA_TPU_COMPILE_CACHE off switch; unset = on."""
+    raw = os.environ.get(CACHE_SWITCH_ENV)
+    return raw is None or raw.strip().lower() not in ("", "0", "off",
+                                                      "false")
+
+
+def compile_cache_dir() -> str:
+    """THE directory of the persistent XLA compile cache, for every
+    entry point (server, bench.py, chip_smoke.py): wherever
+    JAX_COMPILATION_CACHE_DIR points when set, else ``.jax_cache`` next
+    to this package.  The path is part of nothing random — the same
+    checkout always resolves to the same directory, so a second boot
+    finds what the first one compiled."""
+    env = os.environ.get(CACHE_DIR_ENV)
+    if env:
+        return env
+    checkout = os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    return os.path.join(checkout, ".jax_cache")
+
+
+def enable_compile_cache() -> str:
+    """Turn the persistent compile cache on at compile_cache_dir() and
+    report it to the observatory.  Call before the first jax.jit."""
+    import jax
+
+    path = compile_cache_dir()
+    if not os.environ.get(CACHE_DIR_ENV):
+        # placed from outside when the variable is set (JAX reads it
+        # itself); only the checkout-local default is set in code
+        os.makedirs(path, exist_ok=True)
+        jax.config.update("jax_compilation_cache_dir", path)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 1)
+    configure_cache(path)
+    return path
 
 
 def instrument(label: str, fn: Callable[..., Any]) -> Callable[..., Any]:

@@ -28,9 +28,8 @@ instrument under it:
   dispatch kinds in proportion to their modeled roofline seconds, is
   fed back as ``EngineMetrics.record_kernel_sample`` — the
   ``kernel_skew`` (true-device vs modeled) gauge that calibrates the
-  PR 11 fetch-maturation ``model_skew`` per kind.  This is exactly the
-  instrument scripts/BENCH_r06.md's TPU calibration round reads
-  instead of hand math.
+  PR 11 fetch-maturation ``model_skew`` per kind.  This is the
+  instrument a chip calibration run reads instead of hand math.
 
 Trace windows are *deliberately offset*: a sample's trace starts
 before step k and stops at the start of step k+1, so asynchronously

@@ -1300,7 +1300,7 @@ class EngineMetrics:
                 # profiler-sampled kernel truth (ISSUE 18): device
                 # kernel seconds vs modeled seconds for the SAMPLED
                 # steps only — the calibration model_skew is read
-                # against on real chips (scripts/BENCH_r06.md)
+                # against on real chips
                 "kernel_samples": u.kernel_samples,
                 "kernel_busy_s": round(u.kernel_s, 4),
                 "kernel_skew": round(u.kernel_s / u.kernel_modeled_s, 3)

@@ -43,7 +43,8 @@ from ..models.config import ModelConfig
 GiB = 1024**3
 MiB = 1024**2
 
-# chip generation -> HBM bytes per chip
+# chip generation -> HBM bytes per chip (Google Cloud TPU documentation,
+# per-chip "HBM capacity" of each generation's system architecture page)
 HBM_BYTES = {
     "v5e": 16 * GiB,
     "v5p": 95 * GiB,
@@ -53,13 +54,26 @@ HBM_BYTES = {
 
 # chip generation -> (peak dense bf16 FLOP/s, HBM bytes/s) per chip —
 # the roofline the device-utilization estimator (ISSUE 10) divides the
-# planner's modeled per-dispatch flop/byte costs by.  Public datasheet
-# numbers, like HBM_BYTES above.
+# planner's modeled per-dispatch flop/byte costs by.  Same source as
+# HBM_BYTES ("peak compute per chip (bf16)", "HBM bandwidth per chip").
 CHIP_PEAKS = {
     "v5e": (197e12, 819e9),
     "v5p": (459e12, 2765e9),
     "v6e": (918e12, 1640e9),
     "v4": (275e12, 1228e9),
+}
+
+# EXACT jax `device_kind` string -> chip generation (the key of the two
+# tables above).  Source: what `jax.devices()[0].device_kind` prints on
+# each generation — note v5p reports plain "TPU v5", which a substring
+# match on "v5" would file under v5e.  A TPU whose kind is not listed is
+# an error (chip_for_device): a guessed roofline or HBM budget would be
+# reported as a fact about a chip nobody looked up.
+DEVICE_KINDS = {
+    "TPU v4": "v4",
+    "TPU v5 lite": "v5e",
+    "TPU v5": "v5p",
+    "TPU v6 lite": "v6e",
 }
 
 PEAK_TFLOPS_ENV = "KAFKA_TPU_PEAK_TFLOPS"
@@ -91,25 +105,32 @@ def _kv_shard(cfg: ModelConfig, tp: int, kv_shard: Optional[int] = None) -> int:
     return factor_tp_for_kv(tp, cfg.num_kv_heads)[0]
 
 
+def chip_for_device(dev) -> Optional[str]:
+    """Chip generation of a live jax device by its exact `device_kind`
+    (DEVICE_KINDS).  None off-TPU (CPU tests have no datasheet); a TPU
+    whose kind is not in the table raises."""
+    if getattr(dev, "platform", None) != "tpu":
+        return None
+    kind = getattr(dev, "device_kind", "")
+    try:
+        return DEVICE_KINDS[kind]
+    except KeyError:
+        raise ValueError(
+            f"unknown TPU device_kind {kind!r}: add its row to "
+            f"runtime/planner.py DEVICE_KINDS (known: "
+            f"{sorted(DEVICE_KINDS)})"
+        ) from None
+
+
 def hbm_for_device(dev) -> Optional[int]:
-    """Best-effort HBM budget for a live jax device: the runtime's
-    bytes_limit when reported, else the datasheet number for the chip
-    generation parsed from device_kind."""
+    """HBM budget for a live jax device: the runtime's bytes_limit when
+    reported, else the datasheet number for its chip generation.  None
+    off-TPU; raises on a TPU kind DEVICE_KINDS does not list."""
+    chip = chip_for_device(dev)
     stats = getattr(dev, "memory_stats", lambda: None)() or {}
     if stats.get("bytes_limit"):
         return int(stats["bytes_limit"])
-    if dev.platform != "tpu":
-        return None
-    kind = getattr(dev, "device_kind", "").lower()
-    if "v5p" in kind:
-        return HBM_BYTES["v5p"]
-    if "v6" in kind:
-        return HBM_BYTES["v6e"]
-    if "lite" in kind or "v5e" in kind or "v5" in kind:
-        return HBM_BYTES["v5e"]  # plain "v5": conservative (lite) budget
-    if "v4" in kind:
-        return HBM_BYTES["v4"]
-    return None  # unknown generation: skip validation, never misjudge it
+    return HBM_BYTES[chip] if chip is not None else None
 
 
 @dataclasses.dataclass(frozen=True)
@@ -400,10 +421,11 @@ def device_peaks(dev) -> tuple:
     estimator (ISSUE 10).
 
     KAFKA_TPU_PEAK_TFLOPS / KAFKA_TPU_PEAK_HBM_GBPS override everything
-    (CPU runs, unlisted chip revisions, derated shared machines); else
-    the datasheet table by device_kind.  Unknown generations return
-    (None, None, "unknown") — the estimator then reports achieved
-    FLOP/s and GB/s without ratios rather than inventing a roofline.
+    (CPU tests, derated shared machines); else the datasheet row for the
+    device's exact `device_kind`.  Off-TPU there is no datasheet:
+    (None, None, "unknown"), and the estimator reports achieved FLOP/s
+    and GB/s without ratios.  A TPU kind DEVICE_KINDS does not list
+    raises rather than inventing a roofline.
     """
     import os as _os
 
@@ -418,18 +440,10 @@ def device_peaks(dev) -> tuple:
             )
         except ValueError:
             pass
-    if getattr(dev, "platform", None) != "tpu":
+    chip = chip_for_device(dev)
+    if chip is None:
         return None, None, "unknown"
-    kind = getattr(dev, "device_kind", "").lower()
-    if "v5p" in kind:
-        return (*CHIP_PEAKS["v5p"], "datasheet")
-    if "v6" in kind:
-        return (*CHIP_PEAKS["v6e"], "datasheet")
-    if "lite" in kind or "v5e" in kind or "v5" in kind:
-        return (*CHIP_PEAKS["v5e"], "datasheet")
-    if "v4" in kind:
-        return (*CHIP_PEAKS["v4"], "datasheet")
-    return None, None, "unknown"
+    return (*CHIP_PEAKS[chip], "datasheet")
 
 
 @dataclasses.dataclass(frozen=True)

@@ -101,17 +101,10 @@ def build_tpu_provider(cfg: ServingConfig) -> LLMProvider:
     compile_log.init()
     compile_log.set_phase("boot")
 
-    if cfg.compile_cache_dir:
+    if cfg.compile_cache:
         # persistent XLA compile cache: a warm reboot loads every serving
         # program from disk instead of recompiling (~30s per bucket)
-        import os as _os
-
-        cache_dir = _os.path.expanduser(cfg.compile_cache_dir)
-        _os.makedirs(cache_dir, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", cache_dir)
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 1)
-        compile_log.configure_cache(cache_dir)
+        compile_log.enable_compile_cache()
     else:
         compile_log.configure_cache(None)
 
@@ -134,12 +127,16 @@ def build_tpu_provider(cfg: ServingConfig) -> LLMProvider:
             vocab_size=tokenizer.vocab_size, dtype="float32"
         )
     else:
-        tokenizer = ByteTokenizer()
+        # No checkpoint: random weights at the NAMED model's own shape,
+        # vocabulary included (the logits head is a real share of a decode
+        # step) — the byte tokenizer pads its table out to the model's
+        # vocab with never-sampled filler ids.
         base = get_config(cfg.model_name)
-        vocab = max(tokenizer.vocab_size, 262)
+        vocab = max(base.vocab_size, ByteTokenizer().vocab_size)
         if base.image_token_id is not None:
             # the reserved image-placeholder id must stay in-vocab
             vocab = max(vocab, base.image_token_id + 1)
+        tokenizer = ByteTokenizer(vocab_size=vocab)
         model_cfg = base.replace(vocab_size=vocab, dtype=cfg.dtype)
     if cfg.quantize and cfg.quantize != "int8":
         raise ValueError(f"unknown quantize mode {cfg.quantize!r}")
@@ -176,28 +173,26 @@ def build_tpu_provider(cfg: ServingConfig) -> LLMProvider:
     # activation terms are estimates but the weight bytes are exact — fail
     # here, before any weights load.
     memory_plan = None
-    try:
-        from ..runtime.planner import hbm_for_device, plan_for_serving
+    from ..runtime.planner import hbm_for_device, plan_for_serving
 
-        hbm = hbm_for_device(jax.devices()[0])
-        if hbm:
-            memory_plan = plan_for_serving(
-                cfg, hbm_bytes=hbm, model_cfg=model_cfg
+    # None off-TPU (CPU tests have no HBM to plan against); on a TPU this
+    # raises on an unknown chip or an unplannable config — a deployment
+    # that cannot say what it fits in does not boot
+    hbm = hbm_for_device(jax.devices()[0])
+    if hbm:
+        memory_plan = plan_for_serving(
+            cfg, hbm_bytes=hbm, model_cfg=model_cfg
+        )
+        if memory_plan.weight_bytes > memory_plan.usable_bytes:
+            raise MemoryError(
+                f"{model_cfg.name} weights alone need "
+                f"{memory_plan.weight_bytes / 2**30:.1f} GiB/device, "
+                f"budget {memory_plan.usable_bytes / 2**30:.1f} GiB: "
+                f"{memory_plan.summary()} — shard (tp/pp), quantize, "
+                "or pick a bigger topology"
             )
-            if memory_plan.weight_bytes > memory_plan.usable_bytes:
-                raise MemoryError(
-                    f"{model_cfg.name} weights alone need "
-                    f"{memory_plan.weight_bytes / 2**30:.1f} GiB/device, "
-                    f"budget {memory_plan.usable_bytes / 2**30:.1f} GiB: "
-                    f"{memory_plan.summary()} — shard (tp/pp), quantize, "
-                    "or pick a bigger topology"
-                )
-            log = logger.warning if not memory_plan.fits else logger.info
-            log("memory plan: %s", memory_plan.summary())
-    except MemoryError:
-        raise
-    except Exception as e:
-        logger.debug("memory planning skipped: %s", e)
+        log = logger.warning if not memory_plan.fits else logger.info
+        log("memory plan: %s", memory_plan.summary())
 
     # NOW materialize weights (checkpoint load / random init); the
     # plan-validated model_cfg is the one served
@@ -324,28 +319,44 @@ def build_tpu_provider(cfg: ServingConfig) -> LLMProvider:
                 tokenizer, _warm_tools, "required"
             )
             if _warm_mask is not None:
+                # defer=False: boot is not serving anyone, so even a large
+                # vocabulary compiles here rather than on the background
+                # worker (whose first callers take the host mask path)
                 _warmup_grammar = compile_grammar_for_mask_fn(
-                    _warm_mask, model_cfg.vocab_size
+                    _warm_mask, model_cfg.vocab_size, defer=False
                 )
-        for n, e in enumerate(engines):
+
+        def _warm_engine(n: int, e) -> None:
             for j, blen in enumerate(bucket_lens):
                 e.submit(GenRequest(
                     request_id=f"__warmup_b{n}_{j}",
                     prompt_ids=[3] * max(1, blen), max_new_tokens=1,
                 ))
                 e.run_to_completion()  # one at a time: bounded pool use
+                # two concurrent same-bucket prompts fuse into the batched
+                # prefill program wherever the scheduler would fuse them
+                # (an admission burst is exactly that shape)
+                if e.batches_prefill(next(
+                        b for b in engine_cfg.prefill_buckets if b >= blen)):
+                    for i in range(2):
+                        e.submit(GenRequest(
+                            request_id=f"__warmup_bb{n}_{j}_{i}",
+                            prompt_ids=[3] * max(1, blen), max_new_tokens=1,
+                        ))
+                    e.run_to_completion()
             for i in range(per_engine):
                 e.submit(GenRequest(
                     request_id=f"__warmup_{n}_{i}",
                     prompt_ids=[3] * min(8, window // 4),
                     max_new_tokens=engine_cfg.multi_step + 2,
                 ))
-            # Constrained decoding uses three more program variants: the
-            # masked prefill trace, the forced-token chained decode ([B]
-            # override vector), and the ambiguous masked decode ([B, V]
-            # allowed mask — step 1 below returns TWO ids so it actually
-            # traces).  The first tool call would otherwise compile them
-            # on the scheduler thread, stalling every in-flight stream.
+            # Constrained decoding uses two more program variants (the
+            # prefill program takes a mask row either way): the
+            # forced-token chained decode ([B] override vector), and the
+            # ambiguous masked decode ([B, V] allowed mask — step 1 below
+            # returns TWO ids so it actually traces).  The first tool
+            # call would otherwise compile them on the scheduler thread,
+            # stalling every in-flight stream.
             e.submit(GenRequest(
                 request_id=f"__warmup_con_{n}",
                 prompt_ids=[3] * 4, max_new_tokens=3,
@@ -374,6 +385,18 @@ def build_tpu_provider(cfg: ServingConfig) -> LLMProvider:
             # demotion/promotion pays copy latency, not an XLA compile on
             # the scheduler thread (no-op when the tier is off)
             e.warmup_kv_tier()
+
+        # Replicas are independent engines over disjoint devices, and XLA
+        # compiles each one's programs separately (the device assignment
+        # is part of an executable, and of its persistent-cache key): warm
+        # them side by side.  Compilation releases the GIL, so a dp boot
+        # costs about one replica's compile time instead of dp of them.
+        from concurrent.futures import ThreadPoolExecutor
+
+        with ThreadPoolExecutor(len(engines)) as pool:
+            for fut in [pool.submit(_warm_engine, n, e)
+                        for n, e in enumerate(engines)]:
+                fut.result()  # re-raise a replica's warm-up failure
         # cross-replica ship programs (KAFKA_TPU_DP_ROLES): compile the
         # per-bucket gather/scatter pairs across the pool edges so the
         # first prefill-and-hand-off pays copy latency, not an XLA
@@ -1286,6 +1309,31 @@ async def health(request: web.Request) -> web.Response:
         }
         if len(replicas) > 1:
             payload["engine"]["dp"] = len(replicas)
+        info = getattr(replicas[0], "device_info", None)
+        if info is not None:
+            # what is actually being served, and on what: resolved once at
+            # engine construction (runtime/engine.py device_info), so a
+            # client never has to infer the device from logs
+            import jax
+
+            mc = replicas[0].cfg
+            payload["device"] = {
+                **info,
+                # devices the engines occupy vs devices jax can see
+                "count": sum(e.device_info["count"] for e in replicas),
+                "visible": jax.device_count(),
+                "model": {
+                    "name": mc.name,
+                    "num_layers": mc.num_layers,
+                    "hidden_size": mc.hidden_size,
+                    "num_heads": mc.num_heads,
+                    "num_kv_heads": mc.num_kv_heads,
+                    "head_dim": mc.head_dim,
+                    "intermediate_size": mc.intermediate_size,
+                    "vocab_size": mc.vocab_size,
+                    "dtype": mc.dtype,
+                },
+            }
         health_records = getattr(engine, "health", None)
         if health_records:
             # replica supervision at a glance: a load balancer (or a

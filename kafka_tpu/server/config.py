@@ -11,6 +11,8 @@ import dataclasses
 import os
 from typing import Optional, Tuple
 
+from ..runtime.compile_log import compile_cache_enabled
+
 
 @dataclasses.dataclass
 class ServingConfig:
@@ -210,16 +212,14 @@ class ServingConfig:
     # so the first real request doesn't pay the 20-40s XLA compile
     warmup: bool = True
     # persistent XLA compilation cache: warm reboots reuse compiled
-    # programs from disk instead of recompiling every bucket ("" disables).
-    # The default honors KAFKA_TPU_COMPILE_CACHE at CONSTRUCTION time (not
-    # just via from_env): the test suite points it at a fresh per-run dir
-    # because a shared on-disk cache can hold executables AOT-compiled on
-    # a different host of a migrating environment, and XLA hard-aborts
-    # (uncatchably) loading one with mismatched machine features.
-    compile_cache_dir: str = dataclasses.field(
-        default_factory=lambda: os.environ.get(
-            "KAFKA_TPU_COMPILE_CACHE", "~/.cache/kafka_tpu/xla"
-        )
+    # programs from disk instead of recompiling every bucket.  WHERE it
+    # lives is not a serving knob: JAX_COMPILATION_CACHE_DIR when set,
+    # else <checkout>/.jax_cache (runtime/compile_log.compile_cache_dir).
+    # KAFKA_TPU_COMPILE_CACHE=0 turns it off — read at CONSTRUCTION time
+    # (not just via from_env) because the test suite must disable it:
+    # XLA has hard-aborted (uncatchably) serializing CPU SPMD executables.
+    compile_cache: bool = dataclasses.field(
+        default_factory=compile_cache_enabled
     )
 
     @classmethod
@@ -333,7 +333,7 @@ class ServingConfig:
             quantize=get("QUANTIZE", cls.quantize),
             kv_quantize=get("KV_QUANTIZE", cls.kv_quantize),
             warmup=get("WARMUP", "1") not in ("0", "false", "False"),
-            # compile_cache_dir omitted: its default_factory already reads
+            # compile_cache omitted: its default_factory already reads
             # KAFKA_TPU_COMPILE_CACHE
         )
         return dataclasses.replace(cfg, **overrides)
