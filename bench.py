@@ -231,77 +231,28 @@ def flight_overhead_phase(engine, cfg, args, rng) -> dict:
     }
 
 
-def device_truth_phase(engine, cfg, args, rng, sample_period: int = 32) -> dict:
-    """Device-truth telemetry costs (ISSUE 18): two proofs.
-
-    * sampling A/B — the SAME engine decodes with the kernel sampler
-      detached vs attached at N=`sample_period` (every-Nth-step
-      jax.profiler trace window), interleaved best-of-3 like the other
-      sub-1% overhead phases.  OFF is the shipped default — sampler
-      None, engine.step untouched — so tok_s_off doubles as the
-      bit-identical-when-off baseline; acceptance is bounded overhead
-      at N=32 (the 1/N amortization keeps even a ~ms trace start/stop
-      under a few percent).
-    * rebuild compile-outage window — wall seconds from fresh-engine
-      construction to its first generated token: WARM reuses the
-      process jit caches the /admin/resize rebuild path shares (the
-      module _FN_CACHE), COLD clears them first (what a crashed/replaced
-      process pays, modulo the persistent XLA disk cache when one is
-      mounted).  Both legs run under the compile observatory's
-      "rebuild" phase, so the ring attributes their compiles to
-      by_phase["rebuild"] — the same attribution /debug/compiles shows
-      after a live resize.
+def device_truth_phase(engine, cfg, args, rng) -> dict:
+    """Device-truth telemetry (ISSUE 18): the rebuild compile-outage
+    window — wall seconds from fresh-engine construction to its first
+    generated token: WARM reuses the process jit caches the
+    /admin/resize rebuild path shares (the module _FN_CACHE), COLD clears
+    them first (what a crashed/replaced process pays, modulo the
+    persistent XLA disk cache when one is mounted).  Both legs run under
+    the compile observatory's "rebuild" phase, so the ring attributes
+    their compiles to by_phase["rebuild"] — the same attribution
+    /debug/compiles shows after a live resize.  (The every-Nth-step
+    kernel sampler and its overhead A/B were removed in PR 24.)
     """
-    import tempfile as _tempfile
-
     from kafka_tpu.runtime import GenRequest, InferenceEngine, compile_log
-    from kafka_tpu.runtime.kernel_profiler import KernelSampler
-    from kafka_tpu.runtime.metrics import EngineMetrics
 
     compile_log.init()  # idempotent; the server does this in app.py
     obs = compile_log.get()
+    # the caller engine's programs are what the warm leg reuses
+    decode_phase(engine, cfg, min(args.batch, 8), args.prompt_len // 2,
+                 48 if args.quick else 192, rng)
 
-    saved_sampler = getattr(engine, "kernel_sampler", None)
-    gen = 48 if args.quick else 192
-    batch = min(args.batch, 8)
-    spill = _tempfile.mkdtemp(prefix="kafka_tpu_bench_kernels_")
-    tps = {"on": [], "off": []}
-    samples = 0
-    kernels_seen = 0
-    try:
-        for _round in range(3):
-            for mode in ("off", "on"):
-                sampler = (KernelSampler(sample_period, spill_dir=spill)
-                           if mode == "on" else None)
-                engine.kernel_sampler = sampler
-                engine.metrics = EngineMetrics()
-                t, _ = decode_phase(engine, cfg, batch,
-                                    args.prompt_len // 2, gen, rng)
-                if sampler is not None:
-                    sampler.close(engine.metrics)
-                    samples += sampler.samples_total
-                    kernels_seen = max(kernels_seen,
-                                       len(sampler.table(top_k=1000)))
-                tps[mode].append(t)
-    finally:
-        engine.kernel_sampler = saved_sampler
-        engine.metrics = EngineMetrics()
-    on, off = max(tps["on"]), max(tps["off"])
-    sampling = {
-        "sample_period": sample_period,
-        "tok_s_off": round(off, 1),
-        "tok_s_on": round(on, 1),
-        "overhead_frac": round(max(0.0, 1 - on / off), 4) if off else 0.0,
-        "samples": samples,
-        "kernels_seen": kernels_seen,
-        "note": ("same engine/programs, interleaved best-of-3; OFF is "
-                 "the shipped default (sampler detached, dispatch path "
-                 "identical); acceptance: bounded overhead at N="
-                 f"{sample_period}"),
-    }
-
-    # -- rebuild compile-outage window: warm first (the caches are hot
-    # from the A/B above — exactly the /admin/resize state), then cold
+    # warm first (the caches are hot from the decode above — exactly the
+    # /admin/resize state), then cold
     def _first_token_s(cold: bool) -> float:
         if cold:
             import jax as _jax
@@ -341,7 +292,7 @@ def device_truth_phase(engine, cfg, args, rng, sample_period: int = 32) -> dict:
                  "disk cache when mounted); compile counts from the "
                  "observatory ring's by_phase['rebuild']"),
     }
-    return {"sampling": sampling, "rebuild_outage": rebuild}
+    return {"rebuild_outage": rebuild}
 
 
 def shared_prefix_phase(cfg, params, n_threads: int, common_len: int,
@@ -2822,8 +2773,8 @@ def main() -> None:
                          "prefill:1,decode:1 under mixed open-loop traffic); "
                          "'autoscale' runs ONLY the traffic-ramp phase with "
                          "the autoscaler control loop closed (dp 1 -> 2 "
-                         "mid-run); 'device_truth' runs ONLY the kernel-"
-                         "sampling overhead A/B + the warm-vs-cold rebuild "
+                         "mid-run); 'device_truth' runs ONLY "
+                         "the warm-vs-cold rebuild "
                          "compile-outage measurement; 'zero_copy' runs ONLY "
                          "the zero-host-copy movement A/Bs (host vs device "
                          "ship transport, wake prefetch on vs off under "
@@ -2942,8 +2893,8 @@ def main() -> None:
         return
 
     if args.scenario == "device_truth":
-        # bench.py device_truth: ONLY the kernel-sampling overhead A/B +
-        # the warm-vs-cold rebuild compile-outage window (ISSUE 18)
+        # bench.py device_truth: ONLY the warm-vs-cold rebuild
+        # compile-outage window (ISSUE 18)
         ps = 8 if args.quick else 16
         ecfg = EngineConfig(
             max_batch=min(args.batch, 8), page_size=ps,
@@ -2953,22 +2904,14 @@ def main() -> None:
         ecfg.num_pages = ecfg.max_batch * ecfg.max_pages_per_seq + 1
         eng = InferenceEngine(cfg, params, ecfg)
         rng = random.Random(0)
-        # compile the A/B's programs OUTSIDE the measured loops
-        eng.generate(make_prompt(rng, args.prompt_len // 2,
-                                 cfg.vocab_size), max_new_tokens=4)
-        eng.metrics = EngineMetrics()
         out = device_truth_phase(eng, cfg, args, rng)
-        log(f"device_truth: sampling overhead "
-            f"{100 * out['sampling']['overhead_frac']:.2f}% at N="
-            f"{out['sampling']['sample_period']} "
-            f"({out['sampling']['samples']} samples, "
-            f"{out['sampling']['kernels_seen']} kernels); rebuild "
-            f"first-token warm {out['rebuild_outage']['warm_first_token_s']}s "
+        log(f"device_truth: rebuild first-token warm "
+            f"{out['rebuild_outage']['warm_first_token_s']}s "
             f"vs cold {out['rebuild_outage']['cold_first_token_s']}s")
         print(json.dumps({
-            "metric": f"kernel_sampling_overhead_frac_{cfg.name}",
-            "value": out["sampling"]["overhead_frac"],
-            "unit": "frac",
+            "metric": f"rebuild_cold_over_warm_{cfg.name}",
+            "value": out["rebuild_outage"]["cold_over_warm"],
+            "unit": "x",
             "extras": out,
         }))
         return
@@ -3580,14 +3523,11 @@ def main() -> None:
         f"{flight['tok_s_off']} tok/s "
         f"({100 * flight['regression_frac']:.2f}% regression)")
 
-    # ---- device-truth telemetry (ISSUE 18): sampling A/B + rebuild ------
-    # outage.  Runs LAST among the main-engine phases: the cold leg
+    # ---- device-truth telemetry (ISSUE 18): rebuild compile outage.
+    # Runs LAST among the main-engine phases: the cold leg
     # clears the process jit caches, so anything after it would recompile
     device_truth = device_truth_phase(engine, cfg, args, rng)
-    log(f"device_truth: sampling overhead "
-        f"{100 * device_truth['sampling']['overhead_frac']:.2f}% at N="
-        f"{device_truth['sampling']['sample_period']}; rebuild "
-        f"first-token warm "
+    log(f"device_truth: rebuild first-token warm "
         f"{device_truth['rebuild_outage']['warm_first_token_s']}s vs cold "
         f"{device_truth['rebuild_outage']['cold_first_token_s']}s")
 

@@ -47,6 +47,7 @@ def _w(lp: Params, name: str, dtype) -> jnp.ndarray:
     return dequantize(lp[name], dtype)
 
 
+@jax.named_scope("kv_write")
 def _kv_write(cache, idx, rows: jnp.ndarray):
     """Scatter new KV rows into a pool at flat slot indices.
 
@@ -63,6 +64,7 @@ def _kv_write(cache, idx, rows: jnp.ndarray):
     return cache.at[idx].set(rows.astype(cache.dtype))
 
 
+@jax.named_scope("attn_gather")
 def _kv_read(cache, idx, dtype) -> jnp.ndarray:
     """Gather pool rows at flat indices, dequantizing int8 pools in-graph
     (the gather reads int8 — HALF the window traffic — and XLA fuses the
@@ -72,6 +74,7 @@ def _kv_read(cache, idx, dtype) -> jnp.ndarray:
     return cache[idx]
 
 
+@jax.named_scope("attn_gather")
 def _kv_read_pages(cache, page_table: jnp.ndarray, page_size: int,
                    dtype) -> jnp.ndarray:
     """Gather a [B, C, Hkv*D] window by PAGE rather than by slot.
@@ -203,18 +206,37 @@ def _attention_block(
 ) -> Tuple[jnp.ndarray, Optional[jnp.ndarray], Optional[jnp.ndarray]]:
     """One attention sublayer. x: [B, S, H]. Returns (out, k_cache', v_cache')."""
     dt = x.dtype
-    q = jnp.einsum("bsh,hnd->bsnd", x, _w(lp, "wq", dt))
-    k = jnp.einsum("bsh,hnd->bsnd", x, _w(lp, "wk", dt))
-    v = jnp.einsum("bsh,hnd->bsnd", x, _w(lp, "wv", dt))
-    q = apply_rope(q, cos, sin)
-    k = apply_rope(k, cos, sin)
-
+    with jax.named_scope("attn_qkv"):
+        q = jnp.einsum("bsh,hnd->bsnd", x, _w(lp, "wq", dt))
+        k = jnp.einsum("bsh,hnd->bsnd", x, _w(lp, "wk", dt))
+        v = jnp.einsum("bsh,hnd->bsnd", x, _w(lp, "wv", dt))
+        q = apply_rope(q, cos, sin)
+        k = apply_rope(k, cos, sin)
     if paged is not None:
         # Paged pool: k_cache/v_cache are [TOTAL_SLOTS, Hkv*D] this layer
         # (dense arrays, or QTensor int8+scales when kv_quantize is on).
         b, s, hkv, d = k.shape
         k_cache = _kv_write(k_cache, paged.write_idx, k.reshape(b, s, hkv * d))
         v_cache = _kv_write(v_cache, paged.write_idx, v.reshape(b, s, hkv * d))
+    with jax.named_scope("attn_core"):
+        out, k_cache, v_cache = _attention_core(
+            q, k, v, cfg, positions, k_cache, v_cache, kv_valid,
+            cache_positions, paged, mesh,
+        )
+    with jax.named_scope("attn_out"):
+        out = jnp.einsum("bsnd,ndh->bsh", out, _w(lp, "wo", out.dtype))
+    return out, k_cache, v_cache
+
+
+def _attention_core(q, k, v, cfg, positions, k_cache, v_cache, kv_valid,
+                    cache_positions, paged, mesh):
+    """Scores, softmax and weighted sum for one layer, by cache form and
+    backend.  New k/v rows are already in a paged pool (_attention_block
+    wrote them); the contiguous cache is written here.  Returns
+    (out [B, S, Hq, D], k_cache', v_cache')."""
+    dt = q.dtype
+    if paged is not None:
+        b, s, hkv, d = k.shape
         if (
             cfg.attention_backend == "pallas"
             and s == 1
@@ -385,11 +407,12 @@ def _attention_block(
         # for the contiguous cache; the engine passes explicit slots for
         # chunked prefill/decode).
         slots = positions if cache_positions is None else cache_positions
-        b_idx = jnp.arange(x.shape[0])[:, None]
-        k_cache = k_cache.at[b_idx, slots].set(k.astype(k_cache.dtype))
-        v_cache = v_cache.at[b_idx, slots].set(v.astype(v_cache.dtype))
+        b_idx = jnp.arange(q.shape[0])[:, None]
+        with jax.named_scope("kv_write"):
+            k_cache = k_cache.at[b_idx, slots].set(k.astype(k_cache.dtype))
+            v_cache = v_cache.at[b_idx, slots].set(v.astype(v_cache.dtype))
         cap = k_cache.shape[1]
-        kv_pos = jnp.broadcast_to(jnp.arange(cap)[None, :], (x.shape[0], cap))
+        kv_pos = jnp.broadcast_to(jnp.arange(cap)[None, :], (q.shape[0], cap))
         out = causal_attention(
             q,
             k_cache,
@@ -398,7 +421,6 @@ def _attention_block(
             kv_positions=kv_pos,
             kv_valid=kv_valid,
         )
-    out = jnp.einsum("bsnd,ndh->bsh", out, _w(lp, "wo", out.dtype))
     return out, k_cache, v_cache
 
 
@@ -441,11 +463,14 @@ def _moe_block(x: jnp.ndarray, lp: Params, cfg: ModelConfig) -> jnp.ndarray:
     """
     b, s, h = x.shape
     t = x.reshape(b * s, h)
-    w = _routing_weights(t, lp["router"], cfg.num_experts_per_tok)
-    g = jnp.einsum("th,ehf->tef", t, _w(lp, "wg", t.dtype))
-    u = jnp.einsum("th,ehf->tef", t, _w(lp, "wu", t.dtype))
-    y = jnp.einsum("tef,efh->teh", jax.nn.silu(g) * u, _w(lp, "wd", t.dtype))
-    out = jnp.einsum("te,teh->th", w.astype(y.dtype), y)
+    with jax.named_scope("moe_router"):
+        w = _routing_weights(t, lp["router"], cfg.num_experts_per_tok)
+    with jax.named_scope("moe_experts"):
+        g = jnp.einsum("th,ehf->tef", t, _w(lp, "wg", t.dtype))
+        u = jnp.einsum("th,ehf->tef", t, _w(lp, "wu", t.dtype))
+        y = jnp.einsum(
+            "tef,efh->teh", jax.nn.silu(g) * u, _w(lp, "wd", t.dtype))
+        out = jnp.einsum("te,teh->th", w.astype(y.dtype), y)
     return out.reshape(b, s, h)
 
 
@@ -477,51 +502,72 @@ def forward(
         vision models, src/llm/portkey.py:276).
     Returns (logits [B, S, vocab] float32, updated cache or None).
     """
-    embed = params["embed"]
-    if isinstance(embed, QTensor):
-        # per-row dequant of only the looked-up rows (scale is [V, 1])
-        x = (
-            embed.q[token_ids].astype(cfg.activation_dtype)
-            * embed.s[token_ids].astype(cfg.activation_dtype)
-        )
-    else:
-        x = embed[token_ids].astype(cfg.activation_dtype)
-    if embed_override is not None:
-        x = jnp.where(
-            override_on[..., None],
-            embed_override.astype(cfg.activation_dtype), x,
-        )
-    inv_freq = rope_frequencies(cfg)
-    cos, sin = rope_cos_sin(positions, inv_freq)
+    with jax.named_scope("embed"):
+        embed = params["embed"]
+        if isinstance(embed, QTensor):
+            # per-row dequant of only the looked-up rows (scale is [V, 1])
+            x = (
+                embed.q[token_ids].astype(cfg.activation_dtype)
+                * embed.s[token_ids].astype(cfg.activation_dtype)
+            )
+        else:
+            x = embed[token_ids].astype(cfg.activation_dtype)
+        if embed_override is not None:
+            x = jnp.where(
+                override_on[..., None],
+                embed_override.astype(cfg.activation_dtype), x,
+            )
+        inv_freq = rope_frequencies(cfg)
+        cos, sin = rope_cos_sin(positions, inv_freq)
 
+    # Every op of the layer body sits under a leaf scope (residual adds
+    # included), so what a device trace shows under `layers` alone is the
+    # scan's own slicing and write-back of its stacked inputs.
     def layer_body(h, scanned):
         lp, kc, vc = scanned
-        attn_in = rms_norm(h, lp["ln_attn"], cfg.rms_norm_eps)
+        with jax.named_scope("attn_norm"):
+            attn_in = rms_norm(h, lp["ln_attn"], cfg.rms_norm_eps)
         attn_out, kc, vc = _attention_block(
             attn_in, lp, cfg, cos, sin, positions, kc, vc, kv_valid,
             cache_positions, paged, mesh,
         )
-        h = h + attn_out
-        mlp_in = rms_norm(h, lp["ln_mlp"], cfg.rms_norm_eps)
-        h = h + (_moe_block(mlp_in, lp, cfg) if cfg.is_moe
-                 else _mlp_block(mlp_in, lp))
+        with jax.named_scope("attn_out"):
+            h = h + attn_out
+        with jax.named_scope("mlp_norm"):
+            mlp_in = rms_norm(h, lp["ln_mlp"], cfg.rms_norm_eps)
+        if cfg.is_moe:
+            ffn_out = _moe_block(mlp_in, lp, cfg)
+            with jax.named_scope("moe_experts"):
+                h = h + ffn_out
+        else:
+            with jax.named_scope("mlp"):
+                h = h + _mlp_block(mlp_in, lp)
         return h, (kc, vc)
 
-    if kv_cache is None:
-        x, _ = jax.lax.scan(
-            lambda h, lp: (layer_body(h, (lp, None, None))[0], None),
-            x,
-            params["layers"],
-        )
-        new_cache = None
-    else:
-        x, (k_new, v_new) = jax.lax.scan(
-            lambda h, s: layer_body(h, s),
-            x,
-            (params["layers"], kv_cache.k, kv_cache.v),
-        )
-        new_cache = KVCache(k=k_new, v=v_new)
+    with jax.named_scope("layers"):
+        if kv_cache is None:
+            x, _ = jax.lax.scan(
+                lambda h, lp: (layer_body(h, (lp, None, None))[0], None),
+                x,
+                params["layers"],
+            )
+            new_cache = None
+        else:
+            x, (k_new, v_new) = jax.lax.scan(
+                lambda h, s: layer_body(h, s),
+                x,
+                (params["layers"], kv_cache.k, kv_cache.v),
+            )
+            new_cache = KVCache(k=k_new, v=v_new)
 
+    with jax.named_scope("head"):
+        logits = _logits_head(x, params, cfg)
+    return logits, new_cache
+
+
+def _logits_head(x: jnp.ndarray, params: Params,
+                 cfg: ModelConfig) -> jnp.ndarray:
+    """Final RMSNorm + vocabulary projection: [B, S, H] -> f32 logits."""
     x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
     # bf16 matmul with f32 accumulation: the MXU-native mode. Casting the
     # [V, H] table to f32 would stream an extra ~1 GB per step through HBM
@@ -552,4 +598,4 @@ def forward(
             logits = jnp.einsum(
                 "bsh,hv->bsv", x, head, preferred_element_type=jnp.float32
             )
-    return logits, new_cache
+    return logits

@@ -413,12 +413,6 @@ class DataParallelEngines:
         # The fresh engine re-applies its roofline on the first dispatch
         # it records (the PR 10 reset rule), so transplanting is safe.
         engine.metrics = old.metrics
-        # an open kernel-sampler trace window on the discarded engine
-        # would hold the process-wide jax.profiler lock forever (ISSUE
-        # 18): flush it into the transplanted metrics before the swap
-        sampler = getattr(old, "kernel_sampler", None)
-        if sampler is not None:
-            sampler.close(old.metrics)
         self.engines[i] = engine
         for req in pending:
             engine.adopt(req)
@@ -1161,12 +1155,6 @@ class DataParallelEngines:
         pending: List[GenRequest] = []
         for e in self.engines:
             pending.extend(e.take_waiting())
-            # discarded engines must not exit holding the process-wide
-            # jax.profiler trace lock (ISSUE 18): close any open kernel-
-            # sampler window before the replica set is replaced
-            sampler = getattr(e, "kernel_sampler", None)
-            if sampler is not None:
-                sampler.close(e.metrics)
         old_dp = len(self.engines)
         self._build_engines(dp)
         # replica indices changed meaning: stale pins/routes must not leak
@@ -1403,20 +1391,6 @@ class _AggregateMetrics:
                 "model_skew": round(measured_s / modeled_s, 3)
                 if modeled_s > 0 else 0.0,
             }
-            # sampled kernel profiling (ISSUE 18): sample counts and
-            # device-kernel seconds sum; the skew ratio recomputes from
-            # modeled seconds reconstructed per row (busy_s / skew)
-            kern_s = sum(r.get("kernel_busy_s", 0.0) for r in rows)
-            kern_modeled = sum(
-                r.get("kernel_busy_s", 0.0) / r["kernel_skew"]
-                for r in rows if r.get("kernel_skew")
-            )
-            sec["kernel_samples"] = sum(
-                r.get("kernel_samples", 0) for r in rows
-            )
-            sec["kernel_busy_s"] = round(kern_s, 4)
-            sec["kernel_skew"] = (round(kern_s / kern_modeled, 3)
-                                  if kern_modeled > 0 else 0.0)
             # aggregate busy time is SUMMED replica-seconds, so the ratio
             # divides by replica-seconds of roofline — per-chip MFU, not
             # fleet-total

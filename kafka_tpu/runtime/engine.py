@@ -72,7 +72,6 @@ from ..ops.sampling import (
     sample_tokens_per_slot,
 )
 from . import compile_log
-from . import kernel_profiler as _kernel_profiler
 from .failpoints import failpoint
 from .flight_recorder import (
     FlightRecorder,
@@ -124,6 +123,31 @@ WAITING, PREFILLING, PARKED, ACTIVE, DRAINING, FINISHED = (
 # Compiled step functions are cached per (model cfg, engine shape) so that
 # multiple engine instances (tests, restarts) reuse compilations.
 _FN_CACHE: Dict[Tuple, Callable] = {}
+
+
+def program_name(label: str) -> str:
+    """Function name a step program is jitted under, from its
+    /debug/compiles label: `multi_decode[16]_fsm` -> `fn_multi_decode_16_fsm`
+    (a device trace shows the module `jit_fn_multi_decode_16_fsm`).  The
+    benchmark's trace readers find decode programs by the `jit_body` /
+    `jit_fn` prefixes (benchmarks/layer_metrics/decode_step_dev_ms.py), and
+    tell the single decode step from the rest by `jit_body`: it is
+    `body_decode`, every other name begins `fn_`.  (Not the bare `body` it
+    was before the scopes: the persistent compile cache keys a program
+    without its metadata, so an executable compiled by a tree without
+    scopes would be reused under the same name, and its ops would carry
+    no scope in a trace.)"""
+    if label == "decode":
+        return "body_decode"
+    return "fn_" + label.replace("[", "_").replace("]", "")
+
+
+def _jit_step(label: str, fn: Callable) -> Callable:
+    """Jit one engine step program (k/v pools donated) under the name its
+    label gives and hand it to the compile observatory, so compile records
+    and trace module names cannot drift apart."""
+    fn.__name__ = fn.__qualname__ = program_name(label)
+    return compile_log.instrument(label, jax.jit(fn, donate_argnums=(1, 2)))
 
 # Agent-native scheduling (ISSUE 20, README "Agent-native scheduling").
 AGENT_DEMOTE_ENV = "KAFKA_TPU_AGENT_DEMOTE"
@@ -1121,10 +1145,6 @@ class InferenceEngine:
             "interpret": (self.cfg.attention_backend == "pallas"
                           and jax.default_backend() != "tpu"),
         }
-        # Sampled kernel profiling (ISSUE 18): every Nth step traced via
-        # jax.profiler when KAFKA_TPU_PROFILE_SAMPLE > 0, else None with
-        # every dispatch path byte-identical (tested like flight ring=0).
-        self.kernel_sampler = _kernel_profiler.build_from_env()
         # DP replica index (set by runtime/dp_router.py): traced requests'
         # engine spans carry it so a timeline names the replica it ran on
         self.replica: Optional[int] = None
@@ -1253,11 +1273,13 @@ class InferenceEngine:
                 kw["object_tokens"] = req.object_tokens
         return self._tattrs(**kw)
 
-    def _dispatch_scope(self, members: Sequence[Optional["GenRequest"]]):
-        """jax.profiler named scope keyed by the dispatched trace ids, so
-        a /debug/profile xplane capture correlates device slices with
-        server-side spans.  One module-global bool read when disabled
-        (KAFKA_TPU_PROFILING unset)."""
+    def _dispatch_scope(self, kind: str,
+                        members: Sequence[Optional["GenRequest"]]):
+        """Host annotation `kafka.<kind>[<trace ids>]` around one dispatch
+        (kind: prefill, decode, verify), so a /debug/profile xplane capture
+        correlates device slices with server-side spans and labels idle
+        gaps by what the scheduler was dispatching.  One module-global
+        bool read when disabled (KAFKA_TPU_PROFILING unset)."""
         if not profiler_annotations_enabled():
             return contextlib.nullcontext()
         ids = sorted({
@@ -1265,7 +1287,7 @@ class InferenceEngine:
             if m is not None and m.trace is not None
         })
         return jax.profiler.TraceAnnotation(
-            "kafka.decode[" + ",".join(ids) + "]"
+            f"kafka.{kind}[" + ",".join(ids) + "]"
         )
 
     def _dev(self, x) -> jnp.ndarray:
@@ -1304,20 +1326,25 @@ class InferenceEngine:
             # mask from the lane's FSM state, advance it by the sampled
             # token, decrement the wrap-up budget.  None = the plain
             # program (byte-identical dispatch paths when unused).
-            positions = seq_lens[:, None]
-            write_page = page_table[jnp.arange(B), seq_lens // ps]
-            write_idx = (write_page * ps + seq_lens % ps)[:, None]
-            # inactive slots scribble on the trash page
-            write_idx = jnp.where(active[:, None], write_idx, (seq_lens % ps)[:, None])
-            read_idx = (
-                page_table[:, :, None] * ps + jnp.arange(ps)[None, None, :]
-            ).reshape(B, C)
-            kv_positions = jnp.broadcast_to(jnp.arange(C)[None, :], (B, C))
-            kv_valid = (kv_positions <= seq_lens[:, None]) & active[:, None]
-            paged = PagedView(
-                write_idx, read_idx, kv_positions, kv_valid,
-                page_table=page_table, seq_lens=seq_lens, page_size=ps,
-            )
+            with jax.named_scope("step_ctl"):
+                positions = seq_lens[:, None]
+                write_page = page_table[jnp.arange(B), seq_lens // ps]
+                write_idx = (write_page * ps + seq_lens % ps)[:, None]
+                # inactive slots scribble on the trash page
+                write_idx = jnp.where(
+                    active[:, None], write_idx, (seq_lens % ps)[:, None])
+                read_idx = (
+                    page_table[:, :, None] * ps
+                    + jnp.arange(ps)[None, None, :]
+                ).reshape(B, C)
+                kv_positions = jnp.broadcast_to(
+                    jnp.arange(C)[None, :], (B, C))
+                kv_valid = (
+                    kv_positions <= seq_lens[:, None]) & active[:, None]
+                paged = PagedView(
+                    write_idx, read_idx, kv_positions, kv_valid,
+                    page_table=page_table, seq_lens=seq_lens, page_size=ps,
+                )
 
             if pp > 1:
                 from ..parallel.pipeline import pp_forward_paged
@@ -1333,31 +1360,39 @@ class InferenceEngine:
                     kv_cache=KVCache(k_pool, v_pool), paged=paged,
                     mesh=mesh,
                 )
-            logits = logits[:, 0]
-            keys = jax.vmap(
-                lambda s, p: jax.random.fold_in(jax.random.key(s), p)
-            )(seeds, seq_lens)
             if fsm is not None:
                 state, gidx, budget, tcs, trans, dists, slack = fsm
-                gmask = grammar_allowed_mask(
-                    state, gidx, budget, active, tcs, trans, dists, slack
+                with jax.named_scope("fsm"):
+                    gmask = grammar_allowed_mask(
+                        state, gidx, budget, active, tcs, trans, dists,
+                        slack,
+                    )
+                    allowed_mask = (
+                        gmask if allowed_mask is None
+                        else allowed_mask & gmask
+                    )
+            with jax.named_scope("sample"):
+                logits = logits[:, 0]
+                keys = jax.vmap(
+                    lambda s, p: jax.random.fold_in(jax.random.key(s), p)
+                )(seeds, seq_lens)
+                toks = sample_tokens_per_slot(
+                    logits, SamplingParams(temps, top_ks, top_ps), keys,
+                    allowed_mask,
                 )
-                allowed_mask = (
-                    gmask if allowed_mask is None else allowed_mask & gmask
-                )
-            toks = sample_tokens_per_slot(
-                logits, SamplingParams(temps, top_ks, top_ps), keys, allowed_mask
-            )
-            if forced_tok is not None:
-                # grammar-forced lanes: the next token is host-known
-                # (singleton mask) — overriding the sample here replaces a
-                # [B, V] mask upload per chained dispatch with a [B] int32
-                toks = jnp.where(forced_on, forced_tok, toks)
-            next_lens = seq_lens + active.astype(jnp.int32)
+                if forced_tok is not None:
+                    # grammar-forced lanes: the next token is host-known
+                    # (singleton mask) — overriding the sample here
+                    # replaces a [B, V] mask upload per chained dispatch
+                    # with a [B] int32
+                    toks = jnp.where(forced_on, forced_tok, toks)
+            with jax.named_scope("step_ctl"):
+                next_lens = seq_lens + active.astype(jnp.int32)
             if fsm is not None:
-                new_state = grammar_advance(state, gidx, toks, active, tcs,
-                                            trans)
-                new_budget = budget - active.astype(jnp.int32)
+                with jax.named_scope("fsm"):
+                    new_state = grammar_advance(
+                        state, gidx, toks, active, tcs, trans)
+                    new_budget = budget - active.astype(jnp.int32)
                 return (cache.k, cache.v, toks, next_lens,
                         new_state, new_budget)
             return cache.k, cache.v, toks, next_lens
@@ -1369,9 +1404,7 @@ class InferenceEngine:
                      self.ecfg.max_window, self.ecfg.max_batch, self.mesh)
         if cache_key in _FN_CACHE:
             return _FN_CACHE[cache_key]
-        jitted = compile_log.instrument(
-            "decode", jax.jit(self._decode_step_body(),
-                              donate_argnums=(1, 2)))
+        jitted = _jit_step("decode", self._decode_step_body())
         _FN_CACHE[cache_key] = jitted
         return jitted
 
@@ -1396,8 +1429,7 @@ class InferenceEngine:
                      g_slack),
             )
 
-        jitted = compile_log.instrument(
-            "decode_fsm", jax.jit(fn, donate_argnums=(1, 2)))
+        jitted = _jit_step("decode_fsm", fn)
         _FN_CACHE[cache_key] = jitted
         return jitted
 
@@ -1424,53 +1456,58 @@ class InferenceEngine:
                *vis):
             # vis = (ov [W, S, H], ov_on [W, S]) iff cfg.vision
             S, W = bucket, width
-            local = jnp.arange(S)[None, :]
-            pos = starts[:, None] + local  # [W, S]
-            in_chunk = (local < chunk_lens[:, None]) & lane_active[:, None]
-            page_idx = jnp.take_along_axis(page_rows, pos // ps, axis=1)
-            write_idx = jnp.where(
-                in_chunk, page_idx * ps + pos % ps, local % ps
-            )
-            read_idx = (
-                page_rows[:, :, None] * ps + jnp.arange(ps)[None, None, :]
-            ).reshape(W, C)
-            kv_positions = jnp.broadcast_to(jnp.arange(C)[None, :], (W, C))
-            kv_valid = (
-                kv_positions < (starts + chunk_lens)[:, None]
-            ) & lane_active[:, None]
-            paged = PagedView(
-                write_idx, read_idx, kv_positions, kv_valid,
-                page_table=page_rows, page_size=ps,
-            )
+            with jax.named_scope("step_ctl"):
+                local = jnp.arange(S)[None, :]
+                pos = starts[:, None] + local  # [W, S]
+                in_chunk = (
+                    local < chunk_lens[:, None]) & lane_active[:, None]
+                page_idx = jnp.take_along_axis(page_rows, pos // ps, axis=1)
+                write_idx = jnp.where(
+                    in_chunk, page_idx * ps + pos % ps, local % ps
+                )
+                read_idx = (
+                    page_rows[:, :, None] * ps
+                    + jnp.arange(ps)[None, None, :]
+                ).reshape(W, C)
+                kv_positions = jnp.broadcast_to(
+                    jnp.arange(C)[None, :], (W, C))
+                kv_valid = (
+                    kv_positions < (starts + chunk_lens)[:, None]
+                ) & lane_active[:, None]
+                paged = PagedView(
+                    write_idx, read_idx, kv_positions, kv_valid,
+                    page_table=page_rows, page_size=ps,
+                )
             logits, cache = forward(
                 params, cfg, chunks, pos,
                 kv_cache=KVCache(k_pool, v_pool), paged=paged, mesh=mesh,
                 embed_override=vis[0] if vis else None,
                 override_on=vis[1] if vis else None,
             )
-            last = jnp.clip(chunk_lens - 1, 0, S - 1)
-            final_logits = jnp.take_along_axis(
-                logits, last[:, None, None], axis=1
-            )[:, 0]  # [W, V]
-            keys = jax.vmap(
-                lambda s, p: jax.random.fold_in(jax.random.key(s), p)
-            )(seeds, starts + chunk_lens - 1)
-            toks = sample_tokens_per_slot(
-                final_logits, SamplingParams(temps, top_ks, top_ps), keys,
-                None,
-            )
+            with jax.named_scope("sample"):
+                last = jnp.clip(chunk_lens - 1, 0, S - 1)
+                final_logits = jnp.take_along_axis(
+                    logits, last[:, None, None], axis=1
+                )[:, 0]  # [W, V]
+                keys = jax.vmap(
+                    lambda s, p: jax.random.fold_in(jax.random.key(s), p)
+                )(seeds, starts + chunk_lens - 1)
+                toks = sample_tokens_per_slot(
+                    final_logits, SamplingParams(temps, top_ks, top_ps),
+                    keys, None,
+                )
             return cache.k, cache.v, toks
 
-        jitted = compile_log.instrument(
-            f"bprefill[{bucket}x{width}]",
-            jax.jit(fn, donate_argnums=(1, 2)))
+        jitted = _jit_step(f"bprefill[{bucket}x{width}]", fn)
         _FN_CACHE[cache_key] = jitted
         return jitted
 
     def _get_multi_decode_fn(self, steps: int, fsm: bool = False):
         """k fused decode steps in one dispatch (lax.scan over the step
-        body).  Sampling stays per-(seed, position) via the in-carry
-        seq_lens, so outputs are token-identical to k single dispatches.
+        body, itself under the `step_ctl` scope so that a device trace
+        tells this scan's plumbing from the layer scan's).  Sampling stays
+        per-(seed, position) via the in-carry seq_lens, so outputs are
+        token-identical to k single dispatches.
         Returns (k_pool', v_pool', toks [k, B], last [B], seq_lens [B]);
         the fsm variant threads (fsm_state, budget) through the carry and
         appends them to the return, so grammar lanes fuse too."""
@@ -1497,12 +1534,13 @@ class InferenceEngine:
                     )
                     return (kp, vp, toks, lens, st, bd), toks
 
-                (kp, vp, last, lens, st, bd), toks_seq = jax.lax.scan(
-                    one,
-                    (k_pool, v_pool, last_tokens, seq_lens, fsm_state,
-                     budget),
-                    None, length=steps,
-                )
+                with jax.named_scope("step_ctl"):
+                    (kp, vp, last, lens, st, bd), toks_seq = jax.lax.scan(
+                        one,
+                        (k_pool, v_pool, last_tokens, seq_lens, fsm_state,
+                         budget),
+                        None, length=steps,
+                    )
                 return kp, vp, toks_seq, last, lens, st, bd
         else:
             def fn(params, k_pool, v_pool, page_table, last_tokens,
@@ -1515,15 +1553,15 @@ class InferenceEngine:
                     )
                     return (kp, vp, toks, lens), toks
 
-                (kp, vp, last, lens), toks_seq = jax.lax.scan(
-                    one, (k_pool, v_pool, last_tokens, seq_lens), None,
-                    length=steps,
-                )
+                with jax.named_scope("step_ctl"):
+                    (kp, vp, last, lens), toks_seq = jax.lax.scan(
+                        one, (k_pool, v_pool, last_tokens, seq_lens), None,
+                        length=steps,
+                    )
                 return kp, vp, toks_seq, last, lens
 
-        jitted = compile_log.instrument(
-            f"multi_decode[{steps}]{'_fsm' if fsm else ''}",
-            jax.jit(fn, donate_argnums=(1, 2)))
+        jitted = _jit_step(
+            f"multi_decode[{steps}]{'_fsm' if fsm else ''}", fn)
         _FN_CACHE[cache_key] = jitted
         return jitted
 
@@ -1582,41 +1620,38 @@ class InferenceEngine:
             # inputs per lane: [last_token, c_1..c_K] at positions
             # seq_len..seq_len+K; positions past cand_len are garbage
             # lanes' padding and write the trash page
-            toks_in = jnp.concatenate([last_tokens[:, None], cands], axis=1)
-            local = jnp.arange(S)[None, :]
-            pos = seq_lens[:, None] + local  # [B, S]
-            in_run = (local <= cand_lens[:, None]) & active[:, None]
-            page_idx = jnp.take_along_axis(
-                page_table,
-                jnp.minimum(pos // ps, page_table.shape[1] - 1),
-                axis=1,
-            )
-            write_idx = jnp.where(
-                in_run, page_idx * ps + pos % ps, local % ps
-            )
-            read_idx = (
-                page_table[:, :, None] * ps + jnp.arange(ps)[None, None, :]
-            ).reshape(B, C)
-            kv_positions = jnp.broadcast_to(jnp.arange(C)[None, :], (B, C))
-            kv_valid = (
-                kv_positions <= (seq_lens + cand_lens)[:, None]
-            ) & active[:, None]
-            paged = PagedView(
-                write_idx, read_idx, kv_positions, kv_valid,
-                page_table=page_table, seq_lens=seq_lens, page_size=ps,
-                chunk_len=cand_lens + 1,
-            )
+            with jax.named_scope("step_ctl"):
+                toks_in = jnp.concatenate(
+                    [last_tokens[:, None], cands], axis=1)
+                local = jnp.arange(S)[None, :]
+                pos = seq_lens[:, None] + local  # [B, S]
+                in_run = (local <= cand_lens[:, None]) & active[:, None]
+                page_idx = jnp.take_along_axis(
+                    page_table,
+                    jnp.minimum(pos // ps, page_table.shape[1] - 1),
+                    axis=1,
+                )
+                write_idx = jnp.where(
+                    in_run, page_idx * ps + pos % ps, local % ps
+                )
+                read_idx = (
+                    page_table[:, :, None] * ps
+                    + jnp.arange(ps)[None, None, :]
+                ).reshape(B, C)
+                kv_positions = jnp.broadcast_to(
+                    jnp.arange(C)[None, :], (B, C))
+                kv_valid = (
+                    kv_positions <= (seq_lens + cand_lens)[:, None]
+                ) & active[:, None]
+                paged = PagedView(
+                    write_idx, read_idx, kv_positions, kv_valid,
+                    page_table=page_table, seq_lens=seq_lens, page_size=ps,
+                    chunk_len=cand_lens + 1,
+                )
             logits, cache = forward(
                 params, cfg, toks_in, pos,
                 kv_cache=KVCache(k_pool, v_pool), paged=paged, mesh=mesh,
             )  # [B, S, V]
-            # per-(seed, position) keys — IDENTICAL to the keys the
-            # sequential decode path folds for these positions
-            keys = jax.vmap(
-                lambda s, prow: jax.vmap(
-                    lambda p: jax.random.fold_in(jax.random.key(s), p)
-                )(prow)
-            )(seeds, pos)
             V = logits.shape[-1]
             rep = lambda x: jnp.repeat(x, S)
             allowed_flat = None
@@ -1628,56 +1663,71 @@ class InferenceEngine:
                 # automaton after the first j candidate tokens (exactly
                 # the states sequential decode would thread); positions
                 # past cand_len walk garbage that acceptance never reads.
-                sts = [fsm_state]
-                for j in range(K):
-                    sts.append(grammar_advance(
-                        sts[-1], fsm_g, cands[:, j], active, g_tc, g_trans
-                    ))
-                states_arr = jnp.stack(sts, axis=1)  # [B, S]
-                masks = [
-                    grammar_allowed_mask(
-                        sts[j], fsm_g, budget - j, active, g_tc, g_trans,
-                        g_dist, g_slack,
-                    )
-                    for j in range(S)
-                ]
-                allowed_flat = jnp.stack(masks, axis=1).reshape(B * S, V)
-            samples = sample_tokens_per_slot(
-                logits.reshape(B * S, V),
-                SamplingParams(rep(temps), rep(top_ks), rep(top_ps)),
-                keys.reshape(B * S),
-                allowed_flat,
-            ).reshape(B, S)
-            # longest exactly-matching candidate prefix, then the bonus
-            # token (the sample after the last accepted candidate)
-            good = (samples[:, :K] == cands) & (
-                jnp.arange(K)[None, :] < cand_lens[:, None]
-            )
-            m = jnp.sum(jnp.cumprod(good.astype(jnp.int32), axis=1), axis=1)
-            adv = jnp.where(active, m + 1, 0)
-            new_lens = seq_lens + adv  # rejected-tail KV rolled back here
-            bonus = jnp.take_along_axis(samples, m[:, None], axis=1)[:, 0]
-            new_last = jnp.where(active, bonus, last_tokens)
-            out = jnp.concatenate([samples, m[:, None]], axis=1)  # [B, S+1]
+                with jax.named_scope("fsm"):
+                    sts = [fsm_state]
+                    for j in range(K):
+                        sts.append(grammar_advance(
+                            sts[-1], fsm_g, cands[:, j], active, g_tc,
+                            g_trans,
+                        ))
+                    states_arr = jnp.stack(sts, axis=1)  # [B, S]
+                    masks = [
+                        grammar_allowed_mask(
+                            sts[j], fsm_g, budget - j, active, g_tc,
+                            g_trans, g_dist, g_slack,
+                        )
+                        for j in range(S)
+                    ]
+                    allowed_flat = jnp.stack(
+                        masks, axis=1).reshape(B * S, V)
+            with jax.named_scope("sample"):
+                # per-(seed, position) keys — IDENTICAL to the keys the
+                # sequential decode path folds for these positions
+                keys = jax.vmap(
+                    lambda s, prow: jax.vmap(
+                        lambda p: jax.random.fold_in(jax.random.key(s), p)
+                    )(prow)
+                )(seeds, pos)
+                samples = sample_tokens_per_slot(
+                    logits.reshape(B * S, V),
+                    SamplingParams(rep(temps), rep(top_ks), rep(top_ps)),
+                    keys.reshape(B * S),
+                    allowed_flat,
+                ).reshape(B, S)
+            with jax.named_scope("step_ctl"):
+                # longest exactly-matching candidate prefix, then the bonus
+                # token (the sample after the last accepted candidate)
+                good = (samples[:, :K] == cands) & (
+                    jnp.arange(K)[None, :] < cand_lens[:, None]
+                )
+                m = jnp.sum(
+                    jnp.cumprod(good.astype(jnp.int32), axis=1), axis=1)
+                adv = jnp.where(active, m + 1, 0)
+                # rejected-tail KV rolled back here
+                new_lens = seq_lens + adv
+                bonus = jnp.take_along_axis(
+                    samples, m[:, None], axis=1)[:, 0]
+                new_last = jnp.where(active, bonus, last_tokens)
+                out = jnp.concatenate(
+                    [samples, m[:, None]], axis=1)  # [B, S+1]
             if gargs:
                 # rejected-tail FSM rollback: the state the lane keeps is
                 # the one reached through the ACCEPTED prefix (states_arr
                 # at m), advanced once by the bonus token — the exact
                 # mirror of the seq_lens clamp above
-                s_m = jnp.take_along_axis(
-                    states_arr, m[:, None], axis=1
-                )[:, 0]
-                new_fsm = grammar_advance(
-                    s_m, fsm_g, bonus, active, g_tc, g_trans
-                )
-                new_budget = budget - adv
+                with jax.named_scope("fsm"):
+                    s_m = jnp.take_along_axis(
+                        states_arr, m[:, None], axis=1
+                    )[:, 0]
+                    new_fsm = grammar_advance(
+                        s_m, fsm_g, bonus, active, g_tc, g_trans
+                    )
+                    new_budget = budget - adv
                 return (cache.k, cache.v, out, new_last, new_lens,
                         new_fsm, new_budget)
             return cache.k, cache.v, out, new_last, new_lens
 
-        jitted = compile_log.instrument(
-            "verify_fsm" if fsm else "verify",
-            jax.jit(fn, donate_argnums=(1, 2)))
+        jitted = _jit_step("verify_fsm" if fsm else "verify", fn)
         _FN_CACHE[cache_key] = jitted
         if not fsm:
             self._verify_fn = jitted
@@ -1700,21 +1750,25 @@ class InferenceEngine:
             # ov_on [S]) embed-override arrays, present iff cfg.vision —
             # per-engine the arity is constant, so one compile either way.
             S = bucket
-            local = jnp.arange(S)
-            positions = (start + local)[None, :]
-            in_chunk = local < chunk_len
-            write_page = page_row[(start + local) // ps]
-            write_idx = jnp.where(
-                in_chunk, write_page * ps + (start + local) % ps, local % ps
-            )[None, :]
-            read_idx = (page_row[:, None] * ps + jnp.arange(ps)[None, :]).reshape(1, C)
-            kv_positions = jnp.arange(C)[None, :]
-            kv_valid = kv_positions < (start + chunk_len)
-            paged = PagedView(
-                write_idx, read_idx, kv_positions, kv_valid,
-                page_table=page_row[None, :], page_size=ps,
-                start=start, chunk_len=chunk_len,
-            )
+            with jax.named_scope("step_ctl"):
+                local = jnp.arange(S)
+                positions = (start + local)[None, :]
+                in_chunk = local < chunk_len
+                write_page = page_row[(start + local) // ps]
+                write_idx = jnp.where(
+                    in_chunk, write_page * ps + (start + local) % ps,
+                    local % ps,
+                )[None, :]
+                read_idx = (
+                    page_row[:, None] * ps + jnp.arange(ps)[None, :]
+                ).reshape(1, C)
+                kv_positions = jnp.arange(C)[None, :]
+                kv_valid = kv_positions < (start + chunk_len)
+                paged = PagedView(
+                    write_idx, read_idx, kv_positions, kv_valid,
+                    page_table=page_row[None, :], page_size=ps,
+                    start=start, chunk_len=chunk_len,
+                )
 
             if pp > 1:
                 from ..parallel.pipeline import pp_forward_paged
@@ -1731,17 +1785,20 @@ class InferenceEngine:
                     embed_override=vis[0][None] if vis else None,
                     override_on=vis[1][None] if vis else None,
                 )
-            last = jnp.clip(chunk_len - 1, 0, S - 1)
-            final_logits = logits[0, last][None, :]  # [1, V]
-            sp = SamplingParams(
-                temperature=temp[None], top_k=top_k[None], top_p=top_p[None]
-            )
-            key = jax.random.fold_in(jax.random.key(seed[0]), start + chunk_len - 1)
-            tok = sample_tokens_per_slot(final_logits, sp, key[None], allowed_mask)
+            with jax.named_scope("sample"):
+                last = jnp.clip(chunk_len - 1, 0, S - 1)
+                final_logits = logits[0, last][None, :]  # [1, V]
+                sp = SamplingParams(
+                    temperature=temp[None], top_k=top_k[None],
+                    top_p=top_p[None],
+                )
+                key = jax.random.fold_in(
+                    jax.random.key(seed[0]), start + chunk_len - 1)
+                tok = sample_tokens_per_slot(
+                    final_logits, sp, key[None], allowed_mask)
             return cache.k, cache.v, tok[0]
 
-        jitted = compile_log.instrument(
-            f"prefill[{bucket}]", jax.jit(fn, donate_argnums=(1, 2)))
+        jitted = _jit_step(f"prefill[{bucket}]", fn)
         _FN_CACHE[cache_key] = jitted
         self._prefill_fns[bucket] = jitted
         return jitted
@@ -2227,11 +2284,6 @@ class InferenceEngine:
         chunk's compute.
         """
         failpoint("engine.step")
-        if self.kernel_sampler is not None:
-            # close the previous sample's trace window (async device
-            # work has had the inter-step gap to land in it) and open a
-            # new one when this step is due
-            self.kernel_sampler.on_step_begin(self.metrics)
         if self.memory_monitor is not None:
             self.memory_monitor.poll()  # throttled to ~1 Hz internally
         if self.kv_tier is not None:
@@ -3328,13 +3380,14 @@ class InferenceEngine:
                         ovs[i], ons[i] = co
                 vis = (self._arg(ovs), self._arg(ons))
         fn = self._get_batched_prefill_fn(bucket, W)
-        self.k_pool, self.v_pool, toks = fn(
-            self.params, self.k_pool, self.v_pool,
-            self._arg(page_rows), self._arg(chunks), self._arg(starts),
-            self._arg(chunk_lens), self._arg(temps), self._arg(top_ks),
-            self._arg(top_ps), self._arg(seeds), self._arg(lane_active),
-            *vis,
-        )
+        with self._dispatch_scope("prefill", reqs):
+            self.k_pool, self.v_pool, toks = fn(
+                self.params, self.k_pool, self.v_pool,
+                self._arg(page_rows), self._arg(chunks), self._arg(starts),
+                self._arg(chunk_lens), self._arg(temps), self._arg(top_ks),
+                self._arg(top_ps), self._arg(seeds), self._arg(lane_active),
+                *vis,
+            )
         self._accrue_prefill_modeled(self._record_prefill_cost([
             (int(chunk_lens[i]), int(starts[i])) for i in range(len(reqs))
         ]))
@@ -3453,22 +3506,23 @@ class InferenceEngine:
             else:
                 vis = (self._arg(co[0]), self._arg(co[1]))
         fn = self._get_prefill_fn(bucket)
-        self.k_pool, self.v_pool, tok = fn(
-            self.params, self.k_pool, self.v_pool,
-            self._arg(page_row), self._arg(chunk),
-            self._arg(np.int32(start)), self._arg(np.int32(chunk_len)),
-            self._arg(np.float32(req.temperature)),
-            self._arg(np.int32(req.top_k)),
-            self._arg(np.float32(req.top_p)),
-            self._arg(np.asarray([req.seed], np.uint32)),
-            # unconstrained requests pass an all-True row (logits come
-            # through bit-identical): ONE prefill program per bucket, so a
-            # first forced tool call never compiles a masked variant on
-            # the scheduler thread
-            (req.prefill_allowed if req.prefill_allowed is not None
-             else self._all_allowed),
-            *vis,
-        )
+        with self._dispatch_scope("prefill", (req,)):
+            self.k_pool, self.v_pool, tok = fn(
+                self.params, self.k_pool, self.v_pool,
+                self._arg(page_row), self._arg(chunk),
+                self._arg(np.int32(start)), self._arg(np.int32(chunk_len)),
+                self._arg(np.float32(req.temperature)),
+                self._arg(np.int32(req.top_k)),
+                self._arg(np.float32(req.top_p)),
+                self._arg(np.asarray([req.seed], np.uint32)),
+                # unconstrained requests pass an all-True row (logits come
+                # through bit-identical): ONE prefill program per bucket,
+                # so a first forced tool call never compiles a masked
+                # variant on the scheduler thread
+                (req.prefill_allowed if req.prefill_allowed is not None
+                 else self._all_allowed),
+                *vis,
+            )
         self._accrue_prefill_modeled(
             self._record_prefill_cost([(chunk_len, start)])
         )
@@ -3970,7 +4024,7 @@ class InferenceEngine:
         d_act = self._dev(np.array([m is not None for m in members]))
         fsm = any(m is not None and m.grammar is not None for m in members)
         fn = self._get_verify_fn(fsm=fsm)
-        with self._dispatch_scope(members):
+        with self._dispatch_scope("verify", members):
             if fsm:
                 (self.k_pool, self.v_pool, out, new_last, new_lens,
                  self._d_fsm, self._d_budget) = fn(
@@ -4114,7 +4168,7 @@ class InferenceEngine:
             for s in self.slots
         )
         fn = self._get_multi_decode_fn(k, fsm=fsm)
-        with self._dispatch_scope(self.slots):
+        with self._dispatch_scope("decode", self.slots):
             if fsm:
                 (self.k_pool, self.v_pool, toks_seq, last, lens,
                  self._d_fsm, self._d_budget) = fn(
@@ -4170,7 +4224,7 @@ class InferenceEngine:
         automaton state); the fn itself gates state/budget updates on the
         group's active mask, so out-of-group lanes keep theirs.
         """
-        with self._dispatch_scope(members):
+        with self._dispatch_scope("decode", members):
             if fsm:
                 (self.k_pool, self.v_pool, toks, self._d_seq_lens,
                  self._d_fsm, self._d_budget) = \

@@ -245,13 +245,6 @@ UTILIZATION_METRIC_KEYS = (
     "measured_busy_s",
     "modeled_busy_s",
     "model_skew",
-    # sampled kernel profiling (ISSUE 18): TRUE device kernel seconds
-    # from jax.profiler trace samples, paired with the modeled roofline
-    # seconds of the SAME sampled steps — kernel_skew is the chip-truth
-    # calibration beside the fetch-maturation model_skew
-    "kernel_samples",
-    "kernel_busy_s",
-    "kernel_skew",
 )
 UTILIZATION_KINDS = ("prefill", "decode", "verify")
 
@@ -716,12 +709,6 @@ class _UtilCounters:
     measured_dispatches: int = 0
     measured_s: float = 0.0
     modeled_s: float = 0.0
-    # sampled kernel profiling (ISSUE 18): true device kernel seconds
-    # from profiler trace samples vs the modeled seconds of those same
-    # sampled steps — kernel_s / kernel_modeled_s is kernel_skew
-    kernel_samples: int = 0
-    kernel_s: float = 0.0
-    kernel_modeled_s: float = 0.0
 
 
 @dataclasses.dataclass
@@ -1060,22 +1047,6 @@ class EngineMetrics:
         u.measured_s += measured_s
         u.modeled_s += modeled_s
 
-    def record_kernel_sample(self, kind: str, kernel_s: float,
-                             modeled_s: float) -> None:
-        """One profiler sample's TRUE device kernel time for `kind`
-        (runtime/kernel_profiler.py) paired with the modeled roofline
-        seconds of the same sampled step.  Per-kind sums feed the
-        kernel_skew gauge — the chip-truth calibration that the
-        fetch-maturation model_skew is checked against."""
-        if not self.enabled:
-            return
-        u = self.util.get(kind)
-        if u is None:
-            return
-        u.kernel_samples += 1
-        u.kernel_s += kernel_s
-        u.kernel_modeled_s += modeled_s
-
     def mark_idle(self) -> None:
         """The engine drained: the gap until the next dispatch is idle
         time, not TPOT / execution time — drop every timing baseline."""
@@ -1297,14 +1268,6 @@ class EngineMetrics:
                 "modeled_busy_s": round(u.modeled_s, 4),
                 "model_skew": round(u.measured_s / u.modeled_s, 3)
                 if u.modeled_s > 0 else 0.0,
-                # profiler-sampled kernel truth (ISSUE 18): device
-                # kernel seconds vs modeled seconds for the SAMPLED
-                # steps only — the calibration model_skew is read
-                # against on real chips
-                "kernel_samples": u.kernel_samples,
-                "kernel_busy_s": round(u.kernel_s, 4),
-                "kernel_skew": round(u.kernel_s / u.kernel_modeled_s, 3)
-                if u.kernel_modeled_s > 0 else 0.0,
             }
             if u.busy_s > 0:
                 sec["achieved_tflops"] = round(u.flops / u.busy_s / 1e12, 3)

@@ -761,7 +761,6 @@ def _add_routes(app: web.Application) -> None:
     r.add_get("/debug/trace/{request_id}", debug_trace)
     r.add_get("/debug/flight/{replica}", debug_flight)
     r.add_get("/debug/compiles", debug_compiles)
-    r.add_get("/debug/kernels", debug_kernels)
     r.add_get("/playground", playground)
     # OPTIONS preflight is answered by cors_middleware before routing
 
@@ -1626,42 +1625,6 @@ async def debug_compiles(request: web.Request) -> web.Response:
     return web.json_response(obs.snapshot())
 
 
-async def debug_kernels(request: web.Request) -> web.Response:
-    """Sampled per-kernel device timing (ISSUE 18): the top-K kernels by
-    device time, grouped by the dispatch kinds active in each sampled
-    window, from KAFKA_TPU_PROFILE_SAMPLE=N every-Nth-step traces.
-    Aggregated across DP replicas (each engine owns its own sampler).
-    404 when sampling is off — the steady-state default, where every
-    dispatch path is byte-identical to a build without this feature."""
-    llm = _state(request)["llm"]
-    engine = getattr(llm, "engine", None)
-    if engine is None:
-        return web.json_response({"error": "no local engine"}, status=404)
-    try:
-        top_k = int(request.query.get("top_k", "20"))
-    except ValueError:
-        return web.json_response(
-            {"error": "top_k must be an integer"}, status=400
-        )
-    samplers = [
-        (i, s) for i, e in enumerate(getattr(engine, "engines", [engine]))
-        if (s := getattr(e, "kernel_sampler", None)) is not None
-    ]
-    if not samplers:
-        return web.json_response(
-            {"error": "kernel sampling disabled "
-                      "(set KAFKA_TPU_PROFILE_SAMPLE=N)"},
-            status=404,
-        )
-    payload = samplers[0][1].snapshot(top_k=top_k)
-    payload["replicas"] = [
-        dict(s.snapshot(top_k=top_k), replica=i) for i, s in samplers
-    ] if len(samplers) > 1 else None
-    if payload["replicas"] is None:
-        del payload["replicas"]
-    return web.json_response(payload)
-
-
 async def playground(request: web.Request) -> web.Response:
     """The in-tree chat client (reference: playground/src/, a Next.js app).
 
@@ -1675,6 +1638,16 @@ async def playground(request: web.Request) -> web.Response:
 
 _PROFILE_BUSY = False
 _PROFILE_DIR = "/tmp/kafka_tpu_trace"
+
+
+def _profile_idle(stopping) -> None:
+    """Done-callback of the executor future that runs stop_trace: the
+    capture guard is released only once the profiler has stopped."""
+    global _PROFILE_BUSY
+    _PROFILE_BUSY = False
+    if not stopping.cancelled() and stopping.exception() is not None:
+        logger.error("/debug/profile: stop_trace failed: %r",
+                     stopping.exception())
 
 
 def _flight_seqs(llm) -> Optional[List[Dict[str, Any]]]:
@@ -1739,6 +1712,7 @@ async def capture_profile(request: web.Request) -> web.Response:
             {"error": "a profile capture is already running"}, status=409
         )
     _PROFILE_BUSY = True
+    stopping = None
     try:
         import asyncio
 
@@ -1760,37 +1734,53 @@ async def capture_profile(request: web.Request) -> web.Response:
             )
         llm = _state(request)["llm"]
         start_seqs = _flight_seqs(llm)
-        # the process-wide trace lock is shared with the every-Nth-step
-        # kernel sampler (runtime/kernel_profiler.py): jax.profiler
-        # supports one trace at a time, so an open sampler window must
-        # make this capture back off rather than crash the scheduler
-        from ..runtime import kernel_profiler
-
-        if not kernel_profiler.try_acquire_trace():
-            return web.json_response(
-                {"error": "device tracing busy (kernel sampler window "
-                          "open, or another capture running)"},
-                status=409,
-            )
+        # jax.profiler supports one trace at a time: _PROFILE_BUSY above
+        # is the process-wide guard (nothing else in the program traces).
+        # start_trace returns in ~40 ms and stays on the loop; stop_trace
+        # serialises the capture (3.6-5.7 s for 5 s of a busy v5e with
+        # the Python tracer on, PERF.md section 5) and runs in the
+        # default executor, so SSE delivery goes on meanwhile (slowed:
+        # the serialiser holds the GIL for much of that time).
         t_start = _time.time()
+        jax.profiler.start_trace(_PROFILE_DIR)
+        t_trace_on = _time.time()
         try:
-            jax.profiler.start_trace(_PROFILE_DIR)
-            try:
-                await asyncio.sleep(seconds)
-            finally:
-                jax.profiler.stop_trace()
+            await asyncio.sleep(seconds)
         finally:
-            kernel_profiler.release_trace()
+            t_trace_off = _time.time()
+            stopping = asyncio.get_running_loop().run_in_executor(
+                None, jax.profiler.stop_trace)
+            # from here the guard belongs to the thread: it is cleared
+            # when stop_trace has RETURNED, not when this handler leaves,
+            # so a handler cancelled at the await below cannot let a
+            # second capture call start_trace beside a running stop_trace
+            stopping.add_done_callback(_profile_idle)
+        # shield: cancelling the handler must not cancel the future the
+        # callback hangs on (the thread would run on, unguarded)
+        await asyncio.shield(stopping)
         t_end = _time.time()
+        logger.info(
+            "/debug/profile: start_trace %.3f s (on the loop), "
+            "stop_trace %.3f s (in the executor), traced %.3f s",
+            t_trace_on - t_start, t_end - t_trace_off,
+            t_trace_off - t_trace_on,
+        )
         end_seqs = _flight_seqs(llm)
     finally:
-        _PROFILE_BUSY = False
+        if stopping is None:
+            _PROFILE_BUSY = False
     flight_window = None
     if start_seqs is not None and end_seqs is not None:
         ends = {e["replica"]: e["seq"] for e in end_seqs}
         flight_window = {
             "t_start": round(t_start, 4),
             "t_end": round(t_end, 4),
+            # t_start..t_end also brackets start_trace (on the event
+            # loop, ~40 ms) and stop_trace (in the default executor,
+            # seconds during which serving goes on, slowed): the traced
+            # interval is t_trace_on..t_trace_off
+            "t_trace_on": round(t_trace_on, 4),
+            "t_trace_off": round(t_trace_off, 4),
             "replicas": [
                 {"replica": s["replica"], "start_seq": s["seq"],
                  "end_seq": ends.get(s["replica"], s["seq"])}

@@ -393,29 +393,6 @@ def render_prometheus(snap: Dict[str, Any]) -> str:
         for k in kinds:
             w.sample("kafka_tpu_dispatch_model_skew",
                      util[k].get("model_skew", 0), {"kind": k})
-        # Profiler-sampled kernel truth (ISSUE 18, runtime/
-        # kernel_profiler.py): TRUE device kernel seconds from sampled
-        # jax.profiler traces vs the modeled seconds of those same
-        # sampled steps — the chip-truth calibration model_skew is read
-        # against (keys kernel_samples / kernel_busy_s / kernel_skew).
-        w.family("kafka_tpu_kernel_samples_total", "counter",
-                 "Profiler trace samples attributed to this dispatch "
-                 "kind (KAFKA_TPU_PROFILE_SAMPLE).")
-        for k in kinds:
-            w.sample("kafka_tpu_kernel_samples_total",
-                     util[k].get("kernel_samples", 0), {"kind": k})
-        w.family("kafka_tpu_kernel_seconds_total", "counter",
-                 "True device kernel time by dispatch kind, from "
-                 "sampled profiler traces.")
-        for k in kinds:
-            w.sample("kafka_tpu_kernel_seconds_total",
-                     util[k].get("kernel_busy_s", 0), {"kind": k})
-        w.family("kafka_tpu_kernel_skew", "gauge",
-                 "Sampled device kernel time / modeled roofline time "
-                 "for the same steps, by kind (0 = no samples yet).")
-        for k in kinds:
-            w.sample("kafka_tpu_kernel_skew",
-                     util[k].get("kernel_skew", 0), {"kind": k})
         if util.get("peak_tflops"):
             w.family("kafka_tpu_device_peak_teraflops", "gauge",
                      "Roofline peak FLOP/s per chip (datasheet or env "

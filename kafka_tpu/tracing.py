@@ -29,7 +29,8 @@ request's carried context, never off a global.
 
 **Span registry.**  Like failpoints' SITES, every span name emitted in
 code must appear in :data:`SPANS` (and every trace-level event name in
-:data:`EVENTS`) — enforced both directions by a static check in
+:data:`EVENTS`, every ``jax.named_scope`` name in
+:data:`DEVICE_SCOPES`) — enforced both directions by a static check in
 tests/test_tracing.py, so the trace schema cannot silently drift.
 
 Timestamps are wall-clock (``time.time()``), the only base comparable
@@ -129,6 +130,43 @@ EVENTS = (
     "handoff",         # dp_router shipped the thread's prefilled pages to
                        # a decode replica; attrs: from_replica, to_replica,
                        # shipped_pages, shipped_bytes, shipped (bool)
+)
+
+
+# Device-side component scopes: every ``jax.named_scope("...")`` literal
+# under kafka_tpu/ must appear here and vice versa (static check in
+# tests/test_tracing.py, the SPANS contract).  A scope is trace-time
+# metadata: it lands in each HLO op's ``op_name`` (the profiler's
+# ``tf_op`` stat) and adds no instruction to any program.  A device-time
+# account reads the INNERMOST registered scope of an op
+# (benchmarks/scope_reduce.py); ops under ``layers`` with no leaf scope
+# are the layer scan's own slicing and write-back of its stacked inputs.
+DEVICE_SCOPES = (
+    "embed",        # token embedding rows, soft-prompt override, rotary
+                    # tables (models/llama.forward)
+    "layers",       # wraps the lax.scan over layers: scan plumbing reads
+                    # layers/while/body/<op> with no leaf scope
+    "attn_norm",    # pre-attention RMSNorm
+    "attn_qkv",     # q/k/v projections + RoPE
+    "kv_write",     # scatter of the new k/v rows into the layer's pool
+    "attn_core",    # scores, softmax, weighted sum: the Pallas paged
+                    # decode / verify / flash-prefill calls, or XLA
+                    # causal_attention over the gathered window
+    "attn_gather",  # XLA paths only, inside attn_core: the page/slot
+                    # gather that materialises the attention window
+    "attn_out",     # output projection + residual add
+    "mlp_norm",     # pre-MLP RMSNorm
+    "mlp",          # dense SwiGLU MLP + residual add
+    "moe_router",   # router logits, top-k, routing weights
+    "moe_experts",  # expert matmuls, combine + residual add
+    "head",         # final RMSNorm + logits
+    "sample",       # last-position select, per-(seed, position) keys,
+                    # sample_tokens_per_slot (engine step programs)
+    "fsm",          # on-device grammar mask / advance (ops/sampling.py)
+    "step_ctl",     # step programs' own control: index plan, seq_lens /
+                    # budget bookkeeping, speculative acceptance, and the
+                    # fused program's scan over steps (its plumbing reads
+                    # step_ctl/while/body/<op>)
 )
 
 

@@ -11,16 +11,16 @@ The load-bearing claims:
     boot MemoryPlan (worst-device aggregation, plan_skew, watermark
     pressure) and synthesizes plan-sourced samples on chips without
     memory_stats so CPU CI runs the same export path,
-  * KAFKA_TPU_PROFILE_SAMPLE=N traces every Nth engine.step into a
-    bounded spill dir and serves per-kernel device durations by
-    dispatch kind; unset = no sampler with byte-identical outputs,
   * COMPILE/MEMORY metric keys are both-directions registries across
     runtime/metrics.py and server/prometheus.py,
-  * GET /debug/compiles and /debug/kernels answer 404-when-off and
-    serve the live payloads when on; /admin/signals is version 7 with
-    the compiles/memory sections,
-  * the bench device_truth phase (sampling overhead A/B + warm-vs-cold
-    rebuild outage) runs.
+  * GET /debug/compiles answers 404-when-off and serves the live
+    payload when on; /admin/signals is version 7 with the
+    compiles/memory sections,
+  * the bench device_truth phase (warm-vs-cold rebuild outage) runs.
+
+The every-Nth-step kernel sampler (/debug/kernels, kernel_skew) that
+shared this file was removed in PR 24: /debug/profile is the one device
+trace and benchmarks/scope_reduce.py reads it by component.
 """
 
 import os
@@ -34,13 +34,11 @@ import jax.numpy as jnp
 
 from kafka_tpu.models import ModelConfig, init_params
 from kafka_tpu.runtime import EngineConfig, GenRequest, InferenceEngine
-from kafka_tpu.runtime import compile_log, kernel_profiler
+from kafka_tpu.runtime import compile_log
 from kafka_tpu.runtime.compile_log import CompileObservatory
-from kafka_tpu.runtime.kernel_profiler import KernelSampler
 from kafka_tpu.runtime.metrics import (
     COMPILE_METRIC_KEYS,
     MEMORY_METRIC_KEYS,
-    UTILIZATION_METRIC_KEYS,
     EngineMetrics,
 )
 from kafka_tpu.runtime.planner import MemoryMonitor
@@ -393,102 +391,6 @@ class TestMemoryMonitor:
 
 
 # ---------------------------------------------------------------------------
-# sampled kernel profiling
-# ---------------------------------------------------------------------------
-
-
-class TestKernelSampler:
-    def test_zero_period_rejected(self):
-        with pytest.raises(ValueError, match="period"):
-            KernelSampler(0)
-
-    def test_build_from_env(self, monkeypatch):
-        monkeypatch.delenv(kernel_profiler.SAMPLE_ENV, raising=False)
-        assert kernel_profiler.build_from_env() is None
-        for junk in ("0", "-3", "nope", ""):
-            monkeypatch.setenv(kernel_profiler.SAMPLE_ENV, junk)
-            assert kernel_profiler.build_from_env() is None
-        monkeypatch.setenv(kernel_profiler.SAMPLE_ENV, "3")
-        s = kernel_profiler.build_from_env()
-        assert s is not None and s.period == 3
-
-    def test_trace_lock_collision_skips_sample(self, tmp_path):
-        # the on-demand POST /debug/profile capture and the sampler
-        # share one process profiler; a held lock means skip, not crash
-        s = KernelSampler(1, spill_dir=str(tmp_path))
-        assert kernel_profiler.try_acquire_trace()
-        try:
-            s.on_step_begin(EngineMetrics())
-            assert s._open_dir is None
-            assert s.samples_total == 0
-        finally:
-            kernel_profiler.release_trace()
-
-    def test_end_to_end_sampling(self, shared, monkeypatch, tmp_path):
-        """Acceptance (ISSUE 18): KAFKA_TPU_PROFILE_SAMPLE=N on a real
-        engine yields a non-empty per-kernel table with device
-        durations bucketed by dispatch kind."""
-        cfg, params = shared
-        monkeypatch.setenv(kernel_profiler.SAMPLE_ENV, "2")
-        monkeypatch.setenv(kernel_profiler.SPILL_ENV, str(tmp_path))
-        monkeypatch.setenv(kernel_profiler.KEEP_ENV, "2")
-        # the calibration split needs modeled seconds: pin the roofline
-        # via env like the model-skew test (CPU has no known peak)
-        monkeypatch.setenv("KAFKA_TPU_PEAK_TFLOPS", "0.001")
-        monkeypatch.setenv("KAFKA_TPU_PEAK_HBM_GBPS", "1")
-        eng = make_engine(params, cfg)
-        assert eng.kernel_sampler is not None
-        assert eng.kernel_sampler.period == 2
-        run_requests(eng, n=3, gen=8, seed_base=42)
-        eng.kernel_sampler.close(eng.metrics)
-        snap = eng.kernel_sampler.snapshot(top_k=10)
-        assert snap["samples_total"] >= 1
-        rows = snap["kernels"]
-        assert rows, "no kernels parsed from the sampled traces"
-        assert set(rows[0]) == {"kind", "kernel", "count", "total_us",
-                                "avg_us", "frac"}
-        assert rows == sorted(rows, key=lambda r: -r["total_us"])
-        assert all(r["total_us"] > 0 for r in rows)
-        # spill pruning keeps at most KEEP raw trace dirs behind
-        import glob as _glob
-
-        assert len(_glob.glob(str(tmp_path / "sample_*"))) <= 2
-        # calibration feedback reached the metrics plane
-        msnap = eng.metrics.snapshot(eng, reset_peak=False)
-        util = msnap["utilization"]
-        sampled = [u for u in util.values()
-                   if isinstance(u, dict) and u.get("kernel_samples")]
-        assert sampled and all(u["kernel_busy_s"] > 0 for u in sampled)
-        from kafka_tpu.server.prometheus import render_prometheus
-
-        text = render_prometheus(msnap)
-        assert "kafka_tpu_kernel_samples_total" in text
-        assert "kafka_tpu_kernel_skew" in text
-
-    def test_off_is_bit_identical(self, shared, monkeypatch, tmp_path):
-        cfg, params = shared
-        outs = {}
-        for period in (0, 1):
-            if period:
-                monkeypatch.setenv(kernel_profiler.SAMPLE_ENV,
-                                   str(period))
-                monkeypatch.setenv(kernel_profiler.SPILL_ENV,
-                                   str(tmp_path))
-            else:
-                monkeypatch.delenv(kernel_profiler.SAMPLE_ENV,
-                                   raising=False)
-            eng = make_engine(params, cfg)
-            if period == 0:
-                assert eng.kernel_sampler is None
-            done = run_requests(eng, n=3, gen=10, seed_base=period)
-            if eng.kernel_sampler is not None:
-                eng.kernel_sampler.close(eng.metrics)
-            outs[period] = [done[f"dt{period}-{i}"].output_ids
-                            for i in range(3)]
-        assert outs[0] == outs[1]
-
-
-# ---------------------------------------------------------------------------
 # registries
 # ---------------------------------------------------------------------------
 
@@ -514,11 +416,6 @@ class TestDeviceTruthRegistry:
                     or f'"{key}"' in prom_src), (
                 f"{key} missing from server/prometheus.py"
             )
-
-    def test_kernel_keys_registered_in_utilization(self):
-        for key in ("kernel_samples", "kernel_busy_s", "kernel_skew"):
-            assert key in UTILIZATION_METRIC_KEYS
-            assert f'"{key}"' in self._source("server/prometheus.py")
 
     def test_anomaly_kinds_cover_device_truth(self):
         from kafka_tpu.runtime.flight_recorder import ANOMALY_KINDS
@@ -619,62 +516,6 @@ class TestServerEndpoints:
 
         asyncio.run(go())
 
-    def test_debug_kernels_endpoint(self, shared, tmp_path, monkeypatch):
-        import asyncio
-
-        cfg, params = shared
-        monkeypatch.setenv(kernel_profiler.SAMPLE_ENV, "1")
-        monkeypatch.setenv(kernel_profiler.SPILL_ENV,
-                           str(tmp_path / "spill"))
-        eng = make_engine(params, cfg)
-        run_requests(eng, n=2, gen=6, seed_base=9)
-        eng.kernel_sampler.close(eng.metrics)
-        provider = self._provider(eng)
-        build = self._app_client(provider, tmp_path)
-
-        async def go():
-            client = await build()
-            try:
-                r = await client.get("/debug/kernels?top_k=5")
-                assert r.status == 200
-                payload = await r.json()
-                assert payload["period"] == 1
-                assert payload["samples_total"] >= 1
-                assert payload["kernels"]
-                assert len(payload["kernels"]) <= 5
-                assert "replicas" not in payload  # single engine
-                r = await client.get("/debug/kernels?top_k=x")
-                assert r.status == 400
-            finally:
-                await client.close()
-                provider.worker.stop()
-
-        asyncio.run(go())
-
-    def test_debug_kernels_404_when_off(self, shared, tmp_path,
-                                        monkeypatch):
-        import asyncio
-
-        cfg, params = shared
-        monkeypatch.delenv(kernel_profiler.SAMPLE_ENV, raising=False)
-        eng = make_engine(params, cfg)
-        assert eng.kernel_sampler is None
-        provider = self._provider(eng)
-        build = self._app_client(provider, tmp_path)
-
-        async def go():
-            client = await build()
-            try:
-                r = await client.get("/debug/kernels")
-                assert r.status == 404
-                assert "KAFKA_TPU_PROFILE_SAMPLE" in \
-                    (await r.json())["error"]
-            finally:
-                await client.close()
-                provider.worker.stop()
-
-        asyncio.run(go())
-
     def test_signals_v7_device_truth_sections(self, shared):
         cfg, params = shared
         eng = make_engine(params, cfg)
@@ -737,12 +578,7 @@ class TestBenchSmoke:
         eng = make_engine(params, cfg)
         args = SimpleNamespace(quick=True, batch=2, prompt_len=16)
         out = device_truth_phase(eng, cfg, args, random.Random(0))
-        samp = out["sampling"]
-        assert samp["tok_s_off"] > 0 and samp["tok_s_on"] > 0
-        assert samp["samples"] >= 1 and samp["kernels_seen"] > 0
-        assert 0.0 <= samp["overhead_frac"] < 1.0
-        # the phase restores the engine's shipped-default state
-        assert eng.kernel_sampler is None
+        assert set(out) == {"rebuild_outage"}
         reb = out["rebuild_outage"]
         assert reb["warm_first_token_s"] > 0
         assert reb["cold_first_token_s"] > 0

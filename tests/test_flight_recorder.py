@@ -344,6 +344,11 @@ class TestEngineIntegration:
         eng = make_engine(params, cfg)
         assert eng.metrics.peak_source == "env"
         run_requests(eng, n=2, gen=12)
+        # a second, longer run on compiled programs: deeper than
+        # fetch_lag, so the drain blocks on the head fetch and the next
+        # poll sees completed decodes (a cold 12-step run can end before
+        # the first decode is observed ready)
+        run_requests(eng, n=2, gen=40, seed_base=1)
         util = eng.metrics.utilization_snapshot()
         dec = util["decode"]
         assert dec["measured_dispatches"] > 0
@@ -704,6 +709,11 @@ class TestServerEndpoints:
                 fw = body["flight_window"]
                 assert fw is not None
                 assert fw["t_end"] >= fw["t_start"]
+                # the traced interval itself, inside the bracket that
+                # also holds start_trace / stop_trace
+                assert (fw["t_start"] <= fw["t_trace_on"]
+                        <= fw["t_trace_off"] <= fw["t_end"])
+                assert fw["t_trace_off"] - fw["t_trace_on"] >= 0.09
                 reps = {w["replica"]: w for w in fw["replicas"]}
                 assert 0 in reps
                 assert reps[0]["end_seq"] >= reps[0]["start_seq"]
@@ -712,6 +722,70 @@ class TestServerEndpoints:
                 provider.worker.stop()
 
         asyncio.run(go())
+
+    def test_profile_guard_outlives_a_cancelled_handler(self, monkeypatch):
+        """stop_trace runs in the executor: a handler cancelled while it
+        waits there must leave the capture guard set until the thread
+        has returned, or a second capture would call start_trace beside
+        a running stop_trace."""
+        import asyncio
+        import threading
+        import types
+
+        import jax
+
+        from kafka_tpu.server import app as app_mod
+
+        monkeypatch.setenv("KAFKA_TPU_PROFILING", "1")
+        stop_entered, release = threading.Event(), threading.Event()
+        calls = []
+        monkeypatch.setattr(jax.profiler, "start_trace",
+                            lambda d: calls.append("start"))
+
+        def slow_stop():
+            calls.append("stop")
+            stop_entered.set()
+            assert release.wait(10.0)
+
+        monkeypatch.setattr(jax.profiler, "stop_trace", slow_stop)
+
+        class Req:
+            headers = {}
+            app = {app_mod.STATE_KEY: {
+                "cfg": types.SimpleNamespace(api_token=None),
+                "llm": object(),
+            }}
+
+            async def json(self):
+                return {"seconds": 0.1}
+
+        async def go():
+            first = asyncio.ensure_future(app_mod.capture_profile(Req()))
+            while not stop_entered.is_set():
+                await asyncio.sleep(0.01)
+            first.cancel()
+            with pytest.raises(asyncio.CancelledError):
+                await first
+            # the thread is still inside stop_trace: still busy
+            assert app_mod._PROFILE_BUSY is True
+            second = await app_mod.capture_profile(Req())
+            assert second.status == 409
+            release.set()
+            for _ in range(500):
+                if not app_mod._PROFILE_BUSY:
+                    break
+                await asyncio.sleep(0.01)
+            assert app_mod._PROFILE_BUSY is False
+            assert calls == ["start", "stop"]
+            # and an uncancelled capture still clears it on its way out
+            third = await app_mod.capture_profile(Req())
+            assert third.status == 200
+            assert app_mod._PROFILE_BUSY is False
+
+        try:
+            asyncio.run(go())
+        finally:
+            release.set()
 
 
 class TestBenchSmoke:

@@ -86,6 +86,38 @@ class TestSpanRegistry:
             f"{set(tracing.EVENTS) - wired}"
         )
 
+    SCOPE_PATTERN = r"jax\.named_scope\(\s*[\"'](\w+)[\"']"
+
+    def test_device_scopes_registry_both_directions(self):
+        """Every jax.named_scope("...") literal under kafka_tpu/ is in
+        DEVICE_SCOPES and every registered scope is used: the device-time
+        account (benchmarks/scope_reduce.py) reads these names out of the
+        capture, so they cannot silently drift."""
+        wired = self._scan((self.SCOPE_PATTERN,))
+        assert not wired - set(tracing.DEVICE_SCOPES), (
+            f"named scopes wired but missing from DEVICE_SCOPES: "
+            f"{wired - set(tracing.DEVICE_SCOPES)}"
+        )
+        assert not set(tracing.DEVICE_SCOPES) - wired, (
+            f"DEVICE_SCOPES documents unwired names: "
+            f"{set(tracing.DEVICE_SCOPES) - wired}"
+        )
+        assert len(set(tracing.DEVICE_SCOPES)) == len(tracing.DEVICE_SCOPES)
+
+    def test_benchmark_scopes_are_registered(self):
+        """The benchmark keeps its own table of the scopes its metrics
+        read (the yardstick does not move when the program adds a finer
+        scope); every name in it must exist in the program."""
+        import importlib.util
+
+        path = (pathlib.Path(__file__).parent.parent / "benchmarks"
+                / "scope_reduce.py")
+        spec = importlib.util.spec_from_file_location("scope_reduce", path)
+        mod = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(mod)
+        assert set(mod.SCOPES) <= set(tracing.DEVICE_SCOPES)
+        assert mod.SCAN_SCOPE in mod.SCOPES
+
     def test_readme_documents_every_span_and_event(self):
         readme = (pathlib.Path(__file__).parent.parent / "README.md"
                   ).read_text()
@@ -274,13 +306,13 @@ class TestEngineSpans:
 
         req = GenRequest(request_id="prof-r", prompt_ids=[1, 2],
                          max_new_tokens=2)
-        assert isinstance(engine._dispatch_scope([req]),
+        assert isinstance(engine._dispatch_scope("decode", [req]),
                           contextlib.nullcontext)
         tracing.configure(profiling=True)
         try:
             root = tracing.start_trace(request_id="prof1")
             req.trace = tracing.current()
-            scope = engine._dispatch_scope([req, None])
+            scope = engine._dispatch_scope("decode", [req, None])
             assert not isinstance(scope, contextlib.nullcontext)
             with scope:
                 pass  # TraceAnnotation is harmless without a live capture
@@ -292,6 +324,59 @@ class TestEngineSpans:
             tracing.configure(profiling=False)
         tr = tracing.get_trace("prof1")
         assert any(s.name == "engine.decode" for s in tr.spans)
+
+    @pytest.mark.parametrize("kind", ["prefill", "decode", "verify"])
+    def test_dispatch_scope_names_the_kind(self, engine, kind, monkeypatch):
+        """`kafka.<kind>[<trace ids>]` per dispatch kind (what
+        benchmarks/trace_reduce.host_label strips to `kafka.<kind>`), and
+        with profiling off one bool read returning a nullcontext: no
+        annotation object is built, whatever the kind."""
+        import contextlib
+
+        from kafka_tpu.runtime import engine as engine_mod
+
+        names = []
+        monkeypatch.setattr(
+            engine_mod.jax.profiler, "TraceAnnotation",
+            lambda name: names.append(name) or contextlib.nullcontext(),
+        )
+        req = GenRequest(request_id="kind-r", prompt_ids=[1, 2],
+                         max_new_tokens=2)
+        assert isinstance(engine._dispatch_scope(kind, [req]),
+                          contextlib.nullcontext)
+        assert names == []
+        tracing.configure(profiling=True)
+        try:
+            root = tracing.start_trace(request_id="kind1")
+            req.trace = tracing.current()
+            engine._dispatch_scope(kind, [req, None])
+            tracing.finish_trace(root)
+        finally:
+            tracing.configure(profiling=False)
+        assert names == [f"kafka.{kind}[{req.trace.trace_id[:8]}]"]
+
+    def test_every_dispatch_site_is_annotated(self, engine, monkeypatch):
+        """A traced request that prefills and decodes passes through a
+        `kafka.prefill` and a `kafka.decode` annotation."""
+        import contextlib
+
+        from kafka_tpu.runtime import engine as engine_mod
+
+        names = []
+        monkeypatch.setattr(
+            engine_mod.jax.profiler, "TraceAnnotation",
+            lambda name: names.append(name) or contextlib.nullcontext(),
+        )
+        tracing.configure(profiling=True)
+        try:
+            engine.submit(GenRequest(request_id="site-r",
+                                     prompt_ids=[3, 1, 4, 1, 5],
+                                     max_new_tokens=4))
+            engine.run_to_completion()
+        finally:
+            tracing.configure(profiling=False)
+        kinds = {n.split("[")[0] for n in names}
+        assert {"kafka.prefill", "kafka.decode"} <= kinds, names
 
     def test_untraced_request_records_nothing(self, engine):
         before = len(tracing.recent_traces())

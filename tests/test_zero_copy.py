@@ -312,6 +312,10 @@ class TestWakePrefetcher:
         want = tier.get_run(key1)
         tier.prefetcher = pre = WakePrefetcher(tier, 64 * MiB)
         pre.stage_runs([key1, key2], "thr")
+        # a staged run whose worker has not started yet is handed back to
+        # the sync path (test_take_reclaims_queued_unstarted) and counts
+        # no hit: let both start before taking them
+        _wait(lambda: all(pre._staged[k].started for k in (key1, key2)))
         got = tier.fetch_run(key1)  # waits out the inflight fetch
         assert got is not None
         for a, b in zip(want[0] + want[1], got[0] + got[1]):
