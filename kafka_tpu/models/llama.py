@@ -18,9 +18,22 @@ LLM compute lived behind a remote gateway, src/llm/portkey.py):
 * Attention runs through ops.attention (XLA reference) or the Pallas
   kernels on TPU; the choice is a config knob threaded by the engine.
 
-The KV cache here is the *contiguous* [L, B, C, Hkv, D] form addressed by
-absolute position == slot index; the paged cache used for serving lives in
-runtime/kv_cache.py and calls the same layer math with its own gather.
+Two cache forms go through the same layer math: the *contiguous*
+[L, B, C, Hkv, D] KVCache addressed by absolute position == slot index
+(tests, `generate`), and the *paged* pool [L, SLOTS, Hkv*D] that serving uses
+(runtime/kv_cache.py) with a PagedView index plan.
+
+**The stacked cache is scan CARRY, never a scanned input.**  The layer scan
+runs over (layer params, layer index); the caches of all layers travel
+through it whole and a layer addresses its part by index.  The paged pool is
+viewed flat as [L*SLOTS, Hkv*D] (merging the two major axes is a bitcast)
+and the layer's offset goes into the INDICES: slot indices move by
+layer*SLOTS, page ids by layer*num_pages (_layer_view), so the scatter of
+the new rows, the page gather and the Pallas kernels' page-table DMAs all
+address the donated buffer where it lies.  Scanning over the pool instead
+(xs in, ys out) made XLA slice every layer's whole pool out and write it
+back on every forward pass: a third of a decode step's device time moving
+pages that the step reads once (PERF.md, PR 25).
 """
 
 from __future__ import annotations
@@ -45,6 +58,18 @@ def _w(lp: Params, name: str, dtype) -> jnp.ndarray:
     XLA fuses the convert into the matmul's operand read, keeping HBM
     traffic int8-sized)."""
     return dequantize(lp[name], dtype)
+
+
+def _flat_pool(pool):
+    """Stacked pool [L, SLOTS, HD] (each leaf of an int8 QTensor pool)
+    viewed as [L*SLOTS, HD]."""
+    return jax.tree.map(lambda a: a.reshape(-1, a.shape[-1]), pool)
+
+
+def _stacked_pool(pool, num_layers: int):
+    """Inverse of _flat_pool."""
+    return jax.tree.map(
+        lambda a: a.reshape(num_layers, -1, a.shape[-1]), pool)
 
 
 @jax.named_scope("kv_write")
@@ -120,7 +145,9 @@ class PagedView(NamedTuple):
     runtime/kv_cache.py). The runtime's page tables translate each
     sequence's logical positions to physical slots; the model only ever sees
     these precomputed flat indices, so the same layer math serves contiguous
-    and paged caches.
+    and paged caches.  Indices are WITHIN a layer, the same for every layer:
+    the layer scan adds each layer's offset in the stacked pool
+    (_layer_view), callers never do.
 
     write_idx:    [B, S]  flat slot for each new token's k/v
     read_idx:     [B, C]  flat slots forming each sequence's attention window
@@ -141,6 +168,21 @@ class PagedView(NamedTuple):
     # prefill-chunk bounds (pallas flash prefill backend only)
     start: Optional[jnp.ndarray] = None
     chunk_len: Optional[jnp.ndarray] = None
+
+
+@jax.named_scope("step_ctl")
+def _layer_view(paged: PagedView, layer, slots: int) -> PagedView:
+    """`paged` re-addressed to `layer` of the flat [L*SLOTS, HD] pool: slot
+    indices move by layer*SLOTS and page ids by layer*num_pages, so page 0
+    of the layer (its trash page) is page layer*num_pages of the flat pool.
+    """
+    base = layer * slots
+    view = paged._replace(write_idx=paged.write_idx + base,
+                          read_idx=paged.read_idx + base)
+    if paged.page_table is not None and paged.page_size is not None:
+        view = view._replace(
+            page_table=paged.page_table + base // paged.page_size)
+    return view
 
 
 def init_kv_cache(cfg: ModelConfig, batch: int, capacity: int, dtype=None) -> KVCache:
@@ -203,8 +245,13 @@ def _attention_block(
     cache_positions: Optional[jnp.ndarray],
     paged: Optional["PagedView"] = None,
     mesh=None,
+    layer=None,
 ) -> Tuple[jnp.ndarray, Optional[jnp.ndarray], Optional[jnp.ndarray]]:
-    """One attention sublayer. x: [B, S, H]. Returns (out, k_cache', v_cache')."""
+    """One attention sublayer. x: [B, S, H]. Returns (out, k_cache', v_cache').
+
+    k_cache/v_cache are the STACKED caches of all layers the caller scans
+    (None = uncached) and `layer` is this layer's index in them; they are
+    returned stacked, with only this layer's new rows written."""
     dt = x.dtype
     with jax.named_scope("attn_qkv"):
         q = jnp.einsum("bsh,hnd->bsnd", x, _w(lp, "wq", dt))
@@ -213,27 +260,38 @@ def _attention_block(
         q = apply_rope(q, cos, sin)
         k = apply_rope(k, cos, sin)
     if paged is not None:
-        # Paged pool: k_cache/v_cache are [TOTAL_SLOTS, Hkv*D] this layer
-        # (dense arrays, or QTensor int8+scales when kv_quantize is on).
+        # Paged pool [L, TOTAL_SLOTS, Hkv*D] (dense arrays, or QTensor
+        # int8+scales when kv_quantize is on), addressed flat: from here to
+        # the end of attention k_cache/v_cache are [L*TOTAL_SLOTS, Hkv*D]
+        # and `paged` carries this layer's offset in every index.
         b, s, hkv, d = k.shape
-        k_cache = _kv_write(k_cache, paged.write_idx, k.reshape(b, s, hkv * d))
-        v_cache = _kv_write(v_cache, paged.write_idx, v.reshape(b, s, hkv * d))
+        num_layers, slots = k_cache.shape[:2]
+        paged = _layer_view(paged, layer, slots)
+        k_cache = _kv_write(
+            _flat_pool(k_cache), paged.write_idx, k.reshape(b, s, hkv * d))
+        v_cache = _kv_write(
+            _flat_pool(v_cache), paged.write_idx, v.reshape(b, s, hkv * d))
     with jax.named_scope("attn_core"):
         out, k_cache, v_cache = _attention_core(
             q, k, v, cfg, positions, k_cache, v_cache, kv_valid,
-            cache_positions, paged, mesh,
+            cache_positions, paged, mesh, layer,
         )
+    if paged is not None:
+        k_cache = _stacked_pool(k_cache, num_layers)
+        v_cache = _stacked_pool(v_cache, num_layers)
     with jax.named_scope("attn_out"):
         out = jnp.einsum("bsnd,ndh->bsh", out, _w(lp, "wo", out.dtype))
     return out, k_cache, v_cache
 
 
 def _attention_core(q, k, v, cfg, positions, k_cache, v_cache, kv_valid,
-                    cache_positions, paged, mesh):
+                    cache_positions, paged, mesh, layer):
     """Scores, softmax and weighted sum for one layer, by cache form and
-    backend.  New k/v rows are already in a paged pool (_attention_block
-    wrote them); the contiguous cache is written here.  Returns
-    (out [B, S, Hq, D], k_cache', v_cache')."""
+    backend.  Paged: k_cache/v_cache are the flat [L*SLOTS, Hkv*D] pools,
+    the new rows already in them, and `paged` addresses this layer
+    (_attention_block did both).  Contiguous: the stacked [L, B, C, Hkv, D]
+    cache is written here at `layer`.  Returns (out [B, S, Hq, D],
+    k_cache', v_cache')."""
     dt = q.dtype
     if paged is not None:
         b, s, hkv, d = k.shape
@@ -409,14 +467,16 @@ def _attention_core(q, k, v, cfg, positions, k_cache, v_cache, kv_valid,
         slots = positions if cache_positions is None else cache_positions
         b_idx = jnp.arange(q.shape[0])[:, None]
         with jax.named_scope("kv_write"):
-            k_cache = k_cache.at[b_idx, slots].set(k.astype(k_cache.dtype))
-            v_cache = v_cache.at[b_idx, slots].set(v.astype(v_cache.dtype))
-        cap = k_cache.shape[1]
+            k_cache = k_cache.at[layer, b_idx, slots].set(
+                k.astype(k_cache.dtype))
+            v_cache = v_cache.at[layer, b_idx, slots].set(
+                v.astype(v_cache.dtype))
+        cap = k_cache.shape[2]
         kv_pos = jnp.broadcast_to(jnp.arange(cap)[None, :], (q.shape[0], cap))
         out = causal_attention(
             q,
-            k_cache,
-            v_cache,
+            k_cache[layer],
+            v_cache[layer],
             q_positions=positions,
             kv_positions=kv_pos,
             kv_valid=kv_valid,
@@ -520,16 +580,18 @@ def forward(
         inv_freq = rope_frequencies(cfg)
         cos, sin = rope_cos_sin(positions, inv_freq)
 
-    # Every op of the layer body sits under a leaf scope (residual adds
-    # included), so what a device trace shows under `layers` alone is the
-    # scan's own slicing and write-back of its stacked inputs.
-    def layer_body(h, scanned):
-        lp, kc, vc = scanned
+    # The stacked caches are CARRY (module docstring): the scan slices only
+    # the layer's weights.  Every op of the layer body sits under a leaf
+    # scope (residual adds included), so what a device trace shows under
+    # `layers` alone is the scan's own slicing of its stacked inputs.
+    def layer_body(carry, scanned):
+        h, kc, vc = carry
+        lp, layer = scanned
         with jax.named_scope("attn_norm"):
             attn_in = rms_norm(h, lp["ln_attn"], cfg.rms_norm_eps)
         attn_out, kc, vc = _attention_block(
             attn_in, lp, cfg, cos, sin, positions, kc, vc, kv_valid,
-            cache_positions, paged, mesh,
+            cache_positions, paged, mesh, layer,
         )
         with jax.named_scope("attn_out"):
             h = h + attn_out
@@ -542,23 +604,17 @@ def forward(
         else:
             with jax.named_scope("mlp"):
                 h = h + _mlp_block(mlp_in, lp)
-        return h, (kc, vc)
+        return (h, kc, vc), None
 
     with jax.named_scope("layers"):
-        if kv_cache is None:
-            x, _ = jax.lax.scan(
-                lambda h, lp: (layer_body(h, (lp, None, None))[0], None),
-                x,
-                params["layers"],
-            )
-            new_cache = None
-        else:
-            x, (k_new, v_new) = jax.lax.scan(
-                lambda h, s: layer_body(h, s),
-                x,
-                (params["layers"], kv_cache.k, kv_cache.v),
-            )
-            new_cache = KVCache(k=k_new, v=v_new)
+        kc, vc = (None, None) if kv_cache is None else kv_cache
+        num_layers = jax.tree.leaves(params["layers"])[0].shape[0]
+        (x, kc, vc), _ = jax.lax.scan(
+            layer_body,
+            (x, kc, vc),
+            (params["layers"], jnp.arange(num_layers)),
+        )
+        new_cache = None if kv_cache is None else KVCache(k=kc, v=vc)
 
     with jax.named_scope("head"):
         logits = _logits_head(x, params, cfg)

@@ -25,7 +25,9 @@ Two entry points:
 * `pp_forward` — uncached forward (numerics reference, offline scoring).
 * `pp_forward_paged` — the *serving* path: same stage structure but every
   stage reads/writes its local shard of the engine's paged KV pool
-  ([L, SLOTS, Hkv*D] with L sharded over "pp", kv heads over "tp"), so
+  ([L, SLOTS, Hkv*D] with L sharded over "pp", kv heads over "tp"; the
+  shard is the stage's layer-scan carry, addressed by local layer index
+  exactly as models/llama.py forward addresses the whole pool), so
   the continuous-batching engine (runtime/engine.py) drives prefill and
   decode through pipeline stages exactly as it does TP — each device
   holds 1/pp of the weights AND 1/pp of the KV cache.
@@ -144,7 +146,9 @@ def pp_forward_paged(
     flat slot axis; k_pool/v_pool are [L, SLOTS, Hkv*D] placed per
     `kv_pool_spec_pp`.  Returns (logits [B, S, V] f32, k_pool', v_pool').
 
-    Stage s computes its layers (reading/writing its local pool shard),
+    Stage s computes its layers (its local pool shard [L/pp, SLOTS, HD/tp]
+    rides the layer scan as carry; each layer scatters its new rows into
+    it and gathers its window from it at the layer's local offset),
     the hidden state ppermutes to stage s+1, and the last stage's output
     is broadcast for the (replicated) logits head.  Attention inside a
     stage is the XLA gather formulation with heads tp-local and explicit
@@ -172,22 +176,23 @@ def pp_forward_paged(
         paged_local = PagedView(write_idx, read_idx, kv_positions, kv_valid)
 
         def run_stage(operand):
-            h, kp, vp = operand
-
-            def body(hh, scanned):
-                lp, kc, vc = scanned
+            # the stage's pool shard is scan carry and a layer addresses
+            # its part by its LOCAL index (models/llama.py's convention)
+            def body(carry, scanned):
+                hh, kc, vc = carry
+                lp, layer = scanned
                 attn_in = rms_norm(hh, lp["ln_attn"], cfg.rms_norm_eps)
                 attn_out, kc, vc = _attention_block(
                     attn_in, lp, cfg, cos, sin, pos, kc, vc,
-                    None, None, paged_local, None,
+                    None, None, paged_local, None, layer,
                 )
                 hh = hh + tp_reduce(attn_out)
                 mlp_in = rms_norm(hh, lp["ln_mlp"], cfg.rms_norm_eps)
                 hh = hh + tp_reduce(_mlp_block(mlp_in, lp))
-                return hh, (kc, vc)
+                return (hh, kc, vc), None
 
-            h2, (k_new, v_new) = lax.scan(body, h, (layer_params, kp, vp))
-            return h2, k_new, v_new
+            return lax.scan(
+                body, operand, (layer_params, jnp.arange(kp.shape[0])))[0]
 
         h = pcast(h, ("pp", "tp"), to="varying")
         for s in range(pp):  # sequential stages; only rank s computes

@@ -13,6 +13,13 @@ Page tables, not the pool, are what the jitted step functions consume: a
 [B, max_pages] int32 array per step, from which read/write flat indices are
 derived *on device* (models/llama.py PagedView).  Physical page 0 is
 reserved as the trash page — inactive batch slots point their writes at it.
+
+Page ids and slot indices are per layer and the same in every layer.  The
+step programs never slice a layer out of the pool: the stacked arrays ride
+the layer scan as carry, viewed flat as [L * num_pages * page_size, Hkv*D],
+and layer l's page p is page l * num_pages + p of that view
+(models/llama.py _layer_view) — so each layer has its own trash page, page
+l * num_pages, which is what page 0 of its slice was.
 """
 
 from __future__ import annotations
