@@ -96,13 +96,20 @@ def op_calls(ctx: Dict[str, Any], pattern: str) -> Optional[int]:
 
 def batch_occupancy(ctx: Dict[str, Any]) -> Optional[float]:
     """Busy lanes per decode step over the window.  /metrics gives the running
-    ratio busy_slots / steps; the window's is the ratio of the deltas."""
-    a, b = ctx["after"]["decode"], ctx["before"]["decode"]
-    steps = a["steps"] - b["steps"]
-    if steps <= 0:
+    ratio busy_slots / steps; the window's is the ratio of the deltas.  Under
+    dp the aggregate has no `decode` group: busy lanes and steps are summed
+    over the replicas, so the value is still lanes per step of one replica."""
+    after = ctx["after"].get("replicas") or [ctx["after"]]
+    before = ctx["before"].get("replicas") or [ctx["before"]]
+    if len(after) != len(before):
         return None
-    busy = a["batch_occupancy"] * a["steps"] - b["batch_occupancy"] * b["steps"]
-    return busy / steps
+    steps = busy = 0.0
+    for x, y in zip(after, before):
+        a, b = x["decode"], y["decode"]
+        steps += a["steps"] - b["steps"]
+        busy += (a["batch_occupancy"] * a["steps"]
+                 - b["batch_occupancy"] * b["steps"])
+    return busy / steps if steps > 0 else None
 
 
 def memory_peak_bytes(info: Dict[str, Any]) -> int:

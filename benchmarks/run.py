@@ -24,7 +24,6 @@ from __future__ import annotations
 import argparse
 import asyncio
 import gc
-import importlib.util
 import json
 import os
 import random
@@ -45,6 +44,7 @@ import aiohttp  # noqa: E402
 
 import e2e  # noqa: E402
 import loadgen  # noqa: E402
+import named  # noqa: E402
 import readers  # noqa: E402
 
 EXIT_NO_CHIP = 3
@@ -95,7 +95,8 @@ class Cell:
 class Server:
     """The child that holds the chips."""
 
-    def __init__(self, cell: Cell, out: str, trace: bool, rehearse: bool):
+    def __init__(self, cell: Cell, root: str, out: str, trace: bool,
+                 rehearse: bool):
         self.out, self.cell = out, cell
         with socket.socket() as s:
             s.bind(("127.0.0.1", 0))
@@ -118,7 +119,7 @@ class Server:
         argv = [sys.executable, os.path.join(HERE, "serve.py"),
                 "--config", cell.config_file, "--name", cell.config_name,
                 "--port", str(self.port), "--out", out,
-                "--chips", str(cell.chips)]
+                "--chips", str(cell.chips), "--root", root]
         if rehearse:
             argv.append("--rehearse")
         self.log_path = os.path.join(out, "serve.log")
@@ -181,10 +182,14 @@ async def fill(http, server: Server, cell: Cell, seed: int) -> None:
     """Set-up traffic before the lead-in.  One request caches the shared
     system prompt; then a burst runs the fused multi-step decode program with
     several lanes busy, which the program's warm-up leaves to first traffic
-    (PERF.md section 6).  Under dp the router's prefix-aware pick keeps
-    sending cold threads to the replica that is already warm, so the burst
-    is as wide as all the replicas' lanes together and is repeated until
-    every replica holds cached pages."""
+    (PERF.md section 6).  Under dp the router's prefix-aware pick sends a
+    cold thread to a replica that already holds the prefix, so the first
+    burst, as wide as all the replicas' lanes together, lands mostly on one
+    replica and leaves the others the prefix and a lane or two (my chip run
+    1, PR 26: the other three compiled their fused program in the lead-in
+    and inside the window).  The burst is therefore repeated until one that
+    began with every replica warm, and so was spread by load, set off no
+    compile: then every replica has run every program the traffic uses."""
     serving = cell.config["serving"]
     dp = int(serving.get("dp_size", 1))
     width = 4 if dp == 1 else dp * int(serving["max_batch"])
@@ -203,15 +208,24 @@ async def fill(http, server: Server, cell: Cell, seed: int) -> None:
         if bad:
             raise RuntimeError(f"fill request failed: {bad[0]['error']}")
 
+    async def compiles() -> int:
+        snap = await get_json(http, server.base + "/debug/compiles")
+        return int(snap["totals"]["compiles"])  # `records` is a ring
+
     await burst("p", 1, 8)
-    for attempt in range(4):
+    warm = False  # every replica held the prefix when the burst began
+    for attempt in range(4 if dp == 1 else 8):
+        seen = 0 if dp == 1 else await compiles()
         await burst(f"b{attempt}", width, 40)
         snap = await get_json(http, server.base + "/metrics")
-        if all((rep.get("prefix_cache") or {}).get("cached_pages", 0) > 0
-               for rep in snap.get("replicas") or [snap]):
+        cached = all(
+            (rep.get("prefix_cache") or {}).get("cached_pages", 0) > 0
+            for rep in snap.get("replicas") or [snap])
+        if cached and (dp == 1 or (warm and await compiles() == seen)):
             return
-    print("run.py: not every replica cached the system prompt",
-          file=sys.stderr, flush=True)
+        warm = cached
+    print("run.py: fill did not settle: a replica without the system prompt, "
+          "or a compile in every burst", file=sys.stderr, flush=True)
 
 
 async def get_json(http, url: str) -> Any:
@@ -336,17 +350,7 @@ def verdict(cell: Cell, ctx: Dict[str, Any], rehearse: bool) -> Dict[str, Any]:
 def read_layer_metric(root: str, name: str, ctx: Dict[str, Any]):
     """`<root>/layer_metrics/<name>.py` holds `read(ctx) -> value | None`;
     the benchmark's own readers serve any data root that has none."""
-    for base in (root, HERE):
-        path = os.path.join(base, "layer_metrics", name + ".py")
-        if os.path.exists(path):
-            break
-    else:
-        raise FileNotFoundError(f"no reader for per-layer metric {name!r}")
-    spec = importlib.util.spec_from_file_location(
-        "layer_metric_" + name.replace("-", "_").replace(".", "_"), path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read(ctx)
+    return named.load((root, HERE), "layer_metrics", name).read(ctx)
 
 
 def main() -> None:
@@ -371,7 +375,7 @@ def main() -> None:
     shutil.rmtree(out, ignore_errors=True)
     os.makedirs(out)
 
-    server = Server(cell, out, bool(args.trace), args.rehearse)
+    server = Server(cell, root, out, bool(args.trace), args.rehearse)
     ctx = None
     gc.disable()  # no collector pause between a due time and its send
     try:

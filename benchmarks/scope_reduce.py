@@ -2,6 +2,8 @@
 
     python benchmarks/scope_reduce.py <trace_dir>     # the account, as JSON
     python benchmarks/scope_reduce.py --table <trace_dir>   # as a table
+    python benchmarks/scope_reduce.py --table --config <configuration file> \
+        <trace_dir>                 # with the configuration's own `scopes`
 
 The program names its device work (PR 24): `jax.named_scope` components in
 `models/llama.py` and the engine's step programs (`kafka_tpu.tracing.
@@ -34,10 +36,15 @@ the scopes of this PR's).  So a capture in which a step program with 1% of the
 busy time names no component has no shares (`unnamed_programs`), and the
 program's single decode step is `jit_body_decode`, a name the parent never
 compiled.
-The table of scopes is the benchmark's own copy: a scope the program adds
-later, nested inside one of these, stays with the enclosing component until a
-benchmark PR lists it (tests/test_tracing.py holds `SCOPES` to be a subset of
-the program's registry).
+The table of scopes is the benchmark's own copy (tests/test_tracing.py holds
+`SCOPES` to be a subset of the program's registry).  A scope the program adds
+later for a new block is listed by the configuration that runs the block, under
+`scopes` in its own file: `component` then stops at it as at one of `SCOPES`,
+and it is a component of its own, a row of the table and part of no existing
+share.  A scope nobody lists stays with the enclosing component, and directly
+under the layer scan that is `scan_plumbing`, which `dev_kv_move_share` sums:
+so a configuration lists every scope of its block.  With no `scopes` key the
+account is what it was.
 
 The arithmetic (`account`) works on plain lists and is tested without a
 profile; `load_ops` is checked against the recorded v5e capture
@@ -83,20 +90,32 @@ Op = Tuple[str, int, int, Optional[str], Optional[str], Optional[int],
 
 
 @functools.lru_cache(maxsize=None)
-def component(tf_op: Optional[str]) -> str:
+def component(tf_op: Optional[str], extra: Tuple[str, ...] = ()) -> str:
+    """`extra`: the configuration's own scopes, beside `SCOPES`."""
     if not tf_op:
         return UNSCOPED
     for seg in reversed(tf_op.rstrip(":").split("/")):
-        if seg in SCOPES:
+        if seg in SCOPES or seg in extra:
             return SCAN_PLUMBING if seg == SCAN_SCOPE else seg
     return OTHER
 
 
-def account(planes: List[Dict[str, Any]]) -> Optional[Dict[str, Any]]:
+def config_scopes(config: Dict[str, Any]) -> Tuple[str, ...]:
+    """The `scopes` a configuration file lists for its own block."""
+    extra = tuple(config.get("scopes") or ())
+    clash = set(extra) & {SCAN_PLUMBING, OTHER, UNSCOPED}
+    if clash:
+        raise ValueError(f"`scopes` may not list {sorted(clash)}")
+    return extra
+
+
+def account(planes: List[Dict[str, Any]],
+            scopes: Tuple[str, ...] = ()) -> Optional[Dict[str, Any]]:
     """planes: [{"name", "ops": [Op], "modules": [(name, start_ps, dur_ps)]}],
     one per chip.  Seconds are summed over the chips.  An op's program is
     the launch whose fingerprint its `program_id` gives; an op without one
-    (no v5e capture so far has any) is under the program `?`."""
+    (no v5e capture so far has any) is under the program `?`.  `scopes` are
+    the configuration's own, components beside those of `SCOPES`."""
     table: Dict[str, Dict[str, float]] = {}
     # (op label, category, component) -> [seconds, calls, bytes a call]
     loose: Dict[Tuple[str, str, str], List[Any]] = {}
@@ -106,7 +125,7 @@ def account(planes: List[Dict[str, Any]]) -> Optional[Dict[str, Any]]:
                  (FINGERPRINT.match(e[0]) for e in plane["modules"]) if m}
         keyed = []
         for op in plane["ops"]:
-            comp = component(op[3])
+            comp = component(op[3], scopes)
             scoped = scoped or comp not in (UNSCOPED, OTHER)
             label = trace_reduce.op_label(op[0])
             keyed.append(((by_id.get(op[5], "?"), comp, label, op[4] or ""),
@@ -234,10 +253,11 @@ def load_ops(path: str) -> Optional[List[Dict[str, Any]]]:
     return planes
 
 
-def account_dir(trace_dir: str) -> Optional[Dict[str, Any]]:
+def account_dir(trace_dir: str,
+                scopes: Tuple[str, ...] = ()) -> Optional[Dict[str, Any]]:
     path = trace_reduce.find_xplane(trace_dir)
     planes = load_ops(path) if path else None
-    return account(planes) if planes else None
+    return account(planes, scopes) if planes else None
 
 
 def of_ctx(ctx: Dict[str, Any]) -> Optional[Dict[str, Any]]:
@@ -247,7 +267,8 @@ def of_ctx(ctx: Dict[str, Any]) -> Optional[Dict[str, Any]]:
     if "scope_account" not in ctx:
         acc = ctx["scope_account"] = (
             None if not ctx.get("trace") else account_dir(
-                os.path.join(ROOT, ".bench_out", ctx["cell"].name, "trace")))
+                os.path.join(ROOT, ".bench_out", ctx["cell"].name, "trace"),
+                config_scopes(ctx["cell"].config)))
         if acc and acc["scoped"] and acc["unnamed_programs"]:
             print("scope_reduce: no shares, these programs name no "
                   f"component: {acc['unnamed_programs']}", file=sys.stderr,
@@ -272,10 +293,15 @@ def table_lines(acc: Dict[str, Any]) -> List[str]:
 
 
 if __name__ == "__main__":
-    as_table = sys.argv[1] == "--table"
-    result = account_dir(sys.argv[-1])
+    argv = sys.argv[1:]
+    as_table = "--table" in argv
+    own: Tuple[str, ...] = ()
+    if "--config" in argv:
+        with open(argv[argv.index("--config") + 1]) as f:
+            own = config_scopes(json.load(f))
+    result = account_dir(argv[-1], own)
     if result is None:
-        print("scope_reduce: no device ops in " + sys.argv[-1],
+        print("scope_reduce: no device ops in " + argv[-1],
               file=sys.stderr)
         raise SystemExit(1)
     if as_table:

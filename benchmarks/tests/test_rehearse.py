@@ -34,6 +34,14 @@ def run(args, timeout=400):
                                           "limits_met_share",
                                           "client_tpot_p50_ms",
                                           "kv_pool_used_share"}),
+    # the shape of yi-1.5-9b-dp4.chat-decode: a closed loop of dp_size x
+    # max_batch clients across the router; lanes per step summed over the
+    # replicas (readers.batch_occupancy)
+    ("tiny-dense-dp2.chat-decode", 1, {"replica_req_spread",
+                                       "queue_wait_p90_ms",
+                                       "decode_batch_occupancy",
+                                       "prefix_hit_share",
+                                       "kv_pool_used_share"}),
 ])
 def test_rehearsal_prints_a_contract_shaped_line(cell, trace, expect):
     p = run(["--workload", cell, "--seed", "3", "--seconds", "6",

@@ -3,7 +3,6 @@ without a trace, and `load_ops` against a small scoped trace recorded on the
 v5e (benchmarks/tests/record_scoped_trace.py: a 2-layer engine at toy widths
 serving one request alone, then three at once)."""
 
-import json
 import os
 import re
 import shutil
@@ -51,6 +50,75 @@ def op(name, start_us, dur_us, tf_op, cat="fusion", pid=7, nbytes=0):
 ])
 def test_component_of_a_tf_op(tf_op, expected):
     assert scope_reduce.component(tf_op) == expected
+    # a configuration's own scopes move no op that is not under one of them
+    assert scope_reduce.component(tf_op, ("moe_dispatch",)) == expected
+
+
+NEW_BLOCK = "jit(f)/layers/while/body/moe_dispatch/dot_general"
+
+
+@pytest.mark.parametrize("tf_op,listed,expected", [
+    # pinned as today's behaviour: a scope nobody lists, directly under the
+    # layer scan, is the scan's plumbing, which dev_kv_move_share sums
+    (NEW_BLOCK, (), "scan_plumbing"),
+    (NEW_BLOCK, ("qk_norm",), "scan_plumbing"),
+    (NEW_BLOCK, ("moe_dispatch", "qk_norm"), "moe_dispatch"),
+    (NEW_BLOCK + ":", ("moe_dispatch",), "moe_dispatch"),
+    # the innermost wins among the listed and the built-in alike
+    (LAYER + "attn_core/qk_norm/mul:", ("qk_norm",), "qk_norm"),
+    (LAYER + "moe_dispatch/mlp/dot_general:", ("moe_dispatch",), "mlp"),
+])
+def test_a_configurations_own_scope_is_its_own_component(tf_op, listed,
+                                                         expected):
+    assert scope_reduce.component(tf_op, listed) == expected
+
+
+def test_a_listed_scope_is_a_row_of_its_own_and_in_no_existing_share():
+    ops = [
+        op("fusion.1", 0, 30, NEW_BLOCK),
+        op("fusion.2", 30, 10, "jit(f)/layers/while/body/dynamic_slice:"),
+        op("fusion.3", 40, 60, "jit(f)/layers/while/body/mlp/dot_general:"),
+    ]
+    planes = [{"name": "/device:TPU:0", "ops": ops,
+               "modules": [("jit_fn_f(7)", 0, 100 * US)]}]
+    kv = ("kv_write", scope_reduce.SCAN_PLUMBING)
+    plain = scope_reduce.account(planes)
+    assert "moe_dispatch" not in plain["by_component"]
+    assert scope_reduce.share(plain, kv) == pytest.approx(40.0)
+    own = scope_reduce.account(planes, ("moe_dispatch",))
+    assert own["by_component"]["moe_dispatch"] == pytest.approx(30e-6)
+    assert own["busy_s"] == pytest.approx(plain["busy_s"])
+    assert scope_reduce.share(own, kv) == pytest.approx(10.0)
+    for comps in (("attn_core", "attn_gather"),
+                  ("mlp", "moe_router", "moe_experts"), ("unscoped",)):
+        assert scope_reduce.share(own, comps) == scope_reduce.share(
+            plain, comps)
+    assert any(ln.startswith("| `moe_dispatch` | 30.00 |")
+               for ln in scope_reduce.table_lines(own))
+
+
+def test_the_readers_take_the_scopes_from_the_cells_configuration(
+        monkeypatch):
+    seen = []
+
+    def fake_account_dir(trace_dir, scopes=()):
+        seen.append((os.path.basename(os.path.dirname(trace_dir)), scopes))
+        return None
+
+    monkeypatch.setattr(scope_reduce, "account_dir", fake_account_dir)
+
+    class Cell:
+        name = "some-cell"
+        config = {"scopes": ["moe_dispatch", "qk_norm"]}
+
+    assert scope_reduce.of_ctx({"trace": {"busy_s": 1.0},
+                                "cell": Cell()}) is None
+    Cell.config = {}
+    scope_reduce.of_ctx({"trace": {"busy_s": 1.0}, "cell": Cell()})
+    assert seen == [("some-cell", ("moe_dispatch", "qk_norm")),
+                    ("some-cell", ())]
+    with pytest.raises(ValueError):
+        scope_reduce.config_scopes({"scopes": ["unscoped"]})
 
 
 def test_account_nests_attributes_and_sums():
@@ -162,6 +230,7 @@ NEW_READERS = ["dev_attn_share", "dev_kv_move_share", "dev_ffn_share",
 def test_reader_returns_none_without_a_trace(name):
     class Cell:
         name = "no-such-cell"
+        config = {}
 
     for ctx in ({"trace": None, "cell": Cell()},
                 # a reduced trace but no capture on disk (nothing to parse)
@@ -169,45 +238,45 @@ def test_reader_returns_none_without_a_trace(name):
         assert run.read_layer_metric(run.HERE, name, ctx) is None
 
 
-def test_new_readers_are_listed_for_both_cells():
+def test_a_metric_lists_cells_only_where_some_cell_cannot_report_it():
+    """BENCHMARK.json's rule (benchmarks/README.md): a per-layer metric that
+    every cell can report has no `workloads` key, so a new cell is an entry
+    of its own and no edit of these; a list stands only where a reader finds
+    nothing in some cell (a kernel one backend does not run).  The driver
+    refuses a traced line that lacks a metric without the key, which is why
+    such a metric keeps it."""
     bench = run.load_json(os.path.join(run.ROOT, "BENCHMARK.json"))
-    cells = [w["name"] for w in bench["workloads"]]
+    cells = {w["name"] for w in bench["workloads"]}
+    held = {m["name"] for m in bench["end_to_end"]}
+    by_name = {m["name"]: m for m in bench["per_layer"]}
     for name in NEW_READERS:
-        m = next(m for m in bench["per_layer"] if m["name"] == name)
+        m = by_name[name]
+        assert "workloads" not in m, name
         assert m["source"] == "device_trace" and m["unit"] == "%"
         assert m["layer"] == "jitted step programs"
         assert m["moves"] == "tpot_p50_ms"
-        assert set(m.get("workloads", cells)) == set(cells)
+    # an end-to-end metric applies to every cell: none lists any
+    assert not [m["name"] for m in bench["end_to_end"] if "workloads" in m]
+    listed = {n: m["workloads"] for n, m in by_name.items()
+              if "workloads" in m}
+    # Mixtral runs no paged_decode kernel
+    assert listed == {"paged_attn_roofline": ["yi-1.5-9b.chat-decode"]}
+    for name, m in by_name.items():
+        assert set(m.get("workloads", cells)) <= cells, name
+        assert m["moves"] in held, name  # a mistyped `moves` drops nothing
+        assert os.path.exists(os.path.join(  # its reader, found by name
+            run.HERE, "layer_metrics", name + ".py")), name
 
 
-def test_rehearsal_runs_the_new_readers(tmp_path):
-    """run.py --rehearse end to end with the five metrics listed: a copy of
-    the tiny data root whose BENCHMARK.json gains the entries of the real
-    one (tests/tiny itself is not this PR's to change).  On the CPU there is
-    no device trace, so every reader returns None and the line leaves the
-    metric out; a reader that raises fails the run."""
-    tiny = os.path.join(HERE, "tiny")
-    root = str(tmp_path / "tiny")
-    shutil.copytree(tiny, root)
-    bench = run.load_json(os.path.join(root, "BENCHMARK.json"))
+def test_the_tiny_root_lists_the_same_device_account():
+    """tests/tiny/BENCHMARK.json carries the five entries as the real file
+    does, so `run.py --rehearse` on the tiny root calls the five readers
+    (test_rehearse.py; on the CPU each returns None)."""
+    tiny = run.load_json(os.path.join(HERE, "tiny", "BENCHMARK.json"))
     real = run.load_json(os.path.join(run.ROOT, "BENCHMARK.json"))
-    for m in real["per_layer"]:
-        if m["name"] in NEW_READERS:
-            bench["per_layer"].append(
-                {k: v for k, v in m.items() if k != "workloads"})
-    with open(os.path.join(root, "BENCHMARK.json"), "w") as f:
-        json.dump(bench, f)
-    p = subprocess.run(
-        [sys.executable, os.path.join(run.HERE, "run.py"), "--root", root,
-         "--workload", "tiny-moe.chat-decode", "--seed", "3", "--seconds",
-         "6", "--trace", "1", "--rehearse"],
-        cwd=run.ROOT, env=dict(os.environ, JAX_PLATFORMS="cpu"), timeout=400,
-        capture_output=True, text=True)
-    assert p.returncode == 0, p.stdout[-2000:] + p.stderr[-2000:]
-    line = json.loads(p.stdout.strip().splitlines()[-1])
-    assert line["device"]["platform"] == "cpu" and line["failed"] == 0
-    assert "kv_pool_used_share" in line["metrics"]
-    assert not set(NEW_READERS) & set(line["metrics"])
+    for name in NEW_READERS:
+        entry = next(m for m in real["per_layer"] if m["name"] == name)
+        assert entry in tiny["per_layer"], name
 
 
 # --------------------------------------------------------------------------
@@ -261,3 +330,23 @@ def test_recorded_busy_time_agrees_with_trace_reduce(recorded):
     # every op found its launch (a launch may hold no op of its own)
     names = {trace_reduce.instr_name(k) for k in red["modules"]}
     assert set(acc["by_program"]) <= names
+
+
+@pytest.mark.parametrize("extra", [[], ["--config", os.path.join(
+    run.HERE, "configs", "yi-1.5-9b.json")]], ids=["plain", "no-scopes-key"])
+def test_recorded_capture_reduces_to_the_same_table_byte_for_byte(
+        tmp_path, extra):
+    """`recorded/tiny_scoped_v5e.table.txt` is what the parent of PR 26
+    printed for this capture: with no `scopes` the account is what it was."""
+    d = tmp_path / "plugins" / "profile" / "t"
+    d.mkdir(parents=True)
+    shutil.copy(TRACE, d / "tiny.xplane.pb")
+    p = subprocess.run(
+        [sys.executable, os.path.join(run.HERE, "scope_reduce.py"),
+         "--table"] + extra + [str(tmp_path)],
+        env=dict(os.environ, JAX_PLATFORMS="cpu"), capture_output=True,
+        timeout=120)
+    assert p.returncode == 0, p.stderr[-2000:]
+    with open(os.path.join(HERE, "recorded", "tiny_scoped_v5e.table.txt"),
+              "rb") as f:
+        assert p.stdout == f.read()
