@@ -1,4 +1,6 @@
-"""Model family: configs, functional Llama, checkpoint loading, tokenizers."""
+"""Model family: configs, the functional decoder (Llama / Mixtral style, and
+layer patterns of windowed and global attention), checkpoint loading,
+tokenizers."""
 
 from .config import CONFIGS, ModelConfig, config_from_hf_json, get_config
 from .llama import KVCache, forward, init_kv_cache, init_params

@@ -1,4 +1,7 @@
-"""Model configurations for the Llama family served by the TPU engine.
+"""Model configurations served by the TPU engine: Llama-style decoders
+(dense or Mixtral-routed MLP), and decoders whose layers follow a static
+pattern of windowed and global attention with rotary parameters per kind
+(Mellum2: three sliding-window layers, then one full-attention layer).
 
 The reference service routed model names to remote providers by string
 heuristics (src/llm/utils.py:11-29); here a model name resolves to a local
@@ -10,16 +13,44 @@ from __future__ import annotations
 import dataclasses
 import json
 import os
-from typing import Optional
+from typing import Optional, Tuple
 
 import jax.numpy as jnp
 
 from .vision import VisionConfig
 
+# The two kinds of attention layer a pattern is made of (HF `layer_types`).
+WINDOWED = "sliding_attention"
+GLOBAL = "full_attention"
+
+
+class UnsupportedConfigError(ValueError):
+    """A published config.json asks for something the program cannot honour
+    (a dense MLP among routed layers, un-normalised top-k weights, an unknown
+    kind of layer or rope).  Raised instead of reading the key silently."""
+
+
+@dataclasses.dataclass(frozen=True)
+class RopeParams:
+    """Rotary parameters of ONE kind of layer (HF `rope_parameters[kind]`).
+    rope_type "default": theta alone.  "yarn": HF `_compute_yarn_parameters`
+    (ops/rope.yarn_frequencies); `attention_factor` None means HF's default
+    0.1 * ln(factor) + 1."""
+
+    rope_type: str = "default"
+    rope_theta: float = 10000.0
+    factor: float = 1.0
+    original_max_position: int = 8192
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    attention_factor: Optional[float] = None
+
 
 @dataclasses.dataclass(frozen=True)
 class ModelConfig:
-    """Architecture hyperparameters (Llama-style decoder-only transformer)."""
+    """Architecture hyperparameters (decoder-only transformer: pre-norm,
+    rotary GQA attention, SwiGLU MLP dense or routed; optionally a static
+    per-layer pattern of windowed and global attention)."""
 
     name: str = "tiny"
     vocab_size: int = 256
@@ -65,10 +96,58 @@ class ModelConfig:
     # (image parts answer a typed 400 at the provider).
     vision: Optional[VisionConfig] = None
     image_token_id: Optional[int] = None
+    # Static layer pattern (HF `layer_types`): one kind per layer, WINDOWED
+    # or GLOBAL.  Empty = every layer global, the model-wide rope fields
+    # above: exactly what a config was before patterns existed.  A WINDOWED
+    # layer's query at position p attends keys p - sliding_window < k <= p
+    # (HF's sliding mask: `sliding_window` keys, the query's own included),
+    # on every attention path.  `rope_by_kind` holds (kind, RopeParams)
+    # pairs; a kind without an entry uses the model-wide fields.
+    layer_types: Tuple[str, ...] = ()
+    sliding_window: Optional[int] = None
+    rope_by_kind: Tuple[Tuple[str, RopeParams], ...] = ()
+
+    def __post_init__(self):
+        if not self.layer_types:
+            return
+        bad = set(self.layer_types) - {WINDOWED, GLOBAL}
+        if bad:
+            raise UnsupportedConfigError(
+                f"layer_types holds unknown kinds {sorted(bad)}; known: "
+                f"{WINDOWED!r}, {GLOBAL!r}")
+        if len(self.layer_types) != self.num_layers:
+            raise UnsupportedConfigError(
+                f"layer_types has {len(self.layer_types)} entries for "
+                f"{self.num_layers} layers")
+        if WINDOWED in self.layer_types and not (
+                self.sliding_window and self.sliding_window > 0):
+            raise UnsupportedConfigError(
+                "a sliding_attention layer needs a positive sliding_window")
 
     @property
     def is_moe(self) -> bool:
         return self.num_experts > 0
+
+    @property
+    def layer_period(self) -> Tuple[str, ...]:
+        """The kinds of one period of the pattern: the shortest prefix that,
+        repeated, gives `layer_types`.  (GLOBAL,) for a config without one:
+        the layer scan runs over whole periods (models/llama.forward)."""
+        kinds = self.layer_types
+        for p in range(1, len(kinds) + 1):
+            if len(kinds) % p == 0 and kinds == kinds[:p] * (len(kinds) // p):
+                return kinds[:p]
+        return (GLOBAL,)
+
+    @property
+    def is_windowed(self) -> bool:
+        return WINDOWED in self.layer_types
+
+    def window_of(self, kind: str) -> Optional[int]:
+        return self.sliding_window if kind == WINDOWED else None
+
+    def rope_of(self, kind: str) -> Optional[RopeParams]:
+        return dict(self.rope_by_kind).get(kind)
 
     @property
     def q_per_kv(self) -> int:
@@ -210,8 +289,62 @@ def get_config(name: str) -> ModelConfig:
     return CONFIGS[key]
 
 
+def _rope_params(kind: str, rp: dict) -> RopeParams:
+    rope_type = rp.get("rope_type", rp.get("type", "default"))
+    if rope_type not in ("default", "yarn"):
+        raise UnsupportedConfigError(
+            f"rope_parameters[{kind!r}]: rope_type {rope_type!r} is not "
+            "served per layer kind (known: 'default', 'yarn')")
+    return RopeParams(
+        rope_type=rope_type,
+        rope_theta=float(rp.get("rope_theta", 10000.0)),
+        factor=float(rp.get("factor", 1.0)),
+        original_max_position=int(
+            rp.get("original_max_position_embeddings", 8192)),
+        beta_fast=float(rp.get("beta_fast", 32.0)),
+        beta_slow=float(rp.get("beta_slow", 1.0)),
+        attention_factor=rp.get("attention_factor"),
+    )
+
+
+def _layer_pattern(hf: dict) -> dict:
+    """The pattern keys of a published config.json (`layer_types`,
+    `sliding_window`, `rope_parameters` by kind) as ModelConfig fields; {}
+    where the config declares no `layer_types`."""
+    kinds = hf.get("layer_types")
+    if not kinds:
+        return {}
+    # a depth-cut copy of a published config keeps the published list: the
+    # first num_hidden_layers entries are the layers that exist (a list
+    # that is too SHORT fails ModelConfig's own check)
+    kinds = tuple(kinds)[:hf["num_hidden_layers"]]
+    if WINDOWED in kinds and hf.get("use_sliding_window") is False:
+        raise UnsupportedConfigError(
+            "layer_types names sliding_attention layers but "
+            "use_sliding_window is false")
+    by_kind = hf.get("rope_parameters") or {}
+    nested = {k: v for k, v in by_kind.items() if isinstance(v, dict)}
+    # (an unknown kind is ModelConfig's own error, not a missing rope)
+    missing = (set(kinds) & {WINDOWED, GLOBAL}) - set(nested) if nested \
+        else set()
+    if missing:
+        raise UnsupportedConfigError(
+            f"rope_parameters has no entry for {sorted(missing)}")
+    return {
+        "layer_types": kinds,
+        "sliding_window": hf.get("sliding_window"),
+        "rope_by_kind": tuple(
+            (k, _rope_params(k, nested[k]))
+            for k in sorted(set(kinds) & set(nested))),
+    }
+
+
 def config_from_hf_json(path: str) -> ModelConfig:
-    """Build a ModelConfig from a HuggingFace config.json."""
+    """Build a ModelConfig from a HuggingFace config.json: Llama / Mixtral
+    keys, and the published keys of a patterned routed decoder (Mellum2:
+    `layer_types`, `sliding_window`, `rope_parameters`, `num_experts`,
+    `moe_intermediate_size`, `norm_topk_prob`, `mlp_layer_types`).  A key the
+    program cannot honour is an UnsupportedConfigError."""
     with open(path) as f:
         hf = json.load(f)
     rs = hf.get("rope_scaling") or {}
@@ -222,20 +355,41 @@ def config_from_hf_json(path: str) -> ModelConfig:
              "float16": "bfloat16"}.get(
         hf.get("dtype", hf.get("torch_dtype")), "bfloat16"
     )
+    # MoE: `num_local_experts` (HF Mixtral) or `num_experts` with the
+    # experts' own width in `moe_intermediate_size`; absent -> 0 = dense
+    num_experts = hf.get("num_local_experts", hf.get("num_experts", 0)) or 0
+    mlp_kinds = hf.get("mlp_layer_types")
+    if mlp_kinds and (set(mlp_kinds) != {"sparse"} or not num_experts):
+        raise UnsupportedConfigError(
+            "mlp_layer_types must be all 'sparse' (with experts) or absent: "
+            f"found {sorted(set(mlp_kinds))} with {num_experts} experts; a "
+            "mix of dense and routed layers is not served")
+    if num_experts and hf.get("norm_topk_prob") is False:
+        raise UnsupportedConfigError(
+            "norm_topk_prob false (top-k weights of a softmax over ALL "
+            "experts, not renormalised) is not served: routing here is a "
+            "softmax over exactly the top-k logits")
+    pattern = _layer_pattern(hf)
+    rope_theta = hf.get("rope_theta")
+    if rope_theta is None:
+        ropes = dict(pattern.get("rope_by_kind", ()))
+        rope_theta = (ropes[GLOBAL].rope_theta if GLOBAL in ropes
+                      else 10000.0)
     return ModelConfig(
         dtype=dtype,
-        # MoE (HF Mixtral config keys); absent -> 0 = dense
-        num_experts=hf.get("num_local_experts", 0) or 0,
+        num_experts=num_experts,
         num_experts_per_tok=hf.get("num_experts_per_tok", 2),
         name=os.path.basename(os.path.dirname(os.path.abspath(path))),
         vocab_size=hf["vocab_size"],
         hidden_size=hf["hidden_size"],
-        intermediate_size=hf["intermediate_size"],
+        intermediate_size=(hf["moe_intermediate_size"]
+                           if num_experts and "moe_intermediate_size" in hf
+                           else hf["intermediate_size"]),
         num_layers=hf["num_hidden_layers"],
         num_heads=hf["num_attention_heads"],
         num_kv_heads=hf.get("num_key_value_heads", hf["num_attention_heads"]),
         head_dim=hf.get("head_dim", hf["hidden_size"] // hf["num_attention_heads"]),
-        rope_theta=hf.get("rope_theta", 10000.0),
+        rope_theta=rope_theta,
         rms_norm_eps=hf.get("rms_norm_eps", 1e-5),
         max_context=hf.get("max_position_embeddings", 8192),
         tie_word_embeddings=hf.get("tie_word_embeddings", False),
@@ -243,4 +397,5 @@ def config_from_hf_json(path: str) -> ModelConfig:
         rope_low_freq_factor=rs.get("low_freq_factor", 1.0),
         rope_high_freq_factor=rs.get("high_freq_factor", 4.0),
         rope_original_max_position=rs.get("original_max_position_embeddings", 8192),
+        **pattern,
     )

@@ -1,4 +1,5 @@
-"""Llama-family decoder in pure functional JAX.
+"""Decoder-only transformer in pure functional JAX: the Llama family, Mixtral's
+routed MLP, and layer patterns of windowed and global attention (Mellum2).
 
 Design (TPU-first, not a port — the reference has no model code at all; its
 LLM compute lived behind a remote gateway, src/llm/portkey.py):
@@ -23,6 +24,14 @@ Two cache forms go through the same layer math: the *contiguous*
 (tests, `generate`), and the *paged* pool [L, SLOTS, Hkv*D] that serving uses
 (runtime/kv_cache.py) with a PagedView index plan.
 
+* **Layer patterns** — a config whose layers alternate kinds
+  (`ModelConfig.layer_types`: sliding-window and full attention, each with
+  its own rotary table) is scanned over whole PERIODS of the pattern: the
+  weights stay stacked [L, ...], the scan runs over the index of each
+  period's first layer, and its body is the p layers of one period
+  unrolled, each indexing its own weights, each kind its own code under its
+  own scope with its own static window.  A period of one is the plain scan.
+
 **The stacked cache is scan CARRY, never a scanned input.**  The layer scan
 runs over (layer params, layer index); the caches of all layers travel
 through it whole and a layer addresses its part by index.  The paged pool is
@@ -38,16 +47,22 @@ pages that the step reads once (PERF.md, PR 25).
 
 from __future__ import annotations
 
+from contextlib import nullcontext
 from functools import partial
 from typing import Any, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
-from ..models.config import ModelConfig
+from ..models.config import GLOBAL, ModelConfig
 from ..ops.attention import causal_attention
 from ..ops.norms import rms_norm
-from ..ops.rope import apply_rope, rope_cos_sin, rope_frequencies
+from ..ops.rope import (
+    apply_rope,
+    kind_frequencies,
+    rope_cos_sin,
+    rope_frequencies,
+)
 from .quant import QTensor, dequantize, quantize_array
 
 Params = Dict[str, Any]
@@ -246,12 +261,16 @@ def _attention_block(
     paged: Optional["PagedView"] = None,
     mesh=None,
     layer=None,
+    window: Optional[int] = None,
 ) -> Tuple[jnp.ndarray, Optional[jnp.ndarray], Optional[jnp.ndarray]]:
     """One attention sublayer. x: [B, S, H]. Returns (out, k_cache', v_cache').
 
     k_cache/v_cache are the STACKED caches of all layers the caller scans
     (None = uncached) and `layer` is this layer's index in them; they are
-    returned stacked, with only this layer's new rows written."""
+    returned stacked, with only this layer's new rows written.  `window`
+    (static) makes this a sliding-window layer; its attention proper runs
+    under the `attn_window` scope inside `attn_core`, so a device trace
+    splits attention time by kind of layer."""
     dt = x.dtype
     with jax.named_scope("attn_qkv"):
         q = jnp.einsum("bsh,hnd->bsnd", x, _w(lp, "wq", dt))
@@ -271,10 +290,12 @@ def _attention_block(
             _flat_pool(k_cache), paged.write_idx, k.reshape(b, s, hkv * d))
         v_cache = _kv_write(
             _flat_pool(v_cache), paged.write_idx, v.reshape(b, s, hkv * d))
-    with jax.named_scope("attn_core"):
+    with jax.named_scope("attn_core"), (
+            nullcontext() if window is None
+            else jax.named_scope("attn_window")):
         out, k_cache, v_cache = _attention_core(
             q, k, v, cfg, positions, k_cache, v_cache, kv_valid,
-            cache_positions, paged, mesh, layer,
+            cache_positions, paged, mesh, layer, window,
         )
     if paged is not None:
         k_cache = _stacked_pool(k_cache, num_layers)
@@ -284,10 +305,19 @@ def _attention_block(
     return out, k_cache, v_cache
 
 
+class WindowedPathError(NotImplementedError):
+    """An attention path that has no sliding-window form was reached by a
+    windowed layer.  The engine refuses such configurations when it is
+    built (runtime/engine.py); this is the backstop for direct callers of
+    `forward`, so that no path ever ignores a window."""
+
+
 def _attention_core(q, k, v, cfg, positions, k_cache, v_cache, kv_valid,
-                    cache_positions, paged, mesh, layer):
+                    cache_positions, paged, mesh, layer, window=None):
     """Scores, softmax and weighted sum for one layer, by cache form and
-    backend.  Paged: k_cache/v_cache are the flat [L*SLOTS, Hkv*D] pools,
+    backend.  `window` (static, None = global): the layer attends
+    q_pos - window < kv_pos <= q_pos; every path below honours it or raises
+    WindowedPathError.  Paged: k_cache/v_cache are the flat [L*SLOTS, Hkv*D] pools,
     the new rows already in them, and `paged` addresses this layer
     (_attention_block did both).  Contiguous: the stacked [L, B, C, Hkv, D]
     cache is written here at `layer`.  Returns (out [B, S, Hq, D],
@@ -305,6 +335,10 @@ def _attention_core(q, k, v, cfg, positions, k_cache, v_cache, kv_valid,
             if isinstance(k_cache, QTensor):
                 # int8 pool: the int8 kernel DMAs half the bytes and
                 # fuses the per-slot dequant into scores/probabilities
+                if window is not None:
+                    raise WindowedPathError(
+                        "kv_quantize int8 paged-decode kernel has no "
+                        "sliding-window form")
                 from ..ops.pallas import (
                     paged_decode_attention_int8,
                     paged_decode_attention_int8_sharded,
@@ -339,6 +373,20 @@ def _attention_core(q, k, v, cfg, positions, k_cache, v_cache, kv_valid,
                     paged.seq_lens,
                     page_size=paged.page_size,
                     interpret=interp,
+                    window=window,
+                )[:, None]
+            elif window is not None:
+                from ..ops.pallas import paged_decode_attention_window
+
+                out = paged_decode_attention_window(
+                    q[:, 0],
+                    k_cache,
+                    v_cache,
+                    paged.page_table,
+                    paged.seq_lens,
+                    window=window,
+                    page_size=paged.page_size,
+                    interpret=interp,
                 )[:, None]
             else:
                 from ..ops.pallas import paged_decode_attention
@@ -365,6 +413,10 @@ def _attention_core(q, k, v, cfg, positions, k_cache, v_cache, kv_valid,
             # distinguishes it from prefill chunks (which carry `start`)
             # and plain decode (s == 1).  Int8 pools fall through to the
             # dequantizing XLA gather below.
+            if window is not None:
+                raise WindowedPathError(
+                    "speculative verify (paged_verify_attention) has no "
+                    "sliding-window form")
             from ..ops.pallas import (
                 paged_verify_attention,
                 paged_verify_attention_sharded,
@@ -403,6 +455,7 @@ def _attention_core(q, k, v, cfg, positions, k_cache, v_cache, kv_valid,
                 paged.chunk_len,
                 page_size=paged.page_size,
                 interpret=jax.default_backend() != "tpu",
+                window=window,
             )[None]
         elif cfg.prefill_ring and s > 1:
             # Chunked prefill over the sp axis: the chunk's own q/k/v ride
@@ -415,6 +468,10 @@ def _attention_core(q, k, v, cfg, positions, k_cache, v_cache, kv_valid,
                 ulysses_prefill_sharded,
             )
 
+            if window is not None:
+                raise WindowedPathError(
+                    "prefill_ring (ring / ulysses prefill over sp) has no "
+                    "sliding-window form")
             if mesh is None:
                 raise RuntimeError(
                     "prefill_ring requires the mesh (forward(..., mesh=...))"
@@ -444,6 +501,7 @@ def _attention_core(q, k, v, cfg, positions, k_cache, v_cache, kv_valid,
                 q_positions=positions,
                 kv_positions=paged.kv_positions,
                 kv_valid=paged.kv_valid,
+                window=window,
             )
         else:
             k_win = _kv_read(k_cache, paged.read_idx, dt).reshape(b, -1, hkv, d)
@@ -455,10 +513,12 @@ def _attention_core(q, k, v, cfg, positions, k_cache, v_cache, kv_valid,
                 q_positions=positions,
                 kv_positions=paged.kv_positions,
                 kv_valid=paged.kv_valid,
+                window=window,
             )
     elif k_cache is None:
         out = causal_attention(
-            q, k, v, q_positions=positions, kv_positions=positions
+            q, k, v, q_positions=positions, kv_positions=positions,
+            window=window,
         )
     else:
         # Scatter new k/v rows into cache slots (slot == absolute position
@@ -480,6 +540,7 @@ def _attention_core(q, k, v, cfg, positions, k_cache, v_cache, kv_valid,
             q_positions=positions,
             kv_positions=kv_pos,
             kv_valid=kv_valid,
+            window=window,
         )
     return out, k_cache, v_cache
 
@@ -577,21 +638,29 @@ def forward(
                 override_on[..., None],
                 embed_override.astype(cfg.activation_dtype), x,
             )
-        inv_freq = rope_frequencies(cfg)
-        cos, sin = rope_cos_sin(positions, inv_freq)
+        # one rotary table per kind of layer, built once per forward pass;
+        # each layer takes its kind's (a config without a pattern has one)
+        period = cfg.layer_period
+        if cfg.layer_types:
+            rope = {kind: rope_cos_sin(positions, *kind_frequencies(cfg, kind))
+                    for kind in dict.fromkeys(period)}
+        else:
+            inv_freq = rope_frequencies(cfg)
+            rope = {GLOBAL: rope_cos_sin(positions, inv_freq)}
 
     # The stacked caches are CARRY (module docstring): the scan slices only
     # the layer's weights.  Every op of the layer body sits under a leaf
     # scope (residual adds included), so what a device trace shows under
     # `layers` alone is the scan's own slicing of its stacked inputs.
-    def layer_body(carry, scanned):
+    def layer_body(carry, scanned, kind=GLOBAL):
         h, kc, vc = carry
         lp, layer = scanned
+        cos, sin = rope[kind]
         with jax.named_scope("attn_norm"):
             attn_in = rms_norm(h, lp["ln_attn"], cfg.rms_norm_eps)
         attn_out, kc, vc = _attention_block(
             attn_in, lp, cfg, cos, sin, positions, kc, vc, kv_valid,
-            cache_positions, paged, mesh, layer,
+            cache_positions, paged, mesh, layer, cfg.window_of(kind),
         )
         with jax.named_scope("attn_out"):
             h = h + attn_out
@@ -606,14 +675,39 @@ def forward(
                 h = h + _mlp_block(mlp_in, lp)
         return (h, kc, vc), None
 
+    def period_body(carry, first):
+        """One whole period of the pattern: its layers unrolled, each kind
+        its own code with its own static window and rotary table.  Each
+        layer's weights are indexed out of the stacked [L, ...] arrays at
+        `first + j`, one dynamic slice a leaf exactly as the plain scan
+        takes them: scanning over a [L/p, p, ...] view instead made XLA
+        materialise the whole period's weights every iteration (3.2 GB of
+        copies a period at Mellum2's widths, rehearsed for the v5e)."""
+        for j, kind in enumerate(period):
+            lp = jax.tree.map(
+                lambda a: jax.lax.dynamic_index_in_dim(
+                    a, first + j, axis=0, keepdims=False),
+                params["layers"])
+            carry, _ = layer_body(carry, (lp, first + j), kind)
+        return carry, None
+
     with jax.named_scope("layers"):
         kc, vc = (None, None) if kv_cache is None else kv_cache
         num_layers = jax.tree.leaves(params["layers"])[0].shape[0]
-        (x, kc, vc), _ = jax.lax.scan(
-            layer_body,
-            (x, kc, vc),
-            (params["layers"], jnp.arange(num_layers)),
-        )
+        if len(period) == 1:
+            (x, kc, vc), _ = jax.lax.scan(
+                partial(layer_body, kind=period[0]),
+                (x, kc, vc),
+                (params["layers"], jnp.arange(num_layers)),
+            )
+        else:
+            p = len(period)
+            if num_layers % p:
+                raise ValueError(
+                    f"{num_layers} stacked layers are not whole periods of "
+                    f"the {p}-layer pattern")
+            (x, kc, vc), _ = jax.lax.scan(
+                period_body, (x, kc, vc), jnp.arange(0, num_layers, p))
         new_cache = None if kv_cache is None else KVCache(k=kc, v=vc)
 
     with jax.named_scope("head"):

@@ -40,6 +40,7 @@ def causal_attention(
     kv_positions: jnp.ndarray,
     kv_valid: Optional[jnp.ndarray] = None,
     scale: Optional[float] = None,
+    window: Optional[int] = None,
 ) -> jnp.ndarray:
     """Masked scaled-dot-product attention with GQA.
 
@@ -48,6 +49,8 @@ def causal_attention(
     kv_positions: [B, Skv] absolute position of each kv slot
     kv_valid: [B, Skv] bool — False for empty cache slots/padding
     Causality: a query at position p attends kv slots with position <= p.
+    window (static): a sliding-window layer also drops positions <= p - window,
+    so a query reads `window` keys, its own included (HF's sliding mask).
     Works for prefill (Sq == Skv), chunked prefill, and decode (Sq == 1)
     against a longer cache.
     """
@@ -70,6 +73,11 @@ def causal_attention(
     )
 
     mask = q_positions[:, None, None, :, None] >= kv_positions[:, None, None, None, :]
+    if window is not None:
+        mask = mask & (
+            kv_positions[:, None, None, None, :]
+            > q_positions[:, None, None, :, None] - window
+        )
     if kv_valid is not None:
         mask = mask & kv_valid[:, None, None, None, :]
     logits = jnp.where(mask, logits, NEG_INF)

@@ -22,6 +22,7 @@ from .paged_attention import (
     paged_decode_attention_int8,
     paged_decode_attention_int8_sharded,
     paged_decode_attention_sharded,
+    paged_decode_attention_window,
     paged_verify_attention,
     paged_verify_attention_sharded,
     pallas_mesh_ok,
@@ -32,6 +33,7 @@ __all__ = [
     "paged_decode_attention_int8",
     "paged_decode_attention_int8_sharded",
     "paged_decode_attention_sharded",
+    "paged_decode_attention_window",
     "paged_prefill_attention",
     "paged_verify_attention",
     "paged_verify_attention_sharded",

@@ -52,6 +52,7 @@ def _prefill_kernel(
     pages_per_chunk: int,
     q_block: int,
     scale: float,
+    window: int | None = None,
 ):
     qb = pl.program_id(0)
     ps, cp, hq = page_size, pages_per_chunk, num_q_heads
@@ -63,6 +64,14 @@ def _prefill_kernel(
     kv_hi = start + jnp.minimum((qb + 1) * q_block, chunk_len)
     n_pages = pl.cdiv(kv_hi, ps)
     n_chunks = pl.cdiv(n_pages, cp)
+    # A windowed layer (static `window`): the block's FIRST query, at
+    # start + qb * q_block, reads nothing below its own position - window
+    # + 1, and the later rows read nothing below that either, so KV chunks
+    # wholly below it are skipped; the bound per query row is in the mask.
+    first_chunk = 0
+    if window is not None:
+        first_chunk = jnp.maximum(
+            start + qb * q_block - window + 1, 0) // chunk
 
     def issue(c, slot):
         for j in range(cp):
@@ -99,7 +108,10 @@ def _prefill_kernel(
     m_ref[...] = jnp.full_like(m_ref, NEG_INF)
     l_ref[...] = jnp.zeros_like(l_ref)
     acc_ref[...] = jnp.zeros_like(acc_ref)
-    issue(0, 0)
+    if window is None:
+        issue(0, 0)
+    else:
+        issue(first_chunk, jax.lax.rem(first_chunk, 2))
 
     rows = q_block * hq
     # absolute q position of each folded row (row = q_idx * Hq + head)
@@ -118,6 +130,8 @@ def _prefill_kernel(
         col_ids = jax.lax.broadcasted_iota(jnp.int32, (1, chunk), 1)
         kv_pos = c * chunk + col_ids  # [1, chunk]
         mask = (q_pos >= kv_pos) & (kv_pos < kv_hi)  # [rows, chunk]
+        if window is not None:
+            mask = mask & (kv_pos > q_pos - window)
         # column-shaped validity built directly (Mosaic cannot transpose a
         # boolean vector)
         col_iota = jax.lax.broadcasted_iota(jnp.int32, (chunk, 1), 0)
@@ -151,7 +165,7 @@ def _prefill_kernel(
         m_ref[...] = m_new
         return carry
 
-    jax.lax.fori_loop(0, n_chunks, body, 0)
+    jax.lax.fori_loop(first_chunk, n_chunks, body, 0)
     denom = jnp.maximum(l_ref[...], 1e-30)
     out_ref[...] = (acc_ref[...] / denom).astype(out_ref.dtype)
 
@@ -159,7 +173,7 @@ def _prefill_kernel(
 @functools.partial(
     jax.jit,
     static_argnames=("page_size", "pages_per_chunk", "q_block", "scale",
-                     "interpret"),
+                     "interpret", "window"),
 )
 def paged_prefill_attention(
     q: jnp.ndarray,          # [S, Hq, D] roped queries of this chunk
@@ -174,8 +188,12 @@ def paged_prefill_attention(
     q_block: int = 64,
     scale: float | None = None,
     interpret: bool = False,
+    window: int | None = None,
 ) -> jnp.ndarray:
     """Flash attention of one prefill chunk against the paged window.
+    `window` (static): a sliding-window layer; each query row attends
+    q_pos - window < kv_pos <= q_pos and a q block skips the KV chunks
+    wholly below its first row's window.
 
     Returns [S, Hq, D] in q.dtype.  Rows past chunk_len are garbage (their
     KV went to the trash page) — same contract as the XLA path, which only
@@ -240,6 +258,7 @@ def paged_prefill_attention(
         pages_per_chunk=cp,
         q_block=qb,
         scale=scale,
+        window=window,
     )
     out_wide = pl.pallas_call(
         kernel,

@@ -67,6 +67,7 @@ def _decode_kernel(
     page_size: int,
     pages_per_chunk: int,
     scale: float,
+    window: int | None = None,
 ):
     b = pl.program_id(0)
     ps, cp = page_size, pages_per_chunk
@@ -75,6 +76,14 @@ def _decode_kernel(
     n_valid = seq_lens_ref[b] + 1
     n_pages = pl.cdiv(n_valid, ps)
     n_chunks = pl.cdiv(n_pages, cp)
+    # A windowed layer (static `window`) attends positions >= lo only: the
+    # chunk loop starts at the chunk that holds lo, so the call DMAs at most
+    # ceil((window + chunk) / chunk) chunks whatever the context
+    # (decode_chunk_range is the same arithmetic on plain ints).
+    first_chunk, lo = 0, 0
+    if window is not None:
+        lo = jnp.maximum(n_valid - window, 0)
+        first_chunk = lo // chunk
 
     def issue(c, slot):
         for j in range(cp):  # static unroll; per-page scattered DMA
@@ -111,7 +120,10 @@ def _decode_kernel(
     m_ref[...] = jnp.full_like(m_ref, NEG_INF)
     l_ref[...] = jnp.zeros_like(l_ref)
     acc_ref[...] = jnp.zeros_like(acc_ref)
-    issue(0, 0)
+    if window is None:
+        issue(0, 0)
+    else:
+        issue(first_chunk, jax.lax.rem(first_chunk, 2))
 
     def body(c, carry):
         slot = jax.lax.rem(c, 2)
@@ -126,6 +138,10 @@ def _decode_kernel(
         remaining = n_valid - c * chunk
         local = jax.lax.broadcasted_iota(jnp.int32, (1, chunk), dimension=1)
         slot_mask = local < remaining  # [1, chunk]
+        if window is not None:
+            # rows of the first chunk below the window were DMA'd (real
+            # data, so V needs no zeroing) and are masked out of the scores
+            slot_mask = slot_mask & (local >= lo - c * chunk)
         local_col = jax.lax.broadcasted_iota(jnp.int32, (chunk, 1), dimension=0)
         col_mask = local_col < remaining  # [chunk, 1]
 
@@ -167,9 +183,23 @@ def _decode_kernel(
         m_ref[...] = m_new
         return carry
 
-    jax.lax.fori_loop(0, n_chunks, body, 0)
+    jax.lax.fori_loop(first_chunk, n_chunks, body, 0)
     denom = jnp.maximum(l_ref[...], 1e-30)
     out_ref[0, :, :] = (acc_ref[...] / denom).astype(out_ref.dtype)
+
+
+def decode_chunk_range(seq_len: int, window: int | None, page_size: int,
+                       pages_per_chunk: int = 8) -> tuple:
+    """(first, end) of the KV chunks _decode_kernel DMAs for a lane with
+    `seq_len` cached tokens: the kernel's own arithmetic on plain ints, for
+    tests and for the benchmark's byte count.  A global call reads every
+    chunk up to the query; a windowed call at most
+    ceil((window + chunk) / chunk) of them."""
+    chunk = page_size * pages_per_chunk
+    n_valid = seq_len + 1
+    n_chunks = -(-(-(-n_valid // page_size)) // pages_per_chunk)
+    first = 0 if window is None else max(n_valid - window, 0) // chunk
+    return first, n_chunks
 
 
 @functools.partial(
@@ -194,6 +224,40 @@ def paged_decode_attention(
     point at the trash page) produce garbage rows that the engine discards —
     same contract as the XLA gather path.
     """
+    return _paged_decode(q, k_pool, v_pool, page_table, seq_lens,
+                         page_size, pages_per_chunk, scale, interpret, None)
+
+
+@functools.partial(
+    jax.jit,
+    static_argnames=("window", "page_size", "pages_per_chunk", "scale",
+                     "interpret"),
+)
+def paged_decode_attention_window(
+    q: jnp.ndarray,
+    k_pool: jnp.ndarray,
+    v_pool: jnp.ndarray,
+    page_table: jnp.ndarray,
+    seq_lens: jnp.ndarray,
+    *,
+    window: int,
+    page_size: int,
+    pages_per_chunk: int = 8,
+    scale: float | None = None,
+    interpret: bool = False,
+) -> jnp.ndarray:
+    """paged_decode_attention for a sliding-window layer: the query at
+    position seq_len attends seq_len - window < kv_pos <= seq_len, and the
+    kernel starts its chunk loop at the window's first chunk.  A jitted
+    function and a kernel name of its own (`paged_decode_attention_window`):
+    a device trace tells the windowed calls from the global ones, and both
+    still match `paged_decode`."""
+    return _paged_decode(q, k_pool, v_pool, page_table, seq_lens,
+                         page_size, pages_per_chunk, scale, interpret, window)
+
+
+def _paged_decode(q, k_pool, v_pool, page_table, seq_lens, page_size,
+                  pages_per_chunk, scale, interpret, window):
     B, Hq, D = q.shape
     HD = k_pool.shape[1]
     Hkv = HD // D
@@ -236,12 +300,14 @@ def paged_decode_attention(
         page_size=page_size,
         pages_per_chunk=cp,
         scale=scale,
+        window=window,
     )
     out_wide = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hq, HD), q.dtype),
         interpret=interpret,
+        name=None if window is None else "paged_decode_attention_window",
     )(page_table, seq_lens, qx, k_pages, v_pages)
     # each query row's result lives in its own kv head's lane block
     return out_wide.reshape(B, Hq, Hkv, D)[:, jnp.arange(Hq), kv_of_q]
@@ -527,6 +593,7 @@ def paged_decode_attention_sharded(
     *,
     page_size: int,
     interpret: bool = False,
+    window: int | None = None,
 ) -> jnp.ndarray:
     """The decode kernel on a tp(/tq) mesh: one kernel per device over its
     local head shard, zero collectives (heads are embarrassingly parallel
@@ -541,10 +608,14 @@ def paged_decode_attention_sharded(
     from jax.sharding import PartitionSpec as P
 
     q_ax = ("tp", "tq") if mesh.shape.get("tq", 1) > 1 else "tp"
+    kernel = functools.partial(
+        paged_decode_attention, page_size=page_size, interpret=interpret
+    ) if window is None else functools.partial(
+        paged_decode_attention_window, window=window, page_size=page_size,
+        interpret=interpret,
+    )
     fn = shard_map(
-        functools.partial(
-            paged_decode_attention, page_size=page_size, interpret=interpret
-        ),
+        kernel,
         mesh=mesh,
         in_specs=(P(None, q_ax, None), P(None, "tp"), P(None, "tp"),
                   P(None, None), P(None)),

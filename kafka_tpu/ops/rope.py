@@ -1,4 +1,6 @@
-"""Rotary position embeddings (RoPE), including Llama-3.x NTK-by-parts scaling.
+"""Rotary position embeddings (RoPE), including Llama-3.x NTK-by-parts scaling
+and YaRN, and one table per kind of layer for models whose windowed and
+global layers rotate differently.
 
 Frequencies are computed on the fly from integer position ids rather than
 from a precomputed [max_context, dim] table: paged decoding addresses
@@ -41,11 +43,62 @@ def rope_frequencies(cfg) -> jnp.ndarray:
     return out
 
 
+def _plain_frequencies(theta: float, dim: int) -> jnp.ndarray:
+    return 1.0 / (theta ** (jnp.arange(0, dim, 2, dtype=jnp.float32) / dim))
+
+
+def yarn_frequencies(rp, dim: int) -> Tuple[jnp.ndarray, float]:
+    """(inverse frequencies [dim//2], attention factor) of YaRN, as HF
+    `_compute_yarn_parameters` computes them: pairs that turn more than
+    `beta_fast` times over the original context keep their frequency
+    (extrapolation), pairs that turn fewer than `beta_slow` times are slowed
+    by `factor` (interpolation), and a linear ramp over the pair index
+    blends the two in between.  The ramp's ends are rounded outwards (HF's
+    `truncate`, its default) and clipped to the pair range.  The attention
+    factor multiplies cos and sin; left out of the config it is
+    0.1 * ln(factor) + 1."""
+    base, orig = float(rp.rope_theta), float(rp.original_max_position)
+
+    def correction_dim(rotations: float) -> float:
+        return (dim * math.log(orig / (rotations * 2.0 * math.pi))
+                / (2.0 * math.log(base)))
+
+    low = max(math.floor(correction_dim(rp.beta_fast)), 0)
+    high = min(math.ceil(correction_dim(rp.beta_slow)), dim - 1)
+    if low == high:
+        high += 0.001  # HF: no division by zero on a degenerate ramp
+    extrapolation = _plain_frequencies(base, dim)
+    interpolation = extrapolation / rp.factor
+    ramp = jnp.clip(
+        (jnp.arange(dim // 2, dtype=jnp.float32) - low) / (high - low), 0, 1)
+    inv_freq = interpolation * ramp + extrapolation * (1.0 - ramp)
+    factor = rp.attention_factor
+    if factor is None:
+        factor = 0.1 * math.log(rp.factor) + 1.0 if rp.factor > 1 else 1.0
+    return inv_freq, float(factor)
+
+
+def kind_frequencies(cfg, kind: str) -> Tuple[jnp.ndarray, float]:
+    """(inverse frequencies, attention factor) of one kind of layer: its
+    entry of `cfg.rope_by_kind`, else the model-wide table."""
+    rp = cfg.rope_of(kind)
+    if rp is None:
+        return rope_frequencies(cfg), 1.0
+    if rp.rope_type == "yarn":
+        return yarn_frequencies(rp, cfg.head_dim)
+    return _plain_frequencies(rp.rope_theta, cfg.head_dim), 1.0
+
+
 def rope_cos_sin(
-    positions: jnp.ndarray, inv_freq: jnp.ndarray
+    positions: jnp.ndarray, inv_freq: jnp.ndarray,
+    attention_factor: float = 1.0,
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """cos/sin tables for integer positions [...]: returns [..., head_dim//2]."""
+    """cos/sin tables for integer positions [...]: returns [..., head_dim//2].
+    `attention_factor` (YaRN) scales both; 1.0 leaves the program as it was."""
     angles = positions[..., None].astype(jnp.float32) * inv_freq
+    if attention_factor != 1.0:
+        return (jnp.cos(angles) * attention_factor,
+                jnp.sin(angles) * attention_factor)
     return jnp.cos(angles), jnp.sin(angles)
 
 

@@ -89,6 +89,15 @@ def shard_params_pp(params: Params, cfg: ModelConfig, mesh: Mesh) -> Params:
 
 
 def _check_pp_divisibility(cfg: ModelConfig, pp: int, tp: int) -> None:
+    if cfg.layer_types:
+        # the stage body below is ONE homogeneous layer with one rotary
+        # table: it would run a sliding-window layer as a global one
+        from ..models.llama import WindowedPathError
+
+        raise WindowedPathError(
+            "pp stage sharding (parallel/pipeline.py) scans one layer body "
+            "and has no form for a layer pattern "
+            f"({list(cfg.layer_period)})")
     if cfg.num_layers % pp:
         raise ValueError(
             f"num_layers {cfg.num_layers} not divisible by pp={pp}"

@@ -63,7 +63,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..models.config import ModelConfig
+from ..models.config import WINDOWED, ModelConfig
 from ..models.llama import KVCache, PagedView, forward
 from ..ops.sampling import (
     SamplingParams,
@@ -115,6 +115,19 @@ class AdmissionError(RuntimeError):
             f"waiting queue full ({depth}/{limit}); retry in "
             f"~{retry_after_s:.0f}s"
         )
+
+class WindowedAttentionUnsupported(ValueError):
+    """A model with sliding-window layers was given an engine option whose
+    attention path has no windowed form.  Raised when the engine is built,
+    naming the path (`.path`): no path may serve such a model and ignore
+    its window."""
+
+    def __init__(self, path: str, why: str):
+        self.path = path
+        super().__init__(
+            f"{path} does not honour a sliding window and this model has "
+            f"sliding-window layers: {why}")
+
 
 WAITING, PREFILLING, PARKED, ACTIVE, DRAINING, FINISHED = (
     "waiting", "prefilling", "parked", "active", "draining", "finished"
@@ -681,6 +694,27 @@ class InferenceEngine:
         # grouped-GQA kv replica factor (parallel/mesh.py factor_tp_for_kv):
         # q heads/MLP shard over tp*tq, kv params + pool over tp alone
         self._tq = mesh.shape.get("tq", 1) if mesh is not None else 1
+        if cfg.is_windowed:
+            # Every attention path honours the window (models/llama.py) or
+            # is refused here, by name.
+            refused = (
+                ("speculative verify (paged_verify_attention)",
+                 self.ecfg.speculative_k > 0,
+                 "the K+1-query verify kernel masks causally only; set "
+                 "speculative_k=0"),
+                ("prefill_ring", sp > 1,
+                 "ring / ulysses prefill over the sp axis masks causally "
+                 "only; use a mesh with sp=1"),
+                ("kv_quantize int8 kernel", bool(self.ecfg.kv_quantize),
+                 "the int8 paged-decode kernel walks every chunk from "
+                 "position 0; serve with a dense pool"),
+                ("pp > 1 (parallel/pipeline.py)", self._pp > 1,
+                 "the stage splitter scans one homogeneous layer body; "
+                 "use tp / dp"),
+            )
+            for path, hit, why in refused:
+                if hit:
+                    raise WindowedAttentionUnsupported(path, why)
         if self._tq > 1:
             if self._pp > 1:
                 raise ValueError(
@@ -1144,11 +1178,37 @@ class InferenceEngine:
             "attention_backend": self.cfg.attention_backend,
             "interpret": (self.cfg.attention_backend == "pallas"
                           and jax.default_backend() != "tpu"),
+            # the kinds of one period of the layer pattern and the window
+            # of its sliding layers (one global kind, no window: a model
+            # without a pattern)
+            "layer_pattern": list(self.cfg.layer_period),
+            "sliding_window": (self.cfg.sliding_window
+                               if self.cfg.is_windowed else None),
         }
         # DP replica index (set by runtime/dp_router.py): traced requests'
         # engine spans carry it so a timeline names the replica it ran on
         self.replica: Optional[int] = None
         self._rtt_est = self._measure_rtt()
+
+    def kv_window_dead_share(self) -> float:
+        """Of the KV rows live lanes hold (tokens x layers), the share no
+        later query can attend: a sliding-window layer's rows older than
+        the window (the next query, at position `length`, reads positions
+        > length - window).  The pool is uniform, every layer keeps every
+        page: this is the memory a window-aware allocator would give
+        back.  0.0 for a model without windowed layers."""
+        cfg = self.cfg
+        if not cfg.is_windowed:
+            return 0.0
+        windowed = cfg.layer_types.count(WINDOWED)
+        held = dead = 0
+        for req in list(self.slots):
+            seq = getattr(req, "seq", None)
+            if seq is None:
+                continue
+            held += seq.length * cfg.num_layers
+            dead += max(seq.length - cfg.sliding_window + 1, 0) * windowed
+        return dead / held if held else 0.0
 
     def _measure_rtt(self) -> float:
         """Time a device→host fetch to seed the adaptive emit cadence.

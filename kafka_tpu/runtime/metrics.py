@@ -1419,6 +1419,8 @@ class EngineMetrics:
                 "max_batch": engine.ecfg.max_batch,
                 "attention_backend": engine.cfg.attention_backend,
                 "rtt_est_ms": round(engine._rtt_est * 1e3, 3),
+                "kv_window_dead_share": round(
+                    engine.kv_window_dead_share(), 6),
             }
             if engine.prefix_cache is not None:
                 pc = engine.prefix_cache

@@ -1622,7 +1622,20 @@ async def debug_compiles(request: web.Request) -> web.Response:
                       "(KAFKA_TPU_COMPILE_RING=0)"},
             status=404,
         )
-    return web.json_response(obs.snapshot())
+    snap = obs.snapshot()
+    engine = getattr(_state(request).get("llm"), "engine", None)
+    first = getattr(engine, "engines", [engine])[0]
+    info = getattr(first, "device_info", None)
+    if info is not None:
+        # which model's programs these are: its layer pattern and window
+        snap["model"] = {
+            "name": first.cfg.name,
+            "layers": first.cfg.num_layers,
+            "layer_pattern": info.get("layer_pattern"),
+            "sliding_window": info.get("sliding_window"),
+            "attention_backend": info.get("attention_backend"),
+        }
+    return web.json_response(snap)
 
 
 async def playground(request: web.Request) -> web.Response:

@@ -152,6 +152,10 @@ DEVICE_SCOPES = (
     "attn_core",    # scores, softmax, weighted sum: the Pallas paged
                     # decode / verify / flash-prefill calls, or XLA
                     # causal_attention over the gathered window
+    "attn_window",  # inside attn_core: the attention proper of a
+                    # SLIDING-WINDOW layer (every path), so device time
+                    # splits by kind of layer; global layers stay directly
+                    # under attn_core
     "attn_gather",  # XLA paths only, inside attn_core: the page/slot
                     # gather that materialises the attention window
     "attn_out",     # output projection + residual add
