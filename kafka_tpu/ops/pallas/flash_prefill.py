@@ -14,7 +14,8 @@ Structure mirrors the decode kernel (paged_attention.py):
   MXU matmul per chunk, per-head lanes sliced out by the caller;
 * grid = (num_q_blocks,); per block, a dynamic fori_loop over the kv
   chunks the causal mask can reach (a q block early in the prompt skips
-  the chunks after it entirely), each chunk double-buffer DMA'd.
+  the chunks after it entirely, a q block of the bucket's padding skips
+  them all), each chunk double-buffer DMA'd.
 
 Causality: the engine writes the whole chunk's KV to the pool before
 attention, so kv slots carry absolute positions page-order; a query at
@@ -32,6 +33,36 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
+
+
+def prefill_block_chunks(qb, start, chunk_len, *, q_block: int,
+                         page_size: int, pages_per_chunk: int,
+                         window: int | None = None) -> tuple:
+    """(kv_hi, first_chunk, n_chunks) of q block `qb` of a chunk whose first
+    `chunk_len` rows hold tokens at absolute positions start..: the block
+    attends kv positions [0, kv_hi) and walks the KV chunks
+    [first_chunk, n_chunks).  _prefill_kernel calls this with its traced
+    scalars; on plain ints it gives concrete scalars, so a test can hold
+    the walk without a chip.
+
+    A block whose first row is at or past chunk_len holds no token and gets
+    kv_hi 0 and n_chunks 0: it walks nothing.  Any other block attends up to its last
+    REAL row (already bounded by the written total start + chunk_len).  A
+    windowed layer (static `window`): the block's FIRST query, at start +
+    qb * q_block, reads nothing below its own position - window + 1, and
+    the later rows read nothing below that either, so KV chunks wholly
+    below it are skipped; the bound per query row is in the kernel's mask.
+    """
+    chunk = page_size * pages_per_chunk
+    live = qb * q_block < chunk_len
+    kv_hi = jnp.where(
+        live, start + jnp.minimum((qb + 1) * q_block, chunk_len), 0)
+    n_chunks = pl.cdiv(pl.cdiv(kv_hi, page_size), pages_per_chunk)
+    first_chunk = 0
+    if window is not None:
+        first_chunk = jnp.maximum(
+            start + qb * q_block - window + 1, 0) // chunk
+    return kv_hi, first_chunk, n_chunks
 
 
 def _prefill_kernel(
@@ -59,19 +90,14 @@ def _prefill_kernel(
     chunk = cp * ps
     start = bounds_ref[0]
     chunk_len = bounds_ref[1]
-    # kv positions this q block may attend: all of [0, kv_hi) — the block's
-    # last real query position + 1, already bounded by the written total
-    kv_hi = start + jnp.minimum((qb + 1) * q_block, chunk_len)
+    # A block wholly past chunk_len gets kv_hi 0, n_chunks 0: every DMA below
+    # is guarded by n_pages and the chunk loop by n_chunks, so it starts no
+    # copy, signals no semaphore, runs no chunk, and its out block is the
+    # zero accumulator over the 1e-30 floor: exact zeros.
+    kv_hi, first_chunk, n_chunks = prefill_block_chunks(
+        qb, start, chunk_len, q_block=q_block, page_size=ps,
+        pages_per_chunk=cp, window=window)
     n_pages = pl.cdiv(kv_hi, ps)
-    n_chunks = pl.cdiv(n_pages, cp)
-    # A windowed layer (static `window`): the block's FIRST query, at
-    # start + qb * q_block, reads nothing below its own position - window
-    # + 1, and the later rows read nothing below that either, so KV chunks
-    # wholly below it are skipped; the bound per query row is in the mask.
-    first_chunk = 0
-    if window is not None:
-        first_chunk = jnp.maximum(
-            start + qb * q_block - window + 1, 0) // chunk
 
     def issue(c, slot):
         for j in range(cp):
@@ -197,7 +223,9 @@ def paged_prefill_attention(
 
     Returns [S, Hq, D] in q.dtype.  Rows past chunk_len are garbage (their
     KV went to the trash page) — same contract as the XLA path, which only
-    samples from the last real row.
+    samples from the last real row — except that the rows of a q block
+    wholly past chunk_len are zeros: such a block walks no KV
+    (prefill_block_chunks), so a bucket costs what its real blocks cost.
     """
     S, Hq, D = q.shape
     HD = k_pool.shape[1]

@@ -1427,6 +1427,10 @@ class _AggregateMetrics:
             "pages_total": sum(s["engine"]["pages_total"] for s in snaps),
             "pages_free": sum(s["engine"]["pages_free"] for s in snaps),
             "pages_in_use": sum(s["engine"]["pages_in_use"] for s in snaps),
+            "prefill_rows_dispatched": sum(
+                s["engine"]["prefill_rows_dispatched"] for s in snaps),
+            "prefill_rows_filled": sum(
+                s["engine"]["prefill_rows_filled"] for s in snaps),
         }
         if all("prefix_cache" in s for s in snaps):
             agg["prefix_cache"] = {

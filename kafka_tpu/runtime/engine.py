@@ -1188,6 +1188,13 @@ class InferenceEngine:
         # DP replica index (set by runtime/dp_router.py): traced requests'
         # engine spans carry it so a timeline names the replica it ran on
         self.replica: Optional[int] = None
+        # Monotonic: rows of every prefill program dispatched (lanes x
+        # bucket) and the rows of them that held a token (sum of
+        # chunk_len).  1 - filled / dispatched is the padding the buckets
+        # cost: the rows the flash-prefill kernel skips and an XLA prefill
+        # (static shapes) computes.
+        self.prefill_rows_dispatched = 0
+        self.prefill_rows_filled = 0
         self._rtt_est = self._measure_rtt()
 
     def kv_window_dead_share(self) -> float:
@@ -3448,6 +3455,8 @@ class InferenceEngine:
                 self._arg(top_ps), self._arg(seeds), self._arg(lane_active),
                 *vis,
             )
+        self.prefill_rows_dispatched += W * bucket
+        self.prefill_rows_filled += int(chunk_lens.sum())
         self._accrue_prefill_modeled(self._record_prefill_cost([
             (int(chunk_lens[i]), int(starts[i])) for i in range(len(reqs))
         ]))
@@ -3583,6 +3592,8 @@ class InferenceEngine:
                  else self._all_allowed),
                 *vis,
             )
+        self.prefill_rows_dispatched += bucket
+        self.prefill_rows_filled += chunk_len
         self._accrue_prefill_modeled(
             self._record_prefill_cost([(chunk_len, start)])
         )

@@ -1421,6 +1421,8 @@ class EngineMetrics:
                 "rtt_est_ms": round(engine._rtt_est * 1e3, 3),
                 "kv_window_dead_share": round(
                     engine.kv_window_dead_share(), 6),
+                "prefill_rows_dispatched": engine.prefill_rows_dispatched,
+                "prefill_rows_filled": engine.prefill_rows_filled,
             }
             if engine.prefix_cache is not None:
                 pc = engine.prefix_cache

@@ -174,6 +174,50 @@ class TestEnginePallasBackend:
             outs[backend] = eng.generate(prompt, max_new_tokens=6).output_ids
         assert outs["pallas"] == outs["xla"]
 
+    def test_padded_bucket_and_prefix_hit_suffix_match_xla(self):
+        """A 256-row bucket is 8 q blocks of 32 at 32 heads.  A 70-token
+        prompt fills 3 of them, a 19-token suffix after a prefix hit one:
+        the flash-prefill kernel walks no KV for the rest and writes zeros
+        there, which flow through the MLP into the trash page.  Forced
+        pallas (interpreted) against forced xla, token for token, through
+        the engine's own prefill program (`correct` cannot see it: the
+        benchmark's logit check calls `forward`, and its prefill(64) has
+        no padded block)."""
+        from kafka_tpu.models import ModelConfig, init_params
+        from kafka_tpu.runtime import EngineConfig, GenRequest, InferenceEngine
+
+        cfg = ModelConfig(name="pallas-padded", vocab_size=128,
+                          hidden_size=64, intermediate_size=128,
+                          num_layers=2, num_heads=32, num_kv_heads=4,
+                          head_dim=8, dtype="float32")
+        params = init_params(cfg, jax.random.PRNGKey(13))
+        rng = np.random.RandomState(4)
+        shared = list(rng.randint(1, 128, size=70))
+        suffix = list(rng.randint(1, 128, size=19))
+        outs = {}
+        for backend in ("xla", "pallas"):
+            eng = InferenceEngine(
+                cfg, params,
+                EngineConfig(max_batch=2, page_size=16, num_pages=48,
+                             max_pages_per_seq=24, prefill_buckets=(256,),
+                             attention_backend=backend),
+                kv_dtype=jnp.float32,
+            )
+            first = GenRequest(request_id="A", prompt_ids=shared,
+                               max_new_tokens=6, prefix_key="thread-A")
+            eng.submit(first)
+            eng.run_to_completion()
+            second = GenRequest(request_id="B", prompt_ids=shared + suffix,
+                                max_new_tokens=6, prefix_key="thread-B")
+            eng.submit(second)
+            eng.run_to_completion()
+            assert second.cached_tokens == 64  # whole pages of the hit
+            # two launches of the one bucket: 70 and 89 - 64 real rows
+            assert eng.prefill_rows_dispatched == 2 * 256
+            assert eng.prefill_rows_filled == 70 + 25
+            outs[backend] = (first.output_ids, second.output_ids)
+        assert outs["pallas"] == outs["xla"]
+
     @pytest.mark.skipif(len(jax.devices()) < 8,
                         reason="needs 8 virtual devices")
     @pytest.mark.parametrize("mesh_axes", [

@@ -78,6 +78,10 @@ class TestDPServing:
                 assert len(m["replicas"]) == 2
                 assert m["requests"]["finished"] >= 1
                 assert m["engine"]["pages_total"] == 2 * 320
+                rows = [r["engine"]["prefill_rows_dispatched"]
+                        for r in m["replicas"]]
+                assert m["engine"]["prefill_rows_dispatched"] == sum(rows) > 0
+                assert 0 < m["engine"]["prefill_rows_filled"] < sum(rows)
                 # pooled latency percentiles, not zeroed placeholders
                 assert m["ttft_ms"]["p50"] > 0
 
