@@ -235,7 +235,7 @@ def device_truth_phase(engine, cfg, args, rng) -> dict:
     """Device-truth telemetry (ISSUE 18): the rebuild compile-outage
     window — wall seconds from fresh-engine construction to its first
     generated token: WARM reuses the process jit caches the
-    /admin/resize rebuild path shares (the module _FN_CACHE), COLD clears
+    /admin/resize rebuild path shares (runtime/step_programs), COLD clears
     them first (what a crashed/replaced process pays, modulo the
     persistent XLA disk cache when one is mounted).  Both legs run under
     the compile observatory's "rebuild" phase, so the ring attributes
@@ -257,9 +257,9 @@ def device_truth_phase(engine, cfg, args, rng) -> dict:
         if cold:
             import jax as _jax
 
-            from kafka_tpu.runtime import engine as _engine_mod
+            from kafka_tpu.runtime import step_programs
 
-            _engine_mod._FN_CACHE.clear()
+            step_programs.clear()
             _jax.clear_caches()
         compile_log.set_phase("rebuild")
         t0 = time.monotonic()

@@ -407,7 +407,7 @@ def _attention_core(q, k, v, cfg, positions, k_cache, v_cache, kv_valid,
             and paged.page_table is not None
             and not isinstance(k_cache, QTensor)
         ):
-            # Speculative verify step (engine._get_verify_fn): S = K+1
+            # Speculative verify step (StepPrograms.verify): S = K+1
             # query tokens per lane against the paged pool, each causally
             # masked to its own position.  seq_lens present + s>1
             # distinguishes it from prefill chunks (which carry `start`)

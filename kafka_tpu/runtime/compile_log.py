@@ -13,7 +13,7 @@ compile axis:
   (``KAFKA_TPU_COMPILE_RING`` records, default 256; 0 = off with the
   engine byte-identical to an unobserved build — ``instrument`` returns
   the function unchanged and no listener ever registers).  One record =
-  one compilation: program label (the engine's ``_FN_CACHE`` tag),
+  one compilation: program label (``step_programs``' cache tag),
   wall-clock seconds, persistent-cache disposition (``hit`` / ``miss``
   / ``off`` — the directory :func:`enable_compile_cache` reports),
   and the engine phase that triggered it (``boot`` / ``warmup`` /
@@ -462,7 +462,7 @@ def enable_compile_cache() -> str:
 
 
 def instrument(label: str, fn: Callable[..., Any]) -> Callable[..., Any]:
-    """Wrap a freshly-jitted callable at its ``_FN_CACHE`` miss site.
+    """Wrap a freshly-jitted callable at its ``step_programs`` cache miss.
 
     Disabled (ring 0): returns ``fn`` unchanged — the dispatch path is
     byte-identical to an uninstrumented build.  Enabled: every call

@@ -4,11 +4,12 @@ This is the component that replaces the reference's remote LLM hop
 (src/llm/portkey.py — an HTTPS proxy to provider GPUs) with local TPU
 compute.  Architecture:
 
-* **Two jitted device programs.** `prefill` (per chunk-length bucket,
-  one sequence) writes prompt KV into the sequence's pages and samples the
-  first token; `decode` advances *every* active batch slot one token.  Both
-  donate the KV pool arrays, so the pool is updated in place — no per-step
-  copies of cache memory.
+* **Jitted device programs** (runtime/step_programs.py; this module
+  schedules and calls them).  `prefill` (per chunk-length bucket) writes
+  prompt KV into a sequence's pages and samples the first token; `decode`
+  advances *every* active batch slot one token (k tokens fused, or K+1
+  speculated).  All donate the KV pool arrays, so the pool is updated in
+  place — no per-step copies of cache memory.
 * **Static shapes everywhere.** Prompt chunks are bucketed; the decode batch
   is a fixed max_batch wide with inactive slots masked (they write to the
   trash page and their samples are discarded).  Nothing recompiles as
@@ -64,14 +65,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..models.config import WINDOWED, ModelConfig
-from ..models.llama import KVCache, PagedView, forward
-from ..ops.sampling import (
-    SamplingParams,
-    grammar_advance,
-    grammar_allowed_mask,
-    sample_tokens_per_slot,
-)
-from . import compile_log
 from .failpoints import failpoint
 from .flight_recorder import (
     FlightRecorder,
@@ -91,6 +84,7 @@ from .kv_cache import (
 from .metrics import EngineMetrics
 from .prefix_cache import PrefixCache
 from .speculative import LaneSpeculator
+from .step_programs import Fsm, Lanes, StepPrograms
 from .tracing import (
     add_event,
     annotate,
@@ -132,35 +126,6 @@ class WindowedAttentionUnsupported(ValueError):
 WAITING, PREFILLING, PARKED, ACTIVE, DRAINING, FINISHED = (
     "waiting", "prefilling", "parked", "active", "draining", "finished"
 )
-
-# Compiled step functions are cached per (model cfg, engine shape) so that
-# multiple engine instances (tests, restarts) reuse compilations.
-_FN_CACHE: Dict[Tuple, Callable] = {}
-
-
-def program_name(label: str) -> str:
-    """Function name a step program is jitted under, from its
-    /debug/compiles label: `multi_decode[16]_fsm` -> `fn_multi_decode_16_fsm`
-    (a device trace shows the module `jit_fn_multi_decode_16_fsm`).  The
-    benchmark's trace readers find decode programs by the `jit_body` /
-    `jit_fn` prefixes (benchmarks/layer_metrics/decode_step_dev_ms.py), and
-    tell the single decode step from the rest by `jit_body`: it is
-    `body_decode`, every other name begins `fn_`.  (Not the bare `body` it
-    was before the scopes: the persistent compile cache keys a program
-    without its metadata, so an executable compiled by a tree without
-    scopes would be reused under the same name, and its ops would carry
-    no scope in a trace.)"""
-    if label == "decode":
-        return "body_decode"
-    return "fn_" + label.replace("[", "_").replace("]", "")
-
-
-def _jit_step(label: str, fn: Callable) -> Callable:
-    """Jit one engine step program (k/v pools donated) under the name its
-    label gives and hand it to the compile observatory, so compile records
-    and trace module names cannot drift apart."""
-    fn.__name__ = fn.__qualname__ = program_name(label)
-    return compile_log.instrument(label, jax.jit(fn, donate_argnums=(1, 2)))
 
 # Agent-native scheduling (ISSUE 20, README "Agent-native scheduling").
 AGENT_DEMOTE_ENV = "KAFKA_TPU_AGENT_DEMOTE"
@@ -586,7 +551,6 @@ class _GrammarTables:
         self.trans = None         # [S_pad, C_pad] int32
         self.dist = None          # [S_pad] int32
         self.slack = None         # [] int32 (wrap-up window)
-        self.shape_key: Tuple[int, int, int] = (0, 0, 0)
 
     @property
     def active(self) -> bool:
@@ -657,7 +621,6 @@ class _GrammarTables:
         self.slack = dev(np.int32(
             max(g.wrap_slack for g in self.grammars)
         ))
-        self.shape_key = (S_pad, C_pad, G_pad)
 
     def args(self) -> Tuple:
         """The table argument tuple the fsm decode/verify programs take."""
@@ -952,15 +915,16 @@ class InferenceEngine:
         self._park_cooldown = 0
         self._requests: Dict[str, GenRequest] = {}
         self._step_count = 0
-        self._prefill_fns: Dict[int, Callable] = {}
         # device-resident all-zero override buffers (vision engines,
         # text-only chunks) — see _zero_override
         self._zero_ov_cache: Dict[Tuple, Tuple[Any, Any]] = {}
-        self._decode_fn = self._build_decode_fn()
-        # speculative verify program, built lazily on the FIRST proposal
-        # (speculative_k=0 engines never compile it — hard acceptance
-        # criterion for the default-off path)
-        self._verify_fn: Optional[Callable] = None
+        # The jitted device programs (runtime/step_programs.py).  All are
+        # built lazily; the speculative verify program on the FIRST
+        # proposal (speculative_k=0 engines never compile it — hard
+        # acceptance criterion for the default-off path).
+        self._programs = StepPrograms(
+            self.cfg, mesh, self.ecfg.page_size, B,
+            self.ecfg.max_pages_per_seq)
         self._counter = itertools.count()
         # device-resident decode control state (see module docstring)
         self._d_last = self._dev(np.zeros(B, np.int32))
@@ -1375,500 +1339,31 @@ class InferenceEngine:
         """
         return self._dev(x) if self._replicated is not None else x
 
-    # ------------------------------------------------------------------
-    # jitted device programs
-    # ------------------------------------------------------------------
+    def _lanes(self, active) -> Lanes:
+        """The device-resident lane arrays of a decode-side dispatch, with
+        `active` [B] choosing the lanes it advances."""
+        return Lanes(self._d_table, self._d_last, self._d_seq_lens, active,
+                     self._d_temps, self._d_top_ks, self._d_top_ps,
+                     self._d_seeds)
 
-    def _decode_step_body(self):
-        """One decode step as a pure function of device state; shared by the
-        single-step program and the fused multi-step scan."""
-        cfg, ecfg, mesh, pp = self.cfg, self.ecfg, self.mesh, self._pp
-        ps, C, B = ecfg.page_size, ecfg.max_window, ecfg.max_batch
+    def _fsm(self, on: bool) -> Optional[Fsm]:
+        """The on-device grammar automaton of a decode-side dispatch, lane
+        state and tables, or None for the plain program."""
+        if not on:
+            return None
+        return Fsm(self._d_fsm, self._d_fsm_g, self._d_budget,
+                   *self._grammars.args())
 
-        def body(params, k_pool, v_pool, page_table, last_tokens, seq_lens,
-                 active, temps, top_ks, top_ps, seeds, allowed_mask,
-                 forced_tok=None, forced_on=None, fsm=None):
-            # fsm = (state [B], gidx [B], budget [B], token_class [G, V],
-            # trans [S, C], dist [S], slack []): on-device grammar lanes —
-            # mask from the lane's FSM state, advance it by the sampled
-            # token, decrement the wrap-up budget.  None = the plain
-            # program (byte-identical dispatch paths when unused).
-            with jax.named_scope("step_ctl"):
-                positions = seq_lens[:, None]
-                write_page = page_table[jnp.arange(B), seq_lens // ps]
-                write_idx = (write_page * ps + seq_lens % ps)[:, None]
-                # inactive slots scribble on the trash page
-                write_idx = jnp.where(
-                    active[:, None], write_idx, (seq_lens % ps)[:, None])
-                read_idx = (
-                    page_table[:, :, None] * ps
-                    + jnp.arange(ps)[None, None, :]
-                ).reshape(B, C)
-                kv_positions = jnp.broadcast_to(
-                    jnp.arange(C)[None, :], (B, C))
-                kv_valid = (
-                    kv_positions <= seq_lens[:, None]) & active[:, None]
-                paged = PagedView(
-                    write_idx, read_idx, kv_positions, kv_valid,
-                    page_table=page_table, seq_lens=seq_lens, page_size=ps,
-                )
+    def _keep_fsm(self, fsm_out) -> None:
+        """The (state, budget) an fsm program's result ends with is the
+        lanes' new device truth; a plain program's ends with nothing."""
+        if fsm_out:
+            self._d_fsm, self._d_budget = fsm_out
 
-            if pp > 1:
-                from ..parallel.pipeline import pp_forward_paged
-
-                logits, k_new, v_new = pp_forward_paged(
-                    params, cfg, last_tokens[:, None], positions,
-                    k_pool, v_pool, paged, mesh,
-                )
-                cache = KVCache(k_new, v_new)
-            else:
-                logits, cache = forward(
-                    params, cfg, last_tokens[:, None], positions,
-                    kv_cache=KVCache(k_pool, v_pool), paged=paged,
-                    mesh=mesh,
-                )
-            if fsm is not None:
-                state, gidx, budget, tcs, trans, dists, slack = fsm
-                with jax.named_scope("fsm"):
-                    gmask = grammar_allowed_mask(
-                        state, gidx, budget, active, tcs, trans, dists,
-                        slack,
-                    )
-                    allowed_mask = (
-                        gmask if allowed_mask is None
-                        else allowed_mask & gmask
-                    )
-            with jax.named_scope("sample"):
-                logits = logits[:, 0]
-                keys = jax.vmap(
-                    lambda s, p: jax.random.fold_in(jax.random.key(s), p)
-                )(seeds, seq_lens)
-                toks = sample_tokens_per_slot(
-                    logits, SamplingParams(temps, top_ks, top_ps), keys,
-                    allowed_mask,
-                )
-                if forced_tok is not None:
-                    # grammar-forced lanes: the next token is host-known
-                    # (singleton mask) — overriding the sample here
-                    # replaces a [B, V] mask upload per chained dispatch
-                    # with a [B] int32
-                    toks = jnp.where(forced_on, forced_tok, toks)
-            with jax.named_scope("step_ctl"):
-                next_lens = seq_lens + active.astype(jnp.int32)
-            if fsm is not None:
-                with jax.named_scope("fsm"):
-                    new_state = grammar_advance(
-                        state, gidx, toks, active, tcs, trans)
-                    new_budget = budget - active.astype(jnp.int32)
-                return (cache.k, cache.v, toks, next_lens,
-                        new_state, new_budget)
-            return cache.k, cache.v, toks, next_lens
-
-        return body
-
-    def _build_decode_fn(self):
-        cache_key = ("decode", self.cfg, self.ecfg.page_size,
-                     self.ecfg.max_window, self.ecfg.max_batch, self.mesh)
-        if cache_key in _FN_CACHE:
-            return _FN_CACHE[cache_key]
-        jitted = _jit_step("decode", self._decode_step_body())
-        _FN_CACHE[cache_key] = jitted
-        return jitted
-
-    def _get_decode_fsm_fn(self):
-        """Grammar-lane decode program: the plain step body plus FSM mask
-        /advance/budget, keyed on the grammar table shapes (tables grow
-        geometrically, so this retraces O(log states) times)."""
-        cache_key = ("decode_fsm", self.cfg, self.ecfg.page_size,
-                     self.ecfg.max_window, self.ecfg.max_batch, self.mesh,
-                     self._grammars.shape_key)
-        if cache_key in _FN_CACHE:
-            return _FN_CACHE[cache_key]
-        body = self._decode_step_body()
-
-        def fn(params, k_pool, v_pool, page_table, last_tokens, seq_lens,
-               active, temps, top_ks, top_ps, seeds, allowed_mask,
-               fsm_state, fsm_g, budget, g_tc, g_trans, g_dist, g_slack):
-            return body(
-                params, k_pool, v_pool, page_table, last_tokens, seq_lens,
-                active, temps, top_ks, top_ps, seeds, allowed_mask,
-                fsm=(fsm_state, fsm_g, budget, g_tc, g_trans, g_dist,
-                     g_slack),
-            )
-
-        jitted = _jit_step("decode_fsm", fn)
-        _FN_CACHE[cache_key] = jitted
-        return jitted
-
-    def _get_batched_prefill_fn(self, bucket: int, width: int):
-        """Prefill chunks for `width` sequences in ONE dispatch.
-
-        Same index-plan semantics as the single-sequence program but with a
-        leading lane axis: per-lane page rows, starts, and chunk lengths
-        (inactive lanes write the trash page and sample garbage that the
-        scheduler discards).  Used when several admissions share a bucket —
-        one host dispatch instead of one per sequence, and the chunk
-        matmuls batch.  The B>1 shape keeps the XLA attention formulation
-        (the flash kernel's contract is single-sequence).
-        """
-        cfg, ecfg, mesh = self.cfg, self.ecfg, self.mesh
-        ps, C = ecfg.page_size, ecfg.max_window
-        cache_key = ("bprefill", cfg, bucket, width, ps, C,
-                     ecfg.max_pages_per_seq, self.mesh)
-        if cache_key in _FN_CACHE:
-            return _FN_CACHE[cache_key]
-
-        def fn(params, k_pool, v_pool, page_rows, chunks, starts,
-               chunk_lens, temps, top_ks, top_ps, seeds, lane_active,
-               *vis):
-            # vis = (ov [W, S, H], ov_on [W, S]) iff cfg.vision
-            S, W = bucket, width
-            with jax.named_scope("step_ctl"):
-                local = jnp.arange(S)[None, :]
-                pos = starts[:, None] + local  # [W, S]
-                in_chunk = (
-                    local < chunk_lens[:, None]) & lane_active[:, None]
-                page_idx = jnp.take_along_axis(page_rows, pos // ps, axis=1)
-                write_idx = jnp.where(
-                    in_chunk, page_idx * ps + pos % ps, local % ps
-                )
-                read_idx = (
-                    page_rows[:, :, None] * ps
-                    + jnp.arange(ps)[None, None, :]
-                ).reshape(W, C)
-                kv_positions = jnp.broadcast_to(
-                    jnp.arange(C)[None, :], (W, C))
-                kv_valid = (
-                    kv_positions < (starts + chunk_lens)[:, None]
-                ) & lane_active[:, None]
-                paged = PagedView(
-                    write_idx, read_idx, kv_positions, kv_valid,
-                    page_table=page_rows, page_size=ps,
-                )
-            logits, cache = forward(
-                params, cfg, chunks, pos,
-                kv_cache=KVCache(k_pool, v_pool), paged=paged, mesh=mesh,
-                embed_override=vis[0] if vis else None,
-                override_on=vis[1] if vis else None,
-            )
-            with jax.named_scope("sample"):
-                last = jnp.clip(chunk_lens - 1, 0, S - 1)
-                final_logits = jnp.take_along_axis(
-                    logits, last[:, None, None], axis=1
-                )[:, 0]  # [W, V]
-                keys = jax.vmap(
-                    lambda s, p: jax.random.fold_in(jax.random.key(s), p)
-                )(seeds, starts + chunk_lens - 1)
-                toks = sample_tokens_per_slot(
-                    final_logits, SamplingParams(temps, top_ks, top_ps),
-                    keys, None,
-                )
-            return cache.k, cache.v, toks
-
-        jitted = _jit_step(f"bprefill[{bucket}x{width}]", fn)
-        _FN_CACHE[cache_key] = jitted
-        return jitted
-
-    def _get_multi_decode_fn(self, steps: int, fsm: bool = False):
-        """k fused decode steps in one dispatch (lax.scan over the step
-        body, itself under the `step_ctl` scope so that a device trace
-        tells this scan's plumbing from the layer scan's).  Sampling stays
-        per-(seed, position) via the in-carry seq_lens, so outputs are
-        token-identical to k single dispatches.
-        Returns (k_pool', v_pool', toks [k, B], last [B], seq_lens [B]);
-        the fsm variant threads (fsm_state, budget) through the carry and
-        appends them to the return, so grammar lanes fuse too."""
-        cache_key = ("multi_decode", self.cfg, self.ecfg.page_size,
-                     self.ecfg.max_window, self.ecfg.max_batch, self.mesh,
-                     steps,
-                     self._grammars.shape_key if fsm else None)
-        if cache_key in _FN_CACHE:
-            return _FN_CACHE[cache_key]
-        body = self._decode_step_body()
-
-        if fsm:
-            def fn(params, k_pool, v_pool, page_table, last_tokens,
-                   seq_lens, active, temps, top_ks, top_ps, seeds,
-                   fsm_state, fsm_g, budget, g_tc, g_trans, g_dist,
-                   g_slack):
-                def one(carry, _):
-                    kp, vp, last, lens, st, bd = carry
-                    kp, vp, toks, lens, st, bd = body(
-                        params, kp, vp, page_table, last, lens,
-                        active, temps, top_ks, top_ps, seeds, None,
-                        fsm=(st, fsm_g, bd, g_tc, g_trans, g_dist,
-                             g_slack),
-                    )
-                    return (kp, vp, toks, lens, st, bd), toks
-
-                with jax.named_scope("step_ctl"):
-                    (kp, vp, last, lens, st, bd), toks_seq = jax.lax.scan(
-                        one,
-                        (k_pool, v_pool, last_tokens, seq_lens, fsm_state,
-                         budget),
-                        None, length=steps,
-                    )
-                return kp, vp, toks_seq, last, lens, st, bd
-        else:
-            def fn(params, k_pool, v_pool, page_table, last_tokens,
-                   seq_lens, active, temps, top_ks, top_ps, seeds):
-                def one(carry, _):
-                    kp, vp, last, lens = carry
-                    kp, vp, toks, lens = body(
-                        params, kp, vp, page_table, last, lens,
-                        active, temps, top_ks, top_ps, seeds, None,
-                    )
-                    return (kp, vp, toks, lens), toks
-
-                with jax.named_scope("step_ctl"):
-                    (kp, vp, last, lens), toks_seq = jax.lax.scan(
-                        one, (k_pool, v_pool, last_tokens, seq_lens), None,
-                        length=steps,
-                    )
-                return kp, vp, toks_seq, last, lens
-
-        jitted = _jit_step(
-            f"multi_decode[{steps}]{'_fsm' if fsm else ''}", fn)
-        _FN_CACHE[cache_key] = jitted
-        return jitted
-
-    def _get_verify_fn(self, fsm: bool = False):
-        """The speculative verify program: advance every lane 1..K+1 tokens
-        in ONE dispatch (EngineConfig.speculative_k).
-
-        The fsm variant (built only once a grammar lane exists) lets
-        CONSTRAINED lanes speculate: every position samples under the mask
-        of the FSM state reached through the candidate prefix (a host-side
-        sequential decode would compute exactly these states), the
-        accepted count selects the state the lane actually reached, and
-        the bonus token advances it once more — rejected-tail FSM rollback
-        mirrors the seq_lens clamp below.  Free lanes riding the fsm
-        variant see all-True mask rows, which leave the sampler
-        bit-identical to the plain program.
-
-        A [B, K+1]-query forward over the paged pool — the batched-prefill
-        attention formulation with per-query causal masking (on pallas
-        backends models/llama.py routes it to the K+1-query paged verify
-        kernel; elsewhere the page-granular XLA gather).  Non-proposing
-        lanes run with cand_len 0: position 0 is their ordinary decode
-        step and the K candidate positions write the trash page — same
-        compiled program whatever the batch mix, nothing recompiles.
-
-        Every position samples with the sequential decode path's OWN
-        per-(seed, position) key, and acceptance keeps candidates exactly
-        while `sample == candidate` — the emitted tokens ARE the
-        sequential path's samples, so greedy is bit-identical and sampled
-        output follows the target distribution at any temperature (the
-        exact-match special case of Leviathan rejection sampling for a
-        point-mass draft).  Rejected-tail KV is rolled back by clamping
-        the returned seq_lens to the accepted length: stale KV past it is
-        masked by kv_valid in later steps and overwritten when those
-        positions are next written.
-        """
-        if not fsm and self._verify_fn is not None:
-            return self._verify_fn
-        cfg, ecfg, mesh = self.cfg, self.ecfg, self.mesh
-        ps, C, B = ecfg.page_size, ecfg.max_window, ecfg.max_batch
-        K = ecfg.speculative_k
-        S = K + 1
-        cache_key = ("verify", cfg, ps, C, B, self.mesh, K,
-                     self._grammars.shape_key if fsm else None)
-        if cache_key in _FN_CACHE:
-            if not fsm:
-                self._verify_fn = _FN_CACHE[cache_key]
-            return _FN_CACHE[cache_key]
-
-        def fn(params, k_pool, v_pool, page_table, last_tokens, seq_lens,
-               active, temps, top_ks, top_ps, seeds, cands, cand_lens,
-               *gargs):
-            # gargs (fsm variant only) = (fsm_state [B], fsm_g [B],
-            # budget [B], token_class [G, V], trans [S, C], dist [S],
-            # slack [])
-            # inputs per lane: [last_token, c_1..c_K] at positions
-            # seq_len..seq_len+K; positions past cand_len are garbage
-            # lanes' padding and write the trash page
-            with jax.named_scope("step_ctl"):
-                toks_in = jnp.concatenate(
-                    [last_tokens[:, None], cands], axis=1)
-                local = jnp.arange(S)[None, :]
-                pos = seq_lens[:, None] + local  # [B, S]
-                in_run = (local <= cand_lens[:, None]) & active[:, None]
-                page_idx = jnp.take_along_axis(
-                    page_table,
-                    jnp.minimum(pos // ps, page_table.shape[1] - 1),
-                    axis=1,
-                )
-                write_idx = jnp.where(
-                    in_run, page_idx * ps + pos % ps, local % ps
-                )
-                read_idx = (
-                    page_table[:, :, None] * ps
-                    + jnp.arange(ps)[None, None, :]
-                ).reshape(B, C)
-                kv_positions = jnp.broadcast_to(
-                    jnp.arange(C)[None, :], (B, C))
-                kv_valid = (
-                    kv_positions <= (seq_lens + cand_lens)[:, None]
-                ) & active[:, None]
-                paged = PagedView(
-                    write_idx, read_idx, kv_positions, kv_valid,
-                    page_table=page_table, seq_lens=seq_lens, page_size=ps,
-                    chunk_len=cand_lens + 1,
-                )
-            logits, cache = forward(
-                params, cfg, toks_in, pos,
-                kv_cache=KVCache(k_pool, v_pool), paged=paged, mesh=mesh,
-            )  # [B, S, V]
-            V = logits.shape[-1]
-            rep = lambda x: jnp.repeat(x, S)
-            allowed_flat = None
-            states_arr = None
-            if gargs:
-                fsm_state, fsm_g, budget, g_tc, g_trans, g_dist, g_slack \
-                    = gargs
-                # FSM state BEFORE each sample position: state_j is the
-                # automaton after the first j candidate tokens (exactly
-                # the states sequential decode would thread); positions
-                # past cand_len walk garbage that acceptance never reads.
-                with jax.named_scope("fsm"):
-                    sts = [fsm_state]
-                    for j in range(K):
-                        sts.append(grammar_advance(
-                            sts[-1], fsm_g, cands[:, j], active, g_tc,
-                            g_trans,
-                        ))
-                    states_arr = jnp.stack(sts, axis=1)  # [B, S]
-                    masks = [
-                        grammar_allowed_mask(
-                            sts[j], fsm_g, budget - j, active, g_tc,
-                            g_trans, g_dist, g_slack,
-                        )
-                        for j in range(S)
-                    ]
-                    allowed_flat = jnp.stack(
-                        masks, axis=1).reshape(B * S, V)
-            with jax.named_scope("sample"):
-                # per-(seed, position) keys — IDENTICAL to the keys the
-                # sequential decode path folds for these positions
-                keys = jax.vmap(
-                    lambda s, prow: jax.vmap(
-                        lambda p: jax.random.fold_in(jax.random.key(s), p)
-                    )(prow)
-                )(seeds, pos)
-                samples = sample_tokens_per_slot(
-                    logits.reshape(B * S, V),
-                    SamplingParams(rep(temps), rep(top_ks), rep(top_ps)),
-                    keys.reshape(B * S),
-                    allowed_flat,
-                ).reshape(B, S)
-            with jax.named_scope("step_ctl"):
-                # longest exactly-matching candidate prefix, then the bonus
-                # token (the sample after the last accepted candidate)
-                good = (samples[:, :K] == cands) & (
-                    jnp.arange(K)[None, :] < cand_lens[:, None]
-                )
-                m = jnp.sum(
-                    jnp.cumprod(good.astype(jnp.int32), axis=1), axis=1)
-                adv = jnp.where(active, m + 1, 0)
-                # rejected-tail KV rolled back here
-                new_lens = seq_lens + adv
-                bonus = jnp.take_along_axis(
-                    samples, m[:, None], axis=1)[:, 0]
-                new_last = jnp.where(active, bonus, last_tokens)
-                out = jnp.concatenate(
-                    [samples, m[:, None]], axis=1)  # [B, S+1]
-            if gargs:
-                # rejected-tail FSM rollback: the state the lane keeps is
-                # the one reached through the ACCEPTED prefix (states_arr
-                # at m), advanced once by the bonus token — the exact
-                # mirror of the seq_lens clamp above
-                with jax.named_scope("fsm"):
-                    s_m = jnp.take_along_axis(
-                        states_arr, m[:, None], axis=1
-                    )[:, 0]
-                    new_fsm = grammar_advance(
-                        s_m, fsm_g, bonus, active, g_tc, g_trans
-                    )
-                    new_budget = budget - adv
-                return (cache.k, cache.v, out, new_last, new_lens,
-                        new_fsm, new_budget)
-            return cache.k, cache.v, out, new_last, new_lens
-
-        jitted = _jit_step("verify_fsm" if fsm else "verify", fn)
-        _FN_CACHE[cache_key] = jitted
-        if not fsm:
-            self._verify_fn = jitted
-        return jitted
-
-    def _get_prefill_fn(self, bucket: int):
-        if bucket in self._prefill_fns:
-            return self._prefill_fns[bucket]
-        cfg, ecfg, mesh, pp = self.cfg, self.ecfg, self.mesh, self._pp
-        ps, C, P = ecfg.page_size, ecfg.max_window, ecfg.max_pages_per_seq
-        cache_key = ("prefill", cfg, bucket, ps, C, P, self.mesh)
-        if cache_key in _FN_CACHE:
-            self._prefill_fns[bucket] = _FN_CACHE[cache_key]
-            return _FN_CACHE[cache_key]
-
-        def fn(params, k_pool, v_pool, page_row, chunk, start, chunk_len,
-               temp, top_k, top_p, seed, allowed_mask, *vis):
-            # [1, S] shapes throughout; `start` supports chunked prefill and
-            # prefix-cache hits (resume mid-prompt).  `vis` = (ov [S, H],
-            # ov_on [S]) embed-override arrays, present iff cfg.vision —
-            # per-engine the arity is constant, so one compile either way.
-            S = bucket
-            with jax.named_scope("step_ctl"):
-                local = jnp.arange(S)
-                positions = (start + local)[None, :]
-                in_chunk = local < chunk_len
-                write_page = page_row[(start + local) // ps]
-                write_idx = jnp.where(
-                    in_chunk, write_page * ps + (start + local) % ps,
-                    local % ps,
-                )[None, :]
-                read_idx = (
-                    page_row[:, None] * ps + jnp.arange(ps)[None, :]
-                ).reshape(1, C)
-                kv_positions = jnp.arange(C)[None, :]
-                kv_valid = kv_positions < (start + chunk_len)
-                paged = PagedView(
-                    write_idx, read_idx, kv_positions, kv_valid,
-                    page_table=page_row[None, :], page_size=ps,
-                    start=start, chunk_len=chunk_len,
-                )
-
-            if pp > 1:
-                from ..parallel.pipeline import pp_forward_paged
-
-                logits, k_new, v_new = pp_forward_paged(
-                    params, cfg, chunk[None, :], positions,
-                    k_pool, v_pool, paged, mesh,
-                )
-                cache = KVCache(k_new, v_new)
-            else:
-                logits, cache = forward(
-                    params, cfg, chunk[None, :], positions,
-                    kv_cache=KVCache(k_pool, v_pool), paged=paged, mesh=mesh,
-                    embed_override=vis[0][None] if vis else None,
-                    override_on=vis[1][None] if vis else None,
-                )
-            with jax.named_scope("sample"):
-                last = jnp.clip(chunk_len - 1, 0, S - 1)
-                final_logits = logits[0, last][None, :]  # [1, V]
-                sp = SamplingParams(
-                    temperature=temp[None], top_k=top_k[None],
-                    top_p=top_p[None],
-                )
-                key = jax.random.fold_in(
-                    jax.random.key(seed[0]), start + chunk_len - 1)
-                tok = sample_tokens_per_slot(
-                    final_logits, sp, key[None], allowed_mask)
-            return cache.k, cache.v, tok[0]
-
-        jitted = _jit_step(f"prefill[{bucket}]", fn)
-        _FN_CACHE[cache_key] = jitted
-        self._prefill_fns[bucket] = jitted
-        return jitted
+    @property
+    def _verify_fn(self) -> Optional[Callable]:
+        """The plain verify program if this engine ever asked for it."""
+        return self._programs.built.get(("verify", None))
 
     # ------------------------------------------------------------------
     # public API
@@ -1961,16 +1456,7 @@ class InferenceEngine:
         B, K = self.ecfg.max_batch, self.ecfg.speculative_k
         if self._d_table is None or self._ctl_dirty:
             self._refresh_ctl()
-        fn = self._get_verify_fn()
-        (self.k_pool, self.v_pool, out, self._d_last, self._d_seq_lens) = fn(
-            self.params, self.k_pool, self.v_pool,
-            self._d_table, self._d_last, self._d_seq_lens,
-            self._dev(np.zeros(B, bool)),
-            self._d_temps, self._d_top_ks, self._d_top_ps, self._d_seeds,
-            self._arg(np.zeros((B, K), np.int32)),
-            self._arg(np.zeros(B, np.int32)),
-        )
-        np.asarray(out)  # block until the compile + dispatch complete
+        self._warm_verify(self._dev(np.zeros(B, bool)), fsm=False)
 
     def warmup_grammar(self, grammar) -> None:
         """Compile the on-device grammar FSM programs outside serving.
@@ -1993,16 +1479,13 @@ class InferenceEngine:
         if self._d_table is None or self._ctl_dirty:
             self._refresh_ctl()
         inactive = self._dev(np.zeros(B, bool))
-        fn = self._get_decode_fsm_fn()
+        fsm = self._fsm(True)
         (self.k_pool, self.v_pool, toks, self._d_seq_lens,
-         self._d_fsm, self._d_budget) = fn(
-            self.params, self.k_pool, self.v_pool,
-            self._d_table, self._d_last, self._d_seq_lens, inactive,
-            self._d_temps, self._d_top_ks, self._d_top_ps, self._d_seeds,
-            None,
-            self._d_fsm, self._d_fsm_g, self._d_budget,
-            *self._grammars.args(),
+         *fsm_out) = self._programs.decode(fsm)(
+            self.params, self.k_pool, self.v_pool, self._lanes(inactive),
+            None, None, fsm,
         )
+        self._keep_fsm(fsm_out)
         np.asarray(toks)  # block until the compile + dispatch complete
         # the activation-time advance of _set_fsm_lane and its scatter of
         # a device scalar into the lane state (result discarded)
@@ -2012,20 +1495,22 @@ class InferenceEngine:
         )
         np.asarray(self._d_fsm.at[0].set(nxt))
         if self.ecfg.speculative_k > 0:
-            K = self.ecfg.speculative_k
-            fnv = self._get_verify_fn(fsm=True)
-            (self.k_pool, self.v_pool, out, self._d_last,
-             self._d_seq_lens, self._d_fsm, self._d_budget) = fnv(
-                self.params, self.k_pool, self.v_pool,
-                self._d_table, self._d_last, self._d_seq_lens, inactive,
-                self._d_temps, self._d_top_ks, self._d_top_ps,
-                self._d_seeds,
-                self._arg(np.zeros((B, K), np.int32)),
-                self._arg(np.zeros(B, np.int32)),
-                self._d_fsm, self._d_fsm_g, self._d_budget,
-                *self._grammars.args(),
-            )
-            np.asarray(out)
+            self._warm_verify(inactive, fsm=True)
+
+    def _warm_verify(self, inactive, fsm: bool) -> None:
+        """One all-inactive dispatch of the verify program (plain or fsm),
+        blocking until the compile + dispatch complete."""
+        B, K = self.ecfg.max_batch, self.ecfg.speculative_k
+        fsm_arg = self._fsm(fsm)
+        (self.k_pool, self.v_pool, out, self._d_last, self._d_seq_lens,
+         *fsm_out) = self._programs.verify(K, fsm_arg)(
+            self.params, self.k_pool, self.v_pool, self._lanes(inactive),
+            self._arg(np.zeros((B, K), np.int32)),
+            self._arg(np.zeros(B, np.int32)),
+            fsm_arg,
+        )
+        self._keep_fsm(fsm_out)
+        np.asarray(out)
 
     def warmup_kv_tier(self) -> None:
         """Compile the tier's ship (gather/scatter) programs outside
@@ -3446,7 +2931,7 @@ class InferenceEngine:
                     if co is not None:
                         ovs[i], ons[i] = co
                 vis = (self._arg(ovs), self._arg(ons))
-        fn = self._get_batched_prefill_fn(bucket, W)
+        fn = self._programs.batched_prefill(bucket, W)
         with self._dispatch_scope("prefill", reqs):
             self.k_pool, self.v_pool, toks = fn(
                 self.params, self.k_pool, self.v_pool,
@@ -3574,7 +3059,7 @@ class InferenceEngine:
                 vis = self._zero_override((bucket,))
             else:
                 vis = (self._arg(co[0]), self._arg(co[1]))
-        fn = self._get_prefill_fn(bucket)
+        fn = self._programs.prefill(bucket)
         with self._dispatch_scope("prefill", (req,)):
             self.k_pool, self.v_pool, tok = fn(
                 self.params, self.k_pool, self.v_pool,
@@ -4093,30 +3578,18 @@ class InferenceEngine:
             self._assert_private_tail(s, cl)
             s.spec_ahead = cl + 1
         d_act = self._dev(np.array([m is not None for m in members]))
-        fsm = any(m is not None and m.grammar is not None for m in members)
-        fn = self._get_verify_fn(fsm=fsm)
+        fsm = self._fsm(
+            any(m is not None and m.grammar is not None for m in members))
+        fn = self._programs.verify(K, fsm)
         with self._dispatch_scope("verify", members):
-            if fsm:
-                (self.k_pool, self.v_pool, out, new_last, new_lens,
-                 self._d_fsm, self._d_budget) = fn(
-                    self.params, self.k_pool, self.v_pool,
-                    self._d_table, self._d_last, self._d_seq_lens, d_act,
-                    self._d_temps, self._d_top_ks, self._d_top_ps,
-                    self._d_seeds,
-                    self._arg(cand_arr),
-                    self._arg(np.asarray(cand_lens, np.int32)),
-                    self._d_fsm, self._d_fsm_g, self._d_budget,
-                    *self._grammars.args(),
-                )
-            else:
-                (self.k_pool, self.v_pool, out, new_last, new_lens) = fn(
-                    self.params, self.k_pool, self.v_pool,
-                    self._d_table, self._d_last, self._d_seq_lens, d_act,
-                    self._d_temps, self._d_top_ks, self._d_top_ps,
-                    self._d_seeds,
-                    self._arg(cand_arr),
-                    self._arg(np.asarray(cand_lens, np.int32)),
-                )
+            (self.k_pool, self.v_pool, out, new_last, new_lens,
+             *fsm_out) = fn(
+                self.params, self.k_pool, self.v_pool, self._lanes(d_act),
+                self._arg(cand_arr),
+                self._arg(np.asarray(cand_lens, np.int32)),
+                fsm,
+            )
+        self._keep_fsm(fsm_out)
         # device-resident truth: the fn already clamped per-lane advances
         # to the accepted length and kept inactive lanes' values
         self._d_last = new_last
@@ -4234,29 +3707,18 @@ class InferenceEngine:
         the fsm scan variant so their masks apply inside the burst)."""
         if self._ctl_dirty:
             self._refresh_ctl()
-        fsm = any(
+        fsm = self._fsm(any(
             s is not None and s.state == ACTIVE and s.grammar is not None
             for s in self.slots
-        )
-        fn = self._get_multi_decode_fn(k, fsm=fsm)
+        ))
+        fn = self._programs.multi_decode(k, fsm)
         with self._dispatch_scope("decode", self.slots):
-            if fsm:
-                (self.k_pool, self.v_pool, toks_seq, last, lens,
-                 self._d_fsm, self._d_budget) = fn(
-                    self.params, self.k_pool, self.v_pool,
-                    self._d_table, self._d_last, self._d_seq_lens,
-                    self._d_active, self._d_temps, self._d_top_ks,
-                    self._d_top_ps, self._d_seeds,
-                    self._d_fsm, self._d_fsm_g, self._d_budget,
-                    *self._grammars.args(),
-                )
-            else:
-                (self.k_pool, self.v_pool, toks_seq, last, lens) = fn(
-                    self.params, self.k_pool, self.v_pool,
-                    self._d_table, self._d_last, self._d_seq_lens,
-                    self._d_active, self._d_temps, self._d_top_ks,
-                    self._d_top_ps, self._d_seeds,
-                )
+            (self.k_pool, self.v_pool, toks_seq, last, lens,
+             *fsm_out) = fn(
+                self.params, self.k_pool, self.v_pool,
+                self._lanes(self._d_active), fsm,
+            )
+        self._keep_fsm(fsm_out)
         self._d_last = last
         self._d_seq_lens = lens
         entry = self._book_dispatch(toks_seq, list(self.slots), steps=k)
@@ -4295,38 +3757,18 @@ class InferenceEngine:
         automaton state); the fn itself gates state/budget updates on the
         group's active mask, so out-of-group lanes keep theirs.
         """
+        fsm_arg = self._fsm(fsm)
+        fn = self._programs.decode(fsm_arg)
         with self._dispatch_scope("decode", members):
-            if fsm:
-                (self.k_pool, self.v_pool, toks, self._d_seq_lens,
-                 self._d_fsm, self._d_budget) = \
-                    self._get_decode_fsm_fn()(
-                        self.params, self.k_pool, self.v_pool,
-                        self._d_table, self._d_last, self._d_seq_lens,
-                        d_active, self._d_temps, self._d_top_ks,
-                        self._d_top_ps, self._d_seeds,
-                        None if allowed is None else self._arg(allowed),
-                        self._d_fsm, self._d_fsm_g, self._d_budget,
-                        *self._grammars.args(),
-                    )
-            elif forced is None:
-                self.k_pool, self.v_pool, toks, self._d_seq_lens = \
-                    self._decode_fn(
-                        self.params, self.k_pool, self.v_pool,
-                        self._d_table, self._d_last, self._d_seq_lens,
-                        d_active, self._d_temps, self._d_top_ks,
-                        self._d_top_ps, self._d_seeds,
-                        None if allowed is None else self._arg(allowed),
-                    )
-            else:
-                self.k_pool, self.v_pool, toks, self._d_seq_lens = \
-                    self._decode_fn(
-                        self.params, self.k_pool, self.v_pool,
-                        self._d_table, self._d_last, self._d_seq_lens,
-                        d_active, self._d_temps, self._d_top_ks,
-                        self._d_top_ps, self._d_seeds,
-                        None if allowed is None else self._arg(allowed),
-                        self._arg(forced[0]), self._arg(forced[1]),
-                    )
+            (self.k_pool, self.v_pool, toks, self._d_seq_lens,
+             *fsm_out) = fn(
+                self.params, self.k_pool, self.v_pool,
+                self._lanes(d_active),
+                None if allowed is None else self._arg(allowed),
+                None if forced is None else tuple(map(self._arg, forced)),
+                fsm_arg,
+            )
+        self._keep_fsm(fsm_out)
         self._d_last = toks if full else jnp.where(d_active, toks, self._d_last)
         return self._book_dispatch(toks, members, steps=1)
 

@@ -15,7 +15,7 @@ HBM residency, and nothing that perturbs the static-shape continuous-
 batching invariant (non-proposing lanes ride the same verify dispatch
 masked down to ordinary 1-token decode).
 
-Acceptance rule (engine._build_verify_fn): the verify step samples every
+Acceptance rule (StepPrograms.verify): the verify step samples every
 position with the SAME per-(seed, position) key the sequential decode path
 uses, and accepts candidates exactly while `sample == candidate`.  The
 emitted tokens are therefore *literally the sequential path's samples* —

@@ -106,7 +106,6 @@ def pytest_configure(config):
 # order is not load-bearing; intra-module order is unchanged.
 _HEAVY_TAIL = (
     "test_flash_prefill.py",
-    "test_fused_mlp.py",
     "test_kv_quant.py",
     "test_quant.py",
     "test_compaction.py",

@@ -18,7 +18,7 @@ from kafka_tpu import tracing
 from kafka_tpu.models import ModelConfig, init_params
 from kafka_tpu.runtime import EngineConfig, GenRequest, InferenceEngine
 from kafka_tpu.runtime import compile_log
-from kafka_tpu.runtime import engine as engine_mod
+from kafka_tpu.runtime import step_programs
 
 LEAF_SCOPES = set(tracing.DEVICE_SCOPES) - {"layers"}
 # `%dot.3 = f32[2,8]{1,0} dot(...)`, `ROOT %x = (f32[..]) custom-call(...)`
@@ -53,7 +53,7 @@ def _build(cfg, drive, **ecfg_kw):
 
     with pytest.MonkeyPatch.context() as mp:
         mp.setattr(compile_log, "instrument", spy)
-        mp.setattr(engine_mod, "_FN_CACHE", {})
+        mp.setattr(step_programs, "_PROGRAMS", {})
         params = init_params(cfg, jax.random.PRNGKey(7))
         defaults = dict(max_batch=4, page_size=8, num_pages=64,
                         max_pages_per_seq=8, prefill_buckets=(8, 16),
@@ -114,7 +114,7 @@ def test_compiled_ops_carry_a_leaf_scope(programs, family, phase):
     assert rec["args"] is not None, f"{label} was built but never ran"
     text = rec["jit"].lower(*rec["args"]).compile().as_text()
     assert text.startswith(
-        f"HloModule jit_{engine_mod.program_name(label)},")
+        f"HloModule jit_{step_programs.program_name(label)},")
     checked, bare, seen = 0, 0, set()
     for line in text.splitlines():
         m = INSTR.match(line)
@@ -157,7 +157,7 @@ def test_step_programs_have_distinct_module_names():
         eng.warmup_verify()
         eng.warmup_grammar(
             compile_tool_call_grammar(ByteTokenizer(), tools, vocab_size=262))
-        eng._get_multi_decode_fn(4, fsm=True)  # built, not run
+        eng._programs.multi_decode(4, eng._fsm(True))  # built, not run
 
     built = _build(_tiny("scope-names", vocab_size=262), drive,
                    speculative_k=2)
@@ -166,7 +166,7 @@ def test_step_programs_have_distinct_module_names():
     assert any(lb.startswith("bprefill[") for lb in built)
     modules = {}
     for label, rec in built.items():
-        name = engine_mod.program_name(label)
+        name = step_programs.program_name(label)
         assert name == "body_decode" or name.startswith("fn_"), (label, name)
         assert re.fullmatch(r"\w+", name), name
         assert rec["jit"].__name__ == name
@@ -181,15 +181,3 @@ def test_step_programs_have_distinct_module_names():
         == ["jit_body_decode"]
     assert modules["jit_body_decode"] == ["decode"]
 
-
-@pytest.mark.parametrize("label,name", [
-    ("decode", "body_decode"),
-    ("decode_fsm", "fn_decode_fsm"),
-    ("multi_decode[16]", "fn_multi_decode_16"),
-    ("multi_decode[16]_fsm", "fn_multi_decode_16_fsm"),
-    ("verify", "fn_verify"),
-    ("prefill[2048]", "fn_prefill_2048"),
-    ("bprefill[512x4]", "fn_bprefill_512x4"),
-])
-def test_program_name_from_label(label, name):
-    assert engine_mod.program_name(label) == name

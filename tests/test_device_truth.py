@@ -46,7 +46,7 @@ from kafka_tpu.runtime.planner import MemoryMonitor
 
 def tiny_cfg():
     # dims deliberately distinct from every other test module so this
-    # module's first dispatches MISS the process _FN_CACHE and really
+    # module's first dispatches MISS step_programs' process cache and really
     # compile (the observatory integration tests depend on that)
     return ModelConfig(
         name="device-truth-test", vocab_size=322, hidden_size=64,
@@ -266,7 +266,7 @@ class TestObservatoryEngine:
         obs = compile_log.get()
         assert obs.compiles_total > 0
         labels = {r["label"] for r in obs.records()}
-        # the instrumented _FN_CACHE sites attribute their labels
+        # the instrumented step_programs cache misses attribute their labels
         assert any(lbl != "?" for lbl in labels)
         assert all(r["phase"] == "warmup" for r in obs.records())
         assert obs.by_phase["warmup"] == obs.compiles_total

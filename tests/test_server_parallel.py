@@ -225,7 +225,9 @@ class TestWarmup:
             try:
                 engine = _engine(client)
                 # the decode program and a prefill bucket compiled at boot
-                assert engine._prefill_fns, "warmup compiled no prefill"
+                assert any(label.startswith("prefill[")
+                           for label, _ in engine._programs.built), \
+                    "warmup compiled no prefill"
                 # ...and the warmup generation does not pollute metrics
                 m = await (await client.get("/metrics")).json()
                 assert m["requests"]["submitted"] == 0
@@ -239,7 +241,7 @@ class TestWarmup:
         async def run():
             client = await _boot(_cfg(tmp_path, warmup=False))
             try:
-                assert not _engine(client)._prefill_fns
+                assert not _engine(client)._programs.built
             finally:
                 await client.close()
 
