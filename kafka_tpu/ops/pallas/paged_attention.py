@@ -11,13 +11,20 @@ kernel walks each sequence's page list directly:
   lengths ride in as **scalar-prefetch** arguments so the kernel can
   dereference physical page ids at runtime.
 * the kernel iterates only over the sequence's *valid* pages — a dynamic
-  `fori_loop` over chunks of `pages_per_chunk` pages, each chunk landed in
-  VMEM by manually issued per-page async DMAs, double-buffered so chunk
-  c+1's copies overlap chunk c's compute.  A sequence 300 tokens into an
-  8k window reads 300 tokens' worth of KV, not 8k.
-* online softmax (m, l, acc) in VMEM scratch across chunks.  GQA is an
-  unrolled per-kv-head loop over query groups — no repeat_kv
-  materialization.
+  `fori_loop` over softmax steps of STEP_ROWS keys, each step's pages landed
+  in VMEM by manually issued per-page async DMAs into a ring of RING
+  buffers, so the copies of the next two steps overlap this step's compute.
+  A sequence 300 tokens into an 8k window reads 300 tokens' worth of KV, not
+  8k.  A windowed call starts its walk at the chunk (`pages_per_chunk`
+  pages) that holds the window's first key.
+* online softmax (m, l, acc) in VMEM scratch across steps.  GQA is one
+  merged-lane matmul over all heads — no repeat_kv materialization.
+* only a walk's boundary steps (the last; windowed, also the first) can hold
+  a row that is not attended: they alone build masks, zero V and count the
+  pages they copy; every other step runs straight through.
+
+(`_verify_kernel` and `_decode_kernel_int8` below keep the older walk: one
+chunk a step, two buffers, masks and guards on every chunk.)
 
 Layout contract: the pool stores each slot's row as Hkv*D merged lanes
 ([TOTAL_SLOTS, Hkv*D]) — Mosaic requires DMA slices to be lane-tile (128)
@@ -30,7 +37,12 @@ D lanes per head in one full-width MXU matmul, and the PV product yields
 [Hq, Hkv*D] from which the caller slices each row's own kv-head block.
 
 Numerics ground truth: ops.attention.causal_attention (tests compare both
-paths on random page layouts).  f32 accumulation throughout.
+paths on random page layouts), and the kernel keeps its precision: the MXU
+operands are the pool's dtype (K and V as they lie in VMEM, q and the
+probabilities cast to match, as `einsum(qg, k)` and
+`einsum(probs.astype(v.dtype), v)` there), while scores, the softmax state
+(running max and denominator) and the accumulator are f32.  An f32 pool
+therefore multiplies in f32, a bf16 pool in bf16.
 """
 
 from __future__ import annotations
@@ -46,6 +58,81 @@ from jax.experimental.pallas import tpu as pltpu
 NEG_INF = -1e30
 
 
+# A softmax step attends STEP_ROWS keys at once and RING steps' buffers are in
+# flight.  Measured alone at 32/4 x 128, page 16, contexts ~8.3k (PERF.md
+# section 6, PR 30): a step costs ~0.33 us whatever it holds (matmul, max,
+# exp, sum, matmul, one dependent chain) plus 0.08 us per 128 keys, and the
+# scalar core's DMA starts and waits (0.3 us per 8 pages) do not overlap it:
+# one 128-key chunk a step with two buffers ran 0.78 us a chunk against the
+# walk's own 0.36.  512 keys a step over three buffers runs 0.43.
+STEP_ROWS = 512
+RING = 3
+
+
+def _attend(q_ref, kbuf, vbuf, m_ref, l_ref, acc_ref, slot, scale,
+            remaining=None, below=None):
+    """One online-softmax step of _decode_kernel over ring slot `slot`.
+
+    MXU operands are the pool's own rows, with q and the probabilities cast
+    to match: what ops.attention.causal_attention does when it multiplies
+    `qg, k` and `probs.astype(v.dtype), v`.  Scores, softmax state (m, l)
+    and the accumulator are f32.  `remaining`: rows from there on are past
+    the context and were never DMA'd; `below` (windowed): rows under it are
+    under the window.  Both None: the whole step is attended, and no iota,
+    mask or select is built."""
+    rows = kbuf.shape[1]
+    dt = jnp.promote_types(q_ref.dtype, kbuf.dtype)
+    kc = kbuf[slot].astype(dt)  # [rows, HD]
+    vc = vbuf[slot]
+    slot_mask = None
+    if remaining is not None:
+        # local slot index within the step vs remaining valid slots
+        local = jax.lax.broadcasted_iota(jnp.int32, (1, rows), dimension=1)
+        slot_mask = local < remaining  # [1, rows]
+        if below is not None:
+            # rows of the first step below the window were DMA'd (real data,
+            # so V needs no zeroing) and are masked out of the scores
+            slot_mask = slot_mask & (local >= below)
+        local_col = jax.lax.broadcasted_iota(jnp.int32, (rows, 1), dimension=0)
+        # Zero V's never-DMA'd rows before the PV matmul — a NaN there would
+        # poison the accumulator even under zero probability weight
+        # (0 * NaN = NaN).  K needs no masking: its scores are overwritten
+        # by the NEG_INF mask.  (Selected in f32, as the kernel always has.)
+        vc = jnp.where(local_col < remaining, vc.astype(jnp.float32), 0.0)
+    vc = vc.astype(dt)
+    # Merged-lane compute: q arrives pre-expanded block-diagonally
+    # ([Hq, Hkv*D], zeros outside each query head's own kv-head lane block),
+    # so QK^T over the full merged row contracts exactly each head's D lanes
+    # — one MXU matmul for all heads, no in-kernel reshape (Mosaic cannot
+    # unfold merged lanes).
+    s = (
+        jax.lax.dot_general(
+            q_ref[0].astype(dt), kc,
+            dimension_numbers=(((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+        * scale
+    )  # [Hq, rows]
+    if slot_mask is not None:
+        s = jnp.where(slot_mask, s, NEG_INF)
+
+    m_prev = m_ref[...]  # [Hq, 1]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+    alpha = jnp.exp(m_prev - m_new)
+    pexp = jnp.exp(s - m_new)
+    if slot_mask is not None:
+        pexp = jnp.where(slot_mask, pexp, 0.0)
+    l_ref[...] = l_ref[...] * alpha + jnp.sum(pexp, axis=-1, keepdims=True)
+    # [Hq, HD]: each row holds every kv head's weighted V; the caller slices
+    # out the row's own kv-head lane block.
+    acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
+        pexp.astype(dt), vc,
+        dimension_numbers=(((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    )
+    m_ref[...] = m_new
+
+
 def _decode_kernel(
     # scalar prefetch
     page_table_ref,  # [B, P] i32
@@ -56,10 +143,10 @@ def _decode_kernel(
     v_pages_hbm,  # [num_pages, ps, Hkv*D] in HBM/ANY
     out_ref,      # [1, Hq, Hkv*D] VMEM block — caller slices per-head lanes
     # scratch
-    kbuf,     # [2, CP*ps, Hkv*D] pool dtype
-    vbuf,     # [2, CP*ps, Hkv*D]
-    ksem,     # DMA sems [2, CP]
-    vsem,     # DMA sems [2, CP]
+    kbuf,     # [RING, SP*ps, Hkv*D] pool dtype
+    vbuf,     # [RING, SP*ps, Hkv*D]
+    ksem,     # DMA sems [RING, SP]: a buffer's page copies all signal its
+    vsem,     # first; the rest only space them (see _paged_decode)
     m_ref,    # [Hq, 1] f32 running max
     l_ref,    # [Hq, 1] f32 running denominator
     acc_ref,  # [Hq, Hkv*D] f32 running numerator
@@ -70,120 +157,98 @@ def _decode_kernel(
     window: int | None = None,
 ):
     b = pl.program_id(0)
-    ps, cp = page_size, pages_per_chunk
-    chunk = cp * ps
+    ps = page_size
+    ring, sp = kbuf.shape[0], kbuf.shape[1] // ps  # buffers, pages a step
     # query position is seq_len; it attends positions <= seq_len
     n_valid = seq_lens_ref[b] + 1
     n_pages = pl.cdiv(n_valid, ps)
-    n_chunks = pl.cdiv(n_pages, cp)
     # A windowed layer (static `window`) attends positions >= lo only: the
-    # chunk loop starts at the chunk that holds lo, so the call DMAs at most
-    # ceil((window + chunk) / chunk) chunks whatever the context
-    # (decode_chunk_range is the same arithmetic on plain ints).
-    first_chunk, lo = 0, 0
+    # walk starts at the chunk (pages_per_chunk pages) that holds lo, so the
+    # call DMAs at most ceil((window + chunk) / chunk) chunks whatever the
+    # context (decode_chunk_range is the same arithmetic on plain ints).
+    page0, lo = 0, 0
     if window is not None:
         lo = jnp.maximum(n_valid - window, 0)
-        first_chunk = lo // chunk
+        page0 = lo // (pages_per_chunk * ps) * pages_per_chunk
+    n_steps = pl.cdiv(n_pages - page0, sp)
+    last = n_steps - 1
+    pools = ((k_pages_hbm, kbuf, ksem), (v_pages_hbm, vbuf, vsem))
 
-    def issue(c, slot):
-        for j in range(cp):  # static unroll; per-page scattered DMA
-            @pl.when(c * cp + j < n_pages)
-            def _():
-                page = page_table_ref[b, c * cp + j]
+    def dma(k, op, guarded):
+        """Start or wait step k's page copies, one scattered page each, into
+        ring slot k % RING.  `guarded`: the step may end short of sp pages."""
+        slot = jax.lax.rem(k, ring)
+        base = page0 + k * sp
+        if op == "wait" and not guarded:
+            # A DMA semaphore counts bytes: one wait for the whole buffer
+            # stands for its sp page copies.
+            for _, buf, sem in pools:
                 pltpu.make_async_copy(
-                    k_pages_hbm.at[page],
-                    kbuf.at[slot, pl.ds(j * ps, ps)],
-                    ksem.at[slot, j],
-                ).start()
-                pltpu.make_async_copy(
-                    v_pages_hbm.at[page],
-                    vbuf.at[slot, pl.ds(j * ps, ps)],
-                    vsem.at[slot, j],
-                ).start()
+                    buf.at[slot], buf.at[slot], sem.at[slot, 0]).wait()
+            return
 
-    def wait(c, slot):
-        for j in range(cp):
-            @pl.when(c * cp + j < n_pages)
-            def _():
-                page = page_table_ref[b, c * cp + j]
-                pltpu.make_async_copy(
-                    k_pages_hbm.at[page],
-                    kbuf.at[slot, pl.ds(j * ps, ps)],
-                    ksem.at[slot, j],
-                ).wait()
-                pltpu.make_async_copy(
-                    v_pages_hbm.at[page],
-                    vbuf.at[slot, pl.ds(j * ps, ps)],
-                    vsem.at[slot, j],
-                ).wait()
+        def copy(j, carry=None):  # one scattered page, K and V
+            # a wait needs the copy's size only, not where it came from
+            page = page_table_ref[b, base + j] if op == "start" else 0
+            row = j * ps if isinstance(j, int) else pl.multiple_of(j * ps, ps)
+            for hbm, buf, sem in pools:
+                cp = pltpu.make_async_copy(
+                    hbm.at[page], buf.at[slot, pl.ds(row, ps)],
+                    sem.at[slot, 0])
+                getattr(cp, op)()
+            return carry
+
+        if guarded:
+            # A loop over the pages the step has, not a guard a page: five
+            # unrolled call sites of sp pages made `jit.lower` of one kernel
+            # take 0.6 s (0.13 before PR 30) and Mellum2's warm boot 31%
+            # longer; boundary steps are one or two a lane.
+            jax.lax.fori_loop(0, jnp.minimum(sp, n_pages - base), copy, 0)
+        else:
+            for j in range(sp):  # unrolled: 0.43 us a chunk, rolled 0.48
+                copy(j)
 
     m_ref[...] = jnp.full_like(m_ref, NEG_INF)
     l_ref[...] = jnp.zeros_like(l_ref)
     acc_ref[...] = jnp.zeros_like(acc_ref)
-    if window is None:
-        issue(0, 0)
-    else:
-        issue(first_chunk, jax.lax.rem(first_chunk, 2))
+    for d in range(ring - 1):
+        @pl.when(d < n_steps)
+        def _(d=d):
+            dma(d, "start", True)
 
-    def body(c, carry):
-        slot = jax.lax.rem(c, 2)
+    def body(k, carry):
+        ahead = k + ring - 1  # only the walk's last step can end short
 
-        @pl.when(c + 1 < n_chunks)
+        @pl.when(ahead < last)
         def _():
-            issue(c + 1, jax.lax.rem(c + 1, 2))
+            dma(ahead, "start", False)
 
-        wait(c, slot)
+        @pl.when(ahead == last)
+        def _():
+            dma(ahead, "start", True)
 
-        # mask: local slot index within the chunk vs remaining valid slots
-        remaining = n_valid - c * chunk
-        local = jax.lax.broadcasted_iota(jnp.int32, (1, chunk), dimension=1)
-        slot_mask = local < remaining  # [1, chunk]
+        # A step before the last (and, windowed, at or above lo) holds sp
+        # whole pages of attended rows: no guard, no iota, no mask, no select.
+        row0 = (page0 + k * sp) * ps
+        whole = k < last
         if window is not None:
-            # rows of the first chunk below the window were DMA'd (real
-            # data, so V needs no zeroing) and are masked out of the scores
-            slot_mask = slot_mask & (local >= lo - c * chunk)
-        local_col = jax.lax.broadcasted_iota(jnp.int32, (chunk, 1), dimension=0)
-        col_mask = local_col < remaining  # [chunk, 1]
+            whole = whole & (row0 >= lo)
+        state = (q_ref, kbuf, vbuf, m_ref, l_ref, acc_ref,
+                 jax.lax.rem(k, ring), scale)
 
-        # Merged-lane compute: q arrives pre-expanded block-diagonally
-        # ([Hq, Hkv*D], zeros outside each query head's own kv-head lane
-        # block), so QK^T over the full merged row contracts exactly each
-        # head's D lanes — one MXU matmul for all heads, no in-kernel
-        # reshape (Mosaic cannot unfold merged lanes).  Rows past the valid
-        # range were never DMA'd; zero V before the PV matmul — a NaN there
-        # would poison the accumulator even under zero probability weight
-        # (0 * NaN = NaN).  K needs no masking: its scores are overwritten
-        # by the NEG_INF mask.
-        kc = kbuf[slot].astype(jnp.float32)  # [chunk, HD]
-        vc = jnp.where(col_mask, vbuf[slot].astype(jnp.float32), 0.0)
-        qx = q_ref[0].astype(jnp.float32)  # [Hq, HD] block-diagonal
-        s = (
-            jax.lax.dot_general(
-                qx, kc,
-                dimension_numbers=(((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )
-            * scale
-        )  # [Hq, chunk]
-        s = jnp.where(slot_mask, s, NEG_INF)
+        def whole_step():
+            dma(k, "wait", False)
+            _attend(*state)
 
-        m_prev = m_ref[...]  # [Hq, 1]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        alpha = jnp.exp(m_prev - m_new)
-        pexp = jnp.exp(s - m_new)
-        pexp = jnp.where(slot_mask, pexp, 0.0)
-        l_ref[...] = l_ref[...] * alpha + jnp.sum(pexp, axis=-1, keepdims=True)
-        # [Hq, HD]: each row holds every kv head's weighted V; the caller
-        # slices out the row's own kv-head lane block.
-        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-            pexp, vc,
-            dimension_numbers=(((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        m_ref[...] = m_new
+        def boundary_step():
+            dma(k, "wait", True)
+            _attend(*state, remaining=n_valid - row0,
+                    below=None if window is None else lo - row0)
+
+        jax.lax.cond(whole, whole_step, boundary_step)
         return carry
 
-    jax.lax.fori_loop(first_chunk, n_chunks, body, 0)
+    jax.lax.fori_loop(0, n_steps, body, 0)
     denom = jnp.maximum(l_ref[...], 1e-30)
     out_ref[0, :, :] = (acc_ref[...] / denom).astype(out_ref.dtype)
 
@@ -266,6 +331,8 @@ def _paged_decode(q, k_pool, v_pool, page_table, seq_lens, page_size,
     if scale is None:
         scale = D**-0.5
     cp = min(pages_per_chunk, P)
+    # whole chunks a softmax step, no more than the table can name
+    sp = cp * max(1, min(STEP_ROWS // (cp * page_size), -(-P // cp)))
     k_pages = k_pool.reshape(-1, page_size, HD)
     v_pages = v_pool.reshape(-1, page_size, HD)
 
@@ -286,10 +353,14 @@ def _paged_decode(q, k_pool, v_pool, page_table, seq_lens, page_size,
         ],
         out_specs=pl.BlockSpec((1, Hq, HD), lambda b, pt, sl: (b, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((2, cp * page_size, HD), k_pool.dtype),
-            pltpu.VMEM((2, cp * page_size, HD), v_pool.dtype),
-            pltpu.SemaphoreType.DMA((2, cp)),
-            pltpu.SemaphoreType.DMA((2, cp)),
+            pltpu.VMEM((RING, sp * page_size, HD), k_pool.dtype),
+            pltpu.VMEM((RING, sp * page_size, HD), v_pool.dtype),
+            # One semaphore a buffer is all the kernel uses.  Allocated sp
+            # apart (the per-page layout the two-buffer kernel had) the
+            # global call measured 0.429 us a chunk, packed [RING] 0.455
+            # (PERF.md section 6, PR 30, my chip run 8).
+            pltpu.SemaphoreType.DMA((RING, sp)),
+            pltpu.SemaphoreType.DMA((RING, sp)),
             pltpu.VMEM((Hq, 1), jnp.float32),
             pltpu.VMEM((Hq, 1), jnp.float32),
             pltpu.VMEM((Hq, HD), jnp.float32),

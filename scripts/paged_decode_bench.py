@@ -1,0 +1,207 @@
+#!/usr/bin/env python3
+"""The paged-decode kernel alone, on the chip: device time per call and per
+chunk, global and windowed, beside the same walk with the softmax step taken
+out (DMA starts, waits and the loop only).
+
+    python scripts/paged_decode_bench.py                  # Yi's geometry
+    python scripts/paged_decode_bench.py --window 0       # global form only
+    python scripts/paged_decode_bench.py --rehearse       # CPU, tiny, no times
+
+Through the chip tool, from the repo root.  Defaults are the registered
+`chat-decode` cells' geometry (PERF.md section 4): 16 lanes, 32/4 x 128,
+page 16, a 5,120-page bf16 pool, contexts 7.7k-8.9k over a 7,424-token
+prefix whose pages every lane shares, the rest scattered.  Times are the
+Pallas call's own events in one profiler capture (the host clock would add
+the dispatch).  A chunk is `pages_per_chunk` (8) pages, the unit
+`decode_chunk_range` counts and the rooflines of `benchmarks/` charge;
+bytes are those chunks' K and V rows.  Prints one JSON line a form and writes
+them all to chiprun_out/paged_decode_bench.json.
+"""
+
+from __future__ import annotations
+
+import argparse
+import glob
+import json
+import os
+import re
+import sys
+import tempfile
+
+import numpy as np
+
+sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                                ".."))
+
+PAGES_PER_CHUNK = 8
+
+
+def make_case(args, jnp):
+    """Inputs from --seed: a scattered page table over a shared prefix."""
+    rng = np.random.RandomState(args.seed % 2**31)
+    ps, hd = args.page_size, args.kv_heads * args.head_dim
+    dtype = jnp.dtype(args.dtype)
+    total = args.num_pages * ps
+    k = jnp.asarray(rng.randn(total, hd).astype(np.float32), dtype)
+    v = jnp.asarray(rng.randn(total, hd).astype(np.float32), dtype)
+    q = jnp.asarray(
+        rng.randn(args.lanes, args.heads, args.head_dim).astype(np.float32),
+        dtype)
+    lens = rng.randint(args.min_len, args.max_len + 1,
+                       size=args.lanes).astype(np.int32)
+    free = list(range(1, args.num_pages))  # page 0 is the trash page
+    rng.shuffle(free)
+    shared = [free.pop() for _ in range(args.shared_prefix // ps)]
+    table = np.zeros((args.lanes, args.max_pages), np.int32)
+    for b, n in enumerate(lens):
+        need = -(-(int(n) + 1) // ps)
+        table[b, :len(shared)] = shared
+        for i in range(len(shared), need):
+            table[b, i] = free.pop()
+    return (q, k, v, jnp.asarray(table), jnp.asarray(lens)), lens
+
+
+def forms(args, jax, pa):
+    """{name: (jitted fn, window)}: the installed kernel, and the same walk
+    with `_attend` (the softmax step) replaced by nothing; a tree whose
+    kernel has no such function (before PR 30) gets the first form only."""
+    interpret = jax.default_backend() != "tpu"
+
+    def build(name, window, walk_only):
+        def fn(q, k, v, table, lens):
+            if walk_only:
+                attend, pa._attend = pa._attend, lambda *a, **kw: None
+            try:
+                return pa._paged_decode(
+                    q, k, v, table, lens, args.page_size, PAGES_PER_CHUNK,
+                    None, interpret, window)
+            finally:
+                if walk_only:
+                    pa._attend = attend
+        fn.__name__ = name
+        return jax.jit(fn)
+
+    out = {}
+    for window in [None] + ([args.window] if args.window else []):
+        sfx = "window" if window else "global"
+        out[f"bench_{sfx}"] = (build(f"bench_{sfx}", window, False), window)
+        if hasattr(pa, "_attend"):
+            out[f"bench_{sfx}_walk"] = (
+                build(f"bench_{sfx}_walk", window, True), window)
+    return out
+
+
+def kernel_events(trace_dir):
+    """Device ns of every Pallas call in the capture, in launch order."""
+    from jax.profiler import ProfileData
+
+    path = sorted(glob.glob(os.path.join(
+        trace_dir, "plugins", "profile", "*", "*.xplane.pb")))[-1]
+    events = []
+    for plane in ProfileData.from_file(path).planes:
+        if not re.match(r"^/device:TPU:\d+$", plane.name):
+            continue
+        for line in plane.lines:
+            if line.name == "XLA Ops":
+                events += [(ev.start_ns, ev.duration_ns) for ev in line.events
+                           if "custom-call" in ev.name]
+    return [d for _, d in sorted(events)]
+
+
+def main() -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n")[0])
+    ap.add_argument("--lanes", type=int, default=16)
+    ap.add_argument("--heads", type=int, default=32)
+    ap.add_argument("--kv-heads", type=int, default=4)
+    ap.add_argument("--head-dim", type=int, default=128)
+    ap.add_argument("--page-size", type=int, default=16)
+    ap.add_argument("--num-pages", type=int, default=5120)
+    ap.add_argument("--max-pages", type=int, default=1024,
+                    help="page-table width (the cells' 16k window)")
+    ap.add_argument("--min-len", type=int, default=7700)
+    ap.add_argument("--max-len", type=int, default=8900)
+    ap.add_argument("--shared-prefix", type=int, default=7424)
+    ap.add_argument("--window", type=int, default=1024,
+                    help="0: skip the windowed form")
+    ap.add_argument("--dtype", default="bfloat16")
+    ap.add_argument("--seed", type=int, default=2147485003)
+    ap.add_argument("--reps", type=int, default=5)
+    ap.add_argument("--rehearse", action="store_true",
+                    help="tiny geometry, any backend, checks only")
+    args = ap.parse_args()
+    if args.rehearse:
+        args.lanes, args.heads, args.kv_heads, args.head_dim = 3, 8, 2, 16
+        args.page_size, args.num_pages, args.max_pages = 4, 400, 160
+        args.min_len, args.max_len, args.shared_prefix = 300, 600, 280
+        args.window = args.window and 100
+
+    import jax
+    import jax.numpy as jnp
+
+    from kafka_tpu.ops.pallas import paged_attention as pa
+    from kafka_tpu.runtime.planner import device_peaks
+
+    on_chip = jax.default_backend() == "tpu"
+    if not on_chip and not args.rehearse:
+        print("no TPU: a device time comes only from the chip "
+              "(--rehearse checks the command here)", file=sys.stderr)
+        return 3
+    case, lens = make_case(args, jnp)
+    fns = forms(args, jax, pa)
+    outs = {n: np.asarray(fn(*case), np.float32) for n, (fn, _) in fns.items()}
+    for name, (_, window) in fns.items():
+        if name.endswith("_walk"):
+            continue
+        call = (pa.paged_decode_attention if window is None else
+                lambda *a, **kw: pa.paged_decode_attention_window(
+                    *a, window=window, **kw))
+        ref = np.asarray(call(*case, page_size=args.page_size,
+                              interpret=not on_chip), np.float32)
+        assert np.array_equal(outs[name], ref), name  # the installed kernel
+        assert np.isfinite(ref).all(), name
+    if not on_chip:
+        print(json.dumps({"rehearsed": sorted(fns), "device": "cpu"}))
+        return 0
+
+    trace_dir = tempfile.mkdtemp(prefix="paged_decode_bench_")
+    with jax.profiler.trace(trace_dir):
+        for _ in range(args.reps):
+            for fn, _ in fns.values():
+                fn(*case).block_until_ready()
+    # one Pallas call a launch, launched form after form, rep after rep
+    durations = kernel_events(trace_dir)
+    if len(durations) != args.reps * len(fns):
+        print(f"{len(durations)} Pallas events in the capture, expected "
+              f"{args.reps} x {len(fns)}", file=sys.stderr)
+        return 1
+    _, hbm_bytes_per_s, _ = device_peaks(jax.devices()[0])  # unknown: raises
+    row_bytes = args.kv_heads * args.head_dim * jnp.dtype(args.dtype).itemsize
+    chunk_bytes = 2 * PAGES_PER_CHUNK * args.page_size * row_bytes  # K and V
+    result = {"device": jax.devices()[0].device_kind, "args": vars(args),
+              "contexts": [int(n) for n in lens], "forms": {}}
+    for i, (name, (_, window)) in enumerate(fns.items()):
+        durs = durations[i::len(fns)]
+        chunks = 0
+        for n in lens:
+            first, end = pa.decode_chunk_range(
+                int(n), window, args.page_size, PAGES_PER_CHUNK)
+            chunks += end - first
+        us = float(np.median(durs)) / 1e3
+        row = {
+            "calls": len(durs), "us_per_call": us,
+            "min_us": min(durs) / 1e3, "max_us": max(durs) / 1e3,
+            "chunks": chunks, "us_per_chunk": us / chunks,
+            "chunk_bytes": chunk_bytes,
+            "hbm_share": 100.0 * chunks * chunk_bytes / hbm_bytes_per_s
+            / (us / 1e6),
+        }
+        result["forms"][name] = row
+        print(json.dumps({"form": name, **row}))
+    os.makedirs("chiprun_out", exist_ok=True)
+    with open("chiprun_out/paged_decode_bench.json", "w") as f:
+        json.dump(result, f, indent=1)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

@@ -34,22 +34,26 @@ def make_paged_case(seed, B, P, ps, Hq, Hkv, D, num_pages):
     return q, k_pool, v_pool, table, seq_lens
 
 
-def xla_reference(q, k_pool, v_pool, table, seq_lens, ps):
-    """Drive causal_attention through the same index plan the engine builds."""
+def xla_reference(q, k_pool, v_pool, table, seq_lens, ps, window=None):
+    """Drive causal_attention through the same index plan the engine builds.
+    Rows past a lane's context are masked AND zeroed: a pool may hold NaN
+    there, and XLA's 0 * NaN is NaN too."""
     B, P = table.shape
     C = P * ps
     read_idx = (table[:, :, None] * ps + np.arange(ps)[None, None, :]).reshape(B, C)
     kv_positions = np.broadcast_to(np.arange(C)[None, :], (B, C))
-    kv_valid = kv_positions <= seq_lens[:, None]
+    kv_valid = kv_positions <= np.asarray(seq_lens)[:, None]
+    written = jnp.asarray(kv_valid)[:, :, None, None]
     k_win = jnp.asarray(k_pool)[jnp.asarray(read_idx)]  # [B, C, Hkv, D]
     v_win = jnp.asarray(v_pool)[jnp.asarray(read_idx)]
     out = causal_attention(
         jnp.asarray(q)[:, None],  # [B, 1, Hq, D]
-        k_win,
-        v_win,
+        jnp.where(written, k_win, 0).astype(k_win.dtype),
+        jnp.where(written, v_win, 0).astype(v_win.dtype),
         q_positions=jnp.asarray(seq_lens)[:, None],
         kv_positions=jnp.asarray(kv_positions),
         kv_valid=jnp.asarray(kv_valid),
+        window=window,
     )
     return np.asarray(out[:, 0])  # [B, Hq, D]
 
@@ -147,6 +151,122 @@ class TestPagedDecodeAttention:
         np.testing.assert_allclose(
             np.asarray(out, np.float32), ref, atol=0.05, rtol=0.05
         )
+
+
+# Yi-1.5-9B's and Mellum2's attention geometry (q_per_kv 8, D 128, page 16):
+# a chunk is 8 pages = 128 keys, a softmax step 4 chunks, so the contexts
+# below put the query in the first step, on a chunk edge, on a step edge,
+# and several whole steps deep (the unmasked step) with a partial last one.
+BF16_CTX = {
+    "one_chunk": 99,             # n_valid 100: one masked step
+    "chunk_boundary": 383,       # n_valid 384 = 3 chunks exactly
+    "step_boundary": 511,        # n_valid 512: the last row of step 0
+    "step_boundary_plus_one": 512,   # one row alone in step 1
+    "whole_steps_and_partial": 1235,  # two unmasked steps + 212 rows
+    "whole_steps_exactly": 1535,      # n_valid 1536 = 3 steps exactly
+}
+# Both sides multiply the SAME bf16 q, K and V rows with f32 accumulation, so
+# the scores agree to f32 rounding.  They differ in where a probability is
+# rounded to bf16 for the PV product: causal_attention rounds exp / sum, the
+# kernel rounds exp (against the running max) and divides the f32 sum out at
+# the end.  Each rounding is at most 2^-9 relative a term, so the two weighted
+# means of V differ by under 2 x 2^-9 x max|v| (randn: ~4) = 0.016 in the
+# worst case and far less over hundreds of terms of mixed sign; then each side
+# rounds its result to bf16 once (2^-9 of values up to ~1).  Measured here:
+# at most 2^-8, one bf16 ulp of a result near 1; the bound is two.
+BF16_ATOL = 2 ** -7
+
+
+def make_bf16_case(seed, lens, P=104, ps=16, Hq=16, Hkv=2, D=128):
+    """A bf16 pool with NaN in every row no lane has written: pages no table
+    names, and the rows of a lane's last page past its context."""
+    rng = np.random.RandomState(seed)
+    lens = np.asarray(lens, np.int32)
+    B = len(lens)
+    num_pages = B * P + 1
+    k_pool = np.full((num_pages * ps, Hkv, D), np.nan, np.float32)
+    v_pool = np.full((num_pages * ps, Hkv, D), np.nan, np.float32)
+    free = list(range(1, num_pages))
+    rng.shuffle(free)
+    table = np.zeros((B, P), np.int32)
+    for b, n in enumerate(lens):
+        pages = [free.pop() for _ in range(-(-(int(n) + 1) // ps))]
+        table[b, :len(pages)] = pages
+        rows = (np.asarray(pages)[:, None] * ps + np.arange(ps)).ravel()
+        rows = rows[:int(n) + 1]  # position n is this step's own write
+        k_pool[rows] = rng.randn(len(rows), Hkv, D)
+        v_pool[rows] = rng.randn(len(rows), Hkv, D)
+    q = rng.randn(B, Hq, D).astype(np.float32)
+    as_bf16 = lambda a: jnp.asarray(a, jnp.bfloat16)
+    return as_bf16(q), as_bf16(k_pool), as_bf16(v_pool), table, lens
+
+
+class TestPagedDecodeBf16Pools:
+    """ISSUE 30: the kernel multiplies the pool's own bf16 rows (f32 scores,
+    softmax state and accumulators), masks only a walk's boundary steps, and
+    no row a lane has not written can reach the result."""
+
+    @pytest.mark.parametrize("ctx", list(BF16_CTX))
+    def test_global_matches_causal_attention(self, ctx):
+        n = BF16_CTX[ctx]
+        q, k, v, table, lens = make_bf16_case(30, [n, max(n - 37, 0)])
+        out = paged_decode_attention(
+            q, k.reshape(k.shape[0], -1), v.reshape(v.shape[0], -1),
+            jnp.asarray(table), jnp.asarray(lens), page_size=16,
+            interpret=True)
+        assert out.dtype == jnp.bfloat16
+        got = np.asarray(out, np.float32)
+        assert np.isfinite(got).all()
+        ref = xla_reference(q, k, v, table, lens, 16).astype(np.float32)
+        np.testing.assert_allclose(got, ref, atol=BF16_ATOL, rtol=0)
+
+    @pytest.mark.parametrize("ctx,window", [
+        ("one_chunk", 64),                 # window inside the only step
+        ("chunk_boundary", 200),           # lo 184: mid-chunk 1, step 0
+        ("step_boundary_plus_one", 300),   # lo 213: two masked steps
+        ("whole_steps_and_partial", 1000),  # lo 236: mid-chunk, then whole
+        ("whole_steps_exactly", 1024),     # lo 512: a step edge, no mask
+        ("whole_steps_and_partial", 600),  # lo 636: walk starts at chunk 4
+    ])
+    def test_windowed_matches_causal_attention(self, ctx, window):
+        from kafka_tpu.ops.pallas import paged_decode_attention_window
+        from kafka_tpu.ops.pallas.paged_attention import decode_chunk_range
+
+        n = BF16_CTX[ctx]
+        q, k, v, table, lens = make_bf16_case(31, [n, max(n - 90, 0)])
+        k, v = np.asarray(k, np.float32), np.asarray(v, np.float32)
+        for b, m in enumerate(lens):  # chunks below the walk hold NaN too
+            first, _ = decode_chunk_range(int(m), window, 16)
+            for page in table[b, :first * 8]:
+                k[page * 16:(page + 1) * 16] = np.nan
+                v[page * 16:(page + 1) * 16] = np.nan
+        k_nan, v_nan = jnp.asarray(k, jnp.bfloat16), jnp.asarray(v, jnp.bfloat16)
+        out = paged_decode_attention_window(
+            q, k_nan.reshape(k.shape[0], -1), v_nan.reshape(v.shape[0], -1),
+            jnp.asarray(table), jnp.asarray(lens), window=window,
+            page_size=16, interpret=True)
+        got = np.asarray(out, np.float32)
+        assert np.isfinite(got).all()
+        # the reference gathers everything the table names: give it zeros
+        # where the kernel must not have looked
+        ref = xla_reference(
+            q, jnp.nan_to_num(k_nan), jnp.nan_to_num(v_nan), table, lens, 16,
+            window=window).astype(np.float32)
+        np.testing.assert_allclose(got, ref, atol=BF16_ATOL, rtol=0)
+
+    def test_f32_pool_keeps_f32_operands(self):
+        """The operand dtype follows the pool: with an f32 pool a bf16-sized
+        rounding of the probabilities (2^-9) would show at 2e-5."""
+        n = BF16_CTX["whole_steps_and_partial"]
+        q, k, v, table, lens = make_bf16_case(32, [n])
+        q, k, v = (jnp.nan_to_num(a.astype(jnp.float32)) + 0.001
+                   for a in (q, k, v))
+        out = paged_decode_attention(
+            q, k.reshape(k.shape[0], -1), v.reshape(v.shape[0], -1),
+            jnp.asarray(table), jnp.asarray(lens), page_size=16,
+            interpret=True)
+        ref = xla_reference(q, k, v, table, lens, 16)
+        np.testing.assert_allclose(np.asarray(out), ref, atol=2e-5, rtol=2e-5)
 
 
 class TestEnginePallasBackend:
