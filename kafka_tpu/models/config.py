@@ -1,7 +1,10 @@
 """Model configurations served by the TPU engine: Llama-style decoders
-(dense or Mixtral-routed MLP), and decoders whose layers follow a static
+(dense or Mixtral-routed MLP), decoders whose layers follow a static
 pattern of windowed and global attention with rotary parameters per kind
-(Mellum2: three sliding-window layers, then one full-attention layer).
+(Mellum2: three sliding-window layers, then one full-attention layer), and
+`deepseek_v3`-style decoders: latent (MLA) attention whose cache row is one
+compressed vector a token, leading dense layers ahead of routed ones, and
+sigmoid-scored routing with a selection bias beside always-on shared experts.
 
 The reference service routed model names to remote providers by string
 heuristics (src/llm/utils.py:11-29); here a model name resolves to a local
@@ -106,8 +109,51 @@ class ModelConfig:
     layer_types: Tuple[str, ...] = ()
     sliding_window: Optional[int] = None
     rope_by_kind: Tuple[Tuple[str, RopeParams], ...] = ()
+    # Latent attention (MLA, HF `deepseek_v3` without a query low-rank):
+    # kv_lora_rank > 0 turns it on.  A token caches ONE row shared by all
+    # heads: the normed latent c~ (kv_lora_rank) and the roped key part k_r
+    # (qk_rope_head_dim); a head's keys and values are c~ through its slice
+    # of W_kvb ([k_nope (qk_nope_head_dim) | v (v_head_dim)]).  `head_dim`
+    # is the published one, the rotary width.  `num_kv_heads` is published
+    # too and means nothing for the cache.
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    # rotary pairs are published interleaved (x0 x1 | x2 x3 ...): the values
+    # are de-interleaved, then rotated half-split (HF
+    # `apply_rotary_pos_emb_interleave`)
+    rope_interleave: bool = False
+    # The first `first_k_dense` layers have a dense SwiGLU MLP of
+    # `dense_intermediate_size`; the rest are routed.  0 = homogeneous.
+    first_k_dense: int = 0
+    dense_intermediate_size: int = 0
+    # Always-on shared experts beside the routed ones: one SwiGLU of this
+    # width (n_shared_experts * moe_intermediate_size).  0 = none.
+    shared_intermediate_size: int = 0
+    # Routing rule.  "softmax": softmax over exactly the top-k logits
+    # (Mixtral).  "sigmoid": the top-k of sigmoid(logits) + a per-expert
+    # selection bias are chosen, weighed by sigmoid(logits) alone,
+    # renormalised over the chosen and scaled by `routed_scaling_factor`
+    # (HF deepseek_v3 `noaux_tc` with one group).
+    moe_scoring: str = "softmax"
+    routed_scaling_factor: float = 1.0
 
     def __post_init__(self):
+        if self.moe_scoring not in ("softmax", "sigmoid"):
+            raise UnsupportedConfigError(
+                f"moe_scoring {self.moe_scoring!r}: known 'softmax', "
+                "'sigmoid'")
+        if self.kv_lora_rank and self.layer_types:
+            raise UnsupportedConfigError(
+                "latent attention with a windowed/global layer pattern is "
+                "not served")
+        if self.first_k_dense and not (
+                self.is_moe and 0 < self.first_k_dense < self.num_layers
+                and self.dense_intermediate_size > 0):
+            raise UnsupportedConfigError(
+                "first_k_dense needs routed layers after the dense ones and "
+                "a dense_intermediate_size")
         if not self.layer_types:
             return
         bad = set(self.layer_types) - {WINDOWED, GLOBAL}
@@ -152,6 +198,23 @@ class ModelConfig:
     @property
     def q_per_kv(self) -> int:
         return self.num_heads // self.num_kv_heads
+
+    @property
+    def is_latent(self) -> bool:
+        return self.kv_lora_rank > 0
+
+    @property
+    def kv_row_widths(self) -> Tuple[int, int]:
+        """Values a token stores in one layer of the (k, v) pools: THE
+        definition of the paged pool's rows, which the pool's allocation
+        (runtime/kv_cache.py), the memory plan (runtime/planner.py), the
+        engine's backend rules and /metrics all ask.  GQA: Hkv*D keys and as
+        many values.  Latent: the k pool holds c~ (kv_lora_rank) and the v
+        pool the roped k_r padded to whole 128-lane tiles, which the Pallas
+        page DMAs need (64 -> 128: 640 values for 576 stored)."""
+        if self.is_latent:
+            return (self.kv_lora_rank, -(-self.qk_rope_head_dim // 128) * 128)
+        return (self.num_kv_heads * self.head_dim,) * 2
 
     @property
     def activation_dtype(self):
@@ -339,14 +402,58 @@ def _layer_pattern(hf: dict) -> dict:
     }
 
 
+def _latent_keys(hf: dict) -> dict:
+    """The `deepseek_v3` keys of a published config.json (latent attention,
+    leading dense layers, sigmoid routing, shared experts) as ModelConfig
+    fields; {} for any other model.  What is not served is an
+    UnsupportedConfigError, by key."""
+    if not hf.get("kv_lora_rank"):
+        return {}
+    served = (
+        ("q_lora_rank", None, "a query low-rank projection"),
+        ("rope_scaling", None, "scaled rotary positions on latent attention"),
+        ("topk_method", "noaux_tc", "another expert selection method"),
+        ("scoring_func", "sigmoid", "another router scoring function"),
+        ("moe_layer_freq", 1, "dense layers among the routed ones"),
+        ("n_group", 1, "group-limited expert selection"),
+        ("topk_group", 1, "group-limited expert selection"),
+        ("attention_bias", False, "attention biases"),
+    )
+    for key, want, what in served:
+        got = hf.get(key, want)
+        if got != want:
+            raise UnsupportedConfigError(
+                f"{key} = {got!r} ({what}) is not served: only {want!r} is")
+    if hf.get("norm_topk_prob") is not True:
+        raise UnsupportedConfigError(
+            "norm_topk_prob must be true: sigmoid routing weights are "
+            "renormalised over the chosen experts")
+    dense = int(hf.get("first_k_dense_replace", 0))
+    return {
+        "kv_lora_rank": int(hf["kv_lora_rank"]),
+        "qk_nope_head_dim": int(hf["qk_nope_head_dim"]),
+        "qk_rope_head_dim": int(hf["qk_rope_head_dim"]),
+        "v_head_dim": int(hf["v_head_dim"]),
+        "rope_interleave": bool(hf.get("rope_interleave", False)),
+        "first_k_dense": dense,
+        "dense_intermediate_size": int(hf["intermediate_size"]) if dense else 0,
+        "shared_intermediate_size": (int(hf.get("n_shared_experts") or 0)
+                                     * int(hf["moe_intermediate_size"])),
+        "moe_scoring": "sigmoid",
+        "routed_scaling_factor": float(hf.get("routed_scaling_factor", 1.0)),
+    }
+
+
 def config_from_hf_json(path: str) -> ModelConfig:
     """Build a ModelConfig from a HuggingFace config.json: Llama / Mixtral
-    keys, and the published keys of a patterned routed decoder (Mellum2:
+    keys, the published keys of a patterned routed decoder (Mellum2:
     `layer_types`, `sliding_window`, `rope_parameters`, `num_experts`,
-    `moe_intermediate_size`, `norm_topk_prob`, `mlp_layer_types`).  A key the
-    program cannot honour is an UnsupportedConfigError."""
+    `moe_intermediate_size`, `norm_topk_prob`, `mlp_layer_types`), and those
+    of a `deepseek_v3` decoder (`_latent_keys`).  A key the program cannot
+    honour is an UnsupportedConfigError."""
     with open(path) as f:
         hf = json.load(f)
+    latent = _latent_keys(hf)
     rs = hf.get("rope_scaling") or {}
     # honor the checkpoint's own precision ("dtype" since transformers
     # 4.56+, "torch_dtype" before); fp16 checkpoints run as bf16 (same
@@ -357,14 +464,15 @@ def config_from_hf_json(path: str) -> ModelConfig:
     )
     # MoE: `num_local_experts` (HF Mixtral) or `num_experts` with the
     # experts' own width in `moe_intermediate_size`; absent -> 0 = dense
-    num_experts = hf.get("num_local_experts", hf.get("num_experts", 0)) or 0
+    num_experts = (hf.get("num_local_experts", hf.get("num_experts", 0))
+                   or (hf.get("n_routed_experts", 0) if latent else 0) or 0)
     mlp_kinds = hf.get("mlp_layer_types")
     if mlp_kinds and (set(mlp_kinds) != {"sparse"} or not num_experts):
         raise UnsupportedConfigError(
             "mlp_layer_types must be all 'sparse' (with experts) or absent: "
             f"found {sorted(set(mlp_kinds))} with {num_experts} experts; a "
             "mix of dense and routed layers is not served")
-    if num_experts and hf.get("norm_topk_prob") is False:
+    if num_experts and not latent and hf.get("norm_topk_prob") is False:
         raise UnsupportedConfigError(
             "norm_topk_prob false (top-k weights of a softmax over ALL "
             "experts, not renormalised) is not served: routing here is a "
@@ -388,7 +496,9 @@ def config_from_hf_json(path: str) -> ModelConfig:
         num_layers=hf["num_hidden_layers"],
         num_heads=hf["num_attention_heads"],
         num_kv_heads=hf.get("num_key_value_heads", hf["num_attention_heads"]),
-        head_dim=hf.get("head_dim", hf["hidden_size"] // hf["num_attention_heads"]),
+        # (latent attention: the rotary width, what a published `head_dim` is)
+        head_dim=(latent["qk_rope_head_dim"] if latent else hf.get(
+            "head_dim", hf["hidden_size"] // hf["num_attention_heads"])),
         rope_theta=rope_theta,
         rms_norm_eps=hf.get("rms_norm_eps", 1e-5),
         max_context=hf.get("max_position_embeddings", 8192),
@@ -398,4 +508,5 @@ def config_from_hf_json(path: str) -> ModelConfig:
         rope_high_freq_factor=rs.get("high_freq_factor", 4.0),
         rope_original_max_position=rs.get("original_max_position_embeddings", 8192),
         **pattern,
+        **latent,
     )

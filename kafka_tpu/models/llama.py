@@ -1,5 +1,7 @@
 """Decoder-only transformer in pure functional JAX: the Llama family, Mixtral's
-routed MLP, and layer patterns of windowed and global attention (Mellum2).
+routed MLP, layer patterns of windowed and global attention (Mellum2), and
+latent attention behind leading dense layers with sigmoid-routed and shared
+experts (HF `deepseek_v3`; "Latent attention" below).
 
 Design (TPU-first, not a port — the reference has no model code at all; its
 LLM compute lived behind a remote gateway, src/llm/portkey.py):
@@ -32,6 +34,21 @@ Two cache forms go through the same layer math: the *contiguous*
   unrolled, each indexing its own weights, each kind its own code under its
   own scope with its own static window.  A period of one is the plain scan.
 
+* **Latent attention (MLA)** — `cfg.is_latent`.  A token caches ONE row a
+  layer, shared by all heads: the normed latent c~ and the roped key part
+  k_r.  In the paged pool c~ is the k pool's row and k_r (padded to whole
+  lane tiles) the v pool's (`ModelConfig.kv_row_widths`); expanded keys and
+  values never enter a pool.  Two forms of the same attention: *expanded*,
+  as published (each cached row goes through W_kvb to a head's keys and
+  values), for the uncached and contiguous caches and for paged prefill;
+  *absorbed* for paged decode (W_kvb's key half multiplied into the query,
+  scores and the weighted sum taken over the latent rows themselves, W_kvb's
+  value half applied to the result), on the Pallas latent kernel or on XLA.
+  Leading dense layers (`cfg.first_k_dense`) are a stacked tree of their
+  own, `params["dense_layers"]`, run (unrolled) ahead of the scan over the
+  routed `params["layers"]`; both index the one stacked pool by absolute
+  layer.
+
 **The stacked cache is scan CARRY, never a scanned input.**  The layer scan
 runs over (layer params, layer index); the caches of all layers travel
 through it whole and a layer addresses its part by index.  The paged pool is
@@ -54,8 +71,8 @@ from typing import Any, Dict, NamedTuple, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from ..models.config import GLOBAL, ModelConfig
-from ..ops.attention import causal_attention
+from ..models.config import GLOBAL, ModelConfig, UnsupportedConfigError
+from ..ops.attention import NEG_INF, causal_attention
 from ..ops.norms import rms_norm
 from ..ops.rope import (
     apply_rope,
@@ -202,6 +219,12 @@ def _layer_view(paged: PagedView, layer, slots: int) -> PagedView:
 
 def init_kv_cache(cfg: ModelConfig, batch: int, capacity: int, dtype=None) -> KVCache:
     dtype = dtype or cfg.activation_dtype
+    if cfg.is_latent:
+        # one "head": k holds the latent c~, v the roped k_r (no padding:
+        # nothing DMAs this cache by lane tile)
+        lead = (cfg.num_layers, batch, capacity, 1)
+        return KVCache(k=jnp.zeros(lead + (cfg.kv_lora_rank,), dtype),
+                       v=jnp.zeros(lead + (cfg.qk_rope_head_dim,), dtype))
     shape = (cfg.num_layers, batch, capacity, cfg.num_kv_heads, cfg.head_dim)
     return KVCache(k=jnp.zeros(shape, dtype), v=jnp.zeros(shape, dtype))
 
@@ -210,6 +233,11 @@ def init_params(cfg: ModelConfig, key: jax.Array, dtype=None) -> Params:
     """Random-init parameters (layer-stacked). Serving loads checkpoints
     instead; random init exists for tests and micro-benchmarks."""
     dtype = dtype or cfg.activation_dtype
+    if cfg.is_latent or cfg.first_k_dense or cfg.shared_intermediate_size \
+            or cfg.moe_scoring != "softmax":
+        # a tree and a random stream of its own: the stream below is what
+        # every other configuration's seeded weights come from
+        return _init_latent_params(cfg, key, dtype)
     h, f, d = cfg.hidden_size, cfg.intermediate_size, cfg.head_dim
     hq, hkv, L = cfg.num_heads, cfg.num_kv_heads, cfg.num_layers
     keys = jax.random.split(key, 10)
@@ -244,6 +272,85 @@ def init_params(cfg: ModelConfig, key: jax.Array, dtype=None) -> Params:
     }
     if not cfg.tie_word_embeddings:
         params["lm_head"] = norm01(keys[8], (h, cfg.vocab_size), h)
+    return params
+
+
+def _init_latent_params(cfg: ModelConfig, key: jax.Array, dtype) -> Params:
+    """Random weights of a `deepseek_v3`-style decoder: latent attention in
+    every layer; `first_k_dense` dense layers stacked under "dense_layers",
+    the routed ones (router + selection bias, experts, shared branch) under
+    "layers".  The selection bias is N(0, 0.1^2), not zero: with b = 0 a
+    program that weighs by sigma + b, or chooses by sigma, passes every
+    check; the latent norm's weight is 1 + N(0, 0.2^2) for the same reason."""
+    if not cfg.is_latent:
+        raise UnsupportedConfigError(
+            "leading dense layers, shared experts and sigmoid routing are "
+            "built with latent attention only")
+    h, hq, r = cfg.hidden_size, cfg.num_heads, cfg.kv_lora_rank
+    dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+
+    @partial(jax.jit, static_argnums=(1, 2))
+    def norm01(k, shape, fan_in):
+        # one program a leaf: the draw, the scale and the cast fuse, so no
+        # float32 copy of a 1G-element leaf is ever held (init_params' eager
+        # form holds two, which is what caps the other configurations' depth)
+        return (jax.random.normal(k, shape, jnp.float32)
+                * (fan_in**-0.5)).astype(dtype)
+
+    def attention(k, n):
+        ks = jax.random.split(k, 5)
+        return {
+            "ln_attn": jnp.ones((n, h), dtype),
+            "ln_mlp": jnp.ones((n, h), dtype),
+            # not ones: over 512 lanes of unit-variance c the RMS is already
+            # 1 +- 3%, so with a unit weight a program that skips this norm
+            # would pass every check
+            "ln_kv": (1.0 + 0.2 * jax.random.normal(
+                ks[4], (n, r), jnp.float32)).astype(dtype),
+            "wq": norm01(ks[0], (n, h, hq, dn + dr), h),
+            "wkva": norm01(ks[1], (n, h, r + dr), h),
+            # per head [k_nope | v]; the latent axis next to last, where
+            # every stacked matrix has its contracted axis
+            "wkvb": norm01(ks[2], (n, hq, r, dn + dv), r),
+            "wo": norm01(ks[3], (n, hq, dv, h), hq * dv),
+        }
+
+    def mlp(k, n, f, names=("wg", "wu", "wd")):
+        ks = jax.random.split(k, 3)
+        return {names[0]: norm01(ks[0], (n, h, f), h),
+                names[1]: norm01(ks[1], (n, h, f), h),
+                names[2]: norm01(ks[2], (n, f, h), f)}
+
+    keys = jax.random.split(key, 10)
+    n_dense = cfg.first_k_dense
+    n = cfg.num_layers - n_dense
+    layers = attention(keys[1], n)
+    if cfg.is_moe:
+        E, f = cfg.num_experts, cfg.intermediate_size
+        layers["router"] = norm01(keys[2], (n, h, E), h)
+        if cfg.moe_scoring == "sigmoid":
+            layers["router_bias"] = 0.1 * jax.random.normal(
+                keys[3], (n, E), jnp.float32)
+        layers["wg"] = norm01(keys[4], (n, E, h, f), h)
+        layers["wu"] = norm01(keys[5], (n, E, h, f), h)
+        layers["wd"] = norm01(keys[6], (n, E, f, h), f)
+        if cfg.shared_intermediate_size:
+            layers.update(mlp(keys[7], n, cfg.shared_intermediate_size,
+                              ("ws_g", "ws_u", "ws_d")))
+    else:
+        layers.update(mlp(keys[4], n, cfg.intermediate_size))
+    params: Params = {
+        "embed": norm01(keys[0], (cfg.vocab_size, h), h),
+        "final_norm": jnp.ones((h,), dtype),
+        "layers": layers,
+    }
+    if n_dense:
+        kd = jax.random.split(keys[8], 2)
+        params["dense_layers"] = {
+            **attention(kd[0], n_dense),
+            **mlp(kd[1], n_dense, cfg.dense_intermediate_size)}
+    if not cfg.tie_word_embeddings:
+        params["lm_head"] = norm01(keys[9], (h, cfg.vocab_size), h)
     return params
 
 
@@ -545,11 +652,175 @@ def _attention_core(q, k, v, cfg, positions, k_cache, v_cache, kv_valid,
     return out, k_cache, v_cache
 
 
-def _mlp_block(x: jnp.ndarray, lp: Params) -> jnp.ndarray:
+class LatentPathError(NotImplementedError):
+    """An attention path that has no latent (MLA) form was reached by a
+    latent-attention model.  The engine refuses such options when it is
+    built (runtime/engine.py LatentAttentionUnsupported); this is the
+    backstop for direct callers of `forward`."""
+
+
+def _deinterleave(x: jnp.ndarray) -> jnp.ndarray:
+    """x0 x1 x2 x3 ... -> x0 x2 ... | x1 x3 ...: published interleaved rotary
+    pairs into the half-split pairing `apply_rope` rotates."""
+    return jnp.concatenate([x[..., 0::2], x[..., 1::2]], axis=-1)
+
+
+def _latent_attend(q_a, q_rope, keys_a, k_rope, values, mask, scale,
+                   shared: bool):
+    """softmax((q_a . keys_a + q_rope . k_rope) * scale) . values in f32
+    scores, the one latent attention proper on XLA.  `shared` False, the
+    expanded form: keys_a / values are per head, [B, T, N, d].  True, the
+    absorbed form: they are the latent rows themselves, [B, T, r], shared by
+    all heads (and `values is keys_a`).  q_a [B, S, N, d|r], q_rope
+    [B, S, N, dr], k_rope [B, T, dr] (one vector a token), mask [B, S, T]."""
+    kv = "bkr" if shared else "bknr"
+    logits = (
+        jnp.einsum(f"bqnr,{kv}->bnqk", q_a, keys_a,
+                   preferred_element_type=jnp.float32)
+        + jnp.einsum("bqnd,bkd->bnqk", q_rope, k_rope,
+                     preferred_element_type=jnp.float32)
+    ) * scale
+    logits = jnp.where(mask[:, None], logits, NEG_INF)
+    probs = jax.nn.softmax(logits, axis=-1)
+    out = jnp.einsum(f"bnqk,{kv}->bqnr", probs.astype(values.dtype), values,
+                     preferred_element_type=jnp.float32)
+    return out.astype(q_a.dtype)
+
+
+def _latent_attention_block(
+    x: jnp.ndarray,
+    lp: Params,
+    cfg: ModelConfig,
+    cos: jnp.ndarray,
+    sin: jnp.ndarray,
+    positions: jnp.ndarray,
+    k_cache,
+    v_cache,
+    kv_valid: Optional[jnp.ndarray],
+    cache_positions: Optional[jnp.ndarray],
+    paged: Optional["PagedView"] = None,
+    mesh=None,
+    layer=None,
+):
+    """One latent-attention (MLA) sublayer; the signature and the cache
+    contract of _attention_block.  What is cached per token is (c~, roped
+    k_r): k_cache holds c~, v_cache k_r (module docstring).  Paged decode
+    runs the absorbed form, everything else the expanded one; what only the
+    latent form adds around attention proper (the absorb and un-absorb
+    einsums, the expansion of cached rows through W_kvb) sits under
+    `attn_latent_proj` inside `attn_core`."""
+    dt = x.dtype
+    r, dn = cfg.kv_lora_rank, cfg.qk_nope_head_dim
+    scale = (dn + cfg.qk_rope_head_dim) ** -0.5
+    if mesh is not None and mesh.size > 1:
+        raise LatentPathError(
+            "latent attention on a mesh of more than one device (tp / ep / "
+            "sp over the latent pool)")
+    if cfg.prefill_ring:
+        raise LatentPathError("prefill_ring has no latent form")
+    if isinstance(k_cache, QTensor):
+        raise LatentPathError("the int8 KV pool has no latent form")
+    with jax.named_scope("attn_qkv"):
+        q = jnp.einsum("bsh,hnd->bsnd", x, _w(lp, "wq", dt))
+        kva = jnp.einsum("bsh,hr->bsr", x, _w(lp, "wkva", dt))
+        c = rms_norm(kva[..., :r], lp["ln_kv"], cfg.rms_norm_eps)
+        q_nope, q_rope = q[..., :dn], q[..., dn:]
+        k_rope = kva[..., None, r:]  # ONE vector a token: a head axis of 1
+        if cfg.rope_interleave:
+            q_rope, k_rope = _deinterleave(q_rope), _deinterleave(k_rope)
+        q_rope = apply_rope(q_rope, cos, sin)
+        k_rope = apply_rope(k_rope, cos, sin)[..., 0, :]
+    wkvb = _w(lp, "wkvb", dt)  # [N, r, dn + dv]
+    b, s = x.shape[:2]
+    absorbed = False
+    if paged is not None:
+        # Paged pools [L, SLOTS, r] and [L, SLOTS, lanes >= dr], addressed
+        # flat with this layer's offset in every index (_attention_block)
+        num_layers, slots = k_cache.shape[:2]
+        paged = _layer_view(paged, layer, slots)
+        lanes = v_cache.shape[-1]
+        k_cache = _kv_write(_flat_pool(k_cache), paged.write_idx, c)
+        v_cache = _kv_write(
+            _flat_pool(v_cache), paged.write_idx,
+            jnp.pad(k_rope, ((0, 0), (0, 0), (0, lanes - k_rope.shape[-1]))))
+        if s > 1 and paged.seq_lens is not None:
+            raise LatentPathError(
+                "speculative verify (K+1 queries a lane) has no latent form")
+        absorbed = s == 1
+    kernel = (absorbed and cfg.attention_backend == "pallas"
+              and paged.page_table is not None)
+    with jax.named_scope("attn_core"):
+        if not kernel:
+            # the XLA forms: the window of cached rows and who may attend it
+            if paged is not None:
+                c_win, r_win = _latent_window(k_cache, v_cache, paged, dt)
+                r_win = r_win[..., :k_rope.shape[-1]]  # drop the lane padding
+                kv_pos, valid = paged.kv_positions, paged.kv_valid
+            elif k_cache is None:
+                c_win, r_win, kv_pos, valid = c, k_rope, positions, None
+            else:
+                idx = positions if cache_positions is None else cache_positions
+                b_idx = jnp.arange(b)[:, None]
+                with jax.named_scope("kv_write"):
+                    k_cache = k_cache.at[layer, b_idx, idx, 0].set(
+                        c.astype(k_cache.dtype))
+                    v_cache = v_cache.at[layer, b_idx, idx, 0].set(
+                        k_rope.astype(v_cache.dtype))
+                c_win, r_win = k_cache[layer][:, :, 0], v_cache[layer][:, :, 0]
+                cap = c_win.shape[1]
+                kv_pos = jnp.broadcast_to(jnp.arange(cap)[None, :], (b, cap))
+                valid = kv_valid
+            c_win, r_win = c_win.astype(dt), r_win.astype(dt)
+            mask = positions[:, :, None] >= kv_pos[:, None, :]
+            if valid is not None:
+                mask = mask & valid[:, None, :]
+        if absorbed:
+            with jax.named_scope("attn_latent_proj"):
+                q_lat = jnp.einsum("bsnd,nrd->bsnr", q_nope, wkvb[..., :dn])
+            if kernel:
+                from ..ops.pallas import paged_decode_attention_latent
+
+                o_lat = paged_decode_attention_latent(
+                    q_lat[:, 0], q_rope[:, 0], k_cache, v_cache,
+                    paged.page_table, paged.seq_lens, scale=scale,
+                    page_size=paged.page_size,
+                    interpret=jax.default_backend() != "tpu",
+                )[:, None]
+            else:
+                o_lat = _latent_attend(q_lat, q_rope, c_win, r_win, c_win,
+                                       mask, scale, shared=True)
+            with jax.named_scope("attn_latent_proj"):
+                out = jnp.einsum("bsnr,nrd->bsnd", o_lat, wkvb[..., dn:])
+        else:
+            with jax.named_scope("attn_latent_proj"):
+                kv = jnp.einsum("btr,nrd->btnd", c_win, wkvb)
+            out = _latent_attend(q_nope, q_rope, kv[..., :dn], r_win,
+                                 kv[..., dn:], mask, scale, shared=False)
+    if paged is not None:
+        k_cache = _stacked_pool(k_cache, num_layers)
+        v_cache = _stacked_pool(v_cache, num_layers)
+    with jax.named_scope("attn_out"):
+        out = jnp.einsum("bsnd,ndh->bsh", out, _w(lp, "wo", out.dtype))
+    return out, k_cache, v_cache
+
+
+def _latent_window(k_cache, v_cache, paged, dt):
+    """(c~ window [B, C, r], k_r window [B, C, lanes]) gathered from the
+    flat pools: by page where the plan has a page table."""
+    if paged.page_table is not None and paged.page_size is not None:
+        return (_kv_read_pages(k_cache, paged.page_table, paged.page_size, dt),
+                _kv_read_pages(v_cache, paged.page_table, paged.page_size, dt))
+    return (_kv_read(k_cache, paged.read_idx, dt),
+            _kv_read(v_cache, paged.read_idx, dt))
+
+
+def _mlp_block(x: jnp.ndarray, lp: Params,
+               names=("wg", "wu", "wd")) -> jnp.ndarray:
     """SwiGLU MLP: down( silu(gate(x)) * up(x) )."""
-    g = jnp.einsum("bsh,hf->bsf", x, _w(lp, "wg", x.dtype))
-    u = jnp.einsum("bsh,hf->bsf", x, _w(lp, "wu", x.dtype))
-    return jnp.einsum("bsf,fh->bsh", jax.nn.silu(g) * u, _w(lp, "wd", x.dtype))
+    g = jnp.einsum("bsh,hf->bsf", x, _w(lp, names[0], x.dtype))
+    u = jnp.einsum("bsh,hf->bsf", x, _w(lp, names[1], x.dtype))
+    return jnp.einsum(
+        "bsf,fh->bsh", jax.nn.silu(g) * u, _w(lp, names[2], x.dtype))
 
 
 def _routing_weights(t: jnp.ndarray, router: jnp.ndarray,
@@ -569,6 +840,25 @@ def _routing_weights(t: jnp.ndarray, router: jnp.ndarray,
     ].set(w_top)
 
 
+def _routing_weights_sigmoid(t: jnp.ndarray, router: jnp.ndarray,
+                             bias: jnp.ndarray, top_k: int,
+                             scale: float) -> jnp.ndarray:
+    """Per-token expert weights [T, E] of HF deepseek_v3's `noaux_tc` rule
+    with one group: sigma = sigmoid(logits) in f32; the top_k experts by
+    sigma + bias are CHOSEN (the bias chooses, it does not weigh; ties go to
+    the lower index, as lax.top_k); a chosen expert weighs
+    scale * sigma_e / (sum of the chosen sigma + 1e-20)."""
+    logits = jnp.einsum(
+        "th,he->te", t, router, preferred_element_type=jnp.float32
+    )
+    sigma = jax.nn.sigmoid(logits)
+    _, top_idx = jax.lax.top_k(sigma + bias.astype(jnp.float32), top_k)
+    rows = jnp.arange(t.shape[0])[:, None]
+    chosen = sigma[rows, top_idx]
+    w_top = scale * chosen / (jnp.sum(chosen, axis=-1, keepdims=True) + 1e-20)
+    return jnp.zeros_like(sigma).at[rows, top_idx].set(w_top)
+
+
 def _moe_block(x: jnp.ndarray, lp: Params, cfg: ModelConfig) -> jnp.ndarray:
     """Mixtral-style top-k routed MoE MLP. x: [B, S, H].
 
@@ -580,19 +870,30 @@ def _moe_block(x: jnp.ndarray, lp: Params, cfg: ModelConfig) -> jnp.ndarray:
     over ep and inserts the combine psum automatically — the same program
     serves single-device, ep, and ep x tp meshes.  Routing: softmax over
     the top-k router logits only (HF MixtralSparseMoeBlock semantics),
-    computed in f32.
+    computed in f32; `cfg.moe_scoring` "sigmoid" picks deepseek_v3's rule
+    instead.  A shared branch (`cfg.shared_intermediate_size`: one always-on
+    SwiGLU beside the routed experts) runs under its own scope, `moe_shared`.
     """
     b, s, h = x.shape
     t = x.reshape(b * s, h)
     with jax.named_scope("moe_router"):
-        w = _routing_weights(t, lp["router"], cfg.num_experts_per_tok)
+        if cfg.moe_scoring == "sigmoid":
+            w = _routing_weights_sigmoid(
+                t, lp["router"], lp["router_bias"], cfg.num_experts_per_tok,
+                cfg.routed_scaling_factor)
+        else:
+            w = _routing_weights(t, lp["router"], cfg.num_experts_per_tok)
     with jax.named_scope("moe_experts"):
         g = jnp.einsum("th,ehf->tef", t, _w(lp, "wg", t.dtype))
         u = jnp.einsum("th,ehf->tef", t, _w(lp, "wu", t.dtype))
         y = jnp.einsum(
             "tef,efh->teh", jax.nn.silu(g) * u, _w(lp, "wd", t.dtype))
         out = jnp.einsum("te,teh->th", w.astype(y.dtype), y)
-    return out.reshape(b, s, h)
+    out = out.reshape(b, s, h)
+    if cfg.shared_intermediate_size:
+        with jax.named_scope("moe_shared"):
+            out = out + _mlp_block(x, lp, ("ws_g", "ws_u", "ws_d"))
+    return out
 
 
 def forward(
@@ -652,21 +953,27 @@ def forward(
     # the layer's weights.  Every op of the layer body sits under a leaf
     # scope (residual adds included), so what a device trace shows under
     # `layers` alone is the scan's own slicing of its stacked inputs.
-    def layer_body(carry, scanned, kind=GLOBAL):
+    def layer_body(carry, scanned, kind=GLOBAL, routed=cfg.is_moe):
         h, kc, vc = carry
         lp, layer = scanned
         cos, sin = rope[kind]
         with jax.named_scope("attn_norm"):
             attn_in = rms_norm(h, lp["ln_attn"], cfg.rms_norm_eps)
-        attn_out, kc, vc = _attention_block(
-            attn_in, lp, cfg, cos, sin, positions, kc, vc, kv_valid,
-            cache_positions, paged, mesh, layer, cfg.window_of(kind),
-        )
+        if cfg.is_latent:
+            attn_out, kc, vc = _latent_attention_block(
+                attn_in, lp, cfg, cos, sin, positions, kc, vc, kv_valid,
+                cache_positions, paged, mesh, layer,
+            )
+        else:
+            attn_out, kc, vc = _attention_block(
+                attn_in, lp, cfg, cos, sin, positions, kc, vc, kv_valid,
+                cache_positions, paged, mesh, layer, cfg.window_of(kind),
+            )
         with jax.named_scope("attn_out"):
             h = h + attn_out
         with jax.named_scope("mlp_norm"):
             mlp_in = rms_norm(h, lp["ln_mlp"], cfg.rms_norm_eps)
-        if cfg.is_moe:
+        if routed:
             ffn_out = _moe_block(mlp_in, lp, cfg)
             with jax.named_scope("moe_experts"):
                 h = h + ffn_out
@@ -694,11 +1001,25 @@ def forward(
     with jax.named_scope("layers"):
         kc, vc = (None, None) if kv_cache is None else kv_cache
         num_layers = jax.tree.leaves(params["layers"])[0].shape[0]
+        n_dense = 0
+        if "dense_layers" in params:
+            # leading dense layers: a stacked tree of another shape, run
+            # ahead of the scan over the routed layers, which count on from
+            # them.  Unrolled, not a scan of their own: one innermost loop a
+            # forward pass is what a device trace counts passes by.
+            n_dense = jax.tree.leaves(params["dense_layers"])[0].shape[0]
+            carry = (x, kc, vc)
+            for i in range(n_dense):
+                lp = jax.tree.map(lambda a: a[i], params["dense_layers"])
+                carry, _ = layer_body(carry, (lp, i), routed=False)
+            x, kc, vc = carry
+        layer_ids = (jnp.arange(n_dense, n_dense + num_layers) if n_dense
+                     else jnp.arange(num_layers))
         if len(period) == 1:
             (x, kc, vc), _ = jax.lax.scan(
                 partial(layer_body, kind=period[0]),
                 (x, kc, vc),
-                (params["layers"], jnp.arange(num_layers)),
+                (params["layers"], layer_ids),
             )
         else:
             p = len(period)

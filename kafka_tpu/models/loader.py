@@ -41,19 +41,29 @@ def _to_numpy(t: Any) -> np.ndarray:
     return np.asarray(t)
 
 
-def convert_hf_state_dict(
-    state: Mapping[str, Any], cfg: ModelConfig, dtype: Optional[Any] = None
-) -> Params:
-    """Convert an HF Llama state dict to the layer-stacked pytree."""
-    dtype = dtype or cfg.activation_dtype
-    h, d = cfg.hidden_size, cfg.head_dim
-    hq, hkv, L = cfg.num_heads, cfg.num_kv_heads, cfg.num_layers
-
+def _getter(state: Mapping[str, Any]) -> Callable[[str], np.ndarray]:
+    """`get(name)`: a weight by its HF name, with or without the `model.`
+    prefix, as numpy."""
     def get(name: str) -> np.ndarray:
         key = name if name in state else f"model.{name}"
         if key not in state:
             raise KeyError(f"missing weight {name!r} (tried {key!r})")
         return _to_numpy(state[key])
+
+    return get
+
+
+def convert_hf_state_dict(
+    state: Mapping[str, Any], cfg: ModelConfig, dtype: Optional[Any] = None
+) -> Params:
+    """Convert an HF Llama state dict to the layer-stacked pytree."""
+    dtype = dtype or cfg.activation_dtype
+    if cfg.is_latent:
+        return _convert_latent_state_dict(state, cfg, dtype)
+    h, d = cfg.hidden_size, cfg.head_dim
+    hq, hkv, L = cfg.num_heads, cfg.num_kv_heads, cfg.num_layers
+
+    get = _getter(state)
 
     def stack(fmt: str, reshape: Callable[[np.ndarray], np.ndarray]) -> jnp.ndarray:
         return jnp.asarray(
@@ -111,6 +121,76 @@ def convert_hf_state_dict(
         if head is None:
             raise KeyError("config says untied embeddings but lm_head.weight missing")
         params["lm_head"] = jnp.asarray(_to_numpy(head).T, dtype)
+    return params
+
+
+def _convert_latent_state_dict(
+    state: Mapping[str, Any], cfg: ModelConfig, dtype: Any
+) -> Params:
+    """HF `deepseek_v3` names (no query low-rank) -> the latent tree of
+    models/llama._init_latent_params: leading dense layers stacked under
+    "dense_layers", routed ones under "layers".  Rotary columns stay
+    interleaved as published; `forward` de-interleaves them
+    (`cfg.rope_interleave`)."""
+    h, hq, r = cfg.hidden_size, cfg.num_heads, cfg.kv_lora_rank
+    dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+
+    get = _getter(state)
+
+    t = lambda w: w.T  # noqa: E731  [out, in] -> [in, out]
+    attention = {
+        "ln_attn": ("input_layernorm.weight", None),
+        "ln_mlp": ("post_attention_layernorm.weight", None),
+        "ln_kv": ("self_attn.kv_a_layernorm.weight", None),
+        "wq": ("self_attn.q_proj.weight",
+               lambda w: w.T.reshape(h, hq, dn + dr)),
+        "wkva": ("self_attn.kv_a_proj_with_mqa.weight", t),
+        "wkvb": ("self_attn.kv_b_proj.weight",
+                 lambda w: w.reshape(hq, dn + dv, r).transpose(0, 2, 1)),
+        "wo": ("self_attn.o_proj.weight",
+               lambda w: w.T.reshape(hq, dv, h)),
+    }
+
+    def mlp(prefix: str, names=("wg", "wu", "wd")) -> dict:
+        return {names[0]: (f"{prefix}.gate_proj.weight", t),
+                names[1]: (f"{prefix}.up_proj.weight", t),
+                names[2]: (f"{prefix}.down_proj.weight", t)}
+
+    def stack(leaves: dict, ids, dt=dtype) -> dict:
+        return {name: jnp.asarray(np.stack([
+            (fn or (lambda w: w))(get(f"layers.{i}.{hf}")) for i in ids]), dt)
+            for name, (hf, fn) in leaves.items()}
+
+    n_dense = cfg.first_k_dense
+    routed_ids = range(n_dense, cfg.num_layers)
+    if cfg.is_moe:
+        layers = stack({**attention, "router": ("mlp.gate.weight", t)},
+                       routed_ids)
+        layers.update(stack(
+            {"router_bias": ("mlp.gate.e_score_correction_bias", None)},
+            routed_ids, jnp.float32))
+        for name, hf in (("wg", "gate_proj"), ("wu", "up_proj"),
+                         ("wd", "down_proj")):
+            layers[name] = jnp.asarray(np.stack([np.stack([
+                get(f"layers.{i}.mlp.experts.{e}.{hf}.weight").T
+                for e in range(cfg.num_experts)]) for i in routed_ids]), dtype)
+        if cfg.shared_intermediate_size:
+            layers.update(stack(
+                mlp("mlp.shared_experts", ("ws_g", "ws_u", "ws_d")),
+                routed_ids))
+    else:
+        layers = stack({**attention, **mlp("mlp")}, routed_ids)
+    params: Params = {
+        "embed": jnp.asarray(get("embed_tokens.weight"), dtype),
+        "final_norm": jnp.asarray(get("norm.weight"), dtype),
+        "layers": layers,
+    }
+    if n_dense:
+        params["dense_layers"] = stack({**attention, **mlp("mlp")},
+                                       range(n_dense))
+    if not cfg.tie_word_embeddings:
+        params["lm_head"] = jnp.asarray(
+            _to_numpy(state["lm_head.weight"]).T, dtype)
     return params
 
 

@@ -108,6 +108,11 @@ def quantize_params(params: Params, cfg: ModelConfig) -> Params:
     matmul streams the same int8 table.  Norms and the MoE router stay
     dense (tiny, accuracy-critical).
     """
+    if cfg.is_latent:
+        raise NotImplementedError(
+            "int8 weight quantization of a latent-attention model is not "
+            "built: its tree (wkva, wkvb, shared experts, dense_layers) has "
+            "no contraction table here")
     contract = dict(_CONTRACT)
     if cfg.is_moe:
         contract.update(_CONTRACT_MOE)

@@ -21,6 +21,7 @@ from .paged_attention import (
     paged_decode_attention,
     paged_decode_attention_int8,
     paged_decode_attention_int8_sharded,
+    paged_decode_attention_latent,
     paged_decode_attention_sharded,
     paged_decode_attention_window,
     paged_verify_attention,
@@ -32,6 +33,7 @@ __all__ = [
     "paged_decode_attention",
     "paged_decode_attention_int8",
     "paged_decode_attention_int8_sharded",
+    "paged_decode_attention_latent",
     "paged_decode_attention_sharded",
     "paged_decode_attention_window",
     "paged_prefill_attention",

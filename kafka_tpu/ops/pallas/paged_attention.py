@@ -70,7 +70,7 @@ RING = 3
 
 
 def _attend(q_ref, kbuf, vbuf, m_ref, l_ref, acc_ref, slot, scale,
-            remaining=None, below=None):
+            remaining=None, below=None, latent=False):
     """One online-softmax step of _decode_kernel over ring slot `slot`.
 
     MXU operands are the pool's own rows, with q and the probabilities cast
@@ -79,11 +79,17 @@ def _attend(q_ref, kbuf, vbuf, m_ref, l_ref, acc_ref, slot, scale,
     and the accumulator are f32.  `remaining`: rows from there on are past
     the context and were never DMA'd; `below` (windowed): rows under it are
     under the window.  Both None: the whole step is attended, and no iota,
-    mask or select is built."""
+    mask or select is built.
+
+    `latent` (MLA, absorbed form): kbuf holds the latent rows c~ [rows, r],
+    which are keys AND values, and vbuf the roped key part k_r [rows, lanes];
+    q_ref is [1, Hq, r + lanes] = [q^ | q_rope].  Scores are q^ . c~ +
+    q_rope . k_r, the weighted sum is over the SAME c~ chunk: no value
+    copy exists."""
     rows = kbuf.shape[1]
     dt = jnp.promote_types(q_ref.dtype, kbuf.dtype)
     kc = kbuf[slot].astype(dt)  # [rows, HD]
-    vc = vbuf[slot]
+    vc = kc if latent else vbuf[slot]
     slot_mask = None
     if remaining is not None:
         # local slot index within the step vs remaining valid slots
@@ -105,14 +111,22 @@ def _attend(q_ref, kbuf, vbuf, m_ref, l_ref, acc_ref, slot, scale,
     # so QK^T over the full merged row contracts exactly each head's D lanes
     # — one MXU matmul for all heads, no in-kernel reshape (Mosaic cannot
     # unfold merged lanes).
-    s = (
-        jax.lax.dot_general(
-            q_ref[0].astype(dt), kc,
+    q = q_ref[0].astype(dt)
+    r = kbuf.shape[2]
+    s = jax.lax.dot_general(
+        q[:, :r] if latent else q, kc,
+        dimension_numbers=(((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    )  # [Hq, rows]
+    if latent:
+        # (a never-copied row of k_r or c~ may hold anything: its score is
+        # overwritten by the mask below, as K's always were)
+        s = s + jax.lax.dot_general(
+            q[:, r:], vbuf[slot].astype(dt),
             dimension_numbers=(((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
-        * scale
-    )  # [Hq, rows]
+    s = s * scale
     if slot_mask is not None:
         s = jnp.where(slot_mask, s, NEG_INF)
 
@@ -155,6 +169,7 @@ def _decode_kernel(
     pages_per_chunk: int,
     scale: float,
     window: int | None = None,
+    latent: bool = False,
 ):
     b = pl.program_id(0)
     ps = page_size
@@ -238,12 +253,13 @@ def _decode_kernel(
 
         def whole_step():
             dma(k, "wait", False)
-            _attend(*state)
+            _attend(*state, latent=latent)
 
         def boundary_step():
             dma(k, "wait", True)
             _attend(*state, remaining=n_valid - row0,
-                    below=None if window is None else lo - row0)
+                    below=None if window is None else lo - row0,
+                    latent=latent)
 
         jax.lax.cond(whole, whole_step, boundary_step)
         return carry
@@ -321,6 +337,79 @@ def paged_decode_attention_window(
                          page_size, pages_per_chunk, scale, interpret, window)
 
 
+def _step_pages(P: int, pages_per_chunk: int, page_size: int) -> tuple:
+    """(pages a DMA chunk, pages a softmax step) of _decode_kernel's walk for
+    a page table of width P: whole chunks a step, STEP_ROWS keys if the table
+    names that many."""
+    cp = min(pages_per_chunk, P)
+    return cp, cp * max(1, min(STEP_ROWS // (cp * page_size), -(-P // cp)))
+
+
+@functools.partial(
+    jax.jit,
+    static_argnames=("page_size", "pages_per_chunk", "scale", "interpret"),
+)
+def paged_decode_attention_latent(
+    q_lat: jnp.ndarray,        # [B, Hq, r]  absorbed query q^ = q_nope W_kvb^K
+    q_rope: jnp.ndarray,       # [B, Hq, dr] roped query part
+    c_pool: jnp.ndarray,       # [TOTAL_SLOTS, r] normed latent rows c~
+    r_pool: jnp.ndarray,       # [TOTAL_SLOTS, lanes >= dr] roped k_r, padded
+    page_table: jnp.ndarray,   # [B, P] i32 physical page ids
+    seq_lens: jnp.ndarray,     # [B] i32 tokens already cached (query pos)
+    *,
+    scale: float,              # 1 / sqrt(qk_nope_head_dim + qk_rope_head_dim)
+    page_size: int,
+    pages_per_chunk: int = 8,
+    interpret: bool = False,
+) -> jnp.ndarray:
+    """Decode-step latent (MLA) attention in the absorbed form, straight off
+    the paged pools: per lane Hq query rows against ONE row a token whose r
+    latent lanes are keys and values both, plus the k_r lanes for the rotary
+    part of the score.  _decode_kernel's walk (STEP_ROWS keys a softmax
+    step, RING buffers, masks on boundary steps only); the only value read
+    is the latent chunk the scores already hold.  Returns o^ [B, Hq, r] in
+    q_lat.dtype: the caller applies W_kvb^V.  A kernel name of its own
+    (`paged_decode_attention_latent`), so a device trace tells it from the
+    GQA calls."""
+    B, Hq, r = q_lat.shape
+    lanes = r_pool.shape[1]
+    P = page_table.shape[1]
+    cp, sp = _step_pages(P, pages_per_chunk, page_size)
+    q = jnp.concatenate(
+        [q_lat, jnp.pad(q_rope, ((0, 0), (0, 0), (0, lanes - q_rope.shape[-1])))],
+        axis=-1)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(B,),
+        in_specs=[
+            pl.BlockSpec((1, Hq, r + lanes), lambda b, pt, sl: (b, 0, 0)),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
+        ],
+        out_specs=pl.BlockSpec((1, Hq, r), lambda b, pt, sl: (b, 0, 0)),
+        scratch_shapes=[
+            pltpu.VMEM((RING, sp * page_size, r), c_pool.dtype),
+            pltpu.VMEM((RING, sp * page_size, lanes), r_pool.dtype),
+            pltpu.SemaphoreType.DMA((RING, sp)),
+            pltpu.SemaphoreType.DMA((RING, sp)),
+            pltpu.VMEM((Hq, 1), jnp.float32),
+            pltpu.VMEM((Hq, 1), jnp.float32),
+            pltpu.VMEM((Hq, r), jnp.float32),
+        ],
+    )
+    kernel = functools.partial(
+        _decode_kernel, page_size=page_size, pages_per_chunk=cp,
+        scale=scale, latent=True)
+    return pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, Hq, r), q_lat.dtype),
+        interpret=interpret,
+        name="paged_decode_attention_latent",
+    )(page_table, seq_lens, q, c_pool.reshape(-1, page_size, r),
+      r_pool.reshape(-1, page_size, lanes))
+
+
 def _paged_decode(q, k_pool, v_pool, page_table, seq_lens, page_size,
                   pages_per_chunk, scale, interpret, window):
     B, Hq, D = q.shape
@@ -330,9 +419,7 @@ def _paged_decode(q, k_pool, v_pool, page_table, seq_lens, page_size,
     P = page_table.shape[1]
     if scale is None:
         scale = D**-0.5
-    cp = min(pages_per_chunk, P)
-    # whole chunks a softmax step, no more than the table can name
-    sp = cp * max(1, min(STEP_ROWS // (cp * page_size), -(-P // cp)))
+    cp, sp = _step_pages(P, pages_per_chunk, page_size)
     k_pages = k_pool.reshape(-1, page_size, HD)
     v_pages = v_pool.reshape(-1, page_size, HD)
 

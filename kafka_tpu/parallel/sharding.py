@@ -17,6 +17,9 @@ stacked-layer param tree (models/llama.py):
   KV pool [L, S, Hkv*D] -> kv heads on tp     (each chip caches its heads;
                                                heads are the outer factor of
                                                the merged minor axis)
+  latent-attention models -> everything replicated: the cached row is shared
+                             by all heads (no head split of the pool), and
+                             the engine serves them one device a replica
 
 The leading L axis carries "pp" when a pipeline axis is used (stage split =
 contiguous layer ranges); kept None here — PP slicing happens above these
@@ -52,7 +55,7 @@ Params = Dict[str, Any]
 def _kv_axis(cfg: ModelConfig, mesh: Mesh) -> Optional[str]:
     """kv-head shard axis, or None (replicate) when tp doesn't divide."""
     tp = mesh.shape.get("tp", 1)
-    if tp > 1 and cfg.num_kv_heads % tp == 0:
+    if tp > 1 and cfg.num_kv_heads % tp == 0 and not cfg.is_latent:
         return "tp"
     return None
 
@@ -121,6 +124,12 @@ def shard_params(params: Params, cfg: ModelConfig, mesh: Mesh) -> Params:
     """
     from ..models.quant import QTensor
 
+    if cfg.is_latent:
+        # Served on one device a replica (the engine refuses a tp / ep mesh
+        # over the latent pool): a 1-device mesh pins the replica, and every
+        # leaf of the latent tree ("dense_layers" beside "layers") lands
+        # there whole.
+        return replicate(params, mesh)
     specs = param_specs(cfg, mesh)
 
     def place(x, spec):

@@ -1427,6 +1427,7 @@ class _AggregateMetrics:
             "pages_total": sum(s["engine"]["pages_total"] for s in snaps),
             "pages_free": sum(s["engine"]["pages_free"] for s in snaps),
             "pages_in_use": sum(s["engine"]["pages_in_use"] for s in snaps),
+            "kv_bytes_per_token": snaps[0]["engine"]["kv_bytes_per_token"],
             "prefill_rows_dispatched": sum(
                 s["engine"]["prefill_rows_dispatched"] for s in snaps),
             "prefill_rows_filled": sum(

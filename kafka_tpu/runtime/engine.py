@@ -123,6 +123,19 @@ class WindowedAttentionUnsupported(ValueError):
             f"sliding-window layers: {why}")
 
 
+class LatentAttentionUnsupported(ValueError):
+    """A latent-attention (MLA) model was given an engine option that has
+    no latent form: its pool row is one compressed vector a token shared by
+    all heads, which these paths cannot read.  Raised when the engine is
+    built, naming the option (`.path`)."""
+
+    def __init__(self, path: str, why: str):
+        self.path = path
+        super().__init__(
+            f"{path} has no latent-attention form and this model caches "
+            f"latent rows: {why}")
+
+
 WAITING, PREFILLING, PARKED, ACTIVE, DRAINING, FINISHED = (
     "waiting", "prefilling", "parked", "active", "draining", "finished"
 )
@@ -678,6 +691,32 @@ class InferenceEngine:
             for path, hit, why in refused:
                 if hit:
                     raise WindowedAttentionUnsupported(path, why)
+        if cfg.is_latent:
+            # Every option below reads k/v rows of Hkv*D lanes a head; the
+            # latent pool has none (models/llama.py LatentPathError is the
+            # backstop for direct callers of forward).
+            sharded = mesh is not None and mesh.size > 1
+            refused = (
+                ("speculative verify (paged_verify_attention)",
+                 self.ecfg.speculative_k > 0,
+                 "the K+1-query verify kernel reads per-head K and V rows; "
+                 "set speculative_k=0"),
+                ("kv_quantize int8 pool", bool(self.ecfg.kv_quantize),
+                 "the int8 paged-decode kernel dequantises per-head K and V "
+                 "rows; serve with a dense pool"),
+                ("prefill_ring", sp > 1,
+                 "ring / ulysses prefill shards per-head K and V over the "
+                 "sp axis; use sp=1"),
+                ("pp > 1 (parallel/pipeline.py)", self._pp > 1,
+                 "the stage splitter scans one homogeneous layer body and "
+                 "this model leads with dense layers; use dp"),
+                ("a tp / ep mesh", sharded and self._pp == 1 and sp == 1,
+                 "the latent row is shared by all heads and cannot be split "
+                 "by head; serve each replica on one device (dp)"),
+            )
+            for path, hit, why in refused:
+                if hit:
+                    raise LatentAttentionUnsupported(path, why)
         if self._tq > 1:
             if self._pp > 1:
                 raise ValueError(
@@ -802,7 +841,7 @@ class InferenceEngine:
             # CPU-mesh tests deliberately use tiny unaligned shapes.
             if jax.default_backend() == "tpu":
                 tp = mesh.shape.get("tp", 1)
-                merged_kv = cfg.num_kv_heads * cfg.head_dim
+                merged_kv = cfg.kv_row_widths[0]
                 if (merged_kv // tp) % 128 != 0:
                     raise ValueError(
                         "attention_backend='pallas' needs the per-shard "
@@ -878,6 +917,11 @@ class InferenceEngine:
             self.params = params
             self.k_pool, self.v_pool = k_pool, v_pool
             self._replicated = None
+        # bytes one cached token holds over all layers, as allocated (both
+        # pools, every leaf: int8 scales and lane padding included)
+        self.kv_bytes_per_token = sum(
+            a.nbytes for a in jax.tree.leaves((self.k_pool, self.v_pool))
+        ) // (self.ecfg.num_pages * ps)
         if self.ecfg.num_pages - 1 < self.ecfg.max_pages_per_seq:
             raise ValueError(
                 "num_pages must exceed max_pages_per_seq: a lone sequence "
@@ -1231,7 +1275,7 @@ class InferenceEngine:
             # the flash kernel off QTensor pools).
             if choice != "auto":
                 return choice
-            merged_kv = cfg.num_kv_heads * cfg.head_dim
+            merged_kv = cfg.kv_row_widths[0]
             if mesh is not None and mesh.size > 1:
                 from ..ops.pallas import pallas_mesh_ok
 
@@ -1253,8 +1297,16 @@ class InferenceEngine:
             return "pallas" if ok else "xla"
         if choice != "auto":
             return choice
+        if cfg.is_latent:
+            # the latent decode kernel DMAs pages of both pools' rows; there
+            # is no flash prefill over latent rows, so no VMEM rule
+            return "pallas" if (
+                jax.default_backend() == "tpu"
+                and all(w % 128 == 0 for w in cfg.kv_row_widths)
+                and ecfg.page_size % 16 == 0
+            ) else "xla"
         merged_q = cfg.num_heads * cfg.head_dim
-        merged_kv = cfg.num_kv_heads * cfg.head_dim
+        merged_kv = cfg.kv_row_widths[0]
         if mesh is not None and mesh.size > 1:
             # mesh path: the decode kernel runs per-shard via shard_map
             # (paged_decode_attention_sharded); prefill keeps the XLA

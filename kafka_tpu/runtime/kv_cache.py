@@ -2,9 +2,11 @@
 
 TPU-first replacement for what the reference outsourced entirely (its KV
 state lived inside remote providers).  Here the KV pool is two device arrays
-[L, num_pages * page_size, Hkv*D] (heads merged into the minor axis — the
-lane-tile alignment the Pallas paged kernel's DMAs require; see
-make_kv_pool_arrays); sequences own ordered lists of physical pages.  The
+[L, num_pages * page_size, row] whose row widths `ModelConfig.kv_row_widths`
+defines (GQA: Hkv*D each, heads merged into the minor axis — the lane-tile
+alignment the Pallas paged kernel's DMAs require; latent attention: the
+latent c~ in one, the roped k_r in the other; see make_kv_pool_arrays);
+sequences own ordered lists of physical pages.  The
 host-side allocator is refcounted so pages can be shared between sequences —
 the mechanism behind thread-keyed cache reuse and prefix sharing (BASELINE
 configs 2 and 5).
@@ -192,11 +194,15 @@ def make_kv_pool_arrays(
 ) -> Tuple[Any, Any]:
     """Allocate the device-side K and V pools.
 
-    Layout is [L, TOTAL_SLOTS, Hkv*D] — heads and head_dim merged into the
-    minor (lane) axis.  This keeps the per-slot row a multiple of 128 lanes
-    for real model shapes, which the Pallas paged-decode kernel requires for
-    its page DMAs (Mosaic slices must be lane-tile aligned); the XLA gather
-    path just reshapes gathered rows back to [.., Hkv, D].
+    Layout is [L, TOTAL_SLOTS, row], the two rows' widths from
+    `cfg.kv_row_widths`.  GQA: Hkv*D each — heads and head_dim merged into
+    the minor (lane) axis.  This keeps the per-slot row a multiple of 128
+    lanes for real model shapes, which the Pallas paged-decode kernel
+    requires for its page DMAs (Mosaic slices must be lane-tile aligned); the
+    XLA gather path just reshapes gathered rows back to [.., Hkv, D].  Latent
+    attention: the k pool's row is the latent c~, the v pool's the roped k_r
+    padded to whole lane tiles; the pools differ in width and nothing below
+    the model code may assume otherwise.
 
     quantize="int8" returns each pool as a models.quant.QTensor pytree
     node: int8 slot rows plus a per-(layer, slot) f32 scale ([L, SLOTS, 1]).
@@ -209,24 +215,23 @@ def make_kv_pool_arrays(
     change signature.
     """
     dtype = dtype or cfg.activation_dtype
-    shape = (
-        cfg.num_layers,
-        num_pages * page_size,
-        cfg.num_kv_heads * cfg.head_dim,
-    )
+    lead = (cfg.num_layers, num_pages * page_size)
     if quantize == "int8":
         from ..models.quant import QTensor
 
-        def pool():
+        def pool(width):
             return QTensor(
-                q=jnp.zeros(shape, jnp.int8),
-                s=jnp.zeros((shape[0], shape[1], 1), jnp.float32),
+                q=jnp.zeros(lead + (width,), jnp.int8),
+                s=jnp.zeros(lead + (1,), jnp.float32),
             )
-
-        return pool(), pool()
-    if quantize:
+    elif quantize:
         raise ValueError(f"unknown kv quantize mode {quantize!r}")
-    return jnp.zeros(shape, dtype), jnp.zeros(shape, dtype)
+    else:
+        def pool(width):
+            return jnp.zeros(lead + (width,), dtype)
+
+    k_width, v_width = cfg.kv_row_widths
+    return pool(k_width), pool(v_width)
 
 
 def page_table_array(

@@ -1416,6 +1416,7 @@ class EngineMetrics:
                 "pages_free": engine.pool.free_pages,
                 "pages_in_use": engine.pool.num_pages - 1
                 - engine.pool.free_pages,
+                "kv_bytes_per_token": engine.kv_bytes_per_token,
                 "max_batch": engine.ecfg.max_batch,
                 "attention_backend": engine.cfg.attention_backend,
                 "rtt_est_ms": round(engine._rtt_est * 1e3, 3),

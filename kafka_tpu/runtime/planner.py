@@ -98,6 +98,8 @@ def _kv_shard(cfg: ModelConfig, tp: int, kv_shard: Optional[int] = None) -> int:
     keeps the plain tensor axis (ulysses CP, pp stages) —
     plan_for_serving resolves it via the SAME resolve_tensor_axes call
     the server uses, so plan and placement cannot drift."""
+    if cfg.is_latent:
+        return 1  # one row a token shared by all heads: nothing to split
     if kv_shard is not None:
         return kv_shard
     from ..parallel.mesh import factor_tp_for_kv
@@ -243,6 +245,8 @@ def weight_bytes_per_device(
 
     kv_shard = _kv_shard(cfg, tp, kv_shard)
 
+    if cfg.is_latent:
+        return _latent_weight_bytes(cfg, mat, wb)
     per_layer = (
         mat(h, hq * d, tp)            # wq
         + 2 * mat(h, hkv * d, kv_shard)  # wk, wv
@@ -267,6 +271,34 @@ def weight_bytes_per_device(
     return total
 
 
+def _latent_weight_bytes(cfg: ModelConfig, mat, wb: int) -> int:
+    """Weights of a latent-attention model on its one device (the engine
+    refuses a mesh over the latent pool): models/llama._init_latent_params'
+    tree, leaf by leaf."""
+    h, hq, r = cfg.hidden_size, cfg.num_heads, cfg.kv_lora_rank
+    dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+
+    def mlp(f: int) -> int:
+        return 2 * mat(h, f, 1) + mat(f, h, 1)
+
+    attn = (mat(h, hq * (dn + dr), 1) + mat(h, r + dr, 1)
+            + mat(r, hq * (dn + dv), 1) + mat(hq * dv, h, 1)
+            + (2 * h + r) * wb)
+    if cfg.is_moe:
+        ffn = (h * cfg.num_experts * wb + cfg.num_experts * 4  # router, bias
+               + cfg.num_experts * mlp(cfg.intermediate_size)
+               + (mlp(cfg.shared_intermediate_size)
+                  if cfg.shared_intermediate_size else 0))
+    else:
+        ffn = mlp(cfg.intermediate_size)
+    total = (cfg.num_layers * attn
+             + (cfg.num_layers - cfg.first_k_dense) * ffn
+             + cfg.first_k_dense * mlp(cfg.dense_intermediate_size))
+    total += 2 * cfg.vocab_size * h * wb if not cfg.tie_word_embeddings \
+        else cfg.vocab_size * h * wb
+    return total + h * wb
+
+
 def kv_pool_bytes_per_device(
     cfg: ModelConfig,
     *,
@@ -277,12 +309,12 @@ def kv_pool_bytes_per_device(
     kv_dtype: str = "bfloat16",
     kv_shard: Optional[int] = None,
 ) -> int:
-    """Both pool arrays (k + v), [L/pp, num_pages*page_size, Hkv*D]."""
-    hkv_d = cfg.num_kv_heads * cfg.head_dim
+    """Both pool arrays (k + v), [L/pp, num_pages*page_size, row] with the
+    rows `cfg.kv_row_widths` gives."""
     kv_shard = _kv_shard(cfg, tp, kv_shard)
     slots = num_pages * page_size
-    per = cfg.num_layers // pp * slots * hkv_d // kv_shard
-    b = per * _bytes(kv_dtype) * 2
+    b = sum(cfg.num_layers // pp * slots * w // kv_shard
+            for w in cfg.kv_row_widths) * _bytes(kv_dtype)
     if kv_dtype == "int8":
         # per-slot f32 scales, k and v (int8 KV quantization tier)
         b += cfg.num_layers // pp * slots * 2 * 4
@@ -294,10 +326,9 @@ def kv_bytes_per_token(
     kv_dtype: str = "bfloat16", kv_shard: Optional[int] = None,
 ) -> int:
     kv_shard = _kv_shard(cfg, tp, kv_shard)
-    return (
-        cfg.num_layers // pp
-        * cfg.num_kv_heads * cfg.head_dim // kv_shard
-        * _bytes(kv_dtype) * 2
+    return sum(
+        cfg.num_layers // pp * w // kv_shard * _bytes(kv_dtype)
+        for w in cfg.kv_row_widths
     )
 
 
@@ -321,14 +352,14 @@ def activation_bytes_estimate(
     Decode: B * V * 4 * 3 (logits + top-k sort workspace ~2 copies).
     """
     V, H, F = cfg.vocab_size, cfg.hidden_size, cfg.intermediate_size
-    hkv_d = cfg.num_kv_heads * cfg.head_dim
+    kv_row = sum(cfg.kv_row_widths)  # k + v values of one token, one layer
     s_local = max(1, prefill_bucket // max(sp, 1))
     prefill = (
         s_local * (V // tp) * 4
         + s_local * (H + 2 * F // tp) * 2
-        + window * hkv_d * 2 * 2
+        + window * kv_row * 2
     )
-    decode = max_batch * V * 4 * 3 + max_batch * window * hkv_d * 2 * 2
+    decode = max_batch * V * 4 * 3 + max_batch * window * kv_row * 2
     return max(prefill, decode)
 
 
@@ -534,12 +565,15 @@ def dispatch_cost_model(
     # params from the unsharded bf16 arithmetic (stable vs quantization)
     params_total = weight_bytes_per_device(cfg, tp=1) / wb
     n = max(1, n_devices)
-    kv_row = 2 * cfg.num_layers * cfg.num_kv_heads * cfg.head_dim \
-        * kv_dtype_bytes
+    kv_row = cfg.num_layers * sum(cfg.kv_row_widths) * kv_dtype_bytes
+    # per (query, key) pair and head: 2 flops a value of the score and of
+    # the weighted sum.  GQA: D + D.  Latent, absorbed: the score runs over
+    # the latent and the rotary lanes, the sum over the latent ones.
+    pair = (2 * cfg.kv_lora_rank + cfg.qk_rope_head_dim if cfg.is_latent
+            else 2 * cfg.head_dim)
     return DispatchCostModel(
         flops_per_token=2.0 * params_total / n,
-        attn_flops_per_kv=4.0 * cfg.num_layers * cfg.num_heads
-        * cfg.head_dim / n,
+        attn_flops_per_kv=2.0 * cfg.num_layers * cfg.num_heads * pair / n,
         weight_bytes=int(weight_bytes_total // n),
         kv_bytes_per_token=int(kv_row * max(1, kv_replication) // n),
     )

@@ -1328,6 +1328,7 @@ async def health(request: web.Request) -> web.Response:
                     "num_heads": mc.num_heads,
                     "num_kv_heads": mc.num_kv_heads,
                     "head_dim": mc.head_dim,
+                    "kv_row_widths": list(mc.kv_row_widths),
                     "intermediate_size": mc.intermediate_size,
                     "vocab_size": mc.vocab_size,
                     "dtype": mc.dtype,

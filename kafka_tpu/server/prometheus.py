@@ -483,6 +483,12 @@ def render_prometheus(snap: Dict[str, Any]) -> str:
             if key in engine:
                 w.sample("kafka_tpu_kv_pages", engine[key],
                          {"state": label})
+        if "kv_bytes_per_token" in engine:
+            w.family("kafka_tpu_kv_bytes_per_token", "gauge",
+                     "Bytes one cached token holds in the KV pool, all "
+                     "layers, as allocated.")
+            w.sample("kafka_tpu_kv_bytes_per_token",
+                     engine["kv_bytes_per_token"])
         if "rtt_est_ms" in engine:
             w.family("kafka_tpu_device_rtt_milliseconds", "gauge",
                      "Estimated device-to-host fetch round trip.")

@@ -156,6 +156,10 @@ DEVICE_SCOPES = (
                     # SLIDING-WINDOW layer (every path), so device time
                     # splits by kind of layer; global layers stay directly
                     # under attn_core
+    "attn_latent_proj",  # inside attn_core, latent attention (MLA) only:
+                    # what the latent form adds around attention proper (the
+                    # absorb q^ and un-absorb W_kvb^V einsums in decode, the
+                    # expansion of cached rows through W_kvb elsewhere)
     "attn_gather",  # XLA paths only, inside attn_core: the page/slot
                     # gather that materialises the attention window
     "attn_out",     # output projection + residual add
@@ -163,6 +167,7 @@ DEVICE_SCOPES = (
     "mlp",          # dense SwiGLU MLP + residual add
     "moe_router",   # router logits, top-k, routing weights
     "moe_experts",  # expert matmuls, combine + residual add
+    "moe_shared",   # the always-on shared experts beside the routed ones
     "head",         # final RMSNorm + logits
     "sample",       # last-position select, per-(seed, position) keys,
                     # sample_tokens_per_slot (engine step programs)
