@@ -72,7 +72,7 @@ import jax
 import jax.numpy as jnp
 
 from ..models.config import GLOBAL, ModelConfig, UnsupportedConfigError
-from ..ops.attention import NEG_INF, causal_attention
+from ..ops.attention import NEG_INF, causal_attention, paged_decode_walk
 from ..ops.norms import rms_norm
 from ..ops.rope import (
     apply_rope,
@@ -134,13 +134,17 @@ def _kv_read(cache, idx, dtype) -> jnp.ndarray:
 @jax.named_scope("attn_gather")
 def _kv_read_pages(cache, page_table: jnp.ndarray, page_size: int,
                    dtype) -> jnp.ndarray:
-    """Gather a [B, C, Hkv*D] window by PAGE rather than by slot.
+    """Gather the rows of `page_table`'s pages, [B, P * page_size, Hkv*D],
+    by PAGE rather than by slot.
 
     The slot-granular gather moves B*C separate ~1 KB rows — descriptor-
     bound on TPU (measured: the b32 XLA decode path ran at half the
     Pallas kernel's rate with the KV bytes nowhere near the roofline).
     Page-granular gathering moves B*P contiguous page_size-row blocks,
-    16x fewer descriptors at page_size 16.  page_table: [B, P]."""
+    16x fewer descriptors at page_size 16.  page_table: [B, P]: a lane's
+    whole table (the static window: prefill chunks and verify, s > 1), or
+    the columns of one chunk of the decode walk (`_decode_walk`), which
+    never gathers the window."""
     ps = page_size
     lead = page_table.shape[:-1]
     if isinstance(cache, QTensor):
@@ -185,9 +189,14 @@ class PagedView(NamedTuple):
     read_idx:     [B, C]  flat slots forming each sequence's attention window
     kv_positions: [B, C]  absolute position of each window slot
     kv_valid:     [B, C]  False for unallocated/beyond-length slots
-    page_table:   [B, P]  physical page ids (pallas decode backend only)
-    seq_lens:     [B]     cached token counts (pallas decode backend only)
-    page_size:    static int (pallas decode backend only)
+    page_table:   [B, P]  physical page ids
+    seq_lens:     [B]     cached token counts (decode and verify plans)
+    page_size:    static int
+    The last three reach both backends: the Pallas kernels and the XLA
+    decode walk (`_decode_walk`) address the pool by page and bound their
+    reads by seq_lens; the XLA read at s > 1 gathers by page and masks
+    with kv_positions / kv_valid.  A view without a page table (pp) falls
+    back to the slot gather over read_idx.
     """
 
     write_idx: jnp.ndarray
@@ -592,9 +601,17 @@ def _attention_core(q, k, v, cfg, positions, k_cache, v_cache, kv_valid,
                 mesh, q, k, v, positions,
                 k_win, v_win, paged.kv_positions, ctx_valid,
             )
+        elif (
+            s == 1
+            and paged.seq_lens is not None
+            and paged.page_table is not None
+            and paged.page_size is not None
+        ):
+            out = _decode_walk(q, k_cache, v_cache, paged, hkv, window, mesh)
         elif paged.page_table is not None and paged.page_size is not None:
-            # page-granular window gather (see _kv_read_pages: the
-            # slot-granular form is descriptor-bound)
+            # s > 1 (prefill chunks, verify): page-granular gather of the
+            # static window (see _kv_read_pages: the slot-granular form is
+            # descriptor-bound), attended in one shot
             k_win = _kv_read_pages(
                 k_cache, paged.page_table, paged.page_size, dt
             ).reshape(b, -1, hkv, d)
@@ -650,6 +667,28 @@ def _attention_core(q, k, v, cfg, positions, k_cache, v_cache, kv_valid,
             window=window,
         )
     return out, k_cache, v_cache
+
+
+def _decode_walk(q, k_cache, v_cache, paged: PagedView, hkv: int,
+                 window: Optional[int], mesh) -> jnp.ndarray:
+    """The XLA decode read (s == 1, page table present): walk each lane's
+    live context chunk by chunk in the pool's own [.., Hkv*D] rows
+    (ops/attention.py paged_decode_walk) rather than gather its static
+    window and re-lay it out by head.  A lane is active iff its position 0
+    is valid (decode_plan folds activity into kv_valid).  On a mesh of
+    more than one device heads stay a batch dimension of the contraction.
+    q [B, 1, Hq, D] -> [B, 1, Hq, D]."""
+    ps, dt = paged.page_size, q.dtype
+
+    def read_pages(pages):
+        return (_kv_read_pages(k_cache, pages, ps, dt),
+                _kv_read_pages(v_cache, pages, ps, dt))
+
+    return paged_decode_walk(
+        q[:, 0], read_pages, paged.page_table, paged.seq_lens,
+        paged.kv_valid[:, 0], page_size=ps, num_kv_heads=hkv, window=window,
+        heads_batched=mesh is not None and mesh.size > 1,
+    )[:, None]
 
 
 class LatentPathError(NotImplementedError):

@@ -6,6 +6,14 @@ The Pallas kernels in ops/pallas/ override them on TPU for the flash
 (prefill) and paged (decode) paths; this module is the numerics ground truth
 those kernels are tested against.
 
+Two forms live here.  `causal_attention` attends a materialised K/V window
+in one shot: prefill chunks, speculative verify, the contiguous cache.
+`paged_decode_walk` is the decode read of a paged pool (one query a lane):
+it never builds a lane's static window but walks the live context in chunks
+of `DECODE_WALK_KEYS` keys, folding each into a running softmax, and on one
+device contracts a chunk on the pool row's merged Hkv*D axis so that no
+K/V is re-laid out between the page gather and the matmuls.
+
 Layout convention throughout the framework: activations are
 [batch, seq, heads, head_dim] ("BSHD") — the layout that shards naturally
 over a ("dp", "tp") mesh with heads on "tp".
@@ -13,13 +21,20 @@ over a ("dp", "tp") mesh with heads on "tp".
 
 from __future__ import annotations
 
-from typing import Optional
+from typing import Callable, Optional, Tuple
 
 import jax.numpy as jnp
 import jax
 
 
 NEG_INF = -1e30  # large-negative mask value; -inf breaks softmax when a row is fully masked
+
+# Keys one trip of `paged_decode_walk` attends.  Chosen on the chip at
+# Mixtral's geometry (scripts/paged_decode_bench.py --backend xla; PERF.md
+# section 6, PR 32): 1.03 ms a call at 512 against 1.11 at 256, 1.36 at
+# 1,024 and 1.50 at 2,048; up to 2,048 XLA keeps the gathered chunk in
+# VMEM, at 4,096 it goes through HBM (3.49 ms).
+DECODE_WALK_KEYS = 512
 
 
 def repeat_kv(x: jnp.ndarray, n_rep: int) -> jnp.ndarray:
@@ -90,3 +105,128 @@ def causal_attention(
         preferred_element_type=jnp.float32,
     )
     return out.reshape(b, sq, hq, d).astype(q.dtype)
+
+
+def decode_walk_pages(max_pages: int, page_size: int) -> int:
+    """Pages a trip of `paged_decode_walk` gathers per lane: DECODE_WALK_KEYS
+    keys' worth, clamped to the page table's width (tiny test geometries)."""
+    return max(1, min(DECODE_WALK_KEYS // page_size, max_pages))
+
+
+def decode_walk_trips(seq_lens: jnp.ndarray, active: jnp.ndarray,
+                      chunk_keys: int) -> jnp.ndarray:
+    """Chunks `paged_decode_walk` visits: enough for the longest ACTIVE
+    lane's seq_lens + 1 keys (its own new row included); 0 with no lane
+    active.  The engine counts the same bound on the host
+    (StepPrograms.decode_keys)."""
+    n_keys = jnp.max(jnp.where(active, seq_lens + 1, 0))
+    return (n_keys + chunk_keys - 1) // chunk_keys
+
+
+def paged_decode_walk(
+    q: jnp.ndarray,
+    read_pages: Callable[[jnp.ndarray], Tuple[jnp.ndarray, jnp.ndarray]],
+    page_table: jnp.ndarray,
+    seq_lens: jnp.ndarray,
+    active: jnp.ndarray,
+    *,
+    page_size: int,
+    num_kv_heads: int,
+    window: Optional[int] = None,
+    heads_batched: bool = False,
+) -> jnp.ndarray:
+    """Decode attention over a paged pool without materialising the window.
+
+    q: [B, Hq, D], one query a lane at position seq_lens[b] (its own K/V row
+    already in the pool).  page_table [B, P], seq_lens [B], active [B] bool.
+    read_pages(pages [B, cp]) -> (k, v), each [B, cp * page_size, Hkv*D]:
+    the pool rows of those pages in the activation dtype, heads merged in
+    the minor axis as the pool stores them.
+
+    A loop whose trip count is computed on the device,
+    ceil(max over active lanes of (seq_lens + 1) / chunk keys), slices the
+    page table chunk by chunk, gathers that chunk's pages and folds it into
+    a running max / sum / accumulator in f32 (the online softmax of the
+    Pallas kernels' walk; probabilities are cast to the value dtype before
+    the weighted sum, as `causal_attention` does).  A lane attends
+    positions <= seq_lens (and > seq_lens - window in a sliding-window
+    layer) if active; a lane with nothing to attend returns zeros.
+
+    The contraction adapts to where heads live.  On one device
+    (`heads_batched` False) q is expanded block-diagonally to
+    [B, Hq, Hkv*D] (zeros in the other kv heads' lanes) and both matmuls
+    contract / produce the merged axis: Hkv x the useful FLOPs of a
+    bandwidth-bound read, and no transpose of K or V.  On a mesh heads are
+    the sharded axis and stay a batch dimension of the grouped GQA einsum
+    (a merged-axis contraction would all-reduce over tp).
+    Returns [B, Hq, D] in q's dtype.
+    """
+    b, hq, d = q.shape
+    hkv = num_kv_heads
+    g = hq // hkv
+    scale = d**-0.5
+    cp = decode_walk_pages(page_table.shape[1], page_size)
+    ck = cp * page_size
+    # whole chunks: the padding names the trash page, and its positions lie
+    # past every seq_len, so the mask drops them
+    page_table = jnp.pad(page_table, ((0, 0), (0, -page_table.shape[1] % cp)))
+    n_chunks = page_table.shape[1] // cp
+    trips = jnp.minimum(decode_walk_trips(seq_lens, active, ck), n_chunks)
+
+    if heads_batched:
+        qe = q.reshape(b, hkv, g, d)
+
+        def scores(k):
+            return jnp.einsum("bhgd,bkhd->bhgk", qe, k.reshape(b, ck, hkv, d),
+                              preferred_element_type=jnp.float32)
+
+        def weighted(p, v):
+            return jnp.einsum("bhgk,bkhd->bhgd", p, v.reshape(b, ck, hkv, d),
+                              preferred_element_type=jnp.float32)
+
+        lead, acc_shape = (b, hkv, g), (b, hkv, g, d)
+    else:
+        own = jnp.arange(hq)[:, None] // g == jnp.arange(hkv)[None, :]
+        qe = jnp.where(own[None, :, :, None], q[:, :, None, :], 0).reshape(
+            b, hq, hkv * d)
+
+        def scores(k):
+            return jnp.einsum("bnh,bkh->bnk", qe, k,
+                              preferred_element_type=jnp.float32)
+
+        def weighted(p, v):
+            return jnp.einsum("bnk,bkh->bnh", p, v,
+                              preferred_element_type=jnp.float32)
+
+        lead, acc_shape = (b, hq), (b, hq, hkv * d)
+    expand = (slice(None),) + (None,) * (len(lead) - 1)
+
+    def fold(c, carry):
+        m, l, acc = carry
+        k, v = read_pages(
+            jax.lax.dynamic_slice_in_dim(page_table, c * cp, cp, axis=1))
+        pos = c * ck + jnp.arange(ck)[None, :]
+        mask = (pos <= seq_lens[:, None]) & active[:, None]
+        if window is not None:
+            mask = mask & (pos > seq_lens[:, None] - window)
+        mask = mask[expand]
+        s = jnp.where(mask, scores(k) * scale, NEG_INF)
+        m_new = jnp.maximum(m, jnp.max(s, axis=-1))
+        alpha = jnp.exp(m - m_new)
+        p = jnp.where(mask, jnp.exp(s - m_new[..., None]), 0.0)
+        l = alpha * l + jnp.sum(p, axis=-1)
+        acc = alpha[..., None] * acc + weighted(p.astype(v.dtype), v)
+        return m_new, l, acc
+
+    _, l, acc = jax.lax.fori_loop(
+        0, trips, fold,
+        (jnp.full(lead, NEG_INF, jnp.float32), jnp.zeros(lead, jnp.float32),
+         jnp.zeros(acc_shape, jnp.float32)))
+    out = acc / jnp.maximum(l, 1e-30)[..., None]
+    if not heads_batched:
+        # each head's own D lanes of the merged accumulator (the others hold
+        # its probabilities against other kv heads' values)
+        out = jnp.sum(
+            jnp.where(own[None, :, :, None], out.reshape(b, hq, hkv, d), 0.0),
+            axis=2)
+    return out.reshape(b, hq, d).astype(q.dtype)

@@ -1432,6 +1432,10 @@ class _AggregateMetrics:
                 s["engine"]["prefill_rows_dispatched"] for s in snaps),
             "prefill_rows_filled": sum(
                 s["engine"]["prefill_rows_filled"] for s in snaps),
+            "decode_keys_walked": sum(
+                s["engine"]["decode_keys_walked"] for s in snaps),
+            "decode_keys_window": sum(
+                s["engine"]["decode_keys_window"] for s in snaps),
         }
         if all("prefix_cache" in s for s in snaps):
             agg["prefix_cache"] = {

@@ -1203,6 +1203,13 @@ class InferenceEngine:
         # (static shapes) computes.
         self.prefill_rows_dispatched = 0
         self.prefill_rows_filled = 0
+        # Monotonic, and 0 wherever decode does not run the XLA walk
+        # (StepPrograms.decode_keys): the keys every decode step gathered
+        # a layer (lanes x chunks x chunk keys, per step of a fused
+        # dispatch) and the keys of the static windows it no longer
+        # gathers (lanes x max_pages_per_seq x page_size).
+        self.decode_keys_walked = 0
+        self.decode_keys_window = 0
         self._rtt_est = self._measure_rtt()
 
     def kv_window_dead_share(self) -> float:
@@ -3839,6 +3846,11 @@ class InferenceEngine:
         """
         toks.copy_to_host_async()
         self._step_count += steps
+        walked, window = self._programs.decode_keys(
+            max((m.seq.length for m in members if m is not None), default=0),
+            steps)
+        self.decode_keys_walked += walked
+        self.decode_keys_window += window
         # decode-span inputs, computed lazily on the FIRST traced member:
         # an all-untraced dispatch pays one branch per lane, nothing else
         now_mono: Optional[float] = None

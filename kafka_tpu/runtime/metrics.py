@@ -1424,6 +1424,8 @@ class EngineMetrics:
                     engine.kv_window_dead_share(), 6),
                 "prefill_rows_dispatched": engine.prefill_rows_dispatched,
                 "prefill_rows_filled": engine.prefill_rows_filled,
+                "decode_keys_walked": engine.decode_keys_walked,
+                "decode_keys_window": engine.decode_keys_window,
             }
             if engine.prefix_cache is not None:
                 pc = engine.prefix_cache

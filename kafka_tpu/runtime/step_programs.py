@@ -27,6 +27,7 @@ import jax.numpy as jnp
 
 from ..models.config import ModelConfig
 from ..models.llama import KVCache, PagedView, forward
+from ..ops.attention import decode_walk_pages
 from ..ops.sampling import (
     SamplingParams,
     grammar_advance,
@@ -464,6 +465,23 @@ class StepPrograms:
 
     def _geometry(self) -> Tuple:
         return self.cfg, self.ps, self.P * self.ps, self.B, self.mesh
+
+    def decode_keys(self, max_len: int, steps: int) -> Tuple[int, int]:
+        """(keys walked, keys of the static windows) over the B lanes of
+        `steps` decode steps whose longest active lane holds `max_len`
+        tokens before the first: what the XLA decode walk
+        (ops/attention.py paged_decode_walk) gathers a layer, by the bound
+        its device loop computes, beside lanes x max_pages_per_seq x
+        page_size.  (0, 0) where decode does not walk in XLA: a Pallas
+        kernel, a latent model's own read, or pp (no page table in the
+        view)."""
+        mesh = self.mesh
+        if (self.cfg.attention_backend != "xla" or self.cfg.is_latent
+                or (mesh is not None and mesh.shape.get("pp", 1) > 1)):
+            return 0, 0
+        ck = decode_walk_pages(self.P, self.ps) * self.ps
+        chunks = sum(-(-(max_len + i + 1) // ck) for i in range(steps))
+        return self.B * chunks * ck, self.B * steps * self.P * self.ps
 
     def decode(self, fsm: Optional[Fsm] = None):
         """One token for every active lane: fn(params, k_pool, v_pool,

@@ -5,7 +5,7 @@
     python scripts/lowered_programs.py diff A B
 
 `dump` drives an engine per tiny preset (`tiny-gqa`, `tiny-moe`, the tiny
-Mellum2 under benchmarks/tests/mellum2) and attention backend (`xla`,
+Mellum2 and Kanana-2 under benchmarks/tests/) and attention backend (`xla`,
 `pallas`, which lowers in interpret mode off the chip) through single and
 batched prefill, the decode step (plain, host-masked, forced tokens), the
 fused multi-step scan, speculative verify (not on the windowed preset, which
@@ -46,9 +46,12 @@ def _presets():
 
     mellum = config_from_hf_json(
         "benchmarks/tests/mellum2/configs/tiny-mellum2.json")
+    kanana = config_from_hf_json(
+        "benchmarks/tests/kanana2/configs/tiny-kanana2.json")
     for name, cfg in (("tiny-gqa", get_config("tiny-gqa")),
                       ("tiny-moe", get_config("tiny-moe")),
-                      ("tiny-mellum2", mellum)):
+                      ("tiny-mellum2", mellum),
+                      ("tiny-kanana2", kanana)):
         for backend in ("xla", "pallas"):
             yield name, backend, dataclasses.replace(
                 cfg, attention_backend=backend)
@@ -66,8 +69,12 @@ def _engine(cfg):
         EngineConfig(max_batch=4, page_size=8, num_pages=96,
                      max_pages_per_seq=16, prefill_buckets=(8, 16),
                      multi_step=4,
-                     # a windowed model refuses speculative verify
-                     speculative_k=0 if cfg.is_windowed else 2),
+                     # "auto" resolves to xla off the chip whatever the
+                     # model config says: name the preset's backend
+                     attention_backend=cfg.attention_backend,
+                     # windowed and latent models refuse speculative verify
+                     speculative_k=0 if cfg.is_windowed or cfg.is_latent
+                     else 2),
         kv_dtype=jnp.float32)
 
 

@@ -6,6 +6,7 @@ out (DMA starts, waits and the loop only).
     python scripts/paged_decode_bench.py                  # Yi's geometry
     python scripts/paged_decode_bench.py --window 0       # global form only
     python scripts/paged_decode_bench.py --rehearse       # CPU, tiny, no times
+    python scripts/paged_decode_bench.py --backend xla    # the XLA decode read
 
 Through the chip tool, from the repo root.  Defaults are the registered
 `chat-decode` cells' geometry (PERF.md section 4): 16 lanes, 32/4 x 128,
@@ -16,6 +17,15 @@ the dispatch).  A chunk is `pages_per_chunk` (8) pages, the unit
 `decode_chunk_range` counts and the rooflines of `benchmarks/` charge;
 bytes are those chunks' K and V rows.  Prints one JSON line a form and writes
 them all to chiprun_out/paged_decode_bench.json.
+
+`--backend xla`: the XLA decode read alone (models/llama.py `_decode_walk`)
+at Mixtral's geometry (32/8 x 128, the rest as above), once for each
+candidate of `ops.attention.DECODE_WALK_KEYS` (--walk-keys), beside the read
+it replaced: the gather of every lane's static `max_pages` window and
+`causal_attention` over it.  Times are each jitted form's module events in
+one capture, with its costliest ops named (a `copy` of a window- or
+chunk-shaped K/V would head the list); bytes are the walked keys' K and V
+rows, read once.  Writes chiprun_out/paged_decode_bench_xla.json.
 """
 
 from __future__ import annotations
@@ -91,6 +101,144 @@ def forms(args, jax, pa):
     return out
 
 
+def xla_forms(args, jax, jnp):
+    """{name: jitted fn(q, k, v, table, lens) -> [B, Hq, D]}: the static
+    window read, then the walk at each candidate chunk size."""
+    from kafka_tpu.models import llama
+    from kafka_tpu.ops import attention
+    from kafka_tpu.runtime.step_programs import decode_plan
+
+    ps, hkv, d = args.page_size, args.kv_heads, args.head_dim
+
+    def plan(table, lens):
+        return decode_plan(table, lens, jnp.ones(lens.shape, bool), ps)
+
+    def window_read(q, k, v, table, lens):
+        positions, paged = plan(table, lens)
+        b = q.shape[0]
+        return attention.causal_attention(
+            q[:, None],
+            llama._kv_read_pages(k, table, ps, q.dtype).reshape(b, -1, hkv, d),
+            llama._kv_read_pages(v, table, ps, q.dtype).reshape(b, -1, hkv, d),
+            q_positions=positions, kv_positions=paged.kv_positions,
+            kv_valid=paged.kv_valid)[:, 0]
+
+    def walk(keys):
+        def fn(q, k, v, table, lens):
+            _, paged = plan(table, lens)
+            installed = attention.DECODE_WALK_KEYS
+            attention.DECODE_WALK_KEYS = keys  # read when the walk is traced
+            try:
+                return llama._decode_walk(
+                    q[:, None], k, v, paged, hkv, None, None)[:, 0]
+            finally:
+                attention.DECODE_WALK_KEYS = installed
+        return fn
+
+    out = {"bench_xla_window": window_read}
+    for keys in args.walk_keys:
+        out[f"bench_xla_walk_{keys}"] = walk(keys)
+    for name, fn in out.items():
+        fn.__name__ = name
+        out[name] = jax.jit(fn)
+    return out
+
+
+def module_events(trace_dir, names):
+    """{name: ([device ns of each launch of jit_<name>], {op: total ns})}
+    from the capture's `XLA Modules` and `XLA Ops` lines."""
+    from jax.profiler import ProfileData
+
+    path = sorted(glob.glob(os.path.join(
+        trace_dir, "plugins", "profile", "*", "*.xplane.pb")))[-1]
+    launches = {n: [] for n in names}
+    ops = []
+    for plane in ProfileData.from_file(path).planes:
+        if not re.match(r"^/device:TPU:\d+$", plane.name):
+            continue
+        for line in plane.lines:
+            if line.name == "XLA Modules":
+                for ev in line.events:
+                    m = re.match(r"jit_(\w+)", ev.name)
+                    if m and m.group(1) in launches:
+                        launches[m.group(1)].append(
+                            (ev.start_ns, ev.duration_ns))
+            elif line.name == "XLA Ops":
+                ops += [(ev.start_ns, ev.duration_ns, ev.name)
+                        for ev in line.events]
+    out = {}
+    for name, spans in launches.items():
+        by_op = {}
+        for t0, dur in spans:
+            for s0, d, op in ops:
+                # "%copy.3 = bf16[32768,8,8,128]{...} copy(...)": name, shape
+                m = re.match(r"%?([\w.\-]+) = \(?(\w+\[[\d,]*\])", op)
+                op = " ".join(m.groups()) if m else op[:80]
+                if t0 <= s0 < t0 + dur and not op.startswith("while"):
+                    by_op[op] = by_op.get(op, 0) + d
+        out[name] = ([d for _, d in sorted(spans)], by_op)
+    return out
+
+
+def bench_xla(args, jax, jnp) -> int:
+    on_chip = jax.default_backend() == "tpu"
+    case, lens = make_case(args, jnp)
+    fns = xla_forms(args, jax, jnp)
+    outs = {n: np.asarray(fn(*case), np.float32) for n, fn in fns.items()}
+    ref = outs["bench_xla_window"]
+    tol = 1e-5 if args.dtype == "float32" else 2e-2
+    for name, out in outs.items():
+        assert np.isfinite(out).all(), name
+        err = float(np.abs(out - ref).max())
+        assert err <= tol, (name, err)
+    if not on_chip:
+        print(json.dumps({"rehearsed": sorted(fns), "device": "cpu"}))
+        return 0
+    trace_dir = tempfile.mkdtemp(prefix="paged_decode_bench_")
+    with jax.profiler.trace(trace_dir):
+        for _ in range(args.reps):
+            for fn in fns.values():
+                fn(*case).block_until_ready()
+    events = module_events(trace_dir, list(fns))
+    from kafka_tpu.ops.attention import DECODE_WALK_KEYS
+    from kafka_tpu.runtime.planner import device_peaks
+
+    _, hbm_bytes_per_s, _ = device_peaks(jax.devices()[0])  # unknown: raises
+    row_bytes = args.kv_heads * args.head_dim * jnp.dtype(args.dtype).itemsize
+    window_keys = args.max_pages * args.page_size
+    result = {"device": jax.devices()[0].device_kind, "args": vars(args),
+              "contexts": [int(n) for n in lens],
+              "installed_walk_keys": DECODE_WALK_KEYS, "forms": {}}
+    for name in fns:
+        durs, by_op = events[name]
+        if len(durs) != args.reps:
+            print(f"{len(durs)} launches of {name} in the capture, expected "
+                  f"{args.reps}", file=sys.stderr)
+            return 1
+        keys = window_keys
+        if "walk" in name:
+            ck = min(int(name.rsplit("_", 1)[1]), window_keys)
+            keys = -(-(int(lens.max()) + 1) // ck) * ck
+        us = float(np.median(durs)) / 1e3
+        kv_bytes = 2 * args.lanes * keys * row_bytes  # K and V, read once
+        row = {
+            "calls": len(durs), "us_per_call": us,
+            "min_us": min(durs) / 1e3, "max_us": max(durs) / 1e3,
+            "keys_per_lane": keys, "kv_bytes": kv_bytes,
+            "max_abs_diff_vs_window": float(np.abs(outs[name] - ref).max()),
+            "hbm_share": 100.0 * kv_bytes / hbm_bytes_per_s / (us / 1e6),
+            "top_ops_us_per_call": {
+                op: ns / 1e3 / len(durs) for op, ns in sorted(
+                    by_op.items(), key=lambda kv: -kv[1])[:8]},
+        }
+        result["forms"][name] = row
+        print(json.dumps({"form": name, **row}))
+    os.makedirs("chiprun_out", exist_ok=True)
+    with open("chiprun_out/paged_decode_bench_xla.json", "w") as f:
+        json.dump(result, f, indent=1)
+    return 0
+
+
 def kernel_events(trace_dir):
     """Device ns of every Pallas call in the capture, in launch order."""
     from jax.profiler import ProfileData
@@ -112,7 +260,13 @@ def main() -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n")[0])
     ap.add_argument("--lanes", type=int, default=16)
     ap.add_argument("--heads", type=int, default=32)
-    ap.add_argument("--kv-heads", type=int, default=4)
+    ap.add_argument("--backend", choices=("pallas", "xla"), default="pallas",
+                    help="xla: the XLA decode read at Mixtral's geometry")
+    ap.add_argument("--walk-keys", type=int, nargs="+",
+                    default=[512, 1024, 2048],
+                    help="--backend xla: candidate chunk sizes of the walk")
+    ap.add_argument("--kv-heads", type=int, default=None,
+                    help="default 4 (Yi), 8 under --backend xla (Mixtral)")
     ap.add_argument("--head-dim", type=int, default=128)
     ap.add_argument("--page-size", type=int, default=16)
     ap.add_argument("--num-pages", type=int, default=5120)
@@ -129,11 +283,14 @@ def main() -> int:
     ap.add_argument("--rehearse", action="store_true",
                     help="tiny geometry, any backend, checks only")
     args = ap.parse_args()
+    if args.kv_heads is None:
+        args.kv_heads = 8 if args.backend == "xla" else 4
     if args.rehearse:
         args.lanes, args.heads, args.kv_heads, args.head_dim = 3, 8, 2, 16
         args.page_size, args.num_pages, args.max_pages = 4, 400, 160
         args.min_len, args.max_len, args.shared_prefix = 300, 600, 280
         args.window = args.window and 100
+        args.walk_keys = [32, 64, 256]
 
     import jax
     import jax.numpy as jnp
@@ -146,6 +303,8 @@ def main() -> int:
         print("no TPU: a device time comes only from the chip "
               "(--rehearse checks the command here)", file=sys.stderr)
         return 3
+    if args.backend == "xla":
+        return bench_xla(args, jax, jnp)
     case, lens = make_case(args, jnp)
     fns = forms(args, jax, pa)
     outs = {n: np.asarray(fn(*case), np.float32) for n, (fn, _) in fns.items()}

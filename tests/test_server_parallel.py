@@ -82,6 +82,9 @@ class TestDPServing:
                         for r in m["replicas"]]
                 assert m["engine"]["prefill_rows_dispatched"] == sum(rows) > 0
                 assert 0 < m["engine"]["prefill_rows_filled"] < sum(rows)
+                for key in ("decode_keys_walked", "decode_keys_window"):
+                    keys = [r["engine"][key] for r in m["replicas"]]
+                    assert m["engine"][key] == sum(keys) > 0
                 # pooled latency percentiles, not zeroed placeholders
                 assert m["ttft_ms"]["p50"] > 0
 
