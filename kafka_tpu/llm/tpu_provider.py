@@ -27,7 +27,7 @@ import itertools
 import json
 import logging
 import time
-from typing import Any, AsyncIterator, Dict, List, Optional, Sequence
+from typing import Any, AsyncIterator, Dict, List, Optional, Sequence, Tuple
 
 from ..core.types import (
     CompletionResponse,
@@ -125,9 +125,14 @@ class TPULLMProvider(LLMProvider):
         model_name: str = "llama",
         worker: Optional[EngineWorker] = None,
         vision_params: Any = None,
+        ignore_eos: bool = False,
     ):
         self.engine = engine
         self.tokenizer = tokenizer
+        # ServingConfig.ignore_eos: no request of this provider stops at a
+        # stop token
+        self.stop_token_ids: Tuple[int, ...] = (
+            () if ignore_eos else tuple(tokenizer.stop_ids))
         self.model_name = model_name
         self.worker = worker or EngineWorker(engine)
         self.worker.start()
@@ -1032,7 +1037,7 @@ class TPULLMProvider(LLMProvider):
             top_k=top_k,
             top_p=top_p,
             seed=seed if seed is not None else 0,
-            stop_token_ids=tuple(self.tokenizer.stop_ids),
+            stop_token_ids=self.stop_token_ids,
             logits_mask_fn=logits_mask_fn,
             grammar=grammar,
             prefix_key=prefix_key,

@@ -5,6 +5,11 @@ pattern of windowed and global attention with rotary parameters per kind
 `deepseek_v3`-style decoders: latent (MLA) attention whose cache row is one
 compressed vector a token, leading dense layers ahead of routed ones, and
 sigmoid-scored routing with a selection bias beside always-on shared experts.
+A latent decoder may also mix KINDS of layer whose attention blocks have
+sizes of their own (`dots3_note`: full layers that attend a learned top-k
+selection of keys, chosen by an indexer with a cache row of its own, beside
+sliding-window latent layers of another geometry), and hold a share of the
+routed experts (one chip's part of an expert-parallel layer).
 
 The reference service routed model names to remote providers by string
 heuristics (src/llm/utils.py:11-29); here a model name resolves to a local
@@ -47,6 +52,25 @@ class RopeParams:
     beta_fast: float = 32.0
     beta_slow: float = 1.0
     attention_factor: Optional[float] = None
+
+
+@dataclasses.dataclass(frozen=True)
+class LatentGeometry:
+    """The sizes of ONE kind of latent-attention layer (the published
+    `swa_*` keys give a sliding-window layer its own).  `q_lora_rank` 0 = no
+    query low-rank: q = x W_q."""
+
+    num_heads: int
+    q_lora_rank: int
+    kv_lora_rank: int
+    qk_nope_head_dim: int
+    qk_rope_head_dim: int
+    v_head_dim: int
+
+
+def _lane_tiles(width: int) -> int:
+    """`width` values padded to whole 128-lane tiles (Pallas page DMAs)."""
+    return -(-width // 128) * 128
 
 
 @dataclasses.dataclass(frozen=True)
@@ -138,16 +162,62 @@ class ModelConfig:
     # (HF deepseek_v3 `noaux_tc` with one group).
     moe_scoring: str = "softmax"
     routed_scaling_factor: float = 1.0
+    # -- latent attention past Kanana-2's block; any of these makes the
+    # attention leaves and the paged pool per KIND of layer (`by_kind`) --
+    # query low-rank: c_q = rms(x W_qa), q = c_q W_qb
+    q_lora_rank: int = 0
+    # the normed latents are multiplied by sqrt(hidden_size / rank)
+    # (`apply_mla_qkv_lora_rescale`)
+    latent_rescale: bool = False
+    # "headwise": head h's attention output times sigmoid(x W_g)[h] ahead
+    # of W_o ("" = no gate)
+    attention_gate: str = ""
+    # Learned key selection on GLOBAL layers (DeepSeek-V3.2's indexer):
+    # index_topk > 0 turns it on.  A token caches one more row, the
+    # indexer's key k^I (index_head_dim); a query scores every causal key
+    # with index_n_heads small heads and attends only the index_topk best.
+    index_n_heads: int = 0
+    index_head_dim: int = 0
+    index_topk: int = 0
+    # the sizes of a WINDOWED layer's latent block where they are its own
+    # (None = the model-wide ones above)
+    windowed_latent: Optional[LatentGeometry] = None
+    # Experts HELD: `num_experts` expert leaves are stored and computed,
+    # experts expert_offset .. expert_offset + num_experts of the
+    # `num_experts_routed` the router knows (0 = all of them, held whole).
+    # A token's weights are chosen and renormalised over all the router's
+    # experts; what the absent ones would add is left out.
+    num_experts_routed: int = 0
+    expert_offset: int = 0
 
     def __post_init__(self):
         if self.moe_scoring not in ("softmax", "sigmoid"):
             raise UnsupportedConfigError(
                 f"moe_scoring {self.moe_scoring!r}: known 'softmax', "
                 "'sigmoid'")
-        if self.kv_lora_rank and self.layer_types:
+        if self.attention_gate not in ("", "headwise"):
             raise UnsupportedConfigError(
-                "latent attention with a windowed/global layer pattern is "
-                "not served")
+                f"attention_gate_type {self.attention_gate!r} is not "
+                "served: only 'headwise' is")
+        if (self.index_topk or self.windowed_latent or self.q_lora_rank
+                or self.attention_gate or self.latent_rescale) \
+                and not self.is_latent:
+            raise UnsupportedConfigError(
+                "a query low-rank, a latent rescale, an attention gate, an "
+                "indexer and per-kind attention sizes are built with latent "
+                "attention (kv_lora_rank) only")
+        if self.index_topk and not (self.index_n_heads > 0
+                                    and self.index_head_dim > 0):
+            raise UnsupportedConfigError(
+                "index_topk needs index_n_heads and index_head_dim")
+        if self.num_experts_routed and not (
+                self.moe_scoring == "sigmoid" and 0 <= self.expert_offset
+                and self.expert_offset + self.num_experts
+                <= self.num_experts_routed):
+            raise UnsupportedConfigError(
+                "a share of the routed experts (num_experts_routed) needs "
+                "sigmoid routing and expert_offset + num_experts within the "
+                "router's width")
         if self.first_k_dense and not (
                 self.is_moe and 0 < self.first_k_dense < self.num_layers
                 and self.dense_intermediate_size > 0):
@@ -175,15 +245,44 @@ class ModelConfig:
         return self.num_experts > 0
 
     @property
+    def pattern(self) -> Tuple[int, Tuple[str, ...]]:
+        """(lead, period) of the layers after the leading dense ones: `lead`
+        layers that stand alone ahead of whole periods of `period` kinds,
+        the split with the fewest unrolled bodies (lead + period; ties to
+        the shorter lead).  (0, (GLOBAL,)) for a config without a pattern.
+        The layer scan runs over whole periods and the lead layers unrolled
+        ahead of it (models/llama.forward).  dots3's 45 routed layers, one
+        full and then 11 x (3 sliding, 1 full), give (1, (s, s, s, f))."""
+        kinds = self.layer_types[self.first_k_dense:]
+        n = len(kinds)
+        best = None
+        for p in range(1, n + 1):
+            lead = n % p
+            rest = kinds[lead:]
+            if rest == rest[:p] * (len(rest) // p) and (
+                    best is None or (lead + p, lead) < (
+                        best[0] + len(best[1]), best[0])):
+                best = (lead, rest[:p])
+        return best or (0, (GLOBAL,))
+
+    @property
     def layer_period(self) -> Tuple[str, ...]:
-        """The kinds of one period of the pattern: the shortest prefix that,
-        repeated, gives `layer_types`.  (GLOBAL,) for a config without one:
-        the layer scan runs over whole periods (models/llama.forward)."""
-        kinds = self.layer_types
-        for p in range(1, len(kinds) + 1):
-            if len(kinds) % p == 0 and kinds == kinds[:p] * (len(kinds) // p):
-                return kinds[:p]
-        return (GLOBAL,)
+        """The kinds of one period of the pattern (see `pattern`)."""
+        return self.pattern[1]
+
+    def kind_of(self, layer: int) -> str:
+        return self.layer_types[layer] if self.layer_types else GLOBAL
+
+    def layers_of(self, kind: str) -> int:
+        """How many layers are of `kind`."""
+        if not self.layer_types:
+            return self.num_layers if kind == GLOBAL else 0
+        return self.layer_types.count(kind)
+
+    @property
+    def kinds(self) -> Tuple[str, ...]:
+        """The kinds of layer this model has, in order of first use."""
+        return tuple(dict.fromkeys(self.layer_types)) or (GLOBAL,)
 
     @property
     def is_windowed(self) -> bool:
@@ -204,17 +303,54 @@ class ModelConfig:
         return self.kv_lora_rank > 0
 
     @property
-    def kv_row_widths(self) -> Tuple[int, int]:
-        """Values a token stores in one layer of the (k, v) pools: THE
-        definition of the paged pool's rows, which the pool's allocation
-        (runtime/kv_cache.py), the memory plan (runtime/planner.py), the
-        engine's backend rules and /metrics all ask.  GQA: Hkv*D keys and as
-        many values.  Latent: the k pool holds c~ (kv_lora_rank) and the v
-        pool the roped k_r padded to whole 128-lane tiles, which the Pallas
-        page DMAs need (64 -> 128: 640 values for 576 stored)."""
+    def by_kind(self) -> bool:
+        """The attention leaves and the paged pool are PER KIND of layer: a
+        latent model with a layer pattern or anything past Kanana-2's block.
+        One page table serves every kind (a page id means the same tokens in
+        every layer); rows differ by kind (`kv_row_widths`)."""
+        return self.is_latent and bool(
+            self.layer_types or self.index_topk or self.q_lora_rank
+            or self.attention_gate or self.latent_rescale
+            or self.windowed_latent)
+
+    def geometry_of(self, kind: str = GLOBAL) -> LatentGeometry:
+        """The latent block's sizes in a layer of `kind`."""
+        if kind == WINDOWED and self.windowed_latent is not None:
+            return self.windowed_latent
+        return LatentGeometry(
+            self.num_heads, self.q_lora_rank, self.kv_lora_rank,
+            self.qk_nope_head_dim, self.qk_rope_head_dim, self.v_head_dim)
+
+    def has_indexer(self, kind: str = GLOBAL) -> bool:
+        return self.index_topk > 0 and kind == GLOBAL
+
+    @property
+    def num_router_experts(self) -> int:
+        """Columns of the router: the published expert count."""
+        return self.num_experts_routed or self.num_experts
+
+    def kv_row_widths(self, kind: str = GLOBAL) -> Tuple[int, ...]:
+        """Values a token stores in one layer of `kind`, one entry a pool
+        array: THE definition of the paged pool's rows, which the pool's
+        allocation (runtime/kv_cache.py), the memory plan
+        (runtime/planner.py), the engine's backend rules and /metrics all
+        ask.  GQA: Hkv*D keys and as many values.  Latent: the k pool holds
+        c~ (kv_lora_rank) and the v pool the roped k_r padded to whole
+        128-lane tiles, which the Pallas page DMAs need (64 -> 128: 640
+        values for 576 stored); a layer with an indexer stores its key k^I
+        in a third row, padded likewise."""
         if self.is_latent:
-            return (self.kv_lora_rank, -(-self.qk_rope_head_dim // 128) * 128)
+            g = self.geometry_of(kind)
+            index = ((_lane_tiles(self.index_head_dim),)
+                     if self.has_indexer(kind) else ())
+            return (g.kv_lora_rank, _lane_tiles(g.qk_rope_head_dim)) + index
         return (self.num_kv_heads * self.head_dim,) * 2
+
+    @property
+    def kv_values_per_token(self) -> int:
+        """Values one cached token holds over all layers, as allocated."""
+        return sum(self.layers_of(kind) * sum(self.kv_row_widths(kind))
+                   for kind in self.kinds)
 
     @property
     def activation_dtype(self):
@@ -386,6 +522,10 @@ def _layer_pattern(hf: dict) -> dict:
             "layer_types names sliding_attention layers but "
             "use_sliding_window is false")
     by_kind = hf.get("rope_parameters") or {}
+    if not by_kind and "swa_rope_theta" in hf:
+        # `dots3_note` spells the two kinds' thetas as flat keys
+        by_kind = {GLOBAL: {"rope_theta": hf["rope_theta"]},
+                   WINDOWED: {"rope_theta": hf["swa_rope_theta"]}}
     nested = {k: v for k, v in by_kind.items() if isinstance(v, dict)}
     # (an unknown kind is ModelConfig's own error, not a missing rope)
     missing = (set(kinds) & {WINDOWED, GLOBAL}) - set(nested) if nested \
@@ -395,7 +535,8 @@ def _layer_pattern(hf: dict) -> dict:
             f"rope_parameters has no entry for {sorted(missing)}")
     return {
         "layer_types": kinds,
-        "sliding_window": hf.get("sliding_window"),
+        "sliding_window": hf.get("sliding_window",
+                                 hf.get("sliding_window_size")),
         "rope_by_kind": tuple(
             (k, _rope_params(k, nested[k]))
             for k in sorted(set(kinds) & set(nested))),
@@ -410,7 +551,6 @@ def _latent_keys(hf: dict) -> dict:
     if not hf.get("kv_lora_rank"):
         return {}
     served = (
-        ("q_lora_rank", None, "a query low-rank projection"),
         ("rope_scaling", None, "scaled rotary positions on latent attention"),
         ("topk_method", "noaux_tc", "another expert selection method"),
         ("scoring_func", "sigmoid", "another router scoring function"),
@@ -430,11 +570,14 @@ def _latent_keys(hf: dict) -> dict:
             "renormalised over the chosen experts")
     dense = int(hf.get("first_k_dense_replace", 0))
     return {
+        **_kind_keys(hf),
         "kv_lora_rank": int(hf["kv_lora_rank"]),
         "qk_nope_head_dim": int(hf["qk_nope_head_dim"]),
         "qk_rope_head_dim": int(hf["qk_rope_head_dim"]),
         "v_head_dim": int(hf["v_head_dim"]),
-        "rope_interleave": bool(hf.get("rope_interleave", False)),
+        # HF DeepseekV3Config's own default: a file that leaves the key out
+        # publishes interleaved pairs
+        "rope_interleave": bool(hf.get("rope_interleave", True)),
         "first_k_dense": dense,
         "dense_intermediate_size": int(hf["intermediate_size"]) if dense else 0,
         "shared_intermediate_size": (int(hf.get("n_shared_experts") or 0)
@@ -442,6 +585,50 @@ def _latent_keys(hf: dict) -> dict:
         "moe_scoring": "sigmoid",
         "routed_scaling_factor": float(hf.get("routed_scaling_factor", 1.0)),
     }
+
+
+def _kind_keys(hf: dict) -> dict:
+    """The keys of a latent decoder past Kanana-2's block (`dots3_note`: a
+    query low-rank, the rescale, the headwise gate, the indexer, the `swa_*`
+    sizes of sliding layers, a share of the experts) as ModelConfig fields."""
+    out = {
+        "q_lora_rank": int(hf.get("q_lora_rank") or 0),
+        "latent_rescale": bool(hf.get("apply_mla_qkv_lora_rescale", False)),
+        "attention_gate": hf.get("attention_gate_type") or "",
+        "index_n_heads": int(hf.get("index_n_heads") or 0),
+        "index_head_dim": int(hf.get("index_head_dim") or 0),
+        "index_topk": int(hf.get("index_topk") or 0),
+    }
+    swa = {k[4:]: v for k, v in hf.items() if k.startswith("swa_")}
+    if swa:
+        for key, why in (
+                ("index_topk", "an indexer on a sliding layer"),
+                ("index_n_heads", "an indexer on a sliding layer"),
+                ("rope_scaling", "scaled rotary positions on sliding layers")):
+            if swa.get(key):
+                raise UnsupportedConfigError(
+                    f"swa_{key} = {swa[key]!r} ({why}) is not served")
+        gate = swa.get("attention_gate_type") or ""
+        if gate != out["attention_gate"]:
+            raise UnsupportedConfigError(
+                f"swa_attention_gate_type = {gate!r} differs from "
+                f"attention_gate_type = {out['attention_gate']!r}: one gate "
+                "type a model is served")
+        out["windowed_latent"] = LatentGeometry(
+            num_heads=int(swa.get("num_attention_heads",
+                                  hf["num_attention_heads"])),
+            q_lora_rank=int(swa.get("q_lora_rank") or 0),
+            kv_lora_rank=int(swa.get("kv_lora_rank", hf["kv_lora_rank"])),
+            qk_nope_head_dim=int(swa.get("qk_nope_head_dim",
+                                         hf["qk_nope_head_dim"])),
+            qk_rope_head_dim=int(swa.get("qk_rope_head_dim",
+                                         hf["qk_rope_head_dim"])),
+            v_head_dim=int(swa.get("v_head_dim", hf["v_head_dim"])))
+    published = int(hf.get("n_routed_experts_published") or 0)
+    if published:
+        out["num_experts_routed"] = published
+        out["expert_offset"] = int(hf.get("expert_share_offset", 0))
+    return out
 
 
 def config_from_hf_json(path: str) -> ModelConfig:

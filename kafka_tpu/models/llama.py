@@ -134,6 +134,13 @@ def _kv_read(cache, idx, dtype) -> jnp.ndarray:
 @jax.named_scope("attn_gather")
 def _kv_read_pages(cache, page_table: jnp.ndarray, page_size: int,
                    dtype) -> jnp.ndarray:
+    """`_read_pages` under the `attn_gather` scope: the gather that
+    materialises (part of) an attention window on the XLA paths."""
+    return _read_pages(cache, page_table, page_size, dtype)
+
+
+def _read_pages(cache, page_table: jnp.ndarray, page_size: int,
+                dtype) -> jnp.ndarray:
     """Gather the rows of `page_table`'s pages, [B, P * page_size, Hkv*D],
     by PAGE rather than by slot.
 
@@ -228,6 +235,19 @@ def _layer_view(paged: PagedView, layer, slots: int) -> PagedView:
 
 def init_kv_cache(cfg: ModelConfig, batch: int, capacity: int, dtype=None) -> KVCache:
     dtype = dtype or cfg.activation_dtype
+    if cfg.by_kind:
+        # per kind of layer, as the paged pool (runtime/kv_cache.py)
+        def rows(n, width):
+            return jnp.zeros((n, batch, capacity, 1, width), dtype)
+
+        k, v = {}, {}
+        for kind in cfg.kinds:
+            g, n = cfg.geometry_of(kind), cfg.layers_of(kind)
+            k[kind] = rows(n, g.kv_lora_rank)
+            v[kind] = rows(n, g.qk_rope_head_dim)
+            if cfg.has_indexer(kind):
+                v[INDEX] = rows(n, cfg.index_head_dim)
+        return KVCache(k=k, v=v)
     if cfg.is_latent:
         # one "head": k holds the latent c~, v the roped k_r (no padding:
         # nothing DMAs this cache by lane tile)
@@ -242,6 +262,8 @@ def init_params(cfg: ModelConfig, key: jax.Array, dtype=None) -> Params:
     """Random-init parameters (layer-stacked). Serving loads checkpoints
     instead; random init exists for tests and micro-benchmarks."""
     dtype = dtype or cfg.activation_dtype
+    if cfg.by_kind:
+        return _init_kind_params(cfg, key, dtype)
     if cfg.is_latent or cfg.first_k_dense or cfg.shared_intermediate_size \
             or cfg.moe_scoring != "softmax":
         # a tree and a random stream of its own: the stream below is what
@@ -358,6 +380,111 @@ def _init_latent_params(cfg: ModelConfig, key: jax.Array, dtype) -> Params:
         params["dense_layers"] = {
             **attention(kd[0], n_dense),
             **mlp(kd[1], n_dense, cfg.dense_intermediate_size)}
+    if not cfg.tie_word_embeddings:
+        params["lm_head"] = norm01(keys[9], (h, cfg.vocab_size), h)
+    return params
+
+
+# the pool entry (beside the kinds') that holds the indexer's key rows
+INDEX = "index"
+
+
+def _init_kind_params(cfg: ModelConfig, key: jax.Array, dtype) -> Params:
+    """Random weights of a latent decoder whose attention is PER KIND of
+    layer (`cfg.by_kind`): "attn" holds, per kind, the attention leaves of
+    all that kind's layers stacked in layer order (dense and routed alike);
+    "dense_layers" and "layers" hold the norms and the FFN leaves.  Expert
+    leaves are the `num_experts` HELD; the router and its selection bias keep
+    the router's full width.  A matrix that reads a rescaled latent counts
+    the rescale in its fan-in, so queries, keys and values come out at unit
+    scale as everywhere else; norm weights and biases are spread (not 1 / 0)
+    so that a program that skips one fails the check, as
+    `_init_latent_params` says."""
+    h = cfg.hidden_size
+
+    @partial(jax.jit, static_argnums=(1, 2))
+    def norm01(k, shape, fan_in):
+        # one program a leaf: no float32 copy of a 0.75G-element leaf is held
+        return (jax.random.normal(k, shape, jnp.float32)
+                * (fan_in**-0.5)).astype(dtype)
+
+    def spread(k, shape, mean=1.0, sd=0.2):
+        return (mean + sd * jax.random.normal(k, shape, jnp.float32)
+                ).astype(dtype)
+
+    def gain(rank):
+        return h / rank if cfg.latent_rescale else 1.0
+
+    def attention(k, kind, n):
+        g = cfg.geometry_of(kind)
+        hq, r, rq = g.num_heads, g.kv_lora_rank, g.q_lora_rank
+        dn, dr, dv = g.qk_nope_head_dim, g.qk_rope_head_dim, g.v_head_dim
+        ks = jax.random.split(k, 12)
+        out = {
+            "ln_kv": spread(ks[0], (n, r)),
+            "wkva": norm01(ks[1], (n, h, r + dr), h),
+            "wkvb": norm01(ks[2], (n, hq, r, dn + dv), r * gain(r)),
+            "wo": norm01(ks[3], (n, hq, dv, h), hq * dv),
+        }
+        if rq:
+            out["wqa"] = norm01(ks[4], (n, h, rq), h)
+            out["ln_q"] = spread(ks[5], (n, rq))
+            out["wqb"] = norm01(ks[6], (n, rq, hq, dn + dr), rq * gain(rq))
+        else:
+            out["wq"] = norm01(ks[4], (n, h, hq, dn + dr), h)
+        if cfg.attention_gate:
+            out["wgate"] = norm01(ks[7], (n, h, hq), h)
+        if cfg.has_indexer(kind):
+            hi, di = cfg.index_n_heads, cfg.index_head_dim
+            src, fan = (rq, rq * gain(rq)) if rq else (h, h)
+            out["wiq"] = norm01(ks[8], (n, src, hi, di), fan)
+            out["wik"] = norm01(ks[9], (n, h, di), h)
+            out["ln_ik"] = spread(ks[10], (n, di))
+            out["ln_ik_b"] = spread(jax.random.fold_in(ks[10], 1), (n, di),
+                                    0.0, 0.1)
+            out["wiw"] = norm01(ks[11], (n, h, hi), h)
+        return out
+
+    def mlp(k, n, f, names=("wg", "wu", "wd")):
+        ks = jax.random.split(k, 3)
+        return {names[0]: norm01(ks[0], (n, h, f), h),
+                names[1]: norm01(ks[1], (n, h, f), h),
+                names[2]: norm01(ks[2], (n, f, h), f)}
+
+    def norms(n):
+        return {"ln_attn": jnp.ones((n, h), dtype),
+                "ln_mlp": jnp.ones((n, h), dtype)}
+
+    keys = jax.random.split(key, 12)
+    n_dense = cfg.first_k_dense
+    n = cfg.num_layers - n_dense
+    layers = norms(n)
+    if cfg.is_moe:
+        E, f = cfg.num_experts, cfg.intermediate_size
+        layers["router"] = norm01(keys[2], (n, h, cfg.num_router_experts), h)
+        if cfg.moe_scoring == "sigmoid":
+            layers["router_bias"] = 0.1 * jax.random.normal(
+                keys[3], (n, cfg.num_router_experts), jnp.float32)
+        layers["wg"] = norm01(keys[4], (n, E, h, f), h)
+        layers["wu"] = norm01(keys[5], (n, E, h, f), h)
+        layers["wd"] = norm01(keys[6], (n, E, f, h), f)
+        if cfg.shared_intermediate_size:
+            layers.update(mlp(keys[7], n, cfg.shared_intermediate_size,
+                              ("ws_g", "ws_u", "ws_d")))
+    else:
+        layers.update(mlp(keys[4], n, cfg.intermediate_size))
+    params: Params = {
+        "embed": norm01(keys[0], (cfg.vocab_size, h), h),
+        "final_norm": jnp.ones((h,), dtype),
+        "layers": layers,
+        "attn": {kind: attention(jax.random.fold_in(keys[1], i), kind,
+                                 cfg.layers_of(kind))
+                 for i, kind in enumerate(cfg.kinds)},
+    }
+    if n_dense:
+        params["dense_layers"] = {
+            **norms(n_dense),
+            **mlp(keys[8], n_dense, cfg.dense_intermediate_size)}
     if not cfg.tie_word_embeddings:
         params["lm_head"] = norm01(keys[9], (h, cfg.vocab_size), h)
     return params
@@ -740,35 +867,62 @@ def _latent_attention_block(
     paged: Optional["PagedView"] = None,
     mesh=None,
     layer=None,
+    kind: str = GLOBAL,
+    i_cache=None,
 ):
-    """One latent-attention (MLA) sublayer; the signature and the cache
-    contract of _attention_block.  What is cached per token is (c~, roped
-    k_r): k_cache holds c~, v_cache k_r (module docstring).  Paged decode
-    runs the absorbed form, everything else the expanded one; what only the
-    latent form adds around attention proper (the absorb and un-absorb
-    einsums, the expansion of cached rows through W_kvb) sits under
-    `attn_latent_proj` inside `attn_core`."""
+    """One latent-attention (MLA) sublayer of a layer of `kind`; the cache
+    contract of _attention_block, with one more cache: returns (out,
+    k_cache', v_cache', i_cache').  What is cached per token is (c~, roped
+    k_r): k_cache holds c~, v_cache k_r (module docstring); where the kind
+    has an indexer (`cfg.has_indexer`), i_cache holds its key k^I.  Paged
+    decode runs the absorbed form, everything else the expanded one; what
+    only the latent form adds around attention proper (the absorb and
+    un-absorb einsums, the expansion of cached rows through W_kvb) sits
+    under `attn_latent_proj` inside `attn_core`.
+
+    A `cfg.by_kind` model's block also has, by what its leaves and its
+    config say: a query low-rank ("wqa"), the rescale of the normed latents,
+    a sliding window (`cfg.window_of(kind)`: every path masks to it, paged
+    decode reads the window's pages only), the learned key selection
+    (`attn_index`: indexer projections, scores over the live context, exact
+    top-k; `attn_select`: the read of the chosen rows), and the headwise
+    gate (`attn_gate`).  Its paged prefill walks the keys in chunks with a
+    running softmax (`_latent_prefill_walk`), masked to the chosen keys or
+    to the window, and never holds [Hq, S, window] scores."""
     dt = x.dtype
-    r, dn = cfg.kv_lora_rank, cfg.qk_nope_head_dim
-    scale = (dn + cfg.qk_rope_head_dim) ** -0.5
+    g = cfg.geometry_of(kind)
+    r, dn = g.kv_lora_rank, g.qk_nope_head_dim
+    scale = (dn + g.qk_rope_head_dim) ** -0.5
+    window = cfg.window_of(kind)
+    indexed = cfg.has_indexer(kind)
     if mesh is not None and mesh.size > 1:
         raise LatentPathError(
             "latent attention on a mesh of more than one device (tp / ep / "
             "sp over the latent pool)")
     if cfg.prefill_ring:
         raise LatentPathError("prefill_ring has no latent form")
-    if isinstance(k_cache, QTensor):
+    if any(isinstance(c, QTensor) for c in (k_cache, v_cache, i_cache)):
         raise LatentPathError("the int8 KV pool has no latent form")
     with jax.named_scope("attn_qkv"):
-        q = jnp.einsum("bsh,hnd->bsnd", x, _w(lp, "wq", dt))
+        if "wqa" in lp:
+            c_q = rms_norm(jnp.einsum("bsh,hr->bsr", x, _w(lp, "wqa", dt)),
+                           lp["ln_q"], cfg.rms_norm_eps)
+            c_q = _rescaled(c_q, cfg)
+            q = jnp.einsum("bsr,rnd->bsnd", c_q, _w(lp, "wqb", dt))
+        else:
+            c_q = x
+            q = jnp.einsum("bsh,hnd->bsnd", x, _w(lp, "wq", dt))
         kva = jnp.einsum("bsh,hr->bsr", x, _w(lp, "wkva", dt))
-        c = rms_norm(kva[..., :r], lp["ln_kv"], cfg.rms_norm_eps)
+        c = _rescaled(rms_norm(kva[..., :r], lp["ln_kv"], cfg.rms_norm_eps),
+                      cfg)
         q_nope, q_rope = q[..., :dn], q[..., dn:]
         k_rope = kva[..., None, r:]  # ONE vector a token: a head axis of 1
         if cfg.rope_interleave:
             q_rope, k_rope = _deinterleave(q_rope), _deinterleave(k_rope)
         q_rope = apply_rope(q_rope, cos, sin)
         k_rope = apply_rope(k_rope, cos, sin)[..., 0, :]
+    if indexed:
+        q_idx, k_idx, w_idx = _index_projections(x, c_q, lp, cfg, cos, sin)
     wkvb = _w(lp, "wkvb", dt)  # [N, r, dn + dv]
     b, s = x.shape[:2]
     absorbed = False
@@ -782,21 +936,42 @@ def _latent_attention_block(
         v_cache = _kv_write(
             _flat_pool(v_cache), paged.write_idx,
             jnp.pad(k_rope, ((0, 0), (0, 0), (0, lanes - k_rope.shape[-1]))))
+        if indexed:
+            i_lanes = i_cache.shape[-1]
+            i_cache = _kv_write(
+                _flat_pool(i_cache), paged.write_idx,
+                jnp.pad(k_idx, ((0, 0), (0, 0), (0, i_lanes - k_idx.shape[-1]))))
         if s > 1 and paged.seq_lens is not None:
             raise LatentPathError(
                 "speculative verify (K+1 queries a lane) has no latent form")
         absorbed = s == 1
-    kernel = (absorbed and cfg.attention_backend == "pallas"
-              and paged.page_table is not None)
-    with jax.named_scope("attn_core"):
-        if not kernel:
+    by_page = (paged is not None and paged.page_table is not None
+               and paged.page_size is not None)
+    kernel = (absorbed and cfg.attention_backend == "pallas" and by_page
+              and not indexed)
+    # the paged forms of a by_kind model that read less than the static
+    # window: the chosen rows, the window's pages, a walk of the live keys
+    chosen_rows = absorbed and indexed and by_page
+    window_pages = (absorbed and window is not None and by_page
+                    and not kernel)
+    walk = paged is not None and not absorbed and by_page and cfg.by_kind
+    with jax.named_scope("attn_core"), (
+            nullcontext() if window is None
+            else jax.named_scope("attn_window")):
+        mask = None
+        if not (kernel or chosen_rows or window_pages or walk):
             # the XLA forms: the window of cached rows and who may attend it
             if paged is not None:
                 c_win, r_win = _latent_window(k_cache, v_cache, paged, dt)
                 r_win = r_win[..., :k_rope.shape[-1]]  # drop the lane padding
                 kv_pos, valid = paged.kv_positions, paged.kv_valid
+                if indexed:
+                    i_win = _kv_read(i_cache, paged.read_idx, dt)[
+                        ..., :k_idx.shape[-1]]
             elif k_cache is None:
                 c_win, r_win, kv_pos, valid = c, k_rope, positions, None
+                if indexed:
+                    i_win = k_idx
             else:
                 idx = positions if cache_positions is None else cache_positions
                 b_idx = jnp.arange(b)[:, None]
@@ -805,14 +980,42 @@ def _latent_attention_block(
                         c.astype(k_cache.dtype))
                     v_cache = v_cache.at[layer, b_idx, idx, 0].set(
                         k_rope.astype(v_cache.dtype))
+                    if indexed:
+                        i_cache = i_cache.at[layer, b_idx, idx, 0].set(
+                            k_idx.astype(i_cache.dtype))
                 c_win, r_win = k_cache[layer][:, :, 0], v_cache[layer][:, :, 0]
                 cap = c_win.shape[1]
                 kv_pos = jnp.broadcast_to(jnp.arange(cap)[None, :], (b, cap))
                 valid = kv_valid
+                if indexed:
+                    i_win = i_cache[layer][:, :, 0].astype(dt)
             c_win, r_win = c_win.astype(dt), r_win.astype(dt)
             mask = positions[:, :, None] >= kv_pos[:, None, :]
+            if window is not None:
+                mask = mask & (kv_pos[:, None, :]
+                               > positions[:, :, None] - window)
             if valid is not None:
                 mask = mask & valid[:, None, :]
+            if indexed:
+                with jax.named_scope("attn_index"):
+                    scores = _index_scores(q_idx, w_idx, i_win)
+                    mask = _chosen_mask(scores, mask, cfg.index_topk)
+            if cfg.by_kind:
+                # a masked row may hold anything (a page never written)
+                c_win = _zero_unattended(c_win, mask)
+                r_win = _zero_unattended(r_win, mask)
+        elif chosen_rows:
+            with jax.named_scope("attn_index"):
+                chosen, mask = _paged_index_choice(
+                    q_idx, w_idx, i_cache, paged, positions, cfg, dt)
+            with jax.named_scope("attn_select"):
+                c_win, r_win = _read_chosen_rows(
+                    k_cache, v_cache, chosen, dt)
+                r_win = r_win[..., :k_rope.shape[-1]]
+        elif window_pages:
+            c_win, r_win, mask = _latent_window_pages(
+                k_cache, v_cache, paged, window, dt)
+            r_win = r_win[..., :k_rope.shape[-1]]
         if absorbed:
             with jax.named_scope("attn_latent_proj"):
                 q_lat = jnp.einsum("bsnd,nrd->bsnr", q_nope, wkvb[..., :dn])
@@ -824,12 +1027,23 @@ def _latent_attention_block(
                     paged.page_table, paged.seq_lens, scale=scale,
                     page_size=paged.page_size,
                     interpret=jax.default_backend() != "tpu",
+                    **({} if window is None else {"window": window}),
                 )[:, None]
             else:
                 o_lat = _latent_attend(q_lat, q_rope, c_win, r_win, c_win,
                                        mask, scale, shared=True)
             with jax.named_scope("attn_latent_proj"):
                 out = jnp.einsum("bsnr,nrd->bsnd", o_lat, wkvb[..., dn:])
+        elif walk:
+            chosen_of = None
+            if indexed:
+                with jax.named_scope("attn_index"):
+                    _, chosen_of = _paged_index_choice(
+                        q_idx, w_idx, i_cache, paged, positions, cfg, dt,
+                        as_mask=True)
+            out = _latent_prefill_walk(
+                q_nope, q_rope, wkvb, k_cache, v_cache, paged, positions,
+                scale, dn, k_rope.shape[-1], window, chosen_of)
         else:
             with jax.named_scope("attn_latent_proj"):
                 kv = jnp.einsum("btr,nrd->btnd", c_win, wkvb)
@@ -838,9 +1052,321 @@ def _latent_attention_block(
     if paged is not None:
         k_cache = _stacked_pool(k_cache, num_layers)
         v_cache = _stacked_pool(v_cache, num_layers)
+        if indexed:
+            i_cache = _stacked_pool(i_cache, num_layers)
+    if "wgate" in lp:
+        with jax.named_scope("attn_gate"):
+            gate = jax.nn.sigmoid(jnp.einsum(
+                "bsh,hn->bsn", x, _w(lp, "wgate", dt),
+                preferred_element_type=jnp.float32))
+            out = (out * gate[..., None]).astype(out.dtype)
     with jax.named_scope("attn_out"):
         out = jnp.einsum("bsnd,ndh->bsh", out, _w(lp, "wo", out.dtype))
-    return out, k_cache, v_cache
+    return out, k_cache, v_cache, i_cache
+
+
+def _rescaled(latent: jnp.ndarray, cfg: ModelConfig) -> jnp.ndarray:
+    """A normed latent times sqrt(hidden_size / its rank) where the config
+    asks (`apply_mla_qkv_lora_rescale`); as it is where not."""
+    if not cfg.latent_rescale:
+        return latent
+    return latent * jnp.asarray(
+        (cfg.hidden_size / latent.shape[-1]) ** 0.5, latent.dtype)
+
+
+def _zero_unattended(rows: jnp.ndarray, mask: jnp.ndarray) -> jnp.ndarray:
+    """rows [B, T, w] with every row no query attends (mask [B, S, T]) set
+    to zero: a probability of 0 times a NaN a never-written page may hold
+    is NaN."""
+    return jnp.where(jnp.any(mask, axis=1)[..., None], rows, 0)
+
+
+def _index_projections(x, c_q, lp: Params, cfg: ModelConfig, cos, sin):
+    """The indexer's three projections (DeepSeek-V3.2's sparse attention),
+    under `attn_index`: q^I [B, S, Hi, Di] from the query latent, the key k^I
+    [B, S, Di] = layernorm(x W^I_k), ONE row a token, and the head weights
+    w [B, S, Hi] in f32, the two score scales folded in.  Rotary on the
+    first `qk_rope_head_dim` values of q^I and k^I, half-split pairs (never
+    de-interleaved), the layer's own table."""
+    dt = x.dtype
+    hi, di, dr = cfg.index_n_heads, cfg.index_head_dim, cos.shape[-1] * 2
+    with jax.named_scope("attn_index"):
+        q_idx = jnp.einsum("bsr,rnd->bsnd", c_q, _w(lp, "wiq", dt))
+        k32 = jnp.einsum("bsh,hd->bsd", x, _w(lp, "wik", dt),
+                         preferred_element_type=jnp.float32)
+        mu = jnp.mean(k32, axis=-1, keepdims=True)
+        var = jnp.mean((k32 - mu) ** 2, axis=-1, keepdims=True)
+        k_idx = ((k32 - mu) * jax.lax.rsqrt(var + cfg.rms_norm_eps)
+                 * lp["ln_ik"].astype(jnp.float32)
+                 + lp["ln_ik_b"].astype(jnp.float32)).astype(dt)
+        q_idx = jnp.concatenate(
+            [apply_rope(q_idx[..., :dr], cos, sin), q_idx[..., dr:]], axis=-1)
+        k_idx = jnp.concatenate(
+            [apply_rope(k_idx[..., None, :dr], cos, sin)[..., 0, :],
+             k_idx[..., dr:]], axis=-1)
+        w_idx = jnp.einsum("bsh,hn->bsn", x, _w(lp, "wiw", dt),
+                           preferred_element_type=jnp.float32
+                           ) * (hi ** -0.5 * di ** -0.5)
+    return q_idx, k_idx, w_idx
+
+
+def _index_scores(q_idx, w_idx, k_idx) -> jnp.ndarray:
+    """I[t, s] = sum_j w[t, j] * relu(q^I[t, j] . k^I[s]) in f32.  q_idx
+    [B, S, Hi, Di], w_idx [B, S, Hi] f32, k_idx [B, T, Di] -> [B, S, T]."""
+    dots = jnp.einsum("bsnd,btd->bsnt", q_idx, k_idx,
+                      preferred_element_type=jnp.float32)
+    return jnp.einsum("bsn,bsnt->bst", w_idx, jax.nn.relu(dots))
+
+
+def _chosen_mask(scores: jnp.ndarray, mask: jnp.ndarray,
+                 top_k: int) -> jnp.ndarray:
+    """`mask` [B, S, T] narrowed to each query's chosen keys: of the keys it
+    allows, the `top_k` of largest score (all of them where it allows no
+    more), EXACTLY the set `lax.top_k` picks, ties to the lower position.
+
+    No sort: XLA's top-k of 2,048 among 32,768 sorts the whole row (4.1 ms
+    a layer a decode pass, 17 ms a 512-row prefill launch: my chip run 2,
+    PR 33).  The scores become unsigned keys of the same order; the k-th
+    largest key is built bit by bit from the top (32 counts of `key >=
+    candidate`), then the lowest positions among the keys EQUAL to it fill
+    what is left of k, by the same construction over the position's bits.
+    47 passes of compare-and-count over the row, each a few microseconds at
+    decode."""
+    t = scores.shape[-1]
+    if t <= top_k:
+        return mask  # every allowed key is chosen
+    bits = jax.lax.bitcast_convert_type(
+        jnp.where(scores == 0, 0.0, scores).astype(jnp.float32), jnp.int32)
+    keys = jax.lax.bitcast_convert_type(
+        bits ^ ((bits >> 31) & 0x7FFFFFFF), jnp.uint32) ^ jnp.uint32(1 << 31)
+    keys = jnp.where(mask, keys, jnp.uint32(0))  # under every real score
+    k = jnp.minimum(jnp.sum(mask, axis=-1, dtype=jnp.int32), top_k)
+
+    def count(hit):
+        return jnp.sum(hit, axis=-1, dtype=jnp.int32)
+
+    def key_bit(i, kth):
+        cand = kth | (jnp.uint32(1) << (31 - i).astype(jnp.uint32))
+        return jnp.where(count(keys >= cand[..., None]) >= k, cand, kth)
+
+    kth = jax.lax.fori_loop(0, 32, key_bit, jnp.zeros(k.shape, jnp.uint32))
+    above = keys > kth[..., None]
+    equal = keys == kth[..., None]
+    left = k - count(above)  # how many of the equal keys are chosen
+    pos = jnp.arange(t, dtype=jnp.int32)
+    n_bits = max(t - 1, 1).bit_length()
+
+    def pos_bit(i, last):
+        cand = last | (1 << (n_bits - 1 - i))
+        return jnp.where(count(equal & (pos < cand[..., None])) < left,
+                         cand, last)
+
+    # the position of the `left`-th equal key: the largest p with fewer than
+    # `left` equal keys under it
+    last = jax.lax.fori_loop(0, n_bits, pos_bit, jnp.zeros(k.shape, jnp.int32))
+    return above | (equal & (pos <= last[..., None]) & (left > 0)[..., None])
+
+
+COMPACT_BLOCK = 128
+
+
+def _compact_chosen(chosen: jnp.ndarray, values: jnp.ndarray, top_k: int):
+    """(values [B, K], ok [B, K]): `values` [B, T] (int32, under 2**23) at
+    the positions `chosen` [B, T] marks, in ascending position, K =
+    min(top_k, T); `ok` is False past the last one where fewer than K are
+    marked, and such an entry repeats the first value.
+
+    No sort, no scatter and no gather of single elements (65k of them cost
+    0.65 ms on the v5e, a binary search over a running count 10 ms a layer:
+    my chip run 3, PR 33).  The row is cut in blocks of 128; an output
+    slot's block is found by counting the blocks that end at or before it;
+    ONE gather of whole 128-value rows brings each slot its block, in which
+    every value is packed with its rank among the block's marked ones, and
+    the slot takes the value whose rank is its own."""
+    b, t = values.shape
+    k, blk = min(top_k, t), COMPACT_BLOCK
+    pad = -t % blk
+    marked = jnp.pad(chosen, ((0, 0), (0, pad))).reshape(b, -1, blk)
+    vals = jnp.pad(values, ((0, 0), (0, pad))).reshape(b, -1, blk)
+    ones = marked.astype(jnp.int32)
+    upto = jnp.cumsum(ones, axis=-1)                 # within the block
+    counts = upto[..., -1]                           # [B, blocks]
+    ends = jnp.cumsum(counts, axis=-1)
+    slots = jnp.arange(k, dtype=jnp.int32)
+    before = ends[:, None, :] <= slots[None, :, None]    # [B, K, blocks]
+    block_of = jnp.minimum(jnp.sum(before, axis=-1, dtype=jnp.int32),
+                           marked.shape[1] - 1)
+    rank = slots[None, :] - jnp.sum(
+        jnp.where(before, counts[:, None, :], 0), axis=-1)
+    # (value, rank among the block's marked ones | 255 where unmarked)
+    packed = (vals << 8) | jnp.where(marked, upto - ones, 255)
+    rows = jnp.take_along_axis(packed, block_of[..., None], axis=1)
+    out = jnp.sum(jnp.where((rows & 255) == rank[..., None], rows >> 8, 0),
+                  axis=-1)
+    ok = slots[None, :] < ends[:, -1:]
+    return jnp.where(ok, out, out[:, :1]), ok
+
+
+# Keys one trip of the paged index scoring and of the latent prefill walk
+# reads (fewer at many queries: `_walk_chunks`): [Hi | Hq, S, keys] f32
+# scores are held a trip, not a window.
+INDEX_WALK_KEYS = 2048
+PREFILL_WALK_KEYS = 1024
+
+
+def _walk_chunks(paged: "PagedView", keys: int, queries: int):
+    """(pages a trip, padded page table, trips): a walk over the page
+    table's LIVE part in chunks of about `keys` keys (fewer where `queries`
+    rows a lane would make a trip's scores large), up to the longest lane's
+    last valid key: the bound is computed on the device."""
+    ps = paged.page_size
+    P = paged.page_table.shape[1]
+    keys = max(ps, min(keys, (1 << 19) // max(queries, 1)))
+    cp = max(1, min(keys // ps, P))
+    table = jnp.pad(paged.page_table, ((0, 0), (0, -P % cp)))
+    n_keys = jnp.max(jnp.sum(paged.kv_valid, axis=-1))
+    trips = jnp.minimum((n_keys + cp * ps - 1) // (cp * ps),
+                        table.shape[1] // cp)
+    return cp, table, trips
+
+
+def _paged_index_choice(q_idx, w_idx, i_cache, paged: "PagedView", positions,
+                        cfg: ModelConfig, dt, as_mask: bool = False):
+    """The selection step over a paged pool: index scores of every query
+    against the lane's live keys, walked chunk by chunk off the indexer's
+    own pool rows (f32 scores [B, S, C]; keys past the live context stay
+    unscored and unchosen), then the exact top-k of the causal ones.
+    Returns for decode (S = 1) the chosen keys' pool slots and which of
+    them are real, (slots [B, K], ok [B, 1, K]); with `as_mask` (None,
+    chosen [B, S, C]) for a walk that masks."""
+    ps = paged.page_size
+    b, s = q_idx.shape[:2]
+    di = q_idx.shape[-1]
+    C = paged.kv_positions.shape[1]
+    cp, table, trips = _walk_chunks(
+        paged, INDEX_WALK_KEYS, b * s if s > 1 else 1)
+    ck = cp * ps
+
+    def score(c, scores):
+        pages = jax.lax.dynamic_slice_in_dim(table, c * cp, cp, axis=1)
+        keys = _read_pages(i_cache, pages, ps, dt)[..., :di]
+        part = _index_scores(q_idx, w_idx, keys)
+        return jax.lax.dynamic_update_slice_in_dim(scores, part, c * ck, 2)
+
+    scores = jax.lax.fori_loop(
+        0, trips, score,
+        jnp.zeros((b, s, table.shape[1] * ps), jnp.float32))[..., :C]
+    mask = (paged.kv_valid[:, None, :]
+            & (paged.kv_positions[:, None, :] <= positions[:, :, None]))
+    chosen = _chosen_mask(scores, mask, cfg.index_topk)
+    if as_mask:
+        return None, chosen
+    # decode: the chosen keys' pool slots (read_idx names every position's)
+    if i_cache.shape[0] >= 1 << 23:
+        raise LatentPathError(
+            "a pool of 2**23 slots or more a kind (slots are packed with "
+            "their ranks in 32 bits when the chosen keys are compacted)")
+    slots, ok = _compact_chosen(chosen[:, 0], paged.read_idx, cfg.index_topk)
+    return slots, ok[:, None]
+
+
+def _read_chosen_rows(k_cache, v_cache, slots, dt):
+    """The flat pools' rows at the chosen keys' slots [B, K] (decode):
+    (c~ [B, K, r], k_r [B, K, lanes])."""
+    return k_cache[slots].astype(dt), v_cache[slots].astype(dt)
+
+
+def _latent_window_pages(k_cache, v_cache, paged: "PagedView", window: int,
+                         dt):
+    """Decode read of a sliding-window latent layer on XLA: the pages that
+    hold positions seq_len - window + 1 .. seq_len of each lane, and no
+    others ((c~, k_r) [B, n * page_size, .], mask [B, 1, n * page_size])."""
+    ps = paged.page_size
+    P = paged.page_table.shape[1]
+    n = min(P, -(-(window - 1) // ps) + 1)
+    lens = paged.seq_lens
+    first = jnp.clip(jnp.maximum(lens - window + 1, 0) // ps, 0, P - n)
+    cols = first[:, None] + jnp.arange(n)[None, :]
+    pages = jnp.take_along_axis(paged.page_table, cols, axis=1)
+    pos = (cols[:, :, None] * ps + jnp.arange(ps)[None, None, :]).reshape(
+        lens.shape[0], n * ps)
+    mask = ((pos <= lens[:, None]) & (pos > lens[:, None] - window)
+            & paged.kv_valid[:, :1])[:, None, :]
+    c_win = _zero_unattended(_kv_read_pages(k_cache, pages, ps, dt), mask)
+    r_win = _zero_unattended(_kv_read_pages(v_cache, pages, ps, dt), mask)
+    return c_win, r_win, mask
+
+
+def _latent_prefill_walk(q_nope, q_rope, wkvb, k_cache, v_cache,
+                         paged: "PagedView", positions, scale: float, dn: int,
+                         dr: int, window: Optional[int], chosen_of):
+    """Latent attention of a prefill chunk over the paged pool, expanded
+    form, walking the keys chunk by chunk with a running max / sum in f32
+    (PR 32's decode walk at s > 1): a trip gathers one chunk's pages,
+    expands its rows through W_kvb (`attn_latent_proj`) and folds it in, so
+    [Hq, S, window] scores never exist.  A query attends causal valid keys,
+    narrowed to its window (walked from the chunk that holds the window's
+    first key) or to `chosen_of` [B, S, C].  q_nope / q_rope [B, S, N, .];
+    returns [B, S, N, dv] in the query's dtype."""
+    ps, dt = paged.page_size, q_nope.dtype
+    b, s, n = q_nope.shape[:3]
+    dv = wkvb.shape[-1] - dn
+    cp, table, trips = _walk_chunks(paged, PREFILL_WALK_KEYS, b * s)
+    ck = cp * ps
+    pad = table.shape[1] * ps - paged.kv_valid.shape[1]
+    kv_valid = jnp.pad(paged.kv_valid, ((0, 0), (0, pad)))
+    if chosen_of is not None:
+        chosen_of = jnp.pad(chosen_of, ((0, 0), (0, 0), (0, pad)))
+    q = jnp.concatenate([q_nope, q_rope], axis=-1)
+    first = 0
+    if window is not None:
+        live = jnp.any(paged.kv_valid, axis=-1)
+        lo = jnp.min(jnp.where(live, positions[:, 0] - window + 1,
+                               jnp.iinfo(jnp.int32).max))
+        first = jnp.minimum(jnp.maximum(lo, 0) // ck, trips)
+
+    def fold(c, carry):
+        m, l, acc = carry
+        pages = jax.lax.dynamic_slice_in_dim(table, c * cp, cp, axis=1)
+        pos = c * ck + jnp.arange(ck)[None, None, :]
+        mask = (jax.lax.dynamic_slice_in_dim(kv_valid, c * ck, ck, 1)[:, None]
+                & (pos <= positions[:, :, None]))
+        if window is not None:
+            mask = mask & (pos > positions[:, :, None] - window)
+        if chosen_of is not None:
+            mask = mask & jax.lax.dynamic_slice_in_dim(
+                chosen_of, c * ck, ck, 2)
+        c_win = _zero_unattended(_kv_read_pages(k_cache, pages, ps, dt), mask)
+        r_win = _zero_unattended(
+            _kv_read_pages(v_cache, pages, ps, dt)[..., :dr], mask)
+        with jax.named_scope("attn_latent_proj"):
+            kv = jnp.einsum("btr,nrd->btnd", c_win, wkvb)
+            # a head's whole key, [k_nope | k_r]: ONE score matmul a trip.
+            # The two partial products apart were two [Hq, S, keys] f32
+            # tensors through HBM and an add (23 + 13 ms a layer a 512-row
+            # launch against 13 for one: my chip run 3, PR 33)
+            keys = jnp.concatenate(
+                [kv[..., :dn], jnp.broadcast_to(
+                    r_win[:, :, None, :], kv.shape[:3] + (dr,))], axis=-1)
+        sc = jnp.einsum("bqnd,bknd->bnqk", q, keys,
+                        preferred_element_type=jnp.float32) * scale
+        sc = jnp.where(mask[:, None], sc, NEG_INF)
+        m_new = jnp.maximum(m, jnp.max(sc, axis=-1))
+        alpha = jnp.exp(m - m_new)
+        p = jnp.where(mask[:, None], jnp.exp(sc - m_new[..., None]), 0.0)
+        l = alpha * l + jnp.sum(p, axis=-1)
+        acc = alpha[..., None] * acc + jnp.einsum(
+            "bnqk,bknd->bnqd", p.astype(dt), kv[..., dn:],
+            preferred_element_type=jnp.float32)
+        return m_new, l, acc
+
+    _, l, acc = jax.lax.fori_loop(
+        first, trips, fold,
+        (jnp.full((b, n, s), NEG_INF, jnp.float32),
+         jnp.zeros((b, n, s), jnp.float32),
+         jnp.zeros((b, n, s, dv), jnp.float32)))
+    out = acc / jnp.maximum(l, 1e-30)[..., None]
+    return jnp.transpose(out, (0, 2, 1, 3)).astype(dt)
 
 
 def _latent_window(k_cache, v_cache, paged, dt):
@@ -912,6 +1438,10 @@ def _moe_block(x: jnp.ndarray, lp: Params, cfg: ModelConfig) -> jnp.ndarray:
     computed in f32; `cfg.moe_scoring` "sigmoid" picks deepseek_v3's rule
     instead.  A shared branch (`cfg.shared_intermediate_size`: one always-on
     SwiGLU beside the routed experts) runs under its own scope, `moe_shared`.
+    A config that HOLDS a share of the experts (`cfg.num_experts_routed`: one
+    chip of an expert-parallel layer) routes over all the router knows and
+    computes the part of the result its own experts give; what the absent
+    ones would add is left out (the other chips' part of the combine).
     """
     b, s, h = x.shape
     t = x.reshape(b * s, h)
@@ -922,6 +1452,10 @@ def _moe_block(x: jnp.ndarray, lp: Params, cfg: ModelConfig) -> jnp.ndarray:
                 cfg.routed_scaling_factor)
         else:
             w = _routing_weights(t, lp["router"], cfg.num_experts_per_tok)
+        if cfg.num_experts_routed:
+            # the weights of the experts HELD: chosen and renormalised over
+            # all the router's experts, then this share's columns
+            w = w[:, cfg.expert_offset:cfg.expert_offset + cfg.num_experts]
     with jax.named_scope("moe_experts"):
         g = jnp.einsum("th,ehf->tef", t, _w(lp, "wg", t.dtype))
         u = jnp.einsum("th,ehf->tef", t, _w(lp, "wu", t.dtype))
@@ -980,10 +1514,10 @@ def forward(
             )
         # one rotary table per kind of layer, built once per forward pass;
         # each layer takes its kind's (a config without a pattern has one)
-        period = cfg.layer_period
+        lead, period = cfg.pattern
         if cfg.layer_types:
             rope = {kind: rope_cos_sin(positions, *kind_frequencies(cfg, kind))
-                    for kind in dict.fromkeys(period)}
+                    for kind in cfg.kinds}
         else:
             inv_freq = rope_frequencies(cfg)
             rope = {GLOBAL: rope_cos_sin(positions, inv_freq)}
@@ -998,8 +1532,22 @@ def forward(
         cos, sin = rope[kind]
         with jax.named_scope("attn_norm"):
             attn_in = rms_norm(h, lp["ln_attn"], cfg.rms_norm_eps)
-        if cfg.is_latent:
-            attn_out, kc, vc = _latent_attention_block(
+        if cfg.by_kind:
+            # this kind's own caches, `layer` its index among the kind's
+            has_index = cfg.has_indexer(kind) and vc is not None
+            attn_out, k_new, v_new, i_new = _latent_attention_block(
+                attn_in, lp, cfg, cos, sin, positions,
+                None if kc is None else kc[kind],
+                None if vc is None else vc[kind], kv_valid,
+                cache_positions, paged, mesh, layer, kind,
+                vc[INDEX] if has_index else None,
+            )
+            if kc is not None:
+                kc = {**kc, kind: k_new}
+                vc = {**vc, kind: v_new,
+                      **({INDEX: i_new} if has_index else {})}
+        elif cfg.is_latent:
+            attn_out, kc, vc, _ = _latent_attention_block(
                 attn_in, lp, cfg, cos, sin, positions, kc, vc, kv_valid,
                 cache_positions, paged, mesh, layer,
             )
@@ -1021,53 +1569,109 @@ def forward(
                 h = h + _mlp_block(mlp_in, lp)
         return (h, kc, vc), None
 
+    def at(stacked, i, static: bool):
+        """Layer i's leaves of a stacked tree: `a[i]` where i is static,
+        one dynamic slice a leaf where it is the scan's."""
+        if static:
+            return jax.tree.map(lambda a: a[i], stacked)
+        return jax.tree.map(
+            lambda a: jax.lax.dynamic_index_in_dim(
+                a, i, axis=0, keepdims=False), stacked)
+
+    def layer_of(stack: str, i, layer, kind, static: bool, nth=None):
+        """(leaves, cache index) of layer `layer` (absolute), the i-th of
+        `params[stack]`.  A by_kind model takes its attention leaves from
+        its kind's own stack and indexes its kind's caches, both at `nth`,
+        the layer's place among its kind."""
+        lp = at(params[stack], i, static)
+        if not cfg.by_kind:
+            return lp, layer
+        return {**lp, **at(params["attn"][kind], nth, static)}, nth
+
     def period_body(carry, first):
-        """One whole period of the pattern: its layers unrolled, each kind
-        its own code with its own static window and rotary table.  Each
-        layer's weights are indexed out of the stacked [L, ...] arrays at
-        `first + j`, one dynamic slice a leaf exactly as the plain scan
+        """One whole period of the pattern, from absolute layer `first`: its
+        layers unrolled, each kind its own code with its own static window
+        and rotary table.
+        Each layer's weights are indexed out of the stacked [L, ...] arrays
+        at `first + j`, one dynamic slice a leaf exactly as the plain scan
         takes them: scanning over a [L/p, p, ...] view instead made XLA
         materialise the whole period's weights every iteration (3.2 GB of
         copies a period at Mellum2's widths, rehearsed for the v5e)."""
+        ahead = n_dense + lead
+        # (no arithmetic on `first` where there is nothing ahead: the
+        # program of a model without dense or lead layers stays as it was)
+        stacked = first - n_dense if n_dense else first
+        t = (first - ahead) // len(period) if cfg.by_kind else None
         for j, kind in enumerate(period):
-            lp = jax.tree.map(
-                lambda a: jax.lax.dynamic_index_in_dim(
-                    a, first + j, axis=0, keepdims=False),
-                params["layers"])
-            carry, _ = layer_body(carry, (lp, first + j), kind)
+            if cfg.by_kind:
+                nth = (before(ahead, kind) + t * period.count(kind)
+                       + period[:j].count(kind))
+                scanned = layer_of("layers", stacked + j, first + j, kind,
+                                   False, nth)
+            else:
+                # (the index summed anew for every leaf, as it always was:
+                # the lowered text of a patterned GQA model does not move)
+                lp = jax.tree.map(
+                    lambda a: jax.lax.dynamic_index_in_dim(
+                        a, stacked + j, axis=0, keepdims=False),
+                    params["layers"])
+                scanned = (lp, first + j)
+            carry, _ = layer_body(carry, scanned, kind)
         return carry, None
+
+    def before(layer: int, kind: str) -> int:
+        return cfg.layer_types[:layer].count(kind)
 
     with jax.named_scope("layers"):
         kc, vc = (None, None) if kv_cache is None else kv_cache
         num_layers = jax.tree.leaves(params["layers"])[0].shape[0]
         n_dense = 0
+        carry = (x, kc, vc)
         if "dense_layers" in params:
             # leading dense layers: a stacked tree of another shape, run
             # ahead of the scan over the routed layers, which count on from
             # them.  Unrolled, not a scan of their own: one innermost loop a
             # forward pass is what a device trace counts passes by.
             n_dense = jax.tree.leaves(params["dense_layers"])[0].shape[0]
-            carry = (x, kc, vc)
             for i in range(n_dense):
-                lp = jax.tree.map(lambda a: a[i], params["dense_layers"])
-                carry, _ = layer_body(carry, (lp, i), routed=False)
-            x, kc, vc = carry
-        layer_ids = (jnp.arange(n_dense, n_dense + num_layers) if n_dense
-                     else jnp.arange(num_layers))
-        if len(period) == 1:
+                kind = cfg.kind_of(i)
+                carry, _ = layer_body(
+                    carry, layer_of("dense_layers", i, i, kind, True,
+                                    before(i, kind)),
+                    kind, routed=False)
+        if len(period) > 1 and num_layers - lead == len(period):
+            # ONE whole period: XLA removes a one-trip loop anyway, and
+            # nested in the fused program's scan over steps it then copied
+            # every slice the body had taken at the scan's index out of the
+            # stacked leaves (15 x 480 MB of expert weights: my chip run 1,
+            # PR 33).  Unrolled here, each layer's leaves are static slices
+            # of the leading axis: views.
+            lead = num_layers
+        for i in range(lead):
+            # the layers that stand alone ahead of whole periods, unrolled
+            # beside the dense ones
+            kind = cfg.kind_of(n_dense + i)
+            carry, _ = layer_body(
+                carry, layer_of("layers", i, n_dense + i, kind, True,
+                                before(n_dense + i, kind)), kind)
+        x, kc, vc = carry
+        if len(period) == 1 and not (cfg.by_kind or lead):
+            layer_ids = (jnp.arange(n_dense, n_dense + num_layers) if n_dense
+                         else jnp.arange(num_layers))
             (x, kc, vc), _ = jax.lax.scan(
                 partial(layer_body, kind=period[0]),
                 (x, kc, vc),
                 (params["layers"], layer_ids),
             )
-        else:
+        elif lead < num_layers:
             p = len(period)
-            if num_layers % p:
+            if (num_layers - lead) % p:
                 raise ValueError(
-                    f"{num_layers} stacked layers are not whole periods of "
-                    f"the {p}-layer pattern")
+                    f"{num_layers} stacked layers are not {lead} and whole "
+                    f"periods of the {p}-layer pattern")
             (x, kc, vc), _ = jax.lax.scan(
-                period_body, (x, kc, vc), jnp.arange(0, num_layers, p))
+                period_body, (x, kc, vc),
+                jnp.arange(n_dense + lead, n_dense + num_layers, p))
         new_cache = None if kv_cache is None else KVCache(k=kc, v=vc)
 
     with jax.named_scope("head"):

@@ -347,7 +347,8 @@ def _step_pages(P: int, pages_per_chunk: int, page_size: int) -> tuple:
 
 @functools.partial(
     jax.jit,
-    static_argnames=("page_size", "pages_per_chunk", "scale", "interpret"),
+    static_argnames=("page_size", "pages_per_chunk", "scale", "interpret",
+                     "window"),
 )
 def paged_decode_attention_latent(
     q_lat: jnp.ndarray,        # [B, Hq, r]  absorbed query q^ = q_nope W_kvb^K
@@ -361,6 +362,7 @@ def paged_decode_attention_latent(
     page_size: int,
     pages_per_chunk: int = 8,
     interpret: bool = False,
+    window: int | None = None,
 ) -> jnp.ndarray:
     """Decode-step latent (MLA) attention in the absorbed form, straight off
     the paged pools: per lane Hq query rows against ONE row a token whose r
@@ -370,7 +372,11 @@ def paged_decode_attention_latent(
     is the latent chunk the scores already hold.  Returns o^ [B, Hq, r] in
     q_lat.dtype: the caller applies W_kvb^V.  A kernel name of its own
     (`paged_decode_attention_latent`), so a device trace tells it from the
-    GQA calls."""
+    GQA calls.  `window` (static): a sliding-window latent layer, the query
+    at seq_len attends seq_len - window < kv_pos <= seq_len and the walk
+    starts at the chunk that holds the window's first key, as
+    `paged_decode_attention_window`'s does; that call is named
+    `paged_decode_attention_latent_window`."""
     B, Hq, r = q_lat.shape
     lanes = r_pool.shape[1]
     P = page_table.shape[1]
@@ -399,13 +405,14 @@ def paged_decode_attention_latent(
     )
     kernel = functools.partial(
         _decode_kernel, page_size=page_size, pages_per_chunk=cp,
-        scale=scale, latent=True)
+        scale=scale, latent=True, window=window)
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hq, r), q_lat.dtype),
         interpret=interpret,
-        name="paged_decode_attention_latent",
+        name=("paged_decode_attention_latent" if window is None
+              else "paged_decode_attention_latent_window"),
     )(page_table, seq_lens, q, c_pool.reshape(-1, page_size, r),
       r_pool.reshape(-1, page_size, lanes))
 

@@ -1436,6 +1436,14 @@ class _AggregateMetrics:
                 s["engine"]["decode_keys_walked"] for s in snaps),
             "decode_keys_window": sum(
                 s["engine"]["decode_keys_window"] for s in snaps),
+            "index_keys_scored": sum(
+                s["engine"]["index_keys_scored"] for s in snaps),
+            "index_keys_kept": sum(
+                s["engine"]["index_keys_kept"] for s in snaps),
+            "experts_held": sum(
+                s["engine"]["experts_held"] for s in snaps),
+            "experts_routed": sum(
+                s["engine"]["experts_routed"] for s in snaps),
         }
         if all("prefix_cache" in s for s in snaps):
             agg["prefix_cache"] = {

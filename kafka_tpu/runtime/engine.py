@@ -713,6 +713,12 @@ class InferenceEngine:
                 ("a tp / ep mesh", sharded and self._pp == 1 and sp == 1,
                  "the latent row is shared by all heads and cannot be split "
                  "by head; serve each replica on one device (dp)"),
+                ("a KV tier (kv_host_tier_mb / kv_object_dir)",
+                 cfg.by_kind and bool(
+                     self.ecfg.kv_host_tier_mb or self.ecfg.kv_object_dir),
+                 "the page shipper moves pages of one row width and this "
+                 "model's kinds of layer store rows of different widths; "
+                 "serve without a tier"),
             )
             for path, hit, why in refused:
                 if hit:
@@ -841,7 +847,7 @@ class InferenceEngine:
             # CPU-mesh tests deliberately use tiny unaligned shapes.
             if jax.default_backend() == "tpu":
                 tp = mesh.shape.get("tp", 1)
-                merged_kv = cfg.kv_row_widths[0]
+                merged_kv = cfg.kv_row_widths()[0]
                 if (merged_kv // tp) % 128 != 0:
                     raise ValueError(
                         "attention_backend='pallas' needs the per-shard "
@@ -1146,7 +1152,10 @@ class InferenceEngine:
             from ..models.quant import param_bytes as _param_bytes
             from .planner import device_peaks, dispatch_cost_model
 
-            kv_b = int(getattr(self.k_pool.dtype, "itemsize", 2))
+            # (a pool per kind of layer is a dict of arrays of one dtype)
+            pool = (jax.tree.leaves(self.k_pool)[0] if cfg.by_kind
+                    else self.k_pool)
+            kv_b = int(getattr(pool.dtype, "itemsize", 2))
             self._cost_model = dispatch_cost_model(
                 cfg,
                 n_devices=n_dev,
@@ -1210,6 +1219,13 @@ class InferenceEngine:
         # gathers (lanes x max_pages_per_seq x page_size).
         self.decode_keys_walked = 0
         self.decode_keys_window = 0
+        # Monotonic, and 0 for a model without an indexer
+        # (StepPrograms.index_keys): the keys a layer's indexer scored over
+        # every dispatched decode step (each lane's context, its own row
+        # included) and the keys attention then read (at most index_topk a
+        # lane).  kept / scored is what the selection keeps.
+        self.index_keys_scored = 0
+        self.index_keys_kept = 0
         self._rtt_est = self._measure_rtt()
 
     def kv_window_dead_share(self) -> float:
@@ -1282,7 +1298,7 @@ class InferenceEngine:
             # the flash kernel off QTensor pools).
             if choice != "auto":
                 return choice
-            merged_kv = cfg.kv_row_widths[0]
+            merged_kv = cfg.kv_row_widths()[0]
             if mesh is not None and mesh.size > 1:
                 from ..ops.pallas import pallas_mesh_ok
 
@@ -1309,11 +1325,12 @@ class InferenceEngine:
             # is no flash prefill over latent rows, so no VMEM rule
             return "pallas" if (
                 jax.default_backend() == "tpu"
-                and all(w % 128 == 0 for w in cfg.kv_row_widths)
+                and all(w % 128 == 0 for kind in cfg.kinds
+                        for w in cfg.kv_row_widths(kind))
                 and ecfg.page_size % 16 == 0
             ) else "xla"
         merged_q = cfg.num_heads * cfg.head_dim
-        merged_kv = cfg.kv_row_widths[0]
+        merged_kv = cfg.kv_row_widths()[0]
         if mesh is not None and mesh.size > 1:
             # mesh path: the decode kernel runs per-shard via shard_map
             # (paged_decode_attention_sharded); prefill keeps the XLA
@@ -3851,6 +3868,11 @@ class InferenceEngine:
             steps)
         self.decode_keys_walked += walked
         self.decode_keys_window += window
+        if self.cfg.index_topk:
+            scored, kept = self._programs.index_keys(
+                [m.seq.length for m in members if m is not None], steps)
+            self.index_keys_scored += scored
+            self.index_keys_kept += kept
         # decode-span inputs, computed lazily on the FIRST traced member:
         # an all-untraced dispatch pays one branch per lane, nothing else
         now_mono: Optional[float] = None

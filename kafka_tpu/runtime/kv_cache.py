@@ -5,7 +5,9 @@ state lived inside remote providers).  Here the KV pool is two device arrays
 [L, num_pages * page_size, row] whose row widths `ModelConfig.kv_row_widths`
 defines (GQA: Hkv*D each, heads merged into the minor axis — the lane-tile
 alignment the Pallas paged kernel's DMAs require; latent attention: the
-latent c~ in one, the roped k_r in the other; see make_kv_pool_arrays);
+latent c~ in one, the roped k_r in the other; a model whose kinds of layer
+store different rows has a pool pair per kind under the one page table; see
+make_kv_pool_arrays);
 sequences own ordered lists of physical pages.  The
 host-side allocator is refcounted so pages can be shared between sequences —
 the mechanism behind thread-keyed cache reuse and prefix sharing (BASELINE
@@ -219,7 +221,7 @@ def make_kv_pool_arrays(
     if quantize == "int8":
         from ..models.quant import QTensor
 
-        def pool(width):
+        def pool(width, lead=lead):
             return QTensor(
                 q=jnp.zeros(lead + (width,), jnp.int8),
                 s=jnp.zeros(lead + (1,), jnp.float32),
@@ -227,10 +229,27 @@ def make_kv_pool_arrays(
     elif quantize:
         raise ValueError(f"unknown kv quantize mode {quantize!r}")
     else:
-        def pool(width):
+        def pool(width, lead=lead):
             return jnp.zeros(lead + (width,), dtype)
 
-    k_width, v_width = cfg.kv_row_widths
+    if cfg.by_kind:
+        # Two kinds of row under ONE page table: a pool pair per kind of
+        # layer, [layers of the kind, SLOTS, row], and the indexer's key
+        # rows beside them.  A page id means the same tokens in every
+        # layer of every kind, so the allocator, the page tables and the
+        # prefix cache never see the difference.
+        from ..models.llama import INDEX
+
+        k, v = {}, {}
+        for kind in cfg.kinds:
+            widths = cfg.kv_row_widths(kind)
+            of_kind = (cfg.layers_of(kind), num_pages * page_size)
+            k[kind] = pool(widths[0], of_kind)
+            v[kind] = pool(widths[1], of_kind)
+            if len(widths) > 2:
+                v[INDEX] = pool(widths[2], of_kind)
+        return k, v
+    k_width, v_width = cfg.kv_row_widths()
     return pool(k_width), pool(v_width)
 
 

@@ -1426,6 +1426,13 @@ class EngineMetrics:
                 "prefill_rows_filled": engine.prefill_rows_filled,
                 "decode_keys_walked": engine.decode_keys_walked,
                 "decode_keys_window": engine.decode_keys_window,
+                "index_keys_scored": engine.index_keys_scored,
+                "index_keys_kept": engine.index_keys_kept,
+                # gauges: the experts this engine holds and computes, of
+                # those its router chooses among (equal unless the model
+                # is one chip's share of an expert-parallel layer)
+                "experts_held": engine.cfg.num_experts,
+                "experts_routed": engine.cfg.num_router_experts,
             }
             if engine.prefix_cache is not None:
                 pc = engine.prefix_cache

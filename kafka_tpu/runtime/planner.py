@@ -275,23 +275,34 @@ def _latent_weight_bytes(cfg: ModelConfig, mat, wb: int) -> int:
     """Weights of a latent-attention model on its one device (the engine
     refuses a mesh over the latent pool): models/llama._init_latent_params'
     tree, leaf by leaf."""
-    h, hq, r = cfg.hidden_size, cfg.num_heads, cfg.kv_lora_rank
-    dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    h = cfg.hidden_size
 
     def mlp(f: int) -> int:
         return 2 * mat(h, f, 1) + mat(f, h, 1)
 
-    attn = (mat(h, hq * (dn + dr), 1) + mat(h, r + dr, 1)
-            + mat(r, hq * (dn + dv), 1) + mat(hq * dv, h, 1)
-            + (2 * h + r) * wb)
+    def attn(kind: str) -> int:
+        g = cfg.geometry_of(kind)
+        hq, r, rq = g.num_heads, g.kv_lora_rank, g.q_lora_rank
+        dn, dr, dv = g.qk_nope_head_dim, g.qk_rope_head_dim, g.v_head_dim
+        query = (mat(h, rq, 1) + rq * wb + mat(rq, hq * (dn + dr), 1)
+                 if rq else mat(h, hq * (dn + dr), 1))
+        extra = mat(h, hq, 1) if cfg.attention_gate else 0
+        if cfg.has_indexer(kind):
+            hi, di = cfg.index_n_heads, cfg.index_head_dim
+            extra += (mat(rq or h, hi * di, 1) + mat(h, di, 1)
+                      + mat(h, hi, 1) + 2 * di * wb)
+        return (query + mat(h, r + dr, 1) + mat(r, hq * (dn + dv), 1)
+                + mat(hq * dv, h, 1) + (2 * h + r) * wb + extra)
+
     if cfg.is_moe:
-        ffn = (h * cfg.num_experts * wb + cfg.num_experts * 4  # router, bias
+        routed = cfg.num_router_experts
+        ffn = (h * routed * wb + routed * 4  # router, bias
                + cfg.num_experts * mlp(cfg.intermediate_size)
                + (mlp(cfg.shared_intermediate_size)
                   if cfg.shared_intermediate_size else 0))
     else:
         ffn = mlp(cfg.intermediate_size)
-    total = (cfg.num_layers * attn
+    total = (sum(cfg.layers_of(kind) * attn(kind) for kind in cfg.kinds)
              + (cfg.num_layers - cfg.first_k_dense) * ffn
              + cfg.first_k_dense * mlp(cfg.dense_intermediate_size))
     total += 2 * cfg.vocab_size * h * wb if not cfg.tie_word_embeddings \
@@ -313,8 +324,8 @@ def kv_pool_bytes_per_device(
     rows `cfg.kv_row_widths` gives."""
     kv_shard = _kv_shard(cfg, tp, kv_shard)
     slots = num_pages * page_size
-    b = sum(cfg.num_layers // pp * slots * w // kv_shard
-            for w in cfg.kv_row_widths) * _bytes(kv_dtype)
+    b = (cfg.kv_values_per_token // pp * slots // kv_shard
+         * _bytes(kv_dtype))
     if kv_dtype == "int8":
         # per-slot f32 scales, k and v (int8 KV quantization tier)
         b += cfg.num_layers // pp * slots * 2 * 4
@@ -326,10 +337,7 @@ def kv_bytes_per_token(
     kv_dtype: str = "bfloat16", kv_shard: Optional[int] = None,
 ) -> int:
     kv_shard = _kv_shard(cfg, tp, kv_shard)
-    return sum(
-        cfg.num_layers // pp * w // kv_shard * _bytes(kv_dtype)
-        for w in cfg.kv_row_widths
-    )
+    return cfg.kv_values_per_token // pp // kv_shard * _bytes(kv_dtype)
 
 
 def activation_bytes_estimate(
@@ -352,7 +360,8 @@ def activation_bytes_estimate(
     Decode: B * V * 4 * 3 (logits + top-k sort workspace ~2 copies).
     """
     V, H, F = cfg.vocab_size, cfg.hidden_size, cfg.intermediate_size
-    kv_row = sum(cfg.kv_row_widths)  # k + v values of one token, one layer
+    # k + v values of one token, one layer (the widest kind's)
+    kv_row = max(sum(cfg.kv_row_widths(kind)) for kind in cfg.kinds)
     s_local = max(1, prefill_bucket // max(sp, 1))
     prefill = (
         s_local * (V // tp) * 4
@@ -565,7 +574,7 @@ def dispatch_cost_model(
     # params from the unsharded bf16 arithmetic (stable vs quantization)
     params_total = weight_bytes_per_device(cfg, tp=1) / wb
     n = max(1, n_devices)
-    kv_row = cfg.num_layers * sum(cfg.kv_row_widths) * kv_dtype_bytes
+    kv_row = cfg.kv_values_per_token * kv_dtype_bytes
     # per (query, key) pair and head: 2 flops a value of the score and of
     # the weighted sum.  GQA: D + D.  Latent, absorbed: the score runs over
     # the latent and the rotary lanes, the sum over the latent ones.

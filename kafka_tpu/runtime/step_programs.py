@@ -483,6 +483,20 @@ class StepPrograms:
         chunks = sum(-(-(max_len + i + 1) // ck) for i in range(steps))
         return self.B * chunks * ck, self.B * steps * self.P * self.ps
 
+    def index_keys(self, lengths, steps: int) -> Tuple[int, int]:
+        """(keys scored, keys kept) by ONE layer's indexer over `steps`
+        decode steps of lanes holding `lengths` tokens before the first: a
+        step scores the lane's whole context, its own new row included, and
+        keeps at most `index_topk` of it (models/llama.py
+        _paged_index_choice).  (0, 0) for a model without an indexer."""
+        topk = self.cfg.index_topk
+        if not topk:
+            return 0, 0
+        scored = sum(n + i + 1 for n in lengths for i in range(steps))
+        kept = sum(min(n + i + 1, topk) for n in lengths
+                   for i in range(steps))
+        return scored, kept
+
     def decode(self, fsm: Optional[Fsm] = None):
         """One token for every active lane: fn(params, k_pool, v_pool,
         lanes, allowed_mask [B, V] | None, forced ([B] tokens, [B] on-mask)

@@ -425,7 +425,7 @@ def build_tpu_provider(cfg: ServingConfig) -> LLMProvider:
         )
     provider = TPULLMProvider(
         engine, tokenizer, model_name=cfg.model_name,
-        vision_params=vision_params,
+        vision_params=vision_params, ignore_eos=cfg.ignore_eos,
     )
     # the startup plan (actual model_cfg, live-device HBM) rides along so
     # /health reports the numbers this deployment was validated against
@@ -1328,7 +1328,7 @@ async def health(request: web.Request) -> web.Response:
                     "num_heads": mc.num_heads,
                     "num_kv_heads": mc.num_kv_heads,
                     "head_dim": mc.head_dim,
-                    "kv_row_widths": list(mc.kv_row_widths),
+                    "kv_row_widths": list(mc.kv_row_widths()),
                     "intermediate_size": mc.intermediate_size,
                     "vocab_size": mc.vocab_size,
                     "dtype": mc.dtype,

@@ -208,6 +208,12 @@ class ServingConfig:
     # use it to keep the served prompt a realistic size under the
     # byte-level tokenizer.
     system_prompt: Optional[str] = None
+    # A reply ends at its `max_tokens` only: a sampled stop token is text
+    # like any other (vLLM's `ignore_eos`).  For random weights, whose stop
+    # tokens mean nothing: a load generator then gets the lengths it asked
+    # for.  No environment variable: a configuration file's `serving` group
+    # (or the caller) sets it.
+    ignore_eos: bool = False
     # compile the serving programs at boot (one tiny generation per engine)
     # so the first real request doesn't pay the 20-40s XLA compile
     warmup: bool = True

@@ -160,6 +160,13 @@ DEVICE_SCOPES = (
                     # what the latent form adds around attention proper (the
                     # absorb q^ and un-absorb W_kvb^V einsums in decode, the
                     # expansion of cached rows through W_kvb elsewhere)
+    "attn_index",   # learned key selection (full layers of a model with an
+                    # indexer): the indexer's projections, its scores over
+                    # the lane's live keys and the exact top-k
+    "attn_select",  # inside attn_core, decode of such a layer: the read of
+                    # the chosen rows (attention over them stays attn_core)
+    "attn_gate",    # headwise output gate: sigmoid(x W_g) times each
+                    # head's output, ahead of W_o
     "attn_gather",  # XLA paths only, inside attn_core: the page/slot
                     # gather that materialises the attention window
     "attn_out",     # output projection + residual add

@@ -5,7 +5,7 @@
     python scripts/lowered_programs.py diff A B
 
 `dump` drives an engine per tiny preset (`tiny-gqa`, `tiny-moe`, the tiny
-Mellum2 and Kanana-2 under benchmarks/tests/) and attention backend (`xla`,
+Mellum2, Kanana-2 and dots3 under benchmarks/tests/) and attention backend (`xla`,
 `pallas`, which lowers in interpret mode off the chip) through single and
 batched prefill, the decode step (plain, host-masked, forced tokens), the
 fused multi-step scan, speculative verify (not on the windowed preset, which
@@ -48,10 +48,13 @@ def _presets():
         "benchmarks/tests/mellum2/configs/tiny-mellum2.json")
     kanana = config_from_hf_json(
         "benchmarks/tests/kanana2/configs/tiny-kanana2.json")
+    dots3 = config_from_hf_json(
+        "benchmarks/tests/dots3/configs/tiny-dots3.json")
     for name, cfg in (("tiny-gqa", get_config("tiny-gqa")),
                       ("tiny-moe", get_config("tiny-moe")),
                       ("tiny-mellum2", mellum),
-                      ("tiny-kanana2", kanana)):
+                      ("tiny-kanana2", kanana),
+                      ("tiny-dots3", dots3)):
         for backend in ("xla", "pallas"):
             yield name, backend, dataclasses.replace(
                 cfg, attention_backend=backend)

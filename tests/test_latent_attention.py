@@ -470,13 +470,15 @@ def test_config_from_hf_json_reads_the_catalogs_keys(tmp_path):
     assert cfg.layer_types == () and not cfg.is_windowed
     assert not cfg.tie_word_embeddings and cfg.vocab_size == 128256
     # 512 latent values, and the 64 rotary ones padded to a lane tile
-    assert cfg.kv_row_widths == (512, 128)
+    assert cfg.kv_row_widths() == (512, 128)
     hash(cfg)  # a static argument of every jitted step
     assert _load(tmp_path).num_layers == 6  # the benchmark's cut
 
 
 @pytest.mark.parametrize("changes, word", [
-    ({"q_lora_rank": 1536}, "q_lora_rank"),
+    # (a query low-rank is served since PR 33: tests/
+    # test_sparse_latent_attention.py)
+    ({"attention_gate_type": "elementwise"}, "attention_gate_type"),
     ({"n_group": 8}, "n_group"),
     ({"topk_group": 4}, "topk_group"),
     ({"rope_scaling": {"type": "yarn", "factor": 40}}, "rope_scaling"),
@@ -499,7 +501,7 @@ def test_config_from_hf_json_refuses_what_is_not_served(tmp_path, changes,
 
 def test_pool_arrays_and_plan_follow_the_row_widths(model):
     cfg, _ = model
-    assert cfg.kv_row_widths == (32, 128)
+    assert cfg.kv_row_widths() == (32, 128)
     k, v = make_kv_pool_arrays(cfg, 8, 4)
     assert k.shape == (3, 32, 32) and v.shape == (3, 32, 128)
     per_token = 3 * (32 + 128) * 4  # layers x values x float32
@@ -507,7 +509,7 @@ def test_pool_arrays_and_plan_follow_the_row_widths(model):
     assert planner.kv_pool_bytes_per_device(
         cfg, num_pages=8, page_size=4, kv_dtype="float32") == 32 * per_token
     gqa = CONFIGS["tiny-gqa"]
-    assert gqa.kv_row_widths == (32, 32)
+    assert gqa.kv_row_widths() == (32, 32)
     assert [a.shape for a in make_kv_pool_arrays(gqa, 8, 4)] == \
         [(2, 32, 32)] * 2
 

@@ -162,6 +162,38 @@ class TestStreaming:
         assert resp.role == "assistant"
 
 
+class TestIgnoreEos:
+    """ServingConfig.ignore_eos (vLLM's): a sampled stop token does not end
+    the reply, which runs to the `max_tokens` its caller asked for."""
+
+    @pytest.mark.parametrize("ignore_eos, reason, n_tokens",
+                             [(False, "stop", 3), (True, "length", 8)])
+    def test_reply_length(self, provider, ignore_eos, reason, n_tokens):
+        tok = provider.tokenizer
+        script_ids = tok.encode("ab") + [tok.eot_id] + tok.encode("cdefg")
+
+        def mask(output_ids):
+            return [script_ids[len(output_ids)]]
+
+        p = TPULLMProvider(provider.engine, tok, model_name="tiny-test",
+                           worker=provider.worker, ignore_eos=ignore_eos)
+        assert p.stop_token_ids == (() if ignore_eos else tuple(tok.stop_ids))
+        resp = run(p.completion([{"role": "user", "content": "go"}],
+                                max_tokens=8, temperature=0.0,
+                                logits_mask_fn=mask))
+        assert resp.finish_reason == reason
+        assert resp.usage["completion_tokens"] == n_tokens
+        # a stop token renders as nothing either way
+        assert resp.content == ("ab" if not ignore_eos else "abcdefg")
+
+    def test_no_environment_variable_only_the_key(self, monkeypatch):
+        from kafka_tpu.server.config import ServingConfig
+
+        monkeypatch.setenv("KAFKA_TPU_IGNORE_EOS", "1")
+        assert ServingConfig.from_env().ignore_eos is False
+        assert ServingConfig.from_env(ignore_eos=True).ignore_eos is True
+
+
 class TestToolCallDecoding:
     def test_constrained_tool_call_stream(self, provider):
         """Force the model to emit a tool-call JSON via constrained decoding
