@@ -1043,7 +1043,8 @@ def _latent_attention_block(
                         as_mask=True)
             out = _latent_prefill_walk(
                 q_nope, q_rope, wkvb, k_cache, v_cache, paged, positions,
-                scale, dn, k_rope.shape[-1], window, chosen_of)
+                scale, dn, k_rope.shape[-1], window, chosen_of,
+                kernel=cfg.attention_backend == "pallas")
         else:
             with jax.named_scope("attn_latent_proj"):
                 kv = jnp.einsum("btr,nrd->btnd", c_win, wkvb)
@@ -1214,20 +1215,32 @@ INDEX_WALK_KEYS = 2048
 PREFILL_WALK_KEYS = 1024
 
 
-def _walk_chunks(paged: "PagedView", keys: int, queries: int):
-    """(pages a trip, padded page table, trips): a walk over the page
-    table's LIVE part in chunks of about `keys` keys (fewer where `queries`
-    rows a lane would make a trip's scores large), up to the longest lane's
-    last valid key: the bound is computed on the device."""
+def walk_pages(P: int, ps: int, keys: int, queries: int = 1) -> int:
+    """Pages a trip of a walk over a page table of width P reads: about
+    `keys` keys, fewer where `queries` rows would make a trip's f32 scores
+    large.  Plain ints: the engine counts trips with it on the host."""
+    keys = max(ps, min(keys, (1 << 19) // max(queries, 1)))
+    return max(1, min(keys // ps, P))
+
+
+def prefill_walk_pages(P: int, ps: int, queries: int, kernel: bool) -> int:
+    """Pages a trip of `_latent_prefill_walk`: PREFILL_WALK_KEYS keys where
+    the Pallas kernel folds (no score tensor to bound), fewer at many
+    `queries` where XLA does."""
+    return walk_pages(P, ps, PREFILL_WALK_KEYS, 1 if kernel else queries)
+
+
+def _walk_chunks(paged: "PagedView", cp: int):
+    """(padded page table, trips): a walk over the page table's LIVE part
+    in chunks of `cp` pages (`walk_pages`), up to the longest lane's last
+    valid key: the bound is computed on the device."""
     ps = paged.page_size
     P = paged.page_table.shape[1]
-    keys = max(ps, min(keys, (1 << 19) // max(queries, 1)))
-    cp = max(1, min(keys // ps, P))
     table = jnp.pad(paged.page_table, ((0, 0), (0, -P % cp)))
     n_keys = jnp.max(jnp.sum(paged.kv_valid, axis=-1))
     trips = jnp.minimum((n_keys + cp * ps - 1) // (cp * ps),
                         table.shape[1] // cp)
-    return cp, table, trips
+    return table, trips
 
 
 def _paged_index_choice(q_idx, w_idx, i_cache, paged: "PagedView", positions,
@@ -1243,8 +1256,9 @@ def _paged_index_choice(q_idx, w_idx, i_cache, paged: "PagedView", positions,
     b, s = q_idx.shape[:2]
     di = q_idx.shape[-1]
     C = paged.kv_positions.shape[1]
-    cp, table, trips = _walk_chunks(
-        paged, INDEX_WALK_KEYS, b * s if s > 1 else 1)
+    cp = walk_pages(paged.page_table.shape[1], ps, INDEX_WALK_KEYS,
+                    b * s if s > 1 else 1)
+    table, trips = _walk_chunks(paged, cp)
     ck = cp * ps
 
     def score(c, scores):
@@ -1299,7 +1313,8 @@ def _latent_window_pages(k_cache, v_cache, paged: "PagedView", window: int,
 
 def _latent_prefill_walk(q_nope, q_rope, wkvb, k_cache, v_cache,
                          paged: "PagedView", positions, scale: float, dn: int,
-                         dr: int, window: Optional[int], chosen_of):
+                         dr: int, window: Optional[int], chosen_of,
+                         kernel: bool = False):
     """Latent attention of a prefill chunk over the paged pool, expanded
     form, walking the keys chunk by chunk with a running max / sum in f32
     (PR 32's decode walk at s > 1): a trip gathers one chunk's pages,
@@ -1307,26 +1322,29 @@ def _latent_prefill_walk(q_nope, q_rope, wkvb, k_cache, v_cache,
     [Hq, S, window] scores never exist.  A query attends causal valid keys,
     narrowed to its window (walked from the chunk that holds the window's
     first key) or to `chosen_of` [B, S, C].  q_nope / q_rope [B, S, N, .];
-    returns [B, S, N, dv] in the query's dtype."""
+    returns [B, S, N, dv] in the query's dtype.
+
+    One algorithm, two executors of a trip's fold.  In XLA the [Hq, S, keys]
+    f32 scores and probabilities of a trip pass through HBM.  With `kernel`
+    (the Pallas backend) the fold is `latent_prefill_fold`: the score tile
+    stays in VMEM, rows in the lanes, so the queries, the accumulator and a
+    trip's values are held transposed ([.., d, rows] / [.., dv, keys]) and
+    the bucket is padded to whole lane tiles; a trip is PREFILL_WALK_KEYS
+    keys whatever the rows, there being no score tensor to bound."""
     ps, dt = paged.page_size, q_nope.dtype
     b, s, n = q_nope.shape[:3]
     dv = wkvb.shape[-1] - dn
-    cp, table, trips = _walk_chunks(paged, PREFILL_WALK_KEYS, b * s)
+    cp = prefill_walk_pages(paged.page_table.shape[1], ps, b * s, kernel)
+    table, trips = _walk_chunks(paged, cp)
     ck = cp * ps
     pad = table.shape[1] * ps - paged.kv_valid.shape[1]
     kv_valid = jnp.pad(paged.kv_valid, ((0, 0), (0, pad)))
     if chosen_of is not None:
         chosen_of = jnp.pad(chosen_of, ((0, 0), (0, 0), (0, pad)))
-    q = jnp.concatenate([q_nope, q_rope], axis=-1)
-    first = 0
-    if window is not None:
-        live = jnp.any(paged.kv_valid, axis=-1)
-        lo = jnp.min(jnp.where(live, positions[:, 0] - window + 1,
-                               jnp.iinfo(jnp.int32).max))
-        first = jnp.minimum(jnp.maximum(lo, 0) // ck, trips)
 
-    def fold(c, carry):
-        m, l, acc = carry
+    def chunk(c):
+        """Trip c's latent and rotary rows and who attends them
+        ([B, ck, r], [B, ck, dr], mask [B, S, ck])."""
         pages = jax.lax.dynamic_slice_in_dim(table, c * cp, cp, axis=1)
         pos = c * ck + jnp.arange(ck)[None, None, :]
         mask = (jax.lax.dynamic_slice_in_dim(kv_valid, c * ck, ck, 1)[:, None]
@@ -1339,34 +1357,72 @@ def _latent_prefill_walk(q_nope, q_rope, wkvb, k_cache, v_cache,
         c_win = _zero_unattended(_kv_read_pages(k_cache, pages, ps, dt), mask)
         r_win = _zero_unattended(
             _kv_read_pages(v_cache, pages, ps, dt)[..., :dr], mask)
-        with jax.named_scope("attn_latent_proj"):
-            kv = jnp.einsum("btr,nrd->btnd", c_win, wkvb)
-            # a head's whole key, [k_nope | k_r]: ONE score matmul a trip.
-            # The two partial products apart were two [Hq, S, keys] f32
-            # tensors through HBM and an add (23 + 13 ms a layer a 512-row
-            # launch against 13 for one: my chip run 3, PR 33)
-            keys = jnp.concatenate(
-                [kv[..., :dn], jnp.broadcast_to(
-                    r_win[:, :, None, :], kv.shape[:3] + (dr,))], axis=-1)
-        sc = jnp.einsum("bqnd,bknd->bnqk", q, keys,
-                        preferred_element_type=jnp.float32) * scale
-        sc = jnp.where(mask[:, None], sc, NEG_INF)
-        m_new = jnp.maximum(m, jnp.max(sc, axis=-1))
-        alpha = jnp.exp(m - m_new)
-        p = jnp.where(mask[:, None], jnp.exp(sc - m_new[..., None]), 0.0)
-        l = alpha * l + jnp.sum(p, axis=-1)
-        acc = alpha[..., None] * acc + jnp.einsum(
-            "bnqk,bknd->bnqd", p.astype(dt), kv[..., dn:],
-            preferred_element_type=jnp.float32)
-        return m_new, l, acc
+        return c_win, r_win, mask
 
+    if kernel:
+        from ..ops.pallas import latent_prefill_fold
+
+        rows = s + -s % 128  # whole lane tiles
+        lanes = ((0, 0), (0, 0), (0, 0), (0, rows - s))
+        qn_t = jnp.pad(jnp.transpose(q_nope, (0, 2, 3, 1)), lanes)
+        qr_t = jnp.pad(jnp.transpose(q_rope, (0, 2, 3, 1)), lanes)
+        w_k, w_v = wkvb[..., :dn], wkvb[..., dn:]
+
+        def fold(c, carry):
+            c_win, r_win, mask = chunk(c)
+            with jax.named_scope("attn_latent_proj"):
+                k_nope = jnp.einsum("btr,nrd->bntd", c_win, w_k)
+                v_t = jnp.einsum("btr,nrd->bndt", c_win, w_v)
+            bias = jnp.where(
+                jnp.pad(jnp.swapaxes(mask, 1, 2), lanes[1:]), 0.0, NEG_INF)
+            return latent_prefill_fold(
+                qn_t, qr_t, k_nope, r_win, v_t, bias, *carry, scale=scale,
+                interpret=jax.default_backend() != "tpu")
+
+        acc_shape, l_axis, out_axes = (b, n, dv, rows), 2, (0, 3, 1, 2)
+    else:
+        q = jnp.concatenate([q_nope, q_rope], axis=-1)
+
+        def fold(c, carry):
+            m, l, acc = carry
+            c_win, r_win, mask = chunk(c)
+            with jax.named_scope("attn_latent_proj"):
+                kv = jnp.einsum("btr,nrd->btnd", c_win, wkvb)
+                # a head's whole key, [k_nope | k_r]: ONE score matmul a
+                # trip.  The two partial products apart were two [Hq, S,
+                # keys] f32 tensors through HBM and an add (23 + 13 ms a
+                # layer a 512-row launch against 13 for one: my chip run 3,
+                # PR 33)
+                keys = jnp.concatenate(
+                    [kv[..., :dn], jnp.broadcast_to(
+                        r_win[:, :, None, :], kv.shape[:3] + (dr,))], axis=-1)
+            sc = jnp.einsum("bqnd,bknd->bnqk", q, keys,
+                            preferred_element_type=jnp.float32) * scale
+            sc = jnp.where(mask[:, None], sc, NEG_INF)
+            m_new = jnp.maximum(m, jnp.max(sc, axis=-1))
+            alpha = jnp.exp(m - m_new)
+            p = jnp.where(mask[:, None], jnp.exp(sc - m_new[..., None]), 0.0)
+            l = alpha * l + jnp.sum(p, axis=-1)
+            acc = alpha[..., None] * acc + jnp.einsum(
+                "bnqk,bknd->bnqd", p.astype(dt), kv[..., dn:],
+                preferred_element_type=jnp.float32)
+            return m_new, l, acc
+
+        rows, acc_shape, l_axis, out_axes = s, (b, n, s, dv), 3, (0, 2, 1, 3)
+
+    first = 0
+    if window is not None:
+        live = jnp.any(paged.kv_valid, axis=-1)
+        lo = jnp.min(jnp.where(live, positions[:, 0] - window + 1,
+                               jnp.iinfo(jnp.int32).max))
+        first = jnp.minimum(jnp.maximum(lo, 0) // ck, trips)
     _, l, acc = jax.lax.fori_loop(
         first, trips, fold,
-        (jnp.full((b, n, s), NEG_INF, jnp.float32),
-         jnp.zeros((b, n, s), jnp.float32),
-         jnp.zeros((b, n, s, dv), jnp.float32)))
-    out = acc / jnp.maximum(l, 1e-30)[..., None]
-    return jnp.transpose(out, (0, 2, 1, 3)).astype(dt)
+        (jnp.full((b, n, rows), NEG_INF, jnp.float32),
+         jnp.zeros((b, n, rows), jnp.float32),
+         jnp.zeros(acc_shape, jnp.float32)))
+    out = acc / jnp.expand_dims(jnp.maximum(l, 1e-30), l_axis)
+    return jnp.transpose(out, out_axes)[:, :s].astype(dt)
 
 
 def _latent_window(k_cache, v_cache, paged, dt):

@@ -17,6 +17,7 @@ Selection is driven by `ModelConfig.attention_backend`:
 """
 
 from .flash_prefill import paged_prefill_attention
+from .latent_prefill import latent_prefill_fold
 from .paged_attention import (
     paged_decode_attention,
     paged_decode_attention_int8,
@@ -30,6 +31,7 @@ from .paged_attention import (
 )
 
 __all__ = [
+    "latent_prefill_fold",
     "paged_decode_attention",
     "paged_decode_attention_int8",
     "paged_decode_attention_int8_sharded",

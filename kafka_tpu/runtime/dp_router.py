@@ -1440,6 +1440,10 @@ class _AggregateMetrics:
                 s["engine"]["index_keys_scored"] for s in snaps),
             "index_keys_kept": sum(
                 s["engine"]["index_keys_kept"] for s in snaps),
+            "prefill_walk_trips": sum(
+                s["engine"]["prefill_walk_trips"] for s in snaps),
+            "prefill_walk_kernel_trips": sum(
+                s["engine"]["prefill_walk_kernel_trips"] for s in snaps),
             "experts_held": sum(
                 s["engine"]["experts_held"] for s in snaps),
             "experts_routed": sum(

@@ -1226,6 +1226,13 @@ class InferenceEngine:
         # lane).  kept / scored is what the selection keeps.
         self.index_keys_scored = 0
         self.index_keys_kept = 0
+        # Monotonic, and 0 where prefill does not walk the keys in chunks
+        # (StepPrograms.prefill_walk_trips): the trips latent prefill's key
+        # walk looped over every dispatched launch and layer, and those of
+        # them whose fold ran as the Pallas kernel (equal on the Pallas
+        # backend, 0 on XLA).
+        self.prefill_walk_trips = 0
+        self.prefill_walk_kernel_trips = 0
         self._rtt_est = self._measure_rtt()
 
     def kv_window_dead_share(self) -> float:
@@ -3018,6 +3025,9 @@ class InferenceEngine:
             )
         self.prefill_rows_dispatched += W * bucket
         self.prefill_rows_filled += int(chunk_lens.sum())
+        self._count_walk_trips(
+            [(int(starts[i]), int(chunk_lens[i])) for i in range(len(reqs))],
+            W, bucket)
         self._accrue_prefill_modeled(self._record_prefill_cost([
             (int(chunk_lens[i]), int(starts[i])) for i in range(len(reqs))
         ]))
@@ -3155,6 +3165,7 @@ class InferenceEngine:
             )
         self.prefill_rows_dispatched += bucket
         self.prefill_rows_filled += chunk_len
+        self._count_walk_trips([(start, chunk_len)], 1, bucket)
         self._accrue_prefill_modeled(
             self._record_prefill_cost([(chunk_len, start)])
         )
@@ -3847,6 +3858,11 @@ class InferenceEngine:
         self._keep_fsm(fsm_out)
         self._d_last = toks if full else jnp.where(d_active, toks, self._d_last)
         return self._book_dispatch(toks, members, steps=1)
+
+    def _count_walk_trips(self, spans, width: int, bucket: int) -> None:
+        trips, folded = self._programs.prefill_walk_trips(spans, width, bucket)
+        self.prefill_walk_trips += trips
+        self.prefill_walk_kernel_trips += folded
 
     def _book_dispatch(
         self,

@@ -1428,6 +1428,9 @@ class EngineMetrics:
                 "decode_keys_window": engine.decode_keys_window,
                 "index_keys_scored": engine.index_keys_scored,
                 "index_keys_kept": engine.index_keys_kept,
+                "prefill_walk_trips": engine.prefill_walk_trips,
+                "prefill_walk_kernel_trips":
+                    engine.prefill_walk_kernel_trips,
                 # gauges: the experts this engine holds and computes, of
                 # those its router chooses among (equal unless the model
                 # is one chip's share of an expert-parallel layer)

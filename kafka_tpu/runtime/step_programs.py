@@ -26,7 +26,7 @@ import jax
 import jax.numpy as jnp
 
 from ..models.config import ModelConfig
-from ..models.llama import KVCache, PagedView, forward
+from ..models.llama import KVCache, PagedView, forward, prefill_walk_pages
 from ..ops.attention import decode_walk_pages
 from ..ops.sampling import (
     SamplingParams,
@@ -496,6 +496,33 @@ class StepPrograms:
         kept = sum(min(n + i + 1, topk) for n in lengths
                    for i in range(steps))
         return scored, kept
+
+    def prefill_walk_trips(self, spans, width: int,
+                           bucket: int) -> Tuple[int, int]:
+        """(trips, trips the Pallas kernel folds) of ONE prefill launch of
+        `width` lanes x `bucket` rows whose active lanes hold `spans`
+        [(start, chunk_len)]: what models/llama.py _latent_prefill_walk
+        loops, by the bounds its device loop computes, summed over the
+        layers that walk (a full layer from key 0 to the longest lane's last
+        key, a sliding layer from the chunk that holds the first window's
+        first key).  Equal where the fold runs in the kernel, the second 0
+        where it runs in XLA, (0, 0) where prefill does not walk: a model
+        whose kinds of layer do not differ."""
+        cfg = self.cfg
+        if not (cfg.by_kind and spans):
+            return 0, 0
+        kernel = cfg.attention_backend == "pallas"
+        cp = prefill_walk_pages(self.P, self.ps, width * bucket, kernel)
+        ck = cp * self.ps
+        trips = min(-(-max(s + n for s, n in spans) // ck), -(-self.P // cp))
+        total = 0
+        for kind in cfg.kinds:
+            window, first = cfg.window_of(kind), 0
+            if window is not None:
+                lo = min(s for s, _ in spans) - window + 1
+                first = min(max(lo, 0) // ck, trips)
+            total += cfg.layers_of(kind) * (trips - first)
+        return total, total if kernel else 0
 
     def decode(self, fsm: Optional[Fsm] = None):
         """One token for every active lane: fn(params, k_pool, v_pool,

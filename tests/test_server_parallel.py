@@ -85,6 +85,10 @@ class TestDPServing:
                 for key in ("decode_keys_walked", "decode_keys_window"):
                     keys = [r["engine"][key] for r in m["replicas"]]
                     assert m["engine"][key] == sum(keys) > 0
+                # a model whose prefill does not walk counts no trips
+                for key in ("prefill_walk_trips", "prefill_walk_kernel_trips"):
+                    keys = [r["engine"][key] for r in m["replicas"]]
+                    assert m["engine"][key] == sum(keys) == 0
                 # pooled latency percentiles, not zeroed placeholders
                 assert m["ttft_ms"]["p50"] > 0
 

@@ -662,12 +662,13 @@ def test_engine_is_token_exact_and_counts_what_it_keeps(model, backend):
     assert (scored, kept) == (6 + 7 + 21 + 22, 6 + 7 + 8 + 8)
 
 
-def test_prefix_hit_then_suffix_prefill_is_token_exact(model):
+@pytest.mark.parametrize("backend", ["xla", "pallas"])
+def test_prefix_hit_then_suffix_prefill_is_token_exact(model, backend):
     """A prefix hit hands a second thread the first one's pages: latent rows
     of both kinds and the indexer keys; the suffix's queries choose among
-    them."""
+    them, and the walk folds them in XLA or in the Pallas kernel."""
     cfg, params = model
-    eng = make_engine(cfg, params)
+    eng = make_engine(cfg, params, attention_backend=backend)
     rng = np.random.RandomState(24)
     shared = list(rng.randint(1, 128, size=24))
     first = GenRequest(request_id="A", prompt_ids=shared + [3, 7, 11],
