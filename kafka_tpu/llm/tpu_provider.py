@@ -42,7 +42,7 @@ from ..core.types import (
 from ..models.config import ModelConfig
 from ..models.tokenizer import BaseTokenizer, parse_tool_call_text
 from ..runtime.engine import GenRequest, InferenceEngine, TokenEvent
-from ..runtime.tracing import current as current_trace
+from ..tracing import current as current_trace
 from .base import LLMProvider, MessageLike, to_message_dicts
 from .constrained import grammar_ondevice_enabled as _grammar_ondevice_enabled
 from .utils import count_images

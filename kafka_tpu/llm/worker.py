@@ -32,7 +32,7 @@ from typing import Dict, Optional, Tuple
 
 from ..runtime.engine import AdmissionError, GenRequest, InferenceEngine, TokenEvent
 from ..runtime.failpoints import failpoint
-from ..runtime.tracing import add_event
+from ..tracing import add_event
 
 logger = logging.getLogger("kafka_tpu.llm.worker")
 

@@ -63,7 +63,7 @@ from .engine import (
 )
 from .kv_cache import OutOfPagesError
 from .metrics import DisaggMetrics, ReplicaSupervisorMetrics
-from .tracing import add_event
+from ..tracing import add_event
 
 logger = logging.getLogger("kafka_tpu.dp")
 
@@ -1444,6 +1444,16 @@ class _AggregateMetrics:
                 s["engine"]["prefill_walk_trips"] for s in snaps),
             "prefill_walk_kernel_trips": sum(
                 s["engine"]["prefill_walk_kernel_trips"] for s in snaps),
+            "fetch_depth_steps_sum": sum(
+                s["engine"]["fetch_depth_steps_sum"] for s in snaps),
+            "fetch_depth_samples": sum(
+                s["engine"]["fetch_depth_samples"] for s in snaps),
+            "fetch_blocked_s": round(sum(
+                s["engine"]["fetch_blocked_s"] for s in snaps), 6),
+            "fetch_pops": {
+                k: sum(s["engine"]["fetch_pops"][k] for s in snaps)
+                for k in snaps[0]["engine"]["fetch_pops"]
+            },
             "experts_held": sum(
                 s["engine"]["experts_held"] for s in snaps),
             "experts_routed": sum(

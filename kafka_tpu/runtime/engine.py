@@ -85,7 +85,7 @@ from .metrics import EngineMetrics
 from .prefix_cache import PrefixCache
 from .speculative import LaneSpeculator
 from .step_programs import Fsm, Lanes, StepPrograms
-from .tracing import (
+from ..tracing import (
     add_event,
     annotate,
     profiler_annotations_enabled,
@@ -431,7 +431,7 @@ class GenRequest:
     # None for resumed parked lanes — their pending token is host-known
     # (output_ids[-1]).
     pending_tok: Optional[Any] = None
-    # Request tracing (runtime/tracing.py): the trace context this request
+    # Request tracing (kafka_tpu/tracing.py): the trace context this request
     # carries — None = untraced, and every engine span site is then ONE
     # branch.  trace_last_t stamps the previous decode dispatch so
     # engine.decode spans tile the request's timeline at burst granularity.
@@ -523,6 +523,12 @@ class _Fetch:
     # async host copy starts at compute completion and lands ~RTT later —
     # t_ready + rtt_est is when popping becomes non-blocking
     t_ready: Optional[float] = None
+    # the first-fetch ledger's other two marks (metrics.ttft_fetch_stages):
+    # when the device began this dispatch, max(t0, the completion of the
+    # dispatch enqueued before it), kept by _note_ready; and when the
+    # entry left the FIFO (_process_entry)
+    t_start: Optional[float] = None
+    t_pop: Optional[float] = None
     spec: Optional[_SpecMeta] = None
     # Flight-recorder attribution (ISSUE 11): which utilization kind this
     # dispatch bills to and its modeled roofline seconds.  When the
@@ -1233,6 +1239,19 @@ class InferenceEngine:
         # backend, 0 on XLA).
         self.prefill_walk_trips = 0
         self.prefill_walk_kernel_trips = 0
+        # Monotonic: the host's run-ahead, sampled at every decode / fused
+        # / verify dispatch (_backlog_steps: steps in the FIFO the device
+        # has not been seen to finish, the number fetch_lag bounds); sum /
+        # samples is its mean over any window.
+        self.fetch_depth_steps_sum = 0
+        self.fetch_depth_samples = 0
+        # Monotonic: seconds the scheduler thread sat in a read whose
+        # transfer had not landed (_process_entry), and entries popped by
+        # what released them: the age-and-landed rule, the fetch_lag depth
+        # bound, a blocking drain, an out-of-order pop for a constrained
+        # lane (_pop_entry_now / _pop_through).
+        self.fetch_blocked_s = 0.0
+        self.fetch_pops = {"aged": 0, "depth": 0, "blocking": 0, "now": 0}
         self._rtt_est = self._measure_rtt()
 
     def kv_window_dead_share(self) -> float:
@@ -2246,6 +2265,8 @@ class InferenceEngine:
                     break
             popped = self._pending.pop(0)
             self._pending_steps -= popped.steps
+            self.fetch_pops["blocking" if block else
+                            "aged" if within_lag else "depth"] += 1
             emitted += self._process_entry(popped)
         if not self._pending:
             # empty pipeline: the next completion's measured latency
@@ -2257,8 +2278,19 @@ class InferenceEngine:
                 self.flight.note_pop(emitted)
 
     def _push_entry(self, entry: _Fetch) -> None:
+        if entry.kind != "prefill":
+            self.fetch_depth_steps_sum += self._backlog_steps()
+            self.fetch_depth_samples += 1
         self._pending.append(entry)
         self._pending_steps += entry.steps
+
+    def _backlog_steps(self) -> int:
+        """The host's run-ahead: steps dispatched into the FIFO that the
+        device has not been seen to finish, i.e. what a dispatch enqueued
+        now waits behind.  Completion is seen at poll cadence
+        (_stamp_ready), so this reads high by what finished since."""
+        return self._pending_steps - sum(
+            e.steps for e in self._pending if e.t_ready is not None)
 
     def _stamp_ready(self) -> None:
         """Record compute-completion times for the leading in-flight
@@ -2270,7 +2302,8 @@ class InferenceEngine:
             )():
                 self._note_ready(e, now)
 
-    def _note_ready(self, entry: _Fetch, now: float) -> None:
+    def _note_ready(self, entry: _Fetch, now: float,
+                    observed: bool = True) -> None:
         """Stamp one fetch's compute completion and derive its MEASURED
         device time (ISSUE 11): with in-order device execution a dispatch
         starts at max(its enqueue, the previous dispatch's completion),
@@ -2278,14 +2311,19 @@ class InferenceEngine:
         it.  Completions are observed at scheduler-poll cadence —
         several dispatches finishing between polls telescope into the
         first one's sample — so the per-kind SUMS (not the individual
-        samples) are the calibrated quantity the skew gauge reads."""
+        samples) are the calibrated quantity the skew gauge reads.
+        `observed` False: the entry was popped before a poll saw it done,
+        and `now` is the return of its read, which also holds the copy:
+        the stamps are kept for the first-fetch ledger and the chain,
+        and nothing is billed to the gauge."""
         entry.t_ready = now
         start = entry.t0
         if self._last_ready_t is not None and self._last_ready_t > start:
             start = self._last_ready_t
+        entry.t_start = start
         self._last_ready_t = now
         measured = now - start
-        if measured < 0.0 or measured > 10.0:
+        if not observed or measured < 0.0 or measured > 10.0:
             return  # clock weirdness / wedged device: not a calibration
         if entry.modeled_s is not None:
             self.metrics.record_measured_dispatch(
@@ -2323,6 +2361,7 @@ class InferenceEngine:
         """
         self._pending.remove(entry)
         self._pending_steps -= entry.steps
+        self.fetch_pops["now"] += 1
         n = self._process_entry(entry)
         if n:
             self.metrics.record_emit_burst(n)
@@ -2338,6 +2377,7 @@ class InferenceEngine:
         while self._pending:
             e = self._pending.pop(0)
             self._pending_steps -= e.steps
+            self.fetch_pops["now"] += 1
             n += self._process_entry(e)
             if e is entry:
                 break
@@ -2347,10 +2387,22 @@ class InferenceEngine:
     def _process_entry(self, entry: _Fetch) -> int:
         """Materialize one fetch (blocks if the transfer hasn't landed).
         Returns the number of tokens processed."""
-        t0 = time.monotonic()
-        raw = np.asarray(entry.arr)
+        t0 = entry.t_pop = time.monotonic()
+        if profiler_annotations_enabled():
+            # the scheduler thread's reads on the profiler's clock, by
+            # kind of entry (KAFKA_TPU_PROFILING; one bool read otherwise)
+            with jax.profiler.TraceAnnotation(f"kafka.fetch[{entry.kind}]"):
+                raw = np.asarray(entry.arr)
+        else:
+            raw = np.asarray(entry.arr)
         now = time.monotonic()
+        if entry.t_ready is None:
+            # popped before any poll saw its compute done (a blocking or
+            # forced pop): the return of the read is the first the host
+            # knows of the completion, and stands in for it
+            self._note_ready(entry, now, observed=False)
         if now - t0 > 0.001:
+            self.fetch_blocked_s += now - t0
             # The transfer hadn't landed when we popped.  dispatch→landed
             # (now - entry.t0) bounds the copy latency from above but also
             # includes device compute backlog, so an unclamped EWMA ratchets
@@ -2382,7 +2434,8 @@ class InferenceEngine:
                     continue
                 n += 1
                 self._process_token(
-                    req, int(row[i if row.size > 1 else 0]), finals[i]
+                    req, int(row[i if row.size > 1 else 0]), finals[i],
+                    entry,
                 )
         return n
 
@@ -2408,7 +2461,7 @@ class InferenceEngine:
                     self.metrics.record_wasted_token()
                     continue
                 n += 1
-                self._process_token(req, int(row[0]), finals[i])
+                self._process_token(req, int(row[0]), finals[i], entry)
                 continue
             m = int(row[meta.width])  # accepted candidates (0..cl)
             req.spec_ahead = 0
@@ -2446,7 +2499,7 @@ class InferenceEngine:
                 elif old_len + j + 2 >= self.ecfg.max_window:
                     final = "length"
                 n += 1
-                self._process_token(req, int(row[j]), final)
+                self._process_token(req, int(row[j]), final, entry)
                 if req.state == FINISHED:
                     # stop/limit cut the run short: the rest is discarded
                     self.metrics.record_wasted_token(emit - (j + 1))
@@ -2454,7 +2507,7 @@ class InferenceEngine:
         return n
 
     def _process_token(self, req: GenRequest, token: int,
-                       final_reason: Optional[str]) -> None:
+                       final_reason: Optional[str], entry: _Fetch) -> None:
         req.drained += 1
         if req.predicted:
             # singleton-mask chain reconciliation: the dispatch ran with a
@@ -2473,16 +2526,28 @@ class InferenceEngine:
             self.metrics.record_first_token(
                 req.first_token_time - req.submit_time
             )
-            self.metrics.record_ttft_breakdown(
+            stages = self.metrics.record_ttft_breakdown(
                 req.submit_time, req.t_prefill_start,
                 req.t_first_dispatch, req.first_token_time,
+                fetch_marks=(entry.t_start, entry.t_ready, entry.t_pop),
             )
-            if req.trace is not None and req.t_first_dispatch is not None:
-                # fetch+emit runway: first device dispatch -> first token
-                # on the host (the device->host copy's slice of TTFT)
+            if req.trace is not None and stages is not None:
+                # the first-fetch stage of TTFT (last prefill chunk
+                # dispatched -> first token on the host) as four
+                # contiguous spans; `emit`, the last, ends at the first
+                # token and carries the request's TTFT
+                wait, run, hold, emit = stages
+                t = time.time() - (run + hold + emit)
+                record_span(req.trace, "engine.dev_wait", wait, end=t,
+                            attrs=self._tattrs())
+                t += run
+                record_span(req.trace, "engine.dev_exec", run, end=t,
+                            attrs=self._tattrs())
+                t += hold
+                record_span(req.trace, "engine.hold", hold, end=t,
+                            attrs=self._tattrs())
                 record_span(
-                    req.trace, "emit",
-                    req.first_token_time - req.t_first_dispatch,
+                    req.trace, "emit", emit, end=t + emit,
                     attrs=self._tattrs(
                         ttft_ms=round(
                             (req.first_token_time - req.submit_time) * 1e3,
@@ -3050,7 +3115,9 @@ class InferenceEngine:
                         req.trace, "engine.prefill",
                         req.t_first_dispatch - (req.t_prefill_start
                                                 or req.t_first_dispatch),
-                        attrs=self._prefill_attrs(req, fused=True),
+                        attrs=self._prefill_attrs(
+                            req, fused=True,
+                            backlog_steps=self._backlog_steps()),
                     )
             if req.slot < 0:
                 # off-slot lane: park until a decode slot frees (_admit);
@@ -3188,7 +3255,8 @@ class InferenceEngine:
                     req.trace, "engine.prefill",
                     req.t_first_dispatch - (req.t_prefill_start
                                             or req.t_first_dispatch),
-                    attrs=self._prefill_attrs(req),
+                    attrs=self._prefill_attrs(
+                        req, backlog_steps=self._backlog_steps()),
                 )
         if slot < 0:
             req.state = PARKED

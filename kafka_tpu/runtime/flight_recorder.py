@@ -606,7 +606,7 @@ class FlightRecorder:
         )
         # punctuate the active requests' timelines (traced only; bounded)
         try:
-            from .tracing import add_event
+            from ..tracing import add_event
 
             n = 0
             for req in engine._requests.values():

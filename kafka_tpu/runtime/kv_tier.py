@@ -57,7 +57,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from .failpoints import failpoint
-from .tracing import record_span
+from ..tracing import record_span
 
 logger = logging.getLogger("kafka_tpu.kv_tier")
 

@@ -803,9 +803,43 @@ HISTOGRAM_NAMES = (
     "ttft_queue_ms",
     "ttft_prefill_ms",
     "ttft_fetch_ms",
+    "ttft_dev_wait_ms",
+    "ttft_dev_exec_ms",
+    "ttft_hold_ms",
+    "ttft_emit_ms",
     "burst_tokens",
     "burst_gap_ms",
 )
+
+
+def ttft_fetch_stages(first_dispatch: float, dev_start: Optional[float],
+                      ready: Optional[float], pop: Optional[float],
+                      first_token: float
+                      ) -> Tuple[float, float, float, float]:
+    """Tile first_dispatch -> first_token (the ttft_fetch_ms phase) into
+    (dev_wait, dev_exec, hold, emit) seconds at the three marks of the
+    entry that carried the first token:
+
+      dev_wait  -> the device starts the last prefill chunk: work the host
+                   had already queued ahead of it
+      dev_exec  -> its completion, as observed (a poll's is_ready, or the
+                   return of the read where the entry was popped unseen)
+      hold      -> the entry is popped: FIFO order, the age and landed
+                   rules, a scheduler thread blocked in another read
+      emit      -> the first token on the host: the read itself and the
+                   rows processed before this request's
+
+    Each mark is held between its predecessor and first_token, so the
+    four are never negative and add up to first_token - first_dispatch
+    whatever the marks: one the engine never stamped collapses onto its
+    predecessor, and a pop that came before the completion was seen (the
+    completion is then the read's return) leaves hold empty."""
+    out, prev = [], first_dispatch
+    for mark in (dev_start, ready, pop):
+        mark = prev if mark is None else min(max(mark, prev), first_token)
+        out.append(mark - prev)
+        prev = mark
+    return (*out, first_token - prev)
 
 
 @dataclasses.dataclass
@@ -893,6 +927,13 @@ class EngineMetrics:
         self.ttft_queue_ms = StreamingHistogram(LATENCY_MS_BOUNDS)
         self.ttft_prefill_ms = StreamingHistogram(LATENCY_MS_BOUNDS)
         self.ttft_fetch_ms = StreamingHistogram(LATENCY_MS_BOUNDS)
+        # the fetch phase tiled into four stages (ttft_fetch_stages):
+        # per request they sum to its ttft_fetch_ms sample, so the four
+        # means add up to that histogram's mean
+        self.ttft_dev_wait_ms = StreamingHistogram(LATENCY_MS_BOUNDS)
+        self.ttft_dev_exec_ms = StreamingHistogram(LATENCY_MS_BOUNDS)
+        self.ttft_hold_ms = StreamingHistogram(LATENCY_MS_BOUNDS)
+        self.ttft_emit_ms = StreamingHistogram(LATENCY_MS_BOUNDS)
         # token-emission cadence as the client sees it: how many tokens
         # arrive together when the fetch pipeline pops (burst size) and how
         # far apart those arrivals are (gap) — the honest view of stream
@@ -953,16 +994,27 @@ class EngineMetrics:
             self.ttft_ms.record(latency_s * 1e3)
 
     def record_ttft_breakdown(self, submit, prefill_start, first_dispatch,
-                              first_token) -> None:
-        """Split one request's TTFT into queue / prefill / fetch phases.
-        Missing stamps (cancelled mid-phase, legacy paths) record nothing."""
-        if not self.enabled:
-            return
+                              first_token, fetch_marks=(None, None, None),
+                              ) -> Optional[Tuple[float, float, float, float]]:
+        """Split one request's TTFT into queue / prefill / fetch phases,
+        and the fetch phase into its four stages (`fetch_marks`: the
+        device start, observed completion and pop of the entry that
+        carried the first token).  Returns the stages in seconds, for the
+        request's spans.  Missing stamps (cancelled mid-phase, legacy
+        paths) record nothing."""
         if None in (submit, prefill_start, first_dispatch, first_token):
-            return
-        self.ttft_queue_ms.record((prefill_start - submit) * 1e3)
-        self.ttft_prefill_ms.record((first_dispatch - prefill_start) * 1e3)
-        self.ttft_fetch_ms.record((first_token - first_dispatch) * 1e3)
+            return None
+        stages = ttft_fetch_stages(first_dispatch, *fetch_marks, first_token)
+        if self.enabled:
+            self.ttft_queue_ms.record((prefill_start - submit) * 1e3)
+            self.ttft_prefill_ms.record(
+                (first_dispatch - prefill_start) * 1e3)
+            self.ttft_fetch_ms.record((first_token - first_dispatch) * 1e3)
+            for hist, dur in zip(
+                (self.ttft_dev_wait_ms, self.ttft_dev_exec_ms,
+                 self.ttft_hold_ms, self.ttft_emit_ms), stages):
+                hist.record(dur * 1e3)
+        return stages
 
     def record_token(self) -> None:
         self.generated_tokens += 1
@@ -1431,6 +1483,13 @@ class EngineMetrics:
                 "prefill_walk_trips": engine.prefill_walk_trips,
                 "prefill_walk_kernel_trips":
                     engine.prefill_walk_kernel_trips,
+                # the fetch pipeline's run-ahead (steps, sum over samples
+                # at decode dispatches), the scheduler thread's time in
+                # unlanded reads, and pops by what released them
+                "fetch_depth_steps_sum": engine.fetch_depth_steps_sum,
+                "fetch_depth_samples": engine.fetch_depth_samples,
+                "fetch_blocked_s": round(engine.fetch_blocked_s, 6),
+                "fetch_pops": dict(engine.fetch_pops),
                 # gauges: the experts this engine holds and computes, of
                 # those its router chooses among (equal unless the model
                 # is one chip's share of an expert-parallel layer)

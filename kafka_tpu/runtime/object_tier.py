@@ -79,7 +79,7 @@ import numpy as np
 
 from .failpoints import failpoint
 from .store_guard import BREAKER_OPEN, StoreGuard, StoreGuardError
-from .tracing import record_span
+from ..tracing import record_span
 from ..tracing import sanitize_stem
 
 logger = logging.getLogger("kafka_tpu.object_tier")

@@ -422,7 +422,7 @@ class PrefixCache:
         reserved BEFORE the payload fetches, so pool pressure aborts
         without wasting store round-trips.  Returns True when at least
         one run was woken (the caller re-walks)."""
-        from .tracing import record_span
+        from ..tracing import record_span
 
         obj = self.tier.object
         ps = self.pool.page_size

@@ -120,6 +120,15 @@ _HISTOGRAM_FAMILIES = (
      "TTFT decomposition by phase.", {"phase": "prefill"}),
     ("ttft_fetch_ms", "kafka_tpu_ttft_phase_milliseconds",
      "TTFT decomposition by phase.", {"phase": "first_fetch"}),
+    # the first_fetch phase tiled into its four stages
+    ("ttft_dev_wait_ms", "kafka_tpu_ttft_phase_milliseconds",
+     "TTFT decomposition by phase.", {"phase": "dev_wait"}),
+    ("ttft_dev_exec_ms", "kafka_tpu_ttft_phase_milliseconds",
+     "TTFT decomposition by phase.", {"phase": "dev_exec"}),
+    ("ttft_hold_ms", "kafka_tpu_ttft_phase_milliseconds",
+     "TTFT decomposition by phase.", {"phase": "hold"}),
+    ("ttft_emit_ms", "kafka_tpu_ttft_phase_milliseconds",
+     "TTFT decomposition by phase.", {"phase": "emit"}),
     ("burst_tokens", "kafka_tpu_emission_burst_tokens",
      "Tokens arriving together per emission burst.", {}),
     ("burst_gap_ms", "kafka_tpu_emission_burst_gap_milliseconds",

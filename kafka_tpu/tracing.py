@@ -96,7 +96,15 @@ SPANS = (
     "engine.decode",  # one decode dispatch burst; attrs: steps, busy — and
                       # on speculative verify dispatches proposed/accepted
                       # (candidate tokens offered / kept that round) (engine)
-    "emit",           # first dispatch -> first token on host (engine)
+    # the fetch phase of TTFT (last prefill chunk dispatched -> first
+    # token on the host), tiled: four contiguous spans per request
+    "engine.dev_wait", # dispatch -> the device starts the chunk: what the
+                      # host had queued ahead of it (engine)
+    "engine.dev_exec", # device start -> completion observed (engine)
+    "engine.hold",    # completion -> the entry is popped (engine)
+    "emit",           # pop -> first token on host, the last of the four
+                      # (it starts at the pop, not at the dispatch); its END
+                      # is the request's TTFT (_check_slow), attr ttft_ms
     "sandbox.exec",   # tool execution INSIDE the sandbox subprocess
     "kv.demote",      # page run copied device->host under pressure; attrs:
                       # pages, bytes, overlap (runtime/kv_tier.py)

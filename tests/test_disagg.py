@@ -36,9 +36,10 @@ import pytest
 import jax
 import jax.numpy as jnp
 
+from kafka_tpu import tracing
 from kafka_tpu.models import ModelConfig, init_params
 from kafka_tpu.runtime import EngineConfig, GenRequest, InferenceEngine
-from kafka_tpu.runtime import failpoints, tracing
+from kafka_tpu.runtime import failpoints
 from kafka_tpu.runtime.dp_router import (
     PROBATION,
     DataParallelEngines,

@@ -162,6 +162,39 @@ class TestDPRouting:
         assert sup["states"] == [HEALTHY, HEALTHY]
         assert sup["quarantines"] == 0 and sup["readmits"] == 0
 
+    def test_merged_snapshot_holds_the_fetch_stage_ledger(self, model):
+        """The dp aggregate merges the four stage histograms that tile
+        ttft_fetch_ms (they are in HISTOGRAM_NAMES) and sums the fetch
+        pipeline's counters over the replicas."""
+        cfg, params = model
+        dp = DataParallelEngines(cfg, params, EngineConfig(**ECFG),
+                                 dp=2, tp=1, kv_dtype=jnp.float32)
+        for i in range(4):
+            dp.submit(GenRequest(request_id=f"m{i}", prompt_ids=[1 + i, 2, 3],
+                                 max_new_tokens=3))
+        dp.run_to_completion()
+        snap = dp.metrics.snapshot()
+        hists = snap["histograms"]
+        for name in ("ttft_dev_wait_ms", "ttft_dev_exec_ms", "ttft_hold_ms",
+                     "ttft_emit_ms"):
+            assert hists[name]["count"] == hists["ttft_fetch_ms"]["count"] == 4
+            assert hists[name]["count"] == sum(
+                r["histograms"][name]["count"] for r in snap["replicas"])
+        parts = sum(hists[n]["sum"] for n in (
+            "ttft_dev_wait_ms", "ttft_dev_exec_ms", "ttft_hold_ms",
+            "ttft_emit_ms"))
+        # snapshot sums are rounded to the microsecond, per histogram
+        assert abs(parts - hists["ttft_fetch_ms"]["sum"]) < 0.01
+        eng = snap["engine"]
+        for key in ("fetch_depth_steps_sum", "fetch_depth_samples",
+                    "fetch_blocked_s"):
+            assert eng[key] == pytest.approx(
+                sum(r["engine"][key] for r in snap["replicas"]))
+        assert eng["fetch_depth_samples"] > 0
+        assert sum(eng["fetch_pops"].values()) == sum(
+            sum(r["engine"]["fetch_pops"].values())
+            for r in snap["replicas"]) > 0
+
     def test_dp_composes_with_tp(self, model):
         """dp=2 replicas each running tp=2 SPMD — batch spread across
         TP groups, token-exact vs single device."""

@@ -33,6 +33,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
+from kafka_tpu import tracing
 from kafka_tpu.models import ModelConfig, init_params
 from kafka_tpu.runtime import (
     EngineConfig,
@@ -40,7 +41,7 @@ from kafka_tpu.runtime import (
     InferenceEngine,
     PagePool,
 )
-from kafka_tpu.runtime import failpoints, tracing
+from kafka_tpu.runtime import failpoints
 from kafka_tpu.runtime.kv_tier import (
     SHIP_BUCKETS,
     KVTierManager,

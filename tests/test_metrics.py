@@ -18,6 +18,7 @@ from kafka_tpu.runtime.metrics import (
     EngineMetrics,
     StreamingHistogram,
     _percentiles,
+    ttft_fetch_stages,
 )
 
 
@@ -415,6 +416,177 @@ class TestTTFTBreakdown:
         m = EngineMetrics()
         m.record_ttft_breakdown(1.0, None, 2.0, 3.0)
         assert m.ttft_queue_ms.count == 0
+
+
+STAGE_HISTS = ("ttft_dev_wait_ms", "ttft_dev_exec_ms", "ttft_hold_ms",
+               "ttft_emit_ms")
+
+
+class _SlowTokens:
+    """A fetch whose compute is never seen done and whose read blocks: what
+    a busy device looks like to the scheduler thread."""
+
+    def __init__(self, arr, read_s=0.004):
+        self.arr, self.read_s = arr, read_s
+
+    def is_ready(self):
+        return False
+
+    def __array__(self, dtype=None, copy=None):
+        import time
+
+        time.sleep(self.read_s)
+        return np.asarray(self.arr)
+
+
+def _ledger(engine):
+    """Fresh metrics on `engine`, with every record_ttft_breakdown call's
+    (fetch phase ms, its four stages ms) kept per request."""
+    engine.metrics = m = EngineMetrics()
+    rows = []
+    orig = m.record_ttft_breakdown
+
+    def keep(submit, start, dispatch, first, fetch_marks=(None,) * 3):
+        stages = orig(submit, start, dispatch, first, fetch_marks)
+        rows.append(((first - dispatch) * 1e3,
+                     [d * 1e3 for d in stages], fetch_marks))
+        return stages
+
+    m.record_ttft_breakdown = keep
+    return rows
+
+
+def _assert_tiled(engine, rows, n):
+    assert len(rows) == n
+    for fetch_ms, stages, _ in rows:
+        assert all(d >= 0.0 for d in stages), stages
+        assert abs(sum(stages) - fetch_ms) < 1e-6, (stages, fetch_ms)
+    snap = engine.metrics.snapshot(engine)["histograms"]
+    for name in STAGE_HISTS:
+        assert snap[name]["count"] == snap["ttft_fetch_ms"]["count"] == n
+    assert abs(sum(getattr(engine.metrics, h).sum for h in STAGE_HISTS)
+               - engine.metrics.ttft_fetch_ms.sum) < 1e-6 * n
+
+
+class TestFetchStageLedger:
+    """The first-fetch phase of TTFT tiled into dev_wait / dev_exec / hold
+    / emit (PR 35): per request the four sum to its ttft_fetch_ms sample on
+    every path a first token can take."""
+
+    @pytest.mark.parametrize("marks,expect", [
+        # every mark in order
+        ((11.0, 12.5, 13.0), (1.0, 1.5, 0.5, 1.0)),
+        # popped before the completion was seen: the read's return (13.5)
+        # is the completion, and hold is empty
+        ((11.0, 13.5, 13.0), (1.0, 2.5, 0.0, 0.5)),
+        # the device start derived before the request's dispatch stamp
+        ((9.0, 12.0, 13.0), (0.0, 2.0, 1.0, 1.0)),
+        # marks the engine never stamped collapse onto their predecessor
+        ((None, None, None), (0.0, 0.0, 0.0, 4.0)),
+        ((11.0, None, 13.0), (1.0, 0.0, 2.0, 1.0)),
+        # a mark past the first token (clock weirdness) is held to it
+        ((11.0, 15.0, 16.0), (1.0, 3.0, 0.0, 0.0)),
+    ])
+    def test_the_tile_is_exact_whatever_the_marks(self, marks, expect):
+        stages = ttft_fetch_stages(10.0, *marks, 14.0)
+        assert stages == pytest.approx(expect)
+        assert sum(stages) == pytest.approx(4.0, abs=1e-12)
+        assert all(d >= 0.0 for d in stages)
+
+    def test_single_prefill_path(self, engine):
+        rows = _ledger(engine)
+        pops = dict(engine.fetch_pops)
+        engine.generate([5, 9, 23, 4], max_new_tokens=4)
+        _assert_tiled(engine, rows, 1)
+        # the entry was stamped on the way: a device start, a completion
+        # (seen by a poll or by the read's return) and a pop
+        assert None not in rows[0][2]
+        assert sum(engine.fetch_pops.values()) > sum(pops.values())
+
+    def test_batched_prefill_path(self, engine):
+        rows = _ledger(engine)
+        calls = []
+        orig = engine._advance_prefill_batch
+        engine._advance_prefill_batch = (
+            lambda *a, **k: (calls.append(1), orig(*a, **k))[1])
+        try:
+            for i in range(2):
+                engine.submit(GenRequest(
+                    request_id=f"bp{i}", prompt_ids=[5 + i, 9, 23, 4],
+                    max_new_tokens=3))
+            engine.run_to_completion()
+        finally:
+            del engine._advance_prefill_batch
+        assert calls, "the two prompts did not share a batched launch"
+        _assert_tiled(engine, rows, 2)
+
+    def test_multi_chunk_prefill(self, engine):
+        rows = _ledger(engine)
+        prompt = list(np.random.RandomState(3).randint(1, 128, 45))
+        req = engine.generate(prompt, max_new_tokens=3)  # 32 + 16 buckets
+        _assert_tiled(engine, rows, 1)
+        # the phase starts at the LAST chunk's dispatch, and so does the
+        # ledger: its first stage cannot predate that stamp
+        assert req.t_first_dispatch > req.t_prefill_start
+
+    def test_host_constrained_request_pops_now(self, engine):
+        rows = _ledger(engine)
+        before = engine.fetch_pops["now"]
+        engine.submit(GenRequest(
+            request_id="hc", prompt_ids=[5, 2, 9], max_new_tokens=3,
+            logits_mask_fn=lambda out: [7, 11]))
+        engine.run_to_completion()
+        _assert_tiled(engine, rows, 1)
+        assert engine.fetch_pops["now"] > before
+        # popped at once, ahead of any poll: the completion is the return
+        # of the read, so nothing was held
+        assert rows[0][1][2] == 0.0
+
+    def test_blocking_drain(self, engine):
+        rows = _ledger(engine)
+        before = engine.fetch_pops["blocking"]
+        # one token: the request drains at its prefill, nothing is left to
+        # dispatch, and step() flushes the pipeline with a blocking drain
+        engine.generate([5, 9, 23, 4], max_new_tokens=1)
+        _assert_tiled(engine, rows, 1)
+        assert engine.fetch_pops["blocking"] > before
+
+    def test_counters_rise_under_a_slow_fetch(self, engine):
+        rows = _ledger(engine)
+        orig = engine._push_entry
+
+        def slow_push(entry):
+            entry.arr = _SlowTokens(entry.arr)
+            orig(entry)
+
+        engine._push_entry = slow_push
+        before = engine.metrics.snapshot(engine)["engine"]
+        try:
+            engine.generate([5, 9, 23, 4], max_new_tokens=12)
+        finally:
+            del engine._push_entry
+        after = engine.metrics.snapshot(engine)["engine"]
+        # every decode dispatch sampled the run-ahead, and with no
+        # completion ever seen the steps in flight all counted
+        assert after["fetch_depth_samples"] > before["fetch_depth_samples"]
+        assert (after["fetch_depth_steps_sum"]
+                > before["fetch_depth_steps_sum"])
+        assert after["fetch_blocked_s"] >= before["fetch_blocked_s"] + 0.004
+        assert (sum(after["fetch_pops"].values())
+                > sum(before["fetch_pops"].values()))
+        assert set(after["fetch_pops"]) == {"aged", "depth", "blocking",
+                                            "now"}
+        # unseen completions: each read's return stood in, hold is empty
+        _assert_tiled(engine, rows, 1)
+        assert rows[0][1][2] == 0.0
+
+    def test_disabled_telemetry_still_tiles_for_the_spans(self):
+        m = EngineMetrics()
+        m.enabled = False
+        stages = m.record_ttft_breakdown(1.0, 2.0, 3.0, 5.0,
+                                         (3.5, 4.0, 4.5))
+        assert stages == pytest.approx((0.5, 0.5, 0.5, 0.5))
+        assert m.ttft_fetch_ms.count == m.ttft_hold_ms.count == 0
 
     def test_forced_grammar_chains_without_roundtrips(self, engine):
         """A fully-forced grammar (singleton masks) never awaits a round

@@ -28,7 +28,7 @@ from kafka_tpu.runtime import (
     OutOfPagesError,
     PagePool,
 )
-from kafka_tpu.runtime import tracing
+from kafka_tpu import tracing
 from kafka_tpu.runtime.prefix_cache import PrefixCache
 
 

@@ -323,7 +323,9 @@ class TestHistogramExposition:
             families, samples, "kafka_tpu_ttft_phase_milliseconds"
         )
         phases = {dict(k)["phase"] for k in groups}
-        assert phases == {"queue_wait", "prefill", "first_fetch"}
+        # the three phases, and first_fetch's four stages beside it
+        assert phases == {"queue_wait", "prefill", "first_fetch",
+                          "dev_wait", "dev_exec", "hold", "emit"}
 
     def test_per_replica_histogram_series(self):
         """DP aggregates export each replica's histograms as labeled

@@ -293,9 +293,67 @@ class TestEngineSpans:
                    for s in decode)
         assert all(s.parent_id == root.span_id for s in tr.spans
                    if s.name.startswith("engine."))
-        # the emit span records the fetch/emit runway and stamps TTFT
+        # the emit span is the last stage of the fetch phase (the entry's
+        # pop -> first token on the host) and stamps TTFT
         emit = next(s for s in tr.spans if s.name == "emit")
         assert emit.attrs["ttft_ms"] > 0
+
+    def test_fetch_phase_is_four_contiguous_spans(self, engine):
+        """The first-fetch phase of TTFT (last prefill chunk dispatched ->
+        first token on the host) on a traced request: engine.dev_wait,
+        engine.dev_exec, engine.hold and emit, each starting where the one
+        before ended, inside http.request, the last ending at the first
+        token; the prefill span names the host's run-ahead."""
+        root = tracing.start_trace(request_id="e2")
+        req = GenRequest(request_id="er2", prompt_ids=[5, 9, 23, 4],
+                         max_new_tokens=4, trace=tracing.current())
+        engine.submit(req)
+        engine.run_to_completion()
+        tracing.finish_trace(root)
+        tr = tracing.get_trace("e2")
+        assert root.name == "http.request"
+        stages = [next(s for s in tr.spans if s.name == n)
+                  for n in ("engine.dev_wait", "engine.dev_exec",
+                            "engine.hold", "emit")]
+        for a, b in zip(stages, stages[1:]):
+            assert abs(b.t0 - a.t1) < 1e-6, (a.name, b.name)
+        for s in stages:
+            assert s.parent_id == root.span_id
+            assert root.t0 <= s.t0 <= s.t1 <= root.t1
+        # together they are the phase: first dispatch -> first token
+        whole = req.first_token_time - req.t_first_dispatch
+        assert abs((stages[-1].t1 - stages[0].t0) - whole) < 1e-6
+        assert "ttft_ms" not in stages[0].attrs
+        prefill = next(s for s in tr.spans if s.name == "engine.prefill")
+        assert prefill.attrs["backlog_steps"] >= 0
+        # and the phase follows the prefill span (two clock reads apart)
+        assert abs(stages[0].t0 - prefill.t1) < 0.05
+
+    def test_fetch_reads_are_annotated_by_kind(self, engine, monkeypatch):
+        """KAFKA_TPU_PROFILING=1: every read of the fetch pipeline sits in
+        a `kafka.fetch[<kind of entry>]` annotation, so a capture shows
+        when the scheduler thread was in a read; off, none is built."""
+        import contextlib
+
+        from kafka_tpu.runtime import engine as engine_mod
+
+        names = []
+        monkeypatch.setattr(
+            engine_mod.jax.profiler, "TraceAnnotation",
+            lambda name: names.append(name) or contextlib.nullcontext(),
+        )
+        engine.generate([5, 9, 23, 4], max_new_tokens=3)
+        assert names == []
+        tracing.configure(profiling=True)
+        try:
+            engine.generate([5, 9, 23, 4], max_new_tokens=3)
+        finally:
+            tracing.configure(profiling=False)
+        fetches = [n for n in names if n.startswith("kafka.fetch[")]
+        assert "kafka.fetch[prefill]" in fetches
+        assert "kafka.fetch[decode]" in fetches or any(
+            n.startswith("kafka.fetch[") and n != "kafka.fetch[prefill]"
+            for n in fetches)
 
     def test_profiler_annotation_scope_keyed_by_trace_id(self, engine):
         """KAFKA_TPU_PROFILING=1: decode dispatches run inside a
@@ -549,6 +607,8 @@ class TestSlowRequests:
         assert tracing.slow_count() == 0
 
     def test_ttft_threshold_uses_emit_span(self):
+        # the END of `emit` is the first token on the host, wherever the
+        # span starts (since PR 35 at the fetch entry's pop)
         tracing.configure(slow_ttft_ms=0.001)
         root = tracing.start_trace(request_id="ttft1")
         ctx = tracing.current()
