@@ -40,7 +40,8 @@ Two cache forms go through the same layer math: the *contiguous*
   lane tiles) the v pool's (`ModelConfig.kv_row_widths`); expanded keys and
   values never enter a pool.  Two forms of the same attention: *expanded*,
   as published (each cached row goes through W_kvb to a head's keys and
-  values), for the uncached and contiguous caches and for paged prefill;
+  values), for the uncached and contiguous caches and for paged prefill
+  (a walk of the live keys chunk by chunk, `_latent_prefill_walk`);
   *absorbed* for paged decode (W_kvb's key half multiplied into the query,
   scores and the weighted sum taken over the latent rows themselves, W_kvb's
   value half applied to the result), on the Pallas latent kernel or on XLA.
@@ -878,7 +879,10 @@ def _latent_attention_block(
     decode runs the absorbed form, everything else the expanded one; what
     only the latent form adds around attention proper (the absorb and
     un-absorb einsums, the expansion of cached rows through W_kvb) sits
-    under `attn_latent_proj` inside `attn_core`.
+    under `attn_latent_proj` inside `attn_core`.  Paged prefill (s > 1) of
+    every latent model walks the live keys in chunks with a running softmax
+    (`_latent_prefill_walk`) and never holds [Hq, S, window] scores; a paged
+    plan addresses the pool by page (every plan builder hands a page table).
 
     A `cfg.by_kind` model's block also has, by what its leaves and its
     config say: a query low-rank ("wqa"), the rescale of the normed latents,
@@ -886,9 +890,8 @@ def _latent_attention_block(
     decode reads the window's pages only), the learned key selection
     (`attn_index`: indexer projections, scores over the live context, exact
     top-k; `attn_select`: the read of the chosen rows), and the headwise
-    gate (`attn_gate`).  Its paged prefill walks the keys in chunks with a
-    running softmax (`_latent_prefill_walk`), masked to the chosen keys or
-    to the window, and never holds [Hq, S, window] scores."""
+    gate (`attn_gate`).  Its prefill walk is masked to the chosen keys or
+    to the window."""
     dt = x.dtype
     g = cfg.geometry_of(kind)
     r, dn = g.kv_lora_rank, g.qk_nope_head_dim
@@ -927,6 +930,8 @@ def _latent_attention_block(
     b, s = x.shape[:2]
     absorbed = False
     if paged is not None:
+        if paged.page_table is None or paged.page_size is None:
+            raise LatentPathError("a paged plan without a page table (pp)")
         # Paged pools [L, SLOTS, r] and [L, SLOTS, lanes >= dr], addressed
         # flat with this layer's offset in every index (_attention_block)
         num_layers, slots = k_cache.shape[:2]
@@ -945,16 +950,12 @@ def _latent_attention_block(
             raise LatentPathError(
                 "speculative verify (K+1 queries a lane) has no latent form")
         absorbed = s == 1
-    by_page = (paged is not None and paged.page_table is not None
-               and paged.page_size is not None)
-    kernel = (absorbed and cfg.attention_backend == "pallas" and by_page
-              and not indexed)
-    # the paged forms of a by_kind model that read less than the static
-    # window: the chosen rows, the window's pages, a walk of the live keys
-    chosen_rows = absorbed and indexed and by_page
-    window_pages = (absorbed and window is not None and by_page
-                    and not kernel)
-    walk = paged is not None and not absorbed and by_page and cfg.by_kind
+    kernel = absorbed and cfg.attention_backend == "pallas" and not indexed
+    # the paged forms that read less than the static window: the chosen
+    # rows, the window's pages, prefill's walk of the live keys
+    chosen_rows = absorbed and indexed
+    window_pages = absorbed and window is not None and not kernel
+    walk = paged is not None and not absorbed
     with jax.named_scope("attn_core"), (
             nullcontext() if window is None
             else jax.named_scope("attn_window")):
@@ -962,12 +963,13 @@ def _latent_attention_block(
         if not (kernel or chosen_rows or window_pages or walk):
             # the XLA forms: the window of cached rows and who may attend it
             if paged is not None:
-                c_win, r_win = _latent_window(k_cache, v_cache, paged, dt)
-                r_win = r_win[..., :k_rope.shape[-1]]  # drop the lane padding
+                # absorbed decode in XLA: the static window, page by page,
+                # less the rotary rows' lane padding
+                table, ps = paged.page_table, paged.page_size
+                c_win = _kv_read_pages(k_cache, table, ps, dt)
+                r_win = _kv_read_pages(v_cache, table, ps, dt)
+                r_win = r_win[..., :k_rope.shape[-1]]
                 kv_pos, valid = paged.kv_positions, paged.kv_valid
-                if indexed:
-                    i_win = _kv_read(i_cache, paged.read_idx, dt)[
-                        ..., :k_idx.shape[-1]]
             elif k_cache is None:
                 c_win, r_win, kv_pos, valid = c, k_rope, positions, None
                 if indexed:
@@ -1423,16 +1425,6 @@ def _latent_prefill_walk(q_nope, q_rope, wkvb, k_cache, v_cache,
          jnp.zeros(acc_shape, jnp.float32)))
     out = acc / jnp.expand_dims(jnp.maximum(l, 1e-30), l_axis)
     return jnp.transpose(out, out_axes)[:, :s].astype(dt)
-
-
-def _latent_window(k_cache, v_cache, paged, dt):
-    """(c~ window [B, C, r], k_r window [B, C, lanes]) gathered from the
-    flat pools: by page where the plan has a page table."""
-    if paged.page_table is not None and paged.page_size is not None:
-        return (_kv_read_pages(k_cache, paged.page_table, paged.page_size, dt),
-                _kv_read_pages(v_cache, paged.page_table, paged.page_size, dt))
-    return (_kv_read(k_cache, paged.read_idx, dt),
-            _kv_read(v_cache, paged.read_idx, dt))
 
 
 def _mlp_block(x: jnp.ndarray, lp: Params,

@@ -507,9 +507,9 @@ class StepPrograms:
         key, a sliding layer from the chunk that holds the first window's
         first key).  Equal where the fold runs in the kernel, the second 0
         where it runs in XLA, (0, 0) where prefill does not walk: a model
-        whose kinds of layer do not differ."""
+        that is not latent."""
         cfg = self.cfg
-        if not (cfg.by_kind and spans):
+        if not (cfg.is_latent and spans):
             return 0, 0
         kernel = cfg.attention_backend == "pallas"
         cp = prefill_walk_pages(self.P, self.ps, width * bucket, kernel)

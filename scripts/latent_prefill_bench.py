@@ -1,10 +1,12 @@
 #!/usr/bin/env python3
 """Latent prefill's key walk alone, on the chip: one full and one sliding
-layer of dots3-note-prev at the registered shapes, the Pallas fold against
-the XLA fold, and the kernel taken apart.
+layer of dots3-note-prev at the registered shapes, or (`--heads 32`) one
+layer of kanana-2-30b-a3b at its three buckets; the Pallas fold against the
+XLA fold, and the kernel taken apart.
 
     python scripts/latent_prefill_bench.py               # both geometries
     python scripts/latent_prefill_bench.py --rows 256    # another bucket
+    python scripts/latent_prefill_bench.py --heads 32    # Kanana-2's layer
     python scripts/latent_prefill_bench.py --rehearse    # CPU, tiny, no times
 
 Through the chip tool, from the repo root.  Defaults are the cell's
@@ -14,6 +16,9 @@ so a full layer walks 29 trips of 1,024 keys with ~2,048 chosen keys a row
 and a sliding layer (window 513) the last one or two.  What is timed is
 `models/llama.py::_latent_prefill_walk` itself, jitted once a form:
 
+    materialised (`--heads 32` only) what Kanana-2's prefill ran until PR
+                 37, kept here alone: every page of the 16k static window
+                 gathered and expanded, one softmax over [heads, rows, 16384]
     xla          the XLA fold (the `xla` backend's form, the parent's)
     kernel       the fold as `ops/pallas/latent_prefill.latent_prefill_fold`
     k_matmuls    the kernel with the softmax taken out (three dots a head)
@@ -23,7 +28,9 @@ and a sliding layer (window 513) the last one or two.  What is timed is
 Times are each form's module events in one profiler capture (per launch of
 the walk) and, for the kernel forms, the Pallas call's own events (per
 trip); the rest of a launch is the chunk's gather, its expansion through
-W_kvb and the mask, in XLA.  MXU work is what the calls execute, 2 x lanes
+W_kvb and the mask, in XLA.  `--heads 32`: 32 heads, no window, no chosen
+keys, 8,320 live keys (9 trips) in a 16,384-key window, buckets of 512, 256
+and 64 rows in turn (PERF.md section 4).  MXU work is what the calls execute, 2 x lanes
 x heads x rows x keys x (d_qk + d_v) a trip, against the bf16 peak.  Prints
 one JSON line a form and writes them all to
 chiprun_out/latent_prefill_bench.json.
@@ -46,9 +53,12 @@ sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)),
 
 # (heads, d_nope, d_rope, d_v, latent rank, window, chosen keys a row)
 GEOMETRY = {"full": (128, 128, 64, 128, 512, None, 2048),
-            "sliding": (64, 192, 64, 128, 1024, 513, None)}
+            "sliding": (64, 192, 64, 128, 1024, 513, None),
+            "uniform": (32, 128, 64, 128, 512, None, None)}
 TINY = {"full": (4, 16, 8, 16, 32, None, 8),
-        "sliding": (2, 24, 8, 16, 48, 5, None)}
+        "sliding": (2, 24, 8, 16, 48, 5, None),
+        "uniform": (8, 16, 8, 16, 32, None, None)}
+UNIFORM_KEYS, UNIFORM_ROWS, UNIFORM_PAGES = 8320, (512, 256, 64), 1024
 
 
 def make_case(args, geometry, jnp, llama):
@@ -156,7 +166,31 @@ def forms(case, jax, llama, lp, pallas_pkg):
 
     out = {"xla": build("xla", None)}
     out.update({n: build(n, b) for n, b in take_apart(lp).items()})
+    if case["window"] is None and case["chosen_of"] is None:
+        out["materialised"] = jax.jit(materialised(case, llama))
     return out
+
+
+def materialised(case, llama):
+    """The form `_latent_attention_block` ran for a model of one kind of
+    layer until PR 37: the page table's every page, expanded, one softmax."""
+    import jax.numpy as jnp
+
+    paged, positions, dn = case["paged"], case["positions"], case["dn"]
+
+    def bench_materialised(q_nope, q_rope, wkvb, k_cache, v_cache):
+        dt, ps = q_nope.dtype, paged.page_size
+        c_win = llama._kv_read_pages(k_cache, paged.page_table, ps, dt)
+        r_win = llama._kv_read_pages(
+            v_cache, paged.page_table, ps, dt)[..., :case["dr"]]
+        mask = ((positions[:, :, None] >= paged.kv_positions[:, None, :])
+                & paged.kv_valid[:, None, :])
+        kv = jnp.einsum("btr,nrd->btnd", c_win, wkvb)
+        return llama._latent_attend(q_nope, q_rope, kv[..., :dn], r_win,
+                                    kv[..., dn:], mask, case["scale"],
+                                    shared=False)
+
+    return bench_materialised
 
 
 def events(trace_dir, names):
@@ -195,8 +229,11 @@ def main() -> int:
     ap.add_argument("--page-size", type=int, default=16)
     ap.add_argument("--num-pages", type=int, default=8192)
     ap.add_argument("--max-pages", type=int, default=2048)
-    ap.add_argument("--kinds", nargs="+", default=list(GEOMETRY),
-                    choices=list(GEOMETRY))
+    ap.add_argument("--kinds", nargs="+", default=["full", "sliding"],
+                    choices=["full", "sliding"])
+    ap.add_argument("--heads", type=int, default=128, choices=[128, 32],
+                    help="128: dots3's kinds; 32: Kanana-2's one kind at its "
+                    "buckets and live context (--rows / --start ignored)")
     ap.add_argument("--dtype", default="bfloat16")
     ap.add_argument("--seed", type=int, default=2147485003)
     ap.add_argument("--reps", type=int, default=3)
@@ -204,10 +241,19 @@ def main() -> int:
                     help="tiny geometry, any backend, checks only")
     args = ap.parse_args()
     geometries = GEOMETRY
+    uniform_keys, uniform_rows = UNIFORM_KEYS, UNIFORM_ROWS
     if args.rehearse:
         geometries = TINY
         args.rows, args.start, args.page_size = 8, 40, 4
         args.num_pages, args.max_pages, args.dtype = 64, 16, "float32"
+        uniform_keys, uniform_rows = 48, (8,)
+    elif args.heads == 32:
+        args.max_pages = UNIFORM_PAGES
+    # (result key, geometry, bucket, position of its first row)
+    cases = [(kind, kind, args.rows, args.start) for kind in args.kinds]
+    if args.heads == 32:
+        cases = [(f"uniform.{r}", "uniform", r, uniform_keys - r)
+                 for r in uniform_rows]
 
     import jax
     import jax.numpy as jnp
@@ -225,8 +271,9 @@ def main() -> int:
     tol = 1e-5 if args.dtype == "float32" else 2e-2
     result = {"device": jax.devices()[0].device_kind, "args": vars(args),
               "kinds": {}}
-    for kind in args.kinds:
-        geometry = geometries[kind]
+    for kind, shape, bucket, start in cases:
+        args.rows, args.start = bucket, start
+        geometry = geometries[shape]
         n, dn, dr, dv = geometry[:4]
         case = make_case(args, geometry, jnp, llama)
         arrays = [case[k] for k in
@@ -236,6 +283,9 @@ def main() -> int:
                 for name, fn in fns.items()}
         err = float(np.abs(outs["kernel"] - outs["xla"]).max())
         assert np.isfinite(outs["xla"]).all() and err <= tol, (kind, err)
+        if "materialised" in outs:
+            was = float(np.abs(outs["materialised"] - outs["xla"]).max())
+            assert was <= tol, (kind, "materialised", was)
         if not on_chip:
             result["kinds"][kind] = {"rehearsed": sorted(fns),
                                      "max_abs_diff_kernel_vs_xla": err}

@@ -19,8 +19,10 @@ The load-bearing checks:
 * the pool's row widths: one definition, and the three registered
   configurations' memory plans give the bytes the parent commit gave;
 * the engine is token-exact through admission, prefix-cache hit and suffix
-  prefill, exports its bytes a token, and refuses by name every option with
-  no latent form; `forward` backstops the same;
+  prefill (since PR 37 a walk of the live keys, here in 16-key trips over
+  another thread's pages, XLA fold and interpreted kernel), exports its
+  bytes a token, and refuses by name every option with no latent form;
+  `forward` backstops the same, a paged plan without a page table among them;
 * a config without leading dense layers builds the parent's jaxpr.
 """
 
@@ -39,6 +41,7 @@ from kafka_tpu.models import ModelConfig, forward, init_params
 from kafka_tpu.models.config import (
     CONFIGS, UnsupportedConfigError, config_from_hf_json,
 )
+from kafka_tpu.models import llama
 from kafka_tpu.models.llama import (
     KVCache, LatentPathError, PagedView, _moe_block, _routing_weights_sigmoid,
     init_kv_cache,
@@ -572,11 +575,24 @@ def make_engine(cfg, params, mesh=None, **kw):
                            kv_dtype=jnp.float32, mesh=mesh)
 
 
+TRIP = 16  # keys a trip of prefill's key walk under `short_trips`
+
+
+@pytest.fixture
+def short_trips(monkeypatch):
+    """16-key trips of prefill's key walk instead of 1,024, so a 64-key
+    window is a walk of up to four and a 100-key context one of seven (read
+    when a program traces; tests/test_latent_prefill_fold.py runs under it)."""
+    monkeypatch.setattr(llama, "PREFILL_WALK_KEYS", TRIP)
+
+
 @pytest.mark.parametrize("backend", ["xla", "pallas"])
-def test_engine_is_token_exact_for_a_latent_model(model, backend):
-    """Admission, chunked prefill, batched decode: greedy tokens are those
-    of the cache-less forward; the pool holds latent rows only and the
-    engine says how many bytes a token they take."""
+def test_engine_is_token_exact_for_a_latent_model(model, backend,
+                                                  short_trips):
+    """Admission, chunked prefill (a walk of the live keys, three trips for
+    the longest prompt), batched decode: greedy tokens are those of the
+    cache-less forward; the pool holds latent rows only and the engine says
+    how many bytes a token they take."""
     cfg, params = model
     eng = make_engine(cfg, params, attention_backend=backend)
     assert eng.cfg.attention_backend == backend
@@ -594,6 +610,9 @@ def test_engine_is_token_exact_for_a_latent_model(model, backend):
         assert len(done[rid].output_ids) == 10
         assert_greedy_consistent(cfg, params, p, done[rid].output_ids)
     snap = eng.metrics.snapshot(eng)
+    assert snap["engine"]["prefill_walk_trips"] >= 3 * 3
+    assert snap["engine"]["prefill_walk_kernel_trips"] == (
+        snap["engine"]["prefill_walk_trips"] if backend == "pallas" else 0)
     assert snap["engine"]["kv_bytes_per_token"] == 3 * (32 + 128) * 4
     assert snap["engine"]["kv_bytes_per_token"] == \
         planner.kv_bytes_per_token(cfg, kv_dtype="float32")
@@ -602,12 +621,16 @@ def test_engine_is_token_exact_for_a_latent_model(model, backend):
     assert "kafka_tpu_kv_bytes_per_token 1920" in render_prometheus(snap)
 
 
+@pytest.mark.parametrize("backend", ["xla", "pallas"])
 @pytest.mark.parametrize("common", [8, 24], ids=["one-page", "three-pages"])
-def test_prefix_hit_then_suffix_prefill_is_token_exact(model, common):
+def test_prefix_hit_then_suffix_prefill_is_token_exact(model, common, backend,
+                                                       short_trips):
     """A prefix hit hands a second thread the first one's latent rows; the
-    suffix's queries expand them through their own layer's W_kvb."""
+    suffix's queries expand them through their own layer's W_kvb, trip by
+    trip: the three-page prefix is a trip and a half of another thread's
+    pages ahead of the suffix's own."""
     cfg, params = model
-    eng = make_engine(cfg, params)
+    eng = make_engine(cfg, params, attention_backend=backend)
     rng = np.random.RandomState(common)
     shared = list(rng.randint(1, 128, size=common))
     first = GenRequest(request_id="A", prompt_ids=shared + [3, 7, 11],
@@ -620,6 +643,7 @@ def test_prefix_hit_then_suffix_prefill_is_token_exact(model, common):
     eng.submit(second)
     eng.run_to_completion()
     assert second.cached_tokens >= 8 and second.cache_source == "cross"
+    assert eng.prefill_walk_trips >= 3 * -(-(common + 13) // TRIP)
     assert_greedy_consistent(cfg, params, prompt, second.output_ids)
     ref = make_engine(cfg, params, prefix_cache_entries=0).generate(
         prompt, max_new_tokens=8)
@@ -670,6 +694,9 @@ def test_forward_backstops_raise_where_the_engine_is_bypassed(model):
                      chunk_len=jnp.ones((1,), jnp.int32))
     with pytest.raises(LatentPathError, match="verify"):
         forward(params, cfg, i, i, kv_cache=pools, paged=view)
+    with pytest.raises(LatentPathError, match="page table"):
+        forward(params, cfg, i, i, kv_cache=pools,
+                paged=view._replace(seq_lens=None, page_table=None))
     with pytest.raises(LatentPathError, match="prefill_ring"):
         forward(params, cfg.replace(prefill_ring=True), i, i,
                 kv_cache=pools, paged=view._replace(seq_lens=None))

@@ -9,9 +9,15 @@ with matmuls at "highest":
 * the chosen-keys mask, the window with the walk started past chunk 0, padded
   bucket rows, a lane with no live key, three and more trips (the carry) and
   a batched launch give the XLA fold's output;
+* a uniform latent model's dense causal walk (PR 37: Kanana-2's 32 heads, no
+  window, no chosen keys) at buckets of 64 / 256 / 512 rows, over a context
+  that ends inside a trip and one that ends on a trip's last key;
 * the engine counts the walk's trips on the host as the device loop bounds
-  them, all of them kernel trips on `pallas`, none on `xla`, and is token
-  exact after a prefix hit on both backends.
+  them, all of them kernel trips on `pallas`, none on `xla`, for both kinds
+  of latent model and none for a model that is not latent, and is token
+  exact after a prefix hit on both backends;
+* the prefill programs of a GQA model and of the sparse by-kind model lower
+  to the text the parent commit (fe610bc) lowered them to.
 
 Tolerance 2e-6 absolute on outputs of magnitude ~1: both folds multiply the
 same float32 operands and keep the same f32 max / sum / accumulator per
@@ -21,6 +27,7 @@ probabilities over sublanes first, so only the order of f32 additions
 differs (a few ulp of the largest term).
 """
 
+import hashlib
 import os
 import sys
 
@@ -32,10 +39,12 @@ import pytest
 sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 
 from kafka_tpu.models import llama
-from kafka_tpu.models.config import GLOBAL, WINDOWED
+from kafka_tpu.models.config import CONFIGS, GLOBAL, WINDOWED
 from kafka_tpu.ops.pallas import latent_prefill
-from kafka_tpu.runtime import GenRequest
+from kafka_tpu.runtime import GenRequest, step_programs
+from kafka_tpu.runtime.kv_cache import make_kv_pool_arrays
 from test_engine import assert_greedy_consistent
+from test_latent_attention import TRIP, latent_cfg, short_trips  # noqa: F401
 from test_sparse_latent_attention import (
     TOPK,
     WINDOW,
@@ -44,30 +53,27 @@ from test_sparse_latent_attention import (
 )
 
 PS, PAGES, TABLE = 4, 48, 32  # page size, pool pages, page-table width
-TRIP = 16                     # keys a trip in these tests
 TOL = 2e-6
 
-
-@pytest.fixture(autouse=True)
-def short_trips(monkeypatch):
-    """16-key trips, so a 100-key context is a walk of seven."""
-    monkeypatch.setattr(llama, "PREFILL_WALK_KEYS", TRIP)
+# every test here walks in 16-key trips: a 100-key context is a walk of seven
+pytestmark = pytest.mark.usefixtures("short_trips")
 
 
-def _case(kind, spans, rows, seed=0):
+def _case(kind, spans, rows, seed=0, cfg=None, table_pages=TABLE):
     """One layer's pools and a bucket of `rows` queries a lane; lane i holds
     `spans[i]` = (start, chunk_len) (chunk_len 0: an inactive lane).  Rows
     no lane has written hold NaN."""
-    cfg = sparse_cfg()
+    cfg = cfg or sparse_cfg()
     g = cfg.geometry_of(kind)
     n, dn, dr, dv = (g.num_heads, g.qk_nope_head_dim, g.qk_rope_head_dim,
                      g.v_head_dim)
     rng = np.random.RandomState(seed)
-    b, C = len(spans), TABLE * PS
-    k_pool = np.full((PAGES * PS, g.kv_lora_rank), np.nan, np.float32)
-    v_pool = np.full((PAGES * PS, dr), np.nan, np.float32)
-    table = np.zeros((b, TABLE), np.int32)
-    free = list(rng.permutation(np.arange(1, PAGES)))
+    pages = table_pages + PAGES - TABLE
+    b, C = len(spans), table_pages * PS
+    k_pool = np.full((pages * PS, g.kv_lora_rank), np.nan, np.float32)
+    v_pool = np.full((pages * PS, dr), np.nan, np.float32)
+    table = np.zeros((b, table_pages), np.int32)
+    free = list(rng.permutation(np.arange(1, pages)))
     starts = np.asarray([s for s, _ in spans])
     lens = np.asarray([n_ for _, n_ in spans])
     for i, live in enumerate(starts + lens):
@@ -134,6 +140,37 @@ def test_kernel_fold_is_the_xla_fold(kind, spans, rows):
         np.testing.assert_allclose(got[i, :n], want[i, :n], rtol=0, atol=TOL)
 
 
+# Kanana-2's block at a tiny width: 32 heads of [16 nope | 8 rope] over a
+# 32-value latent, one kind of layer, no window and no indexer.  (start,
+# chunk_len) of the one lane: the context ends 5 keys into a trip, or on a
+# trip's last key with the bucket full
+UNIFORM = {
+    "64-rows-mid-trip": (64, (37, 64)),
+    "64-rows-trip-boundary": (64, (32, 64)),
+    "256-rows-mid-trip": (256, (41, 252)),
+    "256-rows-trip-boundary": (256, (64, 256)),
+    "512-rows-mid-trip": (512, (60, 505)),
+    "512-rows-trip-boundary": (512, (48, 512)),
+}
+
+
+@pytest.mark.parametrize("rows, span", UNIFORM.values(), ids=UNIFORM)
+def test_kernel_fold_is_the_xla_fold_on_a_dense_causal_walk(request, rows,
+                                                            span):
+    """What every latent model's paged prefill runs since PR 37: the walk
+    with `window=None, chosen_of=None` from key 0 to the lane's last valid
+    key, the bucket padded to whole lane tiles (64 -> 128 rows)."""
+    cfg = latent_cfg(num_heads=32, num_kv_heads=32)
+    case = _case(GLOBAL, [span], rows, seed=rows, cfg=cfg, table_pages=160)
+    assert case["window"] is None and case["chosen_of"] is None
+    assert (sum(span) % TRIP == 0) == ("boundary" in request.node.name)
+    want, got = _both(case)
+    n = span[1]
+    assert want.shape == got.shape == (1, rows, 32, 16)
+    assert np.isfinite(want[0, :n]).all() and np.abs(want[0, :n]).max() > 0
+    np.testing.assert_allclose(got[0, :n], want[0, :n], rtol=0, atol=2e-5)
+
+
 def test_the_masks_are_exercised():
     """The cases above mean what their names say: the full layer's queries
     keep TOPK of more keys, the sliding walk starts past chunk 0."""
@@ -166,29 +203,40 @@ def model():
     return cfg, llama.init_params(cfg, jax.random.PRNGKey(5))
 
 
+@pytest.fixture(scope="module")
+def uniform_model():
+    """Six latent layers of one kind (a dense one, then five routed)."""
+    cfg = latent_cfg(num_layers=6)
+    return cfg, llama.init_params(cfg, jax.random.PRNGKey(5))
+
+
+# one launch, 16-key trips over a 128-key table.  sparse: 3 full layers walk
+# ceil(45 / 16) = 3 trips, 3 sliding ones from chunk (37 - 5 + 1) // 16 = 2
+# on; batched, the longest lane bounds the trips and the lowest start the
+# sliding layers' first chunk.  uniform: 6 layers x 3 trips, no sliding term
+WALKS = {"sparse": (3 * 3 + 3 * 1, 3 * 3 + 3 * 3), "uniform": (6 * 3, 6 * 3)}
+
+
 @pytest.mark.parametrize("backend", ["xla", "pallas"])
-def test_engine_counts_the_trips_the_device_loops(model, backend):
+@pytest.mark.parametrize("which", sorted(WALKS))
+def test_engine_counts_the_trips_the_device_loops(request, which, backend):
     """`/metrics` `engine.prefill_walk_trips` is launches x layers x trips by
     the device loop's own bounds; `prefill_walk_kernel_trips` equals it where
     the fold runs in the kernel and stays 0 where it runs in XLA."""
-    cfg, params = model
+    cfg, params = request.getfixturevalue(
+        "model" if which == "sparse" else "uniform_model")
     eng = make_engine(cfg, params, attention_backend=backend,
                       max_pages_per_seq=16)
     assert eng._programs.prefill_walk_trips([], 1, 8) == (0, 0)
-    # one launch, 16-key trips over a 128-key table: 3 full layers walk
-    # ceil(45 / 16) = 3 trips, 3 sliding ones from chunk (37 - 5 + 1) // 16
-    # = 2 on: 3 * 3 + 3 * 1
     kernel = backend == "pallas"
-    want = 12
+    want, batched = WALKS[which]
     if not kernel:
         # the XLA fold shrinks a trip at many rows; 8 rows do not
         assert llama.prefill_walk_pages(16, 8, 8, False) == 2
     assert eng._programs.prefill_walk_trips([(37, 8)], 1, 8) == (
         want, want if kernel else 0)
-    # a batched launch: the longest lane bounds the trips, the lowest start
-    # the sliding layers' first chunk
     assert eng._programs.prefill_walk_trips(
-        [(37, 8), (3, 8)], 4, 8)[0] == 3 * 3 + 3 * 3
+        [(37, 8), (3, 8)], 4, 8)[0] == batched
     eng.submit(GenRequest(request_id="a", prompt_ids=list(range(1, 38)),
                           max_new_tokens=3))
     eng.run_to_completion()
@@ -200,9 +248,7 @@ def test_engine_counts_the_trips_the_device_loops(model, backend):
 
 
 def test_a_model_that_does_not_walk_counts_nothing():
-    from test_latent_attention import latent_cfg
-
-    cfg = latent_cfg()
+    cfg = CONFIGS["tiny"].replace(dtype="float32")
     eng = make_engine(cfg, llama.init_params(cfg, jax.random.PRNGKey(1)),
                       max_pages_per_seq=16)
     eng.submit(GenRequest(request_id="a", prompt_ids=[3, 5, 7, 11, 13],
@@ -235,3 +281,61 @@ def test_prefix_hit_then_suffix_prefill_is_token_exact(model, backend):
     assert_greedy_consistent(cfg, params, prompt, second.output_ids)
     assert (eng.prefill_walk_kernel_trips == eng.prefill_walk_trips) == (
         backend == "pallas")
+
+
+# ---------------------------------------------------------------------------
+# what PR 37 leaves alone: prefill programs that never took the form it removed
+# ---------------------------------------------------------------------------
+
+# sha256[:16] of the lowered text as the PARENT commit (fe610bc, before every
+# latent model walked) lowers it: recorded by running `_prefill_text` below,
+# unchanged, as a test in a checkout of that commit (same conftest, same JAX)
+PARENT_TEXTS = {
+    "gqa.xla.prefill": "6c26c2dee962962f",
+    "gqa.xla.bprefill": "e9677dad74f5b5fb",
+    "gqa.pallas.prefill": "b315e30ad47a97be",
+    "gqa.pallas.bprefill": "e9677dad74f5b5fb",
+    "sparse.xla.prefill": "b20ab85bf7399e42",
+    "sparse.xla.bprefill": "8179126e3b4db6c4",
+    "sparse.pallas.prefill": "1fa6d80d746bb962",
+    "sparse.pallas.bprefill": "069864daa438e2ef",
+}
+
+
+def _prefill_text(name, backend, program):
+    """Lowered text of the engine's single (`prefill`) or batched
+    (`bprefill`, 2 lanes) prefill program at a 16-row bucket over a 64-page
+    pool of 8-row pages, from shapes alone."""
+    cfg = {"gqa": CONFIGS["tiny"], "sparse": sparse_cfg()}[name].replace(
+        dtype="float32", attention_backend=backend)
+    params = jax.eval_shape(
+        lambda: llama.init_params(cfg, jax.random.PRNGKey(0)))
+    pools = jax.eval_shape(
+        lambda: make_kv_pool_arrays(cfg, 64, 8, jnp.float32))
+
+    def of(dtype, *shape):
+        return jax.ShapeDtypeStruct(shape, dtype)
+
+    i32, f32 = jnp.int32, jnp.float32
+    if program == "prefill":
+        fn = step_programs._prefill_fn(cfg, None, 8, 16)
+        args = (of(i32, 8), of(i32, 16), of(i32), of(i32), of(f32), of(i32),
+                of(f32), of(jnp.uint32, 1), None)
+    else:
+        fn = step_programs._batched_prefill_fn(cfg, None, 8, 16)
+        args = (of(i32, 2, 8), of(i32, 2, 16), of(i32, 2), of(i32, 2),
+                of(f32, 2), of(i32, 2), of(f32, 2), of(jnp.uint32, 2),
+                of(jnp.bool_, 2))
+    return jax.jit(fn).lower(params, *pools, *args).as_text()
+
+
+@pytest.mark.parametrize("key", [
+    f"{name}.{backend}.{program}" for name in ("gqa", "sparse")
+    for backend in ("xla", "pallas") for program in ("prefill", "bprefill")])
+def test_prefill_programs_that_did_not_change_lower_to_the_parents_text(key):
+    """A GQA model never traces the latent block and the sparse by-kind
+    model already walked with the same arguments: the selection's new
+    condition changes neither program."""
+    digest = hashlib.sha256(
+        _prefill_text(*key.split(".")).encode()).hexdigest()[:16]
+    assert digest == PARENT_TEXTS[key], (key, digest)
