@@ -27,15 +27,25 @@ import jax.numpy as jnp
 
 from .vision import VisionConfig
 
-# The two kinds of attention layer a pattern is made of (HF `layer_types`).
+# The kinds of layer a pattern is made of.  WINDOWED and GLOBAL are HF
+# `layer_types` and own cache rows; the other three are a hybrid decoder's
+# (`phi4flash`): a state-space mixer whose per-thread state is a fixed-size
+# slot and no rows (MAMBA), a gated memory unit with no state at all (GMU),
+# and attention that READS the last GLOBAL layer's rows and writes none
+# (CROSS).
 WINDOWED = "sliding_attention"
 GLOBAL = "full_attention"
+MAMBA = "mamba"
+GMU = "gmu"
+CROSS = "cross_attention"
+ROW_KINDS = (WINDOWED, GLOBAL)
 
 
 class UnsupportedConfigError(ValueError):
     """A published config.json asks for something the program cannot honour
     (a dense MLP among routed layers, un-normalised top-k weights, an unknown
-    kind of layer or rope).  Raised instead of reading the key silently."""
+    kind of layer or rope, a hybrid decoder whose layout is not the one
+    served).  Raised, naming the key, instead of reading it silently."""
 
 
 @dataclasses.dataclass(frozen=True)
@@ -189,6 +199,17 @@ class ModelConfig:
     # experts; what the absent ones would add is left out.
     num_experts_routed: int = 0
     expert_offset: int = 0
+    # -- a hybrid decoder (`phi4flash`; models/hybrid.py): `mamba_d_state`
+    # > 0 turns it on and `layer_types` then names MAMBA / GMU / CROSS
+    # layers beside the attention kinds.  A MAMBA layer is a Mamba-1 mixer
+    # of inner width mamba_expand * hidden_size whose per-thread state (the
+    # last mamba_d_conv - 1 conv inputs and h [d_state, inner], float32)
+    # lives in a STATE SLOT beside the pages (runtime/kv_cache.py); only
+    # WINDOWED / GLOBAL layers hold rows in the paged pool. --
+    mamba_d_state: int = 0
+    mamba_d_conv: int = 4
+    mamba_expand: int = 2
+    mamba_dt_rank: int = 0
 
     def __post_init__(self):
         if self.moe_scoring not in ("softmax", "sigmoid"):
@@ -225,12 +246,19 @@ class ModelConfig:
                 "first_k_dense needs routed layers after the dense ones and "
                 "a dense_intermediate_size")
         if not self.layer_types:
+            if self.mamba_d_state:
+                raise UnsupportedConfigError(
+                    "mamba_d_state needs layer_types naming the mamba layers")
             return
-        bad = set(self.layer_types) - {WINDOWED, GLOBAL}
+        known = {WINDOWED, GLOBAL} | (
+            {MAMBA, GMU, CROSS} if self.mamba_d_state else set())
+        bad = set(self.layer_types) - known
         if bad:
             raise UnsupportedConfigError(
                 f"layer_types holds unknown kinds {sorted(bad)}; known: "
-                f"{WINDOWED!r}, {GLOBAL!r}")
+                f"{sorted(known)}")
+        if self.mamba_d_state:
+            self._check_hybrid()
         if len(self.layer_types) != self.num_layers:
             raise UnsupportedConfigError(
                 f"layer_types has {len(self.layer_types)} entries for "
@@ -240,9 +268,76 @@ class ModelConfig:
             raise UnsupportedConfigError(
                 "a sliding_attention layer needs a positive sliding_window")
 
+    def _check_hybrid(self) -> None:
+        """The one hybrid layout served (models/hybrid.py): n x [mamba,
+        sliding], then [mamba, full], then m x [gmu, cross]; the cross
+        layers read the one full layer's rows, the gmu layers the last
+        mamba layer's memory."""
+        kinds = self.layer_types
+        n_self = 2 * kinds.count(MAMBA)
+        want = ((MAMBA, WINDOWED) * (n_self // 2 - 1) + (MAMBA, GLOBAL)
+                + (GMU, CROSS) * ((len(kinds) - n_self) // 2))
+        if kinds != want or MAMBA not in kinds:
+            raise UnsupportedConfigError(
+                "a hybrid decoder is served as n x [mamba, "
+                "sliding_attention], [mamba, full_attention], then m x "
+                f"[gmu, cross_attention]; layer_types is {list(kinds)}")
+        if self.is_latent or self.is_moe or self.vision is not None:
+            raise UnsupportedConfigError(
+                "a hybrid decoder is dense GQA: no latent attention, "
+                "experts or vision tower")
+        if (self.num_heads % 2 or self.num_kv_heads % 2
+                or self.num_heads % self.num_kv_heads):
+            raise UnsupportedConfigError(
+                "differential attention pairs heads: even head counts, "
+                "query heads a multiple of the key-value heads")
+        if self.mamba_dt_rank <= 0 or self.mamba_d_conv < 2:
+            raise UnsupportedConfigError(
+                "mamba_dt_rank and mamba_d_conv >= 2 are needed")
+
     @property
     def is_moe(self) -> bool:
         return self.num_experts > 0
+
+    @property
+    def has_state(self) -> bool:
+        """Some layers carry a recurrent per-thread state (a state slot)."""
+        return self.mamba_d_state > 0
+
+    @property
+    def mamba_d_inner(self) -> int:
+        return self.mamba_expand * self.hidden_size
+
+    @property
+    def state_layers(self) -> int:
+        """Layers that hold a recurrent state."""
+        return self.layers_of(MAMBA) if self.has_state else 0
+
+    @property
+    def kv_layers(self) -> int:
+        """Layers that hold rows in the paged pool: all of them, but for a
+        hybrid decoder, whose attention layers with K/V of their own do."""
+        if not self.has_state:
+            return self.num_layers
+        return sum(self.layers_of(k) for k in ROW_KINDS)
+
+    def state_shapes(self) -> Tuple[Tuple[str, Tuple[int, ...]], ...]:
+        """(leaf, shape of ONE slot of ONE layer) of the recurrent state,
+        float32: THE definition of a state slot, which the allocation
+        (runtime/kv_cache.make_state_arrays), the memory plan and /metrics
+        ask.  `ssm` is h transposed, [d_state, inner]: the wide axis in the
+        lanes."""
+        if not self.has_state:
+            return ()
+        di = self.mamba_d_inner
+        return (("conv", (self.mamba_d_conv - 1, di)),
+                ("ssm", (self.mamba_d_state, di)))
+
+    @property
+    def state_bytes_per_slot(self) -> int:
+        """Bytes one state slot holds over all state layers (float32)."""
+        return 4 * self.state_layers * sum(
+            a * b for _, (a, b) in self.state_shapes())
 
     @property
     def pattern(self) -> Tuple[int, Tuple[str, ...]]:
@@ -344,6 +439,8 @@ class ModelConfig:
             index = ((_lane_tiles(self.index_head_dim),)
                      if self.has_indexer(kind) else ())
             return (g.kv_lora_rank, _lane_tiles(g.qk_rope_head_dim)) + index
+        if kind not in ROW_KINDS:
+            return ()  # a hybrid decoder's mamba / gmu / cross layers
         return (self.num_kv_heads * self.head_dim,) * 2
 
     @property
@@ -631,13 +728,64 @@ def _kind_keys(hf: dict) -> dict:
     return out
 
 
+def _hybrid_keys(hf: dict) -> dict:
+    """The keys of a `phi4flash` config.json (Phi-4-mini-flash-reasoning: a
+    decoder-hybrid-decoder of Mamba-1 mixers, sliding and full differential
+    attention, gated memory units and cross attention over ONE full cache)
+    as ModelConfig fields; {} for any other model.  The layout is the
+    modeling file's rule over `num_hidden_layers` and `mb_per_layer`; what
+    the config has no key for (the Mamba sizes, differential attention, the
+    biases) is the configuration class's and the modeling file's default,
+    listed as `assumed` beside the benchmark's copy of the file."""
+    if hf.get("model_type") != "phi4flash":
+        return {}
+    served = (
+        ("hidden_act", "silu", "another MLP activation"),
+        ("mb_per_layer", 2, "another spacing of the Mamba layers"),
+        ("mlp_bias", False, "MLP biases"),
+        ("lm_head_bias", False, "a bias on the head"),
+        ("tie_word_embeddings", True, "an untied head"),
+        ("rope_scaling", None, "rotary positions (the model has none)"),
+    )
+    for key, want, what in served:
+        got = hf.get(key, want)
+        if got != want:
+            raise UnsupportedConfigError(
+                f"{key} = {got!r} ({what}) is not served: only {want!r} is")
+    n = int(hf["num_hidden_layers"])
+    if n % 4 or n < 4:
+        raise UnsupportedConfigError(
+            f"num_hidden_layers = {n}: the hybrid layout needs a multiple "
+            "of 4")
+    window = hf.get("sliding_window")
+    if not isinstance(window, int) or window <= 0:
+        raise UnsupportedConfigError(
+            f"sliding_window = {window!r}: one positive window is served")
+    own = n // 2 + 2  # layers with a mixer state or K/V of their own
+    kinds = tuple(
+        (MAMBA if i % 2 == 0 else GLOBAL if i == own - 1 else WINDOWED)
+        if i < own else (GMU if i % 2 == 0 else CROSS)
+        for i in range(n))
+    hidden = int(hf["hidden_size"])
+    return {
+        "layer_types": kinds,
+        "sliding_window": window,
+        "mamba_d_state": int(hf.get("mamba_d_state", 16)),
+        "mamba_d_conv": int(hf.get("mamba_d_conv", 4)),
+        "mamba_expand": int(hf.get("mamba_expand", 2)),
+        "mamba_dt_rank": int(hf.get("mamba_dt_rank", -(-hidden // 16))),
+        "rms_norm_eps": float(hf.get("layer_norm_eps", 1e-5)),
+    }
+
+
 def config_from_hf_json(path: str) -> ModelConfig:
     """Build a ModelConfig from a HuggingFace config.json: Llama / Mixtral
     keys, the published keys of a patterned routed decoder (Mellum2:
     `layer_types`, `sliding_window`, `rope_parameters`, `num_experts`,
-    `moe_intermediate_size`, `norm_topk_prob`, `mlp_layer_types`), and those
-    of a `deepseek_v3` decoder (`_latent_keys`).  A key the program cannot
-    honour is an UnsupportedConfigError."""
+    `moe_intermediate_size`, `norm_topk_prob`, `mlp_layer_types`), those
+    of a `deepseek_v3` decoder (`_latent_keys`) and those of a `phi4flash`
+    hybrid decoder (`_hybrid_keys`).  A key the program cannot honour is an
+    UnsupportedConfigError."""
     with open(path) as f:
         hf = json.load(f)
     latent = _latent_keys(hf)
@@ -664,7 +812,8 @@ def config_from_hf_json(path: str) -> ModelConfig:
             "norm_topk_prob false (top-k weights of a softmax over ALL "
             "experts, not renormalised) is not served: routing here is a "
             "softmax over exactly the top-k logits")
-    pattern = _layer_pattern(hf)
+    hybrid = _hybrid_keys(hf)
+    pattern = {} if hybrid else _layer_pattern(hf)
     rope_theta = hf.get("rope_theta")
     if rope_theta is None:
         ropes = dict(pattern.get("rope_by_kind", ()))
@@ -687,7 +836,8 @@ def config_from_hf_json(path: str) -> ModelConfig:
         head_dim=(latent["qk_rope_head_dim"] if latent else hf.get(
             "head_dim", hf["hidden_size"] // hf["num_attention_heads"])),
         rope_theta=rope_theta,
-        rms_norm_eps=hf.get("rms_norm_eps", 1e-5),
+        rms_norm_eps=hybrid.pop("rms_norm_eps", None) or hf.get(
+            "rms_norm_eps", 1e-5),
         max_context=hf.get("max_position_embeddings", 8192),
         tie_word_embeddings=hf.get("tie_word_embeddings", False),
         rope_scaling_factor=rs.get("factor"),
@@ -696,4 +846,5 @@ def config_from_hf_json(path: str) -> ModelConfig:
         rope_original_max_position=rs.get("original_max_position_embeddings", 8192),
         **pattern,
         **latent,
+        **hybrid,
     )

@@ -217,6 +217,9 @@ class PagedView(NamedTuple):
     # prefill-chunk bounds (pallas flash prefill backend only)
     start: Optional[jnp.ndarray] = None
     chunk_len: Optional[jnp.ndarray] = None
+    # a hybrid decoder's recurrent state: which state slot each lane reads
+    # and writes (models/hybrid.StatePlan); None for every other model
+    state: Optional[Any] = None
 
 
 @jax.named_scope("step_ctl")
@@ -236,6 +239,11 @@ def _layer_view(paged: PagedView, layer, slots: int) -> PagedView:
 
 def init_kv_cache(cfg: ModelConfig, batch: int, capacity: int, dtype=None) -> KVCache:
     dtype = dtype or cfg.activation_dtype
+    if cfg.has_state:
+        from .hybrid import HybridPathError
+
+        raise HybridPathError(
+            "a hybrid decoder has no contiguous cache (models/hybrid.py)")
     if cfg.by_kind:
         # per kind of layer, as the paged pool (runtime/kv_cache.py)
         def rows(n, width):
@@ -263,6 +271,10 @@ def init_params(cfg: ModelConfig, key: jax.Array, dtype=None) -> Params:
     """Random-init parameters (layer-stacked). Serving loads checkpoints
     instead; random init exists for tests and micro-benchmarks."""
     dtype = dtype or cfg.activation_dtype
+    if cfg.has_state:
+        from .hybrid import init_params as init_hybrid_params
+
+        return init_hybrid_params(cfg, key, dtype)
     if cfg.by_kind:
         return _init_kind_params(cfg, key, dtype)
     if cfg.is_latent or cfg.first_k_dense or cfg.shared_intermediate_size \
@@ -1544,7 +1556,21 @@ def forward(
         tokens, models/vision.py; the reference forwarded images to remote
         vision models, src/llm/portkey.py:276).
     Returns (logits [B, S, vocab] float32, updated cache or None).
+
+    A hybrid decoder (`cfg.has_state`: state-space layers beside attention)
+    is models/hybrid.forward behind this same entry: its paged pool carries
+    the recurrent state (`kv_cache.v` a dict), `paged.state` says which
+    slots, and a paged prefill returns its lanes' last real rows only,
+    logits [B, 1, vocab].
     """
+    if cfg.has_state:
+        from .hybrid import HybridPathError, forward as hybrid_forward
+
+        if mesh is not None and mesh.size > 1 or embed_override is not None:
+            raise HybridPathError(
+                "a hybrid decoder runs on one device a replica, text only")
+        return hybrid_forward(params, cfg, token_ids, positions, kv_cache,
+                              paged)
     with jax.named_scope("embed"):
         embed = params["embed"]
         if isinstance(embed, QTensor):

@@ -18,6 +18,7 @@ Selection is driven by `ModelConfig.attention_backend`:
 
 from .flash_prefill import paged_prefill_attention
 from .latent_prefill import latent_prefill_fold
+from .selective_scan import selective_scan
 from .paged_attention import (
     paged_decode_attention,
     paged_decode_attention_int8,
@@ -42,4 +43,5 @@ __all__ = [
     "paged_verify_attention",
     "paged_verify_attention_sharded",
     "pallas_mesh_ok",
+    "selective_scan",
 ]

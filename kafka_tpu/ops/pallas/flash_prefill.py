@@ -199,7 +199,7 @@ def _prefill_kernel(
 @functools.partial(
     jax.jit,
     static_argnames=("page_size", "pages_per_chunk", "q_block", "scale",
-                     "interpret", "window"),
+                     "interpret", "window", "diff"),
 )
 def paged_prefill_attention(
     q: jnp.ndarray,          # [S, Hq, D] roped queries of this chunk
@@ -215,8 +215,11 @@ def paged_prefill_attention(
     scale: float | None = None,
     interpret: bool = False,
     window: int | None = None,
+    diff: bool = False,
 ) -> jnp.ndarray:
     """Flash attention of one prefill chunk against the paged window.
+    `diff`: differential attention's pairing (paged_attention.diff_heads);
+    returns [S, Hq, 2 D], each query head over both value heads of its pair.
     `window` (static): a sliding-window layer; each query row attends
     q_pos - window < kv_pos <= q_pos and a q block skips the KV chunks
     wholly below its first row's window.
@@ -252,7 +255,12 @@ def paged_prefill_attention(
     v_pages = v_pool.reshape(-1, page_size, HD)
 
     # block-diagonal expansion, rows = (q position, head) pairs
-    kv_of_q = jnp.repeat(jnp.arange(Hkv), G)  # [Hq]
+    if diff:
+        from .paged_attention import diff_heads
+
+        kv_of_q, pair_of_q = diff_heads(Hq, Hkv)
+    else:
+        kv_of_q = jnp.repeat(jnp.arange(Hkv), G)  # [Hq]
     qx = jnp.zeros((S, Hq, Hkv, D), q.dtype)
     qx = qx.at[:, jnp.arange(Hq), kv_of_q].set(q)
     qx = qx.reshape(S * Hq, HD)
@@ -294,4 +302,7 @@ def paged_prefill_attention(
         out_shape=jax.ShapeDtypeStruct((S * Hq, HD), q.dtype),
         interpret=interpret,
     )(page_row, bounds, qx, k_pages, v_pages)
+    if diff:
+        return out_wide.reshape(
+            S, Hq, Hkv // 2, 2 * D)[:, jnp.arange(Hq), pair_of_q]
     return out_wide.reshape(S, Hq, Hkv, D)[:, jnp.arange(Hq), kv_of_q]

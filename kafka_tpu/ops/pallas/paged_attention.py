@@ -285,7 +285,8 @@ def decode_chunk_range(seq_len: int, window: int | None, page_size: int,
 
 @functools.partial(
     jax.jit,
-    static_argnames=("page_size", "pages_per_chunk", "scale", "interpret"),
+    static_argnames=("page_size", "pages_per_chunk", "scale", "interpret",
+                     "diff"),
 )
 def paged_decode_attention(
     q: jnp.ndarray,            # [B, Hq, D] — one query token per sequence
@@ -298,21 +299,27 @@ def paged_decode_attention(
     pages_per_chunk: int = 8,
     scale: float | None = None,
     interpret: bool = False,
+    diff: bool = False,
 ) -> jnp.ndarray:
     """Decode-step attention straight off the paged KV pool.
 
     Returns [B, Hq, D] in q.dtype.  Inactive batch lanes (whose table rows
     point at the trash page) produce garbage rows that the engine discards —
-    same contract as the XLA gather path.
+    same contract as the XLA gather path.  `diff` (differential attention,
+    `diff_heads`): query head n scores key head 2 * (n // (2 G)) + n % 2 and
+    the result is [B, Hq, 2 D], the weighted sum over BOTH value heads of
+    that pair: the same kernel, another placement of q on the merged row and
+    another slice of its output.
     """
     return _paged_decode(q, k_pool, v_pool, page_table, seq_lens,
-                         page_size, pages_per_chunk, scale, interpret, None)
+                         page_size, pages_per_chunk, scale, interpret, None,
+                         diff)
 
 
 @functools.partial(
     jax.jit,
     static_argnames=("window", "page_size", "pages_per_chunk", "scale",
-                     "interpret"),
+                     "interpret", "diff"),
 )
 def paged_decode_attention_window(
     q: jnp.ndarray,
@@ -326,6 +333,7 @@ def paged_decode_attention_window(
     pages_per_chunk: int = 8,
     scale: float | None = None,
     interpret: bool = False,
+    diff: bool = False,
 ) -> jnp.ndarray:
     """paged_decode_attention for a sliding-window layer: the query at
     position seq_len attends seq_len - window < kv_pos <= seq_len, and the
@@ -334,7 +342,8 @@ def paged_decode_attention_window(
     a device trace tells the windowed calls from the global ones, and both
     still match `paged_decode`."""
     return _paged_decode(q, k_pool, v_pool, page_table, seq_lens,
-                         page_size, pages_per_chunk, scale, interpret, window)
+                         page_size, pages_per_chunk, scale, interpret, window,
+                         diff)
 
 
 def _step_pages(P: int, pages_per_chunk: int, page_size: int) -> tuple:
@@ -417,8 +426,19 @@ def paged_decode_attention_latent(
       r_pool.reshape(-1, page_size, lanes))
 
 
+def diff_heads(Hq: int, Hkv: int):
+    """Differential attention's pairing on the merged row.  Heads come in
+    pairs (2j, 2j + 1) of queries and (2g, 2g + 1) of keys and values; query
+    pair j reads key-value pair g = j // G, its first head the pair's first
+    key head, its second the second, and BOTH heads' values.  Returns
+    (key head of each query head [Hq], value PAIR of each query head [Hq])."""
+    n = jnp.arange(Hq)
+    pair = n // (2 * (Hq // Hkv))
+    return 2 * pair + n % 2, pair
+
+
 def _paged_decode(q, k_pool, v_pool, page_table, seq_lens, page_size,
-                  pages_per_chunk, scale, interpret, window):
+                  pages_per_chunk, scale, interpret, window, diff=False):
     B, Hq, D = q.shape
     HD = k_pool.shape[1]
     Hkv = HD // D
@@ -432,7 +452,10 @@ def _paged_decode(q, k_pool, v_pool, page_table, seq_lens, page_size,
 
     # Block-diagonal query expansion (see module docstring): qx[b, qh] has
     # q[b, qh] in its own kv head's D-lane block and zeros elsewhere.
-    kv_of_q = jnp.repeat(jnp.arange(Hkv), G)  # [Hq]
+    if diff:
+        kv_of_q, pair_of_q = diff_heads(Hq, Hkv)
+    else:
+        kv_of_q = jnp.repeat(jnp.arange(Hkv), G)  # [Hq]
     qx = jnp.zeros((B, Hq, Hkv, D), q.dtype)
     qx = qx.at[:, jnp.arange(Hq), kv_of_q].set(q)
     qx = qx.reshape(B, Hq, HD)
@@ -474,6 +497,10 @@ def _paged_decode(q, k_pool, v_pool, page_table, seq_lens, page_size,
         interpret=interpret,
         name=None if window is None else "paged_decode_attention_window",
     )(page_table, seq_lens, qx, k_pages, v_pages)
+    if diff:
+        # each query row's result over BOTH value heads of its pair
+        return out_wide.reshape(
+            B, Hq, Hkv // 2, 2 * D)[:, jnp.arange(Hq), pair_of_q]
     # each query row's result lives in its own kv head's lane block
     return out_wide.reshape(B, Hq, Hkv, D)[:, jnp.arange(Hq), kv_of_q]
 

@@ -77,7 +77,9 @@ from .kv_cache import (
     OutOfPagesError,
     PagePool,
     SequencePages,
+    StatePool,
     TRASH_PAGE,
+    default_state_slots,
     make_kv_pool_arrays,
     page_table_array,
 )
@@ -143,6 +145,20 @@ WAITING, PREFILLING, PARKED, ACTIVE, DRAINING, FINISHED = (
 # Agent-native scheduling (ISSUE 20, README "Agent-native scheduling").
 AGENT_DEMOTE_ENV = "KAFKA_TPU_AGENT_DEMOTE"
 AGENT_LINGER_ENV = "KAFKA_TPU_AGENT_LINGER_MS"
+
+
+class RecurrentStateUnsupported(ValueError):
+    """An engine option (or a request) that cannot carry a model's
+    recurrent state: the per-thread state of its state-space layers lives
+    in a state slot beside the pages (models/hybrid.py), and this path
+    moves, shards, rolls back or stores pages alone.  Raised at engine
+    construction or at admission, naming the path: never a wrong token."""
+
+    def __init__(self, path: str, why: str):
+        self.path = path
+        super().__init__(
+            f"{path} cannot carry a recurrent state: {why}"
+        )
 
 
 def agent_demote_default() -> str:
@@ -467,6 +483,14 @@ class GenRequest:
     # (client cancel) or not yet finalized.  The serving layer reads it
     # for span attrs / logs; /metrics aggregates the counters.
     slo_met: Optional[bool] = None
+    # A model with a recurrent state (engine.state_pool): the snapshot slot
+    # the prefix hit found (one reference held until the restore is
+    # enqueued), how far the PAGES matched past it (the first prefill chunk
+    # is cut there, so that boundary's snapshot is stored for the next
+    # request), and that match in tokens (for the counters).
+    state_snapshot: Optional[int] = None
+    state_cut: int = 0
+    state_matched: int = 0
 
     @property
     def cached_len(self) -> int:
@@ -676,6 +700,35 @@ class InferenceEngine:
         # grouped-GQA kv replica factor (parallel/mesh.py factor_tp_for_kv):
         # q heads/MLP shard over tp*tq, kv params + pool over tp alone
         self._tq = mesh.shape.get("tq", 1) if mesh is not None else 1
+        if cfg.has_state:
+            # The recurrent state lives in state slots beside the pages;
+            # whatever moves, shards, rolls back or stores pages alone is
+            # refused here, by name (models/hybrid.HybridPathError is the
+            # backstop for direct callers of forward).
+            sharded = mesh is not None and mesh.size > 1
+            refused = (
+                ("speculative verify (paged_verify_attention)",
+                 self.ecfg.speculative_k > 0,
+                 "a rejected candidate's state update cannot be rolled "
+                 "back; set speculative_k=0"),
+                ("kv_quantize int8 pool", bool(self.ecfg.kv_quantize),
+                 "the int8 kernels have no differential form and the state "
+                 "stays float32; serve with a dense pool"),
+                ("prefill_ring", sp > 1,
+                 "a chunk sharded over the sp axis has no sequential scan; "
+                 "use sp=1"),
+                ("a pp / tp / ep mesh", sharded and sp == 1,
+                 "the state slots and the scan kernel live on one device; "
+                 "serve each replica on one device (dp)"),
+                ("a KV tier (kv_host_tier_mb / kv_object_dir) and its "
+                 "sleep manifests",
+                 bool(self.ecfg.kv_host_tier_mb or self.ecfg.kv_object_dir),
+                 "the page shipper moves pages and a demoted run would "
+                 "come back without its snapshot; serve without a tier"),
+            )
+            for path, hit, why in refused:
+                if hit:
+                    raise RecurrentStateUnsupported(path, why)
         if cfg.is_windowed:
             # Every attention path honours the window (models/llama.py) or
             # is refused here, by name.
@@ -896,9 +949,17 @@ class InferenceEngine:
             )
         ps = self.ecfg.page_size
         self.pool = PagePool(self.ecfg.num_pages, ps)
+        # State slots of a model with a recurrent state (None otherwise):
+        # lane i's slot is slot i, then the trash slot, then snapshots.
+        self.state_pool: Optional[StatePool] = None
+        if cfg.has_state:
+            self.state_pool = StatePool(
+                default_state_slots(self.ecfg.max_batch),
+                self.ecfg.max_batch)
         k_pool, v_pool = make_kv_pool_arrays(
             cfg, self.ecfg.num_pages, ps, kv_dtype,
             quantize=self.ecfg.kv_quantize,
+            state_slots=self.state_pool.n_slots if self.state_pool else 0,
         )
         if mesh is not None:
             # placement happens for ANY mesh, including a 1-device one —
@@ -932,8 +993,18 @@ class InferenceEngine:
         # bytes one cached token holds over all layers, as allocated (both
         # pools, every leaf: int8 scales and lane padding included)
         self.kv_bytes_per_token = sum(
-            a.nbytes for a in jax.tree.leaves((self.k_pool, self.v_pool))
+            a.nbytes for a in jax.tree.leaves(
+                (self.k_pool, self.v_pool["v"] if cfg.has_state
+                 else self.v_pool))
         ) // (self.ecfg.num_pages * ps)
+        # Monotonic (a model with a recurrent state; 0 otherwise): snapshot
+        # restores enqueued, and over the admissions that started a prefill
+        # the tokens their PAGES matched against the tokens a snapshot let
+        # them skip (skipped / matched is what the snapshots make of the
+        # page cache).
+        self.state_restores = 0
+        self.state_tokens_matched = 0
+        self.state_tokens_skipped = 0
         if self.ecfg.num_pages - 1 < self.ecfg.max_pages_per_seq:
             raise ValueError(
                 "num_pages must exceed max_pages_per_seq: a lone sequence "
@@ -1036,7 +1107,8 @@ class InferenceEngine:
                 "agent_demote must be '' (off), 'host', or 'object'"
             )
         self.prefix_cache: Optional[PrefixCache] = (
-            PrefixCache(self.pool, max_pages=self.ecfg.prefix_cache_pages)
+            PrefixCache(self.pool, max_pages=self.ecfg.prefix_cache_pages,
+                        state_pool=self.state_pool)
             if self.ecfg.prefix_cache_entries > 0
             and self.ecfg.prefix_cache_pages != 0
             else None
@@ -1270,7 +1342,7 @@ class InferenceEngine:
             seq = getattr(req, "seq", None)
             if seq is None:
                 continue
-            held += seq.length * cfg.num_layers
+            held += seq.length * cfg.kv_layers
             dead += max(seq.length - cfg.sliding_window + 1, 0) * windowed
         return dead / held if held else 0.0
 
@@ -1474,6 +1546,11 @@ class InferenceEngine:
     def submit(self, req: GenRequest) -> None:
         if len(req.prompt_ids) == 0:
             raise ValueError("empty prompt")
+        if req.handoff and self.state_pool is not None:
+            raise RecurrentStateUnsupported(
+                "dp hand-off of a prefilled run",
+                "the page shipper would move the run's pages and leave its "
+                "state behind; serve colocated (no dp_roles)")
         if (
             not req.background
             and self.ecfg.max_waiting > 0
@@ -1632,6 +1709,14 @@ class InferenceEngine:
             k_leaves, v_leaves = ship.resolve(pending)
             ship.import_run(k_leaves, v_leaves, b, [TRASH_PAGE] * b)
 
+    def warmup_state(self) -> None:
+        """Compile the snapshot-restore program (the trash slot copied onto
+        itself).  No-op for a model without a recurrent state."""
+        if self.state_pool is None:
+            return
+        trash = self._arg(np.int32(self.state_pool.trash))
+        self.v_pool = self._programs.state_copy()(self.v_pool, trash, trash)
+
     def _object_fingerprint(self) -> str:
         """The object tier's content-address fingerprint: model name +
         page geometry + per-slot pool layout (+ an operator namespace,
@@ -1654,6 +1739,10 @@ class InferenceEngine:
         PrefixCache.sleep_to_object for the contract.  Must run with the
         scheduler quiesced (single-writer: the provider parks the
         worker first)."""
+        if self.state_pool is not None:
+            raise RecurrentStateUnsupported(
+                "a sleep manifest (sleep_to_object)",
+                "a thread woken from its pages alone would have no state")
         if (
             self.prefix_cache is None
             or self.kv_tier is None
@@ -2058,6 +2147,20 @@ class InferenceEngine:
         problems += self.pool.reconcile(
             self._expected_page_owners(), repair=repair
         )
+        if self.state_pool is not None:
+            problems += self.state_pool.check_consistency()
+            owners = (self.prefix_cache.snapshot_owners()
+                      if self.prefix_cache is not None else {})
+            for req in self._requests.values():
+                if req.state_snapshot is not None:
+                    owners[req.state_snapshot] = owners.get(
+                        req.state_snapshot, 0) + 1
+            sp = self.state_pool
+            for slot in range(sp.lanes + 1, sp.n_slots):
+                if int(sp.refcount[slot]) != owners.get(slot, 0):
+                    problems.append(
+                        f"state slot {slot}: refcount {sp.refcount[slot]}, "
+                        f"{owners.get(slot, 0)} live owners")
         return problems
 
     def lane_table(self) -> List[Dict[str, Any]]:
@@ -2655,6 +2758,17 @@ class InferenceEngine:
         finally:
             if self.kv_tier is not None:
                 self.kv_tier.trace_ctx = None
+        if hit is not None and self.state_pool is not None:
+            # the hit was shortened to the deepest snapshot under the page
+            # match: resume there, cut the first chunk where the pages
+            # ended (so that boundary's snapshot is stored for the next
+            # request), and hold the snapshot until the restore is enqueued
+            req.state_snapshot = hit.snapshot
+            req.state_matched = hit.matched_tokens
+            req.state_cut = (hit.matched_tokens
+                             if hit.matched_tokens > hit.tokens else 0)
+            if not hit.tokens:
+                hit = None
         if hit is not None:
             req.seq = SequencePages(seq_id=req.request_id)
             req.seq.pages, req.seq.length = hit.pages, hit.tokens
@@ -2691,6 +2805,78 @@ class InferenceEngine:
         req.cache_source = None
         req.promoted_tokens = 0
         req.object_tokens = 0
+        self._drop_state_hit(req)
+
+    def _drop_state_hit(self, req: GenRequest) -> None:
+        """Give back the snapshot reference a prefix hit took and forget
+        where its pages matched (a rolled-back attach, a preemption)."""
+        if req.state_snapshot is not None:
+            self.prefix_cache.release_snapshot(req.state_snapshot)
+            req.state_snapshot = None
+        req.state_cut = req.state_matched = 0
+
+    def _restore_state(self, req: GenRequest, slot: int) -> None:
+        """Copy the snapshot the prefix hit found into the lane's state slot
+        (a state is mutated in place by every pass, pages are not), count
+        the admission, and give the snapshot's reference back: program order
+        keeps the copy ahead of whatever overwrites the snapshot later."""
+        self.state_tokens_matched += req.state_matched
+        self.state_tokens_skipped += req.cached_tokens
+        snap, req.state_snapshot = req.state_snapshot, None
+        if snap is None:
+            return
+        t0 = time.monotonic()
+        annotate = (jax.profiler.TraceAnnotation("kafka.state_restore")
+                    if profiler_annotations_enabled()
+                    else contextlib.nullcontext())
+        with annotate:
+            self.v_pool = self._programs.state_copy()(
+                self.v_pool, self._arg(np.int32(snap)),
+                self._arg(np.int32(slot)))
+        self.state_restores += 1
+        self.prefix_cache.release_snapshot(snap)
+        if req.trace is not None:
+            record_span(req.trace, "kafka.state_restore",
+                        time.monotonic() - t0,
+                        attrs=self._tattrs(tokens=req.cached_tokens))
+
+    def _snapshot_slot(self, req: GenRequest, end: int) -> Optional[int]:
+        """A state slot for the snapshot a prefill chunk ending at token
+        `end` leaves: only a page boundary can be shared from."""
+        if (self.state_pool is None or self.prefix_cache is None
+                or req.prefix_key is None or end % self.ecfg.page_size):
+            return None
+        return self.prefix_cache.alloc_snapshot()
+
+    def _store_prefill(self, req: GenRequest, end: int,
+                       snap: Optional[int] = None) -> None:
+        """A model with a recurrent state stores a prompt's whole pages as
+        they are dispatched, not at the thread's finish: with the snapshot
+        a chunk left at `end`, and at the prompt's end the pages alone, so
+        that the next request's pages match as far as this prompt shares
+        and its cut chunk stores the snapshot there.  Program order keeps
+        any later reader behind the writes."""
+        if (self.state_pool is None or self.prefix_cache is None
+                or req.prefix_key is None):
+            return
+        ps = self.ecfg.page_size
+        n_full = end // ps
+        if n_full:
+            self.prefix_cache.store(
+                req.prefix_key, req.prefill_ids[:n_full * ps],
+                req.seq.pages[:n_full],
+                snapshot=None if snap is None else (end, snap))
+        elif snap is not None:
+            self.prefix_cache.release_snapshot(snap)
+
+    def _prefill_remaining(self, req: GenRequest) -> int:
+        """Tokens the next prefill chunk may hold: the rest of the prompt,
+        or (a model with a recurrent state) up to where the hit's pages
+        matched."""
+        start = req.seq.length
+        if req.state_cut > start:
+            return req.state_cut - start
+        return len(req.prefill_ids) - start
 
     def _admit(self) -> None:
         # Strict submit-order FIFO across BOTH queues: each free slot goes
@@ -2796,6 +2982,8 @@ class InferenceEngine:
         ecfg = self.ecfg
         if ecfg.max_parked <= 0 or not self.waiting:
             return
+        if self.state_pool is not None:
+            return  # a lane's state slot is its decode slot: no slot, no state
         if self._park_cooldown > 0:
             return  # recent page-pressure rollback: let ACTIVE lanes grow
         if self._free_slot() is not None:
@@ -2891,6 +3079,8 @@ class InferenceEngine:
             add_event(req.trace, "resume", self._prefill_attrs(req))
         req.seq = req.seq or SequencePages(seq_id=req.request_id)
         self.pool.ensure_capacity(req.seq, len(req.prefill_ids) + 1)
+        if self.state_pool is not None:
+            self._restore_state(req, slot)
         if req.cached_tokens and self.prefix_cache is not None:
             # the attach survived the page gate: NOW the hit counts (a
             # blocked head's repeated lookups never did — see commit_hit)
@@ -2941,7 +3131,7 @@ class InferenceEngine:
             self._ctl_dirty = True  # decode must mask this lane immediately
 
     def _prefill_bucket_for(self, req: GenRequest) -> int:
-        remaining = len(req.prefill_ids) - req.seq.length
+        remaining = self._prefill_remaining(req)
         if req.background and any(
             s is not None and s.state == ACTIVE and not s.background
             for s in self.slots
@@ -3051,10 +3241,14 @@ class InferenceEngine:
         top_ps = np.ones(W, np.float32)
         seeds = np.zeros(W, np.uint32)
         lane_active = np.zeros(W, bool)
+        if self.state_pool is not None:
+            # (an idle lane reads, writes and snapshots the trash slot)
+            slots = np.full(W, self.state_pool.trash, np.int32)
+            snaps = slots.copy()
         for i, req in enumerate(reqs):
             start = req.seq.length
             prompt = req.prefill_ids
-            clen = min(len(prompt) - start, bucket)
+            clen = min(self._prefill_remaining(req), bucket)
             chunks[i, :clen] = prompt[start:start + clen]
             page_rows[i, : len(req.seq.pages)] = req.seq.pages
             starts[i] = start
@@ -3064,7 +3258,14 @@ class InferenceEngine:
             top_ps[i] = req.top_p
             seeds[i] = req.seed
             lane_active[i] = True
+            if self.state_pool is not None:
+                slots[i] = req.slot
+                snap = self._snapshot_slot(req, start + clen)
+                if snap is not None:
+                    snaps[i] = snap
         vis = ()
+        if self.state_pool is not None:
+            vis = (self._arg(slots), self._arg(snaps))
         if self.cfg.vision is not None:
             chunk_ovs = [
                 self._chunk_override(req, int(starts[i]), bucket)
@@ -3102,6 +3303,11 @@ class InferenceEngine:
         finals_row: List[Optional[str]] = [None] * W
         for i, req in enumerate(reqs):
             req.seq.length += int(chunk_lens[i])
+            if self.state_pool is not None:
+                self._store_prefill(
+                    req, req.seq.length,
+                    int(snaps[i]) if snaps[i] != self.state_pool.trash
+                    else None)
             if req.seq.length < len(req.prefill_ids):
                 continue  # more chunks to go
             req.prefill_allowed = None
@@ -3198,7 +3404,7 @@ class InferenceEngine:
         start = req.seq.length  # >0 after a prefix-cache hit (_attach_prefix)
         prompt = req.prefill_ids
         total = len(prompt)
-        remaining = total - start
+        remaining = self._prefill_remaining(req)
         bucket = self._prefill_bucket_for(req)
         chunk_len = min(remaining, bucket)
         chunk = np.zeros(bucket, np.int32)
@@ -3212,6 +3418,13 @@ class InferenceEngine:
                 vis = self._zero_override((bucket,))
             else:
                 vis = (self._arg(co[0]), self._arg(co[1]))
+        snap = None
+        if self.state_pool is not None:
+            # the lane's state slot and where this chunk leaves a snapshot
+            snap = self._snapshot_slot(req, start + chunk_len)
+            vis = (self._arg(np.int32(req.slot)),
+                   self._arg(np.int32(
+                       self.state_pool.trash if snap is None else snap)))
         fn = self._programs.prefill(bucket)
         with self._dispatch_scope("prefill", (req,)):
             self.k_pool, self.v_pool, tok = fn(
@@ -3239,6 +3452,7 @@ class InferenceEngine:
         if self.flight is not None:
             self.flight.note_prefill(1, chunk_len)
         req.seq.length = start + chunk_len
+        self._store_prefill(req, req.seq.length, snap)
         if req.seq.length < total:
             return  # more chunks to go; decode proceeds meanwhile
         self._finish_prefill(req, tok)
@@ -4374,6 +4588,28 @@ class InferenceEngine:
         if req.seq is not None:
             self.pool.free_sequence(req.seq)
             req.seq = None
+        if self.state_pool is not None:
+            # the lane's state slot goes with its decode slot; a hit not
+            # yet restored gives its snapshot back
+            self._drop_state_hit(req)
+
+    def state_section(self) -> Dict[str, int]:
+        """STATE_METRIC_KEYS snapshot section (runtime/metrics.py): the
+        state slots of a model with a recurrent state."""
+        sp, pc = self.state_pool, self.prefix_cache
+        return {
+            "state_slots_total": sp.n_slots,
+            "state_slots_live": sum(s is not None for s in self.slots)
+            + sp.snapshots_live,
+            "state_snapshots": sp.snapshots_live,
+            "state_snapshot_slots": sp.snapshot_slots,
+            "state_restores": self.state_restores,
+            "state_tokens_matched": self.state_tokens_matched,
+            "state_tokens_skipped": self.state_tokens_skipped,
+            "state_snapshots_stored": pc.snapshots_stored if pc else 0,
+            "state_snapshots_evicted": pc.snapshots_evicted if pc else 0,
+            "state_bytes_per_slot": self.cfg.state_bytes_per_slot,
+        }
 
     def _preempt_youngest(self) -> None:
         """Roll the most recent request back to the waiting queue."""

@@ -8,7 +8,15 @@ alignment the Pallas paged kernel's DMAs require; latent attention: the
 latent c~ in one, the roped k_r in the other; a model whose kinds of layer
 store different rows has a pool pair per kind under the one page table; see
 make_kv_pool_arrays);
-sequences own ordered lists of physical pages.  The
+sequences own ordered lists of physical pages.
+
+A hybrid decoder (`cfg.has_state`: state-space layers) also keeps, per
+thread, a recurrent state that no page can hold: a fixed-size STATE SLOT of
+the device arrays `make_state_arrays` allocates beside the pools (they ride
+in the v pool's pytree, models/hybrid.py), handed out by `StatePool`.  Slot i
+< lanes is decode lane i's for its life, one slot is the trash slot, the rest
+are snapshots the prefix cache owns: a page can be shared from any page
+boundary, a recurrence only from where a snapshot stands.  The
 host-side allocator is refcounted so pages can be shared between sequences —
 the mechanism behind thread-keyed cache reuse and prefix sharing (BASELINE
 configs 2 and 5).
@@ -190,9 +198,89 @@ class PagePool:
         return reports
 
 
+class StatePool:
+    """Host allocator over the state-slot axis of a hybrid decoder's state
+    arrays.  Slots 0 .. lanes - 1 belong to the decode lanes (lane i reads
+    and writes slot i: the decode programs address them by position), slot
+    `lanes` is the trash slot (inactive prefill lanes, snapshots nobody
+    wants), the rest are SNAPSHOT slots, refcounted as pages are: the radix
+    node that owns a snapshot holds one reference, a lookup that means to
+    restore it holds one until the copy is enqueued."""
+
+    def __init__(self, n_slots: int, lanes: int):
+        if n_slots < lanes + 1:
+            raise ValueError(
+                f"{n_slots} state slots cannot hold {lanes} lanes and the "
+                "trash slot")
+        self.n_slots, self.lanes = n_slots, lanes
+        self.trash = lanes
+        self.refcount = np.zeros(n_slots, dtype=np.int32)
+        self._free: List[int] = list(range(n_slots - 1, lanes, -1))
+        # monotonic
+        self.allocs = 0
+        self.alloc_failures = 0
+
+    @property
+    def snapshot_slots(self) -> int:
+        return self.n_slots - self.lanes - 1
+
+    @property
+    def free_slots(self) -> int:
+        return len(self._free)
+
+    @property
+    def snapshots_live(self) -> int:
+        return self.snapshot_slots - len(self._free)
+
+    def alloc(self) -> Optional[int]:
+        """A free snapshot slot with one reference, or None (the caller
+        evicts a snapshot or goes without: never an error)."""
+        if not self._free:
+            self.alloc_failures += 1
+            return None
+        slot = self._free.pop()
+        self.refcount[slot] = 1
+        self.allocs += 1
+        return slot
+
+    def retain(self, slot: int) -> None:
+        assert self.refcount[slot] > 0, f"retain of unowned state slot {slot}"
+        self.refcount[slot] += 1
+
+    def release(self, slot: int) -> None:
+        assert self.refcount[slot] > 0, f"double free of state slot {slot}"
+        self.refcount[slot] -= 1
+        if self.refcount[slot] == 0:
+            self._free.append(slot)
+
+    def check_consistency(self) -> List[str]:
+        problems = []
+        free = set(self._free)
+        for slot in range(self.lanes + 1, self.n_slots):
+            rc = int(self.refcount[slot])
+            if (rc == 0) != (slot in free):
+                problems.append(
+                    f"state slot {slot}: refcount {rc}, "
+                    f"{'' if slot in free else 'not '}free-listed")
+        return problems
+
+
+def default_state_slots(lanes: int) -> int:
+    """State slots of an engine with `lanes` decode lanes: a lane's each,
+    the trash slot, and three snapshots a lane."""
+    return 4 * lanes + 1
+
+
+def make_state_arrays(cfg: ModelConfig, n_slots: int) -> Dict[str, Any]:
+    """The device-side state slots of a hybrid decoder: one float32 array a
+    state leaf, [state layers, n_slots, ...] (`cfg.state_shapes`)."""
+    return {name: jnp.zeros((cfg.state_layers, n_slots) + shape, jnp.float32)
+            for name, shape in cfg.state_shapes()}
+
+
 def make_kv_pool_arrays(
     cfg: ModelConfig, num_pages: int, page_size: int, dtype=None,
-    quantize: str = "",
+    quantize: str = "", state_slots: int = 0,
 ) -> Tuple[Any, Any]:
     """Allocate the device-side K and V pools.
 
@@ -215,9 +303,14 @@ def make_kv_pool_arrays(
     inside the gather (models/llama.py).  The QTensor shape rides through
     every jitted program as an ordinary pytree, so the engine's fns don't
     change signature.
+
+    A hybrid decoder (`cfg.has_state`) holds rows for `cfg.kv_layers` layers
+    and `state_slots` state slots; its v pool is {"v": rows, "conv": ...,
+    "ssm": ...}.
     """
     dtype = dtype or cfg.activation_dtype
-    lead = (cfg.num_layers, num_pages * page_size)
+    # (a hybrid decoder: only its attention layers with K/V of their own)
+    lead = (cfg.kv_layers, num_pages * page_size)
     if quantize == "int8":
         from ..models.quant import QTensor
 
@@ -250,6 +343,12 @@ def make_kv_pool_arrays(
                 v[INDEX] = pool(widths[2], of_kind)
         return k, v
     k_width, v_width = cfg.kv_row_widths()
+    if cfg.has_state:
+        # the recurrent state rides in the v pool's pytree: every step
+        # program donates, carries and returns it with the rows
+        # (models/hybrid.py)
+        return pool(k_width), {
+            "v": pool(v_width), **make_state_arrays(cfg, state_slots)}
     return pool(k_width), pool(v_width)
 
 

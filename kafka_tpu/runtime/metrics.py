@@ -361,6 +361,22 @@ AGENT_METRIC_KEYS = (
                                # interactive work
 )
 
+# State slots of a model with a recurrent state beside its pages
+# (engine.state_section, models/hybrid.py): the /metrics section "state",
+# present only for such a model.
+STATE_METRIC_KEYS = (
+    "state_slots_total",        # gauge: lanes + trash + snapshot slots
+    "state_slots_live",         # gauge: seated lanes + standing snapshots
+    "state_snapshots",          # gauge: snapshots the prefix cache owns
+    "state_snapshot_slots",     # gauge: slots snapshots may take
+    "state_restores",           # counter: snapshots copied into a lane's slot
+    "state_tokens_matched",     # counter: tokens admissions' PAGES matched
+    "state_tokens_skipped",     # counter: tokens a snapshot let them skip
+    "state_snapshots_stored",   # counter: snapshots a radix node took
+    "state_snapshots_evicted",  # counter: dropped for want of slots
+    "state_bytes_per_slot",     # gauge: bytes one slot holds, all layers
+)
+
 # EngineMetrics export lint (ISSUE 10 satellite): every dataclass field is
 # either exported through snapshot() (listed here with the snapshot path
 # that carries it) or on the explicit exclusion list with a reason — a new
@@ -1541,6 +1557,10 @@ class EngineMetrics:
                 mem = mm.section()
                 if mem is not None:
                     snap["memory"] = mem
+            if getattr(engine, "state_pool", None) is not None:
+                # state slots of a model with a recurrent state
+                # (STATE_METRIC_KEYS)
+                snap["state"] = engine.state_section()
             agent_fn = getattr(engine, "agent_section", None)
             if agent_fn is not None:
                 # agent-native scheduling (AGENT_METRIC_KEYS): tool-gap

@@ -38,7 +38,8 @@ from __future__ import annotations
 import dataclasses
 from typing import Dict, Optional
 
-from ..models.config import ModelConfig
+from ..models.config import CROSS, GMU, ModelConfig
+from .kv_cache import default_state_slots
 
 GiB = 1024**3
 MiB = 1024**2
@@ -170,11 +171,14 @@ class MemoryPlan:
     # plan states the full memory footprint — but deliberately NOT part
     # of total_bytes, which is the per-chip HBM budget.  0 = tier off.
     kv_host_tier_bytes: int = 0
+    # State slots of a model with a recurrent state beside its pages
+    # (runtime/kv_cache.make_state_arrays), as the device lays them out.
+    state_bytes: int = 0
     notes: str = ""
 
     @property
     def total_bytes(self) -> int:
-        return (self.weight_bytes + self.kv_pool_bytes
+        return (self.weight_bytes + self.kv_pool_bytes + self.state_bytes
                 + self.activation_bytes + self.grammar_table_bytes)
 
     @property
@@ -205,6 +209,7 @@ class MemoryPlan:
             "hbm_gib": round(self.hbm_bytes / GiB, 2),
             "weight_gib": round(self.weight_bytes / GiB, 3),
             "kv_pool_gib": round(self.kv_pool_bytes / GiB, 3),
+            "state_mib": round(self.state_bytes / MiB, 2),
             "activation_gib": round(self.activation_bytes / GiB, 3),
             "total_gib": round(self.total_bytes / GiB, 3),
             "usable_gib": round(self.usable_bytes / GiB, 3),
@@ -247,6 +252,8 @@ def weight_bytes_per_device(
 
     if cfg.is_latent:
         return _latent_weight_bytes(cfg, mat, wb)
+    if cfg.has_state:
+        return _hybrid_weight_bytes(cfg, wb)
     per_layer = (
         mat(h, hq * d, tp)            # wq
         + 2 * mat(h, hkv * d, kv_shard)  # wk, wv
@@ -269,6 +276,36 @@ def weight_bytes_per_device(
     if not cfg.tie_word_embeddings:
         total += mat(h, cfg.vocab_size, tp)
     return total
+
+
+def _hybrid_weight_bytes(cfg: ModelConfig, wb: int) -> int:
+    """Weights of a hybrid decoder on its one device (the engine refuses
+    meshes for it): the tree models/hybrid.init_params builds.  Float32
+    leaves (conv, dt bias, A_log, D, lambdas) are counted at 4 bytes."""
+    h, f, d = cfg.hidden_size, cfg.intermediate_size, cfg.head_dim
+    hq, hkv = cfg.num_heads, cfg.num_kv_heads
+    di, ds, dc = cfg.mamba_d_inner, cfg.mamba_d_state, cfg.mamba_d_conv
+    r = cfg.mamba_dt_rank
+    n_m = cfg.state_layers
+    n_x = cfg.layers_of(CROSS)
+    common = cfg.num_layers * (3 * h * f + 4 * h) * wb
+    mamba = n_m * ((h * 2 * di + di * (r + 2 * ds) + r * di + di * h) * wb
+                   + (dc * di + 3 * di + ds * di) * 4)
+    diff = 4 * d * 4 + 2 * d * wb  # lambda vectors, sub-layer norm
+    attn = n_m * ((h * (hq + 2 * hkv) * d + (hq + 2 * hkv) * d
+                   + hq * d * h + h) * wb + diff)
+    cross = n_x * ((2 * h * hq * d + hq * d + h) * wb + diff)
+    gmu = n_x * 2 * h * di * wb
+    return (common + mamba + attn + cross + gmu
+            + (cfg.vocab_size * h + 2 * h) * wb)
+
+
+def state_bytes_per_device(cfg: ModelConfig, state_slots: int) -> int:
+    """The state slots as the device holds them: float32, the second-minor
+    axis of each leaf padded to the 8-row sublane tile (a conv tail of 3
+    rows takes 8)."""
+    return 4 * cfg.state_layers * state_slots * sum(
+        -(-rows // 8) * 8 * cols for _, (rows, cols) in cfg.state_shapes())
 
 
 def _latent_weight_bytes(cfg: ModelConfig, mat, wb: int) -> int:
@@ -364,10 +401,16 @@ def activation_bytes_estimate(
     kv_row = max(sum(cfg.kv_row_widths(kind)) for kind in cfg.kinds)
     s_local = max(1, prefill_bucket // max(sp, 1))
     prefill = (
-        s_local * (V // tp) * 4
+        # (a hybrid decoder's prefill computes its lanes' last rows alone)
+        (1 if cfg.has_state else s_local) * (V // tp) * 4
         + s_local * (H + 2 * F // tp) * 2
         + window * kv_row * 2
     )
+    if cfg.has_state:
+        # the scan's float32 operands: x, dt, y, z and the projections
+        # around them, [S, inner] each, and B / C broadcast along 128 lanes
+        prefill += s_local * (cfg.mamba_d_inner * 4 * 6
+                              + cfg.mamba_d_state * 128 * 4 * 2)
     decode = max_batch * V * 4 * 3 + max_batch * window * kv_row * 2
     return max(prefill, decode)
 
@@ -392,6 +435,7 @@ def plan_memory(
     kv_shard: Optional[int] = None,
     grammar_table_bytes: Optional[int] = None,
     kv_host_tier_bytes: int = 0,
+    state_slots: int = 0,
 ) -> MemoryPlan:
     if hbm_bytes is None:
         hbm_bytes = HBM_BYTES[chip]
@@ -437,6 +481,7 @@ def plan_memory(
         tq=tp // kv_shard,
         grammar_table_bytes=grammar_table_bytes,
         kv_host_tier_bytes=kv_host_tier_bytes,
+        state_bytes=state_bytes_per_device(cfg, state_slots),
         notes=(
             (
                 f"grouped GQA layout: tensor degree {tp} factorizes "
@@ -582,7 +627,11 @@ def dispatch_cost_model(
             else 2 * cfg.head_dim)
     return DispatchCostModel(
         flops_per_token=2.0 * params_total / n,
-        attn_flops_per_kv=2.0 * cfg.num_layers * cfg.num_heads * pair / n,
+        # (a hybrid decoder: its attention layers, own K/V and cross)
+        attn_flops_per_kv=2.0 * (cfg.num_layers if not cfg.has_state else
+                                 cfg.num_layers - cfg.state_layers
+                                 - cfg.layers_of(GMU))
+        * cfg.num_heads * pair / n,
         weight_bytes=int(weight_bytes_total // n),
         kv_bytes_per_token=int(kv_row * max(1, kv_replication) // n),
     )
@@ -623,6 +672,8 @@ def plan_for_serving(scfg, hbm_bytes: Optional[int] = None,
         # host-RAM tier budget (not HBM): stated in the plan so capacity
         # reviews see the full footprint of a tiered deployment
         kv_host_tier_bytes=getattr(scfg, "kv_host_tier_mb", 0) * MiB,
+        state_slots=(default_state_slots(scfg.max_batch)
+                     if model_cfg.has_state else 0),
     )
 
 

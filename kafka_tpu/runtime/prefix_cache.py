@@ -50,6 +50,20 @@ Mechanics:
   ``match_tokens`` counts host-resident runs as matchable, so the DP
   router treats a host-tier prefix as routable affinity.
 
+* With a STATE POOL attached (a model with a recurrent state beside its
+  pages, runtime/kv_cache.StatePool), a node may own a SNAPSHOT: the state
+  slot that holds the recurrence's state after the node's last token.
+  Pages can be shared from any page boundary, a recurrence cannot: a hit is
+  only usable where a snapshot stands, so `lookup()` returns the pages up
+  to the DEEPEST SNAPSHOT at or under the page match (and says how far the
+  pages matched, so the caller can cut its prefill there and store the
+  boundary's snapshot for the next request).  Snapshots stand at node ends
+  only: `store(..., snapshot=(position, slot))` splits a run at the
+  position, `_split` leaves the snapshot with the half that ends where it
+  stands, and removing or trimming a node frees its snapshot.  When the
+  snapshot slots run out the least recently used snapshot nobody is
+  restoring is dropped (its pages stay).
+
 Sharing is safe with the engine's async pipeline: a retiring request's
 in-flight decode steps only write KV at positions >= the stored token
 count, which land in the first partial (unshared) page or later.
@@ -81,6 +95,12 @@ class PrefixHit:
     # tokens of the hit re-materialized from the shared OBJECT store —
     # a dormant thread waking on a replica that never served it
     object_tokens: int = 0
+    # With a state pool: the state slot whose snapshot stands at `tokens`
+    # (the caller owns one reference until it has enqueued the restore),
+    # and how many tokens the PAGES matched (>= tokens: the hit was
+    # shortened to the deepest snapshot at or under the match).
+    snapshot: Optional[int] = None
+    matched_tokens: int = 0
 
 
 # Per-node claim cap: a fan-out shared-prefix node is stored through by
@@ -99,7 +119,7 @@ class _Node:
     walk still matches through it."""
 
     __slots__ = ("tokens", "pages", "children", "parent", "keys",
-                 "host_run", "shipped", "woken")
+                 "host_run", "shipped", "woken", "snapshot")
 
     def __init__(
         self,
@@ -130,6 +150,9 @@ class _Node:
         # has stored through it since: lookups crossing it classify as
         # cache_source="object_tier" — the cross-host wake proof.
         self.woken = False
+        # state slot holding the recurrent state after this run's LAST
+        # token (a model with a state pool), else None
+        self.snapshot: Optional[int] = None
 
     def n_pages(self, page_size: int) -> int:
         """Run length in pages regardless of residency."""
@@ -140,8 +163,16 @@ class PrefixCache:
     """Radix tree: token path -> retained pages, shared across threads."""
 
     def __init__(self, pool: PagePool, max_pages: Optional[int] = None,
-                 tier=None):
+                 tier=None, state_pool=None):
         self.pool = pool
+        # runtime/kv_cache.StatePool of a model with a recurrent state: a
+        # hit then needs a snapshot (module docstring).  None = pages alone.
+        self.state_pool = state_pool
+        # nodes that own a snapshot, least recently restored first
+        self._snapshots: "OrderedDict[_Node, None]" = OrderedDict()
+        self.snapshots_stored = 0   # monotonic: snapshots a node took
+        self.snapshots_evicted = 0  # monotonic: dropped for want of slots
+        self.snapshots_freed = 0    # monotonic: freed with their node
         # Page budget for retained pages (None = bounded only by pool
         # pressure via reclaim()).  Replaces the old entry-count cap: pages
         # are what the pool actually runs out of.
@@ -375,6 +406,29 @@ class PrefixCache:
         if last_node is None:
             self.misses += 1
             return None
+        snapshot = None
+        matched_tokens = len(pages) * ps
+        if self.state_pool is not None:
+            # the deepest snapshot at or under the page match: a node's
+            # snapshot stands at its end, so only a run taken whole counts
+            keep, at, holder = 0, 0, None
+            for node, take in segments:
+                at += take
+                if at > len(pages):
+                    break  # past a torn promotion
+                if node.snapshot is not None and take == node.n_pages(ps):
+                    keep, holder = at, node
+            pages = pages[:keep]
+            if holder is not None:
+                snapshot = holder.snapshot
+                self.state_pool.retain(snapshot)
+                self._snapshots.move_to_end(holder)
+            if not pages:
+                # pages matched, no snapshot under them: nothing to share,
+                # but the caller learns where the pages end
+                self.misses += 1
+                return PrefixHit(pages=[], tokens=0, source="cross",
+                                 matched_tokens=matched_tokens)
         # refresh recency: only the deepest matched node can be a leaf
         # (its ancestors have children by construction), so one touch
         # keeps hot prefixes off the eviction front
@@ -398,7 +452,8 @@ class PrefixCache:
             source = "cross"
         return PrefixHit(pages=pages, tokens=cached, source=source,
                          promoted_tokens=promoted,
-                         object_tokens=object_tok)
+                         object_tokens=object_tok, snapshot=snapshot,
+                         matched_tokens=matched_tokens)
 
     def _wake_from_object(self, key: str, prompt_ids: Sequence[int],
                           matched: int, protect) -> bool:
@@ -589,7 +644,8 @@ class PrefixCache:
     # -- store -----------------------------------------------------------
 
     def store(self, key: str, tokens: Sequence[int], pages: Sequence[int],
-              shipped: bool = False, woken: bool = False) -> None:
+              shipped: bool = False, woken: bool = False,
+              snapshot: Optional[Tuple[int, int]] = None) -> None:
         """Insert a finished sequence's materialized tokens along its path.
 
         Only whole pages are stored (`tokens` must count exactly the
@@ -614,6 +670,13 @@ class PrefixCache:
         runs never read their page entries, and the guards below make a
         dummy id inert everywhere one could otherwise be captured (fresh
         insert after a racing eviction, host-run adoption).
+
+        ``snapshot=(position, slot)`` (a state pool): state slot `slot`
+        holds the recurrent state after token `position` - 1, a page
+        boundary inside the stored run.  The store takes the caller's
+        reference: the node that ends there owns it from here (the run is
+        split at the position if need be), or it is released (the node has
+        a snapshot already, or the path no longer reaches the position).
         """
         ps = self.pool.page_size
         n_full = min(len(pages), len(tokens) // ps)
@@ -697,7 +760,63 @@ class PrefixCache:
             self._touch(child)
             node = child
             idx += take
+        if snapshot is not None:
+            self._attach_snapshot(tokens, *snapshot)
         self._evict_to_budget()
+
+    # -- snapshots of a recurrent state ----------------------------------
+
+    def _attach_snapshot(self, tokens: Sequence[int], position: int,
+                         slot: int) -> None:
+        """Give the node that ends at token `position` of the path
+        `tokens` the snapshot in `slot` (store()'s contract)."""
+        ps = self.pool.page_size
+        node, at = self._root, 0
+        target = position // ps
+        while at < target and position % ps == 0:
+            child = node.children.get(tuple(tokens[at * ps:(at + 1) * ps]))
+            if child is None:
+                break
+            n = child.n_pages(ps)
+            if at + n > target and not self._split(child, target - at):
+                break
+            node, at = child, at + child.n_pages(ps)
+        if node is self._root or at != target or node.snapshot is not None:
+            self.state_pool.release(slot)
+            if at == target and node is not self._root:
+                self._snapshots.move_to_end(node)
+            return
+        node.snapshot = slot
+        self._snapshots[node] = None
+        self.snapshots_stored += 1
+
+    def _drop_snapshot(self, node: _Node) -> None:
+        if node.snapshot is not None:
+            self.state_pool.release(node.snapshot)
+            node.snapshot = None
+            self._snapshots.pop(node, None)
+
+    def alloc_snapshot(self) -> Optional[int]:
+        """A state slot for a new snapshot (one reference, the caller's to
+        hand to store()), dropping the least recently used snapshot that
+        nobody is restoring when none is free.  None: go without."""
+        slot = self.state_pool.alloc()
+        if slot is not None:
+            return slot
+        for node in self._snapshots:
+            if self.state_pool.refcount[node.snapshot] == 1:
+                self._drop_snapshot(node)
+                self.snapshots_evicted += 1
+                return self.state_pool.alloc()
+        return None
+
+    def release_snapshot(self, slot: int) -> None:
+        """Give back a reference a lookup (or alloc_snapshot) handed out."""
+        self.state_pool.release(slot)
+
+    def snapshot_owners(self) -> Dict[int, int]:
+        """State slot -> references the tree holds (engine self_check)."""
+        return {node.snapshot: 1 for node in self._snapshots}
 
     def _split(self, node: _Node, take: int) -> bool:
         """Split `node` at `take` pages; the suffix becomes its child.
@@ -717,6 +836,11 @@ class PrefixCache:
         suffix = _Node(node.tokens[take * ps:], node.pages[take:], node)
         suffix.shipped = node.shipped  # both halves are the shipped run
         suffix.woken = node.woken
+        if node.snapshot is not None:
+            # the snapshot stands at the END of the run: the suffix's end
+            suffix.snapshot, node.snapshot = node.snapshot, None
+            self._snapshots = OrderedDict(
+                (suffix if n is node else n, None) for n in self._snapshots)
         suffix.children = node.children
         for c in suffix.children.values():
             c.parent = suffix
@@ -745,6 +869,9 @@ class PrefixCache:
         on /metrics."""
         ps = self.pool.page_size
         parent = node.parent
+        if node.snapshot is not None:
+            self._drop_snapshot(node)
+            self.snapshots_freed += 1
         if parent is not None:
             parent.children.pop(tuple(node.tokens[:ps]), None)
             if (
@@ -876,6 +1003,10 @@ class PrefixCache:
                 self.evictions += 1
                 self._remove(victim)
             else:
+                if victim.snapshot is not None:
+                    # the run's end moves: nothing stands there any more
+                    self._drop_snapshot(victim)
+                    self.snapshots_freed += 1
                 self._release_pages(victim.pages[keep:])
                 self.generation += 1
                 victim.pages = victim.pages[:keep]
@@ -1184,6 +1315,8 @@ class PrefixCache:
                     self.tier.discard(node.host_run)
             else:
                 self.pool.release(node.pages)
+            if node.snapshot is not None:
+                self._drop_snapshot(node)
         self._root = _Node([], [], None)
         self._n_nodes = 0
         self._n_pages = 0

@@ -11,6 +11,12 @@ Three things have their one home here:
   indices of a `PagedView`: the pool's addressing format, for one token per
   lane (decode), a prefill chunk (one sequence, or a row per lane) and K+1
   tokens per lane (speculative verify).
+* **The state plan** of a model with a recurrent state (`cfg.has_state`,
+  models/hybrid.py): which state slot each lane reads and writes rides the
+  `PagedView` as a `StatePlan`.  Decode's lanes are slots 0 .. B - 1; a
+  prefill program takes its lanes' slots and snapshot slots as trailing
+  arguments (where a vision model's take their override arrays), and
+  `state_copy` restores a snapshot into a lane's slot.
 * **One builder per program kind** (`StepPrograms`).  The decode-side
   programs take the lane arrays as one `Lanes` pytree and the on-device
   grammar automaton as one optional `Fsm` pytree: `None` traces the plain
@@ -213,6 +219,23 @@ def verify_plan(page_table, seq_lens, cand_lens, active, S: int, ps: int):
 # ----------------------------------------------------------------------
 
 
+def _with_state(cfg, paged, lens, slots=None, snaps=None, starts=None):
+    """`paged` with the StatePlan of a pass whose lanes hold `lens` real rows
+    (models/hybrid.StatePlan; `paged` itself for a model without a state).
+    Decode: lane i in slot i.  Prefill: lane i reads and writes `slots[i]`,
+    from zeros where it starts at position 0, and leaves a snapshot in
+    `snaps[i]`."""
+    if not cfg.has_state:
+        return paged
+    from ..models.hybrid import StatePlan
+
+    with jax.named_scope("step_ctl"):
+        if slots is None:
+            return paged._replace(state=StatePlan(lens=lens))
+        return paged._replace(state=StatePlan(
+            lens=lens, src=slots, dst=slots, snap=snaps, fresh=starts == 0))
+
+
 def _forward(cfg, mesh, params, tokens, positions, k_pool, v_pool, paged,
              vis=()):
     """The model over a paged pool -> (logits, KVCache).  `vis` = (embed
@@ -240,6 +263,7 @@ def _decode_fn(cfg: ModelConfig, mesh: Any, ps: int):
         (page_table, last_tokens, seq_lens, active, temps, top_ks,
          top_ps, seeds) = lanes
         positions, paged = decode_plan(page_table, seq_lens, active, ps)
+        paged = _with_state(cfg, paged, active.astype(jnp.int32))
         logits, cache = _forward(
             cfg, mesh, params, last_tokens[:, None], positions,
             k_pool, v_pool, paged)
@@ -307,6 +331,9 @@ def _multi_decode_fn(cfg: ModelConfig, mesh: Any, ps: int, steps: int):
 
 def _verify_fn(cfg: ModelConfig, mesh: Any, ps: int, K: int):
     S = K + 1
+    if cfg.has_state:
+        raise NotImplementedError(
+            "speculative verify cannot roll a recurrent state back")
 
     def fn(params, k_pool, v_pool, lanes, cands, cand_lens, fsm=None):
         (page_table, last_tokens, seq_lens, active, temps, top_ks,
@@ -391,13 +418,20 @@ def _prefill_fn(cfg: ModelConfig, mesh: Any, ps: int, bucket: int):
         # prefix-cache hits (resume mid-prompt).  `vis` = (ov [S, H],
         # ov_on [S]) embed-override arrays, present iff cfg.vision —
         # per-engine the arity is constant, so one compile either way.
+        # A model with a recurrent state takes (slot, snap) there instead:
+        # the lane's state slot and where to leave a snapshot.
         S = bucket
         positions, paged = prefill_plan(page_row, start, chunk_len, S, ps)
+        if cfg.has_state:
+            (slot, snap), vis = vis, ()
+            paged = _with_state(cfg, paged, chunk_len[None], slot[None],
+                                snap[None], start[None])
         logits, cache = _forward(
             cfg, mesh, params, chunk[None, :], positions, k_pool, v_pool,
             paged, tuple(v[None] for v in vis))
         with jax.named_scope("sample"):
-            last = jnp.clip(chunk_len - 1, 0, S - 1)
+            # (a hybrid decoder's prefill returns the last real row alone)
+            last = 0 if cfg.has_state else jnp.clip(chunk_len - 1, 0, S - 1)
             final_logits = logits[0, last][None, :]  # [1, V]
             sp = SamplingParams(
                 temperature=temp[None], top_k=top_k[None], top_p=top_p[None])
@@ -413,10 +447,16 @@ def _prefill_fn(cfg: ModelConfig, mesh: Any, ps: int, bucket: int):
 def _batched_prefill_fn(cfg: ModelConfig, mesh: Any, ps: int, bucket: int):
     def fn(params, k_pool, v_pool, page_rows, chunks, starts,
            chunk_lens, temps, top_ks, top_ps, seeds, lane_active, *vis):
-        # vis = (ov [W, S, H], ov_on [W, S]) iff cfg.vision
+        # vis = (ov [W, S, H], ov_on [W, S]) iff cfg.vision; (slots [W],
+        # snaps [W]) iff cfg.has_state
         S = bucket
         pos, paged = chunk_plan(
             page_rows, starts, chunk_lens, lane_active, S, ps)
+        if cfg.has_state:
+            (slots, snaps), vis = vis, ()
+            paged = _with_state(
+                cfg, paged, jnp.where(lane_active, chunk_lens, 0), slots,
+                snaps, starts)
         logits, cache = forward(
             params, cfg, chunks, pos,
             kv_cache=KVCache(k_pool, v_pool), paged=paged, mesh=mesh,
@@ -424,7 +464,8 @@ def _batched_prefill_fn(cfg: ModelConfig, mesh: Any, ps: int, bucket: int):
             override_on=vis[1] if vis else None,
         )
         with jax.named_scope("sample"):
-            last = jnp.clip(chunk_lens - 1, 0, S - 1)
+            last = (jnp.zeros_like(chunk_lens) if cfg.has_state
+                    else jnp.clip(chunk_lens - 1, 0, S - 1))
             final_logits = jnp.take_along_axis(
                 logits, last[:, None, None], axis=1)[:, 0]  # [W, V]
             keys = jax.vmap(
@@ -436,6 +477,14 @@ def _batched_prefill_fn(cfg: ModelConfig, mesh: Any, ps: int, bucket: int):
         return cache.k, cache.v, toks
 
     return fn
+
+
+def fn_state_copy(v_pool, src, dst):
+    """v_pool with state slot `dst` a copy of slot `src`, every state leaf
+    and layer (the rows leaf "v" is passed through)."""
+    with jax.named_scope("step_ctl"):
+        return {name: leaf if name == "v" else leaf.at[:, dst].set(leaf[:, src])
+                for name, leaf in v_pool.items()}
 
 
 class StepPrograms:
@@ -589,6 +638,20 @@ class StepPrograms:
         label = "verify" if fsm is None else "verify_fsm"
         key = ("verify",) + self._geometry() + (K, fsm_key)
         return self._program(label, key, fsm_key, _verify_fn, K)
+
+    def state_copy(self):
+        """Restore a snapshot: fn(v_pool, src, dst) -> v_pool' (donated)
+        with state slot `dst` a copy of `src`.  A state is mutated in place
+        by every pass, pages are not, so a prefix hit copies."""
+        fn = self.built.get(("state_copy", None))
+        if fn is None:
+            fn = _PROGRAMS.get("state_copy")
+            if fn is None:
+                # (one program whatever the model: shapes retrace it)
+                fn = _PROGRAMS["state_copy"] = compile_log.instrument(
+                    "state_copy", jax.jit(fn_state_copy, donate_argnums=(0,)))
+            self.built[("state_copy", None)] = fn
+        return fn
 
     def prefill(self, bucket: int):
         """One chunk of up to `bucket` prompt tokens of ONE sequence:

@@ -385,6 +385,9 @@ def build_tpu_provider(cfg: ServingConfig) -> LLMProvider:
             # demotion/promotion pays copy latency, not an XLA compile on
             # the scheduler thread (no-op when the tier is off)
             e.warmup_kv_tier()
+            # the snapshot restore of a model with a recurrent state: the
+            # first prefix hit pays a copy, not a compile (no-op otherwise)
+            e.warmup_state()
 
         # Replicas are independent engines over disjoint devices, and XLA
         # compiles each one's programs separately (the device assignment

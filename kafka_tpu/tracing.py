@@ -120,6 +120,11 @@ SPANS = (
     "thread.wake",    # dormant thread re-materialized from its sleep
                       # manifest; attrs: tokens, runs, bytes, source
                       # (runtime/prefix_cache.py)
+    "kafka.state_restore",  # a prefix hit's snapshot of a recurrent state
+                      # copied into the lane's state slot (host side of the
+                      # dispatch; also the profiler annotation around it);
+                      # attrs: tokens the snapshot lets the prefill skip
+                      # (engine._restore_state)
 )
 
 # Trace-level instant events (supervisor actions that punctuate a request's
@@ -183,6 +188,19 @@ DEVICE_SCOPES = (
     "moe_router",   # router logits, top-k, routing weights
     "moe_experts",  # expert matmuls, combine + residual add
     "moe_shared",   # the always-on shared experts beside the routed ones
+    # a hybrid decoder's blocks (models/hybrid.py)
+    "ssm_proj",     # a Mamba mixer's projections: W_in, W_x, W_dt +
+                    # softplus, the gate and W_out (+ residual add)
+    "ssm_conv",     # its causal depthwise conv + silu, and the conv tail
+                    # carried to the next pass
+    "ssm_scan",     # the selective scan: the Pallas kernel at s > 1, one
+                    # closed-form step in decode; the state read and write
+    "gmu",          # gated memory unit: W_2 (m * silu(W_1 u)) + residual
+    "attn_cross",   # inside attn_core: attention of a layer that READS the
+                    # full layer's rows and writes none (eight reads of one
+                    # cache a pass)
+    "attn_diff",    # differential attention's combine: P_1 V - lam P_2 V,
+                    # the sub-layer RMSNorm, (1 - lambda_init)
     "head",         # final RMSNorm + logits
     "sample",       # last-position select, per-(seed, position) keys,
                     # sample_tokens_per_slot (engine step programs)
