@@ -14,7 +14,9 @@ Design (SURVEY §2.2 "host-side dispatch thread feeding the device loop"):
   into the per-request event queue, so each request's consumer wakes on its
   own loop with no polling.
 * When idle, the thread blocks on the inbox (zero busy-wait); when active it
-  drains the inbox without blocking between decode steps.
+  drains the inbox without blocking between decode steps; when the engine
+  withheld decode because the device has enough queued, it waits on the
+  inbox for a millisecond or two before it looks again.
 
 The single-writer design means engine state needs no locks — the dispatch
 thread is the only mutator (SURVEY §5.2: the reference's hand-rolled
@@ -35,6 +37,12 @@ from ..runtime.failpoints import failpoint
 from ..tracing import add_event
 
 logger = logging.getLogger("kafka_tpu.llm.worker")
+
+
+# How long the engine thread waits on its inbox: with no work, and
+# between two looks at the device's backlog while decode is held.
+_IDLE_WAIT_S = 1.0
+_HOLD_WAIT_S = 0.0015
 
 
 @dataclass
@@ -146,10 +154,22 @@ class EngineWorker:
             while self._pause_req.is_set() and not self._stopped.is_set():
                 self._pause_ack.set()
                 self._resume_evt.wait(timeout=0.1)
-            # Block when idle; drain without blocking when active.
-            block = not self.engine.has_work
+            # Block when idle; drain without blocking when active.  When
+            # the last step withheld decode (the device has its two
+            # programs queued: engine._hold_decode) there is nothing to
+            # dispatch until one finishes, so wait a moment on the inbox
+            # instead of stepping again at once: a submission or a
+            # cancel ends the wait, and the thread does not spin against
+            # the event loop for the GIL.
+            if not self.engine.has_work:
+                wait: Optional[float] = _IDLE_WAIT_S
+            elif getattr(self.engine, "decode_held", False):
+                wait = _HOLD_WAIT_S
+            else:
+                wait = None
             try:
-                kind, payload = self._inbox.get(block=block, timeout=1.0 if block else None)
+                kind, payload = self._inbox.get(
+                    block=wait is not None, timeout=wait)
                 self._handle(kind, payload)
                 # drain any further queued commands
                 while True:

@@ -55,6 +55,7 @@ import jax
 from ..models.config import ModelConfig
 from ..parallel import MeshConfig, make_mesh, resolve_tensor_axes
 from .engine import (
+    _HOLD_NAP_S,
     FINISHED,
     EngineConfig,
     GenRequest,
@@ -261,6 +262,9 @@ class DataParallelEngines:
         self._expected_cap = 4096
         # which replica raised out of step(), so recovery targets it alone
         self._failed_replica: Optional[int] = None
+        # did every replica that stepped in the last step() withhold
+        # decode (the worker then waits a moment before the next one)
+        self.decode_held = False
         self._pre_failure_events: List[TokenEvent] = []
 
     def _make_engine(self, r: int) -> InferenceEngine:
@@ -869,12 +873,18 @@ class DataParallelEngines:
     def step(self) -> List[TokenEvent]:
         self._refresh_health()
         events: List[TokenEvent] = []
+        # A replica that withholds decode (its device has two programs
+        # queued: InferenceEngine._hold_decode) returns at once, so the
+        # others dispatch in this same pass; the driving loop waits only
+        # when every replica that stepped held.
+        held: List[bool] = []
         for i, e in enumerate(self.engines):
             if not self.health[i].routable:
                 continue  # quarantined: no traffic, no stepping
             if e.has_work:
                 try:
                     events.extend(e.step())
+                    held.append(e.decode_held)
                     self._note_success(i)
                 except Exception:
                     # remember the failing replica and the events already
@@ -904,6 +914,7 @@ class DataParallelEngines:
         for ev in events:
             if ev.finished:
                 self._route.pop(ev.request_id, None)
+        self.decode_held = bool(held) and all(held)
         return events
 
     # -- disaggregated prefill/decode (ISSUE 12) -------------------------
@@ -1075,6 +1086,8 @@ class DataParallelEngines:
             for ev in self.step():
                 if ev.finished and ev.request_id in registry:
                     done[ev.request_id] = registry[ev.request_id]
+            if self.decode_held:
+                time.sleep(_HOLD_NAP_S)
         return done
 
     def recover_from_failure(self) -> List[TokenEvent]:
@@ -1454,6 +1467,10 @@ class _AggregateMetrics:
                 k: sum(s["engine"]["fetch_pops"][k] for s in snaps)
                 for k in snaps[0]["engine"]["fetch_pops"]
             },
+            "decode_holds": sum(
+                s["engine"]["decode_holds"] for s in snaps),
+            "decode_hold_s": round(sum(
+                s["engine"]["decode_hold_s"] for s in snaps), 6),
             "experts_held": sum(
                 s["engine"]["experts_held"] for s in snaps),
             "experts_routed": sum(

@@ -24,12 +24,19 @@ compute.  Architecture:
 * **Pipelined async token fetch.** A blocking device→host read stalls the
   single scheduler thread for the device's backlog plus the copy.  Each
   step's sampled-token vector instead starts an async copy and joins a
-  FIFO, hiding the copy behind dispatched steps; the host only blocks on a
-  fetch once `fetch_lag` newer steps have been dispatched behind it, by
-  which point the transfer has long landed.
+  FIFO, hiding the copy behind dispatched steps; an entry is popped once
+  its transfer has landed, so the host does not block on a read.  How far
+  the host runs ahead of the device is bounded by `step()` itself: decode
+  is dispatched only while at most one program's worth of steps
+  (`multi_step`) is queued that the device has not been seen to finish, so
+  one program runs and one waits behind it, and a new turn's prefill
+  chunk, which is never held, waits for those two and no more.
+  `fetch_lag` is the memory backstop behind that bound (a force-pop the
+  run-ahead no longer reaches).
   Token events are therefore emitted a few steps late; the scheduler
   reconciles (stop tokens found in flight truncate the output and retire
-  the slot, which at worst wasted `fetch_lag` speculative decode steps).
+  the slot, which at worst wasted the run-ahead's speculative decode
+  steps).
 * **Host-side scheduler** (`step()`): admit waiting requests when a batch
   slot + pages are free (prefill), dispatch one decode for everyone, drain
   matured token fetches, retire finished sequences.  Preemption: if page
@@ -142,6 +149,14 @@ WAITING, PREFILLING, PARKED, ACTIVE, DRAINING, FINISHED = (
     "waiting", "prefilling", "parked", "active", "draining", "finished"
 )
 
+# The run-ahead bound (_hold_decode) where no fused program sets it
+# (multi_step <= 1): single steps then queue this deep, enough that a
+# scheduler thread woken a few milliseconds late finds the device busy.
+_HOLD_FLOOR_STEPS = 4
+# What the engine's own loops (run_to_completion, generate) sleep between
+# two held iterations; a serving loop waits on its inbox instead.
+_HOLD_NAP_S = 0.0005
+
 # Agent-native scheduling (ISSUE 20, README "Agent-native scheduling").
 AGENT_DEMOTE_ENV = "KAFKA_TPU_AGENT_DEMOTE"
 AGENT_LINGER_ENV = "KAFKA_TPU_AGENT_LINGER_MS"
@@ -199,8 +214,11 @@ class EngineConfig:
     # Sized so fetch_lag * step_time exceeds the device->host copy time —
     # then every forced read finds its transfer already complete.  The
     # age/landed bounds pop entries long before this depth when copies
-    # land quickly, so the depth is a backstop, not the cadence.  (Value
-    # chosen on an earlier machine: ROADMAP Queue 1 re-decides it.)
+    # land quickly, so the depth is a backstop, not the cadence; and it
+    # is NOT what bounds the host's run-ahead: step() withholds decode
+    # while more than max(multi_step, _HOLD_FLOOR_STEPS) unfinished steps
+    # are queued (_hold_decode), far under this.  (Value chosen on an
+    # earlier machine: ROADMAP Queue 1 re-decides it.)
     fetch_lag: int = 96
     # Also pop a fetch once it has been in flight this long (seconds) —
     # bounds token latency when the pipeline fills slower than fetch_lag
@@ -1313,10 +1331,19 @@ class InferenceEngine:
         self.prefill_walk_kernel_trips = 0
         # Monotonic: the host's run-ahead, sampled at every decode / fused
         # / verify dispatch (_backlog_steps: steps in the FIFO the device
-        # has not been seen to finish, the number fetch_lag bounds); sum /
-        # samples is its mean over any window.
+        # has not been seen to finish, the number _hold_decode bounds);
+        # sum / samples is its mean over any window.
         self.fetch_depth_steps_sum = 0
         self.fetch_depth_samples = 0
+        # The run-ahead's bound (_hold_decode).  decode_held: did the last
+        # step() withhold decode (the driving loop then waits a moment
+        # instead of stepping again at once).  Monotonic: iterations that
+        # withheld it, and the seconds from each held iteration to the
+        # next look at the backlog.
+        self.decode_held = False
+        self.decode_holds = 0
+        self.decode_hold_s = 0.0
+        self._hold_t = 0.0
         # Monotonic: seconds the scheduler thread sat in a read whose
         # transfer had not landed (_process_entry), and entries popped by
         # what released them: the age-and-landed rule, the fetch_lag depth
@@ -2042,7 +2069,9 @@ class InferenceEngine:
         self._drain(block=False)
         self._admit()
         self._advance_prefills()
-        if any(s is not None and s.state == ACTIVE for s in self.slots):
+        if not any(s is not None and s.state == ACTIVE for s in self.slots):
+            self.decode_held = False
+        elif not self._hold_decode():
             self._dispatch_decode()
             self._drain(block=False)
         if not self.num_active and not self.waiting and self._pending:
@@ -2060,8 +2089,11 @@ class InferenceEngine:
         if not self.num_active:
             self.metrics.mark_idle()  # idle gaps are not TPOT
             self._last_ready_t = None  # measured-latency chain restarts
-        if self.flight is not None:
+        if self.flight is not None and not (
+                self.decode_held and self.flight.quiet()):
             # commit this iteration's record + run the anomaly detectors
+            # (iterations that only held decode, a millisecond apart,
+            # would fill the ring with nothing: they commit ten a second)
             self.flight.finish_step(self)
         out, self._out_events = self._out_events, []
         return out
@@ -2074,6 +2106,8 @@ class InferenceEngine:
             for ev in self.step():
                 if ev.finished:
                     done[ev.request_id] = registry[ev.request_id]
+            if self.decode_held:
+                time.sleep(_HOLD_NAP_S)
         return done
 
     def generate(self, prompt_ids: List[int], **kw) -> GenRequest:
@@ -2084,6 +2118,8 @@ class InferenceEngine:
         self.submit(req)
         while req.state != FINISHED:
             self.step()
+            if self.decode_held:
+                time.sleep(_HOLD_NAP_S)
         return req
 
     # ------------------------------------------------------------------
@@ -2322,12 +2358,16 @@ class InferenceEngine:
     def _drain(self, block: bool) -> None:
         """Process matured token fetches into events (self._out_events).
 
-        Non-blocking mode only pops entries older than `fetch_lag` steps —
-        their async copies have had fetch_lag dispatches' worth of wall time
-        to land, so the np.asarray below is effectively free.  `is_ready`
-        cannot be used as the signal: it reports *compute* completion, not
-        transfer completion, and popping on it would reintroduce the
-        blocking round trip per step.
+        Non-blocking mode pops an entry once it has aged (`_emit_wait`) and
+        its transfer has landed (seen compute-done for ~an RTT), so the
+        np.asarray below is effectively free.  `is_ready` alone cannot be
+        the signal: it reports *compute* completion, not transfer
+        completion, and popping on it would reintroduce the blocking round
+        trip per step.  The `fetch_lag` depth force-pops whatever the
+        rules above left, as the memory backstop; the host's run-ahead is
+        held far under it by step() (`_hold_decode`: at most one program's
+        steps unfinished at a decode dispatch), so with three or more
+        streams that pop does not fire.
         """
         emitted = 0
         wait = self._emit_wait()
@@ -2387,6 +2427,30 @@ class InferenceEngine:
         self._pending.append(entry)
         self._pending_steps += entry.steps
 
+    def _hold_decode(self) -> bool:
+        """Should this iteration withhold decode?  Yes while more than one
+        program's worth of steps is queued that the device has not been
+        seen to finish: the device then has the program it runs and one
+        behind it, a third would only lengthen the wait of the next
+        prefill chunk (never held: _advance_prefills ran already), and
+        the device cannot run dry before the next poll.  step() returns
+        with `decode_held` set and blocks nowhere; whoever drives it
+        waits a moment before the next call.  One or two streams (the
+        rule of _emit_wait and of _pick_multi_step: nothing fuses, and
+        nobody queues behind them) keep the cadence they have."""
+        self._stamp_ready()
+        held = self.num_active > 2 and self._backlog_steps() > max(
+            self.ecfg.multi_step, _HOLD_FLOOR_STEPS)
+        now = time.monotonic()
+        if self.decode_held:
+            # the last iteration held: the time since was spent waiting
+            self.decode_hold_s += now - self._hold_t
+        if held:
+            self.decode_holds += 1
+            self._hold_t = now
+        self.decode_held = held
+        return held
+
     def _backlog_steps(self) -> int:
         """The host's run-ahead: steps dispatched into the FIFO that the
         device has not been seen to finish, i.e. what a dispatch enqueued
@@ -2396,14 +2460,19 @@ class InferenceEngine:
             e.steps for e in self._pending if e.t_ready is not None)
 
     def _stamp_ready(self) -> None:
-        """Record compute-completion times for the leading in-flight
-        fetches (is_ready is a cheap non-blocking probe)."""
+        """Record compute-completion times for the in-flight fetches the
+        device has finished (is_ready is a cheap non-blocking probe).
+        The device runs its queue in order, so the probe stops at the
+        first entry that is not done: entries that finished but have not
+        aged out of the FIFO never hide later completions from
+        _backlog_steps."""
         now = time.monotonic()
-        for e in self._pending[:8]:
-            if e.t_ready is None and getattr(
-                e.arr, "is_ready", lambda: True
-            )():
-                self._note_ready(e, now)
+        for e in self._pending:
+            if e.t_ready is not None:
+                continue
+            if not getattr(e.arr, "is_ready", lambda: True)():
+                break
+            self._note_ready(e, now)
 
     def _note_ready(self, entry: _Fetch, now: float,
                     observed: bool = True) -> None:
@@ -2443,13 +2512,14 @@ class InferenceEngine:
     def _emit_wait(self) -> float:
         """Age at which a fetch is popped without depth pressure.
 
-        With few active streams the pipeline never reaches fetch_lag depth,
-        so this age bound IS the token cadence the user sees; cap it near
-        the measured device→host RTT so a lone interactive stream gets
-        smooth ~RTT-latency tokens instead of fetch_wait_s-sized bursts
-        (popping at ≥RTT age means the transfer has already landed, so the
-        dispatch thread still never blocks).  Busy batches keep the
-        configured bound — depth-pops dominate there anyway.
+        The pipeline does not reach fetch_lag depth (one or two streams
+        never fill it, and step() holds a busier batch's run-ahead far
+        under it: _hold_decode), so this age bound IS the token cadence
+        the user sees; cap it near the measured device→host RTT so a lone
+        interactive stream gets smooth ~RTT-latency tokens instead of
+        fetch_wait_s-sized bursts (popping at ≥RTT age means the transfer
+        has already landed, so the dispatch thread still never blocks).
+        Busy batches keep the configured bound.
         """
         if self.num_active <= 2:
             return min(self.ecfg.fetch_wait_s, self._rtt_age_bound())

@@ -130,6 +130,11 @@ ANOMALY_KINDS = (
 )
 
 
+# A held scheduler iteration comes every millisecond or two and stages
+# nothing: such iterations commit one record a QUIET_S (FlightRecorder.quiet).
+QUIET_S = 0.1
+
+
 def ring_default() -> int:
     """KAFKA_TPU_FLIGHT_RING with nonsense clamped to the default (256
     records ~= a few seconds of busy scheduling, a few minutes idle)."""
@@ -374,6 +379,19 @@ class FlightRecorder:
         """A fetch entry matured and was processed (host side)."""
         self._last_pop_t = time.monotonic()
         self._stage.emitted += emitted
+
+    def quiet(self) -> bool:
+        """May an iteration that only held decode skip its record?  Yes
+        where nothing was dispatched or emitted since the last commit and
+        that commit is recent: what else is staged (measured times,
+        causes) keeps for the next record, and the detectors still run
+        every QUIET_S of a hold, however long it lasts."""
+        s = self._stage
+        return (
+            s.kinds == 0 and s.emitted == 0
+            and self._last_finish_t is not None
+            and time.monotonic() - self._last_finish_t < QUIET_S
+        )
 
     # -- commit + detectors ---------------------------------------------
 

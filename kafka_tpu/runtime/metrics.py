@@ -1506,6 +1506,11 @@ class EngineMetrics:
                 "fetch_depth_samples": engine.fetch_depth_samples,
                 "fetch_blocked_s": round(engine.fetch_blocked_s, 6),
                 "fetch_pops": dict(engine.fetch_pops),
+                # the bound on that run-ahead: iterations that withheld
+                # decode because more than one program's worth of steps
+                # was queued behind the device, and the seconds so spent
+                "decode_holds": engine.decode_holds,
+                "decode_hold_s": round(engine.decode_hold_s, 6),
                 # gauges: the experts this engine holds and computes, of
                 # those its router chooses among (equal unless the model
                 # is one chip's share of an expert-parallel layer)

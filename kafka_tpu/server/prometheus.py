@@ -503,6 +503,19 @@ def render_prometheus(snap: Dict[str, Any]) -> str:
                      "Estimated device-to-host fetch round trip.")
             w.sample("kafka_tpu_device_rtt_milliseconds",
                      engine["rtt_est_ms"])
+        if "decode_holds" in engine:
+            w.family("kafka_tpu_engine_decode_holds_total", "counter",
+                     "Scheduler iterations that withheld decode because "
+                     "more than one program's steps were queued behind "
+                     "the device.")
+            w.sample("kafka_tpu_engine_decode_holds_total",
+                     engine["decode_holds"])
+        if "decode_hold_s" in engine:
+            w.family("kafka_tpu_engine_decode_hold_seconds_total",
+                     "counter",
+                     "Seconds the scheduler waited with decode withheld.")
+            w.sample("kafka_tpu_engine_decode_hold_seconds_total",
+                     engine["decode_hold_s"])
 
     if "dp" in snap:
         w.family("kafka_tpu_dp_replicas", "gauge",

@@ -5,7 +5,7 @@
     python scripts/lowered_programs.py diff A B
 
 `dump` drives an engine per tiny preset (`tiny-gqa`, `tiny-moe`, the tiny
-Mellum2, Kanana-2 and dots3 under benchmarks/tests/) and attention backend (`xla`,
+Mellum2, Kanana-2, dots3 and Phi-4-flash under benchmarks/tests/) and attention backend (`xla`,
 `pallas`, which lowers in interpret mode off the chip) through single and
 batched prefill, the decode step (plain, host-masked, forced tokens), the
 fused multi-step scan, speculative verify (not on the windowed preset, which
@@ -50,11 +50,14 @@ def _presets():
         "benchmarks/tests/kanana2/configs/tiny-kanana2.json")
     dots3 = config_from_hf_json(
         "benchmarks/tests/dots3/configs/tiny-dots3.json")
+    phi4flash = config_from_hf_json(
+        "benchmarks/tests/phi4flash/configs/tiny-phi4flash.json")
     for name, cfg in (("tiny-gqa", get_config("tiny-gqa")),
                       ("tiny-moe", get_config("tiny-moe")),
                       ("tiny-mellum2", mellum),
                       ("tiny-kanana2", kanana),
-                      ("tiny-dots3", dots3)):
+                      ("tiny-dots3", dots3),
+                      ("tiny-phi4flash", phi4flash)):
         for backend in ("xla", "pallas"):
             yield name, backend, dataclasses.replace(
                 cfg, attention_backend=backend)
@@ -75,9 +78,10 @@ def _engine(cfg):
                      # "auto" resolves to xla off the chip whatever the
                      # model config says: name the preset's backend
                      attention_backend=cfg.attention_backend,
-                     # windowed and latent models refuse speculative verify
-                     speculative_k=0 if cfg.is_windowed or cfg.is_latent
-                     else 2),
+                     # windowed and latent models, and one with a
+                     # recurrent state, refuse speculative verify
+                     speculative_k=0 if (cfg.is_windowed or cfg.is_latent
+                                         or cfg.has_state) else 2),
         kv_dtype=jnp.float32)
 
 
