@@ -9,7 +9,10 @@ A latent decoder may also mix KINDS of layer whose attention blocks have
 sizes of their own (`dots3_note`: full layers that attend a learned top-k
 selection of keys, chosen by an indexer with a cache row of its own, beside
 sliding-window latent layers of another geometry), and hold a share of the
-routed experts (one chip's part of an expert-parallel layer).
+routed experts (one chip's part of an expert-parallel layer).  The same
+feed-forward tree stands on grouped-query attention too (`exaone_moe`:
+K-EXAONE), whose attention adds per-head QK-norm and full-attention layers
+that do not rotate.
 
 The reference service routed model names to remote providers by string
 heuristics (src/llm/utils.py:11-29); here a model name resolves to a local
@@ -199,6 +202,16 @@ class ModelConfig:
     # experts; what the absent ones would add is left out.
     num_experts_routed: int = 0
     expert_offset: int = 0
+    # Per-head RMSNorm of q and k over head_dim, with a learned weight a
+    # layer, ahead of the rotation (GQA attention; EXAONE-4's QK-norm).
+    qk_norm: bool = False
+    # Kinds of layer whose q and k are NOT rotated (EXAONE-4's hybrid: the
+    # full-attention layers carry no positional signal of their own).
+    unrotated_kinds: Tuple[str, ...] = ()
+    # Published `num_nextn_predict_layers`: the multi-token-prediction
+    # module is recorded and NOT built (a loader that drops `mtp.*` serves
+    # the model); the engine refuses speculative decoding for such a model.
+    nextn_predict_layers: int = 0
     # -- a hybrid decoder (`phi4flash`; models/hybrid.py): `mamba_d_state`
     # > 0 turns it on and `layer_types` then names MAMBA / GMU / CROSS
     # layers beside the attention kinds.  A MAMBA layer is a Mamba-1 mixer
@@ -231,6 +244,15 @@ class ModelConfig:
                                     and self.index_head_dim > 0):
             raise UnsupportedConfigError(
                 "index_topk needs index_n_heads and index_head_dim")
+        if (self.qk_norm or self.unrotated_kinds) and (
+                self.is_latent or self.has_state):
+            raise UnsupportedConfigError(
+                "qk_norm and unrotated_kinds are built with grouped-query "
+                "attention only (no latent attention, no hybrid decoder)")
+        if set(self.unrotated_kinds) - set(ROW_KINDS):
+            raise UnsupportedConfigError(
+                f"unrotated_kinds {list(self.unrotated_kinds)}: known "
+                f"{list(ROW_KINDS)}")
         if self.num_experts_routed and not (
                 self.moe_scoring == "sigmoid" and 0 <= self.expert_offset
                 and self.expert_offset + self.num_experts
@@ -396,6 +418,16 @@ class ModelConfig:
     @property
     def is_latent(self) -> bool:
         return self.kv_lora_rank > 0
+
+    @property
+    def lead_tree(self) -> bool:
+        """The parameter tree is the lead-and-routed one ("dense_layers"
+        beside "layers", a selection bias, a shared branch: models/llama.py
+        `_init_lead_tree_params`, per kind `_init_kind_params`), not the
+        homogeneous stack of `init_params`."""
+        return bool(self.is_latent or self.first_k_dense
+                    or self.shared_intermediate_size
+                    or self.moe_scoring != "softmax")
 
     @property
     def by_kind(self) -> bool:
@@ -640,6 +672,24 @@ def _layer_pattern(hf: dict) -> dict:
     }
 
 
+def _refuse_unless(hf: dict, served) -> None:
+    """Raise, by key, where a published key holds another value than the
+    one served: `served` is (key, the value served and the default where
+    the key is absent, what another value would mean)."""
+    for key, want, what in served:
+        got = hf.get(key, want)
+        if got != want:
+            raise UnsupportedConfigError(
+                f"{key} = {got!r} ({what}) is not served: only {want!r} is")
+
+
+def _refuse_unnormalised_sigmoid(hf: dict) -> None:
+    if hf.get("norm_topk_prob") is not True:
+        raise UnsupportedConfigError(
+            "norm_topk_prob must be true: sigmoid routing weights are "
+            "renormalised over the chosen experts")
+
+
 def _latent_keys(hf: dict) -> dict:
     """The `deepseek_v3` keys of a published config.json (latent attention,
     leading dense layers, sigmoid routing, shared experts) as ModelConfig
@@ -656,15 +706,8 @@ def _latent_keys(hf: dict) -> dict:
         ("topk_group", 1, "group-limited expert selection"),
         ("attention_bias", False, "attention biases"),
     )
-    for key, want, what in served:
-        got = hf.get(key, want)
-        if got != want:
-            raise UnsupportedConfigError(
-                f"{key} = {got!r} ({what}) is not served: only {want!r} is")
-    if hf.get("norm_topk_prob") is not True:
-        raise UnsupportedConfigError(
-            "norm_topk_prob must be true: sigmoid routing weights are "
-            "renormalised over the chosen experts")
+    _refuse_unless(hf, served)
+    _refuse_unnormalised_sigmoid(hf)
     dense = int(hf.get("first_k_dense_replace", 0))
     return {
         **_kind_keys(hf),
@@ -728,6 +771,63 @@ def _kind_keys(hf: dict) -> dict:
     return out
 
 
+def _routed_lead_keys(hf: dict) -> dict:
+    """The `deepseek_v3`-style FEED-FORWARD keys of a config.json whose
+    attention is grouped-query (`exaone_moe`: K-EXAONE): a dense lead
+    (`first_k_dense_replace`, `mlp_layer_types`), sigmoid routing with a
+    selection bias and a scale, shared experts, a held share of the experts;
+    plus what that family's attention adds (QK-norm, full-attention layers
+    that do not rotate) and its multi-token-prediction count.  {} for a
+    latent model (`_latent_keys` reads those) and for one without the keys.
+    What is not served is an UnsupportedConfigError, by key."""
+    if hf.get("kv_lora_rank") or not (
+            "first_k_dense_replace" in hf or "scoring_func" in hf):
+        return {}
+    served = (
+        ("scoring_func", "sigmoid", "another router scoring function"),
+        ("n_group", 1, "group-limited expert selection"),
+        ("topk_group", 1, "group-limited expert selection"),
+        ("hidden_act", "silu", "another MLP activation"),
+        ("attention_bias", False, "attention biases"),
+    )
+    _refuse_unless(hf, served)
+    _refuse_unnormalised_sigmoid(hf)
+    dense = int(hf.get("first_k_dense_replace", 0))
+    mlp_kinds = list(hf.get("mlp_layer_types") or ())
+    if mlp_kinds:
+        lead = next((i for i, k in enumerate(mlp_kinds) if k != "dense"),
+                    len(mlp_kinds))
+        if "dense" in mlp_kinds[lead:]:
+            raise UnsupportedConfigError(
+                "mlp_layer_types puts a sparse layer ahead of a dense one: "
+                "dense layers are served as a lead only")
+        if lead != dense:
+            raise UnsupportedConfigError(
+                f"mlp_layer_types leads with {lead} dense layers but "
+                f"first_k_dense_replace is {dense}")
+    shared = int(hf.get("num_shared_experts", hf.get("n_shared_experts"))
+                 or 0)
+    out = {
+        "first_k_dense": dense,
+        "dense_intermediate_size": int(hf["intermediate_size"]) if dense else 0,
+        "shared_intermediate_size": shared * int(hf["moe_intermediate_size"]),
+        "moe_scoring": "sigmoid",
+        "routed_scaling_factor": float(hf.get("routed_scaling_factor", 1.0)),
+        "nextn_predict_layers": int(hf.get("num_nextn_predict_layers") or 0),
+    }
+    published = int(hf.get("num_experts_published") or 0)
+    if published:
+        out["num_experts_routed"] = published
+        out["expert_offset"] = int(hf.get("expert_share_offset", 0))
+    if hf.get("model_type") in ("exaone_moe", "exaone4"):
+        # the EXAONE-4 block: per-head RMSNorm of q and k, and in a model
+        # that mixes kinds the full-attention layers do not rotate
+        out["qk_norm"] = True
+        if WINDOWED in (hf.get("layer_types") or ()):
+            out["unrotated_kinds"] = (GLOBAL,)
+    return out
+
+
 def _hybrid_keys(hf: dict) -> dict:
     """The keys of a `phi4flash` config.json (Phi-4-mini-flash-reasoning: a
     decoder-hybrid-decoder of Mamba-1 mixers, sliding and full differential
@@ -747,11 +847,7 @@ def _hybrid_keys(hf: dict) -> dict:
         ("tie_word_embeddings", True, "an untied head"),
         ("rope_scaling", None, "rotary positions (the model has none)"),
     )
-    for key, want, what in served:
-        got = hf.get(key, want)
-        if got != want:
-            raise UnsupportedConfigError(
-                f"{key} = {got!r} ({what}) is not served: only {want!r} is")
+    _refuse_unless(hf, served)
     n = int(hf["num_hidden_layers"])
     if n % 4 or n < 4:
         raise UnsupportedConfigError(
@@ -783,12 +879,14 @@ def config_from_hf_json(path: str) -> ModelConfig:
     keys, the published keys of a patterned routed decoder (Mellum2:
     `layer_types`, `sliding_window`, `rope_parameters`, `num_experts`,
     `moe_intermediate_size`, `norm_topk_prob`, `mlp_layer_types`), those
-    of a `deepseek_v3` decoder (`_latent_keys`) and those of a `phi4flash`
-    hybrid decoder (`_hybrid_keys`).  A key the program cannot honour is an
+    of a `deepseek_v3` decoder (`_latent_keys`), the same feed-forward keys
+    on grouped-query attention (`_routed_lead_keys`: `exaone_moe`) and those
+    of a `phi4flash` hybrid decoder (`_hybrid_keys`).  A key the program cannot honour is an
     UnsupportedConfigError."""
     with open(path) as f:
         hf = json.load(f)
     latent = _latent_keys(hf)
+    routed_lead = _routed_lead_keys(hf)
     rs = hf.get("rope_scaling") or {}
     # honor the checkpoint's own precision ("dtype" since transformers
     # 4.56+, "torch_dtype" before); fp16 checkpoints run as bf16 (same
@@ -801,13 +899,17 @@ def config_from_hf_json(path: str) -> ModelConfig:
     # experts' own width in `moe_intermediate_size`; absent -> 0 = dense
     num_experts = (hf.get("num_local_experts", hf.get("num_experts", 0))
                    or (hf.get("n_routed_experts", 0) if latent else 0) or 0)
+    # (a dense LEAD is `_routed_lead_keys`', checked there)
     mlp_kinds = hf.get("mlp_layer_types")
+    if routed_lead.get("first_k_dense"):
+        mlp_kinds = mlp_kinds and mlp_kinds[routed_lead["first_k_dense"]:]
     if mlp_kinds and (set(mlp_kinds) != {"sparse"} or not num_experts):
         raise UnsupportedConfigError(
             "mlp_layer_types must be all 'sparse' (with experts) or absent: "
             f"found {sorted(set(mlp_kinds))} with {num_experts} experts; a "
             "mix of dense and routed layers is not served")
-    if num_experts and not latent and hf.get("norm_topk_prob") is False:
+    if num_experts and not (latent or routed_lead) \
+            and hf.get("norm_topk_prob") is False:
         raise UnsupportedConfigError(
             "norm_topk_prob false (top-k weights of a softmax over ALL "
             "experts, not renormalised) is not served: routing here is a "
@@ -817,8 +919,18 @@ def config_from_hf_json(path: str) -> ModelConfig:
     rope_theta = hf.get("rope_theta")
     if rope_theta is None:
         ropes = dict(pattern.get("rope_by_kind", ()))
-        rope_theta = (ropes[GLOBAL].rope_theta if GLOBAL in ropes
-                      else 10000.0)
+        flat = hf.get("rope_parameters") or {}
+        if GLOBAL in ropes:
+            rope_theta = ropes[GLOBAL].rope_theta
+        elif "rope_theta" in flat:
+            # one table for every kind, spelt as a flat `rope_parameters`
+            rope_theta = _rope_params("rope_parameters", flat).rope_theta
+            if flat.get("rope_type", "default") != "default":
+                raise UnsupportedConfigError(
+                    f"rope_parameters: rope_type {flat['rope_type']!r} is "
+                    "served per layer kind only")
+        else:
+            rope_theta = 10000.0
     return ModelConfig(
         dtype=dtype,
         num_experts=num_experts,
@@ -846,5 +958,6 @@ def config_from_hf_json(path: str) -> ModelConfig:
         rope_original_max_position=rs.get("original_max_position_embeddings", 8192),
         **pattern,
         **latent,
+        **routed_lead,
         **hybrid,
     )

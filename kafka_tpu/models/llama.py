@@ -1,7 +1,9 @@
 """Decoder-only transformer in pure functional JAX: the Llama family, Mixtral's
 routed MLP, layer patterns of windowed and global attention (Mellum2), and
 latent attention behind leading dense layers with sigmoid-routed and shared
-experts (HF `deepseek_v3`; "Latent attention" below).
+experts (HF `deepseek_v3`; "Latent attention" below), and that same
+lead-and-routed tree on grouped-query attention with QK-norm and kinds of
+layer that do not rotate (`exaone_moe`: K-EXAONE).
 
 Design (TPU-first, not a port — the reference has no model code at all; its
 LLM compute lived behind a remote gateway, src/llm/portkey.py):
@@ -277,11 +279,10 @@ def init_params(cfg: ModelConfig, key: jax.Array, dtype=None) -> Params:
         return init_hybrid_params(cfg, key, dtype)
     if cfg.by_kind:
         return _init_kind_params(cfg, key, dtype)
-    if cfg.is_latent or cfg.first_k_dense or cfg.shared_intermediate_size \
-            or cfg.moe_scoring != "softmax":
+    if cfg.lead_tree:
         # a tree and a random stream of its own: the stream below is what
         # every other configuration's seeded weights come from
-        return _init_latent_params(cfg, key, dtype)
+        return _init_lead_tree_params(cfg, key, dtype)
     h, f, d = cfg.hidden_size, cfg.intermediate_size, cfg.head_dim
     hq, hkv, L = cfg.num_heads, cfg.num_kv_heads, cfg.num_layers
     keys = jax.random.split(key, 10)
@@ -319,19 +320,20 @@ def init_params(cfg: ModelConfig, key: jax.Array, dtype=None) -> Params:
     return params
 
 
-def _init_latent_params(cfg: ModelConfig, key: jax.Array, dtype) -> Params:
-    """Random weights of a `deepseek_v3`-style decoder: latent attention in
-    every layer; `first_k_dense` dense layers stacked under "dense_layers",
-    the routed ones (router + selection bias, experts, shared branch) under
-    "layers".  The selection bias is N(0, 0.1^2), not zero: with b = 0 a
-    program that weighs by sigma + b, or chooses by sigma, passes every
-    check; the latent norm's weight is 1 + N(0, 0.2^2) for the same reason."""
-    if not cfg.is_latent:
-        raise UnsupportedConfigError(
-            "leading dense layers, shared experts and sigmoid routing are "
-            "built with latent attention only")
-    h, hq, r = cfg.hidden_size, cfg.num_heads, cfg.kv_lora_rank
-    dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+def _init_lead_tree_params(cfg: ModelConfig, key: jax.Array, dtype) -> Params:
+    """Random weights of a `deepseek_v3`-style tree: `first_k_dense` dense
+    layers stacked under "dense_layers", the routed ones (router + selection
+    bias, experts, shared branch) under "layers", every layer with the SAME
+    attention block: latent (`cfg.is_latent`: Kanana-2) or grouped-query
+    (K-EXAONE: wq / wk / wv / wo, and with `cfg.qk_norm` a norm weight of
+    head_dim a layer for q and for k).  Expert leaves are the `num_experts`
+    HELD; the router and its bias keep the router's full width.  The
+    selection bias is N(0, 0.1^2), not zero: with b = 0 a program that
+    weighs by sigma + b, or chooses by sigma, passes every check; the latent
+    norm's and the q / k norms' weights are 1 + N(0, 0.2^2) for the same
+    reason.  The latent model's random stream is what it was before the
+    grouped-query block came to this tree."""
+    h, hq = cfg.hidden_size, cfg.num_heads
 
     @partial(jax.jit, static_argnums=(1, 2))
     def norm01(k, shape, fan_in):
@@ -341,16 +343,24 @@ def _init_latent_params(cfg: ModelConfig, key: jax.Array, dtype) -> Params:
         return (jax.random.normal(k, shape, jnp.float32)
                 * (fan_in**-0.5)).astype(dtype)
 
-    def attention(k, n):
+    def spread(k, shape):
+        return (1.0 + 0.2 * jax.random.normal(k, shape, jnp.float32)
+                ).astype(dtype)
+
+    def norms(n):
+        return {"ln_attn": jnp.ones((n, h), dtype),
+                "ln_mlp": jnp.ones((n, h), dtype)}
+
+    def latent_attention(k, n):
+        r = cfg.kv_lora_rank
+        dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
         ks = jax.random.split(k, 5)
         return {
-            "ln_attn": jnp.ones((n, h), dtype),
-            "ln_mlp": jnp.ones((n, h), dtype),
+            **norms(n),
             # not ones: over 512 lanes of unit-variance c the RMS is already
             # 1 +- 3%, so with a unit weight a program that skips this norm
             # would pass every check
-            "ln_kv": (1.0 + 0.2 * jax.random.normal(
-                ks[4], (n, r), jnp.float32)).astype(dtype),
+            "ln_kv": spread(ks[4], (n, r)),
             "wq": norm01(ks[0], (n, h, hq, dn + dr), h),
             "wkva": norm01(ks[1], (n, h, r + dr), h),
             # per head [k_nope | v]; the latent axis next to last, where
@@ -358,6 +368,23 @@ def _init_latent_params(cfg: ModelConfig, key: jax.Array, dtype) -> Params:
             "wkvb": norm01(ks[2], (n, hq, r, dn + dv), r),
             "wo": norm01(ks[3], (n, hq, dv, h), hq * dv),
         }
+
+    def gqa_attention(k, n):
+        hkv, d = cfg.num_kv_heads, cfg.head_dim
+        ks = jax.random.split(k, 6)
+        out = {
+            **norms(n),
+            "wq": norm01(ks[0], (n, h, hq, d), h),
+            "wk": norm01(ks[1], (n, h, hkv, d), h),
+            "wv": norm01(ks[2], (n, h, hkv, d), h),
+            "wo": norm01(ks[3], (n, hq, d, h), hq * d),
+        }
+        if cfg.qk_norm:
+            out["ln_q"] = spread(ks[4], (n, d))
+            out["ln_k"] = spread(ks[5], (n, d))
+        return out
+
+    attention = latent_attention if cfg.is_latent else gqa_attention
 
     def mlp(k, n, f, names=("wg", "wu", "wd")):
         ks = jax.random.split(k, 3)
@@ -371,10 +398,11 @@ def _init_latent_params(cfg: ModelConfig, key: jax.Array, dtype) -> Params:
     layers = attention(keys[1], n)
     if cfg.is_moe:
         E, f = cfg.num_experts, cfg.intermediate_size
-        layers["router"] = norm01(keys[2], (n, h, E), h)
+        routed = cfg.num_router_experts
+        layers["router"] = norm01(keys[2], (n, h, routed), h)
         if cfg.moe_scoring == "sigmoid":
             layers["router_bias"] = 0.1 * jax.random.normal(
-                keys[3], (n, E), jnp.float32)
+                keys[3], (n, routed), jnp.float32)
         layers["wg"] = norm01(keys[4], (n, E, h, f), h)
         layers["wu"] = norm01(keys[5], (n, E, h, f), h)
         layers["wd"] = norm01(keys[6], (n, E, f, h), f)
@@ -412,7 +440,7 @@ def _init_kind_params(cfg: ModelConfig, key: jax.Array, dtype) -> Params:
     the rescale in its fan-in, so queries, keys and values come out at unit
     scale as everywhere else; norm weights and biases are spread (not 1 / 0)
     so that a program that skips one fails the check, as
-    `_init_latent_params` says."""
+    `_init_lead_tree_params` says."""
     h = cfg.hidden_size
 
     @partial(jax.jit, static_argnums=(1, 2))
@@ -526,14 +554,24 @@ def _attention_block(
     returned stacked, with only this layer's new rows written.  `window`
     (static) makes this a sliding-window layer; its attention proper runs
     under the `attn_window` scope inside `attn_core`, so a device trace
-    splits attention time by kind of layer."""
+    splits attention time by kind of layer.  `cos` None: this kind of layer
+    does not rotate q and k (`cfg.unrotated_kinds`); "ln_q" / "ln_k" among
+    the leaves: QK-norm, under its own scope `qk_norm`."""
     dt = x.dtype
     with jax.named_scope("attn_qkv"):
         q = jnp.einsum("bsh,hnd->bsnd", x, _w(lp, "wq", dt))
         k = jnp.einsum("bsh,hnd->bsnd", x, _w(lp, "wk", dt))
         v = jnp.einsum("bsh,hnd->bsnd", x, _w(lp, "wv", dt))
-        q = apply_rope(q, cos, sin)
-        k = apply_rope(k, cos, sin)
+    if "ln_q" in lp:
+        # QK-norm: each head's q and k RMS-normed over head_dim with the
+        # layer's learned weights, ahead of the rotation
+        with jax.named_scope("qk_norm"):
+            q = rms_norm(q, lp["ln_q"], cfg.rms_norm_eps)
+            k = rms_norm(k, lp["ln_k"], cfg.rms_norm_eps)
+    if cos is not None:  # (None: a kind of layer that does not rotate)
+        with jax.named_scope("attn_qkv"):
+            q = apply_rope(q, cos, sin)
+            k = apply_rope(k, cos, sin)
     if paged is not None:
         # Paged pool [L, TOTAL_SLOTS, Hkv*D] (dense arrays, or QTensor
         # int8+scales when kv_quantize is on), addressed flat: from here to
@@ -1595,6 +1633,8 @@ def forward(
         else:
             inv_freq = rope_frequencies(cfg)
             rope = {GLOBAL: rope_cos_sin(positions, inv_freq)}
+        for kind in cfg.unrotated_kinds:
+            rope[kind] = (None, None)
 
     # The stacked caches are CARRY (module docstring): the scan slices only
     # the layer's weights.  Every op of the layer body sits under a leaf

@@ -60,6 +60,11 @@ def convert_hf_state_dict(
     dtype = dtype or cfg.activation_dtype
     if cfg.is_latent:
         return _convert_latent_state_dict(state, cfg, dtype)
+    if cfg.lead_tree or cfg.qk_norm:
+        raise NotImplementedError(
+            "no checkpoint converter for a grouped-query model with a dense "
+            "lead, shared experts or QK-norm (`exaone_moe`): its tree is "
+            "models/llama._init_lead_tree_params', served on seeded weights")
     h, d = cfg.hidden_size, cfg.head_dim
     hq, hkv, L = cfg.num_heads, cfg.num_kv_heads, cfg.num_layers
 
@@ -128,7 +133,7 @@ def _convert_latent_state_dict(
     state: Mapping[str, Any], cfg: ModelConfig, dtype: Any
 ) -> Params:
     """HF `deepseek_v3` names (no query low-rank) -> the latent tree of
-    models/llama._init_latent_params: leading dense layers stacked under
+    models/llama._init_lead_tree_params: leading dense layers stacked under
     "dense_layers", routed ones under "layers".  Rotary columns stay
     interleaved as published; `forward` de-interleaves them
     (`cfg.rope_interleave`)."""

@@ -113,6 +113,11 @@ def quantize_params(params: Params, cfg: ModelConfig) -> Params:
             "int8 weight quantization of a latent-attention model is not "
             "built: its tree (wkva, wkvb, shared experts, dense_layers) has "
             "no contraction table here")
+    if "dense_layers" in params or cfg.shared_intermediate_size:
+        raise NotImplementedError(
+            "int8 weight quantization of a tree with a dense lead or shared "
+            "experts is not built: `dense_layers` and the shared branch "
+            "(ws_g, ws_u, ws_d) have no contraction table here")
     contract = dict(_CONTRACT)
     if cfg.is_moe:
         contract.update(_CONTRACT_MOE)

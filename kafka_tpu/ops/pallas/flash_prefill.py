@@ -34,6 +34,10 @@ from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 
+# the largest [q_block * Hq, Hkv * D] tile a q block may be (elements): see
+# q_block_cap
+PREFILL_TILE_ELEMS = 1024 * 832
+
 
 def prefill_block_chunks(qb, start, chunk_len, *, q_block: int,
                          page_size: int, pages_per_chunk: int,
@@ -196,6 +200,35 @@ def _prefill_kernel(
     out_ref[...] = (acc_ref[...] / denom).astype(out_ref.dtype)
 
 
+def q_block_cap(num_q_heads: int, lanes: int) -> int:
+    """The most query positions a q block may hold at `num_q_heads` heads
+    over block-diagonal rows of `lanes` (= Hkv * D) lanes.
+
+    Scoped-VMEM bound: the kernel's per-block footprint scales with
+    rows = q_block * Hq (qx/out pipeline buffers, f32 accumulator, and
+    the [rows, chunk] softmax temporaries).  rows = 2048 measured
+    17.91 MB of scoped VMEM against the 16 MB core limit (Mosaic
+    stack-OOM at compile, first hit by the 2048-token prefill bucket at
+    32 heads); rows <= ~1024 keeps ~9 MB with headroom for the DMA
+    buffers.  The cap is rounded DOWN to a power of two so it divides
+    the power-of-two chunk buckets for any head count (1024//24 = 42
+    would fail S % qb for every bucket).
+
+    The row is Hkv*D lanes wide, so the same rows cost more VMEM the more
+    kv heads there are: 64 query / 8 kv heads x 128 at rows = 1024 is a
+    [1024, 1024] tile, twice what 32 / 4 x 128 holds, and the chip refused
+    it when the program ran (18.04 MB of scoped VMEM against the 16 MB
+    limit: my chip run 1, PR 43; the compile for a DESCRIBED v5e had
+    passed).  So the block is halved until rows x lanes is at most
+    PREFILL_TILE_ELEMS; every geometry that ran before keeps the block it
+    had (the widest, 40 / 20 x 64 at rows 640, is 819,200 elements)."""
+    cap = max(8, 1024 // num_q_heads)
+    cap = 1 << (cap.bit_length() - 1)
+    while cap > 8 and cap * num_q_heads * lanes > PREFILL_TILE_ELEMS:
+        cap //= 2
+    return cap
+
+
 @functools.partial(
     jax.jit,
     static_argnames=("page_size", "pages_per_chunk", "q_block", "scale",
@@ -236,18 +269,7 @@ def paged_prefill_attention(
     G = Hq // Hkv
     if scale is None:
         scale = D**-0.5
-    # Scoped-VMEM bound: the kernel's per-block footprint scales with
-    # rows = q_block * Hq (qx/out pipeline buffers, f32 accumulator, and
-    # the [rows, chunk] softmax temporaries).  rows = 2048 measured
-    # 17.91 MB of scoped VMEM against the 16 MB core limit (Mosaic
-    # stack-OOM at compile, first hit by the 2048-token prefill bucket at
-    # 32 heads); rows <= ~1024 keeps ~9 MB with headroom for the DMA
-    # buffers.  The cap is rounded DOWN to a power of two so it divides
-    # the power-of-two chunk buckets for any head count (1024//24 = 42
-    # would fail S % qb for every bucket).
-    cap = max(8, 1024 // Hq)
-    cap = 1 << (cap.bit_length() - 1)
-    qb = min(q_block, S, cap)
+    qb = min(q_block, S, q_block_cap(Hq, HD))
     if S % qb:
         raise ValueError(f"chunk length {S} not divisible by q_block {qb}")
     cp = min(pages_per_chunk, page_row.shape[0])

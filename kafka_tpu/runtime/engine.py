@@ -71,7 +71,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..models.config import WINDOWED, ModelConfig
+from ..models.config import WINDOWED, ModelConfig, UnsupportedConfigError
 from .failpoints import failpoint
 from .flight_recorder import (
     FlightRecorder,
@@ -143,6 +143,21 @@ class LatentAttentionUnsupported(ValueError):
         super().__init__(
             f"{path} has no latent-attention form and this model caches "
             f"latent rows: {why}")
+
+
+class RoutedTreeUnsupported(ValueError):
+    """A grouped-query model whose tree leads with dense layers ahead of
+    routed ones (shared experts, perhaps a HELD share of the experts) was
+    given an engine option that tree has no form for.  Raised when the
+    engine is built, naming the option (`.path`).  A held share is one
+    chip's part of an expert-parallel layer: the other chips and their
+    exchange are absent, and nothing here stands in for them."""
+
+    def __init__(self, path: str, why: str):
+        self.path = path
+        super().__init__(
+            f"{path} has no form for a dense lead ahead of routed layers "
+            f"on grouped-query attention: {why}")
 
 
 WAITING, PREFILLING, PARKED, ACTIVE, DRAINING, FINISHED = (
@@ -747,6 +762,28 @@ class InferenceEngine:
             for path, hit, why in refused:
                 if hit:
                     raise RecurrentStateUnsupported(path, why)
+        if cfg.nextn_predict_layers and self.ecfg.speculative_k > 0:
+            raise UnsupportedConfigError(
+                f"num_nextn_predict_layers = {cfg.nextn_predict_layers} with "
+                f"speculative_k = {self.ecfg.speculative_k}: the model's "
+                "multi-token-prediction module is recorded and not built, so "
+                "no draft would come from it; set speculative_k=0")
+        if cfg.lead_tree and not cfg.is_latent:
+            # models/llama._init_lead_tree_params' tree on grouped-query
+            # attention: parallel/sharding.param_specs has no rule for it
+            sharded = mesh is not None and mesh.size > 1
+            refused = (
+                ("a held share of the experts (num_experts_routed) on a "
+                 "tp / ep mesh", sharded and bool(cfg.num_experts_routed),
+                 "this chip's experts are one share of an expert-parallel "
+                 "layer already; serve it on one device"),
+                ("a tp / ep / pp / sp mesh", sharded,
+                 "the sharding rules place a homogeneous stack of layers; "
+                 "serve each replica on one device (dp)"),
+            )
+            for path, hit, why in refused:
+                if hit:
+                    raise RoutedTreeUnsupported(path, why)
         if cfg.is_windowed:
             # Every attention path honours the window (models/llama.py) or
             # is refused here, by name.
@@ -1413,6 +1450,19 @@ class InferenceEngine:
         Llama-3-8B geometry (4096 x 1024) measured 19.5 MB against the
         16 MB v5e limit — past ~7 MB for that product, resolve to the XLA
         formulation (3B at 3072 x 1024 = 6.3 MB compiles and runs).
+
+        The 7 MB rule is "auto"'s alone: it was read before the kernel
+        capped its q block and is left where it is because a configuration
+        that "auto" sends to XLA is checked and measured there (Mixtral's
+        cell, 4096 x 1024).  A configuration may PIN "pallas"
+        (EngineConfig / ServingConfig.attention_backend) once its geometry
+        is shown to compile and run on the chip; the pin is returned as
+        given.  Pinned today: K-EXAONE's 64 / 8 x 128 (8192 x 1024 =
+        16.8 MB by that product), for which flash prefill halves its q
+        block to 8 rows (ops/pallas/flash_prefill.PREFILL_TILE_ELEMS) and
+        the decode kernels need nothing.  Resolved by "auto" to the
+        kernels: Yi and Mellum2 (32 / 4 x 128), Phi-4-mini-flash (40 / 20
+        x 64), and every latent model.
         """
         choice = ecfg.attention_backend
         if ecfg.kv_quantize:

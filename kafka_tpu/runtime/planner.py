@@ -250,8 +250,8 @@ def weight_bytes_per_device(
 
     kv_shard = _kv_shard(cfg, tp, kv_shard)
 
-    if cfg.is_latent:
-        return _latent_weight_bytes(cfg, mat, wb)
+    if cfg.lead_tree:
+        return _lead_tree_weight_bytes(cfg, mat, wb)
     if cfg.has_state:
         return _hybrid_weight_bytes(cfg, wb)
     per_layer = (
@@ -308,16 +308,22 @@ def state_bytes_per_device(cfg: ModelConfig, state_slots: int) -> int:
         -(-rows // 8) * 8 * cols for _, (rows, cols) in cfg.state_shapes())
 
 
-def _latent_weight_bytes(cfg: ModelConfig, mat, wb: int) -> int:
-    """Weights of a latent-attention model on its one device (the engine
-    refuses a mesh over the latent pool): models/llama._init_latent_params'
-    tree, leaf by leaf."""
+def _lead_tree_weight_bytes(cfg: ModelConfig, mat, wb: int) -> int:
+    """Weights of a model whose tree is models/llama._init_lead_tree_params'
+    (or, per kind, _init_kind_params') on its one device (the engine refuses
+    a mesh for it), leaf by leaf: latent attention, or grouped-query
+    attention with its QK-norm weights, in every layer; a dense lead; the
+    router at its published width beside the experts HELD."""
     h = cfg.hidden_size
 
     def mlp(f: int) -> int:
         return 2 * mat(h, f, 1) + mat(f, h, 1)
 
     def attn(kind: str) -> int:
+        if not cfg.is_latent:
+            hq, hkv, d = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
+            return (2 * mat(h, hq * d, 1) + 2 * mat(h, hkv * d, 1)
+                    + (2 * h + (2 * d if cfg.qk_norm else 0)) * wb)
         g = cfg.geometry_of(kind)
         hq, r, rq = g.num_heads, g.kv_lora_rank, g.q_lora_rank
         dn, dr, dv = g.qk_nope_head_dim, g.qk_rope_head_dim, g.v_head_dim
@@ -333,7 +339,8 @@ def _latent_weight_bytes(cfg: ModelConfig, mat, wb: int) -> int:
 
     if cfg.is_moe:
         routed = cfg.num_router_experts
-        ffn = (h * routed * wb + routed * 4  # router, bias
+        bias = routed * 4 if cfg.moe_scoring == "sigmoid" else 0
+        ffn = (h * routed * wb + bias  # router, selection bias (f32)
                + cfg.num_experts * mlp(cfg.intermediate_size)
                + (mlp(cfg.shared_intermediate_size)
                   if cfg.shared_intermediate_size else 0))

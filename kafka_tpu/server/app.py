@@ -152,6 +152,7 @@ def build_tpu_provider(cfg: ServingConfig) -> LLMProvider:
         multi_step=cfg.multi_step,
         speculative_k=cfg.speculative_k,
         kv_quantize=cfg.kv_quantize,
+        attention_backend=cfg.attention_backend,
         # 0 disables the radix prefix cache; None = pressure-bounded
         prefix_cache_entries=0 if cfg.prefix_cache_pages == 0 else 64,
         prefix_cache_pages=cfg.prefix_cache_pages or None,

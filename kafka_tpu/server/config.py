@@ -27,6 +27,13 @@ class ServingConfig:
     # runtime/kv_cache.py) — halves KV window traffic and doubles how many
     # context windows a pool holds; attention runs the XLA gather path
     kv_quantize: str = ""
+    # EngineConfig.attention_backend: "auto" (the engine's rule,
+    # InferenceEngine._resolve_backend), or "pallas" / "xla" pinned: a
+    # deployment whose head geometry is shown to compile pins the kernels
+    # where the rule, which is set for the widest geometry it was read at,
+    # would send it to XLA.  No environment variable: a configuration
+    # file's `serving` group (or the caller) sets it.
+    attention_backend: str = "auto"
     # engine shape
     max_batch: int = 8
     page_size: int = 16

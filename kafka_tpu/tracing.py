@@ -161,6 +161,8 @@ DEVICE_SCOPES = (
                     # layers/while/body/<op> with no leaf scope
     "attn_norm",    # pre-attention RMSNorm
     "attn_qkv",     # q/k/v projections + RoPE
+    "qk_norm",      # per-head RMSNorm of q and k ahead of the rotation (a
+                    # model with QK-norm only)
     "kv_write",     # scatter of the new k/v rows into the layer's pool
     "attn_core",    # scores, softmax, weighted sum: the Pallas paged
                     # decode / verify / flash-prefill calls, or XLA
