@@ -982,9 +982,12 @@ class InferenceEngine:
         if self.cfg.attention_backend == "pallas" and (
             mesh is None or mesh.size == 1
         ) and not self.ecfg.kv_quantize:
-            # flash prefill tiles chunks into q_block=64 rows (ops/pallas/
-            # flash_prefill.py); catch the misconfiguration at construction
-            # rather than as an opaque trace-time error.  Mesh engines and
+            # flash prefill tiles a chunk into q blocks of a power of two
+            # of rows that must divide it (ops/pallas/flash_prefill.
+            # prefill_plan: the geometry's byte-sized block, halved until it
+            # does); a multiple of 64 is divided by every block it can reach,
+            # so that stays the rule.  Catch the misconfiguration at
+            # construction rather than as a trace-time error.  Mesh engines and
             # int8-KV engines keep prefill on the XLA path (llama.py), so
             # the constraint is single-device dense-pool only.
             bad = [
@@ -994,8 +997,9 @@ class InferenceEngine:
             if bad:
                 raise ValueError(
                     f"prefill buckets {bad} incompatible with the pallas "
-                    "flash-prefill kernel: buckets over 64 must be "
-                    "multiples of its 64-row q blocks"
+                    "flash-prefill kernel: a bucket over 64 must be a "
+                    "multiple of 64, so that the kernel's power-of-two q "
+                    "block divides it"
                 )
         if self.ecfg.kv_quantize and self._pp > 1:
             raise ValueError(
@@ -1444,24 +1448,27 @@ class InferenceEngine:
         pallas_mesh_ok — shard_map runs the custom call GSPMD cannot
         partition), a merged KV row that is lane-tile aligned
         (Hkv*D % 128, per shard on meshes), page rows aligned
-        to the bf16 sublane tile (page_size % 16), and head geometry whose
-        kernel intermediates fit scoped VMEM: the flash-prefill kernel
-        stacks a [Hq*D, Hkv*D]-shaped bf16 working set, which at
-        Llama-3-8B geometry (4096 x 1024) measured 19.5 MB against the
-        16 MB v5e limit — past ~7 MB for that product, resolve to the XLA
-        formulation (3B at 3072 x 1024 = 6.3 MB compiles and runs).
+        to the bf16 sublane tile (page_size % 16), and the 7 MB rule: the
+        flash-prefill kernel once stacked a [Hq*D, Hkv*D]-shaped bf16
+        working set (block-diagonal q rows over the whole merged row),
+        which at Llama-3-8B geometry (4096 x 1024) measured 19.5 MB against
+        the 16 MB v5e limit — past ~7 MB for that product, resolve to the
+        XLA formulation (3B at 3072 x 1024 = 6.3 MB compiled and ran).
 
-        The 7 MB rule is "auto"'s alone: it was read before the kernel
-        capped its q block and is left where it is because a configuration
-        that "auto" sends to XLA is checked and measured there (Mixtral's
-        cell, 4096 x 1024).  A configuration may PIN "pallas"
-        (EngineConfig / ServingConfig.attention_backend) once its geometry
-        is shown to compile and run on the chip; the pin is returned as
-        given.  Pinned today: K-EXAONE's 64 / 8 x 128 (8192 x 1024 =
-        16.8 MB by that product), for which flash prefill halves its q
-        block to 8 rows (ops/pallas/flash_prefill.PREFILL_TILE_ELEMS) and
-        the decode kernels need nothing.  Resolved by "auto" to the
-        kernels: Yi and Mellum2 (32 / 4 x 128), Phi-4-mini-flash (40 / 20
+        That working set is gone since PR 44: the kernel multiplies one
+        128-lane group of KV heads at a time, a q block's rows are one lane
+        tile wide whatever Hkv is, and its q block is sized from the VMEM
+        bytes it holds (ops/pallas/flash_prefill.q_block_rows).  The rule
+        stays "auto"'s all the same, because a configuration that "auto"
+        sends to XLA is checked and measured there (Mixtral's cell, 4096 x
+        1024, pins "xla" in its check): lifting it is a benchmark change.
+        A configuration may PIN "pallas" (EngineConfig /
+        ServingConfig.attention_backend) once its geometry is shown to
+        compile and run on the chip; the pin is returned as given.  Pinned
+        today: K-EXAONE's 64 / 8 x 128 (8192 x 1024 = 16.8 MB by that
+        product; its q block is 64 positions, 8 before PR 44).  Resolved by
+        "auto" to the kernels: Yi and Mellum2 (32 / 4 x 128),
+        Phi-4-mini-flash (40 / 20
         x 64), and every latent model.
         """
         choice = ecfg.attention_backend

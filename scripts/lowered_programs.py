@@ -2,10 +2,14 @@
 """Do two checkouts lower the engine's step programs to the same text?
 
     python scripts/lowered_programs.py dump OUT    # in each checkout (cwd)
+    python scripts/lowered_programs.py dump OUT tiny-gqa tiny-kexaone
+                       # those presets only: seven presets in one process
+                       # ran XLA's CPU compiler out of memory maps (PR 44)
     python scripts/lowered_programs.py diff A B
 
 `dump` drives an engine per tiny preset (`tiny-gqa`, `tiny-moe`, the tiny
-Mellum2, Kanana-2, dots3 and Phi-4-flash under benchmarks/tests/) and attention backend (`xla`,
+Mellum2, Kanana-2, dots3, Phi-4-flash and K-EXAONE under benchmarks/tests/)
+and attention backend (`xla`,
 `pallas`, which lowers in interpret mode off the chip) through single and
 batched prefill, the decode step (plain, host-masked, forced tokens), the
 fused multi-step scan, speculative verify (not on the windowed preset, which
@@ -52,12 +56,15 @@ def _presets():
         "benchmarks/tests/dots3/configs/tiny-dots3.json")
     phi4flash = config_from_hf_json(
         "benchmarks/tests/phi4flash/configs/tiny-phi4flash.json")
+    kexaone = config_from_hf_json(
+        "benchmarks/tests/kexaone/configs/tiny-kexaone.json")
     for name, cfg in (("tiny-gqa", get_config("tiny-gqa")),
                       ("tiny-moe", get_config("tiny-moe")),
                       ("tiny-mellum2", mellum),
                       ("tiny-kanana2", kanana),
                       ("tiny-dots3", dots3),
-                      ("tiny-phi4flash", phi4flash)):
+                      ("tiny-phi4flash", phi4flash),
+                      ("tiny-kexaone", kexaone)):
         for backend in ("xla", "pallas"):
             yield name, backend, dataclasses.replace(
                 cfg, attention_backend=backend)
@@ -134,7 +141,7 @@ def _drive_fsm(cfg, tok, tools):
     eng.run_to_completion()
 
 
-def dump(out):
+def dump(out, only=()):
     import dataclasses
 
     import jax
@@ -164,6 +171,8 @@ def dump(out):
         "name": "get_time",
         "parameters": {"type": "object", "properties": {}}}}]
     for name, backend, cfg in _presets():
+        if only and name not in only:
+            continue
         tag[0] = f"{name}.{backend}"
         _drive_plain(cfg)
         if cfg.vocab_size < tok.vocab_size:
@@ -223,9 +232,9 @@ def diff(a, b):
 
 
 if __name__ == "__main__":
-    if len(sys.argv) == 3 and sys.argv[1] == "dump":
+    if len(sys.argv) >= 3 and sys.argv[1] == "dump":
         sys.path.insert(0, ".")
-        dump(sys.argv[2])
+        dump(sys.argv[2], sys.argv[3:])
     elif len(sys.argv) == 4 and sys.argv[1] == "diff":
         sys.exit(diff(sys.argv[2], sys.argv[3]))
     else:

@@ -6,6 +6,8 @@ the 128-key window and without.
 
     python scripts/wide_gqa_bench.py               # the cell's shapes
     python scripts/wide_gqa_bench.py --rows 256    # another prefill bucket
+    python scripts/wide_gqa_bench.py --parent DIR  # + DIR's flash prefill
+    python scripts/wide_gqa_bench.py --takeapart   # + the chunk step's parts
     python scripts/wide_gqa_bench.py --rehearse    # CPU, tiny, no times
 
 Through the chip tool, from the repo root.  Defaults are the cell's: 16-row
@@ -20,17 +22,26 @@ the Pallas call's own events in one profiler capture (the host clock would
 add the dispatch).  Beside each: the flops the MODEL needs (4 x pairs under
 the mask x 64 x 128, against the bf16 peak) and the K / V bytes it needs
 (against the HBM peak), whichever bounds the call, so the next `perf_opt` on
-these kernels starts from a number.  The block-diagonal form multiplies
-Hkv = 8 x the lanes a query head needs, 7/8 of them zeros, and prefill does
-it in float32: a low share there is the kernel's form, not the chip's.
-Prints one JSON line a form and writes them all to
-chiprun_out/wide_gqa_bench.json.
+these kernels starts from a number.  Other geometries by option: Yi's and
+Mellum2's is `--heads 32 --kv-heads 4 --window 1024`, Phi-4-mini-flash's
+`--heads 40 --kv-heads 20 --head-dim 64 --window 512 --diff`.
+
+`--parent DIR` (a `git archive` of another commit, unpacked) times that
+tree's `paged_prefill_attention` on the same inputs in the same capture, as
+`parent_prefill_*`, and gives each installed form's largest difference from
+it.  `--takeapart` adds, for the first `--rows` bucket of the global form:
+the operands in float32 (q and both pools cast up: the kernel multiplies in
+the promoted dtype) with whether the bf16-operand output equals it bit for
+bit, and other q blocks and KV chunks (TAKEAPART_Q_BLOCKS,
+TAKEAPART_CHUNK_PAGES).  Prints one JSON line a form and writes them all,
+with the q block and lane group the kernel chose (`plan`), to `--out`.
 """
 
 from __future__ import annotations
 
 import argparse
 import glob
+import importlib.util
 import json
 import os
 import re
@@ -43,6 +54,11 @@ sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)),
                                 ".."))
 
 
+# --takeapart's other sizes: q positions a block, pages a KV chunk
+TAKEAPART_Q_BLOCKS = (32, 64, 128)
+TAKEAPART_CHUNK_PAGES = (8, 16)
+
+
 def pairs(rows: int, start: int, window) -> float:
     """(query, key) pairs of `rows` queries at positions start..; each
     attends itself and what is before it, a sliding layer the last
@@ -51,8 +67,24 @@ def pairs(rows: int, start: int, window) -> float:
                      for i in range(rows)))
 
 
+def load_parent(tree):
+    """`paged_prefill_attention` of the tree unpacked at `tree`, under a
+    module name of its own (the installed one stays what it is)."""
+    path = os.path.join(tree, "kafka_tpu", "ops", "pallas", "flash_prefill.py")
+    spec = importlib.util.spec_from_file_location(
+        "kafka_tpu.ops.pallas.parent_flash_prefill", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod.paged_prefill_attention
+
+
 def kernel_events(trace_dir, names):
-    """{name: [device ns of each Pallas call launched by jit_<name>]}."""
+    """({name: [device ns of the attention kernel's calls in each launch of
+    jit_<name>]}, {name: [device ns of each whole jit_<name> program]},
+    {name: the kernel calls' names}).  A kernel's events are under the name
+    of its jitted entry (`%paged_prefill_attention.1 = ... custom-call(`);
+    any other custom call XLA puts in the program counts under the whole
+    program only."""
     from jax.profiler import ProfileData
 
     path = sorted(glob.glob(os.path.join(
@@ -68,11 +100,18 @@ def kernel_events(trace_dir, names):
                     if m and m.group(1) in spans:
                         spans[m.group(1)].append((ev.start_ns, ev.duration_ns))
             elif line.name == "XLA Ops":
-                calls += [(ev.start_ns, ev.duration_ns) for ev in line.events
-                          if "custom-call" in ev.name]
-    return {n: [d for s0, d in calls
-                if any(t0 <= s0 < t0 + dur for t0, dur in sp)]
-            for n, sp in spans.items()}
+                calls += [(ev.start_ns, ev.duration_ns, ev.name)
+                          for ev in line.events
+                          if re.match(r"%paged_\w+_attention", ev.name)]
+
+    def inside(t0, dur):
+        return [c for c in calls if t0 <= c[0] < t0 + dur]
+
+    return ({n: [sum(d for _, d, _ in inside(*sp)) for sp in sps]
+             for n, sps in spans.items()},
+            {n: [dur for _, dur in sps] for n, sps in spans.items()},
+            {n: sorted({c[2].split(" = ")[0] for sp in sps
+                        for c in inside(*sp)}) for n, sps in spans.items()})
 
 
 def main() -> int:
@@ -92,6 +131,12 @@ def main() -> int:
     ap.add_argument("--max-pages", type=int, default=2048)
     ap.add_argument("--seed", type=int, default=2147485003)
     ap.add_argument("--reps", type=int, default=3)
+    ap.add_argument("--diff", action="store_true",
+                    help="differential attention's pairing (Phi-4)")
+    ap.add_argument("--parent", help="an unpacked tree whose flash prefill "
+                    "is timed beside the installed one")
+    ap.add_argument("--takeapart", action="store_true")
+    ap.add_argument("--out", default="chiprun_out/wide_gqa_bench.json")
     ap.add_argument("--rehearse", action="store_true",
                     help="tiny geometry, any backend, checks only")
     args = ap.parse_args()
@@ -110,6 +155,7 @@ def main() -> int:
         paged_decode_attention_window,
         paged_prefill_attention,
     )
+    from kafka_tpu.ops.pallas.flash_prefill import chunk_pages, prefill_plan
     from kafka_tpu.runtime.planner import device_peaks
 
     on_chip = jax.default_backend() == "tpu"
@@ -138,44 +184,93 @@ def main() -> int:
     assert args.start + max(args.rows) <= int(lens[0]) + 1, "--start"
 
     forms, needs = {}, {}
+    # scores over D lanes, the weighted sum over D (differential: 2 D)
+    pair_flops = (6.0 if args.diff else 4.0) * hq * d
+    kw = {"page_size": ps, "interpret": interp}
+    if args.diff:
+        kw["diff"] = True
+
+    def add_prefill(name, kernel, rows, window, q=None, pools=None, **more):
+        def prefill(q, k, v, row):
+            return kernel(q, k, v, row, jnp.int32(args.start),
+                          jnp.int32(q.shape[0]), window=window, **kw, **more)
+        prefill.__name__ = name
+        if q is None:
+            q = jnp.asarray(rng.randn(rows, hq, d).astype(np.float32), dt)
+        forms[name] = (jax.jit(prefill), (q, *(pools or (k_pool, v_pool)),
+                                          jnp.asarray(table[0])))
+        reach = window if window and window < args.start else None
+        keys = min(args.start + rows, reach + rows if reach
+                   else args.start + rows)
+        needs[name] = (pair_flops * pairs(rows, args.start, reach),
+                       2.0 * keys * hkv * d * 2)
+        return q
+
+    parent = load_parent(args.parent) if args.parent else None
     for window in (None, args.window):
         sfx = "window" if window else "global"
         for rows in args.rows:
-            name = f"prefill_{rows}_{sfx}"
-
-            def prefill(q, k, v, row, window=window):
-                return paged_prefill_attention(
-                    q, k, v, row, jnp.int32(args.start), jnp.int32(q.shape[0]),
-                    page_size=ps, interpret=interp, window=window)
-            prefill.__name__ = name
-            q = jnp.asarray(rng.randn(rows, hq, d).astype(np.float32), dt)
-            forms[name] = (jax.jit(prefill),
-                           (q, k_pool, v_pool, jnp.asarray(table[0])))
-            keys = min(args.start + rows, window + rows if window
-                       else args.start + rows)
-            needs[name] = (4.0 * pairs(rows, args.start, window) * hq * d,
-                           2.0 * keys * hkv * d * 2)
+            q = add_prefill(f"prefill_{rows}_{sfx}", paged_prefill_attention,
+                            rows, window)
+            if parent is not None:
+                add_prefill(f"parent_prefill_{rows}_{sfx}", parent, rows,
+                            window, q=q)
         name = f"decode_{sfx}"
 
         def decode(q, k, v, t, n, window=window):
             if window:
                 return paged_decode_attention_window(
-                    q, k, v, t, n, window=window, page_size=ps,
-                    interpret=interp)
-            return paged_decode_attention(q, k, v, t, n, page_size=ps,
-                                          interpret=interp)
+                    q, k, v, t, n, window=window, **kw)
+            return paged_decode_attention(q, k, v, t, n, **kw)
         decode.__name__ = name
         q = jnp.asarray(rng.randn(args.lanes, hq, d).astype(np.float32), dt)
         forms[name] = (jax.jit(decode), (q, k_pool, v_pool,
                                          jnp.asarray(table), jnp.asarray(lens)))
         seen = [min(int(n) + 1, window or int(n) + 1) for n in lens]
-        needs[name] = (4.0 * sum(seen) * hq * d,
+        needs[name] = (pair_flops * sum(seen),
                        2.0 * sum(-(-s // ps) * ps for s in seen) * hkv * d * 2)
+    if args.takeapart:
+        rows = args.rows[0]
+        base = f"prefill_{rows}_global"
+        q = forms[base][1][0]
+        plan = prefill_plan(rows, hq, hkv, d, jnp.dtype(dt).itemsize,
+                            diff=args.diff)
+        f32 = jnp.float32
+        add_prefill(f"{base}_f32_operands", paged_prefill_attention, rows,
+                    None, q=q.astype(f32),
+                    pools=(k_pool.astype(f32), v_pool.astype(f32)),
+                    q_block=plan["q_block"])
+        # (a form that is the default under another name would be the same
+        # program: the compile cache hands back the first one's, name and all)
+        for qb in TAKEAPART_Q_BLOCKS:
+            if qb != plan["q_block"]:
+                add_prefill(f"{base}_q_block_{qb}", paged_prefill_attention,
+                            rows, None, q=q, q_block=qb)
+        for cp in TAKEAPART_CHUNK_PAGES:
+            if cp != chunk_pages(ps):
+                add_prefill(f"{base}_chunk_{cp * ps}",
+                            paged_prefill_attention, rows, None, q=q,
+                            pages_per_chunk=cp)
 
     outs = {n: np.asarray(fn(*a), np.float32) for n, (fn, a) in forms.items()}
     for name, out in outs.items():
         assert np.isfinite(out).all(), name
-    if args.rehearse:
+    diffs = {n: float(np.abs(outs[n] - outs["parent_" + n]).max())
+             for n in outs if "parent_" + n in outs}
+    if not on_chip:
+        assert all(v < 1e-4 for v in diffs.values()), diffs
+    equal = None
+    if args.takeapart:
+        # the f32-operand output, rounded as the bf16-operand call rounds its
+        # own, against that call's
+        base = f"prefill_{args.rows[0]}_global"
+        want = np.asarray(jnp.asarray(outs[base + "_f32_operands"]
+                                      ).astype(dt), np.float32)
+        equal = bool((want == outs[base]).all())
+        for n in outs:
+            if n.startswith(base + "_") and not n.endswith("_f32_operands"):
+                assert np.abs(outs[n] - outs[base]).max() < 2e-2, n
+    if args.rehearse and not args.diff:
         # the prefill kernel against plain attention over the same rows
         rows = args.rows[0]
         total = args.start + rows
@@ -201,9 +296,14 @@ def main() -> int:
         for _ in range(args.reps):
             for fn, a in forms.values():
                 fn(*a).block_until_ready()
-    events = kernel_events(trace_dir, list(forms))
+    events, modules, called = kernel_events(trace_dir, list(forms))
     peak_flops, hbm_bytes_per_s, _ = device_peaks(jax.devices()[0])
     result = {"device": jax.devices()[0].device_kind, "args": vars(args),
+              "plan": {str(rows): prefill_plan(rows, hq, hkv, d,
+                                               jnp.dtype(dt).itemsize,
+                                               diff=args.diff)
+                       for rows in args.rows},
+              "chunk_keys": chunk_pages(ps) * ps,
               "forms": {}}
     for name in forms:
         durs = events[name]
@@ -215,13 +315,21 @@ def main() -> int:
         flops, nbytes = needs[name]
         row = {"calls": len(durs), "us_per_call": us,
                "min_us": min(durs) / 1e3, "max_us": max(durs) / 1e3,
+               # the whole jitted call: the kernel and the XLA ops around it
+               "module_us": float(np.median(modules[name])) / 1e3,
+               "kernels": called[name],
                "model_flops": flops, "kv_bytes": nbytes,
                "mfu_pct": 100.0 * flops / peak_flops / (us / 1e6),
                "hbm_pct": 100.0 * nbytes / hbm_bytes_per_s / (us / 1e6)}
+        if name in diffs:
+            row["max_abs_diff_vs_parent"] = diffs[name]
         result["forms"][name] = row
         print(json.dumps({"form": name, **row}))
-    os.makedirs("chiprun_out", exist_ok=True)
-    with open("chiprun_out/wide_gqa_bench.json", "w") as f:
+    if equal is not None:
+        result["bf16_operands_equal_f32_bit_for_bit"] = equal
+        print(json.dumps({"bf16_operands_equal_f32_bit_for_bit": equal}))
+    os.makedirs(os.path.dirname(args.out) or ".", exist_ok=True)
+    with open(args.out, "w") as f:
         json.dump(result, f, indent=1)
     return 0
 

@@ -476,23 +476,32 @@ def test_qk_norm_is_a_registered_scope_in_the_compiled_program(model):
 
 
 def test_flash_prefill_halves_its_q_block_for_the_wide_row_only():
-    """rows x lanes of a q block: 64 / 8 x 128 at 16 positions is the
-    [1024, 1024] tile the chip refused (18.04 MB of scoped VMEM); the
-    geometries that ran before keep their blocks."""
-    from kafka_tpu.ops.pallas.flash_prefill import (
-        PREFILL_TILE_ELEMS,
-        q_block_cap,
-    )
+    """Restated in PR 44 for the block sized by BYTES: a q block's rows are
+    one lane tile wide whatever the merged row is, so the wide row halves
+    nothing any more.  64 / 8 x 128 held 8 positions (the [1024, 1024] tile
+    the chip refused at 16, PR 43) and holds 64 or more; no geometry that
+    ran before holds fewer than it did."""
+    from kafka_tpu.ops.pallas.flash_prefill import prefill_plan, q_block_rows
 
-    assert q_block_cap(64, 8 * 128) == 8     # K-EXAONE: halved from 16
-    assert q_block_cap(32, 4 * 128) == 32    # Yi, Mellum2
-    assert q_block_cap(40, 20 * 64) == 16    # Phi-4-mini-flash
-    assert q_block_cap(32, 8 * 64) == 32     # llama-3.2-1b (chip_smoke)
-    assert q_block_cap(256, 1024) == 8       # never under 8 positions
-    for hq, lanes in ((64, 1024), (32, 512), (40, 1280), (32, 1024)):
-        cap = q_block_cap(hq, lanes)
-        assert cap & (cap - 1) == 0  # divides the power-of-two buckets
-        assert cap == 8 or cap * hq * lanes <= PREFILL_TILE_ELEMS
+    served = {  # (Hq, Hkv, D, diff): the block before PR 44
+        (64, 8, 128, False): 8,     # K-EXAONE
+        (32, 4, 128, False): 32,    # Yi, Mellum2
+        (40, 20, 64, True): 16,     # Phi-4-mini-flash
+        (32, 8, 64, False): 32,     # llama-3.2-1b (chip_smoke)
+    }
+    for (hq, hkv, d, diff), before in served.items():
+        for bucket in (64, 256, 512, 2048):
+            plan = prefill_plan(bucket, hq, hkv, d, 2, diff=diff)
+            qb = plan["q_block"]
+            assert qb & (qb - 1) == 0 and bucket % qb == 0
+            assert qb >= min(bucket, before)
+            assert plan["group_lanes"] == 128  # no row wider than a tile
+            assert qb <= q_block_rows(hq, 128, 2)  # the byte rule's cap
+    assert prefill_plan(512, 64, 8, 128, 2)["q_block"] >= 64
+    assert q_block_rows(256, 128, 2) == 16      # never under a bf16 tile
+    # a bucket the cap does not divide takes the largest power of two
+    # under it that does
+    assert prefill_plan(192, 32, 4, 128, 2)["q_block"] == 64
 
 
 # ---------------------------------------------------------------------------
@@ -520,21 +529,37 @@ def _sds(chip, shape, dtype):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
 
 
-@pytest.mark.parametrize("window", [None, 128], ids=["global", "window128"])
-@pytest.mark.parametrize("rows", [64, 256, 512])
-def test_flash_prefill_compiles_at_64_over_8_heads(one_chip, rows, window):
+def _compile_flash_prefill(one_chip, rows, hq, hkv, window):
     from kafka_tpu.ops.pallas import paged_prefill_attention
 
     sds = partial(_sds, one_chip)
-    pool = sds((8192 * PS, HKV * D), jnp.bfloat16)
+    pool = sds((8192 * PS, hkv * D), jnp.bfloat16)
     with jax.default_matmul_precision("default"):
         compiled = jax.jit(
             lambda q, k, v, pr, st, cl: paged_prefill_attention(
                 q, k, v, pr, st, cl, page_size=PS, window=window)
-        ).lower(sds((rows, HQ, D), jnp.bfloat16), pool, pool,
+        ).lower(sds((rows, hq, D), jnp.bfloat16), pool, pool,
                 sds((P,), jnp.int32), sds((), jnp.int32), sds((), jnp.int32)
                 ).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    # no block-diagonal expansion over the merged row, in or out (PR 44)
+    assert f"[{rows * hq},{hkv * D}]" not in text
+    return text
+
+
+@pytest.mark.parametrize("window", [None, 128], ids=["global", "window128"])
+@pytest.mark.parametrize("rows", [64, 256, 512])
+def test_flash_prefill_compiles_at_64_over_8_heads(one_chip, rows, window):
+    _compile_flash_prefill(one_chip, rows, HQ, HKV, window)
+
+
+@pytest.mark.parametrize("window", [None, 1024], ids=["global", "window1024"])
+def test_flash_prefill_compiles_yis_2048_row_bucket(one_chip, window):
+    """32 / 4 x 128 (Yi; Mellum2 with its 1,024-key window) at the largest
+    bucket either serves: the q block the byte rule gives it is 128
+    positions, four times what it held."""
+    _compile_flash_prefill(one_chip, 2048, 32, 4, window)
 
 
 @pytest.mark.parametrize("window", [None, 128], ids=["global", "window128"])
