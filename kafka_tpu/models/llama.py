@@ -1487,17 +1487,21 @@ def _mlp_block(x: jnp.ndarray, lp: Params,
 
 
 def _routing_weights(t: jnp.ndarray, router: jnp.ndarray,
-                     top_k: int) -> jnp.ndarray:
+                     top_k: int, picks: bool = False):
     """Per-token expert weights [T, E]: softmax over EXACTLY the top-k
     router logits, scattered back (HF MixtralSparseMoeBlock semantics —
     a >=threshold mask would activate extra experts on k-th-place ties).
     The canonical routing implementation; parallel/expert.py reuses it.
+    `picks`: the same choice unscattered, (experts [T, k] i32, weights
+    [T, k] f32), for token dispatch (`_experts_token`).
     """
     logits = jnp.einsum(
         "th,he->te", t, router, preferred_element_type=jnp.float32
     )
     top_vals, top_idx = jax.lax.top_k(logits, top_k)
     w_top = jax.nn.softmax(top_vals, axis=-1)
+    if picks:
+        return top_idx, w_top
     return jnp.zeros_like(logits).at[
         jnp.arange(t.shape[0])[:, None], top_idx
     ].set(w_top)
@@ -1505,12 +1509,13 @@ def _routing_weights(t: jnp.ndarray, router: jnp.ndarray,
 
 def _routing_weights_sigmoid(t: jnp.ndarray, router: jnp.ndarray,
                              bias: jnp.ndarray, top_k: int,
-                             scale: float) -> jnp.ndarray:
+                             scale: float, picks: bool = False):
     """Per-token expert weights [T, E] of HF deepseek_v3's `noaux_tc` rule
     with one group: sigma = sigmoid(logits) in f32; the top_k experts by
     sigma + bias are CHOSEN (the bias chooses, it does not weigh; ties go to
     the lower index, as lax.top_k); a chosen expert weighs
-    scale * sigma_e / (sum of the chosen sigma + 1e-20)."""
+    scale * sigma_e / (sum of the chosen sigma + 1e-20).  `picks` as in
+    `_routing_weights`."""
     logits = jnp.einsum(
         "th,he->te", t, router, preferred_element_type=jnp.float32
     )
@@ -1519,23 +1524,134 @@ def _routing_weights_sigmoid(t: jnp.ndarray, router: jnp.ndarray,
     rows = jnp.arange(t.shape[0])[:, None]
     chosen = sigma[rows, top_idx]
     w_top = scale * chosen / (jnp.sum(chosen, axis=-1, keepdims=True) + 1e-20)
+    if picks:
+        return top_idx, w_top
     return jnp.zeros_like(sigma).at[rows, top_idx].set(w_top)
 
 
-def _moe_block(x: jnp.ndarray, lp: Params, cfg: ModelConfig) -> jnp.ndarray:
-    """Mixtral-style top-k routed MoE MLP. x: [B, S, H].
+# Rows of one pass (lanes x bucket) from which the routed block dispatches by
+# token.  Dense dispatch does 2 T FLOPs a weight element (2 bytes), so on a
+# v5e (197 TFLOP/s, 819 GB/s) it is weight-read-bound below T ~ 240 and
+# compute-bound above.  Measured, the block alone on the chip, us a layer,
+# dense | token (scripts/moe_dispatch_bench.py; PERF.md section 6, PR 45):
+#   rows  Mixtral      Mellum2      Kanana-2     K-EXAONE     dots3
+#   256   4348 | 4986  1267 | 1367  1969 | 1870  1921 | 1968  2362 | 2361
+#   320   5001 | 5570  1586 | 1465  2360 | 1930  2295 | 2084  2808 | 2361
+#   384   6137 | 5587  1810 | 1548  2817 | 1978  2755 | 2294  3416 | 2448
+#   512   8370 | 6188  2435 | 1723  3689 | 2118  3820 | 2424  4613 | 2626
+# 384 is the first row count at which the token form is the faster one at
+# every routed configuration (a sort, two row gathers and a visit a (row
+# tile, expert) are what it pays below).
+TOKEN_DISPATCH_MIN_ROWS = 384
 
-    Dense dispatch (parallel/expert.py's capacity-unlimited formulation,
-    validated there against a per-token loop): every expert computes every
-    token, the [T, E] routing weights zero the non-selected contributions,
-    and the combine einsum contracts the expert axis.  With wg/wu/wd
-    sharded P(layer, "ep", ..., "tp") GSPMD partitions the expert einsums
-    over ep and inserts the combine psum automatically — the same program
-    serves single-device, ep, and ep x tp meshes.  Routing: softmax over
-    the top-k router logits only (HF MixtralSparseMoeBlock semantics),
-    computed in f32; `cfg.moe_scoring` "sigmoid" picks deepseek_v3's rule
-    instead.  A shared branch (`cfg.shared_intermediate_size`: one always-on
-    SwiGLU beside the routed experts) runs under its own scope, `moe_shared`.
+
+def moe_dispatch_form(rows: int, held: int, top_k: int, sharded: bool) -> str:
+    """"token" or "dense": the form of the routed block for a pass of `rows`
+    rows (static) over `held` experts of which a row picks `top_k`.  Token
+    dispatch where dense dispatch is compute-bound and computes products it
+    then zeroes; dense below that (decode: the weights' read bounds both,
+    and dense has no sort or gather), where every held expert takes every
+    row anyway, and on an ep / tp mesh (GSPMD partitions the dense einsums;
+    a sharded grouped matmul is ROADMAP R4's).  The one rule: `_moe_block`
+    traces by it and the engine counts launches by it."""
+    if sharded or held <= top_k or rows < TOKEN_DISPATCH_MIN_ROWS:
+        return "dense"
+    return "token"
+
+
+# XLA's row gather on the v5e (jaxlib 0.9.0) keeps an operand of up to ~7.3 MB
+# in VMEM and then asks for twice the operand + ~3 MiB of scoped VMEM, of which
+# a fusion has 16 MiB: with an operand between ~6.9 and ~7.3 MB the program
+# does not compile ("Ran out of memory in memory space vmem ... please file a
+# bug against XLA": Mellum2's 1,536 rows x 2,304 bf16, the logit check's
+# launch; 1,504 and 1,600 rows compile).  `_experts_token` pads an operand of
+# (6, 7.5] MiB past the window, where the gather reads it from HBM as it does
+# every larger one; tests/test_exaone_moe.py compiles the case for a
+# described v5e.
+GATHER_VMEM_WINDOW = (6 << 20, 15 << 19)
+
+# the routed experts' leaves: what token dispatch reads from the layer stack
+EXPERT_LEAVES = ("wg", "wu", "wd")
+
+
+def _experts_token(t: jnp.ndarray, top_idx: jnp.ndarray, w_top: jnp.ndarray,
+                   stack: Params, layer, routed: int, offset: int,
+                   real: Optional[jnp.ndarray] = None) -> jnp.ndarray:
+    """The routed experts by token: the T x k (row, expert, weight) picks
+    sorted by expert, the rows gathered into that order, each projection ONE
+    grouped matmul whose groups are the held experts (operands in t's dtype,
+    f32 accumulation, as the dense einsums), and each row's k results
+    weighted and summed in f32.  t [T, H]; `stack` the expert leaves stacked
+    over layers [L, E, ...], of which this is `layer`; top_idx [T, k] counts
+    over ALL the router's `routed` experts, of which this chip holds
+    offset.. ; `real` [T] bool marks the rows that hold a token.  A pick of
+    an expert held elsewhere, or of a pad row, sorts past every group: no
+    matmul rows, zero weight.  No capacity, nothing dropped."""
+    from ..ops.pallas.grouped_matmul import grouped_matmul, tile_rows
+
+    n, k = top_idx.shape
+    held = stack["wg"].shape[1]
+    e = top_idx - offset
+    mine = (e >= 0) & (e < held)
+    if real is not None:
+        mine = mine & real[:, None]
+    e = jnp.where(mine, e, held).reshape(-1)
+    order = jnp.argsort(e, stable=True)
+    sizes = jnp.sum(e[:, None] == jnp.arange(held)[None, :], axis=0,
+                    dtype=jnp.int32)
+    # whole row tiles: the rows added sort past every group too
+    tile = tile_rows(n * k, routed)
+    src, row_bytes = t, t.shape[1] * t.dtype.itemsize
+    low, high = GATHER_VMEM_WINDOW
+    if low < n * row_bytes <= high:
+        src = jnp.pad(t, ((0, high // row_bytes + 1 - n), (0, 0)))
+    xs = src[jnp.pad(order // k, (0, -(n * k) % tile))]
+    g = grouped_matmul(xs, stack["wg"], sizes, layer, tile)
+    u = grouped_matmul(xs, stack["wu"], sizes, layer, tile)
+    y = grouped_matmul(jax.nn.silu(g) * u, stack["wd"], sizes, layer, tile)
+    # each pick's result from where the sort put it (a pick that is not
+    # `mine` finds a row no group wrote: whatever the buffer held)
+    y = y[jnp.argsort(order)].reshape(n, k, -1)
+    y = jnp.where(mine[:, :, None], y.astype(jnp.float32), 0.0)
+    return jnp.sum(y * w_top[:, :, None], axis=1).astype(t.dtype)
+
+
+def _moe_block(x: jnp.ndarray, lp: Params, cfg: ModelConfig,
+               chunk_len: Optional[jnp.ndarray] = None,
+               sharded: bool = False,
+               stacked: Optional[Tuple[Params, Any]] = None) -> jnp.ndarray:
+    """Top-k routed MoE MLP. x: [B, S, H].
+
+    Routing: softmax over the top-k router logits only (HF
+    MixtralSparseMoeBlock semantics), computed in f32; `cfg.moe_scoring`
+    "sigmoid" picks deepseek_v3's rule instead.  The experts then run in one
+    of two forms of the same arithmetic, chosen by `moe_dispatch_form` from
+    the pass's static row count B x S:
+
+    dense (decode, verify, the small prefill buckets, every mesh): every
+    expert computes every row (parallel/expert.py's capacity-unlimited
+    formulation, validated there against a per-token loop), the [T, E]
+    routing weights zero the non-selected contributions, and the combine
+    einsum contracts the expert axis.  Below ~240 rows the experts' weight
+    read bounds the block and the products thrown away are free.  With
+    wg/wu/wd sharded P(layer, "ep", ..., "tp") GSPMD partitions the expert
+    einsums over ep and inserts the combine psum automatically, so the same
+    program serves single-device, ep, and ep x tp meshes: meshes keep this
+    form until a sharded grouped matmul exists (ROADMAP R4).
+
+    token (prefill launches of TOKEN_DISPATCH_MIN_ROWS rows or more on one
+    device): `_experts_token`, each row through its own k experts only, where
+    dense dispatch would be compute-bound at E / k times the FLOPs needed.
+    `chunk_len` [B] or scalar (the view's, at prefill): rows at or past it
+    are padding, fall in no group and get a zero routed output (nothing
+    reads their feed-forward output; None: every row is real).  `stacked`:
+    (the EXPERT_LEAVES as the layer stack holds them, this layer's index),
+    which `forward` hands over in place of `lp`'s slices of them so that
+    the grouped matmul reads the weights where they lie (None: `lp` holds
+    the layer's own).
+
+    A shared branch (`cfg.shared_intermediate_size`: one always-on SwiGLU
+    beside the routed experts) runs under its own scope, `moe_shared`.
     A config that HOLDS a share of the experts (`cfg.num_experts_routed`: one
     chip of an expert-parallel layer) routes over all the router knows and
     computes the part of the result its own experts give; what the absent
@@ -1543,23 +1659,38 @@ def _moe_block(x: jnp.ndarray, lp: Params, cfg: ModelConfig) -> jnp.ndarray:
     """
     b, s, h = x.shape
     t = x.reshape(b * s, h)
+    token = moe_dispatch_form(
+        b * s, cfg.num_experts, cfg.num_experts_per_tok, sharded) == "token"
     with jax.named_scope("moe_router"):
         if cfg.moe_scoring == "sigmoid":
             w = _routing_weights_sigmoid(
                 t, lp["router"], lp["router_bias"], cfg.num_experts_per_tok,
-                cfg.routed_scaling_factor)
+                cfg.routed_scaling_factor, token)
         else:
-            w = _routing_weights(t, lp["router"], cfg.num_experts_per_tok)
-        if cfg.num_experts_routed:
+            w = _routing_weights(
+                t, lp["router"], cfg.num_experts_per_tok, token)
+        if cfg.num_experts_routed and not token:
             # the weights of the experts HELD: chosen and renormalised over
             # all the router's experts, then this share's columns
             w = w[:, cfg.expert_offset:cfg.expert_offset + cfg.num_experts]
     with jax.named_scope("moe_experts"):
-        g = jnp.einsum("th,ehf->tef", t, _w(lp, "wg", t.dtype))
-        u = jnp.einsum("th,ehf->tef", t, _w(lp, "wu", t.dtype))
-        y = jnp.einsum(
-            "tef,efh->teh", jax.nn.silu(g) * u, _w(lp, "wd", t.dtype))
-        out = jnp.einsum("te,teh->th", w.astype(y.dtype), y)
+        if token:
+            real = None
+            if chunk_len is not None:
+                real = (jnp.arange(s)[None, :]
+                        < jnp.reshape(chunk_len, (-1, 1))).reshape(b * s)
+            stack, at = stacked or (
+                {name: _w(lp, name, t.dtype)[None] for name in EXPERT_LEAVES},
+                0)
+            out = _experts_token(
+                t, *w, stack, at, cfg.num_router_experts,
+                cfg.expert_offset if cfg.num_experts_routed else 0, real)
+        else:
+            g = jnp.einsum("th,ehf->tef", t, _w(lp, "wg", t.dtype))
+            u = jnp.einsum("th,ehf->tef", t, _w(lp, "wu", t.dtype))
+            y = jnp.einsum(
+                "tef,efh->teh", jax.nn.silu(g) * u, _w(lp, "wd", t.dtype))
+            out = jnp.einsum("te,teh->th", w.astype(y.dtype), y)
     out = out.reshape(b, s, h)
     if cfg.shared_intermediate_size:
         with jax.named_scope("moe_shared"):
@@ -1636,13 +1767,34 @@ def forward(
         for kind in cfg.unrotated_kinds:
             rope[kind] = (None, None)
 
+    # Where the routed blocks dispatch by token (moe_dispatch_form: this
+    # pass's rows), the expert leaves stay out of what is sliced a layer:
+    # `_moe_block` is handed the stack and the layer's index in it (a third
+    # entry of `scanned`), and a program that keeps the dense form is traced
+    # as it always was.  (int8 experts are dequantized a layer, from
+    # their slices.)
+    sharded = mesh is not None and mesh.size > 1
+    layers, experts = params["layers"], None
+    if (cfg.is_moe and moe_dispatch_form(
+            token_ids.shape[0] * token_ids.shape[1], cfg.num_experts,
+            cfg.num_experts_per_tok, sharded) == "token"
+            and not any(isinstance(layers[n], QTensor)
+                        for n in EXPERT_LEAVES)):
+        experts = {n: layers[n] for n in EXPERT_LEAVES}
+        layers = {n: a for n, a in layers.items() if n not in experts}
+
+    def indexed(index):
+        """`scanned`'s third entry, the layer's index() in the expert stack
+        (nothing, and not an op traced, where the block keeps `lp`'s)."""
+        return () if experts is None else (index(),)
+
     # The stacked caches are CARRY (module docstring): the scan slices only
     # the layer's weights.  Every op of the layer body sits under a leaf
     # scope (residual adds included), so what a device trace shows under
     # `layers` alone is the scan's own slicing of its stacked inputs.
     def layer_body(carry, scanned, kind=GLOBAL, routed=cfg.is_moe):
         h, kc, vc = carry
-        lp, layer = scanned
+        lp, layer, *slot = scanned
         cos, sin = rope[kind]
         with jax.named_scope("attn_norm"):
             attn_in = rms_norm(h, lp["ln_attn"], cfg.rms_norm_eps)
@@ -1675,7 +1827,9 @@ def forward(
         with jax.named_scope("mlp_norm"):
             mlp_in = rms_norm(h, lp["ln_mlp"], cfg.rms_norm_eps)
         if routed:
-            ffn_out = _moe_block(mlp_in, lp, cfg)
+            ffn_out = _moe_block(
+                mlp_in, lp, cfg, None if paged is None else paged.chunk_len,
+                sharded, (experts, slot[0]) if slot else None)
             with jax.named_scope("moe_experts"):
                 h = h + ffn_out
         else:
@@ -1697,10 +1851,12 @@ def forward(
         `params[stack]`.  A by_kind model takes its attention leaves from
         its kind's own stack and indexes its kind's caches, both at `nth`,
         the layer's place among its kind."""
-        lp = at(params[stack], i, static)
+        routed = stack == "layers"
+        lp = at(layers if routed else params[stack], i, static)
         if not cfg.by_kind:
-            return lp, layer
-        return {**lp, **at(params["attn"][kind], nth, static)}, nth
+            return (lp, layer) + (indexed(lambda: i) if routed else ())
+        return ({**lp, **at(params["attn"][kind], nth, static)}, nth) + (
+            indexed(lambda: i) if routed else ())
 
     def period_body(carry, first):
         """One whole period of the pattern, from absolute layer `first`: its
@@ -1728,8 +1884,8 @@ def forward(
                 lp = jax.tree.map(
                     lambda a: jax.lax.dynamic_index_in_dim(
                         a, stacked + j, axis=0, keepdims=False),
-                    params["layers"])
-                scanned = (lp, first + j)
+                    layers)
+                scanned = (lp, first + j) + indexed(lambda: stacked + j)
             carry, _ = layer_body(carry, scanned, kind)
         return carry, None
 
@@ -1775,7 +1931,8 @@ def forward(
             (x, kc, vc), _ = jax.lax.scan(
                 partial(layer_body, kind=period[0]),
                 (x, kc, vc),
-                (params["layers"], layer_ids),
+                (layers, layer_ids) + indexed(
+                    lambda: jnp.arange(num_layers)),
             )
         elif lead < num_layers:
             p = len(period)

@@ -1457,6 +1457,10 @@ class _AggregateMetrics:
                 s["engine"]["prefill_walk_trips"] for s in snaps),
             "prefill_walk_kernel_trips": sum(
                 s["engine"]["prefill_walk_kernel_trips"] for s in snaps),
+            "moe_dispatch": {
+                k: sum(s["engine"]["moe_dispatch"][k] for s in snaps)
+                for k in snaps[0]["engine"]["moe_dispatch"]
+            },
             "fetch_depth_steps_sum": sum(
                 s["engine"]["fetch_depth_steps_sum"] for s in snaps),
             "fetch_depth_samples": sum(

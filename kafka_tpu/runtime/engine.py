@@ -1370,6 +1370,13 @@ class InferenceEngine:
         # backend, 0 on XLA).
         self.prefill_walk_trips = 0
         self.prefill_walk_kernel_trips = 0
+        # Monotonic, and all 0 for a model with no routed block
+        # (StepPrograms.moe_dispatch): step programs dispatched by the form
+        # their routed blocks take, and the rows those blocks were handed
+        # (rows a pass x the passes of a fused dispatch).
+        self.moe_dispatch = dict.fromkeys(
+            ("token_launches", "token_rows", "dense_launches", "dense_rows"),
+            0)
         # Monotonic: the host's run-ahead, sampled at every decode / fused
         # / verify dispatch (_backlog_steps: steps in the FIFO the device
         # has not been seen to finish, the number _hold_decode bounds);
@@ -4092,6 +4099,7 @@ class InferenceEngine:
         self._d_seq_lens = new_lens
         out.copy_to_host_async()
         self._step_count += 1
+        self._count_moe_dispatch(B * (K + 1))
         finals: List[Optional[str]] = [None] * B
         now_mono: Optional[float] = None
         busy = sum(1 for m in members if m is not None)
@@ -4272,6 +4280,13 @@ class InferenceEngine:
         trips, folded = self._programs.prefill_walk_trips(spans, width, bucket)
         self.prefill_walk_trips += trips
         self.prefill_walk_kernel_trips += folded
+        self._count_moe_dispatch(width * bucket)
+
+    def _count_moe_dispatch(self, rows: int, passes: int = 1) -> None:
+        form = self._programs.moe_dispatch(rows)
+        if form is not None:
+            self.moe_dispatch[form + "_launches"] += 1
+            self.moe_dispatch[form + "_rows"] += rows * passes
 
     def _book_dispatch(
         self,
@@ -4293,6 +4308,7 @@ class InferenceEngine:
             steps)
         self.decode_keys_walked += walked
         self.decode_keys_window += window
+        self._count_moe_dispatch(len(members), steps)
         if self.cfg.index_topk:
             scored, kept = self._programs.index_keys(
                 [m.seq.length for m in members if m is not None], steps)

@@ -1499,6 +1499,7 @@ class EngineMetrics:
                 "prefill_walk_trips": engine.prefill_walk_trips,
                 "prefill_walk_kernel_trips":
                     engine.prefill_walk_kernel_trips,
+                "moe_dispatch": dict(engine.moe_dispatch),
                 # the fetch pipeline's run-ahead (steps, sum over samples
                 # at decode dispatches), the scheduler thread's time in
                 # unlanded reads, and pops by what released them

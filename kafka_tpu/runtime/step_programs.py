@@ -32,7 +32,13 @@ import jax
 import jax.numpy as jnp
 
 from ..models.config import ModelConfig
-from ..models.llama import KVCache, PagedView, forward, prefill_walk_pages
+from ..models.llama import (
+    KVCache,
+    PagedView,
+    forward,
+    moe_dispatch_form,
+    prefill_walk_pages,
+)
 from ..ops.attention import decode_walk_pages
 from ..ops.sampling import (
     SamplingParams,
@@ -158,9 +164,12 @@ def chunk_plan(page_rows, starts, chunk_lens, lane_active, S: int, ps: int):
         kv_valid = (
             kv_positions < (starts + chunk_lens)[:, None]
         ) & lane_active[:, None]
+        # (chunk_len with no `start`: no attention path reads it; the routed
+        # block leaves the rows past it out of its groups)
         paged = PagedView(
             write_idx, read_idx, kv_positions, kv_valid,
-            page_table=page_rows, page_size=ps)
+            page_table=page_rows, page_size=ps,
+            chunk_len=jnp.where(lane_active, chunk_lens, 0))
     return pos, paged
 
 
@@ -572,6 +581,18 @@ class StepPrograms:
                 first = min(max(lo, 0) // ck, trips)
             total += cfg.layers_of(kind) * (trips - first)
         return total, total if kernel else 0
+
+    def moe_dispatch(self, rows: int) -> Optional[str]:
+        """"token" or "dense": the form the routed blocks of a pass of
+        `rows` rows (lanes x rows a lane) trace to, by the rule
+        models/llama.py _moe_block itself asks (moe_dispatch_form); None
+        for a model with no routed block."""
+        cfg, mesh = self.cfg, self.mesh
+        if not cfg.is_moe:
+            return None
+        return moe_dispatch_form(
+            rows, cfg.num_experts, cfg.num_experts_per_tok,
+            mesh is not None and mesh.size > 1)
 
     def decode(self, fsm: Optional[Fsm] = None):
         """One token for every active lane: fn(params, k_pool, v_pool,

@@ -584,3 +584,37 @@ def test_paged_decode_compiles_at_64_over_8_heads(one_chip, window):
             sds((B, HQ, D), jnp.bfloat16), pool, pool, sds((B, P), jnp.int32),
             sds((B,), jnp.int32)).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_token_dispatch_compiles_in_mellum2s_1536_row_launch(
+        one_chip, monkeypatch):
+    """The logit check's launch of `mellum2-12b-a2.5b` whole, at its real
+    widths: XLA's gather of the 1,536 x 2,304 bf16 rows into expert order
+    asked for 16.41 MiB of the 16 MiB of scoped VMEM a fusion has (PR 45, on
+    the chip and here alike; models/llama.py GATHER_VMEM_WINDOW)."""
+    path = os.path.join(BENCH, "configs", "mellum2-12b-a2.5b.json")
+    with open(path) as f:
+        srv = json.load(f)["serving"]
+    cfg = config_from_hf_json(path).replace(
+        dtype=srv["dtype"], attention_backend="pallas")
+    ps, rows = srv["page_size"], 1536
+
+    def on_chip(tree):
+        return jax.tree.map(
+            lambda a: _sds(one_chip, a.shape, a.dtype), tree)
+
+    params = on_chip(jax.eval_shape(
+        lambda: init_params(cfg, jax.random.PRNGKey(0))))
+    pools = on_chip(jax.eval_shape(
+        lambda: make_kv_pool_arrays(cfg, 128, ps)))
+    scalar = _sds(one_chip, (), jnp.int32)
+    # (the program asks the backend whether to interpret its kernels)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with jax.default_matmul_precision("default"):
+        text = jax.jit(
+            partial(paged_step.prefill_chunk, page_size=ps),
+            static_argnums=(1,),
+        ).lower(params, cfg, *pools, _sds(one_chip, (100,), jnp.int32),
+                _sds(one_chip, (rows,), jnp.int32), scalar, scalar
+                ).compile().as_text()
+    assert text.count("gmm") >= 3  # the grouped matmuls, not the dense form
