@@ -39,6 +39,14 @@ from typing import Any, Dict, Iterable, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+# the grammar-table budget (device bytes) and the pending-compile gauge (a
+# queue depth) are the runtime's; compile_pending is named here for callers
+from ..runtime.metrics import (  # noqa: F401
+    GRAMMAR_COMPILES_PENDING,
+    compile_pending,
+)
+from ..runtime.planner import grammar_ondevice_enabled, grammar_table_cap_bytes
+
 WS = " \t\n\r"
 DIGITS = "0123456789"
 # characters probed when asking an automaton "what may come next"
@@ -1012,9 +1020,6 @@ class ToolCallMaskFn:
 # safe characters never changes `in_str`), keeping the compile
 # O(states x structural-tokens) instead of O(states x vocab).
 
-GRAMMAR_ONDEVICE_ENV = "KAFKA_TPU_GRAMMAR_ONDEVICE"
-GRAMMAR_TABLE_MB_ENV = "KAFKA_TPU_GRAMMAR_TABLE_MB"
-_GRAMMAR_TABLE_MB_DEFAULT = 64
 # BFS guard independent of the byte cap (a runaway grammar must fail the
 # compile, not stall the process)
 _GRAMMAR_MAX_STATES = 32768
@@ -1024,26 +1029,6 @@ _GRAMMAR_MAX_STATES = 32768
 GRAMMAR_WRAP_SLACK = 4
 
 _GRAMMAR_COMPILE_LOCK = __import__("threading").Lock()
-
-
-def grammar_ondevice_enabled() -> bool:
-    import os
-
-    return os.environ.get(GRAMMAR_ONDEVICE_ENV, "1") not in (
-        "0", "false", "off"
-    )
-
-
-def _grammar_table_cap_bytes() -> int:
-    import os
-
-    try:
-        mb = float(os.environ.get(GRAMMAR_TABLE_MB_ENV, ""))
-    except ValueError:
-        mb = _GRAMMAR_TABLE_MB_DEFAULT
-    if not mb:
-        mb = _GRAMMAR_TABLE_MB_DEFAULT
-    return int(mb * (1 << 20))
 
 
 class CompiledGrammar:
@@ -1154,7 +1139,7 @@ def compile_tool_call_grammar(
         return None
     cap = (
         max_table_bytes if max_table_bytes is not None
-        else _grammar_table_cap_bytes()
+        else grammar_table_cap_bytes()
     )
     try:
         auto0 = ToolCallAutomaton(tools, force_name=force_name)
@@ -1307,14 +1292,7 @@ GRAMMAR_SYNC_VOCAB_ENV = "KAFKA_TPU_GRAMMAR_SYNC_VOCAB"
 _GRAMMAR_SYNC_VOCAB_DEFAULT = 32768
 
 _DEFER_LOCK = __import__("threading").Lock()
-_DEFER_PENDING: set = set()  # (id(tokenizer), schema key) being compiled
 _DEFER_QUEUE: Optional[Any] = None  # queue.Queue, created with the worker
-
-
-def compile_pending() -> int:
-    """Gauge: grammar compiles queued/running on the background worker
-    (exported as constrained_compile_pending in /metrics)."""
-    return len(_DEFER_PENDING)
 
 
 def _grammar_sync_vocab() -> int:
@@ -1372,7 +1350,7 @@ def _defer_worker() -> None:
             log.warning("deferred grammar compile failed: %s", e)
         finally:
             with _DEFER_LOCK:
-                _DEFER_PENDING.discard((id(tok), key))
+                GRAMMAR_COMPILES_PENDING.discard((id(tok), key))
 
 
 def _enqueue_deferred(tok, mask_fn, vocab_size: int, key) -> None:
@@ -1382,9 +1360,9 @@ def _enqueue_deferred(tok, mask_fn, vocab_size: int, key) -> None:
 
     with _DEFER_LOCK:
         pkey = (id(tok), key)
-        if pkey in _DEFER_PENDING:
+        if pkey in GRAMMAR_COMPILES_PENDING:
             return  # one compile per schema, however many callers race
-        _DEFER_PENDING.add(pkey)
+        GRAMMAR_COMPILES_PENDING.add(pkey)
         if _DEFER_QUEUE is None:
             _DEFER_QUEUE = _queue.Queue()
             _threading.Thread(

@@ -1243,344 +1243,33 @@ class _AggregateMetrics:
 
     def snapshot(self, engine=None,
                  reset_peak: bool = True) -> Dict[str, Any]:
-        from .metrics import HISTOGRAM_NAMES, StreamingHistogram
+        from .metrics import (
+            UTILIZATION_KINDS,
+            merge_snapshots,
+            utilization_ratios,
+        )
 
         snaps = [e.metrics.snapshot(e, reset_peak=reset_peak)
                  for e in self._engines]
+        # every section the replicas report merges by the metric table
+        # (runtime/metrics.py), from the SAME snapshots exported as the
+        # per-replica detail, so the aggregate equals their combination
+        # within one scrape; below come the sections the router owns
         agg: Dict[str, Any] = {
             "dp": len(snaps),
-            "replicas": snaps,  # per-replica detail
-            "uptime_s": snaps[0]["uptime_s"],
+            "replicas": snaps,
+            **merge_snapshots(snaps, decode_busy_slots=sum(
+                e.metrics.decode_busy_slots for e in self._engines)),
         }
-        # summable counters aggregate
-        agg["requests"] = {
-            k: sum(s["requests"][k] for s in snaps)
-            for k in snaps[0]["requests"]
-        }
-        agg["queue"] = {
-            "depth": sum(s["queue"]["depth"] for s in snaps),
-            "peak": max(s["queue"]["peak"] for s in snaps),
-            # depth slopes add: the dp-wide queue's growth rate
-            "trend_per_s": round(
-                sum(s["queue"]["trend_per_s"] for s in snaps), 4
-            ),
-        }
-        gen = sum(s["tokens"]["generated"] for s in snaps)
-        wasted = sum(s["tokens"]["fetch_pipeline_wasted"] for s in snaps)
-        agg["tokens"] = {
-            "prompt": sum(s["tokens"]["prompt"] for s in snaps),
-            "generated": gen,
-            # rates sum across replicas (each is tokens over the same wall
-            # clock), ratios do not — recompute anything derived
-            "generated_per_s": round(
-                sum(s["tokens"]["generated_per_s"] for s in snaps), 2
-            ),
-            "fetch_pipeline_wasted": wasted,
-            "fetch_pipeline_waste_frac": round(
-                wasted / (gen + wasted), 4
-            ) if (gen + wasted) else 0.0,
-        }
-        # constrained decoding: every key is a summable counter EXCEPT
-        # compile_pending, a process-wide gauge every replica reports
-        # identically (the deferred-compile queue is shared) — summing it
-        # would multiply by dp
-        agg["constrained"] = {
-            k: (s0_v if k == "constrained_compile_pending"
-                else sum(s["constrained"][k] for s in snaps))
-            for k, s0_v in snaps[0]["constrained"].items()
-        }
-        agg["constrained_roundtrips"] = \
-            agg["constrained"]["constrained_roundtrips"]
-        # speculative decoding: counters sum, rates recompute.  Summed
-        # from the SAME snaps as the exported per-replica detail so the
-        # aggregate always equals the sum of agg["replicas"] within one
-        # scrape (live re-reads could disagree)
-        prop = sum(s["speculation"]["speculation_proposed_tokens"]
-                   for s in snaps)
-        acc = sum(s["speculation"]["speculation_accepted_tokens"]
-                  for s in snaps)
-        rej = sum(s["speculation"]["speculation_rejected_tokens"]
-                  for s in snaps)
-        steps_v = sum(s["speculation"]["speculation_verify_steps"]
-                      for s in snaps)
-        agg["speculation"] = {
-            "speculation_proposed_tokens": prop,
-            "speculation_accepted_tokens": acc,
-            "speculation_rejected_tokens": rej,
-            "speculation_verify_steps": steps_v,
-            "speculation_acceptance_rate": round(
-                acc / (acc + rej), 4
-            ) if (acc + rej) else 0.0,
-            "speculation_accepted_per_step": round(
-                acc / steps_v, 3
-            ) if steps_v else 0.0,
-        }
-        # latency distributions MERGE exactly (the whole point of the
-        # fixed-bucket streaming histograms, ISSUE 10): same bounds, bucket
-        # counts add — no raw-sample pooling, no percentile-of-percentiles.
-        # Merged from the SAME per-replica snapshots exported below so the
-        # aggregate equals the sum of agg["replicas"] within one scrape.
-        merged = {
-            name: StreamingHistogram.merged([
-                StreamingHistogram.from_snapshot(s["histograms"][name])
-                for s in snaps
-            ])
-            for name in HISTOGRAM_NAMES
-        }
-        agg["histograms"] = {
-            name: h.snapshot() for name, h in merged.items()
-        }
-        agg["ttft_ms"] = merged["ttft_ms"].quantiles()
-        agg["tpot_ms"] = merged["tpot_ms"].quantiles()
-        agg["ttft_breakdown_ms"] = {
-            "queue_wait": merged["ttft_queue_ms"].quantiles(),
-            "prefill": merged["ttft_prefill_ms"].quantiles(),
-            "first_fetch": merged["ttft_fetch_ms"].quantiles(),
-        }
-        agg["emission"] = {
-            "burst_tokens": merged["burst_tokens"].quantiles(),
-            "burst_gap_ms": merged["burst_gap_ms"].quantiles(),
-        }
-        # SLO/goodput (SLO_METRIC_KEYS): counters and raw window sums add,
-        # then the SHARED builder recomputes every ratio (one home for the
-        # attainment/goodput math — metrics.build_slo_section — so the
-        # aggregate cannot drift from the per-engine exposition); targets
-        # are deployment-wide (same env), reported once
-        from .metrics import build_slo_section
-
-        slos = [s["slo"] for s in snaps]
-
-        def _wsum(key):
-            return {
-                f: sum(s[key][f] for s in slos)
-                for f in ("met", "missed", "goodput_tokens")
-            }
-
-        agg["slo"] = build_slo_section(
-            ttft_target_ms=slos[0]["slo_ttft_target_ms"],
-            tpot_target_ms=slos[0]["slo_tpot_target_ms"],
-            met=sum(s["slo_met_requests"] for s in slos),
-            missed=sum(s["slo_missed_requests"] for s in slos),
-            ttft_violations=sum(s["slo_ttft_violations"] for s in slos),
-            tpot_violations=sum(s["slo_tpot_violations"] for s in slos),
-            goodput_tokens=sum(s["goodput_tokens"] for s in slos),
-            generated_tokens=gen,
-            uptime_s=snaps[0]["uptime_s"],
-            window_1m=_wsum("window_1m"),
-            window_5m=_wsum("window_5m"),
-        )
-        # device utilization (UTILIZATION_METRIC_KEYS): per-kind counters
-        # sum; the MFU / HBM-BW ratios are recomputed from the summed
-        # flop/byte/busy totals against the (homogeneous) replica roofline
-        from .metrics import UTILIZATION_KINDS
-
-        utils = [s["utilization"] for s in snaps]
-        agg_util: Dict[str, Any] = {
-            "peak_tflops": utils[0]["peak_tflops"],
-            "peak_hbm_gbps": utils[0]["peak_hbm_gbps"],
-            "peak_source": utils[0]["peak_source"],
-        }
-        peak_f = (utils[0]["peak_tflops"] or 0) * 1e12
-        peak_b = (utils[0]["peak_hbm_gbps"] or 0) * 1e9
-        for kind in UTILIZATION_KINDS:
-            rows = [u[kind] for u in utils]
-            measured_s = sum(r.get("measured_busy_s", 0.0) for r in rows)
-            modeled_s = sum(r.get("modeled_busy_s", 0.0) for r in rows)
-            sec: Dict[str, Any] = {
-                "dispatches": sum(r["dispatches"] for r in rows),
-                "tokens": sum(r["tokens"] for r in rows),
-                "flops": sum(r["flops"] for r in rows),
-                "hbm_bytes": sum(r["hbm_bytes"] for r in rows),
-                "busy_s": round(sum(r["busy_s"] for r in rows), 3),
-                "mfu": 0.0, "hbm_bw_util": 0.0,
-                "mfu_1m": 0.0, "hbm_bw_util_1m": 0.0,
-                # measured dispatch timing (ISSUE 11): sums add across
-                # replicas; the skew RATIO recomputes from the sums
-                "measured_dispatches": sum(
-                    r.get("measured_dispatches", 0) for r in rows
-                ),
-                "measured_busy_s": round(measured_s, 4),
-                "modeled_busy_s": round(modeled_s, 4),
-                "model_skew": round(measured_s / modeled_s, 3)
-                if modeled_s > 0 else 0.0,
-            }
-            # aggregate busy time is SUMMED replica-seconds, so the ratio
-            # divides by replica-seconds of roofline — per-chip MFU, not
-            # fleet-total
-            if sec["busy_s"] > 0:
-                if peak_f:
-                    sec["mfu"] = round(
-                        sec["flops"] / (sec["busy_s"] * peak_f), 4
-                    )
-                if peak_b:
-                    sec["hbm_bw_util"] = round(
-                        sec["hbm_bytes"] / (sec["busy_s"] * peak_b), 4
-                    )
-            wf = sum(r["window_1m"]["flops"] for r in rows)
-            wb = sum(r["window_1m"]["hbm_bytes"] for r in rows)
-            ws = sum(r["window_1m"]["busy_s"] for r in rows)
-            if ws > 0:
-                if peak_f:
-                    sec["mfu_1m"] = round(wf / (ws * peak_f), 4)
-                if peak_b:
-                    sec["hbm_bw_util_1m"] = round(wb / (ws * peak_b), 4)
-            sec["window_1m"] = {"flops": wf, "hbm_bytes": wb,
-                                "busy_s": round(ws, 4)}
-            agg_util[kind] = sec
-        agg["utilization"] = agg_util
-        steps = sum(s["decode"]["steps"] for s in snaps)
-        busy = sum(e.metrics.decode_busy_slots for e in self._engines)
-        agg["decode"] = {
-            "steps": steps,
-            "batch_occupancy": round(busy / steps, 3) if steps else 0.0,
-        }
-        agg["engine"] = {
-            "active": sum(s["engine"]["active"] for s in snaps),
-            "waiting": sum(s["engine"]["waiting"] for s in snaps),
-            "pages_total": sum(s["engine"]["pages_total"] for s in snaps),
-            "pages_free": sum(s["engine"]["pages_free"] for s in snaps),
-            "pages_in_use": sum(s["engine"]["pages_in_use"] for s in snaps),
-            "kv_bytes_per_token": snaps[0]["engine"]["kv_bytes_per_token"],
-            "prefill_rows_dispatched": sum(
-                s["engine"]["prefill_rows_dispatched"] for s in snaps),
-            "prefill_rows_filled": sum(
-                s["engine"]["prefill_rows_filled"] for s in snaps),
-            "decode_keys_walked": sum(
-                s["engine"]["decode_keys_walked"] for s in snaps),
-            "decode_keys_window": sum(
-                s["engine"]["decode_keys_window"] for s in snaps),
-            "index_keys_scored": sum(
-                s["engine"]["index_keys_scored"] for s in snaps),
-            "index_keys_kept": sum(
-                s["engine"]["index_keys_kept"] for s in snaps),
-            "prefill_walk_trips": sum(
-                s["engine"]["prefill_walk_trips"] for s in snaps),
-            "prefill_walk_kernel_trips": sum(
-                s["engine"]["prefill_walk_kernel_trips"] for s in snaps),
-            "moe_dispatch": {
-                k: sum(s["engine"]["moe_dispatch"][k] for s in snaps)
-                for k in snaps[0]["engine"]["moe_dispatch"]
-            },
-            "fetch_depth_steps_sum": sum(
-                s["engine"]["fetch_depth_steps_sum"] for s in snaps),
-            "fetch_depth_samples": sum(
-                s["engine"]["fetch_depth_samples"] for s in snaps),
-            "fetch_blocked_s": round(sum(
-                s["engine"]["fetch_blocked_s"] for s in snaps), 6),
-            "fetch_pops": {
-                k: sum(s["engine"]["fetch_pops"][k] for s in snaps)
-                for k in snaps[0]["engine"]["fetch_pops"]
-            },
-            "decode_holds": sum(
-                s["engine"]["decode_holds"] for s in snaps),
-            "decode_hold_s": round(sum(
-                s["engine"]["decode_hold_s"] for s in snaps), 6),
-            "experts_held": sum(
-                s["engine"]["experts_held"] for s in snaps),
-            "experts_routed": sum(
-                s["engine"]["experts_routed"] for s in snaps),
-        }
-        if all("prefix_cache" in s for s in snaps):
-            agg["prefix_cache"] = {
-                k: sum(s["prefix_cache"][k] for s in snaps)
-                for k in snaps[0]["prefix_cache"]
-            }
-        # KV tier (ISSUE 9): every key is a summable counter or a gauge
-        # whose per-replica values add up (bytes/runs per replica tier)
-        tier_snaps = [s["kv_tier"] for s in snaps if "kv_tier" in s]
-        if tier_snaps:
-            agg["kv_tier"] = {
-                k: sum(t[k] for t in tier_snaps)
-                for k in tier_snaps[0]
-            }
-        # Object-store KV tier (ISSUE 14, OBJECT_TIER_METRIC_KEYS):
-        # per-owner counters sum; the store gauges describe the ONE
-        # SHARED store every replica mounts, so they report once,
-        # unsummed (summing would multiply by dp); the breaker-state
-        # gauge maxes — one replica's open breaker must stay visible in
-        # the fleet view, and 2=open dominates 1=half-open dominates 0
-        obj_snaps = [s["object_tier"] for s in snaps
-                     if "object_tier" in s]
-        if obj_snaps:
-            shared = ("store_bytes", "store_objects")
-
-            def _agg_obj(k: str) -> Any:
-                if k in shared:
-                    return obj_snaps[0][k]
-                if k == "store_breaker_state":
-                    return max(t.get(k, 0) for t in obj_snaps)
-                return sum(t[k] for t in obj_snaps)
-
-            agg["object_tier"] = {k: _agg_obj(k) for k in obj_snaps[0]}
-        # Flight recorder + anomaly detectors (ISSUE 11): counters sum;
-        # each active anomaly carries the replica it fires on so the
-        # autoscaler's "don't scale while an anomaly is active" guard can
-        # tell a sick replica from a sick fleet.
-        anoms = [s.get("anomalies") or {} for s in snaps]
-        active: List[Dict[str, Any]] = []
-        for i, a in enumerate(anoms):
-            for entry in a.get("active", []):
-                active.append({**entry, "replica": i})
-        from .flight_recorder import ANOMALY_KINDS
-
-        agg["anomalies"] = {
-            f"anomaly_{kind}": sum(
-                a.get(f"anomaly_{kind}", 0) for a in anoms
-            )
-            for kind in ANOMALY_KINDS
-        }
-        agg["anomalies"]["anomalies_active"] = len(active)
-        agg["anomalies"]["active"] = active
-        flights = [s["flight"] for s in snaps if "flight" in s]
-        if flights:
-            agg["flight"] = {
-                k: sum(f[k] for f in flights) for k in flights[0]
-            }
-        # Agent-native scheduling (ISSUE 20, AGENT_METRIC_KEYS): every
-        # key is per-replica (counters and queue/awaiting gauges alike),
-        # so the fleet view is a straight sum across replicas.
-        agents = [s["agent"] for s in snaps if "agent" in s]
-        if agents:
-            agg["agent"] = {
-                k: sum(a[k] for a in agents) for k in agents[0]
-            }
-        # Live HBM accounting (ISSUE 18, MEMORY_METRIC_KEYS): the fleet
-        # view is worst-case — the plan is per-replica, so the tightest
-        # replica bounds the fleet (max in_use/peak/skew/pressure, min
-        # limit/headroom); component attribution is identical across
-        # replicas (same plan), reported once
-        mems = [s["memory"] for s in snaps if "memory" in s]
-        if mems:
-            agg["memory"] = {
-                "source": mems[0]["source"],
-                "hbm_bytes_in_use": max(
-                    m["hbm_bytes_in_use"] for m in mems
-                ),
-                "hbm_bytes_peak": max(m["hbm_bytes_peak"] for m in mems),
-                "hbm_bytes_limit": min(
-                    m["hbm_bytes_limit"] for m in mems
-                ),
-                "hbm_headroom_bytes": min(
-                    m["hbm_headroom_bytes"] for m in mems
-                ),
-                "hbm_plan_skew": max(m["hbm_plan_skew"] for m in mems),
-                "hbm_pressure": max(m["hbm_pressure"] for m in mems),
-                "hbm_component_bytes": dict(
-                    mems[0].get("hbm_component_bytes") or {}
-                ),
-                "devices": [
-                    d for m in mems for d in m.get("devices", [])
-                ],
-            }
-        # Disaggregated prefill/decode (ISSUE 12, DISAGG_METRIC_KEYS):
-        # router-owned ship counters + the ship-latency histogram,
-        # reported once (one router per process), plus a per-pool section
-        # (role, replica ids, queue/occupancy, per-kind MFU/HBM-BW) so
-        # the autoscaler can size the pools independently.  Absent when
-        # role pools are not configured — the colocated exposition is
-        # byte-identical to before.
+        # Disaggregated prefill/decode: the router's own ship counters and
+        # ship-latency histogram (one router a process), plus a section a
+        # role pool (replica ids, queue, occupancy, per-kind MFU / HBM-BW)
+        # so the autoscaler can size the pools independently.  Absent
+        # when role pools are not configured.
         router = self._router
         if router._prefill_pool:
+            peak_f = (agg["utilization"]["peak_tflops"] or 0) * 1e12
+            peak_b = (agg["utilization"]["peak_hbm_gbps"] or 0) * 1e9
             pools: List[Dict[str, Any]] = []
             for role, idxs in (("prefill", router._prefill_pool),
                                ("decode", router._decode_pool)):
@@ -1589,23 +1278,14 @@ class _AggregateMetrics:
                 for kind in UTILIZATION_KINDS:
                     krs = [r["utilization"][kind] for r in rows
                            if "utilization" in r]
-                    fl = sum(x["flops"] for x in krs)
-                    hb = sum(x["hbm_bytes"] for x in krs)
-                    bs = sum(x["busy_s"] for x in krs)
-                    w1f = sum(x["window_1m"]["flops"] for x in krs)
-                    w1b = sum(x["window_1m"]["hbm_bytes"] for x in krs)
-                    w1s = sum(x["window_1m"]["busy_s"] for x in krs)
-                    util[kind] = {
-                        # per-chip ratios over the pool's replica-seconds
-                        "mfu": round(fl / (bs * peak_f), 4)
-                        if bs > 0 and peak_f else 0.0,
-                        "hbm_bw_util": round(hb / (bs * peak_b), 4)
-                        if bs > 0 and peak_b else 0.0,
-                        "mfu_1m": round(w1f / (w1s * peak_f), 4)
-                        if w1s > 0 and peak_f else 0.0,
-                        "hbm_bw_util_1m": round(w1b / (w1s * peak_b), 4)
-                        if w1s > 0 and peak_b else 0.0,
-                    }
+                    # per-chip ratios over the pool's replica-seconds
+                    util[kind] = utilization_ratios(
+                        sum(x["flops"] for x in krs),
+                        sum(x["hbm_bytes"] for x in krs),
+                        sum(x["busy_s"] for x in krs),
+                        [sum(x["window_1m"][f] for x in krs)
+                         for f in ("flops", "hbm_bytes", "busy_s")],
+                        peak_f, peak_b)
                 occ = [r["decode"]["batch_occupancy"] for r in rows
                        if "decode" in r]
                 pools.append({

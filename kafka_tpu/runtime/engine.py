@@ -91,6 +91,7 @@ from .kv_cache import (
     page_table_array,
 )
 from .metrics import EngineMetrics
+from .planner import grammar_table_cap_bytes
 from .prefix_cache import PrefixCache
 from .speculative import LaneSpeculator
 from .step_programs import Fsm, Lanes, StepPrograms
@@ -645,13 +646,11 @@ class _GrammarTables:
             return None
         if grammar.vocab_size != self._engine.cfg.vocab_size:
             return None
-        from ..llm.constrained import _grammar_table_cap_bytes
-
         if self._padded_bytes(
             self._total_states + grammar.num_states,
             max([grammar.num_classes] + [g.num_classes
                                          for g in self.grammars]),
-        ) > _grammar_table_cap_bytes():
+        ) > grammar_table_cap_bytes():
             return None
         self.grammars.append(grammar)
         self.offsets.append(self._total_states)

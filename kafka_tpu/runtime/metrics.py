@@ -52,7 +52,9 @@ from __future__ import annotations
 import bisect
 import dataclasses
 import math
+import operator
 import os
+import re
 import threading
 import time
 from typing import Any, Dict, List, Optional, Sequence, Tuple
@@ -61,370 +63,789 @@ TELEMETRY_ENV = "KAFKA_TPU_TELEMETRY"
 SLO_TTFT_ENV = "KAFKA_TPU_SLO_TTFT_MS"
 SLO_TPOT_ENV = "KAFKA_TPU_SLO_TPOT_MS"
 
-# Speculative-decoding metric keys (ISSUE 5).  Registry shared with the
-# Prometheus exposition layer the same way failpoints.SITES / tracing.SPANS
-# are: a static test asserts every name here appears in BOTH
-# runtime/metrics.py (this snapshot) and server/prometheus.py (the text
-# format), and that neither file invents speculation metrics outside it.
-SPECULATION_METRIC_KEYS = (
-    "speculation_proposed_tokens",
-    "speculation_accepted_tokens",
-    "speculation_rejected_tokens",
-    "speculation_verify_steps",
-    "speculation_acceptance_rate",
-    "speculation_accepted_per_step",
-)
+# ---------------------------------------------------------------------------
+# THE metric table: every scalar key of the /metrics snapshot is declared
+# here once, and its three consumers are loops over these entries — the
+# replica snapshot (EngineMetrics.snapshot: the `read` / `field` of an
+# entry), the dp merge (merge_snapshots: its `merge`) and the Prometheus
+# text (server/prometheus.py: its `family`).  A new counter is its field
+# where it is counted plus one entry here.
+# ---------------------------------------------------------------------------
 
-# Constrained-decoding metric keys (ISSUE 7).  Same registry discipline:
-# every key must appear in BOTH this snapshot and server/prometheus.py,
-# and neither file may invent constrained_* metrics outside the tuple
-# (static check in tests/test_grammar_fsm.py).
-CONSTRAINED_METRIC_KEYS = (
-    # genuine constrained choice points that awaited a device->host round
-    # trip (the host mask-fn micro-batch; ~0 in on-device grammar mode)
-    "constrained_roundtrips",
-    # over-tight mask rows (no token can satisfy the grammar here): the
-    # sampler degrades the row to unconstrained — silently, before this
-    # counter existed
-    "constrained_mask_overtight",
-    # tokens emitted by lanes advancing through the device-resident
-    # grammar FSM (zero-roundtrip constrained decoding)
-    "constrained_ondevice_tokens",
-    # grammar compiles queued/running on the background deferred-compile
-    # worker (llm/constrained.py): requests on those schemas take the
-    # host-mask path until the table lands.  A PROCESS-WIDE gauge, not a
-    # per-engine counter — the DP aggregate reports it once, unsummed.
-    "constrained_compile_pending",
-)
+COUNTER, GAUGE = "counter", "gauge"
+# rides beside a section's metrics (raw window sums, lists, strings): in
+# the JSON and merged like any entry, but not one of the section's metrics
+DETAIL = "detail"
 
-# Tiered-KV-cache metric keys (ISSUE 9, runtime/kv_tier.py snapshot()).
-# Same registry discipline as the families above: every key appears in
-# BOTH this module's snapshot section and server/prometheus.py, and
-# neither file invents kv-tier metrics outside the tuple (static check in
-# tests/test_kv_tier.py).  Gauges (host/disk occupancy) sum meaningfully
-# across DP replicas — each replica owns an independent tier.
-KV_TIER_METRIC_KEYS = (
-    "host_budget_bytes",
-    "host_bytes",
-    "host_runs",
-    "disk_bytes",
-    "disk_runs",
-    "demotions",
-    "pages_demoted",
-    "bytes_demoted",
-    "demote_failures",
-    "promotions",
-    "pages_promoted",
-    "bytes_promoted",
-    "promote_failures",
-    "host_evictions",
-    "disk_spills",
-    "disk_loads",
-)
+# how replicas combine into the dp aggregate
+SUM, MAX, MIN = "sum", "max", "min"
+FIRST = "first"      # deployment-wide: every replica reports it alike
+CONCAT = "concat"    # lists chained in replica order
+DERIVED = "derived"  # recomputed from merged counters (_DERIVE below, the
+#                      functions the replica snapshot uses too)
+# merge=None: not in the aggregate (a replica's own detail, or a section
+# that the router or the server lays over the merged snapshot)
 
-# Object-store-tier metric keys (ISSUE 14, runtime/object_tier.py
-# snapshot()).  Same registry discipline: every key appears in BOTH this
-# module's snapshot section and server/prometheus.py, and neither file
-# invents object-tier metrics outside the tuple (static check in
-# tests/test_object_tier.py).  store_bytes / store_objects are gauges of
-# the ONE SHARED store every replica mounts — the DP aggregate reports
-# them once, unsummed; everything else is per-owner and sums.
-OBJECT_TIER_METRIC_KEYS = (
-    "store_bytes",          # gauge: run-payload bytes in the shared store
-    "store_objects",        # gauge: run objects in the shared store
-    "owned_bytes",          # gauge: bytes this replica holds references on
-    "object_puts",          # counter: run payloads written
-    "object_put_failures",  # counter: torn/failed puts (archive degraded)
-    "object_bytes_put",     # counter: payload bytes written
-    "object_gets",          # counter: run payloads fetched (wake path)
-    "object_get_failures",  # counter: misses/torn fetches (wake aborted)
-    "object_bytes_got",     # counter: payload bytes fetched
-    "dedupe_hits",          # counter: puts of content already present
-                            # (cross-host prefix dedupe — reference only)
-    "wake_threads",         # counter: sleep-manifest wakes served
-    "wake_tokens",          # counter: tokens re-materialized by wakes
-    "manifests_written",    # counter: per-thread sleep manifests written
-    "objects_released",     # counter: owner references dropped (budget /
-                            # invalidation; last ref deletes the object)
-    # -- store-guard families (ISSUE 17, runtime/store_guard.py): zeros
-    #    when the store is mounted bare (unit tests) --
-    "store_retries",        # counter: store ops retried by the guard
-    "store_timeouts",       # counter: per-op deadlines exceeded
-    "store_breaker_opens",  # counter: circuit-breaker open transitions
-    "store_breaker_state",  # gauge: 0=closed 1=half-open 2=open (the DP
-                            # aggregate maxes — any open breaker shows)
-    "store_probe_neg_cached",  # counter: manifest probes served from the
-                            # negative cache while the store is unhealthy
-                            # (zero store RTT on the submit path)
-    "store_scrub_repairs",  # counter: crash-window orphans repaired by
-                            # the scrubber (ObjectTier.scrub / fsck)
-    # -- wake-prefetch families (ISSUE 19, ObjectTier + WakePrefetcher):
-    #    zeros when KAFKA_TPU_WAKE_PREFETCH_MB is unset --
-    "prefetch_hits",        # counter: staged payloads consumed by a wake
-                            # (the fetch RTT was hidden behind queueing)
-    "prefetch_wasted",      # counter: staged/fetched payloads dropped
-                            # (cancel, budget eviction, superseded)
-    "prefetch_bytes",       # counter: run payload bytes staged
-    "prefetch_inflight",    # gauge: prefetch GETs not yet resolved
-)
-
-# Disaggregated prefill/decode metric keys (ISSUE 12, the DP router's
-# DisaggMetrics).  Same registry discipline as the families above: every
-# key appears in BOTH this module's snapshot (the aggregate "disagg"
-# section) and server/prometheus.py, and neither file invents disagg_*
-# metrics outside the tuple (static check in tests/test_disagg.py).  All
-# counters are router-owned (one router per process), so the aggregate
-# reports them once, unsummed; the ship-latency histogram rides alongside
-# as "ship_ms" (rendered as kafka_tpu_disagg_ship_milliseconds).
-DISAGG_METRIC_KEYS = (
-    "disagg_shipped_runs",      # counter: page runs shipped prefill->decode
-    "disagg_shipped_pages",     # counter: pages shipped
-    "disagg_shipped_bytes",     # counter: real (unpadded) bytes shipped
-    "disagg_ship_failures",     # counter: torn/failed ships (degraded to
-                                # re-prefill on the decode replica)
-    "disagg_ship_skips",        # counter: hand-offs that skipped the ship
-                                # (no destination pages / sub-page run /
-                                # destination already warm / self-target)
-    "disagg_handoffs",          # counter: prefill-and-hand-off completions
-    "disagg_prefill_in_place",  # counter: short prompts served colocated on
-                                # the decode pool (below the min-token knob)
-    # -- ship-transport dimension (ISSUE 19): which transport moved each
-    #    shipped run.  host + device sum to disagg_shipped_runs. --
-    "disagg_ship_host_runs",    # counter: runs moved by the host-staged path
-    "disagg_ship_device_runs",  # counter: runs moved device-to-device
-                                # (zero numpy materialization)
-    "disagg_ship_staging_bytes",  # gauge: peak host bytes pinned by
-                                # host-staged ship chunks since the last
-                                # snapshot (peak-since-last, re-armed on
-                                # read; 0 under the device transport)
-)
-
-# SLO / goodput metric keys (ISSUE 10).  Same registry discipline: every
-# key appears in BOTH this snapshot's "slo" section and
-# server/prometheus.py, and neither file invents slo_*/goodput_* metrics
-# outside the tuple (static check in tests/test_prometheus.py).  Counters
-# sum across DP replicas; targets are deployment-wide gauges every replica
-# reports identically; window attainments are recomputed by the aggregate
-# from the summed window sections.
-SLO_METRIC_KEYS = (
-    "slo_ttft_target_ms",      # gauge: the configured TTFT target (0 = off)
-    "slo_tpot_target_ms",      # gauge: the configured TPOT target (0 = off)
-    "slo_met_requests",        # counter: requests that met every target
-    "slo_missed_requests",     # counter: missed (incl. timeout/error/429)
-    "slo_ttft_violations",     # counter: missed specifically on TTFT
-    "slo_tpot_violations",     # counter: missed specifically on TPOT
-    "slo_attainment",          # gauge: met / (met + missed) since boot
-    "slo_attainment_1m",       # gauge: sliding 60s window
-    "slo_attainment_5m",       # gauge: sliding 300s window
-    "goodput_tokens",          # counter: tokens from SLO-met requests
-    "goodput_tok_s",           # gauge: goodput_tokens / uptime
-    "goodput_tok_s_1m",        # gauge: sliding 60s window
-    "goodput_frac",            # gauge: goodput / raw generated tokens
-)
-
-# Device-utilization metric keys (ISSUE 10), per dispatch kind
-# (prefill / decode / verify).  Counters (flops/bytes/busy/dispatches/
-# tokens) sum across replicas; mfu / hbm_bw_util are RATIOS the aggregate
-# recomputes from the summed counters, never averaged.  The measured_*
-# keys (ISSUE 11) carry the flight recorder's fetch-maturation timing:
-# wall time each dispatch MEASURABLY took on device next to the planner's
-# modeled roofline time for the same dispatches, and model_skew =
-# measured / modeled (kafka_tpu_dispatch_model_skew) — the gauge that
-# calibrates the modeled MFU/HBM-BW figures the autoscaler reads.
-UTILIZATION_METRIC_KEYS = (
-    "dispatches",
-    "tokens",
-    "flops",
-    "hbm_bytes",
-    "busy_s",
-    "mfu",
-    "hbm_bw_util",
-    "mfu_1m",
-    "hbm_bw_util_1m",
-    "measured_dispatches",
-    "measured_busy_s",
-    "modeled_busy_s",
-    "model_skew",
-)
 UTILIZATION_KINDS = ("prefill", "decode", "verify")
 
-# Flight-recorder metric keys (ISSUE 11, runtime/flight_recorder.py).
-# Same registry discipline as the families above: every key appears in
-# BOTH this module's "flight" snapshot section and server/prometheus.py,
-# and neither file invents flight_* metrics outside the tuple (static
-# check in tests/test_flight_recorder.py).
-FLIGHT_METRIC_KEYS = (
-    "flight_ring_size",    # gauge: configured ring length (0 = off)
-    "flight_records",      # counter: scheduler iterations recorded
-    "flight_postmortems",  # counter: postmortem dumps written
+
+@dataclasses.dataclass(frozen=True)
+class Metric:
+    section: str                   # "" = the snapshot's top level
+    key: str
+    kind: str = COUNTER
+    merge: Optional[str] = SUM
+    # exposition: the Prometheus family and this key's labels in it, None
+    # = JSON only.  TYPE is "counter" for a *_total family, else "gauge";
+    # HELP stands on the family's first entry.
+    family: Optional[str] = None
+    labels: Tuple[Tuple[str, str], ...] = ()
+    help: str = ""
+    digits: Optional[int] = None   # rounding, in `read` and in a SUM merge
+    # where the replica snapshot takes the value: an attribute path or a
+    # function of the section's source object (the engine; its prefix
+    # cache; its flight recorder)
+    read: Any = None
+    field: Optional[str] = None    # the EngineMetrics field behind the key
+    # DERIVED from an EngineMetrics accumulator that is not exported raw
+    accumulator: Optional[str] = None
+    per_kind: bool = False         # one value per UTILIZATION_KINDS sub-dict
+    per_replica: bool = False      # the text also labels each replica's
+    each: Optional[str] = None     # dict-valued: one sample a item, its
+    #                                name under this label
+
+    def read_from(self, source) -> Any:
+        value = (self.read(source) if callable(self.read)
+                 else operator.attrgetter(self.read)(source))
+        if isinstance(value, dict):
+            return dict(value)
+        return value if self.digits is None else round(value, self.digits)
+
+    def samples(self, snap: Dict[str, Any]):
+        """(labels, value) for each sample this entry has in `snap`."""
+        node = snap if not self.section else snap.get(self.section)
+        if not isinstance(node, dict):
+            return
+        subs = ([(k, node[k]) for k in UTILIZATION_KINDS if k in node]
+                if self.per_kind else [(None, node)])
+        for kind, sub in subs:
+            value = sub.get(self.key)
+            if value is None:
+                continue
+            labels = {"kind": kind} if kind else {}
+            labels.update(self.labels)
+            if self.each:
+                for name, v in value.items():
+                    yield {**labels, self.each: name}, v
+            else:
+                yield labels, value
+
+
+_FAMILY_SPEC = re.compile(r"^(\w+)(?:\{(.*)\})?$")
+
+
+def _m(key: str, expo: Optional[str] = None, help: str = "",
+       kind: Optional[str] = None, merge: Optional[str] = SUM,
+       **kw) -> Dict[str, Any]:
+    """One row: `expo` is 'family' or 'family{label=value,...}'; the kind
+    defaults to the family's TYPE (COUNTER where there is none)."""
+    family, labels = None, ()
+    if expo is not None:
+        family, raw = _FAMILY_SPEC.match(expo).groups()
+        labels = tuple(tuple(p.split("=")) for p in raw.split(",")) \
+            if raw else ()
+    if kind is None:
+        kind = family_type(family) if family else COUNTER
+    return dict(key=key, kind=kind, merge=merge, family=family,
+                labels=labels, help=help, **kw)
+
+
+def _section(name: str, *rows: Dict[str, Any]) -> Tuple[Metric, ...]:
+    return tuple(Metric(section=name, **row) for row in rows)
+
+
+def family_type(family: str) -> str:
+    return COUNTER if family.endswith("_total") else GAUGE
+
+
+METRICS: Tuple[Metric, ...] = (
+    *_section(
+        "",
+        _m("uptime_s", "kafka_tpu_uptime_seconds", "Engine uptime.",
+           merge=FIRST),
+        _m("dp", "kafka_tpu_dp_replicas", "Configured DP replica count.",
+           merge=None),
+        # legacy alias of constrained.constrained_roundtrips
+        _m("constrained_roundtrips", merge=DERIVED),
+    ),
+    *_section(
+        "requests",
+        _m("submitted", "kafka_tpu_requests_total{state=submitted}",
+           "Requests by terminal state (submitted counts ingress).",
+           field="requests_submitted"),
+        _m("finished", "kafka_tpu_requests_total{state=finished}",
+           field="requests_finished"),
+        _m("cancelled", "kafka_tpu_requests_total{state=cancelled}",
+           field="requests_cancelled"),
+        _m("preempted", "kafka_tpu_requests_total{state=preempted}",
+           field="requests_preempted"),
+        _m("timeout", "kafka_tpu_requests_total{state=timeout}",
+           field="requests_timeout"),
+        _m("rejected", "kafka_tpu_requests_total{state=rejected}",
+           field="requests_rejected"),
+        _m("failed", "kafka_tpu_requests_total{state=failed}",
+           field="requests_failed"),
+        # requests over the slow-request thresholds (server/app.py adds it
+        # from the tracing counters)
+        _m("slow", "kafka_tpu_requests_total{state=slow}", merge=None),
+    ),
+    *_section(
+        "queue",
+        _m("depth", "kafka_tpu_queue_depth",
+           "Engine waiting-queue depth (last scheduler iteration).",
+           field="queue_depth"),
+        _m("peak", "kafka_tpu_queue_depth_peak",
+           "Peak waiting-queue depth since the previous snapshot (each "
+           "scrape re-arms the high-water mark).",
+           merge=MAX, field="queue_depth_peak"),
+        # depth slopes add: the dp-wide queue's growth rate
+        _m("trend_per_s", "kafka_tpu_queue_depth_trend_per_second",
+           "Queue-depth slope over the last minute (>0 = growing; an "
+           "autoscaler scale-up signal).", digits=4),
+    ),
+    *_section(
+        "tokens",
+        _m("prompt", "kafka_tpu_tokens_total{kind=prompt}",
+           "Token counters by kind.", field="prompt_tokens"),
+        _m("generated", "kafka_tpu_tokens_total{kind=generated}",
+           field="generated_tokens"),
+        # rates sum across replicas (tokens over the same wall clock);
+        # ratios do not, they are DERIVED
+        _m("generated_per_s", "kafka_tpu_tokens_generated_per_second",
+           "Decode throughput over uptime.", digits=2),
+        # tokens dispatched for a lane that had finished when the fetch
+        # matured: the cost of dispatch running ahead of drain
+        _m("fetch_pipeline_wasted",
+           "kafka_tpu_tokens_total{kind=fetch_pipeline_wasted}",
+           field="fetch_pipeline_wasted_tokens"),
+        _m("fetch_pipeline_waste_frac", kind=GAUGE, merge=DERIVED),
+    ),
+    *_section(
+        "constrained",
+        _m("constrained_roundtrips",
+           "kafka_tpu_constrained_roundtrips_total",
+           "Constrained choice points that awaited a device fetch.",
+           field="constrained_roundtrips"),
+        _m("constrained_mask_overtight",
+           "kafka_tpu_constrained_overtight_total",
+           "Over-tight constrained mask rows degraded to unconstrained "
+           "sampling.", field="constrained_mask_overtight"),
+        _m("constrained_ondevice_tokens",
+           "kafka_tpu_constrained_ondevice_tokens_total",
+           "Tokens emitted through the device-resident grammar FSM "
+           "(zero-roundtrip constrained decoding).",
+           field="constrained_ondevice_tokens"),
+        # the deferred-compile queue is one per process
+        _m("constrained_compile_pending",
+           "kafka_tpu_constrained_compile_pending",
+           "Grammar compiles queued/running on the background "
+           "deferred-compile worker (requests use the host-mask path "
+           "until their table lands).", merge=FIRST),
+    ),
+    *_section(
+        "speculation",
+        # proposed counts candidates at dispatch; accepted / rejected
+        # reconcile at drain, so all three stay monotone across rollback
+        _m("speculation_proposed_tokens",
+           "kafka_tpu_speculation_tokens_total{kind=proposed}",
+           "Speculative candidate tokens by outcome.",
+           field="speculation_proposed_tokens"),
+        _m("speculation_accepted_tokens",
+           "kafka_tpu_speculation_tokens_total{kind=accepted}",
+           field="speculation_accepted_tokens"),
+        _m("speculation_rejected_tokens",
+           "kafka_tpu_speculation_tokens_total{kind=rejected}",
+           field="speculation_rejected_tokens"),
+        _m("speculation_verify_steps",
+           "kafka_tpu_speculation_verify_steps_total",
+           "Speculative verify dispatches.",
+           field="speculation_verify_steps"),
+        _m("speculation_acceptance_rate",
+           "kafka_tpu_speculation_acceptance_rate",
+           "Accepted / (accepted + rejected) candidate tokens.",
+           merge=DERIVED),
+        _m("speculation_accepted_per_step",
+           "kafka_tpu_speculation_accepted_per_step",
+           "Mean accepted candidates per verify dispatch.",
+           merge=DERIVED),
+    ),
+    *_section(
+        "slo",
+        _m("slo_ttft_target_ms",
+           "kafka_tpu_slo_target_milliseconds{kind=ttft}",
+           "Configured SLO targets (0 = target disabled).", merge=FIRST),
+        _m("slo_tpot_target_ms",
+           "kafka_tpu_slo_target_milliseconds{kind=tpot}", merge=FIRST),
+        _m("slo_met_requests", "kafka_tpu_slo_requests_total{result=met}",
+           "Requests by SLO verdict at finalize (timeouts, engine "
+           "failures and 429 rejections count as missed; client "
+           "cancels are excluded).", field="slo_met_requests"),
+        _m("slo_missed_requests",
+           "kafka_tpu_slo_requests_total{result=missed}",
+           field="slo_missed_requests"),
+        _m("slo_ttft_violations",
+           "kafka_tpu_slo_violations_total{kind=ttft}",
+           "Missed-SLO attributions by violated target.",
+           field="slo_ttft_violations"),
+        _m("slo_tpot_violations",
+           "kafka_tpu_slo_violations_total{kind=tpot}",
+           field="slo_tpot_violations"),
+        _m("slo_attainment", "kafka_tpu_slo_attainment{window=total}",
+           "Fraction of finalized requests meeting every SLO target, "
+           "by window (1.0 when the window saw none).", merge=DERIVED),
+        _m("slo_attainment_1m", "kafka_tpu_slo_attainment{window=1m}",
+           merge=DERIVED),
+        _m("slo_attainment_5m", "kafka_tpu_slo_attainment{window=5m}",
+           merge=DERIVED),
+        _m("goodput_tokens", "kafka_tpu_goodput_tokens_total",
+           "Tokens generated by SLO-met requests.",
+           field="goodput_tokens"),
+        _m("goodput_tok_s",
+           "kafka_tpu_goodput_tokens_per_second{window=total}",
+           "Goodput rate by window (SLO-met tokens only).",
+           merge=DERIVED),
+        _m("goodput_tok_s_1m",
+           "kafka_tpu_goodput_tokens_per_second{window=1m}",
+           merge=DERIVED),
+        _m("goodput_frac", "kafka_tpu_goodput_fraction",
+           "Goodput tokens / raw generated tokens.", merge=DERIVED),
+        # raw window sums: what the aggregate adds before the windowed
+        # ratios are recomputed (an idle replica must not weigh like a
+        # loaded one)
+        _m("window_1m", kind=DETAIL),
+        _m("window_5m", kind=DETAIL),
+    ),
+    # Per dispatch kind, the planner's modeled cost over attributed busy
+    # time.  mfu / hbm_bw_util are ratios against the roofline, recomputed
+    # from merged sums (replica-seconds: per-chip, not fleet-total); the
+    # measured_* keys pair the flight recorder's fetch-maturation timing
+    # with the modeled time of the SAME dispatches.
+    *_section(
+        "utilization",
+        _m("peak_tflops", "kafka_tpu_device_peak_teraflops",
+           "Roofline peak FLOP/s per chip (datasheet or env override), "
+           "in TFLOP/s.", merge=FIRST),
+        _m("peak_hbm_gbps",
+           "kafka_tpu_device_peak_hbm_gigabytes_per_second",
+           "Roofline peak HBM bandwidth per chip, in GB/s.", merge=FIRST),
+        _m("peak_source", kind=DETAIL, merge=FIRST),
+        _m("dispatches", "kafka_tpu_dispatches_total",
+           "Device dispatches by kind.", per_kind=True),
+        _m("tokens", "kafka_tpu_dispatch_tokens_total",
+           "Tokens processed by dispatch kind.", per_kind=True),
+        _m("flops", "kafka_tpu_device_flops_total",
+           "Modeled device FLOPs by dispatch kind (planner cost model).",
+           per_kind=True),
+        _m("hbm_bytes", "kafka_tpu_device_hbm_bytes_total",
+           "Modeled HBM bytes moved by dispatch kind.", per_kind=True),
+        _m("busy_s", "kafka_tpu_dispatch_busy_seconds_total",
+           "Wall time attributed to dispatch execution by kind.",
+           per_kind=True, digits=3),
+        _m("mfu", "kafka_tpu_mfu{window=total}",
+           "Model FLOPs utilization vs the chip roofline, by dispatch "
+           "kind and window (0 when no roofline known).",
+           merge=DERIVED, per_kind=True, per_replica=True),
+        _m("hbm_bw_util",
+           "kafka_tpu_hbm_bandwidth_utilization{window=total}",
+           "HBM bandwidth utilization vs the chip roofline, by "
+           "dispatch kind and window.",
+           merge=DERIVED, per_kind=True, per_replica=True),
+        _m("mfu_1m", "kafka_tpu_mfu{window=1m}",
+           merge=DERIVED, per_kind=True, per_replica=True),
+        _m("hbm_bw_util_1m",
+           "kafka_tpu_hbm_bandwidth_utilization{window=1m}",
+           merge=DERIVED, per_kind=True, per_replica=True),
+        _m("measured_dispatches", "kafka_tpu_measured_dispatches_total",
+           "Dispatches with a measured device-time sample by kind.",
+           per_kind=True),
+        _m("measured_busy_s", "kafka_tpu_dispatch_measured_seconds_total",
+           "Measured device execution time by dispatch kind "
+           "(fetch-maturation timing).", per_kind=True, digits=4),
+        _m("modeled_busy_s", "kafka_tpu_dispatch_modeled_seconds_total",
+           "Modeled roofline execution time for the SAME measured "
+           "dispatches, by kind.", per_kind=True, digits=4),
+        _m("model_skew", "kafka_tpu_dispatch_model_skew",
+           "Measured / modeled dispatch time by kind (>1 = the device "
+           "runs slower than the cost model assumes, so the modeled "
+           "MFU/HBM-BW figures read high by this factor; 0 = no "
+           "samples yet).", merge=DERIVED, per_kind=True),
+        # the raw 60 s sums behind the *_1m ratios
+        _m("window_1m", kind=DETAIL, per_kind=True, digits=4),
+        _m("achieved_tflops", kind=DETAIL, merge=None, per_kind=True),
+        _m("achieved_gb_s", kind=DETAIL, merge=None, per_kind=True),
+    ),
+    *_section(
+        "decode",
+        _m("steps", "kafka_tpu_decode_steps_total",
+           "Decode steps dispatched (fused steps count k).",
+           field="decode_steps"),
+        # busy slots summed over steps / steps; the raw accumulator alone
+        # would mislead dashboards
+        _m("batch_occupancy", "kafka_tpu_batch_occupancy",
+           "Mean busy decode slots per step.", merge=DERIVED,
+           accumulator="decode_busy_slots"),
+    ),
+    *_section(
+        "engine",
+        _m("active", "kafka_tpu_engine_active",
+           "Requests holding a decode slot.", read="num_active"),
+        _m("waiting", "kafka_tpu_engine_waiting",
+           "Requests in the waiting queue.",
+           read=lambda e: len(e.waiting)),
+        _m("in_flight_fetches", kind=GAUGE, merge=None,
+           read=lambda e: len(e._pending)),
+        _m("pages_total", "kafka_tpu_kv_pages{state=total}",
+           "KV pool pages by state.", read="pool.num_pages"),
+        _m("pages_free", "kafka_tpu_kv_pages{state=free}",
+           read="pool.free_pages"),
+        _m("pages_in_use", "kafka_tpu_kv_pages{state=in_use}",
+           read=lambda e: e.pool.num_pages - 1 - e.pool.free_pages),
+        _m("kv_bytes_per_token", "kafka_tpu_kv_bytes_per_token",
+           "Bytes one cached token holds in the KV pool, all layers, "
+           "as allocated.", merge=FIRST, read="kv_bytes_per_token"),
+        _m("max_batch", kind=GAUGE, merge=None, read="ecfg.max_batch"),
+        _m("attention_backend", kind=DETAIL, merge=None,
+           read="cfg.attention_backend"),
+        _m("rtt_est_ms", "kafka_tpu_device_rtt_milliseconds",
+           "Estimated device-to-host fetch round trip.", merge=None,
+           read=lambda e: e._rtt_est * 1e3, digits=3),
+        _m("kv_window_dead_share", kind=GAUGE, merge=None,
+           read=lambda e: e.kv_window_dead_share(), digits=6),
+        _m("prefill_rows_dispatched", read="prefill_rows_dispatched"),
+        _m("prefill_rows_filled", read="prefill_rows_filled"),
+        _m("decode_keys_walked", read="decode_keys_walked"),
+        _m("decode_keys_window", read="decode_keys_window"),
+        _m("index_keys_scored", read="index_keys_scored"),
+        _m("index_keys_kept", read="index_keys_kept"),
+        _m("prefill_walk_trips", read="prefill_walk_trips"),
+        _m("prefill_walk_kernel_trips", read="prefill_walk_kernel_trips"),
+        _m("moe_dispatch", read="moe_dispatch"),
+        # the fetch pipeline's run-ahead (steps, summed over samples at
+        # decode dispatches), the scheduler thread's time in unlanded
+        # reads, and pops by what released them
+        _m("fetch_depth_steps_sum", read="fetch_depth_steps_sum"),
+        _m("fetch_depth_samples", read="fetch_depth_samples"),
+        _m("fetch_blocked_s", read="fetch_blocked_s", digits=6),
+        _m("fetch_pops", read="fetch_pops"),
+        _m("decode_holds", "kafka_tpu_engine_decode_holds_total",
+           "Scheduler iterations that withheld decode because more "
+           "than one program's steps were queued behind the device.",
+           read="decode_holds"),
+        _m("decode_hold_s", "kafka_tpu_engine_decode_hold_seconds_total",
+           "Seconds the scheduler waited with decode withheld.",
+           read="decode_hold_s", digits=6),
+        # the experts this engine holds and computes, of those its router
+        # chooses among (equal unless the model is one chip's share of an
+        # expert-parallel layer)
+        _m("experts_held", kind=GAUGE, read="cfg.num_experts"),
+        _m("experts_routed", kind=GAUGE, read="cfg.num_router_experts"),
+    ),
+    # The text carries each replica's radix tree beside the summed one
+    # (replica="<i>"), so a dashboard sees WHERE the trees are hot.
+    *_section(
+        "prefix_cache",
+        _m("entries", "kafka_tpu_prefix_cache_entries",
+           "Live prefix-cache entries (radix nodes; legacy name).",
+           read=len),
+        _m("nodes", "kafka_tpu_prefix_cache_nodes",
+           "Radix-tree nodes (page-aligned token runs).",
+           read=len, per_replica=True),
+        _m("cached_pages", "kafka_tpu_prefix_cache_pages",
+           "KV pages the prefix cache currently retains.",
+           read="total_pages", per_replica=True),
+        *(_m(k, "kafka_tpu_prefix_cache_total{kind=%s}" % k,
+             "Prefix-cache events by kind." if k == "hits" else "",
+             read=k, per_replica=True)
+          for k in ("hits", "misses", "tokens_reused", "cross_thread_hits",
+                    "host_tier_hits", "shipped_hits", "object_tier_hits")),
+        _m("host_nodes",
+           "kafka_tpu_prefix_cache_host_resident{kind=host_nodes}",
+           "Radix runs currently demoted to the KV tier (still "
+           "matchable; promoted back on lookup).", read="host_nodes"),
+        _m("host_pages",
+           "kafka_tpu_prefix_cache_host_resident{kind=host_pages}",
+           read="host_pages"),
+        _m("evictions", "kafka_tpu_prefix_cache_total{kind=evictions}",
+           read="evictions", per_replica=True),
+        _m("pages_evicted",
+           "kafka_tpu_prefix_cache_total{kind=pages_evicted}",
+           read="pages_evicted", per_replica=True),
+    ),
+    # runtime/kv_tier.py snapshot(): each replica owns an independent
+    # tier, so the occupancy gauges add up like the counters
+    *_section(
+        "kv_tier",
+        _m("host_budget_bytes",
+           "kafka_tpu_kv_tier_bytes{tier=host,kind=budget}",
+           "Tiered-KV occupancy and budget by tier."),
+        _m("host_bytes", "kafka_tpu_kv_tier_bytes{tier=host,kind=used}"),
+        _m("host_runs", "kafka_tpu_kv_tier_runs{tier=host}",
+           "Demoted page runs resident per tier."),
+        _m("disk_bytes", "kafka_tpu_kv_tier_bytes{tier=disk,kind=used}"),
+        _m("disk_runs", "kafka_tpu_kv_tier_runs{tier=disk}"),
+        _m("demotions", "kafka_tpu_kv_tier_total{event=demotions}",
+           "Tiered-KV events by kind."),
+        _m("pages_demoted", "kafka_tpu_kv_tier_pages_total{dir=demoted}",
+           "Pages shipped between tiers by direction."),
+        _m("bytes_demoted", "kafka_tpu_kv_tier_bytes_total{dir=demoted}",
+           "Bytes shipped between tiers by direction."),
+        _m("demote_failures",
+           "kafka_tpu_kv_tier_total{event=demote_failures}"),
+        _m("promotions", "kafka_tpu_kv_tier_total{event=promotions}"),
+        _m("pages_promoted",
+           "kafka_tpu_kv_tier_pages_total{dir=promoted}"),
+        _m("bytes_promoted",
+           "kafka_tpu_kv_tier_bytes_total{dir=promoted}"),
+        _m("promote_failures",
+           "kafka_tpu_kv_tier_total{event=promote_failures}"),
+        _m("host_evictions",
+           "kafka_tpu_kv_tier_total{event=host_evictions}"),
+        _m("disk_spills", "kafka_tpu_kv_tier_total{event=disk_spills}"),
+        _m("disk_loads", "kafka_tpu_kv_tier_total{event=disk_loads}"),
+    ),
+    # runtime/object_tier.py snapshot(), present when
+    # KAFKA_TPU_KV_OBJECT_DIR mounts the store.  store_* gauges describe
+    # the ONE store every replica mounts; the rest is per owner.  The
+    # store_guard keys are zero on a bare store, the prefetch keys while
+    # KAFKA_TPU_WAKE_PREFETCH_MB is unset.
+    *_section(
+        "object_tier",
+        _m("store_bytes", "kafka_tpu_object_tier_bytes{scope=store}",
+           "Object-store occupancy: scope=store is the SHARED store "
+           "(report once per store when aggregating scrapes); "
+           "scope=owned is this replica's references.", merge=FIRST),
+        _m("store_objects", "kafka_tpu_object_tier_objects",
+           "Run objects resident in the shared store.", merge=FIRST),
+        _m("owned_bytes", "kafka_tpu_object_tier_bytes{scope=owned}"),
+        _m("object_puts", "kafka_tpu_object_tier_puts_total",
+           "Run payloads archived into the store."),
+        _m("object_put_failures",
+           "kafka_tpu_object_tier_failures_total{op=put}",
+           "Torn/failed store operations (put = archive degraded to "
+           "plain eviction; get = wake aborted, pages freed)."),
+        _m("object_bytes_put", "kafka_tpu_object_tier_bytes_total{dir=put}",
+           "Object-store payload bytes moved by direction."),
+        _m("object_gets", "kafka_tpu_object_tier_gets_total",
+           "Run payloads fetched from the store (wakes)."),
+        _m("object_get_failures",
+           "kafka_tpu_object_tier_failures_total{op=get}"),
+        _m("object_bytes_got",
+           "kafka_tpu_object_tier_bytes_total{dir=get}"),
+        _m("dedupe_hits", "kafka_tpu_object_tier_dedupe_hits_total",
+           "Puts whose content was already present (cross-host prefix "
+           "dedupe — only a reference was added)."),
+        _m("wake_threads", "kafka_tpu_object_tier_wake_threads_total",
+           "Dormant threads re-materialized from their sleep manifests "
+           "(cache_source=\"object_tier\")."),
+        _m("wake_tokens", "kafka_tpu_object_tier_wake_tokens_total",
+           "Tokens re-materialized by sleep-manifest wakes (prompt "
+           "tokens NOT re-prefilled)."),
+        _m("manifests_written", "kafka_tpu_object_tier_manifests_total",
+           "Per-thread sleep manifests written."),
+        _m("objects_released", "kafka_tpu_object_tier_released_total",
+           "Owner references dropped (budget eviction / thread "
+           "invalidation; the last reference deletes the object)."),
+        _m("store_retries", "kafka_tpu_object_store_retries_total",
+           "Store ops retried by the guard (idempotent protocol ops, "
+           "bounded exponential backoff)."),
+        _m("store_timeouts", "kafka_tpu_object_store_timeouts_total",
+           "Store op attempts that exceeded the per-op deadline "
+           "(KAFKA_TPU_KV_OBJECT_TIMEOUT_S)."),
+        _m("store_breaker_opens",
+           "kafka_tpu_object_store_breaker_open_total",
+           "Circuit-breaker open transitions (consecutive store "
+           "failures crossed the trip threshold)."),
+        # one replica's open breaker must stay visible in the fleet view
+        _m("store_breaker_state", "kafka_tpu_object_store_breaker_state",
+           "Store circuit-breaker state: 0=closed, 1=half-open, 2=open "
+           "(ops fast-fail).", merge=MAX),
+        _m("store_probe_neg_cached",
+           "kafka_tpu_object_store_probe_neg_cached_total",
+           "Manifest probes answered from the negative cache while the "
+           "store is unhealthy (zero store RTT on the submit path)."),
+        _m("store_scrub_repairs",
+           "kafka_tpu_object_store_scrub_repairs_total",
+           "Crash-window orphans repaired by the scrubber (ref-less "
+           "objects, dangling refs, dead manifests)."),
+        _m("prefetch_hits",
+           "kafka_tpu_object_tier_prefetch_total{outcome=hit}",
+           "Wake-prefetch outcomes: hit = staged payload consumed by "
+           "admission (zero fetch RTT); wasted = staged/fetched but "
+           "dropped (cancel, budget eviction, superseded)."),
+        _m("prefetch_wasted",
+           "kafka_tpu_object_tier_prefetch_total{outcome=wasted}"),
+        _m("prefetch_bytes", "kafka_tpu_object_tier_prefetch_bytes_total",
+           "Run payload bytes staged by wake prefetch."),
+        _m("prefetch_inflight", "kafka_tpu_object_tier_prefetch_inflight",
+           "Prefetch GETs scheduled but not yet resolved."),
+    ),
+    # The flight recorder's step-cadence detectors: edge-triggered firing
+    # counts (zero forever with KAFKA_TPU_FLIGHT_RING=0).  Each active
+    # anomaly carries the replica it fires on in the aggregate, so the
+    # autoscaler can tell a sick replica from a sick fleet.
+    *_section(
+        "anomalies",
+        *(_m("anomaly_" + k, "kafka_tpu_anomalies_total{kind=%s}" % k,
+             "Scheduler anomaly detector firings by kind "
+             "(edge-triggered)." if k == "queue_stall" else "",
+             field="anomaly_" + k)
+          for k in ("queue_stall", "fetch_starvation", "mfu_collapse",
+                    "prefill_convoy", "compile_storm", "hbm_pressure")),
+        _m("anomalies_active", "kafka_tpu_anomalies_active",
+           "Anomaly detectors currently firing.", merge=DERIVED),
+        _m("active", kind=DETAIL, merge=DERIVED),
+    ),
+    # ring state only: the records are at GET /debug/flight/{replica}
+    *_section(
+        "flight",
+        _m("flight_ring_size", "kafka_tpu_flight_ring_size",
+           "Configured flight-recorder ring length (records; summed "
+           "across DP replicas).", read="size"),
+        _m("flight_records", "kafka_tpu_flight_records_total",
+           "Scheduler iterations recorded by the flight recorder.",
+           read="next_seq"),
+        _m("flight_postmortems", "kafka_tpu_flight_postmortems_total",
+           "Flight-recorder postmortem dumps written.",
+           read="postmortems"),
+    ),
+    # Engine.agent_section(): all zero unless KAFKA_TPU_AGENT_DEMOTE is
+    # set or background-class requests ran; every key is per replica
+    *_section(
+        "agent",
+        _m("agent_gaps", "kafka_tpu_agent_events_total{event=gap}",
+           "Agent tool-gap scheduling events by kind."),
+        _m("agent_gap_demotions",
+           "kafka_tpu_agent_events_total{event=demote}"),
+        _m("agent_gap_pages_demoted",
+           "kafka_tpu_agent_gap_pages_demoted_total",
+           "KV pages freed from HBM by tool-gap demotions."),
+        _m("agent_gap_bytes_demoted",
+           "kafka_tpu_agent_gap_bytes_demoted_total",
+           "KV bytes moved down-tier by tool-gap demotions."),
+        _m("agent_gap_cancelled",
+           "kafka_tpu_agent_events_total{event=cancel}"),
+        _m("agent_hint_hits",
+           "kafka_tpu_agent_events_total{event=hint_hit}"),
+        _m("agent_hint_misses",
+           "kafka_tpu_agent_events_total{event=hint_miss}"),
+        _m("agent_awaiting_threads", "kafka_tpu_agent_awaiting_threads",
+           "Threads mid-tool-gap (lingering or demoted); not load — "
+           "the autoscaler must not count them."),
+        _m("agent_awaiting_bytes", "kafka_tpu_agent_awaiting_bytes",
+           "Demoted KV bytes parked in lower tiers awaiting a tool "
+           "return."),
+        _m("bg_queue_depth", "kafka_tpu_bg_queue_depth",
+           "Background-class requests queued (admit only into idle "
+           "capacity)."),
+        _m("bg_admitted", "kafka_tpu_bg_events_total{event=admit}",
+           "Background-class scheduling events by kind."),
+        _m("bg_chunks", "kafka_tpu_bg_events_total{event=chunk}"),
+        _m("bg_yields", "kafka_tpu_bg_events_total{event=yield}"),
+    ),
+    # runtime/planner.MemoryMonitor.section(): the worst device's bytes
+    # at step cadence against the boot-time MemoryPlan.  The plan is per
+    # replica, so the tightest replica bounds the fleet.
+    *_section(
+        "memory",
+        _m("source", kind=DETAIL, merge=FIRST),
+        _m("hbm_bytes_in_use", "kafka_tpu_hbm_bytes_in_use",
+           "Live HBM bytes in use (worst device; source=plan on chips "
+           "without memory_stats).", merge=MAX),
+        _m("hbm_bytes_peak", "kafka_tpu_hbm_bytes_peak",
+           "Peak HBM bytes in use (worst device).", merge=MAX),
+        _m("hbm_bytes_limit", "kafka_tpu_hbm_bytes_limit",
+           "HBM byte limit (smallest device).", merge=MIN),
+        _m("hbm_headroom_bytes", "kafka_tpu_hbm_headroom_bytes",
+           "Measured HBM headroom: limit - in_use (size against this, "
+           "not the plan).", merge=MIN),
+        _m("hbm_plan_skew", "kafka_tpu_hbm_plan_skew",
+           "Measured bytes / MemoryPlan predicted bytes (1.0 = the "
+           "plan was right).", merge=MAX),
+        _m("hbm_pressure", "kafka_tpu_hbm_pressure",
+           "Headroom under the watermark (KAFKA_TPU_HBM_WATERMARK).",
+           merge=MAX),
+        _m("hbm_component_bytes", "kafka_tpu_hbm_component_bytes",
+           "HBM attribution by MemoryPlan line item (unattributed = "
+           "measured residual: gather staging, scratch, "
+           "fragmentation).", merge=FIRST, each="component"),
+        _m("devices", kind=DETAIL, merge=CONCAT),
+    ),
+    # The router's DisaggMetrics, present when KAFKA_TPU_DP_ROLES sets
+    # role pools: one router a process, laid over the aggregate as it is.
+    *_section(
+        "disagg",
+        _m("disagg_shipped_runs", "kafka_tpu_disagg_shipped_runs_total",
+           "Page runs shipped from prefill-pool to decode-pool "
+           "replicas.", merge=None),
+        _m("disagg_shipped_pages", "kafka_tpu_disagg_shipped_pages_total",
+           "KV pages shipped across replicas.", merge=None),
+        _m("disagg_shipped_bytes", "kafka_tpu_disagg_shipped_bytes_total",
+           "Bytes shipped across replicas (real, unpadded).", merge=None),
+        _m("disagg_ship_failures", "kafka_tpu_disagg_ship_failures_total",
+           "Torn/failed cross-replica ships (thread degraded to "
+           "re-prefill; never partial KV).", merge=None),
+        _m("disagg_ship_skips",
+           "kafka_tpu_disagg_fallback_total{kind=ship_skip}",
+           "Hand-off fallbacks by kind: prefill_in_place = short "
+           "prompts served colocated on the decode pool; ship_skip = "
+           "hand-offs completed without a copy (destination warm / no "
+           "pages / sole survivor).", merge=None),
+        _m("disagg_handoffs", "kafka_tpu_disagg_handoffs_total",
+           "Prefill-and-hand-off completions (shipped or degraded).",
+           merge=None),
+        _m("disagg_prefill_in_place",
+           "kafka_tpu_disagg_fallback_total{kind=prefill_in_place}",
+           merge=None),
+        # host + device sum to disagg_shipped_runs
+        _m("disagg_ship_host_runs",
+           "kafka_tpu_disagg_ship_runs_by_transport_total{transport=host}",
+           "Shipped runs by transport: host = staged through a numpy "
+           "copy; device = device-to-device (zero host "
+           "materialization).", merge=None),
+        _m("disagg_ship_device_runs",
+           "kafka_tpu_disagg_ship_runs_by_transport_total"
+           "{transport=device}", merge=None),
+        _m("disagg_ship_staging_bytes",
+           "kafka_tpu_disagg_ship_staging_bytes",
+           "Peak host bytes pinned by host-staged ship chunks since "
+           "the last scrape (peak-since-last, re-armed on read).",
+           merge=None),
+        _m("ship_ms", kind=DETAIL, merge=None),
+        _m("pools", kind=DETAIL, merge=None),
+    ),
+    *_section(
+        "replica_supervisor",
+        *(_m(k, "kafka_tpu_replica_supervisor_total{event=%s}" % k,
+             "Replica supervision events." if k == "quarantines" else "",
+             merge=None)
+          for k in ("quarantines", "readmits", "waiting_migrated",
+                    "affinity_resteered", "rebuilds", "replica_rebuilds")),
+        *(_m(k, kind=DETAIL, merge=None)
+          for k in ("health", "states", "consecutive_failures",
+                    "total_failures")),
+    ),
+    # engine.state_section(): the state slots of a model with a recurrent
+    # state beside its pages (models/hybrid.py); the aggregate has never
+    # carried the section
+    *_section(
+        "state",
+        _m("state_slots_total", kind=GAUGE, merge=None),
+        _m("state_slots_live", kind=GAUGE, merge=None),
+        _m("state_snapshots", kind=GAUGE, merge=None),
+        _m("state_snapshot_slots", kind=GAUGE, merge=None),
+        _m("state_restores", merge=None),
+        _m("state_tokens_matched", merge=None),
+        _m("state_tokens_skipped", merge=None),
+        _m("state_snapshots_stored", merge=None),
+        _m("state_snapshots_evicted", merge=None),
+        _m("state_bytes_per_slot", kind=GAUGE, merge=None),
+    ),
+    # Process-wide sections server/app.py lays over the snapshot: sandbox
+    # supervision, tracing, the autoscaler's control loop
+    # (runtime/autoscaler.py, when KAFKA_TPU_AUTOSCALE runs one) and the
+    # compile observatory (runtime/compile_log.py).
+    *_section(
+        "sandbox",
+        *(_m(k, "kafka_tpu_sandbox_total{event=%s}" % k,
+             "Sandbox subprocess supervision events."
+             if k == "crashes" else "", merge=None)
+          for k in ("crashes", "restarts", "crash_loops", "reaped")),
+    ),
+    *_section(
+        "tracing",
+        _m("traces", "kafka_tpu_traces_total",
+           "Traces started since boot.", merge=None),
+        _m("stitched_spans", "kafka_tpu_stitched_spans_total",
+           "Cross-process spans stitched into parent traces.",
+           merge=None),
+        _m("slow", merge=None),
+    ),
+    *_section(
+        "autoscaler",
+        *(_m(key, "kafka_tpu_autoscaler_events_total{event=%s}" % event,
+             "Autoscaler control-loop events by kind."
+             if event == "poll" else "", merge=None)
+          for key, event in (
+              ("autoscaler_polls", "poll"),
+              ("autoscaler_scale_outs", "scale_out"),
+              ("autoscaler_scale_ins", "scale_in"),
+              ("autoscaler_resize_failures", "resize_failure"),
+              ("autoscaler_degrades", "degrade"),
+              ("autoscaler_recovers", "recover"),
+              ("autoscaler_vetoes", "veto"),
+              # replicas drained to the object store before scale-in
+              ("autoscaler_drains", "drain"))),
+        _m("autoscaler_ladder_level", "kafka_tpu_autoscaler_ladder_level",
+           "Current degradation-ladder rung (0 = normal).", merge=None),
+        _m("autoscaler_dp", "kafka_tpu_autoscaler_dp",
+           "dp at the controller's last signal poll.", merge=None),
+    ),
+    *_section(
+        "compiles",
+        _m("compiles_total", merge=None),
+        _m("compile_seconds_total", "kafka_tpu_compile_seconds_total",
+           "Wall-clock seconds spent in XLA compilation.", merge=None),
+        _m("compile_storm_active", "kafka_tpu_compile_storm_active",
+           "Compile storm condition currently held (recompiles under "
+           "live traffic).", merge=None),
+        _m("compile_storms_total", "kafka_tpu_compile_storms_total",
+           "Compile storm episodes entered.", merge=None),
+        _m("by_cache", "kafka_tpu_compiles_total",
+           "XLA compilations observed, by persistent-cache disposition "
+           "and engine phase.", kind=DETAIL, merge=None, each="cache"),
+        _m("by_phase", "kafka_tpu_compiles_total", kind=DETAIL,
+           merge=None, each="phase"),
+    ),
 )
 
-# Anomaly metric keys (ISSUE 11): the flight recorder's step-cadence
-# detectors.  Counters count edge-triggered FIRINGS and sum across DP
-# replicas; anomalies_active is a gauge (currently-firing detector
-# count) the aggregate sums too.  Both-directions registry check in
-# tests/test_flight_recorder.py.
-ANOMALY_METRIC_KEYS = (
-    "anomaly_queue_stall",       # queue > 0, no dispatch for N s
-    "anomaly_fetch_starvation",  # oldest in-flight fetch stuck
-    "anomaly_mfu_collapse",      # 1m decode MFU under frac of boot MFU
-    "anomaly_prefill_convoy",    # prefill monopolizing the engine
-    "anomaly_compile_storm",     # XLA recompiling under live traffic
-    "anomaly_hbm_pressure",      # measured headroom under the watermark
-    "anomalies_active",          # gauge: detectors currently firing
-)
 
-# Autoscaler control-loop metric keys (ISSUE 13, runtime/autoscaler.py).
-# The controller is a per-process singleton above the engines, so the
-# section is reported once (server/app.py merges it into the snapshot the
-# way it merges sandbox/tracing counters) — no DP aggregation.  Counters
-# count control-loop events; ladder_level and dp are gauges.  Both-
-# directions registry check in tests/test_autoscaler.py.
-AUTOSCALER_METRIC_KEYS = (
-    "autoscaler_polls",            # counter: signal polls evaluated
-    "autoscaler_scale_outs",       # counter: scale-out resizes performed
-    "autoscaler_scale_ins",        # counter: scale-in resizes performed
-    "autoscaler_resize_failures",  # counter: resize attempts that raised
-    "autoscaler_degrades",         # counter: ladder rungs descended
-    "autoscaler_recovers",         # counter: ladder rungs climbed back
-    "autoscaler_vetoes",           # counter: actionable decisions held
-    "autoscaler_drains",           # counter: replicas drained to the
-                                   # object store before scale-in
-                                   # (drain-then-shrink, ISSUE 14)
-    "autoscaler_ladder_level",     # gauge: current degradation rung
-    "autoscaler_dp",               # gauge: dp at the last poll
-)
+def section_entries(section: str) -> List[Metric]:
+    """The table's entries for one section, in table order.  Read from
+    METRICS at each call (a few hundred rows, off the scheduler thread)."""
+    return [m for m in METRICS if m.section == section]
 
-# Compile-observatory metric keys (ISSUE 18, runtime/compile_log.py).
-# XLA compilation is process-global (dp replicas share one persistent
-# cache and one jax.monitoring stream), so the section is reported once
-# — server/app.py merges it into the snapshot the way it merges the
-# autoscaler section.  by_cache / by_phase ride along as the label
-# matrices under kafka_tpu_compiles_total{cache=,phase=}.  Both-
-# directions registry check in tests/test_device_truth.py.
-COMPILE_METRIC_KEYS = (
-    "compiles_total",          # counter: XLA compilations observed
-    "compile_seconds_total",   # counter: wall seconds spent compiling
-    "compile_storm_active",    # gauge: storm condition currently held
-    "compile_storms_total",    # counter: storm episodes entered
-)
 
-# Live-HBM metric keys (ISSUE 18, runtime/planner.MemoryMonitor).
-# Per-replica device truth: worst-device bytes at step cadence,
-# reconciled against the boot-time MemoryPlan (plan_skew = measured /
-# planned; component attribution under
-# kafka_tpu_hbm_component_bytes{component=}).  On devices without
-# memory_stats (CPU smoke) the sample is synthesized from the plan
-# itself (source="plan", skew pinned 1.0) so the full export path runs
-# everywhere.  Both-directions registry check in
-# tests/test_device_truth.py.
-MEMORY_METRIC_KEYS = (
-    "hbm_bytes_in_use",      # gauge: worst-device live bytes
-    "hbm_bytes_peak",        # gauge: worst-device peak bytes
-    "hbm_bytes_limit",       # gauge: smallest device byte limit
-    "hbm_headroom_bytes",    # gauge: limit - in_use (measured, not plan)
-    "hbm_plan_skew",         # gauge: in_use / MemoryPlan.total_bytes
-    "hbm_pressure",          # gauge: headroom under the watermark
-    "hbm_component_bytes",   # labeled: plan line items + unattributed
-)
+def _registry(section: str, per_kind: bool = False) -> Tuple[str, ...]:
+    return tuple(m.key for m in METRICS
+                 if m.section == section and m.kind != DETAIL
+                 and m.per_kind == per_kind)
 
-# Agent-native scheduling metric keys (ISSUE 20, Engine.agent_section()).
-# Same registry discipline: every key appears in BOTH this module's
-# snapshot "agent" section and server/prometheus.py, and neither file
-# invents agent metrics outside the tuple (static check in
-# tests/test_agent_sched.py).  All keys are per-replica — counters and
-# the awaiting/queue gauges alike — so the DP aggregate is a straight
-# sum.  Every key is zero when KAFKA_TPU_AGENT_DEMOTE is unset and no
-# background-class request was submitted.
-AGENT_METRIC_KEYS = (
-    "agent_gaps",              # counter: tool_calls finishes observed
-    "agent_gap_demotions",     # counter: threads whose KV was demoted
-                               # after the linger expired mid-gap
-    "agent_gap_pages_demoted",  # counter: HBM pages freed by gap demotes
-    "agent_gap_bytes_demoted",  # counter: bytes those pages carried
-    "agent_gap_cancelled",     # counter: tool returned inside the linger
-                               # window — demote skipped entirely
-    "agent_hint_hits",         # counter: expected-return hints that fired
-                               # (cancel-in-linger or wake prefetch kick)
-    "agent_hint_misses",       # counter: returns with no demoted state
-                               # (evicted meanwhile / restarted replica)
-    "agent_awaiting_threads",  # gauge: threads currently mid-tool-gap
-                               # (lingering or demoted-awaiting-return)
-    "agent_awaiting_bytes",    # gauge: demoted KV bytes parked in lower
-                               # tiers awaiting a tool return
-    "bg_queue_depth",          # gauge: background-class requests queued
-    "bg_admitted",             # counter: background requests admitted
-                               # into idle capacity
-    "bg_chunks",               # counter: background prefill chunks run
-    "bg_yields",               # counter: scheduler iterations where
-                               # background prefill yielded to
-                               # interactive work
-)
 
-# State slots of a model with a recurrent state beside its pages
-# (engine.state_section, models/hybrid.py): the /metrics section "state",
-# present only for such a model.
-STATE_METRIC_KEYS = (
-    "state_slots_total",        # gauge: lanes + trash + snapshot slots
-    "state_slots_live",         # gauge: seated lanes + standing snapshots
-    "state_snapshots",          # gauge: snapshots the prefix cache owns
-    "state_snapshot_slots",     # gauge: slots snapshots may take
-    "state_restores",           # counter: snapshots copied into a lane's slot
-    "state_tokens_matched",     # counter: tokens admissions' PAGES matched
-    "state_tokens_skipped",     # counter: tokens a snapshot let them skip
-    "state_snapshots_stored",   # counter: snapshots a radix node took
-    "state_snapshots_evicted",  # counter: dropped for want of slots
-    "state_bytes_per_slot",     # gauge: bytes one slot holds, all layers
-)
-
-# EngineMetrics export lint (ISSUE 10 satellite): every dataclass field is
-# either exported through snapshot() (listed here with the snapshot path
-# that carries it) or on the explicit exclusion list with a reason — a new
-# counter can no longer silently drop from the exposition
-# (tests/test_prometheus.py asserts both directions against
-# dataclasses.fields(EngineMetrics)).
-ENGINE_METRIC_EXPORTS = {
-    "requests_submitted": ("requests", "submitted"),
-    "requests_finished": ("requests", "finished"),
-    "requests_cancelled": ("requests", "cancelled"),
-    "requests_preempted": ("requests", "preempted"),
-    "requests_timeout": ("requests", "timeout"),
-    "requests_rejected": ("requests", "rejected"),
-    "requests_failed": ("requests", "failed"),
-    "queue_depth": ("queue", "depth"),
-    "queue_depth_peak": ("queue", "peak"),
-    "prompt_tokens": ("tokens", "prompt"),
-    "generated_tokens": ("tokens", "generated"),
-    "fetch_pipeline_wasted_tokens": ("tokens", "fetch_pipeline_wasted"),
-    "decode_steps": ("decode", "steps"),
-    "speculation_proposed_tokens": ("speculation",
-                                    "speculation_proposed_tokens"),
-    "speculation_accepted_tokens": ("speculation",
-                                    "speculation_accepted_tokens"),
-    "speculation_rejected_tokens": ("speculation",
-                                    "speculation_rejected_tokens"),
-    "speculation_verify_steps": ("speculation", "speculation_verify_steps"),
-    "constrained_roundtrips": ("constrained", "constrained_roundtrips"),
-    "constrained_mask_overtight": ("constrained",
-                                   "constrained_mask_overtight"),
-    "constrained_ondevice_tokens": ("constrained",
-                                    "constrained_ondevice_tokens"),
-    "slo_met_requests": ("slo", "slo_met_requests"),
-    "slo_missed_requests": ("slo", "slo_missed_requests"),
-    "slo_ttft_violations": ("slo", "slo_ttft_violations"),
-    "slo_tpot_violations": ("slo", "slo_tpot_violations"),
-    "goodput_tokens": ("slo", "goodput_tokens"),
-    "anomaly_queue_stall": ("anomalies", "anomaly_queue_stall"),
-    "anomaly_fetch_starvation": ("anomalies", "anomaly_fetch_starvation"),
-    "anomaly_mfu_collapse": ("anomalies", "anomaly_mfu_collapse"),
-    "anomaly_prefill_convoy": ("anomalies", "anomaly_prefill_convoy"),
-    "anomaly_compile_storm": ("anomalies", "anomaly_compile_storm"),
-    "anomaly_hbm_pressure": ("anomalies", "anomaly_hbm_pressure"),
-}
+# The sections' key sets, as views of the table (tests hold each
+# subsystem's snapshot() to its view).
+SPECULATION_METRIC_KEYS = _registry("speculation")
+CONSTRAINED_METRIC_KEYS = _registry("constrained")
+KV_TIER_METRIC_KEYS = _registry("kv_tier")
+OBJECT_TIER_METRIC_KEYS = _registry("object_tier")
+DISAGG_METRIC_KEYS = _registry("disagg")
+SLO_METRIC_KEYS = _registry("slo")
+UTILIZATION_METRIC_KEYS = _registry("utilization", per_kind=True)
+FLIGHT_METRIC_KEYS = _registry("flight")
+ANOMALY_METRIC_KEYS = _registry("anomalies")
+AUTOSCALER_METRIC_KEYS = _registry("autoscaler")
+COMPILE_METRIC_KEYS = _registry("compiles")
+MEMORY_METRIC_KEYS = _registry("memory")
+AGENT_METRIC_KEYS = _registry("agent")
+STATE_METRIC_KEYS = _registry("state")
+# every EngineMetrics field is exported under a key (field -> snapshot
+# path) or is the accumulator behind a derived one
+# (tests/test_prometheus.py holds dataclasses.fields(EngineMetrics) to the
+# two, both directions)
+ENGINE_METRIC_EXPORTS = {m.field: (m.section, m.key)
+                         for m in METRICS if m.field}
 ENGINE_METRIC_EXCLUDED = {
-    # decode_busy_slots is exported as the derived batch_occupancy ratio
-    # (busy / steps); the raw accumulator alone would mislead dashboards
-    "decode_busy_slots": "exported derived: decode.batch_occupancy",
+    m.accumulator: f"exported derived: {m.section}.{m.key}"
+    for m in METRICS if m.accumulator
 }
 
 
@@ -468,6 +889,17 @@ def slo_targets() -> Tuple[float, float]:
     if tpot is None:
         tpot = _env_float(SLO_TPOT_ENV, 0.0)
     return max(0.0, ttft), max(0.0, tpot)
+
+
+# (id(tokenizer), schema key) of the grammar compiles queued or running on
+# the background worker of llm/constrained.py, which adds and discards
+# under its own lock: one queue a process, whatever the number of engines.
+GRAMMAR_COMPILES_PENDING: set = set()
+
+
+def compile_pending() -> int:
+    """Gauge: constrained.constrained_compile_pending in /metrics."""
+    return len(GRAMMAR_COMPILES_PENDING)
 
 
 def telemetry_enabled() -> bool:
@@ -706,6 +1138,183 @@ def build_slo_section(
         "window_1m": window_1m,
         "window_5m": window_5m,
     }
+
+
+def waste_frac(generated: int, wasted: int) -> float:
+    """Share of dispatched decode tokens the fetch pipeline threw away."""
+    total = generated + wasted
+    return round(wasted / total, 4) if total else 0.0
+
+
+def speculation_rates(accepted: int, rejected: int,
+                      verify_steps: int) -> Dict[str, float]:
+    """Acceptance over drained rounds, and accepted candidates per verify
+    step (the amortization factor the weight stream gains)."""
+    drained = accepted + rejected
+    return {
+        "speculation_acceptance_rate":
+            round(accepted / drained, 4) if drained else 0.0,
+        "speculation_accepted_per_step":
+            round(accepted / verify_steps, 3) if verify_steps else 0.0,
+    }
+
+
+def batch_occupancy(busy_slots: float, steps: int) -> float:
+    return round(busy_slots / steps, 3) if steps else 0.0
+
+
+def utilization_ratios(flops: float, hbm_bytes: float, busy_s: float,
+                       window: Sequence[float], peak_flops: Optional[float],
+                       peak_hbm_bps: Optional[float]) -> Dict[str, float]:
+    """MFU / HBM-bandwidth share since boot and over the 60 s `window`
+    (flops, bytes, busy seconds); 0.0 without busy time or a roofline.
+    Busy time summed over replicas is replica-seconds, so the ratio of
+    sums is per chip."""
+
+    def ratio(amount, secs, peak):
+        return round(amount / (secs * peak), 4) if secs > 0 and peak else 0.0
+
+    wf, wb, ws = window
+    return {
+        "mfu": ratio(flops, busy_s, peak_flops),
+        "hbm_bw_util": ratio(hbm_bytes, busy_s, peak_hbm_bps),
+        "mfu_1m": ratio(wf, ws, peak_flops),
+        "hbm_bw_util_1m": ratio(wb, ws, peak_hbm_bps),
+    }
+
+
+def model_skew(measured_s: float, modeled_s: float) -> float:
+    """>1 = the device runs slower than the cost model thinks, so the
+    modeled MFU / HBM-BW figures read HIGH by this factor."""
+    return round(measured_s / modeled_s, 3) if modeled_s > 0 else 0.0
+
+
+def quantile_sections(hists: Dict[str, "StreamingHistogram"]
+                      ) -> Dict[str, Any]:
+    """The JSON's quantile sections, derived from the histograms (the
+    math PromQL's histogram_quantile does on the _bucket series)."""
+    return {
+        "ttft_ms": hists["ttft_ms"].quantiles(),
+        "ttft_breakdown_ms": {
+            "queue_wait": hists["ttft_queue_ms"].quantiles(),
+            "prefill": hists["ttft_prefill_ms"].quantiles(),
+            "first_fetch": hists["ttft_fetch_ms"].quantiles(),
+        },
+        "tpot_ms": hists["tpot_ms"].quantiles(),
+        "emission": {
+            "burst_tokens": hists["burst_tokens"].quantiles(),
+            "burst_gap_ms": hists["burst_gap_ms"].quantiles(),
+        },
+    }
+
+
+def _merged_value(m: Metric, values: List[Any]) -> Any:
+    if m.merge == FIRST:
+        return dict(values[0]) if isinstance(values[0], dict) else values[0]
+    if m.merge == MAX:
+        return max(values)
+    if m.merge == MIN:
+        return min(values)
+    if m.merge == CONCAT:
+        return [x for v in values for x in v]
+
+    def rounded(x):
+        return x if m.digits is None else round(x, m.digits)
+
+    if isinstance(values[0], dict):
+        return {k: rounded(sum(v[k] for v in values)) for k in values[0]}
+    return rounded(sum(values))
+
+
+def _merge_rows(entries: List[Metric],
+                rows: List[Dict[str, Any]]) -> Dict[str, Any]:
+    out = {}
+    for m in entries:
+        values = [r[m.key] for r in rows if m.key in r]
+        if values and m.merge not in (None, DERIVED):
+            out[m.key] = _merged_value(m, values)
+    return out
+
+
+def merge_snapshots(snaps: List[Dict[str, Any]],
+                    decode_busy_slots: Optional[float] = None
+                    ) -> Dict[str, Any]:
+    """Replica snapshots -> the dp aggregate: each table entry by its
+    `merge`, histograms bucket by bucket, then every ratio recomputed
+    from the merged counters (never averaged: an idle replica must not
+    weigh like a loaded one).  A section is merged over the replicas that
+    carry it.  `decode_busy_slots` is the one accumulator the JSON does
+    not carry (summed over the replicas' EngineMetrics); without it the
+    occupancies are weighted by their steps."""
+    out: Dict[str, Any] = {}
+    for section in dict.fromkeys(m.section for m in METRICS):
+        entries = section_entries(section)
+        rows = snaps if not section else [
+            s[section] for s in snaps if section in s]
+        merged = _merge_rows([m for m in entries if not m.per_kind], rows)
+        per_kind = [m for m in entries if m.per_kind]
+        for kind in UTILIZATION_KINDS if per_kind else ():
+            merged[kind] = _merge_rows(
+                per_kind, [r[kind] for r in rows if kind in r])
+        if not section:
+            out.update(merged)
+        elif merged:
+            out[section] = merged
+    # distributions merge exactly: same fixed bounds, bucket counts add
+    hists = {
+        name: StreamingHistogram.merged([
+            StreamingHistogram.from_snapshot(s["histograms"][name])
+            for s in snaps])
+        for name in HISTOGRAM_NAMES
+    }
+    out["histograms"] = {name: h.snapshot() for name, h in hists.items()}
+    out.update(quantile_sections(hists))
+
+    tokens, decode = out["tokens"], out["decode"]
+    tokens["fetch_pipeline_waste_frac"] = waste_frac(
+        tokens["generated"], tokens["fetch_pipeline_wasted"])
+    out["constrained_roundtrips"] = \
+        out["constrained"]["constrained_roundtrips"]
+    spec = out["speculation"]
+    spec.update(speculation_rates(spec["speculation_accepted_tokens"],
+                                  spec["speculation_rejected_tokens"],
+                                  spec["speculation_verify_steps"]))
+    if decode_busy_slots is None:
+        decode_busy_slots = sum(
+            s["decode"]["batch_occupancy"] * s["decode"]["steps"]
+            for s in snaps)
+    decode["batch_occupancy"] = batch_occupancy(decode_busy_slots,
+                                                decode["steps"])
+    slo = out["slo"]
+    out["slo"] = build_slo_section(
+        ttft_target_ms=slo["slo_ttft_target_ms"],
+        tpot_target_ms=slo["slo_tpot_target_ms"],
+        met=slo["slo_met_requests"],
+        missed=slo["slo_missed_requests"],
+        ttft_violations=slo["slo_ttft_violations"],
+        tpot_violations=slo["slo_tpot_violations"],
+        goodput_tokens=slo["goodput_tokens"],
+        generated_tokens=tokens["generated"],
+        uptime_s=out["uptime_s"],
+        window_1m=slo["window_1m"],
+        window_5m=slo["window_5m"],
+    )
+    util = out["utilization"]
+    peak_flops = (util["peak_tflops"] or 0) * 1e12
+    peak_hbm_bps = (util["peak_hbm_gbps"] or 0) * 1e9
+    for kind in UTILIZATION_KINDS:
+        sec, w = util[kind], util[kind]["window_1m"]
+        sec.update(utilization_ratios(
+            sec["flops"], sec["hbm_bytes"], sec["busy_s"],
+            (w["flops"], w["hbm_bytes"], w["busy_s"]),
+            peak_flops, peak_hbm_bps))
+        sec["model_skew"] = model_skew(sec["measured_busy_s"],
+                                       sec["modeled_busy_s"])
+    # each active anomaly carries the replica it fires on
+    active = [{**entry, "replica": i} for i, s in enumerate(snaps)
+              for entry in s["anomalies"]["active"]]
+    out["anomalies"].update(anomalies_active=len(active), active=active)
+    return out
 
 
 @dataclasses.dataclass
@@ -1243,40 +1852,25 @@ class EngineMetrics:
 
     # -- cross-thread export --------------------------------------------
 
-    def constrained_snapshot(self) -> Dict[str, int]:
-        """The constrained-decoding section (CONSTRAINED_METRIC_KEYS)."""
-        try:
-            from ..llm.constrained import compile_pending
-            pending = compile_pending()
-        except Exception:
-            pending = 0  # import-light contexts (no llm tier loaded)
+    def _own(self, section: str, **computed: Any) -> Dict[str, Any]:
+        """One section in table order: this object's fields under their
+        keys, and `computed` for the keys that are not a field."""
         return {
-            "constrained_roundtrips": self.constrained_roundtrips,
-            "constrained_mask_overtight": self.constrained_mask_overtight,
-            "constrained_ondevice_tokens": self.constrained_ondevice_tokens,
-            "constrained_compile_pending": pending,
+            m.key: getattr(self, m.field) if m.field else computed[m.key]
+            for m in section_entries(section)
+            if m.field or m.key in computed
         }
 
+    def constrained_snapshot(self) -> Dict[str, int]:
+        return self._own("constrained",
+                         constrained_compile_pending=compile_pending())
+
     def speculation_snapshot(self) -> Dict[str, object]:
-        """The speculative-decoding section (SPECULATION_METRIC_KEYS):
-        raw monotone counters plus the two derived rates dashboards want
-        (acceptance = accepted/proposed over drained rounds; accepted per
-        verify step = the amortization factor the weight-stream gains)."""
-        drained = (self.speculation_accepted_tokens
-                   + self.speculation_rejected_tokens)
-        return {
-            "speculation_proposed_tokens": self.speculation_proposed_tokens,
-            "speculation_accepted_tokens": self.speculation_accepted_tokens,
-            "speculation_rejected_tokens": self.speculation_rejected_tokens,
-            "speculation_verify_steps": self.speculation_verify_steps,
-            "speculation_acceptance_rate": round(
-                self.speculation_accepted_tokens / drained, 4
-            ) if drained else 0.0,
-            "speculation_accepted_per_step": round(
-                self.speculation_accepted_tokens
-                / self.speculation_verify_steps, 3
-            ) if self.speculation_verify_steps else 0.0,
-        }
+        """Raw monotone counters plus the two rates dashboards want."""
+        return self._own("speculation", **speculation_rates(
+            self.speculation_accepted_tokens,
+            self.speculation_rejected_tokens,
+            self.speculation_verify_steps))
 
     def slo_snapshot(self, uptime_s: Optional[float] = None
                      ) -> Dict[str, object]:
@@ -1322,43 +1916,21 @@ class EngineMetrics:
                 "flops": round(u.flops, 0),
                 "hbm_bytes": round(u.hbm_bytes, 0),
                 "busy_s": round(u.busy_s, 3),
-                "mfu": 0.0,
-                "hbm_bw_util": 0.0,
-                "mfu_1m": 0.0,
-                "hbm_bw_util_1m": 0.0,
-                # flight-recorder measured timing (ISSUE 11): wall time
-                # these dispatches measurably took vs the modeled
-                # roofline time for the same set; skew calibrates the
-                # modeled MFU/HBM-BW figures (>1 = device slower than
-                # the model thinks, the MFU estimate reads HIGH by skew)
+                **utilization_ratios(u.flops, u.hbm_bytes, u.busy_s, w,
+                                     self.peak_flops, self.peak_hbm_bps),
+                # flight-recorder measured timing: wall time these
+                # dispatches measurably took vs the modeled roofline
+                # time for the same set
                 "measured_dispatches": u.measured_dispatches,
                 "measured_busy_s": round(u.measured_s, 4),
                 "modeled_busy_s": round(u.modeled_s, 4),
-                "model_skew": round(u.measured_s / u.modeled_s, 3)
-                if u.modeled_s > 0 else 0.0,
+                "model_skew": model_skew(u.measured_s, u.modeled_s),
             }
             if u.busy_s > 0:
                 sec["achieved_tflops"] = round(u.flops / u.busy_s / 1e12, 3)
                 sec["achieved_gb_s"] = round(
                     u.hbm_bytes / u.busy_s / 1e9, 2
                 )
-                if self.peak_flops:
-                    sec["mfu"] = round(
-                        u.flops / (u.busy_s * self.peak_flops), 4
-                    )
-                if self.peak_hbm_bps:
-                    sec["hbm_bw_util"] = round(
-                        u.hbm_bytes / (u.busy_s * self.peak_hbm_bps), 4
-                    )
-            if w[2] > 0:
-                if self.peak_flops:
-                    sec["mfu_1m"] = round(
-                        w[0] / (w[2] * self.peak_flops), 4
-                    )
-                if self.peak_hbm_bps:
-                    sec["hbm_bw_util_1m"] = round(
-                        w[1] / (w[2] * self.peak_hbm_bps), 4
-                    )
             # raw 60s sums: what the DP aggregate combines before
             # recomputing the windowed ratios (never averages ratios)
             sec["window_1m"] = {
@@ -1375,16 +1947,8 @@ class EngineMetrics:
         attached, the currently-active anomaly list the autoscaler's
         "don't scale on stale math" guard reads from /admin/signals."""
         active = flight.active_anomalies() if flight is not None else []
-        return {
-            "anomaly_queue_stall": self.anomaly_queue_stall,
-            "anomaly_fetch_starvation": self.anomaly_fetch_starvation,
-            "anomaly_mfu_collapse": self.anomaly_mfu_collapse,
-            "anomaly_prefill_convoy": self.anomaly_prefill_convoy,
-            "anomaly_compile_storm": self.anomaly_compile_storm,
-            "anomaly_hbm_pressure": self.anomaly_hbm_pressure,
-            "anomalies_active": len(active),
-            "active": active,
-        }
+        return self._own("anomalies", anomalies_active=len(active),
+                         active=active)
 
     def histograms_snapshot(self) -> Dict[str, Dict[str, Any]]:
         return {
@@ -1403,47 +1967,25 @@ class EngineMetrics:
         race; worst case one burst lands in the next interval (gauge
         precision, same torn-tolerance contract as every other read)."""
         up = time.monotonic() - self._started
+        flight = getattr(engine, "flight", None)
+        # quantiles DERIVED from the streaming histograms: cumulative
+        # over the process lifetime, not a biased last-512 window
+        quantiles = quantile_sections(
+            {name: getattr(self, name) for name in HISTOGRAM_NAMES})
         snap: Dict[str, object] = {
             "uptime_s": round(up, 1),
-            "requests": {
-                "submitted": self.requests_submitted,
-                "finished": self.requests_finished,
-                "cancelled": self.requests_cancelled,
-                "preempted": self.requests_preempted,
-                "timeout": self.requests_timeout,
-                "rejected": self.requests_rejected,
-                "failed": self.requests_failed,
-            },
-            "queue": {
-                "depth": self.queue_depth,
-                "peak": self.queue_depth_peak,
-                "trend_per_s": self.queue_trend_per_s(),
-            },
-            "tokens": {
-                "prompt": self.prompt_tokens,
-                "generated": self.generated_tokens,
-                "generated_per_s": round(self.generated_tokens / up, 2)
+            "requests": self._own("requests"),
+            "queue": self._own("queue",
+                               trend_per_s=self.queue_trend_per_s()),
+            "tokens": self._own(
+                "tokens",
+                generated_per_s=round(self.generated_tokens / up, 2)
                 if up > 0 else 0.0,
-                "fetch_pipeline_wasted": self.fetch_pipeline_wasted_tokens,
-                "fetch_pipeline_waste_frac": round(
-                    self.fetch_pipeline_wasted_tokens
-                    / (self.generated_tokens
-                       + self.fetch_pipeline_wasted_tokens),
-                    4,
-                ) if (self.generated_tokens
-                      + self.fetch_pipeline_wasted_tokens) else 0.0,
-            },
-            # quantiles DERIVED from the streaming histograms (ISSUE 10):
-            # same JSON shape as before, but cumulative over the process
-            # lifetime instead of a biased last-512 window, and identical
-            # math to what PromQL's histogram_quantile computes from the
-            # exposition's _bucket series
-            "ttft_ms": self.ttft_ms.quantiles(),
-            "ttft_breakdown_ms": {
-                "queue_wait": self.ttft_queue_ms.quantiles(),
-                "prefill": self.ttft_prefill_ms.quantiles(),
-                "first_fetch": self.ttft_fetch_ms.quantiles(),
-            },
+                fetch_pipeline_waste_frac=waste_frac(
+                    self.generated_tokens,
+                    self.fetch_pipeline_wasted_tokens)),
+            "ttft_ms": quantiles["ttft_ms"],
+            "ttft_breakdown_ms": quantiles["ttft_breakdown_ms"],
             # legacy top-level key kept for dashboards; the full family
             # lives in the "constrained" section
             "constrained_roundtrips": self.constrained_roundtrips,
@@ -1451,21 +1993,11 @@ class EngineMetrics:
             "speculation": self.speculation_snapshot(),
             "slo": self.slo_snapshot(up),
             "utilization": self.utilization_snapshot(),
-            "anomalies": self.anomalies_snapshot(
-                getattr(engine, "flight", None) if engine is not None
-                else None
-            ),
-            "tpot_ms": self.tpot_ms.quantiles(),
-            "decode": {
-                "steps": self.decode_steps,
-                "batch_occupancy": round(
-                    self.decode_busy_slots / self.decode_steps, 3
-                ) if self.decode_steps else 0.0,
-            },
-            "emission": {
-                "burst_tokens": self.burst_tokens.quantiles(),
-                "burst_gap_ms": self.burst_gap_ms.quantiles(),
-            },
+            "anomalies": self.anomalies_snapshot(flight),
+            "tpot_ms": quantiles["tpot_ms"],
+            "decode": self._own("decode", batch_occupancy=batch_occupancy(
+                self.decode_busy_slots, self.decode_steps)),
+            "emission": quantiles["emission"],
             # raw bucket data: the Prometheus renderer's source for the
             # _bucket/_sum/_count families, and what the DP aggregate
             # merges (counts add; identical fixed bounds)
@@ -1476,69 +2008,16 @@ class EngineMetrics:
         if reset_peak:
             self.queue_depth_peak = self.queue_depth
         if engine is not None:
-            snap["engine"] = {
-                "active": engine.num_active,
-                "waiting": len(engine.waiting),
-                "in_flight_fetches": len(engine._pending),
-                "pages_total": engine.pool.num_pages,
-                "pages_free": engine.pool.free_pages,
-                "pages_in_use": engine.pool.num_pages - 1
-                - engine.pool.free_pages,
-                "kv_bytes_per_token": engine.kv_bytes_per_token,
-                "max_batch": engine.ecfg.max_batch,
-                "attention_backend": engine.cfg.attention_backend,
-                "rtt_est_ms": round(engine._rtt_est * 1e3, 3),
-                "kv_window_dead_share": round(
-                    engine.kv_window_dead_share(), 6),
-                "prefill_rows_dispatched": engine.prefill_rows_dispatched,
-                "prefill_rows_filled": engine.prefill_rows_filled,
-                "decode_keys_walked": engine.decode_keys_walked,
-                "decode_keys_window": engine.decode_keys_window,
-                "index_keys_scored": engine.index_keys_scored,
-                "index_keys_kept": engine.index_keys_kept,
-                "prefill_walk_trips": engine.prefill_walk_trips,
-                "prefill_walk_kernel_trips":
-                    engine.prefill_walk_kernel_trips,
-                "moe_dispatch": dict(engine.moe_dispatch),
-                # the fetch pipeline's run-ahead (steps, sum over samples
-                # at decode dispatches), the scheduler thread's time in
-                # unlanded reads, and pops by what released them
-                "fetch_depth_steps_sum": engine.fetch_depth_steps_sum,
-                "fetch_depth_samples": engine.fetch_depth_samples,
-                "fetch_blocked_s": round(engine.fetch_blocked_s, 6),
-                "fetch_pops": dict(engine.fetch_pops),
-                # the bound on that run-ahead: iterations that withheld
-                # decode because more than one program's worth of steps
-                # was queued behind the device, and the seconds so spent
-                "decode_holds": engine.decode_holds,
-                "decode_hold_s": round(engine.decode_hold_s, 6),
-                # gauges: the experts this engine holds and computes, of
-                # those its router chooses among (equal unless the model
-                # is one chip's share of an expert-parallel layer)
-                "experts_held": engine.cfg.num_experts,
-                "experts_routed": engine.cfg.num_router_experts,
-            }
-            if engine.prefix_cache is not None:
-                pc = engine.prefix_cache
-                snap["prefix_cache"] = {
-                    # radix tree shape: nodes (page-aligned token runs) and
-                    # the pages they retain ("entries" keeps the legacy
-                    # name for the node count)
-                    "entries": len(pc),
-                    "nodes": len(pc),
-                    "cached_pages": pc.total_pages,
-                    "hits": pc.hits,
-                    "misses": pc.misses,
-                    "tokens_reused": pc.tokens_reused,
-                    "cross_thread_hits": pc.cross_thread_hits,
-                    "host_tier_hits": pc.host_tier_hits,
-                    "shipped_hits": pc.shipped_hits,
-                    "object_tier_hits": pc.object_tier_hits,
-                    "host_nodes": pc.host_nodes,
-                    "host_pages": pc.host_pages,
-                    "evictions": pc.evictions,
-                    "pages_evicted": pc.pages_evicted,
-                }
+            # sections read off the engine, its prefix cache and its
+            # flight recorder (ring state only) by the entries' `read`
+            for section, source in (("engine", engine),
+                                    ("prefix_cache", engine.prefix_cache),
+                                    ("flight", flight)):
+                if source is not None:
+                    snap[section] = {
+                        m.key: m.read_from(source)
+                        for m in section_entries(section)
+                    }
             tier = getattr(engine, "kv_tier", None)
             if tier is not None:
                 # tiered KV cache (KV_TIER_METRIC_KEYS)
@@ -1546,15 +2025,6 @@ class EngineMetrics:
                 if getattr(tier, "object", None) is not None:
                     # object-store tier (OBJECT_TIER_METRIC_KEYS)
                     snap["object_tier"] = tier.object.snapshot()
-            flight = getattr(engine, "flight", None)
-            if flight is not None:
-                # flight recorder (FLIGHT_METRIC_KEYS): ring state only —
-                # the record contents live at GET /debug/flight/{replica}
-                snap["flight"] = {
-                    "flight_ring_size": flight.size,
-                    "flight_records": flight.next_seq,
-                    "flight_postmortems": flight.postmortems,
-                }
             mm = getattr(engine, "memory_monitor", None)
             if mm is not None:
                 # live HBM accounting (MEMORY_METRIC_KEYS): latest

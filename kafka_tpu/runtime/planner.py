@@ -36,6 +36,7 @@ Known HBM budgets (public datasheet numbers):
 from __future__ import annotations
 
 import dataclasses
+import os
 from typing import Dict, Optional
 
 from ..models.config import CROSS, GMU, ModelConfig
@@ -79,6 +80,31 @@ DEVICE_KINDS = {
 
 PEAK_TFLOPS_ENV = "KAFKA_TPU_PEAK_TFLOPS"
 PEAK_HBM_GBPS_ENV = "KAFKA_TPU_PEAK_HBM_GBPS"
+
+# The device bytes the on-device grammar tables may take in all (the engine's
+# _GrammarTables holds the live ones under it; llm/constrained.py refuses to
+# compile a larger artifact), charged in every MemoryPlan unless on-device
+# grammars are off.
+GRAMMAR_ONDEVICE_ENV = "KAFKA_TPU_GRAMMAR_ONDEVICE"
+GRAMMAR_TABLE_MB_ENV = "KAFKA_TPU_GRAMMAR_TABLE_MB"
+_GRAMMAR_TABLE_MB_DEFAULT = 64
+
+
+def grammar_ondevice_enabled() -> bool:
+    return os.environ.get(GRAMMAR_ONDEVICE_ENV, "1") not in (
+        "0", "false", "off"
+    )
+
+
+def grammar_table_cap_bytes() -> int:
+    try:
+        mb = float(os.environ.get(GRAMMAR_TABLE_MB_ENV, ""))
+    except ValueError:
+        mb = _GRAMMAR_TABLE_MB_DEFAULT
+    if not mb:
+        mb = _GRAMMAR_TABLE_MB_DEFAULT
+    return int(mb * (1 << 20))
+
 
 _DTYPE_BYTES = {"bfloat16": 2, "float32": 4, "float16": 2, "int8": 1}
 
@@ -450,13 +476,8 @@ def plan_memory(
         # charge the on-device constrained-decoding table reservation
         # (the compiler caps artifacts at this size; tables replicate
         # per device) unless the feature is disabled
-        from ..llm.constrained import (
-            _grammar_table_cap_bytes,
-            grammar_ondevice_enabled,
-        )
-
         grammar_table_bytes = (
-            _grammar_table_cap_bytes() if grammar_ondevice_enabled() else 0
+            grammar_table_cap_bytes() if grammar_ondevice_enabled() else 0
         )
     kv_shard = _kv_shard(cfg, tp, kv_shard)
     kv_replicated = tp > 1 and kv_shard < tp

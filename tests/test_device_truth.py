@@ -11,8 +11,8 @@ The load-bearing claims:
     boot MemoryPlan (worst-device aggregation, plan_skew, watermark
     pressure) and synthesizes plan-sourced samples on chips without
     memory_stats so CPU CI runs the same export path,
-  * COMPILE/MEMORY metric keys are both-directions registries across
-    runtime/metrics.py and server/prometheus.py,
+  * the compiles / memory sections carry their views of the metric
+    table and render,
   * GET /debug/compiles answers 404-when-off and serves the live
     payload when on; /admin/signals is version 7 with the
     compiles/memory sections,
@@ -38,7 +38,6 @@ from kafka_tpu.runtime import compile_log
 from kafka_tpu.runtime.compile_log import CompileObservatory
 from kafka_tpu.runtime.metrics import (
     COMPILE_METRIC_KEYS,
-    MEMORY_METRIC_KEYS,
     EngineMetrics,
 )
 from kafka_tpu.runtime.planner import MemoryMonitor
@@ -396,26 +395,8 @@ class TestMemoryMonitor:
 
 
 class TestDeviceTruthRegistry:
-    """COMPILE_METRIC_KEYS and MEMORY_METRIC_KEYS are both-directions
-    registries across runtime/metrics.py and server/prometheus.py
-    (same pattern as FLIGHT/ANOMALY in test_flight_recorder.py)."""
-
-    def _source(self, relpath):
-        root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-        with open(os.path.join(root, "kafka_tpu", relpath)) as f:
-            return f.read()
-
-    def test_registry_both_directions(self):
-        metrics_src = self._source("runtime/metrics.py")
-        prom_src = self._source("server/prometheus.py")
-        for key in COMPILE_METRIC_KEYS + MEMORY_METRIC_KEYS:
-            assert f'"{key}"' in metrics_src, (
-                f"{key} missing from runtime/metrics.py"
-            )
-            assert (f"kafka_tpu_{key}" in prom_src
-                    or f'"{key}"' in prom_src), (
-                f"{key} missing from server/prometheus.py"
-            )
+    """The compiles / memory sections against the metric table (the
+    both-directions check is tests/test_prometheus.py::TestMetricTable)."""
 
     def test_anomaly_kinds_cover_device_truth(self):
         from kafka_tpu.runtime.flight_recorder import ANOMALY_KINDS

@@ -21,9 +21,8 @@ The load-bearing claims:
   * quarantine escalation: after KAFKA_TPU_REPLICA_REBUILD_THRESHOLD
     trips the supervisor rebuilds the replica's engine instead of
     re-admitting it forever,
-  * DISAGG_METRIC_KEYS is a both-directions registry across
-    runtime/metrics.py and server/prometheus.py, and the disagg families
-    render as parseable exposition,
+  * the disagg section carries exactly DISAGG_METRIC_KEYS, the metric
+    table's view of it, and its families render as parseable exposition,
   * the bench disagg phase smoke-runs on CPU.
 """
 
@@ -600,24 +599,6 @@ class TestQuarantineEscalation:
 
 
 class TestDisaggMetricsRegistry:
-    def _source(self, relpath):
-        root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-        with open(os.path.join(root, relpath)) as f:
-            return f.read()
-
-    def test_registry_both_directions(self):
-        from kafka_tpu.runtime.metrics import DISAGG_METRIC_KEYS
-
-        metrics_src = self._source("kafka_tpu/runtime/metrics.py")
-        prom_src = self._source("kafka_tpu/server/prometheus.py")
-        for key in DISAGG_METRIC_KEYS:
-            assert f'"{key}"' in metrics_src, (
-                f"{key} missing from runtime/metrics.py"
-            )
-            assert f'"{key}"' in prom_src, (
-                f"{key} missing from server/prometheus.py"
-            )
-
     def test_snapshot_matches_registry_exactly(self):
         from kafka_tpu.runtime.metrics import (
             DISAGG_METRIC_KEYS,
@@ -691,5 +672,12 @@ class TestBenchSmoke:
         assert out["shipped_runs"] >= 1
         assert out["prefill_tokens_recomputed"] == 0
         assert out["ship_failures"] == 0
-        assert (out["decode_tpot_p99_ms"]["disaggregated"]
-                < out["decode_tpot_p99_ms"]["colocated"])
+        # the stall on the scheduler's clock: lane-iterations a decoding
+        # chatty lane shared with a long prompt's prefill chunk.  The
+        # wall-clock decode_tpot_p99_ms beside it is reported, not held:
+        # two ~10 ms CPU legs next to five other xdist workers ordered
+        # either way in four driver runs of six.
+        stall = out["decode_stall_steps"]
+        assert stall["disaggregated"] == 0 < stall["colocated"], stall
+        assert set(out["decode_tpot_p99_ms"]) >= {"colocated",
+                                                  "disaggregated"}

@@ -263,7 +263,11 @@ class TestReplicaSupervision:
         """Killing one replica's engine: circuit breaker trips after the
         threshold, every affected request still gets exactly one terminal
         event, zero pages leak, and NEW requests route to the survivor."""
-        dp = make_dp(model, threshold=2)
+        # a window no run outlasts: the default 0.15 s expired, under a
+        # loaded host, while the survivor was still compiling its first
+        # programs, and replica 0 was found on probation (re-admission
+        # after the window is test_probation_and_warm_readmit's subject)
+        dp = make_dp(model, threshold=2, window=60.0)
         restore = kill_replica(dp, 0)
         for i in range(4):  # spreads 2/2 across replicas
             dp.submit(GenRequest(request_id=f"r{i}", prompt_ids=[1, 2, 3],

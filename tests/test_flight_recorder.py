@@ -16,8 +16,8 @@ The load-bearing claims:
   * a failpoint-killed engine and a quarantined DP replica each leave a
     readable postmortem JSON (schema asserted, file names sanitized like
     the persisted traces) whose last records explain the failing step,
-  * FLIGHT/ANOMALY are both-directions registries across
-    runtime/metrics.py and server/prometheus.py,
+  * the anomalies section carries exactly ANOMALY_METRIC_KEYS, the
+    metric table's view of it,
   * the bench recorder-overhead A/B phase runs.
 """
 
@@ -538,36 +538,8 @@ class TestPostmortem:
 
 
 class TestFlightRegistry:
-    """ISSUE 11 satellite: FLIGHT_METRIC_KEYS and ANOMALY_METRIC_KEYS are
-    both-directions registries across runtime/metrics.py and
-    server/prometheus.py, matching the SLO/KV-tier/constrained pattern."""
-
-    def _source(self, relpath):
-        root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-        with open(os.path.join(root, "kafka_tpu", relpath)) as f:
-            return f.read()
-
-    def test_registry_both_directions(self):
-        metrics_src = self._source("runtime/metrics.py")
-        prom_src = self._source("server/prometheus.py")
-        for key in FLIGHT_METRIC_KEYS + ANOMALY_METRIC_KEYS:
-            assert f'"{key}"' in metrics_src, (
-                f"{key} missing from runtime/metrics.py"
-            )
-            assert f'"{key}"' in prom_src, (
-                f"{key} missing from server/prometheus.py"
-            )
-
-    def test_no_unregistered_flight_metrics(self):
-        """Neither file invents flight_*/anomaly_* names outside the
-        registries (the invent-proof direction)."""
-        pattern = re.compile(
-            r'"((?:flight|anomaly|anomalies)_[a-z0-9_]+)"'
-        )
-        allowed = set(FLIGHT_METRIC_KEYS) | set(ANOMALY_METRIC_KEYS)
-        for rel in ("runtime/metrics.py", "server/prometheus.py"):
-            for name in pattern.findall(self._source(rel)):
-                assert name in allowed, f"{name} in {rel} not registered"
+    """The anomalies section against the metric table's view of it (the
+    both-directions check is tests/test_prometheus.py::TestMetricTable)."""
 
     def test_anomaly_snapshot_matches_registry(self):
         snap = EngineMetrics().anomalies_snapshot()

@@ -399,45 +399,9 @@ class TestHistogramExposition:
 
 
 class TestSLORegistry:
-    """ISSUE 10 satellite: SLO_METRIC_KEYS and UTILIZATION_METRIC_KEYS
-    are both-directions registries across runtime/metrics.py and
-    server/prometheus.py, and every EngineMetrics field is either
-    exported or on the explicit exclusion list."""
-
-    def _source(self, relpath):
-        root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-        with open(os.path.join(root, "kafka_tpu", relpath)) as f:
-            return f.read()
-
-    def test_registry_both_directions(self):
-        from kafka_tpu.runtime.metrics import (
-            SLO_METRIC_KEYS,
-            UTILIZATION_METRIC_KEYS,
-        )
-
-        metrics_src = self._source("runtime/metrics.py")
-        prom_src = self._source("server/prometheus.py")
-        for key in SLO_METRIC_KEYS + UTILIZATION_METRIC_KEYS:
-            assert f'"{key}"' in metrics_src, (
-                f"{key} missing from runtime/metrics.py"
-            )
-            assert f'"{key}"' in prom_src, (
-                f"{key} missing from server/prometheus.py"
-            )
-
-    def test_no_unregistered_slo_metrics(self):
-        """Neither file invents slo_*/goodput_* names outside the
-        registry (the invent-proof direction)."""
-        from kafka_tpu.runtime.metrics import SLO_METRIC_KEYS
-
-        pattern = re.compile(r'"((?:slo|goodput)_[a-z0-9_]+)"')
-        allowed = set(SLO_METRIC_KEYS) | {
-            # request-local span attrs / config knobs, not metric keys
-            "slo_met", "slo_ttft_ms", "slo_tpot_ms",
-        }
-        for rel in ("runtime/metrics.py", "server/prometheus.py"):
-            for name in pattern.findall(self._source(rel)):
-                assert name in allowed, f"{name} in {rel} not registered"
+    """The SLO and utilization sections carry exactly their views of the
+    metric table, and every EngineMetrics field is either exported or the
+    accumulator behind a derived key."""
 
     def test_slo_snapshot_matches_registry(self):
         from kafka_tpu.runtime.metrics import SLO_METRIC_KEYS
@@ -491,6 +455,179 @@ class TestSLORegistry:
                     f"{field}: snapshot path {path} broken at {part!r}"
                 )
                 node = node[part]
+
+
+# the fifteen sections a subsystem fills, by their names in the snapshot
+# (the compile observatory's is "compiles")
+TABLE_SECTIONS = (
+    "engine", "speculation", "constrained", "slo", "utilization",
+    "anomalies", "flight", "kv_tier", "object_tier", "disagg",
+    "autoscaler", "compiles", "memory", "agent", "state",
+)
+# of those, the ones that hold scalars under keys and nothing else
+SCALAR_SECTIONS = (
+    "engine", "speculation", "constrained", "anomalies", "flight",
+    "kv_tier", "object_tier", "autoscaler", "agent", "state",
+)
+
+
+@pytest.fixture(scope="module")
+def full_snapshots():
+    """The recorded dp=2 aggregate as GET /metrics serves it, every
+    optional section present, and its replica 0 (a one-engine shape, with
+    the keys the aggregate leaves out)."""
+    import _metric_goldens as G
+
+    replicas = G.load("metrics_replicas.json")
+    served = G.served_snapshot(G.load("metrics_aggregate.json"), replicas)
+    return served, replicas[0]
+
+
+@pytest.fixture(scope="module")
+def live_engine():
+    """A tiny engine after tests/_metric_goldens.py's script of requests."""
+    import _metric_goldens as G
+
+    eng = G.tiny_engine()
+    G.run_script(eng)
+    return eng
+
+
+class TestMetricTable:
+    """runtime/metrics.METRICS is the one place a key is declared: what a
+    full snapshot carries has an entry, and what an entry exposes comes
+    out of the renderer (ISSUE 46; these two cases a section replace the
+    twelve tests that grepped metrics.py and prometheus.py for literals)."""
+
+    @pytest.mark.parametrize("section", TABLE_SECTIONS)
+    def test_every_snapshot_key_has_an_entry(self, section, full_snapshots):
+        from kafka_tpu.runtime.metrics import UTILIZATION_KINDS, METRICS
+
+        entries = [m for m in METRICS if m.section == section]
+        flat = {m.key for m in entries if not m.per_kind}
+        per_kind = {m.key for m in entries if m.per_kind}
+        seen = False
+        for snap in full_snapshots:
+            body = dict(snap.get(section) or {})
+            seen = seen or bool(body)
+            for kind in UTILIZATION_KINDS if per_kind else ():
+                extra = set(body.pop(kind)) - per_kind
+                assert not extra, (kind, extra)
+            assert set(body) <= flat, set(body) - flat
+        assert seen, f"no recorded snapshot carries {section!r}"
+
+    @pytest.mark.parametrize("section", TABLE_SECTIONS)
+    def test_every_exposed_entry_yields_its_family(self, section,
+                                                   full_snapshots):
+        from kafka_tpu.runtime.metrics import METRICS, family_type
+
+        families, samples = {}, set()
+        for snap in full_snapshots:
+            fams, rows = parse_exposition(render_prometheus(snap))
+            families.update(fams)
+            samples |= {(n, tuple(sorted(l.items()))) for n, l, _ in rows}
+        for m in METRICS:
+            if m.section != section or not m.family:
+                continue
+            assert families.get(m.family) == family_type(m.family), m
+            want = set(m.labels)
+            assert any(name == m.family and want <= set(labels)
+                       for name, labels in samples), (
+                f"{section}.{m.key}: no {m.family} sample with {m.labels}")
+
+    def test_family_type_is_the_kind_of_its_entries(self):
+        """TYPE comes from the family's name (counter = *_total); every
+        counter or gauge entry agrees with the family it sits in, and a
+        family has one HELP."""
+        from kafka_tpu.runtime.metrics import DETAIL, METRICS, family_type
+
+        helps = {}
+        for m in METRICS:
+            if not m.family:
+                continue
+            if m.kind != DETAIL:
+                assert m.kind == family_type(m.family), m
+            if m.help:
+                assert helps.setdefault(m.family, m.help) == m.help, m
+        assert set(helps) == {m.family for m in METRICS if m.family}
+
+    def test_scalar_keys_are_named_in_the_table_only(self):
+        """No key of a purely scalar section is a string literal in the
+        renderer or the router: what it is called and how it merges is the
+        table's to say."""
+        import ast
+
+        import kafka_tpu
+        from kafka_tpu.runtime.metrics import METRICS
+
+        keys = {m.key for m in METRICS if m.section in SCALAR_SECTIONS}
+        # a role pool's own count of seated requests (disagg.pools[].active)
+        keys -= {"active"}
+        root = os.path.dirname(kafka_tpu.__file__)
+        for rel in ("server/prometheus.py", "runtime/dp_router.py"):
+            with open(os.path.join(root, rel)) as f:
+                tree = ast.parse(f.read())
+            named = {n.value for n in ast.walk(tree)
+                     if isinstance(n, ast.Constant)
+                     and isinstance(n.value, str)} & keys
+            assert not named, f"{rel} names {sorted(named)}"
+
+    def test_one_entry_reaches_all_three_consumers(self, live_engine,
+                                                   monkeypatch):
+        """A counter is its field plus ONE entry: added to the table and
+        nothing else, it is in the replica snapshot, in the merge of two
+        and in the text."""
+        from kafka_tpu.runtime import metrics as M
+
+        live_engine.zz_probe = 7
+        monkeypatch.setattr(M, "METRICS", M.METRICS + (M.Metric(
+            section="engine", key="zz_probe", read="zz_probe",
+            family="kafka_tpu_zz_probe_total", labels=(("who", "me"),),
+            help="A throwaway."),))
+        snap = live_engine.metrics.snapshot(live_engine, reset_peak=False)
+        assert snap["engine"]["zz_probe"] == 7
+        merged = M.merge_snapshots([snap, snap])
+        assert merged["engine"]["zz_probe"] == 14
+        families, samples = parse_exposition(render_prometheus(merged))
+        assert families["kafka_tpu_zz_probe_total"] == "counter"
+        assert ("kafka_tpu_zz_probe_total", {"who": "me"}, 14.0) in samples
+
+
+class TestGoldens:
+    """Recorded at the parent commit 646876c by
+    scripts/record_metric_goldens.py (its docstring lists the files), from
+    the hand-written snapshot, dp merge and renderer this table replaced."""
+
+    @pytest.mark.parametrize("which", ["served", "replica"])
+    def test_text_is_the_recorded_set_of_lines(self, which, full_snapshots):
+        import _metric_goldens as G
+
+        snap = full_snapshots[0 if which == "served" else 1]
+        text = render_prometheus(snap)
+        parse_exposition(text)  # every family contiguous, typed, no dup
+        assert sorted(text.splitlines()) == sorted(
+            G.load(f"metrics_{which}.prom").splitlines())
+
+    def test_dp_aggregate_is_the_recorded_one(self):
+        """Every key, every rounding of what the parent's
+        _AggregateMetrics.snapshot gave for the two recorded replicas."""
+        import json
+
+        import _metric_goldens as G
+
+        got = G.aggregate(G.load("metrics_replicas.json"))
+        assert json.loads(json.dumps(got)) == G.load(
+            "metrics_aggregate.json")
+
+    def test_live_engine_snapshot_has_the_recorded_shape(self, live_engine):
+        """Nested key set, leaf types, and the integer counters the
+        clock does not decide."""
+        import _metric_goldens as G
+
+        snap = live_engine.metrics.snapshot(live_engine, reset_peak=False)
+        recorded = G.load("metrics_live.json")
+        assert G.shape(snap) == recorded["shape"]
+        assert G.int_leaves(snap) == recorded["ints"]
 
 
 class TestPrometheusHTTP:

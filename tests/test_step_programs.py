@@ -375,25 +375,36 @@ def test_programs_run_without_an_engine():
 ROOT = pathlib.Path(kafka_tpu.__file__).parent
 
 
+def _imports(path):
+    """Every module a file imports, at any depth (an import inside a
+    function counts), relative ones with their dots."""
+    for node in ast.walk(ast.parse(path.read_text())):
+        if isinstance(node, ast.Import):
+            yield from (a.name for a in node.names)
+        elif isinstance(node, ast.ImportFrom):
+            yield from (f"{'.' * node.level}{node.module or ''}.{a.name}"
+                        for a in node.names)
+
+
 def test_step_programs_does_not_import_the_engine():
     """One direction only: engine -> step_programs.  Every import in the
-    module, at any depth (the pipeline import is inside a function), names
-    models, ops, parallel or compile_log."""
-    tree = ast.parse((ROOT / "runtime" / "step_programs.py").read_text())
-    for node in ast.walk(tree):
-        if isinstance(node, ast.Import):
-            names = [a.name for a in node.names]
-        elif isinstance(node, ast.ImportFrom):
-            names = [f"{'.' * node.level}{node.module or ''}"
-                     f".{a.name}" for a in node.names]
-        else:
-            continue
-        for name in names:
-            assert "engine" not in name, name
-            if name.startswith("."):
-                assert re.match(
-                    r"\.\.(models|ops|parallel)\.|\.\.compile_log$", name
-                ), name
+    module names models, ops, parallel or compile_log."""
+    for name in _imports(ROOT / "runtime" / "step_programs.py"):
+        assert "engine" not in name, name
+        if name.startswith("."):
+            assert re.match(
+                r"\.\.(models|ops|parallel)\.|\.\.compile_log$", name
+            ), name
+
+
+def test_runtime_does_not_import_the_llm_tier():
+    """One direction only: llm -> runtime.  The grammar-table budget and
+    the pending-compile gauge are runtime quantities (device bytes, a
+    queue depth) that llm/constrained.py imports, not the reverse."""
+    for path in sorted((ROOT / "runtime").glob("*.py")):
+        for name in _imports(path):
+            assert not re.match(r"(\.\.|kafka_tpu\.)llm(\.|$)", name), (
+                f"{path.name} imports {name}")
 
 
 def test_the_index_plan_has_one_home():
