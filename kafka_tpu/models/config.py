@@ -12,7 +12,9 @@ sliding-window latent layers of another geometry), and hold a share of the
 routed experts (one chip's part of an expert-parallel layer).  The same
 feed-forward tree stands on grouped-query attention too (`exaone_moe`:
 K-EXAONE), whose attention adds per-head QK-norm and full-attention layers
-that do not rotate.
+that do not rotate, and under a second hybrid layout (`lfm2_moe`:
+LFM2-8B-A1B) whose layers are gated short convolutions, each with a two-row
+tail in a state slot, beside full-attention layers that alone hold rows.
 
 The reference service routed model names to remote providers by string
 heuristics (src/llm/utils.py:11-29); here a model name resolves to a local
@@ -31,24 +33,28 @@ import jax.numpy as jnp
 from .vision import VisionConfig
 
 # The kinds of layer a pattern is made of.  WINDOWED and GLOBAL are HF
-# `layer_types` and own cache rows; the other three are a hybrid decoder's
-# (`phi4flash`): a state-space mixer whose per-thread state is a fixed-size
-# slot and no rows (MAMBA), a gated memory unit with no state at all (GMU),
-# and attention that READS the last GLOBAL layer's rows and writes none
-# (CROSS).
+# `layer_types` and own cache rows; three are `phi4flash`'s hybrid decoder's:
+# a state-space mixer whose per-thread state is a fixed-size slot and no rows
+# (MAMBA), a gated memory unit with no state at all (GMU), and attention that
+# READS the last GLOBAL layer's rows and writes none (CROSS); CONV is
+# `lfm2_moe`'s gated short convolution, whose state is the last
+# conv_L_cache - 1 rows of its gated input and no rows either.
 WINDOWED = "sliding_attention"
 GLOBAL = "full_attention"
 MAMBA = "mamba"
 GMU = "gmu"
 CROSS = "cross_attention"
+CONV = "conv"
 ROW_KINDS = (WINDOWED, GLOBAL)
+# the kinds whose layers hold a recurrent state (a state slot a thread)
+STATE_KINDS = (MAMBA, CONV)
 
 
 class UnsupportedConfigError(ValueError):
     """A published config.json asks for something the program cannot honour
     (a dense MLP among routed layers, un-normalised top-k weights, an unknown
-    kind of layer or rope, a hybrid decoder whose layout is not the one
-    served).  Raised, naming the key, instead of reading it silently."""
+    kind of layer or rope, a hybrid decoder whose layout is neither of the
+    two served).  Raised, naming the key, instead of reading it silently."""
 
 
 @dataclasses.dataclass(frozen=True)
@@ -223,6 +229,16 @@ class ModelConfig:
     mamba_d_conv: int = 4
     mamba_expand: int = 2
     mamba_dt_rank: int = 0
+    # -- the second hybrid layout (`lfm2_moe`; the conv mixer in
+    # models/llama.py): `conv_L_cache` > 0 turns it on and `layer_types` then
+    # names CONV layers beside GLOBAL ones, in any order.  A CONV layer is a
+    # gated short convolution: [B | C | u] = x W_in, a causal depthwise conv
+    # of conv_L_cache taps over B * u, times C, through W_out.  Its
+    # per-thread state is the last conv_L_cache - 1 rows of B * u, in a state
+    # slot of its own shape; the rest of the decoder (RMSNorm, rotation,
+    # QK-norm, the dense lead and the routed experts) is the lead-and-routed
+    # tree's. --
+    conv_L_cache: int = 0
 
     def __post_init__(self):
         if self.moe_scoring not in ("softmax", "sigmoid"):
@@ -245,10 +261,10 @@ class ModelConfig:
             raise UnsupportedConfigError(
                 "index_topk needs index_n_heads and index_head_dim")
         if (self.qk_norm or self.unrotated_kinds) and (
-                self.is_latent or self.has_state):
+                self.is_latent or self.mamba_d_state):
             raise UnsupportedConfigError(
                 "qk_norm and unrotated_kinds are built with grouped-query "
-                "attention only (no latent attention, no hybrid decoder)")
+                "attention only (no latent attention, no Mamba decoder)")
         if set(self.unrotated_kinds) - set(ROW_KINDS):
             raise UnsupportedConfigError(
                 f"unrotated_kinds {list(self.unrotated_kinds)}: known "
@@ -273,7 +289,8 @@ class ModelConfig:
                     "mamba_d_state needs layer_types naming the mamba layers")
             return
         known = {WINDOWED, GLOBAL} | (
-            {MAMBA, GMU, CROSS} if self.mamba_d_state else set())
+            {MAMBA, GMU, CROSS} if self.mamba_d_state else set()) | (
+            {CONV} if self.conv_L_cache else set())
         bad = set(self.layer_types) - known
         if bad:
             raise UnsupportedConfigError(
@@ -281,6 +298,8 @@ class ModelConfig:
                 f"{sorted(known)}")
         if self.mamba_d_state:
             self._check_hybrid()
+        if self.conv_L_cache:
+            self._check_conv_layout()
         if len(self.layer_types) != self.num_layers:
             raise UnsupportedConfigError(
                 f"layer_types has {len(self.layer_types)} entries for "
@@ -291,10 +310,10 @@ class ModelConfig:
                 "a sliding_attention layer needs a positive sliding_window")
 
     def _check_hybrid(self) -> None:
-        """The one hybrid layout served (models/hybrid.py): n x [mamba,
-        sliding], then [mamba, full], then m x [gmu, cross]; the cross
-        layers read the one full layer's rows, the gmu layers the last
-        mamba layer's memory."""
+        """`phi4flash`'s layout (models/hybrid.py): n x [mamba, sliding],
+        then [mamba, full], then m x [gmu, cross]; the cross layers read the
+        one full layer's rows, the gmu layers the last mamba layer's
+        memory."""
         kinds = self.layer_types
         n_self = 2 * kinds.count(MAMBA)
         want = ((MAMBA, WINDOWED) * (n_self // 2 - 1) + (MAMBA, GLOBAL)
@@ -306,7 +325,7 @@ class ModelConfig:
                 f"[gmu, cross_attention]; layer_types is {list(kinds)}")
         if self.is_latent or self.is_moe or self.vision is not None:
             raise UnsupportedConfigError(
-                "a hybrid decoder is dense GQA: no latent attention, "
+                "the Mamba hybrid decoder is dense GQA: no latent attention, "
                 "experts or vision tower")
         if (self.num_heads % 2 or self.num_kv_heads % 2
                 or self.num_heads % self.num_kv_heads):
@@ -317,14 +336,50 @@ class ModelConfig:
             raise UnsupportedConfigError(
                 "mamba_dt_rank and mamba_d_conv >= 2 are needed")
 
+    def _check_conv_layout(self) -> None:
+        """The second hybrid layout (`lfm2_moe`): short convolutions and full
+        attention in whatever order `layer_types` gives, judged by what the
+        program needs of it and not against a sequence.  The mixers are
+        chosen by kind inside the lead-and-routed tree's period body
+        (models/llama.forward), so the order is free; what is needed is a
+        tail to carry (two taps or more), rows for some layer to hold (the
+        page table and the prefix cache key on pages), grouped-query
+        attention on one model-wide rope, and no second kind of state."""
+        kinds = set(self.layer_types)
+        if CONV not in kinds or kinds - {CONV, GLOBAL}:
+            raise UnsupportedConfigError(
+                "conv_L_cache is served with layer_types of conv and "
+                f"full_attention layers; layer_types is "
+                f"{list(self.layer_types)}")
+        if GLOBAL not in kinds:
+            raise UnsupportedConfigError(
+                "layer_types names no full_attention layer: the paged pool "
+                "and the prefix cache need one layer that holds rows")
+        if self.conv_L_cache < 2:
+            raise UnsupportedConfigError(
+                f"conv_L_cache = {self.conv_L_cache}: a short convolution "
+                "of two taps or more is served (its state is the "
+                "conv_L_cache - 1 rows before the pass)")
+        if self.is_latent or self.mamba_d_state or self.vision is not None:
+            raise UnsupportedConfigError(
+                "conv layers stand beside grouped-query attention: no latent "
+                "attention, Mamba layers or vision tower")
+
     @property
     def is_moe(self) -> bool:
         return self.num_experts > 0
 
     @property
     def has_state(self) -> bool:
-        """Some layers carry a recurrent per-thread state (a state slot)."""
-        return self.mamba_d_state > 0
+        """Some KIND of layer this model has carries a recurrent per-thread
+        state (a state slot)."""
+        return any(kind in STATE_KINDS for kind in self.layer_types)
+
+    @property
+    def hybrid_decoder(self) -> bool:
+        """`phi4flash`'s decoder-hybrid-decoder, a forward pass and a
+        parameter tree of its own (models/hybrid.py)."""
+        return MAMBA in self.layer_types
 
     @property
     def mamba_d_inner(self) -> int:
@@ -333,7 +388,7 @@ class ModelConfig:
     @property
     def state_layers(self) -> int:
         """Layers that hold a recurrent state."""
-        return self.layers_of(MAMBA) if self.has_state else 0
+        return sum(self.layers_of(kind) for kind in STATE_KINDS)
 
     @property
     def kv_layers(self) -> int:
@@ -347,13 +402,17 @@ class ModelConfig:
         """(leaf, shape of ONE slot of ONE layer) of the recurrent state,
         float32: THE definition of a state slot, which the allocation
         (runtime/kv_cache.make_state_arrays), the memory plan and /metrics
-        ask.  `ssm` is h transposed, [d_state, inner]: the wide axis in the
-        lanes."""
-        if not self.has_state:
-            return ()
-        di = self.mamba_d_inner
-        return (("conv", (self.mamba_d_conv - 1, di)),
-                ("ssm", (self.mamba_d_state, di)))
+        ask, of the KIND of state layer the model has.  A Mamba layer: the
+        conv tail and `ssm`, h transposed, [d_state, inner] (the wide axis in
+        the lanes).  A short convolution: its tail alone."""
+        if MAMBA in self.layer_types:
+            di = self.mamba_d_inner
+            return (("conv", (self.mamba_d_conv - 1, di)),
+                    ("ssm", (self.mamba_d_state, di)))
+        if CONV in self.layer_types:
+            # the rows of B * u before the pass: nothing accumulates
+            return (("conv", (self.conv_L_cache - 1, self.hidden_size)),)
+        return ()
 
     @property
     def state_bytes_per_slot(self) -> int:
@@ -427,7 +486,17 @@ class ModelConfig:
         homogeneous stack of `init_params`."""
         return bool(self.is_latent or self.first_k_dense
                     or self.shared_intermediate_size
-                    or self.moe_scoring != "softmax")
+                    or self.moe_scoring != "softmax"
+                    or CONV in self.layer_types)
+
+    @property
+    def kind_leaves(self) -> bool:
+        """The mixer's leaves are stacked per KIND of layer, under
+        `params["attn"][kind]` in layer order, and "layers" /
+        "dense_layers" hold the norms and the feed-forward leaves: a latent
+        model whose kinds differ, and the conv layout (a CONV layer's leaves
+        have nothing in common with an attention layer's)."""
+        return self.by_kind or CONV in self.layer_types
 
     @property
     def by_kind(self) -> bool:
@@ -472,7 +541,7 @@ class ModelConfig:
                      if self.has_indexer(kind) else ())
             return (g.kv_lora_rank, _lane_tiles(g.qk_rope_head_dim)) + index
         if kind not in ROW_KINDS:
-            return ()  # a hybrid decoder's mamba / gmu / cross layers
+            return ()  # mamba / gmu / cross / conv layers hold no rows
         return (self.num_kv_heads * self.head_dim,) * 2
 
     @property
@@ -874,14 +943,57 @@ def _hybrid_keys(hf: dict) -> dict:
     }
 
 
+def _conv_keys(hf: dict) -> dict:
+    """The keys of an `lfm2_moe` config.json (LFM2-8B-A1B: gated short
+    convolutions beside full attention, `num_dense_layers` dense layers, then
+    sigmoid-routed experts chosen with a bias) as ModelConfig fields; {} for
+    a config whose `layer_types` names no conv layer.  What the config has no
+    key for (the order of the conv's three chunks, QK-norm, the tied head) is
+    the family's modeling code's, listed as `assumed` beside the benchmark's
+    copy of the file; the renormalisation adds the sigmoid rule's one 1e-20
+    to the sum (the family's 1e-6 is a relative 5e-7 of a sum near 2: under
+    what bfloat16 or any test here reads).  What is not served is an
+    UnsupportedConfigError, by key."""
+    if CONV not in (hf.get("layer_types") or ()):
+        return {}
+    served = (
+        ("conv_bias", False, "biases on the short convolution"),
+        ("use_expert_bias", True, "experts chosen without a selection bias"),
+        ("hidden_act", "silu", "another MLP activation"),
+        ("rope_scaling", None, "scaled rotary positions"),
+        ("attention_bias", False, "attention biases"),
+    )
+    _refuse_unless(hf, served)
+    experts = int(hf.get("num_experts") or 0)
+    dense = int(hf.get("num_dense_layers", 0))
+    out = {
+        "conv_L_cache": int(hf.get("conv_L_cache", 3)),
+        "qk_norm": True,
+        "tie_word_embeddings": bool(hf.get("tie_word_embeddings", True)),
+        "rms_norm_eps": float(hf.get("norm_eps", 1e-5)),
+    }
+    if experts:
+        _refuse_unnormalised_sigmoid(hf)
+        out.update({
+            "first_k_dense": dense,
+            "dense_intermediate_size": (int(hf["intermediate_size"])
+                                        if dense else 0),
+            "moe_scoring": "sigmoid",
+            "routed_scaling_factor": float(
+                hf.get("routed_scaling_factor", 1.0)),
+        })
+    return out
+
+
 def config_from_hf_json(path: str) -> ModelConfig:
     """Build a ModelConfig from a HuggingFace config.json: Llama / Mixtral
     keys, the published keys of a patterned routed decoder (Mellum2:
     `layer_types`, `sliding_window`, `rope_parameters`, `num_experts`,
     `moe_intermediate_size`, `norm_topk_prob`, `mlp_layer_types`), those
     of a `deepseek_v3` decoder (`_latent_keys`), the same feed-forward keys
-    on grouped-query attention (`_routed_lead_keys`: `exaone_moe`) and those
-    of a `phi4flash` hybrid decoder (`_hybrid_keys`).  A key the program cannot honour is an
+    on grouped-query attention (`_routed_lead_keys`: `exaone_moe`), those of
+    a `phi4flash` hybrid decoder (`_hybrid_keys`) and those of an `lfm2_moe`
+    one (`_conv_keys`).  A key the program cannot honour is an
     UnsupportedConfigError."""
     with open(path) as f:
         hf = json.load(f)
@@ -914,8 +1026,8 @@ def config_from_hf_json(path: str) -> ModelConfig:
             "norm_topk_prob false (top-k weights of a softmax over ALL "
             "experts, not renormalised) is not served: routing here is a "
             "softmax over exactly the top-k logits")
-    hybrid = _hybrid_keys(hf)
-    pattern = {} if hybrid else _layer_pattern(hf)
+    hybrid = _hybrid_keys(hf) or _conv_keys(hf)
+    pattern = {} if "layer_types" in hybrid else _layer_pattern(hf)
     rope_theta = hf.get("rope_theta")
     if rope_theta is None:
         ropes = dict(pattern.get("rope_by_kind", ()))
@@ -951,7 +1063,8 @@ def config_from_hf_json(path: str) -> ModelConfig:
         rms_norm_eps=hybrid.pop("rms_norm_eps", None) or hf.get(
             "rms_norm_eps", 1e-5),
         max_context=hf.get("max_position_embeddings", 8192),
-        tie_word_embeddings=hf.get("tie_word_embeddings", False),
+        tie_word_embeddings=hybrid.pop(
+            "tie_word_embeddings", hf.get("tie_word_embeddings", False)),
         rope_scaling_factor=rs.get("factor"),
         rope_low_freq_factor=rs.get("low_freq_factor", 1.0),
         rope_high_freq_factor=rs.get("high_freq_factor", 4.0),

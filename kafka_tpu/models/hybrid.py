@@ -1,7 +1,11 @@
-"""A hybrid decoder (`phi4flash`, Phi-4-mini-flash-reasoning): state-space
-mixers whose per-thread state lives in a STATE SLOT beside the pages, sliding
-and full differential attention with K/V rows of their own, and a second half
-of gated memory units and cross attention that reads ONE full cache.
+"""What every decoder with a per-thread recurrent state shares (`StatePlan`,
+the slot read and write, `HybridPathError`), and ONE of the two such decoders
+whole: `phi4flash` (Phi-4-mini-flash-reasoning), state-space mixers whose
+per-thread state lives in a STATE SLOT beside the pages, sliding and full
+differential attention with K/V rows of their own, and a second half of gated
+memory units and cross attention that reads ONE full cache.  The other,
+`lfm2_moe`'s conv layout, is the lead-and-routed tree of models/llama.py with
+its mixers chosen by kind; it imports the state plan from here.
 
 Layout (`ModelConfig._check_hybrid`): n x [mamba, sliding attention], then
 [mamba, full attention], then m x [gmu, cross attention]; every layer is
@@ -59,7 +63,7 @@ NEG_INF = -1e30
 
 class HybridPathError(NotImplementedError):
     """A path that cannot carry a recurrent state (or has no differential
-    form) was reached by a hybrid decoder.  The engine refuses such options by
+    form) was reached by a decoder with a state.  The engine refuses such options by
     name when it is built (runtime/engine.py RecurrentStateUnsupported); this
     is the backstop for direct callers of `forward`."""
 
@@ -430,7 +434,7 @@ def _at(stacked, i):
 
 
 def _read_state(leaf, layer, plan: StatePlan, batch: int):
-    """Lanes' incoming state of Mamba layer `layer`, [B, ...] float32, read
+    """Lanes' incoming state of state layer `layer`, [B, ...] float32, read
     where it lies in the stacked leaf [n, n_slots, ...] (no layer's slots are
     sliced out: the leaf is the layer scan's carry)."""
     if plan.src is None:
@@ -445,7 +449,7 @@ def _read_state(leaf, layer, plan: StatePlan, batch: int):
 
 
 def _write_state(leaf, layer, plan: StatePlan, new, old):
-    """`leaf` with the lanes' outgoing state of Mamba layer `layer` written
+    """`leaf` with the lanes' outgoing state of state layer `layer` written
     (an inactive lane writes back what it read)."""
     new = jnp.where((plan.lens > 0)[:, None, None], new, old).astype(leaf.dtype)
     if plan.dst is None:

@@ -3,7 +3,10 @@ routed MLP, layer patterns of windowed and global attention (Mellum2), and
 latent attention behind leading dense layers with sigmoid-routed and shared
 experts (HF `deepseek_v3`; "Latent attention" below), and that same
 lead-and-routed tree on grouped-query attention with QK-norm and kinds of
-layer that do not rotate (`exaone_moe`: K-EXAONE).
+layer that do not rotate (`exaone_moe`: K-EXAONE), and with gated short
+convolutions for mixers in most layers, each carrying a two-row tail in a
+state slot beside the pages (`lfm2_moe`: LFM2-8B-A1B; "The conv layout"
+below).
 
 Design (TPU-first, not a port — the reference has no model code at all; its
 LLM compute lived behind a remote gateway, src/llm/portkey.py):
@@ -52,6 +55,18 @@ Two cache forms go through the same layer math: the *contiguous*
   routed `params["layers"]`; both index the one stacked pool by absolute
   layer.
 
+* **The conv layout** — `cfg.conv_L_cache`.  The period body picks each
+  layer's MIXER by its kind: attention, or the gated short convolution
+  (`_short_conv_block`).  Mixer leaves are stacked per kind under
+  `params["attn"][kind]` (`cfg.kind_leaves`); norms and feed-forward leaves
+  stay in "dense_layers" / "layers".  Only the attention layers hold rows:
+  the paged pool is [attention layers, SLOTS, Hkv*D], and the v pool is a
+  dict {"v": rows, "conv": [conv layers, n_slots, L - 1, H] float32}, the
+  state slots riding in its pytree as `phi4flash`'s do, addressed by the
+  same `PagedView.state` plan through the same slot read and write
+  (models/hybrid.py).  A paged prefill returns its lanes' last real rows
+  only, logits [B, 1, V], as every model with a state does.
+
 **The stacked cache is scan CARRY, never a scanned input.**  The layer scan
 runs over (layer params, layer index); the caches of all layers travel
 through it whole and a layer addresses its part by index.  The paged pool is
@@ -74,7 +89,7 @@ from typing import Any, Dict, NamedTuple, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from ..models.config import GLOBAL, ModelConfig, UnsupportedConfigError
+from ..models.config import CONV, GLOBAL, ModelConfig, UnsupportedConfigError
 from ..ops.attention import NEG_INF, causal_attention, paged_decode_walk
 from ..ops.norms import rms_norm
 from ..ops.rope import (
@@ -83,6 +98,7 @@ from ..ops.rope import (
     rope_cos_sin,
     rope_frequencies,
 )
+from .hybrid import HybridPathError, StatePlan, _read_state, _write_state
 from .quant import QTensor, dequantize, quantize_array
 
 Params = Dict[str, Any]
@@ -242,10 +258,9 @@ def _layer_view(paged: PagedView, layer, slots: int) -> PagedView:
 def init_kv_cache(cfg: ModelConfig, batch: int, capacity: int, dtype=None) -> KVCache:
     dtype = dtype or cfg.activation_dtype
     if cfg.has_state:
-        from .hybrid import HybridPathError
-
         raise HybridPathError(
-            "a hybrid decoder has no contiguous cache (models/hybrid.py)")
+            "a decoder with a recurrent state has no contiguous cache: rows "
+            "go through the paged pool, the state through its slots")
     if cfg.by_kind:
         # per kind of layer, as the paged pool (runtime/kv_cache.py)
         def rows(n, width):
@@ -273,7 +288,7 @@ def init_params(cfg: ModelConfig, key: jax.Array, dtype=None) -> Params:
     """Random-init parameters (layer-stacked). Serving loads checkpoints
     instead; random init exists for tests and micro-benchmarks."""
     dtype = dtype or cfg.activation_dtype
-    if cfg.has_state:
+    if cfg.hybrid_decoder:
         from .hybrid import init_params as init_hybrid_params
 
         return init_hybrid_params(cfg, key, dtype)
@@ -332,7 +347,16 @@ def _init_lead_tree_params(cfg: ModelConfig, key: jax.Array, dtype) -> Params:
     weighs by sigma + b, or chooses by sigma, passes every check; the latent
     norm's and the q / k norms' weights are 1 + N(0, 0.2^2) for the same
     reason.  The latent model's random stream is what it was before the
-    grouped-query block came to this tree."""
+    grouped-query block came to this tree.
+
+    The conv layout (`cfg.conv_L_cache`: LFM2) keeps "dense_layers" and
+    "layers" for the norms and the feed-forward leaves and stacks each
+    KIND's mixer under `params["attn"][kind]` in layer order
+    (`cfg.kind_leaves`): the grouped-query block above for the attention
+    layers, and for a conv layer W_in [H, 3H] (chunks B | C | u), the taps
+    [L, H] (tap L - 1 multiplies the row's own product; N(0, 1 / L), so the
+    taps that read the tail weigh as much as the one that does not and a
+    check on the logits sees a lost tail) and W_out [H, H]."""
     h, hq = cfg.hidden_size, cfg.num_heads
 
     @partial(jax.jit, static_argnums=(1, 2))
@@ -369,11 +393,11 @@ def _init_lead_tree_params(cfg: ModelConfig, key: jax.Array, dtype) -> Params:
             "wo": norm01(ks[3], (n, hq, dv, h), hq * dv),
         }
 
-    def gqa_attention(k, n):
+    def gqa_attention(k, n, with_norms=True):
         hkv, d = cfg.num_kv_heads, cfg.head_dim
         ks = jax.random.split(k, 6)
         out = {
-            **norms(n),
+            **(norms(n) if with_norms else {}),
             "wq": norm01(ks[0], (n, h, hq, d), h),
             "wk": norm01(ks[1], (n, h, hkv, d), h),
             "wv": norm01(ks[2], (n, h, hkv, d), h),
@@ -384,7 +408,20 @@ def _init_lead_tree_params(cfg: ModelConfig, key: jax.Array, dtype) -> Params:
             out["ln_k"] = spread(ks[5], (n, d))
         return out
 
+    def conv_mixer(k, n):
+        taps = cfg.conv_L_cache
+        ks = jax.random.split(k, 3)
+        return {"w_in": norm01(ks[0], (n, h, 3 * h), h),
+                "conv_w": norm01(ks[1], (n, taps, h), taps),
+                "w_out": norm01(ks[2], (n, h, h), h)}
+
     attention = latent_attention if cfg.is_latent else gqa_attention
+    # the conv layout: a mixer a KIND, and the two stacks keep the norms
+    mixers = {CONV: conv_mixer,
+              GLOBAL: partial(gqa_attention, with_norms=False)}
+    if CONV in cfg.layer_types:
+        def attention(k, n):
+            return norms(n)
 
     def mlp(k, n, f, names=("wg", "wu", "wd")):
         ks = jax.random.split(k, 3)
@@ -416,6 +453,11 @@ def _init_lead_tree_params(cfg: ModelConfig, key: jax.Array, dtype) -> Params:
         "final_norm": jnp.ones((h,), dtype),
         "layers": layers,
     }
+    if CONV in cfg.layer_types:
+        params["attn"] = {
+            kind: mixers[kind](jax.random.fold_in(keys[1], i),
+                               cfg.layers_of(kind))
+            for i, kind in enumerate(cfg.kinds)}
     if n_dense:
         kd = jax.random.split(keys[8], 2)
         params["dense_layers"] = {
@@ -597,6 +639,53 @@ def _attention_block(
     with jax.named_scope("attn_out"):
         out = jnp.einsum("bsnd,ndh->bsh", out, _w(lp, "wo", out.dtype))
     return out, k_cache, v_cache
+
+
+def _short_conv_block(x: jnp.ndarray, lp: Params, leaf, layer,
+                      plan: StatePlan):
+    """One gated short convolution (`lfm2_moe`'s conv mixer).  x: [B, S, H].
+    [B | C | u] = x W_in; z = B * u; c_t = sum_j w_j * z_(t - L + 1 + j)
+    over the L taps (depthwise, causal: tap L - 1 is the row's own); the
+    block is (C * c) W_out.  The L - 1 products z before the pass are the
+    layer's STATE: `leaf` is the stacked state array [conv layers, n_slots,
+    L - 1, H] float32 (None: uncached, a zero tail) and `layer` this layer's
+    place in it; a lane's tail comes from its `plan.src` slot and the tail
+    after its last real row (`plan.lens`) goes to `dst` and `snap`, an
+    inactive lane's passing through (models/hybrid._read_state /
+    _write_state, one implementation for every decoder with a state).  z is
+    the EXACT product of the two gates' values, taken in float32 (two
+    bfloat16 values multiply into 16 significant bits), which the float32
+    slot holds as it is and a slot of the activations' dtype would round: on
+    the chip XLA computes the bfloat16 product unrounded anyway (excess
+    precision; my chip run A, PR 47: 97% of a slot's values needed more than
+    bfloat16), so saying float32 makes every backend and every fusion agree
+    on what a tail row is.  The taps accumulate in float32.  At S == 1 this
+    is decode's closed-form step.
+    Returns (out [B, S, H] ahead of the residual add, leaf')."""
+    dt, f32 = x.dtype, jnp.float32
+    b, s, h = x.shape
+    with jax.named_scope("conv_proj"):
+        bcu = jnp.einsum("bsh,hf->bsf", x, _w(lp, "w_in", dt))
+    with jax.named_scope("conv_mix"):
+        gate_b, gate_c, u = bcu[..., :h], bcu[..., h:2 * h], bcu[..., 2 * h:]
+        w = lp["conv_w"].astype(f32)  # [L, H]
+        taps = w.shape[0]
+        tail = (jnp.zeros((b, taps - 1, h), f32) if leaf is None
+                else _read_state(leaf, layer, plan, b))
+        seq = jnp.concatenate([tail, gate_b.astype(f32) * u.astype(f32)],
+                              axis=1)
+        c = sum(w[j] * seq[:, j:j + s] for j in range(taps))
+        if leaf is not None:
+            # the last L - 1 REAL products: rows lens - L + 1 .. lens - 1 of
+            # the pass are rows lens .. lens + L - 2 of `seq`
+            new = jax.vmap(
+                lambda rows, n: jax.lax.dynamic_slice_in_dim(
+                    rows, n, taps - 1, axis=0))(seq, plan.lens)
+            leaf = _write_state(leaf, layer, plan, new, tail)
+        y = gate_c * c.astype(dt)
+    with jax.named_scope("conv_proj"):
+        out = jnp.einsum("bsh,hk->bsk", y, _w(lp, "w_out", dt))
+    return out, leaf
 
 
 class WindowedPathError(NotImplementedError):
@@ -1726,20 +1815,34 @@ def forward(
         vision models, src/llm/portkey.py:276).
     Returns (logits [B, S, vocab] float32, updated cache or None).
 
-    A hybrid decoder (`cfg.has_state`: state-space layers beside attention)
-    is models/hybrid.forward behind this same entry: its paged pool carries
-    the recurrent state (`kv_cache.v` a dict), `paged.state` says which
-    slots, and a paged prefill returns its lanes' last real rows only,
-    logits [B, 1, vocab].
+    A model with a recurrent state (`cfg.has_state`): its paged pool carries
+    the state (`kv_cache.v` a dict), `paged.state` says which slots, and a
+    paged prefill returns its lanes' last real rows only, logits [B, 1,
+    vocab].  `phi4flash`'s decoder (`cfg.hybrid_decoder`) is
+    models/hybrid.forward behind this same entry; the conv layout runs here,
+    its mixers chosen by kind in the layer body.
     """
+    plan = None
     if cfg.has_state:
-        from .hybrid import HybridPathError, forward as hybrid_forward
-
         if mesh is not None and mesh.size > 1 or embed_override is not None:
             raise HybridPathError(
-                "a hybrid decoder runs on one device a replica, text only")
-        return hybrid_forward(params, cfg, token_ids, positions, kv_cache,
-                              paged)
+                "a decoder with a recurrent state runs on one device a "
+                "replica, text only")
+        if cfg.hybrid_decoder:
+            from .hybrid import forward as hybrid_forward
+
+            return hybrid_forward(params, cfg, token_ids, positions,
+                                  kv_cache, paged)
+        if kv_cache is not None and (paged is None or paged.state is None
+                                     or paged.page_table is None):
+            raise HybridPathError(
+                "a decoder with a recurrent state has no contiguous cache "
+                "and no paged plan without a page table and a StatePlan: "
+                "rows go through the paged pool, the state through its "
+                "slots")
+        plan = paged.state if paged is not None else StatePlan(
+            lens=jnp.full(token_ids.shape[:1], token_ids.shape[1],
+                          jnp.int32))
     with jax.named_scope("embed"):
         embed = params["embed"]
         if isinstance(embed, QTensor):
@@ -1760,7 +1863,7 @@ def forward(
         lead, period = cfg.pattern
         if cfg.layer_types:
             rope = {kind: rope_cos_sin(positions, *kind_frequencies(cfg, kind))
-                    for kind in cfg.kinds}
+                    for kind in cfg.kinds if kind != CONV}
         else:
             inv_freq = rope_frequencies(cfg)
             rope = {GLOBAL: rope_cos_sin(positions, inv_freq)}
@@ -1795,10 +1898,16 @@ def forward(
     def layer_body(carry, scanned, kind=GLOBAL, routed=cfg.is_moe):
         h, kc, vc = carry
         lp, layer, *slot = scanned
-        cos, sin = rope[kind]
+        cos, sin = (None, None) if kind == CONV else rope[kind]
         with jax.named_scope("attn_norm"):
             attn_in = rms_norm(h, lp["ln_attn"], cfg.rms_norm_eps)
-        if cfg.by_kind:
+        if kind == CONV:
+            # `layer` counts the conv layers: its place in the state array
+            attn_out, tail = _short_conv_block(
+                attn_in, lp, None if vc is None else vc["conv"], layer, plan)
+            if vc is not None:
+                vc = {**vc, "conv": tail}
+        elif cfg.by_kind:
             # this kind's own caches, `layer` its index among the kind's
             has_index = cfg.has_indexer(kind) and vc is not None
             attn_out, k_new, v_new, i_new = _latent_attention_block(
@@ -1818,11 +1927,16 @@ def forward(
                 cache_positions, paged, mesh, layer,
             )
         else:
-            attn_out, kc, vc = _attention_block(
-                attn_in, lp, cfg, cos, sin, positions, kc, vc, kv_valid,
+            # (beside conv layers `layer` counts the layers that hold rows,
+            # and their v pool rides beside the state in the v pool's dict)
+            in_dict = plan is not None and vc is not None
+            attn_out, kc, v_rows = _attention_block(
+                attn_in, lp, cfg, cos, sin, positions, kc,
+                vc["v"] if in_dict else vc, kv_valid,
                 cache_positions, paged, mesh, layer, cfg.window_of(kind),
             )
-        with jax.named_scope("attn_out"):
+            vc = {**vc, "v": v_rows} if in_dict else v_rows
+        with jax.named_scope("conv_proj" if kind == CONV else "attn_out"):
             h = h + attn_out
         with jax.named_scope("mlp_norm"):
             mlp_in = rms_norm(h, lp["ln_mlp"], cfg.rms_norm_eps)
@@ -1853,7 +1967,7 @@ def forward(
         the layer's place among its kind."""
         routed = stack == "layers"
         lp = at(layers if routed else params[stack], i, static)
-        if not cfg.by_kind:
+        if not cfg.kind_leaves:
             return (lp, layer) + (indexed(lambda: i) if routed else ())
         return ({**lp, **at(params["attn"][kind], nth, static)}, nth) + (
             indexed(lambda: i) if routed else ())
@@ -1871,9 +1985,9 @@ def forward(
         # (no arithmetic on `first` where there is nothing ahead: the
         # program of a model without dense or lead layers stays as it was)
         stacked = first - n_dense if n_dense else first
-        t = (first - ahead) // len(period) if cfg.by_kind else None
+        t = (first - ahead) // len(period) if cfg.kind_leaves else None
         for j, kind in enumerate(period):
-            if cfg.by_kind:
+            if cfg.kind_leaves:
                 nth = (before(ahead, kind) + t * period.count(kind)
                        + period[:j].count(kind))
                 scanned = layer_of("layers", stacked + j, first + j, kind,
@@ -1925,7 +2039,7 @@ def forward(
                 carry, layer_of("layers", i, n_dense + i, kind, True,
                                 before(n_dense + i, kind)), kind)
         x, kc, vc = carry
-        if len(period) == 1 and not (cfg.by_kind or lead):
+        if len(period) == 1 and not (cfg.kind_leaves or lead):
             layer_ids = (jnp.arange(n_dense, n_dense + num_layers) if n_dense
                          else jnp.arange(num_layers))
             (x, kc, vc), _ = jax.lax.scan(
@@ -1946,6 +2060,11 @@ def forward(
         new_cache = None if kv_cache is None else KVCache(k=kc, v=vc)
 
     with jax.named_scope("head"):
+        if plan is not None and paged is not None and x.shape[1] > 1:
+            # a prefill launch of a model with a state: each lane's last
+            # real row is all anybody reads (as models/hybrid.forward)
+            last = jnp.clip(plan.lens - 1, 0, x.shape[1] - 1)
+            x = jnp.take_along_axis(x, last[:, None, None], axis=1)
         logits = _logits_head(x, params, cfg)
     return logits, new_cache
 

@@ -180,8 +180,9 @@ AGENT_LINGER_ENV = "KAFKA_TPU_AGENT_LINGER_MS"
 
 class RecurrentStateUnsupported(ValueError):
     """An engine option (or a request) that cannot carry a model's
-    recurrent state: the per-thread state of its state-space layers lives
-    in a state slot beside the pages (models/hybrid.py), and this path
+    recurrent state: the per-thread state of its state-space or short
+    convolution layers lives in a state slot beside the pages
+    (models/hybrid.py), and this path
     moves, shards, rolls back or stores pages alone.  Raised at engine
     construction or at admission, naming the path: never a wrong token."""
 
@@ -736,22 +737,32 @@ class InferenceEngine:
             # The recurrent state lives in state slots beside the pages;
             # whatever moves, shards, rolls back or stores pages alone is
             # refused here, by name (models/hybrid.HybridPathError is the
-            # backstop for direct callers of forward).
+            # backstop for direct callers of forward).  Each reason is the
+            # one that is true of THIS model's kind of state: a Mamba
+            # decoder's scan and differential kernels, a conv layer's tail.
             sharded = mesh is not None and mesh.size > 1
+            mamba = cfg.hybrid_decoder
             refused = (
                 ("speculative verify (paged_verify_attention)",
                  self.ecfg.speculative_k > 0,
                  "a rejected candidate's state update cannot be rolled "
                  "back; set speculative_k=0"),
                 ("kv_quantize int8 pool", bool(self.ecfg.kv_quantize),
-                 "the int8 kernels have no differential form and the state "
-                 "stays float32; serve with a dense pool"),
+                 ("the int8 kernels have no differential form and the state "
+                  "stays float32" if mamba else
+                  "the float32 state slots ride in the v pool's pytree "
+                  "beside dense rows, and int8 rows beside them are not "
+                  "built") + "; serve with a dense pool"),
                 ("prefill_ring", sp > 1,
-                 "a chunk sharded over the sp axis has no sequential scan; "
-                 "use sp=1"),
+                 ("a chunk sharded over the sp axis has no sequential scan"
+                  if mamba else
+                  "a chunk sharded over the sp axis would need its "
+                  "neighbour's last conv rows, and no such exchange is "
+                  "built") + "; use sp=1"),
                 ("a pp / tp / ep mesh", sharded and sp == 1,
-                 "the state slots and the scan kernel live on one device; "
-                 "serve each replica on one device (dp)"),
+                 ("the state slots and the scan kernel live" if mamba else
+                  "the state slots live") + " on one device; serve each "
+                 "replica on one device (dp)"),
                 ("a KV tier (kv_host_tier_mb / kv_object_dir) and its "
                  "sleep manifests",
                  bool(self.ecfg.kv_host_tier_mb or self.ecfg.kv_object_dir),

@@ -10,10 +10,14 @@ store different rows has a pool pair per kind under the one page table; see
 make_kv_pool_arrays);
 sequences own ordered lists of physical pages.
 
-A hybrid decoder (`cfg.has_state`: state-space layers) also keeps, per
-thread, a recurrent state that no page can hold: a fixed-size STATE SLOT of
-the device arrays `make_state_arrays` allocates beside the pools (they ride
-in the v pool's pytree, models/hybrid.py), handed out by `StatePool`.  Slot i
+A model with a recurrent state (`cfg.has_state`: state-space layers, or
+gated short convolutions) also keeps, per thread, a state that no page can
+hold: a fixed-size STATE SLOT of the device arrays `make_state_arrays`
+allocates beside the pools (they ride in the v pool's pytree,
+models/hybrid.py), handed out by `StatePool`.  The slot's shape is the
+model's kind of state layer's (`cfg.state_shapes`: a Mamba layer's conv tail
+and h, 3.5 MB a slot at Phi-4's sizes; a short convolution's two rows, 180 KB
+at LFM2's); nothing here knows which.  Slot i
 < lanes is decode lane i's for its life, one slot is the trash slot, the rest
 are snapshots the prefix cache owns: a page can be shared from any page
 boundary, a recurrence only from where a snapshot stands.  The
@@ -199,8 +203,8 @@ class PagePool:
 
 
 class StatePool:
-    """Host allocator over the state-slot axis of a hybrid decoder's state
-    arrays.  Slots 0 .. lanes - 1 belong to the decode lanes (lane i reads
+    """Host allocator over the state-slot axis of the state arrays of a
+    model with a recurrent state.  Slots 0 .. lanes - 1 belong to the decode lanes (lane i reads
     and writes slot i: the decode programs address them by position), slot
     `lanes` is the trash slot (inactive prefill lanes, snapshots nobody
     wants), the rest are SNAPSHOT slots, refcounted as pages are: the radix
@@ -272,8 +276,9 @@ def default_state_slots(lanes: int) -> int:
 
 
 def make_state_arrays(cfg: ModelConfig, n_slots: int) -> Dict[str, Any]:
-    """The device-side state slots of a hybrid decoder: one float32 array a
-    state leaf, [state layers, n_slots, ...] (`cfg.state_shapes`)."""
+    """The device-side state slots of a model with a recurrent state: one
+    float32 array a state leaf, [state layers, n_slots, ...]
+    (`cfg.state_shapes`, which asks the kind of state layer the model has)."""
     return {name: jnp.zeros((cfg.state_layers, n_slots) + shape, jnp.float32)
             for name, shape in cfg.state_shapes()}
 
@@ -304,12 +309,13 @@ def make_kv_pool_arrays(
     every jitted program as an ordinary pytree, so the engine's fns don't
     change signature.
 
-    A hybrid decoder (`cfg.has_state`) holds rows for `cfg.kv_layers` layers
-    and `state_slots` state slots; its v pool is {"v": rows, "conv": ...,
-    "ssm": ...}.
+    A model with a recurrent state (`cfg.has_state`) holds rows for
+    `cfg.kv_layers` layers and `state_slots` state slots; its v pool is
+    {"v": rows, **state leaves} ("conv" and "ssm" for Mamba layers, "conv"
+    alone for short convolutions).
     """
     dtype = dtype or cfg.activation_dtype
-    # (a hybrid decoder: only its attention layers with K/V of their own)
+    # (a model with a state: only its attention layers with K/V of their own)
     lead = (cfg.kv_layers, num_pages * page_size)
     if quantize == "int8":
         from ..models.quant import QTensor

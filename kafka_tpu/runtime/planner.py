@@ -39,7 +39,7 @@ import dataclasses
 import os
 from typing import Dict, Optional
 
-from ..models.config import CROSS, GMU, ModelConfig
+from ..models.config import CONV, CROSS, GMU, ModelConfig
 from .kv_cache import default_state_slots
 
 GiB = 1024**3
@@ -278,7 +278,7 @@ def weight_bytes_per_device(
 
     if cfg.lead_tree:
         return _lead_tree_weight_bytes(cfg, mat, wb)
-    if cfg.has_state:
+    if cfg.hybrid_decoder:
         return _hybrid_weight_bytes(cfg, wb)
     per_layer = (
         mat(h, hq * d, tp)            # wq
@@ -305,8 +305,8 @@ def weight_bytes_per_device(
 
 
 def _hybrid_weight_bytes(cfg: ModelConfig, wb: int) -> int:
-    """Weights of a hybrid decoder on its one device (the engine refuses
-    meshes for it): the tree models/hybrid.init_params builds.  Float32
+    """Weights of `phi4flash`'s hybrid decoder on its one device (the engine
+    refuses meshes for it): the tree models/hybrid.init_params builds.  Float32
     leaves (conv, dt bias, A_log, D, lambdas) are counted at 4 bytes."""
     h, f, d = cfg.hidden_size, cfg.intermediate_size, cfg.head_dim
     hq, hkv = cfg.num_heads, cfg.num_kv_heads
@@ -327,9 +327,10 @@ def _hybrid_weight_bytes(cfg: ModelConfig, wb: int) -> int:
 
 
 def state_bytes_per_device(cfg: ModelConfig, state_slots: int) -> int:
-    """The state slots as the device holds them: float32, the second-minor
-    axis of each leaf padded to the 8-row sublane tile (a conv tail of 3
-    rows takes 8)."""
+    """The state slots as the device holds them, whatever kind of layer
+    they are the state of (`cfg.state_shapes`): float32, the second-minor
+    axis of each leaf padded to the 8-row sublane tile (a conv tail of 3 or
+    of 2 rows takes 8)."""
     return 4 * cfg.state_layers * state_slots * sum(
         -(-rows // 8) * 8 * cols for _, (rows, cols) in cfg.state_shapes())
 
@@ -338,14 +339,19 @@ def _lead_tree_weight_bytes(cfg: ModelConfig, mat, wb: int) -> int:
     """Weights of a model whose tree is models/llama._init_lead_tree_params'
     (or, per kind, _init_kind_params') on its one device (the engine refuses
     a mesh for it), leaf by leaf: latent attention, or grouped-query
-    attention with its QK-norm weights, in every layer; a dense lead; the
-    router at its published width beside the experts HELD."""
+    attention with its QK-norm weights, or a gated short convolution, by
+    each layer's kind; a dense lead; the router at its published width
+    beside the experts HELD."""
     h = cfg.hidden_size
 
     def mlp(f: int) -> int:
         return 2 * mat(h, f, 1) + mat(f, h, 1)
 
     def attn(kind: str) -> int:
+        """A layer's mixer and its two norms."""
+        if kind == CONV:
+            return (mat(h, 3 * h, 1) + mat(h, h, 1)
+                    + (cfg.conv_L_cache * h + 2 * h) * wb)
         if not cfg.is_latent:
             hq, hkv, d = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
             return (2 * mat(h, hq * d, 1) + 2 * mat(h, hkv * d, 1)
@@ -439,11 +445,14 @@ def activation_bytes_estimate(
         + s_local * (H + 2 * F // tp) * 2
         + window * kv_row * 2
     )
-    if cfg.has_state:
+    if cfg.hybrid_decoder:
         # the scan's float32 operands: x, dt, y, z and the projections
         # around them, [S, inner] each, and B / C broadcast along 128 lanes
         prefill += s_local * (cfg.mamba_d_inner * 4 * 6
                               + cfg.mamba_d_state * 128 * 4 * 2)
+    elif cfg.has_state:
+        # a short convolution's [B | C | u] and its float32 products
+        prefill += s_local * H * (3 * 2 + 2 * 4)
     decode = max_batch * V * 4 * 3 + max_batch * window * kv_row * 2
     return max(prefill, decode)
 
@@ -655,7 +664,7 @@ def dispatch_cost_model(
             else 2 * cfg.head_dim)
     return DispatchCostModel(
         flops_per_token=2.0 * params_total / n,
-        # (a hybrid decoder: its attention layers, own K/V and cross)
+        # (a model with a state: its attention layers, own K/V and cross)
         attn_flops_per_kv=2.0 * (cfg.num_layers if not cfg.has_state else
                                  cfg.num_layers - cfg.state_layers
                                  - cfg.layers_of(GMU))

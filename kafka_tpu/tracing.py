@@ -203,6 +203,12 @@ DEVICE_SCOPES = (
                     # cache a pass)
     "attn_diff",    # differential attention's combine: P_1 V - lam P_2 V,
                     # the sub-layer RMSNorm, (1 - lambda_init)
+    # the conv layout's mixer (models/llama._short_conv_block)
+    "conv_proj",    # a gated short convolution's projections: W_in, W_out
+                    # (+ residual add)
+    "conv_mix",     # its elementwise middle: B * u, the taps over [tail |
+                    # pass] with the tail's read from and write to the state
+                    # slot, and the C * gate
     "head",         # final RMSNorm + logits
     "sample",       # last-position select, per-(seed, position) keys,
                     # sample_tokens_per_slot (engine step programs)

@@ -8,7 +8,8 @@
     python scripts/lowered_programs.py diff A B
 
 `dump` drives an engine per tiny preset (`tiny-gqa`, `tiny-moe`, the tiny
-Mellum2, Kanana-2, dots3, Phi-4-flash and K-EXAONE under benchmarks/tests/)
+Mellum2, Kanana-2, dots3, Phi-4-flash, K-EXAONE and LFM2 under
+benchmarks/tests/)
 and attention backend (`xla`,
 `pallas`, which lowers in interpret mode off the chip) through single and
 batched prefill, the decode step (plain, host-masked, forced tokens), the
@@ -58,13 +59,16 @@ def _presets():
         "benchmarks/tests/phi4flash/configs/tiny-phi4flash.json")
     kexaone = config_from_hf_json(
         "benchmarks/tests/kexaone/configs/tiny-kexaone.json")
+    lfm2moe = config_from_hf_json(
+        "benchmarks/tests/lfm2moe/configs/tiny-lfm2moe.json")
     for name, cfg in (("tiny-gqa", get_config("tiny-gqa")),
                       ("tiny-moe", get_config("tiny-moe")),
                       ("tiny-mellum2", mellum),
                       ("tiny-kanana2", kanana),
                       ("tiny-dots3", dots3),
                       ("tiny-phi4flash", phi4flash),
-                      ("tiny-kexaone", kexaone)):
+                      ("tiny-kexaone", kexaone),
+                      ("tiny-lfm2moe", lfm2moe)):
         for backend in ("xla", "pallas"):
             yield name, backend, dataclasses.replace(
                 cfg, attention_backend=backend)
