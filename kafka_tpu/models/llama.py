@@ -1634,18 +1634,63 @@ def _routing_weights_sigmoid(t: jnp.ndarray, router: jnp.ndarray,
 TOKEN_DISPATCH_MIN_ROWS = 384
 
 
-def moe_dispatch_form(rows: int, held: int, top_k: int, sharded: bool) -> str:
+# Below TOKEN_DISPATCH_MIN_ROWS the weights' read bounds the block, and token
+# dispatch visits only the experts that have rows: the form reads fewer bytes
+# where the pass leaves a share of the held experts unpicked.  That share is
+# expected to be (1 - top_k / routed) ** rows under even routing (random
+# weights route evenly; a trained router is more skewed and reads fewer).
+# Measured, the block alone on the chip, every row active, us a layer,
+# dense | token, the experts read of those held and the expected unread share
+# (scripts/moe_dispatch_bench.py --rows 16 32 64; PERF.md section 6, PR 48):
+#   rows  Mixtral              Mellum2                LFM2
+#   16    3743 | 3772  8/8  .010   1095 |  928 55/64 .118    943 | 787 26/32 .118
+#   32    3746 | 3776  8/8  .000   1102 | 1056 62/64 .014    948 | 965 32/32 .014
+#   64    3796 | 3793  8/8  .000   1095 | 1151 64/64 .000    957 | 979 32/32 .000
+#   rows  Kanana-2             K-EXAONE               dots3
+#   16    1613 |  871  67/128 .464  1665 | 1463 14/16 .356   2018 |  931 14/32 .602
+#   32    1620 | 1273  97/128 .215  1686 | 1288 12/16 .127   2030 | 1210 18/32 .362
+#   64    1637 | 1569 119/128 .046  1745 | 1711 16/16 .016   2053 | 1873 28/32 .131
+# From an expected share of 0.046 up the token form is the faster one at every
+# configuration and row count measured (by 4 % at the least); at 0.016 and
+# under it is within 5 % of dense on either side (its sort and two row
+# gathers, with little or nothing left unread to pay for them).  (A kernel
+# that walked the picked experts with dense dispatch's arithmetic, no sort
+# and no gathers, read 0-4 % faster than the token form in the same table
+# and was not kept.)
+TOKEN_DISPATCH_MIN_UNREAD = 0.04
+# ... and the fewest rows that table timed, one sublane tile of bf16: a pass
+# of fewer rows (a single stream's decode, the benchmark's one-lane logit
+# check) keeps the dense einsums.
+TOKEN_DISPATCH_UNREAD_ROWS = 16
+
+
+def moe_dispatch_form(rows: int, held: int, top_k: int, sharded: bool,
+                      routed: Optional[int] = None,
+                      int8: bool = False) -> str:
     """"token" or "dense": the form of the routed block for a pass of `rows`
-    rows (static) over `held` experts of which a row picks `top_k`.  Token
-    dispatch where dense dispatch is compute-bound and computes products it
-    then zeroes; dense below that (decode: the weights' read bounds both,
-    and dense has no sort or gather), where every held expert takes every
-    row anyway, and on an ep / tp mesh (GSPMD partitions the dense einsums;
-    a sharded grouped matmul is ROADMAP R4's).  The one rule: `_moe_block`
-    traces by it and the engine counts launches by it."""
-    if sharded or held <= top_k or rows < TOKEN_DISPATCH_MIN_ROWS:
+    rows (static) over `held` experts, of the `routed` the router knows
+    (None: all held), of which a row picks `top_k`; `int8`: the experts'
+    leaves are quantized.  Token dispatch where dense dispatch is
+    compute-bound and computes products it then zeroes
+    (TOKEN_DISPATCH_MIN_ROWS), and below that, where the weights' read
+    bounds both, where few rows over many experts are expected to leave a
+    share of them unpicked (TOKEN_DISPATCH_MIN_UNREAD: decode at 16-32
+    lanes over 64 experts or more, a 64-row launch over 128 or more): token
+    dispatch fetches no expert without rows.  Dense between the two (nearly
+    every expert is somebody's pick: no sort, no gather), where every held
+    expert takes every row anyway, at decode over int8 experts (dequantized
+    whole, a layer) and on an ep / tp mesh (GSPMD partitions the dense
+    einsums; a sharded grouped matmul is ROADMAP R4's).  The one rule:
+    `_moe_block` traces by it and the engine counts launches by it."""
+    if sharded or held <= top_k:
         return "dense"
-    return "token"
+    if rows >= TOKEN_DISPATCH_MIN_ROWS:
+        return "token"
+    if (not int8 and rows >= TOKEN_DISPATCH_UNREAD_ROWS
+            and (1.0 - top_k / (routed or held)) ** rows
+            >= TOKEN_DISPATCH_MIN_UNREAD):
+        return "token"
+    return "dense"
 
 
 # XLA's row gather on the v5e (jaxlib 0.9.0) keeps an operand of up to ~7.3 MB
@@ -1663,9 +1708,16 @@ GATHER_VMEM_WINDOW = (6 << 20, 15 << 19)
 EXPERT_LEAVES = ("wg", "wu", "wd")
 
 
+def experts_int8(layers: Params) -> bool:
+    """Whether the routed experts' leaves of a layer tree (stacked, or one
+    layer's) are int8 `QTensor`s."""
+    return any(isinstance(layers.get(name), QTensor)
+               for name in EXPERT_LEAVES)
+
+
 def _experts_token(t: jnp.ndarray, top_idx: jnp.ndarray, w_top: jnp.ndarray,
                    stack: Params, layer, routed: int, offset: int,
-                   real: Optional[jnp.ndarray] = None) -> jnp.ndarray:
+                   real: Optional[jnp.ndarray] = None):
     """The routed experts by token: the T x k (row, expert, weight) picks
     sorted by expert, the rows gathered into that order, each projection ONE
     grouped matmul whose groups are the held experts (operands in t's dtype,
@@ -1675,7 +1727,9 @@ def _experts_token(t: jnp.ndarray, top_idx: jnp.ndarray, w_top: jnp.ndarray,
     over ALL the router's `routed` experts, of which this chip holds
     offset.. ; `real` [T] bool marks the rows that hold a token.  A pick of
     an expert held elsewhere, or of a pad row, sorts past every group: no
-    matmul rows, zero weight.  No capacity, nothing dropped."""
+    matmul rows, zero weight.  No capacity, nothing dropped.  -> (out [T, H],
+    the held experts that have rows, i32: the ones whose weights the grouped
+    matmuls read)."""
     from ..ops.pallas.grouped_matmul import grouped_matmul, tile_rows
 
     n, k = top_idx.shape
@@ -1702,14 +1756,17 @@ def _experts_token(t: jnp.ndarray, top_idx: jnp.ndarray, w_top: jnp.ndarray,
     # `mine` finds a row no group wrote: whatever the buffer held)
     y = y[jnp.argsort(order)].reshape(n, k, -1)
     y = jnp.where(mine[:, :, None], y.astype(jnp.float32), 0.0)
-    return jnp.sum(y * w_top[:, :, None], axis=1).astype(t.dtype)
+    out = jnp.sum(y * w_top[:, :, None], axis=1).astype(t.dtype)
+    return out, jnp.sum(sizes > 0, dtype=jnp.int32)
 
 
 def _moe_block(x: jnp.ndarray, lp: Params, cfg: ModelConfig,
                chunk_len: Optional[jnp.ndarray] = None,
                sharded: bool = False,
-               stacked: Optional[Tuple[Params, Any]] = None) -> jnp.ndarray:
-    """Top-k routed MoE MLP. x: [B, S, H].
+               stacked: Optional[Tuple[Params, Any]] = None):
+    """Top-k routed MoE MLP. x: [B, S, H] -> (output [B, S, H], the held
+    experts whose weights the block read: an i32 the token form counts, all
+    of them, a Python int, in the dense form).
 
     Routing: softmax over the top-k router logits only (HF
     MixtralSparseMoeBlock semantics), computed in f32; `cfg.moe_scoring`
@@ -1717,9 +1774,11 @@ def _moe_block(x: jnp.ndarray, lp: Params, cfg: ModelConfig,
     of two forms of the same arithmetic, chosen by `moe_dispatch_form` from
     the pass's static row count B x S:
 
-    dense (decode, verify, the small prefill buckets, every mesh): every
-    expert computes every row (parallel/expert.py's capacity-unlimited
-    formulation, validated there against a per-token loop), the [T, E]
+    dense (verify, the prefill buckets under TOKEN_DISPATCH_MIN_ROWS, decode
+    where nearly every expert is some lane's pick or the experts are int8,
+    every mesh): every expert computes every row (parallel/expert.py's
+    capacity-unlimited formulation, validated there against a per-token
+    loop), the [T, E]
     routing weights zero the non-selected contributions, and the combine
     einsum contracts the expert axis.  Below ~240 rows the experts' weight
     read bounds the block and the products thrown away are free.  With
@@ -1728,16 +1787,19 @@ def _moe_block(x: jnp.ndarray, lp: Params, cfg: ModelConfig,
     program serves single-device, ep, and ep x tp meshes: meshes keep this
     form until a sharded grouped matmul exists (ROADMAP R4).
 
-    token (prefill launches of TOKEN_DISPATCH_MIN_ROWS rows or more on one
-    device): `_experts_token`, each row through its own k experts only, where
-    dense dispatch would be compute-bound at E / k times the FLOPs needed.
-    `chunk_len` [B] or scalar (the view's, at prefill): rows at or past it
-    are padding, fall in no group and get a zero routed output (nothing
-    reads their feed-forward output; None: every row is real).  `stacked`:
-    (the EXPERT_LEAVES as the layer stack holds them, this layer's index),
-    which `forward` hands over in place of `lp`'s slices of them so that
-    the grouped matmul reads the weights where they lie (None: `lp` holds
-    the layer's own).
+    token (on one device: prefill launches of TOKEN_DISPATCH_MIN_ROWS rows or
+    more, and the passes of few rows over many experts, decode at 16-32
+    lanes over 64 or more): `_experts_token`, each row through its own k
+    experts only: where dense dispatch would be compute-bound at E / k times
+    the FLOPs needed, and where it would read experts no row picked.
+    `chunk_len` [B] or scalar (the view's: a prefill's real rows, a decode
+    step's active lanes): rows at or past it are padding, fall in no group,
+    pick nothing and get a zero routed output in the token form (nothing
+    reads their feed-forward output; None: every row is real).
+    `stacked`: (the EXPERT_LEAVES as the layer stack holds them, this
+    layer's index), which `forward` hands over in place of `lp`'s slices of
+    them so that the grouped matmul reads the weights where they lie (None:
+    `lp` holds the layer's own).
 
     A shared branch (`cfg.shared_intermediate_size`: one always-on SwiGLU
     beside the routed experts) runs under its own scope, `moe_shared`.
@@ -1749,7 +1811,8 @@ def _moe_block(x: jnp.ndarray, lp: Params, cfg: ModelConfig,
     b, s, h = x.shape
     t = x.reshape(b * s, h)
     token = moe_dispatch_form(
-        b * s, cfg.num_experts, cfg.num_experts_per_tok, sharded) == "token"
+        b * s, cfg.num_experts, cfg.num_experts_per_tok, sharded,
+        cfg.num_router_experts, experts_int8(lp)) == "token"
     with jax.named_scope("moe_router"):
         if cfg.moe_scoring == "sigmoid":
             w = _routing_weights_sigmoid(
@@ -1762,6 +1825,7 @@ def _moe_block(x: jnp.ndarray, lp: Params, cfg: ModelConfig,
             # the weights of the experts HELD: chosen and renormalised over
             # all the router's experts, then this share's columns
             w = w[:, cfg.expert_offset:cfg.expert_offset + cfg.num_experts]
+    read = cfg.num_experts
     with jax.named_scope("moe_experts"):
         if token:
             real = None
@@ -1771,7 +1835,7 @@ def _moe_block(x: jnp.ndarray, lp: Params, cfg: ModelConfig,
             stack, at = stacked or (
                 {name: _w(lp, name, t.dtype)[None] for name in EXPERT_LEAVES},
                 0)
-            out = _experts_token(
+            out, read = _experts_token(
                 t, *w, stack, at, cfg.num_router_experts,
                 cfg.expert_offset if cfg.num_experts_routed else 0, real)
         else:
@@ -1784,7 +1848,7 @@ def _moe_block(x: jnp.ndarray, lp: Params, cfg: ModelConfig,
     if cfg.shared_intermediate_size:
         with jax.named_scope("moe_shared"):
             out = out + _mlp_block(x, lp, ("ws_g", "ws_u", "ws_d"))
-    return out
+    return out, read
 
 
 def forward(
@@ -1799,6 +1863,7 @@ def forward(
     mesh=None,
     embed_override: Optional[jnp.ndarray] = None,
     override_on: Optional[jnp.ndarray] = None,
+    expert_reads: bool = False,
 ) -> Tuple[jnp.ndarray, Optional[KVCache]]:
     """Run the decoder.
 
@@ -1813,7 +1878,10 @@ def forward(
         input embedding is REPLACED (image patches entering as soft-prompt
         tokens, models/vision.py; the reference forwarded images to remote
         vision models, src/llm/portkey.py:276).
-    Returns (logits [B, S, vocab] float32, updated cache or None).
+    Returns (logits [B, S, vocab] float32, updated cache or None), and with
+    `expert_reads` (a routed model's own layer tree) a third: the held
+    experts whose weights this pass's routed layers read, summed over them
+    (i32; `_moe_block`'s count, what token dispatch leaves unread).
 
     A model with a recurrent state (`cfg.has_state`): its paged pool carries
     the state (`kv_cache.v` a dict), `paged.state` says which slots, and a
@@ -1880,9 +1948,9 @@ def forward(
     layers, experts = params["layers"], None
     if (cfg.is_moe and moe_dispatch_form(
             token_ids.shape[0] * token_ids.shape[1], cfg.num_experts,
-            cfg.num_experts_per_tok, sharded) == "token"
-            and not any(isinstance(layers[n], QTensor)
-                        for n in EXPERT_LEAVES)):
+            cfg.num_experts_per_tok, sharded,
+            cfg.num_router_experts) == "token"
+            and not experts_int8(layers)):
         experts = {n: layers[n] for n in EXPERT_LEAVES}
         layers = {n: a for n, a in layers.items() if n not in experts}
 
@@ -1896,7 +1964,7 @@ def forward(
     # scope (residual adds included), so what a device trace shows under
     # `layers` alone is the scan's own slicing of its stacked inputs.
     def layer_body(carry, scanned, kind=GLOBAL, routed=cfg.is_moe):
-        h, kc, vc = carry
+        h, kc, vc, tally = carry
         lp, layer, *slot = scanned
         cos, sin = (None, None) if kind == CONV else rope[kind]
         with jax.named_scope("attn_norm"):
@@ -1941,15 +2009,17 @@ def forward(
         with jax.named_scope("mlp_norm"):
             mlp_in = rms_norm(h, lp["ln_mlp"], cfg.rms_norm_eps)
         if routed:
-            ffn_out = _moe_block(
+            ffn_out, read = _moe_block(
                 mlp_in, lp, cfg, None if paged is None else paged.chunk_len,
                 sharded, (experts, slot[0]) if slot else None)
+            if tally is not None:
+                tally = tally + read
             with jax.named_scope("moe_experts"):
                 h = h + ffn_out
         else:
             with jax.named_scope("mlp"):
                 h = h + _mlp_block(mlp_in, lp)
-        return (h, kc, vc), None
+        return (h, kc, vc, tally), None
 
     def at(stacked, i, static: bool):
         """Layer i's leaves of a stacked tree: `a[i]` where i is static,
@@ -2010,7 +2080,9 @@ def forward(
         kc, vc = (None, None) if kv_cache is None else kv_cache
         num_layers = jax.tree.leaves(params["layers"])[0].shape[0]
         n_dense = 0
-        carry = (x, kc, vc)
+        # (the tally of experts read rides the carry: None, no leaf, unless
+        # `expert_reads`)
+        carry = (x, kc, vc, jnp.int32(0) if expert_reads else None)
         if "dense_layers" in params:
             # leading dense layers: a stacked tree of another shape, run
             # ahead of the scan over the routed layers, which count on from
@@ -2038,13 +2110,12 @@ def forward(
             carry, _ = layer_body(
                 carry, layer_of("layers", i, n_dense + i, kind, True,
                                 before(n_dense + i, kind)), kind)
-        x, kc, vc = carry
         if len(period) == 1 and not (cfg.kind_leaves or lead):
             layer_ids = (jnp.arange(n_dense, n_dense + num_layers) if n_dense
                          else jnp.arange(num_layers))
-            (x, kc, vc), _ = jax.lax.scan(
+            carry, _ = jax.lax.scan(
                 partial(layer_body, kind=period[0]),
-                (x, kc, vc),
+                carry,
                 (layers, layer_ids) + indexed(
                     lambda: jnp.arange(num_layers)),
             )
@@ -2054,9 +2125,10 @@ def forward(
                 raise ValueError(
                     f"{num_layers} stacked layers are not {lead} and whole "
                     f"periods of the {p}-layer pattern")
-            (x, kc, vc), _ = jax.lax.scan(
-                period_body, (x, kc, vc),
+            carry, _ = jax.lax.scan(
+                period_body, carry,
                 jnp.arange(n_dense + lead, n_dense + num_layers, p))
+        x, kc, vc, tally = carry
         new_cache = None if kv_cache is None else KVCache(k=kc, v=vc)
 
     with jax.named_scope("head"):
@@ -2066,6 +2138,8 @@ def forward(
             last = jnp.clip(plan.lens - 1, 0, x.shape[1] - 1)
             x = jnp.take_along_axis(x, last[:, None, None], axis=1)
         logits = _logits_head(x, params, cfg)
+    if expert_reads:
+        return logits, new_cache, tally
     return logits, new_cache
 
 

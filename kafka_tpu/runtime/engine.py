@@ -72,6 +72,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..models.config import WINDOWED, ModelConfig, UnsupportedConfigError
+from ..models.llama import experts_int8
 from .failpoints import failpoint
 from .flight_recorder import (
     FlightRecorder,
@@ -597,6 +598,9 @@ class _Fetch:
     # entry is timed for the ring but never billed to the skew gauge.
     kind: str = "decode"
     modeled_s: Optional[float] = None
+    # the held experts each pass of this dispatch read ([] or [steps] i32),
+    # where its program counts them (StepPrograms.moe_dispatch "token")
+    reads: Optional[jnp.ndarray] = None
 
 
 class _GrammarTables:
@@ -1120,7 +1124,8 @@ class InferenceEngine:
         # acceptance criterion for the default-off path).
         self._programs = StepPrograms(
             self.cfg, mesh, self.ecfg.page_size, B,
-            self.ecfg.max_pages_per_seq)
+            self.ecfg.max_pages_per_seq,
+            self.cfg.is_moe and experts_int8(params["layers"]))
         self._counter = itertools.count()
         # device-resident decode control state (see module docstring)
         self._d_last = self._dev(np.zeros(B, np.int32))
@@ -1387,6 +1392,15 @@ class InferenceEngine:
         self.moe_dispatch = dict.fromkeys(
             ("token_launches", "token_rows", "dense_launches", "dense_rows"),
             0)
+        # Monotonic, 0 for a model with no routed block: over the decode
+        # passes (single and fused steps), the held experts whose weights
+        # the routed layers read, and those they hold (held experts x routed
+        # layers a pass).  Equal where the blocks read every held expert
+        # (dense: counted at dispatch); where they dispatch by token the
+        # program counts the experts that had rows on the device and both
+        # move when the step's tokens are fetched (_Fetch.reads).
+        self.moe_experts_read = 0
+        self.moe_experts_held = 0
         # Monotonic: the host's run-ahead, sampled at every decode / fused
         # / verify dispatch (_backlog_steps: steps in the FIFO the device
         # has not been seen to finish, the number _hold_decode bounds);
@@ -1761,7 +1775,7 @@ class InferenceEngine:
         inactive = self._dev(np.zeros(B, bool))
         fsm = self._fsm(True)
         (self.k_pool, self.v_pool, toks, self._d_seq_lens,
-         *fsm_out) = self._programs.decode(fsm)(
+         *fsm_out, _) = self._programs.decode(fsm)(
             self.params, self.k_pool, self.v_pool, self._lanes(inactive),
             None, None, fsm,
         )
@@ -2666,6 +2680,11 @@ class InferenceEngine:
                 )
         if entry.spec is not None:
             return self._finish_verify_entry(entry, raw)
+        if entry.reads is not None:
+            # (computed by the program that made `arr`: landed with it)
+            self.moe_experts_read += int(np.sum(np.asarray(entry.reads)))
+            self.moe_experts_held += (
+                entry.steps * self._programs.experts_held())
         vals = raw.reshape(entry.steps, -1)
         n = 0
         for j in range(entry.steps):
@@ -4228,7 +4247,7 @@ class InferenceEngine:
         fn = self._programs.multi_decode(k, fsm)
         with self._dispatch_scope("decode", self.slots):
             (self.k_pool, self.v_pool, toks_seq, last, lens,
-             *fsm_out) = fn(
+             *fsm_out, reads) = fn(
                 self.params, self.k_pool, self.v_pool,
                 self._lanes(self._d_active), fsm,
             )
@@ -4236,6 +4255,7 @@ class InferenceEngine:
         self._d_last = last
         self._d_seq_lens = lens
         entry = self._book_dispatch(toks_seq, list(self.slots), steps=k)
+        self._count_expert_reads(entry, reads)
         self.metrics.record_decode_step(
             sum(1 for m in entry.items if m is not None), steps=k
         )
@@ -4275,7 +4295,7 @@ class InferenceEngine:
         fn = self._programs.decode(fsm_arg)
         with self._dispatch_scope("decode", members):
             (self.k_pool, self.v_pool, toks, self._d_seq_lens,
-             *fsm_out) = fn(
+             *fsm_out, reads) = fn(
                 self.params, self.k_pool, self.v_pool,
                 self._lanes(d_active),
                 None if allowed is None else self._arg(allowed),
@@ -4284,7 +4304,24 @@ class InferenceEngine:
             )
         self._keep_fsm(fsm_out)
         self._d_last = toks if full else jnp.where(d_active, toks, self._d_last)
-        return self._book_dispatch(toks, members, steps=1)
+        entry = self._book_dispatch(toks, members, steps=1)
+        self._count_expert_reads(entry, reads)
+        return entry
+
+    def _count_expert_reads(self, entry: _Fetch,
+                            reads: Optional[jnp.ndarray]) -> None:
+        """Account a decode dispatch's expert reads: `reads`, the program's
+        own count (its last output: token dispatch), rides the entry and is
+        added when its tokens are fetched (_process_entry); a program that
+        returns None read every held expert, counted here (0 for a model
+        with no routed block)."""
+        if reads is not None:
+            reads.copy_to_host_async()
+            entry.reads = reads
+            return
+        held = entry.steps * self._programs.experts_held()
+        self.moe_experts_read += held
+        self.moe_experts_held += held
 
     def _count_walk_trips(self, spans, width: int, bucket: int) -> None:
         trips, folded = self._programs.prefill_walk_trips(spans, width, bucket)

@@ -430,6 +430,12 @@ METRICS: Tuple[Metric, ...] = (
         _m("prefill_walk_trips", read="prefill_walk_trips"),
         _m("prefill_walk_kernel_trips", read="prefill_walk_kernel_trips"),
         _m("moe_dispatch", read="moe_dispatch"),
+        _m("moe_experts_read", "kafka_tpu_engine_moe_experts_total{kind=read}",
+           "Held experts over the routed layers of the decode passes: those "
+           "whose weights a pass read, and those held.",
+           read="moe_experts_read"),
+        _m("moe_experts_held", "kafka_tpu_engine_moe_experts_total{kind=held}",
+           read="moe_experts_held"),
         # the fetch pipeline's run-ahead (steps, summed over samples at
         # decode dispatches), the scheduler thread's time in unlanded
         # reads, and pops by what released them

@@ -36,6 +36,7 @@ from ..models.llama import (
     KVCache,
     PagedView,
     forward,
+    experts_int8,
     moe_dispatch_form,
     prefill_walk_pages,
 )
@@ -245,10 +246,22 @@ def _with_state(cfg, paged, lens, slots=None, snaps=None, starts=None):
             lens=lens, src=slots, dst=slots, snap=snaps, fresh=starts == 0))
 
 
+def _moe_form(cfg, mesh, rows: int, int8: bool) -> Optional[str]:
+    """The form the routed blocks of a pass of `rows` rows trace to, over
+    experts that are `int8` or not (models/llama.py moe_dispatch_form, the
+    rule `_moe_block` itself asks); None for a model with no routed
+    block."""
+    if not cfg.is_moe:
+        return None
+    return moe_dispatch_form(
+        rows, cfg.num_experts, cfg.num_experts_per_tok,
+        mesh is not None and mesh.size > 1, cfg.num_router_experts, int8)
+
+
 def _forward(cfg, mesh, params, tokens, positions, k_pool, v_pool, paged,
-             vis=()):
-    """The model over a paged pool -> (logits, KVCache).  `vis` = (embed
-    override, on-mask), present iff cfg.vision."""
+             vis=(), expert_reads=False):
+    """The model over a paged pool -> (logits, KVCache[, experts read]).
+    `vis` = (embed override, on-mask), present iff cfg.vision."""
     if mesh is not None and mesh.shape.get("pp", 1) > 1:
         from ..parallel.pipeline import pp_forward_paged
 
@@ -260,12 +273,16 @@ def _forward(cfg, mesh, params, tokens, positions, k_pool, v_pool, paged,
         kv_cache=KVCache(k_pool, v_pool), paged=paged, mesh=mesh,
         embed_override=vis[0] if vis else None,
         override_on=vis[1] if vis else None,
+        expert_reads=expert_reads,
     )
 
 
 def _decode_fn(cfg: ModelConfig, mesh: Any, ps: int):
     """One decode step as a pure function of device state; the single-step
-    program, and the body of the fused multi-step scan."""
+    program, and the body of the fused multi-step scan.  Returns, last, the
+    held experts its routed layers read (i32) where they dispatch by token
+    (`_moe_form` at this many lanes: an idle lane then picks none), else
+    None: every held expert, which the host knows."""
 
     def body(params, k_pool, v_pool, lanes, allowed_mask, forced=None,
              fsm=None):
@@ -273,9 +290,18 @@ def _decode_fn(cfg: ModelConfig, mesh: Any, ps: int):
          top_ps, seeds) = lanes
         positions, paged = decode_plan(page_table, seq_lens, active, ps)
         paged = _with_state(cfg, paged, active.astype(jnp.int32))
-        logits, cache = _forward(
-            cfg, mesh, params, last_tokens[:, None], positions,
-            k_pool, v_pool, paged)
+        reads = None
+        if _moe_form(cfg, mesh, page_table.shape[0], cfg.is_moe
+                     and experts_int8(params["layers"])) == "token":
+            with jax.named_scope("step_ctl"):
+                paged = paged._replace(chunk_len=active.astype(jnp.int32))
+            logits, cache, reads = _forward(
+                cfg, mesh, params, last_tokens[:, None], positions,
+                k_pool, v_pool, paged, expert_reads=True)
+        else:
+            logits, cache = _forward(
+                cfg, mesh, params, last_tokens[:, None], positions,
+                k_pool, v_pool, paged)
         if fsm is not None:
             with jax.named_scope("fsm"):
                 gmask = grammar_allowed_mask(
@@ -300,13 +326,14 @@ def _decode_fn(cfg: ModelConfig, mesh: Any, ps: int):
         with jax.named_scope("step_ctl"):
             next_lens = seq_lens + active.astype(jnp.int32)
         if fsm is None:
-            return cache.k, cache.v, toks, next_lens
+            return cache.k, cache.v, toks, next_lens, reads
         with jax.named_scope("fsm"):
             new_state = grammar_advance(
                 fsm.state, fsm.g_idx, toks, active, fsm.token_class,
                 fsm.trans)
             new_budget = fsm.budget - active.astype(jnp.int32)
-        return cache.k, cache.v, toks, next_lens, new_state, new_budget
+        return (cache.k, cache.v, toks, next_lens, new_state, new_budget,
+                reads)
 
     return body
 
@@ -319,21 +346,22 @@ def _multi_decode_fn(cfg: ModelConfig, mesh: Any, ps: int, steps: int):
 
         def one(carry, _):
             kp, vp, last, lens, *fs = carry
-            kp, vp, toks, lens, *fs = body(
+            # (the step's experts read, or None, stacked beside its tokens)
+            kp, vp, toks, lens, *fs, reads = body(
                 params, kp, vp,
                 lanes._replace(last_tokens=last, seq_lens=lens), None,
                 fsm=(None if fsm is None else
                      fsm._replace(state=fs[0], budget=fs[1])),
             )
-            return (kp, vp, toks, lens, *fs), toks
+            return (kp, vp, toks, lens, *fs), (toks, reads)
 
         with jax.named_scope("step_ctl"):
-            (kp, vp, last, lens, *fs), toks_seq = jax.lax.scan(
+            (kp, vp, last, lens, *fs), (toks_seq, reads) = jax.lax.scan(
                 one,
                 (k_pool, v_pool, lanes.last_tokens, lanes.seq_lens, *fs0),
                 None, length=steps,
             )
-        return (kp, vp, toks_seq, last, lens, *fs)
+        return (kp, vp, toks_seq, last, lens, *fs, reads)
 
     return fn
 
@@ -502,12 +530,17 @@ class StepPrograms:
     `built`, this engine's record of what it has been handed (and so of
     what warm-up compiled), else from the process-wide cache, else by
     jitting it.  A decode-side builder is handed the `Fsm` the program
-    will be called with, or `None` for the plain program."""
+    will be called with, or `None` for the plain program.  `int8_experts`:
+    the params the programs will be handed hold their routed experts as
+    int8 (`models/llama.py experts_int8`: what the programs themselves see
+    when traced)."""
 
     def __init__(self, cfg: ModelConfig, mesh: Any, page_size: int,
-                 max_batch: int, max_pages_per_seq: int):
+                 max_batch: int, max_pages_per_seq: int,
+                 int8_experts: bool = False):
         self.cfg, self.mesh, self.ps = cfg, mesh, page_size
         self.B, self.P = max_batch, max_pages_per_seq
+        self.int8_experts = int8_experts
         self.built: Dict[Tuple[str, Optional[Tuple]], Callable] = {}
 
     def _program(self, label: str, key: Tuple, fsm_key: Optional[Tuple],
@@ -586,13 +619,19 @@ class StepPrograms:
         """"token" or "dense": the form the routed blocks of a pass of
         `rows` rows (lanes x rows a lane) trace to, by the rule
         models/llama.py _moe_block itself asks (moe_dispatch_form); None
-        for a model with no routed block."""
-        cfg, mesh = self.cfg, self.mesh
+        for a model with no routed block.  A decode or fused-decode program
+        whose form is "token" returns, last, the held experts its passes
+        read ([] a step, [steps] fused), and None otherwise."""
+        return _moe_form(self.cfg, self.mesh, rows, self.int8_experts)
+
+    def experts_held(self) -> int:
+        """Held experts x routed layers: what one pass's routed blocks read
+        in the dense form, and at most in the token form (0: no routed
+        block)."""
+        cfg = self.cfg
         if not cfg.is_moe:
-            return None
-        return moe_dispatch_form(
-            rows, cfg.num_experts, cfg.num_experts_per_tok,
-            mesh is not None and mesh.size > 1)
+            return 0
+        return cfg.num_experts * (cfg.num_layers - cfg.first_k_dense)
 
     def decode(self, fsm: Optional[Fsm] = None):
         """One token for every active lane: fn(params, k_pool, v_pool,

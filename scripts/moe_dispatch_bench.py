@@ -2,9 +2,9 @@
 """The routed feed-forward block alone, on the chip, in its two forms and
 with each candidate grouped matmul, at every routed configuration's widths
 (`benchmarks/configs/`: Mixtral 8 experts top-2, Mellum2 64 top-8, Kanana-2
-128 top-6, K-EXAONE 16 held of 128 top-8, dots3 32 held of 256 top-8) and at
-the row counts one pass can have (decode lanes, the prefill buckets, a
-batched prefill's lanes x bucket).
+128 top-6, K-EXAONE 16 held of 128 top-8, dots3 32 held of 256 top-8, LFM2 32
+top-4) and at the row counts one pass can have (decode lanes, the prefill
+buckets, a batched prefill's lanes x bucket).
 
     python scripts/moe_dispatch_bench.py                 # every config
     python scripts/moe_dispatch_bench.py --configs kanana-2-30b-a3b --rows 512
@@ -21,10 +21,11 @@ profiler capture (`XLA Modules`), routing included, the shared expert left
 out.  Forms:
 
   installed      `models/llama._moe_block` as `moe_dispatch_form` chooses
-  dense          every row through every held expert (the block below
-                 TOKEN_DISPATCH_MIN_ROWS and on meshes)
+  dense          every row through every held expert (the block where
+                 the token form is not chosen, and on meshes)
   token          `_experts_token` at every row count: picks sorted by expert,
-                 `ops/pallas/grouped_matmul.py` (megablox `gmm`, its tiling)
+                 `ops/pallas/grouped_matmul.py` (megablox `gmm`, its tiling):
+                 an expert no row picked is never fetched
   ragged_dot     the same with `jax.lax.ragged_dot` as XLA lowers it
   gmm_<m>x<k>x<n>  (--gmm) the same with `gmm` at other tiles: rows, most of
                  the contraction, most of the output
@@ -36,8 +37,11 @@ program, a launch) to its line.
 Beside each time: the FLOPs the chosen rows need (2 x 3 x picks on held
 experts x H x F, against the bf16 peak) and the bytes of the held experts'
 weights read once (against the HBM peak), whichever bounds the block: the
-floor a form can reach.  Prints one JSON line a form and writes them all to
-`--out`; `--table` prints the markdown table of PERF.md section 6.
+floor a form can reach; and `picked_floor_us`, the same for the weights of
+the experts some row picked alone (`experts_read` of `experts_held`, as the
+token form counts them), the floor of a form that skips the rest.  Prints
+one JSON line a form and writes them all to `--out`; `--table` prints the
+markdown table of PERF.md section 6.
 """
 
 from __future__ import annotations
@@ -58,9 +62,9 @@ ROOT = os.path.join(os.path.dirname(os.path.abspath(__file__)), "..")
 sys.path.insert(0, ROOT)
 
 CONFIGS = ("mixtral-8x7b", "mellum2-12b-a2.5b", "kanana-2-30b-a3b",
-           "k-exaone-236b-a23b", "dots3-note-prev")
+           "k-exaone-236b-a23b", "dots3-note-prev", "lfm2-8b-a1b")
 # decode lanes | the prefill buckets | a batched prefill's lanes x bucket
-ROWS = (32, 64, 128, 256, 512, 1024, 2048)
+ROWS = (16, 32, 64, 128, 256, 512, 1024, 2048)
 # (rows, most of the contraction, most of the output) a tile: each width is
 # cut to its largest multiple of 128 lanes that divides the matrix's
 GMM_TILINGS = ((128, 512, 512), (128, 1024, 1024), (256, 1024, 1024),
@@ -160,13 +164,15 @@ def main() -> int:
     parent = load_parent(args.parent) if args.parent else None
 
     def block_with(min_rows, matmul=None, rows_a_tile=None):
-        """`_moe_block` taking the token form from `min_rows` rows, with
+        """`_moe_block` taking the token form from `min_rows` rows (and at
+        no fewer, whatever share the pass is expected to leave unread), with
         `matmul` in `grouped_matmul`'s place and `rows_a_tile` rows a tile
-        (the installed ones where None)."""
+        (the installed ones where None); -> (output, experts read)."""
         def block(x, lp, cfg, chunk_len):
             saved = (llama.TOKEN_DISPATCH_MIN_ROWS, gm.grouped_matmul,
-                     gm.tile_rows)
+                     gm.tile_rows, llama.TOKEN_DISPATCH_MIN_UNREAD)
             llama.TOKEN_DISPATCH_MIN_ROWS = min_rows
+            llama.TOKEN_DISPATCH_MIN_UNREAD = 2.0
             gm.grouped_matmul = matmul or saved[1]
             if rows_a_tile:
                 gm.tile_rows = lambda rows, groups: rows_a_tile
@@ -174,7 +180,7 @@ def main() -> int:
                 return llama._moe_block(x, lp, cfg, chunk_len)
             finally:
                 (llama.TOKEN_DISPATCH_MIN_ROWS, gm.grouped_matmul,
-                 gm.tile_rows) = saved
+                 gm.tile_rows, llama.TOKEN_DISPATCH_MIN_UNREAD) = saved
         return block
 
     # (the alternatives are handed the layer's own matrices, `rhs[layer]`:
@@ -201,7 +207,8 @@ def main() -> int:
     for tm, tk, tn in tilings:
         blocks[f"gmm_{tm}x{tk}x{tn}"] = block_with(0, gmm_at(tk, tn), tm)
     if parent is not None:
-        blocks["parent"] = lambda x, lp, cfg, chunk_len: parent(x, lp, cfg)
+        blocks["parent"] = lambda x, lp, cfg, chunk_len: (
+            parent(x, lp, cfg), cfg.num_experts)
     if args.forms:
         blocks = {form: block for form, block in blocks.items()
                   if form == "dense" or form.startswith(tuple(args.forms))}
@@ -210,7 +217,8 @@ def main() -> int:
     if on_chip:
         peak_flops, hbm_bytes_per_s, _ = device_peaks(jax.devices()[0])
     result = {"device": jax.devices()[0].device_kind, "args": vars(args),
-              "min_rows": llama.TOKEN_DISPATCH_MIN_ROWS, "forms": []}
+              "min_rows": llama.TOKEN_DISPATCH_MIN_ROWS,
+              "min_unread": llama.TOKEN_DISPATCH_MIN_UNREAD, "forms": []}
     rng = np.random.RandomState(args.seed % 2**31)
     for name in args.configs:
         cfg = model_config.config_from_hf_json(
@@ -246,17 +254,19 @@ def main() -> int:
                 # share one cached executable, and its name in the capture)
                 def fn(x, lp, chunk_len, block=block,
                        uid=list(blocks).index(form)):
-                    return block(x, lp, cfg, chunk_len), jnp.int32(uid)
+                    out, read = block(x, lp, cfg, chunk_len)
+                    return out, jnp.int32(read), jnp.int32(uid)
                 fn.__name__ = f"{tag}_{rows}_{form}"
                 jitted = jax.jit(fn)
                 try:
-                    out, _ = jitted(x, lp, chunk_len)
+                    out, read, _ = jitted(x, lp, chunk_len)
                     out.block_until_ready()
                 except Exception as e:  # a tiling the compiler refuses
                     print(f"{fn.__name__}: {type(e).__name__}: "
                           f"{str(e)[:300]}", file=sys.stderr)
                     continue
-                forms[fn.__name__] = (jitted, (x, lp, chunk_len), rows, form)
+                forms[fn.__name__] = (jitted, (x, lp, chunk_len), rows, form,
+                                      int(read))
                 outs[(rows, form)] = np.asarray(out, np.float32)
         for (rows, form), out in outs.items():
             assert np.isfinite(out).all(), (name, rows, form)
@@ -275,7 +285,10 @@ def main() -> int:
                 for jitted, a, *_ in forms.values():
                     jitted(*a)[0].block_until_ready()
         events, by_op = module_events(trace_dir, list(forms))
-        for fname, (_, (x, _, _), rows, form, err) in forms.items():
+        # the experts some row picked, as the token form counted them
+        picked = {rows: read for _, _, rows, form, read, _ in forms.values()
+                  if form == "token"}
+        for fname, (_, (x, _, _), rows, form, read, err) in forms.items():
             durs = events[fname]
             if len(durs) != args.reps:
                 print(f"{len(durs)} launches of {fname} in the capture, "
@@ -289,11 +302,17 @@ def main() -> int:
             nbytes = 3.0 * held * h * f * 2
             floor_us = 1e6 * max(flops / peak_flops, nbytes / hbm_bytes_per_s)
             row = {"config": name, "rows": rows, "form": form,
-                   "chosen": llama.moe_dispatch_form(rows, held, k, False),
+                   "chosen": llama.moe_dispatch_form(
+                       rows, held, k, False, cfg.num_router_experts),
                    "us": us, "min_us": min(durs) / 1e3,
                    "max_us": max(durs) / 1e3, "floor_us": floor_us,
                    "needed_flops": flops, "weight_bytes": nbytes,
+                   "experts_read": read, "experts_held": held,
                    "max_abs_diff_vs_dense": err}
+            if rows in picked:
+                row["picked_floor_us"] = 1e6 * max(
+                    flops / peak_flops,
+                    nbytes * picked[rows] / held / hbm_bytes_per_s)
             if args.ops:
                 top = sorted(by_op[fname].items(), key=lambda kv: -kv[1])
                 row["ops_us"] = {op: round(ns / 1e3, 1)
@@ -312,17 +331,24 @@ def main() -> int:
 
 
 def print_table(rows) -> None:
-    """us a layer by (config, rows) x form, the floor beside."""
+    """us a layer by (config, rows) x form; beside them the floor of
+    reading every held expert and (read / held) that of the picked ones."""
     forms = list(dict.fromkeys(r["form"] for r in rows))
-    print("| config | rows | chosen | floor | " + " | ".join(forms) + " |")
-    print("|---|---|---|---|" + "---|" * len(forms))
+    print("| config | rows | chosen | floor | picked floor (read / held) | "
+          + " | ".join(forms) + " |")
+    print("|---|---|---|---|---|" + "---|" * len(forms))
     cells = {}
     for r in rows:
         cells.setdefault((r["config"], r["rows"]), {})[r["form"]] = r
     for (config, n), by in cells.items():
         any_row = next(iter(by.values()))
+        least = "-"
+        if "token" in by:
+            least = (f"{by['token']['picked_floor_us']:.0f} "
+                     f"({by['token']['experts_read']} / "
+                     f"{any_row['experts_held']})")
         print(f"| {config} | {n} | {any_row['chosen']} | "
-              f"{any_row['floor_us']:.0f} | " + " | ".join(
+              f"{any_row['floor_us']:.0f} | {least} | " + " | ".join(
                   f"{by[f]['us']:.0f}" if f in by else "-" for f in forms)
               + " |")
 

@@ -243,9 +243,9 @@ def test_the_eight_held_shares_add_up_to_the_uncut_layer():
                       init_params(whole, jax.random.PRNGKey(5))["layers"])
     x = jax.random.normal(jax.random.PRNGKey(6), (2, 9, h), jnp.float32)
     with jax.default_matmul_precision("highest"):
-        uncut = _moe_block(x, lp, whole)
+        uncut, _ = _moe_block(x, lp, whole)
         shared = uncut - _moe_block(
-            x, lp, whole.replace(shared_intermediate_size=0))
+            x, lp, whole.replace(shared_intermediate_size=0))[0]
         routed = jnp.zeros_like(uncut)
         hp = dict(kexaone.hyper(whole.replace(
             num_experts_routed=E, num_experts=held)))
@@ -255,7 +255,7 @@ def test_the_eight_held_shares_add_up_to_the_uncut_layer():
                                   expert_offset=i * held)
             part = dict(lp, **{k: lp[k][i * held:(i + 1) * held]
                                for k in ("wg", "wu", "wd")})
-            routed = routed + (_moe_block(x, part, share) - shared)
+            routed = routed + (_moe_block(x, part, share)[0] - shared)
             out, _ = kexaone._moe(x.reshape(18, h), part, dict(
                 hp, expert_offset=i * held, skip_shared=True))
             ref_routed += np.asarray(out)

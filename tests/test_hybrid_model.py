@@ -335,11 +335,12 @@ def test_fused_multi_decode_equals_single_steps(model):
                   jnp.asarray([True, True]), jnp.zeros(2),
                   jnp.zeros(2, jnp.int32), jnp.ones(2),
                   jnp.zeros(2, jnp.uint32))
-    k, v, toks, last, lens = progs.multi_decode(4)(params, *pools(), lanes)
+    k, v, toks, last, lens, _ = progs.multi_decode(4)(
+        params, *pools(), lanes)
     k1, v1 = pools()
     step, seq = lanes, []
     for _ in range(4):
-        k1, v1, t, n = progs.decode()(params, k1, v1, step, None)
+        k1, v1, t, n, _ = progs.decode()(params, k1, v1, step, None)
         seq.append(np.asarray(t))
         step = step._replace(last_tokens=t, seq_lens=n)
     assert np.array_equal(np.asarray(toks), np.stack(seq))

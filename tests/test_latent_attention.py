@@ -433,8 +433,9 @@ def test_shared_branch_is_always_on(model):
     cfg, params = model
     lp = {k: v[0] for k, v in params["layers"].items()}
     x = jax.random.normal(jax.random.PRNGKey(1), (2, 5, cfg.hidden_size))
-    full = _moe_block(x, lp, cfg)
-    without = _moe_block(x, dict(lp, ws_d=jnp.zeros_like(lp["ws_d"])), cfg)
+    full, _ = _moe_block(x, lp, cfg)
+    without, _ = _moe_block(
+        x, dict(lp, ws_d=jnp.zeros_like(lp["ws_d"])), cfg)
     shared = _swiglu(_np(x), _np(lp["ws_g"]), _np(lp["ws_u"]), _np(lp["ws_d"]))
     np.testing.assert_allclose(_np(full - without), shared, atol=1e-5)
     assert np.abs(shared).min(axis=-1).max() > 0  # every token, non-zero

@@ -48,7 +48,7 @@ class TestMoEBlock:
                               jnp.float32)
         for layer in range(cfg.num_layers):
             lp = {k: v[layer] for k, v in params["layers"].items()}
-            got = _moe_block(x, lp, cfg)
+            got, _ = _moe_block(x, lp, cfg)
             ref = moe_mlp_reference(
                 x.reshape(-1, cfg.hidden_size),
                 {"router": lp["router"], "wg": lp["wg"], "wu": lp["wu"],

@@ -80,9 +80,10 @@ def layer(cfg, how=None, key=0):
 def both_forms(monkeypatch, x, lp, cfg, chunk_len=None):
     assert moe_dispatch_form(x.shape[0] * x.shape[1], cfg.num_experts,
                              cfg.num_experts_per_tok, False) == "token"
-    token = _moe_block(x, lp, cfg, chunk_len)
+    token, _ = _moe_block(x, lp, cfg, chunk_len)
     monkeypatch.setattr(llama, "TOKEN_DISPATCH_MIN_ROWS", 1 << 30)
-    dense = _moe_block(x, lp, cfg, chunk_len)
+    monkeypatch.setattr(llama, "TOKEN_DISPATCH_MIN_UNREAD", 2.0)
+    dense, _ = _moe_block(x, lp, cfg, chunk_len)
     monkeypatch.undo()
     return np.asarray(token), np.asarray(dense)
 
@@ -183,8 +184,10 @@ def test_the_rule_at_every_routed_configuration(name):
     cfg = config_from_hf_json(
         os.path.join(ROOT, "benchmarks", "configs", name + ".json"))
     held, k = cfg.num_experts, cfg.num_experts_per_tok
-    for rows in (1, 16, 32):  # decode lanes
-        assert moe_dispatch_form(rows, held, k, False) == "dense"
+    # a single stream, and the benchmark's one-lane logit check (the decode
+    # lanes: tests/test_decode_token_dispatch.py)
+    assert moe_dispatch_form(1, held, k, False,
+                             cfg.num_router_experts) == "dense"
     for rows in (512, 2048):  # the large prefill buckets, lanes x bucket
         assert moe_dispatch_form(rows, held, k, False) == "token"
         assert moe_dispatch_form(rows, held, k, True) == "dense"  # a mesh
@@ -201,7 +204,7 @@ def test_the_block_traces_the_form_the_rule_names():
     def prims(rows, sharded):
         x = jnp.zeros((1, rows, cfg.hidden_size))
         text = str(jax.make_jaxpr(
-            lambda x: _moe_block(x, lp, cfg, None, sharded))(x))
+            lambda x: _moe_block(x, lp, cfg, None, sharded)[0])(x))
         return "name=gmm" in text  # the grouped matmul's jitted entry
     rows = TOKEN_DISPATCH_MIN_ROWS
     assert prims(rows, False) and not prims(rows - 1, False)

@@ -483,15 +483,15 @@ def test_the_eight_shares_add_up_to_the_uncut_layer(model):
         lp[name] = jax.random.normal(jax.random.fold_in(key, i), shape) * 0.1
     x = jax.random.normal(key, (2, 7, 64))
     with jax.default_matmul_precision("highest"):
-        uncut = _moe_block(x, lp, whole)
-        shared = _moe_block(x, lp, whole) - _moe_block(
-            x, lp, whole.replace(shared_intermediate_size=0))
+        uncut, _ = _moe_block(x, lp, whole)
+        shared = uncut - _moe_block(
+            x, lp, whole.replace(shared_intermediate_size=0))[0]
         parts = 0
         for off in range(0, 16, 4):
             share = cfg.replace(expert_offset=off)
             held = dict(lp, **{n: lp[n][off:off + 4]
                                for n in ("wg", "wu", "wd")})
-            parts = parts + _moe_block(x, held, share) - shared
+            parts = parts + _moe_block(x, held, share)[0] - shared
     np.testing.assert_allclose(np.asarray(parts + shared), np.asarray(uncut),
                                rtol=1e-4, atol=1e-5)
     assert float(jnp.abs(parts).max()) > 1e-2

@@ -342,13 +342,14 @@ def test_programs_run_without_an_engine():
     singles = []
     for _ in range(4):
         out = progs.decode()(params, k, v, lanes, None)
-        assert len(out) == 4
-        k, v, toks, lens = out
+        # (last: the experts its routed blocks read; None, no routed block)
+        assert len(out) == 5 and out[-1] is None
+        k, v, toks, lens, _ = out
         lanes = lanes._replace(last_tokens=toks, seq_lens=lens)
         singles.append(np.asarray(toks))
     k, v, lanes = prefilled()
     out = progs.multi_decode(4)(params, k, v, lanes)
-    assert len(out) == 5
+    assert len(out) == 6 and out[-1] is None
     np.testing.assert_array_equal(
         np.asarray(out[2])[:, :2], np.stack(singles)[:, :2])
     np.testing.assert_array_equal(np.asarray(out[4]), [9, 6, 0])
@@ -362,7 +363,7 @@ def test_programs_run_without_an_engine():
               jnp.zeros((4, 2), jnp.int32), jnp.zeros(4, jnp.int32),
               jnp.int32(0))
     out = progs.multi_decode(4, fsm)(params, k, v, lanes, fsm)
-    assert len(out) == 7
+    assert len(out) == 8 and out[-1] is None
     np.testing.assert_array_equal(
         np.asarray(out[2])[:, :2], np.stack(singles)[:, :2])
     np.testing.assert_array_equal(np.asarray(out[6]), [96, 96, 100])
