@@ -1,0 +1,88 @@
+"""`index_shared_key_share` (PR 49): the reader on synthetic counters, its
+entry in BENCHMARK.json, and the CPU rehearsal of three tiny cells under
+`benchmarks/tests/index_shared/`: an indexer under a system prompt longer than
+one trip of its walk (the share well above 0), the tiny dots3 whose threads
+share less than a trip (a lane shares a trip only with itself, where it
+decodes alone), and a model with no indexer (the counters stay 0: the line
+lacks the metric)."""
+
+import json
+import os
+import subprocess
+import sys
+
+import pytest
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+BENCH = os.path.dirname(HERE)
+ROOT = os.path.dirname(BENCH)
+sys.path.insert(0, BENCH)
+
+import named  # noqa: E402
+
+TWIN = os.path.join(HERE, "index_shared")
+NAME = "index_shared_key_share"
+
+
+def engine(shared, scored):
+    return {"engine": {"index_keys_shared": shared,
+                       "index_keys_scored": scored}}
+
+
+@pytest.mark.parametrize("before,after,want", [
+    (engine(1000, 1200), engine(27100, 30200), 90.0),  # 13 of 15 trips
+    (engine(0, 500), engine(0, 4500), 0.0),            # nothing in common
+    (engine(0, 0), engine(0, 0), None),                # no indexer
+    (engine(7, 9), engine(7, 9), None),                # no decode step in it
+    ({"engine": {"index_keys_scored": 5}},
+     {"engine": {"index_keys_scored": 9}}, None),      # the parent: no counter
+    ({}, None, None),
+])
+def test_the_reader_reads_the_window_or_nothing(before, after, want):
+    value = named.load((BENCH,), "layer_metrics", NAME).read(
+        {"before": before, "after": after})
+    assert value == (want if want is None else pytest.approx(want))
+
+
+def test_the_entry_lists_the_cells_with_an_indexer():
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        real = json.load(f)
+    entry = real["per_layer"][-1]
+    assert entry == {
+        "name": NAME, "unit": "%", "better": "higher",
+        "source": "program_counter", "layer": "jitted step programs",
+        "moves": "tpot_p50_ms", "workloads": ["dots3-note-prev.chat-decode"]}
+    files = {c["name"]: c["file"] for c in real["configs"]}
+    for cell in real["workloads"]:
+        with open(os.path.join(ROOT, files[cell["config"]])) as f:
+            indexed = "index_topk" in json.load(f)
+        assert (cell["name"] in entry["workloads"]) == indexed, cell["name"]
+    with open(os.path.join(TWIN, "BENCHMARK.json")) as f:
+        twin = json.load(f)
+    listed = dict(entry)
+    del listed["workloads"]  # the twin asks every cell: a reader says None
+    assert listed in twin["per_layer"]
+
+
+@pytest.mark.parametrize("cell,share", [
+    # ~2.5k keys a lane, 2,048 of them one shared trip
+    ("tiny-shared.chat-decode", (60.0, 95.0)),
+    ("tiny-dots3.chat-decode", (0.0, 100.0)),
+    ("tiny-dense.chat-decode", None),
+])
+def test_rehearsal_of_the_tiny_cells(cell, share):
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    p = subprocess.run(
+        [sys.executable, os.path.join(BENCH, "run.py"), "--root", TWIN,
+         "--workload", cell, "--seed", "3000000019", "--seconds", "6",
+         "--trace", "1", "--rehearse"],
+        cwd=ROOT, env=env, timeout=400, capture_output=True, text=True)
+    assert p.returncode == 0, p.stdout[-2000:] + p.stderr[-2000:]
+    line = json.loads(p.stdout.strip().splitlines()[-1])
+    assert line["correct"] is False and line["device"]["platform"] == "cpu"
+    assert line["attempted"] > 0 and line["failed"] == 0
+    if share is None:
+        assert NAME not in line["metrics"]
+        return
+    value = line["metrics"][NAME]
+    assert value["unit"] == "%" and share[0] <= value["value"] <= share[1]

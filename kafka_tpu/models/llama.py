@@ -1254,8 +1254,11 @@ def _index_projections(x, c_q, lp: Params, cfg: ModelConfig, cos, sin):
 
 def _index_scores(q_idx, w_idx, k_idx) -> jnp.ndarray:
     """I[t, s] = sum_j w[t, j] * relu(q^I[t, j] . k^I[s]) in f32.  q_idx
-    [B, S, Hi, Di], w_idx [B, S, Hi] f32, k_idx [B, T, Di] -> [B, S, T]."""
-    dots = jnp.einsum("bsnd,btd->bsnt", q_idx, k_idx,
+    [B, S, Hi, Di], w_idx [B, S, Hi] f32, k_idx [B, T, Di] -> [B, S, T];
+    k_idx [T, Di] where every lane scores the SAME keys: one
+    [B * S * Hi, Di] x [Di, T] product, the keys read once."""
+    keys = "btd" if k_idx.ndim == 3 else "td"
+    dots = jnp.einsum(f"bsnd,{keys}->bsnt", q_idx, k_idx,
                       preferred_element_type=jnp.float32)
     return jnp.einsum("bsn,bsnt->bst", w_idx, jax.nn.relu(dots))
 
@@ -1384,15 +1387,34 @@ def _walk_chunks(paged: "PagedView", cp: int):
     return table, trips
 
 
-def _paged_index_choice(q_idx, w_idx, i_cache, paged: "PagedView", positions,
-                        cfg: ModelConfig, dt, as_mask: bool = False):
-    """The selection step over a paged pool: index scores of every query
-    against the lane's live keys, walked chunk by chunk off the indexer's
-    own pool rows (f32 scores [B, S, C]; keys past the live context stay
-    unscored and unchosen), then the exact top-k of the causal ones.
-    Returns for decode (S = 1) the chosen keys' pool slots and which of
-    them are real, (slots [B, K], ok [B, 1, K]); with `as_mask` (None,
-    chosen [B, S, C]) for a walk that masks."""
+def _common_pages(paged: "PagedView"):
+    """(lane, common): the first lane that holds keys, and how many of the
+    page table's leading columns name that lane's page in EVERY lane that
+    holds keys (a prefix attached to all of them: the same physical pages
+    in the same columns).  A lane without a valid key (idle, its row on the
+    trash page) does not end the run; a lane shorter than the others ends
+    it past its own last page, where its columns differ."""
+    table = paged.page_table
+    held = jnp.sum(paged.kv_valid, axis=-1) > 0
+    lane = jnp.argmax(held)
+    same = jnp.all((table == table[lane][None, :]) | ~held[:, None], axis=0)
+    cols = jnp.arange(same.shape[0], dtype=jnp.int32)
+    return lane, jnp.min(jnp.where(same, same.shape[0], cols))
+
+
+def _paged_index_scores(q_idx, w_idx, i_cache, paged: "PagedView",
+                        dt) -> jnp.ndarray:
+    """Index scores of every query against the lanes' live keys, f32
+    [B, S, C], walked chunk by chunk off the indexer's own pool rows (keys
+    past the longest live context stay unscored, 0).
+
+    At decode (S = 1) the walk splits where the lanes' page tables part
+    (`_common_pages`): a trip whose pages every lane shares reads them ONCE
+    and scores all lanes against them in one product (an indexer key is
+    rotated by position, not by lane), whole trips only; from there on, and
+    from trip 0 where the lanes share nothing, a trip gathers each lane's
+    own pages.  The same scores either way, in the same places.  A prefill
+    chunk (S > 1) is one lane's rows against its own keys and never splits."""
     ps = paged.page_size
     b, s = q_idx.shape[:2]
     di = q_idx.shape[-1]
@@ -1400,17 +1422,38 @@ def _paged_index_choice(q_idx, w_idx, i_cache, paged: "PagedView", positions,
     cp = walk_pages(paged.page_table.shape[1], ps, INDEX_WALK_KEYS,
                     b * s if s > 1 else 1)
     table, trips = _walk_chunks(paged, cp)
-    ck = cp * ps
 
-    def score(c, scores):
-        pages = jax.lax.dynamic_slice_in_dim(table, c * cp, cp, axis=1)
-        keys = _read_pages(i_cache, pages, ps, dt)[..., :di]
-        part = _index_scores(q_idx, w_idx, keys)
-        return jax.lax.dynamic_update_slice_in_dim(scores, part, c * ck, 2)
+    def score(rows):
+        """A trip over `rows`: every lane's page-table row [B, P], or the
+        one row [P] all of them share."""
+        def trip(c, scores):
+            pages = jax.lax.dynamic_slice_in_dim(rows, c * cp, cp, axis=-1)
+            keys = _read_pages(i_cache, pages, ps, dt)[..., :di]
+            return jax.lax.dynamic_update_index_in_dim(
+                scores, _index_scores(q_idx, w_idx, keys), c, 0)
+        return trip
 
-    scores = jax.lax.fori_loop(
-        0, trips, score,
-        jnp.zeros((b, s, table.shape[1] * ps), jnp.float32))[..., :C]
+    # held trip-major while the walk runs: a trip's scores land in one
+    # block (as a slice of [B, S, C]'s key axis they are B x S strided rows,
+    # 15 us a trip at decode on the v5e: twice the product that makes them)
+    scores = jnp.zeros((table.shape[1] // cp, b, s, cp * ps), jnp.float32)
+    own = 0  # the first trip that gathers lane by lane
+    if s == 1:
+        lane, common = _common_pages(paged)
+        own = jnp.minimum(common // cp, trips)
+        scores = jax.lax.fori_loop(0, own, score(table[lane]), scores)
+    scores = jax.lax.fori_loop(own, trips, score(table), scores)
+    return jnp.moveaxis(scores, 0, 2).reshape(b, s, -1)[..., :C]
+
+
+def _paged_index_choice(q_idx, w_idx, i_cache, paged: "PagedView", positions,
+                        cfg: ModelConfig, dt, as_mask: bool = False):
+    """The selection step over a paged pool: `_paged_index_scores`, then
+    the exact top-k of each query's causal keys.  Returns for decode (S = 1)
+    the chosen keys' pool slots and which of them are real, (slots [B, K],
+    ok [B, 1, K]); with `as_mask` (None, chosen [B, S, C]) for a walk that
+    masks."""
+    scores = _paged_index_scores(q_idx, w_idx, i_cache, paged, dt)
     mask = (paged.kv_valid[:, None, :]
             & (paged.kv_positions[:, None, :] <= positions[:, :, None]))
     chosen = _chosen_mask(scores, mask, cfg.index_topk)

@@ -1375,9 +1375,13 @@ class InferenceEngine:
         # (StepPrograms.index_keys): the keys a layer's indexer scored over
         # every dispatched decode step (each lane's context, its own row
         # included) and the keys attention then read (at most index_topk a
-        # lane).  kept / scored is what the selection keeps.
+        # lane).  kept / scored is what the selection keeps; `shared` are
+        # the scored keys that sat in pages every lane of the dispatch held
+        # in the same leading columns of its page table and were scored for
+        # all of them in one product (StepPrograms.index_keys_shared).
         self.index_keys_scored = 0
         self.index_keys_kept = 0
+        self.index_keys_shared = 0
         # Monotonic, and 0 where prefill does not walk the keys in chunks
         # (StepPrograms.prefill_walk_trips): the trips latent prefill's key
         # walk looped over every dispatched launch and layer, and those of
@@ -4357,10 +4361,13 @@ class InferenceEngine:
         self.decode_keys_window += window
         self._count_moe_dispatch(len(members), steps)
         if self.cfg.index_topk:
+            seqs = [m.seq for m in members if m is not None]
             scored, kept = self._programs.index_keys(
-                [m.seq.length for m in members if m is not None], steps)
+                [seq.length for seq in seqs], steps)
             self.index_keys_scored += scored
             self.index_keys_kept += kept
+            self.index_keys_shared += self._programs.index_keys_shared(
+                [(seq.pages, seq.length) for seq in seqs], steps)
         # decode-span inputs, computed lazily on the FIRST traced member:
         # an all-untraced dispatch pays one branch per lane, nothing else
         now_mono: Optional[float] = None

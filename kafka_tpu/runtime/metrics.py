@@ -425,8 +425,16 @@ METRICS: Tuple[Metric, ...] = (
         _m("prefill_rows_filled", read="prefill_rows_filled"),
         _m("decode_keys_walked", read="decode_keys_walked"),
         _m("decode_keys_window", read="decode_keys_window"),
-        _m("index_keys_scored", read="index_keys_scored"),
-        _m("index_keys_kept", read="index_keys_kept"),
+        _m("index_keys_scored",
+           "kafka_tpu_engine_index_keys_total{kind=scored}",
+           "Keys a layer's indexer scored over the decode steps, those "
+           "attention then read, and those scored once for every lane "
+           "(pages the lanes share).", read="index_keys_scored"),
+        _m("index_keys_kept", "kafka_tpu_engine_index_keys_total{kind=kept}",
+           read="index_keys_kept"),
+        _m("index_keys_shared",
+           "kafka_tpu_engine_index_keys_total{kind=shared}",
+           read="index_keys_shared"),
         _m("prefill_walk_trips", read="prefill_walk_trips"),
         _m("prefill_walk_kernel_trips", read="prefill_walk_kernel_trips"),
         _m("moe_dispatch", read="moe_dispatch"),

@@ -33,12 +33,14 @@ import jax.numpy as jnp
 
 from ..models.config import ModelConfig
 from ..models.llama import (
+    INDEX_WALK_KEYS,
     KVCache,
     PagedView,
     forward,
     experts_int8,
     moe_dispatch_form,
     prefill_walk_pages,
+    walk_pages,
 )
 from ..ops.attention import decode_walk_pages
 from ..ops.sampling import (
@@ -587,6 +589,31 @@ class StepPrograms:
         kept = sum(min(n + i + 1, topk) for n in lengths
                    for i in range(steps))
         return scored, kept
+
+    def index_keys_shared(self, lanes, steps: int) -> int:
+        """Of `index_keys`' scored keys, those scored through the walk's
+        SHARED trips: `lanes` [(pages, tokens held before the first step)]
+        of the dispatch's active lanes, by the device's own arithmetic
+        (models/llama.py _common_pages, _paged_index_choice): the leading
+        columns in which every lane's page-table row names one page, whole
+        trips of them, up to each lane's live context."""
+        if not (self.cfg.index_topk and lanes):
+            return 0
+        cp = walk_pages(self.P, self.ps, INDEX_WALK_KEYS)
+        ck = cp * self.ps
+        # the rows part where their lexicographic extremes part; rows alike
+        # to the end are alike in the trash columns behind them too
+        lo, hi = min(p for p, _ in lanes), max(p for p, _ in lanes)
+        common = self.P if lo == hi else next(
+            (j for j, (a, b) in enumerate(zip(lo, hi)) if a != b),
+            min(len(lo), len(hi)))
+        longest = max(n for _, n in lanes)
+        total = 0
+        for i in range(steps):
+            trips = min(-(-(longest + i + 1) // ck), -(-self.P // cp))
+            shared = min(common // cp, trips) * ck
+            total += sum(min(n + i + 1, shared) for _, n in lanes)
+        return total
 
     def prefill_walk_trips(self, spans, width: int,
                            bucket: int) -> Tuple[int, int]:

@@ -73,15 +73,15 @@ GMM_MIN_ROWS = 256
 
 
 def load_parent(tree):
-    """`_moe_block` of the tree unpacked at `tree`, under a module name of
-    its own inside the installed package (its relative imports resolve
-    there; the installed block stays what it is)."""
+    """`models/llama.py` of the tree unpacked at `tree`, under a module name
+    of its own inside the installed package (its relative imports resolve
+    there; the installed module stays what it is)."""
     path = os.path.join(tree, "kafka_tpu", "models", "llama.py")
     spec = importlib.util.spec_from_file_location(
         "kafka_tpu.models.parent_llama", path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
-    return mod._moe_block
+    return mod
 
 
 def module_events(trace_dir, names):
@@ -161,7 +161,7 @@ def main() -> int:
     if args.rehearse:
         args.rows, args.reps = [16, 96], 1
         tilings = ((128, 128, 128),) if args.gmm else ()
-    parent = load_parent(args.parent) if args.parent else None
+    parent = load_parent(args.parent)._moe_block if args.parent else None
 
     def block_with(min_rows, matmul=None, rows_a_tile=None):
         """`_moe_block` taking the token form from `min_rows` rows (and at

@@ -295,13 +295,16 @@ PARENT_TEXTS = {
     "gqa.xla.bprefill": "e9677dad74f5b5fb",
     # (the one program of the eight that calls the flash-prefill kernel,
     # whose chunk step PR 44 rewrote: this digest is PR 44's text, recorded
-    # the same way; the other seven still read as fe610bc lowered them)
+    # the same way)
     "gqa.pallas.prefill": "b608355daa0eae32",
     "gqa.pallas.bprefill": "e9677dad74f5b5fb",
-    "sparse.xla.prefill": "b20ab85bf7399e42",
-    "sparse.xla.bprefill": "8179126e3b4db6c4",
-    "sparse.pallas.prefill": "1fa6d80d746bb962",
-    "sparse.pallas.bprefill": "069864daa438e2ef",
+    # (the four that trace the indexer's walk, whose scores PR 49 holds
+    # trip-major until the loops end: these digests are PR 49's text,
+    # recorded the same way; the walk itself still gathers lane by lane)
+    "sparse.xla.prefill": "d7293a9eab2bdb2e",
+    "sparse.xla.bprefill": "1bcc39c46b242d29",
+    "sparse.pallas.prefill": "59854056271026e8",
+    "sparse.pallas.bprefill": "331da4948836c36a",
 }
 
 

@@ -592,6 +592,28 @@ class TestMetricTable:
         assert families["kafka_tpu_zz_probe_total"] == "counter"
         assert ("kafka_tpu_zz_probe_total", {"who": "me"}, 14.0) in samples
 
+    @pytest.mark.parametrize("kind", ["scored", "kept", "shared"])
+    def test_the_indexer_key_counts_reach_all_three(self, live_engine,
+                                                    monkeypatch, kind):
+        """engine.index_keys_scored / _kept / _shared (the last PR 49's: the
+        keys a decode pass scored once for every lane) are the engine's
+        fields under one family: in the replica snapshot, summed by the
+        merge of two, one sample a kind in the text."""
+        from kafka_tpu.runtime import metrics as M
+
+        key = "index_keys_" + kind
+        monkeypatch.setattr(live_engine, key, 2048 * 3)
+        snap = live_engine.metrics.snapshot(live_engine, reset_peak=False)
+        assert snap["engine"][key] == 6144
+        merged = M.merge_snapshots([snap, snap])
+        assert merged["engine"][key] == 12288
+        families, samples = parse_exposition(render_prometheus(merged))
+        family = "kafka_tpu_engine_index_keys_total"
+        assert families[family] == "counter"
+        assert (family, {"kind": kind}, 12288.0) in samples
+        assert sorted(l["kind"] for n, l, _ in samples if n == family) == [
+            "kept", "scored", "shared"]
+
 
 class TestGoldens:
     """Recorded at the parent commit 646876c by

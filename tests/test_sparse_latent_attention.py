@@ -40,6 +40,7 @@ from kafka_tpu.models.config import (
     UnsupportedConfigError,
     config_from_hf_json,
 )
+from kafka_tpu.models import llama
 from kafka_tpu.models.llama import (
     INDEX,
     _chosen_mask,
@@ -54,6 +55,7 @@ from kafka_tpu.runtime.engine import (
     WindowedAttentionUnsupported,
 )
 from kafka_tpu.runtime.kv_cache import make_kv_pool_arrays
+from kafka_tpu.runtime.step_programs import decode_plan
 from test_engine import assert_greedy_consistent
 from test_latent_attention import PARENT_PLANS, _plan
 
@@ -618,6 +620,94 @@ def test_per_kind_keys_need_latent_attention():
     with pytest.raises(UnsupportedConfigError, match="latent"):
         ModelConfig(attention_gate="headwise")
 
+def _tables(case):
+    """(page table [B, 32], tokens held [B], active [B], common) of one case
+    of the walk's split: pages of 4 keys, trips of 8 pages (INDEX_WALK_KEYS
+    patched to 32), page 0 the trash page, private pages from 100 on."""
+    P = 32
+    own = iter(range(100, 1000))
+    prefix = list(range(1, 1 + P))
+
+    def lane(shared, tokens, prefix=prefix):
+        pages = prefix[:shared]
+        pages += [next(own) for _ in range(tokens // 4 + 1 - shared)]
+        return pages + [0] * (P - len(pages)), tokens
+
+    idle = ([0] * P, 0)
+    other = list(range(40, 40 + P))
+    lanes, common, active = {
+        "nothing in common": ([lane(0, 77), lane(0, 90), lane(0, 41)], 0, ()),
+        "less than one trip": ([lane(5, 77), lane(5, 90), lane(5, 41)], 5, ()),
+        "two trips and a remainder": (
+            [lane(19, 100), lane(19, 81), lane(19, 126)], 19, ()),
+        # (three lanes reading one sequence's pages at three lengths)
+        "the whole table": (
+            [(prefix, 126), (prefix, 90), (prefix, 127)], P, ()),
+        "an idle lane on the trash page": (
+            [idle, lane(19, 100), idle, lane(19, 126)], 19,
+            [False, True, False, True]),
+        "a lane shorter than the run": (
+            [lane(19, 100), lane(10, 41), lane(19, 126)], 10, ()),
+        "two prefixes": (
+            [lane(19, 100), lane(19, 81), lane(19, 126, other),
+             lane(19, 90, other)], 0, ()),
+        "one lane": ([lane(19, 100)], P, ()),
+    }[case]
+    table = np.array([row for row, _ in lanes], np.int32)
+    lens = np.array([n for _, n in lanes], np.int32)
+    active = np.array(active or [True] * len(lanes))
+    return table, lens, active, common
+
+
+@pytest.mark.parametrize("case", [
+    "nothing in common", "less than one trip", "two trips and a remainder",
+    "the whole table", "an idle lane on the trash page",
+    "a lane shorter than the run", "two prefixes", "one lane"])
+def test_shared_trips_choose_what_per_lane_trips_choose(monkeypatch, case):
+    """Decode's index walk scores the pages every lane holds in the same
+    leading columns once for all lanes, whole trips of them: the same
+    scores, chosen slots and `ok` as the walk that gathers every trip lane
+    by lane (`_common_pages` answering 0).  The scores agree to the last
+    bit: a score is a sum over a head's 16 values, then over the 4 heads,
+    in one order whichever operand carries the lane axis."""
+    table, lens, active, common = _tables(case)
+    cfg = sparse_cfg(index_topk=TOPK)
+    ps, di, hi = 4, cfg.index_head_dim, cfg.index_n_heads
+    rng = np.random.RandomState(5)
+    b = table.shape[0]
+    pool = rng.standard_normal((1000 * ps, di)).astype(np.float32)
+    pool[:ps] = np.nan  # the trash page holds anything
+    pool = jnp.asarray(pool)
+    q = jnp.asarray(rng.standard_normal((b, 1, hi, di)), jnp.float32)
+    w = jnp.asarray(rng.standard_normal((b, 1, hi)), jnp.float32)
+
+    def fn(table, lens, active):
+        positions, paged = decode_plan(table, lens, active, ps)
+        scores = llama._paged_index_scores(q, w, pool, paged, jnp.float32)
+        slots, ok = llama._paged_index_choice(
+            q, w, pool, paged, positions, cfg, jnp.float32)
+        return jnp.where(paged.kv_valid[:, None], scores, 0), slots, ok
+
+    def run(split):
+        with monkeypatch.context() as m:
+            m.setattr(llama, "INDEX_WALK_KEYS", 32)
+            if not split:
+                m.setattr(llama, "_common_pages",
+                          lambda paged: (jnp.int32(0), jnp.int32(0)))
+            return [np.asarray(x) for x in jax.jit(fn)(table, lens, active)]
+
+    lane, found = jax.jit(lambda *a: llama._common_pages(
+        decode_plan(*a, ps)[1]))(table, lens, active)
+    assert (int(lane), int(found)) == (int(np.argmax(active)), common)
+    scores, slots, ok = run(split=True)
+    scores_own, slots_own, ok_own = run(split=False)
+    np.testing.assert_array_equal(scores, scores_own)
+    np.testing.assert_array_equal(ok, ok_own)
+    np.testing.assert_array_equal(slots[ok[:, 0]], slots_own[ok[:, 0]])
+    assert [int(n) for n in ok.sum(axis=(1, 2))] == [
+        min(TOPK, n + 1) if on else 0 for n, on in zip(lens, active)]
+    assert np.isfinite(scores).all() and scores[active].any(axis=-1).all()
+
 
 # ---------------------------------------------------------------------------
 # the engine
@@ -660,6 +750,13 @@ def test_engine_is_token_exact_and_counts_what_it_keeps(model, backend):
     assert e["index_keys_kept"] <= TOPK * 3 * 10
     scored, kept = eng._programs.index_keys([5, 20], 2)
     assert (scored, kept) == (6 + 7 + 21 + 22, 6 + 7 + 8 + 8)
+    # distinct prompts hold distinct pages: lanes that decode together
+    # share no trip, a lane that decodes alone shares its one trip with
+    # itself (8 pages of 8 keys: the whole table)
+    shared = eng._programs.index_keys_shared
+    assert shared([([3, 4], 5), ([5, 6, 7], 20)], 2) == 0
+    assert shared([([5, 6, 7], 20)], 2) == 21 + 22
+    assert 0 <= e["index_keys_shared"] < e["index_keys_scored"]
 
 
 @pytest.mark.parametrize("backend", ["xla", "pallas"])
@@ -682,6 +779,71 @@ def test_prefix_hit_then_suffix_prefill_is_token_exact(model, backend):
     eng.run_to_completion()
     assert second.cached_tokens >= 8 and second.cache_source == "cross"
     assert_greedy_consistent(cfg, params, prompt, second.output_ids)
+
+
+def _shared_keys(lanes, steps, P, ps, trip_keys=2048):
+    """`index_keys_shared` the plain way: the page table as the device sees
+    it, column by column."""
+    table = np.zeros((len(lanes), P), np.int64)
+    for row, (pages, _) in zip(table, lanes):
+        row[:len(pages)] = pages
+    same = np.cumprod((table == table[0]).all(axis=0))
+    cp = min(trip_keys // ps, P)
+    total = 0
+    for i in range(steps):
+        live = [n + i + 1 for _, n in lanes]
+        trips = min(-(-max(live) // (cp * ps)), -(-P // cp))
+        total += sum(min(n, min(int(same.sum()) // cp, trips) * cp * ps)
+                     for n in live)
+    return total
+
+
+@pytest.mark.parametrize("backend", ["xla", "pallas"])
+def test_lanes_on_one_attached_prefix_share_its_index_keys(model, backend):
+    """Three threads over one cached prefix of 2,080 tokens (260 pages, one
+    whole trip of the index walk and a remainder) decode together: their
+    tables name the prefix's pages in the same leading columns, the walk's
+    first trip scores them once for the three lanes, and every token is the
+    uncached forward's.  /metrics counts the keys scored that way, by the
+    device's arithmetic; a batch of distinct prompts shares none."""
+    cfg, params = model
+    P, ps = 288, 8
+    eng = make_engine(cfg, params, attention_backend=backend, num_pages=640,
+                      max_pages_per_seq=P, prefill_buckets=(64, 512))
+    dispatched, count = [], eng._programs.index_keys_shared
+
+    def recorded(lanes, steps):
+        dispatched.append(([(list(p), n) for p, n in lanes], steps))
+        return count(lanes, steps)
+
+    eng._programs.index_keys_shared = recorded
+    rng = np.random.RandomState(31)
+    shared = list(rng.randint(1, 128, size=2080))
+    eng.submit(GenRequest(request_id="first", prompt_ids=shared + [3, 7],
+                          max_new_tokens=2, prefix_key="thread-0"))
+    eng.run_to_completion()
+    alone = eng.index_keys_shared  # one lane shares its first trip
+    assert alone == sum(_shared_keys(*d, P, ps) for d in dispatched) > 0
+    # (one length: the uncached forward that checks them compiles once)
+    threads = {f"t{i}": shared + list(rng.randint(1, 128, size=9))
+               for i in range(3)}
+    reqs = {rid: GenRequest(request_id=rid, prompt_ids=p, max_new_tokens=6,
+                            prefix_key="thread-" + rid)
+            for rid, p in threads.items()}
+    for req in reqs.values():
+        eng.submit(req)
+    eng.run_to_completion()
+    for rid, req in reqs.items():
+        assert req.cached_tokens >= 2048 and req.cache_source == "cross"
+        assert_greedy_consistent(cfg, params, threads[rid], req.output_ids)
+    together = [d for d in dispatched if len(d[0]) == 3]
+    assert together and all(
+        _shared_keys(*d, P, ps) == 3 * 2048 * d[1] for d in together)
+    assert eng.index_keys_shared == sum(
+        _shared_keys(*d, P, ps) for d in dispatched)
+    e = eng.metrics.snapshot(eng)["engine"]
+    assert e["index_keys_shared"] == eng.index_keys_shared
+    assert e["index_keys_scored"] > e["index_keys_shared"] > alone
 
 
 @pytest.mark.parametrize("error, path, kw, mesh_axes", [
