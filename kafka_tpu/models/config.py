@@ -14,7 +14,10 @@ feed-forward tree stands on grouped-query attention too (`exaone_moe`:
 K-EXAONE), whose attention adds per-head QK-norm and full-attention layers
 that do not rotate, and under a second hybrid layout (`lfm2_moe`:
 LFM2-8B-A1B) whose layers are gated short convolutions, each with a two-row
-tail in a state slot, beside full-attention layers that alone hold rows.
+tail in a state slot, beside full-attention layers that alone hold rows, and
+under a third (`solar_open2`: Solar-Open2-250B) whose layers are gated
+delta-rule linear attention, each with a matrix a head in a state slot, beside
+gated full-attention layers that do not rotate.
 
 The reference service routed model names to remote providers by string
 heuristics (src/llm/utils.py:11-29); here a model name resolves to a local
@@ -38,16 +41,20 @@ from .vision import VisionConfig
 # (MAMBA), a gated memory unit with no state at all (GMU), and attention that
 # READS the last GLOBAL layer's rows and writes none (CROSS); CONV is
 # `lfm2_moe`'s gated short convolution, whose state is the last
-# conv_L_cache - 1 rows of its gated input and no rows either.
+# conv_L_cache - 1 rows of its gated input and no rows either; DELTA is
+# `solar_open2`'s gated delta-rule linear attention, whose state is a
+# [head_dim, head_dim] matrix a head beside the tails of its three short
+# convolutions, and no rows.
 WINDOWED = "sliding_attention"
 GLOBAL = "full_attention"
 MAMBA = "mamba"
 GMU = "gmu"
 CROSS = "cross_attention"
 CONV = "conv"
+DELTA = "linear_attention"
 ROW_KINDS = (WINDOWED, GLOBAL)
 # the kinds whose layers hold a recurrent state (a state slot a thread)
-STATE_KINDS = (MAMBA, CONV)
+STATE_KINDS = (MAMBA, CONV, DELTA)
 
 
 class UnsupportedConfigError(ValueError):
@@ -239,23 +246,44 @@ class ModelConfig:
     # QK-norm, the dense lead and the routed experts) is the lead-and-routed
     # tree's. --
     conv_L_cache: int = 0
+    # -- the third hybrid layout (`solar_open2`; `_delta_attention_block` in
+    # models/llama.py): `delta_heads` > 0 turns it on and `layer_types` then
+    # names DELTA layers beside GLOBAL ones, in any order.  A DELTA layer is
+    # the gated delta rule with a decay per key channel (Kimi Delta
+    # Attention): delta_heads heads of delta_head_dim keys and values, q / k /
+    # v each through a depthwise causal convolution of delta_conv_kernel taps
+    # and SiLU, the decay and the output gate through low-rank pairs of
+    # delta_head_dim, beta in (0, 2) where `delta_neg_eigval`.  Its per-thread
+    # state is the convolutions' delta_conv_kernel - 1 rows and S^T,
+    # [delta_heads * delta_head_dim, delta_head_dim] float32, in a state slot
+    # of its own shape. --
+    delta_heads: int = 0
+    delta_head_dim: int = 0
+    delta_conv_kernel: int = 4
+    delta_neg_eigval: bool = False
 
     def __post_init__(self):
         if self.moe_scoring not in ("softmax", "sigmoid"):
             raise UnsupportedConfigError(
                 f"moe_scoring {self.moe_scoring!r}: known 'softmax', "
                 "'sigmoid'")
-        if self.attention_gate not in ("", "headwise"):
+        if self.attention_gate not in ("", "headwise", "elementwise"):
             raise UnsupportedConfigError(
                 f"attention_gate_type {self.attention_gate!r} is not "
-                "served: only 'headwise' is")
+                "served: 'headwise' (latent attention) and 'elementwise' "
+                "(grouped-query attention) are")
         if (self.index_topk or self.windowed_latent or self.q_lora_rank
-                or self.attention_gate or self.latent_rescale) \
+                or self.attention_gate == "headwise" or self.latent_rescale) \
                 and not self.is_latent:
             raise UnsupportedConfigError(
-                "a query low-rank, a latent rescale, an attention gate, an "
-                "indexer and per-kind attention sizes are built with latent "
-                "attention (kv_lora_rank) only")
+                "a query low-rank, a latent rescale, a headwise attention "
+                "gate, an indexer and per-kind attention sizes are built "
+                "with latent attention (kv_lora_rank) only")
+        if self.attention_gate == "elementwise" and (
+                self.is_latent or self.mamba_d_state):
+            raise UnsupportedConfigError(
+                "attention_gate_type 'elementwise' is built on grouped-query "
+                "attention only (no latent attention, no Mamba decoder)")
         if self.index_topk and not (self.index_n_heads > 0
                                     and self.index_head_dim > 0):
             raise UnsupportedConfigError(
@@ -290,7 +318,8 @@ class ModelConfig:
             return
         known = {WINDOWED, GLOBAL} | (
             {MAMBA, GMU, CROSS} if self.mamba_d_state else set()) | (
-            {CONV} if self.conv_L_cache else set())
+            {CONV} if self.conv_L_cache else set()) | (
+            {DELTA} if self.delta_heads else set())
         bad = set(self.layer_types) - known
         if bad:
             raise UnsupportedConfigError(
@@ -300,6 +329,8 @@ class ModelConfig:
             self._check_hybrid()
         if self.conv_L_cache:
             self._check_conv_layout()
+        if self.delta_heads:
+            self._check_delta_layout()
         if len(self.layer_types) != self.num_layers:
             raise UnsupportedConfigError(
                 f"layer_types has {len(self.layer_types)} entries for "
@@ -365,6 +396,34 @@ class ModelConfig:
                 "conv layers stand beside grouped-query attention: no latent "
                 "attention, Mamba layers or vision tower")
 
+    def _check_delta_layout(self) -> None:
+        """The third hybrid layout (`solar_open2`): linear attention and full
+        attention in whatever order `layer_types` gives, judged by what the
+        program needs of it, as the conv layout is: a matrix to carry, a
+        tail to carry (two taps or more), rows for some layer to hold,
+        grouped-query attention, and no second kind of state."""
+        kinds = set(self.layer_types)
+        if DELTA not in kinds or kinds - {DELTA, GLOBAL}:
+            raise UnsupportedConfigError(
+                "linear attention is served with layer_types of "
+                "linear_attention and full_attention layers; layer_types is "
+                f"{list(self.layer_types)}")
+        if GLOBAL not in kinds:
+            raise UnsupportedConfigError(
+                "layer_types names no full_attention layer: the paged pool "
+                "and the prefix cache need one layer that holds rows")
+        if self.delta_head_dim <= 0 or self.delta_conv_kernel < 2:
+            raise UnsupportedConfigError(
+                f"linear attention needs a head size (delta_head_dim = "
+                f"{self.delta_head_dim}) and a short convolution of two taps "
+                f"or more (delta_conv_kernel = {self.delta_conv_kernel})")
+        if (self.is_latent or self.mamba_d_state or self.conv_L_cache
+                or self.vision is not None):
+            raise UnsupportedConfigError(
+                "linear-attention layers stand beside grouped-query "
+                "attention: no latent attention, Mamba or conv layers, or "
+                "vision tower")
+
     @property
     def is_moe(self) -> bool:
         return self.num_experts > 0
@@ -404,7 +463,13 @@ class ModelConfig:
         (runtime/kv_cache.make_state_arrays), the memory plan and /metrics
         ask, of the KIND of state layer the model has.  A Mamba layer: the
         conv tail and `ssm`, h transposed, [d_state, inner] (the wide axis in
-        the lanes).  A short convolution: its tail alone."""
+        the lanes).  A short convolution: its tail alone.  A linear-attention
+        layer: the tails of its three convolutions side by side (q | k | v),
+        their taps - 1 rows laid out over 8 rows where they divide so (a leaf
+        whose second-minor axis is 3 is tiled to 8 on the device: 2.7x the
+        bytes, and the layer scan copies such a leaf whole every pass), and
+        `delta`, S transposed a head, the heads stacked along the rows
+        (ops/pallas/gated_delta.py)."""
         if MAMBA in self.layer_types:
             di = self.mamba_d_inner
             return (("conv", (self.mamba_d_conv - 1, di)),
@@ -412,6 +477,12 @@ class ModelConfig:
         if CONV in self.layer_types:
             # the rows of B * u before the pass: nothing accumulates
             return (("conv", (self.conv_L_cache - 1, self.hidden_size)),)
+        if DELTA in self.layer_types:
+            wide = self.delta_heads * self.delta_head_dim
+            tail = (self.delta_conv_kernel - 1, 3 * wide)
+            if tail[0] * tail[1] % 8 == 0:
+                tail = (8, tail[0] * tail[1] // 8)
+            return (("conv", tail), ("delta", (wide, self.delta_head_dim)))
         return ()
 
     @property
@@ -487,16 +558,19 @@ class ModelConfig:
         return bool(self.is_latent or self.first_k_dense
                     or self.shared_intermediate_size
                     or self.moe_scoring != "softmax"
-                    or CONV in self.layer_types)
+                    or CONV in self.layer_types
+                    or DELTA in self.layer_types)
 
     @property
     def kind_leaves(self) -> bool:
         """The mixer's leaves are stacked per KIND of layer, under
         `params["attn"][kind]` in layer order, and "layers" /
         "dense_layers" hold the norms and the feed-forward leaves: a latent
-        model whose kinds differ, and the conv layout (a CONV layer's leaves
-        have nothing in common with an attention layer's)."""
-        return self.by_kind or CONV in self.layer_types
+        model whose kinds differ, and the conv and linear-attention layouts
+        (a CONV or DELTA layer's leaves have nothing in common with an
+        attention layer's)."""
+        return (self.by_kind or CONV in self.layer_types
+                or DELTA in self.layer_types)
 
     @property
     def by_kind(self) -> bool:
@@ -541,7 +615,7 @@ class ModelConfig:
                      if self.has_indexer(kind) else ())
             return (g.kv_lora_rank, _lane_tiles(g.qk_rope_head_dim)) + index
         if kind not in ROW_KINDS:
-            return ()  # mamba / gmu / cross / conv layers hold no rows
+            return ()  # mamba / gmu / cross / conv / delta layers: no rows
         return (self.num_kv_heads * self.head_dim,) * 2
 
     @property
@@ -850,7 +924,8 @@ def _routed_lead_keys(hf: dict) -> dict:
     latent model (`_latent_keys` reads those) and for one without the keys.
     What is not served is an UnsupportedConfigError, by key."""
     if hf.get("kv_lora_rank") or not (
-            "first_k_dense_replace" in hf or "scoring_func" in hf):
+            "first_k_dense_replace" in hf or "scoring_func" in hf
+            or hf.get("model_type") == "solar_open2"):
         return {}
     served = (
         ("scoring_func", "sigmoid", "another router scoring function"),
@@ -985,6 +1060,66 @@ def _conv_keys(hf: dict) -> dict:
     return out
 
 
+def _delta_keys(hf: dict) -> dict:
+    """The keys of a `solar_open2` config.json (Solar-Open2-250B: gated
+    delta-rule linear attention beside gated full attention that does not
+    rotate, every layer routed) as ModelConfig fields; {} for any other
+    model.  The feed-forward keys are `_routed_lead_keys`' (the family's
+    modeling code derives from `glm4_moe`: sigmoid scores, a selection bias,
+    one shared expert).  What the config has no key for (the low-rank width
+    of the decay and the output gate, the position of the attention gate, the
+    router's scoring) is listed as `assumed` beside the benchmark's copy of
+    the file.  What is not served is an UnsupportedConfigError, by key."""
+    if hf.get("model_type") != "solar_open2":
+        return {}
+    served = (
+        ("kda_use_full_proj", False, "a full-rank decay projection"),
+        ("partial_rotary_factor", 1, "a partial rotation"),
+        ("rope_scaling", None, "scaled rotary positions"),
+        ("hidden_act", "silu", "another MLP activation"),
+        ("attention_bias", False, "attention biases"),
+    )
+    _refuse_unless(hf, served)
+    n = int(hf["num_hidden_layers"])
+    gqa = sorted(int(i) for i in hf.get("gqa_layers") or ())
+    if not gqa:
+        raise UnsupportedConfigError(
+            "gqa_layers names no layer: the paged pool and the prefix cache "
+            "need one full-attention layer that holds rows")
+    every = int(hf.get("gqa_interval", 0)) + 1
+    if every > 1 and any(i % every for i in gqa):
+        raise UnsupportedConfigError(
+            f"gqa_layers {gqa} is not every {every}th layer, which "
+            f"gqa_interval = {every - 1} says")
+    lin = hf.get("linear_attn_config") or {}
+    heads = int(lin.get("num_heads") or 0)
+    if lin.get("num_kv_heads") not in (None, heads):
+        raise UnsupportedConfigError(
+            f"linear_attn_config.num_kv_heads = {lin['num_kv_heads']!r} "
+            "(grouped keys and values in linear attention) is not served: "
+            "only null is")
+    # a depth-cut copy keeps the published list: the layers that exist
+    kinds = tuple(GLOBAL if i in gqa else DELTA for i in range(n))
+    spelt = tuple(hf.get("layer_types") or kinds)[:n]
+    if spelt != kinds:
+        raise UnsupportedConfigError(
+            f"layer_types {list(spelt)} is not what gqa_layers {gqa} says")
+    out = {
+        "layer_types": kinds,
+        "delta_heads": heads,
+        "delta_head_dim": int(lin.get("head_dim") or 0),
+        "delta_conv_kernel": int(lin.get("short_conv_kernel_size", 4)),
+        "delta_neg_eigval": bool(hf.get("kda_allow_neg_eigval", False)),
+        "attention_gate": "elementwise" if hf.get("use_gqa_gate") else "",
+        "unrotated_kinds": () if hf.get("use_rope", False) else (GLOBAL,),
+    }
+    published = int(hf.get("n_routed_experts_published") or 0)
+    if published:
+        out["num_experts_routed"] = published
+        out["expert_offset"] = int(hf.get("expert_share_offset", 0))
+    return out
+
+
 def config_from_hf_json(path: str) -> ModelConfig:
     """Build a ModelConfig from a HuggingFace config.json: Llama / Mixtral
     keys, the published keys of a patterned routed decoder (Mellum2:
@@ -992,13 +1127,15 @@ def config_from_hf_json(path: str) -> ModelConfig:
     `moe_intermediate_size`, `norm_topk_prob`, `mlp_layer_types`), those
     of a `deepseek_v3` decoder (`_latent_keys`), the same feed-forward keys
     on grouped-query attention (`_routed_lead_keys`: `exaone_moe`), those of
-    a `phi4flash` hybrid decoder (`_hybrid_keys`) and those of an `lfm2_moe`
-    one (`_conv_keys`).  A key the program cannot honour is an
+    a `phi4flash` hybrid decoder (`_hybrid_keys`), those of an `lfm2_moe`
+    one (`_conv_keys`) and those of a `solar_open2` one (`_delta_keys`).  A
+    key the program cannot honour is an
     UnsupportedConfigError."""
     with open(path) as f:
         hf = json.load(f)
     latent = _latent_keys(hf)
     routed_lead = _routed_lead_keys(hf)
+    delta = _delta_keys(hf)
     rs = hf.get("rope_scaling") or {}
     # honor the checkpoint's own precision ("dtype" since transformers
     # 4.56+, "torch_dtype" before); fp16 checkpoints run as bf16 (same
@@ -1010,7 +1147,8 @@ def config_from_hf_json(path: str) -> ModelConfig:
     # MoE: `num_local_experts` (HF Mixtral) or `num_experts` with the
     # experts' own width in `moe_intermediate_size`; absent -> 0 = dense
     num_experts = (hf.get("num_local_experts", hf.get("num_experts", 0))
-                   or (hf.get("n_routed_experts", 0) if latent else 0) or 0)
+                   or (hf.get("n_routed_experts", 0) if latent or delta
+                       else 0) or 0)
     # (a dense LEAD is `_routed_lead_keys`', checked there)
     mlp_kinds = hf.get("mlp_layer_types")
     if routed_lead.get("first_k_dense"):
@@ -1026,7 +1164,7 @@ def config_from_hf_json(path: str) -> ModelConfig:
             "norm_topk_prob false (top-k weights of a softmax over ALL "
             "experts, not renormalised) is not served: routing here is a "
             "softmax over exactly the top-k logits")
-    hybrid = _hybrid_keys(hf) or _conv_keys(hf)
+    hybrid = _hybrid_keys(hf) or _conv_keys(hf) or delta
     pattern = {} if "layer_types" in hybrid else _layer_pattern(hf)
     rope_theta = hf.get("rope_theta")
     if rope_theta is None:

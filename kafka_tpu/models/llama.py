@@ -6,7 +6,11 @@ lead-and-routed tree on grouped-query attention with QK-norm and kinds of
 layer that do not rotate (`exaone_moe`: K-EXAONE), and with gated short
 convolutions for mixers in most layers, each carrying a two-row tail in a
 state slot beside the pages (`lfm2_moe`: LFM2-8B-A1B; "The conv layout"
-below).
+below), or with gated delta-rule linear attention for mixers, each carrying a
+matrix a head and its convolutions' tails in a state slot, beside gated
+full-attention layers that do not rotate (`solar_open2`: Solar-Open2-250B;
+`_delta_attention_block`, the conv layout's mechanism with a second state
+leaf).
 
 Design (TPU-first, not a port — the reference has no model code at all; its
 LLM compute lived behind a remote gateway, src/llm/portkey.py):
@@ -89,9 +93,16 @@ from typing import Any, Dict, NamedTuple, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from ..models.config import CONV, GLOBAL, ModelConfig, UnsupportedConfigError
+from ..models.config import (
+    CONV,
+    DELTA,
+    GLOBAL,
+    ModelConfig,
+    UnsupportedConfigError,
+)
 from ..ops.attention import NEG_INF, causal_attention, paged_decode_walk
 from ..ops.norms import rms_norm
+from ..ops.pallas.gated_delta import gated_delta
 from ..ops.rope import (
     apply_rope,
     kind_frequencies,
@@ -356,7 +367,18 @@ def _init_lead_tree_params(cfg: ModelConfig, key: jax.Array, dtype) -> Params:
     layers, and for a conv layer W_in [H, 3H] (chunks B | C | u), the taps
     [L, H] (tap L - 1 multiplies the row's own product; N(0, 1 / L), so the
     taps that read the tail weigh as much as the one that does not and a
-    check on the logits sees a lost tail) and W_out [H, H]."""
+    check on the logits sees a lost tail) and W_out [H, H].
+
+    The linear-attention layout (`cfg.delta_heads`: Solar-Open2) is the conv
+    layout's tree with a DELTA mixer (`_delta_attention_block` names the
+    leaves; with W = heads x head size: wq / wk / wv [H, W], the three
+    convolutions' taps side by side [L, 3W], the decay's and the output
+    gate's low-rank pairs [H, head size] and [head size, W], A_log a head
+    drawn log U(1, 16) and dt_bias a channel the inverse softplus of a step
+    drawn log-uniform in [0.001, 0.1], so a channel's decay a row spreads
+    over 0.9999 .. 0.2 and a decay taken per head, or a state rounded to
+    bfloat16, moves the logits) and, where the config gates its attention
+    elementwise, "wgate" [H, heads x head_dim] among the attention leaves."""
     h, hq = cfg.hidden_size, cfg.num_heads
 
     @partial(jax.jit, static_argnums=(1, 2))
@@ -406,7 +428,32 @@ def _init_lead_tree_params(cfg: ModelConfig, key: jax.Array, dtype) -> Params:
         if cfg.qk_norm:
             out["ln_q"] = spread(ks[4], (n, d))
             out["ln_k"] = spread(ks[5], (n, d))
+        if cfg.attention_gate == "elementwise":
+            out["wgate"] = norm01(jax.random.fold_in(k, 6), (n, h, hq * d), h)
         return out
+
+    def delta_mixer(k, n):
+        H, D, taps = cfg.delta_heads, cfg.delta_head_dim, cfg.delta_conv_kernel
+        W = H * D
+        ks = jax.random.split(k, 13)
+        step = jnp.exp(jax.random.uniform(
+            ks[11], (n, W), jnp.float32, jnp.log(0.001), jnp.log(0.1)))
+        return {
+            "wq": norm01(ks[0], (n, h, W), h),
+            "wk": norm01(ks[1], (n, h, W), h),
+            "wv": norm01(ks[2], (n, h, W), h),
+            "conv_w": norm01(ks[3], (n, taps, 3 * W), taps),
+            "wf1": norm01(ks[4], (n, h, D), h),
+            "wf2": norm01(ks[5], (n, D, W), D),
+            "wg1": norm01(ks[6], (n, h, D), h),
+            "wg2": norm01(ks[7], (n, D, W), D),
+            "wbeta": norm01(ks[8], (n, h, H), h),
+            "ln_o": spread(ks[9], (n, D)),
+            "A_log": jnp.log(jax.random.uniform(
+                ks[10], (n, H), jnp.float32, 1.0, 16.0)),
+            "dt_bias": jnp.log(jnp.expm1(step)),
+            "w_out": norm01(ks[12], (n, W, h), W),
+        }
 
     def conv_mixer(k, n):
         taps = cfg.conv_L_cache
@@ -417,9 +464,10 @@ def _init_lead_tree_params(cfg: ModelConfig, key: jax.Array, dtype) -> Params:
 
     attention = latent_attention if cfg.is_latent else gqa_attention
     # the conv layout: a mixer a KIND, and the two stacks keep the norms
-    mixers = {CONV: conv_mixer,
+    mixers = {CONV: conv_mixer, DELTA: delta_mixer,
               GLOBAL: partial(gqa_attention, with_norms=False)}
-    if CONV in cfg.layer_types:
+    kinded = CONV in cfg.layer_types or DELTA in cfg.layer_types
+    if kinded:
         def attention(k, n):
             return norms(n)
 
@@ -453,7 +501,7 @@ def _init_lead_tree_params(cfg: ModelConfig, key: jax.Array, dtype) -> Params:
         "final_norm": jnp.ones((h,), dtype),
         "layers": layers,
     }
-    if CONV in cfg.layer_types:
+    if kinded:
         params["attn"] = {
             kind: mixers[kind](jax.random.fold_in(keys[1], i),
                                cfg.layers_of(kind))
@@ -636,9 +684,100 @@ def _attention_block(
     if paged is not None:
         k_cache = _stacked_pool(k_cache, num_layers)
         v_cache = _stacked_pool(v_cache, num_layers)
+    if "wgate" in lp:
+        # the elementwise output gate ("Gated Attention for LLMs", G1): every
+        # value of every head's output times sigmoid(x W_gate), ahead of W_o
+        with jax.named_scope("attn_gate"):
+            gate = jnp.einsum("bsh,hw->bsw", x, _w(lp, "wgate", dt))
+            out = out * jax.nn.sigmoid(
+                gate.astype(jnp.float32)).astype(out.dtype).reshape(out.shape)
     with jax.named_scope("attn_out"):
         out = jnp.einsum("bsnd,ndh->bsh", out, _w(lp, "wo", out.dtype))
     return out, k_cache, v_cache
+
+
+def _delta_attention_block(x: jnp.ndarray, lp: Params, cfg: ModelConfig,
+                           leaves, layer, plan: StatePlan):
+    """One gated delta-rule linear-attention layer (`solar_open2`'s mixer;
+    Kimi Delta Attention).  x: [B, S, H].  With W = heads x head size D:
+
+        q~, k~, v~ = x W_q, x W_k, x W_v;  q, k, v = SiLU(conv(.)), a
+        depthwise causal convolution of `delta_conv_kernel` taps a channel;
+        q <- q / ||q|| D^-1/2, k <- k / ||k|| a head
+        g = -exp(A_log) softplus(x W_f1 W_f2 + dt_bias)   a key CHANNEL
+        beta = sigmoid(x W_beta) (x 2 with `delta_neg_eigval`)   a head
+        S_t = (I - beta k k^T) Diag(exp g) S_(t-1) + beta k v^T;  o = S_t^T q
+        y = (RMSNorm_head(o) * sigmoid(x W_g1 W_g2)) W_o
+
+    The layer's STATE is two leaves of `leaves` (the v pool's dict; None:
+    uncached, from zeros), `layer` this layer's place in both: "conv", the
+    last taps - 1 rows of [q~ | k~ | v~] in float32 (laid out in the slot as
+    `cfg.state_shapes` says), read and written as a
+    short convolution's tail is (models/hybrid._read_state / _write_state),
+    and "delta", S transposed a head, float32, which ops/pallas/gated_delta
+    updates IN PLACE on the Pallas backend (the chunk kernel at S > 1, the
+    step kernel in decode) and through the same slot read and write under a
+    row-by-row scan elsewhere.  Everything between the projections and W_o is
+    float32.  Returns (out [B, S, H] ahead of the residual add, leaves')."""
+    dt, f32 = x.dtype, jnp.float32
+    b, s, _ = x.shape
+    H, D = cfg.delta_heads, cfg.delta_head_dim
+    with jax.named_scope("kda_proj"):
+        qkv = jnp.concatenate(
+            [jnp.einsum("bsh,hw->bsw", x, _w(lp, n, dt))
+             for n in ("wq", "wk", "wv")], axis=-1)
+        decay, gate = (
+            jnp.einsum("bsr,rw->bsw",
+                       jnp.einsum("bsh,hr->bsr", x, _w(lp, a, dt)),
+                       _w(lp, c, dt))
+            for a, c in (("wf1", "wf2"), ("wg1", "wg2")))
+        beta = jnp.einsum("bsh,hn->bsn", x, _w(lp, "wbeta", dt))
+    conv_leaf, delta_leaf = (None, None) if leaves is None else (
+        leaves["conv"], leaves["delta"])
+    with jax.named_scope("kda_conv"):
+        w = lp["conv_w"].astype(f32)  # [taps, 3W]
+        taps = w.shape[0]
+        # (the slot lays the tail's rows out as `cfg.state_shapes` says)
+        tail = (jnp.zeros((b, taps - 1, 3 * H * D), f32) if conv_leaf is None
+                else _read_state(conv_leaf, layer, plan, b).reshape(
+                    b, taps - 1, 3 * H * D))
+        seq = jnp.concatenate([tail, qkv.astype(f32)], axis=1)
+        qkv = jax.nn.silu(sum(w[j] * seq[:, j:j + s] for j in range(taps)))
+        if conv_leaf is not None:
+            # the last taps - 1 REAL rows (as _short_conv_block's tail)
+            new = jax.vmap(
+                lambda rows, n: jax.lax.dynamic_slice_in_dim(
+                    rows, n, taps - 1, axis=0))(seq, plan.lens)
+            slot = (b,) + conv_leaf.shape[2:]
+            conv_leaf = _write_state(conv_leaf, layer, plan,
+                                     new.reshape(slot), tail.reshape(slot))
+    with jax.named_scope("kda_gate"):
+        q, k, v = (a.reshape(b, s, H, D) for a in jnp.split(qkv, 3, axis=-1))
+
+        def unit(a):
+            return a * jax.lax.rsqrt(
+                jnp.sum(a * a, axis=-1, keepdims=True) + 1e-6)
+
+        q, k = unit(q) * D**-0.5, unit(k)
+        g = -jnp.exp(lp["A_log"].astype(f32))[:, None] * jax.nn.softplus(
+            decay.astype(f32) + lp["dt_bias"].astype(f32)
+        ).reshape(b, s, H, D)
+        beta = jax.nn.sigmoid(beta.astype(f32)) * (
+            2.0 if cfg.delta_neg_eigval else 1.0)
+    with jax.named_scope("kda_delta"):
+        o, delta_leaf = gated_delta(
+            delta_leaf, layer, plan, q, k, v, g, beta,
+            kernel=cfg.attention_backend == "pallas",
+            read_state=_read_state, write_state=_write_state)
+    with jax.named_scope("kda_gate"):
+        o = rms_norm(o, lp["ln_o"].astype(f32), cfg.rms_norm_eps) \
+            * jax.nn.sigmoid(gate.astype(f32)).reshape(b, s, H, D)
+    with jax.named_scope("kda_proj"):
+        out = jnp.einsum("bsw,wh->bsh", o.astype(dt).reshape(b, s, H * D),
+                         _w(lp, "w_out", dt))
+    if leaves is not None:
+        leaves = {**leaves, "conv": conv_leaf, "delta": delta_leaf}
+    return out, leaves
 
 
 def _short_conv_block(x: jnp.ndarray, lp: Params, leaf, layer,
@@ -1974,7 +2113,7 @@ def forward(
         lead, period = cfg.pattern
         if cfg.layer_types:
             rope = {kind: rope_cos_sin(positions, *kind_frequencies(cfg, kind))
-                    for kind in cfg.kinds if kind != CONV}
+                    for kind in cfg.kinds if kind not in (CONV, DELTA)}
         else:
             inv_freq = rope_frequencies(cfg)
             rope = {GLOBAL: rope_cos_sin(positions, inv_freq)}
@@ -2009,10 +2148,14 @@ def forward(
     def layer_body(carry, scanned, kind=GLOBAL, routed=cfg.is_moe):
         h, kc, vc, tally = carry
         lp, layer, *slot = scanned
-        cos, sin = (None, None) if kind == CONV else rope[kind]
+        cos, sin = (None, None) if kind in (CONV, DELTA) else rope[kind]
         with jax.named_scope("attn_norm"):
             attn_in = rms_norm(h, lp["ln_attn"], cfg.rms_norm_eps)
-        if kind == CONV:
+        if kind == DELTA:
+            # `layer` counts the linear layers: its place in both state leaves
+            attn_out, vc = _delta_attention_block(
+                attn_in, lp, cfg, vc, layer, plan)
+        elif kind == CONV:
             # `layer` counts the conv layers: its place in the state array
             attn_out, tail = _short_conv_block(
                 attn_in, lp, None if vc is None else vc["conv"], layer, plan)
@@ -2047,7 +2190,8 @@ def forward(
                 cache_positions, paged, mesh, layer, cfg.window_of(kind),
             )
             vc = {**vc, "v": v_rows} if in_dict else v_rows
-        with jax.named_scope("conv_proj" if kind == CONV else "attn_out"):
+        with jax.named_scope({CONV: "conv_proj", DELTA: "kda_proj"}.get(
+                kind, "attn_out")):
             h = h + attn_out
         with jax.named_scope("mlp_norm"):
             mlp_in = rms_norm(h, lp["ln_mlp"], cfg.rms_norm_eps)

@@ -525,6 +525,9 @@ class GenRequest:
     # is cut there, so that boundary's snapshot is stored for the next
     # request), and that match in tokens (for the counters).
     state_snapshot: Optional[int] = None
+    # the snapshot slot the last admission restored into the lane's slot
+    # (None: a cold start); the engine.prefill span carries it
+    state_restored: Optional[int] = None
     state_cut: int = 0
     state_matched: int = 0
 
@@ -1389,6 +1392,14 @@ class InferenceEngine:
         # backend, 0 on XLA).
         self.prefill_walk_trips = 0
         self.prefill_walk_kernel_trips = 0
+        # Monotonic, and both 0 for a model without linear-attention layers:
+        # the chunks the gated-delta prefill kernel looped over every
+        # dispatched launch, layer and active lane
+        # (StepPrograms.delta_chunk_trips; 0 where the XLA scan runs), and
+        # the bytes of delta state the decode passes read and wrote (busy
+        # lanes x layers x 2 x a layer's slot: StepPrograms.delta_state_bytes)
+        self.delta_chunk_trips = 0
+        self.delta_state_bytes = 0
         # Monotonic, and all 0 for a model with no routed block
         # (StepPrograms.moe_dispatch): step programs dispatched by the form
         # their routed blocks take, and the rows those blocks were handed
@@ -1595,6 +1606,10 @@ class InferenceEngine:
                 kw["promoted_tokens"] = req.promoted_tokens
             if req.object_tokens:
                 kw["object_tokens"] = req.object_tokens
+        if req.state_restored is not None:
+            # a model with a recurrent state: the snapshot slot that was
+            # copied into the lane's slot ahead of this prefill
+            kw["state_snapshot"] = req.state_restored
         return self._tattrs(**kw)
 
     def _dispatch_scope(self, kind: str,
@@ -2990,6 +3005,7 @@ class InferenceEngine:
         self.state_tokens_matched += req.state_matched
         self.state_tokens_skipped += req.cached_tokens
         snap, req.state_snapshot = req.state_snapshot, None
+        req.state_restored = snap
         if snap is None:
             return
         t0 = time.monotonic()
@@ -4331,6 +4347,8 @@ class InferenceEngine:
         trips, folded = self._programs.prefill_walk_trips(spans, width, bucket)
         self.prefill_walk_trips += trips
         self.prefill_walk_kernel_trips += folded
+        self.delta_chunk_trips += self._programs.delta_chunk_trips(
+            len(spans), bucket)
         self._count_moe_dispatch(width * bucket)
 
     def _count_moe_dispatch(self, rows: int, passes: int = 1) -> None:
@@ -4359,6 +4377,9 @@ class InferenceEngine:
             steps)
         self.decode_keys_walked += walked
         self.decode_keys_window += window
+        if self.cfg.delta_heads:
+            self.delta_state_bytes += self._programs.delta_state_bytes(
+                sum(m is not None for m in members), steps)
         self._count_moe_dispatch(len(members), steps)
         if self.cfg.index_topk:
             seqs = [m.seq for m in members if m is not None]

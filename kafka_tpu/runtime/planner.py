@@ -39,7 +39,7 @@ import dataclasses
 import os
 from typing import Dict, Optional
 
-from ..models.config import CONV, CROSS, GMU, ModelConfig
+from ..models.config import CONV, CROSS, DELTA, GMU, ModelConfig
 from .kv_cache import default_state_slots
 
 GiB = 1024**3
@@ -352,9 +352,20 @@ def _lead_tree_weight_bytes(cfg: ModelConfig, mat, wb: int) -> int:
         if kind == CONV:
             return (mat(h, 3 * h, 1) + mat(h, h, 1)
                     + (cfg.conv_L_cache * h + 2 * h) * wb)
+        if kind == DELTA:
+            # q / k / v / o, the decay's and the gate's low-rank pairs, beta;
+            # the taps, the head norm and the two norms; A_log and dt_bias
+            # are float32
+            n, d = cfg.delta_heads, cfg.delta_head_dim
+            w = n * d
+            return (4 * mat(h, w, 1) + 2 * (mat(h, d, 1) + mat(d, w, 1))
+                    + mat(h, n, 1)
+                    + (cfg.delta_conv_kernel * 3 * w + d + 2 * h) * wb
+                    + (n + w) * 4)
         if not cfg.is_latent:
             hq, hkv, d = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
-            return (2 * mat(h, hq * d, 1) + 2 * mat(h, hkv * d, 1)
+            gates = 3 if cfg.attention_gate == "elementwise" else 2
+            return (gates * mat(h, hq * d, 1) + 2 * mat(h, hkv * d, 1)
                     + (2 * h + (2 * d if cfg.qk_norm else 0)) * wb)
         g = cfg.geometry_of(kind)
         hq, r, rq = g.num_heads, g.kv_lora_rank, g.q_lora_rank
@@ -450,6 +461,12 @@ def activation_bytes_estimate(
         # around them, [S, inner] each, and B / C broadcast along 128 lanes
         prefill += s_local * (cfg.mamba_d_inner * 4 * 6
                               + cfg.mamba_d_state * 128 * 4 * 2)
+    elif DELTA in cfg.layer_types:
+        # [q | k | v] and the convolution over them, then the chunk kernel's
+        # six float32 operands (q, k, beta k, beta v, the log-decay and its
+        # cumulative sum) and its output, [S, heads x head size] each
+        prefill += s_local * cfg.delta_heads * cfg.delta_head_dim * (
+            3 * 2 + 3 * 4 * 2 + 7 * 4)
     elif cfg.has_state:
         # a short convolution's [B | C | u] and its float32 products
         prefill += s_local * H * (3 * 2 + 2 * 4)

@@ -31,7 +31,7 @@ from typing import Any, Callable, Dict, NamedTuple, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from ..models.config import ModelConfig
+from ..models.config import DELTA, ModelConfig
 from ..models.llama import (
     INDEX_WALK_KEYS,
     KVCache,
@@ -43,6 +43,7 @@ from ..models.llama import (
     walk_pages,
 )
 from ..ops.attention import decode_walk_pages
+from ..ops.pallas.gated_delta import chunk_rows
 from ..ops.sampling import (
     SamplingParams,
     grammar_advance,
@@ -641,6 +642,28 @@ class StepPrograms:
                 first = min(max(lo, 0) // ck, trips)
             total += cfg.layers_of(kind) * (trips - first)
         return total, total if kernel else 0
+
+    def delta_chunk_trips(self, lanes: int, bucket: int) -> int:
+        """Chunks the gated-delta prefill kernel loops over ONE launch of
+        `bucket` rows whose `lanes` active lanes it computes, by the grid the
+        kernel itself is given (ops/pallas/gated_delta.chunk_rows), summed
+        over the linear-attention layers; 0 for a model without them, on the
+        XLA backend and for a bucket the kernel does not tile (the
+        row-by-row scan runs)."""
+        cfg = self.cfg
+        n = cfg.layers_of(DELTA)
+        if not n or cfg.attention_backend != "pallas":
+            return 0
+        rows = chunk_rows(bucket)
+        return n * lanes * (bucket // rows) if rows else 0
+
+    def delta_state_bytes(self, lanes: int, steps: int) -> int:
+        """Bytes of delta state `steps` decode passes over `lanes` busy
+        lanes read and wrote: every linear-attention layer's matrix a head,
+        once in and once out (0 for a model without them)."""
+        cfg = self.cfg
+        return (2 * 4 * cfg.layers_of(DELTA) * cfg.delta_heads
+                * cfg.delta_head_dim ** 2 * lanes * steps)
 
     def moe_dispatch(self, rows: int) -> Optional[str]:
         """"token" or "dense": the form the routed blocks of a pass of

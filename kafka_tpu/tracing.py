@@ -180,8 +180,9 @@ DEVICE_SCOPES = (
                     # the lane's live keys and the exact top-k
     "attn_select",  # inside attn_core, decode of such a layer: the read of
                     # the chosen rows (attention over them stays attn_core)
-    "attn_gate",    # headwise output gate: sigmoid(x W_g) times each
-                    # head's output, ahead of W_o
+    "attn_gate",    # output gate ahead of W_o: sigmoid(x W_g) times each
+                    # head's output (headwise, latent attention) or times
+                    # every value of it (elementwise, grouped-query)
     "attn_gather",  # XLA paths only, inside attn_core: the page/slot
                     # gather that materialises the attention window
     "attn_out",     # output projection + residual add
@@ -209,6 +210,18 @@ DEVICE_SCOPES = (
     "conv_mix",     # its elementwise middle: B * u, the taps over [tail |
                     # pass] with the tail's read from and write to the state
                     # slot, and the C * gate
+    # the linear-attention layout's mixer (models/llama._delta_attention_block)
+    "kda_proj",     # a gated delta-rule layer's projections: W_q, W_k, W_v,
+                    # the decay's and the output gate's low-rank pairs,
+                    # W_beta, W_o (+ residual add)
+    "kda_conv",     # its three short convolutions + SiLU, and the tails'
+                    # read from and write to the state slot
+    "kda_gate",     # its elementwise parts: the L2 norms of q and k, the
+                    # softplus and exp of the decay, beta, the head norm
+                    # and the output gate
+    "kda_delta",    # the recurrence: the chunked Pallas kernel at s > 1,
+                    # the step kernel in decode (the state updated in place
+                    # in its slot), or the row-by-row XLA scan
     "head",         # final RMSNorm + logits
     "sample",       # last-position select, per-(seed, position) keys,
                     # sample_tokens_per_slot (engine step programs)

@@ -244,6 +244,7 @@ UNREAD = {
     ("kanana-2-30b-a3b", 32, "decode"), ("kanana-2-30b-a3b", 64, "prefill"),
     ("mellum2-12b-a2.5b", 16, "decode"),
     ("k-exaone-236b-a23b", 32, "decode"),
+    ("solar-open2-250b", 32, "decode"), ("solar-open2-250b", 64, "prefill"),
 }
 
 
