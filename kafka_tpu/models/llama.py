@@ -100,7 +100,12 @@ from ..models.config import (
     ModelConfig,
     UnsupportedConfigError,
 )
-from ..ops.attention import NEG_INF, causal_attention, paged_decode_walk
+from ..ops.attention import (
+    NEG_INF,
+    causal_attention,
+    common_pages,
+    paged_decode_walk,
+)
 from ..ops.norms import rms_norm
 from ..ops.pallas.gated_delta import gated_delta
 from ..ops.rope import (
@@ -1527,18 +1532,9 @@ def _walk_chunks(paged: "PagedView", cp: int):
 
 
 def _common_pages(paged: "PagedView"):
-    """(lane, common): the first lane that holds keys, and how many of the
-    page table's leading columns name that lane's page in EVERY lane that
-    holds keys (a prefix attached to all of them: the same physical pages
-    in the same columns).  A lane without a valid key (idle, its row on the
-    trash page) does not end the run; a lane shorter than the others ends
-    it past its own last page, where its columns differ."""
-    table = paged.page_table
-    held = jnp.sum(paged.kv_valid, axis=-1) > 0
-    lane = jnp.argmax(held)
-    same = jnp.all((table == table[lane][None, :]) | ~held[:, None], axis=0)
-    cols = jnp.arange(same.shape[0], dtype=jnp.int32)
-    return lane, jnp.min(jnp.where(same, same.shape[0], cols))
+    """`common_pages` over the lanes that hold keys: (the first of them, the
+    page table's leading columns that name its page in every one)."""
+    return common_pages(paged.page_table, jnp.sum(paged.kv_valid, axis=-1) > 0)
 
 
 def _paged_index_scores(q_idx, w_idx, i_cache, paged: "PagedView",

@@ -12,7 +12,9 @@ in one shot: prefill chunks, speculative verify, the contiguous cache.
 it never builds a lane's static window but walks the live context in chunks
 of `DECODE_WALK_KEYS` keys, folding each into a running softmax, and on one
 device contracts a chunk on the pool row's merged Hkv*D axis so that no
-K/V is re-laid out between the page gather and the matmuls.
+K/V is re-laid out between the page gather and the matmuls.  Where the
+lanes' page tables open on the same pages (`common_pages`: a prefix attached
+to all of them) those trips read the pages once for every lane.
 
 Layout convention throughout the framework: activations are
 [batch, seq, heads, head_dim] ("BSHD") — the layout that shards naturally
@@ -123,6 +125,39 @@ def decode_walk_trips(seq_lens: jnp.ndarray, active: jnp.ndarray,
     return (n_keys + chunk_keys - 1) // chunk_keys
 
 
+def common_pages(table: jnp.ndarray, held: jnp.ndarray):
+    """(lane, common): the first lane of `held` [B] bool, and how many of
+    page table `table`'s [B, P] leading columns name that lane's page in
+    EVERY held lane (a prefix attached to all of them: the same physical
+    pages in the same columns).  A lane not held (idle, its row on the trash
+    page; still prefilling) does not end the run; a lane shorter than the
+    others ends it past its own last page, where its columns differ."""
+    lane = jnp.argmax(held)
+    same = jnp.all((table == table[lane][None, :]) | ~held[:, None], axis=0)
+    cols = jnp.arange(same.shape[0], dtype=jnp.int32)
+    return lane, jnp.min(jnp.where(same, same.shape[0], cols))
+
+
+def shared_walk_trips(lanes, steps: int, P: int, cp: int, ck: int):
+    """The SHARED trips of each of `steps` decode steps of a walk in trips
+    of `cp` pages (`ck` keys) over a page table `P` pages wide, by the
+    device's own arithmetic (`common_pages`, whole trips of it, within the
+    trips the longest lane needs) on the host: `lanes` [(pages, tokens held
+    before the first step)] of the dispatch's active lanes.  Plain ints,
+    one a step."""
+    if not lanes:
+        return [0] * steps
+    # the rows part where their lexicographic extremes part; rows alike to
+    # the end are alike in the trash columns behind them too
+    lo, hi = min(p for p, _ in lanes), max(p for p, _ in lanes)
+    common = P if lo == hi else next(
+        (j for j, (a, b) in enumerate(zip(lo, hi)) if a != b),
+        min(len(lo), len(hi)))
+    longest = max(n for _, n in lanes)
+    return [min(common // cp, -(-(longest + i + 1) // ck), -(-P // cp))
+            for i in range(steps)]
+
+
 def paged_decode_walk(
     q: jnp.ndarray,
     read_pages: Callable[[jnp.ndarray], Tuple[jnp.ndarray, jnp.ndarray]],
@@ -152,6 +187,16 @@ def paged_decode_walk(
     positions <= seq_lens (and > seq_lens - window in a sliding-window
     layer) if active; a lane with nothing to attend returns zeros.
 
+    The walk splits where the lanes' page tables part (`common_pages` over
+    the active lanes: a prefix attached to all of them).  A trip whose pages
+    every active lane names reads them ONCE, from the first active lane's
+    row (read_pages of [1, cp] pages -> [1, ck, Hkv*D]), and folds them into
+    the same carry for all lanes in one product, on one device
+    [B*Hq, Hkv*D] x [Hkv*D, ck]; whole trips only, and each lane's own mask
+    as in any trip, so nothing rests on the shared pages being full.  From
+    there on, and from trip 0 where the lanes share nothing, a trip gathers
+    each lane's own pages.  The same chunks in the same order either way.
+
     The contraction adapts to where heads live.  On one device
     (`heads_batched` False) q is expanded block-diagonally to
     [B, Hq, Hkv*D] (zeros in the other kv heads' lanes) and both matmuls
@@ -167,66 +212,69 @@ def paged_decode_walk(
     scale = d**-0.5
     cp = decode_walk_pages(page_table.shape[1], page_size)
     ck = cp * page_size
+    # the first trip that gathers lane by lane: whole trips of the pages
+    # every active lane names come before it (a lone lane shares them all)
+    lane, common = common_pages(page_table, active)
     # whole chunks: the padding names the trash page, and its positions lie
     # past every seq_len, so the mask drops them
     page_table = jnp.pad(page_table, ((0, 0), (0, -page_table.shape[1] % cp)))
     n_chunks = page_table.shape[1] // cp
     trips = jnp.minimum(decode_walk_trips(seq_lens, active, ck), n_chunks)
+    own = jnp.minimum(common // cp, trips)
 
     if heads_batched:
         qe = q.reshape(b, hkv, g, d)
-
-        def scores(k):
-            return jnp.einsum("bhgd,bkhd->bhgk", qe, k.reshape(b, ck, hkv, d),
-                              preferred_element_type=jnp.float32)
-
-        def weighted(p, v):
-            return jnp.einsum("bhgk,bkhd->bhgd", p, v.reshape(b, ck, hkv, d),
-                              preferred_element_type=jnp.float32)
-
+        sc, wt, row = "bhgd,{}khd->bhgk", "bhgk,{}khd->bhgd", (hkv, d)
         lead, acc_shape = (b, hkv, g), (b, hkv, g, d)
     else:
-        own = jnp.arange(hq)[:, None] // g == jnp.arange(hkv)[None, :]
-        qe = jnp.where(own[None, :, :, None], q[:, :, None, :], 0).reshape(
+        mine = jnp.arange(hq)[:, None] // g == jnp.arange(hkv)[None, :]
+        qe = jnp.where(mine[None, :, :, None], q[:, :, None, :], 0).reshape(
             b, hq, hkv * d)
-
-        def scores(k):
-            return jnp.einsum("bnh,bkh->bnk", qe, k,
-                              preferred_element_type=jnp.float32)
-
-        def weighted(p, v):
-            return jnp.einsum("bnk,bkh->bnh", p, v,
-                              preferred_element_type=jnp.float32)
-
+        sc, wt, row = "bnh,{}kh->bnk", "bnk,{}kh->bnh", (hkv * d,)
         lead, acc_shape = (b, hq), (b, hq, hkv * d)
     expand = (slice(None),) + (None,) * (len(lead) - 1)
 
-    def fold(c, carry):
-        m, l, acc = carry
-        k, v = read_pages(
-            jax.lax.dynamic_slice_in_dim(page_table, c * cp, cp, axis=1))
-        pos = c * ck + jnp.arange(ck)[None, :]
-        mask = (pos <= seq_lens[:, None]) & active[:, None]
-        if window is not None:
-            mask = mask & (pos > seq_lens[:, None] - window)
-        mask = mask[expand]
-        s = jnp.where(mask, scores(k) * scale, NEG_INF)
-        m_new = jnp.maximum(m, jnp.max(s, axis=-1))
-        alpha = jnp.exp(m - m_new)
-        p = jnp.where(mask, jnp.exp(s - m_new[..., None]), 0.0)
-        l = alpha * l + jnp.sum(p, axis=-1)
-        acc = alpha[..., None] * acc + weighted(p.astype(v.dtype), v)
-        return m_new, l, acc
+    def fold(rows, lanes):
+        """A trip over `rows`: every lane's page-table row ([B, P], `lanes`
+        "b": each lane's scores against its own chunk), or the one row
+        [1, P] all of them read (""): ONE chunk, and every lane's heads
+        against it in one product.  The same masks and running softmax."""
+        def trip(c, carry):
+            m, l, acc = carry
+            k, v = read_pages(
+                jax.lax.dynamic_slice_in_dim(rows, c * cp, cp, axis=1))
+            if not lanes:
+                k, v = k[0], v[0]
+            k = k.reshape(k.shape[:-1] + row)
+            v = v.reshape(v.shape[:-1] + row)
+            pos = c * ck + jnp.arange(ck)[None, :]
+            mask = (pos <= seq_lens[:, None]) & active[:, None]
+            if window is not None:
+                mask = mask & (pos > seq_lens[:, None] - window)
+            mask = mask[expand]
+            s = jnp.einsum(sc.format(lanes), qe, k,
+                           preferred_element_type=jnp.float32) * scale
+            s = jnp.where(mask, s, NEG_INF)
+            m_new = jnp.maximum(m, jnp.max(s, axis=-1))
+            alpha = jnp.exp(m - m_new)
+            p = jnp.where(mask, jnp.exp(s - m_new[..., None]), 0.0)
+            l = alpha * l + jnp.sum(p, axis=-1)
+            acc = alpha[..., None] * acc + jnp.einsum(
+                wt.format(lanes), p.astype(v.dtype), v,
+                preferred_element_type=jnp.float32)
+            return m_new, l, acc
+        return trip
 
-    _, l, acc = jax.lax.fori_loop(
-        0, trips, fold,
+    carry = jax.lax.fori_loop(
+        0, own, fold(page_table[lane][None], ""),
         (jnp.full(lead, NEG_INF, jnp.float32), jnp.zeros(lead, jnp.float32),
          jnp.zeros(acc_shape, jnp.float32)))
+    _, l, acc = jax.lax.fori_loop(own, trips, fold(page_table, "b"), carry)
     out = acc / jnp.maximum(l, 1e-30)[..., None]
     if not heads_batched:
         # each head's own D lanes of the merged accumulator (the others hold
         # its probabilities against other kv heads' values)
         out = jnp.sum(
-            jnp.where(own[None, :, :, None], out.reshape(b, hq, hkv, d), 0.0),
+            jnp.where(mine[None, :, :, None], out.reshape(b, hq, hkv, d), 0.0),
             axis=2)
     return out.reshape(b, hq, d).astype(q.dtype)

@@ -1371,9 +1371,13 @@ class InferenceEngine:
         # (StepPrograms.decode_keys): the keys every decode step gathered
         # a layer (lanes x chunks x chunk keys, per step of a fused
         # dispatch) and the keys of the static windows it no longer
-        # gathers (lanes x max_pages_per_seq x page_size).
+        # gathers (lanes x max_pages_per_seq x page_size); `shared` are the
+        # walked keys of the trips whose pages every active lane of the
+        # dispatch held in the same leading columns of its page table, read
+        # once for all of them (StepPrograms.decode_keys_shared).
         self.decode_keys_walked = 0
         self.decode_keys_window = 0
+        self.decode_keys_shared = 0
         # Monotonic, and 0 for a model without an indexer
         # (StepPrograms.index_keys): the keys a layer's indexer scored over
         # every dispatched decode step (each lane's context, its own row
@@ -4372,23 +4376,25 @@ class InferenceEngine:
         """
         toks.copy_to_host_async()
         self._step_count += steps
+        seqs = [m.seq for m in members if m is not None]
+        tables = [(seq.pages, seq.length) for seq in seqs]
         walked, window = self._programs.decode_keys(
-            max((m.seq.length for m in members if m is not None), default=0),
-            steps)
+            max((seq.length for seq in seqs), default=0), steps)
         self.decode_keys_walked += walked
         self.decode_keys_window += window
+        self.decode_keys_shared += self._programs.decode_keys_shared(
+            tables, steps)
         if self.cfg.delta_heads:
             self.delta_state_bytes += self._programs.delta_state_bytes(
-                sum(m is not None for m in members), steps)
+                len(seqs), steps)
         self._count_moe_dispatch(len(members), steps)
         if self.cfg.index_topk:
-            seqs = [m.seq for m in members if m is not None]
             scored, kept = self._programs.index_keys(
                 [seq.length for seq in seqs], steps)
             self.index_keys_scored += scored
             self.index_keys_kept += kept
             self.index_keys_shared += self._programs.index_keys_shared(
-                [(seq.pages, seq.length) for seq in seqs], steps)
+                tables, steps)
         # decode-span inputs, computed lazily on the FIRST traced member:
         # an all-untraced dispatch pays one branch per lane, nothing else
         now_mono: Optional[float] = None

@@ -42,7 +42,7 @@ from ..models.llama import (
     prefill_walk_pages,
     walk_pages,
 )
-from ..ops.attention import decode_walk_pages
+from ..ops.attention import decode_walk_pages, shared_walk_trips
 from ..ops.pallas.gated_delta import chunk_rows
 from ..ops.sampling import (
     SamplingParams,
@@ -560,22 +560,40 @@ class StepPrograms:
     def _geometry(self) -> Tuple:
         return self.cfg, self.ps, self.P * self.ps, self.B, self.mesh
 
+    def _decode_walk_pages(self) -> int:
+        """Pages a trip of the XLA decode walk reads a lane; 0 where decode
+        does not walk in XLA: a Pallas kernel, a latent model's own read,
+        or pp (no page table in the view)."""
+        mesh = self.mesh
+        if (self.cfg.attention_backend != "xla" or self.cfg.is_latent
+                or (mesh is not None and mesh.shape.get("pp", 1) > 1)):
+            return 0
+        return decode_walk_pages(self.P, self.ps)
+
     def decode_keys(self, max_len: int, steps: int) -> Tuple[int, int]:
         """(keys walked, keys of the static windows) over the B lanes of
         `steps` decode steps whose longest active lane holds `max_len`
         tokens before the first: what the XLA decode walk
         (ops/attention.py paged_decode_walk) gathers a layer, by the bound
         its device loop computes, beside lanes x max_pages_per_seq x
-        page_size.  (0, 0) where decode does not walk in XLA: a Pallas
-        kernel, a latent model's own read, or pp (no page table in the
-        view)."""
-        mesh = self.mesh
-        if (self.cfg.attention_backend != "xla" or self.cfg.is_latent
-                or (mesh is not None and mesh.shape.get("pp", 1) > 1)):
+        page_size.  (0, 0) where decode does not walk in XLA."""
+        ck = self._decode_walk_pages() * self.ps
+        if not ck:
             return 0, 0
-        ck = decode_walk_pages(self.P, self.ps) * self.ps
         chunks = sum(-(-(max_len + i + 1) // ck) for i in range(steps))
         return self.B * chunks * ck, self.B * steps * self.P * self.ps
+
+    def decode_keys_shared(self, lanes, steps: int) -> int:
+        """Of `decode_keys`' walked keys, those of the walk's SHARED trips
+        (read once for every lane, counted for the B lanes as `walked`
+        counts them): `lanes` [(pages, tokens held before the first step)]
+        of the dispatch's active lanes, by the device's own arithmetic
+        (ops/attention.py common_pages, paged_decode_walk).  0 where decode
+        does not walk in XLA."""
+        cp = self._decode_walk_pages()
+        ck = cp * self.ps
+        return cp and self.B * ck * sum(
+            shared_walk_trips(lanes, steps, self.P, cp, ck))
 
     def index_keys(self, lengths, steps: int) -> Tuple[int, int]:
         """(keys scored, keys kept) by ONE layer's indexer over `steps`
@@ -595,26 +613,16 @@ class StepPrograms:
         """Of `index_keys`' scored keys, those scored through the walk's
         SHARED trips: `lanes` [(pages, tokens held before the first step)]
         of the dispatch's active lanes, by the device's own arithmetic
-        (models/llama.py _common_pages, _paged_index_choice): the leading
-        columns in which every lane's page-table row names one page, whole
-        trips of them, up to each lane's live context."""
-        if not (self.cfg.index_topk and lanes):
+        (ops/attention.py common_pages, models/llama.py
+        _paged_index_scores): the leading columns in which every lane's
+        page-table row names one page, whole trips of them, up to each
+        lane's live context."""
+        if not self.cfg.index_topk:
             return 0
         cp = walk_pages(self.P, self.ps, INDEX_WALK_KEYS)
         ck = cp * self.ps
-        # the rows part where their lexicographic extremes part; rows alike
-        # to the end are alike in the trash columns behind them too
-        lo, hi = min(p for p, _ in lanes), max(p for p, _ in lanes)
-        common = self.P if lo == hi else next(
-            (j for j, (a, b) in enumerate(zip(lo, hi)) if a != b),
-            min(len(lo), len(hi)))
-        longest = max(n for _, n in lanes)
-        total = 0
-        for i in range(steps):
-            trips = min(-(-(longest + i + 1) // ck), -(-self.P // cp))
-            shared = min(common // cp, trips) * ck
-            total += sum(min(n + i + 1, shared) for _, n in lanes)
-        return total
+        return sum(min(n + i + 1, own * ck) for i, own in enumerate(
+            shared_walk_trips(lanes, steps, self.P, cp, ck)) for _, n in lanes)
 
     def prefill_walk_trips(self, spans, width: int,
                            bucket: int) -> Tuple[int, int]:

@@ -7,6 +7,7 @@ out (DMA starts, waits and the loop only).
     python scripts/paged_decode_bench.py --window 0       # global form only
     python scripts/paged_decode_bench.py --rehearse       # CPU, tiny, no times
     python scripts/paged_decode_bench.py --backend xla    # the XLA decode read
+    python scripts/paged_decode_bench.py --backend xla --shared-keys 0 7424
 
 Through the chip tool, from the repo root.  Defaults are the registered
 `chat-decode` cells' geometry (PERF.md section 4): 16 lanes, 32/4 x 128,
@@ -25,12 +26,18 @@ it replaced: the gather of every lane's static `max_pages` window and
 `causal_attention` over it.  Times are each jitted form's module events in
 one capture, with its costliest ops named (a `copy` of a window- or
 chunk-shaped K/V would head the list); bytes are the walked keys' K and V
-rows, read once.  Writes chiprun_out/paged_decode_bench_xla.json.
+rows, read once.  `--shared-keys` (one or more): the page tables' common
+leading keys, a table each over the same contexts (0: no two lanes share a
+page, the per-lane walk; 7,424: the cells' system prompt, read once a trip
+since PR 51), every walk form timed on every table with the trips it shared
+beside it; the pool grows to hold what the lanes do not share.  Writes
+chiprun_out/paged_decode_bench_xla.json (--out).
 """
 
 from __future__ import annotations
 
 import argparse
+import bisect
 import glob
 import json
 import os
@@ -46,14 +53,21 @@ sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)),
 PAGES_PER_CHUNK = 8
 
 
-def make_case(args, jnp):
-    """Inputs from --seed: a scattered page table over a shared prefix."""
+def make_case(args, jnp, shared_keys=None, pools=None):
+    """Inputs from --seed: a scattered page table over a shared prefix
+    (`shared_keys`, default --shared-prefix), the pools drawn here or
+    `pools` (k, v) as handed in (then args.num_pages says their pages)."""
     rng = np.random.RandomState(args.seed % 2**31)
     ps, hd = args.page_size, args.kv_heads * args.head_dim
     dtype = jnp.dtype(args.dtype)
-    total = args.num_pages * ps
-    k = jnp.asarray(rng.randn(total, hd).astype(np.float32), dtype)
-    v = jnp.asarray(rng.randn(total, hd).astype(np.float32), dtype)
+    if shared_keys is None:
+        shared_keys = args.shared_prefix
+    if pools is None:
+        total = args.num_pages * ps
+        k = jnp.asarray(rng.randn(total, hd).astype(np.float32), dtype)
+        v = jnp.asarray(rng.randn(total, hd).astype(np.float32), dtype)
+    else:
+        k, v = pools
     q = jnp.asarray(
         rng.randn(args.lanes, args.heads, args.head_dim).astype(np.float32),
         dtype)
@@ -61,7 +75,7 @@ def make_case(args, jnp):
                        size=args.lanes).astype(np.int32)
     free = list(range(1, args.num_pages))  # page 0 is the trash page
     rng.shuffle(free)
-    shared = [free.pop() for _ in range(args.shared_prefix // ps)]
+    shared = [free.pop() for _ in range(shared_keys // ps)]
     table = np.zeros((args.lanes, args.max_pages), np.int32)
     for b, n in enumerate(lens):
         need = -(-(int(n) + 1) // ps)
@@ -135,18 +149,18 @@ def xla_forms(args, jax, jnp):
                 attention.DECODE_WALK_KEYS = installed
         return fn
 
-    out = {"bench_xla_window": window_read}
+    out = {} if args.no_window_form else {"bench_xla_window": window_read}
     for keys in args.walk_keys:
         out[f"bench_xla_walk_{keys}"] = walk(keys)
     for name, fn in out.items():
         fn.__name__ = name
         out[name] = jax.jit(fn)
-    return out
+    return out, jax.jit(window_read)
 
 
 def module_events(trace_dir, names):
-    """{name: ([device ns of each launch of jit_<name>], {op: total ns})}
-    from the capture's `XLA Modules` and `XLA Ops` lines."""
+    """{name: [(device ns, {op: ns}) of each launch of jit_<name>, in launch
+    order]} from the capture's `XLA Modules` and `XLA Ops` lines."""
     from jax.profiler import ProfileData
 
     path = sorted(glob.glob(os.path.join(
@@ -164,77 +178,122 @@ def module_events(trace_dir, names):
                         launches[m.group(1)].append(
                             (ev.start_ns, ev.duration_ns))
             elif line.name == "XLA Ops":
-                ops += [(ev.start_ns, ev.duration_ns, ev.name)
-                        for ev in line.events]
+                for ev in line.events:
+                    # "%copy.3 = bf16[32768,8,8,128]{...} copy(...)"
+                    m = re.match(r"%?([\w.\-]+) = \(?(\w+\[[\d,]*\])",
+                                 ev.name)
+                    op = " ".join(m.groups()) if m else ev.name[:80]
+                    if not op.startswith("while"):
+                        ops.append((ev.start_ns, ev.duration_ns, op))
+    ops.sort()
+    starts = [s0 for s0, _, _ in ops]
     out = {}
     for name, spans in launches.items():
-        by_op = {}
-        for t0, dur in spans:
-            for s0, d, op in ops:
-                # "%copy.3 = bf16[32768,8,8,128]{...} copy(...)": name, shape
-                m = re.match(r"%?([\w.\-]+) = \(?(\w+\[[\d,]*\])", op)
-                op = " ".join(m.groups()) if m else op[:80]
-                if t0 <= s0 < t0 + dur and not op.startswith("while"):
-                    by_op[op] = by_op.get(op, 0) + d
-        out[name] = ([d for _, d in sorted(spans)], by_op)
+        out[name] = []
+        for t0, dur in sorted(spans):
+            by_op = {}
+            for _, d, op in ops[bisect.bisect_left(starts, t0):
+                                bisect.bisect_left(starts, t0 + dur)]:
+                by_op[op] = by_op.get(op, 0) + d
+            out[name].append((dur, by_op))
     return out
 
 
 def bench_xla(args, jax, jnp) -> int:
+    from kafka_tpu.ops import attention
+
     on_chip = jax.default_backend() == "tpu"
-    case, lens = make_case(args, jnp)
-    fns = xla_forms(args, jax, jnp)
-    outs = {n: np.asarray(fn(*case), np.float32) for n, fn in fns.items()}
-    ref = outs["bench_xla_window"]
+    ps, hd = args.page_size, args.kv_heads * args.head_dim
+    # one pool for every table: room for the least shared of them
+    least = min(args.shared_keys)
+    args.num_pages = max(args.num_pages, 2 + least // ps + args.lanes * (
+        args.max_len // ps + 1 - least // ps))
+    kk, kv = jax.random.split(jax.random.PRNGKey(args.seed % 2**31))
+    pools = tuple(
+        jax.random.normal(key, (args.num_pages * ps, hd), jnp.dtype(args.dtype))
+        for key in (kk, kv))
+    fns, window_read = xla_forms(args, jax, jnp)
     tol = 1e-5 if args.dtype == "float32" else 2e-2
-    for name, out in outs.items():
-        assert np.isfinite(out).all(), name
-        err = float(np.abs(out - ref).max())
-        assert err <= tol, (name, err)
+    runs = {}   # name: (fn, case, shared keys, shared trips, max diff)
+    for shared in args.shared_keys:
+        case, lens = make_case(args, jnp, shared, pools)
+        q, k, v, table, dlens = case
+        # the reference four lanes at a time: 32 lanes' 32k windows at once
+        # would not fit beside the pools
+        ref = np.concatenate([
+            np.asarray(window_read(q[i:i + 4], k, v, table[i:i + 4],
+                                   dlens[i:i + 4]), np.float32)
+            for i in range(0, args.lanes, 4)])
+        for name, fn in fns.items():
+            if name == "bench_xla_window" and shared != args.shared_keys[0]:
+                continue  # the materialised window reads what it reads
+            out = np.asarray(fn(*case), np.float32)
+            assert np.isfinite(out).all(), (name, shared)
+            err = float(np.abs(out - ref).max())
+            assert err <= tol, (name, shared, err)
+            own = 0
+            if "walk" in name:
+                cp = min(int(name.rsplit("_", 1)[1]) // ps, args.max_pages)
+                own = min(int(attention.common_pages(
+                    case[3], jnp.ones(args.lanes, bool))[1]) // cp,
+                    -(-(int(lens.max()) + 1) // (cp * ps)))
+            runs[f"{name}.s{shared}"] = (fn, case, shared, own, err)
     if not on_chip:
-        print(json.dumps({"rehearsed": sorted(fns), "device": "cpu"}))
+        print(json.dumps({"rehearsed": {n: r[3] for n, r in runs.items()},
+                          "device": "cpu"}))
         return 0
     trace_dir = tempfile.mkdtemp(prefix="paged_decode_bench_")
+    # a form's launches follow one another table by table, rep by rep
     with jax.profiler.trace(trace_dir):
         for _ in range(args.reps):
-            for fn in fns.values():
+            for fn, case, *_ in runs.values():
                 fn(*case).block_until_ready()
     events = module_events(trace_dir, list(fns))
-    from kafka_tpu.ops.attention import DECODE_WALK_KEYS
     from kafka_tpu.runtime.planner import device_peaks
 
     _, hbm_bytes_per_s, _ = device_peaks(jax.devices()[0])  # unknown: raises
-    row_bytes = args.kv_heads * args.head_dim * jnp.dtype(args.dtype).itemsize
-    window_keys = args.max_pages * args.page_size
+    row_bytes = hd * jnp.dtype(args.dtype).itemsize
+    window_keys = args.max_pages * ps
     result = {"device": jax.devices()[0].device_kind, "args": vars(args),
               "contexts": [int(n) for n in lens],
-              "installed_walk_keys": DECODE_WALK_KEYS, "forms": {}}
+              "installed_walk_keys": attention.DECODE_WALK_KEYS, "forms": {}}
     for name in fns:
-        durs, by_op = events[name]
-        if len(durs) != args.reps:
-            print(f"{len(durs)} launches of {name} in the capture, expected "
-                  f"{args.reps}", file=sys.stderr)
+        tables = [r for r in runs if r.startswith(name + ".")]
+        launches = events[name]
+        if len(launches) != args.reps * len(tables):
+            print(f"{len(launches)} launches of {name} in the capture, "
+                  f"expected {args.reps} x {len(tables)}", file=sys.stderr)
             return 1
-        keys = window_keys
+        ck, trips = window_keys, 1   # the window form: one read of it all
         if "walk" in name:
             ck = min(int(name.rsplit("_", 1)[1]), window_keys)
-            keys = -(-(int(lens.max()) + 1) // ck) * ck
-        us = float(np.median(durs)) / 1e3
-        kv_bytes = 2 * args.lanes * keys * row_bytes  # K and V, read once
-        row = {
-            "calls": len(durs), "us_per_call": us,
-            "min_us": min(durs) / 1e3, "max_us": max(durs) / 1e3,
-            "keys_per_lane": keys, "kv_bytes": kv_bytes,
-            "max_abs_diff_vs_window": float(np.abs(outs[name] - ref).max()),
-            "hbm_share": 100.0 * kv_bytes / hbm_bytes_per_s / (us / 1e6),
-            "top_ops_us_per_call": {
-                op: ns / 1e3 / len(durs) for op, ns in sorted(
-                    by_op.items(), key=lambda kv: -kv[1])[:8]},
-        }
-        result["forms"][name] = row
-        print(json.dumps({"form": name, **row}))
-    os.makedirs("chiprun_out", exist_ok=True)
-    with open("chiprun_out/paged_decode_bench_xla.json", "w") as f:
+            trips = -(-(int(lens.max()) + 1) // ck)
+        for i, run in enumerate(tables):
+            _, _, shared, own, err = runs[run]
+            mine = [d for d, _ in launches[i::len(tables)]]
+            by_op = {}
+            for _, ops in launches[i::len(tables)]:
+                for op, ns in ops.items():
+                    by_op[op] = by_op.get(op, 0) + ns
+            us = float(np.median(mine)) / 1e3
+            # K and V: a shared trip's keys once, the rest once a lane
+            kv_bytes = 2 * row_bytes * ck * (own + args.lanes * (trips - own))
+            row = {
+                "lanes": args.lanes, "shared_keys": shared,
+                "trips": trips, "shared_trips": own,
+                "calls": len(mine), "us_per_call": us,
+                "min_us": min(mine) / 1e3, "max_us": max(mine) / 1e3,
+                "keys_per_lane": trips * ck, "kv_bytes": kv_bytes,
+                "max_abs_diff_vs_window": err,
+                "hbm_share": 100.0 * kv_bytes / hbm_bytes_per_s / (us / 1e6),
+                "top_ops_us_per_call": {
+                    op: ns / 1e3 / len(mine) for op, ns in sorted(
+                        by_op.items(), key=lambda kv: -kv[1])[:8]},
+            }
+            result["forms"][run] = row
+            print(json.dumps({"form": run, **row}))
+    os.makedirs(os.path.dirname(args.out) or ".", exist_ok=True)
+    with open(args.out, "w") as f:
         json.dump(result, f, indent=1)
     return 0
 
@@ -275,6 +334,14 @@ def main() -> int:
     ap.add_argument("--min-len", type=int, default=7700)
     ap.add_argument("--max-len", type=int, default=8900)
     ap.add_argument("--shared-prefix", type=int, default=7424)
+    ap.add_argument("--shared-keys", type=int, nargs="+", default=None,
+                    help="--backend xla: the page tables' common leading "
+                    "keys, a table each (default: --shared-prefix alone)")
+    ap.add_argument("--no-window-form", action="store_true",
+                    help="--backend xla: do not time the static-window read "
+                    "(long windows x many lanes do not fit)")
+    ap.add_argument("--out", default=None,
+                    help="default chiprun_out/paged_decode_bench[_xla].json")
     ap.add_argument("--window", type=int, default=1024,
                     help="0: skip the windowed form")
     ap.add_argument("--dtype", default="bfloat16")
@@ -285,12 +352,18 @@ def main() -> int:
     args = ap.parse_args()
     if args.kv_heads is None:
         args.kv_heads = 8 if args.backend == "xla" else 4
+    if args.out is None:
+        args.out = "chiprun_out/paged_decode_bench{}.json".format(
+            "_xla" if args.backend == "xla" else "")
     if args.rehearse:
         args.lanes, args.heads, args.kv_heads, args.head_dim = 3, 8, 2, 16
         args.page_size, args.num_pages, args.max_pages = 4, 400, 160
         args.min_len, args.max_len, args.shared_prefix = 300, 600, 280
         args.window = args.window and 100
         args.walk_keys = [32, 64, 256]
+        args.shared_keys = args.shared_keys and [0, 280]
+    if args.shared_keys is None:
+        args.shared_keys = [args.shared_prefix]
 
     import jax
     import jax.numpy as jnp
@@ -356,8 +429,8 @@ def main() -> int:
         }
         result["forms"][name] = row
         print(json.dumps({"form": name, **row}))
-    os.makedirs("chiprun_out", exist_ok=True)
-    with open("chiprun_out/paged_decode_bench.json", "w") as f:
+    os.makedirs(os.path.dirname(args.out) or ".", exist_ok=True)
+    with open(args.out, "w") as f:
         json.dump(result, f, indent=1)
     return 0
 

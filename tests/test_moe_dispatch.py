@@ -213,12 +213,16 @@ def test_the_block_traces_the_form_the_rule_names():
 
 # sha256[:16] of the lowered text as the PARENT commit (b9b2e45, dense
 # dispatch alone) lowers it: recorded by running `_decode_text` below,
-# unchanged, as a test in a checkout of that commit (same conftest, same JAX)
+# unchanged, as a test in a checkout of that commit (same conftest, same JAX).
+# The four decode digests were recorded again at PR 51, which split the XLA
+# decode walk's loop in two (ops/attention.py paged_decode_walk) and touched
+# nothing of the routed block: the batched prefill, which holds no walk, still
+# lowers to b9b2e45's text.
 PARENT_TEXTS = {
-    "tiny-moe.decode": "6d748c1b8754100d",
-    "tiny-moe.multi_decode": "39c4914f11be7e58",
-    "sigmoid.decode": "f6a8ef0dab86abec",
-    "sigmoid.multi_decode": "f6b1606d05412e1c",
+    "tiny-moe.decode": "01d1a24dc76875c0",
+    "tiny-moe.multi_decode": "1ea2ec63bd9eb61c",
+    "sigmoid.decode": "a756d47f875b7af2",
+    "sigmoid.multi_decode": "cdfb139cfaf42ae8",
     "sigmoid.bprefill": "335b8cfb25dab145",
 }
 

@@ -5,7 +5,14 @@ softmax, contracting on the pool row's merged Hkv*D axis.  Held here: the
 walk against `causal_attention` over the materialised window, the lowered
 decode step's temporaries, and the engine's two counters of what the walk
 read (`decode_keys_walked`, `decode_keys_window`) with the benchmark's
-reader of their window delta."""
+reader of their window delta.
+
+Since ISSUE 51 the walk splits where the lanes' page tables part: the trips
+whose pages every active lane names read them once for all lanes.  Held
+here too: the split walk against the per-lane walk (the same rows under
+page ids no two lanes share) and the static-window read, the `[1, ck,
+Hkv*D]` read in the lowered step, `decode_keys_shared` against the device's
+own bound, and an engine whose threads hang off one prefix."""
 
 import importlib.util
 import os
@@ -23,8 +30,10 @@ from kafka_tpu.models.quant import quantize_array
 from kafka_tpu.ops.attention import (
     DECODE_WALK_KEYS,
     causal_attention,
+    common_pages,
     decode_walk_pages,
     decode_walk_trips,
+    paged_decode_walk,
 )
 from kafka_tpu.runtime import EngineConfig, GenRequest, InferenceEngine
 from kafka_tpu.runtime import step_programs
@@ -185,6 +194,209 @@ def test_windowed_walk_drops_keys_older_than_the_window():
 
 
 # ----------------------------------------------------------------------
+# the split: trips whose pages every active lane names are read once
+# ----------------------------------------------------------------------
+
+CP = CK // PS          # pages a trip
+IDLE = ("idle",)       # a lane on the trash row, not active
+
+
+def shared_case(rng, P, shared_pages, lanes):
+    """(table, lens, active) over a prefix of `shared_pages` pages attached
+    to every lane that says so.  `lanes`: (tokens held, active, pages of
+    the prefix the lane names in its leading columns), or IDLE.  The rest
+    of what a lane holds (its new row's page too) are pages of its own."""
+    free = iter(rng.permutation(np.arange(1, 1 << 12)))
+    prefix = [next(free) for _ in range(shared_pages)]
+    table = np.zeros((len(lanes), P), np.int32)
+    lens, active = [], []
+    for row, lane in zip(table, lanes):
+        if lane == IDLE:
+            lens.append(0)
+            active.append(False)
+            continue
+        n, on, attached = lane
+        held = -(-(n + 1) // PS)
+        attached = min(attached, held)
+        row[:attached] = prefix[:attached]
+        row[attached:held] = [next(free) for _ in range(held - attached)]
+        lens.append(n)
+        active.append(on)
+    return table, np.asarray(lens, np.int32), np.asarray(active, bool)
+
+
+def privately(table, k_pool, v_pool):
+    """The same rows under page ids no two lanes share: every page a lane
+    names is copied to a page of its own past the pool's end (the per-lane
+    walk, forced: no column is common)."""
+    table = np.array(table)
+    pages = sorted(set(table.ravel()) - {0})
+    first = k_pool.shape[0] // PS
+    rows = (np.asarray(pages)[:, None] * PS + np.arange(PS)).ravel()
+    copies = [(k_pool, v_pool)]
+    out = np.zeros_like(table)
+    for b, row in enumerate(table):
+        moved = {pg: first + b * len(pages) + i for i, pg in enumerate(pages)}
+        out[b] = [moved.get(pg, 0) for pg in row]
+        copies.append((k_pool[rows], v_pool[rows]))
+    return (out, jnp.concatenate([k for k, _ in copies]),
+            jnp.concatenate([v for _, v in copies]))
+
+
+def walk_of(q, k_pool, v_pool, table, lens, active, hkv, window,
+            heads_batched=False):
+    """`paged_decode_walk` as `_decode_walk` calls it, and the trips it
+    shared: (out [B, Hq, D], own)."""
+    dt = q.dtype
+
+    def read_pages(pages):
+        return (_kv_read_pages(k_pool, pages, PS, dt),
+                _kv_read_pages(v_pool, pages, PS, dt))
+
+    table, lens, active = map(jnp.asarray, (table, lens, active))
+    out = jax.jit(lambda q: paged_decode_walk(
+        q, read_pages, table, lens, active, page_size=PS, num_kv_heads=hkv,
+        window=window, heads_batched=heads_batched))(q)
+    return np.asarray(out, np.float32), device_shared_trips(table, lens,
+                                                            active)
+
+
+def device_shared_trips(table, lens, active):
+    """The walk's own bound: the table's common leading pages in whole
+    trips, within the trips the longest active lane needs."""
+    table, lens, active = map(jnp.asarray, (table, lens, active))
+    P = table.shape[1]
+    cp = decode_walk_pages(P, PS)
+    return min(int(common_pages(table, active)[1]) // cp,
+               int(decode_walk_trips(lens, active, cp * PS)), -(-P // cp))
+
+
+SPLITS = {
+    # name: (P, shared pages, lanes, shared trips)
+    "whole_trips_and_ragged_tails": (
+        128, 2 * CP, [(2 * CK + 5, True, 2 * CP), (2 * CK + 700, True, 2 * CP),
+                      (3 * CK - 1, True, 2 * CP), (3 * CK, True, 2 * CP)], 2),
+    "a_remainder_under_one_trip": (
+        128, CP + 10, [(CK + 300, True, CP + 10), (CK + 170, True, CP + 10),
+                       (2 * CK + 9, True, CP + 10)], 1),
+    "less_than_one_trip_in_common": (
+        128, CP - 1, [(CK + 300, True, CP - 1), (700, True, CP - 1)], 0),
+    "no_sharing": (128, 0, [(CK + 300, True, 0), (2 * CK, True, 0)], 0),
+    "an_idle_and_a_prefilling_lane_inside_the_run": (
+        128, 2 * CP, [IDLE, (2 * CK + 40, True, 2 * CP), (900, False, 0),
+                      (2 * CK + 400, True, 2 * CP), IDLE], 2),
+    "a_lane_of_another_prefix": (
+        128, 2 * CP, [(2 * CK + 40, True, 2 * CP), (2 * CK + 90, True, 0),
+                      (2 * CK + 400, True, 2 * CP)], 0),
+    "a_lane_shorter_than_the_shared_run": (
+        128, 3 * CP, [(3 * CK + 40, True, 3 * CP), (CK + 130, True, 3 * CP),
+                      (3 * CK + 400, True, 3 * CP)], 1),
+    "the_first_lane_inactive": (
+        128, 2 * CP, [(2 * CK + 7, False, 0), (2 * CK + 40, True, 2 * CP),
+                      (2 * CK + 300, True, 2 * CP)], 2),
+    "a_lone_lane": (128, 0, [(2 * CK + 77, True, 0)], 3),
+    "the_table_ends_in_a_ragged_trip": (
+        100, 3 * CP, [(100 * PS - 1, True, 3 * CP), (3 * CK + 1, True, 3 * CP)],
+        3),
+    # the table's last, ragged trip goes lane by lane even for a lone lane
+    "a_lone_lane_to_the_tables_ragged_end": (
+        100, 0, [(100 * PS - 1, True, 0)], 3),
+}
+
+
+FORMS = ["whole_trips_and_ragged_tails", "a_remainder_under_one_trip",
+         "an_idle_and_a_prefilling_lane_inside_the_run", "a_lone_lane"]
+SPLIT_CASES = (
+    [(name, None, False, "f32") for name in SPLITS]
+    + [(name, 600, False, "f32") for name in FORMS]      # a windowed layer
+    + [(name, None, True, "f32") for name in FORMS]      # heads on a mesh
+    + [(FORMS[0], 600, True, "f32")]
+    + [(name, None, False, kind) for name in (FORMS[0], FORMS[3])
+       for kind in ("bf16", "int8")])
+
+
+@pytest.mark.parametrize("name, window, heads_batched, pool_kind",
+                         SPLIT_CASES)
+def test_split_walk_is_the_per_lane_walk(name, window, heads_batched,
+                                         pool_kind):
+    """The split walk over tables that share their leading pages equals the
+    per-lane walk over the same rows (page ids no two lanes share) and the
+    static-window read; it shares the trips the case says, and the per-lane
+    form none but a lone lane's."""
+    P, shared_pages, lanes, want = SPLITS[name]
+    rng = np.random.RandomState(len(name))
+    hq, hkv, d = SMALL["hq"], SMALL["hkv"], SMALL["d"]
+    table, lens, active = shared_case(rng, P, shared_pages, lanes)
+    k_pool, dt = make_pool(rng, (1 << 12) * PS, hkv * d, pool_kind)
+    v_pool, _ = make_pool(rng, (1 << 12) * PS, hkv * d, pool_kind)
+    q = jnp.asarray(rng.randn(len(lanes), hq, d).astype(np.float32), dt)
+    split, own = walk_of(q, k_pool, v_pool, table, lens, active, hkv, window,
+                         heads_batched)
+    assert own == want
+    _, ref = both_reads(q, k_pool, v_pool, jnp.asarray(table),
+                        jnp.asarray(lens), jnp.asarray(active), hkv, window,
+                        dt)
+    tol = 2e-2 if pool_kind == "bf16" else 2e-5
+    np.testing.assert_allclose(split[active], ref[active], rtol=tol, atol=tol)
+    assert not split[~active].any()
+    if pool_kind == "int8":   # rows and scales: not copied page by page
+        return
+    apart, k_apart, v_apart = privately(table, k_pool, v_pool)
+    lane_by_lane, none = walk_of(q, k_apart, v_apart, apart, lens, active,
+                                 hkv, window, heads_batched)
+    assert none == (want if len(lanes) == 1 else 0)
+    np.testing.assert_allclose(split, lane_by_lane, rtol=tol, atol=tol)
+
+
+def test_shared_trips_mask_each_lane_by_its_own_length():
+    """Nothing rests on the shared pages being full: a lone lane shares
+    every trip with itself, its last one ragged, and moving the rows past
+    its length (and the trash page's) changes nothing."""
+    rng = np.random.RandomState(5)
+    hq, hkv, d = SMALL["hq"], SMALL["hkv"], SMALL["d"]
+    table, lens, active = shared_case(rng, 128, 0, [(CK + 201, True, 0)])
+    k_pool, dt = make_pool(rng, (1 << 12) * PS, hkv * d, "f32")
+    v_pool, _ = make_pool(rng, (1 << 12) * PS, hkv * d, "f32")
+    q = jnp.asarray(rng.randn(1, hq, d).astype(np.float32))
+    clean, own = walk_of(q, k_pool, v_pool, table, lens, active, hkv, None)
+    assert own == 2
+    last = int(table[0, (CK + 201) // PS]) * PS
+    past = slice(last + (CK + 201) % PS + 1, last + PS)
+    for rows in (past, slice(0, PS)):
+        k_pool, v_pool = k_pool.at[rows].add(5.0), v_pool.at[rows].add(5.0)
+    dirty, _ = walk_of(q, k_pool, v_pool, table, lens, active, hkv, None)
+    np.testing.assert_array_equal(dirty, clean)
+
+
+@pytest.mark.parametrize("steps", [1, 4])
+@pytest.mark.parametrize("name", list(SPLITS))
+def test_host_counts_the_devices_shared_trips(name, steps):
+    """`StepPrograms.decode_keys_shared` from each active lane's page list
+    and length, as `_book_dispatch` hands them over, is the device's
+    `own x ck` for every lane of the program, step by step of a fused
+    dispatch (a lane grows a token a step; its pages are held already)."""
+    P, shared_pages, lanes, want = SPLITS[name]
+    table, lens, active = shared_case(np.random.RandomState(len(name)), P,
+                                      shared_pages, lanes)
+    if lens.max() + steps > P * PS:
+        lens = np.minimum(lens, P * PS - steps)
+    programs = step_programs.StepPrograms(_tiny(), None, PS, len(lanes), P)
+    held = [([int(pg) for pg in row[:-(-(int(n) + steps) // PS)]], int(n))
+            for row, n, on in zip(table, lens, active) if on]
+    device = sum(len(lanes) * CK * device_shared_trips(table, lens + i, active)
+                 for i in range(steps))
+    assert programs.decode_keys_shared(held, steps) == device
+    assert (steps > 1 or device == len(lanes) * want * CK)
+    walked, _ = programs.decode_keys(int(lens[active].max()), steps)
+    assert device <= walked
+    # a decode that does not walk in XLA shares nothing
+    pallas = step_programs.StepPrograms(
+        ModelConfig(name="k", attention_backend="pallas"), None, PS,
+        len(lanes), P)
+    assert pallas.decode_keys_shared(held, steps) == 0
+
+
+# ----------------------------------------------------------------------
 # the lowered decode step
 # ----------------------------------------------------------------------
 
@@ -230,6 +442,9 @@ def test_decode_step_holds_no_temporary_of_the_static_window():
     assert chunk_elems in sizes
     assert window_elems not in sizes
     assert "stablehlo.while" in decode
+    # the shared trips' read: one chunk for all lanes, [1, ck, Hkv*D]
+    assert f"tensor<1x{CK}x{hd}xf32>" in decode
+    assert f"tensor<{B}x{CK}x{hd}xf32>" in decode
     verify = jax.jit(step_programs._verify_fn(cfg, None, PS, 2)).lower(
         params, pool, pool, lanes, jnp.zeros((B, 2), i32),
         jnp.zeros(B, i32)).as_text()
@@ -260,21 +475,25 @@ def read():
 
 def make_engine(model, **kw):
     cfg, params = model
-    return InferenceEngine(
-        cfg, params,
-        EngineConfig(max_batch=2, page_size=PS, num_pages=200,
-                     max_pages_per_seq=128, prefill_buckets=(16, 512), **kw),
-        kv_dtype=jnp.float32)
+    defaults = dict(max_batch=2, page_size=PS, num_pages=200,
+                    max_pages_per_seq=128, prefill_buckets=(16, 512))
+    defaults.update(kw)
+    return InferenceEngine(cfg, params, EngineConfig(**defaults),
+                           kv_dtype=jnp.float32)
 
 
 def spy_dispatches(eng, monkeypatch):
-    """Record (active lanes' lengths, steps) of every decode dispatch."""
+    """Record (active lanes' lengths, steps, active lanes' page-table rows)
+    of every decode dispatch."""
     seen = []
     book = eng._book_dispatch
 
     def spy(toks, members, steps):
-        seen.append(([m.seq.length for m in members if m is not None],
-                     steps))
+        seqs = [m.seq for m in members if m is not None]
+        rows = np.zeros((len(seqs), eng.ecfg.max_pages_per_seq), np.int32)
+        for row, seq in zip(rows, seqs):
+            row[:len(seq.pages)] = seq.pages
+        seen.append(([seq.length for seq in seqs], steps, rows))
         return book(toks, members, steps)
 
     monkeypatch.setattr(eng, "_book_dispatch", spy)
@@ -299,20 +518,26 @@ def test_counters_are_the_device_loops_bound(model, monkeypatch, prompts,
                               max_new_tokens=14))
     eng.run_to_completion()
     B, C = 2, 128 * PS
-    walked = window = 0
-    for lens, steps in seen:
+    walked = window = shared = 0
+    for lens, steps, rows in seen:
+        on = jnp.ones(len(lens), bool)
+        common = int(common_pages(jnp.asarray(rows), on)[1])
         for i in range(steps):
             trips = int(decode_walk_trips(
-                jnp.asarray(lens, jnp.int32) + i, jnp.ones(len(lens), bool),
-                CK))
+                jnp.asarray(lens, jnp.int32) + i, on, CK))
             walked += B * trips * CK
             window += B * C
+            shared += B * min(common // CP, trips) * CK
     assert seen and window > 0
-    assert (eng.decode_keys_walked, eng.decode_keys_window) == (walked,
-                                                                 window)
+    assert (eng.decode_keys_walked, eng.decode_keys_window,
+            eng.decode_keys_shared) == (walked, window, shared)
     snap = eng.metrics.snapshot(eng)["engine"]
     assert snap["decode_keys_walked"] == walked
     assert snap["decode_keys_window"] == window
+    assert snap["decode_keys_shared"] == shared
+    # distinct prompts share no page: a lane shares its trips with itself
+    # while it decodes alone, two lanes together share none
+    assert (shared == walked) == (len(prompts) == 1)
     crossed = max(prompts) + 14 > CK
     assert (walked / window > CK / C) == crossed
     assert walked / window == CK / C or crossed
@@ -326,7 +551,9 @@ def test_counters_stay_zero_on_a_pallas_engine(model, read):
     before = eng.metrics.snapshot(eng)
     eng.run_to_completion()
     after = eng.metrics.snapshot(eng)
-    assert (eng.decode_keys_walked, eng.decode_keys_window) == (0, 0)
+    assert (eng.decode_keys_walked, eng.decode_keys_window,
+            eng.decode_keys_shared) == (0, 0, 0)
+    assert after["engine"]["decode_keys_shared"] == 0
     assert read({"before": before, "after": after}) is None
 
 
@@ -351,3 +578,61 @@ def test_reader_gives_the_windows_share_or_nothing(model, read):
         snap = dict(snap, engine={k: v for k, v in snap["engine"].items()
                                   if not k.startswith("decode_keys")})
         assert read({"before": snap, "after": snap}) is None
+
+
+# ----------------------------------------------------------------------
+# an engine whose threads hang off one prefix
+# ----------------------------------------------------------------------
+
+
+def run_threads(model, threads, together, monkeypatch):
+    """Tokens of `threads` {rid: prompt} behind a prefix a first request
+    left in the cache, submitted all at once or one at a time; and the
+    engine, with the dispatches it booked."""
+    eng = make_engine(model, multi_step=4, max_batch=3)
+    eng.submit(GenRequest(request_id="first",
+                          prompt_ids=threads["t0"][:1000] + [3, 7],
+                          max_new_tokens=2, prefix_key="thread-first"))
+    eng.run_to_completion()
+    seen = spy_dispatches(eng, monkeypatch)
+    reqs = {rid: GenRequest(request_id=rid, prompt_ids=p, max_new_tokens=14,
+                            prefix_key="thread-" + rid)
+            for rid, p in threads.items()}
+    for req in reqs.values():
+        eng.submit(req)
+        if not together:
+            eng.run_to_completion()
+    eng.run_to_completion()
+    for req in reqs.values():
+        assert req.cached_tokens >= 992 and req.cache_source == "cross"
+    return {rid: list(req.output_ids) for rid, req in reqs.items()}, eng, seen
+
+
+def test_threads_on_one_prefix_give_the_tokens_of_each_alone(model,
+                                                             monkeypatch):
+    """Three threads over one cached prefix of 1,000 tokens (62 whole
+    pages: one shared trip and a remainder) decode together in fused
+    launches of 4 steps and cross the walk's second trip boundary (1,024
+    keys) mid-program: the first trip is read once for the three, the
+    tokens are those of each thread run alone (a lone lane shares every
+    trip with itself), and /metrics counts the shared trips by the device's
+    arithmetic."""
+    rng = np.random.RandomState(51)
+    shared = [int(t) for t in rng.randint(1, 128, size=1000)]
+    threads = {f"t{i}": shared + [int(t) for t in rng.randint(1, 128, size=n)]
+               for i, n in enumerate((13, 19, 5))}
+    with pytest.MonkeyPatch.context() as mp:
+        alone, eng_alone, _ = run_threads(model, threads, False, mp)
+    both, eng, seen = run_threads(model, threads, True, monkeypatch)
+    assert both == alone
+    assert all(len(out) == 14 for out in both.values())
+    together = [d for d in seen if len(d[0]) == 3]
+    assert any(steps == 4 and max(lens) < 2 * CK <= max(lens) + steps
+               for lens, steps, _ in together)
+    for _, _, rows in together:
+        assert int(common_pages(jnp.asarray(rows), jnp.ones(3, bool))[1]) == 62
+    # one trip of two or three is shared while all decode; alone, all are
+    assert 0 < eng.decode_keys_shared < eng.decode_keys_walked
+    assert eng_alone.decode_keys_shared == eng_alone.decode_keys_walked
+    assert eng.decode_keys_shared >= sum(
+        3 * CK * steps for _, steps, _ in together) > 0
