@@ -34,6 +34,7 @@ from typing import Dict, Optional, Tuple
 
 from ..runtime.engine import AdmissionError, GenRequest, InferenceEngine, TokenEvent
 from ..runtime.failpoints import failpoint
+from ..runtime.phase_clock import SchedClock
 from ..tracing import add_event
 
 logger = logging.getLogger("kafka_tpu.llm.worker")
@@ -72,6 +73,12 @@ class EngineWorker:
         # terminal events whose dispatch failed, awaiting a paced retry
         # (worker-thread only; see _dispatch_guarded/_retry_redispatches)
         self._redispatches: list = []
+        # The engine thread's clock (runtime/phase_clock.py): every instant
+        # of _run and of the step() it calls is charged to one phase
+        # (tracing.SCHED_PHASES).  The thread owns it and hands it to the
+        # engine it drives, in place of the engine's own.
+        self.sched = SchedClock()
+        engine.sched = self.sched
 
     # -- lifecycle -----------------------------------------------------
 
@@ -149,11 +156,18 @@ class EngineWorker:
 
     def _run(self) -> None:
         logger.info("engine worker started")
+        clock = self.sched
+        mark = clock.mark
+        mark("inbox")
         while not self._stopped.is_set():
             # pause seam: park between steps until resumed (or stopped)
-            while self._pause_req.is_set() and not self._stopped.is_set():
-                self._pause_ack.set()
-                self._resume_evt.wait(timeout=0.1)
+            if self._pause_req.is_set():
+                mark("paused")
+                while (self._pause_req.is_set()
+                       and not self._stopped.is_set()):
+                    self._pause_ack.set()
+                    self._resume_evt.wait(timeout=0.1)
+                mark("inbox")
             # Block when idle; drain without blocking when active.  When
             # the last step withheld decode (the device has its two
             # programs queued: engine._hold_decode) there is nothing to
@@ -163,13 +177,17 @@ class EngineWorker:
             # the event loop for the GIL.
             if not self.engine.has_work:
                 wait: Optional[float] = _IDLE_WAIT_S
+                t_wait = mark("idle_wait")
             elif getattr(self.engine, "decode_held", False):
                 wait = _HOLD_WAIT_S
+                t_wait = mark("hold_wait")
             else:
                 wait = None
             try:
                 kind, payload = self._inbox.get(
                     block=wait is not None, timeout=wait)
+                if wait is not None:
+                    clock.begin_iteration(mark("inbox"))
                 self._handle(kind, payload)
                 # drain any further queued commands
                 while True:
@@ -179,7 +197,12 @@ class EngineWorker:
                         break
                     self._handle(kind, payload)
             except queue.Empty:
-                pass
+                if wait is not None:
+                    # the wait ran out: what it took past its timeout is
+                    # what the GIL and the OS kept from this thread
+                    t_woke = mark("inbox")
+                    clock.begin_iteration(t_woke)
+                    clock.wait_over(t_woke - t_wait - wait)
             if self._stopped.is_set():
                 break
             # paced retry of parked terminal events: one attempt per loop
@@ -197,6 +220,7 @@ class EngineWorker:
                 # recovery ITSELF dies, fall back to failing everything —
                 # "every request gets a terminal event" must hold even
                 # when the engine is beyond repair.
+                mark("inbox")  # recovery is not the phase that raised
                 logger.exception("engine step failed; recovering")
                 try:
                     events = self.engine.recover_from_failure()
@@ -205,8 +229,15 @@ class EngineWorker:
                         "engine recovery failed; failing all requests"
                     )
                     events = self._fail_all()
-            for ev in events:
-                self._dispatch_guarded(ev)
+            if events:
+                mark("deliver")
+                for ev in events:
+                    self._dispatch_guarded(ev)
+                clock.note_delivered(len(events))
+            # one iteration, from the end of its wait to here, under what
+            # it dispatched (tracing.SCHED_ITER_CLASSES)
+            clock.end_iteration(mark("inbox"))
+        clock.stop()
         logger.info("engine worker stopped")
 
     def _dispatch_guarded(self, ev: TokenEvent, attempts: int = 0) -> None:

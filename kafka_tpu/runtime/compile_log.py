@@ -70,6 +70,15 @@ _COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 # BEFORE the duration event above closes: it marks the in-flight compile
 # so the record that follows lands as "hit"
 _CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
+# the two stages ahead of the backend compile (jax._src.dispatch
+# JAXPR_TRACE_EVENT, JAXPR_TO_MLIR_MODULE_EVENT): tracing a program to a
+# jaxpr and lowering it to a module.  The persistent cache saves neither,
+# so their sums are what a program costs at EVERY boot (a Pallas call
+# lowers its kernel each time).
+_STAGE_EVENTS = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+}
 
 # JAX's own variable for the persistent-cache directory; when set, JAX
 # reads it and this module only reports it (enable_compile_cache)
@@ -131,6 +140,11 @@ class CompileObservatory:
         self.compile_seconds_total = 0.0
         self.by_cache: Dict[str, int] = {"hit": 0, "miss": 0, "off": 0}
         self.by_phase: Dict[str, int] = {p: 0 for p in PHASES}
+        # seconds in the stages ahead of the backend compile, by stage
+        # ("trace", "lower") then by phase: no ring record, sums only
+        self.stage_seconds: Dict[str, Dict[str, float]] = {
+            stage: {p: 0.0 for p in PHASES}
+            for stage in _STAGE_EVENTS.values()}
         # storm detector: wall times of first_traffic-phase compiles
         self.storm_n = max(1, int(_env_pos(STORM_N_ENV, 3)))
         self.storm_s = _env_pos(STORM_S_ENV, 60.0)
@@ -212,6 +226,13 @@ class CompileObservatory:
             logger.info("compile: %s %.2fs (phase=%s, cache=%s)",
                         label, seconds, self.phase, cache)
 
+    def record_stage(self, stage: str, seconds: float) -> None:
+        """`seconds` of tracing or lowering happened, in the phase that
+        stands now."""
+        with self._lock:
+            by_phase = self.stage_seconds[stage]
+            by_phase[self.phase] = by_phase.get(self.phase, 0.0) + seconds
+
     def mark_cache_hit(self) -> None:
         """The persistent cache served the compile in flight on this
         thread (the cache-hit monitoring event, which fires before the
@@ -269,6 +290,11 @@ class CompileObservatory:
                 "compile_storms_total": self.storms_total,
                 "by_cache": dict(self.by_cache),
                 "by_phase": dict(self.by_phase),
+                **{f"{stage}_seconds_total": round(sum(by.values()), 4)
+                   for stage, by in self.stage_seconds.items()},
+                **{f"{stage}_seconds_by_phase":
+                   {p: round(v, 4) for p, v in by.items()}
+                   for stage, by in self.stage_seconds.items()},
             }
 
     def signals_section(self) -> Dict[str, Any]:
@@ -313,6 +339,8 @@ class CompileObservatory:
                 "seconds": sec["compile_seconds_total"],
                 "by_cache": sec["by_cache"],
                 "by_phase": sec["by_phase"],
+                "trace_seconds": sec["trace_seconds_total"],
+                "lower_seconds": sec["lower_seconds_total"],
             },
             "records": self.records(),
         }
@@ -328,7 +356,13 @@ _INIT_LOCK = threading.Lock()
 
 def _on_duration_event(event: str, duration_s: float, **kw: Any) -> None:
     obs = _OBS
-    if obs is None or event != _COMPILE_EVENT:
+    if obs is None:
+        return
+    stage = _STAGE_EVENTS.get(event)
+    if stage is not None:
+        obs.record_stage(stage, duration_s)
+        return
+    if event != _COMPILE_EVENT:
         return
     label = obs._current_label()
     if label is not None:

@@ -226,6 +226,8 @@ class DataParallelEngines:
         self.supervisor = ReplicaSupervisorMetrics()
         self.engines: List[InferenceEngine] = []
         self.health: List[ReplicaHealth] = []
+        # the worker's clock once a worker drives the router (`sched`)
+        self._sched = None
         self._build_engines(dp)
         if self._prefill_pool and self.engines[0].prefix_cache is None:
             logger.warning(
@@ -292,6 +294,8 @@ class DataParallelEngines:
         if engine.flight is not None:
             # postmortems and /debug/flight/{replica} name the replica
             engine.flight.replica = r
+        if self._sched is not None:
+            engine.sched = self._sched
         return engine
 
     def _build_engines(self, dp: int) -> None:
@@ -1087,7 +1091,7 @@ class DataParallelEngines:
                 if ev.finished and ev.request_id in registry:
                     done[ev.request_id] = registry[ev.request_id]
             if self.decode_held:
-                time.sleep(_HOLD_NAP_S)
+                self.sched.nap(_HOLD_NAP_S)
         return done
 
     def recover_from_failure(self) -> List[TokenEvent]:
@@ -1207,6 +1211,19 @@ class DataParallelEngines:
         return min(e.retry_after_estimate() for e in self.engines)
 
     @property
+    def sched(self):
+        """The clock of the thread that steps the replicas: the worker's
+        once one drives the router (every replica is handed it, a rebuilt
+        one too), else replica 0's own."""
+        return self._sched or self.engines[0].sched
+
+    @sched.setter
+    def sched(self, clock) -> None:
+        self._sched = clock
+        for e in self.engines:
+            e.sched = clock
+
+    @property
     def metrics(self):
         # expose replica 0's metrics object shape with aggregate snapshot
         return _AggregateMetrics(self)
@@ -1244,6 +1261,7 @@ class _AggregateMetrics:
     def snapshot(self, engine=None,
                  reset_peak: bool = True) -> Dict[str, Any]:
         from .metrics import (
+            SCHED_ITER_HISTOGRAMS,
             UTILIZATION_KINDS,
             merge_snapshots,
             utilization_ratios,
@@ -1251,6 +1269,18 @@ class _AggregateMetrics:
 
         snaps = [e.metrics.snapshot(e, reset_peak=reset_peak)
                  for e in self._engines]
+        # replicas that one thread steps share its clock: the thread's
+        # account stands in the first of them only, so the aggregate below
+        # sums threads, not copies
+        clocks = set()
+        for e, snap in zip(self._engines, snaps):
+            clock = getattr(e, "sched", None)
+            if clock is not None and id(clock) in clocks:
+                snap.pop("sched", None)
+                snap.pop("sched_iter_ms", None)
+                for name in SCHED_ITER_HISTOGRAMS:
+                    snap["histograms"].pop(name, None)
+            clocks.add(id(clock))
         # every section the replicas report merges by the metric table
         # (runtime/metrics.py), from the SAME snapshots exported as the
         # per-replica detail, so the aggregate equals their combination

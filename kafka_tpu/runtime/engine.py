@@ -92,6 +92,7 @@ from .kv_cache import (
     page_table_array,
 )
 from .metrics import EngineMetrics
+from .phase_clock import SchedClock
 from .planner import grammar_table_cap_bytes
 from .prefix_cache import PrefixCache
 from .speculative import LaneSpeculator
@@ -592,6 +593,10 @@ class _Fetch:
     # entry left the FIFO (_process_entry)
     t_start: Optional[float] = None
     t_pop: Optional[float] = None
+    # how many step programs the engine had dispatched when this entry was
+    # queued, its own included (_push_entry): the entry whose number is
+    # still the engine's is the last thing the device was given
+    seq: int = 0
     spec: Optional[_SpecMeta] = None
     # Flight-recorder attribution (ISSUE 11): which utilization kind this
     # dispatch bills to and its modeled roofline seconds.  When the
@@ -604,6 +609,38 @@ class _Fetch:
     # the held experts each pass of this dispatch read ([] or [steps] i32),
     # where its program counts them (StepPrograms.moe_dispatch "token")
     reads: Optional[jnp.ndarray] = None
+
+
+class _DispatchScope:
+    """Around one dispatch call of a step program: the profiler annotation
+    where there is one (engine._dispatch_scope), and the starvation
+    account.  If a stamp has seen the device's queue empty since the last
+    dispatch (engine._starve), this call ends the gap: from its start
+    (lower bound) and its return (upper bound) the thread's clock books
+    how long the device had nothing to run and what the thread did
+    meanwhile (SchedClock.book_starved)."""
+
+    __slots__ = ("engine", "ann", "t_call")
+
+    def __init__(self, engine: "InferenceEngine", ann):
+        self.engine, self.ann = engine, ann
+
+    def __enter__(self):
+        self.t_call = time.monotonic()
+        if self.ann is not None:
+            self.ann.__enter__()
+        return self
+
+    def __exit__(self, exc_type, exc, tb):
+        if self.ann is not None:
+            self.ann.__exit__(exc_type, exc, tb)
+        engine, t_return = self.engine, time.monotonic()
+        engine._dispatch_seq += 1
+        since, engine._starve = engine._starve, None
+        if since is not None and exc_type is None:
+            engine.sched.book_starved(since, self.t_call, t_return)
+        engine._seen_running = engine.sched.seen_running(t_return)
+        return False
 
 
 class _GrammarTables:
@@ -1277,6 +1314,25 @@ class InferenceEngine:
         # order device execution — a dispatch starts when its predecessor
         # finishes or when it was enqueued, whichever is later)
         self._last_ready_t: Optional[float] = None
+        # The clock of the thread that drives this engine: every instant
+        # of step() is charged to one phase (tracing.SCHED_PHASES).  An
+        # engine driven by a worker is handed the worker's
+        # (llm/worker.EngineWorker); driven without one it keeps its own.
+        self.sched = SchedClock()
+        # The device's starvation as the host knows it: set by the stamp
+        # that saw the last queued program done (SchedClock.emptied: the
+        # stamp, the last look that saw the program running, the clock's
+        # vector then), booked by the next dispatch (_DispatchScope),
+        # dropped when no lane is active (time without work is not
+        # starvation).
+        self._starve: Optional[Tuple] = None
+        # the last instant the program dispatched last was known to be
+        # unfinished, with the clock's vector then: its dispatch call's
+        # return, or a later poll that found it running (_stamp_ready)
+        self._seen_running: Tuple[float, List[float]] = (0.0, [])
+        # step programs dispatched (a prefill chunk that is not a prompt's
+        # last queues no fetch entry, and still keeps the device busy)
+        self._dispatch_seq = 0
         # Modeled roofline seconds accumulated over prefill chunk
         # dispatches whose completions are UNOBSERVED (intermediate
         # chunks create no fetch entry).  The final chunk's entry
@@ -1617,21 +1673,27 @@ class InferenceEngine:
         return self._tattrs(**kw)
 
     def _dispatch_scope(self, kind: str,
-                        members: Sequence[Optional["GenRequest"]]):
+                        members: Sequence[Optional["GenRequest"]],
+                        fused: bool = False):
         """Host annotation `kafka.<kind>[<trace ids>]` around one dispatch
         (kind: prefill, decode, verify), so a /debug/profile xplane capture
         correlates device slices with server-side spans and labels idle
         gaps by what the scheduler was dispatching.  One module-global
-        bool read when disabled (KAFKA_TPU_PROFILING unset)."""
+        bool read when disabled (KAFKA_TPU_PROFILING unset).  The scope
+        also books the device's starvation (_DispatchScope): every step
+        program is dispatched inside one, which is also where the
+        iteration learns what it did (tracing.SCHED_ITER_CLASSES)."""
+        self.sched.did("prefill" if kind == "prefill"
+                       else "multi" if fused else "decode")
         if not profiler_annotations_enabled():
-            return contextlib.nullcontext()
+            return _DispatchScope(self, None)
         ids = sorted({
             m.trace.trace_id[:8] for m in members
             if m is not None and m.trace is not None
         })
-        return jax.profiler.TraceAnnotation(
+        return _DispatchScope(self, jax.profiler.TraceAnnotation(
             f"kafka.{kind}[" + ",".join(ids) + "]"
-        )
+        ))
 
     def _dev(self, x) -> jnp.ndarray:
         """Host -> device, replicated across the mesh when one is active.
@@ -2164,6 +2226,8 @@ class InferenceEngine:
         for its whole prefill — their inter-token gap is bounded by ~one
         chunk's compute.
         """
+        mark = self.sched.mark
+        mark("house")
         failpoint("engine.step")
         if self.memory_monitor is not None:
             self.memory_monitor.poll()  # throttled to ~1 Hz internally
@@ -2177,14 +2241,21 @@ class InferenceEngine:
         if self._agent_gaps:
             self._process_agent_gaps()
         self.metrics.record_queue_depth(len(self.waiting))
+        mark("drain")
         self._drain(block=False)
+        mark("admit")
         self._admit()
+        mark("prefill")
         self._advance_prefills()
+        mark("hold_check")
         if not any(s is not None and s.state == ACTIVE for s in self.slots):
             self.decode_held = False
         elif not self._hold_decode():
+            mark("decode")
             self._dispatch_decode()
+            mark("drain")
             self._drain(block=False)
+        mark("house")
         if not self.num_active and not self.waiting and self._pending:
             # Nothing left to dispatch: flush the pipeline — EXCEPT when
             # the pending work is a prefill-and-hand-off.  The DP router
@@ -2196,16 +2267,23 @@ class InferenceEngine:
             # them, so the drive loop keeps coming back).
             if not any(r.handoff and r.state == DRAINING
                        for r in self._requests.values()):
+                mark("flush")
                 self._drain(block=True)
+                mark("house")
         if not self.num_active:
             self.metrics.mark_idle()  # idle gaps are not TPOT
             self._last_ready_t = None  # measured-latency chain restarts
+            self._starve = None  # a device without work is not starved
         if self.flight is not None and not (
                 self.decode_held and self.flight.quiet()):
             # commit this iteration's record + run the anomaly detectors
             # (iterations that only held decode, a millisecond apart,
             # would fill the ring with nothing: they commit ten a second)
+            mark("flight")
             self.flight.finish_step(self)
+        # whatever the caller does before its next wait or step() is the
+        # loop's own bookkeeping
+        mark("inbox")
         out, self._out_events = self._out_events, []
         return out
 
@@ -2218,7 +2296,7 @@ class InferenceEngine:
                 if ev.finished:
                     done[ev.request_id] = registry[ev.request_id]
             if self.decode_held:
-                time.sleep(_HOLD_NAP_S)
+                self.sched.nap(_HOLD_NAP_S)
         return done
 
     def generate(self, prompt_ids: List[int], **kw) -> GenRequest:
@@ -2230,7 +2308,7 @@ class InferenceEngine:
         while req.state != FINISHED:
             self.step()
             if self.decode_held:
-                time.sleep(_HOLD_NAP_S)
+                self.sched.nap(_HOLD_NAP_S)
         return req
 
     # ------------------------------------------------------------------
@@ -2414,6 +2492,7 @@ class InferenceEngine:
         self._pending_steps = 0
         self._constrained_fetch = None
         self._last_ready_t = None
+        self._starve = None
         self._prefill_modeled_acc = None  # its chunks died with the step
         for req in list(self._requests.values()):
             if req.state == WAITING:
@@ -2532,6 +2611,7 @@ class InferenceEngine:
                 self.flight.note_pop(emitted)
 
     def _push_entry(self, entry: _Fetch) -> None:
+        entry.seq = self._dispatch_seq
         if entry.kind != "prefill":
             self.fetch_depth_steps_sum += self._backlog_steps()
             self.fetch_depth_samples += 1
@@ -2582,6 +2662,8 @@ class InferenceEngine:
             if e.t_ready is not None:
                 continue
             if not getattr(e.arr, "is_ready", lambda: True)():
+                if e.seq == self._dispatch_seq:
+                    self._seen_running = self.sched.seen_running(now)
                 break
             self._note_ready(e, now)
 
@@ -2605,6 +2687,12 @@ class InferenceEngine:
             start = self._last_ready_t
         entry.t_start = start
         self._last_ready_t = now
+        if entry.seq == self._dispatch_seq:
+            # the device runs its queue in order, and nothing was
+            # dispatched after this program: nothing is queued behind its
+            # completion, which happened no later than `now` and no
+            # earlier than the program was last known to be running
+            self._starve = self.sched.emptied(now, self._seen_running)
         measured = now - start
         if not observed or measured < 0.0 or measured > 10.0:
             return  # clock weirdness / wedged device: not a calibration
@@ -3145,6 +3233,7 @@ class InferenceEngine:
         req.slot = slot
         self.slots[slot] = req
         self._ctl_dirty = True
+        self.sched.did("admit")
         if req.state == PARKED:
             req.state = ACTIVE
             pending = (
@@ -3248,6 +3337,7 @@ class InferenceEngine:
         The lane is masked out of decode (state PREFILLING) until the last
         chunk lands; decode for other lanes proceeds between chunks.
         """
+        self.sched.did("admit")
         if req.t_prefill_start is None:  # keep the FIRST start on resume
             req.t_prefill_start = time.monotonic()
             # queue wait ends here (untraced requests: record_span is one
@@ -4269,7 +4359,7 @@ class InferenceEngine:
             for s in self.slots
         ))
         fn = self._programs.multi_decode(k, fsm)
-        with self._dispatch_scope("decode", self.slots):
+        with self._dispatch_scope("decode", self.slots, fused=True):
             (self.k_pool, self.v_pool, toks_seq, last, lens,
              *fsm_out, reads) = fn(
                 self.params, self.k_pool, self.v_pool,

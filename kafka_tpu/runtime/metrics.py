@@ -59,6 +59,8 @@ import threading
 import time
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
+from ..tracing import BOOT_STAGES, SCHED_ITER_CLASSES, SCHED_PHASES
+
 TELEMETRY_ENV = "KAFKA_TPU_TELEMETRY"
 SLO_TTFT_ENV = "KAFKA_TPU_SLO_TTFT_MS"
 SLO_TPOT_ENV = "KAFKA_TPU_SLO_TPOT_MS"
@@ -767,10 +769,68 @@ METRICS: Tuple[Metric, ...] = (
         _m("state_snapshots_evicted", merge=None),
         _m("state_bytes_per_slot", kind=GAUGE, merge=None),
     ),
-    # Process-wide sections server/app.py lays over the snapshot: sandbox
-    # supervision, tracing, the autoscaler's control loop
-    # (runtime/autoscaler.py, when KAFKA_TPU_AUTOSCALE runs one) and the
-    # compile observatory (runtime/compile_log.py).
+    # The engine THREAD's account (runtime/phase_clock.SchedClock): one
+    # section a thread.  Replicas that one thread steps share its clock and
+    # report it once (dp_router lays the distinct clocks over the
+    # aggregate); where each replica has a thread the aggregate sums them
+    # and a reader divides by `threads`.
+    *_section(
+        "sched",
+        _m("threads", kind=GAUGE),
+        *(_m(p + "_s", "kafka_tpu_sched_phase_seconds_total{phase=%s}" % p,
+             "Seconds of the engine thread by phase (tracing.SCHED_PHASES); "
+             "every instant belongs to one phase, so they add up to the "
+             "thread's wall time." if p == SCHED_PHASES[0] else "",
+             digits=6)
+          for p in SCHED_PHASES),
+        # over the timed waits that ran out, elapsed less the timeout: what
+        # the thread lost to the GIL and the OS after it asked to be woken
+        _m("wait_over_s", digits=6),
+        # events handed to the event loop: deliver_s / delivered is the
+        # price of one call_soon_threadsafe
+        _m("delivered"),
+        _m("dev_starved_s",
+           "kafka_tpu_sched_dev_starved_seconds_total{bound=lower}",
+           "Seconds the device had nothing queued while lanes were active, "
+           "as the host knows it: from the poll that saw the last program "
+           "done (lower) or the last look that saw it running (upper) to "
+           "the next dispatch.", digits=6),
+        _m("dev_starved_hi_s",
+           "kafka_tpu_sched_dev_starved_seconds_total{bound=upper}",
+           digits=6),
+        _m("dev_starved_gaps"),
+        *(_m("starved_%s%s_s" % (hi, p),
+             "kafka_tpu_sched_starved_seconds_total{bound=%s,phase=%s}"
+             % (bound, p),
+             "The device's starved seconds by what the engine thread was "
+             "doing meanwhile, each bound over its own interval (the upper "
+             "one also holds the phase in which the completion went "
+             "unseen)." if (p, hi) == (SCHED_PHASES[0], "") else "",
+             digits=6)
+          for p in SCHED_PHASES
+          for hi, bound in (("", "lower"), ("hi_", "upper"))),
+    ),
+    # Process-wide sections server/app.py lays over the snapshot: the boot
+    # by stage (a PhaseClock on the booting thread), what building the
+    # snapshots themselves costs, sandbox supervision, tracing, the
+    # autoscaler's control loop (runtime/autoscaler.py, when
+    # KAFKA_TPU_AUTOSCALE runs one) and the compile observatory
+    # (runtime/compile_log.py).
+    *_section(
+        "boot",
+        *(_m(stage + "_s", "kafka_tpu_boot_stage_seconds{stage=%s}" % stage,
+             "Seconds of the boot by stage (tracing.BOOT_STAGES), from "
+             "create_app's first line to its return."
+             if stage == BOOT_STAGES[0] else "", merge=None, digits=3)
+          for stage in BOOT_STAGES),
+    ),
+    *_section(
+        "metrics",
+        # /metrics snapshots are built on the event loop's thread and hold
+        # the GIL while they are
+        _m("snapshot_s", merge=None, digits=6),
+        _m("snapshots", merge=None),
+    ),
     *_section(
         "sandbox",
         *(_m(k, "kafka_tpu_sandbox_total{event=%s}" % k,
@@ -822,6 +882,12 @@ METRICS: Tuple[Metric, ...] = (
            "and engine phase.", kind=DETAIL, merge=None, each="cache"),
         _m("by_phase", "kafka_tpu_compiles_total", kind=DETAIL,
            merge=None, each="phase"),
+        # what a program costs at EVERY boot whatever the cache holds:
+        # jax's jaxpr_trace_duration and jaxpr_to_mlir_module_duration
+        _m("trace_seconds_total", merge=None),
+        _m("lower_seconds_total", merge=None),
+        _m("trace_seconds_by_phase", kind=DETAIL, merge=None),
+        _m("lower_seconds_by_phase", kind=DETAIL, merge=None),
     ),
 )
 
@@ -854,6 +920,8 @@ COMPILE_METRIC_KEYS = _registry("compiles")
 MEMORY_METRIC_KEYS = _registry("memory")
 AGENT_METRIC_KEYS = _registry("agent")
 STATE_METRIC_KEYS = _registry("state")
+SCHED_METRIC_KEYS = _registry("sched")
+BOOT_METRIC_KEYS = _registry("boot")
 # every EngineMetrics field is exported under a key (field -> snapshot
 # path) or is the accumulator behind a derived one
 # (tests/test_prometheus.py holds dataclasses.fields(EngineMetrics) to the
@@ -1278,14 +1346,21 @@ def merge_snapshots(snaps: List[Dict[str, Any]],
         elif merged:
             out[section] = merged
     # distributions merge exactly: same fixed bounds, bucket counts add
+    # (the thread's iteration histograms ride on the replicas that carry a
+    # thread's account: dp_router leaves one copy a thread)
+    names = HISTOGRAM_NAMES + tuple(
+        n for n in SCHED_ITER_HISTOGRAMS
+        if any(n in s["histograms"] for s in snaps))
     hists = {
         name: StreamingHistogram.merged([
             StreamingHistogram.from_snapshot(s["histograms"][name])
-            for s in snaps])
-        for name in HISTOGRAM_NAMES
+            for s in snaps if name in s["histograms"]])
+        for name in names
     }
     out["histograms"] = {name: h.snapshot() for name, h in hists.items()}
     out.update(quantile_sections(hists))
+    if "sched" in out:
+        out["sched_iter_ms"] = sched_iter_quantiles(out["histograms"])
 
     tokens, decode = out["tokens"], out["decode"]
     tokens["fetch_pipeline_waste_frac"] = waste_frac(
@@ -1452,6 +1527,20 @@ HISTOGRAM_NAMES = (
     "burst_tokens",
     "burst_gap_ms",
 )
+# one `_run` iteration of the engine thread by what it dispatched: the
+# thread's clock keeps them (runtime/phase_clock.SchedClock), not
+# EngineMetrics, and a snapshot taken off an engine carries them beside
+# the others
+SCHED_ITER_HISTOGRAMS = tuple(
+    f"sched_iter_{c}_ms" for c in SCHED_ITER_CLASSES)
+
+
+def sched_iter_quantiles(hists: Dict[str, Any]) -> Dict[str, Any]:
+    """The JSON's `sched_iter_ms` section: quantiles by class, from
+    histogram snapshots."""
+    return {c: StreamingHistogram.from_snapshot(hists[name]).quantiles()
+            for c, name in zip(SCHED_ITER_CLASSES, SCHED_ITER_HISTOGRAMS)
+            if name in hists}
 
 
 def ttft_fetch_stages(first_dispatch: float, dev_start: Optional[float],
@@ -2054,6 +2143,14 @@ class EngineMetrics:
                 # state slots of a model with a recurrent state
                 # (STATE_METRIC_KEYS)
                 snap["state"] = engine.state_section()
+            clock = getattr(engine, "sched", None)
+            if clock is not None:
+                # the account of the thread that drives this engine
+                # (SCHED_METRIC_KEYS; runtime/phase_clock.SchedClock)
+                snap["sched"] = clock.section()
+                snap["histograms"].update(clock.histograms())
+                snap["sched_iter_ms"] = sched_iter_quantiles(
+                    snap["histograms"])
             agent_fn = getattr(engine, "agent_section", None)
             if agent_fn is not None:
                 # agent-native scheduling (AGENT_METRIC_KEYS): tool-gap

@@ -26,10 +26,12 @@ agent_done, SURVEY §5.8), so the reference playground works unmodified.
 
 from __future__ import annotations
 
+import contextlib
 import hashlib
 import hmac
 import json
 import logging
+import time
 from dataclasses import replace as dataclasses_replace
 from typing import Any, AsyncIterator, Dict, List, Optional
 
@@ -62,7 +64,19 @@ STATE_KEY = web.AppKey("kafka_tpu_state", dict)
 # ---------------------------------------------------------------------------
 
 
-def build_tpu_provider(cfg: ServingConfig) -> LLMProvider:
+BOOT_ANNOTATION_PREFIX = "kafka.boot."
+
+
+def _boot_clock() -> tracing.PhaseClock:
+    """The booting thread's clock: seconds by stage (tracing.BOOT_STAGES),
+    read once as /metrics `boot.<stage>_s`."""
+    return tracing.PhaseClock(tracing.BOOT_STAGES, BOOT_ANNOTATION_PREFIX,
+                              "rest")
+
+
+def build_tpu_provider(cfg: ServingConfig,
+                       boot: Optional[tracing.PhaseClock] = None
+                       ) -> LLMProvider:
     """Construct tokenizer + engine + provider per the serving config.
 
     Parallelism wiring (the reference wired its whole stack in the server
@@ -78,7 +92,12 @@ def build_tpu_provider(cfg: ServingConfig) -> LLMProvider:
     the hosts — dp_size here is replicas per host.  tp/sp SPMD engines, by
     contrast, span the global device set the way jax.distributed programs
     do.
+
+    `boot` is the booting thread's clock (create_app's): each stage below
+    is one mark on it.
     """
+    boot = boot or _boot_clock()
+    boot.mark("import")
     import jax
 
     from ..llm.tpu_provider import TPULLMProvider
@@ -87,6 +106,7 @@ def build_tpu_provider(cfg: ServingConfig) -> LLMProvider:
     from ..parallel.distributed import init_distributed
     from ..runtime import EngineConfig, InferenceEngine
 
+    boot.mark("rest")
     # before any backend use: multi-host init when KAFKA_TPU_COORDINATOR /
     # NUM_PROCESSES are set (SURVEY §2.2 "distributed communication
     # backend"); returns False and costs nothing single-process
@@ -197,6 +217,7 @@ def build_tpu_provider(cfg: ServingConfig) -> LLMProvider:
 
     # NOW materialize weights (checkpoint load / random init); the
     # plan-validated model_cfg is the one served
+    boot.mark("weights")
     if cfg.checkpoint_dir:
         _, params = load_checkpoint(cfg.checkpoint_dir, model_cfg)
     else:
@@ -205,7 +226,11 @@ def build_tpu_provider(cfg: ServingConfig) -> LLMProvider:
         from ..models import quantize_params
 
         params = quantize_params(params, model_cfg)
+    # (jax dispatches asynchronously: random weights still being made on
+    # the device are waited for by whoever first reads them, which is the
+    # engine's construction)
 
+    boot.mark("engine_build")
     if cfg.dp_roles and cfg.dp_size <= 1:
         raise ValueError(
             "KAFKA_TPU_DP_ROLES needs dp_size > 1: role pools split the "
@@ -259,6 +284,7 @@ def build_tpu_provider(cfg: ServingConfig) -> LLMProvider:
                 ep=cfg.ep_size,
             ))
         engine = InferenceEngine(model_cfg, params, engine_cfg, mesh=mesh)
+    boot.mark("rest")
     if memory_plan is not None:
         # live HBM accounting (runtime/planner.py MemoryMonitor): the plan
         # attaches after construction so measured bytes_in_use can report
@@ -311,6 +337,7 @@ def build_tpu_provider(cfg: ServingConfig) -> LLMProvider:
         )
 
         if grammar_ondevice_enabled():
+            boot.mark("grammar")
             from ..agents.base import IDLE_TOOL
 
             _warm_tools = [
@@ -397,6 +424,7 @@ def build_tpu_provider(cfg: ServingConfig) -> LLMProvider:
         # costs about one replica's compile time instead of dp of them.
         from concurrent.futures import ThreadPoolExecutor
 
+        boot.mark("warmup")
         with ThreadPoolExecutor(len(engines)) as pool:
             for fut in [pool.submit(_warm_engine, n, e)
                         for n, e in enumerate(engines)]:
@@ -409,6 +437,7 @@ def build_tpu_provider(cfg: ServingConfig) -> LLMProvider:
         if warm_disagg is not None:
             warm_disagg()
         engine.run_to_completion()
+        boot.mark("rest")
         engine_cfg.max_waiting = _admission_bound
         for e in engines:
             e.metrics = EngineMetrics()
@@ -453,6 +482,7 @@ async def create_app(
     """Build the application; DI parameters override config-driven wiring
     (the testing seams the reference got from its ABC layering)."""
     cfg = cfg or ServingConfig.from_env()
+    boot = _boot_clock()
     # late env injection (KAFKA_TPU_FAILPOINTS set after import): arm any
     # configured failpoints before the engine builds
     from ..runtime.failpoints import load_env as _load_failpoints
@@ -475,7 +505,7 @@ async def create_app(
 
     configure_slo(ttft_ms=cfg.slo_ttft_ms, tpot_ms=cfg.slo_tpot_ms)
     if llm_provider is None:
-        llm_provider = build_tpu_provider(cfg)
+        llm_provider = build_tpu_provider(cfg, boot)
     if db is None:
         # remote (PostgREST/Supabase) when KAFKA_TPU_REMOTE_DB_URL is set
         db = make_db_client(cfg.db_path)
@@ -518,6 +548,13 @@ async def create_app(
         "kafka": kafka,
         "draining": False,
         "autoscaler": None,
+        # /metrics `boot` and `metrics`: the boot by stage, and what
+        # building the replies themselves has cost
+        "boot": boot,
+        "metrics_cost": {"snapshot_s": 0.0, "snapshots": 0},
+        # the engine thread's account at the edges of the newest
+        # /debug/profile capture, mark by mark as they are taken
+        "sched_window": {},
     }
     app[STATE_KEY] = state
     # Autoscaler control loop (ISSUE 13, README "Autoscaler"): built only
@@ -551,6 +588,7 @@ async def create_app(
     _add_routes(app)
     app.on_shutdown.append(_drain_on_shutdown)
     app.on_cleanup.append(_cleanup)
+    boot.stop()
     return app
 
 
@@ -1351,11 +1389,44 @@ async def metrics(request: web.Request) -> web.Response:
     """Serving counters (SURVEY §5.1/5.5): TTFT/TPOT percentiles, token
     throughput, batch occupancy, pages in use, prefix-cache reuse.  These
     are the numbers bench.py reports — one source of truth."""
-    llm = _state(request)["llm"]
-    engine = getattr(llm, "engine", None)
+    state = _state(request)
+    engine = getattr(state["llm"], "engine", None)
     if engine is None:
         return web.json_response({"error": "no local engine"}, status=404)
+    # The reply is built and serialised on the event loop's thread with the
+    # GIL held, beside an engine thread that needs it: what that has cost
+    # so far is in the reply (`metrics`), and under profiling the build is
+    # a span of its own.
+    cost = state["metrics_cost"]
+    t0 = time.monotonic()
+    with _snapshot_scope():
+        response = _metrics_response(request, engine, cost)
+    cost["snapshot_s"] += time.monotonic() - t0
+    cost["snapshots"] += 1
+    return response
+
+
+def _snapshot_scope():
+    if not tracing.profiler_annotations_enabled():
+        return contextlib.nullcontext()
+    import jax
+
+    return jax.profiler.TraceAnnotation("kafka.metrics.snapshot")
+
+
+def _metrics_response(request: web.Request, engine,
+                      cost: Dict[str, Any]) -> web.Response:
     snap = engine.metrics.snapshot(engine)
+    # the boot by stage (tracing.BOOT_STAGES), and the seconds and count of
+    # the replies built before this one
+    snap["boot"] = _state(request)["boot"].section()
+    snap["metrics"] = {"snapshot_s": round(cost["snapshot_s"], 6),
+                       "snapshots": cost["snapshots"]}
+    edges = _state(request)["sched_window"]
+    if edges and all(edges.values()):
+        # the newest /debug/profile capture's edges, as far as they have
+        # been taken (its reply carries all three)
+        snap["sched_window"] = dict(edges)
     # sandbox subprocess supervision counters (crashes, supervised
     # restarts, crash loops, reaped zombie handles) — module-aggregated
     # across factories, same one-source-of-truth rule as the engine
@@ -1668,6 +1739,14 @@ def _profile_idle(stopping) -> None:
                      stopping.exception())
 
 
+def _sched_mark(llm) -> Optional[Dict[str, Any]]:
+    """The engine thread's account as it stands, with its wall time."""
+    clock = getattr(getattr(llm, "engine", None), "sched", None)
+    if clock is None:
+        return None
+    return {"t": time.time(), "sched": clock.section()}
+
+
 def _flight_seqs(llm) -> Optional[List[Dict[str, Any]]]:
     """Per-replica flight-recorder sequence cursors (None = no engine or
     recorder off everywhere)."""
@@ -1752,6 +1831,12 @@ async def capture_profile(request: web.Request) -> web.Response:
             )
         llm = _state(request)["llm"]
         start_seqs = _flight_seqs(llm)
+        # filled mark by mark, and served on /metrics meanwhile: a client
+        # that stopped waiting for this reply (stop_trace can outlast its
+        # patience) still finds the capture's edges there
+        sched_window: Dict[str, Any] = _state(request).setdefault(
+            "sched_window", {})
+        sched_window.clear()
         # jax.profiler supports one trace at a time: _PROFILE_BUSY above
         # is the process-wide guard (nothing else in the program traces).
         # start_trace returns in ~40 ms and stays on the loop; stop_trace
@@ -1762,9 +1847,11 @@ async def capture_profile(request: web.Request) -> web.Response:
         t_start = _time.time()
         jax.profiler.start_trace(_PROFILE_DIR)
         t_trace_on = _time.time()
+        sched_window["at_start"] = _sched_mark(llm)
         try:
             await asyncio.sleep(seconds)
         finally:
+            sched_window["at_stop_call"] = _sched_mark(llm)
             t_trace_off = _time.time()
             stopping = asyncio.get_running_loop().run_in_executor(
                 None, jax.profiler.stop_trace)
@@ -1777,6 +1864,7 @@ async def capture_profile(request: web.Request) -> web.Response:
         # callback hangs on (the thread would run on, unguarded)
         await asyncio.shield(stopping)
         t_end = _time.time()
+        sched_window["at_stop_return"] = _sched_mark(llm)
         logger.info(
             "/debug/profile: start_trace %.3f s (on the loop), "
             "stop_trace %.3f s (in the executor), traced %.3f s",
@@ -1812,6 +1900,15 @@ async def capture_profile(request: web.Request) -> web.Response:
         # /debug/flight/{replica} and select records with
         # start_seq <= seq < end_seq (or t in [t_start, t_end])
         "flight_window": flight_window,
+        # the engine thread's account (/metrics `sched`) at the capture's
+        # edges, each with its time.time(): at_start .. at_stop_call
+        # brackets the traced seconds, so the program's by-phase
+        # starvation and the capture's idle gaps can be laid side by side
+        # over the SAME interval; at_start .. at_stop_return brackets what
+        # the capture did to the host (stop_trace holds the GIL for
+        # seconds), so a reader can take a window LESS that bracket
+        "sched_window": (dict(sched_window)
+                         if all(sched_window.values()) else None),
     })
 
 

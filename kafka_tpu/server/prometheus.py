@@ -139,6 +139,14 @@ _HISTOGRAM_FAMILIES = (
      "Tokens arriving together per emission burst.", {}),
     ("burst_gap_ms", "kafka_tpu_emission_burst_gap_milliseconds",
      "Gap between emission bursts.", {}),
+    # one iteration of the engine thread's loop by what it dispatched
+    *((name, "kafka_tpu_sched_iteration_milliseconds",
+       "One iteration of the engine thread's loop, end of its wait to "
+       "end of delivery, by what it did: seated a request (admit), "
+       "dispatched a prefill chunk, a fused decode (multi), single "
+       "decode steps, or nothing (held).", {"did": did})
+      for name, did in zip(metrics.SCHED_ITER_HISTOGRAMS,
+                           metrics.SCHED_ITER_CLASSES)),
 )
 
 

@@ -56,7 +56,16 @@ import threading
 import time
 import uuid
 from collections import OrderedDict
-from typing import Any, Dict, Iterator, List, NamedTuple, Optional
+from typing import (
+    Any,
+    Callable,
+    Dict,
+    Iterator,
+    List,
+    NamedTuple,
+    Optional,
+    Sequence,
+)
 
 logger = logging.getLogger("kafka_tpu.tracing")
 
@@ -233,6 +242,63 @@ DEVICE_SCOPES = (
 )
 
 
+# The engine thread's phases (runtime/phase_clock.py): every instant of the
+# thread's life belongs to exactly one of them.  A phase change is one
+# `clock.mark("<phase>")` in llm/worker.py `_run` or runtime/engine.py
+# `step()`; it charges the time since the last mark to the phase that ends
+# (/metrics `sched.<phase>_s`) and, under KAFKA_TPU_PROFILING, closes that
+# phase's `kafka.sched.<phase>` profiler annotation and opens the next one.
+# Every `.mark("...")` literal in those two files must appear here and vice
+# versa (static check in tests/test_tracing.py, the SPANS contract).
+SCHED_PHASES = (
+    "idle_wait",   # _run: the inbox wait with no work (_IDLE_WAIT_S)
+    "hold_wait",   # _run: the inbox wait while decode is withheld
+                   # (_HOLD_WAIT_S); run_to_completion's nap
+    "inbox",       # _run: _handle of submits, cancels, agent signals; the
+                   # paced retry of parked terminal events; the loop's own
+                   # bookkeeping between a wait and step()
+    "paused",      # _run: parked at the pause seam (topology rebuilds)
+    "house",       # step(): failpoint, memory_monitor.poll, kv_tier.drain,
+                   # deadlines, agent gaps, queue depth; the tail of step()
+    "drain",       # step(): both _drain(block=False) calls: polls, pops,
+                   # _process_entry, _process_token
+    "admit",       # step(): _admit (prefix attach, seating, state restore)
+    "prefill",     # step(): _advance_prefills (chunk dispatches)
+    "hold_check",  # step(): _hold_decode (_stamp_ready, _backlog_steps)
+    "decode",      # step(): _dispatch_decode (_refresh_ctl, _dev,
+                   # _pick_multi_step, the dispatch call, _book_dispatch)
+    "flush",       # step(): _drain(block=True), nothing left to dispatch
+    "flight",      # step(): flight.finish_step
+    "deliver",     # _run: one _dispatch_guarded (call_soon_threadsafe) an
+                   # event step() returned
+)
+
+# What one `_run` iteration did, decided from what it dispatched, in order
+# of precedence: the classes of the `sched_iter_<class>_ms` histograms.
+SCHED_ITER_CLASSES = (
+    "admit",    # seated a request
+    "prefill",  # dispatched a prefill chunk, seated nobody
+    "multi",    # dispatched a fused multi-step decode
+    "decode",   # dispatched single decode steps (or a verify) only
+    "held",     # dispatched nothing
+)
+
+# The booting thread's stages (server/app.py): a second clock of the same
+# kind, read once as /metrics `boot.<stage>_s`, annotations
+# `kafka.boot.<stage>` under profiling.  Same both-directions check against
+# the `.mark("...")` literals of server/app.py.
+BOOT_STAGES = (
+    "import",        # the model / runtime / provider modules
+    "weights",       # init_params or load_checkpoint, quantize_params
+    "engine_build",  # InferenceEngine / DataParallelEngines construction:
+                     # pools, step programs, prefix cache, the RTT probe
+    "grammar",       # the builtin tools' grammar for the fsm warm-up
+    "warmup",        # _warm_engine and the warmup_* calls: every compile
+    "rest",          # everything else from create_app's first line to its
+                     # return: config, memory plan, db, tools, the provider
+)
+
+
 class TraceContext(NamedTuple):
     """What crosses a boundary: enough to parent new spans."""
 
@@ -383,6 +449,88 @@ def profiler_annotations_enabled() -> bool:
     """Should the engine wrap device dispatches in jax.profiler named
     scopes keyed by trace id?  Costs one module-global bool read."""
     return _profiling
+
+
+_READ_RETRIES = 64
+
+
+class PhaseClock:
+    """Seconds by phase of one thread; see runtime/phase_clock.py's
+    module docstring (the engine thread's clock lives there; the booting
+    thread's is a plain one of these over BOOT_STAGES, server/app.py)."""
+
+    def __init__(self, phases: Sequence[str], prefix: str, first: str,
+                 now: Callable[[], float] = time.monotonic):
+        self.phases = tuple(phases)
+        self._index: Dict[Optional[str], Optional[int]] = {
+            p: i for i, p in enumerate(self.phases)}
+        self._index[None] = None  # `stop`
+        self._prefix = prefix
+        self._now = now
+        self.seconds = [0.0] * len(self.phases)
+        self._cur: Optional[int] = self._index[first]
+        self._t = now()
+        self._seq = 0  # odd while the owner is mid-write
+        self._ann: Any = None
+
+    # -- the owning thread ---------------------------------------------
+
+    def mark(self, phase: Optional[str]) -> float:
+        """The open phase ends and `phase` begins (None: none does, see
+        `stop`); returns the instant."""
+        now = self._now()
+        self._seq += 1
+        if self._cur is not None:
+            self.seconds[self._cur] += now - self._t
+        self._t = now
+        self._cur = self._index[phase]
+        self._seq += 1
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+            self._ann = None
+        if profiler_annotations_enabled() and phase is not None:
+            import jax
+
+            self._ann = jax.profiler.TraceAnnotation(self._prefix + phase)
+            self._ann.__enter__()
+        return now
+
+    def stop(self) -> float:
+        """Charge the open phase and open none: the thread's account is
+        closed (the boot clock, once the app is built)."""
+        return self.mark(None)
+
+    def vector(self, at: float) -> List[float]:
+        """Seconds by phase as they stood at `at`, an instant inside the
+        open phase.  Whole on the owner's thread; elsewhere only inside
+        `_consistent`."""
+        out = list(self.seconds)
+        if self._cur is not None:
+            out[self._cur] += at - self._t
+        return out
+
+    # -- any thread ----------------------------------------------------
+
+    def _consistent(self, copy: Callable[[], Any]) -> Any:
+        """`copy()` taken while the owner was between writes."""
+        got = None
+        for _ in range(_READ_RETRIES):
+            s0 = self._seq
+            if not s0 & 1:
+                got = copy()
+                if self._seq == s0:
+                    return got
+            time.sleep(0)  # let the owner finish its write
+        return copy() if got is None else got  # torn at worst by one mark
+
+    def read(self) -> List[float]:
+        """Seconds by phase up to now, the open phase included: their sum
+        is the time since the clock was made (or until `stop`), exactly."""
+        return self._consistent(lambda: self.vector(self._now()))
+
+    def section(self) -> Dict[str, Any]:
+        return {f"{p}_s": round(s, 6)
+                for p, s in zip(self.phases, self.read())}
 
 
 def reset() -> None:

@@ -14,7 +14,8 @@ failure / quarantine / failed recovery)::
 Output: one line per scheduler iteration — seq, wall time, inter-
 iteration gap, dispatch kinds, batch composition, queue/page pressure,
 modeled vs measured dispatch time, cause codes — followed by the anomaly
-state and (for postmortems) the active-lane table and headline metrics
+state and (for postmortems) the active-lane table, headline metrics and
+the engine thread's account by phase (`sched`)
 (including the live-HBM ``memory`` section when present, ISSUE 18).
 The record schema and cause-code table are documented in README
 "Flight recorder".
@@ -173,6 +174,30 @@ def print_metrics_headline(m: Dict[str, Any]) -> None:
                   f"mfu={u.get('mfu')} skew={u.get('model_skew')} "
                   f"measured_s={u.get('measured_busy_s')}")
     print_memory(m.get("memory") or {})
+    print_sched(m.get("sched") or {})
+
+
+def print_sched(sched: Dict[str, Any]) -> None:
+    """The engine thread's account (ISSUE 52) — the `sched` metrics section:
+    seconds by phase, largest first, each with the device's starved seconds
+    charged to it."""
+    phases = sorted(((k[:-2], v) for k, v in sched.items()
+                     if k.endswith("_s") and f"starved_{k}" in sched),
+                    key=lambda kv: -kv[1])
+    if not phases:
+        return
+    total = sum(v for _, v in phases)
+    print(f"  sched: {total:.3f}s over {sched.get('threads', 1)} thread(s); "
+          f"dev_starved={sched.get('dev_starved_s')}s.."
+          f"{sched.get('dev_starved_hi_s')}s in "
+          f"{sched.get('dev_starved_gaps')} gaps; "
+          f"wait_over={sched.get('wait_over_s')}s "
+          f"delivered={sched.get('delivered')}")
+    for name, secs in phases:
+        if secs:
+            print(f"    {name:<11} {secs:>12.6f}s {100 * secs / total:>6.2f}%"
+                  f"  starved {sched.get(f'starved_{name}_s', 0.0):.6f}s"
+                  f"..{sched.get(f'starved_hi_{name}_s', 0.0):.6f}s")
 
 
 def print_memory(mem: Dict[str, Any]) -> None:

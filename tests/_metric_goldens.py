@@ -26,6 +26,7 @@ CLOCKED = {
     ("histograms",),
     ("slo",),                          # met / missed against 200 ms
     ("anomalies",),
+    ("sched",),                        # gaps and deliveries the clock saw
 }
 
 
@@ -216,5 +217,10 @@ def served_snapshot(agg, replica_snaps):
         "compile_seconds_total": 92.125,
         "by_cache": {"hit": 9210, "miss": 9211},
         "by_phase": {"boot": 9212, "warmup": 9213, "first_traffic": 9214},
+        "trace_seconds_by_phase": {"boot": 92.5, "warmup": 93.5},
+        "lower_seconds_by_phase": {"boot": 94.5, "warmup": 95.5},
     }
+    snap["boot"] = {
+        k: 9300.5 + i for i, k in enumerate(M.BOOT_METRIC_KEYS)}
+    snap["metrics"] = {"snapshot_s": 94.125, "snapshots": 9401}
     return snap

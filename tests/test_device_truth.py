@@ -199,6 +199,7 @@ class TestObservatoryUnit:
         msec = obs.metrics_section()
         assert set(msec) == set(COMPILE_METRIC_KEYS) | {
             "by_cache", "by_phase",
+            "trace_seconds_by_phase", "lower_seconds_by_phase",
         }
         ssec = obs.signals_section()
         assert ssec["storm_active"] is False

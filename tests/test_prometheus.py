@@ -457,17 +457,20 @@ class TestSLORegistry:
                 node = node[part]
 
 
-# the fifteen sections a subsystem fills, by their names in the snapshot
+# the eighteen sections a subsystem fills, by their names in the snapshot
 # (the compile observatory's is "compiles")
 TABLE_SECTIONS = (
     "engine", "speculation", "constrained", "slo", "utilization",
     "anomalies", "flight", "kv_tier", "object_tier", "disagg",
     "autoscaler", "compiles", "memory", "agent", "state",
+    # the engine thread's account, the boot by stage, the replies' own cost
+    "sched", "boot", "metrics",
 )
 # of those, the ones that hold scalars under keys and nothing else
 SCALAR_SECTIONS = (
     "engine", "speculation", "constrained", "anomalies", "flight",
     "kv_tier", "object_tier", "autoscaler", "agent", "state",
+    "sched", "boot", "metrics",
 )
 
 

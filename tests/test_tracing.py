@@ -104,6 +104,45 @@ class TestSpanRegistry:
         )
         assert len(set(tracing.DEVICE_SCOPES)) == len(tracing.DEVICE_SCOPES)
 
+    MARK_PATTERN = r"\bmark\(\s*[\"'](\w+)[\"']"
+
+    def _marks(self, *rel):
+        import kafka_tpu
+
+        root = pathlib.Path(kafka_tpu.__file__).parent
+        return set().union(*(
+            re.findall(self.MARK_PATTERN, (root / r).read_text())
+            for r in rel))
+
+    def test_sched_phases_registry_both_directions(self):
+        """Every `mark("...")` in the worker's loop, step() and the clock's
+        own nap (for loops that drive step() themselves) names a phase of
+        SCHED_PHASES, and every phase is
+        marked somewhere: the phases tile the engine thread's time, and
+        /metrics `sched`, the `kafka.sched.*` annotations and the
+        benchmark's readers all go by these names."""
+        wired = self._marks("llm/worker.py", "runtime/engine.py",
+                            "runtime/phase_clock.py")
+        assert not wired - set(tracing.SCHED_PHASES), (
+            f"phases marked but missing from SCHED_PHASES: "
+            f"{wired - set(tracing.SCHED_PHASES)}")
+        assert not set(tracing.SCHED_PHASES) - wired, (
+            f"SCHED_PHASES documents unmarked phases: "
+            f"{set(tracing.SCHED_PHASES) - wired}")
+        assert len(set(tracing.SCHED_PHASES)) == len(tracing.SCHED_PHASES)
+        # step() alone goes through its nine, the worker's loop the rest
+        assert self._marks("llm/worker.py") >= {
+            "idle_wait", "hold_wait", "inbox", "paused", "deliver"}
+        assert self._marks("runtime/engine.py") >= {
+            "house", "drain", "admit", "prefill", "hold_check", "decode",
+            "flush", "flight"}
+
+    def test_boot_stages_registry_both_directions(self):
+        wired = self._marks("server/app.py")
+        assert wired == set(tracing.BOOT_STAGES), (
+            wired ^ set(tracing.BOOT_STAGES))
+        assert len(set(tracing.BOOT_STAGES)) == len(tracing.BOOT_STAGES)
+
     def test_benchmark_scopes_are_registered(self):
         """The benchmark keeps its own table of the scopes its metrics
         read (the yardstick does not move when the program adds a finer
@@ -359,19 +398,17 @@ class TestEngineSpans:
         """KAFKA_TPU_PROFILING=1: decode dispatches run inside a
         jax.profiler.TraceAnnotation scope named by the dispatched trace
         ids — the xplane/server-span correlation key.  Disabled (the
-        default) it degrades to a nullcontext."""
-        import contextlib
-
+        default) the scope carries no annotation (it still books the
+        device's starvation: engine._DispatchScope)."""
         req = GenRequest(request_id="prof-r", prompt_ids=[1, 2],
                          max_new_tokens=2)
-        assert isinstance(engine._dispatch_scope("decode", [req]),
-                          contextlib.nullcontext)
+        assert engine._dispatch_scope("decode", [req]).ann is None
         tracing.configure(profiling=True)
         try:
             root = tracing.start_trace(request_id="prof1")
             req.trace = tracing.current()
             scope = engine._dispatch_scope("decode", [req, None])
-            assert not isinstance(scope, contextlib.nullcontext)
+            assert scope.ann is not None
             with scope:
                 pass  # TraceAnnotation is harmless without a live capture
             # a traced end-to-end generation still works under the flag
@@ -387,7 +424,7 @@ class TestEngineSpans:
     def test_dispatch_scope_names_the_kind(self, engine, kind, monkeypatch):
         """`kafka.<kind>[<trace ids>]` per dispatch kind (what
         benchmarks/trace_reduce.host_label strips to `kafka.<kind>`), and
-        with profiling off one bool read returning a nullcontext: no
+        with profiling off one bool read and a scope without one: no
         annotation object is built, whatever the kind."""
         import contextlib
 
@@ -400,8 +437,7 @@ class TestEngineSpans:
         )
         req = GenRequest(request_id="kind-r", prompt_ids=[1, 2],
                          max_new_tokens=2)
-        assert isinstance(engine._dispatch_scope(kind, [req]),
-                          contextlib.nullcontext)
+        assert engine._dispatch_scope(kind, [req]).ann is None
         assert names == []
         tracing.configure(profiling=True)
         try:
