@@ -12,11 +12,20 @@ kernel walks each sequence's page list directly:
   dereference physical page ids at runtime.
 * the kernel iterates only over the sequence's *valid* pages — a dynamic
   `fori_loop` over softmax steps of STEP_ROWS keys, each step's pages landed
-  in VMEM by manually issued per-page async DMAs into a ring of RING
-  buffers, so the copies of the next two steps overlap this step's compute.
-  A sequence 300 tokens into an 8k window reads 300 tokens' worth of KV, not
-  8k.  A windowed call starts its walk at the chunk (`pages_per_chunk`
-  pages) that holds the window's first key.
+  in VMEM by manually issued async DMAs into a ring of RING buffers, so the
+  copies of the next two steps overlap this step's compute.  A sequence 300
+  tokens into an 8k window reads 300 tokens' worth of KV, not 8k.  A
+  windowed call starts its walk at the chunk (`pages_per_chunk` pages) that
+  holds the window's first key.
+* a whole step whose pages are ONE ascending run of physical pages
+  (`page_table[b, base + j] == page_table[b, base] + j`: a prompt reserved
+  in one go on a pool that hands out its lowest free page first, such as the
+  system prompt every lane attaches) lies side by side in the pool and is
+  fetched by ONE copy a pool; every other step by one copy a page.  The page
+  table decides, step by step (`pages_one_run`); the rows and the buffer they
+  land in are the same either way, so the result is too, bit for bit.  What
+  it buys is the scalar core's time: 64 descriptors a step do not overlap
+  the softmax step, two all but vanish (the numbers are at STEP_ROWS).
 * online softmax (m, l, acc) in VMEM scratch across steps.  GQA is one
   merged-lane matmul over all heads — no repeat_kv materialization.
 * only a walk's boundary steps (the last; windowed, also the first) can hold
@@ -64,7 +73,17 @@ NEG_INF = -1e30
 # exp, sum, matmul, one dependent chain) plus 0.08 us per 128 keys, and the
 # scalar core's DMA starts and waits (0.3 us per 8 pages) do not overlap it:
 # one 128-key chunk a step with two buffers ran 0.78 us a chunk against the
-# walk's own 0.36.  512 keys a step over three buffers runs 0.43.
+# walk's own 0.36.  512 keys a step over three buffers runs 0.43 on a
+# scattered step, a copy a page (K and V: 64 descriptors a step, which the
+# scalar core issues and which do not overlap the softmax step).  A RUN step
+# is one copy a pool.  The WHOLE kernel a chunk, a table whose prompt is one
+# run (14 of a lane's ~16 whole steps) beside the parent's per-page walk
+# (PERF.md section 6, PR 53, `scripts/paged_decode_bench.py --prefix-run
+# --parent`): rows of 512 + 128 lanes (the latent form, 164 KB a chunk)
+# 0.431 -> 0.288 us, of 512 + 512 (Yi, LFM2) 0.433 -> 0.374, of 1,024 +
+# 1,024 (byte-bound at 92% of the chip's bandwidth) 0.701 -> 0.702.  The run
+# test is what a scattered table pays for it: 0.431 -> 0.458 (latent),
+# 0.433 -> 0.430 (Yi), 0.701 -> 0.702.
 STEP_ROWS = 512
 RING = 3
 
@@ -153,8 +172,8 @@ def _decode_kernel(
     seq_lens_ref,    # [B] i32
     # inputs
     q_ref,        # [1, Hq, Hkv*D] VMEM block — block-diagonal expanded q
-    k_pages_hbm,  # [num_pages, ps, Hkv*D] in HBM/ANY
-    v_pages_hbm,  # [num_pages, ps, Hkv*D] in HBM/ANY
+    k_rows_hbm,   # [num_pages * ps, Hkv*D] in HBM/ANY: the pool as it lies,
+    v_rows_hbm,   # a page's ps rows side by side, page after page
     out_ref,      # [1, Hq, Hkv*D] VMEM block — caller slices per-head lanes
     # scratch
     kbuf,     # [RING, SP*ps, Hkv*D] pool dtype
@@ -187,16 +206,20 @@ def _decode_kernel(
         page0 = lo // (pages_per_chunk * ps) * pages_per_chunk
     n_steps = pl.cdiv(n_pages - page0, sp)
     last = n_steps - 1
-    pools = ((k_pages_hbm, kbuf, ksem), (v_pages_hbm, vbuf, vsem))
+    pools = ((k_rows_hbm, kbuf, ksem), (v_rows_hbm, vbuf, vsem))
+
+    def rows(page, n_pages=1):
+        """The pool rows of `n_pages` pages that lie side by side from `page`."""
+        return pl.ds(pl.multiple_of(page * ps, ps), n_pages * ps)
 
     def dma(k, op, guarded):
-        """Start or wait step k's page copies, one scattered page each, into
-        ring slot k % RING.  `guarded`: the step may end short of sp pages."""
+        """Start or wait step k's copies into ring slot k % RING.  `guarded`:
+        the step may end short of sp pages."""
         slot = jax.lax.rem(k, ring)
         base = page0 + k * sp
         if op == "wait" and not guarded:
             # A DMA semaphore counts bytes: one wait for the whole buffer
-            # stands for its sp page copies.
+            # stands for its sp page copies, or for the one run copy.
             for _, buf, sem in pools:
                 pltpu.make_async_copy(
                     buf.at[slot], buf.at[slot], sem.at[slot, 0]).wait()
@@ -208,7 +231,7 @@ def _decode_kernel(
             row = j * ps if isinstance(j, int) else pl.multiple_of(j * ps, ps)
             for hbm, buf, sem in pools:
                 cp = pltpu.make_async_copy(
-                    hbm.at[page], buf.at[slot, pl.ds(row, ps)],
+                    hbm.at[rows(page)], buf.at[slot, pl.ds(row, ps)],
                     sem.at[slot, 0])
                 getattr(cp, op)()
             return carry
@@ -219,28 +242,50 @@ def _decode_kernel(
             # take 0.6 s (0.13 before PR 30) and Mellum2's warm boot 31%
             # longer; boundary steps are one or two a lane.
             jax.lax.fori_loop(0, jnp.minimum(sp, n_pages - base), copy, 0)
-        else:
+            return
+
+        def by_page():
             for j in range(sp):  # unrolled: 0.43 us a chunk, rolled 0.48
                 copy(j)
+
+        def as_run():
+            # The step's pages lie side by side in the pool: ONE descriptor
+            # a pool moves the same rows into the same buffer.
+            for hbm, buf, sem in pools:
+                pltpu.make_async_copy(
+                    hbm.at[rows(page_table_ref[b, base], sp)], buf.at[slot],
+                    sem.at[slot, 0]).start()
+
+        # sp - 1 compares on the scalar core, as one traced compare unrolled
+        # where it is lowered (traced sp - 1 times, `jit.lower` of a kernel
+        # took 0.06 s longer).  Straight-line code ahead of the branch: every
+        # form that decided with less measured a scattered step SLOWER (the
+        # run's two ends first in a `cond` of their own; one flag word a step
+        # computed from the page table by an XLA op ahead of the call, read
+        # here: 0.473 us a chunk against these compares' 0.458 and no test's
+        # 0.431 at the latent geometry; PERF.md section 6, PR 53).
+        run = pages_one_run(
+            lambda i: page_table_ref[b, i], base, sp,
+            functools.partial(jax.lax.fori_loop, unroll=True))
+        jax.lax.cond(run, as_run, by_page)
 
     m_ref[...] = jnp.full_like(m_ref, NEG_INF)
     l_ref[...] = jnp.zeros_like(l_ref)
     acc_ref[...] = jnp.zeros_like(acc_ref)
-    for d in range(ring - 1):
-        @pl.when(d < n_steps)
-        def _(d=d):
-            dma(d, "start", True)
 
-    def body(k, carry):
-        ahead = k + ring - 1  # only the walk's last step can end short
+    def body(i, carry):
+        # Trip i starts step i's copies and attends step k = i - (RING - 1):
+        # every step's start is this one site, the walk's first RING - 1
+        # included.  Only the walk's last step can end short.
+        k = i - (ring - 1)
 
-        @pl.when(ahead < last)
+        @pl.when(i < last)
         def _():
-            dma(ahead, "start", False)
+            dma(i, "start", False)
 
-        @pl.when(ahead == last)
+        @pl.when(i == last)
         def _():
-            dma(ahead, "start", True)
+            dma(i, "start", True)
 
         # A step before the last (and, windowed, at or above lo) holds sp
         # whole pages of attended rows: no guard, no iota, no mask, no select.
@@ -261,12 +306,51 @@ def _decode_kernel(
                     below=None if window is None else lo - row0,
                     latent=latent)
 
-        jax.lax.cond(whole, whole_step, boundary_step)
+        @pl.when(k >= 0)
+        def _():
+            jax.lax.cond(whole, whole_step, boundary_step)
+
         return carry
 
-    jax.lax.fori_loop(0, n_steps, body, 0)
+    jax.lax.fori_loop(0, n_steps + ring - 1, body, 0)
     denom = jnp.maximum(l_ref[...], 1e-30)
     out_ref[0, :, :] = (acc_ref[...] / denom).astype(out_ref.dtype)
+
+
+def _fori(lo: int, hi: int, body, carry):
+    for j in range(lo, hi):
+        carry = body(j, carry)
+    return carry
+
+
+def pages_one_run(page_at, base, n: int, fori=_fori):
+    """Whether pages `base` .. `base + n - 1` of a page list are ONE ascending
+    run of physical pages, `page_at(base + j) == page_at(base) + j`: their
+    rows then lie side by side in the pool.  _decode_kernel's own test of a
+    softmax step (page ids read from SMEM, `fori` the device's loop), and on
+    plain ints the host's."""
+    first = page_at(base)
+    return fori(
+        1, n, lambda j, run: run & (page_at(base + j) == first + j), True)
+
+
+def decode_step_runs(pages, seq_len: int, window: int | None, page_size: int,
+                     max_pages: int, pages_per_chunk: int = 8) -> tuple:
+    """(whole, run) softmax steps of _decode_kernel's walk over a lane whose
+    page list is `pages` with `seq_len` cached tokens, under a page table
+    `max_pages` wide: the steps before the walk's last, each fetched as sp
+    whole pages, and those of them fetched as one run copy a pool.  The
+    kernel's own arithmetic on plain ints: the tests' and the bench script's
+    oracle (the engine counts a global walk's steps a lane at a time,
+    StepPrograms.decode_steps, from SequencePages.run_steps)."""
+    cp, sp = step_pages(max_pages, pages_per_chunk, page_size)
+    first, end = decode_chunk_range(seq_len, window, page_size, cp)
+    page0 = first * cp
+    n_pages = -(-(seq_len + 1) // page_size)
+    whole = -(-(n_pages - page0) // sp) - 1
+    return whole, sum(
+        pages_one_run(pages.__getitem__, page0 + k * sp, sp)
+        for k in range(whole))
 
 
 def decode_chunk_range(seq_len: int, window: int | None, page_size: int,
@@ -346,7 +430,7 @@ def paged_decode_attention_window(
                          diff)
 
 
-def _step_pages(P: int, pages_per_chunk: int, page_size: int) -> tuple:
+def step_pages(P: int, pages_per_chunk: int, page_size: int) -> tuple:
     """(pages a DMA chunk, pages a softmax step) of _decode_kernel's walk for
     a page table of width P: whole chunks a step, STEP_ROWS keys if the table
     names that many."""
@@ -389,7 +473,7 @@ def paged_decode_attention_latent(
     B, Hq, r = q_lat.shape
     lanes = r_pool.shape[1]
     P = page_table.shape[1]
-    cp, sp = _step_pages(P, pages_per_chunk, page_size)
+    cp, sp = step_pages(P, pages_per_chunk, page_size)
     q = jnp.concatenate(
         [q_lat, jnp.pad(q_rope, ((0, 0), (0, 0), (0, lanes - q_rope.shape[-1])))],
         axis=-1)
@@ -422,8 +506,7 @@ def paged_decode_attention_latent(
         interpret=interpret,
         name=("paged_decode_attention_latent" if window is None
               else "paged_decode_attention_latent_window"),
-    )(page_table, seq_lens, q, c_pool.reshape(-1, page_size, r),
-      r_pool.reshape(-1, page_size, lanes))
+    )(page_table, seq_lens, q, c_pool, r_pool)
 
 
 def diff_heads(Hq: int, Hkv: int):
@@ -446,9 +529,7 @@ def _paged_decode(q, k_pool, v_pool, page_table, seq_lens, page_size,
     P = page_table.shape[1]
     if scale is None:
         scale = D**-0.5
-    cp, sp = _step_pages(P, pages_per_chunk, page_size)
-    k_pages = k_pool.reshape(-1, page_size, HD)
-    v_pages = v_pool.reshape(-1, page_size, HD)
+    cp, sp = step_pages(P, pages_per_chunk, page_size)
 
     # Block-diagonal query expansion (see module docstring): qx[b, qh] has
     # q[b, qh] in its own kv head's D-lane block and zeros elsewhere.
@@ -496,7 +577,7 @@ def _paged_decode(q, k_pool, v_pool, page_table, seq_lens, page_size,
         out_shape=jax.ShapeDtypeStruct((B, Hq, HD), q.dtype),
         interpret=interpret,
         name=None if window is None else "paged_decode_attention_window",
-    )(page_table, seq_lens, qx, k_pages, v_pages)
+    )(page_table, seq_lens, qx, k_pool, v_pool)
     if diff:
         # each query row's result over BOTH value heads of its pair
         return out_wide.reshape(

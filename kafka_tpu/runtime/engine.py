@@ -1165,7 +1165,8 @@ class InferenceEngine:
         self._programs = StepPrograms(
             self.cfg, mesh, self.ecfg.page_size, B,
             self.ecfg.max_pages_per_seq,
-            self.cfg.is_moe and experts_int8(params["layers"]))
+            self.cfg.is_moe and experts_int8(params["layers"]),
+            bool(self.ecfg.kv_quantize))
         self._counter = itertools.count()
         # device-resident decode control state (see module docstring)
         self._d_last = self._dev(np.zeros(B, np.int32))
@@ -1434,6 +1435,15 @@ class InferenceEngine:
         self.decode_keys_walked = 0
         self.decode_keys_window = 0
         self.decode_keys_shared = 0
+        # Monotonic, and 0 where no global layer's decode walks in the
+        # Pallas kernel (StepPrograms.decode_steps): the whole softmax steps
+        # that walk fetched over the dispatched decode steps, a lane and a
+        # pass, one layer's worth, and those of them whose pages were one
+        # ascending run of physical pages and were fetched as one copy a
+        # pool (counted from SequencePages.run_steps, kept as pages are
+        # appended: no page list is scanned a dispatch).
+        self.decode_steps_walked = 0
+        self.decode_steps_run = 0
         # Monotonic, and 0 for a model without an indexer
         # (StepPrograms.index_keys): the keys a layer's indexer scored over
         # every dispatched decode step (each lane's context, its own row
@@ -4474,6 +4484,9 @@ class InferenceEngine:
         self.decode_keys_window += window
         self.decode_keys_shared += self._programs.decode_keys_shared(
             tables, steps)
+        walked, run = self._programs.decode_steps(seqs, steps)
+        self.decode_steps_walked += walked
+        self.decode_steps_run += run
         if self.cfg.delta_heads:
             self.delta_state_bytes += self._programs.delta_state_bytes(
                 len(seqs), steps)

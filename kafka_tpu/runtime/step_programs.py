@@ -44,6 +44,7 @@ from ..models.llama import (
 )
 from ..ops.attention import decode_walk_pages, shared_walk_trips
 from ..ops.pallas.gated_delta import chunk_rows
+from ..ops.pallas.paged_attention import step_pages
 from ..ops.sampling import (
     SamplingParams,
     grammar_advance,
@@ -540,10 +541,10 @@ class StepPrograms:
 
     def __init__(self, cfg: ModelConfig, mesh: Any, page_size: int,
                  max_batch: int, max_pages_per_seq: int,
-                 int8_experts: bool = False):
+                 int8_experts: bool = False, int8_kv: bool = False):
         self.cfg, self.mesh, self.ps = cfg, mesh, page_size
         self.B, self.P = max_batch, max_pages_per_seq
-        self.int8_experts = int8_experts
+        self.int8_experts, self.int8_kv = int8_experts, int8_kv
         self.built: Dict[Tuple[str, Optional[Tuple]], Callable] = {}
 
     def _program(self, label: str, key: Tuple, fsm_key: Optional[Tuple],
@@ -594,6 +595,34 @@ class StepPrograms:
         ck = cp * self.ps
         return cp and self.B * ck * sum(
             shared_walk_trips(lanes, steps, self.P, cp, ck))
+
+    def decode_steps(self, seqs, steps: int) -> Tuple[int, int]:
+        """(whole softmax steps the Pallas decode walk fetches, those of
+        them fetched as ONE run copy a pool) over `steps` decode steps of
+        the sequences `seqs` (kv_cache.SequencePages, their `length` the
+        tokens held before the first): a global layer's walk, one layer's
+        worth, by the kernel's own arithmetic (ops/pallas/paged_attention.py
+        _decode_kernel: a lane holding n tokens walks
+        n // step_keys whole steps before its last).  (0, 0) where no global
+        layer walks in that kernel: the `xla` backend, pp, a model with an
+        indexer (its full layers read chosen rows), an int8 pool (its kernel
+        keeps the older walk)."""
+        mesh = self.mesh
+        if (self.cfg.attention_backend != "pallas" or self.cfg.index_topk
+                or self.int8_kv
+                or (mesh is not None and mesh.shape.get("pp", 1) > 1)):
+            return 0, 0
+        _, sp = step_pages(self.P, 8, self.ps)  # the wrappers' default chunk
+        keys = sp * self.ps
+        walked = run = 0
+        for seq in seqs:
+            n, end = seq.length, seq.length + steps
+            for whole in range(n // keys, (end - 1) // keys + 1):
+                # the decode steps that find `whole` whole steps behind them
+                passes = min(end, (whole + 1) * keys) - max(n, whole * keys)
+                walked += passes * whole
+                run += passes * seq.run_steps(sp, whole)
+        return walked, run
 
     def index_keys(self, lengths, steps: int) -> Tuple[int, int]:
         """(keys scored, keys kept) by ONE layer's indexer over `steps`

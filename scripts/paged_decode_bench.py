@@ -6,6 +6,8 @@ out (DMA starts, waits and the loop only).
     python scripts/paged_decode_bench.py                  # Yi's geometry
     python scripts/paged_decode_bench.py --window 0       # global form only
     python scripts/paged_decode_bench.py --rehearse       # CPU, tiny, no times
+    python scripts/paged_decode_bench.py --prefix-run     # + the run table
+    python scripts/paged_decode_bench.py --latent --prefix-run --parent DIR
     python scripts/paged_decode_bench.py --backend xla    # the XLA decode read
     python scripts/paged_decode_bench.py --backend xla --shared-keys 0 7424
 
@@ -18,6 +20,20 @@ the dispatch).  A chunk is `pages_per_chunk` (8) pages, the unit
 `decode_chunk_range` counts and the rooflines of `benchmarks/` charge;
 bytes are those chunks' K and V rows.  Prints one JSON line a form and writes
 them all to chiprun_out/paged_decode_bench.json.
+
+`--prefix-run` times every form on a second table too: the shared prefix's
+pages ONE ascending run of physical pages from page 1 (what the engine's
+first allocation on a fresh pool lays down: `PagePool` pops 1, 2, 3, ...),
+the tails scattered as before.  The kernel fetches a softmax step whose pages
+are one run as one copy a pool (`decode_step_runs` counts them; each row
+carries `steps_whole` / `steps_run`).  `--latent`: the MLA form
+(`paged_decode_attention_latent`) at Kanana-2's geometry, 32 lanes, 32 heads,
+rows of 512 latent + 128 rotary lanes (`--latent-rank 1024 --heads 128
+--window 513 --max-pages 2048`: dots3's sliding layers).  `--parent DIR
+[DIR ...]`: another tree's `kafka_tpu/ops/pallas/paged_attention.py` timed
+beside the installed one on the same inputs (forms `parent_*`, a second
+tree's `parent2_*`, ...), its output required to equal the installed
+kernel's bit for bit on every table.
 
 `--backend xla`: the XLA decode read alone (models/llama.py `_decode_walk`)
 at Mixtral's geometry (32/8 x 128, the rest as above), once for each
@@ -38,6 +54,7 @@ from __future__ import annotations
 
 import argparse
 import bisect
+import functools
 import glob
 import json
 import os
@@ -53,10 +70,12 @@ sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)),
 PAGES_PER_CHUNK = 8
 
 
-def make_case(args, jnp, shared_keys=None, pools=None):
+def make_case(args, jnp, shared_keys=None, pools=None, prefix_run=False):
     """Inputs from --seed: a scattered page table over a shared prefix
     (`shared_keys`, default --shared-prefix), the pools drawn here or
-    `pools` (k, v) as handed in (then args.num_pages says their pages)."""
+    `pools` (k, v) as handed in (then args.num_pages says their pages).
+    `prefix_run`: the prefix's pages are 1, 2, 3, ... (one ascending run),
+    the same draws otherwise."""
     rng = np.random.RandomState(args.seed % 2**31)
     ps, hd = args.page_size, args.kv_heads * args.head_dim
     dtype = jnp.dtype(args.dtype)
@@ -76,6 +95,12 @@ def make_case(args, jnp, shared_keys=None, pools=None):
     free = list(range(1, args.num_pages))  # page 0 is the trash page
     rng.shuffle(free)
     shared = [free.pop() for _ in range(shared_keys // ps)]
+    if prefix_run:
+        # the prefix takes pages 1..n; a tail page among them becomes one of
+        # the pages the shuffled prefix held instead
+        spare = [p for p in shared if p > len(shared)]
+        free = [p if p > len(shared) else spare.pop() for p in free]
+        shared = list(range(1, len(shared) + 1))
     table = np.zeros((args.lanes, args.max_pages), np.int32)
     for b, n in enumerate(lens):
         need = -(-(int(n) + 1) // ps)
@@ -85,17 +110,39 @@ def make_case(args, jnp, shared_keys=None, pools=None):
     return (q, k, v, jnp.asarray(table), jnp.asarray(lens)), lens
 
 
-def forms(args, jax, pa):
-    """{name: (jitted fn, window)}: the installed kernel, and the same walk
-    with `_attend` (the softmax step) replaced by nothing; a tree whose
-    kernel has no such function (before PR 30) gets the first form only."""
+def load_parent(tree):
+    """Another tree's paged_attention module (it imports nothing of its own
+    package), under a name of its own."""
+    import importlib.util
+
+    spec = importlib.util.spec_from_file_location(
+        "parent_paged_attention", os.path.join(
+            tree, "kafka_tpu", "ops", "pallas", "paged_attention.py"))
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def forms(args, jax, modules):
+    """{name: (jitted fn, window)}: for each tree of `modules` {prefix:
+    paged_attention module} the kernel, and the same walk with `_attend` (the
+    softmax step) replaced by nothing; a tree whose kernel has no such
+    function (before PR 30) gets the first form only."""
     interpret = jax.default_backend() != "tpu"
 
-    def build(name, window, walk_only):
+    def build(pa, name, window, walk_only):
         def fn(q, k, v, table, lens):
             if walk_only:
                 attend, pa._attend = pa._attend, lambda *a, **kw: None
             try:
+                if args.latent:
+                    r = args.latent_rank
+                    return pa.paged_decode_attention_latent.__wrapped__(
+                        q[..., :r], q[..., r:], k, v, table, lens,
+                        scale=(r // 4 + q.shape[-1] - r) ** -0.5,
+                        page_size=args.page_size,
+                        pages_per_chunk=PAGES_PER_CHUNK, interpret=interpret,
+                        window=window)
                 return pa._paged_decode(
                     q, k, v, table, lens, args.page_size, PAGES_PER_CHUNK,
                     None, interpret, window)
@@ -106,12 +153,13 @@ def forms(args, jax, pa):
         return jax.jit(fn)
 
     out = {}
-    for window in [None] + ([args.window] if args.window else []):
-        sfx = "window" if window else "global"
-        out[f"bench_{sfx}"] = (build(f"bench_{sfx}", window, False), window)
-        if hasattr(pa, "_attend"):
-            out[f"bench_{sfx}_walk"] = (
-                build(f"bench_{sfx}_walk", window, True), window)
+    for prefix, pa in modules.items():
+        for window in [None] + ([args.window] if args.window else []):
+            name = prefix + ("window" if window else "global")
+            out[name] = (build(pa, name, window, False), window)
+            if hasattr(pa, "_attend"):
+                out[name + "_walk"] = (
+                    build(pa, name + "_walk", window, True), window)
     return out
 
 
@@ -317,7 +365,8 @@ def kernel_events(trace_dir):
 
 def main() -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n")[0])
-    ap.add_argument("--lanes", type=int, default=16)
+    ap.add_argument("--lanes", type=int, default=None,
+                    help="default 16, 32 under --latent")
     ap.add_argument("--heads", type=int, default=32)
     ap.add_argument("--backend", choices=("pallas", "xla"), default="pallas",
                     help="xla: the XLA decode read at Mixtral's geometry")
@@ -349,9 +398,24 @@ def main() -> int:
     ap.add_argument("--reps", type=int, default=5)
     ap.add_argument("--rehearse", action="store_true",
                     help="tiny geometry, any backend, checks only")
+    ap.add_argument("--prefix-run", action="store_true",
+                    help="time every form on a second table too, the shared "
+                    "prefix one ascending run of physical pages")
+    ap.add_argument("--latent", action="store_true",
+                    help="the latent (MLA) form at Kanana-2's geometry: 32 "
+                    "lanes, 32 heads, rows of 512 + 128 lanes")
+    ap.add_argument("--latent-rank", type=int, default=512,
+                    help="--latent: the latent row's lanes (dots3's sliding "
+                    "layers hold 1,024)")
+    ap.add_argument("--parent", nargs="+", default=[],
+                    help="other trees, their kernels timed beside this "
+                    "one's (forms parent_*, parent2_*, ...)")
     args = ap.parse_args()
     if args.kv_heads is None:
         args.kv_heads = 8 if args.backend == "xla" else 4
+    args.rope_dim, args.rope_lanes = 64, 128
+    if args.lanes is None:
+        args.lanes = 32 if args.latent else 16
     if args.out is None:
         args.out = "chiprun_out/paged_decode_bench{}.json".format(
             "_xla" if args.backend == "xla" else "")
@@ -362,6 +426,13 @@ def main() -> int:
         args.window = args.window and 100
         args.walk_keys = [32, 64, 256]
         args.shared_keys = args.shared_keys and [0, 280]
+        args.latent_rank, args.rope_dim, args.rope_lanes = 128, 16, 128
+        if args.backend == "pallas":
+            # two whole softmax steps of shared prefix, so that the run
+            # table's run copies are taken here too
+            args.page_size, args.num_pages, args.max_pages = 16, 240, 96
+            args.min_len, args.max_len, args.shared_prefix = 1100, 1500, 1040
+            args.window = args.window and 700
     if args.shared_keys is None:
         args.shared_keys = [args.shared_prefix]
 
@@ -369,7 +440,6 @@ def main() -> int:
     import jax.numpy as jnp
 
     from kafka_tpu.ops.pallas import paged_attention as pa
-    from kafka_tpu.runtime.planner import device_peaks
 
     on_chip = jax.default_backend() == "tpu"
     if not on_chip and not args.rehearse:
@@ -378,52 +448,105 @@ def main() -> int:
         return 3
     if args.backend == "xla":
         return bench_xla(args, jax, jnp)
-    case, lens = make_case(args, jnp)
-    fns = forms(args, jax, pa)
-    outs = {n: np.asarray(fn(*case), np.float32) for n, (fn, _) in fns.items()}
-    for name, (_, window) in fns.items():
-        if name.endswith("_walk"):
-            continue
-        call = (pa.paged_decode_attention if window is None else
-                lambda *a, **kw: pa.paged_decode_attention_window(
-                    *a, window=window, **kw))
-        ref = np.asarray(call(*case, page_size=args.page_size,
-                              interpret=not on_chip), np.float32)
-        assert np.array_equal(outs[name], ref), name  # the installed kernel
-        assert np.isfinite(ref).all(), name
+    return bench_pallas(args, jax, jnp, pa)
+
+
+def bench_pallas(args, jax, jnp, pa) -> int:
+    from kafka_tpu.runtime.planner import device_peaks
+
+    on_chip = jax.default_backend() == "tpu"
+    modules = {"bench_": pa}
+    for i, tree in enumerate(args.parent):
+        modules[f"parent{i + 1 if i else ''}_"] = load_parent(tree)
+    fns = forms(args, jax, modules)
+    tables = {"shuffled": make_case(args, jnp)}
+    if args.prefix_run:
+        tables["run"] = make_case(args, jnp, prefix_run=True)
+    lens = tables["shuffled"][1]
+    if args.latent:
+        # c~ rows in the K pool's place, k_r rows (padded to a lane tile) in
+        # V's; q = [q^ | q_rope], split again in `forms`
+        rng = np.random.RandomState(args.seed % 2**31 + 1)
+        r, lanes, dt = args.latent_rank, args.rope_lanes, jnp.dtype(args.dtype)
+        slots = args.num_pages * args.page_size
+        c = jnp.asarray(rng.randn(slots, r).astype(np.float32), dt)
+        kr = jnp.asarray(rng.randn(slots, lanes).astype(np.float32), dt)
+        q = jnp.asarray(rng.randn(args.lanes, args.heads, r + args.rope_dim)
+                        .astype(np.float32), dt)
+        tables = {t: ((q, c, kr) + case[3:], ln)
+                  for t, (case, ln) in tables.items()}
+    runs = {}   # "<form>.<table>": (fn, case, window, table)
+    for tname, (case, _) in tables.items():
+        outs = {}
+        for name, (fn, window) in fns.items():
+            outs[name] = np.asarray(fn(*case), np.float32)
+            runs[f"{name}.{tname}"] = (fn, case, window, tname)
+        for name, (_, window) in fns.items():
+            if name.endswith("_walk"):
+                continue
+            assert np.isfinite(outs[name]).all(), (name, tname)
+            # every tree's kernel, bit for bit the installed one's
+            twin = "bench_" + name.split("_", 1)[1]
+            assert np.array_equal(outs[name], outs[twin]), (name, tname)
+            if args.latent or name != twin:
+                continue
+            call = (pa.paged_decode_attention if window is None else
+                    functools.partial(pa.paged_decode_attention_window,
+                                      window=window))
+            ref = np.asarray(call(*case, page_size=args.page_size,
+                                  interpret=not on_chip), np.float32)
+            assert np.array_equal(outs[name], ref), (name, tname)
+    def step_counts(table, window):
+        """(whole steps, run steps) of the walk, summed over the lanes."""
+        rows = np.asarray(table)
+        return tuple(map(sum, zip(*(
+            pa.decode_step_runs(rows[b].tolist(), int(n), window,
+                                args.page_size, args.max_pages,
+                                PAGES_PER_CHUNK)
+            for b, n in enumerate(lens)))))
+
+    steps = {tname: {window: step_counts(case[3], window)
+                     for window in {w for _, w in fns.values()}}
+             for tname, (case, _) in tables.items()}
     if not on_chip:
-        print(json.dumps({"rehearsed": sorted(fns), "device": "cpu"}))
+        print(json.dumps({"rehearsed": sorted(runs), "steps": {
+            t: {str(w): v for w, v in d.items()} for t, d in steps.items()},
+            "device": "cpu"}))
         return 0
 
     trace_dir = tempfile.mkdtemp(prefix="paged_decode_bench_")
     with jax.profiler.trace(trace_dir):
         for _ in range(args.reps):
-            for fn, _ in fns.values():
+            for fn, case, *_ in runs.values():
                 fn(*case).block_until_ready()
     # one Pallas call a launch, launched form after form, rep after rep
     durations = kernel_events(trace_dir)
-    if len(durations) != args.reps * len(fns):
+    if len(durations) != args.reps * len(runs):
         print(f"{len(durations)} Pallas events in the capture, expected "
-              f"{args.reps} x {len(fns)}", file=sys.stderr)
+              f"{args.reps} x {len(runs)}", file=sys.stderr)
         return 1
     _, hbm_bytes_per_s, _ = device_peaks(jax.devices()[0])  # unknown: raises
-    row_bytes = args.kv_heads * args.head_dim * jnp.dtype(args.dtype).itemsize
-    chunk_bytes = 2 * PAGES_PER_CHUNK * args.page_size * row_bytes  # K and V
+    item = jnp.dtype(args.dtype).itemsize
+    row_bytes = ((args.latent_rank + args.rope_lanes) * item if args.latent
+                 else 2 * args.kv_heads * args.head_dim * item)  # K and V
+    chunk_bytes = PAGES_PER_CHUNK * args.page_size * row_bytes
     result = {"device": jax.devices()[0].device_kind, "args": vars(args),
               "contexts": [int(n) for n in lens], "forms": {}}
-    for i, (name, (_, window)) in enumerate(fns.items()):
-        durs = durations[i::len(fns)]
+    for i, (name, (_, _, window, tname)) in enumerate(runs.items()):
+        durs = durations[i::len(runs)]
         chunks = 0
         for n in lens:
             first, end = pa.decode_chunk_range(
                 int(n), window, args.page_size, PAGES_PER_CHUNK)
             chunks += end - first
         us = float(np.median(durs)) / 1e3
+        whole, run = steps[tname][window]
         row = {
             "calls": len(durs), "us_per_call": us,
             "min_us": min(durs) / 1e3, "max_us": max(durs) / 1e3,
             "chunks": chunks, "us_per_chunk": us / chunks,
             "chunk_bytes": chunk_bytes,
+            "steps_whole": whole, "steps_run": run,
             "hbm_share": 100.0 * chunks * chunk_bytes / hbm_bytes_per_s
             / (us / 1e6),
         }

@@ -206,6 +206,23 @@ class TestPagePool:
         pool = PagePool(num_pages=4, page_size=4)
         assert 0 not in pool.alloc(3)
 
+    @pytest.mark.parametrize("order", ["held", "reversed", "second_first"])
+    def test_released_pages_are_handed_out_lowest_first(self, order):
+        """Whatever was given back and in whatever order, the next prompt
+        reserved in one go is one ascending run of pages (what boot's
+        warm-up requests held comes back as 1, 2, 3, ...)."""
+        pool = PagePool(num_pages=64, page_size=4)
+        a, b = pool.alloc(5), pool.alloc(17)
+        assert a + b == list(range(1, 23))
+        kept = pool.alloc(2)    # still held: the run goes round it
+        for pages in {"held": (a, b), "reversed": (b[::-1], a[::-1]),
+                      "second_first": (b, a)}[order]:
+            pool.release(pages)
+        assert pool.alloc(30) == list(range(1, 23)) + list(range(25, 33))
+        assert pool.check_consistency() == []
+        pool.release(kept)
+        assert pool.alloc(3) == kept + [33]
+
 
 class TestReviewRegressions:
     def test_overlong_prompt_rejected_cleanly(self, model):

@@ -384,10 +384,11 @@ def test_latent_kernel_has_its_own_name_and_no_value_pool():
     call = [e for e in jaxpr.jaxpr.eqns[0].params["jaxpr"].eqns
             if e.primitive.name == "pallas_call"]
     assert len(call) == 1
-    # page table, lengths, [q^ | q_rope], latent pages, rotary pages
+    # page table, lengths, [q^ | q_rope], latent rows, rotary rows: the
+    # pools as they lie (a step whose pages are one run is one slice of them)
     shapes = [tuple(v.aval.shape) for v in call[0].invars]
-    assert shapes == [(1, 2), (1,), (1, 4, r + lanes), (4, ps, r),
-                      (4, ps, lanes)]
+    assert shapes == [(1, 2), (1,), (1, 4, r + lanes), (4 * ps, r),
+                      (4 * ps, lanes)]
 
 
 # ---------------------------------------------------------------------------

@@ -2,7 +2,9 @@
 plane (ISSUE 10), and the /metrics + /admin/signals endpoints."""
 
 import asyncio
+import importlib.util
 import math
+import os
 
 import numpy as np
 import pytest
@@ -11,6 +13,7 @@ import jax
 import jax.numpy as jnp
 
 from kafka_tpu.models import ModelConfig, init_params
+from kafka_tpu.ops.pallas.paged_attention import decode_step_runs
 from kafka_tpu.runtime import EngineConfig, GenRequest, InferenceEngine
 from kafka_tpu.runtime.metrics import (
     BURST_TOKEN_BOUNDS,
@@ -839,3 +842,145 @@ class TestMetricsEndpoint:
                 provider.worker.stop()
 
         asyncio.run(go())
+
+
+# ----------------------------------------------------------------------
+# decode_steps_walked / decode_steps_run (PR 53): the Pallas decode walk's
+# whole softmax steps, and those fetched as one run copy a pool
+# ----------------------------------------------------------------------
+
+RUN_READER = os.path.join(
+    os.path.dirname(os.path.dirname(__file__)), "benchmarks", "layer_metrics",
+    "decode_run_step_share.py")
+
+
+@pytest.fixture(scope="module")
+def run_share():
+    with pytest.MonkeyPatch.context() as mp:
+        mp.syspath_prepend(os.path.dirname(os.path.dirname(RUN_READER)))
+        spec = importlib.util.spec_from_file_location(
+            "decode_run_step_share", RUN_READER)
+        module = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(module)
+        yield module.read
+
+
+@pytest.fixture(scope="module")
+def walk_model():
+    cfg = ModelConfig(name="run-count", vocab_size=128, hidden_size=32,
+                      intermediate_size=64, num_layers=2, num_heads=4,
+                      num_kv_heads=2, head_dim=16, dtype="float32")
+    return cfg, init_params(cfg, jax.random.PRNGKey(3))
+
+
+def _walk_engine(model, backend, **kw):
+    cfg, params = model
+    kw = {"num_pages": 160, "max_pages_per_seq": 64, **kw}
+    return InferenceEngine(
+        cfg, params,
+        EngineConfig(max_batch=2, page_size=16, prefill_buckets=(16, 512),
+                     attention_backend=backend, **kw),
+        kv_dtype=jnp.float32)
+
+
+@pytest.mark.parametrize("multi_step", [1, 4])
+def test_decode_step_counters_are_the_kernels_arithmetic(
+        walk_model, run_share, monkeypatch, multi_step):
+    """Over two threads that share 300 tokens of prompt (the second attaches
+    the first's 18 pages and goes on with pages of its own: its first step is
+    no run), the counters equal `decode_step_runs` redone from every
+    dispatch's page lists, pass by pass, although the engine never scans a
+    list (SequencePages.run_steps)."""
+    eng = _walk_engine(walk_model, "pallas", multi_step=multi_step)
+    assert eng.cfg.attention_backend == "pallas"
+    seen, book = [], eng._book_dispatch
+
+    def spy(toks, members, steps):
+        seen.append((steps, [(list(m.seq.pages), m.seq.length)
+                             for m in members if m is not None]))
+        return book(toks, members, steps)
+
+    monkeypatch.setattr(eng, "_book_dispatch", spy)
+    rng = np.random.RandomState(53)
+    shared = list(rng.randint(1, 128, size=300))
+    shares = []
+    for i, n in enumerate((230, 235)):
+        before = eng.metrics.snapshot(eng)
+        eng.submit(GenRequest(
+            request_id=f"r{i}", max_new_tokens=6, prefix_key=f"t{i}",
+            prompt_ids=shared + list(rng.randint(1, 128, size=n))))
+        eng.run_to_completion()
+        shares.append(run_share(
+            {"before": before, "after": eng.metrics.snapshot(eng)}))
+    walked = run = 0
+    for steps, lanes in seen:
+        for pages, length in lanes:
+            for i in range(steps):
+                w, r = decode_step_runs(pages, length + i, None, 16, 64)
+                walked, run = walked + w, run + r
+    assert walked > 0 and 0 < run < walked
+    assert (eng.decode_steps_walked, eng.decode_steps_run) == (walked, run)
+    snap = eng.metrics.snapshot(eng)["engine"]
+    assert (snap["decode_steps_walked"], snap["decode_steps_run"]) == (
+        walked, run)
+    # a fresh pool hands out 1, 2, 3, ...: the first thread's step is a run
+    assert shares == [100.0, 0.0]
+
+
+def test_the_first_prompt_after_boots_warm_up_is_one_run(
+        walk_model, run_share):
+    """server/app.py `_warm_engine` at this size (a prompt a prefill bucket,
+    two at once after it, then short decoders), which takes pages and gives
+    them back, then what `benchmarks/run.py::fill` sends first: one long
+    prompt, reserved in one go.  Its whole steps are runs, every one, because
+    the pool hands out its lowest free page first; last-released-first (a
+    stack) scrambled the pages the warm-up had held.  And after threads of
+    many lengths have come and gone, pages still come lowest first."""
+    eng = _walk_engine(walk_model, "pallas", num_pages=400,
+                       max_pages_per_seq=128)
+    for j, n in enumerate((16, 512)):
+        for burst in (1, 2):
+            for i in range(burst):
+                eng.submit(GenRequest(request_id=f"wb{j}_{burst}_{i}",
+                                      prompt_ids=[3] * n, max_new_tokens=1))
+            eng.run_to_completion()
+    for i in range(3):
+        eng.submit(GenRequest(request_id=f"w{i}", prompt_ids=[3] * 8,
+                              max_new_tokens=eng.ecfg.multi_step + 2))
+    eng.run_to_completion()
+    rng = np.random.RandomState(531)
+    before = eng.metrics.snapshot(eng)
+    eng.submit(GenRequest(
+        request_id="p0", max_new_tokens=4, prefix_key="p0",
+        prompt_ids=list(rng.randint(1, 128, size=1100))))
+    eng.run_to_completion()
+    after = eng.metrics.snapshot(eng)
+    assert after["engine"]["decode_steps_walked"] > (
+        before["engine"]["decode_steps_walked"])
+    assert run_share({"before": before, "after": after}) == 100.0
+    for i, n in enumerate((40, 300, 90, 700, 20, 530)):
+        eng.submit(GenRequest(
+            request_id=f"c{i}", max_new_tokens=3 + i,
+            prompt_ids=list(rng.randint(1, 128, size=n))))
+    eng.run_to_completion()
+    pages = eng.pool.alloc(60)
+    assert pages == sorted(pages)
+    eng.pool.release(pages)
+    assert eng.pool.check_consistency() == []
+
+
+@pytest.mark.parametrize("backend, kw", [
+    ("xla", {}), ("pallas", {"kv_quantize": "int8"})])
+def test_decode_step_counters_stay_zero_where_no_kernel_walks(
+        walk_model, run_share, backend, kw):
+    eng = _walk_engine(walk_model, backend, **kw)
+    before = eng.metrics.snapshot(eng)
+    eng.submit(GenRequest(request_id="a", max_new_tokens=4,
+                          prompt_ids=list(range(1, 521))))
+    eng.run_to_completion()
+    after = eng.metrics.snapshot(eng)
+    assert (eng.decode_steps_walked, eng.decode_steps_run) == (0, 0)
+    assert after["engine"]["decode_steps_walked"] == 0
+    assert after["engine"]["decode_steps_run"] == 0
+    assert run_share({"before": before, "after": after}) is None
+    assert run_share({"before": {"engine": {}}, "after": after}) is None

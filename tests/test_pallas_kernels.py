@@ -5,6 +5,8 @@ Mosaic. The reference is ops.attention.causal_attention driven exactly the
 way the engine's decode step drives it (PagedView index plan).
 """
 
+import functools
+
 import numpy as np
 import pytest
 
@@ -12,7 +14,16 @@ import jax
 import jax.numpy as jnp
 
 from kafka_tpu.ops.attention import causal_attention
-from kafka_tpu.ops.pallas import paged_decode_attention
+from kafka_tpu.ops.pallas import (
+    paged_decode_attention,
+    paged_decode_attention_latent,
+    paged_decode_attention_window,
+)
+from kafka_tpu.ops.pallas.paged_attention import (
+    decode_step_runs,
+    pages_one_run,
+)
+from kafka_tpu.runtime.kv_cache import SequencePages
 
 
 def make_paged_case(seed, B, P, ps, Hq, Hkv, D, num_pages):
@@ -584,3 +595,174 @@ class TestPagedVerifyAttention:
         assert outs[("pallas", 4)] == outs[("xla", 0)]
         # the pallas run must have actually exercised the verify kernel
         assert engines[("pallas", 4)].metrics.speculation_verify_steps > 0
+
+
+# ----------------------------------------------------------------------
+# one physical layout or another: the run copy (PR 53)
+# ----------------------------------------------------------------------
+#
+# _decode_kernel fetches a whole softmax step whose pages are one ascending
+# run of physical pages as ONE copy a pool, every other step a copy a page.
+# Only the route the rows take into VMEM differs, so the same logical K/V
+# must give the same bits whatever the physical layout: each case lays the
+# lanes' pages out as named, then the SAME pages shuffled (no run anywhere:
+# the copies the kernel always made), and compares with array_equal.
+
+RUN_PS, RUN_P = 16, 128      # 2,048-key tables: four 512-key steps of 32 pages
+RUN_SP = 32
+RUN_WINDOW = 1200
+
+
+def _run_layouts():
+    """{name: (per-lane physical page lists, seq_lens)}."""
+    rng = np.random.RandomState(53)
+    far = lambda n, lo: [int(p) for p in rng.permutation(  # noqa: E731
+        np.arange(lo, lo + 4 * n))[:n]]
+    lens = [2040, 1500, 700]
+    need = [-(-(n + 1) // RUN_PS) for n in lens]
+    runs = [list(range(1 + 200 * b, 1 + 200 * b + n))
+            for b, n in enumerate(need)]
+    out = {"all_runs": (runs, lens)}
+    # two whole steps of a prefix every lane shares, the tails scattered
+    prefix = list(range(1, 2 * RUN_SP + 1))
+    out["run_prefix_scattered_tail"] = (
+        [prefix[:n] + far(max(n - len(prefix), 0), 1000 + 600 * b)
+         for b, n in enumerate(need)], lens)
+    broken = [list(r) for r in runs]
+    for b, r in enumerate(broken):      # one page out of line, mid-step
+        if len(r) > RUN_SP + 13:
+            r[RUN_SP + 13] = 900 + b
+    out["run_broken_mid_step"] = (broken, lens)
+    out["descending_run"] = ([r[::-1] for r in runs], lens)
+    out["run_starts_mid_step"] = (
+        [far(16, 1000 + 100 * b) + r[16:] for b, r in enumerate(runs)], lens)
+    # windowed: page0 = (n + 1 - window) // 128 * 8 is 48, 16 (chunk- but
+    # not step-aligned) and 32 (step-aligned)
+    lens = [2040, 1500, RUN_WINDOW + 4 * 128 + 5]
+    need = [-(-(n + 1) // RUN_PS) for n in lens]
+    out["window_starts_off_step"] = (
+        [list(range(1 + 200 * b, 1 + 200 * b + n))
+         for b, n in enumerate(need)], lens)
+    # the last step full to its last row; one row into the next step
+    lens = [2 * 512 - 1, 3 * 512, 512 - 1]
+    need = [-(-(n + 1) // RUN_PS) for n in lens]
+    out["context_ends_on_a_step"] = (
+        [list(range(1 + 200 * b, 1 + 200 * b + n))
+         for b, n in enumerate(need)], lens)
+    return out
+
+
+RUN_LAYOUTS = _run_layouts()
+RUN_FORMS = ("global", "window", "latent", "latent_window", "diff")
+
+
+RUN_POOL_PAGES = 2400   # one pool shape for every case: one compile a form
+
+
+def _lay_out(pages, content, place):
+    """(pools, table): the lanes' page lists `pages` with page p at physical
+    page place(p), holding `content[p]` (its rows in each pool); every other
+    page NaN."""
+    pools = [np.full((RUN_POOL_PAGES * RUN_PS, rows.shape[1]), np.nan,
+                     np.float32) for rows in next(iter(content.values()))]
+    table = np.zeros((len(pages), RUN_P), np.int32)
+    for b, row in enumerate(pages):
+        table[b, :len(row)] = [place(p) for p in row]
+    assert table.max() < RUN_POOL_PAGES
+    for p, both in content.items():
+        for pool, rows in zip(pools, both):
+            pool[place(p) * RUN_PS:(place(p) + 1) * RUN_PS] = rows
+    return pools, table
+
+
+def _run_case(form, dtype, layout):
+    """[(output, table)] of `form` over the layout as named and over the
+    same pages spread out, and the lanes' contexts."""
+    pages, lens = RUN_LAYOUTS[layout]
+    rng = np.random.RandomState(len(layout))
+    latent = form.startswith("latent")
+    widths = (64, 32) if latent else (64, 64)   # c~ | k_r, or K | V at 2 x 32
+    logical = sorted({p for row in pages for p in row})
+    content = {p: [rng.randn(RUN_PS, w).astype(np.float32) for w in widths]
+               for p in logical}
+    # the same pages, nowhere two side by side in a lane's list
+    spread = dict(zip(logical, (int(p) for p in rng.permutation(
+        np.arange(1, RUN_POOL_PAGES // 2 - 1))[:len(logical)] * 2 + 1)))
+    q = rng.randn(len(pages), 4, 64 + 16 if latent else 32).astype(np.float32)
+    window = RUN_WINDOW if form.endswith("window") else None
+    outs = []
+    for place in (lambda p: p, spread.__getitem__):
+        pools, table = _lay_out(pages, content, place)
+        args = ([jnp.asarray(pool, dtype) for pool in pools]
+                + [jnp.asarray(table), jnp.asarray(lens, jnp.int32)])
+        if latent:
+            out = paged_decode_attention_latent(
+                jnp.asarray(q[..., :64], dtype), jnp.asarray(q[..., 64:], dtype),
+                *args, scale=0.11, page_size=RUN_PS, interpret=True,
+                window=window)
+        elif window:
+            out = paged_decode_attention_window(
+                jnp.asarray(q, dtype), *args, window=window,
+                page_size=RUN_PS, interpret=True)
+        else:
+            out = paged_decode_attention(
+                jnp.asarray(q, dtype), *args, page_size=RUN_PS,
+                interpret=True, diff=form == "diff")
+        outs.append((np.asarray(out, np.float32), table))
+    return outs, lens
+
+
+@pytest.mark.parametrize("layout", sorted(RUN_LAYOUTS))
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+@pytest.mark.parametrize("form", RUN_FORMS)
+def test_one_physical_layout_or_another_gives_the_same_bits(form, dtype,
+                                                            layout):
+    (laid, table), (spread, spread_table) = _run_case(
+        form, jnp.dtype(dtype), layout)[0]
+    assert np.isfinite(laid).all() and np.isfinite(spread).all()
+    assert np.array_equal(laid, spread)
+    # the spread layout is the control: no step of it is a run
+    pages, lens = RUN_LAYOUTS[layout]
+    window = RUN_WINDOW if form.endswith("window") else None
+    runs = [decode_step_runs(t[b].tolist(), n, window, RUN_PS, RUN_P)
+            for t in (table, spread_table) for b, n in enumerate(lens)]
+    assert sum(r for _, r in runs[len(lens):]) == 0
+    if layout in ("all_runs", "window_starts_off_step"):
+        assert all(w == r for w, r in runs[:len(lens)])
+        assert sum(w for w, _ in runs[:len(lens)]) > 0
+
+
+@pytest.mark.parametrize("window", [None, RUN_WINDOW])
+@pytest.mark.parametrize("layout", sorted(RUN_LAYOUTS))
+def test_run_step_count_is_the_kernels_own_test(layout, window):
+    """`decode_step_runs` (plain ints: the engine's counter) against the
+    walk's arithmetic redone here and `pages_one_run` traced the way the
+    kernel traces it, and `SequencePages.run_steps` against both."""
+    pages, lens = RUN_LAYOUTS[layout]
+    cp = 8
+
+    @jax.jit
+    def traced(row, base):
+        return pages_one_run(
+            lambda i: row[i], base, RUN_SP,
+            functools.partial(jax.lax.fori_loop, unroll=True))
+
+    for row, n in zip(pages, lens):
+        n_pages = -(-(n + 1) // RUN_PS)
+        page0 = 0 if window is None else (
+            max(n + 1 - window, 0) // (cp * RUN_PS) * cp)
+        last = -(-(n_pages - page0) // RUN_SP) - 1
+        padded = jnp.asarray(row + [0] * (RUN_P - len(row)), jnp.int32)
+        want = [bool(np.all(np.diff(row[page0 + k * RUN_SP:][:RUN_SP]) == 1))
+                for k in range(last)]
+        assert [bool(traced(padded, page0 + k * RUN_SP))
+                for k in range(last)] == want
+        assert decode_step_runs(
+            row, n, window, RUN_PS, RUN_P) == (last, sum(want))
+        if window is None:
+            seq = SequencePages("s", pages=[])
+            for cut in (len(row) // 3, len(row)):   # as the list grows
+                seq.pages.extend(row[len(seq.pages):cut])
+                for upto in range(last + 1):
+                    done = min(upto, cut // RUN_SP)
+                    assert seq.run_steps(RUN_SP, upto) == sum(want[:done])

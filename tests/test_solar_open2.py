@@ -767,10 +767,12 @@ def test_new_per_layer_entries_list_the_new_cell_alone():
         assert m["workloads"] == [cell], m["name"]
         assert os.path.exists(os.path.join(
             ROOT, "benchmarks", "layer_metrics", m["name"] + ".py"))
-    # and no older metric's list gained the cell
-    for m in bench["per_layer"]:
-        if m["name"] not in new:
-            assert cell not in m.get("workloads", ()), m["name"]
+    # and no older metric's list gained the cell (a LATER PR's metric may
+    # list it: `decode_run_step_share`, PR 53)
+    first = min(i for i, m in enumerate(bench["per_layer"])
+                if m["name"] in new)
+    for m in bench["per_layer"][:first]:
+        assert cell not in m.get("workloads", ()), m["name"]
     entry = next(w for w in bench["workloads"] if w["name"] == cell)
     assert (entry["config"], entry["traffic"], entry["chips"]) == (
         "solar-open2-250b", "chat-decode", 1)
