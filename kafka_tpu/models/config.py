@@ -17,7 +17,10 @@ LFM2-8B-A1B) whose layers are gated short convolutions, each with a two-row
 tail in a state slot, beside full-attention layers that alone hold rows, and
 under a third (`solar_open2`: Solar-Open2-250B) whose layers are gated
 delta-rule linear attention, each with a matrix a head in a state slot, beside
-gated full-attention layers that do not rotate.
+gated full-attention layers that do not rotate.  A fourth (`falcon_h1`:
+Falcon-H1-34B) is a dense decoder every layer of which holds rows AND a
+state: grouped-query attention and a Mamba-2 (SSD) mixer side by side on one
+normed input, under the family's muP multipliers.
 
 The reference service routed model names to remote providers by string
 heuristics (src/llm/utils.py:11-29); here a model name resolves to a local
@@ -44,7 +47,9 @@ from .vision import VisionConfig
 # conv_L_cache - 1 rows of its gated input and no rows either; DELTA is
 # `solar_open2`'s gated delta-rule linear attention, whose state is a
 # [head_dim, head_dim] matrix a head beside the tails of its three short
-# convolutions, and no rows.
+# convolutions, and no rows; PARALLEL is `falcon_h1`'s layer, grouped-query
+# attention and a Mamba-2 (SSD) mixer on the same normed input, their outputs
+# summed into the residual: the one kind that holds rows AND a state.
 WINDOWED = "sliding_attention"
 GLOBAL = "full_attention"
 MAMBA = "mamba"
@@ -52,9 +57,17 @@ GMU = "gmu"
 CROSS = "cross_attention"
 CONV = "conv"
 DELTA = "linear_attention"
-ROW_KINDS = (WINDOWED, GLOBAL)
-# the kinds whose layers hold a recurrent state (a state slot a thread)
-STATE_KINDS = (MAMBA, CONV, DELTA)
+PARALLEL = "parallel_ssd_attention"
+
+
+def holds_rows(kind: str) -> bool:
+    """A layer of `kind` holds rows of its own in the paged pool."""
+    return kind in (WINDOWED, GLOBAL, PARALLEL)
+
+
+def holds_state(kind: str) -> bool:
+    """A layer of `kind` holds a recurrent state (a state slot a thread)."""
+    return kind in (MAMBA, CONV, DELTA, PARALLEL)
 
 
 class UnsupportedConfigError(ValueError):
@@ -92,6 +105,13 @@ class LatentGeometry:
     qk_nope_head_dim: int
     qk_rope_head_dim: int
     v_head_dim: int
+
+
+def _tail_layout(rows: int, width: int) -> Tuple[int, int]:
+    """A convolution's tail of `rows` x `width` values as a state slot holds
+    it: over 8 rows where they divide so (a leaf whose second-minor axis is 3
+    is tiled to 8 on the device: 2.7x the bytes)."""
+    return (8, rows * width // 8) if rows * width % 8 == 0 else (rows, width)
 
 
 def _lane_tiles(width: int) -> int:
@@ -261,6 +281,36 @@ class ModelConfig:
     delta_head_dim: int = 0
     delta_conv_kernel: int = 4
     delta_neg_eigval: bool = False
+    # -- the parallel layout (`falcon_h1`; `_ssd_block` in models/llama.py):
+    # `ssd_heads` > 0 turns it on and every layer is then PARALLEL:
+    # grouped-query attention and a Mamba-2 (SSD) mixer read ONE normed input
+    # and both add into the residual.  The mixer has ssd_heads heads of
+    # ssd_head_dim channels, a scalar decay a head, B and C of ssd_d_state
+    # values shared by the heads of each of ssd_groups groups, a depthwise
+    # causal convolution of ssd_conv_kernel taps with a bias over [x | B | C]
+    # and a gated RMSNorm over each group's channels.  A layer holds rows in
+    # the paged pool AND, in a state slot, the convolution's ssd_conv_kernel -
+    # 1 rows and the heads' states [ssd_heads * ssd_head_dim, ssd_d_state]
+    # float32 (ops/pallas/ssd.py). --
+    ssd_heads: int = 0
+    ssd_head_dim: int = 0
+    ssd_d_state: int = 0
+    ssd_groups: int = 1
+    ssd_conv_kernel: int = 4
+    # -- the family's muP scalars, applied to ACTIVATIONS where the equations
+    # put them and folded into no weight; 1.0 (and () for the two vectors) =
+    # absent, so a model without them traces as it always did.
+    # `ssm_multipliers` is (z, x, B, C, dt) over the column ranges of the
+    # mixer's input projection, `mlp_multipliers` (gate, down). --
+    embedding_multiplier: float = 1.0
+    lm_head_multiplier: float = 1.0
+    attention_in_multiplier: float = 1.0
+    attention_out_multiplier: float = 1.0
+    key_multiplier: float = 1.0
+    ssm_in_multiplier: float = 1.0
+    ssm_out_multiplier: float = 1.0
+    ssm_multipliers: Tuple[float, ...] = ()
+    mlp_multipliers: Tuple[float, ...] = ()
 
     def __post_init__(self):
         if self.moe_scoring not in ("softmax", "sigmoid"):
@@ -293,10 +343,10 @@ class ModelConfig:
             raise UnsupportedConfigError(
                 "qk_norm and unrotated_kinds are built with grouped-query "
                 "attention only (no latent attention, no Mamba decoder)")
-        if set(self.unrotated_kinds) - set(ROW_KINDS):
+        if set(self.unrotated_kinds) - {WINDOWED, GLOBAL}:
             raise UnsupportedConfigError(
                 f"unrotated_kinds {list(self.unrotated_kinds)}: known "
-                f"{list(ROW_KINDS)}")
+                f"{[WINDOWED, GLOBAL]}")
         if self.num_experts_routed and not (
                 self.moe_scoring == "sigmoid" and 0 <= self.expert_offset
                 and self.expert_offset + self.num_experts
@@ -319,7 +369,8 @@ class ModelConfig:
         known = {WINDOWED, GLOBAL} | (
             {MAMBA, GMU, CROSS} if self.mamba_d_state else set()) | (
             {CONV} if self.conv_L_cache else set()) | (
-            {DELTA} if self.delta_heads else set())
+            {DELTA} if self.delta_heads else set()) | (
+            {PARALLEL} if self.ssd_heads else set())
         bad = set(self.layer_types) - known
         if bad:
             raise UnsupportedConfigError(
@@ -331,6 +382,8 @@ class ModelConfig:
             self._check_conv_layout()
         if self.delta_heads:
             self._check_delta_layout()
+        if self.ssd_heads:
+            self._check_parallel_layout()
         if len(self.layer_types) != self.num_layers:
             raise UnsupportedConfigError(
                 f"layer_types has {len(self.layer_types)} entries for "
@@ -424,6 +477,43 @@ class ModelConfig:
                 "attention: no latent attention, Mamba or conv layers, or "
                 "vision tower")
 
+    def _check_parallel_layout(self) -> None:
+        """The parallel layout (`falcon_h1`): every layer PARALLEL, judged by
+        what the program needs of it: a state to carry (heads, a head size, a
+        state size), groups that divide the heads, a tail to carry (two taps
+        or more), the homogeneous dense tree on grouped-query attention, and
+        no second kind of state."""
+        if set(self.layer_types) != {PARALLEL}:
+            raise UnsupportedConfigError(
+                "an SSD mixer beside attention is served with every layer "
+                f"of kind {PARALLEL}; layer_types is "
+                f"{list(self.layer_types)}")
+        if (self.ssd_head_dim <= 0 or self.ssd_d_state <= 0
+                or self.ssd_groups <= 0 or self.ssd_heads % self.ssd_groups
+                or self.ssd_conv_kernel < 2):
+            raise UnsupportedConfigError(
+                f"the SSD mixer needs a head size (ssd_head_dim = "
+                f"{self.ssd_head_dim}), a state size (ssd_d_state = "
+                f"{self.ssd_d_state}), groups that divide its heads "
+                f"({self.ssd_heads} heads, {self.ssd_groups} groups) and a "
+                f"convolution of two taps or more (ssd_conv_kernel = "
+                f"{self.ssd_conv_kernel})")
+        if self.ssm_multipliers and len(self.ssm_multipliers) != 5:
+            raise UnsupportedConfigError(
+                f"ssm_multipliers has {len(self.ssm_multipliers)} entries: "
+                "five are served (z, x, B, C, dt)")
+        if self.mlp_multipliers and len(self.mlp_multipliers) != 2:
+            raise UnsupportedConfigError(
+                f"mlp_multipliers has {len(self.mlp_multipliers)} entries: "
+                "two are served (gate, down)")
+        if (self.is_latent or self.is_moe or self.mamba_d_state
+                or self.conv_L_cache or self.delta_heads
+                or self.vision is not None or self.lead_tree):
+            raise UnsupportedConfigError(
+                "the parallel layout is a dense grouped-query decoder: no "
+                "latent attention, experts, Mamba-1, conv or linear-attention "
+                "layers, or vision tower")
+
     @property
     def is_moe(self) -> bool:
         return self.num_experts > 0
@@ -432,7 +522,7 @@ class ModelConfig:
     def has_state(self) -> bool:
         """Some KIND of layer this model has carries a recurrent per-thread
         state (a state slot)."""
-        return any(kind in STATE_KINDS for kind in self.layer_types)
+        return any(holds_state(kind) for kind in self.layer_types)
 
     @property
     def hybrid_decoder(self) -> bool:
@@ -445,9 +535,15 @@ class ModelConfig:
         return self.mamba_expand * self.hidden_size
 
     @property
+    def ssd_conv_dim(self) -> int:
+        """Channels of the SSD mixer's convolution: [x | B | C]."""
+        return (self.ssd_heads * self.ssd_head_dim
+                + 2 * self.ssd_groups * self.ssd_d_state)
+
+    @property
     def state_layers(self) -> int:
         """Layers that hold a recurrent state."""
-        return sum(self.layers_of(kind) for kind in STATE_KINDS)
+        return sum(holds_state(kind) for kind in self.layer_types)
 
     @property
     def kv_layers(self) -> int:
@@ -455,7 +551,7 @@ class ModelConfig:
         hybrid decoder, whose attention layers with K/V of their own do."""
         if not self.has_state:
             return self.num_layers
-        return sum(self.layers_of(k) for k in ROW_KINDS)
+        return sum(holds_rows(kind) for kind in self.layer_types)
 
     def state_shapes(self) -> Tuple[Tuple[str, Tuple[int, ...]], ...]:
         """(leaf, shape of ONE slot of ONE layer) of the recurrent state,
@@ -469,7 +565,15 @@ class ModelConfig:
         whose second-minor axis is 3 is tiled to 8 on the device: 2.7x the
         bytes, and the layer scan copies such a leaf whole every pass), and
         `delta`, S transposed a head, the heads stacked along the rows
-        (ops/pallas/gated_delta.py)."""
+        (ops/pallas/gated_delta.py).  A parallel layer: the tail of its one
+        convolution over [x | B | C], laid out by the same rule, and `ssd`,
+        the heads' states [head size, state size] stacked along the rows
+        (ops/pallas/ssd.py)."""
+        if PARALLEL in self.layer_types:
+            return (("conv", _tail_layout(self.ssd_conv_kernel - 1,
+                                          self.ssd_conv_dim)),
+                    ("ssd", (self.ssd_heads * self.ssd_head_dim,
+                             self.ssd_d_state)))
         if MAMBA in self.layer_types:
             di = self.mamba_d_inner
             return (("conv", (self.mamba_d_conv - 1, di)),
@@ -479,10 +583,9 @@ class ModelConfig:
             return (("conv", (self.conv_L_cache - 1, self.hidden_size)),)
         if DELTA in self.layer_types:
             wide = self.delta_heads * self.delta_head_dim
-            tail = (self.delta_conv_kernel - 1, 3 * wide)
-            if tail[0] * tail[1] % 8 == 0:
-                tail = (8, tail[0] * tail[1] // 8)
-            return (("conv", tail), ("delta", (wide, self.delta_head_dim)))
+            return (("conv", _tail_layout(self.delta_conv_kernel - 1,
+                                          3 * wide)),
+                    ("delta", (wide, self.delta_head_dim)))
         return ()
 
     @property
@@ -614,7 +717,7 @@ class ModelConfig:
             index = ((_lane_tiles(self.index_head_dim),)
                      if self.has_indexer(kind) else ())
             return (g.kv_lora_rank, _lane_tiles(g.qk_rope_head_dim)) + index
-        if kind not in ROW_KINDS:
+        if not holds_rows(kind):
             return ()  # mamba / gmu / cross / conv / delta layers: no rows
         return (self.num_kv_heads * self.head_dim,) * 2
 
@@ -1120,6 +1223,54 @@ def _delta_keys(hf: dict) -> dict:
     return out
 
 
+def _parallel_keys(hf: dict) -> dict:
+    """The keys of a `falcon_h1` config.json (Falcon-H1: every layer a
+    Mamba-2 mixer in parallel with grouped-query attention, a SwiGLU MLP, the
+    family's muP multipliers) as ModelConfig fields; {} for any other model.
+    What the config has no key for (the order of the input projection's
+    columns, the ranges of the muP vector, the grouped gated norm) is listed
+    as `assumed` beside the benchmark's copy of the file.  What is not served
+    is an UnsupportedConfigError, by key."""
+    if hf.get("model_type") != "falcon_h1":
+        return {}
+    served = (
+        ("attn_layer_indices", None, "attention in some layers only"),
+        ("rope_scaling", None, "scaled rotary positions"),
+        ("mamba_use_mlp", True, "a block without its MLP"),
+        ("mamba_rms_norm", True, "a mixer without its gated norm"),
+        ("mamba_norm_before_gate", False, "the norm ahead of the gate"),
+        ("hidden_act", "silu", "another MLP activation"),
+        ("attention_bias", False, "attention biases"),
+        ("mamba_proj_bias", False, "biases on the mixer's projections"),
+        ("mlp_bias", False, "MLP biases"),
+        ("projectors_bias", False, "biases on the projections"),
+        ("mamba_conv_bias", True, "a convolution without its bias"),
+    )
+    _refuse_unless(hf, served)
+    heads, size = int(hf["mamba_n_heads"]), int(hf["mamba_d_head"])
+    if hf.get("mamba_d_ssm", heads * size) != heads * size:
+        raise UnsupportedConfigError(
+            f"mamba_d_ssm = {hf['mamba_d_ssm']!r} is not mamba_n_heads x "
+            f"mamba_d_head = {heads} x {size}")
+    out = {
+        "layer_types": (PARALLEL,) * int(hf["num_hidden_layers"]),
+        "ssd_heads": heads,
+        "ssd_head_dim": size,
+        "ssd_d_state": int(hf["mamba_d_state"]),
+        "ssd_groups": int(hf.get("mamba_n_groups", 1)),
+        "ssd_conv_kernel": int(hf.get("mamba_d_conv", 4)),
+        "ssm_multipliers": tuple(
+            float(m) for m in hf.get("ssm_multipliers") or ()),
+        "mlp_multipliers": tuple(
+            float(m) for m in hf.get("mlp_multipliers") or ()),
+    }
+    for key in ("embedding_multiplier", "lm_head_multiplier",
+                "attention_in_multiplier", "attention_out_multiplier",
+                "key_multiplier", "ssm_in_multiplier", "ssm_out_multiplier"):
+        out[key] = float(hf.get(key, 1.0))
+    return out
+
+
 def config_from_hf_json(path: str) -> ModelConfig:
     """Build a ModelConfig from a HuggingFace config.json: Llama / Mixtral
     keys, the published keys of a patterned routed decoder (Mellum2:
@@ -1128,9 +1279,9 @@ def config_from_hf_json(path: str) -> ModelConfig:
     of a `deepseek_v3` decoder (`_latent_keys`), the same feed-forward keys
     on grouped-query attention (`_routed_lead_keys`: `exaone_moe`), those of
     a `phi4flash` hybrid decoder (`_hybrid_keys`), those of an `lfm2_moe`
-    one (`_conv_keys`) and those of a `solar_open2` one (`_delta_keys`).  A
-    key the program cannot honour is an
-    UnsupportedConfigError."""
+    one (`_conv_keys`), those of a `solar_open2` one (`_delta_keys`) and those
+    of a `falcon_h1` one (`_parallel_keys`).  A key the program cannot honour
+    is an UnsupportedConfigError."""
     with open(path) as f:
         hf = json.load(f)
     latent = _latent_keys(hf)
@@ -1164,7 +1315,8 @@ def config_from_hf_json(path: str) -> ModelConfig:
             "norm_topk_prob false (top-k weights of a softmax over ALL "
             "experts, not renormalised) is not served: routing here is a "
             "softmax over exactly the top-k logits")
-    hybrid = _hybrid_keys(hf) or _conv_keys(hf) or delta
+    hybrid = (_hybrid_keys(hf) or _conv_keys(hf) or delta
+              or _parallel_keys(hf))
     pattern = {} if "layer_types" in hybrid else _layer_pattern(hf)
     rope_theta = hf.get("rope_theta")
     if rope_theta is None:
@@ -1197,7 +1349,10 @@ def config_from_hf_json(path: str) -> ModelConfig:
         # (latent attention: the rotary width, what a published `head_dim` is)
         head_dim=(latent["qk_rope_head_dim"] if latent else hf.get(
             "head_dim", hf["hidden_size"] // hf["num_attention_heads"])),
-        rope_theta=rope_theta,
+        # (a theta past int32, Falcon-H1's 1e11 spelt as an integer, would
+        # be parsed as one where the frequencies are computed)
+        rope_theta=(float(rope_theta) if abs(rope_theta) >= 2**31
+                    else rope_theta),
         rms_norm_eps=hybrid.pop("rms_norm_eps", None) or hf.get(
             "rms_norm_eps", 1e-5),
         max_context=hf.get("max_position_embeddings", 8192),

@@ -10,7 +10,10 @@ below), or with gated delta-rule linear attention for mixers, each carrying a
 matrix a head and its convolutions' tails in a state slot, beside gated
 full-attention layers that do not rotate (`solar_open2`: Solar-Open2-250B;
 `_delta_attention_block`, the conv layout's mechanism with a second state
-leaf).
+leaf).  `falcon_h1` (Falcon-H1-34B) is the homogeneous dense stack with TWO
+mixers a layer: grouped-query attention and a Mamba-2 (SSD) mixer read one
+normed input and both add into the residual (`_ssd_block`; every layer holds
+rows in the paged pool AND a state slot), under the family's muP multipliers.
 
 Design (TPU-first, not a port — the reference has no model code at all; its
 LLM compute lived behind a remote gateway, src/llm/portkey.py):
@@ -92,11 +95,13 @@ from typing import Any, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ..models.config import (
     CONV,
     DELTA,
     GLOBAL,
+    PARALLEL,
     ModelConfig,
     UnsupportedConfigError,
 )
@@ -108,6 +113,7 @@ from ..ops.attention import (
 )
 from ..ops.norms import rms_norm
 from ..ops.pallas.gated_delta import gated_delta
+from ..ops.pallas.ssd import ssd
 from ..ops.rope import (
     apply_rope,
     kind_frequencies,
@@ -317,6 +323,8 @@ def init_params(cfg: ModelConfig, key: jax.Array, dtype=None) -> Params:
     h, f, d = cfg.hidden_size, cfg.intermediate_size, cfg.head_dim
     hq, hkv, L = cfg.num_heads, cfg.num_kv_heads, cfg.num_layers
     keys = jax.random.split(key, 10)
+    if cfg.ssd_heads:
+        return _init_parallel_params(cfg, keys, dtype)
 
     def norm01(k, shape, fan_in):
         return (jax.random.normal(k, shape, jnp.float32) * (fan_in**-0.5)).astype(dtype)
@@ -348,6 +356,102 @@ def init_params(cfg: ModelConfig, key: jax.Array, dtype=None) -> Params:
     }
     if not cfg.tie_word_embeddings:
         params["lm_head"] = norm01(keys[8], (h, cfg.vocab_size), h)
+    return params
+
+
+def ssd_mup_vector(cfg: ModelConfig):
+    """`ssm_multipliers` spread over the columns of the SSD mixer's input
+    projection, [z | x | B | C | dt], as a float32 vector (None: the config
+    has none)."""
+    if not cfg.ssm_multipliers:
+        return None
+    d_ssm = cfg.ssd_heads * cfg.ssd_head_dim
+    gw = cfg.ssd_groups * cfg.ssd_d_state
+    return np.repeat(np.asarray(cfg.ssm_multipliers, np.float32),
+                     (d_ssm, d_ssm, gw, gw, cfg.ssd_heads))
+
+
+def _init_parallel_params(cfg: ModelConfig, keys, dtype,
+                          scaled: bool = True) -> Params:
+    """Random weights of the parallel layout (`falcon_h1`): `init_params`'
+    homogeneous stack with the SSD mixer's leaves beside the attention's in
+    "layers" (`_ssd_block` names them): w_in [H, 2 d_ssm + 2 groups N +
+    heads] (columns z | x | B | C | dt), the taps [L, conv] and their bias,
+    A_log a head drawn log U(1, 16), dt_bias the inverse softplus of a step
+    drawn log-uniform in [0.001, 0.1] (Mamba-2's own initialiser: a head's
+    decay a row spreads over 0.9999 .. 0.2), D and the gated norm's weight
+    spread around 1, w_out [d_ssm, H].
+
+    THE MULTIPLIERS.  Every leaf that a muP multiplier scales is drawn at its
+    fan-in standard deviation DIVIDED by that multiplier (`scaled`; the
+    input projection's columns by their range's entry of `ssm_multipliers`
+    too; the embedding at 1 / its multiplier, so that a row times it is of
+    unit variance), so that scores, both mixers' outputs, the MLP's and the
+    logits are of order 1 as every other preset's are.  At 1 / sqrt(fan_in) the
+    published `key_multiplier` 0.011 would flatten every softmax to a mean
+    over the keys and both mixers would enter the residual at 0.04 and 0.09:
+    a check on the logits would be blind to a wrong mask, rotation or scan
+    (a trained model's weights have grown against their multipliers)."""
+    h, f, d = cfg.hidden_size, cfg.intermediate_size, cfg.head_dim
+    hq, hkv, L = cfg.num_heads, cfg.num_kv_heads, cfg.num_layers
+    H, P = cfg.ssd_heads, cfg.ssd_head_dim
+    d_ssm, conv, taps = H * P, cfg.ssd_conv_dim, cfg.ssd_conv_kernel
+    proj = d_ssm + conv + H
+    gate_m, down_m = cfg.mlp_multipliers or (1.0, 1.0)
+
+    @partial(jax.jit, static_argnums=(1, 2, 3))
+    def norm01(k, shape, fan_in, mult=1.0):
+        # one program a leaf: no float32 copy of a 0.8G-element leaf is held
+        return (jax.random.normal(k, shape, jnp.float32)
+                * (fan_in**-0.5 / (mult if scaled else 1.0))).astype(dtype)
+
+    def spread(k, shape, out_dtype=dtype):
+        return (1.0 + 0.2 * jax.random.normal(k, shape, jnp.float32)
+                ).astype(out_dtype)
+
+    ks = jax.random.split(keys[9], 9)
+    mup = ssd_mup_vector(cfg)
+    w_in = norm01(ks[0], (L, h, proj), h, cfg.ssm_in_multiplier)
+    if mup is not None and scaled:
+        # (one program: no float32 copy of the leaf is held)
+        w_in = jax.jit(lambda w: (w / mup).astype(dtype),
+                       donate_argnums=0)(w_in)
+    step = jnp.exp(jax.random.uniform(
+        ks[5], (L, H), jnp.float32, jnp.log(0.001), jnp.log(0.1)))
+    a_in = cfg.attention_in_multiplier
+    layers: Params = {
+        "ln_attn": jnp.ones((L, h), dtype),
+        "ln_mlp": jnp.ones((L, h), dtype),
+        "wq": norm01(keys[1], (L, h, hq, d), h, a_in),
+        "wk": norm01(keys[2], (L, h, hkv, d), h, a_in * cfg.key_multiplier),
+        "wv": norm01(keys[3], (L, h, hkv, d), h, a_in),
+        "wo": norm01(keys[4], (L, hq, d, h), hq * d,
+                     cfg.attention_out_multiplier),
+        "wg": norm01(keys[5], (L, h, f), h, gate_m),
+        "wu": norm01(keys[6], (L, h, f), h),
+        "wd": norm01(keys[7], (L, f, h), f, down_m),
+        "w_in": w_in,
+        "conv_w": norm01(ks[1], (L, taps, conv), taps),
+        "conv_b": (0.1 * jax.random.normal(ks[2], (L, conv), jnp.float32)
+                   ).astype(dtype),
+        "A_log": jnp.log(jax.random.uniform(
+            ks[3], (L, H), jnp.float32, 1.0, 16.0)),
+        "D": spread(ks[4], (L, H), jnp.float32),
+        "dt_bias": jnp.log(jnp.expm1(step)),
+        "ln_ssd": spread(ks[6], (L, d_ssm)),
+        "w_out": norm01(ks[7], (L, d_ssm, h), d_ssm, cfg.ssm_out_multiplier),
+    }
+    params: Params = {
+        # (a row times its multiplier of unit variance, as the blocks'
+        # outputs are: the embedding then weighs in the residual stream)
+        "embed": norm01(keys[0], (cfg.vocab_size, h), 1 if scaled else h,
+                        cfg.embedding_multiplier),
+        "final_norm": jnp.ones((h,), dtype),
+        "layers": layers,
+    }
+    if not cfg.tie_word_embeddings:
+        params["lm_head"] = norm01(keys[8], (h, cfg.vocab_size), h,
+                                   cfg.lm_head_multiplier)
     return params
 
 
@@ -657,6 +761,8 @@ def _attention_block(
         q = jnp.einsum("bsh,hnd->bsnd", x, _w(lp, "wq", dt))
         k = jnp.einsum("bsh,hnd->bsnd", x, _w(lp, "wk", dt))
         v = jnp.einsum("bsh,hnd->bsnd", x, _w(lp, "wv", dt))
+        if cfg.key_multiplier != 1.0:
+            k = k * jnp.asarray(cfg.key_multiplier, dt)
     if "ln_q" in lp:
         # QK-norm: each head's q and k RMS-normed over head_dim with the
         # layer's learned weights, ahead of the rotation
@@ -701,6 +807,35 @@ def _attention_block(
     return out, k_cache, v_cache
 
 
+def _tail_conv_silu(rows: jnp.ndarray, w: jnp.ndarray, bias, leaf, layer,
+                    plan: StatePlan):
+    """SiLU of a depthwise causal convolution whose tail is a layer's STATE
+    (the delta layout's three convolutions side by side, the parallel
+    layout's one over [x | B | C]).  rows [B, S, C]; w [taps, C] float32 (tap
+    taps - 1 multiplies the row's own value); bias [C] float32 or None.  The
+    taps - 1 rows before the pass come from `leaf`, the stacked state array
+    (laid out in the slot as `cfg.state_shapes` says; None: uncached, zeros)
+    at `layer`, and the last taps - 1 REAL rows (`plan.lens`; as
+    `_short_conv_block`'s tail) go back to it (models/hybrid._read_state /
+    _write_state).  Returns (float32 [B, S, C], leaf')."""
+    f32 = jnp.float32
+    b, s, c = rows.shape
+    taps = w.shape[0]
+    tail = (jnp.zeros((b, taps - 1, c), f32) if leaf is None
+            else _read_state(leaf, layer, plan, b).reshape(b, taps - 1, c))
+    seq = jnp.concatenate([tail, rows.astype(f32)], axis=1)
+    out = sum(w[j] * seq[:, j:j + s] for j in range(taps))
+    out = jax.nn.silu(out if bias is None else out + bias)
+    if leaf is not None:
+        new = jax.vmap(
+            lambda rows, n: jax.lax.dynamic_slice_in_dim(
+                rows, n, taps - 1, axis=0))(seq, plan.lens)
+        slot = (b,) + leaf.shape[2:]
+        leaf = _write_state(leaf, layer, plan, new.reshape(slot),
+                            tail.reshape(slot))
+    return out, leaf
+
+
 def _delta_attention_block(x: jnp.ndarray, lp: Params, cfg: ModelConfig,
                            leaves, layer, plan: StatePlan):
     """One gated delta-rule linear-attention layer (`solar_open2`'s mixer;
@@ -740,22 +875,8 @@ def _delta_attention_block(x: jnp.ndarray, lp: Params, cfg: ModelConfig,
     conv_leaf, delta_leaf = (None, None) if leaves is None else (
         leaves["conv"], leaves["delta"])
     with jax.named_scope("kda_conv"):
-        w = lp["conv_w"].astype(f32)  # [taps, 3W]
-        taps = w.shape[0]
-        # (the slot lays the tail's rows out as `cfg.state_shapes` says)
-        tail = (jnp.zeros((b, taps - 1, 3 * H * D), f32) if conv_leaf is None
-                else _read_state(conv_leaf, layer, plan, b).reshape(
-                    b, taps - 1, 3 * H * D))
-        seq = jnp.concatenate([tail, qkv.astype(f32)], axis=1)
-        qkv = jax.nn.silu(sum(w[j] * seq[:, j:j + s] for j in range(taps)))
-        if conv_leaf is not None:
-            # the last taps - 1 REAL rows (as _short_conv_block's tail)
-            new = jax.vmap(
-                lambda rows, n: jax.lax.dynamic_slice_in_dim(
-                    rows, n, taps - 1, axis=0))(seq, plan.lens)
-            slot = (b,) + conv_leaf.shape[2:]
-            conv_leaf = _write_state(conv_leaf, layer, plan,
-                                     new.reshape(slot), tail.reshape(slot))
+        qkv, conv_leaf = _tail_conv_silu(
+            qkv, lp["conv_w"].astype(f32), None, conv_leaf, layer, plan)
     with jax.named_scope("kda_gate"):
         q, k, v = (a.reshape(b, s, H, D) for a in jnp.split(qkv, 3, axis=-1))
 
@@ -782,6 +903,80 @@ def _delta_attention_block(x: jnp.ndarray, lp: Params, cfg: ModelConfig,
                          _w(lp, "w_out", dt))
     if leaves is not None:
         leaves = {**leaves, "conv": conv_leaf, "delta": delta_leaf}
+    return out, leaves
+
+
+def _ssd_block(x: jnp.ndarray, lp: Params, cfg: ModelConfig, leaves, layer,
+               plan: StatePlan):
+    """One Mamba-2 (SSD) mixer (`falcon_h1`'s, beside attention on the same
+    normed input).  x: [B, S, H].  With d = heads x head size P, N the state
+    size and G groups:
+
+        p = ((x ssm_in_multiplier) W_in) * m, m the muP vector over the
+        column ranges; [z | xBC | dt] = p (d | d + 2 G N | heads)
+        xBC <- SiLU(conv(xBC) + b), a depthwise causal convolution of
+        `ssd_conv_kernel` taps a channel; [x | B | C] = xBC
+        dt = softplus(dt + dt_bias), g = -exp(A_log) dt   a SCALAR a head
+        S_t = exp(g_t) S_(t-1) + dt_t x_t B_t^T;  y_t = S_t C_t + D x_t
+        (head h reads group h // (heads / G)'s B and C)
+        y <- RMSNorm_grouped(y * SiLU(z)), each group's d / G channels
+        normalised apart under one learned weight of d
+        out = (y W_out) ssm_out_multiplier
+
+    The multipliers are applied in the activations' dtype where the equations
+    put them; none is folded into a weight.  The layer's STATE is two leaves
+    of `leaves` (the v pool's dict, whose "v" is the SAME layer's attention
+    rows; None: uncached, from zeros), `layer` this layer's place in both:
+    "conv", the last taps - 1 rows of xBC ahead of the convolution in float32
+    (laid out in the slot as `cfg.state_shapes` says; models/hybrid._read_state
+    / _write_state), and "ssd", S a head, float32, which ops/pallas/ssd
+    updates IN PLACE on the Pallas backend (the chunk kernel at S > 1, the
+    step kernel in decode) and through the same slot read and write under a
+    row-by-row scan elsewhere.  Everything between the projections is
+    float32.  Returns (out [B, S, H] ahead of the residual add, leaves')."""
+    dt_, f32 = x.dtype, jnp.float32
+    b, s, _ = x.shape
+    H, P, N, G = (cfg.ssd_heads, cfg.ssd_head_dim, cfg.ssd_d_state,
+                  cfg.ssd_groups)
+    d, cw = H * P, cfg.ssd_conv_dim
+    with jax.named_scope("ssd_proj"):
+        if cfg.ssm_in_multiplier != 1.0:
+            x = x * jnp.asarray(cfg.ssm_in_multiplier, dt_)
+        p = jnp.einsum("bsh,hw->bsw", x, _w(lp, "w_in", dt_))
+        mup = ssd_mup_vector(cfg)
+        if mup is not None:
+            p = p * jnp.asarray(mup, dt_)
+        z, xbc, step = p[..., :d], p[..., d:d + cw], p[..., d + cw:]
+    conv_leaf, ssd_leaf = (None, None) if leaves is None else (
+        leaves["conv"], leaves["ssd"])
+    with jax.named_scope("ssd_conv"):
+        xbc, conv_leaf = _tail_conv_silu(
+            xbc, lp["conv_w"].astype(f32), lp["conv_b"].astype(f32),
+            conv_leaf, layer, plan)
+    with jax.named_scope("ssd_gate"):
+        xs = xbc[..., :d].reshape(b, s, H, P)
+        Bm = xbc[..., d:d + G * N].reshape(b, s, G, N)
+        Cm = xbc[..., d + G * N:].reshape(b, s, G, N)
+        step = jax.nn.softplus(step.astype(f32) + lp["dt_bias"].astype(f32))
+        g = -jnp.exp(lp["A_log"].astype(f32)) * step
+    with jax.named_scope("ssd_scan"):
+        y, ssd_leaf = ssd(
+            ssd_leaf, layer, plan, xs * step[..., None], Bm, Cm, g,
+            kernel=cfg.attention_backend == "pallas",
+            read_state=_read_state, write_state=_write_state)
+    with jax.named_scope("ssd_gate"):
+        y = y + lp["D"].astype(f32)[:, None] * xs
+        y = (y.reshape(b, s, d) * jax.nn.silu(z.astype(f32))).reshape(
+            b, s, G, d // G)
+        y = y * jax.lax.rsqrt(
+            jnp.mean(y * y, axis=-1, keepdims=True) + cfg.rms_norm_eps)
+        y = y.reshape(b, s, d) * lp["ln_ssd"].astype(f32)
+    with jax.named_scope("ssd_proj"):
+        out = jnp.einsum("bsw,wh->bsh", y.astype(dt_), _w(lp, "w_out", dt_))
+        if cfg.ssm_out_multiplier != 1.0:
+            out = out * jnp.asarray(cfg.ssm_out_multiplier, dt_)
+    if leaves is not None:
+        leaves = {**leaves, "conv": conv_leaf, "ssd": ssd_leaf}
     return out, leaves
 
 
@@ -1745,12 +1940,20 @@ def _latent_prefill_walk(q_nope, q_rope, wkvb, k_cache, v_cache,
 
 
 def _mlp_block(x: jnp.ndarray, lp: Params,
-               names=("wg", "wu", "wd")) -> jnp.ndarray:
-    """SwiGLU MLP: down( silu(gate(x)) * up(x) )."""
+               names=("wg", "wu", "wd"),
+               multipliers: Tuple[float, ...] = ()) -> jnp.ndarray:
+    """SwiGLU MLP: down( silu(gate(x)) * up(x) ).  `multipliers` (gate,
+    down), a muP model's: the gate's pre-activation and the block's output
+    are scaled, in the activations' dtype."""
     g = jnp.einsum("bsh,hf->bsf", x, _w(lp, names[0], x.dtype))
     u = jnp.einsum("bsh,hf->bsf", x, _w(lp, names[1], x.dtype))
+    if not multipliers:
+        return jnp.einsum(
+            "bsf,fh->bsh", jax.nn.silu(g) * u, _w(lp, names[2], x.dtype))
+    gate_m, down_m = (jnp.asarray(m, x.dtype) for m in multipliers)
     return jnp.einsum(
-        "bsf,fh->bsh", jax.nn.silu(g) * u, _w(lp, names[2], x.dtype))
+        "bsf,fh->bsh", jax.nn.silu(g * gate_m) * u,
+        _w(lp, names[2], x.dtype)) * down_m
 
 
 def _routing_weights(t: jnp.ndarray, router: jnp.ndarray,
@@ -2104,6 +2307,8 @@ def forward(
                 override_on[..., None],
                 embed_override.astype(cfg.activation_dtype), x,
             )
+        if cfg.embedding_multiplier != 1.0:
+            x = x * jnp.asarray(cfg.embedding_multiplier, x.dtype)
         # one rotary table per kind of layer, built once per forward pass;
         # each layer takes its kind's (a config without a pattern has one)
         lead, period = cfg.pattern
@@ -2180,12 +2385,27 @@ def forward(
             # (beside conv layers `layer` counts the layers that hold rows,
             # and their v pool rides beside the state in the v pool's dict)
             in_dict = plan is not None and vc is not None
+            a_in = attn_in
+            if cfg.attention_in_multiplier != 1.0:
+                with jax.named_scope("attn_qkv"):
+                    a_in = attn_in * jnp.asarray(
+                        cfg.attention_in_multiplier, attn_in.dtype)
             attn_out, kc, v_rows = _attention_block(
-                attn_in, lp, cfg, cos, sin, positions, kc,
+                a_in, lp, cfg, cos, sin, positions, kc,
                 vc["v"] if in_dict else vc, kv_valid,
                 cache_positions, paged, mesh, layer, cfg.window_of(kind),
             )
             vc = {**vc, "v": v_rows} if in_dict else v_rows
+            if cfg.attention_out_multiplier != 1.0:
+                with jax.named_scope("attn_out"):
+                    attn_out = attn_out * jnp.asarray(
+                        cfg.attention_out_multiplier, attn_out.dtype)
+        if kind == PARALLEL:
+            # the layer's SECOND mixer, on the same normed input: ONE `layer`
+            # indexes the page pool above and both state leaves here
+            ssd_out, vc = _ssd_block(attn_in, lp, cfg, vc, layer, plan)
+            with jax.named_scope("ssd_proj"):
+                attn_out = ssd_out + attn_out
         with jax.named_scope({CONV: "conv_proj", DELTA: "kda_proj"}.get(
                 kind, "attn_out")):
             h = h + attn_out
@@ -2201,7 +2421,8 @@ def forward(
                 h = h + ffn_out
         else:
             with jax.named_scope("mlp"):
-                h = h + _mlp_block(mlp_in, lp)
+                h = h + _mlp_block(mlp_in, lp,
+                                   multipliers=cfg.mlp_multipliers)
         return (h, kc, vc, tally), None
 
     def at(stacked, i, static: bool):
@@ -2359,4 +2580,6 @@ def _logits_head(x: jnp.ndarray, params: Params,
             logits = jnp.einsum(
                 "bsh,hv->bsv", x, head, preferred_element_type=jnp.float32
             )
+    if cfg.lm_head_multiplier != 1.0:
+        logits = logits * cfg.lm_head_multiplier
     return logits

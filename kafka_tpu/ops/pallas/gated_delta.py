@@ -56,6 +56,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .state_slot import chunk_slots, load_state, store_state
+
 CHUNK = 64        # rows of one triangular system
 SUB = 16          # rows of a sub-block: one reference point for the decay
 HEADS_A_STEP = 4  # heads a grid step holds (independent chains to overlap)
@@ -135,16 +137,7 @@ def _chunk_kernel(layer_ref, src_ref, dst_ref, snap_ref, flag_ref,
 
     @pl.when(active & (c == 0))
     def _():
-        @pl.when((flag & 2) == 2)
-        def _():
-            s_scr[...] = jnp.zeros_like(s_scr)
-
-        @pl.when((flag & 2) == 0)
-        def _():
-            cp = pltpu.make_async_copy(
-                leaf_in.at[layer, src_ref[b], rows], s_scr, sem.at[0])
-            cp.start()
-            cp.wait()
+        load_state(leaf_in, layer, src_ref, b, rows, s_scr, sem, flag)
 
     @pl.when(active)
     def _():
@@ -163,13 +156,8 @@ def _chunk_kernel(layer_ref, src_ref, dst_ref, snap_ref, flag_ref,
 
     @pl.when(active & (c == pl.num_programs(2) - 1))
     def _():
-        out = [pltpu.make_async_copy(
-            s_scr, leaf_out.at[layer, ref[b], rows], sem.at[i])
-            for i, ref in enumerate((dst_ref, snap_ref))]
-        for cp in out:
-            cp.start()
-        for cp in out:
-            cp.wait()
+        store_state(leaf_out, layer, (dst_ref, snap_ref), b, rows, s_scr,
+                    sem)
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
@@ -337,13 +325,7 @@ def gated_delta(leaf, layer, plan, q, k, v, g, beta, *, kernel: bool,
             leaf, layer, jnp.arange(B, dtype=jnp.int32), q[:, 0], k[:, 0], kb[:, 0], vb[:, 0],
             g[:, 0], interpret=not on_chip)
         return o.reshape(B, 1, H, dv), leaf
-    lanes = jnp.arange(B, dtype=jnp.int32)
-    src = lanes if plan.src is None else plan.src
-    dst = lanes if plan.dst is None else plan.dst
-    snap = dst if plan.snap is None else plan.snap
-    flag = (plan.lens > 0).astype(jnp.int32)
-    if plan.fresh is not None:
-        flag = flag + 2 * plan.fresh.astype(jnp.int32)
+    src, dst, snap, flag = chunk_slots(plan, B)
     o, leaf = gated_delta_chunk(
         leaf, layer, src, dst, snap, flag, q, k, kb, vb, g, chunk=tiles,
         interpret=not on_chip)

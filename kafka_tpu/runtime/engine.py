@@ -1470,6 +1470,10 @@ class InferenceEngine:
         # lanes x layers x 2 x a layer's slot: StepPrograms.delta_state_bytes)
         self.delta_chunk_trips = 0
         self.delta_state_bytes = 0
+        # The same two of a model with an SSD mixer (both 0 without one):
+        # StepPrograms.ssd_chunk_trips / ssd_state_bytes
+        self.ssd_chunk_trips = 0
+        self.ssd_state_bytes = 0
         # Monotonic, and all 0 for a model with no routed block
         # (StepPrograms.moe_dispatch): step programs dispatched by the form
         # their routed blocks take, and the rows those blocks were handed
@@ -4453,6 +4457,8 @@ class InferenceEngine:
         self.prefill_walk_kernel_trips += folded
         self.delta_chunk_trips += self._programs.delta_chunk_trips(
             len(spans), bucket)
+        self.ssd_chunk_trips += self._programs.ssd_chunk_trips(
+            len(spans), bucket)
         self._count_moe_dispatch(width * bucket)
 
     def _count_moe_dispatch(self, rows: int, passes: int = 1) -> None:
@@ -4489,6 +4495,9 @@ class InferenceEngine:
         self.decode_steps_run += run
         if self.cfg.delta_heads:
             self.delta_state_bytes += self._programs.delta_state_bytes(
+                len(seqs), steps)
+        if self.cfg.ssd_heads:
+            self.ssd_state_bytes += self._programs.ssd_state_bytes(
                 len(seqs), steps)
         self._count_moe_dispatch(len(members), steps)
         if self.cfg.index_topk:

@@ -444,6 +444,8 @@ METRICS: Tuple[Metric, ...] = (
         _m("prefill_walk_kernel_trips", read="prefill_walk_kernel_trips"),
         _m("delta_chunk_trips", read="delta_chunk_trips"),
         _m("delta_state_bytes", read="delta_state_bytes"),
+        _m("ssd_chunk_trips", read="ssd_chunk_trips"),
+        _m("ssd_state_bytes", read="ssd_state_bytes"),
         _m("moe_dispatch", read="moe_dispatch"),
         _m("moe_experts_read", "kafka_tpu_engine_moe_experts_total{kind=read}",
            "Held experts over the routed layers of the decode passes: those "

@@ -39,7 +39,14 @@ import dataclasses
 import os
 from typing import Dict, Optional
 
-from ..models.config import CONV, CROSS, DELTA, GMU, ModelConfig
+from ..models.config import (
+    CONV,
+    CROSS,
+    DELTA,
+    PARALLEL,
+    ModelConfig,
+    holds_rows,
+)
 from .kv_cache import default_state_slots
 
 GiB = 1024**3
@@ -294,6 +301,16 @@ def weight_bytes_per_device(
         )
     else:
         per_layer += 2 * mat(h, f, tp) + mat(f, h, tp)
+    if cfg.ssd_heads:
+        # the parallel layout's second mixer (one device: the engine refuses
+        # a mesh): W_in and W_out, the taps, their bias and the gated norm's
+        # weight; A_log, D and dt_bias are float32
+        d_ssm = cfg.ssd_heads * cfg.ssd_head_dim
+        conv = cfg.ssd_conv_dim
+        per_layer += (mat(h, d_ssm + conv + cfg.ssd_heads, 1)
+                      + mat(d_ssm, h, 1)
+                      + ((cfg.ssd_conv_kernel + 1) * conv + d_ssm) * wb
+                      + 3 * cfg.ssd_heads * 4)
 
     total = per_layer * L // pp
     # embed replicated (lookup local); untied lm_head tp-sharded over V
@@ -461,6 +478,13 @@ def activation_bytes_estimate(
         # around them, [S, inner] each, and B / C broadcast along 128 lanes
         prefill += s_local * (cfg.mamba_d_inner * 4 * 6
                               + cfg.mamba_d_state * 128 * 4 * 2)
+    elif PARALLEL in cfg.layer_types:
+        # the input projection and the convolution over [x | B | C] in
+        # float32, then the chunk kernel's operands (dt x, B, C, the
+        # log-decay's cumulative sum) and its output, and the gated norm
+        d_ssm = cfg.ssd_heads * cfg.ssd_head_dim
+        prefill += s_local * ((2 * d_ssm + cfg.ssd_conv_dim) * 2
+                              + cfg.ssd_conv_dim * 4 * 3 + d_ssm * 4 * 4)
     elif DELTA in cfg.layer_types:
         # [q | k | v] and the convolution over them, then the chunk kernel's
         # six float32 operands (q, k, beta k, beta v, the log-decay and its
@@ -681,10 +705,11 @@ def dispatch_cost_model(
             else 2 * cfg.head_dim)
     return DispatchCostModel(
         flops_per_token=2.0 * params_total / n,
-        # (a model with a state: its attention layers, own K/V and cross)
+        # (a model with a state: the layers that attend, over rows of their
+        # own or, cross attention, another layer's)
         attn_flops_per_kv=2.0 * (cfg.num_layers if not cfg.has_state else
-                                 cfg.num_layers - cfg.state_layers
-                                 - cfg.layers_of(GMU))
+                                 sum(holds_rows(kind) or kind == CROSS
+                                     for kind in cfg.layer_types))
         * cfg.num_heads * pair / n,
         weight_bytes=int(weight_bytes_total // n),
         kv_bytes_per_token=int(kv_row * max(1, kv_replication) // n),

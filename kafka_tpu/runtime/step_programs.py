@@ -31,7 +31,7 @@ from typing import Any, Callable, Dict, NamedTuple, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from ..models.config import DELTA, ModelConfig
+from ..models.config import DELTA, PARALLEL, ModelConfig
 from ..models.llama import (
     INDEX_WALK_KEYS,
     KVCache,
@@ -43,6 +43,7 @@ from ..models.llama import (
     walk_pages,
 )
 from ..ops.attention import decode_walk_pages, shared_walk_trips
+from ..ops.pallas import ssd as ssd_kernels
 from ..ops.pallas.gated_delta import chunk_rows
 from ..ops.pallas.paged_attention import step_pages
 from ..ops.sampling import (
@@ -701,6 +702,27 @@ class StepPrograms:
         cfg = self.cfg
         return (2 * 4 * cfg.layers_of(DELTA) * cfg.delta_heads
                 * cfg.delta_head_dim ** 2 * lanes * steps)
+
+    def ssd_chunk_trips(self, lanes: int, bucket: int) -> int:
+        """Chunks the SSD prefill kernel loops over ONE launch of `bucket`
+        rows whose `lanes` active lanes it computes, by the grid the kernel
+        itself is given (ops/pallas/ssd.chunk_rows), summed over the layers;
+        0 for a model without an SSD mixer, on the XLA backend and for a
+        bucket the kernel does not tile (the row-by-row scan runs)."""
+        cfg = self.cfg
+        n = cfg.layers_of(PARALLEL)
+        if not n or cfg.attention_backend != "pallas":
+            return 0
+        rows = ssd_kernels.chunk_rows(bucket)
+        return n * lanes * (bucket // rows) if rows else 0
+
+    def ssd_state_bytes(self, lanes: int, steps: int) -> int:
+        """Bytes of SSD state `steps` decode passes over `lanes` busy lanes
+        read and wrote: every layer's heads' states, once in and once out (0
+        for a model without an SSD mixer)."""
+        cfg = self.cfg
+        return (2 * 4 * cfg.layers_of(PARALLEL) * cfg.ssd_heads
+                * cfg.ssd_head_dim * cfg.ssd_d_state * lanes * steps)
 
     def moe_dispatch(self, rows: int) -> Optional[str]:
         """"token" or "dense": the form the routed blocks of a pass of

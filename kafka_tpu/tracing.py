@@ -231,6 +231,18 @@ DEVICE_SCOPES = (
     "kda_delta",    # the recurrence: the chunked Pallas kernel at s > 1,
                     # the step kernel in decode (the state updated in place
                     # in its slot), or the row-by-row XLA scan
+    # the parallel layout's second mixer (models/llama._ssd_block); its
+    # attention keeps attn_qkv / kv_write / attn_core / attn_out
+    "ssd_proj",     # a Mamba-2 (SSD) mixer's projections: W_in with its two
+                    # multipliers, W_out with its one, and the branch's add
+                    # to the attention branch ahead of the residual
+    "ssd_conv",     # its short convolution, bias and SiLU, and the tail's
+                    # read from and write to the state slot
+    "ssd_gate",     # its elementwise parts: softplus, the decay, the D
+                    # skip, the gate and the grouped norm
+    "ssd_scan",     # the recurrence: the chunked Pallas kernel at s > 1, the
+                    # step kernel in decode (the state updated in place in
+                    # its slot), or the row-by-row XLA scan
     "head",         # final RMSNorm + logits
     "sample",       # last-position select, per-(seed, position) keys,
                     # sample_tokens_per_slot (engine step programs)
