@@ -93,7 +93,12 @@ from .kv_cache import (
 )
 from .metrics import EngineMetrics
 from .phase_clock import SchedClock
-from .planner import grammar_table_cap_bytes
+from .planner import (
+    first_fit_bucket,
+    first_fit_launches,
+    grammar_table_cap_bytes,
+    prefill_launches,
+)
 from .prefix_cache import PrefixCache
 from .speculative import LaneSpeculator
 from .step_programs import Fsm, Lanes, StepPrograms
@@ -531,6 +536,11 @@ class GenRequest:
     state_restored: Optional[int] = None
     state_cut: int = 0
     state_matched: int = 0
+    # the chunk plan cuts the remainder now being prefilled into more
+    # launches than the first bucket that holds it would make, and did so to
+    # some remainder of this prefill (_count_prefill_plan)
+    split_now: bool = False
+    plan_split: bool = False
 
     @property
     def cached_len(self) -> int:
@@ -1360,6 +1370,13 @@ class InferenceEngine:
         self._cost_model = None
         self._roofline: Optional[Tuple] = None
         self._have_roofline = False
+        # The chunk plan (planner.prefill_launches): what one prefill launch
+        # of (rows, tokens held, start) is modeled to cost on this chip, and
+        # the first bucket of each plan made so far.  No roofline (the CPU,
+        # an unlisted chip): no price, and a remainder goes out in the first
+        # bucket that holds it.
+        self._launch_price: Optional[Callable[[int, int, int], float]] = None
+        self._first_buckets: Dict[Tuple[int, int], Tuple[int, bool]] = {}
         try:
             from ..models.quant import param_bytes as _param_bytes
             from .planner import device_peaks, dispatch_cost_model
@@ -1374,6 +1391,8 @@ class InferenceEngine:
                 weight_bytes_total=_param_bytes(params),
                 kv_dtype_bytes=kv_b,
                 kv_replication=self._tq,
+                int8_experts=(cfg.is_moe
+                              and experts_int8(params["layers"])),
             )
             self._roofline = device_peaks(dev)
             self.metrics.set_roofline(*self._roofline)
@@ -1381,6 +1400,9 @@ class InferenceEngine:
             # bench swap in fresh EngineMetrics objects): the cost
             # helpers re-apply it on the first dispatch they record
             self._have_roofline = self._roofline[2] != "unknown"
+            if self._have_roofline and all(self._roofline[:2]):
+                self._launch_price = self._cost_model.launch_price(
+                    *self._roofline[:2])
         except Exception as e:
             if on_tpu:
                 raise
@@ -1424,6 +1446,10 @@ class InferenceEngine:
         # (static shapes) computes.
         self.prefill_rows_dispatched = 0
         self.prefill_rows_filled = 0
+        # requests prefilled, and those of them the chunk plan split
+        # (_count_prefill_plan)
+        self.prefill_plans = 0
+        self.prefill_plans_split = 0
         # Monotonic, and 0 wherever decode does not run the XLA walk
         # (StepPrograms.decode_keys): the keys every decode step gathered
         # a layer (lanes x chunks x chunk keys, per step of a fused
@@ -3131,9 +3157,17 @@ class InferenceEngine:
 
     def _snapshot_slot(self, req: GenRequest, end: int) -> Optional[int]:
         """A state slot for the snapshot a prefill chunk ending at token
-        `end` leaves: only a page boundary can be shared from."""
+        `end` leaves: only a page boundary can be shared from.  A chunk of a
+        split plan that does not finish its remainder asks for none: the one
+        launch the split stands for would have left no snapshot there, the
+        position is shared with no other request, and each such snapshot
+        pushes one that is out of the pool (Phi-4's cell: 66 of 96 snapshot
+        slots after 51 s instead of 5-8)."""
         if (self.state_pool is None or self.prefix_cache is None
                 or req.prefix_key is None or end % self.ecfg.page_size):
+            return None
+        if req.split_now and end < (req.seq.length
+                                    + self._prefill_remaining(req)):
             return None
         return self.prefix_cache.alloc_snapshot()
 
@@ -3423,6 +3457,7 @@ class InferenceEngine:
 
     def _prefill_bucket_for(self, req: GenRequest) -> int:
         remaining = self._prefill_remaining(req)
+        req.split_now = False
         if req.background and any(
             s is not None and s.state == ACTIVE and not s.background
             for s in self.slots
@@ -3431,10 +3466,38 @@ class InferenceEngine:
             # interactive lane is decoding: the added inter-token gap is
             # bounded by one SMALL chunk's compute, not a 512-token one
             return self.ecfg.prefill_buckets[0]
-        return next(
-            (b for b in self.ecfg.prefill_buckets if b >= remaining),
-            self.ecfg.prefill_buckets[-1],
-        )
+        bucket, req.split_now = self._first_bucket(remaining, req.seq.length)
+        req.plan_split = req.plan_split or req.split_now
+        return bucket
+
+    def _first_bucket(self, remaining: int, start: int) -> Tuple[int, bool]:
+        """(the bucket of the chunk plan's first launch, whether the plan
+        makes more launches than the first bucket that holds `remaining`
+        would): a pure function of the remainder rounded up to a page, its
+        position, the ladder and the price, so each is planned once."""
+        buckets = self.ecfg.prefill_buckets
+        if self._launch_price is None:
+            return first_fit_bucket(remaining, buckets), False
+        ps = self.ecfg.page_size
+        whole = -(-remaining // ps) * ps
+        found = self._first_buckets.get((whole, start))
+        if found is None:
+            if len(self._first_buckets) >= 65536:  # a few MB of keys
+                self._first_buckets.clear()
+            plan = prefill_launches(whole, buckets, self._launch_price,
+                                    start, ps)
+            found = self._first_buckets[whole, start] = (
+                plan[0][0],
+                len(plan) > len(first_fit_launches(whole, buckets)))
+        return found
+
+    def _count_prefill_plan(self, req: GenRequest) -> None:
+        """A request's last prefill chunk is out: count it, and whether any
+        of its remainders went out in more launches than the first bucket
+        that holds it would have made."""
+        self.prefill_plans += 1
+        self.prefill_plans_split += req.plan_split
+        req.plan_split = False
 
     def batches_prefill(self, bucket: int) -> bool:
         """May same-bucket prefill chunks of this size fuse into one
@@ -3589,7 +3652,9 @@ class InferenceEngine:
             (int(chunk_lens[i]), int(starts[i])) for i in range(len(reqs))
         ]))
         if self.flight is not None:
-            self.flight.note_prefill(len(reqs), int(chunk_lens.sum()))
+            self.flight.note_prefill(
+                len(reqs), int(chunk_lens.sum()), W * bucket,
+                sum(r.plan_split for r in reqs))
         items: List[Optional[GenRequest]] = [None] * W
         finals_row: List[Optional[str]] = [None] * W
         for i, req in enumerate(reqs):
@@ -3601,6 +3666,7 @@ class InferenceEngine:
                     else None)
             if req.seq.length < len(req.prefill_ids):
                 continue  # more chunks to go
+            self._count_prefill_plan(req)
             req.prefill_allowed = None
             if req.t_first_dispatch is None:
                 # stamp the fused path too: the TTFT breakdown and the
@@ -3741,7 +3807,7 @@ class InferenceEngine:
             self._record_prefill_cost([(chunk_len, start)])
         )
         if self.flight is not None:
-            self.flight.note_prefill(1, chunk_len)
+            self.flight.note_prefill(1, chunk_len, bucket, req.plan_split)
         req.seq.length = start + chunk_len
         self._store_prefill(req, req.seq.length, snap)
         if req.seq.length < total:
@@ -3752,6 +3818,7 @@ class InferenceEngine:
         """Last chunk dispatched: the lane joins the decode batch (or parks
         awaiting a slot when it prefilled off-slot)."""
         slot = req.slot
+        self._count_prefill_plan(req)
         req.prefill_allowed = None
         if req.t_first_dispatch is None:
             req.t_first_dispatch = time.monotonic()

@@ -200,7 +200,7 @@ class _Rec:
     __slots__ = (
         "seq", "t", "gap_ms",
         "kinds", "lanes", "toks", "steps",
-        "prefill_lanes", "prefill_toks",
+        "prefill_lanes", "prefill_toks", "prefill_rows", "prefill_split",
         "spec_cands", "chained", "awaited",
         "queue_depth", "active", "parked", "pending", "pending_steps",
         "pages_free", "pages_total", "cache_pages", "tier_bytes",
@@ -222,6 +222,8 @@ class _Rec:
         self.steps = 0
         self.prefill_lanes = 0
         self.prefill_toks = 0
+        self.prefill_rows = 0
+        self.prefill_split = 0
         self.spec_cands = 0
         self.chained = 0
         self.awaited = 0
@@ -257,6 +259,8 @@ class _Rec:
             "steps": self.steps,
             "prefill_lanes": self.prefill_lanes,
             "prefill_toks": self.prefill_toks,
+            "prefill_rows": self.prefill_rows,
+            "prefill_split": self.prefill_split,
             "spec_cands": self.spec_cands,
             "chained": self.chained,
             "awaited": self.awaited,
@@ -340,11 +344,17 @@ class FlightRecorder:
         s.toks += toks
         s.steps += steps
 
-    def note_prefill(self, lanes: int, toks: int) -> None:
+    def note_prefill(self, lanes: int, toks: int, rows: int = 0,
+                     split: int = 0) -> None:
+        """One prefill launch: the lanes it advanced, the tokens they held,
+        the bucket rows it dispatched, and how many of the lanes are on a
+        chunk plan that split their remainder (engine._first_bucket)."""
         s = self._stage
         s.kinds |= KIND_PREFILL
         s.prefill_lanes += lanes
         s.prefill_toks += toks
+        s.prefill_rows += rows
+        s.prefill_split += split
 
     def note_spec(self, candidates: int) -> None:
         self._stage.spec_cands += candidates
@@ -443,6 +453,8 @@ class FlightRecorder:
         rec.steps = s.steps
         rec.prefill_lanes = s.prefill_lanes
         rec.prefill_toks = s.prefill_toks
+        rec.prefill_rows = s.prefill_rows
+        rec.prefill_split = s.prefill_split
         rec.spec_cands = s.spec_cands
         rec.chained = s.chained
         rec.awaited = s.awaited

@@ -425,6 +425,13 @@ METRICS: Tuple[Metric, ...] = (
            read=lambda e: e.kv_window_dead_share(), digits=6),
         _m("prefill_rows_dispatched", read="prefill_rows_dispatched"),
         _m("prefill_rows_filled", read="prefill_rows_filled"),
+        _m("prefill_plans", "kafka_tpu_engine_prefill_plans_total{plan=all}",
+           "Requests prefilled, and those whose uncached suffix the chunk "
+           "plan launched in more launches than the first bucket that "
+           "holds it makes.", read="prefill_plans"),
+        _m("prefill_plans_split",
+           "kafka_tpu_engine_prefill_plans_total{plan=split}",
+           read="prefill_plans_split"),
         _m("decode_keys_walked", read="decode_keys_walked"),
         _m("decode_keys_window", read="decode_keys_window"),
         _m("decode_keys_shared", read="decode_keys_shared"),

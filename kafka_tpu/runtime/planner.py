@@ -37,7 +37,7 @@ from __future__ import annotations
 
 import dataclasses
 import os
-from typing import Dict, Optional
+from typing import Callable, Dict, Optional, Sequence, Tuple
 
 from ..models.config import (
     CONV,
@@ -330,17 +330,28 @@ def _hybrid_weight_bytes(cfg: ModelConfig, wb: int) -> int:
     di, ds, dc = cfg.mamba_d_inner, cfg.mamba_d_state, cfg.mamba_d_conv
     r = cfg.mamba_dt_rank
     n_m = cfg.state_layers
-    n_x = cfg.layers_of(CROSS)
-    common = cfg.num_layers * (3 * h * f + 4 * h) * wb
+    common = 2 * n_m * (3 * h * f + 4 * h) * wb
     mamba = n_m * ((h * 2 * di + di * (r + 2 * ds) + r * di + di * h) * wb
                    + (dc * di + 3 * di + ds * di) * 4)
     diff = 4 * d * 4 + 2 * d * wb  # lambda vectors, sub-layer norm
     attn = n_m * ((h * (hq + 2 * hkv) * d + (hq + 2 * hkv) * d
                    + hq * d * h + h) * wb + diff)
-    cross = n_x * ((2 * h * hq * d + hq * d + h) * wb + diff)
-    gmu = n_x * 2 * h * di * wb
-    return (common + mamba + attn + cross + gmu
+    return (common + mamba + attn + _hybrid_second_half_bytes(cfg, wb)
             + (cfg.vocab_size * h + 2 * h) * wb)
+
+
+def _hybrid_second_half_bytes(cfg: ModelConfig, wb: int) -> int:
+    """The hybrid decoder's cross periods (gated memory unit, cross
+    attention, an MLP each): the half a prefill launch runs on each lane's
+    last real row alone (models/hybrid.forward)."""
+    h, f, d = cfg.hidden_size, cfg.intermediate_size, cfg.head_dim
+    hq = cfg.num_heads
+    n_x = cfg.layers_of(CROSS)
+    diff = 4 * d * 4 + 2 * d * wb  # lambda vectors, sub-layer norm
+    common = 2 * n_x * (3 * h * f + 4 * h) * wb
+    cross = n_x * ((2 * h * hq * d + hq * d + h) * wb + diff)
+    gmu = n_x * 2 * h * cfg.mamba_d_inner * wb
+    return common + cross + gmu
 
 
 def state_bytes_per_device(cfg: ModelConfig, state_slots: int) -> int:
@@ -629,6 +640,20 @@ class DispatchCostModel:
     attn_flops_per_kv: float     # per device: per (query, kv-token) pair
     weight_bytes: int            # per device: read once per dispatch step
     kv_bytes_per_token: int      # per device: one token's k+v row
+    # What ONE prefill launch pays, by what pays it (prefill_launch_cost):
+    # the products every dispatched row takes part in, those a lane's last
+    # real row alone does, one (token, expert) pick through every routed
+    # layer, and the attention pairs by kind of layer.
+    row_flops: float = 0.0
+    lane_flops: float = 0.0
+    pick_flops: float = 0.0
+    attn_kinds: Tuple[Tuple[float, Optional[int]], ...] = ()  # (a pair, window)
+    pad_rows_attend: bool = False  # XLA prefill: rows past chunk_len attend
+    # (experts held, experts the router knows, picks a token, sharded or
+    # int8: models/llama.moe_dispatch_form's arguments); None = no router
+    moe: Optional[Tuple[int, int, int, bool, bool]] = None
+    expert_bytes: int = 0        # every routed expert held, all layers
+    launch_bytes: int = 0        # weights a launch reads whatever it holds
 
     def decode_cost(self, new_tokens: int, kv_tokens: int,
                     steps: int = 1) -> tuple:
@@ -654,6 +679,51 @@ class DispatchCostModel:
                   + (start_tokens + chunk_tokens) * self.kv_bytes_per_token
                   + chunk_tokens * self.kv_bytes_per_token)
         return flops, bytes_
+
+    def prefill_launch_cost(self, rows: int, tokens: int,
+                            start: int) -> tuple:
+        """(flops, bytes) of ONE prefill launch of `rows` bucket rows of
+        which the first `tokens` hold a token, from position `start`: what
+        the chunk plan prices (prefill_launches).  Rows that hold no token
+        still take part in every dense product (projections, dense and
+        shared-expert MLPs, a state model's row-wise half); they attend
+        nothing on the Pallas path (the flash-prefill kernel skips their q
+        blocks) and enter no expert group under token dispatch, which the
+        routed block takes by models/llama.moe_dispatch_form's rule: below
+        it every held expert multiplies every row.  A hybrid decoder's
+        second half and the head run on each lane's last real row.  The
+        weights are read once: every routed expert under dense dispatch,
+        under token dispatch those some token picked."""
+        flops = rows * self.row_flops + self.lane_flops
+        q = rows if self.pad_rows_attend else tokens
+        for pair_flops, window in self.attn_kinds:
+            keys = start + q / 2
+            flops += q * (keys if window is None else min(keys, window)) \
+                * pair_flops
+        bytes_ = (self.launch_bytes
+                  + (start + 2 * tokens) * self.kv_bytes_per_token)
+        if self.moe is not None:
+            from ..models.llama import moe_dispatch_form
+
+            held, routed, top_k, sharded, int8 = self.moe
+            if moe_dispatch_form(rows, held, top_k, sharded, routed,
+                                 int8) == "dense":
+                flops += rows * held * self.pick_flops
+                bytes_ += self.expert_bytes
+            else:
+                flops += tokens * top_k * (held / routed) * self.pick_flops
+                bytes_ += self.expert_bytes * (
+                    1.0 - (1.0 - top_k / routed) ** tokens)
+        return flops, bytes_
+
+    def launch_price(self, peak_flops: float, peak_hbm_bps: float
+                     ) -> Callable[[int, int, int], float]:
+        """price(rows, tokens, start): the modeled seconds of one prefill
+        launch on a chip of these peaks, the slower of its two bounds."""
+        def price(rows: int, tokens: int, start: int) -> float:
+            flops, bytes_ = self.prefill_launch_cost(rows, tokens, start)
+            return max(flops / peak_flops, bytes_ / peak_hbm_bps)
+        return price
 
     def verify_cost(self, query_tokens: int, kv_tokens: int,
                     attn_pairs: Optional[float] = None) -> tuple:
@@ -682,6 +752,7 @@ def dispatch_cost_model(
     weight_bytes_total: Optional[int] = None,
     kv_dtype_bytes: int = 2,
     kv_replication: int = 1,
+    int8_experts: bool = False,
 ) -> DispatchCostModel:
     """Build the per-device dispatch cost model for an engine.
 
@@ -690,6 +761,8 @@ def dispatch_cost_model(
     falls back to the planner's bf16 arithmetic.  `kv_replication` is the
     tq factor (grouped GQA replicates each kv head across its tq group,
     so per-device KV traffic does not shrink by the full device count).
+    `int8_experts`: the routed experts' leaves are quantized
+    (models/llama.experts_int8), which the routed block's form asks.
     """
     if weight_bytes_total is None:
         weight_bytes_total = weight_bytes_per_device(cfg, tp=1)
@@ -703,6 +776,34 @@ def dispatch_cost_model(
     # the latent and the rotary lanes, the sum over the latent ones.
     pair = (2 * cfg.kv_lora_rank + cfg.qk_rope_head_dim if cfg.is_latent
             else 2 * cfg.head_dim)
+    # The launch price's split of the parameters (prefill_launch_cost): the
+    # routed experts, the table a launch gathers rows of (and, tied, its
+    # head), a hybrid decoder's second half; every other leaf multiplies
+    # every dispatched row.
+    table = cfg.vocab_size * cfg.hidden_size
+    expert = 3 * cfg.hidden_size * cfg.intermediate_size if cfg.is_moe else 0
+    routed_layers = (cfg.num_layers - cfg.first_k_dense) if cfg.is_moe else 0
+    experts_total = routed_layers * cfg.num_experts * expert
+    second_half = (_hybrid_second_half_bytes(cfg, wb) / wb
+                   if cfg.hybrid_decoder else 0.0)
+    gathered = 0 if cfg.tie_word_embeddings else table
+    # (a model with a state takes the head of each lane's last real row;
+    # the others' prefill programs multiply every row by it and pick one)
+    last_row = (table if cfg.has_state else 0) + second_half
+    row_params = params_total - experts_total - gathered - last_row
+    # bytes by the share of the ACTUAL tree (an int8 tree's leaves shrink
+    # together, near enough)
+    per_param = weight_bytes_total / params_total / n
+
+    def pair_flops(kind: str) -> float:
+        """One (query, key) pair through every layer of `kind`."""
+        if cfg.is_latent:
+            g = cfg.geometry_of(kind)
+            heads, width = g.num_heads, 2 * g.kv_lora_rank + g.qk_rope_head_dim
+        else:
+            heads, width = cfg.num_heads, 2 * cfg.head_dim
+        return 2.0 * cfg.layers_of(kind) * heads * width / n
+
     return DispatchCostModel(
         flops_per_token=2.0 * params_total / n,
         # (a model with a state: the layers that attend, over rows of their
@@ -713,7 +814,95 @@ def dispatch_cost_model(
         * cfg.num_heads * pair / n,
         weight_bytes=int(weight_bytes_total // n),
         kv_bytes_per_token=int(kv_row * max(1, kv_replication) // n),
+        row_flops=2.0 * row_params / n,
+        lane_flops=2.0 * last_row / n,
+        pick_flops=2.0 * routed_layers * expert / n,
+        attn_kinds=tuple((pair_flops(kind), cfg.window_of(kind))
+                         for kind in cfg.kinds
+                         if cfg.is_latent or holds_rows(kind)),
+        pad_rows_attend=cfg.attention_backend != "pallas",
+        moe=((cfg.num_experts, cfg.num_router_experts,
+              cfg.num_experts_per_tok, n > 1, int8_experts)
+             if cfg.is_moe else None),
+        expert_bytes=int(experts_total * per_param),
+        launch_bytes=int((params_total - experts_total - gathered)
+                         * per_param),
     )
+
+
+# A split plan must be modeled this much cheaper than the launches the
+# first-bucket-that-holds-it rule makes: the price sees device seconds alone,
+# and a launch is also a host dispatch and one more place in the device's
+# queue between the request and its first token.
+PREFILL_SPLIT_MIN_SAVING = 0.10
+
+Launches = Tuple[Tuple[int, int], ...]  # (bucket rows, tokens held) a launch
+
+
+def first_fit_bucket(remaining: int, buckets: Sequence[int]) -> int:
+    """The first bucket of the ladder that holds `remaining`, else the
+    largest."""
+    return next((b for b in buckets if b >= remaining), buckets[-1])
+
+
+def first_fit_launches(remaining: int, buckets: Sequence[int]) -> Launches:
+    """The launches of the rule without a price: the first bucket that
+    holds what is left, until nothing is."""
+    out = []
+    while remaining > 0:
+        b = first_fit_bucket(remaining, buckets)
+        out.append((b, min(b, remaining)))
+        remaining -= out[-1][1]
+    return tuple(out)
+
+
+def prefill_launches(
+    remaining: int,
+    buckets: Sequence[int],
+    price: Optional[Callable[[int, int, int], float]] = None,
+    start: int = 0,
+    page_size: int = 1,
+) -> Launches:
+    """The chunk plan: the launches that cover `remaining` prompt tokens
+    from position `start` at the least `price(rows, tokens, start)`, the
+    modeled seconds of one launch.  The candidates are the rule without a
+    price (the first bucket that holds the remainder; past the largest,
+    whole launches of it) and, for each smaller bucket the remainder fills
+    at least once, as many FULL launches of it as fit, each followed by the
+    plan of the rest: every launch but the last is full, and ends on a page
+    boundary (a resumed prefill must start on one), so a bucket that is no
+    multiple of `page_size` splits nothing.  The cheapest candidate wins if
+    it is PREFILL_SPLIT_MIN_SAVING cheaper than the first; ties go to fewer
+    launches.  No price: the rule without one."""
+    if remaining <= 0:
+        return ()
+    if price is None:
+        return first_fit_launches(remaining, buckets)
+
+    def cost(launches: Launches) -> float:
+        total, at = 0.0, start
+        for rows, tokens in launches:
+            total += price(rows, tokens, at)
+            at += tokens
+        return total
+
+    def full_then_rest(b: int) -> Launches:
+        n = max(1, remaining // b)
+        head = ((b, min(b, remaining)),) * n
+        done = sum(t for _, t in head)
+        return head + prefill_launches(remaining - done, buckets, price,
+                                       start + done, page_size)
+
+    b0 = first_fit_bucket(remaining, buckets)
+    first = full_then_rest(b0)
+    splits = [full_then_rest(b) for b in buckets
+              if b < b0 and b % page_size == 0]
+    if not splits:
+        return first
+    least, _, best = min((cost(ls), len(ls), ls) for ls in splits)
+    if least <= (1.0 - PREFILL_SPLIT_MIN_SAVING) * cost(first):
+        return best
+    return first
 
 
 def plan_for_serving(scfg, hbm_bytes: Optional[int] = None,
