@@ -31,6 +31,7 @@ from __future__ import annotations
 
 import dataclasses
 import json
+import math
 import os
 from typing import Optional, Tuple
 
@@ -91,6 +92,10 @@ class RopeParams:
     beta_fast: float = 32.0
     beta_slow: float = 1.0
     attention_factor: Optional[float] = None
+    # `deepseek_v3`'s `rope_scaling.mscale_all_dim` (0 = absent): the softmax
+    # scale of a latent layer is then multiplied by (0.1 * mscale_all_dim *
+    # ln(factor) + 1) ** 2 (`ModelConfig.latent_softmax_scale`)
+    mscale_all_dim: float = 0.0
 
 
 @dataclasses.dataclass(frozen=True)
@@ -245,6 +250,20 @@ class ModelConfig:
     # module is recorded and NOT built (a loader that drops `mtp.*` serves
     # the model); the engine refuses speculative decoding for such a model.
     nextn_predict_layers: int = 0
+    # -- a widened residual stream (`xing4_0`; `_hc_in` / `_hc_out` in
+    # models/llama.py; "mHC: Manifold-Constrained Hyper-Connections"):
+    # `hc_mult` = n > 1 turns it on.  A token's state is n rows of
+    # hidden_size; every sublayer reads one row mixed from them by a
+    # per-token H_pre (sigmoid) and writes back H_res X + H_post^T y, H_post
+    # = 2 sigmoid and H_res an n x n matrix made doubly stochastic by
+    # `hc_sinkhorn_iters` Sinkhorn rounds over exp of its clamped logits
+    # (`hc_eps` in every divisor).  1 = absent: one row, `h + y`, and not an
+    # op traced.  The stream lives inside `forward` alone. --
+    hc_mult: int = 1
+    hc_sinkhorn_iters: int = 20
+    hc_eps: float = 1e-6
+    hc_res_clamp_min: float = -30.0
+    hc_res_clamp_max: float = 30.0
     # -- a hybrid decoder (`phi4flash`; models/hybrid.py): `mamba_d_state`
     # > 0 turns it on and `layer_types` then names MAMBA / GMU / CROSS
     # layers beside the attention kinds.  A MAMBA layer is a Mamba-1 mixer
@@ -355,6 +374,14 @@ class ModelConfig:
                 "a share of the routed experts (num_experts_routed) needs "
                 "sigmoid routing and expert_offset + num_experts within the "
                 "router's width")
+        if self.hc_mult < 1 or self.hc_mult > 1 and (
+                self.hc_sinkhorn_iters < 1 or not self.is_latent
+                or self.has_state):
+            raise UnsupportedConfigError(
+                f"hc_mult = {self.hc_mult} (a residual stream of that many "
+                "rows a token) is built with latent attention "
+                "(kv_lora_rank), hc_sinkhorn_iters >= 1 and no "
+                "state-holding kind of layer")
         if self.first_k_dense and not (
                 self.is_moe and 0 < self.first_k_dense < self.num_layers
                 and self.dense_intermediate_size > 0):
@@ -684,7 +711,7 @@ class ModelConfig:
         return self.is_latent and bool(
             self.layer_types or self.index_topk or self.q_lora_rank
             or self.attention_gate or self.latent_rescale
-            or self.windowed_latent)
+            or self.windowed_latent or self.hc_mult > 1)
 
     def geometry_of(self, kind: str = GLOBAL) -> LatentGeometry:
         """The latent block's sizes in a layer of `kind`."""
@@ -696,6 +723,21 @@ class ModelConfig:
 
     def has_indexer(self, kind: str = GLOBAL) -> bool:
         return self.index_topk > 0 and kind == GLOBAL
+
+    def latent_softmax_scale(self, kind: str = GLOBAL) -> float:
+        """The softmax scale of a latent layer of `kind`: (nope + rope
+        widths) ** -0.5, times m^2 where the kind's rotation is YaRN's with
+        `mscale_all_dim` (HF `DeepseekV3Attention`: m = 0.1 *
+        mscale_all_dim * ln(factor) + 1; Xing4.0's 1.4159^2 = 2.0048).  A
+        kind without such a rotation: the plain scale, the float it always
+        was."""
+        g = self.geometry_of(kind)
+        scale = (g.qk_nope_head_dim + g.qk_rope_head_dim) ** -0.5
+        rp = self.rope_of(kind)
+        if rp is not None and rp.mscale_all_dim and rp.factor > 1:
+            m = 0.1 * rp.mscale_all_dim * math.log(rp.factor) + 1.0
+            scale = scale * m * m
+        return scale
 
     @property
     def num_router_experts(self) -> int:
@@ -944,7 +986,6 @@ def _latent_keys(hf: dict) -> dict:
     if not hf.get("kv_lora_rank"):
         return {}
     served = (
-        ("rope_scaling", None, "scaled rotary positions on latent attention"),
         ("topk_method", "noaux_tc", "another expert selection method"),
         ("scoring_func", "sigmoid", "another router scoring function"),
         ("moe_layer_freq", 1, "dense layers among the routed ones"),
@@ -957,6 +998,8 @@ def _latent_keys(hf: dict) -> dict:
     dense = int(hf.get("first_k_dense_replace", 0))
     return {
         **_kind_keys(hf),
+        **_latent_rope_scaling(hf),
+        "nextn_predict_layers": int(hf.get("num_nextn_predict_layers") or 0),
         "kv_lora_rank": int(hf["kv_lora_rank"]),
         "qk_nope_head_dim": int(hf["qk_nope_head_dim"]),
         "qk_rope_head_dim": int(hf["qk_rope_head_dim"]),
@@ -970,6 +1013,78 @@ def _latent_keys(hf: dict) -> dict:
                                      * int(hf["moe_intermediate_size"])),
         "moe_scoring": "sigmoid",
         "routed_scaling_factor": float(hf.get("routed_scaling_factor", 1.0)),
+    }
+
+
+# `rope_scaling` of a `deepseek_v3`-style config.json, as served: YaRN with
+# exactly these keys (the type under either spelling)
+_YARN_KEYS = {"type", "rope_type", "factor",
+              "original_max_position_embeddings", "beta_fast", "beta_slow",
+              "mscale", "mscale_all_dim"}
+
+
+def _latent_rope_scaling(hf: dict) -> dict:
+    """`rope_scaling` on latent attention: {} where absent; YaRN as HF
+    `DeepseekV3` applies it (the blended frequencies of
+    ops/rope.yarn_frequencies, cos and sin times get_mscale(mscale) /
+    get_mscale(mscale_all_dim), the softmax scale times
+    get_mscale(mscale_all_dim) ** 2) as the GLOBAL kind's RopeParams.
+    Anything else is refused by name."""
+    rs = hf.get("rope_scaling")
+    if not rs:
+        return {}
+    kind = rs.get("rope_type", rs.get("type"))
+    if kind != "yarn":
+        raise UnsupportedConfigError(
+            f"rope_scaling type {kind!r} on latent attention is not served: "
+            "only 'yarn' is")
+    unknown = set(rs) - _YARN_KEYS
+    if unknown:
+        raise UnsupportedConfigError(
+            f"rope_scaling keys {sorted(unknown)} on latent attention are "
+            f"not served: known {sorted(_YARN_KEYS)}")
+    if hf.get("layer_types") or any(k.startswith("swa_") for k in hf):
+        raise UnsupportedConfigError(
+            "rope_scaling on a latent model with kinds of layer "
+            "(layer_types / swa_*: scaled rotary positions on a windowed "
+            "kind) is not served")
+    missing = {"factor", "original_max_position_embeddings"} - set(rs)
+    if missing:
+        raise UnsupportedConfigError(
+            f"rope_scaling of type yarn on latent attention needs "
+            f"{sorted(missing)}")
+    mscale = float(rs.get("mscale", 1.0))
+    all_dim = float(rs.get("mscale_all_dim", 0.0))
+    if mscale != all_dim:
+        raise UnsupportedConfigError(
+            f"rope_scaling mscale = {mscale!r} differs from mscale_all_dim "
+            f"= {all_dim!r}: the factor on cos and sin would be "
+            "get_mscale(mscale) / get_mscale(mscale_all_dim) != 1, which "
+            "latent attention is not held to; only equal values are served")
+    return {"rope_by_kind": ((GLOBAL, RopeParams(
+        rope_type="yarn",
+        rope_theta=float(hf.get("rope_theta", 10000.0)),
+        factor=float(rs["factor"]),
+        original_max_position=int(rs["original_max_position_embeddings"]),
+        beta_fast=float(rs.get("beta_fast", 32.0)),
+        beta_slow=float(rs.get("beta_slow", 1.0)),
+        attention_factor=1.0,  # get_mscale(mscale) / get_mscale(all_dim)
+        mscale_all_dim=all_dim)),)}
+
+
+def _stream_keys(hf: dict) -> dict:
+    """The widened residual stream's keys (`hc_mult`, `hc_sinkhorn_iters`,
+    `hc_eps`, `mhc_h_res_clamp_min` / `_max`) as ModelConfig fields; {} for
+    a config without `hc_mult` or with one row."""
+    n = int(hf.get("hc_mult", 1))
+    if n == 1:
+        return {}
+    return {
+        "hc_mult": n,
+        "hc_sinkhorn_iters": int(hf.get("hc_sinkhorn_iters", 20)),
+        "hc_eps": float(hf.get("hc_eps", 1e-6)),
+        "hc_res_clamp_min": float(hf.get("mhc_h_res_clamp_min", -30.0)),
+        "hc_res_clamp_max": float(hf.get("mhc_h_res_clamp_max", 30.0)),
     }
 
 
@@ -1287,7 +1402,9 @@ def config_from_hf_json(path: str) -> ModelConfig:
     latent = _latent_keys(hf)
     routed_lead = _routed_lead_keys(hf)
     delta = _delta_keys(hf)
-    rs = hf.get("rope_scaling") or {}
+    # (a latent model's `rope_scaling` is `_latent_rope_scaling`'s, not the
+    # Llama-3 form the model-wide fields hold)
+    rs = {} if latent else hf.get("rope_scaling") or {}
     # honor the checkpoint's own precision ("dtype" since transformers
     # 4.56+, "torch_dtype" before); fp16 checkpoints run as bf16 (same
     # width, TPU-native — fp16 has no MXU path)
@@ -1366,4 +1483,6 @@ def config_from_hf_json(path: str) -> ModelConfig:
         **latent,
         **routed_lead,
         **hybrid,
+        # (ModelConfig refuses the stream where it is not built)
+        **_stream_keys(hf),
     )

@@ -74,6 +74,15 @@ Two cache forms go through the same layer math: the *contiguous*
   (models/hybrid.py).  A paged prefill returns its lanes' last real rows
   only, logits [B, 1, V], as every model with a state does.
 
+* **The widened residual stream** — `cfg.hc_mult` = n > 1 (`xing4_0`, on
+  latent attention).  The hidden state in the scan's carry is n rows a token,
+  [B, T, n * C], widened once under `embed` and collapsed once under `head`;
+  every sublayer reads ONE row mixed from them and writes all n back, by
+  per-token mappings (`_hc_in` / `_hc_out`, the only code that knows).  The
+  mappings' leaves (`hc_<site>_*`, HC_SITES) are stacked beside the norms in
+  "dense_layers" / "layers".  With n = 1 the two helpers are `h` and `h + y`
+  and not an op is traced (tests/test_lowered_pins.py).
+
 **The stacked cache is scan CARRY, never a scanned input.**  The layer scan
 runs over (layer params, layer index); the caches of all layers travel
 through it whole and a layer addresses its part by index.  The paged pool is
@@ -695,10 +704,37 @@ def _init_kind_params(cfg: ModelConfig, key: jax.Array, dtype) -> Params:
         return {"ln_attn": jnp.ones((n, h), dtype),
                 "ln_mlp": jnp.ones((n, h), dtype)}
 
+    def stream_maps(k, n):
+        """The residual stream's mappings, both sites of `n` layers (the
+        leaves `_hc_in` names; {} where the stream is one row).  NOT the
+        paper's initial values (alpha 0.01, H_res near the identity): there
+        the dynamic term sits under any bfloat16 tolerance and Sinkhorn's
+        input is nearly a permutation, so a program without either would
+        pass every check.  alpha = 1; Phi N(0, 1 / nC), so its product with
+        the normed stream is of unit scale; the biases N(0, 1), H_res's plus
+        2 I; the stream norm's weight spread like every norm a check must
+        see."""
+        m = cfg.hc_mult
+        if m == 1:
+            return {}
+        out = {}
+        for s, site in enumerate(HC_SITES):
+            ks = jax.random.split(jax.random.fold_in(k, s), 3)
+            bias = jax.random.normal(ks[1], (n, 2 * m + m * m), jnp.float32)
+            out.update({
+                f"hc_{site}_phi": norm01(ks[0], (n, m * h, 2 * m + m * m),
+                                         m * h),
+                f"hc_{site}_bias": bias.at[:, 2 * m:].add(
+                    2.0 * jnp.eye(m).reshape(-1)),
+                f"hc_{site}_alpha": jnp.ones((n, 3), jnp.float32),
+                f"hc_{site}_norm": spread(ks[2], (n, m * h)),
+            })
+        return out
+
     keys = jax.random.split(key, 12)
     n_dense = cfg.first_k_dense
     n = cfg.num_layers - n_dense
-    layers = norms(n)
+    layers = {**norms(n), **stream_maps(keys[10], n)}
     if cfg.is_moe:
         E, f = cfg.num_experts, cfg.intermediate_size
         layers["router"] = norm01(keys[2], (n, h, cfg.num_router_experts), h)
@@ -723,11 +759,97 @@ def _init_kind_params(cfg: ModelConfig, key: jax.Array, dtype) -> Params:
     }
     if n_dense:
         params["dense_layers"] = {
-            **norms(n_dense),
+            **norms(n_dense), **stream_maps(keys[11], n_dense),
             **mlp(keys[8], n_dense, cfg.dense_intermediate_size)}
     if not cfg.tie_word_embeddings:
         params["lm_head"] = norm01(keys[9], (h, cfg.vocab_size), h)
     return params
+
+
+# The two sublayers of a layer, each with mappings of its own where the
+# residual stream is widened (`cfg.hc_mult` > 1): leaves `hc_<site>_phi`
+# [nC, n + n + n^2] (ONE matrix: H~_pre | H~_post | H~_res, split after the
+# one product), `hc_<site>_bias` [n + n + n^2], `hc_<site>_alpha` [3] (both
+# float32) and `hc_<site>_norm` [nC].
+HC_SITES = ("attn", "mlp")
+
+
+@partial(jax.jit, static_argnames="cfg")
+def _sinkhorn(logits: jnp.ndarray, cfg: ModelConfig):
+    """[..., n * n] float32 logits (a row's matrix row-major) -> the doubly
+    stochastic matrix as n x n arrays [...], res[i][j]: exp of the clamped
+    logits, then `cfg.hc_sinkhorn_iters` rounds of (each row by its sum +
+    eps; each column by its sum + eps).  All the rounds, unrolled: no early
+    exit.  Entry by entry on purpose: a sum over an axis of 4 is a reduction
+    XLA fuses nothing across (eighty fusions a site on the v5e's compiler);
+    sums of four arrays are elementwise, and the rounds compile to ONE.
+    Jitted so that its 1,300 equations are traced once a shape and lowered
+    as one function the sites call (XLA inlines it): unjitted, six sites a
+    program cost a boot 25 s of tracing."""
+    n = cfg.hc_mult
+    m = jnp.exp(jnp.clip(logits, cfg.hc_res_clamp_min, cfg.hc_res_clamp_max))
+    m = [[m[..., i * n + j] for j in range(n)] for i in range(n)]
+    for _ in range(cfg.hc_sinkhorn_iters):
+        for i in range(n):
+            inv = 1.0 / (sum(m[i]) + cfg.hc_eps)
+            m[i] = [v * inv for v in m[i]]
+        for j in range(n):
+            inv = 1.0 / (sum(m[i][j] for i in range(n)) + cfg.hc_eps)
+            for i in range(n):
+                m[i][j] = m[i][j] * inv
+    return tuple(tuple(row) for row in m)
+
+
+def _hc_rows(h: jnp.ndarray, n: int):
+    """The stream's n rows of a token, float32: h is [B, T, n * C], row j at
+    lanes j * C .. (j + 1) * C (a [.., n, C] array would be tiled with its
+    second-minor axis padded from 4 to 8 or 16 sublanes on the device)."""
+    c = h.shape[-1] // n
+    return [h[..., j * c:(j + 1) * c].astype(jnp.float32) for j in range(n)]
+
+
+def _hc_in(h: jnp.ndarray, lp: Params, site: str, cfg: ModelConfig):
+    """Ahead of a sublayer: (u, maps).  One row a token (`cfg.hc_mult` 1): h
+    itself and None, and not an op traced.  n rows: the site's per-token
+    mappings from the normed stream (`hc_map`: float32, the one product with
+    `hc_<site>_phi` at full precision), u = H_pre X (`hc_mix`) and maps =
+    (H_post [B, T, n], H_res as `_sinkhorn` gives it) for `_hc_out`."""
+    n = cfg.hc_mult
+    if n == 1:
+        return h, None
+    with jax.named_scope("hc_map"):
+        x = h.astype(jnp.float32)
+        x = x * jax.lax.rsqrt(
+            jnp.mean(x * x, axis=-1, keepdims=True) + cfg.rms_norm_eps)
+        x = x * lp[f"hc_{site}_norm"].astype(jnp.float32)
+        t = jnp.einsum("btk,km->btm", x,
+                       lp[f"hc_{site}_phi"].astype(jnp.float32),
+                       precision=jax.lax.Precision.HIGHEST)
+        alpha, bias = lp[f"hc_{site}_alpha"], lp[f"hc_{site}_bias"]
+        pre = jax.nn.sigmoid(alpha[0] * t[..., :n] + bias[:n])
+        post = 2.0 * jax.nn.sigmoid(
+            alpha[1] * t[..., n:2 * n] + bias[n:2 * n])
+        res = _sinkhorn(alpha[2] * t[..., 2 * n:] + bias[2 * n:], cfg)
+    with jax.named_scope("hc_mix"):
+        rows = _hc_rows(h, n)
+        u = sum(pre[..., j, None] * rows[j] for j in range(n)).astype(h.dtype)
+    return u, (post, res)
+
+
+def _hc_out(h: jnp.ndarray, y: jnp.ndarray, maps, scope: str) -> jnp.ndarray:
+    """After a sublayer: one row a token, `h + y` under `scope` (where the
+    add always sat); n rows, X <- H_res X + H_post^T y under `hc_mix`."""
+    if maps is None:
+        with jax.named_scope(scope):
+            return h + y
+    post, res = maps
+    n = post.shape[-1]
+    with jax.named_scope("hc_mix"):
+        rows, y32 = _hc_rows(h, n), y.astype(jnp.float32)
+        return jnp.concatenate(
+            [sum(res[i][j][..., None] * rows[j] for j in range(n))
+             + post[..., i, None] * y32 for i in range(n)],
+            axis=-1).astype(h.dtype)
 
 
 def _attention_block(
@@ -1373,7 +1495,7 @@ def _latent_attention_block(
     dt = x.dtype
     g = cfg.geometry_of(kind)
     r, dn = g.kv_lora_rank, g.qk_nope_head_dim
-    scale = (dn + g.qk_rope_head_dim) ** -0.5
+    scale = cfg.latent_softmax_scale(kind)
     window = cfg.window_of(kind)
     indexed = cfg.has_indexer(kind)
     if mesh is not None and mesh.size > 1:
@@ -2312,7 +2434,7 @@ def forward(
         # one rotary table per kind of layer, built once per forward pass;
         # each layer takes its kind's (a config without a pattern has one)
         lead, period = cfg.pattern
-        if cfg.layer_types:
+        if cfg.layer_types or cfg.rope_by_kind:
             rope = {kind: rope_cos_sin(positions, *kind_frequencies(cfg, kind))
                     for kind in cfg.kinds if kind not in (CONV, DELTA)}
         else:
@@ -2320,6 +2442,10 @@ def forward(
             rope = {GLOBAL: rope_cos_sin(positions, inv_freq)}
         for kind in cfg.unrotated_kinds:
             rope[kind] = (None, None)
+        if cfg.hc_mult > 1:
+            # the widened residual stream: the embedding row in every one of
+            # a token's n rows ([B, S, n * C]: `_hc_rows`)
+            x = jnp.tile(x, (1, 1, cfg.hc_mult))
 
     # Where the routed blocks dispatch by token (moe_dispatch_form: this
     # pass's rows), the expert leaves stay out of what is sliced a layer:
@@ -2350,8 +2476,9 @@ def forward(
         h, kc, vc, tally = carry
         lp, layer, *slot = scanned
         cos, sin = (None, None) if kind in (CONV, DELTA) else rope[kind]
+        u, maps = _hc_in(h, lp, "attn", cfg)
         with jax.named_scope("attn_norm"):
-            attn_in = rms_norm(h, lp["ln_attn"], cfg.rms_norm_eps)
+            attn_in = rms_norm(u, lp["ln_attn"], cfg.rms_norm_eps)
         if kind == DELTA:
             # `layer` counts the linear layers: its place in both state leaves
             attn_out, vc = _delta_attention_block(
@@ -2406,23 +2533,24 @@ def forward(
             ssd_out, vc = _ssd_block(attn_in, lp, cfg, vc, layer, plan)
             with jax.named_scope("ssd_proj"):
                 attn_out = ssd_out + attn_out
-        with jax.named_scope({CONV: "conv_proj", DELTA: "kda_proj"}.get(
-                kind, "attn_out")):
-            h = h + attn_out
+        h = _hc_out(h, attn_out, maps,
+                    {CONV: "conv_proj", DELTA: "kda_proj"}.get(
+                        kind, "attn_out"))
+        u, maps = _hc_in(h, lp, "mlp", cfg)
         with jax.named_scope("mlp_norm"):
-            mlp_in = rms_norm(h, lp["ln_mlp"], cfg.rms_norm_eps)
+            mlp_in = rms_norm(u, lp["ln_mlp"], cfg.rms_norm_eps)
         if routed:
             ffn_out, read = _moe_block(
                 mlp_in, lp, cfg, None if paged is None else paged.chunk_len,
                 sharded, (experts, slot[0]) if slot else None)
             if tally is not None:
                 tally = tally + read
-            with jax.named_scope("moe_experts"):
-                h = h + ffn_out
+            h = _hc_out(h, ffn_out, maps, "moe_experts")
         else:
             with jax.named_scope("mlp"):
-                h = h + _mlp_block(mlp_in, lp,
-                                   multipliers=cfg.mlp_multipliers)
+                ffn_out = _mlp_block(mlp_in, lp,
+                                     multipliers=cfg.mlp_multipliers)
+            h = _hc_out(h, ffn_out, maps, "mlp")
         return (h, kc, vc, tally), None
 
     def at(stacked, i, static: bool):
@@ -2478,7 +2606,8 @@ def forward(
         return carry, None
 
     def before(layer: int, kind: str) -> int:
-        return cfg.layer_types[:layer].count(kind)
+        # (a model without `layer_types` has one kind: every layer is of it)
+        return sum(cfg.kind_of(i) == kind for i in range(layer))
 
     with jax.named_scope("layers"):
         kc, vc = (None, None) if kv_cache is None else kv_cache
@@ -2541,6 +2670,9 @@ def forward(
             # real row is all anybody reads (as models/hybrid.forward)
             last = jnp.clip(plan.lens - 1, 0, x.shape[1] - 1)
             x = jnp.take_along_axis(x, last[:, None, None], axis=1)
+        if cfg.hc_mult > 1:
+            # the stream collapses to the sum of a token's rows
+            x = sum(_hc_rows(x, cfg.hc_mult)).astype(x.dtype)
         logits = _logits_head(x, params, cfg)
     if expert_reads:
         return logits, new_cache, tally

@@ -132,29 +132,67 @@ def convert_hf_state_dict(
 def _convert_latent_state_dict(
     state: Mapping[str, Any], cfg: ModelConfig, dtype: Any
 ) -> Params:
-    """HF `deepseek_v3` names (no query low-rank) -> the latent tree of
-    models/llama._init_lead_tree_params: leading dense layers stacked under
-    "dense_layers", routed ones under "layers".  Rotary columns stay
-    interleaved as published; `forward` de-interleaves them
+    """HF `deepseek_v3` names -> the latent tree: leading dense layers
+    stacked under "dense_layers", routed ones under "layers".  Without a
+    query low-rank, models/llama._init_lead_tree_params' tree (attention
+    leaves in the two stacks).  With one kind of layer past that block
+    (`cfg.by_kind`: a query low-rank `q_a_proj` / `q_a_layernorm` /
+    `q_b_proj`, a widened residual stream's `hc_*` leaves a site),
+    _init_kind_params' tree: the attention leaves of ALL layers in layer
+    order under "attn".  A multi-token-prediction module's keys (`mtp.*`,
+    layers past `num_hidden_layers`) are never asked for, which is how they
+    are dropped (`cfg.nextn_predict_layers`: recorded, not built).  Rotary
+    columns stay interleaved as published; `forward` de-interleaves them
     (`cfg.rope_interleave`)."""
     h, hq, r = cfg.hidden_size, cfg.num_heads, cfg.kv_lora_rank
     dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    if cfg.by_kind and (cfg.layer_types or cfg.index_topk
+                        or cfg.attention_gate or cfg.latent_rescale
+                        or cfg.windowed_latent):
+        raise NotImplementedError(
+            "no checkpoint converter for a latent model with kinds of "
+            "layer, an indexer, a gate or a rescale (`dots3_note`): its "
+            "tree is models/llama._init_kind_params', served on seeded "
+            "weights")
 
     get = _getter(state)
 
     t = lambda w: w.T  # noqa: E731  [out, in] -> [in, out]
-    attention = {
+    norms = {
         "ln_attn": ("input_layernorm.weight", None),
         "ln_mlp": ("post_attention_layernorm.weight", None),
+    }
+    mixer = {
         "ln_kv": ("self_attn.kv_a_layernorm.weight", None),
-        "wq": ("self_attn.q_proj.weight",
-               lambda w: w.T.reshape(h, hq, dn + dr)),
         "wkva": ("self_attn.kv_a_proj_with_mqa.weight", t),
         "wkvb": ("self_attn.kv_b_proj.weight",
                  lambda w: w.reshape(hq, dn + dv, r).transpose(0, 2, 1)),
         "wo": ("self_attn.o_proj.weight",
                lambda w: w.T.reshape(hq, dv, h)),
     }
+    if cfg.q_lora_rank:
+        rq = cfg.q_lora_rank
+        mixer.update({
+            "wqa": ("self_attn.q_a_proj.weight", t),
+            "ln_q": ("self_attn.q_a_layernorm.weight", None),
+            "wqb": ("self_attn.q_b_proj.weight",
+                    lambda w: w.T.reshape(rq, hq, dn + dr)),
+        })
+    else:
+        mixer["wq"] = ("self_attn.q_proj.weight",
+                       lambda w: w.T.reshape(h, hq, dn + dr))
+    # a widened residual stream's leaves a site (published names ASSUMED: no
+    # checkpoint of such a model is at hand; they follow the `hc_*` config
+    # keys); Phi's three maps' rows pre | post | res; the float32 leaves apart
+    stream, stream32 = {}, {}
+    if cfg.hc_mult > 1:
+        for site, hf_site in (("attn", "attn_hc"), ("mlp", "mlp_hc")):
+            stream[f"hc_{site}_phi"] = (f"{hf_site}.hc_fn.weight", t)
+            stream[f"hc_{site}_norm"] = (f"{hf_site}.hc_norm.weight", None)
+            stream32[f"hc_{site}_bias"] = (f"{hf_site}.hc_base", None)
+            stream32[f"hc_{site}_alpha"] = (f"{hf_site}.hc_scale", None)
+    # (the lead tree keeps the mixer's leaves in the two stacks)
+    attention = {**norms, **stream, **({} if cfg.by_kind else mixer)}
 
     def mlp(prefix: str, names=("wg", "wu", "wd")) -> dict:
         return {names[0]: (f"{prefix}.gate_proj.weight", t),
@@ -185,14 +223,19 @@ def _convert_latent_state_dict(
                 routed_ids))
     else:
         layers = stack({**attention, **mlp("mlp")}, routed_ids)
+    layers.update(stack(stream32, routed_ids, jnp.float32))
     params: Params = {
         "embed": jnp.asarray(get("embed_tokens.weight"), dtype),
         "final_norm": jnp.asarray(get("norm.weight"), dtype),
         "layers": layers,
     }
+    if cfg.by_kind:
+        (kind,) = cfg.kinds
+        params["attn"] = {kind: stack(mixer, range(cfg.num_layers))}
     if n_dense:
-        params["dense_layers"] = stack({**attention, **mlp("mlp")},
-                                       range(n_dense))
+        params["dense_layers"] = {
+            **stack({**attention, **mlp("mlp")}, range(n_dense)),
+            **stack(stream32, range(n_dense), jnp.float32)}
     if not cfg.tie_word_embeddings:
         params["lm_head"] = jnp.asarray(
             _to_numpy(state["lm_head.weight"]).T, dtype)

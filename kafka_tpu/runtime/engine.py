@@ -832,6 +832,12 @@ class InferenceEngine:
                 f"speculative_k = {self.ecfg.speculative_k}: the model's "
                 "multi-token-prediction module is recorded and not built, so "
                 "no draft would come from it; set speculative_k=0")
+        if cfg.hc_mult > 1 and mesh is not None and mesh.size > 1:
+            raise UnsupportedConfigError(
+                f"hc_mult = {cfg.hc_mult} on a mesh of {mesh.size} devices "
+                "(tp / ep / pp / sp): the widened residual stream and its "
+                "per-token mappings are built on one device; serve each "
+                "replica on one device (dp)")
         if cfg.lead_tree and not cfg.is_latent:
             # models/llama._init_lead_tree_params' tree on grouped-query
             # attention: parallel/sharding.param_specs has no rule for it
@@ -1500,6 +1506,10 @@ class InferenceEngine:
         # StepPrograms.ssd_chunk_trips / ssd_state_bytes
         self.ssd_chunk_trips = 0
         self.ssd_state_bytes = 0
+        # Rows x mapping sites of a widened residual stream (two a layer)
+        # the dispatched launches ran, padding included; 0 with one row
+        self.hc_site_rows = 0
+        self._hc_sites = 2 * cfg.num_layers if cfg.hc_mult > 1 else 0
         # Monotonic, and all 0 for a model with no routed block
         # (StepPrograms.moe_dispatch): step programs dispatched by the form
         # their routed blocks take, and the rows those blocks were handed
@@ -1693,6 +1703,14 @@ class InferenceEngine:
             kw["replica"] = self.replica
         return kw
 
+    def _pass_attrs(self, **kw) -> Dict[str, Any]:
+        """Attrs of a span over forward passes (engine.prefill,
+        engine.decode); a model with a widened residual stream says how many
+        rows a token."""
+        if self.cfg.hc_mult > 1:
+            kw["residual_streams"] = self.cfg.hc_mult
+        return self._tattrs(**kw)
+
     def _prefill_attrs(self, req: "GenRequest", **kw) -> Dict[str, Any]:
         """engine.prefill span attrs: prompt size plus the radix-cache
         share (cached_tokens / cache_source: own-thread vs cross-thread)
@@ -1710,7 +1728,7 @@ class InferenceEngine:
             # a model with a recurrent state: the snapshot slot that was
             # copied into the lane's slot ahead of this prefill
             kw["state_snapshot"] = req.state_restored
-        return self._tattrs(**kw)
+        return self._pass_attrs(**kw)
 
     def _dispatch_scope(self, kind: str,
                         members: Sequence[Optional["GenRequest"]],
@@ -2903,7 +2921,7 @@ class InferenceEngine:
                         or now_mono)
                 record_span(
                     req.trace, "engine.decode", now_mono - prev,
-                    attrs=self._tattrs(steps=1, proposed=cl, accepted=m),
+                    attrs=self._pass_attrs(steps=1, proposed=cl, accepted=m),
                 )
                 req.trace_last_t = now_mono
             for j in range(emit):
@@ -4340,7 +4358,7 @@ class InferenceEngine:
                     req.trace, "engine.decode",
                     now_mono - (req.trace_last_t or req.t_first_dispatch
                                 or now_mono),
-                    attrs=self._tattrs(steps=1, busy=busy),
+                    attrs=self._pass_attrs(steps=1, busy=busy),
                 )
                 req.trace_last_t = now_mono
         entry = _Fetch(
@@ -4526,6 +4544,7 @@ class InferenceEngine:
             len(spans), bucket)
         self.ssd_chunk_trips += self._programs.ssd_chunk_trips(
             len(spans), bucket)
+        self.hc_site_rows += self._hc_sites * width * bucket
         self._count_moe_dispatch(width * bucket)
 
     def _count_moe_dispatch(self, rows: int, passes: int = 1) -> None:
@@ -4566,6 +4585,7 @@ class InferenceEngine:
         if self.cfg.ssd_heads:
             self.ssd_state_bytes += self._programs.ssd_state_bytes(
                 len(seqs), steps)
+        self.hc_site_rows += self._hc_sites * len(members) * steps
         self._count_moe_dispatch(len(members), steps)
         if self.cfg.index_topk:
             scored, kept = self._programs.index_keys(
@@ -4598,7 +4618,7 @@ class InferenceEngine:
                         or now_mono)
                 record_span(
                     req.trace, "engine.decode", now_mono - prev,
-                    attrs=self._tattrs(steps=steps, busy=busy),
+                    attrs=self._pass_attrs(steps=steps, busy=busy),
                 )
                 req.trace_last_t = now_mono
             items.append(req)

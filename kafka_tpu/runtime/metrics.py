@@ -453,6 +453,11 @@ METRICS: Tuple[Metric, ...] = (
         _m("delta_state_bytes", read="delta_state_bytes"),
         _m("ssd_chunk_trips", read="ssd_chunk_trips"),
         _m("ssd_state_bytes", read="ssd_state_bytes"),
+        # rows of the residual stream a token (1: the plain residual), and
+        # rows x mapping sites the dispatched launches ran, padding included
+        # (0 for a model with one row)
+        _m("residual_streams", kind=GAUGE, merge=None, read="cfg.hc_mult"),
+        _m("hc_site_rows", read="hc_site_rows"),
         _m("moe_dispatch", read="moe_dispatch"),
         _m("moe_experts_read", "kafka_tpu_engine_moe_experts_total{kind=read}",
            "Held experts over the routed layers of the decode passes: those "

@@ -420,6 +420,14 @@ def _lead_tree_weight_bytes(cfg: ModelConfig, mat, wb: int) -> int:
     total = (sum(cfg.layers_of(kind) * attn(kind) for kind in cfg.kinds)
              + (cfg.num_layers - cfg.first_k_dense) * ffn
              + cfg.first_k_dense * mlp(cfg.dense_intermediate_size))
+    if cfg.hc_mult > 1:
+        # a widened residual stream's mappings, two sites a layer: Phi
+        # [nC, n + n + n^2] and the stream norm's weight; the bias and the
+        # three scalars are float32 (models/llama.HC_SITES)
+        n = cfg.hc_mult
+        maps = 2 * n + n * n
+        total += 2 * cfg.num_layers * (mat(n * h, maps, 1) + n * h * wb
+                                       + (maps + 3) * 4)
     total += 2 * cfg.vocab_size * h * wb if not cfg.tie_word_embeddings \
         else cfg.vocab_size * h * wb
     return total + h * wb
@@ -505,6 +513,12 @@ def activation_bytes_estimate(
     elif cfg.has_state:
         # a short convolution's [B | C | u] and its float32 products
         prefill += s_local * H * (3 * 2 + 2 * 4)
+    if cfg.hc_mult > 1:
+        # a widened residual stream: the carry's n rows a token beside the
+        # one the trio counts, as much again for a mix's result, and the
+        # mappings' float32 normed copy of all n
+        prefill += s_local * H * ((2 * cfg.hc_mult - 1) * 2
+                                  + cfg.hc_mult * 4)
     decode = max_batch * V * 4 * 3 + max_batch * window * kv_row * 2
     return max(prefill, decode)
 

@@ -243,6 +243,14 @@ DEVICE_SCOPES = (
     "ssd_scan",     # the recurrence: the chunked Pallas kernel at s > 1, the
                     # step kernel in decode (the state updated in place in
                     # its slot), or the row-by-row XLA scan
+    # a widened residual stream (models/llama._hc_in / _hc_out; `hc_mult` >
+    # 1 only: with one row the adds stay where they sat, under attn_out / mlp
+    # / moe_experts).  The widening sits under embed, the collapse under head
+    "hc_map",       # a sublayer's per-token mappings: the norm over all n
+                    # rows, the one product with Phi, the sigmoids, the
+                    # clamp, exp and every Sinkhorn round
+    "hc_mix",       # the mixes: H_pre X ahead of the sublayer, H_res X +
+                    # H_post^T y after it (the residual add of such a model)
     "head",         # final RMSNorm + logits
     "sample",       # last-position select, per-(seed, position) keys,
                     # sample_tokens_per_slot (engine step programs)

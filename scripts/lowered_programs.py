@@ -8,8 +8,8 @@
     python scripts/lowered_programs.py diff A B
 
 `dump` drives an engine per tiny preset (`tiny-gqa`, `tiny-moe`, the tiny
-Mellum2, Kanana-2, dots3, Phi-4-flash, K-EXAONE, LFM2, Solar-Open2 and
-Falcon-H1 under
+Mellum2, Kanana-2, dots3, Phi-4-flash, K-EXAONE, LFM2, Solar-Open2,
+Falcon-H1 and Xing4.0 under
 benchmarks/tests/)
 and attention backend (`xla`,
 `pallas`, which lowers in interpret mode off the chip) through single and
@@ -66,6 +66,8 @@ def _presets():
         "benchmarks/tests/solar_open2/configs/tiny-solaropen2.json")
     falcon = config_from_hf_json(
         "benchmarks/tests/falcon_h1/configs/tiny-falconh1.json")
+    xing4 = config_from_hf_json(
+        "benchmarks/tests/xing4/configs/tiny-xing4.json")
     for name, cfg in (("tiny-gqa", get_config("tiny-gqa")),
                       ("tiny-moe", get_config("tiny-moe")),
                       ("tiny-mellum2", mellum),
@@ -75,7 +77,8 @@ def _presets():
                       ("tiny-kexaone", kexaone),
                       ("tiny-lfm2moe", lfm2moe),
                       ("tiny-solaropen2", solar),
-                      ("tiny-falconh1", falcon)):
+                      ("tiny-falconh1", falcon),
+                      ("tiny-xing4", xing4)):
         for backend in ("xla", "pallas"):
             yield name, backend, dataclasses.replace(
                 cfg, attention_backend=backend)

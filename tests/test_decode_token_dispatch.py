@@ -245,6 +245,8 @@ UNREAD = {
     ("mellum2-12b-a2.5b", 16, "decode"),
     ("k-exaone-236b-a23b", 32, "decode"),
     ("solar-open2-250b", 32, "decode"), ("solar-open2-250b", 64, "prefill"),
+    # (PR 56: 128 picks over 64 experts leave an expected 13% unread)
+    ("xing4.0-29b-a4b", 32, "decode"),
 }
 
 
