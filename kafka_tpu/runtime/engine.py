@@ -1391,8 +1391,10 @@ class InferenceEngine:
             pool = (jax.tree.leaves(self.k_pool)[0] if cfg.by_kind
                     else self.k_pool)
             kv_b = int(getattr(pool.dtype, "itemsize", 2))
+            # (self.cfg: the price follows the backend the engine RESOLVED,
+            # which the constructor's argument does not name)
             self._cost_model = dispatch_cost_model(
-                cfg,
+                self.cfg,
                 n_devices=n_dev,
                 weight_bytes_total=_param_bytes(params),
                 kv_dtype_bytes=kv_b,

@@ -657,12 +657,21 @@ class DispatchCostModel:
     # What ONE prefill launch pays, by what pays it (prefill_launch_cost):
     # the products every dispatched row takes part in, those a lane's last
     # real row alone does, one (token, expert) pick through every routed
-    # layer, and the attention pairs by kind of layer.
+    # layer, the attention pairs by kind of layer, and what a latent launch's
+    # walk expands whatever its rows hold.
     row_flops: float = 0.0
     lane_flops: float = 0.0
     pick_flops: float = 0.0
     attn_kinds: Tuple[Tuple[float, Optional[int]], ...] = ()  # (a pair, window)
-    pad_rows_attend: bool = False  # XLA prefill: rows past chunk_len attend
+    # The rows of a launch that attend: 0 = those that hold a token (flash
+    # prefill skips the q blocks of the others), else every bucket row,
+    # rounded up to this many (1: XLA prefill and the latent walk's XLA
+    # fold; 128: latent_prefill_fold's whole lane tiles).
+    attend_row_tile: int = 0
+    # (a context key through W_kvb in every layer of the kind, window), and
+    # the keys a trip of the walk reads: latent models alone
+    walk_kinds: Tuple[Tuple[float, Optional[int]], ...] = ()
+    walk_chunk_keys: int = 1
     # (experts held, experts the router knows, picks a token, sharded or
     # int8: models/llama.moe_dispatch_form's arguments); None = no router
     moe: Optional[Tuple[int, int, int, bool, bool]] = None
@@ -700,20 +709,31 @@ class DispatchCostModel:
         which the first `tokens` hold a token, from position `start`: what
         the chunk plan prices (prefill_launches).  Rows that hold no token
         still take part in every dense product (projections, dense and
-        shared-expert MLPs, a state model's row-wise half); they attend
-        nothing on the Pallas path (the flash-prefill kernel skips their q
-        blocks) and enter no expert group under token dispatch, which the
-        routed block takes by models/llama.moe_dispatch_form's rule: below
-        it every held expert multiplies every row.  A hybrid decoder's
-        second half and the head run on each lane's last real row.  The
-        weights are read once: every routed expert under dense dispatch,
-        under token dispatch those some token picked."""
+        shared-expert MLPs, a state model's row-wise half).  Whether they
+        attend is the path's (`attend_row_tile`): in XLA prefill every
+        bucket row attends the context, masked or not; the flash-prefill
+        kernel (GQA on Pallas) skips the q blocks that hold no token; the
+        latent walk folds every bucket row against every chunk it walks, and
+        its kernel pads the bucket to whole lane tiles of 128 first, so a
+        64-row launch folds 128.  A latent launch also pays its WALK, which
+        no row count changes (`walk_kinds`): every trip expands the chunk's
+        latent rows through W_kvb for all heads, over the whole context in a
+        full layer and from the chunk that holds the window's first key in a
+        sliding one.  Rows that hold no token enter no expert group under
+        token dispatch, which the routed block takes by
+        models/llama.moe_dispatch_form's rule: below it every held expert
+        multiplies every row.  A hybrid decoder's second half and the head
+        run on each lane's last real row.  The weights are read once: every
+        routed expert under dense dispatch, under token dispatch those some
+        token picked."""
         flops = rows * self.row_flops + self.lane_flops
-        q = rows if self.pad_rows_attend else tokens
+        tile = self.attend_row_tile
+        q = rows + -rows % tile if tile else tokens
         for pair_flops, window in self.attn_kinds:
             keys = start + q / 2
             flops += q * (keys if window is None else min(keys, window)) \
                 * pair_flops
+        flops += self.walk_flops(tokens, start)
         bytes_ = (self.launch_bytes
                   + (start + 2 * tokens) * self.kv_bytes_per_token)
         if self.moe is not None:
@@ -730,13 +750,33 @@ class DispatchCostModel:
                     1.0 - (1.0 - top_k / routed) ** tokens)
         return flops, bytes_
 
+    def walk_flops(self, tokens: int, start: int) -> float:
+        """The flops of a latent prefill launch's walk (0 for a model that
+        is not latent): every context key a layer's trips read, from key 0
+        in a full layer and from the chunk that holds the first query's
+        window in a sliding one (at most the window and one chunk more),
+        through W_kvb.  The rows of the bucket do not enter."""
+        flops = 0.0
+        for key_flops, window in self.walk_kinds:
+            first = 0 if window is None else (
+                max(start - window + 1, 0)
+                // self.walk_chunk_keys * self.walk_chunk_keys)
+            flops += (start + tokens - first) * key_flops
+        return flops
+
     def launch_price(self, peak_flops: float, peak_hbm_bps: float
                      ) -> Callable[[int, int, int], float]:
         """price(rows, tokens, start): the modeled seconds of one prefill
-        launch on a chip of these peaks, the slower of its two bounds."""
+        launch on a chip of these peaks: the slower of its two bounds, and
+        the walk in series.  A launch has two costs that no row count
+        changes, the weights it reads and the context it walks; one is
+        bound by HBM in the MLP's ops and one by the MXU in attention's, so
+        a small launch pays both, not the larger."""
         def price(rows: int, tokens: int, start: int) -> float:
             flops, bytes_ = self.prefill_launch_cost(rows, tokens, start)
-            return max(flops / peak_flops, bytes_ / peak_hbm_bps)
+            walk = self.walk_flops(tokens, start)
+            return (max((flops - walk) / peak_flops, bytes_ / peak_hbm_bps)
+                    + walk / peak_flops)
         return price
 
     def verify_cost(self, query_tokens: int, kv_tokens: int,
@@ -818,6 +858,28 @@ def dispatch_cost_model(
             heads, width = cfg.num_heads, 2 * cfg.head_dim
         return 2.0 * cfg.layers_of(kind) * heads * width / n
 
+    def key_flops(kind: str) -> float:
+        """One context key through W_kvb (every head's k_nope and v from the
+        latent row) in every layer of `kind`: what a trip of
+        models/llama._latent_prefill_walk expands, whoever attends it."""
+        g = cfg.geometry_of(kind)
+        return (2.0 * cfg.layers_of(kind) * g.kv_lora_rank * g.num_heads
+                * (g.qk_nope_head_dim + g.v_head_dim) / n)
+
+    # The rows of a launch that attend, by the path the layer body takes
+    # (DispatchCostModel.attend_row_tile).  The latent walk folds the whole
+    # bucket, in its kernel padded to lane tiles; flash prefill, which skips
+    # the q blocks past the last token, runs for GQA on Pallas on one device
+    # over a pool that is not int8 (one byte a value: that one prefills
+    # through the dequantizing XLA gather, as a mesh does); every other
+    # prefill is XLA's over all bucket rows.
+    from ..models.llama import PREFILL_WALK_KEYS
+
+    kernels = cfg.attention_backend == "pallas"
+    if cfg.is_latent:
+        attend_row_tile = 128 if kernels else 1
+    else:
+        attend_row_tile = 0 if kernels and n == 1 and kv_dtype_bytes > 1 else 1
     return DispatchCostModel(
         flops_per_token=2.0 * params_total / n,
         # (a model with a state: the layers that attend, over rows of their
@@ -834,7 +896,10 @@ def dispatch_cost_model(
         attn_kinds=tuple((pair_flops(kind), cfg.window_of(kind))
                          for kind in cfg.kinds
                          if cfg.is_latent or holds_rows(kind)),
-        pad_rows_attend=cfg.attention_backend != "pallas",
+        attend_row_tile=attend_row_tile,
+        walk_kinds=tuple((key_flops(kind), cfg.window_of(kind))
+                         for kind in cfg.kinds) if cfg.is_latent else (),
+        walk_chunk_keys=PREFILL_WALK_KEYS,
         moe=((cfg.num_experts, cfg.num_router_experts,
               cfg.num_experts_per_tok, n > 1, int8_experts)
              if cfg.is_moe else None),
