@@ -251,7 +251,7 @@ class ModelConfig:
     # the model); the engine refuses speculative decoding for such a model.
     nextn_predict_layers: int = 0
     # -- a widened residual stream (`xing4_0`; `_hc_in` / `_hc_out` in
-    # models/llama.py; "mHC: Manifold-Constrained Hyper-Connections"):
+    # models/residual.py; "mHC: Manifold-Constrained Hyper-Connections"):
     # `hc_mult` = n > 1 turns it on.  A token's state is n rows of
     # hidden_size; every sublayer reads one row mixed from them by a
     # per-token H_pre (sigmoid) and writes back H_res X + H_post^T y, H_post
@@ -276,7 +276,7 @@ class ModelConfig:
     mamba_expand: int = 2
     mamba_dt_rank: int = 0
     # -- the second hybrid layout (`lfm2_moe`; the conv mixer in
-    # models/llama.py): `conv_L_cache` > 0 turns it on and `layer_types` then
+    # mixers/state.py): `conv_L_cache` > 0 turns it on and `layer_types` then
     # names CONV layers beside GLOBAL ones, in any order.  A CONV layer is a
     # gated short convolution: [B | C | u] = x W_in, a causal depthwise conv
     # of conv_L_cache taps over B * u, times C, through W_out.  Its
@@ -286,7 +286,7 @@ class ModelConfig:
     # tree's. --
     conv_L_cache: int = 0
     # -- the third hybrid layout (`solar_open2`; `_delta_attention_block` in
-    # models/llama.py): `delta_heads` > 0 turns it on and `layer_types` then
+    # mixers/state.py): `delta_heads` > 0 turns it on and `layer_types` then
     # names DELTA layers beside GLOBAL ones, in any order.  A DELTA layer is
     # the gated delta rule with a decay per key channel (Kimi Delta
     # Attention): delta_heads heads of delta_head_dim keys and values, q / k /
@@ -300,7 +300,7 @@ class ModelConfig:
     delta_head_dim: int = 0
     delta_conv_kernel: int = 4
     delta_neg_eigval: bool = False
-    # -- the parallel layout (`falcon_h1`; `_ssd_block` in models/llama.py):
+    # -- the parallel layout (`falcon_h1`; `_ssd_block` in mixers/state.py):
     # `ssd_heads` > 0 turns it on and every layer is then PARALLEL:
     # grouped-query attention and a Mamba-2 (SSD) mixer read ONE normed input
     # and both add into the residual.  The mixer has ssd_heads heads of
@@ -665,6 +665,12 @@ class ModelConfig:
     def is_windowed(self) -> bool:
         return WINDOWED in self.layer_types
 
+    def mixer_of(self, kind: str) -> str:
+        """Which mixer a layer of `kind` takes: the key of its row in
+        `models/mixers.MIXERS`."""
+        return {CONV: "conv", DELTA: "delta", PARALLEL: "ssd"}.get(
+            kind, "latent" if self.is_latent else "gqa")
+
     def window_of(self, kind: str) -> Optional[int]:
         return self.sliding_window if kind == WINDOWED else None
 
@@ -682,7 +688,7 @@ class ModelConfig:
     @property
     def lead_tree(self) -> bool:
         """The parameter tree is the lead-and-routed one ("dense_layers"
-        beside "layers", a selection bias, a shared branch: models/llama.py
+        beside "layers", a selection bias, a shared branch: init_params.py's
         `_init_lead_tree_params`, per kind `_init_kind_params`), not the
         homogeneous stack of `init_params`."""
         return bool(self.is_latent or self.first_k_dense

@@ -1,11 +1,11 @@
-"""What every decoder with a per-thread recurrent state shares (`StatePlan`,
-the slot read and write, `HybridPathError`), and ONE of the two such decoders
-whole: `phi4flash` (Phi-4-mini-flash-reasoning), state-space mixers whose
-per-thread state lives in a STATE SLOT beside the pages, sliding and full
-differential attention with K/V rows of their own, and a second half of gated
-memory units and cross attention that reads ONE full cache.  The other,
-`lfm2_moe`'s conv layout, is the lead-and-routed tree of models/llama.py with
-its mixers chosen by kind; it imports the state plan from here.
+"""ONE decoder with a per-thread recurrent state, whole: `phi4flash`
+(Phi-4-mini-flash-reasoning), state-space mixers whose per-thread state lives
+in a STATE SLOT beside the pages, sliding and full differential attention
+with K/V rows of their own, and a second half of gated memory units and cross
+attention that reads ONE full cache.  What every such decoder shares
+(`StatePlan`, the slot read and write, `HybridPathError`) is models/cache.py's;
+the others (the conv, linear-attention and parallel layouts) run through
+models/llama.forward with their mixers taken from its table.
 
 Layout (`ModelConfig._check_hybrid`): n x [mamba, sliding attention], then
 [mamba, full attention], then m x [gmu, cross attention]; every layer is
@@ -49,40 +49,22 @@ from __future__ import annotations
 
 from contextlib import nullcontext
 from functools import partial
-from typing import Any, Dict, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
 
+from ..ops.pallas.selective_scan import selective_scan
+from .cache import (
+    HybridPathError, KVCache, StatePlan, _flat_pool, _kv_read_pages,
+    _kv_write, _layer_view, _read_state, _stacked_pool, _write_state,
+)
 from .config import CROSS, GLOBAL, WINDOWED, ModelConfig
+from .ffn import _mlp_block
+from .quant import Params
 
-Params = Dict[str, Any]
+# (benchmarks/ imports `StatePlan` from here and swaps `_write_state` here)
 
 NEG_INF = -1e30
-
-
-class HybridPathError(NotImplementedError):
-    """A path that cannot carry a recurrent state (or has no differential
-    form) was reached by a decoder with a state.  The engine refuses such options by
-    name when it is built (runtime/engine.py RecurrentStateUnsupported); this
-    is the backstop for direct callers of `forward`."""
-
-
-class StatePlan(NamedTuple):
-    """Which state slot each lane of a pass reads and writes.
-
-    src / dst / snap: [B] int32 slot ids, or all None for decode, where lane
-    i's slot is slot i.  A lane's incoming state is `src` (zeros where
-    `fresh`), its outgoing state goes to `dst` AND to `snap` (a snapshot the
-    prefix cache may keep; the engine's trash slot when none is wanted).
-    lens: [B] int32, the pass's real rows a lane (0 = the lane is inactive:
-    its state passes through untouched)."""
-
-    lens: jnp.ndarray
-    src: Optional[jnp.ndarray] = None
-    dst: Optional[jnp.ndarray] = None
-    snap: Optional[jnp.ndarray] = None
-    fresh: Optional[jnp.ndarray] = None
 
 
 def lambda_init(layer) -> jnp.ndarray:
@@ -214,8 +196,6 @@ def _mamba_block(u, lp, cfg: ModelConfig, conv0, h0, lens):
     scan's output WITH its D * x term and ahead of the gate, conv', h'): the
     state after each lane's last real row (`lens`; a lane of 0 rows returns
     its state as it came)."""
-    from ..ops.pallas.selective_scan import selective_scan
-
     dt_ = u.dtype
     f32 = jnp.float32
     di, ds, r = cfg.mamba_d_inner, cfg.mamba_d_state, cfg.mamba_dt_rank
@@ -340,8 +320,6 @@ def _attend(q, k_rows, v_rows, cfg: ModelConfig, positions, paged, window,
             q[0], k_rows, v_rows, paged.page_table[0], paged.start,
             paged.chunk_len, page_size=paged.page_size, interpret=interp,
             window=window, diff=True)[None]
-    from .llama import _kv_read_pages
-
     hkv = cfg.num_kv_heads
     k_win = _kv_read_pages(k_rows, paged.page_table, paged.page_size,
                            q.dtype).reshape(b, -1, hkv, d)
@@ -369,8 +347,6 @@ def _self_attention(u, lp, cfg, positions, layer, pool_layer, k_pool, v_rows,
     """A differential attention layer with K/V of its own.  Paged: its rows
     are written into layer `pool_layer` of the stacked pools (returned);
     uncached: returns its own k, v in their place."""
-    from .llama import _flat_pool, _kv_write, _layer_view, _stacked_pool
-
     dt_ = u.dtype
     with jax.named_scope("attn_qkv"):
         q = jnp.einsum("bsh,hnd->bsnd", u, lp["wq"].astype(dt_)) + lp["bq"].astype(dt_)
@@ -403,8 +379,6 @@ def _cross_attention(u, lp, cfg, positions, layer, pool_layer, k_pool, v_rows,
     """Differential attention of q = W_q u over the FULL layer's rows (layer
     `pool_layer` of the pools; uncached: that layer's own k, v).  Writes
     nothing."""
-    from .llama import _flat_pool, _layer_view
-
     dt_ = u.dtype
     with jax.named_scope("attn_qkv"):
         q = jnp.einsum("bsh,hnd->bsnd", u, lp["wq"].astype(dt_)) + lp["bq"].astype(dt_)
@@ -433,33 +407,6 @@ def _at(stacked, i):
         stacked)
 
 
-def _read_state(leaf, layer, plan: StatePlan, batch: int):
-    """Lanes' incoming state of state layer `layer`, [B, ...] float32, read
-    where it lies in the stacked leaf [n, n_slots, ...] (no layer's slots are
-    sliced out: the leaf is the layer scan's carry)."""
-    if plan.src is None:
-        return jax.lax.dynamic_slice(
-            leaf, (layer, 0, 0, 0), (1, batch) + leaf.shape[2:])[0]
-    rows = leaf[layer, plan.src]
-    if plan.fresh is not None:
-        # (an inactive lane writes back what it read: not zeros)
-        fresh = plan.fresh & (plan.lens > 0)
-        rows = jnp.where(fresh[:, None, None], 0.0, rows)
-    return rows
-
-
-def _write_state(leaf, layer, plan: StatePlan, new, old):
-    """`leaf` with the lanes' outgoing state of state layer `layer` written
-    (an inactive lane writes back what it read)."""
-    new = jnp.where((plan.lens > 0)[:, None, None], new, old).astype(leaf.dtype)
-    if plan.dst is None:
-        return jax.lax.dynamic_update_slice(leaf, new[None], (layer, 0, 0, 0))
-    leaf = leaf.at[layer, plan.dst].set(new)
-    if plan.snap is not None:
-        leaf = leaf.at[layer, plan.snap].set(new)
-    return leaf
-
-
 def forward(params: Params, cfg: ModelConfig, token_ids, positions,
             kv_cache=None, paged=None):
     """The hybrid decoder.  token_ids, positions [B, S].
@@ -469,8 +416,6 @@ def forward(params: Params, cfg: ModelConfig, token_ids, positions,
     in `v_pool`, `paged.state` a StatePlan): logits [B, S, V] at s == 1,
     [B, 1, V] (each lane's last real row) at s > 1.  Returns (logits f32,
     the new KVCache or None)."""
-    from .llama import KVCache, _mlp_block
-
     eps = cfg.rms_norm_eps
     n_m = cfg.state_layers      # mamba layers = attention layers w/ K/V
     n_x = cfg.layers_of(CROSS)  # cross layers = gated memory units

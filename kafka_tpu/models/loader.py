@@ -16,7 +16,6 @@ leading [L, ...] axis for `lax.scan` (see models/llama.py).
 from __future__ import annotations
 
 import glob
-import json
 import os
 from typing import Any, Callable, Dict, Mapping, Optional
 
@@ -64,7 +63,7 @@ def convert_hf_state_dict(
         raise NotImplementedError(
             "no checkpoint converter for a grouped-query model with a dense "
             "lead, shared experts or QK-norm (`exaone_moe`): its tree is "
-            "models/llama._init_lead_tree_params', served on seeded weights")
+            "models/init_params._init_lead_tree_params', served on seeded weights")
     h, d = cfg.hidden_size, cfg.head_dim
     hq, hkv, L = cfg.num_heads, cfg.num_kv_heads, cfg.num_layers
 
@@ -134,7 +133,7 @@ def _convert_latent_state_dict(
 ) -> Params:
     """HF `deepseek_v3` names -> the latent tree: leading dense layers
     stacked under "dense_layers", routed ones under "layers".  Without a
-    query low-rank, models/llama._init_lead_tree_params' tree (attention
+    query low-rank, models/init_params._init_lead_tree_params' tree (attention
     leaves in the two stacks).  With one kind of layer past that block
     (`cfg.by_kind`: a query low-rank `q_a_proj` / `q_a_layernorm` /
     `q_b_proj`, a widened residual stream's `hc_*` leaves a site),
@@ -152,7 +151,7 @@ def _convert_latent_state_dict(
         raise NotImplementedError(
             "no checkpoint converter for a latent model with kinds of "
             "layer, an indexer, a gate or a rescale (`dots3_note`): its "
-            "tree is models/llama._init_kind_params', served on seeded "
+            "tree is models/init_params._init_kind_params', served on seeded "
             "weights")
 
     get = _getter(state)

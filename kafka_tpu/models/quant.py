@@ -10,7 +10,7 @@ Scheme: symmetric per-output-channel int8.  Each quantized leaf becomes a
 `QTensor(q=int8, s=bf16 scale)` where the scale broadcasts over the
 contraction axis, so `q.astype(bf16) * s` reconstructs the weight.  The
 dequantize runs INSIDE the jitted step at each use site
-(models/llama.py:_w): XLA fuses the convert+multiply into the matmul's
+(`_w` below): XLA fuses the convert+multiply into the matmul's
 operand read, so HBM traffic stays int8-sized and the MXU still sees bf16
 operands — the standard weight-only serving pattern on TPU.  Activations,
 norms, the MoE router, and the KV cache are untouched.
@@ -82,7 +82,14 @@ def dequantize(w: Any, dtype) -> jnp.ndarray:
     return w
 
 
-# Contraction axes per layer-stacked weight (models/llama.py layouts).
+def _w(lp: Params, name: str, dtype) -> jnp.ndarray:
+    """Fetch a weight, dequantizing int8 QTensors in-graph (XLA fuses the
+    convert into the matmul's operand read, keeping HBM traffic
+    int8-sized)."""
+    return dequantize(lp[name], dtype)
+
+
+# Contraction axes per layer-stacked weight (models/init_params.py layouts).
 # Axis 0 is the layer stack; scales are per (layer, output-channel).
 _CONTRACT = {
     "wq": (1,),        # [L, H, hq, d]   contract H

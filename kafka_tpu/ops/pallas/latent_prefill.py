@@ -1,6 +1,6 @@
 """The fold of one key chunk of latent prefill's walk, tile by tile in VMEM.
 
-`models/llama.py::_latent_prefill_walk` folds a chunk of expanded keys and
+`models/mixers/latent.py::_latent_prefill_walk` folds a chunk of expanded keys and
 values into the running (max, sum, accumulator) of every query row.  In XLA
 each trip writes and re-reads the [heads, rows, keys] f32 scores and
 probabilities through HBM, because XLA does not fuse score matmul -> softmax

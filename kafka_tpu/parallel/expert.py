@@ -28,6 +28,8 @@ import jax.numpy as jnp
 from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from ..models.ffn import _routing_weights as _canonical_routing_weights
+
 Params = Dict[str, jnp.ndarray]
 
 
@@ -49,11 +51,9 @@ def init_moe_params(
 
 
 def _routing_weights(x: jnp.ndarray, router: jnp.ndarray, top_k: int):
-    """Canonical exact-top-k routing lives in models/llama.py (the served
+    """Canonical exact-top-k routing lives in models/ffn.py (the served
     model); reused here so the two cannot drift."""
-    from ..models.llama import _routing_weights as impl
-
-    return impl(x, router, top_k)
+    return _canonical_routing_weights(x, router, top_k)
 
 
 def moe_mlp_reference(x: jnp.ndarray, params: Params, top_k: int = 2):

@@ -35,17 +35,17 @@ Two entry points:
 
 from __future__ import annotations
 
-import functools
-from typing import Any, Dict
-
 import jax
 import jax.numpy as jnp
 from jax import lax, shard_map
 from jax.lax import pcast
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from ..models.cache import PagedView
 from ..models.config import ModelConfig
-from ..models.llama import Params, _attention_block, _mlp_block
+from ..models.ffn import _mlp_block
+from ..models.mixers.gqa import WindowedPathError, _attention_block
+from ..models.quant import Params
 from ..ops.norms import rms_norm
 from ..ops.rope import rope_cos_sin, rope_frequencies
 
@@ -92,8 +92,6 @@ def _check_pp_divisibility(cfg: ModelConfig, pp: int, tp: int) -> None:
     if cfg.layer_types:
         # the stage body below is ONE homogeneous layer with one rotary
         # table: it would run a sliding-window layer as a global one
-        from ..models.llama import WindowedPathError
-
         raise WindowedPathError(
             "pp stage sharding (parallel/pipeline.py) scans one layer body "
             "and has no form for a layer pattern "
@@ -180,8 +178,6 @@ def pp_forward_paged(
         # Same index-plan contract as the engine's TP path, minus the
         # pallas/ring fields (page_table=None selects _attention_block's
         # XLA gather branch — the only backend legal on a pp mesh).
-        from ..models.llama import PagedView
-
         paged_local = PagedView(write_idx, read_idx, kv_positions, kv_valid)
 
         def run_stage(operand):

@@ -81,7 +81,7 @@ def param_specs(cfg: ModelConfig, mesh: Mesh) -> Params:
         "wo": P(None, tx, None, None),
     }
     if cfg.is_moe:
-        # MoE (models/llama.py:_moe_block): experts over "ep", per-expert
+        # MoE (models/ffn.py:_moe_block): experts over "ep", per-expert
         # FFN dim still Megatron-split over "tp" — ep x tp composes.  The
         # router stays replicated so every rank routes identically; GSPMD
         # inserts the expert-axis psum at the combine einsum.

@@ -72,7 +72,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..models.config import WINDOWED, ModelConfig, UnsupportedConfigError
-from ..models.llama import experts_int8
+from ..models.ffn import experts_int8
 from .failpoints import failpoint
 from .flight_recorder import (
     FlightRecorder,
@@ -839,7 +839,7 @@ class InferenceEngine:
                 "per-token mappings are built on one device; serve each "
                 "replica on one device (dp)")
         if cfg.lead_tree and not cfg.is_latent:
-            # models/llama._init_lead_tree_params' tree on grouped-query
+            # models/init_params._init_lead_tree_params' tree on grouped-query
             # attention: parallel/sharding.param_specs has no rule for it
             sharded = mesh is not None and mesh.size > 1
             refused = (
@@ -877,7 +877,7 @@ class InferenceEngine:
                     raise WindowedAttentionUnsupported(path, why)
         if cfg.is_latent:
             # Every option below reads k/v rows of Hkv*D lanes a head; the
-            # latent pool has none (models/llama.py LatentPathError is the
+            # latent pool has none (mixers/index.py LatentPathError is the
             # backstop for direct callers of forward).
             sharded = mesh is not None and mesh.size > 1
             refused = (

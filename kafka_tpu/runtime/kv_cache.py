@@ -27,14 +27,14 @@ configs 2 and 5).
 
 Page tables, not the pool, are what the jitted step functions consume: a
 [B, max_pages] int32 array per step, from which read/write flat indices are
-derived *on device* (models/llama.py PagedView).  Physical page 0 is
+derived *on device* (models/cache.py PagedView).  Physical page 0 is
 reserved as the trash page — inactive batch slots point their writes at it.
 
 Page ids and slot indices are per layer and the same in every layer.  The
 step programs never slice a layer out of the pool: the stacked arrays ride
 the layer scan as carry, viewed flat as [L * num_pages * page_size, Hkv*D],
 and layer l's page p is page l * num_pages + p of that view
-(models/llama.py _layer_view) — so each layer has its own trash page, page
+(models/cache.py _layer_view) — so each layer has its own trash page, page
 l * num_pages, which is what page 0 of its slice was.
 """
 
@@ -43,10 +43,10 @@ from __future__ import annotations
 import dataclasses
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..models.cache import INDEX
 from ..models.config import ModelConfig
 from ..ops.pallas.paged_attention import pages_one_run
 from .failpoints import failpoint
@@ -374,8 +374,6 @@ def make_kv_pool_arrays(
         # rows beside them.  A page id means the same tokens in every
         # layer of every kind, so the allocator, the page tables and the
         # prefix cache never see the difference.
-        from ..models.llama import INDEX
-
         k, v = {}, {}
         for kind in cfg.kinds:
             widths = cfg.kv_row_widths(kind)

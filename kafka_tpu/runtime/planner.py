@@ -37,16 +37,24 @@ from __future__ import annotations
 
 import dataclasses
 import os
+import functools
 from typing import Callable, Dict, Optional, Sequence, Tuple
 
+import jax
+from jax.sharding import AbstractMesh
+
 from ..models.config import (
-    CONV,
     CROSS,
     DELTA,
     PARALLEL,
     ModelConfig,
     holds_rows,
 )
+from ..models.ffn import moe_dispatch_form
+from ..models.init_params import init_params
+from ..models.mixers.latent import PREFILL_WALK_KEYS
+from ..models.quant import param_bytes, quantize_params
+from ..parallel.sharding import param_specs
 from .kv_cache import default_state_slots
 
 GiB = 1024**3
@@ -259,6 +267,17 @@ class MemoryPlan:
         }
 
 
+@functools.lru_cache(maxsize=64)
+def _abstract_params(cfg: ModelConfig, quantize: str):
+    """The parameter tree `init_params` would build (through
+    `quantize_params` for "int8") as shapes and dtypes, no array made: 0.01-
+    0.34 s a configuration of the benchmark's on a CPU, once a process."""
+    tree = jax.eval_shape(lambda: init_params(cfg, jax.random.PRNGKey(0)))
+    if quantize == "int8":
+        tree = jax.eval_shape(lambda t: quantize_params(t, cfg), tree)
+    return tree
+
+
 def weight_bytes_per_device(
     cfg: ModelConfig,
     *,
@@ -268,76 +287,33 @@ def weight_bytes_per_device(
     quantize: str = "",
     kv_shard: Optional[int] = None,
 ) -> int:
-    """Per-device weight bytes under parallel/sharding.py's rules."""
-    h, f, d = cfg.hidden_size, cfg.intermediate_size, cfg.head_dim
-    hq, hkv = cfg.num_heads, cfg.num_kv_heads
-    L = cfg.num_layers
-    wb = _bytes(cfg.dtype)
-    int8 = quantize == "int8"
-
-    def mat(rows: int, cols: int, shard: int) -> int:
-        """One weight matrix sharded `shard`-ways; int8 = 1B + f32 scale
-        per output channel (quant.py: scale shape keeps the out axis)."""
-        n = rows * cols // shard
-        return n + (cols // shard) * 4 if int8 else n * wb
-
+    """Per-device weight bytes: `param_bytes` of the abstract tree (a new
+    leaf is counted the day the initialiser has it), each leaf divided by the
+    mesh axes parallel/sharding.param_specs names for it and the stacked axis
+    by `pp`.  A tree of another shape than the homogeneous stack's is served
+    one device a replica (the engine refuses it a mesh): counted whole."""
+    tree = _abstract_params(cfg, quantize)
+    if tp * pp * ep == 1 or cfg.lead_tree or cfg.hybrid_decoder:
+        return param_bytes(tree)
     kv_shard = _kv_shard(cfg, tp, kv_shard)
-
-    if cfg.lead_tree:
-        return _lead_tree_weight_bytes(cfg, mat, wb)
-    if cfg.hybrid_decoder:
-        return _hybrid_weight_bytes(cfg, wb)
-    per_layer = (
-        mat(h, hq * d, tp)            # wq
-        + 2 * mat(h, hkv * d, kv_shard)  # wk, wv
-        + mat(hq * d, h, tp)          # wo (row-parallel: heads on tp)
-        + 2 * h * wb                  # norms (replicated)
-    )
-    if cfg.is_moe:
-        e_shard = ep if (ep > 1 and cfg.num_experts % ep == 0) else 1
-        per_layer += h * cfg.num_experts * wb  # router, replicated
-        per_layer += cfg.num_experts // e_shard * (
-            2 * mat(h, f, tp) + mat(f, h, tp)
-        )
-    else:
-        per_layer += 2 * mat(h, f, tp) + mat(f, h, tp)
-    if cfg.ssd_heads:
-        # the parallel layout's second mixer (one device: the engine refuses
-        # a mesh): W_in and W_out, the taps, their bias and the gated norm's
-        # weight; A_log, D and dt_bias are float32
-        d_ssm = cfg.ssd_heads * cfg.ssd_head_dim
-        conv = cfg.ssd_conv_dim
-        per_layer += (mat(h, d_ssm + conv + cfg.ssd_heads, 1)
-                      + mat(d_ssm, h, 1)
-                      + ((cfg.ssd_conv_kernel + 1) * conv + d_ssm) * wb
-                      + 3 * cfg.ssd_heads * 4)
-
-    total = per_layer * L // pp
-    # embed replicated (lookup local); untied lm_head tp-sharded over V
-    total += mat(cfg.vocab_size, h, 1) if int8 else cfg.vocab_size * h * wb
-    total += h * wb  # final norm
-    if not cfg.tie_word_embeddings:
-        total += mat(h, cfg.vocab_size, tp)
+    # the mesh the server would build, as axis sizes: tp = kv_shard x tq
+    mesh = AbstractMesh((kv_shard, tp // kv_shard, ep), ("tp", "tq", "ep"))
+    specs = param_specs(cfg, mesh)
+    total = 0
+    for path, leaf in jax.tree_util.tree_leaves_with_path(tree):
+        keys = [k.key for k in path if hasattr(k, "key")]
+        spec = specs
+        for key in keys:
+            # (a leaf the rules do not name is replicated)
+            spec = spec.get(key, ()) if isinstance(spec, dict) else spec
+        shards = pp if keys[0] == "layers" else 1
+        for size, axes in zip(leaf.shape, spec):
+            if axes is not None and size > 1:
+                # (an int8 leaf's scale keeps its contracted axes at 1)
+                for axis in (axes,) if isinstance(axes, str) else axes:
+                    shards *= mesh.shape[axis]
+        total += leaf.size * leaf.dtype.itemsize // shards
     return total
-
-
-def _hybrid_weight_bytes(cfg: ModelConfig, wb: int) -> int:
-    """Weights of `phi4flash`'s hybrid decoder on its one device (the engine
-    refuses meshes for it): the tree models/hybrid.init_params builds.  Float32
-    leaves (conv, dt bias, A_log, D, lambdas) are counted at 4 bytes."""
-    h, f, d = cfg.hidden_size, cfg.intermediate_size, cfg.head_dim
-    hq, hkv = cfg.num_heads, cfg.num_kv_heads
-    di, ds, dc = cfg.mamba_d_inner, cfg.mamba_d_state, cfg.mamba_d_conv
-    r = cfg.mamba_dt_rank
-    n_m = cfg.state_layers
-    common = 2 * n_m * (3 * h * f + 4 * h) * wb
-    mamba = n_m * ((h * 2 * di + di * (r + 2 * ds) + r * di + di * h) * wb
-                   + (dc * di + 3 * di + ds * di) * 4)
-    diff = 4 * d * 4 + 2 * d * wb  # lambda vectors, sub-layer norm
-    attn = n_m * ((h * (hq + 2 * hkv) * d + (hq + 2 * hkv) * d
-                   + hq * d * h + h) * wb + diff)
-    return (common + mamba + attn + _hybrid_second_half_bytes(cfg, wb)
-            + (cfg.vocab_size * h + 2 * h) * wb)
 
 
 def _hybrid_second_half_bytes(cfg: ModelConfig, wb: int) -> int:
@@ -361,76 +337,6 @@ def state_bytes_per_device(cfg: ModelConfig, state_slots: int) -> int:
     of 2 rows takes 8)."""
     return 4 * cfg.state_layers * state_slots * sum(
         -(-rows // 8) * 8 * cols for _, (rows, cols) in cfg.state_shapes())
-
-
-def _lead_tree_weight_bytes(cfg: ModelConfig, mat, wb: int) -> int:
-    """Weights of a model whose tree is models/llama._init_lead_tree_params'
-    (or, per kind, _init_kind_params') on its one device (the engine refuses
-    a mesh for it), leaf by leaf: latent attention, or grouped-query
-    attention with its QK-norm weights, or a gated short convolution, by
-    each layer's kind; a dense lead; the router at its published width
-    beside the experts HELD."""
-    h = cfg.hidden_size
-
-    def mlp(f: int) -> int:
-        return 2 * mat(h, f, 1) + mat(f, h, 1)
-
-    def attn(kind: str) -> int:
-        """A layer's mixer and its two norms."""
-        if kind == CONV:
-            return (mat(h, 3 * h, 1) + mat(h, h, 1)
-                    + (cfg.conv_L_cache * h + 2 * h) * wb)
-        if kind == DELTA:
-            # q / k / v / o, the decay's and the gate's low-rank pairs, beta;
-            # the taps, the head norm and the two norms; A_log and dt_bias
-            # are float32
-            n, d = cfg.delta_heads, cfg.delta_head_dim
-            w = n * d
-            return (4 * mat(h, w, 1) + 2 * (mat(h, d, 1) + mat(d, w, 1))
-                    + mat(h, n, 1)
-                    + (cfg.delta_conv_kernel * 3 * w + d + 2 * h) * wb
-                    + (n + w) * 4)
-        if not cfg.is_latent:
-            hq, hkv, d = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
-            gates = 3 if cfg.attention_gate == "elementwise" else 2
-            return (gates * mat(h, hq * d, 1) + 2 * mat(h, hkv * d, 1)
-                    + (2 * h + (2 * d if cfg.qk_norm else 0)) * wb)
-        g = cfg.geometry_of(kind)
-        hq, r, rq = g.num_heads, g.kv_lora_rank, g.q_lora_rank
-        dn, dr, dv = g.qk_nope_head_dim, g.qk_rope_head_dim, g.v_head_dim
-        query = (mat(h, rq, 1) + rq * wb + mat(rq, hq * (dn + dr), 1)
-                 if rq else mat(h, hq * (dn + dr), 1))
-        extra = mat(h, hq, 1) if cfg.attention_gate else 0
-        if cfg.has_indexer(kind):
-            hi, di = cfg.index_n_heads, cfg.index_head_dim
-            extra += (mat(rq or h, hi * di, 1) + mat(h, di, 1)
-                      + mat(h, hi, 1) + 2 * di * wb)
-        return (query + mat(h, r + dr, 1) + mat(r, hq * (dn + dv), 1)
-                + mat(hq * dv, h, 1) + (2 * h + r) * wb + extra)
-
-    if cfg.is_moe:
-        routed = cfg.num_router_experts
-        bias = routed * 4 if cfg.moe_scoring == "sigmoid" else 0
-        ffn = (h * routed * wb + bias  # router, selection bias (f32)
-               + cfg.num_experts * mlp(cfg.intermediate_size)
-               + (mlp(cfg.shared_intermediate_size)
-                  if cfg.shared_intermediate_size else 0))
-    else:
-        ffn = mlp(cfg.intermediate_size)
-    total = (sum(cfg.layers_of(kind) * attn(kind) for kind in cfg.kinds)
-             + (cfg.num_layers - cfg.first_k_dense) * ffn
-             + cfg.first_k_dense * mlp(cfg.dense_intermediate_size))
-    if cfg.hc_mult > 1:
-        # a widened residual stream's mappings, two sites a layer: Phi
-        # [nC, n + n + n^2] and the stream norm's weight; the bias and the
-        # three scalars are float32 (models/llama.HC_SITES)
-        n = cfg.hc_mult
-        maps = 2 * n + n * n
-        total += 2 * cfg.num_layers * (mat(n * h, maps, 1) + n * h * wb
-                                       + (maps + 3) * 4)
-    total += 2 * cfg.vocab_size * h * wb if not cfg.tie_word_embeddings \
-        else cfg.vocab_size * h * wb
-    return total + h * wb
 
 
 def kv_pool_bytes_per_device(
@@ -673,7 +579,7 @@ class DispatchCostModel:
     walk_kinds: Tuple[Tuple[float, Optional[int]], ...] = ()
     walk_chunk_keys: int = 1
     # (experts held, experts the router knows, picks a token, sharded or
-    # int8: models/llama.moe_dispatch_form's arguments); None = no router
+    # int8: models/ffn.moe_dispatch_form's arguments); None = no router
     moe: Optional[Tuple[int, int, int, bool, bool]] = None
     expert_bytes: int = 0        # every routed expert held, all layers
     launch_bytes: int = 0        # weights a launch reads whatever it holds
@@ -721,7 +627,7 @@ class DispatchCostModel:
         full layer and from the chunk that holds the window's first key in a
         sliding one.  Rows that hold no token enter no expert group under
         token dispatch, which the routed block takes by
-        models/llama.moe_dispatch_form's rule: below it every held expert
+        models/ffn.moe_dispatch_form's rule: below it every held expert
         multiplies every row.  A hybrid decoder's second half and the head
         run on each lane's last real row.  The weights are read once: every
         routed expert under dense dispatch, under token dispatch those some
@@ -737,8 +643,6 @@ class DispatchCostModel:
         bytes_ = (self.launch_bytes
                   + (start + 2 * tokens) * self.kv_bytes_per_token)
         if self.moe is not None:
-            from ..models.llama import moe_dispatch_form
-
             held, routed, top_k, sharded, int8 = self.moe
             if moe_dispatch_form(rows, held, top_k, sharded, routed,
                                  int8) == "dense":
@@ -816,7 +720,7 @@ def dispatch_cost_model(
     tq factor (grouped GQA replicates each kv head across its tq group,
     so per-device KV traffic does not shrink by the full device count).
     `int8_experts`: the routed experts' leaves are quantized
-    (models/llama.experts_int8), which the routed block's form asks.
+    (models/ffn.experts_int8), which the routed block's form asks.
     """
     if weight_bytes_total is None:
         weight_bytes_total = weight_bytes_per_device(cfg, tp=1)
@@ -861,7 +765,8 @@ def dispatch_cost_model(
     def key_flops(kind: str) -> float:
         """One context key through W_kvb (every head's k_nope and v from the
         latent row) in every layer of `kind`: what a trip of
-        models/llama._latent_prefill_walk expands, whoever attends it."""
+        models/mixers/latent._latent_prefill_walk expands, whoever attends
+        it."""
         g = cfg.geometry_of(kind)
         return (2.0 * cfg.layers_of(kind) * g.kv_lora_rank * g.num_heads
                 * (g.qk_nope_head_dim + g.v_head_dim) / n)
@@ -873,8 +778,6 @@ def dispatch_cost_model(
     # over a pool that is not int8 (one byte a value: that one prefills
     # through the dequantizing XLA gather, as a mesh does); every other
     # prefill is XLA's over all bucket rows.
-    from ..models.llama import PREFILL_WALK_KEYS
-
     kernels = cfg.attention_backend == "pallas"
     if cfg.is_latent:
         attend_row_tile = 128 if kernels else 1

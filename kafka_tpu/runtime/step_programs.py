@@ -1,6 +1,6 @@
 """The jitted step programs: everything the engine runs on the device.
 
-One direction only: `engine -> step_programs -> models.llama / ops.sampling`.
+One direction only: `engine -> step_programs -> models / ops.sampling`.
 Nothing here imports the engine or sees a request; a program is a pure
 function of device arrays, built for one model config and one pool geometry.
 Three things have their one home here:
@@ -31,17 +31,12 @@ from typing import Any, Callable, Dict, NamedTuple, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from ..models.cache import KVCache, PagedView, StatePlan
 from ..models.config import DELTA, PARALLEL, ModelConfig
-from ..models.llama import (
-    INDEX_WALK_KEYS,
-    KVCache,
-    PagedView,
-    forward,
-    experts_int8,
-    moe_dispatch_form,
-    prefill_walk_pages,
-    walk_pages,
-)
+from ..models.ffn import experts_int8, moe_dispatch_form
+from ..models.llama import forward
+from ..models.mixers.index import INDEX_WALK_KEYS, walk_pages
+from ..models.mixers.latent import prefill_walk_pages
 from ..ops.attention import decode_walk_pages, shared_walk_trips
 from ..ops.pallas import ssd as ssd_kernels
 from ..ops.pallas.gated_delta import chunk_rows
@@ -236,14 +231,12 @@ def verify_plan(page_table, seq_lens, cand_lens, active, S: int, ps: int):
 
 def _with_state(cfg, paged, lens, slots=None, snaps=None, starts=None):
     """`paged` with the StatePlan of a pass whose lanes hold `lens` real rows
-    (models/hybrid.StatePlan; `paged` itself for a model without a state).
+    (models/cache.StatePlan; `paged` itself for a model without a state).
     Decode: lane i in slot i.  Prefill: lane i reads and writes `slots[i]`,
     from zeros where it starts at position 0, and leaves a snapshot in
     `snaps[i]`."""
     if not cfg.has_state:
         return paged
-    from ..models.hybrid import StatePlan
-
     with jax.named_scope("step_ctl"):
         if slots is None:
             return paged._replace(state=StatePlan(lens=lens))
@@ -253,7 +246,7 @@ def _with_state(cfg, paged, lens, slots=None, snaps=None, starts=None):
 
 def _moe_form(cfg, mesh, rows: int, int8: bool) -> Optional[str]:
     """The form the routed blocks of a pass of `rows` rows trace to, over
-    experts that are `int8` or not (models/llama.py moe_dispatch_form, the
+    experts that are `int8` or not (models/ffn.py moe_dispatch_form, the
     rule `_moe_block` itself asks); None for a model with no routed
     block."""
     if not cfg.is_moe:
@@ -537,7 +530,7 @@ class StepPrograms:
     jitting it.  A decode-side builder is handed the `Fsm` the program
     will be called with, or `None` for the plain program.  `int8_experts`:
     the params the programs will be handed hold their routed experts as
-    int8 (`models/llama.py experts_int8`: what the programs themselves see
+    int8 (`models/ffn.py experts_int8`: what the programs themselves see
     when traced)."""
 
     def __init__(self, cfg: ModelConfig, mesh: Any, page_size: int,
@@ -629,7 +622,7 @@ class StepPrograms:
         """(keys scored, keys kept) by ONE layer's indexer over `steps`
         decode steps of lanes holding `lengths` tokens before the first: a
         step scores the lane's whole context, its own new row included, and
-        keeps at most `index_topk` of it (models/llama.py
+        keeps at most `index_topk` of it (models/mixers/index.py
         _paged_index_choice).  (0, 0) for a model without an indexer."""
         topk = self.cfg.index_topk
         if not topk:
@@ -643,7 +636,7 @@ class StepPrograms:
         """Of `index_keys`' scored keys, those scored through the walk's
         SHARED trips: `lanes` [(pages, tokens held before the first step)]
         of the dispatch's active lanes, by the device's own arithmetic
-        (ops/attention.py common_pages, models/llama.py
+        (ops/attention.py common_pages, models/mixers/index.py
         _paged_index_scores): the leading columns in which every lane's
         page-table row names one page, whole trips of them, up to each
         lane's live context."""
@@ -658,7 +651,7 @@ class StepPrograms:
                            bucket: int) -> Tuple[int, int]:
         """(trips, trips the Pallas kernel folds) of ONE prefill launch of
         `width` lanes x `bucket` rows whose active lanes hold `spans`
-        [(start, chunk_len)]: what models/llama.py _latent_prefill_walk
+        [(start, chunk_len)]: what models/mixers/latent.py _latent_prefill_walk
         loops, by the bounds its device loop computes, summed over the
         layers that walk (a full layer from key 0 to the longest lane's last
         key, a sliding layer from the chunk that holds the first window's
@@ -727,7 +720,7 @@ class StepPrograms:
     def moe_dispatch(self, rows: int) -> Optional[str]:
         """"token" or "dense": the form the routed blocks of a pass of
         `rows` rows (lanes x rows a lane) trace to, by the rule
-        models/llama.py _moe_block itself asks (moe_dispatch_form); None
+        models/ffn.py _moe_block itself asks (moe_dispatch_form); None
         for a model with no routed block.  A decode or fused-decode program
         whose form is "token" returns, last, the held experts its passes
         read ([] a step, [steps] fused), and None otherwise."""

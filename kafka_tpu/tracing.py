@@ -213,13 +213,14 @@ DEVICE_SCOPES = (
                     # cache a pass)
     "attn_diff",    # differential attention's combine: P_1 V - lam P_2 V,
                     # the sub-layer RMSNorm, (1 - lambda_init)
-    # the conv layout's mixer (models/llama._short_conv_block)
+    # the conv layout's mixer (models/mixers/state._short_conv_block)
     "conv_proj",    # a gated short convolution's projections: W_in, W_out
                     # (+ residual add)
     "conv_mix",     # its elementwise middle: B * u, the taps over [tail |
                     # pass] with the tail's read from and write to the state
                     # slot, and the C * gate
-    # the linear-attention layout's mixer (models/llama._delta_attention_block)
+    # the linear-attention layout's mixer
+    # (models/mixers/state._delta_attention_block)
     "kda_proj",     # a gated delta-rule layer's projections: W_q, W_k, W_v,
                     # the decay's and the output gate's low-rank pairs,
                     # W_beta, W_o (+ residual add)
@@ -231,7 +232,7 @@ DEVICE_SCOPES = (
     "kda_delta",    # the recurrence: the chunked Pallas kernel at s > 1,
                     # the step kernel in decode (the state updated in place
                     # in its slot), or the row-by-row XLA scan
-    # the parallel layout's second mixer (models/llama._ssd_block); its
+    # the parallel layout's second mixer (models/mixers/state._ssd_block); its
     # attention keeps attn_qkv / kv_write / attn_core / attn_out
     "ssd_proj",     # a Mamba-2 (SSD) mixer's projections: W_in with its two
                     # multipliers, W_out with its one, and the branch's add
@@ -243,7 +244,7 @@ DEVICE_SCOPES = (
     "ssd_scan",     # the recurrence: the chunked Pallas kernel at s > 1, the
                     # step kernel in decode (the state updated in place in
                     # its slot), or the row-by-row XLA scan
-    # a widened residual stream (models/llama._hc_in / _hc_out; `hc_mult` >
+    # a widened residual stream (models/residual._hc_in / _hc_out; `hc_mult` >
     # 1 only: with one row the adds stay where they sat, under attn_out / mlp
     # / moe_experts).  The widening sits under embed, the collapse under head
     "hc_map",       # a sublayer's per-token mappings: the norm over all n

@@ -24,7 +24,7 @@ import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 
-from kafka_tpu.models.hybrid import (  # noqa: E402
+from kafka_tpu.models.cache import (  # noqa: E402
     StatePlan, _read_state, _write_state)
 from kafka_tpu.ops.pallas import gated_delta as gd  # noqa: E402
 

@@ -2,7 +2,7 @@
 """The indexer's selection step at decode alone, on the chip, at dots3's
 shapes (`benchmarks/configs/dots3-note-prev.json`: 32 lanes, a page table
 2,048 wide, pages of 16, 64 index heads of 128, a bf16 pool of 8,192 pages,
-index_topk 2,048): `models/llama._paged_index_choice` over one layer's
+index_topk 2,048): `models/mixers/index._paged_index_choice` over one layer's
 indexer rows, under page tables that share more or less of their leading
 columns.
 
@@ -99,7 +99,7 @@ def main() -> int:
     import jax.numpy as jnp
 
     from kafka_tpu.models import config as model_config
-    from kafka_tpu.models import llama
+    from kafka_tpu.models.mixers import index
     from kafka_tpu.runtime.step_programs import decode_plan
     from moe_dispatch_bench import load_parent, module_events
 
@@ -121,7 +121,8 @@ def main() -> int:
         cfg = cfg.replace(index_topk=16, index_n_heads=4)
         args.shared_keys, args.own_keys, args.reps = 150, [5, 60], 1
     hi, di = cfg.index_n_heads, cfg.index_head_dim
-    parent = load_parent(args.parent) if args.parent else None
+    parent = (load_parent(args.parent, "mixers/index.py") if args.parent
+              else None)
     rng = np.random.RandomState(args.seed % 2**31)
     # one layer's indexer rows (`_flat_pool` of a stack one layer deep)
     pool = jnp.asarray(rng.standard_normal((num_pages * ps, di)), dt)
@@ -147,14 +148,14 @@ def main() -> int:
             return fn
 
         def walk(q, w, pool, lane_state):
-            return llama._paged_index_scores(
+            return index._paged_index_scores(
                 q, w, pool, decode_plan(*lane_state, ps)[1], dt)
 
         def top_k(scores, lane_state):
             positions, paged = decode_plan(*lane_state, ps)
             causal = (paged.kv_valid[:, None, :] & (
                 paged.kv_positions[:, None, :] <= positions[:, :, None]))
-            return llama._chosen_mask(scores, causal, cfg.index_topk)
+            return index._chosen_mask(scores, causal, cfg.index_topk)
 
         outs = {}
 
@@ -171,15 +172,15 @@ def main() -> int:
             forms[named.__name__] = (jitted, a, kind, form)
 
         a = (q, w, pool, lane_state)
-        run("installed", choice(llama), a)
+        run("installed", choice(index), a)
         run("walk", walk, a)
         if parent is not None:
             run("parent", choice(parent), a)
         run("mask", top_k, (outs["walk"], lane_state))
-        run("compact", lambda c, r: llama._compact_chosen(
+        run("compact", lambda c, r: index._compact_chosen(
             c[:, 0], r, cfg.index_topk),
             (outs["mask"], decode_plan(*lane_state, ps)[1].read_idx))
-        run("common", lambda lane_state: llama._common_pages(
+        run("common", lambda lane_state: index._common_pages(
             decode_plan(*lane_state, ps)[1])[1], (lane_state,))
         row = {"table": kind, "lanes": int(b), "live_keys": int(lens.sum()),
                "common_pages": int(outs["common"])}

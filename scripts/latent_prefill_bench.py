@@ -14,7 +14,7 @@ Through the chip tool, from the repo root.  Defaults are the cell's
 a 32,768-key window of 16-row pages scattered in an 8,192-page bf16 pool,
 so a full layer walks 29 trips of 1,024 keys with ~2,048 chosen keys a row
 and a sliding layer (window 513) the last one or two.  What is timed is
-`models/llama.py::_latent_prefill_walk` itself, jitted once a form:
+`models/mixers/latent.py::_latent_prefill_walk` itself, jitted once a form:
 
     materialised (`--heads 32` only) what Kanana-2's prefill ran until PR
                  37, kept here alone: every page of the 16k static window
@@ -61,7 +61,7 @@ TINY = {"full": (4, 16, 8, 16, 32, None, 8),
 UNIFORM_KEYS, UNIFORM_ROWS, UNIFORM_PAGES = 8320, (512, 256, 64), 1024
 
 
-def make_case(args, geometry, jnp, llama):
+def make_case(args, geometry, jnp, latent):
     """One layer's pools, a scattered page table and a bucket of queries,
     from --seed."""
     n, dn, dr, dv, rank, window, topk = geometry
@@ -79,7 +79,7 @@ def make_case(args, geometry, jnp, llama):
         table[i, :need] = rng.permutation(np.arange(1, args.num_pages))[:need]
     positions = np.broadcast_to(args.start + np.arange(s), (b, s))
     kv_pos = np.broadcast_to(np.arange(C), (b, C))
-    paged = llama.PagedView(
+    paged = latent.PagedView(
         write_idx=jnp.zeros((b, s), jnp.int32),
         read_idx=jnp.zeros((b, C), jnp.int32),
         kv_positions=jnp.asarray(kv_pos, jnp.int32),
@@ -143,7 +143,7 @@ def take_apart(lp):
             "k_softmax": heads_loop(softmax), "k_carries": heads_loop(carries)}
 
 
-def forms(case, jax, llama, lp, pallas_pkg):
+def forms(case, jax, latent, lp, pallas_pkg):
     """{name: jitted walk}; a kernel form traces the walk with
     `_fold_kernel` swapped for its body."""
     def build(name, body):
@@ -151,13 +151,13 @@ def forms(case, jax, llama, lp, pallas_pkg):
             kw = dict(case, q_nope=q_nope, q_rope=q_rope, wkvb=wkvb,
                       k_cache=k_cache, v_cache=v_cache)
             if body is None:
-                return llama._latent_prefill_walk(**kw, kernel=False)
+                return latent._latent_prefill_walk(**kw, kernel=False)
             installed, fold = lp._fold_kernel, lp.latent_prefill_fold
             # the jitted wrapper caches its trace: trace the plain function
             lp._fold_kernel = body
             pallas_pkg.latent_prefill_fold = fold.__wrapped__
             try:
-                return llama._latent_prefill_walk(**kw, kernel=True)
+                return latent._latent_prefill_walk(**kw, kernel=True)
             finally:
                 lp._fold_kernel = installed
                 pallas_pkg.latent_prefill_fold = fold
@@ -167,11 +167,11 @@ def forms(case, jax, llama, lp, pallas_pkg):
     out = {"xla": build("xla", None)}
     out.update({n: build(n, b) for n, b in take_apart(lp).items()})
     if case["window"] is None and case["chosen_of"] is None:
-        out["materialised"] = jax.jit(materialised(case, llama))
+        out["materialised"] = jax.jit(materialised(case, latent))
     return out
 
 
-def materialised(case, llama):
+def materialised(case, latent):
     """The form `_latent_attention_block` ran for a model of one kind of
     layer until PR 37: the page table's every page, expanded, one softmax."""
     import jax.numpy as jnp
@@ -180,13 +180,13 @@ def materialised(case, llama):
 
     def bench_materialised(q_nope, q_rope, wkvb, k_cache, v_cache):
         dt, ps = q_nope.dtype, paged.page_size
-        c_win = llama._kv_read_pages(k_cache, paged.page_table, ps, dt)
-        r_win = llama._kv_read_pages(
+        c_win = latent._kv_read_pages(k_cache, paged.page_table, ps, dt)
+        r_win = latent._kv_read_pages(
             v_cache, paged.page_table, ps, dt)[..., :case["dr"]]
         mask = ((positions[:, :, None] >= paged.kv_positions[:, None, :])
                 & paged.kv_valid[:, None, :])
         kv = jnp.einsum("btr,nrd->btnd", c_win, wkvb)
-        return llama._latent_attend(q_nope, q_rope, kv[..., :dn], r_win,
+        return latent._latent_attend(q_nope, q_rope, kv[..., :dn], r_win,
                                     kv[..., dn:], mask, case["scale"],
                                     shared=False)
 
@@ -259,7 +259,7 @@ def main() -> int:
     import jax.numpy as jnp
 
     import kafka_tpu.ops.pallas as pallas_pkg
-    from kafka_tpu.models import llama
+    from kafka_tpu.models.mixers import latent
     from kafka_tpu.ops.pallas import latent_prefill as lp
     from kafka_tpu.runtime.planner import device_peaks
 
@@ -275,10 +275,10 @@ def main() -> int:
         args.rows, args.start = bucket, start
         geometry = geometries[shape]
         n, dn, dr, dv = geometry[:4]
-        case = make_case(args, geometry, jnp, llama)
+        case = make_case(args, geometry, jnp, latent)
         arrays = [case[k] for k in
                   ("q_nope", "q_rope", "wkvb", "k_cache", "v_cache")]
-        fns = forms(case, jax, llama, lp, pallas_pkg)
+        fns = forms(case, jax, latent, lp, pallas_pkg)
         outs = {name: np.asarray(fn(*arrays), np.float32)
                 for name, fn in fns.items()}
         err = float(np.abs(outs["kernel"] - outs["xla"]).max())
@@ -297,7 +297,7 @@ def main() -> int:
                     fn(*arrays).block_until_ready()
         launches, calls = events(trace_dir, list(fns))
         peak_flops, _, _ = device_peaks(jax.devices()[0])  # unknown: raises
-        ck = min(llama.PREFILL_WALK_KEYS, args.max_pages * args.page_size)
+        ck = min(latent.PREFILL_WALK_KEYS, args.max_pages * args.page_size)
         rows = args.rows + -args.rows % 128
         flop_trip = 2.0 * args.lanes * n * rows * ck * (dn + dr + dv)
         forms_out = {}

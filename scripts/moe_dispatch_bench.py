@@ -20,7 +20,7 @@ name of its own; times are the jitted programs' device durations in one
 profiler capture (`XLA Modules`), routing included, the shared expert left
 out.  Forms:
 
-  installed      `models/llama._moe_block` as `moe_dispatch_form` chooses
+  installed      `models/ffn._moe_block` as `moe_dispatch_form` chooses
   dense          every row through every held expert (the block where
                  the token form is not chosen, and on meshes)
   token          `_experts_token` at every row count: picks sorted by expert,
@@ -72,13 +72,19 @@ GMM_TILINGS = ((128, 512, 512), (128, 1024, 1024), (256, 1024, 1024),
 GMM_MIN_ROWS = 256
 
 
-def load_parent(tree):
-    """`models/llama.py` of the tree unpacked at `tree`, under a module name
-    of its own inside the installed package (its relative imports resolve
-    there; the installed module stays what it is)."""
-    path = os.path.join(tree, "kafka_tpu", "models", "llama.py")
+def load_parent(tree, home="ffn.py"):
+    """The module `home` (under `kafka_tpu/models/`) of the tree unpacked at
+    `tree` (its `models/llama.py` where the tree has no such file: that held
+    every block before PR 58), under a module name of its own inside the
+    installed package (its relative imports resolve there; the installed
+    module stays what it is)."""
+    models = os.path.join(tree, "kafka_tpu", "models")
+    if not os.path.exists(os.path.join(models, home)):
+        home = "llama.py"
+    package = ".".join(["kafka_tpu", "models"] + home.split("/")[:-1])
     spec = importlib.util.spec_from_file_location(
-        "kafka_tpu.models.parent_llama", path)
+        f"{package}.parent_{os.path.basename(home)[:-3]}",
+        os.path.join(models, home))
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     return mod
@@ -147,7 +153,7 @@ def main() -> int:
     from kafka_tpu.models import config as model_config
     from jax.experimental.pallas.ops.tpu.megablox import gmm
 
-    from kafka_tpu.models import llama
+    from kafka_tpu.models import ffn
     import kafka_tpu.ops.pallas.grouped_matmul as gm
     from kafka_tpu.runtime.planner import device_peaks
 
@@ -169,18 +175,18 @@ def main() -> int:
         `matmul` in `grouped_matmul`'s place and `rows_a_tile` rows a tile
         (the installed ones where None); -> (output, experts read)."""
         def block(x, lp, cfg, chunk_len):
-            saved = (llama.TOKEN_DISPATCH_MIN_ROWS, gm.grouped_matmul,
-                     gm.tile_rows, llama.TOKEN_DISPATCH_MIN_UNREAD)
-            llama.TOKEN_DISPATCH_MIN_ROWS = min_rows
-            llama.TOKEN_DISPATCH_MIN_UNREAD = 2.0
+            saved = (ffn.TOKEN_DISPATCH_MIN_ROWS, gm.grouped_matmul,
+                     gm.tile_rows, ffn.TOKEN_DISPATCH_MIN_UNREAD)
+            ffn.TOKEN_DISPATCH_MIN_ROWS = min_rows
+            ffn.TOKEN_DISPATCH_MIN_UNREAD = 2.0
             gm.grouped_matmul = matmul or saved[1]
             if rows_a_tile:
                 gm.tile_rows = lambda rows, groups: rows_a_tile
             try:
-                return llama._moe_block(x, lp, cfg, chunk_len)
+                return ffn._moe_block(x, lp, cfg, chunk_len)
             finally:
-                (llama.TOKEN_DISPATCH_MIN_ROWS, gm.grouped_matmul,
-                 gm.tile_rows, llama.TOKEN_DISPATCH_MIN_UNREAD) = saved
+                (ffn.TOKEN_DISPATCH_MIN_ROWS, gm.grouped_matmul,
+                 gm.tile_rows, ffn.TOKEN_DISPATCH_MIN_UNREAD) = saved
         return block
 
     # (the alternatives are handed the layer's own matrices, `rhs[layer]`:
@@ -199,7 +205,7 @@ def main() -> int:
         return jax.lax.ragged_dot(lhs, rhs[layer], sizes)
 
     blocks = {
-        "installed": llama._moe_block,
+        "installed": ffn._moe_block,
         "dense": block_with(sys.maxsize),
         "token": block_with(0),
         "ragged_dot": block_with(0, ragged_dot),
@@ -217,8 +223,8 @@ def main() -> int:
     if on_chip:
         peak_flops, hbm_bytes_per_s, _ = device_peaks(jax.devices()[0])
     result = {"device": jax.devices()[0].device_kind, "args": vars(args),
-              "min_rows": llama.TOKEN_DISPATCH_MIN_ROWS,
-              "min_unread": llama.TOKEN_DISPATCH_MIN_UNREAD, "forms": []}
+              "min_rows": ffn.TOKEN_DISPATCH_MIN_ROWS,
+              "min_unread": ffn.TOKEN_DISPATCH_MIN_UNREAD, "forms": []}
     rng = np.random.RandomState(args.seed % 2**31)
     for name in args.configs:
         cfg = model_config.config_from_hf_json(
@@ -302,7 +308,7 @@ def main() -> int:
             nbytes = 3.0 * held * h * f * 2
             floor_us = 1e6 * max(flops / peak_flops, nbytes / hbm_bytes_per_s)
             row = {"config": name, "rows": rows, "form": form,
-                   "chosen": llama.moe_dispatch_form(
+                   "chosen": ffn.moe_dispatch_form(
                        rows, held, k, False, cfg.num_router_experts),
                    "us": us, "min_us": min(durs) / 1e3,
                    "max_us": max(durs) / 1e3, "floor_us": floor_us,

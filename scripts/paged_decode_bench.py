@@ -35,7 +35,7 @@ beside the installed one on the same inputs (forms `parent_*`, a second
 tree's `parent2_*`, ...), its output required to equal the installed
 kernel's bit for bit on every table.
 
-`--backend xla`: the XLA decode read alone (models/llama.py `_decode_walk`)
+`--backend xla`: the XLA decode read alone (models/mixers/gqa.py `_decode_walk`)
 at Mixtral's geometry (32/8 x 128, the rest as above), once for each
 candidate of `ops.attention.DECODE_WALK_KEYS` (--walk-keys), beside the read
 it replaced: the gather of every lane's static `max_pages` window and
@@ -166,7 +166,7 @@ def forms(args, jax, modules):
 def xla_forms(args, jax, jnp):
     """{name: jitted fn(q, k, v, table, lens) -> [B, Hq, D]}: the static
     window read, then the walk at each candidate chunk size."""
-    from kafka_tpu.models import llama
+    from kafka_tpu.models.mixers import gqa
     from kafka_tpu.ops import attention
     from kafka_tpu.runtime.step_programs import decode_plan
 
@@ -180,8 +180,8 @@ def xla_forms(args, jax, jnp):
         b = q.shape[0]
         return attention.causal_attention(
             q[:, None],
-            llama._kv_read_pages(k, table, ps, q.dtype).reshape(b, -1, hkv, d),
-            llama._kv_read_pages(v, table, ps, q.dtype).reshape(b, -1, hkv, d),
+            gqa._kv_read_pages(k, table, ps, q.dtype).reshape(b, -1, hkv, d),
+            gqa._kv_read_pages(v, table, ps, q.dtype).reshape(b, -1, hkv, d),
             q_positions=positions, kv_positions=paged.kv_positions,
             kv_valid=paged.kv_valid)[:, 0]
 
@@ -191,7 +191,7 @@ def xla_forms(args, jax, jnp):
             installed = attention.DECODE_WALK_KEYS
             attention.DECODE_WALK_KEYS = keys  # read when the walk is traced
             try:
-                return llama._decode_walk(
+                return gqa._decode_walk(
                     q[:, None], k, v, paged, hkv, None, None)[:, 0]
             finally:
                 attention.DECODE_WALK_KEYS = installed

@@ -1,4 +1,4 @@
-"""Token dispatch at DECODE (models/llama.py `_moe_block`, `_experts_token`;
+"""Token dispatch at DECODE (models/ffn.py `_moe_block`, `_experts_token`;
 the grouped matmul interpreted off the chip): a pass of few rows over many
 experts fetches only the held experts some row picked, against the dense
 einsums on the same inputs in float32; the rule that chooses the form from
@@ -16,9 +16,9 @@ import jax
 import jax.numpy as jnp
 
 from kafka_tpu.models import forward, init_params
-from kafka_tpu.models import llama
+from kafka_tpu.models import ffn
 from kafka_tpu.models.config import ModelConfig, config_from_hf_json
-from kafka_tpu.models.llama import (
+from kafka_tpu.models.ffn import (
     _experts_token, _moe_block, moe_dispatch_form,
 )
 from kafka_tpu.runtime import EngineConfig, GenRequest, InferenceEngine
@@ -67,11 +67,11 @@ def weights(x, lp, cfg):
     """[T, held] routing weights of the block's own rule."""
     t = x.reshape(-1, x.shape[-1])
     if cfg.moe_scoring == "sigmoid":
-        w = llama._routing_weights_sigmoid(
+        w = ffn._routing_weights_sigmoid(
             t, lp["router"], lp["router_bias"], cfg.num_experts_per_tok,
             cfg.routed_scaling_factor)
     else:
-        w = llama._routing_weights(t, lp["router"], cfg.num_experts_per_tok)
+        w = ffn._routing_weights(t, lp["router"], cfg.num_experts_per_tok)
     if cfg.num_experts_routed:
         w = w[:, cfg.expert_offset:cfg.expert_offset + cfg.num_experts]
     return np.asarray(w)
@@ -79,9 +79,9 @@ def weights(x, lp, cfg):
 
 def as_form(monkeypatch, form, *args, **kw):
     """`_moe_block` made to take `form` whatever the pass's shape."""
-    monkeypatch.setattr(llama, "TOKEN_DISPATCH_MIN_UNREAD",
+    monkeypatch.setattr(ffn, "TOKEN_DISPATCH_MIN_UNREAD",
                         0.0 if form == "token" else 2.0)
-    monkeypatch.setattr(llama, "TOKEN_DISPATCH_UNREAD_ROWS", 0)
+    monkeypatch.setattr(ffn, "TOKEN_DISPATCH_UNREAD_ROWS", 0)
     out = _moe_block(*args, **kw)
     monkeypatch.undo()
     return out
@@ -193,7 +193,7 @@ def test_idle_lanes_pick_nothing(monkeypatch):
                                np.asarray(dense)[[0, 2]], rtol=1e-5,
                                atol=1e-5)
     with jax.named_scope("shared"):
-        shared = np.asarray(llama._mlp_block(x, lp, ("ws_g", "ws_u", "ws_d")))
+        shared = np.asarray(ffn._mlp_block(x, lp, ("ws_g", "ws_u", "ws_d")))
     np.testing.assert_allclose(np.asarray(token)[1], shared[1], rtol=1e-6,
                                atol=1e-6)
     w = weights(x, lp, cfg)
@@ -236,7 +236,7 @@ def _registered():
 
 
 # the launches under TOKEN_DISPATCH_MIN_ROWS that dispatch by token (an
-# expected unread share of TOKEN_DISPATCH_MIN_UNREAD or more: llama.py's chip
+# expected unread share of TOKEN_DISPATCH_MIN_UNREAD or more: ffn.py's chip
 # table); every other launch of a registered configuration keeps the form
 # PR 45 gave it
 UNREAD = {
@@ -256,10 +256,10 @@ def test_the_rule_at_every_registered_launch(name, rows, what):
     form = moe_dispatch_form(rows, cfg.num_experts, cfg.num_experts_per_tok,
                              False, cfg.num_router_experts)
     if (name, rows, what) in UNREAD:
-        assert form == "token" and rows < llama.TOKEN_DISPATCH_MIN_ROWS
+        assert form == "token" and rows < ffn.TOKEN_DISPATCH_MIN_ROWS
     else:
         assert form == (
-            "token" if rows >= llama.TOKEN_DISPATCH_MIN_ROWS else "dense")
+            "token" if rows >= ffn.TOKEN_DISPATCH_MIN_ROWS else "dense")
     # a mesh keeps the dense einsums, whatever the rows
     assert moe_dispatch_form(rows, cfg.num_experts, cfg.num_experts_per_tok,
                              True, cfg.num_router_experts) == "dense"
@@ -283,7 +283,7 @@ def test_the_logit_checks_own_launches(name):
     assert moe_dispatch_form(n_prefill, *shape) == (
         "dense" if name == "mixtral-8x7b" else "token")
     if (name, ROUTED[name][1]["max_batch"], "decode") in UNREAD:
-        assert n_prefill >= llama.TOKEN_DISPATCH_MIN_ROWS
+        assert n_prefill >= ffn.TOKEN_DISPATCH_MIN_ROWS
 
 
 def test_the_rule_counts_the_experts_the_router_knows():
@@ -299,7 +299,7 @@ def test_the_rule_counts_the_experts_the_router_knows():
     # int8 experts: by token only where dense dispatch is compute-bound
     assert moe_dispatch_form(16, 64, 2, False, None, True) == "dense"
     assert moe_dispatch_form(
-        llama.TOKEN_DISPATCH_MIN_ROWS, 64, 2, False, None, True) == "token"
+        ffn.TOKEN_DISPATCH_MIN_ROWS, 64, 2, False, None, True) == "token"
 
 
 def test_the_block_traces_the_grouped_matmul_where_the_rule_says_token():
@@ -323,7 +323,7 @@ def test_int8_experts_keep_the_dense_form_at_decode():
     # (no shared branch: quantize_params has no table for one)
     cfg = ModelConfig(**dict(SOFTMAX, num_experts=64, num_experts_per_tok=2))
     params = quantize_params(init_params(cfg, jax.random.PRNGKey(0)), cfg)
-    assert llama.experts_int8(params["layers"])
+    assert ffn.experts_int8(params["layers"])
     ids = jnp.arange(16, dtype=jnp.int32)[:, None]
     text = str(jax.make_jaxpr(lambda p: forward(
         p, cfg, ids, jnp.zeros_like(ids), expert_reads=True))(params))

@@ -31,7 +31,7 @@ from kafka_tpu.models.config import (  # noqa: E402
     UnsupportedConfigError,
     config_from_hf_json,
 )
-from kafka_tpu.models.llama import _moe_block  # noqa: E402
+from kafka_tpu.models.ffn import _moe_block  # noqa: E402
 from kafka_tpu.runtime import EngineConfig, GenRequest, InferenceEngine  # noqa: E402
 from kafka_tpu.runtime.engine import (  # noqa: E402
     RoutedTreeUnsupported,
@@ -591,7 +591,7 @@ def test_token_dispatch_compiles_in_mellum2s_1536_row_launch(
     """The logit check's launch of `mellum2-12b-a2.5b` whole, at its real
     widths: XLA's gather of the 1,536 x 2,304 bf16 rows into expert order
     asked for 16.41 MiB of the 16 MiB of scoped VMEM a fusion has (PR 45, on
-    the chip and here alike; models/llama.py GATHER_VMEM_WINDOW)."""
+    the chip and here alike; models/ffn.py GATHER_VMEM_WINDOW)."""
     path = os.path.join(BENCH, "configs", "mellum2-12b-a2.5b.json")
     with open(path) as f:
         srv = json.load(f)["serving"]

@@ -33,14 +33,15 @@ import jax
 import jax.numpy as jnp
 
 from kafka_tpu.models import ModelConfig, forward, init_params
-from kafka_tpu.models import llama
 from kafka_tpu.models.config import (
     CONFIGS, PARALLEL, UnsupportedConfigError, config_from_hf_json,
     holds_rows, holds_state,
 )
-from kafka_tpu.models.hybrid import (
+from kafka_tpu.models.cache import (
     HybridPathError, StatePlan, _read_state, _write_state,
 )
+from kafka_tpu.models.init_params import _init_parallel_params
+from kafka_tpu.models.mixers.state import ssd_mup_vector
 from kafka_tpu.ops.pallas import ssd as sk
 from kafka_tpu.runtime import EngineConfig, GenRequest, InferenceEngine
 from kafka_tpu.runtime.engine import RecurrentStateUnsupported
@@ -198,8 +199,8 @@ def test_config_from_hf_json_honours_every_key(tmp_path):
         "attention_out_multiplier", "key_multiplier", "ssm_in_multiplier",
         "ssm_out_multiplier")}, ssm_multipliers=None, mlp_multipliers=None)
     assert bare.ssm_multipliers == () and bare.mlp_multipliers == ()
-    assert llama.ssd_mup_vector(bare) is None
-    mup = llama.ssd_mup_vector(cfg)
+    assert ssd_mup_vector(bare) is None
+    mup = ssd_mup_vector(cfg)
     assert len(mup) == 9248 and mup[0] == mup[4095] == cfg.ssm_multipliers[0]
     assert mup[4096] == 0.25 and mup[8192] == cfg.ssm_multipliers[2]
     assert mup[8704] == 0.5 and mup[9216] == mup[-1] == cfg.ssm_multipliers[4]
@@ -530,7 +531,7 @@ def test_the_unscaled_initialiser_would_blind_the_check(model):
     at most of them (a fiftieth of what it moves under the scaled one).  The
     SSM branch is then seen through its D skip alone: the scan is not."""
     cfg, params = model
-    unscaled = llama._init_parallel_params(
+    unscaled = _init_parallel_params(
         cfg, jax.random.split(jax.random.PRNGKey(0), 10), jnp.float32,
         scaled=False)
     errors = _variant_errors(cfg, unscaled)

@@ -35,8 +35,8 @@ from kafka_tpu.models.config import (
     config_from_hf_json,
 )
 from kafka_tpu.models import hybrid
-from kafka_tpu.models.hybrid import HybridPathError
-from kafka_tpu.models.llama import KVCache, init_kv_cache
+from kafka_tpu.models.cache import HybridPathError, KVCache
+from kafka_tpu.models.llama import init_kv_cache
 from kafka_tpu.ops.pallas import (
     paged_decode_attention, paged_decode_attention_window,
     paged_prefill_attention,

@@ -2,7 +2,7 @@
 
 The paged pools become QTensor pytrees (int8 rows + per-slot f32 scales,
 runtime/kv_cache.py) and the attention layer quantizes at write /
-dequantizes at gather (models/llama.py _kv_write/_kv_read).  Covered:
+dequantizes at gather (models/cache.py _kv_write/_kv_read).  Covered:
 roundtrip error bounds, engine serving vs the dense-KV engine, pool
 sharing (prefix cache) with quantized pages, TP-mesh consistency, and the
 config wiring.
@@ -15,7 +15,7 @@ import jax
 import jax.numpy as jnp
 
 from kafka_tpu.models import ModelConfig, init_params
-from kafka_tpu.models.llama import _kv_read, _kv_write
+from kafka_tpu.models.cache import _kv_read, _kv_write
 from kafka_tpu.models.quant import QTensor
 from kafka_tpu.runtime import EngineConfig, GenRequest, InferenceEngine
 from kafka_tpu.runtime.kv_cache import make_kv_pool_arrays

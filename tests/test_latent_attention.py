@@ -41,11 +41,11 @@ from kafka_tpu.models import ModelConfig, forward, init_params
 from kafka_tpu.models.config import (
     CONFIGS, UnsupportedConfigError, config_from_hf_json,
 )
-from kafka_tpu.models import llama
-from kafka_tpu.models.llama import (
-    KVCache, LatentPathError, PagedView, _moe_block, _routing_weights_sigmoid,
-    init_kv_cache,
-)
+from kafka_tpu.models.cache import KVCache, PagedView
+from kafka_tpu.models.ffn import _moe_block, _routing_weights_sigmoid
+from kafka_tpu.models.llama import init_kv_cache
+from kafka_tpu.models.mixers import latent
+from kafka_tpu.models.mixers.index import LatentPathError
 from kafka_tpu.ops.pallas import paged_attention
 from kafka_tpu.ops.pallas import paged_decode_attention_latent
 from kafka_tpu.runtime import EngineConfig, GenRequest, InferenceEngine
@@ -585,7 +585,7 @@ def short_trips(monkeypatch):
     """16-key trips of prefill's key walk instead of 1,024, so a 64-key
     window is a walk of up to four and a 100-key context one of seven (read
     when a program traces; tests/test_latent_prefill_fold.py runs under it)."""
-    monkeypatch.setattr(llama, "PREFILL_WALK_KEYS", TRIP)
+    monkeypatch.setattr(latent, "PREFILL_WALK_KEYS", TRIP)
 
 
 @pytest.mark.parametrize("backend", ["xla", "pallas"])

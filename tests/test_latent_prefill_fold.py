@@ -1,6 +1,6 @@
 """Latent prefill's key walk, the Pallas fold against the XLA fold (PR 34).
 
-`models/llama.py::_latent_prefill_walk` is one algorithm with two executors
+`models/mixers/latent.py::_latent_prefill_walk` is one algorithm with two executors
 of a trip's fold: XLA ops (the `xla` backend's form and the reference here)
 and `ops/pallas/latent_prefill.latent_prefill_fold` (the `pallas` backend's,
 interpreted on the CPU).  At the tiny dots3 preset's two geometries, float32
@@ -38,7 +38,10 @@ import pytest
 
 sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 
-from kafka_tpu.models import llama
+from kafka_tpu.models import init_params
+from kafka_tpu.models.cache import PagedView
+from kafka_tpu.models.mixers import latent
+from kafka_tpu.models.mixers.index import _chosen_mask
 from kafka_tpu.models.config import CONFIGS, GLOBAL, WINDOWED
 from kafka_tpu.ops.pallas import latent_prefill
 from kafka_tpu.runtime import GenRequest, step_programs
@@ -85,7 +88,7 @@ def _case(kind, spans, rows, seed=0, cfg=None, table_pages=TABLE):
     positions = starts[:, None] + np.arange(rows)[None, :]
     kv_pos = np.broadcast_to(np.arange(C), (b, C))
     kv_valid = (kv_pos < (starts + lens)[:, None]) & (lens > 0)[:, None]
-    paged = llama.PagedView(
+    paged = PagedView(
         write_idx=None, read_idx=None,
         kv_positions=jnp.asarray(kv_pos, jnp.int32),
         kv_valid=jnp.asarray(kv_valid), page_table=jnp.asarray(table),
@@ -94,7 +97,7 @@ def _case(kind, spans, rows, seed=0, cfg=None, table_pages=TABLE):
     if cfg.has_indexer(kind):
         scores = jnp.asarray(rng.randn(b, rows, C).astype(np.float32))
         causal = kv_valid[:, None] & (kv_pos[:, None] <= positions[..., None])
-        chosen = llama._chosen_mask(scores, jnp.asarray(causal), TOPK)
+        chosen = _chosen_mask(scores, jnp.asarray(causal), TOPK)
     return dict(
         q_nope=jnp.asarray(rng.randn(b, rows, n, dn), jnp.float32),
         q_rope=jnp.asarray(rng.randn(b, rows, n, dr), jnp.float32),
@@ -109,7 +112,7 @@ def _case(kind, spans, rows, seed=0, cfg=None, table_pages=TABLE):
 def _both(case):
     with jax.default_matmul_precision("highest"):
         return [np.asarray(jax.jit(
-            lambda: llama._latent_prefill_walk(**case, kernel=kernel))())
+            lambda: latent._latent_prefill_walk(**case, kernel=kernel))())
             for kernel in (False, True)]
 
 
@@ -200,14 +203,14 @@ def test_blocks_come_from_the_shapes():
 @pytest.fixture(scope="module")
 def model():
     cfg = sparse_cfg()
-    return cfg, llama.init_params(cfg, jax.random.PRNGKey(5))
+    return cfg, init_params(cfg, jax.random.PRNGKey(5))
 
 
 @pytest.fixture(scope="module")
 def uniform_model():
     """Six latent layers of one kind (a dense one, then five routed)."""
     cfg = latent_cfg(num_layers=6)
-    return cfg, llama.init_params(cfg, jax.random.PRNGKey(5))
+    return cfg, init_params(cfg, jax.random.PRNGKey(5))
 
 
 # one launch, 16-key trips over a 128-key table.  sparse: 3 full layers walk
@@ -232,7 +235,7 @@ def test_engine_counts_the_trips_the_device_loops(request, which, backend):
     want, batched = WALKS[which]
     if not kernel:
         # the XLA fold shrinks a trip at many rows; 8 rows do not
-        assert llama.prefill_walk_pages(16, 8, 8, False) == 2
+        assert latent.prefill_walk_pages(16, 8, 8, False) == 2
     assert eng._programs.prefill_walk_trips([(37, 8)], 1, 8) == (
         want, want if kernel else 0)
     assert eng._programs.prefill_walk_trips(
@@ -249,7 +252,7 @@ def test_engine_counts_the_trips_the_device_loops(request, which, backend):
 
 def test_a_model_that_does_not_walk_counts_nothing():
     cfg = CONFIGS["tiny"].replace(dtype="float32")
-    eng = make_engine(cfg, llama.init_params(cfg, jax.random.PRNGKey(1)),
+    eng = make_engine(cfg, init_params(cfg, jax.random.PRNGKey(1)),
                       max_pages_per_seq=16)
     eng.submit(GenRequest(request_id="a", prompt_ids=[3, 5, 7, 11, 13],
                           max_new_tokens=2))
@@ -315,7 +318,7 @@ def _prefill_text(name, backend, program):
     cfg = {"gqa": CONFIGS["tiny"], "sparse": sparse_cfg()}[name].replace(
         dtype="float32", attention_backend=backend)
     params = jax.eval_shape(
-        lambda: llama.init_params(cfg, jax.random.PRNGKey(0)))
+        lambda: init_params(cfg, jax.random.PRNGKey(0)))
     pools = jax.eval_shape(
         lambda: make_kv_pool_arrays(cfg, 64, 8, jnp.float32))
 

@@ -34,9 +34,9 @@ from kafka_tpu.models.config import (
     CONFIGS, GLOBAL, WINDOWED, RopeParams, UnsupportedConfigError,
     config_from_hf_json,
 )
-from kafka_tpu.models.llama import (
-    KVCache, PagedView, WindowedPathError, init_kv_cache,
-)
+from kafka_tpu.models.cache import KVCache, PagedView
+from kafka_tpu.models.llama import init_kv_cache
+from kafka_tpu.models.mixers.gqa import WindowedPathError
 from kafka_tpu.ops.attention import causal_attention
 from kafka_tpu.ops.pallas import (
     paged_decode_attention, paged_decode_attention_window,

@@ -22,9 +22,10 @@ import jax
 import jax.numpy as jnp
 
 from kafka_tpu.models import ModelConfig, init_params
-from kafka_tpu.models.llama import (
-    KVCache, PagedView, _attention_block, _logits_head, _mlp_block, forward,
-)
+from kafka_tpu.models.cache import KVCache, PagedView
+from kafka_tpu.models.ffn import _mlp_block
+from kafka_tpu.models.llama import _logits_head, forward
+from kafka_tpu.models.mixers.gqa import _attention_block
 from kafka_tpu.models.quant import QTensor
 from kafka_tpu.ops.norms import rms_norm
 from kafka_tpu.ops.rope import rope_cos_sin, rope_frequencies

@@ -33,8 +33,8 @@ from kafka_tpu.models import ModelConfig, forward, init_params
 from kafka_tpu.models.config import (
     CONV, GLOBAL, UnsupportedConfigError, config_from_hf_json,
 )
-from kafka_tpu.models.hybrid import HybridPathError
-from kafka_tpu.models.llama import KVCache, init_kv_cache
+from kafka_tpu.models.cache import HybridPathError, KVCache
+from kafka_tpu.models.llama import init_kv_cache
 from kafka_tpu.runtime import EngineConfig, GenRequest, InferenceEngine
 from kafka_tpu.runtime.engine import RecurrentStateUnsupported
 from kafka_tpu.runtime.kv_cache import default_state_slots, make_kv_pool_arrays

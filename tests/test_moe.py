@@ -39,10 +39,10 @@ def make_engine(cfg, params, mesh=None, **kw):
 
 class TestMoEBlock:
     def test_matches_expert_module_reference(self, moe_model):
-        """models/llama.py:_moe_block == parallel/expert.py's validated
+        """models/ffn.py:_moe_block == parallel/expert.py's validated
         dense-dispatch reference, layer by layer."""
         cfg, params = moe_model
-        from kafka_tpu.models.llama import _moe_block
+        from kafka_tpu.models.ffn import _moe_block
 
         x = jax.random.normal(jax.random.PRNGKey(3), (2, 5, cfg.hidden_size),
                               jnp.float32)

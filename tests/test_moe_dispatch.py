@@ -1,4 +1,4 @@
-"""The routed block's two forms (models/llama.py `_moe_block`): token dispatch
+"""The routed block's two forms (models/ffn.py `_moe_block`): token dispatch
 (picks sorted by expert, one grouped matmul a projection) against dense
 dispatch (every row through every held expert), row for row in float32; the
 rule that chooses between them from the pass's shape; pad rows in no group;
@@ -15,11 +15,11 @@ import jax
 import jax.numpy as jnp
 
 from kafka_tpu.models import forward, init_params
-from kafka_tpu.models import llama
+from kafka_tpu.models import ffn
+from kafka_tpu.models.cache import KVCache
 from kafka_tpu.models.config import CONFIGS, ModelConfig, config_from_hf_json
-from kafka_tpu.models.llama import (
+from kafka_tpu.models.ffn import (
     TOKEN_DISPATCH_MIN_ROWS,
-    KVCache,
     _moe_block,
     moe_dispatch_form,
 )
@@ -81,8 +81,8 @@ def both_forms(monkeypatch, x, lp, cfg, chunk_len=None):
     assert moe_dispatch_form(x.shape[0] * x.shape[1], cfg.num_experts,
                              cfg.num_experts_per_tok, False) == "token"
     token, _ = _moe_block(x, lp, cfg, chunk_len)
-    monkeypatch.setattr(llama, "TOKEN_DISPATCH_MIN_ROWS", 1 << 30)
-    monkeypatch.setattr(llama, "TOKEN_DISPATCH_MIN_UNREAD", 2.0)
+    monkeypatch.setattr(ffn, "TOKEN_DISPATCH_MIN_ROWS", 1 << 30)
+    monkeypatch.setattr(ffn, "TOKEN_DISPATCH_MIN_UNREAD", 2.0)
     dense, _ = _moe_block(x, lp, cfg, chunk_len)
     monkeypatch.undo()
     return np.asarray(token), np.asarray(dense)
@@ -101,10 +101,10 @@ def test_token_form_equals_dense_form_row_for_row(monkeypatch, case):
     np.testing.assert_allclose(token, dense, rtol=1e-5, atol=1e-5)
     assert np.abs(dense).max() > 0.1
     # what the case says of the groups
-    picks = llama._routing_weights_sigmoid(
+    picks = ffn._routing_weights_sigmoid(
         x.reshape(rows, -1), lp["router"], lp["router_bias"],
         cfg.num_experts_per_tok, cfg.routed_scaling_factor, True
-    )[0] if cfg.moe_scoring == "sigmoid" else llama._routing_weights(
+    )[0] if cfg.moe_scoring == "sigmoid" else ffn._routing_weights(
         x.reshape(rows, -1), lp["router"], cfg.num_experts_per_tok, True)[0]
     counts = np.bincount(np.asarray(picks).reshape(-1),
                          minlength=cfg.num_router_experts)
@@ -135,7 +135,7 @@ def test_pad_rows_fall_in_no_group(monkeypatch, chunk_len):
                                atol=1e-5)
     # a pad row's routed output is zero: what is left is the shared expert's
     with jax.named_scope("shared"):
-        shared = np.asarray(llama._mlp_block(x, lp, ("ws_g", "ws_u", "ws_d")))
+        shared = np.asarray(ffn._mlp_block(x, lp, ("ws_g", "ws_u", "ws_d")))
     np.testing.assert_allclose(masked[~real], shared[~real], rtol=1e-6,
                                atol=1e-6)
     assert np.abs(dense[~real] - shared[~real]).max() > 0.1

@@ -9,9 +9,16 @@ parallel/sharding.py applies, so the planner's credibility rests on the
 observed cases matching.
 """
 
+import glob
 import math
+import os
 
-from kafka_tpu.models.config import get_config
+import jax
+import pytest
+
+from kafka_tpu.models import init_params, quantize_params
+from kafka_tpu.models.config import config_from_hf_json, get_config
+from kafka_tpu.models.quant import param_bytes
 from kafka_tpu.runtime.planner import (
     GiB,
     HBM_BYTES,
@@ -192,3 +199,71 @@ class TestMachineReadableFactorization:
             max_pages_per_seq=16, max_batch=4,
         )
         assert plan.kv_shard == 1 and plan.tq == 1
+
+
+# ----------------------------------------------------------------------
+# the planner asks the tree (ISSUE 58): `weight_bytes_per_device` is
+# `param_bytes` of the abstract tree `init_params` builds, no formula of its
+# own.  The numbers below were taken AT THE PARENT (f31dccb, whose leaf-by-leaf
+# formulas they are), so a change of a tree shows here as a changed plan.
+# ----------------------------------------------------------------------
+
+CONFIG_DIR = os.path.join(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))), "benchmarks", "configs")
+PARENT_WEIGHT_BYTES = {
+    "dots3-note-prev": 10_022_188_544,
+    "falcon-h1-34b": 6_690_159_232,
+    "k-exaone-236b-a23b": 8_935_605_760,
+    "kanana-2-30b-a3b": 7_579_169_280,
+    "lfm2-8b-a1b": 9_334_155_520,
+    "mellum2-12b-a2.5b": 7_589_933_568,
+    "mixtral-8x7b": 6_329_376_768,
+    "phi-4-mini-flash-reasoning": 7_707_253_760,
+    "solar-open2-250b": 7_797_691_392,
+    "xing4.0-29b-a4b": 11_332_171_968,
+    "yi-1.5-9b": 7_969_513_472,
+    "yi-1.5-9b-dp4": 7_969_513_472,
+}
+# (preset, mesh) -> the parent's sharded arithmetic, which dividing each
+# leaf by the axes parallel/sharding.param_specs names reproduces
+PARENT_SHARDED_BYTES = {
+    ("llama-3-8b", (("tp", 8),)): 2_927_370_240,
+    ("llama-3-8b", (("tp", 4), ("pp", 2))): 3_058_442_240,
+    ("llama-3-70b", (("tp", 16),)): 10_959_470_592,
+    ("llama-3-70b", (("tp", 8), ("pp", 4))): 6_642_876_416,
+    ("llama-3-70b", (("tp", 8), ("kv_shard", 1))): 21_828_222_976,
+    ("llama-3.2-1b", (("tp", 2), ("pp", 2))): 1_011_945_472,
+    ("mixtral-8x7b", (("ep", 8),)): 14_485_561_344,
+    ("mixtral-8x7b", (("ep", 8), ("tp", 4))): 3_819_970_560,
+    ("mixtral-8x7b", (("ep", 3), ("tp", 2))): 46_835_179_520,
+}
+
+
+@pytest.mark.parametrize("name", sorted(PARENT_WEIGHT_BYTES))
+def test_a_configurations_weights_are_what_the_parent_planned(name):
+    cfg = config_from_hf_json(os.path.join(CONFIG_DIR, name + ".json"))
+    assert weight_bytes_per_device(cfg) == PARENT_WEIGHT_BYTES[name]
+
+
+def test_every_configuration_file_has_its_weights_pinned():
+    files = {os.path.basename(p)[:-len(".json")]
+             for p in glob.glob(os.path.join(CONFIG_DIR, "*.json"))}
+    assert files == set(PARENT_WEIGHT_BYTES)
+
+
+@pytest.mark.parametrize("preset, mesh", sorted(PARENT_SHARDED_BYTES))
+def test_a_mesh_divides_each_leaf_by_its_specs_axes(preset, mesh):
+    assert weight_bytes_per_device(
+        get_config(preset), **dict(mesh)) == PARENT_SHARDED_BYTES[preset, mesh]
+
+
+@pytest.mark.parametrize("preset", ["tiny-gqa", "tiny-moe"])
+def test_an_int8_plan_counts_the_quantized_tree(preset):
+    """Every scale `quantize_params` really makes (the parent's formula
+    missed some: 512 B on each of these two)."""
+    cfg = get_config(preset)
+    held = param_bytes(quantize_params(
+        init_params(cfg, jax.random.PRNGKey(0)), cfg))
+    assert weight_bytes_per_device(cfg, quantize="int8") == held
+    assert weight_bytes_per_device(cfg) == param_bytes(
+        init_params(cfg, jax.random.PRNGKey(0)))

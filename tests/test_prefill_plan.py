@@ -447,7 +447,7 @@ def test_a_sliding_layers_walk_is_bounded_by_its_window():
     """dots3's sliding kind (513 keys): walked from the chunk that holds the
     first query's window, so never more than the window, the launch's own
     tokens and one chunk of the walk."""
-    from kafka_tpu.models.llama import PREFILL_WALK_KEYS
+    from kafka_tpu.models.mixers.latent import PREFILL_WALK_KEYS
 
     cfg, _, _ = served("dots3-note-prev")
     cm = planner.dispatch_cost_model(cfg)

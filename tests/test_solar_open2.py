@@ -32,7 +32,7 @@ from kafka_tpu.models import ModelConfig, forward, init_params
 from kafka_tpu.models.config import (
     DELTA, GLOBAL, UnsupportedConfigError, config_from_hf_json,
 )
-from kafka_tpu.models.hybrid import (
+from kafka_tpu.models.cache import (
     HybridPathError, StatePlan, _read_state, _write_state,
 )
 from kafka_tpu.ops.pallas import gated_delta as gd

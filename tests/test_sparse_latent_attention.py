@@ -40,14 +40,11 @@ from kafka_tpu.models.config import (
     UnsupportedConfigError,
     config_from_hf_json,
 )
-from kafka_tpu.models import llama
-from kafka_tpu.models.llama import (
-    INDEX,
-    _chosen_mask,
-    _compact_chosen,
-    _moe_block,
-    init_kv_cache,
-)
+from kafka_tpu.models.cache import INDEX
+from kafka_tpu.models.ffn import _moe_block
+from kafka_tpu.models.llama import init_kv_cache
+from kafka_tpu.models.mixers import index
+from kafka_tpu.models.mixers.index import _chosen_mask, _compact_chosen
 from kafka_tpu.ops.pallas import paged_decode_attention_latent
 from kafka_tpu.runtime import EngineConfig, GenRequest, InferenceEngine, planner
 from kafka_tpu.runtime.engine import (
@@ -683,20 +680,20 @@ def test_shared_trips_choose_what_per_lane_trips_choose(monkeypatch, case):
 
     def fn(table, lens, active):
         positions, paged = decode_plan(table, lens, active, ps)
-        scores = llama._paged_index_scores(q, w, pool, paged, jnp.float32)
-        slots, ok = llama._paged_index_choice(
+        scores = index._paged_index_scores(q, w, pool, paged, jnp.float32)
+        slots, ok = index._paged_index_choice(
             q, w, pool, paged, positions, cfg, jnp.float32)
         return jnp.where(paged.kv_valid[:, None], scores, 0), slots, ok
 
     def run(split):
         with monkeypatch.context() as m:
-            m.setattr(llama, "INDEX_WALK_KEYS", 32)
+            m.setattr(index, "INDEX_WALK_KEYS", 32)
             if not split:
-                m.setattr(llama, "_common_pages",
+                m.setattr(index, "_common_pages",
                           lambda paged: (jnp.int32(0), jnp.int32(0)))
             return [np.asarray(x) for x in jax.jit(fn)(table, lens, active)]
 
-    lane, found = jax.jit(lambda *a: llama._common_pages(
+    lane, found = jax.jit(lambda *a: index._common_pages(
         decode_plan(*a, ps)[1]))(table, lens, active)
     assert (int(lane), int(found)) == (int(np.argmax(active)), common)
     scores, slots, ok = run(split=True)

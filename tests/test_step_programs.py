@@ -409,13 +409,13 @@ def test_runtime_does_not_import_the_llm_tier():
 
 
 def test_the_index_plan_has_one_home():
-    """`PagedView(` is constructed by the plan functions, by models/llama.py
+    """`PagedView(` is constructed by the plan functions, by models/cache.py
     (which defines it) and by parallel/pipeline.py's re-wrap of arrays it
     is handed; the engine holds no program, jit or cache of its own but
     the `_fsm_advance` helper."""
     homes = {str(p.relative_to(ROOT)) for p in ROOT.rglob("*.py")
              if "PagedView(" in p.read_text()}
-    assert homes == {"runtime/step_programs.py", "models/llama.py",
+    assert homes == {"runtime/step_programs.py", "models/cache.py",
                      "parallel/pipeline.py"}
     engine = (ROOT / "runtime" / "engine.py").read_text()
     assert "_jit_step" not in engine and "_FN_CACHE" not in engine

@@ -32,7 +32,7 @@ import jax
 import jax.numpy as jnp
 
 from kafka_tpu.models import ModelConfig, forward, init_params
-from kafka_tpu.models import llama
+from kafka_tpu.models import residual
 from kafka_tpu.models.config import (
     GLOBAL, RopeParams, UnsupportedConfigError, config_from_hf_json,
 )
@@ -253,7 +253,7 @@ def test_sinkhorn_is_doubly_stochastic_for_inputs_clamped_at_both_ends(n):
     logits[0, 2, ::2] = 45.0                  # both ends in one matrix
     logits[0, 2, 1::2] = -45.0
     logits[0, 3] = 2.0 * np.eye(n).reshape(-1) + 31.0
-    m = llama._sinkhorn(jnp.asarray(logits), cfg)
+    m = residual._sinkhorn(jnp.asarray(logits), cfg)
     mat = np.stack([np.stack([np.asarray(v) for v in row], -1) for row in m],
                    -2)  # [..., i, j]
     assert mat.shape == (5, 7, n, n) and np.isfinite(mat).all()
@@ -273,14 +273,14 @@ def test_sinkhorn_is_doubly_stochastic_for_inputs_clamped_at_both_ends(n):
         jnp.asarray(logits).reshape(-1, n, n), hp)).reshape(mat.shape)
     np.testing.assert_allclose(mat, want, atol=1e-6)
     # the clamp binds: 100 and 31 + 2 I give what 30 gives
-    flat = llama._sinkhorn(jnp.full((n * n,), 30.0), cfg)
+    flat = residual._sinkhorn(jnp.full((n * n,), 30.0), cfg)
     assert np.asarray(m[0][0])[0, 0] == pytest.approx(
         float(flat[0][0]), abs=1e-6)
 
 
 def test_all_the_rounds_are_in_the_program(model):
     cfg, _ = model
-    jaxpr = str(jax.make_jaxpr(lambda x: llama._sinkhorn(x, cfg))(
+    jaxpr = str(jax.make_jaxpr(lambda x: residual._sinkhorn(x, cfg))(
         jnp.zeros((3, 16))))
     # a round: n row reciprocals and n column reciprocals
     assert jaxpr.count(" div ") == 2 * 4 * cfg.hc_sinkhorn_iters == 160
@@ -326,8 +326,8 @@ def test_the_mix_helpers_are_the_equations(model):
     hp = ref.hyper(cfg)
     with jax.default_matmul_precision("highest"):
         pre, post, res = ref._mappings(X, lp, "mlp", hp)
-        u, maps = llama._hc_in(X.reshape(1, 9, 256), lp, "mlp", cfg)
-        out = llama._hc_out(X.reshape(1, 9, 256), y[None], maps, "mlp")
+        u, maps = residual._hc_in(X.reshape(1, 9, 256), lp, "mlp", cfg)
+        out = residual._hc_out(X.reshape(1, 9, 256), y[None], maps, "mlp")
     np.testing.assert_allclose(
         u[0], jnp.einsum("sn,snc->sc", pre, X), atol=1e-5)
     want = (jnp.einsum("sij,sjc->sic", res, X)
@@ -336,8 +336,8 @@ def test_the_mix_helpers_are_the_equations(model):
     # one row a token: h and h + y, and nothing else
     plain = tiny_cfg(n=1)
     h = X[:, 0][None]
-    assert llama._hc_in(h, {}, "attn", plain) == (h, None)
-    np.testing.assert_array_equal(llama._hc_out(h, y[None], None, "mlp"),
+    assert residual._hc_in(h, {}, "attn", plain) == (h, None)
+    np.testing.assert_array_equal(residual._hc_out(h, y[None], None, "mlp"),
                                   h + y[None])
 
 

@@ -25,7 +25,8 @@ import jax
 import jax.numpy as jnp
 
 from kafka_tpu.models import ModelConfig, init_params
-from kafka_tpu.models.llama import _attention_core, _kv_read_pages
+from kafka_tpu.models.cache import _kv_read_pages
+from kafka_tpu.models.mixers.gqa import _attention_core
 from kafka_tpu.models.quant import quantize_array
 from kafka_tpu.ops.attention import (
     DECODE_WALK_KEYS,
