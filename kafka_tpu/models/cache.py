@@ -201,11 +201,15 @@ class StatePlan(NamedTuple):
 def _read_state(leaf, layer, plan: StatePlan, batch: int):
     """Lanes' incoming state of state layer `layer`, [B, ...] float32, read
     where it lies in the stacked leaf [n, n_slots, ...] (no layer's slots are
-    sliced out: the leaf is the layer scan's carry)."""
+    sliced out: the leaf is the layer scan's carry).  A prefill launch's rows
+    (`plan.src`) are a value of their own before anything is written: left to
+    fuse into `_write_state`'s write of ANOTHER slot of the same buffer, the
+    read makes XLA copy the whole leaf ahead of every write (PERF.md section
+    6, PR 59)."""
     if plan.src is None:
         return jax.lax.dynamic_slice(
             leaf, (layer, 0, 0, 0), (1, batch) + leaf.shape[2:])[0]
-    rows = leaf[layer, plan.src]
+    rows = jax.lax.optimization_barrier(leaf[layer, plan.src])
     if plan.fresh is not None:
         # (an inactive lane writes back what it read: not zeros)
         fresh = plan.fresh & (plan.lens > 0)
@@ -215,11 +219,19 @@ def _read_state(leaf, layer, plan: StatePlan, batch: int):
 
 def _write_state(leaf, layer, plan: StatePlan, new, old):
     """`leaf` with the lanes' outgoing state of state layer `layer` written
-    (an inactive lane writes back what it read)."""
+    (an inactive lane writes back what it read).  A prefill launch writes one
+    slot a lane, `dst` of every lane and then `snap` of every lane, each a
+    slice update that the layer scan makes in place (a scatter over the slot
+    axis kept a copy of the leaf a write; the lanes of a prefill are few and
+    static; slot ids are the engine's, in range: a slice update clamps one
+    that is not, where the scatter dropped it)."""
     new = jnp.where((plan.lens > 0)[:, None, None], new, old).astype(leaf.dtype)
     if plan.dst is None:
         return jax.lax.dynamic_update_slice(leaf, new[None], (layer, 0, 0, 0))
-    leaf = leaf.at[layer, plan.dst].set(new)
-    if plan.snap is not None:
-        leaf = leaf.at[layer, plan.snap].set(new)
+    for slots in (plan.dst, plan.snap):
+        if slots is None:
+            continue
+        for lane in range(new.shape[0]):
+            leaf = jax.lax.dynamic_update_slice(
+                leaf, new[lane][None, None], (layer, slots[lane], 0, 0))
     return leaf
