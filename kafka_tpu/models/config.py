@@ -20,7 +20,11 @@ delta-rule linear attention, each with a matrix a head in a state slot, beside
 gated full-attention layers that do not rotate.  A fourth (`falcon_h1`:
 Falcon-H1-34B) is a dense decoder every layer of which holds rows AND a
 state: grouped-query attention and a Mamba-2 (SSD) mixer side by side on one
-normed input, under the family's muP multipliers.
+normed input, under the family's muP multipliers.  A fifth (`nemotron_h`:
+Nemotron-3-Nano-30B-A3B) gives every layer ONE sublayer: a Mamba-2 mixer that
+stands alone, grouped-query attention that does not rotate, or a routed
+feed-forward of ungated squared-ReLU experts, by a pattern of three kinds;
+which half a layer has is its kind's (`mixer_of`, `has_ffn`).
 
 The reference service routed model names to remote providers by string
 heuristics (src/llm/utils.py:11-29); here a model name resolves to a local
@@ -50,7 +54,11 @@ from .vision import VisionConfig
 # [head_dim, head_dim] matrix a head beside the tails of its three short
 # convolutions, and no rows; PARALLEL is `falcon_h1`'s layer, grouped-query
 # attention and a Mamba-2 (SSD) mixer on the same normed input, their outputs
-# summed into the residual: the one kind that holds rows AND a state.
+# summed into the residual: the one kind that holds rows AND a state.  Two are
+# `nemotron_h`'s, whose layers hold ONE sublayer each: MAMBA2, a Mamba-2 (SSD)
+# mixer that stands alone (a state, no rows, no feed-forward), and MOE, a
+# routed feed-forward that stands alone (no mixer, no state, no rows); beside
+# them a GLOBAL layer is attention alone.
 WINDOWED = "sliding_attention"
 GLOBAL = "full_attention"
 MAMBA = "mamba"
@@ -59,6 +67,8 @@ CROSS = "cross_attention"
 CONV = "conv"
 DELTA = "linear_attention"
 PARALLEL = "parallel_ssd_attention"
+MAMBA2 = "mamba2"
+MOE = "moe"
 
 
 def holds_rows(kind: str) -> bool:
@@ -68,7 +78,7 @@ def holds_rows(kind: str) -> bool:
 
 def holds_state(kind: str) -> bool:
     """A layer of `kind` holds a recurrent state (a state slot a thread)."""
-    return kind in (MAMBA, CONV, DELTA, PARALLEL)
+    return kind in (MAMBA, CONV, DELTA, PARALLEL, MAMBA2)
 
 
 class UnsupportedConfigError(ValueError):
@@ -330,8 +340,22 @@ class ModelConfig:
     ssm_out_multiplier: float = 1.0
     ssm_multipliers: Tuple[float, ...] = ()
     mlp_multipliers: Tuple[float, ...] = ()
+    # The feed-forward's activation, a row of models/ffn.ACTIVATIONS: "silu"
+    # is the gated SwiGLU every other model has (gate, up and down matrices);
+    # "relu2" is `nemotron_h`'s UNGATED squared ReLU, down(relu(up(x)) ** 2):
+    # two matrices a block, in the experts and the shared branch alike.
+    mlp_act: str = "silu"
 
     def __post_init__(self):
+        if self.mlp_act not in ("silu", "relu2"):
+            raise UnsupportedConfigError(
+                f"mlp_act {self.mlp_act!r}: known 'silu' (gated), 'relu2' "
+                "(ungated squared ReLU)")
+        if self.mlp_act != "silu" and not self.lone_layers:
+            raise UnsupportedConfigError(
+                f"mlp_act {self.mlp_act!r} is built in the one-sublayer "
+                "layout's routed feed-forward only (layer_types naming "
+                f"{MOE!r} layers)")
         if self.moe_scoring not in ("softmax", "sigmoid"):
             raise UnsupportedConfigError(
                 f"moe_scoring {self.moe_scoring!r}: known 'softmax', "
@@ -397,7 +421,7 @@ class ModelConfig:
             {MAMBA, GMU, CROSS} if self.mamba_d_state else set()) | (
             {CONV} if self.conv_L_cache else set()) | (
             {DELTA} if self.delta_heads else set()) | (
-            {PARALLEL} if self.ssd_heads else set())
+            {PARALLEL, MAMBA2, MOE} if self.ssd_heads else set())
         bad = set(self.layer_types) - known
         if bad:
             raise UnsupportedConfigError(
@@ -409,7 +433,9 @@ class ModelConfig:
             self._check_conv_layout()
         if self.delta_heads:
             self._check_delta_layout()
-        if self.ssd_heads:
+        if self.lone_layers or MAMBA2 in self.layer_types:
+            self._check_lone_layout()
+        elif self.ssd_heads:
             self._check_parallel_layout()
         if len(self.layer_types) != self.num_layers:
             raise UnsupportedConfigError(
@@ -515,16 +541,7 @@ class ModelConfig:
                 "an SSD mixer beside attention is served with every layer "
                 f"of kind {PARALLEL}; layer_types is "
                 f"{list(self.layer_types)}")
-        if (self.ssd_head_dim <= 0 or self.ssd_d_state <= 0
-                or self.ssd_groups <= 0 or self.ssd_heads % self.ssd_groups
-                or self.ssd_conv_kernel < 2):
-            raise UnsupportedConfigError(
-                f"the SSD mixer needs a head size (ssd_head_dim = "
-                f"{self.ssd_head_dim}), a state size (ssd_d_state = "
-                f"{self.ssd_d_state}), groups that divide its heads "
-                f"({self.ssd_heads} heads, {self.ssd_groups} groups) and a "
-                f"convolution of two taps or more (ssd_conv_kernel = "
-                f"{self.ssd_conv_kernel})")
+        self._check_ssd_mixer()
         if self.ssm_multipliers and len(self.ssm_multipliers) != 5:
             raise UnsupportedConfigError(
                 f"ssm_multipliers has {len(self.ssm_multipliers)} entries: "
@@ -541,6 +558,60 @@ class ModelConfig:
                 "latent attention, experts, Mamba-1, conv or linear-attention "
                 "layers, or vision tower")
 
+    def _check_ssd_mixer(self) -> None:
+        """What an SSD mixer needs wherever it stands: a state to carry
+        (heads, a head size, a state size), groups that divide the heads, a
+        tail to carry (two taps or more)."""
+        if (self.ssd_head_dim <= 0 or self.ssd_d_state <= 0
+                or self.ssd_groups <= 0 or self.ssd_heads % self.ssd_groups
+                or self.ssd_conv_kernel < 2):
+            raise UnsupportedConfigError(
+                f"the SSD mixer needs a head size (ssd_head_dim = "
+                f"{self.ssd_head_dim}), a state size (ssd_d_state = "
+                f"{self.ssd_d_state}), groups that divide its heads "
+                f"({self.ssd_heads} heads, {self.ssd_groups} groups) and a "
+                f"convolution of two taps or more (ssd_conv_kernel = "
+                f"{self.ssd_conv_kernel})")
+
+    def _check_lone_layout(self) -> None:
+        """The one-sublayer layout (`nemotron_h`): every layer ONE sublayer,
+        a lone SSD mixer (MAMBA2), attention (GLOBAL) or a routed feed-forward
+        (MOE), in whatever order `layer_types` gives, judged by what the
+        program needs of it: an SSD mixer's geometry, rows for some layer to
+        hold (the page table and the prefix cache key on pages), experts
+        routed by the sigmoid rule for the MOE layers, grouped-query
+        attention, one row a token and no second kind of state."""
+        kinds = set(self.layer_types)
+        if kinds - {MAMBA2, GLOBAL, MOE}:
+            raise UnsupportedConfigError(
+                "a one-sublayer pattern is served with layer_types of "
+                f"{MAMBA2}, {GLOBAL} and {MOE} layers; layer_types is "
+                f"{list(self.layer_types)}")
+        if GLOBAL not in kinds:
+            raise UnsupportedConfigError(
+                "layer_types names no full_attention layer: the paged pool "
+                "and the prefix cache need one layer that holds rows")
+        if MOE not in kinds:
+            raise UnsupportedConfigError(
+                f"a lone SSD mixer ({MAMBA2}) is served in a pattern that "
+                f"names {MOE} layers (the one-sublayer layout); layer_types "
+                f"is {list(self.layer_types)}")
+        if MAMBA2 in kinds:
+            self._check_ssd_mixer()
+        if not (self.is_moe and self.moe_scoring == "sigmoid"):
+            raise UnsupportedConfigError(
+                f"a {MOE} layer is a routed feed-forward: it needs experts "
+                "(num_experts) routed by the sigmoid rule")
+        if (self.is_latent or self.mamba_d_state or self.conv_L_cache
+                or self.delta_heads or self.vision is not None
+                or self.first_k_dense or self.hc_mult > 1 or self.qk_norm
+                or self.ssm_multipliers or self.mlp_multipliers):
+            raise UnsupportedConfigError(
+                "the one-sublayer layout stands on grouped-query attention "
+                "and one row a token: no latent attention, Mamba-1, conv or "
+                "linear-attention layers, vision tower, dense lead, widened "
+                "residual stream (hc_mult > 1), QK-norm or muP multipliers")
+
     @property
     def is_moe(self) -> bool:
         return self.num_experts > 0
@@ -556,6 +627,27 @@ class ModelConfig:
         """`phi4flash`'s decoder-hybrid-decoder, a forward pass and a
         parameter tree of its own (models/hybrid.py)."""
         return MAMBA in self.layer_types
+
+    @property
+    def lone_layers(self) -> bool:
+        """Every layer holds ONE sublayer (`nemotron_h`: a mixer OR a
+        feed-forward, one norm, one residual add), which a layout shows by
+        naming layers that are a routed feed-forward alone."""
+        return MOE in self.layer_types
+
+    def has_ffn(self, kind: str) -> bool:
+        """A layer of `kind` has a feed-forward half: every layer, but in the
+        one-sublayer layout the MOE layers alone."""
+        return not self.lone_layers or kind == MOE
+
+    @property
+    def routed_layers(self) -> int:
+        """Layers whose feed-forward is the routed block."""
+        if not self.is_moe:
+            return 0
+        if self.lone_layers:
+            return self.layers_of(MOE)
+        return self.num_layers - self.first_k_dense
 
     @property
     def mamba_d_inner(self) -> int:
@@ -595,8 +687,8 @@ class ModelConfig:
         (ops/pallas/gated_delta.py).  A parallel layer: the tail of its one
         convolution over [x | B | C], laid out by the same rule, and `ssd`,
         the heads' states [head size, state size] stacked along the rows
-        (ops/pallas/ssd.py)."""
-        if PARALLEL in self.layer_types:
+        (ops/pallas/ssd.py); a lone SSD mixer's slot is the same."""
+        if PARALLEL in self.layer_types or MAMBA2 in self.layer_types:
             return (("conv", _tail_layout(self.ssd_conv_kernel - 1,
                                           self.ssd_conv_dim)),
                     ("ssd", (self.ssd_heads * self.ssd_head_dim,
@@ -665,10 +757,12 @@ class ModelConfig:
     def is_windowed(self) -> bool:
         return WINDOWED in self.layer_types
 
-    def mixer_of(self, kind: str) -> str:
+    def mixer_of(self, kind: str) -> Optional[str]:
         """Which mixer a layer of `kind` takes: the key of its row in
-        `models/mixers.MIXERS`."""
-        return {CONV: "conv", DELTA: "delta", PARALLEL: "ssd"}.get(
+        `models/mixers.MIXERS`; None for a kind that has none (a layer that
+        is a feed-forward alone)."""
+        return {CONV: "conv", DELTA: "delta", PARALLEL: "ssd",
+                MAMBA2: "mamba2", MOE: None}.get(
             kind, "latent" if self.is_latent else "gqa")
 
     def window_of(self, kind: str) -> Optional[int]:
@@ -704,9 +798,12 @@ class ModelConfig:
         "dense_layers" hold the norms and the feed-forward leaves: a latent
         model whose kinds differ, and the conv and linear-attention layouts
         (a CONV or DELTA layer's leaves have nothing in common with an
-        attention layer's)."""
+        attention layer's), and the one-sublayer layout, whose routed
+        feed-forward's leaves are stacked per kind too, under
+        `params["ffn"][kind]`, and whose "layers" holds each layer's one
+        norm."""
         return (self.by_kind or CONV in self.layer_types
-                or DELTA in self.layer_types)
+                or DELTA in self.layer_types or self.lone_layers)
 
     @property
     def by_kind(self) -> bool:
@@ -1392,6 +1489,86 @@ def _parallel_keys(hf: dict) -> dict:
     return out
 
 
+# `hybrid_override_pattern`'s letters (`nemotron_h`): one sublayer a layer
+_LONE_KINDS = {"M": MAMBA2, "*": GLOBAL, "E": MOE}
+
+
+def _lone_keys(hf: dict) -> dict:
+    """The keys of a `nemotron_h` config.json (Nemotron-H / Nemotron-3: every
+    layer ONE sublayer by `hybrid_override_pattern`'s letter: M a Mamba-2
+    mixer, * grouped-query attention that does not rotate, E a sigmoid-routed
+    feed-forward of ungated squared-ReLU experts beside one shared expert) as
+    ModelConfig fields; {} for any other model.  What the config has no key
+    for (the order of the input projection's columns, the unclamped step, the
+    grouped gated norm, the absent rotation) is listed as `assumed` beside
+    the benchmark's copy of the file.  What is not served is an
+    UnsupportedConfigError, by key."""
+    if hf.get("model_type") != "nemotron_h":
+        return {}
+    served = (
+        ("mlp_hidden_act", "relu2", "another feed-forward activation"),
+        ("mamba_hidden_act", "silu", "another activation in the mixer"),
+        ("attention_bias", False, "attention biases"),
+        ("mamba_proj_bias", False, "biases on the mixer's projections"),
+        ("mlp_bias", False, "feed-forward biases"),
+        ("use_bias", False, "biases on the projections"),
+        ("use_conv_bias", True, "a convolution without its bias"),
+        ("n_group", 1, "group-limited expert selection"),
+        ("topk_group", 1, "group-limited expert selection"),
+        ("sliding_window", None, "a sliding window"),
+        ("residual_in_fp32", False, "a float32 residual stream"),
+        ("rope_scaling", None, "scaled rotary positions (the attention "
+                               "layers do not rotate)"),
+    )
+    _refuse_unless(hf, served)
+    _refuse_unnormalised_sigmoid(hf)
+    n = int(hf["num_hidden_layers"])
+    # a depth-cut copy keeps the published pattern: the layers that exist
+    letters = str(hf.get("hybrid_override_pattern") or "")[:n]
+    bad = sorted(set(letters) - set(_LONE_KINDS))
+    if bad or len(letters) != n:
+        raise UnsupportedConfigError(
+            f"hybrid_override_pattern = {hf.get('hybrid_override_pattern')!r}"
+            f" for {n} layers: one letter a layer of M (Mamba-2), * "
+            "(attention) and E (routed feed-forward) is served"
+            + (f"; {bad} (a dense MLP layer is '-') is not" if bad else ""))
+    experts = int(hf.get("n_routed_experts") or 0)
+    if not experts:
+        raise UnsupportedConfigError(
+            "n_routed_experts = 0: an E layer is a ROUTED feed-forward")
+    kinds = tuple(_LONE_KINDS[c] for c in letters)
+    # (a copy of the file may spell the pattern out, so that a program that
+    # does not know the kinds refuses it by that name)
+    spelt = tuple(hf.get("layer_types") or kinds)[:n]
+    if spelt != kinds:
+        raise UnsupportedConfigError(
+            f"layer_types {list(spelt)} is not what hybrid_override_pattern "
+            f"{letters!r} says")
+    out = {
+        "layer_types": kinds,
+        "unrotated_kinds": (GLOBAL,),
+        "ssd_heads": int(hf["mamba_num_heads"]),
+        "ssd_head_dim": int(hf["mamba_head_dim"]),
+        "ssd_d_state": int(hf["ssm_state_size"]),
+        "ssd_groups": int(hf.get("n_groups", 1)),
+        "ssd_conv_kernel": int(hf.get("conv_kernel", 4)),
+        "mlp_act": "relu2",
+        "moe_scoring": "sigmoid",
+        "routed_scaling_factor": float(hf.get("routed_scaling_factor", 1.0)),
+        "shared_intermediate_size": int(
+            hf.get("moe_shared_expert_intermediate_size")
+            or int(hf.get("n_shared_experts") or 0)
+            * int(hf["moe_intermediate_size"])),
+        "rms_norm_eps": float(hf.get("layer_norm_epsilon",
+                                     hf.get("norm_eps", 1e-5))),
+    }
+    published = int(hf.get("n_routed_experts_published") or 0)
+    if published:
+        out["num_experts_routed"] = published
+        out["expert_offset"] = int(hf.get("expert_share_offset", 0))
+    return out
+
+
 def config_from_hf_json(path: str) -> ModelConfig:
     """Build a ModelConfig from a HuggingFace config.json: Llama / Mixtral
     keys, the published keys of a patterned routed decoder (Mellum2:
@@ -1400,14 +1577,16 @@ def config_from_hf_json(path: str) -> ModelConfig:
     of a `deepseek_v3` decoder (`_latent_keys`), the same feed-forward keys
     on grouped-query attention (`_routed_lead_keys`: `exaone_moe`), those of
     a `phi4flash` hybrid decoder (`_hybrid_keys`), those of an `lfm2_moe`
-    one (`_conv_keys`), those of a `solar_open2` one (`_delta_keys`) and those
-    of a `falcon_h1` one (`_parallel_keys`).  A key the program cannot honour
+    one (`_conv_keys`), those of a `solar_open2` one (`_delta_keys`), those
+    of a `falcon_h1` one (`_parallel_keys`) and those of a `nemotron_h` one
+    (`_lone_keys`).  A key the program cannot honour
     is an UnsupportedConfigError."""
     with open(path) as f:
         hf = json.load(f)
     latent = _latent_keys(hf)
     routed_lead = _routed_lead_keys(hf)
     delta = _delta_keys(hf)
+    lone = _lone_keys(hf)
     # (a latent model's `rope_scaling` is `_latent_rope_scaling`'s, not the
     # Llama-3 form the model-wide fields hold)
     rs = {} if latent else hf.get("rope_scaling") or {}
@@ -1421,8 +1600,8 @@ def config_from_hf_json(path: str) -> ModelConfig:
     # MoE: `num_local_experts` (HF Mixtral) or `num_experts` with the
     # experts' own width in `moe_intermediate_size`; absent -> 0 = dense
     num_experts = (hf.get("num_local_experts", hf.get("num_experts", 0))
-                   or (hf.get("n_routed_experts", 0) if latent or delta
-                       else 0) or 0)
+                   or (hf.get("n_routed_experts", 0)
+                       if latent or delta or lone else 0) or 0)
     # (a dense LEAD is `_routed_lead_keys`', checked there)
     mlp_kinds = hf.get("mlp_layer_types")
     if routed_lead.get("first_k_dense"):
@@ -1439,7 +1618,7 @@ def config_from_hf_json(path: str) -> ModelConfig:
             "experts, not renormalised) is not served: routing here is a "
             "softmax over exactly the top-k logits")
     hybrid = (_hybrid_keys(hf) or _conv_keys(hf) or delta
-              or _parallel_keys(hf))
+              or _parallel_keys(hf) or lone)
     pattern = {} if "layer_types" in hybrid else _layer_pattern(hf)
     rope_theta = hf.get("rope_theta")
     if rope_theta is None:

@@ -1,7 +1,9 @@
-"""The feed-forward half of a layer: the SwiGLU MLP, and the routed block
+"""The feed-forward half of a layer: the MLP, and the routed block
 (Mixtral's softmax-over-top-k rule and `deepseek_v3`'s sigmoid rule with a
 selection bias and shared experts) in its two forms of the same arithmetic,
-dense and by token, chosen by `moe_dispatch_form`.
+dense and by token, chosen by `moe_dispatch_form`.  The activation is a row of
+`ACTIVATIONS`: gated SiLU (SwiGLU: gate, up and down matrices) or
+`nemotron_h`'s ungated squared ReLU (up and down alone).
 """
 
 from __future__ import annotations
@@ -15,20 +17,45 @@ from .config import ModelConfig
 from .quant import Params, QTensor, _w
 
 
+def _relu2(u: jnp.ndarray) -> jnp.ndarray:
+    r = jax.nn.relu(u)
+    return r * r
+
+
+# `cfg.mlp_act` -> (gated, what stands between the up and the down product):
+# a gated block holds a gate matrix and multiplies act(gate(x)) * up(x), an
+# ungated one holds none and applies act to up(x) itself.
+# An ungated EXPERT's up matrix is stored as published, out x in: "wu" [E, f,
+# H] beside "wd" [E, f, H].  Nemotron-H's f = 1,856 is no whole number of
+# 128-lane tiles, and the chip then lays an [H, f] leaf out with H in the
+# lanes whatever order the program names: handed to the grouped matmul, which
+# wants the named order, the whole 4.3 GB stack was copied ahead of every
+# product (compiled for the v5e, PR 60).  Stored [f, H] the lanes hold H
+# either way and the kernel contracts the minor axis (`transposed`).
+ACTIVATIONS = {
+    "silu": (True, lambda g, u: jax.nn.silu(g) * u),
+    "relu2": (False, lambda _, u: _relu2(u)),
+}
+
+
 def _mlp_block(x: jnp.ndarray, lp: Params,
                names=("wg", "wu", "wd"),
-               multipliers: Tuple[float, ...] = ()) -> jnp.ndarray:
-    """SwiGLU MLP: down( silu(gate(x)) * up(x) ).  `multipliers` (gate,
-    down), a muP model's: the gate's pre-activation and the block's output
-    are scaled, in the activations' dtype."""
-    g = jnp.einsum("bsh,hf->bsf", x, _w(lp, names[0], x.dtype))
+               multipliers: Tuple[float, ...] = (),
+               act: str = "silu") -> jnp.ndarray:
+    """The MLP: SwiGLU, down( silu(gate(x)) * up(x) ), or with an ungated
+    `act` down( act(up(x)) ) (`names[0]` is then read by nobody).
+    `multipliers` (gate, down), a muP model's: the gate's pre-activation and
+    the block's output are scaled, in the activations' dtype."""
+    gated, mid = ACTIVATIONS[act]
+    g = (jnp.einsum("bsh,hf->bsf", x, _w(lp, names[0], x.dtype)) if gated
+         else None)
     u = jnp.einsum("bsh,hf->bsf", x, _w(lp, names[1], x.dtype))
     if not multipliers:
         return jnp.einsum(
-            "bsf,fh->bsh", jax.nn.silu(g) * u, _w(lp, names[2], x.dtype))
+            "bsf,fh->bsh", mid(g, u), _w(lp, names[2], x.dtype))
     gate_m, down_m = (jnp.asarray(m, x.dtype) for m in multipliers)
     return jnp.einsum(
-        "bsf,fh->bsh", jax.nn.silu(g * gate_m) * u,
+        "bsf,fh->bsh", mid(g * gate_m, u),
         _w(lp, names[2], x.dtype)) * down_m
 
 
@@ -162,7 +189,13 @@ def moe_dispatch_form(rows: int, held: int, top_k: int, sharded: bool,
 GATHER_VMEM_WINDOW = (6 << 20, 15 << 19)
 
 # the routed experts' leaves: what token dispatch reads from the layer stack
+# (an ungated expert's tree holds no "wg": `expert_leaves`)
 EXPERT_LEAVES = ("wg", "wu", "wd")
+
+
+def expert_leaves(tree: Params) -> Tuple[str, ...]:
+    """The EXPERT_LEAVES a layer tree (stacked, or one layer's) holds."""
+    return tuple(name for name in EXPERT_LEAVES if name in tree)
 
 
 def experts_int8(layers: Params) -> bool:
@@ -174,14 +207,15 @@ def experts_int8(layers: Params) -> bool:
 
 def _experts_token(t: jnp.ndarray, top_idx: jnp.ndarray, w_top: jnp.ndarray,
                    stack: Params, layer, routed: int, offset: int,
-                   real: Optional[jnp.ndarray] = None):
+                   real: Optional[jnp.ndarray] = None, act: str = "silu"):
     """The routed experts by token: the T x k (row, expert, weight) picks
     sorted by expert, the rows gathered into that order, each projection ONE
     grouped matmul whose groups are the held experts (operands in t's dtype,
-    f32 accumulation, as the dense einsums), and each row's k results
-    weighted and summed in f32.  t [T, H]; `stack` the expert leaves stacked
-    over layers [L, E, ...], of which this is `layer`; top_idx [T, k] counts
-    over ALL the router's `routed` experts, of which this chip holds
+    f32 accumulation, as the dense einsums; three products an expert, two
+    where `act` is ungated and `stack` holds no "wg"), and each row's k
+    results weighted and summed in f32.  t [T, H]; `stack` the expert leaves
+    stacked over layers [L, E, ...], of which this is `layer`; top_idx [T, k]
+    counts over ALL the router's `routed` experts, of which this chip holds
     offset.. ; `real` [T] bool marks the rows that hold a token.  A pick of
     an expert held elsewhere, or of a pad row, sorts past every group: no
     matmul rows, zero weight.  No capacity, nothing dropped.  -> (out [T, H],
@@ -190,7 +224,8 @@ def _experts_token(t: jnp.ndarray, top_idx: jnp.ndarray, w_top: jnp.ndarray,
     from ..ops.pallas.grouped_matmul import grouped_matmul, tile_rows
 
     n, k = top_idx.shape
-    held = stack["wg"].shape[1]
+    gated, mid = ACTIVATIONS[act]
+    held = stack["wu"].shape[1]
     e = top_idx - offset
     mine = (e >= 0) & (e < held)
     if real is not None:
@@ -206,9 +241,11 @@ def _experts_token(t: jnp.ndarray, top_idx: jnp.ndarray, w_top: jnp.ndarray,
     if low < n * row_bytes <= high:
         src = jnp.pad(t, ((0, high // row_bytes + 1 - n), (0, 0)))
     xs = src[jnp.pad(order // k, (0, -(n * k) % tile))]
-    g = grouped_matmul(xs, stack["wg"], sizes, layer, tile)
-    u = grouped_matmul(xs, stack["wu"], sizes, layer, tile)
-    y = grouped_matmul(jax.nn.silu(g) * u, stack["wd"], sizes, layer, tile)
+    g = (grouped_matmul(xs, stack["wg"], sizes, layer, tile) if gated
+         else None)
+    u = grouped_matmul(xs, stack["wu"], sizes, layer, tile,
+                       transposed=not gated)
+    y = grouped_matmul(mid(g, u), stack["wd"], sizes, layer, tile)
     # each pick's result from where the sort put it (a pick that is not
     # `mine` finds a row no group wrote: whatever the buffer held)
     y = y[jnp.argsort(order)].reshape(n, k, -1)
@@ -281,19 +318,24 @@ def _moe_block(x: jnp.ndarray, lp: Params, cfg: ModelConfig,
                 real = (jnp.arange(s)[None, :]
                         < jnp.reshape(chunk_len, (-1, 1))).reshape(b * s)
             stack, at = stacked or (
-                {name: _w(lp, name, t.dtype)[None] for name in EXPERT_LEAVES},
-                0)
+                {name: _w(lp, name, t.dtype)[None]
+                 for name in expert_leaves(lp)}, 0)
             out, read = _experts_token(
                 t, *w, stack, at, cfg.num_router_experts,
-                cfg.expert_offset if cfg.num_experts_routed else 0, real)
+                cfg.expert_offset if cfg.num_experts_routed else 0, real,
+                cfg.mlp_act)
         else:
-            g = jnp.einsum("th,ehf->tef", t, _w(lp, "wg", t.dtype))
-            u = jnp.einsum("th,ehf->tef", t, _w(lp, "wu", t.dtype))
+            gated, mid = ACTIVATIONS[cfg.mlp_act]
+            g = (jnp.einsum("th,ehf->tef", t, _w(lp, "wg", t.dtype))
+                 if gated else None)
+            u = jnp.einsum("th,ehf->tef" if gated else "th,efh->tef", t,
+                           _w(lp, "wu", t.dtype))
             y = jnp.einsum(
-                "tef,efh->teh", jax.nn.silu(g) * u, _w(lp, "wd", t.dtype))
+                "tef,efh->teh", mid(g, u), _w(lp, "wd", t.dtype))
             out = jnp.einsum("te,teh->th", w.astype(y.dtype), y)
     out = out.reshape(b, s, h)
     if cfg.shared_intermediate_size:
         with jax.named_scope("moe_shared"):
-            out = out + _mlp_block(x, lp, ("ws_g", "ws_u", "ws_d"))
+            out = out + _mlp_block(x, lp, ("ws_g", "ws_u", "ws_d"),
+                                   act=cfg.mlp_act)
     return out, read

@@ -11,7 +11,7 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
-from .config import CONV, DELTA, GLOBAL, ModelConfig
+from .config import CONV, DELTA, GLOBAL, MAMBA2, MOE, ModelConfig
 from .hybrid import init_params as init_hybrid_params
 from .mixers.state import ssd_mup_vector
 from .quant import Params
@@ -26,6 +26,8 @@ def init_params(cfg: ModelConfig, key: jax.Array, dtype=None) -> Params:
         return init_hybrid_params(cfg, key, dtype)
     if cfg.by_kind:
         return _init_kind_params(cfg, key, dtype)
+    if cfg.lone_layers:
+        return _init_lone_params(cfg, key, dtype)
     if cfg.lead_tree:
         # a tree and a random stream of its own: the stream below is what
         # every other configuration's seeded weights come from
@@ -320,6 +322,94 @@ def _init_lead_tree_params(cfg: ModelConfig, key: jax.Array, dtype) -> Params:
             **mlp(kd[1], n_dense, cfg.dense_intermediate_size)}
     if not cfg.tie_word_embeddings:
         params["lm_head"] = norm01(keys[9], (h, cfg.vocab_size), h)
+    return params
+
+
+def _init_lone_params(cfg: ModelConfig, key: jax.Array, dtype) -> Params:
+    """Random weights of the one-sublayer layout (`nemotron_h`): "layers"
+    holds each layer's ONE norm, "ln" [L, H]; every other leaf is stacked per
+    KIND of layer in layer order, the mixers' under `params["attn"][kind]`
+    (a lone SSD mixer's as `_init_parallel_params` names and draws them,
+    without multipliers; attention's wq / wk / wv / wo) and the routed
+    feed-forward's under `params["ffn"][kind]`: the router at its full width
+    and its selection bias N(0, 0.1^2), the HELD experts' "wu" and "wd", both
+    [n, E, f, H] (an ungated expert has no gate matrix, and its up matrix is
+    stored out x in: models/ffn.ACTIVATIONS) and the shared expert's "ws_u"
+    [n, H, fs] / "ws_d".  A homogeneous [L, ...] stack would give every
+    layer experts.
+    The down matrices of a squared-ReLU block are drawn at 1 / sqrt(1.5 f):
+    relu(u)^2 of a unit normal u has second moment 3 / 2, so the block's
+    output is of unit variance as every other preset's is."""
+    h, hq, hkv, d = (cfg.hidden_size, cfg.num_heads, cfg.num_kv_heads,
+                     cfg.head_dim)
+
+    @partial(jax.jit, static_argnums=(1, 2))
+    def norm01(k, shape, fan_in):
+        # one program a leaf: no float32 copy of a 2G-element leaf is held
+        return (jax.random.normal(k, shape, jnp.float32)
+                * (fan_in**-0.5)).astype(dtype)
+
+    def spread(k, shape, out_dtype=dtype):
+        return (1.0 + 0.2 * jax.random.normal(k, shape, jnp.float32)
+                ).astype(out_dtype)
+
+    def mamba2_mixer(k, n):
+        H, P = cfg.ssd_heads, cfg.ssd_head_dim
+        d_ssm, conv, taps = H * P, cfg.ssd_conv_dim, cfg.ssd_conv_kernel
+        ks = jax.random.split(k, 8)
+        step = jnp.exp(jax.random.uniform(
+            ks[5], (n, H), jnp.float32, jnp.log(0.001), jnp.log(0.1)))
+        return {
+            "w_in": norm01(ks[0], (n, h, d_ssm + conv + H), h),
+            "conv_w": norm01(ks[1], (n, taps, conv), taps),
+            "conv_b": (0.1 * jax.random.normal(ks[2], (n, conv), jnp.float32)
+                       ).astype(dtype),
+            "A_log": jnp.log(jax.random.uniform(
+                ks[3], (n, H), jnp.float32, 1.0, 16.0)),
+            "D": spread(ks[4], (n, H), jnp.float32),
+            "dt_bias": jnp.log(jnp.expm1(step)),
+            "ln_ssd": spread(ks[6], (n, d_ssm)),
+            "w_out": norm01(ks[7], (n, d_ssm, h), d_ssm),
+        }
+
+    def gqa_attention(k, n):
+        ks = jax.random.split(k, 4)
+        return {"wq": norm01(ks[0], (n, h, hq, d), h),
+                "wk": norm01(ks[1], (n, h, hkv, d), h),
+                "wv": norm01(ks[2], (n, h, hkv, d), h),
+                "wo": norm01(ks[3], (n, hq, d, h), hq * d)}
+
+    def routed(k, n):
+        E, f, fs = (cfg.num_experts, cfg.intermediate_size,
+                    cfg.shared_intermediate_size)
+        width = cfg.num_router_experts
+        ks = jax.random.split(k, 6)
+        out = {
+            "router": norm01(ks[0], (n, h, width), h),
+            "router_bias": 0.1 * jax.random.normal(
+                ks[1], (n, width), jnp.float32),
+            # (an ungated expert's up matrix out x in: models/ffn.ACTIVATIONS)
+            "wu": norm01(ks[2], (n, E, f, h), h),
+            "wd": norm01(ks[3], (n, E, f, h), 1.5 * f),
+        }
+        if fs:
+            out["ws_u"] = norm01(ks[4], (n, h, fs), h)
+            out["ws_d"] = norm01(ks[5], (n, fs, h), 1.5 * fs)
+        return out
+
+    keys = jax.random.split(key, 5)
+    mixers = {MAMBA2: mamba2_mixer, GLOBAL: gqa_attention}
+    params: Params = {
+        "embed": norm01(keys[0], (cfg.vocab_size, h), h),
+        "final_norm": jnp.ones((h,), dtype),
+        "layers": {"ln": jnp.ones((cfg.num_layers, h), dtype)},
+        "attn": {kind: mixers[kind](jax.random.fold_in(keys[1], i),
+                                    cfg.layers_of(kind))
+                 for i, kind in enumerate(cfg.kinds) if kind in mixers},
+        "ffn": {MOE: routed(keys[2], cfg.layers_of(MOE))},
+    }
+    if not cfg.tie_word_embeddings:
+        params["lm_head"] = norm01(keys[3], (h, cfg.vocab_size), h)
     return params
 
 

@@ -45,9 +45,9 @@ from .cache import (
     INDEX, HybridPathError, KVCache, PagedView, StatePlan, _read_state,
     _write_state,
 )
-from .config import GLOBAL, ModelConfig
+from .config import GLOBAL, MOE, ModelConfig
 from .ffn import (
-    EXPERT_LEAVES, _mlp_block, _moe_block, experts_int8, moe_dispatch_form,
+    _mlp_block, _moe_block, expert_leaves, experts_int8, moe_dispatch_form,
 )
 from .hybrid import forward as hybrid_forward
 from .init_params import init_params
@@ -169,7 +169,8 @@ def forward(
         if cfg.layer_types or cfg.rope_by_kind:
             rope = {kind: rope_cos_sin(positions, *kind_frequencies(cfg, kind))
                     for kind in cfg.kinds
-                    if MIXERS[cfg.mixer_of(kind)].positional}
+                    if cfg.mixer_of(kind) is not None
+                    and MIXERS[cfg.mixer_of(kind)].positional}
         else:
             inv_freq = rope_frequencies(cfg)
             rope = {GLOBAL: rope_cos_sin(positions, inv_freq)}
@@ -186,15 +187,23 @@ def forward(
     # entry of `scanned`), and a program that keeps the dense form is traced
     # as it always was.  (int8 experts are dequantized a layer, from
     # their slices.)
+    # (a one-sublayer pattern stacks the routed feed-forward's leaves per
+    # kind, `ffn`: the expert stack is then its MOE layers')
     sharded = mesh is not None and mesh.size > 1
-    layers, experts = params["layers"], None
+    layers, experts, ffn = params["layers"], None, params.get("ffn", {})
+    routed_stack = ffn[MOE] if ffn else layers
     if (cfg.is_moe and moe_dispatch_form(
             token_ids.shape[0] * token_ids.shape[1], cfg.num_experts,
             cfg.num_experts_per_tok, sharded,
             cfg.num_router_experts) == "token"
-            and not experts_int8(layers)):
-        experts = {n: layers[n] for n in EXPERT_LEAVES}
-        layers = {n: a for n, a in layers.items() if n not in experts}
+            and not experts_int8(routed_stack)):
+        experts = {n: routed_stack[n] for n in expert_leaves(routed_stack)}
+        routed_stack = {n: a for n, a in routed_stack.items()
+                        if n not in experts}
+        if ffn:
+            ffn = {MOE: routed_stack}
+        else:
+            layers = routed_stack
 
     def indexed(index):
         """`scanned`'s third entry, the layer's index() in the expert stack
@@ -210,20 +219,32 @@ def forward(
     # the layer's weights.  Every op of the layer body sits under a leaf
     # scope (residual adds included), so what a device trace shows under
     # `layers` alone is the scan's own slicing of its stacked inputs.
+    # A kind runs the halves it HAS (`cfg.mixer_of`, `cfg.has_ffn`): in a
+    # one-sublayer pattern a layer is a mixer or a feed-forward under its
+    # one norm ("ln") and one residual add, and no op of the absent half is
+    # traced.
     def layer_body(carry, scanned, kind=GLOBAL, routed=cfg.is_moe):
         h, kc, vc, tally = carry
         lp, layer, *slot = scanned
-        mixer = MIXERS[cfg.mixer_of(kind)]
-        u, maps = _hc_in(h, lp, "attn", cfg)
-        with jax.named_scope("attn_norm"):
-            attn_in = rms_norm(u, lp["ln_attn"], cfg.rms_norm_eps)
-        # (`layer` is the layer's index in the caches its mixer holds: among
-        # its kind where the leaves are per kind, absolute elsewhere)
-        attn_out, kc, vc = mixer.mix(attn_in, lp, ctx, kc, vc, layer, kind)
-        h = _hc_out(h, attn_out, maps, mixer.scope)
+        lone = cfg.lone_layers
+        if cfg.mixer_of(kind) is not None:
+            mixer = MIXERS[cfg.mixer_of(kind)]
+            u, maps = _hc_in(h, lp, "attn", cfg)
+            with jax.named_scope("attn_norm"):
+                attn_in = rms_norm(u, lp["ln" if lone else "ln_attn"],
+                                   cfg.rms_norm_eps)
+            # (`layer` is the layer's index in the caches its mixer holds:
+            # among its kind where the leaves are per kind, absolute
+            # elsewhere)
+            attn_out, kc, vc = mixer.mix(attn_in, lp, ctx, kc, vc, layer,
+                                         kind)
+            h = _hc_out(h, attn_out, maps, mixer.scope)
+        if not cfg.has_ffn(kind):
+            return (h, kc, vc, tally), None
         u, maps = _hc_in(h, lp, "mlp", cfg)
         with jax.named_scope("mlp_norm"):
-            mlp_in = rms_norm(u, lp["ln_mlp"], cfg.rms_norm_eps)
+            mlp_in = rms_norm(u, lp["ln" if lone else "ln_mlp"],
+                              cfg.rms_norm_eps)
         if routed:
             ffn_out, read = _moe_block(
                 mlp_in, lp, cfg, None if paged is None else paged.chunk_len,
@@ -251,13 +272,18 @@ def forward(
         """(leaves, cache index) of layer `layer` (absolute), the i-th of
         `params[stack]`.  A by_kind model takes its attention leaves from
         its kind's own stack and indexes its kind's caches, both at `nth`,
-        the layer's place among its kind."""
+        the layer's place among its kind; so with the feed-forward leaves
+        of a kind that has a stack of its own (`ffn`), the expert stack
+        among them."""
         routed = stack == "layers"
         lp = at(layers if routed else params[stack], i, static)
         if not cfg.kind_leaves:
             return (lp, layer) + (indexed(lambda: i) if routed else ())
-        return ({**lp, **at(params["attn"][kind], nth, static)}, nth) + (
-            indexed(lambda: i) if routed else ())
+        for own in (params["attn"].get(kind), ffn.get(kind)):
+            if own is not None:
+                lp = {**lp, **at(own, nth, static)}
+        return (lp, nth) + (
+            indexed(lambda: nth if ffn else i) if routed else ())
 
     def period_body(carry, first):
         """One whole period of the pattern, from absolute layer `first`: its

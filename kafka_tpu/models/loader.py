@@ -22,7 +22,7 @@ from typing import Any, Callable, Dict, Mapping, Optional
 import jax.numpy as jnp
 import numpy as np
 
-from .config import ModelConfig, config_from_hf_json
+from .config import GLOBAL, MAMBA2, MOE, ModelConfig, config_from_hf_json
 
 Params = Dict[str, Any]
 
@@ -40,11 +40,12 @@ def _to_numpy(t: Any) -> np.ndarray:
     return np.asarray(t)
 
 
-def _getter(state: Mapping[str, Any]) -> Callable[[str], np.ndarray]:
-    """`get(name)`: a weight by its HF name, with or without the `model.`
-    prefix, as numpy."""
+def _getter(state: Mapping[str, Any],
+            prefix: str = "model.") -> Callable[[str], np.ndarray]:
+    """`get(name)`: a weight by its HF name, with or without the family's
+    prefix (`model.`; `nemotron_h`'s `backbone.`), as numpy."""
     def get(name: str) -> np.ndarray:
-        key = name if name in state else f"model.{name}"
+        key = name if name in state else prefix + name
         if key not in state:
             raise KeyError(f"missing weight {name!r} (tried {key!r})")
         return _to_numpy(state[key])
@@ -59,6 +60,8 @@ def convert_hf_state_dict(
     dtype = dtype or cfg.activation_dtype
     if cfg.is_latent:
         return _convert_latent_state_dict(state, cfg, dtype)
+    if cfg.lone_layers:
+        return _convert_lone_state_dict(state, cfg, dtype)
     if cfg.lead_tree or cfg.qk_norm:
         raise NotImplementedError(
             "no checkpoint converter for a grouped-query model with a dense "
@@ -239,6 +242,75 @@ def _convert_latent_state_dict(
         params["lm_head"] = jnp.asarray(
             _to_numpy(state["lm_head.weight"]).T, dtype)
     return params
+
+
+def _convert_lone_state_dict(
+    state: Mapping[str, Any], cfg: ModelConfig, dtype: Any
+) -> Params:
+    """HF `nemotron_h` names -> the one-sublayer tree
+    (models/init_params._init_lone_params): `backbone.layers.N.norm` under
+    "layers", `backbone.layers.N.mixer.*` stacked per KIND of layer in layer
+    order, a Mamba-2 mixer's and attention's under "attn", a routed
+    feed-forward's under "ffn"; of the published experts the
+    `cfg.num_experts` from `cfg.expert_offset` are read (a held share asks
+    for no other).  The convolution's taps [C, 1, taps] become [taps, C]:
+    the last tap multiplies the row's own value in both."""
+    h, d = cfg.hidden_size, cfg.head_dim
+    hq, hkv = cfg.num_heads, cfg.num_kv_heads
+    get = _getter(state, "backbone.")
+    t = lambda w: w.T  # noqa: E731  [out, in] -> [in, out]
+    f32 = jnp.float32
+    leaves = {
+        MAMBA2: {
+            "w_in": ("in_proj.weight", t, dtype),
+            "conv_w": ("conv1d.weight", lambda w: w[:, 0, :].T, dtype),
+            "conv_b": ("conv1d.bias", None, dtype),
+            "A_log": ("A_log", None, f32),
+            "D": ("D", None, f32),
+            "dt_bias": ("dt_bias", None, f32),
+            "ln_ssd": ("norm.weight", None, dtype),
+            "w_out": ("out_proj.weight", t, dtype),
+        },
+        GLOBAL: {
+            "wq": ("q_proj.weight", lambda w: w.T.reshape(h, hq, d), dtype),
+            "wk": ("k_proj.weight", lambda w: w.T.reshape(h, hkv, d), dtype),
+            "wv": ("v_proj.weight", lambda w: w.T.reshape(h, hkv, d), dtype),
+            "wo": ("o_proj.weight", lambda w: w.T.reshape(hq, d, h), dtype),
+        },
+        MOE: {
+            "router": ("gate.weight", t, dtype),
+            "router_bias": ("gate.e_score_correction_bias", None, f32),
+            "ws_u": ("shared_experts.up_proj.weight", t, dtype),
+            "ws_d": ("shared_experts.down_proj.weight", t, dtype),
+        },
+    }
+    ids = {kind: [i for i in range(cfg.num_layers) if cfg.kind_of(i) == kind]
+           for kind in cfg.kinds}
+
+    def stack(kind: str) -> dict:
+        return {name: jnp.asarray(np.stack([
+            (fn or (lambda w: w))(get(f"layers.{i}.mixer.{hf}"))
+            for i in ids[kind]]), dt)
+            for name, (hf, fn, dt) in leaves[kind].items()}
+
+    routed = stack(MOE)
+    held = range(cfg.expert_offset, cfg.expert_offset + cfg.num_experts)
+    # (both [f, H]: the up matrix as published, the down matrix transposed)
+    for name, hf, fn in (("wu", "up_proj", lambda w: w),
+                         ("wd", "down_proj", t)):
+        routed[name] = jnp.asarray(np.stack([np.stack([
+            fn(get(f"layers.{i}.mixer.experts.{e}.{hf}.weight"))
+            for e in held]) for i in ids[MOE]]), dtype)
+    return {
+        "embed": jnp.asarray(get("embeddings.weight"), dtype),
+        "final_norm": jnp.asarray(get("norm_f.weight"), dtype),
+        "layers": {"ln": jnp.asarray(np.stack([
+            get(f"layers.{i}.norm.weight")
+            for i in range(cfg.num_layers)]), dtype)},
+        "attn": {kind: stack(kind) for kind in cfg.kinds if kind != MOE},
+        "ffn": {MOE: routed},
+        "lm_head": jnp.asarray(_to_numpy(state["lm_head.weight"]).T, dtype),
+    }
 
 
 def load_safetensors_dir(path: str) -> Dict[str, np.ndarray]:

@@ -39,4 +39,5 @@ MIXERS = {
     "conv": Mixer(state.mix_conv, "conv_proj", False),
     "delta": Mixer(state.mix_delta, "kda_proj", False),
     "ssd": Mixer(state.mix_ssd, "attn_out", True),
+    "mamba2": Mixer(state.mix_mamba2, "ssd_proj", False),
 }

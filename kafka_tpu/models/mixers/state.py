@@ -11,7 +11,10 @@ that swaps one there reaches every write.
   Delta Attention): the conv layout's mechanism with a second state leaf;
 * the Mamba-2 (SSD) mixer of the parallel layout (`falcon_h1`): attention
   (mixers/gqa.py) and this mixer read one normed input and both add into the
-  residual; every layer holds rows in the paged pool AND a state slot.
+  residual; every layer holds rows in the paged pool AND a state slot;
+* the same mixer standing ALONE in a layer (`nemotron_h`'s one-sublayer
+  layout): the conv layout's mechanism (leaves per kind, a state and no
+  rows) with the parallel layout's two state leaves.
 """
 
 from __future__ import annotations
@@ -298,3 +301,12 @@ def mix_ssd(x, lp: Params, ctx, kc, vc, layer, kind):
                              ctx.read_state, ctx.write_state)
     with jax.named_scope("ssd_proj"):
         return ssd_out + attn_out, kc, vc
+
+
+def mix_mamba2(x, lp: Params, ctx, kc, vc, layer, kind):
+    """`MIXERS["mamba2"]`: the SSD mixer ALONE in its layer; `layer` counts
+    the layers of its kind, its place in both state leaves of the v pool's
+    dict, and no page is touched."""
+    out, vc = _ssd_block(x, lp, ctx.cfg, vc, layer, ctx.plan,
+                         ctx.read_state, ctx.write_state)
+    return out, kc, vc

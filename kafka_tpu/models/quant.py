@@ -120,6 +120,12 @@ def quantize_params(params: Params, cfg: ModelConfig) -> Params:
             "int8 weight quantization of a latent-attention model is not "
             "built: its tree (wkva, wkvb, shared experts, dense_layers) has "
             "no contraction table here")
+    if cfg.lone_layers or cfg.mlp_act != "silu":
+        raise NotImplementedError(
+            "int8 weight quantization of a one-sublayer pattern is not "
+            "built: its ungated experts (wu, wd alone) and its mixers are "
+            "stacked per kind (`ffn`, `attn`) and have no contraction table "
+            "here")
     if "dense_layers" in params or cfg.shared_intermediate_size:
         raise NotImplementedError(
             "int8 weight quantization of a tree with a dense lead or shared "

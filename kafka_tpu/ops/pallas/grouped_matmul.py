@@ -45,12 +45,14 @@ def whole_tile(width: int, most: int = TILE_WIDTH) -> int:
 
 
 def grouped_matmul(lhs: jnp.ndarray, rhs: jnp.ndarray, sizes: jnp.ndarray,
-                   layer, rows_a_tile: int) -> jnp.ndarray:
+                   layer, rows_a_tile: int,
+                   transposed: bool = False) -> jnp.ndarray:
     """lhs [m, k] (rows sorted by group, m a multiple of `rows_a_tile`) x
     rhs[layer] of the STACKED rhs [L, g, k, n] by `sizes` [g] i32 consecutive
     rows a group -> [m, n] in lhs.dtype, accumulated in f32.  The sizes may
     sum to less than m: rows past the last group are multiplied by nothing
-    and their output is whatever the buffer held.
+    and their output is whatever the buffer held.  `transposed`: the stack is
+    [L, g, n, k], each matrix out x in, contracted over its minor axis.
 
     The kernel reads the layer's weights where the stack holds them: it is
     handed all L x g matrices as groups of which only `layer`'s have rows
@@ -61,9 +63,12 @@ def grouped_matmul(lhs: jnp.ndarray, rhs: jnp.ndarray, sizes: jnp.ndarray,
     stack, groups = rhs.shape[:2]
     every = jax.lax.dynamic_update_slice(
         jnp.zeros((stack * groups,), jnp.int32), sizes, (layer * groups,))
+    k, n = rhs.shape[2:][::-1] if transposed else rhs.shape[2:]
+    # (`transpose_rhs` is handed over only where asked for: the programs of
+    # every [k, n] stack lower as they did)
     return gmm(
         lhs, rhs.reshape((stack * groups,) + rhs.shape[2:]), every,
         preferred_element_type=lhs.dtype,
-        tiling=(rows_a_tile, whole_tile(rhs.shape[2]),
-                whole_tile(rhs.shape[3])),
-        interpret=jax.default_backend() != "tpu")
+        tiling=(rows_a_tile, whole_tile(k), whole_tile(n)),
+        interpret=jax.default_backend() != "tpu",
+        **({"transpose_rhs": True} if transposed else {}))

@@ -27,6 +27,9 @@ Three forms of the one recurrence, behind `ssd`:
   and a chunk whose cumulative decay underflows float32 loses nothing but
   what had decayed.  Three MXU products a head a chunk; C B^T is taken ONCE
   for the heads of a grid step, which are one group's (or a part of one).
+  Heads narrower than a lane tile (P = 64) are taken 128 / P together, the
+  products over the tile and each head keeping its own lanes
+  (`heads_a_tile`).
   The state stays in VMEM across a lane's chunks; it comes from the lane's
   `src` slot by one DMA and goes to `dst` and `snap` by two, the leaf aliased
   in and out (ops/pallas/state_slot.py, shared with gated_delta.py).
@@ -76,6 +79,24 @@ def heads_a_step(H: int, groups: int, P: int, N: int) -> int:
     return hb
 
 
+def heads_a_tile(P: int, hb: int) -> int:
+    """Heads the kernels take together so that every slice of the lanes is a
+    whole 128-lane tile: 1 where a head's P channels are whole tiles
+    themselves (or nothing tiles: the interpreter slices anything), 128 / P
+    of the `hb` heads of a grid step where P divides a tile and that many
+    divide `hb` (Nemotron-H's heads of 64: pairs)."""
+    q = 128 // P if P < 128 and 128 % P == 0 else 1
+    return q if hb % q == 0 else 1
+
+
+def tiles(H: int, groups: int, P: int, N: int) -> bool:
+    """Whether the kernels tile this geometry on the chip: the state size in
+    whole lane tiles, and a head's channels whole tiles or whole heads a
+    tile."""
+    hb = heads_a_step(H, groups, P, N)
+    return N % 128 == 0 and (P * heads_a_tile(P, hb)) % 128 == 0
+
+
 def _dot(a, b, dims):
     return jax.lax.dot_general(a, b, (dims, ((), ())), precision=_HI,
                                preferred_element_type=_F32)
@@ -113,21 +134,54 @@ def _chunk_kernel(layer_ref, src_ref, dst_ref, snap_ref, flag_ref,
         col = jax.lax.broadcasted_iota(jnp.int32, (C, C), 1)
         eye = (row == col).astype(_F32)
         CB = _dot(Cm, Bm, ((1,), (1,)))                  # [C, C], once
-        for j in range(hb):
-            ps = slice(j * P, (j + 1) * P)
-            Gc = _column(G, j)                           # [C, 1]
+
+        def decay(j):
+            """Head j's cumulative log-decay [C, 1] and its L [C, C]."""
+            Gc = _column(G, j)
             # the same values along the lanes (through the identity)
             Gr = jnp.sum(eye * Gc, axis=0, keepdims=True)  # [1, C]
-            L = jnp.where(row >= col,
-                          jnp.exp(jnp.minimum(Gc - Gr, 0.0)), 0.0)
-            X = x_ref[0, :, ps]                          # [C, P]: dt x
-            S0 = s_scr[ps, :]                            # [P, N]
-            y_ref[0, :, ps] = (
-                _dot(Cm * jnp.exp(Gc), S0, ((1,), (1,)))
-                + _dot(L * CB, X, ((1,), (0,))))
-            last = Gc[C - 1:C, :]                        # [1, 1]
-            s_scr[ps, :] = S0 * jnp.exp(last) + _dot(
-                X, Bm * jnp.exp(last - Gc), ((0,), (0,)))
+            return Gc, jnp.where(
+                row >= col, jnp.exp(jnp.minimum(Gc - Gr, 0.0)), 0.0)
+
+        q = heads_a_tile(P, hb)
+        W = q * P
+        if q > 1:
+            for u in range(hb // q):
+                # `q` heads a lane tile: every product is taken over the tile's
+                # W = 128 lanes (rows of the state) and each head keeps its own
+                # lanes (rows) of the result, so no slice cuts a tile
+                ws = slice(u * W, (u + 1) * W)
+                X = x_ref[0, :, ws]                          # [C, W]: dt x
+                S0 = s_scr[ws, :]                            # [W, N]
+                lane = jax.lax.broadcasted_iota(jnp.int32, (1, W), 1)
+                srow = jax.lax.broadcasted_iota(jnp.int32, (W, 1), 0)
+                y = jnp.zeros((C, W), _F32)
+                S1 = jnp.zeros(S0.shape, _F32)
+                for j in range(q):
+                    Gc, L = decay(u * q + j)
+                    yj = (_dot(Cm * jnp.exp(Gc), S0, ((1,), (1,)))
+                          + _dot(L * CB, X, ((1,), (0,))))
+                    y = y + jnp.where(
+                        (lane >= j * P) & (lane < (j + 1) * P), yj, 0.0)
+                    last = Gc[C - 1:C, :]                    # [1, 1]
+                    Sj = S0 * jnp.exp(last) + _dot(
+                        X, Bm * jnp.exp(last - Gc), ((0,), (0,)))
+                    S1 = S1 + jnp.where(
+                        (srow >= j * P) & (srow < (j + 1) * P), Sj, 0.0)
+                y_ref[0, :, ws] = y
+                s_scr[ws, :] = S1
+        else:
+            for j in range(hb):
+                ps = slice(j * P, (j + 1) * P)
+                Gc, L = decay(j)
+                X = x_ref[0, :, ps]                          # [C, P]: dt x
+                S0 = s_scr[ps, :]                            # [P, N]
+                y_ref[0, :, ps] = (
+                    _dot(Cm * jnp.exp(Gc), S0, ((1,), (1,)))
+                    + _dot(L * CB, X, ((1,), (0,))))
+                last = Gc[C - 1:C, :]                        # [1, 1]
+                s_scr[ps, :] = S0 * jnp.exp(last) + _dot(
+                    X, Bm * jnp.exp(last - Gc), ((0,), (0,)))
 
     @pl.when(jnp.logical_not(active))
     def _():
@@ -202,19 +256,37 @@ def _step_kernel(layer_ref, slot_ref, x_ref, b_ref, c_ref, g_ref, s_ref,
     """One row of `hb` heads of one lane: the slot's block in, the updated
     block out (the same bytes of the aliased leaf)."""
     del layer_ref, slot_ref
-    eye = (jax.lax.broadcasted_iota(jnp.int32, (P, P), 0)
-           == jax.lax.broadcasted_iota(jnp.int32, (P, P), 1)).astype(_F32)
+    q = heads_a_tile(P, hb)
+    W = q * P
+    eye = (jax.lax.broadcasted_iota(jnp.int32, (W, W), 0)
+           == jax.lax.broadcasted_iota(jnp.int32, (W, W), 1)).astype(_F32)
     Bm, Cm = b_ref[0], c_ref[0]                          # [1, N]
     a = jnp.exp(g_ref[0, 0])                             # [1, hb]
-    for j in range(hb):
-        ps = slice(j * P, (j + 1) * P)
-        # a row of P lanes as a column of P sublanes, and back: through the
-        # identity (Mosaic does not transpose a single row)
-        x = jnp.sum(eye * x_ref[0, :, ps], axis=1, keepdims=True)  # [P, 1]
-        S = s_ref[0, 0, ps, :] * _column(a, j) + x * Bm
-        y = jnp.sum(S * Cm, axis=1, keepdims=True)       # [P, 1]
-        y_ref[0, :, ps] = jnp.sum(eye * y, axis=0, keepdims=True)
-        s_out_ref[0, 0, ps, :] = S
+    if q > 1:
+        for u in range(hb // q):
+            # `q` heads a lane tile (`_chunk_kernel`): each row of the tile's
+            # states decays by its own head's a
+            ws = slice(u * W, (u + 1) * W)
+            srow = jax.lax.broadcasted_iota(jnp.int32, (W, 1), 0)
+            decay = jnp.zeros((W, 1), _F32)
+            for j in range(q):
+                decay = jnp.where((srow >= j * P) & (srow < (j + 1) * P),
+                                  _column(a, u * q + j), decay)
+            x = jnp.sum(eye * x_ref[0, :, ws], axis=1, keepdims=True)  # [W, 1]
+            S = s_ref[0, 0, ws, :] * decay + x * Bm
+            y = jnp.sum(S * Cm, axis=1, keepdims=True)       # [W, 1]
+            y_ref[0, :, ws] = jnp.sum(eye * y, axis=0, keepdims=True)
+            s_out_ref[0, 0, ws, :] = S
+    else:
+        for j in range(hb):
+            ps = slice(j * P, (j + 1) * P)
+            # a row of P lanes as a column of P sublanes, and back: through the
+            # identity (Mosaic does not transpose a single row)
+            x = jnp.sum(eye * x_ref[0, :, ps], axis=1, keepdims=True)  # [P, 1]
+            S = s_ref[0, 0, ps, :] * _column(a, j) + x * Bm
+            y = jnp.sum(S * Cm, axis=1, keepdims=True)       # [P, 1]
+            y_ref[0, :, ps] = jnp.sum(eye * y, axis=0, keepdims=True)
+            s_out_ref[0, 0, ps, :] = S
 
 
 @functools.partial(jax.jit, static_argnames=("groups", "interpret"))
@@ -297,11 +369,11 @@ def ssd(leaf, layer, plan, x, Bm, Cm, g, *, kernel: bool, read_state,
     real = (jnp.arange(S)[None, :] < plan.lens[:, None])[..., None]
     g = jnp.where(real, g, 0.0)
     x = jnp.where(real[..., None], x, 0.0)
-    tiles = chunk_rows(S) if S > 1 else 1
+    rows = chunk_rows(S) if S > 1 else 1
     on_chip = jax.default_backend() == "tpu"
-    if (not kernel or leaf is None or tiles is None
+    if (not kernel or leaf is None or rows is None
             or (S == 1 and plan.src is not None)
-            or (on_chip and (P % 128 or N % 128))):
+            or (on_chip and not tiles(H, groups, P, N))):
         S0 = (jnp.zeros((B, H, P, N), _F32) if leaf is None
               else read_state(leaf, layer, plan, B).astype(_F32).reshape(
                   B, H, P, N))
@@ -322,5 +394,5 @@ def ssd(leaf, layer, plan, x, Bm, Cm, g, *, kernel: bool, read_state,
         return y.reshape(B, 1, H, P), leaf
     src, dst, snap, flag = chunk_slots(plan, B)
     y, leaf = ssd_chunk(leaf, layer, src, dst, snap, flag, x, Bm, Cm, g,
-                        groups=groups, chunk=tiles, interpret=not on_chip)
+                        groups=groups, chunk=rows, interpret=not on_chip)
     return y.reshape(B, S, H, P), leaf

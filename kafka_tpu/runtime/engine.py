@@ -1508,10 +1508,20 @@ class InferenceEngine:
         # StepPrograms.ssd_chunk_trips / ssd_state_bytes
         self.ssd_chunk_trips = 0
         self.ssd_state_bytes = 0
+        # ... and the rows x SSD layers of the dispatched launches, padding
+        # included, counted where prefill_rows_dispatched is
+        self.ssd_rows_dispatched = 0
         # Rows x mapping sites of a widened residual stream (two a layer)
         # the dispatched launches ran, padding included; 0 with one row
         self.hc_site_rows = 0
         self._hc_sites = 2 * cfg.num_layers if cfg.hc_mult > 1 else 0
+        # what every traced pass's span says of the model (`_pass_attrs`; the
+        # default samples every request, so this is read a request a
+        # dispatch: computed once)
+        self._layer_attrs = dict(
+            state_layers=cfg.state_layers, row_layers=cfg.kv_layers,
+            routed_layers=cfg.routed_layers,
+            **({"residual_streams": cfg.hc_mult} if cfg.hc_mult > 1 else {}))
         # Monotonic, and all 0 for a model with no routed block
         # (StepPrograms.moe_dispatch): step programs dispatched by the form
         # their routed blocks take, and the rows those blocks were handed
@@ -1707,10 +1717,11 @@ class InferenceEngine:
 
     def _pass_attrs(self, **kw) -> Dict[str, Any]:
         """Attrs of a span over forward passes (engine.prefill,
-        engine.decode); a model with a widened residual stream says how many
-        rows a token."""
-        if self.cfg.hc_mult > 1:
-            kw["residual_streams"] = self.cfg.hc_mult
+        engine.decode): the layers the pass ran by what they hold and by
+        their feed-forward (the `engine.state_layers` / `row_layers` /
+        `routed_layers` gauges' values); a model with a widened residual
+        stream says how many rows a token."""
+        kw.update(self._layer_attrs)
         return self._tattrs(**kw)
 
     def _prefill_attrs(self, req: "GenRequest", **kw) -> Dict[str, Any]:
@@ -3664,6 +3675,7 @@ class InferenceEngine:
                 *vis,
             )
         self.prefill_rows_dispatched += W * bucket
+        self.ssd_rows_dispatched += self._programs.ssd_rows(W, bucket)
         self.prefill_rows_filled += int(chunk_lens.sum())
         self._count_walk_trips(
             [(int(starts[i]), int(chunk_lens[i])) for i in range(len(reqs))],
@@ -3821,6 +3833,7 @@ class InferenceEngine:
                 *vis,
             )
         self.prefill_rows_dispatched += bucket
+        self.ssd_rows_dispatched += self._programs.ssd_rows(1, bucket)
         self.prefill_rows_filled += chunk_len
         self._count_walk_trips([(start, chunk_len)], 1, bucket)
         self._accrue_prefill_modeled(

@@ -453,6 +453,16 @@ METRICS: Tuple[Metric, ...] = (
         _m("delta_state_bytes", read="delta_state_bytes"),
         _m("ssd_chunk_trips", read="ssd_chunk_trips"),
         _m("ssd_state_bytes", read="ssd_state_bytes"),
+        # rows x SSD layers of the dispatched launches, padding included,
+        # counted where prefill_rows_dispatched is (0 without an SSD mixer)
+        _m("ssd_rows_dispatched", read="ssd_rows_dispatched"),
+        # the model's layers by what they hold between passes and by their
+        # feed-forward: a recurrent state, rows in the paged pool, a routed
+        # block (a one-sublayer pattern's layers are one of the three)
+        _m("state_layers", kind=GAUGE, merge=None, read="cfg.state_layers"),
+        _m("row_layers", kind=GAUGE, merge=None, read="cfg.kv_layers"),
+        _m("routed_layers", kind=GAUGE, merge=None,
+           read="cfg.routed_layers"),
         # rows of the residual stream a token (1: the plain residual), and
         # rows x mapping sites the dispatched launches ran, padding included
         # (0 for a model with one row)

@@ -46,7 +46,6 @@ from jax.sharding import AbstractMesh
 from ..models.config import (
     CROSS,
     DELTA,
-    PARALLEL,
     ModelConfig,
     holds_rows,
 )
@@ -403,8 +402,8 @@ def activation_bytes_estimate(
         # around them, [S, inner] each, and B / C broadcast along 128 lanes
         prefill += s_local * (cfg.mamba_d_inner * 4 * 6
                               + cfg.mamba_d_state * 128 * 4 * 2)
-    elif PARALLEL in cfg.layer_types:
-        # the input projection and the convolution over [x | B | C] in
+    elif cfg.ssd_heads:
+        # (an SSD mixer, beside attention or alone in its layer) the input projection and the convolution over [x | B | C] in
         # float32, then the chunk kernel's operands (dt x, B, C, the
         # log-decay's cumulative sum) and its output, and the gated norm
         d_ssm = cfg.ssd_heads * cfg.ssd_head_dim
@@ -739,8 +738,10 @@ def dispatch_cost_model(
     # head), a hybrid decoder's second half; every other leaf multiplies
     # every dispatched row.
     table = cfg.vocab_size * cfg.hidden_size
-    expert = 3 * cfg.hidden_size * cfg.intermediate_size if cfg.is_moe else 0
-    routed_layers = (cfg.num_layers - cfg.first_k_dense) if cfg.is_moe else 0
+    # (an ungated expert is two matrices, a gated one three)
+    expert = ((2 if cfg.mlp_act == "relu2" else 3) * cfg.hidden_size
+              * cfg.intermediate_size if cfg.is_moe else 0)
+    routed_layers = cfg.routed_layers
     experts_total = routed_layers * cfg.num_experts * expert
     second_half = (_hybrid_second_half_bytes(cfg, wb) / wb
                    if cfg.hybrid_decoder else 0.0)

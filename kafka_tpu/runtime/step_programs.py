@@ -32,7 +32,7 @@ import jax
 import jax.numpy as jnp
 
 from ..models.cache import KVCache, PagedView, StatePlan
-from ..models.config import DELTA, PARALLEL, ModelConfig
+from ..models.config import DELTA, ModelConfig
 from ..models.ffn import experts_int8, moe_dispatch_form
 from ..models.llama import forward
 from ..models.mixers.index import INDEX_WALK_KEYS, walk_pages
@@ -696,14 +696,18 @@ class StepPrograms:
         return (2 * 4 * cfg.layers_of(DELTA) * cfg.delta_heads
                 * cfg.delta_head_dim ** 2 * lanes * steps)
 
+    def _ssd_layers(self) -> int:
+        """Layers that hold an SSD mixer, beside attention or alone."""
+        return self.cfg.state_layers if self.cfg.ssd_heads else 0
+
     def ssd_chunk_trips(self, lanes: int, bucket: int) -> int:
         """Chunks the SSD prefill kernel loops over ONE launch of `bucket`
         rows whose `lanes` active lanes it computes, by the grid the kernel
-        itself is given (ops/pallas/ssd.chunk_rows), summed over the layers;
-        0 for a model without an SSD mixer, on the XLA backend and for a
-        bucket the kernel does not tile (the row-by-row scan runs)."""
-        cfg = self.cfg
-        n = cfg.layers_of(PARALLEL)
+        itself is given (ops/pallas/ssd.chunk_rows), summed over the layers
+        that hold one (beside attention or alone); 0 for a model without an
+        SSD mixer, on the XLA backend and for a bucket the kernel does not
+        tile (the row-by-row scan runs)."""
+        cfg, n = self.cfg, self._ssd_layers()
         if not n or cfg.attention_backend != "pallas":
             return 0
         rows = ssd_kernels.chunk_rows(bucket)
@@ -714,8 +718,13 @@ class StepPrograms:
         read and wrote: every layer's heads' states, once in and once out (0
         for a model without an SSD mixer)."""
         cfg = self.cfg
-        return (2 * 4 * cfg.layers_of(PARALLEL) * cfg.ssd_heads
-                * cfg.ssd_head_dim * cfg.ssd_d_state * lanes * steps)
+        return (2 * 4 * self._ssd_layers() * cfg.ssd_heads * cfg.ssd_head_dim
+                * cfg.ssd_d_state * lanes * steps)
+
+    def ssd_rows(self, lanes: int, bucket: int) -> int:
+        """Rows x SSD layers of ONE launch of `lanes` lanes x `bucket` rows,
+        padding included (0 for a model without an SSD mixer)."""
+        return self._ssd_layers() * lanes * bucket
 
     def moe_dispatch(self, rows: int) -> Optional[str]:
         """"token" or "dense": the form the routed blocks of a pass of
@@ -730,10 +739,7 @@ class StepPrograms:
         """Held experts x routed layers: what one pass's routed blocks read
         in the dense form, and at most in the token form (0: no routed
         block)."""
-        cfg = self.cfg
-        if not cfg.is_moe:
-            return 0
-        return cfg.num_experts * (cfg.num_layers - cfg.first_k_dense)
+        return self.cfg.num_experts * self.cfg.routed_layers
 
     def decode(self, fsm: Optional[Fsm] = None):
         """One token for every active lane: fn(params, k_pool, v_pool,

@@ -233,7 +233,10 @@ DEVICE_SCOPES = (
                     # the step kernel in decode (the state updated in place
                     # in its slot), or the row-by-row XLA scan
     # the parallel layout's second mixer (models/mixers/state._ssd_block); its
-    # attention keeps attn_qkv / kv_write / attn_core / attn_out
+    # attention keeps attn_qkv / kv_write / attn_core / attn_out.  The same
+    # four where the mixer stands ALONE in its layer (a one-sublayer pattern:
+    # the layer's residual add then sits under ssd_proj, its one norm under
+    # attn_norm; a routed layer's under mlp_norm and moe_experts)
     "ssd_proj",     # a Mamba-2 (SSD) mixer's projections: W_in with its two
                     # multipliers, W_out with its one, and the branch's add
                     # to the attention branch ahead of the residual

@@ -247,6 +247,8 @@ UNREAD = {
     ("solar-open2-250b", 32, "decode"), ("solar-open2-250b", 64, "prefill"),
     # (PR 56: 128 picks over 64 experts leave an expected 13% unread)
     ("xing4.0-29b-a4b", 32, "decode"),
+    # (PR 60: 192 picks over 128 experts leave an expected 21.5% unread)
+    ("nemotron-3-nano-30b-a3b", 32, "decode"),
 }
 
 

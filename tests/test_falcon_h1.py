@@ -279,6 +279,11 @@ NEW_ACCOUNT = {
         2.0 * 7 * 20 * 256],
     # (PR 56: latent rows in all 8 layers, no state; 32 heads x (2 x 512 + 64))
     "file:xing4.0-29b-a4b.json": [8, 0, [], 0, 2.0 * 8 * 32 * 1088],
+    # (PR 60: a layer is ONE sublayer: rows in 2 layers, a lone SSD mixer's
+    # state in 7, neither in the 7 routed ones; 32 heads x 2 x 128)
+    "file:nemotron-3-nano-30b-a3b.json": [
+        2, 7, [["conv", [8, 2304]], ["ssd", [4096, 128]]], 15196160,
+        2.0 * 2 * 32 * 256],
 }
 
 

@@ -7,7 +7,7 @@ two-lane batched prefill launch (from shapes alone,
 `tests/test_moe_dispatch.py`'s way; the full-width files too, which lower in
 seconds and hold no array) is what the parent commit lowers:
 `tests/recorded/lowered_pins.json` holds the digests, recorded AT THE PARENT
-(f4d1a60, PR 58, for PR 59) by running this file in a checkout of it with
+(28aebc4, PR 59, for PR 60) by running this file in a checkout of it with
 `KAFKA_TPU_RECORD_PINS=<path>` (same conftest, same JAX).  Equal text = the
 same executable and a warm compile cache across the two trees.
 
@@ -45,18 +45,11 @@ from kafka_tpu.runtime.kv_cache import default_state_slots, make_kv_pool_arrays
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 PINS = os.path.join(ROOT, "tests", "recorded", "lowered_pins.json")
 RECORD = os.environ.get("KAFKA_TPU_RECORD_PINS")
-# the files of a model this very PR adds (docstring): not pinned
-NEW = ()
-# the programs this very PR means to move (docstring).  PR 59: a prefill
-# launch writes a lane's state into its slot in place (models/cache.py
-# `_read_state` / `_write_state` where `plan.src` is given): the batched
-# prefill of the four configurations with a recurrent state and of their tiny
-# twins, and no decode step
-MOVED = frozenset(
-    f"file:{name}.xla.bprefill" for name in (
-        "phi-4-mini-flash-reasoning", "tiny-phi4flash", "lfm2-8b-a1b",
-        "tiny-lfm2moe", "solar-open2-250b", "tiny-solaropen2",
-        "falcon-h1-34b", "tiny-falconh1"))
+# the files of a model this very PR adds (docstring): not pinned.  PR 60:
+# Nemotron-3-Nano-30B-A3B's configuration and its tiny twin
+NEW = ("nemotron-3-nano-30b-a3b", "tiny-nemotronh")
+# the programs this very PR means to move (docstring).  PR 60: none
+MOVED = frozenset()
 PS, LANES, PAGES, BUCKET, WIDTH = 8, 4, 8, 16, 2
 
 

@@ -218,6 +218,8 @@ PARENT_WEIGHT_BYTES = {
     "lfm2-8b-a1b": 9_334_155_520,
     "mellum2-12b-a2.5b": 7_589_933_568,
     "mixtral-8x7b": 6_329_376_768,
+    # (PR 60: 7 layers hold experts, stacked per kind; no parent to compare)
+    "nemotron-3-nano-30b-a3b": 10_565_072_896,
     "phi-4-mini-flash-reasoning": 7_707_253_760,
     "solar-open2-250b": 7_797_691_392,
     "xing4.0-29b-a4b": 11_332_171_968,
