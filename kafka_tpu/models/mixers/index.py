@@ -63,6 +63,40 @@ def _index_scores(q_idx, w_idx, k_idx) -> jnp.ndarray:
     return jnp.einsum("bsn,bsnt->bst", w_idx, jax.nn.relu(dots))
 
 
+# Bits of the threshold one trip of `_threshold` decides: 2**r - 1 counts a
+# trip.  Chosen on the chip (my chip runs 1, 2, 5 and 6, PR 61; `_chosen_mask`
+# alone, top_k 2,048, us a call over [32, 1, 32768] | [1, 512, 32768]): 1: 81.9
+# | 943, 2: 78.9 | 1,090, and on the cell's own scores 1: 88.8, 2: 78.8, 4:
+# 133.9; unrolled, no loop at all, 1: 78.8 | 900, 2: 71.7 | 1,000, 3: 87.5 |
+# 1,351, 4: 126.5 | 2,285, 8 (the key search alone, 255 candidates on a
+# leading axis): 2,766 | not run.  A trip is VPU-bound (1.2 us at one
+# candidate, 2.3 at three, 9.3 at fifteen), so a wider trip's candidates cost
+# what they save in trips: 2 gains 10 us a layer at decode and loses 147 a
+# layer of a 512-row launch, and the cell makes a launch every nine passes.
+# The unrolled forms cost a boot 10 s of tracing and compiling (`setup_s` 98
+# -> 108).
+PASS_BITS = 1
+
+
+def _threshold(holds, rows: tuple, n_bits: int, dtype) -> jnp.ndarray:
+    """The largest number of `n_bits` bits that `holds`, one a row, [*rows]:
+    `holds(c)` [*rows] is true of every c up to it and of none past it.  Built from the top, `PASS_BITS` bits a trip of one loop: a trip
+    asks of each candidate that extends the prefix found so far by one digit
+    (a count a candidate, siblings over one read of the rows), and the digit
+    is how many of them hold.  The bits `PASS_BITS` does not divide go
+    first."""
+    def step(acc, shift, n):
+        return acc | (sum(holds(acc | (dtype(d) << shift)).astype(dtype)
+                          for d in range(1, n + 1)) << shift)
+
+    r, acc = PASS_BITS, jnp.zeros(rows, dtype)
+    trips, short = divmod(n_bits, r)
+    if short:
+        acc = step(acc, n_bits - short, (1 << short) - 1)
+    return jax.lax.fori_loop(0, trips, lambda i, acc: step(
+        acc, ((trips - 1 - i) * r).astype(dtype), (1 << r) - 1), acc)
+
+
 def _chosen_mask(scores: jnp.ndarray, mask: jnp.ndarray,
                  top_k: int) -> jnp.ndarray:
     """`mask` [B, S, T] narrowed to each query's chosen keys: of the keys it
@@ -72,14 +106,19 @@ def _chosen_mask(scores: jnp.ndarray, mask: jnp.ndarray,
     No sort: XLA's top-k of 2,048 among 32,768 sorts the whole row (4.1 ms
     a layer a decode pass, 17 ms a 512-row prefill launch: my chip run 2,
     PR 33).  The scores become unsigned keys of the same order; the k-th
-    largest key is built bit by bit from the top (32 counts of `key >=
-    candidate`), then the lowest positions among the keys EQUAL to it fill
-    what is left of k, by the same construction over the position's bits.
-    47 passes of compare-and-count over the row, each a few microseconds at
-    decode."""
-    t = scores.shape[-1]
+    largest key is the largest candidate that `key >= candidate` still
+    counts k times (`_threshold`: 32 counts over the rows, each waiting for
+    the one before), then the lowest positions among the keys EQUAL to it
+    fill what is left of k, by the same search over the position's bits (15
+    at T = 32,768).  With one query a lane (decode) the lanes are the rows,
+    [B, T]: held [B, 1, T] across the loops, a row took a tile to itself,
+    one sublane of eight, and a count 10 us where it takes 1.2 (566 us a
+    call against 82: my chip run 5, PR 61)."""
+    shape, t = mask.shape, mask.shape[-1]
     if t <= top_k:
         return mask  # every allowed key is chosen
+    if shape[-2] == 1:
+        scores, mask = scores.reshape(-1, t), mask.reshape(-1, t)
     bits = jax.lax.bitcast_convert_type(
         jnp.where(scores == 0, 0.0, scores).astype(jnp.float32), jnp.int32)
     keys = jax.lax.bitcast_convert_type(
@@ -90,26 +129,18 @@ def _chosen_mask(scores: jnp.ndarray, mask: jnp.ndarray,
     def count(hit):
         return jnp.sum(hit, axis=-1, dtype=jnp.int32)
 
-    def key_bit(i, kth):
-        cand = kth | (jnp.uint32(1) << (31 - i).astype(jnp.uint32))
-        return jnp.where(count(keys >= cand[..., None]) >= k, cand, kth)
-
-    kth = jax.lax.fori_loop(0, 32, key_bit, jnp.zeros(k.shape, jnp.uint32))
+    kth = _threshold(lambda c: count(keys >= c[..., None]) >= k,
+                     k.shape, 32, jnp.uint32)
     above = keys > kth[..., None]
     equal = keys == kth[..., None]
     left = k - count(above)  # how many of the equal keys are chosen
     pos = jnp.arange(t, dtype=jnp.int32)
-    n_bits = max(t - 1, 1).bit_length()
-
-    def pos_bit(i, last):
-        cand = last | (1 << (n_bits - 1 - i))
-        return jnp.where(count(equal & (pos < cand[..., None])) < left,
-                         cand, last)
-
     # the position of the `left`-th equal key: the largest p with fewer than
     # `left` equal keys under it
-    last = jax.lax.fori_loop(0, n_bits, pos_bit, jnp.zeros(k.shape, jnp.int32))
-    return above | (equal & (pos <= last[..., None]) & (left > 0)[..., None])
+    last = _threshold(lambda c: count(equal & (pos < c[..., None])) < left,
+                      k.shape, max(t - 1, 1).bit_length(), jnp.int32)
+    return (above | (equal & (pos <= last[..., None]) & (left > 0)[..., None])
+            ).reshape(shape)
 
 
 COMPACT_BLOCK = 128

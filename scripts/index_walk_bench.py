@@ -9,6 +9,7 @@ columns.
     python scripts/index_walk_bench.py                # every table
     python scripts/index_walk_bench.py --parent DIR   # + DIR's function
     python scripts/index_walk_bench.py --ops 8        # + the longest ops
+    python scripts/index_walk_bench.py --pass-bits 2 4    # + `mask` at these
     python scripts/index_walk_bench.py --rehearse     # CPU, tiny, no times
 
 Through the chip tool, from the repo root.  Tables (`--tables`):
@@ -25,7 +26,9 @@ Through the chip tool, from the repo root.  Tables (`--tables`):
 Forms: `installed` (this tree's function), `parent` (--parent DIR: the
 function of the tree unpacked at DIR), and this tree's parts alone:
 `walk` (`_paged_index_scores`: both loops), `common` (`_common_pages`),
-`mask` (`_chosen_mask` over the walk's scores), `compact`
+`mask` (`_chosen_mask` over the walk's scores; its line also gives the
+dependent passes the installed form makes, key search and position search;
+`mask_r<R>` with `--pass-bits R`: the same at R bits a pass), `compact`
 (`_compact_chosen`).  Times
 are the jitted programs' device durations in one profiler capture (`XLA
 Modules`); beside them whether the installed form chose the parent's slots.
@@ -39,6 +42,7 @@ import json
 import os
 import sys
 import tempfile
+from unittest import mock
 
 import numpy as np
 
@@ -89,6 +93,8 @@ def main() -> int:
     ap.add_argument("--own-keys", type=int, nargs=2, default=[300, 1800])
     ap.add_argument("--reps", type=int, default=20)
     ap.add_argument("--ops", type=int, default=0)
+    ap.add_argument("--pass-bits", type=int, nargs="+", default=[],
+                    help="also time `mask` with `index.PASS_BITS` at these")
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--rehearse", action="store_true")
     ap.add_argument("--out", default=os.path.join(
@@ -123,6 +129,12 @@ def main() -> int:
     hi, di = cfg.index_n_heads, cfg.index_head_dim
     parent = (load_parent(args.parent, "mixers/index.py") if args.parent
               else None)
+
+    def mask_passes(r):
+        """Dependent passes of `_chosen_mask` at r bits a pass: [the key
+        search, the position search] (the parent's loops: 32 + 15)."""
+        return [-(-n // r) for n in (32, (P * ps - 1).bit_length())]
+
     rng = np.random.RandomState(args.seed % 2**31)
     # one layer's indexer rows (`_flat_pool` of a stack one layer deep)
     pool = jnp.asarray(rng.standard_normal((num_pages * ps, di)), dt)
@@ -177,13 +189,18 @@ def main() -> int:
         if parent is not None:
             run("parent", choice(parent), a)
         run("mask", top_k, (outs["walk"], lane_state))
+        for r in args.pass_bits:  # traced inside `run`, under this constant
+            with mock.patch.object(index, "PASS_BITS", r):
+                run(f"mask_r{r}", top_k, (outs["walk"], lane_state))
+            assert (outs[f"mask_r{r}"] == outs["mask"]).all(), (kind, r)
         run("compact", lambda c, r: index._compact_chosen(
             c[:, 0], r, cfg.index_topk),
             (outs["mask"], decode_plan(*lane_state, ps)[1].read_idx))
         run("common", lambda lane_state: index._common_pages(
             decode_plan(*lane_state, ps)[1])[1], (lane_state,))
         row = {"table": kind, "lanes": int(b), "live_keys": int(lens.sum()),
-               "common_pages": int(outs["common"])}
+               "common_pages": int(outs["common"]),
+               "mask_passes": mask_passes(index.PASS_BITS)}
         slots, ok = (np.asarray(x) for x in outs["installed"])
         assert ok[active].any() and not ok[~active].any(), kind
         if parent is not None:
@@ -212,6 +229,9 @@ def main() -> int:
         row = {"table": kind, "form": form,
                "us": float(np.median(durs)) / 1e3,
                "min_us": min(durs) / 1e3, "max_us": max(durs) / 1e3}
+        if form.startswith("mask"):
+            row["passes"] = mask_passes(
+                int(form.partition("_r")[2] or index.PASS_BITS))
         if args.ops:
             top = sorted(by_op[fname].items(), key=lambda kv: -kv[1])
             row["ops_us"] = {op: round(ns / 1e3, 1)

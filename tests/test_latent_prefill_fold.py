@@ -301,13 +301,16 @@ PARENT_TEXTS = {
     # the same way)
     "gqa.pallas.prefill": "b608355daa0eae32",
     "gqa.pallas.bprefill": "e9677dad74f5b5fb",
-    # (the four that trace the indexer's walk, whose scores PR 49 holds
-    # trip-major until the loops end: these digests are PR 49's text,
-    # recorded the same way; the walk itself still gathers lane by lane)
-    "sparse.xla.prefill": "d7293a9eab2bdb2e",
-    "sparse.xla.bprefill": "1bcc39c46b242d29",
-    "sparse.pallas.prefill": "59854056271026e8",
-    "sparse.pallas.bprefill": "331da4948836c36a",
+    # (the four that trace the indexer's selection, whose threshold search
+    # PR 61 moved into `mixers/index._threshold` (one loop body for both
+    # searches, `acc | (holds << shift)` for the parent's `where`): these
+    # digests are PR 61's text, recorded the same way; PR 49's, with the
+    # walk's scores held trip-major, were d7293a9eab2bdb2e,
+    # 1bcc39c46b242d29, 59854056271026e8, 331da4948836c36a)
+    "sparse.xla.prefill": "2dd0465094958bbb",
+    "sparse.xla.bprefill": "5fff9374fb2d3fa6",
+    "sparse.pallas.prefill": "7c6d53d7c9c299dd",
+    "sparse.pallas.bprefill": "35cd5d1d02816fc1",
 }
 
 

@@ -7,7 +7,7 @@ two-lane batched prefill launch (from shapes alone,
 `tests/test_moe_dispatch.py`'s way; the full-width files too, which lower in
 seconds and hold no array) is what the parent commit lowers:
 `tests/recorded/lowered_pins.json` holds the digests, recorded AT THE PARENT
-(28aebc4, PR 59, for PR 60) by running this file in a checkout of it with
+(9dfc90c, PR 60, for PR 61) by running this file in a checkout of it with
 `KAFKA_TPU_RECORD_PINS=<path>` (same conftest, same JAX).  Equal text = the
 same executable and a warm compile cache across the two trees.
 
@@ -45,11 +45,19 @@ from kafka_tpu.runtime.kv_cache import default_state_slots, make_kv_pool_arrays
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 PINS = os.path.join(ROOT, "tests", "recorded", "lowered_pins.json")
 RECORD = os.environ.get("KAFKA_TPU_RECORD_PINS")
-# the files of a model this very PR adds (docstring): not pinned.  PR 60:
-# Nemotron-3-Nano-30B-A3B's configuration and its tiny twin
-NEW = ("nemotron-3-nano-30b-a3b", "tiny-nemotronh")
-# the programs this very PR means to move (docstring).  PR 60: none
-MOVED = frozenset()
+# the files of a model this very PR adds (docstring): not pinned.  PR 61: none
+NEW = ()
+# the programs this very PR means to move (docstring).  PR 61: those of the
+# configurations with an indexer whose shapes reach `t > index_topk`, where
+# `mixers/index._chosen_mask` searches over [B, T] rows at decode and through
+# `_threshold` (the tiny twins with an `index_topk`; `file:dots3-note-prev.*`
+# holds 64 keys here, under its top-k of 2,048, and lowers to the parent's
+# text)
+MOVED = frozenset(
+    f"file:{name}.{backend}.{program}"
+    for name, backends in (("tiny-dots3", ("xla", "pallas")),
+                           ("tiny-shared", ("xla",)))
+    for backend in backends for program in ("decode", "bprefill"))
 PS, LANES, PAGES, BUCKET, WIDTH = 8, 4, 8, 16, 2
 
 

@@ -328,33 +328,117 @@ def test_prefill_then_paged_decode_matches_uncached(model, backend,
     np.testing.assert_allclose(got, want, rtol=3e-4, atol=3e-4)
 
 
-@pytest.mark.parametrize("k, allowed, ties", [
-    (8, 0.9, True), (8, 0.5, False), (20, 0.1, True), (1, 0.9, False),
-    (76, 1.0, True), (40, 0.0, False),
-], ids=["ties", "half-masked", "fewer-than-k", "k-1", "all-but-one",
-        "none-allowed"])
-def test_the_chosen_set_is_exactly_top_k(k, allowed, ties):
+def _scores_of(keys):
+    """float32 scores whose order is that of the uint32 `keys`, the inverse
+    of `_chosen_mask`'s own map (keys in [0x00800000, 0xFF7FFFFF]: finite)."""
+    keys = np.asarray(keys, np.uint32)
+    assert keys.min() >= 0x00800000 and keys.max() <= 0xFF7FFFFF
+    return np.where(keys >> 31, keys ^ np.uint32(1 << 31), ~keys).view(
+        np.float32)
+
+
+def _drawn(k, allowed, ties, shape=(2, 3, 77)):
+    def case(rng):
+        scores = rng.randn(*shape).astype(np.float32)
+        if ties:
+            scores = np.round(scores * 2) / 2
+            scores[0, 0, :5] = -0.0
+        return scores, rng.rand(*shape) < allowed, k
+    return case
+
+
+def _kth_in(k, lo, hi, low_bits, shape=(2, 3, 77)):
+    """Distinct keys in [lo, hi) sixteen apart, the k-th largest of each row
+    with `low_bits` in its lowest four bits: a threshold whose top digit is
+    the range's and whose last digit is all zeros or all ones at any r <= 4."""
+    def case(rng):
+        t, n = shape[-1], int(np.prod(shape[:-1]))
+        step = (hi - lo) // 16 // t  # one key a stratum: no two alike
+        rows = (rng.randint(0, step, (n, t)) + np.arange(t) * step) * 16 + lo
+        rows = np.stack([rng.permutation(r) for r in rows]).astype(np.uint32)
+        kth = np.sort(rows, axis=-1)[:, -k]
+        rows[rows == kth[:, None]] += np.uint32(low_bits)
+        return _scores_of(rows.reshape(shape)), np.ones(shape, bool), k
+    return case
+
+
+def _low_bits_only(rng):
+    # every key the same but for its lowest 3 bits (r - 1 at r = 4): the
+    # last pass alone tells them apart, then the tie search
+    keys = np.uint32(0xC0490FD8) + rng.randint(0, 8, (2, 3, 77)).astype(
+        np.uint32)
+    return _scores_of(keys), rng.rand(2, 3, 77) < 0.8, 20
+
+
+def _all_equal(rng):
+    # the tie search carries the whole choice
+    return (np.full((2, 3, 77), -2.5, np.float32), rng.rand(2, 3, 77) < 0.7,
+            11)
+
+
+def _exactly_k(rng):
+    mask = np.zeros((2, 3, 77), bool)
+    for row in mask.reshape(6, 77):
+        row[rng.choice(77, 13, replace=False)] = True
+    return rng.randn(2, 3, 77).astype(np.float32), mask, 13
+
+
+def _around_zero(rng):
+    values = np.asarray([-1.5, -1e-40, -0.0, 0.0, 1e-40, 2.0 ** -126, 0.25],
+                        np.float32)
+    return values[rng.randint(0, len(values), (2, 3, 77))], \
+        rng.rand(2, 3, 77) < 0.9, 30
+
+
+TOP_K_CASES = {
+    "ties": _drawn(8, 0.9, True), "half-masked": _drawn(8, 0.5, False),
+    "fewer-than-k": _drawn(20, 0.1, True), "k-1": _drawn(1, 0.9, False),
+    "all-but-one": _drawn(76, 1.0, True), "none-allowed": _drawn(40, 0.0,
+                                                                 False),
+    # what a pass of several bits can get wrong and a bit a pass cannot
+    "low-bits-only": _low_bits_only,
+    "top-digit-zero": _kth_in(9, 0x00800000, 0x0FFFFFF0, 0),
+    "top-digit-ones": _kth_in(9, 0xF0000000, 0xFF7FFFF0, 0),
+    "last-digit-zero": _kth_in(30, 0x3F000000, 0xC1000000, 0),
+    "last-digit-ones": _kth_in(30, 0x3F000000, 0xC1000000, 15),
+    "all-equal": _all_equal, "exactly-k": _exactly_k,
+    "around-zero": _around_zero,
+    # T - 1 of 13 and of 15 bits (77 is 7): the position's top pass is short
+    "t-4097": _drawn(300, 0.9, True, (1, 2, 4097)),
+    "t-32768": _drawn(2048, 0.95, True, (1, 2, 32768)),
+}
+
+
+@pytest.mark.parametrize("bits", [index.PASS_BITS, 2, 3, 4],
+                         ids=["installed", "r2", "r3", "r4"])
+@pytest.mark.parametrize("case", list(TOP_K_CASES))
+def test_the_chosen_set_is_exactly_top_k(case, bits, monkeypatch):
     """The selection without a sort picks the set `lax.top_k` picks: ties to
     the lower position (signed zeros are one value), masked keys never, all
-    the allowed keys where there are no more than k."""
-    rng = np.random.RandomState(k)
-    scores = rng.randn(2, 3, 77).astype(np.float32)
-    if ties:
-        scores = np.round(scores * 2) / 2
-        scores[0, 0, :5] = -0.0
-    mask = rng.rand(2, 3, 77) < allowed
-    got = np.asarray(jax.jit(_chosen_mask, static_argnums=2)(
-        jnp.asarray(scores), jnp.asarray(mask), k))
-    vals, idx = jax.lax.top_k(jnp.where(mask, scores, -jnp.inf), k)
+    the allowed keys where there are no more than k; at the installed bits
+    a trip of the search, and at 2, 3 (which does not divide a key's 32) and
+    4."""
+    monkeypatch.setattr(index, "PASS_BITS", bits)
+    scores, mask, k = TOP_K_CASES[case](np.random.RandomState(
+        list(TOP_K_CASES).index(case)))
+    # (a function of its own a case: the jit cache does not see PASS_BITS)
+    got = np.asarray(jax.jit(lambda s, m: _chosen_mask(s, m, k))(
+        jnp.asarray(scores), jnp.asarray(mask)))
+    # (the oracle orders -0.0 under 0.0, and a denormal apart from both where
+    # the platform's `== 0` holds for it: it is shown the zeros as one value)
+    one_zero = jnp.where(jnp.asarray(scores) == 0, 0.0, scores)
+    vals, idx = jax.lax.top_k(jnp.where(mask, one_zero, -jnp.inf), k)
     want = np.zeros_like(mask)
     rows = np.indices(idx.shape)
     want[rows[0], rows[1], np.asarray(idx)] = np.asarray(vals) > -np.inf
     np.testing.assert_array_equal(got, want)
     # and the marked keys' values come out in order, without a sort
-    flat = want.reshape(6, 77)
-    values = jnp.asarray(1000 + 7 * np.arange(6 * 77).reshape(6, 77))
+    t = mask.shape[-1]
+    flat = want.reshape(-1, t)
+    n = len(flat)
+    values = jnp.asarray(1000 + 7 * np.arange(n * t).reshape(n, t))
     out, ok = _compact_chosen(jnp.asarray(flat), values, k)
-    for row in range(6):
+    for row in range(n):
         where = np.nonzero(flat[row])[0]
         np.testing.assert_array_equal(
             np.asarray(out[row])[np.asarray(ok[row])],
