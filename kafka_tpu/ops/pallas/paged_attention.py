@@ -9,11 +9,15 @@ kernel walks each sequence's page list directly:
 
 * grid = (B,): one program per sequence.  The page table and sequence
   lengths ride in as **scalar-prefetch** arguments so the kernel can
-  dereference physical page ids at runtime.
+  dereference physical page ids at runtime: its own lane's and, since the
+  walk is one pipeline across the call's lanes, its neighbours'.
 * the kernel iterates only over the sequence's *valid* pages — a dynamic
   `fori_loop` over softmax steps of STEP_ROWS keys, each step's pages landed
   in VMEM by manually issued async DMAs into a ring of RING buffers, so the
-  copies of the next two steps overlap this step's compute.  A sequence 300
+  copies of the next two steps overlap this step's compute: the lane's own
+  next steps or, in its last trips, the NEXT lane's first ones (the ring,
+  its semaphores and the place in the stream outlive a grid step), so only
+  the call's first lane waits for a copy nothing overlaps.  A sequence 300
   tokens into an 8k window reads 300 tokens' worth of KV, not 8k.  A
   windowed call starts its walk at the chunk (`pages_per_chunk` pages) that
   holds the window's first key.
@@ -183,6 +187,7 @@ def _decode_kernel(
     m_ref,    # [Hq, 1] f32 running max
     l_ref,    # [Hq, 1] f32 running denominator
     acc_ref,  # [Hq, Hkv*D] f32 running numerator
+    call_ref,  # SMEM [5] i32: where the CALL's pipeline stands (see below)
     *,
     page_size: int,
     pages_per_chunk: int,
@@ -190,33 +195,51 @@ def _decode_kernel(
     window: int | None = None,
     latent: bool = False,
 ):
+    # The call's lanes are ONE stream of softmax steps, lane after lane, and
+    # the ring runs over the stream: the copies of the step RING - 1 ahead
+    # are started before a step is attended, whichever lane that step is in.
+    # A lane's last RING - 1 trips so start its neighbour's first steps (the
+    # neighbour after that one's, where the neighbour is one step long), and
+    # the neighbour's program begins attending at once; only the call's lane
+    # 0 fills the ring and only its last lane drains it.  The page table and
+    # the lengths of every lane are in SMEM, the ring and its semaphores are
+    # scratch and outlive a grid step, and the grid runs in order on one core
+    # ("arbitrary").  call_ref carries the stream from program to program:
+    # [0] the ring slot of this lane's first step, [1:4] the next step to
+    # start (its lane, its first page there, the pages the lane holds from
+    # that page on) and [4] its ring slot.  A step's rows, buffer and order
+    # of arithmetic are what they were when each lane filled and drained a
+    # ring of its own: only WHEN a copy starts differs.
     b = pl.program_id(0)
+    lanes = pl.num_programs(0)
     ps = page_size
     ring, sp = kbuf.shape[0], kbuf.shape[1] // ps  # buffers, pages a step
-    # query position is seq_len; it attends positions <= seq_len
-    n_valid = seq_lens_ref[b] + 1
-    n_pages = pl.cdiv(n_valid, ps)
-    # A windowed layer (static `window`) attends positions >= lo only: the
-    # walk starts at the chunk (pages_per_chunk pages) that holds lo, so the
-    # call DMAs at most ceil((window + chunk) / chunk) chunks whatever the
-    # context (decode_chunk_range is the same arithmetic on plain ints).
-    page0, lo = 0, 0
-    if window is not None:
-        lo = jnp.maximum(n_valid - window, 0)
-        page0 = lo // (pages_per_chunk * ps) * pages_per_chunk
-    n_steps = pl.cdiv(n_pages - page0, sp)
-    last = n_steps - 1
     pools = ((k_rows_hbm, kbuf, ksem), (v_rows_hbm, vbuf, vsem))
+
+    def walk(lane):
+        """(keys, window's first key, first page, pages, steps) of a lane."""
+        # query position is seq_len; it attends positions <= seq_len
+        n_valid = seq_lens_ref[lane] + 1
+        n_pages = pl.cdiv(n_valid, ps)
+        # A windowed layer (static `window`) attends positions >= lo only:
+        # the walk starts at the chunk (pages_per_chunk pages) that holds lo,
+        # so the call DMAs at most ceil((window + chunk) / chunk) chunks
+        # whatever the context (decode_chunk_range is the same arithmetic on
+        # plain ints).
+        page0, lo = 0, 0
+        if window is not None:
+            lo = jnp.maximum(n_valid - window, 0)
+            page0 = lo // (pages_per_chunk * ps) * pages_per_chunk
+        return n_valid, lo, page0, n_pages, pl.cdiv(n_pages - page0, sp)
 
     def rows(page, n_pages=1):
         """The pool rows of `n_pages` pages that lie side by side from `page`."""
         return pl.ds(pl.multiple_of(page * ps, ps), n_pages * ps)
 
-    def dma(k, op, guarded):
-        """Start or wait step k's copies into ring slot k % RING.  `guarded`:
-        the step may end short of sp pages."""
-        slot = jax.lax.rem(k, ring)
-        base = page0 + k * sp
+    def dma(lane, base, left, slot, op, guarded):
+        """Start or wait the copies into ring slot `slot` of the step of
+        `lane`'s walk that begins at page `base`, `left` pages before the
+        lane's end.  `guarded`: the step may end short of sp pages."""
         if op == "wait" and not guarded:
             # A DMA semaphore counts bytes: one wait for the whole buffer
             # stands for its sp page copies, or for the one run copy.
@@ -225,23 +248,35 @@ def _decode_kernel(
                     buf.at[slot], buf.at[slot], sem.at[slot, 0]).wait()
             return
 
-        def copy(j, carry=None):  # one scattered page, K and V
-            # a wait needs the copy's size only, not where it came from
-            page = page_table_ref[b, base + j] if op == "start" else 0
+        def copy(j, carry=None):  # start one scattered page, K and V
+            page = page_table_ref[lane, base + j]
             row = j * ps if isinstance(j, int) else pl.multiple_of(j * ps, ps)
             for hbm, buf, sem in pools:
-                cp = pltpu.make_async_copy(
+                pltpu.make_async_copy(
                     hbm.at[rows(page)], buf.at[slot, pl.ds(row, ps)],
-                    sem.at[slot, 0])
-                getattr(cp, op)()
+                    sem.at[slot, 0]).start()
             return carry
 
         if guarded:
             # A loop over the pages the step has, not a guard a page: five
             # unrolled call sites of sp pages made `jit.lower` of one kernel
             # take 0.6 s (0.13 before PR 30) and Mellum2's warm boot 31%
-            # longer; boundary steps are one or two a lane.
-            jax.lax.fori_loop(0, jnp.minimum(sp, n_pages - base), copy, 0)
+            # longer; a walk's last step is one a lane.
+            held = jnp.minimum(sp, left)
+            if op == "start":
+                jax.lax.fori_loop(0, held, copy, 0)
+                return
+            # The semaphore counts bytes, so the waits need not be a page
+            # each: one a set bit of the page count, at most six in place of
+            # thirty-two a pool (with the windowed first step's one wait,
+            # 0.24 of a 1,024-key lane's 4.74 us; PERF.md section 6, PR 62).
+            for bit in reversed(range(sp.bit_length())):
+                @pl.when((held & (1 << bit)) != 0)
+                def _():
+                    for _, buf, sem in pools:
+                        part = buf.at[slot, pl.ds(0, ps << bit)]
+                        pltpu.make_async_copy(
+                            part, part, sem.at[slot, 0]).wait()
             return
 
         def by_page():
@@ -253,8 +288,8 @@ def _decode_kernel(
             # a pool moves the same rows into the same buffer.
             for hbm, buf, sem in pools:
                 pltpu.make_async_copy(
-                    hbm.at[rows(page_table_ref[b, base], sp)], buf.at[slot],
-                    sem.at[slot, 0]).start()
+                    hbm.at[rows(page_table_ref[lane, base], sp)],
+                    buf.at[slot], sem.at[slot, 0]).start()
 
         # sp - 1 compares on the scalar core, as one traced compare unrolled
         # where it is lowered (traced sp - 1 times, `jit.lower` of a kernel
@@ -265,43 +300,75 @@ def _decode_kernel(
         # here: 0.473 us a chunk against these compares' 0.458 and no test's
         # 0.431 at the latent geometry; PERF.md section 6, PR 53).
         run = pages_one_run(
-            lambda i: page_table_ref[b, i], base, sp,
+            lambda i: page_table_ref[lane, i], base, sp,
             functools.partial(jax.lax.fori_loop, unroll=True))
         jax.lax.cond(run, as_run, by_page)
+
+    n_valid, lo, page0, n_pages, n_steps = walk(b)
+    last = n_steps - 1
+
+    @pl.when(b == 0)
+    def _():
+        # nobody ran before lane 0: its own first step is the next to start
+        for i, x in enumerate((0, 0, page0, n_pages - page0, 0)):
+            call_ref[i] = x
+
+    # ... and its first RING - 1 trips only start copies
+    fill = jnp.where(b == 0, ring - 1, 0)
+
+    def after(slot):
+        return jnp.where(slot == ring - 1, 0, slot + 1)
 
     m_ref[...] = jnp.full_like(m_ref, NEG_INF)
     l_ref[...] = jnp.zeros_like(l_ref)
     acc_ref[...] = jnp.zeros_like(acc_ref)
 
     def body(i, carry):
-        # Trip i starts step i's copies and attends step k = i - (RING - 1):
-        # every step's start is this one site, the walk's first RING - 1
-        # included.  Only the walk's last step can end short.
-        k = i - (ring - 1)
+        # A trip starts the stream's next unstarted step, RING - 1 beyond
+        # the step it then attends, this lane's k: every start of the call is
+        # this one site.  Only a walk's last step can end short.
+        lane, base, left, slot, mine = carry
+        live = lane < lanes  # the call's last steps have nothing to start
+        ends = left <= sp    # the step is its lane's last
+        start = functools.partial(dma, lane, base, left, slot, "start")
 
-        @pl.when(i < last)
+        @pl.when(live & ~ends)
         def _():
-            dma(i, "start", False)
+            start(False)
 
-        @pl.when(i == last)
+        @pl.when(live & ends)
         def _():
-            dma(i, "start", True)
+            start(True)
+
+        # the step after it: the lane's next, or the next lane's first
+        _, _, its_page0, its_pages, _ = walk(jnp.minimum(lane + 1, lanes - 1))
+        ahead = (jnp.where(live & ends, lane + 1, lane),
+                 jnp.where(ends, its_page0, base + sp),
+                 jnp.where(ends, its_pages - its_page0, left - sp),
+                 jnp.where(live, after(slot), slot))
 
         # A step before the last (and, windowed, at or above lo) holds sp
         # whole pages of attended rows: no guard, no iota, no mask, no select.
-        row0 = (page0 + k * sp) * ps
+        k = i - fill
+        at = page0 + k * sp
+        row0 = at * ps
         whole = k < last
         if window is not None:
             whole = whole & (row0 >= lo)
-        state = (q_ref, kbuf, vbuf, m_ref, l_ref, acc_ref,
-                 jax.lax.rem(k, ring), scale)
+        state = (q_ref, kbuf, vbuf, m_ref, l_ref, acc_ref, mine, scale)
+        wait = functools.partial(dma, b, at, n_pages - at, mine, "wait")
 
         def whole_step():
-            dma(k, "wait", False)
+            wait(False)
             _attend(*state, latent=latent)
 
         def boundary_step():
-            dma(k, "wait", True)
+            if window is None:
+                wait(True)
+            else:
+                # a windowed walk's first step is a boundary step of sp
+                # whole pages: one wait, as a whole step's
+                jax.lax.cond(k < last, lambda: wait(False), lambda: wait(True))
             _attend(*state, remaining=n_valid - row0,
                     below=None if window is None else lo - row0,
                     latent=latent)
@@ -310,11 +377,20 @@ def _decode_kernel(
         def _():
             jax.lax.cond(whole, whole_step, boundary_step)
 
-        return carry
+        return (*ahead, jnp.where(k >= 0, after(mine), mine))
 
-    jax.lax.fori_loop(0, n_steps + ring - 1, body, 0)
+    carry = jax.lax.fori_loop(
+        0, n_steps + fill, body,
+        (call_ref[1], call_ref[2], call_ref[3], call_ref[4], call_ref[0]))
+    for i, x in enumerate((carry[4], *carry[:4])):
+        call_ref[i] = x
     denom = jnp.maximum(l_ref[...], 1e-30)
     out_ref[0, :, :] = (acc_ref[...] / denom).astype(out_ref.dtype)
+
+
+# _decode_kernel's pipeline runs from one lane's program into the next: the
+# grid is one core's, in order.
+_LANES_IN_ORDER = pltpu.CompilerParams(dimension_semantics=("arbitrary",))
 
 
 def _fori(lo: int, hi: int, body, carry):
@@ -494,6 +570,7 @@ def paged_decode_attention_latent(
             pltpu.VMEM((Hq, 1), jnp.float32),
             pltpu.VMEM((Hq, 1), jnp.float32),
             pltpu.VMEM((Hq, r), jnp.float32),
+            pltpu.SMEM((5,), jnp.int32),
         ],
     )
     kernel = functools.partial(
@@ -506,6 +583,7 @@ def paged_decode_attention_latent(
         interpret=interpret,
         name=("paged_decode_attention_latent" if window is None
               else "paged_decode_attention_latent_window"),
+        compiler_params=_LANES_IN_ORDER,
     )(page_table, seq_lens, q, c_pool, r_pool)
 
 
@@ -562,6 +640,7 @@ def _paged_decode(q, k_pool, v_pool, page_table, seq_lens, page_size,
             pltpu.VMEM((Hq, 1), jnp.float32),
             pltpu.VMEM((Hq, 1), jnp.float32),
             pltpu.VMEM((Hq, HD), jnp.float32),
+            pltpu.SMEM((5,), jnp.int32),
         ],
     )
     kernel = functools.partial(
@@ -577,6 +656,7 @@ def _paged_decode(q, k_pool, v_pool, page_table, seq_lens, page_size,
         out_shape=jax.ShapeDtypeStruct((B, Hq, HD), q.dtype),
         interpret=interpret,
         name=None if window is None else "paged_decode_attention_window",
+        compiler_params=_LANES_IN_ORDER,
     )(page_table, seq_lens, qx, k_pool, v_pool)
     if diff:
         # each query row's result over BOTH value heads of its pair

@@ -1475,9 +1475,15 @@ class InferenceEngine:
         # pass, one layer's worth, and those of them whose pages were one
         # ascending run of physical pages and were fetched as one copy a
         # pool (counted from SequencePages.run_steps, kept as pages are
-        # appended: no page list is scanned a dispatch).
+        # appended: no page list is scanned a dispatch); `all` are that
+        # walk's softmax steps, each lane's last included, and `ahead` those
+        # of them whose copies the kernel had started before their lane's
+        # program began (the call's lanes are one pipeline: every lane but
+        # the first finds its first RING - 1 steps under way).
         self.decode_steps_walked = 0
         self.decode_steps_run = 0
+        self.decode_steps_all = 0
+        self.decode_steps_ahead = 0
         # Monotonic, and 0 for a model without an indexer
         # (StepPrograms.index_keys): the keys a layer's indexer scored over
         # every dispatched decode step (each lane's context, its own row
@@ -4591,9 +4597,12 @@ class InferenceEngine:
         self.decode_keys_window += window
         self.decode_keys_shared += self._programs.decode_keys_shared(
             tables, steps)
-        walked, run = self._programs.decode_steps(seqs, steps)
+        walked, run, every, ahead = self._programs.decode_steps(
+            [m and m.seq for m in members], steps)
         self.decode_steps_walked += walked
         self.decode_steps_run += run
+        self.decode_steps_all += every
+        self.decode_steps_ahead += ahead
         if self.cfg.delta_heads:
             self.delta_state_bytes += self._programs.delta_state_bytes(
                 len(seqs), steps)

@@ -437,6 +437,8 @@ METRICS: Tuple[Metric, ...] = (
         _m("decode_keys_shared", read="decode_keys_shared"),
         _m("decode_steps_walked", read="decode_steps_walked"),
         _m("decode_steps_run", read="decode_steps_run"),
+        _m("decode_steps_all", read="decode_steps_all"),
+        _m("decode_steps_ahead", read="decode_steps_ahead"),
         _m("index_keys_scored",
            "kafka_tpu_engine_index_keys_total{kind=scored}",
            "Keys a layer's indexer scored over the decode steps, those "

@@ -40,7 +40,7 @@ from ..models.mixers.latent import prefill_walk_pages
 from ..ops.attention import decode_walk_pages, shared_walk_trips
 from ..ops.pallas import ssd as ssd_kernels
 from ..ops.pallas.gated_delta import chunk_rows
-from ..ops.pallas.paged_attention import step_pages
+from ..ops.pallas.paged_attention import RING, step_pages
 from ..ops.sampling import (
     SamplingParams,
     grammar_advance,
@@ -590,33 +590,41 @@ class StepPrograms:
         return cp and self.B * ck * sum(
             shared_walk_trips(lanes, steps, self.P, cp, ck))
 
-    def decode_steps(self, seqs, steps: int) -> Tuple[int, int]:
+    def decode_steps(self, lanes, steps: int) -> Tuple[int, int, int, int]:
         """(whole softmax steps the Pallas decode walk fetches, those of
-        them fetched as ONE run copy a pool) over `steps` decode steps of
-        the sequences `seqs` (kv_cache.SequencePages, their `length` the
-        tokens held before the first): a global layer's walk, one layer's
-        worth, by the kernel's own arithmetic (ops/pallas/paged_attention.py
-        _decode_kernel: a lane holding n tokens walks
-        n // step_keys whole steps before its last).  (0, 0) where no global
-        layer walks in that kernel: the `xla` backend, pp, a model with an
-        indexer (its full layers read chosen rows), an int8 pool (its kernel
-        keeps the older walk)."""
+        them fetched as ONE run copy a pool, every softmax step it walks,
+        those of them whose copies were started before their lane's program
+        began) over `steps` decode steps of the call's `lanes` (slot by
+        slot a kv_cache.SequencePages, its `length` the tokens held before
+        the first, or None: not in this dispatch): a global layer's walk,
+        one layer's worth, by the kernel's own arithmetic
+        (ops/pallas/paged_attention.py _decode_kernel: a lane holding n
+        tokens walks n // step_keys whole steps and a last one; the call's
+        lanes are one stream of steps whose copies start RING - 1 steps
+        ahead, so every lane but the call's first finds its first RING - 1
+        steps started).  Zeros where no global layer walks in that kernel:
+        the `xla` backend, pp, a model with an indexer (its full layers read
+        chosen rows), an int8 pool (its kernel keeps the older walk)."""
         mesh = self.mesh
         if (self.cfg.attention_backend != "pallas" or self.cfg.index_topk
                 or self.int8_kv
                 or (mesh is not None and mesh.shape.get("pp", 1) > 1)):
-            return 0, 0
+            return 0, 0, 0, 0
         _, sp = step_pages(self.P, 8, self.ps)  # the wrappers' default chunk
         keys = sp * self.ps
-        walked = run = 0
-        for seq in seqs:
+        walked = run = every = ahead = 0
+        for lane, seq in enumerate(lanes):
+            if seq is None:
+                continue
             n, end = seq.length, seq.length + steps
             for whole in range(n // keys, (end - 1) // keys + 1):
                 # the decode steps that find `whole` whole steps behind them
                 passes = min(end, (whole + 1) * keys) - max(n, whole * keys)
                 walked += passes * whole
                 run += passes * seq.run_steps(sp, whole)
-        return walked, run
+                every += passes * (whole + 1)
+                ahead += passes * min(whole + 1, RING - 1) * (lane > 0)
+        return walked, run, every, ahead
 
     def index_keys(self, lengths, steps: int) -> Tuple[int, int]:
         """(keys scored, keys kept) by ONE layer's indexer over `steps`

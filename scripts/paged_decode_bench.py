@@ -5,6 +5,7 @@ out (DMA starts, waits and the loop only).
 
     python scripts/paged_decode_bench.py                  # Yi's geometry
     python scripts/paged_decode_bench.py --window 0       # global form only
+    python scripts/paged_decode_bench.py --window 128 513 1024   # a form each
     python scripts/paged_decode_bench.py --rehearse       # CPU, tiny, no times
     python scripts/paged_decode_bench.py --prefix-run     # + the run table
     python scripts/paged_decode_bench.py --latent --prefix-run --parent DIR
@@ -19,7 +20,12 @@ Pallas call's own events in one profiler capture (the host clock would add
 the dispatch).  A chunk is `pages_per_chunk` (8) pages, the unit
 `decode_chunk_range` counts and the rooflines of `benchmarks/` charge;
 bytes are those chunks' K and V rows.  Prints one JSON line a form and writes
-them all to chiprun_out/paged_decode_bench.json.
+them all to chiprun_out/paged_decode_bench.json.  Each line also lays the call
+out a LANE: `us_per_lane`, the time its bytes take at the chip's HBM peak
+(`bytes_us_per_lane`) and what is left (`excess_us_per_lane`): the lane's
+fixed cost, whatever it read (`--window 128 513 1024`: forms `window128`,
+`window513`, `window1024`, lanes of one to three softmax steps, beside the
+global form's ~17).
 
 `--prefix-run` times every form on a second table too: the shared prefix's
 pages ONE ascending run of physical pages from page 1 (what the engine's
@@ -154,8 +160,8 @@ def forms(args, jax, modules):
 
     out = {}
     for prefix, pa in modules.items():
-        for window in [None] + ([args.window] if args.window else []):
-            name = prefix + ("window" if window else "global")
+        for window in [None] + args.window:
+            name = prefix + (f"window{window}" if window else "global")
             out[name] = (build(pa, name, window, False), window)
             if hasattr(pa, "_attend"):
                 out[name + "_walk"] = (
@@ -347,7 +353,8 @@ def bench_xla(args, jax, jnp) -> int:
 
 
 def kernel_events(trace_dir):
-    """Device ns of every Pallas call in the capture, in launch order."""
+    """Device ns of every Pallas call in the capture, in launch order, and
+    the events' names."""
     from jax.profiler import ProfileData
 
     path = sorted(glob.glob(os.path.join(
@@ -358,9 +365,12 @@ def kernel_events(trace_dir):
             continue
         for line in plane.lines:
             if line.name == "XLA Ops":
-                events += [(ev.start_ns, ev.duration_ns) for ev in line.events
-                           if "custom-call" in ev.name]
-    return [d for _, d in sorted(events)]
+                # the kernel's calls: their first operand is the page table
+                # (XLA's own custom calls, a sliced pool's copy, take none)
+                events += [(ev.start_ns, ev.duration_ns, ev.name)
+                           for ev in line.events
+                           if "custom-call(s32[" in ev.name]
+    return [d for _, d, _ in sorted(events)], {n[:120] for _, _, n in events}
 
 
 def main() -> int:
@@ -391,8 +401,8 @@ def main() -> int:
                     "(long windows x many lanes do not fit)")
     ap.add_argument("--out", default=None,
                     help="default chiprun_out/paged_decode_bench[_xla].json")
-    ap.add_argument("--window", type=int, default=1024,
-                    help="0: skip the windowed form")
+    ap.add_argument("--window", type=int, nargs="+", default=[1024],
+                    help="the windowed forms, one a window; 0: none")
     ap.add_argument("--dtype", default="bfloat16")
     ap.add_argument("--seed", type=int, default=2147485003)
     ap.add_argument("--reps", type=int, default=5)
@@ -411,6 +421,7 @@ def main() -> int:
                     help="other trees, their kernels timed beside this "
                     "one's (forms parent_*, parent2_*, ...)")
     args = ap.parse_args()
+    args.window = [w for w in args.window if w]
     if args.kv_heads is None:
         args.kv_heads = 8 if args.backend == "xla" else 4
     args.rope_dim, args.rope_lanes = 64, 128
@@ -423,7 +434,7 @@ def main() -> int:
         args.lanes, args.heads, args.kv_heads, args.head_dim = 3, 8, 2, 16
         args.page_size, args.num_pages, args.max_pages = 4, 400, 160
         args.min_len, args.max_len, args.shared_prefix = 300, 600, 280
-        args.window = args.window and 100
+        args.window = args.window and [100]
         args.walk_keys = [32, 64, 256]
         args.shared_keys = args.shared_keys and [0, 280]
         args.latent_rank, args.rope_dim, args.rope_lanes = 128, 16, 128
@@ -432,7 +443,7 @@ def main() -> int:
             # table's run copies are taken here too
             args.page_size, args.num_pages, args.max_pages = 16, 240, 96
             args.min_len, args.max_len, args.shared_prefix = 1100, 1500, 1040
-            args.window = args.window and 700
+            args.window = [100, 400, 700][-len(args.window):]
     if args.shared_keys is None:
         args.shared_keys = [args.shared_prefix]
 
@@ -520,10 +531,10 @@ def bench_pallas(args, jax, jnp, pa) -> int:
             for fn, case, *_ in runs.values():
                 fn(*case).block_until_ready()
     # one Pallas call a launch, launched form after form, rep after rep
-    durations = kernel_events(trace_dir)
+    durations, names = kernel_events(trace_dir)
     if len(durations) != args.reps * len(runs):
         print(f"{len(durations)} Pallas events in the capture, expected "
-              f"{args.reps} x {len(runs)}", file=sys.stderr)
+              f"{args.reps} x {len(runs)}: {sorted(names)}", file=sys.stderr)
         return 1
     _, hbm_bytes_per_s, _ = device_peaks(jax.devices()[0])  # unknown: raises
     item = jnp.dtype(args.dtype).itemsize
@@ -541,14 +552,17 @@ def bench_pallas(args, jax, jnp, pa) -> int:
             chunks += end - first
         us = float(np.median(durs)) / 1e3
         whole, run = steps[tname][window]
+        bytes_us = 1e6 * chunks * chunk_bytes / hbm_bytes_per_s
         row = {
             "calls": len(durs), "us_per_call": us,
             "min_us": min(durs) / 1e3, "max_us": max(durs) / 1e3,
             "chunks": chunks, "us_per_chunk": us / chunks,
             "chunk_bytes": chunk_bytes,
             "steps_whole": whole, "steps_run": run,
-            "hbm_share": 100.0 * chunks * chunk_bytes / hbm_bytes_per_s
-            / (us / 1e6),
+            "hbm_share": 100.0 * bytes_us / us,
+            "us_per_lane": us / args.lanes,
+            "bytes_us_per_lane": bytes_us / args.lanes,
+            "excess_us_per_lane": (us - bytes_us) / args.lanes,
         }
         result["forms"][name] = row
         print(json.dumps({"form": name, **row}))

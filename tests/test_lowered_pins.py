@@ -7,7 +7,7 @@ two-lane batched prefill launch (from shapes alone,
 `tests/test_moe_dispatch.py`'s way; the full-width files too, which lower in
 seconds and hold no array) is what the parent commit lowers:
 `tests/recorded/lowered_pins.json` holds the digests, recorded AT THE PARENT
-(9dfc90c, PR 60, for PR 61) by running this file in a checkout of it with
+(e5b0bc5, PR 61, for PR 62) by running this file in a checkout of it with
 `KAFKA_TPU_RECORD_PINS=<path>` (same conftest, same JAX).  Equal text = the
 same executable and a warm compile cache across the two trees.
 
@@ -45,19 +45,16 @@ from kafka_tpu.runtime.kv_cache import default_state_slots, make_kv_pool_arrays
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 PINS = os.path.join(ROOT, "tests", "recorded", "lowered_pins.json")
 RECORD = os.environ.get("KAFKA_TPU_RECORD_PINS")
-# the files of a model this very PR adds (docstring): not pinned.  PR 61: none
+# the files of a model this very PR adds (docstring): not pinned.  PR 62: none
 NEW = ()
-# the programs this very PR means to move (docstring).  PR 61: those of the
-# configurations with an indexer whose shapes reach `t > index_topk`, where
-# `mixers/index._chosen_mask` searches over [B, T] rows at decode and through
-# `_threshold` (the tiny twins with an `index_topk`; `file:dots3-note-prev.*`
-# holds 64 keys here, under its top-k of 2,048, and lowers to the parent's
-# text)
+# the programs this very PR means to move (docstring).  PR 62: the decode
+# steps that hold `ops/pallas/paged_attention._decode_kernel`, whose walk is
+# one pipeline across a call's lanes: the three latent twins lowered on the
+# Pallas backend (every other key here lowers on `xla` and holds no kernel;
+# a batched prefill launch holds the prefill kernels only)
 MOVED = frozenset(
-    f"file:{name}.{backend}.{program}"
-    for name, backends in (("tiny-dots3", ("xla", "pallas")),
-                           ("tiny-shared", ("xla",)))
-    for backend in backends for program in ("decode", "bprefill"))
+    f"file:{name}.pallas.decode"
+    for name in ("tiny-kanana2", "tiny-dots3", "tiny-xing4"))
 PS, LANES, PAGES, BUCKET, WIDTH = 8, 4, 8, 16, 2
 
 

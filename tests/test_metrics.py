@@ -854,15 +854,21 @@ RUN_READER = os.path.join(
     "decode_run_step_share.py")
 
 
-@pytest.fixture(scope="module")
-def run_share():
+def _layer_metric_reader(path):
+    """`read` of a benchmarks/layer_metrics file, loaded with `benchmarks/`
+    on the path for the time of the tests that use it."""
     with pytest.MonkeyPatch.context() as mp:
-        mp.syspath_prepend(os.path.dirname(os.path.dirname(RUN_READER)))
+        mp.syspath_prepend(os.path.dirname(os.path.dirname(path)))
         spec = importlib.util.spec_from_file_location(
-            "decode_run_step_share", RUN_READER)
+            os.path.basename(path)[:-len(".py")], path)
         module = importlib.util.module_from_spec(spec)
         spec.loader.exec_module(module)
         yield module.read
+
+
+@pytest.fixture(scope="module")
+def run_share():
+    yield from _layer_metric_reader(RUN_READER)
 
 
 @pytest.fixture(scope="module")
@@ -984,3 +990,90 @@ def test_decode_step_counters_stay_zero_where_no_kernel_walks(
     assert after["engine"]["decode_steps_run"] == 0
     assert run_share({"before": before, "after": after}) is None
     assert run_share({"before": {"engine": {}}, "after": after}) is None
+
+
+# ----------------------------------------------------------------------
+# decode_steps_all / decode_steps_ahead (PR 62): every softmax step of the
+# Pallas decode walk, and those started before their lane's program began
+# ----------------------------------------------------------------------
+
+AHEAD_READER = os.path.join(
+    os.path.dirname(RUN_READER), "decode_ahead_step_share.py")
+
+
+@pytest.fixture(scope="module")
+def ahead_share():
+    yield from _layer_metric_reader(AHEAD_READER)
+
+
+@pytest.mark.parametrize("multi_step", [1, 4])
+def test_ahead_step_counters_are_the_kernels_arithmetic(
+        walk_model, ahead_share, monkeypatch, multi_step):
+    """Two threads decoding side by side, 530 and 40 tokens of prompt: the
+    counters equal the kernel's walk redone from every dispatch's lanes,
+    slot by slot and pass by pass (`decode_step_runs`: the whole steps and a
+    last one; a lane in any slot but the call's first finds its first
+    RING - 1 steps started by the lane before it)."""
+    from kafka_tpu.ops.pallas.paged_attention import RING
+
+    eng = _walk_engine(walk_model, "pallas", multi_step=multi_step)
+    seen, book = [], eng._book_dispatch
+
+    def spy(toks, members, steps):
+        seen.append((steps, [m and (list(m.seq.pages), m.seq.length)
+                             for m in members]))
+        return book(toks, members, steps)
+
+    monkeypatch.setattr(eng, "_book_dispatch", spy)
+    rng = np.random.RandomState(62)
+    before = eng.metrics.snapshot(eng)
+    for i, n in enumerate((530, 40)):
+        eng.submit(GenRequest(
+            request_id=f"r{i}", max_new_tokens=9,
+            prompt_ids=list(rng.randint(1, 128, size=n))))
+    eng.run_to_completion()
+    every = ahead = 0
+    for steps, lanes in seen:
+        for slot, lane in enumerate(lanes):
+            for i in range(steps if lane else 0):
+                pages, length = lane
+                n = decode_step_runs(pages, length + i, None, 16, 64)[0] + 1
+                every += n
+                ahead += min(n, RING - 1) if slot else 0
+    assert any(lanes[0] and lanes[1] for _, lanes in seen)
+    assert 0 < ahead < every
+    assert (eng.decode_steps_all, eng.decode_steps_ahead) == (every, ahead)
+    after = eng.metrics.snapshot(eng)
+    assert (after["engine"]["decode_steps_all"],
+            after["engine"]["decode_steps_ahead"]) == (every, ahead)
+    assert ahead_share({"before": before, "after": after}) == pytest.approx(
+        100.0 * ahead / every)
+
+
+@pytest.mark.parametrize("backend, kw", [
+    ("xla", {}), ("pallas", {"kv_quantize": "int8"})])
+def test_ahead_step_counters_stay_zero_where_no_kernel_walks(
+        walk_model, ahead_share, backend, kw):
+    eng = _walk_engine(walk_model, backend, **kw)
+    before = eng.metrics.snapshot(eng)
+    eng.submit(GenRequest(request_id="a", max_new_tokens=4,
+                          prompt_ids=list(range(1, 521))))
+    eng.run_to_completion()
+    after = eng.metrics.snapshot(eng)
+    assert (eng.decode_steps_all, eng.decode_steps_ahead) == (0, 0)
+    assert ahead_share({"before": before, "after": after}) is None
+    # the parent: /metrics without the counters
+    assert ahead_share({"before": {"engine": {}}, "after": after}) is None
+
+
+@pytest.mark.parametrize("before, after, want", [
+    ((1000, 100), (18000, 2100), 100.0 * 2000 / 17000),  # 2 of 17, 15 of 16
+    ((0, 0), (340, 0), 0.0),                             # one lane a call
+    ((50, 6), (50, 6), None),                            # no decode step
+])
+def test_the_ahead_reader_reads_the_window(ahead_share, before, after, want):
+    ctx = {k: {"engine": {"decode_steps_all": v[0],
+                          "decode_steps_ahead": v[1]}}
+           for k, v in (("before", before), ("after", after))}
+    got = ahead_share(ctx)
+    assert got == (want if want is None else pytest.approx(want))

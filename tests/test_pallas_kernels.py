@@ -6,6 +6,7 @@ way the engine's decode step drives it (PagedView index plan).
 """
 
 import functools
+import itertools
 
 import numpy as np
 import pytest
@@ -20,6 +21,7 @@ from kafka_tpu.ops.pallas import (
     paged_decode_attention_window,
 )
 from kafka_tpu.ops.pallas.paged_attention import (
+    RING,
     decode_step_runs,
     pages_one_run,
 )
@@ -675,6 +677,20 @@ def _lay_out(pages, content, place):
     return pools, table
 
 
+def _form_call(form, q, args, window, interpret=True):
+    """One of RUN_FORMS over q [B, 4, 32] (latent: [q^ | q_rope], 64 + 16)
+    and args = (pool, pool, table, lens)."""
+    if form.startswith("latent"):
+        return paged_decode_attention_latent(
+            q[..., :64], q[..., 64:], *args, scale=0.11, page_size=RUN_PS,
+            interpret=interpret, window=window)
+    if window:
+        return paged_decode_attention_window(
+            q, *args, window=window, page_size=RUN_PS, interpret=interpret)
+    return paged_decode_attention(
+        q, *args, page_size=RUN_PS, interpret=interpret, diff=form == "diff")
+
+
 def _run_case(form, dtype, layout):
     """[(output, table)] of `form` over the layout as named and over the
     same pages spread out, and the lanes' contexts."""
@@ -695,19 +711,7 @@ def _run_case(form, dtype, layout):
         pools, table = _lay_out(pages, content, place)
         args = ([jnp.asarray(pool, dtype) for pool in pools]
                 + [jnp.asarray(table), jnp.asarray(lens, jnp.int32)])
-        if latent:
-            out = paged_decode_attention_latent(
-                jnp.asarray(q[..., :64], dtype), jnp.asarray(q[..., 64:], dtype),
-                *args, scale=0.11, page_size=RUN_PS, interpret=True,
-                window=window)
-        elif window:
-            out = paged_decode_attention_window(
-                jnp.asarray(q, dtype), *args, window=window,
-                page_size=RUN_PS, interpret=True)
-        else:
-            out = paged_decode_attention(
-                jnp.asarray(q, dtype), *args, page_size=RUN_PS,
-                interpret=True, diff=form == "diff")
+        out = _form_call(form, jnp.asarray(q, dtype), args, window)
         outs.append((np.asarray(out, np.float32), table))
     return outs, lens
 
@@ -766,3 +770,115 @@ def test_run_step_count_is_the_kernels_own_test(layout, window):
                 for upto in range(last + 1):
                     done = min(upto, cut // RUN_SP)
                     assert seq.run_steps(RUN_SP, upto) == sum(want[:done])
+
+
+# ----------------------------------------------------------------------
+# one pipeline across a call's lanes (PR 62)
+# ----------------------------------------------------------------------
+#
+# _decode_kernel starts a lane's first RING - 1 steps while the lane before
+# it attends its last ones: the ring's slots, the semaphores and the place in
+# the stream carry over from one lane's program to the next.  What only that
+# can get wrong is a start made for the wrong neighbour (its rows, its slot,
+# its count of pages), so each case asserts the batched call equals the same
+# lanes called ONE AT A TIME (a call of one lane fills and drains by itself),
+# bit for bit, every lane, the discarded ones too.
+
+PIPE_KEYS = {1: 300, 2: 700, 3: 1100, 4: 1800}   # steps of a global walk: keys
+assert RING == 3   # the cases below are RING - 1 = 2 and RING + 1 = 4 steps
+
+
+def _pipe_cases():
+    """{name: [(keys held, live)] lane by lane}; a lane that is not live has
+    the trash page in every column of its row."""
+    out = {}
+    for order in itertools.permutations((1, RING - 1, RING + 1)):
+        out["steps_" + "_".join(map(str, order))] = [
+            (PIPE_KEYS[n], True) for n in order]
+    out["one_lane"] = [(PIPE_KEYS[3], True)]
+    out["zero_length_lane"] = [
+        (PIPE_KEYS[2], True), (0, True), (PIPE_KEYS[4], True)]
+    out["trash_lane_between"] = [
+        (PIPE_KEYS[4], True), (PIPE_KEYS[2], False), (PIPE_KEYS[1], True)]
+    out["last_lane_longest"] = [
+        (PIPE_KEYS[1], True), (PIPE_KEYS[1], True), (2040, True)]
+    out["last_lane_shortest"] = [
+        (2040, True), (PIPE_KEYS[3], True), (3, True)]
+    return out
+
+
+PIPE_CASES = _pipe_cases()
+
+
+def _pipe_inputs(form, case):
+    """(q, pools, table, lens, window) of a case: scattered pages but every
+    other lane's, which are one run; every row of the pools finite (a lane on
+    the trash page reads page 0)."""
+    lanes = PIPE_CASES[case]
+    rng = np.random.RandomState(62 + len(case))
+    latent = form.startswith("latent")
+    widths = (64, 128) if latent else (64, 64)
+    pools = [rng.randn(RUN_POOL_PAGES * RUN_PS, w).astype(np.float32)
+             for w in widths]
+    free = [int(p) for p in rng.permutation(np.arange(700, RUN_POOL_PAGES))]
+    table = np.zeros((len(lanes), RUN_P), np.int32)
+    for b, (n, live) in enumerate(lanes):
+        if not live:
+            continue
+        need = -(-(n + 1) // RUN_PS)
+        table[b, :need] = (range(1 + 200 * b, 1 + 200 * b + need) if b % 2
+                           else [free.pop() for _ in range(need)])
+    q = rng.randn(len(lanes), 4, 64 + 16 if latent else 32).astype(np.float32)
+    lens = np.asarray([n for n, _ in lanes], np.int32)
+    return q, pools, table, lens, (
+        RUN_WINDOW if form.endswith("window") else None)
+
+
+def _pipe_call(form, q, pools, table, lens, window, interpret=True):
+    args = ([jnp.asarray(pool) for pool in pools]
+            + [jnp.asarray(table), jnp.asarray(lens)])
+    return _form_call(form, jnp.asarray(q), args, window, interpret)
+
+
+@pytest.mark.parametrize("case", sorted(PIPE_CASES))
+@pytest.mark.parametrize("form", RUN_FORMS)
+def test_a_call_of_many_lanes_is_its_lanes_called_one_at_a_time(form, case):
+    q, pools, table, lens, window = _pipe_inputs(form, case)
+    together = np.asarray(_pipe_call(form, q, pools, table, lens, window))
+    assert np.isfinite(together).all()
+    for b in range(len(lens)):
+        alone = np.asarray(_pipe_call(
+            form, q[b:b + 1], pools, table[b:b + 1], lens[b:b + 1], window))
+        assert np.array_equal(together[b:b + 1], alone), (b, int(lens[b]))
+    if window is None:
+        # the cases are what their names say: steps of the global walk
+        steps = [decode_step_runs(table[b].tolist(), int(n), None, RUN_PS,
+                                  RUN_P)[0] + 1 for b, n in enumerate(lens)]
+        assert steps == [n // 512 + 1 for n in lens]
+        if case.startswith("steps_"):
+            assert steps == [int(s) for s in case.split("_")[1:]]
+    if form in ("global", "window"):
+        live = [b for b, (_, on) in enumerate(PIPE_CASES[case]) if on]
+        k_pool, v_pool = (pool.reshape(-1, 2, 32) for pool in pools)
+        ref = xla_reference(q, k_pool, v_pool, table, lens, RUN_PS, window)
+        np.testing.assert_allclose(
+            together[live], np.asarray(ref)[live], rtol=2e-4, atol=2e-4)
+
+
+@pytest.mark.parametrize("form", RUN_FORMS)
+def test_every_copy_a_lane_reads_was_waited_for(form):
+    """The TPU interpreter with copies that land only when they are WAITED
+    for and its race detector on: a step attended before the wait that
+    stands for its copies (a neighbour's start signalled on another slot's
+    semaphore, or counted other pages than the lane waits for) reads what
+    the ring held before, or is reported as a race."""
+    from jax._src.pallas.mosaic.interpret import interpret_pallas_call
+    from jax.experimental.pallas import tpu as pltpu
+
+    case = "steps_2_1_4"   # a lane of one step hands a start on
+    args = _pipe_inputs(form, case)
+    want = np.asarray(_pipe_call(form, *args))
+    got = np.asarray(_pipe_call(form, *args, interpret=pltpu.InterpretParams(
+        dma_execution_mode="on_wait", detect_races=True)))
+    assert np.array_equal(got, want)
+    assert not interpret_pallas_call.races.races_found
