@@ -24,7 +24,12 @@ normed input, under the family's muP multipliers.  A fifth (`nemotron_h`:
 Nemotron-3-Nano-30B-A3B) gives every layer ONE sublayer: a Mamba-2 mixer that
 stands alone, grouped-query attention that does not rotate, or a routed
 feed-forward of ungated squared-ReLU experts, by a pattern of three kinds;
-which half a layer has is its kind's (`mixer_of`, `has_ffn`).
+which half a layer has is its kind's (`mixer_of`, `has_ffn`).  A sixth
+(`granitemoehybrid`: Granite-4.0-H) gives every layer TWO sublayers: a lone
+Mamba-2 mixer OR grouped-query attention that does not rotate, then a
+softmax-routed feed-forward beside a shared expert, each added into the stream
+at `residual_multiplier`; attention's softmax scale is the published
+`attention_multiplier`.
 
 The reference service routed model names to remote providers by string
 heuristics (src/llm/utils.py:11-29); here a model name resolves to a local
@@ -58,7 +63,9 @@ from .vision import VisionConfig
 # `nemotron_h`'s, whose layers hold ONE sublayer each: MAMBA2, a Mamba-2 (SSD)
 # mixer that stands alone (a state, no rows, no feed-forward), and MOE, a
 # routed feed-forward that stands alone (no mixer, no state, no rows); beside
-# them a GLOBAL layer is attention alone.
+# them a GLOBAL layer is attention alone.  `granitemoehybrid` names MAMBA2
+# and GLOBAL layers WITHOUT naming MOE ones: every layer then has its
+# feed-forward behind the mixer (`ModelConfig.mixer_then_ffn`).
 WINDOWED = "sliding_attention"
 GLOBAL = "full_attention"
 MAMBA = "mamba"
@@ -340,6 +347,12 @@ class ModelConfig:
     ssm_out_multiplier: float = 1.0
     ssm_multipliers: Tuple[float, ...] = ()
     mlp_multipliers: Tuple[float, ...] = ()
+    # `granitemoehybrid`'s two: every sublayer's output enters the stream
+    # times `residual_multiplier`, h <- h + r F(norm(h)) (1.0 = absent), and
+    # grouped-query attention's softmax scale is `attention_multiplier` in
+    # place of head_dim ** -0.5 (0.0 = absent: `softmax_scale` None)
+    residual_multiplier: float = 1.0
+    attention_multiplier: float = 0.0
     # The feed-forward's activation, a row of models/ffn.ACTIVATIONS: "silu"
     # is the gated SwiGLU every other model has (gate, up and down matrices);
     # "relu2" is `nemotron_h`'s UNGATED squared ReLU, down(relu(up(x)) ** 2):
@@ -391,13 +404,37 @@ class ModelConfig:
                 f"unrotated_kinds {list(self.unrotated_kinds)}: known "
                 f"{[WINDOWED, GLOBAL]}")
         if self.num_experts_routed and not (
-                self.moe_scoring == "sigmoid" and 0 <= self.expert_offset
-                and self.expert_offset + self.num_experts
-                <= self.num_experts_routed):
+                0 <= self.expert_offset and self.expert_offset
+                + self.num_experts <= self.num_experts_routed):
             raise UnsupportedConfigError(
                 "a share of the routed experts (num_experts_routed) needs "
-                "sigmoid routing and expert_offset + num_experts within the "
-                "router's width")
+                "expert_offset + num_experts within the router's width")
+        if self.num_experts_routed and self.moe_scoring == "softmax" \
+                and not self.mixer_then_ffn:
+            # (the homogeneous stack shards whole experts over "ep"; a held
+            # share is the lead-and-routed tree's)
+            raise UnsupportedConfigError(
+                "a share of the routed experts (num_experts_routed) under "
+                "the softmax rule is built in the mixer-then-feed-forward "
+                f"layout only (layer_types naming {MAMBA2!r} and no {MOE!r} "
+                "layers); elsewhere it needs sigmoid routing")
+        if self.attention_multiplier < 0 or self.residual_multiplier <= 0:
+            raise UnsupportedConfigError(
+                f"attention_multiplier = {self.attention_multiplier!r} and "
+                f"residual_multiplier = {self.residual_multiplier!r}: a "
+                "softmax scale and a residual scale are positive")
+        if self.attention_multiplier and (self.is_latent
+                                          or self.mamba_d_state):
+            raise UnsupportedConfigError(
+                "attention_multiplier (a published softmax scale) is built "
+                "on grouped-query attention only (no latent attention, no "
+                "Mamba-1 decoder)")
+        if self.residual_multiplier != 1.0 and (
+                self.hc_mult > 1 or self.mamba_d_state):
+            raise UnsupportedConfigError(
+                "residual_multiplier is built on the one-row residual "
+                "stream of models/llama.forward (no hc_mult > 1, no Mamba-1 "
+                "decoder)")
         if self.hc_mult < 1 or self.hc_mult > 1 and (
                 self.hc_sinkhorn_iters < 1 or not self.is_latent
                 or self.has_state):
@@ -433,8 +470,10 @@ class ModelConfig:
             self._check_conv_layout()
         if self.delta_heads:
             self._check_delta_layout()
-        if self.lone_layers or MAMBA2 in self.layer_types:
+        if self.lone_layers:
             self._check_lone_layout()
+        elif MAMBA2 in self.layer_types:
+            self._check_mixer_then_ffn_layout()
         elif self.ssd_heads:
             self._check_parallel_layout()
         if len(self.layer_types) != self.num_layers:
@@ -612,6 +651,44 @@ class ModelConfig:
                 "linear-attention layers, vision tower, dense lead, widened "
                 "residual stream (hc_mult > 1), QK-norm or muP multipliers")
 
+    def _check_mixer_then_ffn_layout(self) -> None:
+        """The mixer-then-feed-forward layout (`granitemoehybrid`): every
+        layer a lone SSD mixer (MAMBA2) OR attention (GLOBAL), and BEHIND it,
+        under a norm of its own, the routed feed-forward, in whatever order
+        `layer_types` gives, judged by what the program needs of it: an SSD
+        mixer's geometry, rows for some layer to hold (the page table and the
+        prefix cache key on pages), experts for the feed-forward (the tree is
+        the lead-and-routed one with the mixers' leaves per kind), grouped-
+        query attention, one row a token and no second kind of state."""
+        kinds = set(self.layer_types)
+        if kinds - {MAMBA2, GLOBAL}:
+            raise UnsupportedConfigError(
+                f"a lone SSD mixer ({MAMBA2}) with a feed-forward behind it "
+                f"is served with layer_types of {MAMBA2} and {GLOBAL} layers "
+                f"(and with {MOE} layers in the one-sublayer layout); "
+                f"layer_types is {list(self.layer_types)}")
+        if GLOBAL not in kinds:
+            raise UnsupportedConfigError(
+                "layer_types names no full_attention layer: the paged pool "
+                "and the prefix cache need one layer that holds rows")
+        self._check_ssd_mixer()
+        if not self.is_moe:
+            raise UnsupportedConfigError(
+                f"a {MAMBA2} layer with a feed-forward behind it is served "
+                "with a ROUTED feed-forward (num_experts): the dense tree "
+                "has no leaves per kind")
+        if (self.is_latent or self.mamba_d_state or self.conv_L_cache
+                or self.delta_heads or self.vision is not None
+                or self.first_k_dense or self.hc_mult > 1 or self.qk_norm
+                or self.ssm_multipliers or self.mlp_multipliers
+                or self.mlp_act != "silu"):
+            raise UnsupportedConfigError(
+                "the mixer-then-feed-forward layout stands on grouped-query "
+                "attention, gated SiLU experts and one row a token: no "
+                "latent attention, Mamba-1, conv or linear-attention layers, "
+                "vision tower, dense lead, widened residual stream (hc_mult "
+                "> 1), QK-norm or muP vectors")
+
     @property
     def is_moe(self) -> bool:
         return self.num_experts > 0
@@ -634,6 +711,21 @@ class ModelConfig:
         feed-forward, one norm, one residual add), which a layout shows by
         naming layers that are a routed feed-forward alone."""
         return MOE in self.layer_types
+
+    @property
+    def mixer_then_ffn(self) -> bool:
+        """Lone SSD mixers (and attention) each with the feed-forward BEHIND
+        them in the same layer (`granitemoehybrid`): a layout that names
+        MAMBA2 layers and no layer that is a feed-forward alone."""
+        return MAMBA2 in self.layer_types and not self.lone_layers
+
+    @property
+    def softmax_scale(self) -> Optional[float]:
+        """Grouped-query attention's softmax scale where the config
+        publishes one (`attention_multiplier`); None: head_dim ** -0.5, which
+        every attention path defaults to itself (and not an argument
+        traced)."""
+        return self.attention_multiplier or None
 
     def has_ffn(self, kind: str) -> bool:
         """A layer of `kind` has a feed-forward half: every layer, but in the
@@ -789,7 +881,8 @@ class ModelConfig:
                     or self.shared_intermediate_size
                     or self.moe_scoring != "softmax"
                     or CONV in self.layer_types
-                    or DELTA in self.layer_types)
+                    or DELTA in self.layer_types
+                    or self.mixer_then_ffn)
 
     @property
     def kind_leaves(self) -> bool:
@@ -798,12 +891,14 @@ class ModelConfig:
         "dense_layers" hold the norms and the feed-forward leaves: a latent
         model whose kinds differ, and the conv and linear-attention layouts
         (a CONV or DELTA layer's leaves have nothing in common with an
-        attention layer's), and the one-sublayer layout, whose routed
+        attention layer's), the mixer-then-feed-forward layout (a MAMBA2
+        layer's neither), and the one-sublayer layout, whose routed
         feed-forward's leaves are stacked per kind too, under
         `params["ffn"][kind]`, and whose "layers" holds each layer's one
         norm."""
         return (self.by_kind or CONV in self.layer_types
-                or DELTA in self.layer_types or self.lone_layers)
+                or DELTA in self.layer_types or self.lone_layers
+                or self.mixer_then_ffn)
 
     @property
     def by_kind(self) -> bool:
@@ -1568,6 +1663,88 @@ def _lone_keys(hf: dict) -> dict:
         out["expert_offset"] = int(hf.get("expert_share_offset", 0))
     return out
 
+# `granitemoehybrid`'s published `layer_types` words.  "mamba" there is a
+# Mamba-2 mixer; letter for letter it is this module's MAMBA, `phi4flash`'s
+# Mamba-1 layer with another state, another tree and another forward pass:
+# the words are translated by `model_type`, never read as kinds.
+_GRANITE_KINDS = {"mamba": MAMBA2, "attention": GLOBAL}
+
+
+def _granite_keys(hf: dict) -> dict:
+    """The keys of a `granitemoehybrid` config.json (Granite-4.0-H: every
+    layer a Mamba-2 mixer OR grouped-query attention that does not rotate,
+    then a softmax-routed feed-forward of gated SiLU experts beside one
+    shared expert; four muP scalars) as ModelConfig fields; {} for any other
+    model.  `logits_scaling` DIVIDES the logits (read into
+    `lm_head_multiplier` as its reciprocal), `embedding_multiplier`
+    multiplies the embedding, `residual_multiplier` every sublayer's output
+    on its way into the stream and `attention_multiplier` IS the softmax
+    scale.  What the config has no key for (the order of the input
+    projection's columns, the unclamped step, the gated norm over the one
+    group, which half of an expert's input matrix is gated) is listed as
+    `assumed` beside the benchmark's copy of the file.  What is not served is
+    an UnsupportedConfigError, by key."""
+    if hf.get("model_type") != "granitemoehybrid":
+        return {}
+    served = (
+        ("hidden_act", "silu", "another feed-forward activation"),
+        ("attention_bias", False, "attention biases"),
+        ("mamba_proj_bias", False, "biases on the mixer's projections"),
+        ("mamba_conv_bias", True, "a convolution without its bias"),
+        ("normalization_function", "rmsnorm", "another normalisation"),
+        ("position_embedding_type", "nope", "rotary positions: the "
+         "attention layers of the hybrid carry no position signal"),
+        ("rope_scaling", None, "scaled rotary positions (the attention "
+                               "layers do not rotate)"),
+        ("tie_word_embeddings", True, "an untied head"),
+    )
+    _refuse_unless(hf, served)
+    n = int(hf["num_hidden_layers"])
+    # a depth-cut copy keeps the published list: the layers that exist
+    words = tuple(hf.get("layer_types") or ())[:n]
+    bad = sorted(set(words) - set(_GRANITE_KINDS))
+    if bad or len(words) != n:
+        raise UnsupportedConfigError(
+            f"layer_types {list(hf.get('layer_types') or ())} for {n} "
+            f"layers: one word a layer of {sorted(_GRANITE_KINDS)} is "
+            "served under granitemoehybrid"
+            + (f"; {bad} is not" if bad else ""))
+    heads, size = int(hf["mamba_n_heads"]), int(hf["mamba_d_head"])
+    if int(hf.get("mamba_expand", 2)) * int(hf["hidden_size"]) != heads * size:
+        raise UnsupportedConfigError(
+            f"mamba_expand x hidden_size = {hf.get('mamba_expand', 2)} x "
+            f"{hf['hidden_size']} is not mamba_n_heads x mamba_d_head = "
+            f"{heads} x {size}")
+    if not int(hf.get("num_local_experts") or 0):
+        raise UnsupportedConfigError(
+            "num_local_experts = 0 (a dense feed-forward behind the mixers) "
+            "is not served: the layout's feed-forward is the routed block")
+    scaling = float(hf.get("logits_scaling", 1.0))
+    if scaling <= 0:
+        raise UnsupportedConfigError(
+            f"logits_scaling = {scaling!r}: the logits are divided by it")
+    out = {
+        "layer_types": tuple(_GRANITE_KINDS[w] for w in words),
+        "unrotated_kinds": (GLOBAL,),
+        "ssd_heads": heads,
+        "ssd_head_dim": size,
+        "ssd_d_state": int(hf["mamba_d_state"]),
+        "ssd_groups": int(hf.get("mamba_n_groups", 1)),
+        "ssd_conv_kernel": int(hf.get("mamba_d_conv", 4)),
+        "shared_intermediate_size": int(
+            hf.get("shared_intermediate_size") or 0),
+        "embedding_multiplier": float(hf.get("embedding_multiplier", 1.0)),
+        "lm_head_multiplier": 1.0 / scaling,
+        "residual_multiplier": float(hf.get("residual_multiplier", 1.0)),
+        "attention_multiplier": float(hf.get("attention_multiplier", 0.0)),
+        "tie_word_embeddings": True,
+    }
+    published = int(hf.get("num_local_experts_published") or 0)
+    if published:
+        out["num_experts_routed"] = published
+        out["expert_offset"] = int(hf.get("expert_share_offset", 0))
+    return out
+
 
 def config_from_hf_json(path: str) -> ModelConfig:
     """Build a ModelConfig from a HuggingFace config.json: Llama / Mixtral
@@ -1578,8 +1755,9 @@ def config_from_hf_json(path: str) -> ModelConfig:
     on grouped-query attention (`_routed_lead_keys`: `exaone_moe`), those of
     a `phi4flash` hybrid decoder (`_hybrid_keys`), those of an `lfm2_moe`
     one (`_conv_keys`), those of a `solar_open2` one (`_delta_keys`), those
-    of a `falcon_h1` one (`_parallel_keys`) and those of a `nemotron_h` one
-    (`_lone_keys`).  A key the program cannot honour
+    of a `falcon_h1` one (`_parallel_keys`), those of a `nemotron_h` one
+    (`_lone_keys`) and those of a `granitemoehybrid` one (`_granite_keys`).
+    A key the program cannot honour
     is an UnsupportedConfigError."""
     with open(path) as f:
         hf = json.load(f)
@@ -1618,7 +1796,7 @@ def config_from_hf_json(path: str) -> ModelConfig:
             "experts, not renormalised) is not served: routing here is a "
             "softmax over exactly the top-k logits")
     hybrid = (_hybrid_keys(hf) or _conv_keys(hf) or delta
-              or _parallel_keys(hf) or lone)
+              or _parallel_keys(hf) or lone or _granite_keys(hf))
     pattern = {} if "layer_types" in hybrid else _layer_pattern(hf)
     rope_theta = hf.get("rope_theta")
     if rope_theta is None:

@@ -60,18 +60,29 @@ def _mlp_block(x: jnp.ndarray, lp: Params,
 
 
 def _routing_weights(t: jnp.ndarray, router: jnp.ndarray,
-                     top_k: int, picks: bool = False):
+                     top_k: int, picks: bool = False,
+                     choice: Optional[jnp.ndarray] = None):
     """Per-token expert weights [T, E]: softmax over EXACTLY the top-k
     router logits, scattered back (HF MixtralSparseMoeBlock semantics —
     a >=threshold mask would activate extra experts on k-th-place ties).
     The canonical routing implementation; parallel/expert.py reuses it.
     `picks`: the same choice unscattered, (experts [T, k] i32, weights
     [T, k] f32), for token dispatch (`_experts_token`).
+    `choice` [E] float32, a leaf no published tree holds ("router_choice":
+    None, and not an op traced): added to the logits for the CHOICE alone,
+    as the sigmoid rule's selection bias is; the softmax is over the chosen
+    experts' own logits.  A check hands it over to name a row's experts
+    (benchmarks/drivers/granitemoehybrid_pool.py).
     """
     logits = jnp.einsum(
         "th,he->te", t, router, preferred_element_type=jnp.float32
     )
-    top_vals, top_idx = jax.lax.top_k(logits, top_k)
+    if choice is None:
+        top_vals, top_idx = jax.lax.top_k(logits, top_k)
+    else:
+        _, top_idx = jax.lax.top_k(
+            logits + choice.astype(jnp.float32), top_k)
+        top_vals = jnp.take_along_axis(logits, top_idx, axis=-1)
     w_top = jax.nn.softmax(top_vals, axis=-1)
     if picks:
         return top_idx, w_top
@@ -257,10 +268,16 @@ def _experts_token(t: jnp.ndarray, top_idx: jnp.ndarray, w_top: jnp.ndarray,
 def _moe_block(x: jnp.ndarray, lp: Params, cfg: ModelConfig,
                chunk_len: Optional[jnp.ndarray] = None,
                sharded: bool = False,
-               stacked: Optional[Tuple[Params, Any]] = None):
+               stacked: Optional[Tuple[Params, Any]] = None,
+               count_picks: bool = False):
     """Top-k routed MoE MLP. x: [B, S, H] -> (output [B, S, H], the held
     experts whose weights the block read: an i32 the token form counts, all
-    of them, a Python int, in the dense form).
+    of them, a Python int, in the dense form).  `count_picks`: the second
+    value is i32 [2] instead, (experts read, the real rows' picks that fell
+    on an expert HELD here), in either form; the second is counted only
+    where the config holds a SHARE of the experts (else 0 and not an op more
+    a layer: every pick is held, and `forward` knows how many a pass makes):
+    what `kafka_tpu_engine_moe_picks_total` counts.
 
     Routing: softmax over the top-k router logits only (HF
     MixtralSparseMoeBlock semantics), computed in f32; `cfg.moe_scoring`
@@ -305,25 +322,32 @@ def _moe_block(x: jnp.ndarray, lp: Params, cfg: ModelConfig,
                 cfg.routed_scaling_factor, token)
         else:
             w = _routing_weights(
-                t, lp["router"], cfg.num_experts_per_tok, token)
+                t, lp["router"], cfg.num_experts_per_tok, token,
+                lp.get("router_choice"))
         if cfg.num_experts_routed and not token:
             # the weights of the experts HELD: chosen and renormalised over
             # all the router's experts, then this share's columns
             w = w[:, cfg.expert_offset:cfg.expert_offset + cfg.num_experts]
     read = cfg.num_experts
     with jax.named_scope("moe_experts"):
+        real = None
+        share = count_picks and bool(cfg.num_experts_routed)
+        if chunk_len is not None and (token or share):
+            real = (jnp.arange(s)[None, :]
+                    < jnp.reshape(chunk_len, (-1, 1))).reshape(b * s)
         if token:
-            real = None
-            if chunk_len is not None:
-                real = (jnp.arange(s)[None, :]
-                        < jnp.reshape(chunk_len, (-1, 1))).reshape(b * s)
             stack, at = stacked or (
                 {name: _w(lp, name, t.dtype)[None]
                  for name in expert_leaves(lp)}, 0)
+            offset = cfg.expert_offset if cfg.num_experts_routed else 0
             out, read = _experts_token(
-                t, *w, stack, at, cfg.num_router_experts,
-                cfg.expert_offset if cfg.num_experts_routed else 0, real,
+                t, *w, stack, at, cfg.num_router_experts, offset, real,
                 cfg.mlp_act)
+            if share:
+                mine = (w[0] >= offset) & (w[0] < offset + cfg.num_experts)
+                if real is not None:
+                    mine = mine & real[:, None]
+                held_picks = jnp.sum(mine, dtype=jnp.int32)
         else:
             gated, mid = ACTIVATIONS[cfg.mlp_act]
             g = (jnp.einsum("th,ehf->tef", t, _w(lp, "wg", t.dtype))
@@ -333,6 +357,14 @@ def _moe_block(x: jnp.ndarray, lp: Params, cfg: ModelConfig,
             y = jnp.einsum(
                 "tef,efh->teh", mid(g, u), _w(lp, "wd", t.dtype))
             out = jnp.einsum("te,teh->th", w.astype(y.dtype), y)
+            if share:
+                # (a chosen expert's weight is a softmax's or a sigmoid's
+                # share: never exactly 0)
+                mine = w != 0 if real is None else (w != 0) & real[:, None]
+                held_picks = jnp.sum(mine, dtype=jnp.int32)
+        if count_picks:
+            read = jnp.stack([jnp.asarray(read, jnp.int32),
+                              held_picks if share else jnp.int32(0)])
     out = out.reshape(b, s, h)
     if cfg.shared_intermediate_size:
         with jax.named_scope("moe_shared"):

@@ -155,6 +155,43 @@ def _init_parallel_params(cfg: ModelConfig, keys, dtype,
     return params
 
 
+def _scaled(fan_in, mult: float):
+    """The fan-in under which a leaf that a multiplier scales is drawn: its
+    deviation is fan_in ** -0.5 / mult (`_init_parallel_params`: THE
+    MULTIPLIERS); `fan_in` itself where there is none."""
+    return fan_in if mult == 1.0 else fan_in * mult * mult
+
+
+def _mamba2_leaves(cfg: ModelConfig, k, n: int, dtype, norm01,
+                   out_mult: float = 1.0) -> Params:
+    """The leaves of `n` lone SSD mixers (`_ssd_block` names them), drawn as
+    `_init_parallel_params` draws them, without a muP vector; `norm01(key,
+    shape, fan_in)` is the caller's one-program-a-leaf draw and `out_mult`
+    the scalar the mixer's output meets on its way into the stream."""
+    h, H, P = cfg.hidden_size, cfg.ssd_heads, cfg.ssd_head_dim
+    d_ssm, conv, taps = H * P, cfg.ssd_conv_dim, cfg.ssd_conv_kernel
+
+    def spread(k, shape, out_dtype=dtype):
+        return (1.0 + 0.2 * jax.random.normal(k, shape, jnp.float32)
+                ).astype(out_dtype)
+
+    ks = jax.random.split(k, 8)
+    step = jnp.exp(jax.random.uniform(
+        ks[5], (n, H), jnp.float32, jnp.log(0.001), jnp.log(0.1)))
+    return {
+        "w_in": norm01(ks[0], (n, h, d_ssm + conv + H), h),
+        "conv_w": norm01(ks[1], (n, taps, conv), taps),
+        "conv_b": (0.1 * jax.random.normal(ks[2], (n, conv), jnp.float32)
+                   ).astype(dtype),
+        "A_log": jnp.log(jax.random.uniform(
+            ks[3], (n, H), jnp.float32, 1.0, 16.0)),
+        "D": spread(ks[4], (n, H), jnp.float32),
+        "dt_bias": jnp.log(jnp.expm1(step)),
+        "ln_ssd": spread(ks[6], (n, d_ssm)),
+        "w_out": norm01(ks[7], (n, d_ssm, h), _scaled(d_ssm, out_mult)),
+    }
+
+
 def _init_lead_tree_params(cfg: ModelConfig, key: jax.Array, dtype) -> Params:
     """Random weights of a `deepseek_v3`-style tree: `first_k_dense` dense
     layers stacked under "dense_layers", the routed ones (router + selection
@@ -187,8 +224,33 @@ def _init_lead_tree_params(cfg: ModelConfig, key: jax.Array, dtype) -> Params:
     drawn log-uniform in [0.001, 0.1], so a channel's decay a row spreads
     over 0.9999 .. 0.2 and a decay taken per head, or a state rounded to
     bfloat16, moves the logits) and, where the config gates its attention
-    elementwise, "wgate" [H, heads x head_dim] among the attention leaves."""
+    elementwise, "wgate" [H, heads x head_dim] among the attention leaves.
+
+    The mixer-then-feed-forward layout (`cfg.mixer_then_ffn`: Granite-4.0-H)
+    is the conv layout's tree with a lone SSD mixer a MAMBA2 layer
+    (`_mamba2_leaves`), a softmax router of the router's full width with NO
+    selection bias, the HELD experts and the shared SwiGLU.  Its scalars
+    matter to a check only under THE MULTIPLIERS' rule of
+    `_init_parallel_params`: W_k is drawn at its fan-in deviation times 2
+    head_dim ** -0.5 / `attention_multiplier` (scores of deviation 2 under
+    the published scale: at deviation 1 a softmax over the check's 1,536
+    keys is still nearly their mean, the one attention layer in ten adds
+    0.04 a value to the stream and a rotation read 0.021-0.025 against a
+    served error of 0.018-0.025: my chip run 2, PR 63), the out-projections
+    and the down matrices divided by `residual_multiplier`.  NOT the embedding: its
+    head is TIED, and a row drawn at 1 / `embedding_multiplier` a value
+    (times 12: unit variance, as Falcon-H1's untied tree draws it) makes the
+    last token's own logit, 12 |E_t|^2, fourteen deviations of the other
+    tokens' at 4,096 wide: greedy decoding then echoes its input for ever
+    (every lane of the benchmark's cell repeated the prompt's last byte, the
+    detokenizer held the invalid UTF-8 back and `tpot_p50_ms` read 0.0: my
+    chip run 1b, PR 63).  At the fan-in deviation a row times 12 weighs 0.19
+    a value beside sublayers of order 1: enough that a dropped multiplier
+    moves the logits past the check's tolerance, and the echo is 2.7
+    deviations, under the largest of 50,176 draws.  The logits come out at
+    1 / 16 and the comparison is relative."""
     h, hq = cfg.hidden_size, cfg.num_heads
+    r_mult = cfg.residual_multiplier
 
     @partial(jax.jit, static_argnums=(1, 2))
     def norm01(k, shape, fan_in):
@@ -227,12 +289,14 @@ def _init_lead_tree_params(cfg: ModelConfig, key: jax.Array, dtype) -> Params:
     def gqa_attention(k, n, with_norms=True):
         hkv, d = cfg.num_kv_heads, cfg.head_dim
         ks = jax.random.split(k, 6)
+        k_mult = (cfg.attention_multiplier * d**0.5 / 2.0
+                  if cfg.attention_multiplier else 1.0)
         out = {
             **(norms(n) if with_norms else {}),
             "wq": norm01(ks[0], (n, h, hq, d), h),
-            "wk": norm01(ks[1], (n, h, hkv, d), h),
+            "wk": norm01(ks[1], (n, h, hkv, d), _scaled(h, k_mult)),
             "wv": norm01(ks[2], (n, h, hkv, d), h),
-            "wo": norm01(ks[3], (n, hq, d, h), hq * d),
+            "wo": norm01(ks[3], (n, hq, d, h), _scaled(hq * d, r_mult)),
         }
         if cfg.qk_norm:
             out["ln_q"] = spread(ks[4], (n, d))
@@ -273,9 +337,13 @@ def _init_lead_tree_params(cfg: ModelConfig, key: jax.Array, dtype) -> Params:
 
     attention = latent_attention if cfg.is_latent else gqa_attention
     # the conv layout: a mixer a KIND, and the two stacks keep the norms
-    mixers = {CONV: conv_mixer, DELTA: delta_mixer,
+    def mamba2_mixer(k, n):
+        return _mamba2_leaves(cfg, k, n, dtype, norm01, r_mult)
+
+    mixers = {CONV: conv_mixer, DELTA: delta_mixer, MAMBA2: mamba2_mixer,
               GLOBAL: partial(gqa_attention, with_norms=False)}
-    kinded = CONV in cfg.layer_types or DELTA in cfg.layer_types
+    kinded = (CONV in cfg.layer_types or DELTA in cfg.layer_types
+              or cfg.mixer_then_ffn)
     if kinded:
         def attention(k, n):
             return norms(n)
@@ -284,7 +352,7 @@ def _init_lead_tree_params(cfg: ModelConfig, key: jax.Array, dtype) -> Params:
         ks = jax.random.split(k, 3)
         return {names[0]: norm01(ks[0], (n, h, f), h),
                 names[1]: norm01(ks[1], (n, h, f), h),
-                names[2]: norm01(ks[2], (n, f, h), f)}
+                names[2]: norm01(ks[2], (n, f, h), _scaled(f, r_mult))}
 
     keys = jax.random.split(key, 10)
     n_dense = cfg.first_k_dense
@@ -299,7 +367,7 @@ def _init_lead_tree_params(cfg: ModelConfig, key: jax.Array, dtype) -> Params:
                 keys[3], (n, routed), jnp.float32)
         layers["wg"] = norm01(keys[4], (n, E, h, f), h)
         layers["wu"] = norm01(keys[5], (n, E, h, f), h)
-        layers["wd"] = norm01(keys[6], (n, E, f, h), f)
+        layers["wd"] = norm01(keys[6], (n, E, f, h), _scaled(f, r_mult))
         if cfg.shared_intermediate_size:
             layers.update(mlp(keys[7], n, cfg.shared_intermediate_size,
                               ("ws_g", "ws_u", "ws_d")))
@@ -349,28 +417,8 @@ def _init_lone_params(cfg: ModelConfig, key: jax.Array, dtype) -> Params:
         return (jax.random.normal(k, shape, jnp.float32)
                 * (fan_in**-0.5)).astype(dtype)
 
-    def spread(k, shape, out_dtype=dtype):
-        return (1.0 + 0.2 * jax.random.normal(k, shape, jnp.float32)
-                ).astype(out_dtype)
-
     def mamba2_mixer(k, n):
-        H, P = cfg.ssd_heads, cfg.ssd_head_dim
-        d_ssm, conv, taps = H * P, cfg.ssd_conv_dim, cfg.ssd_conv_kernel
-        ks = jax.random.split(k, 8)
-        step = jnp.exp(jax.random.uniform(
-            ks[5], (n, H), jnp.float32, jnp.log(0.001), jnp.log(0.1)))
-        return {
-            "w_in": norm01(ks[0], (n, h, d_ssm + conv + H), h),
-            "conv_w": norm01(ks[1], (n, taps, conv), taps),
-            "conv_b": (0.1 * jax.random.normal(ks[2], (n, conv), jnp.float32)
-                       ).astype(dtype),
-            "A_log": jnp.log(jax.random.uniform(
-                ks[3], (n, H), jnp.float32, 1.0, 16.0)),
-            "D": spread(ks[4], (n, H), jnp.float32),
-            "dt_bias": jnp.log(jnp.expm1(step)),
-            "ln_ssd": spread(ks[6], (n, d_ssm)),
-            "w_out": norm01(ks[7], (n, d_ssm, h), d_ssm),
-        }
+        return _mamba2_leaves(cfg, k, n, dtype, norm01)
 
     def gqa_attention(k, n):
         ks = jax.random.split(k, 4)

@@ -116,9 +116,11 @@ def forward(
         tokens, models/vision.py; the reference forwarded images to remote
         vision models, src/llm/portkey.py:276).
     Returns (logits [B, S, vocab] float32, updated cache or None), and with
-    `expert_reads` (a routed model's own layer tree) a third: the held
-    experts whose weights this pass's routed layers read, summed over them
-    (i32; `_moe_block`'s count, what token dispatch leaves unread).
+    `expert_reads` (a routed model's own layer tree) a third, i32 [3] over
+    this pass's routed layers: the held experts whose weights they read
+    (what token dispatch leaves unread) and the real rows' picks that fell on
+    an expert held here, both summed a layer (`_moe_block`'s `count_picks`),
+    and all their picks, rows x top-k x routed layers.
 
     A model with a recurrent state (`cfg.has_state`): its paged pool carries
     the state (`kv_cache.v` a dict), `paged.state` says which slots, and a
@@ -238,7 +240,8 @@ def forward(
             # elsewhere)
             attn_out, kc, vc = mixer.mix(attn_in, lp, ctx, kc, vc, layer,
                                          kind)
-            h = _hc_out(h, attn_out, maps, mixer.scope)
+            h = _hc_out(h, attn_out, maps, mixer.scope,
+                        cfg.residual_multiplier)
         if not cfg.has_ffn(kind):
             return (h, kc, vc, tally), None
         u, maps = _hc_in(h, lp, "mlp", cfg)
@@ -248,15 +251,17 @@ def forward(
         if routed:
             ffn_out, read = _moe_block(
                 mlp_in, lp, cfg, None if paged is None else paged.chunk_len,
-                sharded, (experts, slot[0]) if slot else None)
+                sharded, (experts, slot[0]) if slot else None,
+                count_picks=tally is not None)
             if tally is not None:
                 tally = tally + read
-            h = _hc_out(h, ffn_out, maps, "moe_experts")
+            h = _hc_out(h, ffn_out, maps, "moe_experts",
+                        cfg.residual_multiplier)
         else:
             with jax.named_scope("mlp"):
                 ffn_out = _mlp_block(mlp_in, lp,
                                      multipliers=cfg.mlp_multipliers)
-            h = _hc_out(h, ffn_out, maps, "mlp")
+            h = _hc_out(h, ffn_out, maps, "mlp", cfg.residual_multiplier)
         return (h, kc, vc, tally), None
 
     def at(stacked, i, static: bool):
@@ -324,9 +329,10 @@ def forward(
         kc, vc = (None, None) if kv_cache is None else kv_cache
         num_layers = jax.tree.leaves(params["layers"])[0].shape[0]
         n_dense = 0
-        # (the tally of experts read rides the carry: None, no leaf, unless
-        # `expert_reads`)
-        carry = (x, kc, vc, jnp.int32(0) if expert_reads else None)
+        # (the tally of experts read and of picks rides the carry: None, no
+        # leaf, unless `expert_reads`)
+        carry = (x, kc, vc,
+                 jnp.zeros((2,), jnp.int32) if expert_reads else None)
         if "dense_layers" in params:
             # leading dense layers: a stacked tree of another shape, run
             # ahead of the scan over the routed layers, which count on from
@@ -374,6 +380,21 @@ def forward(
                 jnp.arange(n_dense + lead, n_dense + num_layers, p))
         x, kc, vc, tally = carry
         new_cache = None if kv_cache is None else KVCache(k=kc, v=vc)
+        if expert_reads:
+            # all the pass's picks, counted ONCE: its real rows (the view's
+            # `chunk_len`: a decode step's active lanes) x top-k x routed
+            # layers; where the experts are held whole every one of them is
+            # a held pick, and no routed layer counted any
+            rows = x.shape[0] * x.shape[1]
+            if paged is not None and paged.chunk_len is not None:
+                rows = jnp.sum(jnp.clip(jnp.broadcast_to(
+                    paged.chunk_len, x.shape[:1]), 0, x.shape[1]))
+            picks = jnp.asarray(
+                rows * (cfg.num_experts_per_tok * cfg.routed_layers),
+                jnp.int32)
+            tally = jnp.stack([
+                tally[0], tally[1] if cfg.num_experts_routed else picks,
+                picks])
 
     with jax.named_scope("head"):
         if plan is not None and paged is not None and x.shape[1] > 1:

@@ -62,6 +62,14 @@ def convert_hf_state_dict(
         return _convert_latent_state_dict(state, cfg, dtype)
     if cfg.lone_layers:
         return _convert_lone_state_dict(state, cfg, dtype)
+    if cfg.mixer_then_ffn:
+        raise NotImplementedError(
+            "no checkpoint converter for the mixer-then-feed-forward layout "
+            "(`granitemoehybrid`): its tree (Mamba-2 mixers and attention "
+            "stacked per kind under `attn`, a softmax router, a held share "
+            "of the experts and a shared expert under `layers`) is "
+            "models/init_params._init_lead_tree_params', served on seeded "
+            "weights")
     if cfg.lead_tree or cfg.qk_norm:
         raise NotImplementedError(
             "no checkpoint converter for a grouped-query model with a dense "

@@ -112,12 +112,23 @@ def _attention_core(q, k, v, cfg, positions, k_cache, v_cache, kv_valid,
     """Scores, softmax and weighted sum for one layer, by cache form and
     backend.  `window` (static, None = global): the layer attends
     q_pos - window < kv_pos <= q_pos; every path below honours it or raises
-    WindowedPathError.  Paged: k_cache/v_cache are the flat [L*SLOTS, Hkv*D] pools,
+    WindowedPathError.  A published softmax scale (`cfg.softmax_scale`, else
+    None: each path's own default and not an argument more) goes to every
+    path that takes one; the mesh paths take none and raise.
+    Paged: k_cache/v_cache are the flat [L*SLOTS, Hkv*D] pools,
     the new rows already in them, and `paged` addresses this layer
     (_attention_block did both).  Contiguous: the stacked [L, B, C, Hkv, D]
     cache is written here at `layer`.  Returns (out [B, S, Hq, D],
     k_cache', v_cache')."""
     dt = q.dtype
+    # (nothing where the model publishes no scale: the calls below are then
+    # what they always were)
+    scaled = {} if cfg.softmax_scale is None else {
+        "scale": cfg.softmax_scale}
+    if scaled and (cfg.prefill_ring or mesh is not None and mesh.size > 1):
+        raise NotImplementedError(
+            "a published softmax scale (attention_multiplier) has no "
+            "sharded attention path")
     if paged is not None:
         b, s, hkv, d = k.shape
         if (
@@ -126,7 +137,7 @@ def _attention_core(q, k, v, cfg, positions, k_cache, v_cache, kv_valid,
             and paged.page_table is not None
         ):
             on_mesh = mesh is not None and mesh.size > 1
-            pools, kw = (k_cache, v_cache), {}
+            pools, kw = (k_cache, v_cache), dict(scaled)
             if isinstance(k_cache, QTensor):
                 # int8 pool: the int8 kernel DMAs half the bytes and
                 # fuses the per-slot dequant into scores/probabilities
@@ -145,7 +156,7 @@ def _attention_core(q, k, v, cfg, positions, k_cache, v_cache, kv_valid,
                 kw = {"window": window}
             elif window is not None:
                 kernel = kernels.paged_decode_attention_window
-                kw = {"window": window}
+                kw = {"window": window, **scaled}
             else:
                 kernel = kernels.paged_decode_attention
             out = kernel(
@@ -177,7 +188,7 @@ def _attention_core(q, k, v, cfg, positions, k_cache, v_cache, kv_valid,
                 *((mesh,) if on_mesh else ()), q, k_cache, v_cache,
                 paged.page_table, paged.seq_lens, paged.chunk_len,
                 page_size=paged.page_size,
-                interpret=jax.default_backend() != "tpu",
+                interpret=jax.default_backend() != "tpu", **scaled,
             )
         elif (
             cfg.attention_backend == "pallas"
@@ -197,7 +208,7 @@ def _attention_core(q, k, v, cfg, positions, k_cache, v_cache, kv_valid,
                 paged.chunk_len,
                 page_size=paged.page_size,
                 interpret=jax.default_backend() != "tpu",
-                window=window,
+                window=window, **scaled,
             )[None]
         elif cfg.prefill_ring and s > 1:
             # Chunked prefill over the sp axis: the chunk's own q/k/v ride
@@ -233,7 +244,8 @@ def _attention_core(q, k, v, cfg, positions, k_cache, v_cache, kv_valid,
             and paged.page_table is not None
             and paged.page_size is not None
         ):
-            out = _decode_walk(q, k_cache, v_cache, paged, hkv, window, mesh)
+            out = _decode_walk(q, k_cache, v_cache, paged, hkv, window, mesh,
+                               cfg.softmax_scale)
         else:
             # s > 1 (prefill chunks, verify): page-granular gather of the
             # static window (see _kv_read_pages: the slot-granular form is
@@ -255,12 +267,12 @@ def _attention_core(q, k, v, cfg, positions, k_cache, v_cache, kv_valid,
                 q_positions=positions,
                 kv_positions=paged.kv_positions,
                 kv_valid=paged.kv_valid,
-                window=window,
+                window=window, **scaled,
             )
     elif k_cache is None:
         out = causal_attention(
             q, k, v, q_positions=positions, kv_positions=positions,
-            window=window,
+            window=window, **scaled,
         )
     else:
         # Scatter new k/v rows into cache slots (slot == absolute position
@@ -282,13 +294,14 @@ def _attention_core(q, k, v, cfg, positions, k_cache, v_cache, kv_valid,
             q_positions=positions,
             kv_positions=kv_pos,
             kv_valid=kv_valid,
-            window=window,
+            window=window, **scaled,
         )
     return out, k_cache, v_cache
 
 
 def _decode_walk(q, k_cache, v_cache, paged: PagedView, hkv: int,
-                 window: Optional[int], mesh) -> jnp.ndarray:
+                 window: Optional[int], mesh,
+                 scale: Optional[float] = None) -> jnp.ndarray:
     """The XLA decode read (s == 1, page table present): walk each lane's
     live context chunk by chunk in the pool's own [.., Hkv*D] rows
     (ops/attention.py paged_decode_walk) rather than gather its static
@@ -305,7 +318,7 @@ def _decode_walk(q, k_cache, v_cache, paged: PagedView, hkv: int,
     return paged_decode_walk(
         q[:, 0], read_pages, paged.page_table, paged.seq_lens,
         paged.kv_valid[:, 0], page_size=ps, num_kv_heads=hkv, window=window,
-        heads_batched=mesh is not None and mesh.size > 1,
+        heads_batched=mesh is not None and mesh.size > 1, scale=scale,
     )[:, None]
 
 

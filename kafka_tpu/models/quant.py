@@ -126,6 +126,12 @@ def quantize_params(params: Params, cfg: ModelConfig) -> Params:
             "built: its ungated experts (wu, wd alone) and its mixers are "
             "stacked per kind (`ffn`, `attn`) and have no contraction table "
             "here")
+    if cfg.mixer_then_ffn:
+        raise NotImplementedError(
+            "int8 weight quantization of the mixer-then-feed-forward layout "
+            "(`granitemoehybrid`) is not built: its mixers are stacked per "
+            "kind (`attn`), and neither they nor the shared expert have a "
+            "contraction table here")
     if "dense_layers" in params or cfg.shared_intermediate_size:
         raise NotImplementedError(
             "int8 weight quantization of a tree with a dense lead or shared "

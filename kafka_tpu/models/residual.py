@@ -88,11 +88,17 @@ def _hc_in(h: jnp.ndarray, lp: Params, site: str, cfg: ModelConfig):
     return u, (post, res)
 
 
-def _hc_out(h: jnp.ndarray, y: jnp.ndarray, maps, scope: str) -> jnp.ndarray:
+def _hc_out(h: jnp.ndarray, y: jnp.ndarray, maps, scope: str,
+            mult: float = 1.0) -> jnp.ndarray:
     """After a sublayer: one row a token, `h + y` under `scope` (where the
-    add always sat); n rows, X <- H_res X + H_post^T y under `hc_mix`."""
+    add always sat), `h + mult * y` where the model publishes a
+    `residual_multiplier` (`mult` != 1: the sublayer's output scaled in the
+    stream's dtype, inside the add's scope, folded into no weight); n rows,
+    X <- H_res X + H_post^T y under `hc_mix`."""
     if maps is None:
         with jax.named_scope(scope):
+            if mult != 1.0:
+                y = y * jnp.asarray(mult, y.dtype)
             return h + y
     post, res = maps
     n = post.shape[-1]

@@ -169,6 +169,7 @@ def paged_decode_walk(
     num_kv_heads: int,
     window: Optional[int] = None,
     heads_batched: bool = False,
+    scale: Optional[float] = None,
 ) -> jnp.ndarray:
     """Decode attention over a paged pool without materialising the window.
 
@@ -209,7 +210,8 @@ def paged_decode_walk(
     b, hq, d = q.shape
     hkv = num_kv_heads
     g = hq // hkv
-    scale = d**-0.5
+    if scale is None:  # (a published softmax scale: `attention_multiplier`)
+        scale = d**-0.5
     cp = decode_walk_pages(page_table.shape[1], page_size)
     ck = cp * page_size
     # the first trip that gathers lane by lane: whole trips of the pages

@@ -616,8 +616,9 @@ class _Fetch:
     # entry is timed for the ring but never billed to the skew gauge.
     kind: str = "decode"
     modeled_s: Optional[float] = None
-    # the held experts each pass of this dispatch read ([] or [steps] i32),
-    # where its program counts them (StepPrograms.moe_dispatch "token")
+    # each pass of this dispatch's experts read and picks ([3] or [steps, 3]
+    # i32: `forward`'s tally), where its program counts them
+    # (StepPrograms.tallies)
     reads: Optional[jnp.ndarray] = None
 
 
@@ -1544,6 +1545,14 @@ class InferenceEngine:
         # move when the step's tokens are fetched (_Fetch.reads).
         self.moe_experts_read = 0
         self.moe_experts_held = 0
+        # Monotonic, 0 for a model with no routed block: over the same decode
+        # passes, the picks (active rows x top-k x routed layers) that fell
+        # on an expert THIS chip holds, and all of them.  Equal where the
+        # experts are held whole; a held share (`cfg.num_experts_routed`)
+        # sees the part of its deployment's load that its experts draw,
+        # counted by the program beside the experts read (_Fetch.reads).
+        self.moe_picks_held = 0
+        self.moe_picks_routed = 0
         # Monotonic: the host's run-ahead, sampled at every decode / fused
         # / verify dispatch (_backlog_steps: steps in the FIFO the device
         # has not been seen to finish, the number _hold_decode bounds);
@@ -2870,9 +2879,13 @@ class InferenceEngine:
             return self._finish_verify_entry(entry, raw)
         if entry.reads is not None:
             # (computed by the program that made `arr`: landed with it)
-            self.moe_experts_read += int(np.sum(np.asarray(entry.reads)))
+            read, held, routed = (int(n) for n in np.sum(
+                np.asarray(entry.reads).reshape(-1, 3), axis=0))
+            self.moe_experts_read += read
             self.moe_experts_held += (
                 entry.steps * self._programs.experts_held())
+            self.moe_picks_held += held
+            self.moe_picks_routed += routed
         vals = raw.reshape(entry.steps, -1)
         n = 0
         for j in range(entry.steps):
@@ -4544,11 +4557,12 @@ class InferenceEngine:
 
     def _count_expert_reads(self, entry: _Fetch,
                             reads: Optional[jnp.ndarray]) -> None:
-        """Account a decode dispatch's expert reads: `reads`, the program's
-        own count (its last output: token dispatch), rides the entry and is
-        added when its tokens are fetched (_process_entry); a program that
-        returns None read every held expert, counted here (0 for a model
-        with no routed block)."""
+        """Account a decode dispatch's expert reads and picks: `reads`, the
+        program's own count (its last output: `StepPrograms.tallies`), rides
+        the entry and is added when its tokens are fetched (_process_entry);
+        a program that returns None read every held expert and holds every
+        expert its lanes picked, counted here (0 for a model with no routed
+        block)."""
         if reads is not None:
             reads.copy_to_host_async()
             entry.reads = reads
@@ -4556,6 +4570,10 @@ class InferenceEngine:
         held = entry.steps * self._programs.experts_held()
         self.moe_experts_read += held
         self.moe_experts_held += held
+        picks = entry.steps * self._programs.picks_a_pass(
+            sum(m is not None for m in entry.items))
+        self.moe_picks_held += picks
+        self.moe_picks_routed += picks
 
     def _count_walk_trips(self, spans, width: int, bucket: int) -> None:
         trips, folded = self._programs.prefill_walk_trips(spans, width, bucket)

@@ -477,6 +477,12 @@ METRICS: Tuple[Metric, ...] = (
            read="moe_experts_read"),
         _m("moe_experts_held", "kafka_tpu_engine_moe_experts_total{kind=held}",
            read="moe_experts_held"),
+        _m("moe_picks_held", "kafka_tpu_engine_moe_picks_total{kind=held}",
+           "Picks (active rows x top-k) of the routed layers of the decode "
+           "passes: those that fell on an expert this chip holds, and all.",
+           read="moe_picks_held"),
+        _m("moe_picks_routed", "kafka_tpu_engine_moe_picks_total{kind=routed}",
+           read="moe_picks_routed"),
         # the fetch pipeline's run-ahead (steps, summed over samples at
         # decode dispatches), the scheduler thread's time in unlanded
         # reads, and pops by what released them

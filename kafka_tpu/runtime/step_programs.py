@@ -256,6 +256,18 @@ def _moe_form(cfg, mesh, rows: int, int8: bool) -> Optional[str]:
         mesh is not None and mesh.size > 1, cfg.num_router_experts, int8)
 
 
+def _tallies(cfg, mesh, rows: int, int8: bool) -> bool:
+    """Whether a decode program of `rows` lanes counts its routed layers'
+    reads and picks ITSELF (`forward`'s `expert_reads`): where they dispatch
+    by token (an idle lane picks none and an expert may go unread) and where
+    the model holds a SHARE of the experts in the dense form (which picks
+    fell on an expert held here only the device knows).  Elsewhere every
+    held expert is read and every pick is held: the host knows both."""
+    form = _moe_form(cfg, mesh, rows, int8)
+    return form == "token" or (
+        form == "dense" and bool(cfg.num_experts_routed))
+
+
 def _forward(cfg, mesh, params, tokens, positions, k_pool, v_pool, paged,
              vis=(), expert_reads=False):
     """The model over a paged pool -> (logits, KVCache[, experts read]).
@@ -277,10 +289,11 @@ def _forward(cfg, mesh, params, tokens, positions, k_pool, v_pool, paged,
 
 def _decode_fn(cfg: ModelConfig, mesh: Any, ps: int):
     """One decode step as a pure function of device state; the single-step
-    program, and the body of the fused multi-step scan.  Returns, last, the
-    held experts its routed layers read (i32) where they dispatch by token
-    (`_moe_form` at this many lanes: an idle lane then picks none), else
-    None: every held expert, which the host knows."""
+    program, and the body of the fused multi-step scan.  Returns, last, i32
+    [3], the held experts its routed layers read, their picks that fell on
+    an expert held here and all their picks, where the program counts them
+    (`_tallies` at this many lanes: an idle lane then picks none), else None:
+    every held expert and every pick, which the host knows."""
 
     def body(params, k_pool, v_pool, lanes, allowed_mask, forced=None,
              fsm=None):
@@ -289,8 +302,8 @@ def _decode_fn(cfg: ModelConfig, mesh: Any, ps: int):
         positions, paged = decode_plan(page_table, seq_lens, active, ps)
         paged = _with_state(cfg, paged, active.astype(jnp.int32))
         reads = None
-        if _moe_form(cfg, mesh, page_table.shape[0], cfg.is_moe
-                     and experts_int8(params["layers"])) == "token":
+        if _tallies(cfg, mesh, page_table.shape[0], cfg.is_moe
+                    and experts_int8(params["layers"])):
             with jax.named_scope("step_ctl"):
                 paged = paged._replace(chunk_len=active.astype(jnp.int32))
             logits, cache, reads = _forward(
@@ -738,10 +751,20 @@ class StepPrograms:
         """"token" or "dense": the form the routed blocks of a pass of
         `rows` rows (lanes x rows a lane) trace to, by the rule
         models/ffn.py _moe_block itself asks (moe_dispatch_form); None
-        for a model with no routed block.  A decode or fused-decode program
-        whose form is "token" returns, last, the held experts its passes
-        read ([] a step, [steps] fused), and None otherwise."""
+        for a model with no routed block."""
         return _moe_form(self.cfg, self.mesh, rows, self.int8_experts)
+
+    def tallies(self, rows: int) -> bool:
+        """Whether the decode and fused-decode programs of `rows` lanes
+        return, last, their own count of experts read and picks ([3] a step,
+        [steps, 3] fused: `_tallies`), or None and the host counts."""
+        return _tallies(self.cfg, self.mesh, rows, self.int8_experts)
+
+    def picks_a_pass(self, lanes: int) -> int:
+        """Picks the routed layers of ONE decode pass make with `lanes` lanes
+        active: lanes x top-k x routed layers (0: no routed block)."""
+        return (lanes * self.cfg.num_experts_per_tok
+                * self.cfg.routed_layers)
 
     def experts_held(self) -> int:
         """Held experts x routed layers: what one pass's routed blocks read

@@ -236,7 +236,11 @@ DEVICE_SCOPES = (
     # attention keeps attn_qkv / kv_write / attn_core / attn_out.  The same
     # four where the mixer stands ALONE in its layer (a one-sublayer pattern:
     # the layer's residual add then sits under ssd_proj, its one norm under
-    # attn_norm; a routed layer's under mlp_norm and moe_experts)
+    # attn_norm; a routed layer's under mlp_norm and moe_experts), and where
+    # it stands at the head of a layer with the routed block behind it
+    # (`granitemoehybrid`).  A published `residual_multiplier` scales a
+    # sublayer's output INSIDE the scope of the add it belongs to (ssd_proj,
+    # attn_out, moe_experts / mlp), so nothing of it is unscoped
     "ssd_proj",     # a Mamba-2 (SSD) mixer's projections: W_in with its two
                     # multipliers, W_out with its one, and the branch's add
                     # to the attention branch ahead of the residual

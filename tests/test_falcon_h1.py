@@ -284,6 +284,12 @@ NEW_ACCOUNT = {
     "file:nemotron-3-nano-30b-a3b.json": [
         2, 7, [["conv", [8, 2304]], ["ssd", [4096, 128]]], 15196160,
         2.0 * 2 * 32 * 256],
+    # (PR 63: two sublayers a layer: rows in the ONE attention layer, a lone
+    # SSD mixer's state in 9, a routed block behind all ten; 32 heads x 2 x
+    # 128)
+    "file:granite-4.0-h-small.json": [
+        1, 9, [["conv", [8, 3168]], ["ssd", [8192, 128]]], 38661120,
+        2.0 * 1 * 32 * 256],
 }
 
 

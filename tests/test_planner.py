@@ -213,6 +213,7 @@ CONFIG_DIR = os.path.join(os.path.dirname(os.path.dirname(
 PARENT_WEIGHT_BYTES = {
     "dots3-note-prev": 10_022_188_544,
     "falcon-h1-34b": 6_690_159_232,
+    "granite-4.0-h-small": 9_514_430_464,
     "k-exaone-236b-a23b": 8_935_605_760,
     "kanana-2-30b-a3b": 7_579_169_280,
     "lfm2-8b-a1b": 9_334_155_520,
