@@ -26,6 +26,7 @@ import numpy as np
 from ...ops.norms import rms_norm
 from ...ops.pallas.gated_delta import gated_delta
 from ...ops.pallas.ssd import ssd
+from ...ops.pallas.tail_conv import piece, tail_conv_step, tiles as tail_tiles
 from ..cache import StatePlan, _read_state, _write_state
 from ..config import ModelConfig
 from ..quant import Params, _w
@@ -34,7 +35,7 @@ from . import gqa
 
 def _tail_conv_silu(rows: jnp.ndarray, w: jnp.ndarray, bias, leaf, layer,
                     plan: StatePlan, read_state=_read_state,
-                    write_state=_write_state):
+                    write_state=_write_state, kernel: bool = False):
     """SiLU of a depthwise causal convolution whose tail is a layer's STATE
     (the delta layout's three convolutions side by side, the parallel
     layout's one over [x | B | C]).  rows [B, S, C]; w [taps, C] float32 (tap
@@ -43,11 +44,22 @@ def _tail_conv_silu(rows: jnp.ndarray, w: jnp.ndarray, bias, leaf, layer,
     (laid out in the slot as `cfg.state_shapes` says; None: uncached, zeros)
     at `layer`, and the last taps - 1 REAL rows (`plan.lens`; as
     `_short_conv_block`'s tail) go back to it (`read_state` / `write_state`:
-    models/cache.py's, as the forward pass names them).  Returns (float32
-    [B, S, C], leaf')."""
+    models/cache.py's, as the forward pass names them).  `kernel` (the Pallas
+    backend): decode's one row a lane (S == 1, lane i in slot i) is ONE pass
+    over the slots as they are stored, in place, where the tail's sizes tile
+    (ops/pallas/tail_conv.py: the same sums in the same order, interpreted
+    off the chip); every other pass is the body below.  Returns (float32 [B,
+    S, C], leaf')."""
     f32 = jnp.float32
     b, s, c = rows.shape
     taps = w.shape[0]
+    if (kernel and s == 1 and leaf is not None and plan.src is None
+            and tail_tiles(taps, c, leaf.shape[2:])):
+        out, leaf = tail_conv_step(
+            leaf, jnp.asarray(layer, jnp.int32), plan.lens,
+            rows[:, 0].astype(f32), w, bias,
+            interpret=jax.default_backend() != "tpu")
+        return out[:, None], leaf
     tail = (jnp.zeros((b, taps - 1, c), f32) if leaf is None
             else read_state(leaf, layer, plan, b).reshape(b, taps - 1, c))
     seq = jnp.concatenate([tail, rows.astype(f32)], axis=1)
@@ -61,6 +73,27 @@ def _tail_conv_silu(rows: jnp.ndarray, w: jnp.ndarray, bias, leaf, layer,
         leaf = write_state(leaf, layer, plan, new.reshape(slot),
                             tail.reshape(slot))
     return out, leaf
+
+
+def tail_step_note(cfg: ModelConfig):
+    """For the engine's start-up log: what decode's tail convolution of this
+    model's state layers runs as (`_tail_conv_silu`'s rule on the configured
+    sizes), or None where no layer has such a tail."""
+    if cfg.ssd_heads:
+        taps, c = cfg.ssd_conv_kernel, cfg.ssd_conv_dim
+    elif cfg.delta_heads:
+        taps = cfg.delta_conv_kernel
+        c = 3 * cfg.delta_heads * cfg.delta_head_dim
+    else:
+        return None
+    slot = dict(cfg.state_shapes())["conv"]
+    what = f"{cfg.state_layers} state layers' tail ({taps - 1} x {c} as {slot})"
+    if cfg.attention_backend != "pallas":
+        return f"{what}: the XLA body ({cfg.attention_backend} backend)"
+    if tail_tiles(taps, c, slot):
+        return f"{what}: tail_conv_step, pieces of {piece(c, slot)}"
+    return (f"{what}: the XLA body, tail_conv_step declines a piece of "
+            f"{piece(c, slot)} values (no whole 128-lane tiles)")
 
 
 def _short_conv_block(x: jnp.ndarray, lp: Params, leaf, layer,
@@ -165,7 +198,8 @@ def _delta_attention_block(x: jnp.ndarray, lp: Params, cfg: ModelConfig,
     with jax.named_scope("kda_conv"):
         qkv, conv_leaf = _tail_conv_silu(
             qkv, lp["conv_w"].astype(f32), None, conv_leaf, layer, plan,
-            read_state, write_state)
+            read_state, write_state,
+            kernel=cfg.attention_backend == "pallas")
     with jax.named_scope("kda_gate"):
         q, k, v = (a.reshape(b, s, H, D) for a in jnp.split(qkv, 3, axis=-1))
 
@@ -264,7 +298,8 @@ def _ssd_block(x: jnp.ndarray, lp: Params, cfg: ModelConfig, leaves, layer,
     with jax.named_scope("ssd_conv"):
         xbc, conv_leaf = _tail_conv_silu(
             xbc, lp["conv_w"].astype(f32), lp["conv_b"].astype(f32),
-            conv_leaf, layer, plan, read_state, write_state)
+            conv_leaf, layer, plan, read_state, write_state,
+            kernel=cfg.attention_backend == "pallas")
     with jax.named_scope("ssd_gate"):
         xs = xbc[..., :d].reshape(b, s, H, P)
         Bm = xbc[..., d:d + G * N].reshape(b, s, G, N)

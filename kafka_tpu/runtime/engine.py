@@ -73,6 +73,7 @@ import numpy as np
 
 from ..models.config import WINDOWED, ModelConfig, UnsupportedConfigError
 from ..models.ffn import experts_int8
+from ..models.mixers.state import tail_step_note
 from .failpoints import failpoint
 from .flight_recorder import (
     FlightRecorder,
@@ -1445,6 +1446,10 @@ class InferenceEngine:
             "sliding_window": (self.cfg.sliding_window
                                if self.cfg.is_windowed else None),
         }
+        note = tail_step_note(self.cfg)
+        if note:
+            logger.info("attention backend %s; %s",
+                        self.cfg.attention_backend, note)
         # DP replica index (set by runtime/dp_router.py): traced requests'
         # engine spans carry it so a timeline names the replica it ran on
         self.replica: Optional[int] = None

@@ -88,10 +88,12 @@ def in_while(comps: dict) -> set:
 
 
 def leaf_copies(text: str, shapes) -> list:
-    """Every `copy` of compiled `text` whose result has one of `shapes`:
-    [{shape, op, computation, in_while}].  (A copy inside a fusion is the
-    fusion's own loop, not a pass over the leaf by itself: fused
-    computations are not searched.)"""
+    """Every `copy` of compiled `text` whose result has one of `shapes`, a
+    `copy-done` among them (the leaf moved between memories whole: XLA
+    prefetches a custom call's operand into fast memory where it fits, PERF.md
+    section 6, PR 64): [{shape, op, computation, in_while}].  (A copy inside a
+    fusion is the fusion's own loop, not a pass over the leaf by itself:
+    fused computations are not searched.)"""
     comps = computations(text)
     loops = in_while(comps)
     found = []
@@ -100,7 +102,7 @@ def leaf_copies(text: str, shapes) -> list:
             continue
         for line in lines:
             m = re.match(r"^(?:ROOT\s+)?(%?[\w.\-]+) = (\w+\[[\d,]*\])\S* "
-                         r"copy\(", line)
+                         r"copy(?:-done)?\(", line)
             if m and m.group(2) in shapes:
                 found.append({"shape": m.group(2),
                               "op": m.group(1).lstrip("%"),
@@ -251,7 +253,7 @@ def traced(trace_dir: str, shape: str) -> int:
                     seen[op][1] += dur / 1e9
     copies = 0
     for op, (calls, seconds) in sorted(seen.items(), key=lambda kv: -kv[1][1]):
-        is_copy = bool(re.match(r"^%?copy[.\d]* = ", op))
+        is_copy = bool(re.match(r"^%?copy(-done)?[.\d]* = ", op))
         copies += is_copy
         print(json.dumps({"op": op, "calls": calls,
                           "seconds": round(seconds, 6), "copy": is_copy}))

@@ -7,7 +7,7 @@ two-lane batched prefill launch (from shapes alone,
 `tests/test_moe_dispatch.py`'s way; the full-width files too, which lower in
 seconds and hold no array) is what the parent commit lowers:
 `tests/recorded/lowered_pins.json` holds the digests, recorded AT THE PARENT
-(24e5ace, PR 62, for PR 63) by running this file in a checkout of it with
+(67ed503, PR 63, for PR 64) by running this file in a checkout of it with
 `KAFKA_TPU_RECORD_PINS=<path>` (same conftest, same JAX).  Equal text = the
 same executable and a warm compile cache across the two trees.
 
@@ -45,26 +45,20 @@ from kafka_tpu.runtime.kv_cache import default_state_slots, make_kv_pool_arrays
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 PINS = os.path.join(ROOT, "tests", "recorded", "lowered_pins.json")
 RECORD = os.environ.get("KAFKA_TPU_RECORD_PINS")
-# the files of a model this very PR adds (docstring): not pinned.  PR 63:
-# granite-4.0-h-small's configuration file and its tiny twin
-NEW = ("granite-4.0-h-small", "tiny-granitemoehybrid")
-# the programs this very PR means to move (docstring).  PR 63: the decode
-# step of every configuration that HOLDS A SHARE of its routed experts, at
-# these 4 lanes, where the routed block keeps the dense form: the program now
-# counts its picks itself (`step_programs._tallies`: which of them fell on an
-# expert held here only the device knows; `kafka_tpu_engine_moe_picks_total`)
-# and returns the tally where it returned None.  No batched prefill moves, and
-# no program of a model that holds its experts whole or has none (the two new
-# scalars and the threaded softmax scale trace nothing where they are absent).
+# the files of a model this very PR adds (docstring): not pinned
+NEW = ()
+# the programs this very PR means to move (docstring).  PR 64: the decode step
+# of the three configurations whose state layers' convolution tail tiles for
+# `ops/pallas/tail_conv.tail_conv_step`, on the Pallas backend (`STATE_PALLAS`
+# below: pinned from this PR on, recorded at the parent like the rest).  Their
+# batched prefill does not move (S > 1 keeps `_tail_conv_silu`'s body), nor
+# does either Pallas program of Granite, whose (8, 3168) tail the rule
+# declines, nor any `xla` program, nor any tiny twin (their tails are narrower
+# than a lane tile).
 MOVED = frozenset(
-    f"file:{name}.{backend}.decode"
-    for name, backends in (
-        ("dots3-note-prev", ("xla",)), ("tiny-dots3", ("xla", "pallas")),
-        ("tiny-shared", ("xla",)), ("k-exaone-236b-a23b", ("xla",)),
-        ("tiny-kexaone", ("xla",)), ("solar-open2-250b", ("xla",)),
-        ("tiny-solaropen2", ("xla",)), ("nemotron-3-nano-30b-a3b", ("xla",)),
-        ("tiny-nemotronh", ("xla",)))
-    for backend in backends)
+    f"file:{name}.pallas.decode"
+    for name in ("solar-open2-250b", "nemotron-3-nano-30b-a3b",
+                 "falcon-h1-34b"))
 PS, LANES, PAGES, BUCKET, WIDTH = 8, 4, 8, 16, 2
 
 
@@ -87,6 +81,11 @@ CASES = _configs()
 BACKENDS = {"file:tiny-kanana2": ("xla", "pallas"),
             "file:tiny-dots3": ("xla", "pallas"),
             "file:tiny-xing4": ("xla", "pallas")}
+# the configurations whose state layers' tail goes through `_tail_conv_silu`,
+# at their published widths, with every state kernel traced (interpreted)
+STATE_PALLAS = ("solar-open2-250b", "nemotron-3-nano-30b-a3b",
+                "falcon-h1-34b", "granite-4.0-h-small")
+BACKENDS.update({f"file:{name}": ("xla", "pallas") for name in STATE_PALLAS})
 KEYS = [f"{name}.{backend}.{program}" for name in CASES
         for backend in BACKENDS.get(name, ("xla",))
         for program in ("decode", "bprefill")]

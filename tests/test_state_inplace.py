@@ -19,6 +19,7 @@ described inside a fixture (on-chip-measurement guide, section 2).
 """
 
 import importlib.util
+import json
 import os
 
 import numpy as np
@@ -267,8 +268,11 @@ HYBRIDS = {"phi4flash": ("prefill[128]", "bprefill[64x4]"),
            "solar_open2": ("prefill[128]",)}
 
 
-def _compiled_copies(lc, name, label, sharding):
-    path = os.path.join(ROOT, "benchmarks", "tests", TWINS[name])
+DECODES = dict(TWINS, nemotronh="nemotronh/configs/tiny-nemotronh.json")
+
+
+def _compiled_copies(lc, name, label, sharding, only=None):
+    path = os.path.join(ROOT, "benchmarks", "tests", DECODES[name])
     # (the XLA forms: the Pallas kernels do not tile a twin's widths for
     # the chip, and under them EVERY state leaf goes through the accessors,
     # the ones a kernel of the served path updates in place too)
@@ -279,7 +283,8 @@ def _compiled_copies(lc, name, label, sharding):
     fn, args = programs[label]
     text = lc.compile_for(fn, args, label).as_text()
     assert " while(" in text  # the layer scan is there to look into
-    return lc.leaf_copies(text, set(leaves.values()))
+    return lc.leaf_copies(
+        text, {leaves[only]} if only else set(leaves.values()))
 
 
 @pytest.mark.parametrize("name,label", [
@@ -292,6 +297,77 @@ def test_no_compiled_prefill_copies_a_state_leaf(one_chip, name, label):
         # copy, PERF.md section 7's own item: none inside the scan)
         found = [row for row in found if row["in_while"]]
     assert found == []
+
+
+@pytest.mark.parametrize("name,label", [
+    (name, label) for name in ("nemotronh", "solar_open2")
+    for label in ("decode", "multi_decode[16]")])
+def test_no_compiled_decode_program_copies_the_conv_leaf(
+        one_chip, name, label):
+    """ISSUE 64: the tail's slot is read and written where it lies at decode
+    too, in the single step and in the fused one, as at the parent."""
+    assert _compiled_copies(
+        _leaf_copies(), name, label, one_chip, only="conv") == []
+
+
+# channels a row, state layers, lanes, bias: the three served tails that
+# `ops/pallas/tail_conv.tiles` takes
+SERVED_TAILS = {"solar-open2": (24576, 6, 32, False),
+                "nemotron-3-nano": (6144, 7, 32, True),
+                "falcon-h1": (5120, 7, 32, True)}
+
+
+@pytest.mark.parametrize("name", sorted(SERVED_TAILS))
+def test_the_tail_kernel_compiles_for_the_chip_and_copies_no_leaf(
+        one_chip, name):
+    """`tail_conv_step` at a served geometry, compiled for a described v5e
+    (what the interpreter cannot show: every slice a whole tile, the blocks
+    inside VMEM): one custom call, the leaf aliased through, no copy."""
+    from kafka_tpu.models.config import _tail_layout
+    from kafka_tpu.ops.pallas import tail_conv
+
+    C, layers, lanes, bias = SERVED_TAILS[name]
+    slot = _tail_layout(3, C)
+    assert tail_conv.tiles(4, C, slot)
+
+    def of(dtype, *shape):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    f32, i32 = jnp.float32, jnp.int32
+    leaf = of(f32, layers, 129, *slot)
+    args = [leaf, of(i32), of(i32, lanes), of(f32, lanes, C), of(f32, 4, C)]
+    if bias:
+        args.append(of(f32, C))
+    text = jax.jit(
+        lambda *a: tail_conv.tail_conv_step(*a, interpret=False),
+        donate_argnums=0).lower(*args).compile().as_text()
+    assert text.count("tpu_custom_call") == 1 and "tail_conv_step" in text
+    assert _leaf_copies().leaf_copies(
+        text, {_leaf_copies().hlo_shape(leaf)}) == []
+    assert "input_output_alias" in text
+
+
+def test_the_served_falcon_h1_decode_step_moves_no_state_leaf(one_chip):
+    """The engine's decode program at Falcon-H1's PUBLISHED widths, compiled
+    for a described v5e: its `conv` leaf is 55 MB, small enough to fit the
+    chip's fast memory beside a kernel's VMEM, and XLA prefetched it whole
+    ahead of `tail_conv_step` in every layer and copied it back (`copy-start`
+    / `copy-done` in the scan's body, +0.85 ms a pass on the chip: PERF.md
+    section 6, PR 64) until the kernel's result held the leaf in HBM."""
+    lc = _leaf_copies()
+    path = os.path.join(ROOT, "benchmarks", "configs", "falcon-h1-34b.json")
+    with open(path) as f:
+        spec = json.load(f)
+    cfg = config_from_hf_json(path).replace(
+        dtype=spec["serving"]["dtype"],
+        attention_backend=spec["expect"]["attention_backend"])
+    assert cfg.attention_backend == "pallas"
+    programs, leaves = lc.engine_programs(cfg, spec["serving"], one_chip)
+    # (the paged-decode kernel does not compile under the suite's "highest")
+    with jax.default_matmul_precision("default"):
+        text = lc.compile_for(*programs["decode"], "decode").as_text()
+    assert "tail_conv_step" in text and "ssd_step" in text
+    assert lc.leaf_copies(text, set(leaves.values())) == []
 
 
 def test_the_parents_accessors_do_copy_a_leaf_inside_the_scan(
@@ -324,6 +400,8 @@ def test_leaf_copies_reads_a_modules_text():
   %g = f32[2,5,3,8]{3,2,1,0} get-tuple-element(%c), index=1
   %x = f32[2,5,3,8]{3,2,1,0} call(%g), to_apply=%inner
   %y = f32[4,4]{1,0} copy(%z)
+  %cs = (f32[2,5,3,8]{3,2,1,0:S(1)}, f32[2,5,3,8]{3,2,1,0}, u32[]) copy-start(%g)
+  %copy-done.3 = f32[2,5,3,8]{3,2,1,0:T(8,128)S(1)} copy-done(%cs)
   ROOT %t = (s32[], f32[2,5,3,8]{3,2,1,0}) tuple(%i, %x)
 }
 
@@ -340,5 +418,6 @@ ENTRY %main.7 (a: f32[2,5,3,8]) -> f32[2,5,3,8] {
 """
     rows = lc.leaf_copies(text, {"f32[2,5,3,8]"})
     assert sorted((r["op"], r["computation"], r["in_while"])
-                  for r in rows) == [("copy.1", "main.7", False),
+                  for r in rows) == [("copy-done.3", "body.1", True),
+                                     ("copy.1", "main.7", False),
                                      ("copy.2", "inner", True)]
