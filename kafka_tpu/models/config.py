@@ -29,7 +29,12 @@ which half a layer has is its kind's (`mixer_of`, `has_ffn`).  A sixth
 Mamba-2 mixer OR grouped-query attention that does not rotate, then a
 softmax-routed feed-forward beside a shared expert, each added into the stream
 at `residual_multiplier`; attention's softmax scale is the published
-`attention_multiplier`.
+`attention_multiplier`.  A seventh (`olmo_hybrid`: Olmo-Hybrid-7B) is the
+third's layout with Gated DeltaNet layers (ONE decay a head, key and value
+heads of sizes of their own: `delta_gate`, `delta_value_dim`), every layer
+dense, the norms on the sublayers' OUTPUTS (`norm_position`) and multi-head
+attention that does not rotate under a QK-norm over the whole projection
+(`qk_norm_whole`).
 
 The reference service routed model names to remote providers by string
 heuristics (src/llm/utils.py:11-29); here a model name resolves to a local
@@ -317,6 +322,24 @@ class ModelConfig:
     delta_head_dim: int = 0
     delta_conv_kernel: int = 4
     delta_neg_eigval: bool = False
+    # Gated DeltaNet's form of the same layer (`olmo_hybrid`; arXiv:2412.06464):
+    # `delta_gate` "head" is ONE log-decay a head from a full-rank projection
+    # [H, heads] (no low-rank pair) and a full-rank SiLU output gate, where
+    # "channel" is the decay a key channel above; `delta_value_dim` > 0 is a
+    # value head of another size than the key head's (0 = delta_head_dim:
+    # square heads).  The state a head is then [delta_head_dim,
+    # delta_value_dim], NOT transposed, the heads side by side along the
+    # lanes (ops/pallas/gdn.py).
+    delta_gate: str = "channel"
+    delta_value_dim: int = 0
+    # Where a sublayer's RMSNorm sits: "pre", h + F(norm(h)), or "post", h +
+    # norm(F(h)), the reordered norm of Olmo 2 / Olmo 3 (on each sublayer's
+    # OUTPUT, none on its input; the leaves keep the names ln_attn / ln_mlp).
+    norm_position: str = "pre"
+    # QK-norm over the WHOLE projection (all heads' values under one weight
+    # of heads x head_dim) ahead of the split into heads, where `qk_norm`
+    # alone is a norm a head.
+    qk_norm_whole: bool = False
     # -- the parallel layout (`falcon_h1`; `_ssd_block` in mixers/state.py):
     # `ssd_heads` > 0 turns it on and every layer is then PARALLEL:
     # grouped-query attention and a Mamba-2 (SSD) mixer read ONE normed input
@@ -399,6 +422,19 @@ class ModelConfig:
             raise UnsupportedConfigError(
                 "qk_norm and unrotated_kinds are built with grouped-query "
                 "attention only (no latent attention, no Mamba decoder)")
+        if self.qk_norm_whole and not self.qk_norm:
+            raise UnsupportedConfigError(
+                "qk_norm_whole says how wide qk_norm is: it needs qk_norm")
+        if self.norm_position not in ("pre", "post"):
+            raise UnsupportedConfigError(
+                f"norm_position {self.norm_position!r}: known 'pre', 'post'")
+        if self.norm_position == "post" and (
+                self.mamba_d_state or self.lone_layers or self.hc_mult > 1
+                or self.residual_multiplier != 1.0):
+            raise UnsupportedConfigError(
+                "norm_position 'post' (a norm on each sublayer's output) is "
+                "built on the two-sublayer layer of models/llama.forward "
+                "with a one-row stream and no residual_multiplier")
         if set(self.unrotated_kinds) - {WINDOWED, GLOBAL}:
             raise UnsupportedConfigError(
                 f"unrotated_kinds {list(self.unrotated_kinds)}: known "
@@ -562,6 +598,16 @@ class ModelConfig:
                 f"linear attention needs a head size (delta_head_dim = "
                 f"{self.delta_head_dim}) and a short convolution of two taps "
                 f"or more (delta_conv_kernel = {self.delta_conv_kernel})")
+        if self.delta_gate not in ("channel", "head"):
+            raise UnsupportedConfigError(
+                f"delta_gate {self.delta_gate!r}: known 'channel' (a decay a "
+                "key channel), 'head' (one decay a head)")
+        if self.delta_value_dim < 0 or (
+                self.delta_value_dim and self.delta_gate != "head"):
+            raise UnsupportedConfigError(
+                f"delta_value_dim = {self.delta_value_dim} (a value head of "
+                "its own size) is built with delta_gate 'head' only: a decay "
+                "a key channel keeps square heads")
         if (self.is_latent or self.mamba_d_state or self.conv_L_cache
                 or self.vision is not None):
             raise UnsupportedConfigError(
@@ -752,6 +798,19 @@ class ModelConfig:
                 + 2 * self.ssd_groups * self.ssd_d_state)
 
     @property
+    def delta_v_dim(self) -> int:
+        """A linear-attention head's VALUE size (the key size where the
+        config names no other)."""
+        return self.delta_value_dim or self.delta_head_dim
+
+    @property
+    def delta_conv_dim(self) -> int:
+        """Channels of a linear-attention layer's three convolutions side by
+        side: [q | k | v]."""
+        return self.delta_heads * (2 * self.delta_head_dim
+                                   + self.delta_v_dim)
+
+    @property
     def state_layers(self) -> int:
         """Layers that hold a recurrent state."""
         return sum(holds_state(kind) for kind in self.layer_types)
@@ -793,10 +852,17 @@ class ModelConfig:
             # the rows of B * u before the pass: nothing accumulates
             return (("conv", (self.conv_L_cache - 1, self.hidden_size)),)
         if DELTA in self.layer_types:
+            tail = _tail_layout(self.delta_conv_kernel - 1,
+                                self.delta_conv_dim)
+            if self.delta_gate == "head":
+                # S itself, [d_k, d_v] a head, the heads side by side along
+                # the lanes (ops/pallas/gdn.py): 96 x 5,760 is whole tiles
+                # where S transposed would pad 96 lanes to 128
+                return (("conv", tail),
+                        ("delta", (self.delta_head_dim,
+                                   self.delta_heads * self.delta_v_dim)))
             wide = self.delta_heads * self.delta_head_dim
-            return (("conv", _tail_layout(self.delta_conv_kernel - 1,
-                                          3 * wide)),
-                    ("delta", (wide, self.delta_head_dim)))
+            return (("conv", tail), ("delta", (wide, self.delta_head_dim)))
         return ()
 
     @property
@@ -1536,6 +1602,51 @@ def _delta_keys(hf: dict) -> dict:
     return out
 
 
+def _gdn_keys(hf: dict) -> dict:
+    """The keys of an `olmo_hybrid` config.json (Olmo-Hybrid-7B: Gated
+    DeltaNet layers beside post-normed multi-head attention, every layer
+    dense) as ModelConfig fields; {} for any other model.  `linear_*` are the
+    arguments of flash-linear-attention's GatedDeltaNet one for one (heads, a
+    key and a value head size, the convolution's taps, `allow_neg_eigval`);
+    `rope_parameters.rope_theta` null is NO rotation (a theta of null cannot
+    be rotated by).  What the config has no key for (where the norms sit,
+    the width of the QK-norm) is the family's convention, listed as `assumed`
+    beside the benchmark's copy of the file.  What is not served is an
+    UnsupportedConfigError, by key."""
+    if hf.get("model_type") != "olmo_hybrid":
+        return {}
+    _refuse_unless(hf, (
+        ("hidden_act", "silu", "another MLP activation"),
+        ("attention_bias", False, "attention biases"),
+        ("rope_scaling", None, "scaled rotary positions"),
+        ("sliding_window", None, "a sliding window"),
+    ))
+    heads = int(hf.get("linear_num_key_heads") or 0)
+    if hf.get("linear_num_value_heads", heads) != heads:
+        raise UnsupportedConfigError(
+            f"linear_num_value_heads = {hf['linear_num_value_heads']!r} "
+            f"beside linear_num_key_heads = {heads} (grouped keys in linear "
+            "attention) is not served: only equal counts are")
+    kinds = tuple(hf.get("layer_types") or ())[:int(hf["num_hidden_layers"])]
+    rope = hf.get("rope_parameters") or {}
+    theta = rope.get("rope_theta", hf.get("rope_theta"))
+    d_k = int(hf.get("linear_key_head_dim") or 0)
+    d_v = int(hf.get("linear_value_head_dim") or d_k)
+    return {
+        "layer_types": kinds,
+        "delta_heads": heads,
+        "delta_head_dim": d_k,
+        "delta_value_dim": 0 if d_v == d_k else d_v,
+        "delta_conv_kernel": int(hf.get("linear_conv_kernel_dim", 4)),
+        "delta_neg_eigval": bool(hf.get("linear_allow_neg_eigval", False)),
+        "delta_gate": "head",
+        "norm_position": "post",
+        "qk_norm": True,
+        "qk_norm_whole": True,
+        "unrotated_kinds": (GLOBAL,) if theta is None else (),
+    }
+
+
 def _parallel_keys(hf: dict) -> dict:
     """The keys of a `falcon_h1` config.json (Falcon-H1: every layer a
     Mamba-2 mixer in parallel with grouped-query attention, a SwiGLU MLP, the
@@ -1755,7 +1866,7 @@ def config_from_hf_json(path: str) -> ModelConfig:
     on grouped-query attention (`_routed_lead_keys`: `exaone_moe`), those of
     a `phi4flash` hybrid decoder (`_hybrid_keys`), those of an `lfm2_moe`
     one (`_conv_keys`), those of a `solar_open2` one (`_delta_keys`), those
-    of a `falcon_h1` one (`_parallel_keys`), those of a `nemotron_h` one
+    of an `olmo_hybrid` one (`_gdn_keys`), those of a `falcon_h1` one (`_parallel_keys`), those of a `nemotron_h` one
     (`_lone_keys`) and those of a `granitemoehybrid` one (`_granite_keys`).
     A key the program cannot honour
     is an UnsupportedConfigError."""
@@ -1795,7 +1906,7 @@ def config_from_hf_json(path: str) -> ModelConfig:
             "norm_topk_prob false (top-k weights of a softmax over ALL "
             "experts, not renormalised) is not served: routing here is a "
             "softmax over exactly the top-k logits")
-    hybrid = (_hybrid_keys(hf) or _conv_keys(hf) or delta
+    hybrid = (_hybrid_keys(hf) or _conv_keys(hf) or delta or _gdn_keys(hf)
               or _parallel_keys(hf) or lone or _granite_keys(hf))
     pattern = {} if "layer_types" in hybrid else _layer_pattern(hf)
     rope_theta = hf.get("rope_theta")
@@ -1804,6 +1915,14 @@ def config_from_hf_json(path: str) -> ModelConfig:
         flat = hf.get("rope_parameters") or {}
         if GLOBAL in ropes:
             rope_theta = ropes[GLOBAL].rope_theta
+        elif "rope_theta" in flat and flat["rope_theta"] is None:
+            # a theta of null: the layers do not rotate, which only a reader
+            # that says so may serve (`_gdn_keys`); never read as a number
+            if GLOBAL not in hybrid.get("unrotated_kinds", ()):
+                raise UnsupportedConfigError(
+                    "rope_parameters.rope_theta = null (no rotation) is "
+                    f"not served for model_type {hf.get('model_type')!r}")
+            rope_theta = 10000.0  # recorded, read by no layer
         elif "rope_theta" in flat:
             # one table for every kind, spelt as a flat `rope_parameters`
             rope_theta = _rope_params("rope_parameters", flat).rope_theta
@@ -1828,7 +1947,7 @@ def config_from_hf_json(path: str) -> ModelConfig:
         num_kv_heads=hf.get("num_key_value_heads", hf["num_attention_heads"]),
         # (latent attention: the rotary width, what a published `head_dim` is)
         head_dim=(latent["qk_rope_head_dim"] if latent else hf.get(
-            "head_dim", hf["hidden_size"] // hf["num_attention_heads"])),
+            "head_dim") or hf["hidden_size"] // hf["num_attention_heads"]),
         # (a theta past int32, Falcon-H1's 1e11 spelt as an integer, would
         # be parsed as one where the frequencies are computed)
         rope_theta=(float(rope_theta) if abs(rope_theta) >= 2**31

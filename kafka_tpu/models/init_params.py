@@ -155,6 +155,12 @@ def _init_parallel_params(cfg: ModelConfig, keys, dtype,
     return params
 
 
+# What `gdn_mixer` draws W_v and W_a at, times their fan-in deviation (see
+# there).
+GDN_V_GAIN = 0.03
+GDN_A_GAIN = 0.05
+
+
 def _scaled(fan_in, mult: float):
     """The fan-in under which a leaf that a multiplier scales is drawn: its
     deviation is fan_in ** -0.5 / mult (`_init_parallel_params`: THE
@@ -265,6 +271,13 @@ def _init_lead_tree_params(cfg: ModelConfig, key: jax.Array, dtype) -> Params:
                 ).astype(dtype)
 
     def norms(n):
+        if cfg.norm_position == "post":
+            # not ones: a unit weight on a sublayer's OUTPUT would let a norm
+            # moved ahead of the sublayer, or swapped with its neighbour's,
+            # pass a check
+            ka, km = jax.random.split(jax.random.fold_in(key, n + 11))
+            return {"ln_attn": spread(ka, (n, h)),
+                    "ln_mlp": spread(km, (n, h))}
         return {"ln_attn": jnp.ones((n, h), dtype),
                 "ln_mlp": jnp.ones((n, h), dtype)}
 
@@ -299,8 +312,10 @@ def _init_lead_tree_params(cfg: ModelConfig, key: jax.Array, dtype) -> Params:
             "wo": norm01(ks[3], (n, hq, d, h), _scaled(hq * d, r_mult)),
         }
         if cfg.qk_norm:
-            out["ln_q"] = spread(ks[4], (n, d))
-            out["ln_k"] = spread(ks[5], (n, d))
+            # (a weight over the whole projection where the norm is)
+            whole = cfg.qk_norm_whole
+            out["ln_q"] = spread(ks[4], (n, hq * d if whole else d))
+            out["ln_k"] = spread(ks[5], (n, hkv * d if whole else d))
         if cfg.attention_gate == "elementwise":
             out["wgate"] = norm01(jax.random.fold_in(k, 6), (n, h, hq * d), h)
         return out
@@ -328,6 +343,38 @@ def _init_lead_tree_params(cfg: ModelConfig, key: jax.Array, dtype) -> Params:
             "w_out": norm01(ks[12], (n, W, h), W),
         }
 
+    def gdn_mixer(k, n):
+        """Gated DeltaNet's leaves (`cfg.delta_gate` "head"): A_log a head
+        is log U(0, 16) and dt_bias a head the inverse softplus of a step
+        drawn log-uniform in [0.001, 0.1], the layer's own initialiser, so
+        some heads forget in a few rows and others carry the whole prefix (a
+        state lost at a launch boundary moves the logits); W_a is drawn at
+        `GDN_A_GAIN` times its fan-in deviation, so that the bias and not the
+        projection of a post-normed stream (whose size grows with depth)
+        sets a head's rate.  W_v is drawn at
+        `GDN_V_GAIN` times its fan-in deviation: o = S^T q then lies within
+        two orders of sqrt(eps), where the head norm behind it is NOT blind
+        to o's scale, so a dropped q scale moves the logits."""
+        H, D, Dv = cfg.delta_heads, cfg.delta_head_dim, cfg.delta_v_dim
+        taps = cfg.delta_conv_kernel
+        ks = jax.random.split(k, 12)
+        step = jnp.exp(jax.random.uniform(
+            ks[9], (n, H), jnp.float32, jnp.log(0.001), jnp.log(0.1)))
+        return {
+            "wq": norm01(ks[0], (n, h, H * D), h),
+            "wk": norm01(ks[1], (n, h, H * D), h),
+            "wv": norm01(ks[2], (n, h, H * Dv), _scaled(h, 1.0 / GDN_V_GAIN)),
+            "conv_w": norm01(ks[3], (n, taps, H * (2 * D + Dv)), taps),
+            "wa": norm01(ks[4], (n, h, H), _scaled(h, 1.0 / GDN_A_GAIN)),
+            "wbeta": norm01(ks[5], (n, h, H), h),
+            "wgo": norm01(ks[6], (n, h, H * Dv), h),
+            "ln_o": spread(ks[7], (n, Dv)),
+            "A_log": jnp.log(jax.random.uniform(
+                ks[8], (n, H), jnp.float32, 1e-4, 16.0)),
+            "dt_bias": jnp.log(jnp.expm1(step)),
+            "w_out": norm01(ks[10], (n, H * Dv, h), H * Dv),
+        }
+
     def conv_mixer(k, n):
         taps = cfg.conv_L_cache
         ks = jax.random.split(k, 3)
@@ -340,7 +387,8 @@ def _init_lead_tree_params(cfg: ModelConfig, key: jax.Array, dtype) -> Params:
     def mamba2_mixer(k, n):
         return _mamba2_leaves(cfg, k, n, dtype, norm01, r_mult)
 
-    mixers = {CONV: conv_mixer, DELTA: delta_mixer, MAMBA2: mamba2_mixer,
+    mixers = {CONV: conv_mixer, MAMBA2: mamba2_mixer,
+              DELTA: gdn_mixer if cfg.delta_gate == "head" else delta_mixer,
               GLOBAL: partial(gqa_attention, with_norms=False)}
     kinded = (CONV in cfg.layer_types or DELTA in cfg.layer_types
               or cfg.mixer_then_ffn)

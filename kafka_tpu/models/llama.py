@@ -225,29 +225,40 @@ def forward(
     # one-sublayer pattern a layer is a mixer or a feed-forward under its
     # one norm ("ln") and one residual add, and no op of the absent half is
     # traced.
+    # Where a sublayer's norm sits (`cfg.norm_position`): on its INPUT under
+    # the norm's own scope, or ("post", the reordered norm) on its OUTPUT,
+    # inside the scope of the add it precedes (residual._hc_out) with the
+    # sublayer reading the stream as it is and no input norm traced.
+    post = cfg.norm_position == "post"
+
+    def norm_in(u, w, scope):
+        if post:
+            return u
+        with jax.named_scope(scope):
+            return rms_norm(u, w, cfg.rms_norm_eps)
+
+    def norm_out(w):
+        return (w, cfg.rms_norm_eps) if post else None
+
     def layer_body(carry, scanned, kind=GLOBAL, routed=cfg.is_moe):
         h, kc, vc, tally = carry
         lp, layer, *slot = scanned
-        lone = cfg.lone_layers
+        lone, r = cfg.lone_layers, cfg.residual_multiplier
         if cfg.mixer_of(kind) is not None:
             mixer = MIXERS[cfg.mixer_of(kind)]
             u, maps = _hc_in(h, lp, "attn", cfg)
-            with jax.named_scope("attn_norm"):
-                attn_in = rms_norm(u, lp["ln" if lone else "ln_attn"],
-                                   cfg.rms_norm_eps)
+            w = lp["ln" if lone else "ln_attn"]
             # (`layer` is the layer's index in the caches its mixer holds:
             # among its kind where the leaves are per kind, absolute
             # elsewhere)
-            attn_out, kc, vc = mixer.mix(attn_in, lp, ctx, kc, vc, layer,
-                                         kind)
-            h = _hc_out(h, attn_out, maps, mixer.scope,
-                        cfg.residual_multiplier)
+            attn_out, kc, vc = mixer.mix(norm_in(u, w, "attn_norm"), lp, ctx,
+                                         kc, vc, layer, kind)
+            h = _hc_out(h, attn_out, maps, mixer.scope, r, norm_out(w))
         if not cfg.has_ffn(kind):
             return (h, kc, vc, tally), None
         u, maps = _hc_in(h, lp, "mlp", cfg)
-        with jax.named_scope("mlp_norm"):
-            mlp_in = rms_norm(u, lp["ln" if lone else "ln_mlp"],
-                              cfg.rms_norm_eps)
+        w = lp["ln" if lone else "ln_mlp"]
+        mlp_in = norm_in(u, w, "mlp_norm")
         if routed:
             ffn_out, read = _moe_block(
                 mlp_in, lp, cfg, None if paged is None else paged.chunk_len,
@@ -255,13 +266,12 @@ def forward(
                 count_picks=tally is not None)
             if tally is not None:
                 tally = tally + read
-            h = _hc_out(h, ffn_out, maps, "moe_experts",
-                        cfg.residual_multiplier)
+            h = _hc_out(h, ffn_out, maps, "moe_experts", r, norm_out(w))
         else:
             with jax.named_scope("mlp"):
                 ffn_out = _mlp_block(mlp_in, lp,
                                      multipliers=cfg.mlp_multipliers)
-            h = _hc_out(h, ffn_out, maps, "mlp", cfg.residual_multiplier)
+            h = _hc_out(h, ffn_out, maps, "mlp", r, norm_out(w))
         return (h, kc, vc, tally), None
 
     def at(stacked, i, static: bool):

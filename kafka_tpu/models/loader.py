@@ -70,6 +70,13 @@ def convert_hf_state_dict(
             "of the experts and a shared expert under `layers`) is "
             "models/init_params._init_lead_tree_params', served on seeded "
             "weights")
+    if cfg.delta_gate == "head" and cfg.delta_heads:
+        raise NotImplementedError(
+            "no checkpoint converter for the Gated DeltaNet layout "
+            "(`olmo_hybrid`): its tree (the linear mixers and the post-normed "
+            "attention stacked per kind under `attn`, dense feed-forwards "
+            "under `layers`) is models/init_params._init_lead_tree_params', "
+            "served on seeded weights until the published files are here")
     if cfg.lead_tree or cfg.qk_norm:
         raise NotImplementedError(
             "no checkpoint converter for a grouped-query model with a dense "

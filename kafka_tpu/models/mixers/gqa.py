@@ -60,8 +60,15 @@ def _attention_block(
         # QK-norm: each head's q and k RMS-normed over head_dim with the
         # layer's learned weights, ahead of the rotation
         with jax.named_scope("qk_norm"):
-            q = rms_norm(q, lp["ln_q"], cfg.rms_norm_eps)
-            k = rms_norm(k, lp["ln_k"], cfg.rms_norm_eps)
+            if cfg.qk_norm_whole:
+                # over the WHOLE projection, all heads' values under one
+                # weight, ahead of the split into heads (Olmo 2 / Olmo 3)
+                q, k = (rms_norm(a.reshape(a.shape[:2] + (-1,)), lp[n],
+                                 cfg.rms_norm_eps).reshape(a.shape)
+                        for a, n in ((q, "ln_q"), (k, "ln_k")))
+            else:
+                q = rms_norm(q, lp["ln_q"], cfg.rms_norm_eps)
+                k = rms_norm(k, lp["ln_k"], cfg.rms_norm_eps)
     if cos is not None:  # (None: a kind of layer that does not rotate)
         with jax.named_scope("attn_qkv"):
             q = apply_rope(q, cos, sin)

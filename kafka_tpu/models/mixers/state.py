@@ -19,12 +19,19 @@ that swaps one there reaches every write.
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from ...ops.norms import rms_norm
+from ...ops.pallas import gated_delta as kda_kernels
+from ...ops.pallas import gdn as gdn_kernels
+from ...ops.pallas import ssd as ssd_kernels
 from ...ops.pallas.gated_delta import gated_delta
+from ...ops.pallas.gdn import gdn
+from ...ops.pallas.selective_scan import kernel_ok as scan_kernel_ok
 from ...ops.pallas.ssd import ssd
 from ...ops.pallas.tail_conv import piece, tail_conv_step, tiles as tail_tiles
 from ..cache import StatePlan, _read_state, _write_state
@@ -53,8 +60,9 @@ def _tail_conv_silu(rows: jnp.ndarray, w: jnp.ndarray, bias, leaf, layer,
     f32 = jnp.float32
     b, s, c = rows.shape
     taps = w.shape[0]
-    if (kernel and s == 1 and leaf is not None and plan.src is None
-            and tail_tiles(taps, c, leaf.shape[2:])):
+    if leaf is not None and tail_form(
+            kernel, s, plan.src is not None, taps, c,
+            leaf.shape[2:]) == "kernel":
         out, leaf = tail_conv_step(
             leaf, jnp.asarray(layer, jnp.int32), plan.lens,
             rows[:, 0].astype(f32), w, bias,
@@ -75,6 +83,56 @@ def _tail_conv_silu(rows: jnp.ndarray, w: jnp.ndarray, bias, leaf, layer,
     return out, leaf
 
 
+def tail_form(kernel: bool, S: int, own_slots: bool, taps: int, C: int,
+              slot) -> str:
+    """Which form a CACHED pass of S rows a lane takes of the tail
+    convolution, "kernel" or "xla": `_tail_conv_silu`'s own rule, from what
+    is known when the program is traced (`own_slots`: a launch that names
+    its lanes' slots, a prefill)."""
+    if kernel and S == 1 and not own_slots and tail_tiles(taps, C, slot):
+        return "kernel"
+    return "xla"
+
+
+@functools.lru_cache(maxsize=None)
+def state_launch_forms(cfg: ModelConfig, S: int, own_slots: bool):
+    """{"recurrence" | "tail": "kernel" | "xla"} of ONE cached pass of S rows
+    a lane through this model's state layers: which form each op of a layer
+    takes, decided by shapes when the program is traced and otherwise
+    visible in a device capture only (Granite's tail runs the XLA body and
+    nothing said so).  Each mixer's own rule (`form`, `tail_form`, the scan
+    kernel's `kernel_ok`), so the two cannot part.  An op the model's state
+    layers do not have (a short convolution has no recurrence) is absent; {}
+    for a model without a state.  (Asked once a dispatch by the engine:
+    remembered, and the dict is the callers' to read only.)"""
+    kernel = cfg.attention_backend == "pallas"
+    if not cfg.has_state:
+        return {}
+    slot = dict(cfg.state_shapes())["conv"]
+    if cfg.ssd_heads:
+        return {
+            "recurrence": ssd_kernels.form(
+                kernel, True, S, own_slots, cfg.ssd_heads, cfg.ssd_groups,
+                cfg.ssd_head_dim, cfg.ssd_d_state),
+            "tail": tail_form(kernel, S, own_slots, cfg.ssd_conv_kernel,
+                              cfg.ssd_conv_dim, slot)}
+    if cfg.delta_heads:
+        args = (kernel, True, S, own_slots)
+        return {
+            "recurrence": gdn_kernels.form(
+                *args, cfg.delta_heads, cfg.delta_head_dim, cfg.delta_v_dim)
+            if cfg.delta_gate == "head" else kda_kernels.form(
+                *args, cfg.delta_head_dim, cfg.delta_v_dim),
+            "tail": tail_form(kernel, S, own_slots, cfg.delta_conv_kernel,
+                              cfg.delta_conv_dim, slot)}
+    if cfg.hybrid_decoder:
+        # (models/hybrid.py: the scan kernel where it tiles a chunk, decode's
+        # closed form and the convolution in XLA)
+        scan = kernel and S > 1 and scan_kernel_ok(S, cfg.mamba_d_inner)
+        return {"recurrence": "kernel" if scan else "xla", "tail": "xla"}
+    return {"tail": "xla"}  # a gated short convolution: its tail alone
+
+
 def tail_step_note(cfg: ModelConfig):
     """For the engine's start-up log: what decode's tail convolution of this
     model's state layers runs as (`_tail_conv_silu`'s rule on the configured
@@ -82,8 +140,7 @@ def tail_step_note(cfg: ModelConfig):
     if cfg.ssd_heads:
         taps, c = cfg.ssd_conv_kernel, cfg.ssd_conv_dim
     elif cfg.delta_heads:
-        taps = cfg.delta_conv_kernel
-        c = 3 * cfg.delta_heads * cfg.delta_head_dim
+        taps, c = cfg.delta_conv_kernel, cfg.delta_conv_dim
     else:
         return None
     slot = dict(cfg.state_shapes())["conv"]
@@ -179,19 +236,31 @@ def _delta_attention_block(x: jnp.ndarray, lp: Params, cfg: ModelConfig,
     on the Pallas backend (the chunk kernel at S > 1, the step kernel in
     decode) and through the same slot read and write under a row-by-row scan
     elsewhere.  Everything between the projections and W_o is float32.
+
+    `cfg.delta_gate` "head" is Gated DeltaNet's form of the same layer
+    (`olmo_hybrid`): key heads of D and value heads of `cfg.delta_v_dim`
+    values, ONE log-decay a head, g = -exp(A_log) softplus(x W_a + dt_bias)
+    with W_a [H, heads], a full-rank gate under SiLU, y = (RMSNorm_head(o) *
+    SiLU(x W_go)) W_o, and "delta" S itself, [D, heads x d_v]
+    (ops/pallas/gdn.py).
     Returns (out [B, S, H] ahead of the residual add, leaves')."""
     dt, f32 = x.dtype, jnp.float32
     b, s, _ = x.shape
-    H, D = cfg.delta_heads, cfg.delta_head_dim
+    H, D, Dv = cfg.delta_heads, cfg.delta_head_dim, cfg.delta_v_dim
+    a_head = cfg.delta_gate == "head"
     with jax.named_scope("kda_proj"):
         qkv = jnp.concatenate(
             [jnp.einsum("bsh,hw->bsw", x, _w(lp, n, dt))
              for n in ("wq", "wk", "wv")], axis=-1)
-        decay, gate = (
-            jnp.einsum("bsr,rw->bsw",
-                       jnp.einsum("bsh,hr->bsr", x, _w(lp, a, dt)),
-                       _w(lp, c, dt))
-            for a, c in (("wf1", "wf2"), ("wg1", "wg2")))
+        if a_head:
+            decay, gate = (jnp.einsum("bsh,hw->bsw", x, _w(lp, n, dt))
+                           for n in ("wa", "wgo"))
+        else:
+            decay, gate = (
+                jnp.einsum("bsr,rw->bsw",
+                           jnp.einsum("bsh,hr->bsr", x, _w(lp, a, dt)),
+                           _w(lp, c, dt))
+                for a, c in (("wf1", "wf2"), ("wg1", "wg2")))
         beta = jnp.einsum("bsh,hn->bsn", x, _w(lp, "wbeta", dt))
     conv_leaf, delta_leaf = (None, None) if leaves is None else (
         leaves["conv"], leaves["delta"])
@@ -201,28 +270,35 @@ def _delta_attention_block(x: jnp.ndarray, lp: Params, cfg: ModelConfig,
             read_state, write_state,
             kernel=cfg.attention_backend == "pallas")
     with jax.named_scope("kda_gate"):
-        q, k, v = (a.reshape(b, s, H, D) for a in jnp.split(qkv, 3, axis=-1))
+        # (thirds where the heads are square, as it always was)
+        q, k, v = (a.reshape(b, s, H, -1) for a in jnp.split(
+            qkv, 3 if Dv == D else (H * D, 2 * H * D), axis=-1))
 
         def unit(a):
             return a * jax.lax.rsqrt(
                 jnp.sum(a * a, axis=-1, keepdims=True) + 1e-6)
 
         q, k = unit(q) * D**-0.5, unit(k)
-        g = -jnp.exp(lp["A_log"].astype(f32))[:, None] * jax.nn.softplus(
-            decay.astype(f32) + lp["dt_bias"].astype(f32)
-        ).reshape(b, s, H, D)
+        if a_head:
+            g = -jnp.exp(lp["A_log"].astype(f32)) * jax.nn.softplus(
+                decay.astype(f32) + lp["dt_bias"].astype(f32))
+        else:
+            g = -jnp.exp(lp["A_log"].astype(f32))[:, None] * jax.nn.softplus(
+                decay.astype(f32) + lp["dt_bias"].astype(f32)
+            ).reshape(b, s, H, D)
         beta = jax.nn.sigmoid(beta.astype(f32)) * (
             2.0 if cfg.delta_neg_eigval else 1.0)
     with jax.named_scope("kda_delta"):
-        o, delta_leaf = gated_delta(
+        o, delta_leaf = (gdn if a_head else gated_delta)(
             delta_leaf, layer, plan, q, k, v, g, beta,
             kernel=cfg.attention_backend == "pallas",
             read_state=read_state, write_state=write_state)
     with jax.named_scope("kda_gate"):
         o = rms_norm(o, lp["ln_o"].astype(f32), cfg.rms_norm_eps) \
-            * jax.nn.sigmoid(gate.astype(f32)).reshape(b, s, H, D)
+            * (jax.nn.silu if a_head else jax.nn.sigmoid)(
+                gate.astype(f32)).reshape(b, s, H, Dv)
     with jax.named_scope("kda_proj"):
-        out = jnp.einsum("bsw,wh->bsh", o.astype(dt).reshape(b, s, H * D),
+        out = jnp.einsum("bsw,wh->bsh", o.astype(dt).reshape(b, s, H * Dv),
                          _w(lp, "w_out", dt))
     if leaves is not None:
         leaves = {**leaves, "conv": conv_leaf, "delta": delta_leaf}

@@ -132,6 +132,11 @@ def quantize_params(params: Params, cfg: ModelConfig) -> Params:
             "(`granitemoehybrid`) is not built: its mixers are stacked per "
             "kind (`attn`), and neither they nor the shared expert have a "
             "contraction table here")
+    if cfg.delta_gate == "head" and cfg.delta_heads:
+        raise NotImplementedError(
+            "int8 weight quantization of the Gated DeltaNet layout "
+            "(`olmo_hybrid`) is not built: its mixers are stacked per kind "
+            "(`attn`) and have no contraction table here")
     if "dense_layers" in params or cfg.shared_intermediate_size:
         raise NotImplementedError(
             "int8 weight quantization of a tree with a dense lead or shared "

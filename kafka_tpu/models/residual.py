@@ -14,6 +14,7 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
+from ..ops.norms import rms_norm
 from .config import ModelConfig
 from .quant import Params
 
@@ -89,14 +90,18 @@ def _hc_in(h: jnp.ndarray, lp: Params, site: str, cfg: ModelConfig):
 
 
 def _hc_out(h: jnp.ndarray, y: jnp.ndarray, maps, scope: str,
-            mult: float = 1.0) -> jnp.ndarray:
+            mult: float = 1.0, norm=None) -> jnp.ndarray:
     """After a sublayer: one row a token, `h + y` under `scope` (where the
     add always sat), `h + mult * y` where the model publishes a
     `residual_multiplier` (`mult` != 1: the sublayer's output scaled in the
     stream's dtype, inside the add's scope, folded into no weight); n rows,
-    X <- H_res X + H_post^T y under `hc_mix`."""
+    X <- H_res X + H_post^T y under `hc_mix`.  `norm` (weight, eps): the
+    reordered norm (`cfg.norm_position` "post"), h + RMSNorm(y), inside the
+    add's scope too; None, and not an op traced, elsewhere."""
     if maps is None:
         with jax.named_scope(scope):
+            if norm is not None:
+                y = rms_norm(y, *norm)
             if mult != 1.0:
                 y = y * jnp.asarray(mult, y.dtype)
             return h + y

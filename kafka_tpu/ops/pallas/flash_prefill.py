@@ -83,6 +83,8 @@ STEP_KEYS = 512
 # (PERF.md section 6, PR 44).
 PREFILL_VMEM_LIMIT = 32 * 1024 * 1024
 PREFILL_VMEM_BYTES = 12 * 1024 * 1024
+# ... and the part the K / V chunk buffers may take (chunk_pages)
+CHUNK_VMEM_BYTES = 8 * 1024 * 1024
 
 
 def prefill_block_chunks(qb, start, chunk_len, *, q_block: int,
@@ -303,12 +305,19 @@ def _prefill_kernel(
     jax.lax.fori_loop(0, hq, unstack, 0)
 
 
-def chunk_pages(page_size: int) -> int:
+def chunk_pages(page_size: int, row_bytes: int = 0) -> int:
     """Pages a KV chunk holds: STEP_KEYS keys, under a window too (a 128-key
     window's q block reaches one or two such chunks and copies four times
     the keys it attends, which costs less than the two or three short steps
-    of 128-key chunks did: a lane group's step has a latency floor)."""
-    return max(1, STEP_KEYS // page_size)
+    of 128-key chunks did: a lane group's step has a latency floor).  Over
+    pool rows of `row_bytes` (0: not said) the keys are halved until the two
+    K and two V buffers fit CHUNK_VMEM_BYTES: a merged row of 3,840 lanes
+    (30 KV heads x 128) takes 256 keys a chunk, 7.9 MB, where 512 would be
+    15.7 MB beside the q block's 12."""
+    keys = STEP_KEYS
+    while keys > 128 and 4 * keys * row_bytes > CHUNK_VMEM_BYTES:
+        keys //= 2
+    return max(1, keys // page_size)
 
 
 def prefill_plan(S: int, num_q_heads: int, num_kv_heads: int, head_dim: int,
@@ -377,7 +386,9 @@ def paged_prefill_attention(
     kvg, gl, qb = (plan[k] for k in
                    ("kv_heads_per_group", "group_lanes", "q_block"))
     n_groups = Hkv // kvg
-    cp = min(pages_per_chunk or chunk_pages(page_size), page_row.shape[0])
+    cp = min(pages_per_chunk
+             or chunk_pages(page_size, HD * k_pool.dtype.itemsize),
+             page_row.shape[0])
     k_pages = k_pool.reshape(-1, page_size, HD)
     v_pages = v_pool.reshape(-1, page_size, HD)
 

@@ -56,7 +56,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .state_slot import chunk_slots, load_state, store_state
+from .state_slot import chunk_slots, kernel_form, load_state, store_state
 
 CHUNK = 64        # rows of one triangular system
 SUB = 16          # rows of a sub-block: one reference point for the decay
@@ -286,6 +286,14 @@ def _scan_xla(q, k, v, g, beta, S0):
     return jnp.swapaxes(o, 0, 1), S
 
 
+def form(kernel: bool, cached: bool, S: int, own_slots: bool, dk: int,
+         dv: int) -> str:
+    """Which form a pass of S rows a lane takes, "kernel" or "xla"
+    (state_slot.kernel_form: `gated_delta`'s own rule)."""
+    return kernel_form(kernel, cached, S, own_slots, chunk_rows(S),
+                       not (dk % 128 or dv % 128))
+
+
 def gated_delta(leaf, layer, plan, q, k, v, g, beta, *, kernel: bool,
                 read_state, write_state):
     """The layer's recurrence over a pass, from each lane's state and back
@@ -303,9 +311,8 @@ def gated_delta(leaf, layer, plan, q, k, v, g, beta, *, kernel: bool,
     beta = jnp.where(real, beta, 0.0)
     tiles = chunk_rows(S) if S > 1 else 1
     on_chip = jax.default_backend() == "tpu"
-    if (not kernel or leaf is None or tiles is None
-            or (S == 1 and plan.src is not None)
-            or (on_chip and (dk % 128 or dv % 128))):
+    if form(kernel, leaf is not None, S, plan.src is not None, dk,
+            dv) == "xla":
         S0 = (jnp.zeros((B, H, dv, dk), _F32) if leaf is None
               else read_state(leaf, layer, plan, B).astype(_F32).reshape(
                   B, H, dv, dk))

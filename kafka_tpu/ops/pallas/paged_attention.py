@@ -90,6 +90,25 @@ NEG_INF = -1e30
 # 0.433 -> 0.430 (Yi), 0.701 -> 0.702.
 STEP_ROWS = 512
 RING = 3
+# The bytes the ring's K and V buffers may hold together, and the scoped VMEM
+# a call whose ring passes RING_VMEM_DEFAULT is compiled with.  A step
+# shrinks, never the row: at Olmo-Hybrid's 30 KV heads x 128 a merged row is
+# 3,840 lanes, 3.75 x the widest before it (1,024: a ring of 6.3 MB at 512
+# keys a step), and 512 keys a step would be 23.6 MB of ring; 256 are 11.8
+# MB, which with the step's temporaries passes the 16 MB a call gets unasked.
+RING_VMEM_BYTES = 12 << 20
+RING_VMEM_DEFAULT = 8 << 20
+RING_VMEM_LIMIT = 40 << 20
+
+
+def step_rows(row_bytes: int) -> int:
+    """Keys a softmax step attends for pool rows of `row_bytes` (0: not
+    said, STEP_ROWS): STEP_ROWS halved until the ring fits RING_VMEM_BYTES,
+    never under a 128-key chunk."""
+    rows = STEP_ROWS
+    while rows > 128 and 2 * RING * rows * row_bytes > RING_VMEM_BYTES:
+        rows //= 2
+    return rows
 
 
 def _attend(q_ref, kbuf, vbuf, m_ref, l_ref, acc_ref, slot, scale,
@@ -391,6 +410,8 @@ def _decode_kernel(
 # _decode_kernel's pipeline runs from one lane's program into the next: the
 # grid is one core's, in order.
 _LANES_IN_ORDER = pltpu.CompilerParams(dimension_semantics=("arbitrary",))
+_LANES_IN_ORDER_WIDE = pltpu.CompilerParams(
+    dimension_semantics=("arbitrary",), vmem_limit_bytes=RING_VMEM_LIMIT)
 
 
 def _fori(lo: int, hi: int, body, carry):
@@ -411,7 +432,8 @@ def pages_one_run(page_at, base, n: int, fori=_fori):
 
 
 def decode_step_runs(pages, seq_len: int, window: int | None, page_size: int,
-                     max_pages: int, pages_per_chunk: int = 8) -> tuple:
+                     max_pages: int, pages_per_chunk: int = 8,
+                     row_bytes: int = 0) -> tuple:
     """(whole, run) softmax steps of _decode_kernel's walk over a lane whose
     page list is `pages` with `seq_len` cached tokens, under a page table
     `max_pages` wide: the steps before the walk's last, each fetched as sp
@@ -419,7 +441,7 @@ def decode_step_runs(pages, seq_len: int, window: int | None, page_size: int,
     kernel's own arithmetic on plain ints: the tests' and the bench script's
     oracle (the engine counts a global walk's steps a lane at a time,
     StepPrograms.decode_steps, from SequencePages.run_steps)."""
-    cp, sp = step_pages(max_pages, pages_per_chunk, page_size)
+    cp, sp = step_pages(max_pages, pages_per_chunk, page_size, row_bytes)
     first, end = decode_chunk_range(seq_len, window, page_size, cp)
     page0 = first * cp
     n_pages = -(-(seq_len + 1) // page_size)
@@ -506,12 +528,14 @@ def paged_decode_attention_window(
                          diff)
 
 
-def step_pages(P: int, pages_per_chunk: int, page_size: int) -> tuple:
+def step_pages(P: int, pages_per_chunk: int, page_size: int,
+               row_bytes: int = 0) -> tuple:
     """(pages a DMA chunk, pages a softmax step) of _decode_kernel's walk for
-    a page table of width P: whole chunks a step, STEP_ROWS keys if the table
-    names that many."""
+    a page table of width P over pool rows of `row_bytes`: whole chunks a
+    step, `step_rows` keys if the table names that many."""
     cp = min(pages_per_chunk, P)
-    return cp, cp * max(1, min(STEP_ROWS // (cp * page_size), -(-P // cp)))
+    return cp, cp * max(1, min(step_rows(row_bytes) // (cp * page_size),
+                               -(-P // cp)))
 
 
 @functools.partial(
@@ -607,7 +631,10 @@ def _paged_decode(q, k_pool, v_pool, page_table, seq_lens, page_size,
     P = page_table.shape[1]
     if scale is None:
         scale = D**-0.5
-    cp, sp = step_pages(P, pages_per_chunk, page_size)
+    row_bytes = HD * k_pool.dtype.itemsize
+    cp, sp = step_pages(P, pages_per_chunk, page_size, row_bytes)
+    # (a ring past what a call gets unasked is compiled with room for it)
+    wide = 2 * RING * sp * page_size * row_bytes > RING_VMEM_DEFAULT
 
     # Block-diagonal query expansion (see module docstring): qx[b, qh] has
     # q[b, qh] in its own kv head's D-lane block and zeros elsewhere.
@@ -656,7 +683,7 @@ def _paged_decode(q, k_pool, v_pool, page_table, seq_lens, page_size,
         out_shape=jax.ShapeDtypeStruct((B, Hq, HD), q.dtype),
         interpret=interpret,
         name=None if window is None else "paged_decode_attention_window",
-        compiler_params=_LANES_IN_ORDER,
+        compiler_params=_LANES_IN_ORDER_WIDE if wide else _LANES_IN_ORDER,
     )(page_table, seq_lens, qx, k_pool, v_pool)
     if diff:
         # each query row's result over BOTH value heads of its pair

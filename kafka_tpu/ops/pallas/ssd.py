@@ -52,7 +52,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .state_slot import chunk_slots, load_state, store_state
+from .state_slot import chunk_slots, kernel_form, load_state, store_state
 
 CHUNK = 128        # rows of one chunk (`mamba_chunk_size`)
 SMALL = (16, 32, 64)  # launches of fewer rows are one chunk
@@ -353,6 +353,14 @@ def _scan_xla(x, Bm, Cm, g, S0):
     return jnp.swapaxes(y, 0, 1), S
 
 
+def form(kernel: bool, cached: bool, S: int, own_slots: bool, H: int,
+         groups: int, P: int, N: int) -> str:
+    """Which form a pass of S rows a lane takes, "kernel" or "xla"
+    (state_slot.kernel_form: `ssd`'s own rule)."""
+    return kernel_form(kernel, cached, S, own_slots, chunk_rows(S),
+                       tiles(H, groups, P, N))
+
+
 def ssd(leaf, layer, plan, x, Bm, Cm, g, *, kernel: bool, read_state,
         write_state):
     """The layer's recurrence over a pass, from each lane's state and back
@@ -371,9 +379,8 @@ def ssd(leaf, layer, plan, x, Bm, Cm, g, *, kernel: bool, read_state,
     x = jnp.where(real[..., None], x, 0.0)
     rows = chunk_rows(S) if S > 1 else 1
     on_chip = jax.default_backend() == "tpu"
-    if (not kernel or leaf is None or rows is None
-            or (S == 1 and plan.src is not None)
-            or (on_chip and not tiles(H, groups, P, N))):
+    if form(kernel, leaf is not None, S, plan.src is not None, H, groups, P,
+            N) == "xla":
         S0 = (jnp.zeros((B, H, P, N), _F32) if leaf is None
               else read_state(leaf, layer, plan, B).astype(_F32).reshape(
                   B, H, P, N))

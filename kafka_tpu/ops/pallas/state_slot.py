@@ -9,9 +9,27 @@ block a launch and no copy of the leaf.
 
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+
+def kernel_form(kernel: bool, cached: bool, S: int, own_slots: bool, chunk,
+                tiles: bool) -> str:
+    """Which form a pass of S rows a lane takes of a state's recurrence,
+    "kernel" or "xla": the one rule of `gated_delta`, `gdn` and `ssd`, from
+    what is known when the program is traced.  `kernel`: the Pallas backend;
+    `cached`: there is a leaf to update; `own_slots`: a launch that names its
+    lanes' slots (a prefill: one row of it is not decode's step); `chunk`:
+    the rows of a chunk the module's chunk kernel takes of S > 1 rows (None:
+    it does not tile them); `tiles`: the geometry is one the CHIP's compiler
+    takes (the interpreter takes any)."""
+    if (not kernel or not cached or (S > 1 and chunk is None)
+            or (S == 1 and own_slots)
+            or (jax.default_backend() == "tpu" and not tiles)):
+        return "xla"
+    return "kernel"
 
 
 def chunk_slots(plan, B: int):

@@ -1520,6 +1520,14 @@ class InferenceEngine:
         # StepPrograms.ssd_chunk_trips / ssd_state_bytes
         self.ssd_chunk_trips = 0
         self.ssd_state_bytes = 0
+        # Monotonic, all 0 for a model without a state: passes x state
+        # layers of the dispatched programs by the FORM each op of a state
+        # layer took, the recurrence and the convolution's tail as a Pallas
+        # kernel or in XLA (StepPrograms.state_forms: decided by shapes when
+        # a program is traced, and seen in a device capture only before)
+        self.state_launches = dict.fromkeys(
+            (f"{op}_{form}" for op in ("recurrence", "tail")
+             for form in ("kernel", "xla")), 0)
         # ... and the rows x SSD layers of the dispatched launches, padding
         # included, counted where prefill_rows_dispatched is
         self.ssd_rows_dispatched = 0
@@ -4590,6 +4598,13 @@ class InferenceEngine:
             len(spans), bucket)
         self.hc_site_rows += self._hc_sites * width * bucket
         self._count_moe_dispatch(width * bucket)
+        self._count_state_launches(bucket, True)
+
+    def _count_state_launches(self, rows: int, own_slots: bool,
+                              passes: int = 1) -> None:
+        for op, form in self._programs.state_forms(rows, own_slots).items():
+            self.state_launches[f"{op}_{form}"] += (
+                passes * self.cfg.state_layers)
 
     def _count_moe_dispatch(self, rows: int, passes: int = 1) -> None:
         form = self._programs.moe_dispatch(rows)
@@ -4634,6 +4649,7 @@ class InferenceEngine:
                 len(seqs), steps)
         self.hc_site_rows += self._hc_sites * len(members) * steps
         self._count_moe_dispatch(len(members), steps)
+        self._count_state_launches(1, False, steps)
         if self.cfg.index_topk:
             scored, kept = self._programs.index_keys(
                 [seq.length for seq in seqs], steps)

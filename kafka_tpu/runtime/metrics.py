@@ -455,6 +455,16 @@ METRICS: Tuple[Metric, ...] = (
         _m("delta_state_bytes", read="delta_state_bytes"),
         _m("ssd_chunk_trips", read="ssd_chunk_trips"),
         _m("ssd_state_bytes", read="ssd_state_bytes"),
+        # passes x state layers of the dispatched programs by the form each
+        # op of a state layer took (engine.state_launches)
+        *(_m(f"state_launches_{op}_{form}",
+             f"kafka_tpu_engine_state_launches_total{{op={op},form={form}}}",
+             "Passes x state layers of the dispatched programs by the form "
+             "each op of a state layer took: the recurrence and the "
+             "convolution's tail, as a Pallas kernel or in XLA."
+             if (op, form) == ("recurrence", "kernel") else "",
+             read=lambda e, k=f"{op}_{form}": e.state_launches[k])
+          for op in ("recurrence", "tail") for form in ("kernel", "xla")),
         # rows x SSD layers of the dispatched launches, padding included,
         # counted where prefill_rows_dispatched is (0 without an SSD mixer)
         _m("ssd_rows_dispatched", read="ssd_rows_dispatched"),

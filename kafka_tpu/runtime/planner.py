@@ -413,7 +413,9 @@ def activation_bytes_estimate(
         # [q | k | v] and the convolution over them, then the chunk kernel's
         # six float32 operands (q, k, beta k, beta v, the log-decay and its
         # cumulative sum) and its output, [S, heads x head size] each
-        prefill += s_local * cfg.delta_heads * cfg.delta_head_dim * (
+        # (a third of the convolutions' channels: heads x head size where
+        # the heads are square)
+        prefill += s_local * (cfg.delta_conv_dim // 3) * (
             3 * 2 + 3 * 4 * 2 + 7 * 4)
     elif cfg.has_state:
         # a short convolution's [B | C | u] and its float32 products

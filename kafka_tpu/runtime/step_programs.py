@@ -37,6 +37,7 @@ from ..models.ffn import experts_int8, moe_dispatch_form
 from ..models.llama import forward
 from ..models.mixers.index import INDEX_WALK_KEYS, walk_pages
 from ..models.mixers.latent import prefill_walk_pages
+from ..models.mixers.state import state_launch_forms
 from ..ops.attention import decode_walk_pages, shared_walk_trips
 from ..ops.pallas import ssd as ssd_kernels
 from ..ops.pallas.gated_delta import chunk_rows
@@ -623,7 +624,12 @@ class StepPrograms:
                 or self.int8_kv
                 or (mesh is not None and mesh.shape.get("pp", 1) > 1)):
             return 0, 0, 0, 0
-        _, sp = step_pages(self.P, 8, self.ps)  # the wrappers' default chunk
+        # (the wrappers' default chunk; a GQA pool's merged row sizes the step)
+        cfg = self.cfg
+        row_bytes = 0 if cfg.is_latent else (
+            cfg.num_kv_heads * cfg.head_dim
+            * jnp.dtype(cfg.activation_dtype).itemsize)
+        _, sp = step_pages(self.P, 8, self.ps, row_bytes)
         keys = sp * self.ps
         walked = run = every = ahead = 0
         for lane, seq in enumerate(lanes):
@@ -716,6 +722,14 @@ class StepPrograms:
         cfg = self.cfg
         return (2 * 4 * cfg.layers_of(DELTA) * cfg.delta_heads
                 * cfg.delta_head_dim ** 2 * lanes * steps)
+
+    def state_forms(self, rows: int, own_slots: bool):
+        """{op: form} of ONE pass of `rows` rows a lane through the state
+        layers (models/mixers/state.state_launch_forms: the rule each mixer
+        itself asks when the program is traced; `own_slots`: a prefill
+        launch, which names its lanes' slots); {} for a model without a
+        state."""
+        return state_launch_forms(self.cfg, rows, own_slots)
 
     def _ssd_layers(self) -> int:
         """Layers that hold an SSD mixer, beside attention or alone."""

@@ -220,10 +220,14 @@ DEVICE_SCOPES = (
                     # pass] with the tail's read from and write to the state
                     # slot, and the C * gate
     # the linear-attention layout's mixer
-    # (models/mixers/state._delta_attention_block)
+    # (models/mixers/state._delta_attention_block), in both its forms: a
+    # decay a key channel (`solar_open2`) and Gated DeltaNet's one decay a
+    # head (`olmo_hybrid`, whose post-norm on the mixer's output sits under
+    # kda_proj with the add it precedes, as the feed-forward's under mlp)
     "kda_proj",     # a gated delta-rule layer's projections: W_q, W_k, W_v,
-                    # the decay's and the output gate's low-rank pairs,
-                    # W_beta, W_o (+ residual add)
+                    # the decay's and the output gate's (low-rank pairs, or
+                    # W_a and the full-rank gate), W_beta, W_o (+ residual
+                    # add)
     "kda_conv",     # its three short convolutions + SiLU, and the tails'
                     # read from and write to the state slot
     "kda_gate",     # its elementwise parts: the L2 norms of q and k, the

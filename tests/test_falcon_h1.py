@@ -290,6 +290,11 @@ NEW_ACCOUNT = {
     "file:granite-4.0-h-small.json": [
         1, 9, [["conv", [8, 3168]], ["ssd", [8192, 128]]], 38661120,
         2.0 * 1 * 32 * 256],
+    # (PR 66: rows in the 4 multi-head attention layers, a Gated DeltaNet
+    # state laid [96, 5760] in 12; 30 heads x 2 x 128)
+    "file:olmo-hybrid-7b.json": [
+        4, 12, [["conv", [8, 4320]], ["delta", [96, 5760]]], 28200960,
+        2.0 * 4 * 30 * 256],
 }
 
 

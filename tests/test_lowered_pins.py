@@ -7,7 +7,7 @@ two-lane batched prefill launch (from shapes alone,
 `tests/test_moe_dispatch.py`'s way; the full-width files too, which lower in
 seconds and hold no array) is what the parent commit lowers:
 `tests/recorded/lowered_pins.json` holds the digests, recorded AT THE PARENT
-(67ed503, PR 63, for PR 64) by running this file in a checkout of it with
+(48aecb9, PR 64, for PR 66) by running this file in a checkout of it with
 `KAFKA_TPU_RECORD_PINS=<path>` (same conftest, same JAX).  Equal text = the
 same executable and a warm compile cache across the two trees.
 
@@ -45,20 +45,14 @@ from kafka_tpu.runtime.kv_cache import default_state_slots, make_kv_pool_arrays
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 PINS = os.path.join(ROOT, "tests", "recorded", "lowered_pins.json")
 RECORD = os.environ.get("KAFKA_TPU_RECORD_PINS")
-# the files of a model this very PR adds (docstring): not pinned
-NEW = ()
-# the programs this very PR means to move (docstring).  PR 64: the decode step
-# of the three configurations whose state layers' convolution tail tiles for
-# `ops/pallas/tail_conv.tail_conv_step`, on the Pallas backend (`STATE_PALLAS`
-# below: pinned from this PR on, recorded at the parent like the rest).  Their
-# batched prefill does not move (S > 1 keeps `_tail_conv_silu`'s body), nor
-# does either Pallas program of Granite, whose (8, 3168) tail the rule
-# declines, nor any `xla` program, nor any tiny twin (their tails are narrower
-# than a lane tile).
-MOVED = frozenset(
-    f"file:{name}.pallas.decode"
-    for name in ("solar-open2-250b", "nemotron-3-nano-30b-a3b",
-                 "falcon-h1-34b"))
+# the files of a model this very PR adds (docstring): not pinned.  PR 66:
+# Olmo-Hybrid-7B's configuration and its tiny twin.
+NEW = ("olmo-hybrid-7b", "tiny-olmohybrid")
+# the programs this very PR means to move (docstring).  PR 66 moves none: the
+# shared delta block, the kernels' call sites, the residual form and the
+# decode kernel's step size trace nothing new where the new fields are absent
+# (PR 64's three moved decode steps are pinned as they stood at its end).
+MOVED = frozenset()
 PS, LANES, PAGES, BUCKET, WIDTH = 8, 4, 8, 16, 2
 
 

@@ -221,6 +221,9 @@ PARENT_WEIGHT_BYTES = {
     "mixtral-8x7b": 6_329_376_768,
     # (PR 60: 7 layers hold experts, stacked per kind; no parent to compare)
     "nemotron-3-nano-30b-a3b": 10_565_072_896,
+    # (PR 66: 12 linear + 4 full layers, dense, the whole vocabulary; no
+    # parent to compare)
+    "olmo-hybrid-7b": 8_201_579_328,
     "phi-4-mini-flash-reasoning": 7_707_253_760,
     "solar-open2-250b": 7_797_691_392,
     "xing4.0-29b-a4b": 11_332_171_968,

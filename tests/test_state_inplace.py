@@ -421,3 +421,68 @@ ENTRY %main.7 (a: f32[2,5,3,8]) -> f32[2,5,3,8] {
                   for r in rows) == [("copy-done.3", "body.1", True),
                                      ("copy.1", "main.7", False),
                                      ("copy.2", "inner", True)]
+
+
+@pytest.mark.parametrize("kernel", ["gdn_step", "gdn_chunk"])
+def test_the_gdn_kernels_compile_for_the_chip_at_the_published_heads(
+        one_chip, kernel):
+    """ISSUE 66: `gdn_step` (16 lanes, a lane's whole [96, 5760] block a grid
+    step) and `gdn_chunk` (512 rows, a pair of heads a grid step) at
+    Olmo-Hybrid's 30 heads x 96 x 192, where no head starts on a lane tile,
+    compiled for a described v5e (what the interpreter cannot show: every
+    slice a whole tile, the blocks inside VMEM): one custom call, the leaf
+    aliased through, no copy."""
+    from kafka_tpu.ops.pallas import gdn
+
+    H, dk, dv = 30, 96, 192
+    assert gdn.tiles(H, dk, dv)
+
+    def of(dtype, *shape):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    f32, i32 = jnp.float32, jnp.int32
+    leaf = of(f32, 12, 65, dk, H * dv)
+    if kernel == "gdn_step":
+        B = 16
+        args = [leaf, of(i32), of(i32, B), of(f32, B, H * 128),
+                of(f32, B, H * 128)] + [of(f32, B, H * dv)] * 3
+        fn = lambda *a: gdn.gdn_step(*a, dv=dv, interpret=False)  # noqa: E731
+    else:
+        B, S = 1, 512
+        args = ([leaf, of(i32)] + [of(i32, B)] * 4
+                + [of(f32, B, S, H * 128)] * 2
+                + [of(f32, B, S, H * dv), of(f32, B, S, H), of(f32, B, S, H)])
+        fn = lambda *a: gdn.gdn_chunk(  # noqa: E731
+            *a, dv=dv, chunk=64, interpret=False)
+    text = jax.jit(fn, donate_argnums=0).lower(*args).compile().as_text()
+    assert text.count("tpu_custom_call") == 1 and kernel in text
+    assert _leaf_copies().leaf_copies(
+        text, {_leaf_copies().hlo_shape(leaf)}) == []
+    assert "input_output_alias" in text
+
+
+def test_the_served_olmo_hybrid_decode_step_runs_kernels_on_its_state(
+        one_chip):
+    """The engine's decode program at Olmo-Hybrid's PUBLISHED widths,
+    compiled for a described v5e: the recurrence is `gdn_step` (no row-by-row
+    scan's `while` over the state), attention is `paged_decode_attention` at
+    a merged row of 3,840 lanes with its ring inside the VMEM the call asks
+    for, and neither state leaf is copied (the tail's XLA body too reads and
+    writes its slot where it lies)."""
+    lc = _leaf_copies()
+    path = os.path.join(ROOT, "benchmarks", "configs", "olmo-hybrid-7b.json")
+    with open(path) as f:
+        spec = json.load(f)
+    cfg = config_from_hf_json(path).replace(
+        dtype=spec["serving"]["dtype"],
+        attention_backend=spec["expect"]["attention_backend"])
+    assert cfg.attention_backend == "pallas"
+    programs, leaves = lc.engine_programs(cfg, spec["serving"], one_chip)
+    assert leaves == {"conv": "f32[12,65,8,4320]",
+                      "delta": "f32[12,65,96,5760]"}
+    # (the paged-decode kernel does not compile under the suite's "highest")
+    with jax.default_matmul_precision("default"):
+        text = lc.compile_for(*programs["decode"], "decode").as_text()
+    assert "gdn_step" in text and "paged_decode_attention" in text
+    assert "tail_conv_step" not in text  # pieces of 11.25 lane tiles
+    assert lc.leaf_copies(text, set(leaves.values())) == []
