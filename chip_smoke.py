@@ -632,8 +632,11 @@ class Parent:
                   f"cache hit={totals['by_cache']['hit']} "
                   f"miss={totals['by_cache']['miss']} "
                   f"off={totals['by_cache']['off']} "
+                  f"store={totals['by_cache'].get('store', 0)} "
                   f"dir={c.get('cache_dir')}")
-        hit, miss = totals["by_cache"]["hit"], totals["by_cache"]["miss"]
+        # (a step program the program store loaded was not compiled at all)
+        hit = totals["by_cache"]["hit"] + totals["by_cache"].get("store", 0)
+        miss = totals["by_cache"]["miss"]
         self.fact(f"{leg}.boot_seconds",
                   f"{boot_s:.1f} (" + ("cold compile" if hit == 0 else
                   f"cache hit on {hit} of {hit + miss} programs") + ")")

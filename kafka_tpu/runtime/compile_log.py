@@ -15,7 +15,9 @@ compile axis:
   the function unchanged and no listener ever registers).  One record =
   one compilation: program label (``step_programs``' cache tag),
   wall-clock seconds, persistent-cache disposition (``hit`` / ``miss``
-  / ``off`` — the directory :func:`enable_compile_cache` reports),
+  / ``off`` — the directory :func:`enable_compile_cache` reports; or
+  ``store``: no compilation at all, the program store beside that cache
+  (``program_store.py``) loaded the executable, in that many seconds),
   and the engine phase that triggered it (``boot`` / ``warmup`` /
   ``first_traffic`` / ``rebuild``).
 
@@ -54,6 +56,8 @@ import os
 import threading
 import time
 from typing import Any, Callable, Dict, List, Optional
+
+from . import program_store
 
 logger = logging.getLogger("kafka_tpu.compile")
 
@@ -138,7 +142,8 @@ class CompileObservatory:
         # totals (monotone counters)
         self.compiles_total = 0
         self.compile_seconds_total = 0.0
-        self.by_cache: Dict[str, int] = {"hit": 0, "miss": 0, "off": 0}
+        self.by_cache: Dict[str, int] = {"hit": 0, "miss": 0, "off": 0,
+                                         "store": 0}
         self.by_phase: Dict[str, int] = {p: 0 for p in PHASES}
         # seconds in the stages ahead of the backend compile, by stage
         # ("trace", "lower") then by phase: no ring record, sums only
@@ -295,6 +300,7 @@ class CompileObservatory:
                 **{f"{stage}_seconds_by_phase":
                    {p: round(v, 4) for p, v in by.items()}
                    for stage, by in self.stage_seconds.items()},
+                **program_store.counters(),
             }
 
     def signals_section(self) -> Dict[str, Any]:
@@ -450,10 +456,24 @@ def get_phase() -> Optional[str]:
 
 def configure_cache(cache_dir: Optional[str]) -> None:
     """Tell the observatory whether a persistent compile cache is in
-    play (decides the default cache disposition: off vs miss)."""
+    play (decides the default cache disposition: off vs miss).  No cache,
+    no program store either: it lives under the cache's directory."""
+    if not cache_dir:
+        program_store.enable(None)
     obs = _OBS
     if obs is not None:
         obs.cache_dir = cache_dir or None
+
+
+def record_store_load(label: str, seconds: float) -> None:
+    """The program store loaded `label`'s executable on this thread: one
+    ring record of disposition ``store`` (a load is what the boot did
+    INSTEAD of a compile), and the instrument() wrapper around the call
+    stands down as it does for a compile the listener saw."""
+    obs = _OBS
+    if obs is not None:
+        obs._tls.observed = True
+        obs.record(label, seconds, cache="store")
 
 
 def compile_cache_enabled() -> bool:
@@ -478,9 +498,26 @@ def compile_cache_dir() -> str:
     return os.path.join(checkout, ".jax_cache")
 
 
+def flat_locations() -> None:
+    """An op's location is the line that made it, not its callers' too.  A
+    Mosaic kernel rides in its custom call's `backend_config` WITH its
+    locations, which the compile cache's key does not strip, and jax keeps a
+    kernel's traced body for whoever lowers it next: with tracebacks in
+    them, what a program lowered to depended on who had traced the kernel
+    first (the benchmark's logit check missed the cache, +50 s in Granite,
+    on the first boot that LOADED the step programs it used to follow).
+    Set wherever the cache is on; `scripts/program_store.py verify` sets it
+    to lower as a server does."""
+    import jax
+
+    jax.config.update("jax_include_full_tracebacks_in_locations", False)
+
+
 def enable_compile_cache() -> str:
-    """Turn the persistent compile cache on at compile_cache_dir() and
-    report it to the observatory.  Call before the first jax.jit."""
+    """Turn the persistent compile cache on at compile_cache_dir(), and
+    with it the program store under it (program_store.py: the second boot
+    loads its step programs), and report it to the observatory.  Call
+    before the first jax.jit."""
     import jax
 
     path = compile_cache_dir()
@@ -491,7 +528,9 @@ def enable_compile_cache() -> str:
         jax.config.update("jax_compilation_cache_dir", path)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 1)
+    flat_locations()
     configure_cache(path)
+    program_store.enable(path)
     return path
 
 

@@ -867,6 +867,10 @@ METRICS: Tuple[Metric, ...] = (
              "create_app's first line to its return."
              if stage == BOOT_STAGES[0] else "", merge=None, digits=3)
           for stage in BOOT_STAGES),
+        # seconds the program store (runtime/program_store.py) spent
+        # reading and loading step programs: what a warm boot pays in
+        # place of tracing, lowering and looking them up
+        _m("store_load_s", merge=None, digits=3),
     ),
     *_section(
         "metrics",
@@ -932,6 +936,15 @@ METRICS: Tuple[Metric, ...] = (
         _m("lower_seconds_total", merge=None),
         _m("trace_seconds_by_phase", kind=DETAIL, merge=None),
         _m("lower_seconds_by_phase", kind=DETAIL, merge=None),
+        # the program store beside the persistent cache: step-program
+        # variants loaded with no trace, compiled and written, and handed
+        # back to the jit by a call that did not match the stored one
+        *(_m(key, "kafka_tpu_program_store_total{event=%s}" % event,
+             "Step-program variants by what the program store did with "
+             "them." if event == "hit" else "", merge=None)
+          for key, event in (("store_hits", "hit"),
+                             ("store_misses", "miss"),
+                             ("store_fallbacks", "fallback"))),
     ),
 )
 

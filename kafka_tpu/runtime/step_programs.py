@@ -48,7 +48,7 @@ from ..ops.sampling import (
     grammar_allowed_mask,
     sample_tokens_per_slot,
 )
-from . import compile_log
+from . import compile_log, program_store
 
 # Compiled step functions are cached per (model cfg, engine shape) so that
 # multiple engine instances (tests, restarts) reuse compilations.
@@ -77,12 +77,21 @@ def program_name(label: str) -> str:
     return "fn_" + label.replace("[", "_").replace("]", "")
 
 
-def _jit_step(label: str, fn: Callable) -> Callable:
+def _jit_step(label: str, fn: Callable, key: Any = None,
+              recipe: Optional[Tuple] = None) -> Callable:
     """Jit one engine step program (k/v pools donated) under the name its
     label gives and hand it to the compile observatory, so compile records
-    and trace module names cannot drift apart."""
+    and trace module names cannot drift apart.  Where the persistent compile
+    cache is on, the program store stands between the two
+    (program_store.wrap: `key`, what the program is cached under in
+    `_PROGRAMS`, goes into its key, and `recipe`, how to build `fn` again,
+    beside its entry): the executable of a program it holds is loaded, not
+    traced."""
     fn.__name__ = fn.__qualname__ = program_name(label)
-    return compile_log.instrument(label, jax.jit(fn, donate_argnums=(1, 2)))
+    jitted = jax.jit(fn, donate_argnums=program_store.DONATED)
+    if key is not None:
+        jitted = program_store.wrap(label, jitted, key, recipe)
+    return compile_log.instrument(label, jitted)
 
 
 class Lanes(NamedTuple):
@@ -561,8 +570,12 @@ class StepPrograms:
         if fn is None:
             fn = _PROGRAMS.get(key)
             if fn is None:
+                # (a program over a mesh is left to the jit: no store key)
                 fn = _PROGRAMS[key] = _jit_step(
-                    label, make(self.cfg, self.mesh, self.ps, *args))
+                    label, make(self.cfg, self.mesh, self.ps, *args),
+                    None if self.mesh is not None else
+                    (key, self.int8_experts, self.int8_kv),
+                    (make.__name__, self.cfg, self.ps, args))
             self.built[(label, fsm_key)] = fn
         return fn
 

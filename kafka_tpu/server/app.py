@@ -1416,10 +1416,14 @@ def _snapshot_scope():
 
 def _metrics_response(request: web.Request, engine,
                       cost: Dict[str, Any]) -> web.Response:
+    from ..runtime import program_store
+
     snap = engine.metrics.snapshot(engine)
-    # the boot by stage (tracing.BOOT_STAGES), and the seconds and count of
-    # the replies built before this one
-    snap["boot"] = _state(request)["boot"].section()
+    # the boot by stage (tracing.BOOT_STAGES) with the seconds the program
+    # store spent loading step programs, and the seconds and count of the
+    # replies built before this one
+    snap["boot"] = {**_state(request)["boot"].section(),
+                    "store_load_s": round(program_store.load_seconds(), 3)}
     snap["metrics"] = {"snapshot_s": round(cost["snapshot_s"], 6),
                        "snapshots": cost["snapshots"]}
     edges = _state(request)["sched_window"]

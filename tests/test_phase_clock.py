@@ -557,7 +557,9 @@ class TestSections:
                 provider.worker.stop()
 
         first, again, text, profile, after = asyncio.run(go())
-        assert set(first["boot"]) == {s + "_s" for s in BOOT_STAGES}
+        # (the stages, and what the program store spent loading programs)
+        assert set(first["boot"]) == {s + "_s" for s in BOOT_STAGES} | {
+            "store_load_s"}
         assert first["boot"] == again["boot"]  # closed when the app was built
         assert first["boot"]["rest_s"] > 0.0
         assert first["metrics"] == {"snapshot_s": 0.0, "snapshots": 0}

@@ -389,12 +389,13 @@ def _imports(path):
 
 def test_step_programs_does_not_import_the_engine():
     """One direction only: engine -> step_programs.  Every import in the
-    module names models, ops, parallel or compile_log."""
+    module names models, ops, parallel, compile_log or program_store."""
     for name in _imports(ROOT / "runtime" / "step_programs.py"):
         assert "engine" not in name, name
         if name.startswith("."):
             assert re.match(
-                r"\.\.(models|ops|parallel)\.|\.\.compile_log$", name
+                r"\.\.(models|ops|parallel)\.|\.\.(compile_log|program_store)$",
+                name
             ), name
 
 
